@@ -42,7 +42,8 @@
 # stay >= 10B) and ``nvme_param.o_direct_stall_share`` (the O_DIRECT
 # pipelined leg's exposed-stall share of the step — the honest-cache
 # counterpart of the buffered stall gate) — gate against
-# BENCH_r19.json or newer to arm both.
+# a record that carries both (none is left in the tree: the PR-20
+# record was deleted with the harness it named; S1 re-records).
 #
 # The --candidate path never imports jax and finishes in <2 s, so this
 # runs on artifact files on any CI box. Typical wiring:

@@ -8,6 +8,7 @@ covers host-side ops (SIMD optimizer, async IO).
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,6 +21,24 @@ BUILD_DIR = os.path.join(CSRC, "build")
 
 _lock = threading.Lock()
 _cache = {}
+
+
+def loaded_ops():
+    """Names of the native libraries this process has loaded."""
+    return sorted(_cache)
+
+
+def _host_cpu_flags():
+    """The host CPU's feature flags: ``-march=native`` bakes them into the
+    library, so a build made on another machine must not be reused."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if line.startswith(("flags", "Features")):
+                    return line.split(":", 1)[1].strip()
+    except OSError:
+        pass
+    return ""
 
 
 class OpBuilder:
@@ -58,25 +77,48 @@ class OpBuilder:
         from shutil import which
         return which("g++") is not None
 
-    def command(self):
+    def flags(self):
         cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
                "-march=native", "-fopenmp"]
         if self._tsan():
             cmd += ["-fsanitize=thread", "-g", "-O1"]
-        return (cmd + self.extra_flags + self.absolute_sources()
-                + ["-o", self.so_path()])
+        return cmd + self.extra_flags
+
+    def command(self, out=None):
+        return (self.flags() + self.absolute_sources()
+                + ["-o", out or self.so_path()])
+
+    def build_key(self):
+        """Hash of everything the library depends on: source bytes, the
+        compile flags, and the host CPU (``-march=native``)."""
+        h = hashlib.sha256()
+        for part in self.flags() + [_host_cpu_flags()]:
+            h.update(part.encode() + b"\0")
+        for src in self.absolute_sources():
+            with open(src, "rb") as f:
+                h.update(f.read())
+        return h.hexdigest()
+
+    def key_path(self):
+        return self.so_path() + ".key"
 
     def needs_build(self):
-        so = self.so_path()
-        if not os.path.exists(so):
+        """True unless the cached library was built from these sources with
+        these flags on this CPU. A library without its key file (one copied
+        in from elsewhere) is rebuilt."""
+        try:
+            with open(self.key_path()) as f:
+                return f.read() != self.build_key() \
+                    or not os.path.exists(self.so_path())
+        except OSError:
             return True
-        so_mtime = os.path.getmtime(so)
-        return any(os.path.getmtime(s) > so_mtime
-                   for s in self.absolute_sources())
 
     def build(self):
         os.makedirs(BUILD_DIR, exist_ok=True)
-        cmd = self.command()
+        # build beside the target and rename: a concurrent process never
+        # loads a half-written library
+        tmp = f"{self.so_path()}.{os.getpid()}.tmp"
+        cmd = self.command(out=tmp)
         logger.info(f"[op_builder] building {self.name}: {' '.join(cmd)}")
         try:
             subprocess.check_output(cmd, stderr=subprocess.STDOUT)
@@ -88,6 +130,10 @@ class OpBuilder:
             except subprocess.CalledProcessError as e2:
                 raise RuntimeError(
                     f"failed to build {self.name}: {e2.output.decode()}") from e
+        os.replace(tmp, self.so_path())
+        with open(f"{self.key_path()}.{os.getpid()}.tmp", "w") as f:
+            f.write(self.build_key())
+        os.replace(f.name, self.key_path())
 
     def load(self):
         with _lock:

@@ -637,7 +637,14 @@ def _pick_block_l(L, H, D, itemsize, budget_bytes=1 << 21):
         blk //= 2
     while L % blk:
         blk //= 2
-    return max(blk, 1)
+    if blk < 8 and blk != L:
+        # an odd-ish cache length halves down to a 1-4 row block, which
+        # the TPU tiling refuses and which would crawl where it ran
+        raise ValueError(
+            f"cache length {L} has no row block of 8 or more dividing "
+            f"it; size the static KV cache to a multiple of 8 (128 for "
+            f"full tiles)")
+    return blk
 
 
 def _decode_attn_stacked_kernel(sc_ref, q_ref, *rest, scale, block_l,

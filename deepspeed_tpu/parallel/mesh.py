@@ -14,12 +14,12 @@ reference's topology axis order ['pipe','data','model']
 """
 
 import dataclasses
-import functools
 from typing import Optional, Sequence
 
 import numpy as np
 import jax
-from jax.sharding import Mesh, PartitionSpec, NamedSharding
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
+                          get_abstract_mesh)
 
 # Mesh axis names. ZeRO shards over DATA_AXIS; tensor parallelism over
 # MODEL_AXIS; pipeline stages over PIPE_AXIS; ring-attention/sequence
@@ -41,114 +41,11 @@ DATA_INTER_AXIS = "data_inter"
 DATA_INTRA_AXIS = "data_intra"
 
 
-# ---------------------------------------------------------------------------
-# shard_map compat shim
-#
-# Every explicit-comm program in this repo targets the modern `jax.shard_map`
-# API (top-level export, `axis_names=` manual subset, `check_vma=`). The
-# pinned jax (0.4.37) only has `jax.experimental.shard_map.shard_map` with the
-# older (check_rep, auto) signature, and two of the new API's features do not
-# exist there at all:
-#
-#   * partial-manual (`axis_names` a strict subset of the mesh axes) — the
-#     old `auto=` parameter is NotImplemented in eager mode and crashes XLA's
-#     SPMD partitioner under jit (IsManualSubgroup check failure), so the
-#     shim lowers `axis_names` to FULL-manual: axes the body does not name
-#     are simply absent from every spec, which replicates inputs over them at
-#     entry. Numerically identical (the bodies only ever bind the named
-#     axis); the cost is an entry gather when an input was sharded over an
-#     unnamed axis.
-#   * the VMA (varying-manual-axes) system — `check_vma` maps onto
-#     `check_rep`, and `pvary` (below) becomes a no-op. The old rep-checker
-#     predates VMA and rejects valid ppermute/cond carries, so the shim
-#     defaults it OFF unless explicitly requested via check_rep=True.
-#
-# All in-repo call sites import `shard_map`/`pvary` from here instead of
-# touching `jax.shard_map` / `jax.lax.pvary` directly.
-# ---------------------------------------------------------------------------
-
-#: True when the shim below lowers `axis_names` to FULL-manual (legacy
-#: jax). Callers that name secondary mesh axes in their specs to avoid the
-#: entry replication (see passthrough_axis) must only do so here — on
-#: modern jax the unnamed axes stay auto (partial-manual), specs may not
-#: mention them, and there is no replication to avoid.
-FULL_MANUAL_LOWERING = not hasattr(jax, "shard_map")
-
-if not FULL_MANUAL_LOWERING:         # modern jax: pass straight through
-    shard_map = jax.shard_map
-
-    def pvary(x, axis_names):
-        return jax.lax.pvary(x, tuple(axis_names))
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f=None, *, mesh, in_specs=None, out_specs=None,
-                  axis_names=None, check_vma=None, check_rep=None,
-                  auto=None):
-        """Modern `jax.shard_map` surface on the legacy experimental API.
-
-        ``axis_names``/``auto`` are accepted for source compatibility but the
-        lowering is always full-manual (see module comment); ``check_vma``
-        aliases ``check_rep`` and both default to False."""
-        if f is None:
-            return functools.partial(
-                shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                axis_names=axis_names, check_vma=check_vma,
-                check_rep=check_rep, auto=auto)
-        check = check_rep if check_rep is not None else check_vma
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs,
-                                 check_rep=bool(check) if check is not None
-                                 else False)
-
-    def pvary(x, axis_names):
-        """No-op on pre-VMA jax: with check_rep off there is no varying/
-        unvarying distinction to annotate."""
-        return x
-
-
-def passthrough_axis(mesh, axis: str, dim_size: int):
-    """``axis`` if the FULL-manual lowering is active and the axis exists in
-    ``mesh``, is live (>1), and divides ``dim_size`` — for naming secondary
-    axes in shard_map specs so their tiles pass through manually instead of
-    replicating at entry (the full-manual lowering's cost, see the shim
-    comment). None otherwise — in particular always None on modern jax,
-    where unnamed axes stay auto (no replication) and specs may only
-    mention axes in ``axis_names``."""
-    if not FULL_MANUAL_LOWERING:
-        return None
-    n = mesh.shape.get(axis, 1) if hasattr(mesh, "shape") else 1
-    if n > 1 and dim_size % n == 0:
-        return axis
-    return None
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a bound collective axis — `jax.lax.axis_size` compat
-    (that API landed after the pinned 0.4.37). On legacy jax, psum of a
-    Python literal constant-folds to the axis size at trace time."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
 def in_manual_region() -> bool:
     """True when the current trace sits inside an explicit-comm region
-    (shard_map Manual axes, or any bound collective axis on jax without
-    abstract-mesh introspection). Model layout pins must not apply there —
-    the data is already device-local."""
-    try:
-        from jax.sharding import get_abstract_mesh, AxisType
-        am = get_abstract_mesh()
-        if any(t == AxisType.Manual for t in getattr(am, "axis_types", ())):
-            return True
-    except ImportError:
-        pass
-    try:
-        from jax._src.core import get_axis_env
-        return bool(get_axis_env().axis_sizes)
-    except Exception:
-        return False
+    (shard_map Manual axes). Model layout pins must not apply there — the
+    data is already device-local."""
+    return any(t == AxisType.Manual for t in get_abstract_mesh().axis_types)
 
 
 _current_mesh: Optional[Mesh] = None
@@ -289,9 +186,9 @@ def make_mesh(config: Optional[MeshConfig] = None,
               axis_order: Sequence[str] = AXIS_ORDER) -> Mesh:
     """Build the global device mesh.
 
-    Prefers ``jax.experimental.mesh_utils.create_device_mesh`` so the logical
-    mesh lines up with the physical ICI torus; falls back to a plain reshape
-    for CPU meshes used in tests.
+    ``jax.experimental.mesh_utils.create_device_mesh`` lines the logical
+    mesh up with the physical ICI torus; a failure there raises. CPU
+    devices have no topology, so test meshes are a plain reshape.
     """
     if devices is None:
         devices = jax.devices()
@@ -303,11 +200,11 @@ def make_mesh(config: Optional[MeshConfig] = None,
         SEQ_AXIS: config.seq,
         MODEL_AXIS: config.model,
     }[a] for a in axis_order)
-    try:
+    if devices[0].platform == "cpu":
+        dev_array = np.asarray(devices).reshape(shape)  # sync-ok: host device list
+    else:
         from jax.experimental import mesh_utils
         dev_array = mesh_utils.create_device_mesh(shape, devices=list(devices))
-    except Exception:
-        dev_array = np.asarray(devices).reshape(shape)  # sync-ok: host device list
     return Mesh(dev_array, axis_names=tuple(axis_order))
 
 
@@ -327,18 +224,6 @@ def split_data_axis(mesh: Mesh, inter: int) -> Mesh:
     shape[di:di + 1] = [inter, n // inter]
     names[di:di + 1] = [DATA_INTER_AXIS, DATA_INTRA_AXIS]
     return Mesh(mesh.devices.reshape(shape), tuple(names))
-
-
-def linear_axis_index(axis):
-    """`jax.lax.axis_index` linearized over one bound axis name or an
-    (outer, ..., inner) tuple — the device's odometer rank over the named
-    axes (the pinned 0.4.37 axis_index takes a single name only)."""
-    if isinstance(axis, (tuple, list)):
-        idx = jax.lax.axis_index(axis[0])
-        for a in axis[1:]:
-            idx = idx * axis_size(a) + jax.lax.axis_index(a)
-        return idx
-    return jax.lax.axis_index(axis)
 
 
 def single_device_mesh() -> Mesh:

@@ -113,7 +113,7 @@ def _pvary(x):
     that collectives/conditionals make device-varying. Nothing
     differentiates through these programs (the custom VJP is the backward),
     so the cast has no transpose cost."""
-    return mesh_lib.pvary(x, (mesh_lib.PIPE_AXIS,))
+    return jax.lax.pcast(x, (mesh_lib.PIPE_AXIS,), to="varying")
 
 
 def _psum_pipe(x):
@@ -303,7 +303,7 @@ def _pipeline_prologue(stage_params, microbatches, mesh, interleave,
         lambda x: P(mesh_lib.PIPE_AXIS, *([None] * (x.ndim - 1))),
         stage_params)
     shard = functools.partial(
-        mesh_lib.shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         axis_names=frozenset({mesh_lib.PIPE_AXIS}))
     return S, M, interleave, fwd_perm, param_specs, shard
 

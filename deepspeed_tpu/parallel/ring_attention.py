@@ -43,20 +43,12 @@ def ring_attention(q, k, v, mesh, causal=False, scale=None,
     assert S % n == 0, f"seq len {S} not divisible by seq axis {n}"
     chunk = S // n
     perm = [(j, (j + 1) % n) for j in range(n)]
-    # name the batch/head mesh axes too (when live and divisible): the body
-    # is fully batch/head-parallel, and under the full-manual shard_map
-    # lowering (mesh_lib.shard_map on jax 0.4.x) an unnamed-but-sharded
-    # axis would otherwise replicate q/k/v at entry — an involuntary
-    # full-remat on dp x sp x tp meshes
-    b_ax = mesh_lib.passthrough_axis(mesh, mesh_lib.DATA_AXIS, B)
-    h_ax = mesh_lib.passthrough_axis(mesh, mesh_lib.MODEL_AXIS, H)
-    spec = P(b_ax, h_ax, axis, None)
-    # per-device block sizes for the scan carries
-    Bl = B // (mesh.shape[b_ax] if b_ax else 1)
-    Hl = H // (mesh.shape[h_ax] if h_ax else 1)
+    # manual over the seq axis only: batch/head mesh axes stay auto, so
+    # the body sees global B and H and GSPMD keeps their tiling
+    spec = P(None, None, axis, None)
 
     @functools.partial(
-        mesh_lib.shard_map, mesh=mesh, axis_names=frozenset({axis}),
+        jax.shard_map, mesh=mesh, axis_names=frozenset({axis}),
         in_specs=(spec, spec, spec), out_specs=spec)
     def run(ql, kl, vl):
         idx = jax.lax.axis_index(axis)
@@ -86,10 +78,10 @@ def ring_attention(q, k, v, mesh, causal=False, scale=None,
             return (o_new, m_new, l_new, kc, vc), None
 
         zeros_f32 = functools.partial(jnp.zeros, dtype=jnp.float32)
-        var = lambda x: mesh_lib.pvary(x, (axis,))  # noqa: E731
-        o0 = var(zeros_f32((Bl, Hl, chunk, D)))
-        m0 = var(jnp.full((Bl, Hl, chunk), NEG_INF, jnp.float32))
-        l0 = var(zeros_f32((Bl, Hl, chunk)))
+        var = lambda x: jax.lax.pcast(x, (axis,), to="varying")  # noqa: E731
+        o0 = var(zeros_f32((B, H, chunk, D)))
+        m0 = var(jnp.full((B, H, chunk), NEG_INF, jnp.float32))
+        l0 = var(zeros_f32((B, H, chunk)))
         (o, m, l, _, _), _ = jax.lax.scan(
             step, (o0, m0, l0, kl, vl), jnp.arange(n))
         l_safe = jnp.maximum(l, 1e-30)

@@ -47,17 +47,10 @@ def ulysses_attention(q, k, v, mesh, causal=False, scale=None,
         return dot_product_attention(q, k, v, causal=causal, scale=scale)
     assert H % n == 0, f"n_head {H} not divisible by seq axis {n}"
     assert S % n == 0, f"seq len {S} not divisible by seq axis {n}"
-    # pass batch/head tiles through manually when live (see ring_attention);
-    # the head axis additionally needs H/tp to stay divisible by n for the
-    # in-body head-scatter all_to_all
-    tp_axis = mesh_lib.passthrough_axis(mesh, mesh_lib.MODEL_AXIS, H)
-    if tp_axis is not None and (H // mesh.shape[tp_axis]) % n != 0:
-        tp_axis = None
-    spec = P(mesh_lib.passthrough_axis(mesh, mesh_lib.DATA_AXIS, B),
-             tp_axis, axis, None)
+    spec = P(None, None, axis, None)
 
     @functools.partial(
-        mesh_lib.shard_map, mesh=mesh, axis_names=frozenset({axis}),
+        jax.shard_map, mesh=mesh, axis_names=frozenset({axis}),
         in_specs=(spec, spec, spec), out_specs=spec)
     def run(ql, kl, vl):
         # local blocks [B, H, S/n, D] → head-sharded full-seq

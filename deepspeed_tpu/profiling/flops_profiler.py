@@ -16,9 +16,10 @@ import numpy as np
 from deepspeed_tpu.utils.logging import logger
 
 
-# per-chip dense bf16 peak FLOPS by device kind — the denominator of
-# MFU. The single source of truth: bench.py and the engine's telemetry
-# MFU gauge both resolve through peak_device_flops().
+# per-chip dense bf16 peak FLOPS keyed by ``device.device_kind`` exactly
+# as JAX reports it (Google Cloud TPU documentation, per-chip figures) —
+# the denominator of MFU. The single source of truth: bench.py and the
+# engine's telemetry MFU gauge both resolve through peak_device_flops().
 PEAK_BF16_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,   # v5e
@@ -27,22 +28,20 @@ PEAK_BF16_FLOPS = {
     "TPU v5": 459e12,
     "TPU v6 lite": 918e12,   # v6e
 }
-_PEAK_FALLBACK = 197e12
 
 
 def peak_device_flops(device=None):
-    """Dense bf16 peak of ``device`` (default: jax.devices()[0]).
-    Unknown kinds (including CPU backends) fall back to the v5e figure
-    so an MFU computed against it is a LOWER bound on a real chip and
-    an explicitly-absurd number on CPU — callers that care tag the
-    device kind next to the gauge (the engine does)."""
+    """Dense bf16 peak of ``device`` (default: jax.devices()[0]). A
+    device kind that is not in the table (a CPU included) raises: an MFU
+    against a guessed peak is not a measurement."""
     if device is None:
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "")
-    for key, val in PEAK_BF16_FLOPS.items():
-        if kind.startswith(key):
-            return val
-    return _PEAK_FALLBACK
+    kind = device.device_kind
+    if kind not in PEAK_BF16_FLOPS:
+        raise ValueError(
+            f"no bf16 peak recorded for device_kind {kind!r}; known kinds: "
+            f"{sorted(PEAK_BF16_FLOPS)}")
+    return PEAK_BF16_FLOPS[kind]
 
 
 def model_flops_per_token(cfg):

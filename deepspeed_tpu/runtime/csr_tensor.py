@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.parallel.mesh import shard_map
 
 
 @jax.tree_util.register_pytree_node_class
@@ -107,7 +106,7 @@ def sparse_all_reduce(dense_grad, mesh, axis: str, max_rows: int):
             all_val.reshape(-1, E), mode="drop")
         return out[None]
 
-    fn = shard_map(local_reduce, mesh=mesh,
+    fn = jax.shard_map(local_reduce, mesh=mesh,
                    in_specs=P(axis, None, None),
                    out_specs=P(axis, None, None))
     summed = fn(dense_grad)

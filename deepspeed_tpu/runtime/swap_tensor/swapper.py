@@ -25,7 +25,7 @@ kept open without ``O_TRUNC`` across steps, so steady-state writes reuse
 extents instead of reallocating them, and swap-in issues an
 ``fadvise(WILLNEED)`` readahead pass before reading — the first-epoch
 read path runs at steady-state bandwidth instead of the 5x-slower
-cold-file rate (BENCH_r05 ``aio_disk.first_read_mbps``).
+cold-file rate (bench.py ``aio_disk.first_read_mbps``).
 
 All swap-path telemetry is sync-free (host wall timers + byte counters
 into the process registry): ``swap/bytes_read``, ``swap/bytes_written``,
@@ -403,7 +403,7 @@ class PartitionedParamSwapper:
     def _readahead(self, indices):
         """fadvise(WILLNEED) the files about to be read — kernel
         readahead fills the page cache while earlier leaves process, so
-        the first epoch reads at steady-state bandwidth (the BENCH_r05
+        the first epoch reads at steady-state bandwidth (the earlier
         first_read_mbps=298-vs-1640 fix). Under active O_DIRECT there
         is no page cache to warm — the pass would be a pure syscall tax
         per file per window, so it is gated off entirely."""

@@ -15,11 +15,9 @@ through HBM in bounded chunks:
     pinned_host; bf16 params out to HBM for the next forward.
 
 One step therefore moves 2x the state bytes over the device's host link
-(PCIe-class, ~9-10 GB/s measured) instead of moving gradients + params over
-whatever link connects the *client* process to the chip — on tunneled or
-disaggregated deployments that link is orders of magnitude slower, and on
-a TPU-VM this path still wins: the VPU applies the update at HBM bandwidth
-and no host SIMD library or core count is on the critical path.
+(PCIe-class) instead of running the update in host SIMD: the VPU applies
+it at HBM bandwidth and no host library or core count is on the critical
+path.
 
 HBM discipline (the analog of the reference's tiled pinned-buffer bounds,
 swap_tensor/optimizer_utils.py): state is stored pre-chunked — leaves whose

@@ -25,7 +25,7 @@ def main(numel=8_388_608):
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
     from deepspeed_tpu.parallel import compression as comp
-    from deepspeed_tpu.parallel.mesh import shard_map
+    from jax import shard_map
 
     n = len(jax.devices())
     mesh = Mesh(np.asarray(jax.devices()), ("data",))

@@ -116,7 +116,7 @@ def _time_comm_stream(engine, steps):
         return total
 
     # shard_map with the resting specs hands each device its local shard
-    fn = jax.jit(mesh_lib.shard_map(
+    fn = jax.jit(jax.shard_map(
         comm_only, mesh=mesh,
         in_specs=tuple(sharded_specs),
         out_specs=PartitionSpec(), check_vma=False))

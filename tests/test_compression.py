@@ -11,7 +11,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.parallel import compression as comp
-from deepspeed_tpu.parallel.mesh import shard_map
+from jax import shard_map
 
 
 def _mesh(n):

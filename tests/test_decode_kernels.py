@@ -243,3 +243,15 @@ def test_decode_attention_stacked_gqa_rows(rs):
         vs.reshape(Lyr, B, Hkv, 1, L), pos, layer, block_l=64)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=1e-5, atol=1e-6)
+
+
+def test_pick_block_l_refuses_cache_lengths_without_an_aligned_block():
+    """ADVICE r5: an odd cache length used to halve down to a 1-row block
+    and trip a bare assert (or crawl); now it is a stated precondition."""
+    from deepspeed_tpu.ops.pallas.decode import _pick_block_l
+    assert _pick_block_l(1024, 20, 64, 2) == 512
+    assert _pick_block_l(1000, 20, 64, 2) == 8
+    assert _pick_block_l(24, 4, 32, 4) == 24          # one block spans it
+    assert _pick_block_l(5, 4, 32, 4) == 5
+    with pytest.raises(ValueError, match="cache length 1023"):
+        _pick_block_l(1023, 20, 64, 2)

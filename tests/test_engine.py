@@ -257,8 +257,8 @@ def test_wall_clock_breakdown_fused_path():
         l_fused = float(e_fused.train_batch(batch))
     assert l_inst == pytest.approx(l_fused, rel=1e-4)
     times = e_inst.wall_clock_times()
-    # 'fence' is the measured per-phase readback cost (a full round trip
-    # on tunneled backends) that the phase numbers are reported NET of
+    # 'fence' is the measured per-phase readback cost that the phase
+    # numbers are reported NET of
     assert set(times) == {"forward", "backward", "step", "fence"}
     assert times["forward"] > 0 and times["step"] > 0
     # uninstrumented engine reports no phase timers

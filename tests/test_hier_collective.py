@@ -24,7 +24,8 @@ from deepspeed_tpu.ops.pallas import fused_collective as fc
 from deepspeed_tpu.parallel import compression as comp
 from deepspeed_tpu.parallel import overlap as ov
 from deepspeed_tpu.parallel import topology as topo
-from deepspeed_tpu.parallel.mesh import MeshConfig, make_mesh, shard_map
+from jax import shard_map
+from deepspeed_tpu.parallel.mesh import MeshConfig, make_mesh
 
 SPLITS = [(2, 4), (4, 2)]
 

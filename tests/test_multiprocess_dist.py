@@ -27,7 +27,7 @@ _WORKER = textwrap.dedent("""
     import numpy as np
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from deepspeed_tpu.parallel.mesh import shard_map
+    from jax import shard_map
 
     devs = jax.devices()             # global device list across processes
     mesh = Mesh(np.asarray(devs), ("data",))

@@ -17,7 +17,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 import deepspeed_tpu as dstpu
 from deepspeed_tpu.parallel import overlap
-from deepspeed_tpu.parallel.mesh import shard_map, make_mesh, MeshConfig
+from jax import shard_map
+from deepspeed_tpu.parallel.mesh import make_mesh, MeshConfig
 from tests.simple_model import SimpleModel, random_batch, base_config
 
 N = 8

@@ -22,7 +22,8 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 import deepspeed_tpu as dstpu
 from deepspeed_tpu.parallel import prefetch
-from deepspeed_tpu.parallel.mesh import shard_map, make_mesh, MeshConfig
+from jax import shard_map
+from deepspeed_tpu.parallel.mesh import make_mesh, MeshConfig
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
 
 N = 8

@@ -209,23 +209,41 @@ def test_model_flops_per_token_known_shape():
 
 
 def test_mfu_math_and_peak_table():
+    import types
     from deepspeed_tpu.profiling.flops_profiler import (
         mfu, peak_device_flops, PEAK_BF16_FLOPS)
-    peak = peak_device_flops()          # fallback on CPU backends
-    assert peak in set(PEAK_BF16_FLOPS.values()) | {197e12}
-    assert mfu(peak / 2.0, 1.0) == pytest.approx(0.5)
-    assert mfu(peak, 2.0) == pytest.approx(0.5)       # flops/s halves
-    assert mfu(peak, 1.0, n_devices=4) == pytest.approx(0.25)
-    assert mfu(peak, 0.0) == 0.0
+    v5e = types.SimpleNamespace(device_kind="TPU v5 lite")
+    peak = peak_device_flops(v5e)
+    assert peak == PEAK_BF16_FLOPS["TPU v5 lite"] == 197e12
+    assert mfu(peak / 2.0, 1.0, device=v5e) == pytest.approx(0.5)
+    assert mfu(peak, 2.0, device=v5e) == pytest.approx(0.5)
+    assert mfu(peak, 1.0, device=v5e, n_devices=4) == pytest.approx(0.25)
+    assert mfu(peak, 0.0, device=v5e) == 0.0
+
+
+def test_peak_flops_raises_on_unknown_device_kind():
+    """No default peak: the CPU backend and a TPU kind outside the table
+    both raise, naming the kind."""
+    import types
+    import jax
+    from deepspeed_tpu.profiling.flops_profiler import mfu, peak_device_flops
+    cpu_kind = jax.devices()[0].device_kind
+    with pytest.raises(ValueError, match=cpu_kind):
+        peak_device_flops()
+    with pytest.raises(ValueError, match="TPU v5 litex"):
+        peak_device_flops(types.SimpleNamespace(device_kind="TPU v5 litex"))
+    with pytest.raises(ValueError, match=cpu_kind):
+        mfu(1e12, 1.0)
 
 
 # ------------------------------------------------- engine + trace window
 
-def test_engine_scalar_stream_mfu_and_trace_window(tmp_path):
+def test_engine_scalar_stream_mfu_and_trace_window(tmp_path, monkeypatch):
     """One tiny engine exercises the whole integration: per-step
     counters, boundary window folds (step-time histogram, throughput
-    gauges), MFU priced from the compiled step's cost analysis, memory
-    gauges, the JSONL stream, and a 2-step XLA trace window."""
+    gauges), flops priced from the compiled step's cost analysis (MFU
+    only once the device kind has a recorded peak), memory gauges, the
+    JSONL stream, and a 2-step XLA trace window."""
     default_registry().reset()
     jsonl = str(tmp_path / "tel.jsonl")
     cfg = base_config(steps_per_print=2)
@@ -246,9 +264,10 @@ def test_engine_scalar_stream_mfu_and_trace_window(tmp_path):
     assert snap["histograms"]["train/step_time_s"]["count"] >= 2
     assert snap["histograms"]["span/train/step_dispatch"]["count"] == 6
     assert snap["gauges"]["train/samples_per_sec"] > 0
-    # MFU priced (monitor gate on): exact flops from cost analysis
+    # flops priced (monitor gate on): exact, from cost analysis. The
+    # CPU mesh's device kind has no recorded peak, so no MFU gauge
     assert snap["gauges"]["train/flops_per_step"] > 0
-    assert snap["gauges"]["train/mfu"] >= 0
+    assert "train/mfu" not in snap["gauges"]
     assert snap["gauges"]["memory/host_max_rss_mb"] > 0
 
     events = [json.loads(l) for l in open(jsonl)]
@@ -259,6 +278,15 @@ def test_engine_scalar_stream_mfu_and_trace_window(tmp_path):
     assert snap["counters"]["profiling/trace_windows"] == 1
     n_files = sum(len(fs) for _, _, fs in os.walk(tmp_path / "trace"))
     assert n_files > 0
+
+    # a device kind that IS in the peak table gets the MFU gauge
+    import jax
+    from deepspeed_tpu.profiling import flops_profiler
+    monkeypatch.setitem(flops_profiler.PEAK_BF16_FLOPS,
+                        jax.devices()[0].device_kind, 1e12)
+    for _ in range(2):
+        engine.train_batch(batch)
+    assert engine.telemetry_flush(batch)["gauges"]["train/mfu"] > 0
 
 
 def test_engine_without_gates_records_but_never_prices_or_exports():
