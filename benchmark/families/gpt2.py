@@ -1,0 +1,288 @@
+"""The GPT-2 family: how a configuration file becomes a running system.
+
+A family file is the only place that knows a model's classes. It builds
+the model from a configuration's sizes, makes the weights on the device
+from the seed, hands the system under test to a traffic kind through the
+program's normal entry points (``dstpu.initialize``,
+``serving.build_engine``), maps the system's parameter tree onto the plain
+reference's layout, and decides ``correct``. A later family is a new file
+here with the same functions.
+"""
+
+import dataclasses
+
+import numpy as np
+
+from benchmark import roofline
+from benchmark.reference import gpt2 as ref
+
+
+def sizes(config, rehearse):
+    """The configuration's sizes; a CPU rehearsal overrides them with the
+    tiny ones the file carries."""
+    keys = ("vocab_size", "n_positions", "n_embd", "n_layer", "n_head",
+            "layer_norm_epsilon")
+    out = {k: config[k] for k in keys}
+    if rehearse:
+        out.update({k: v for k, v in config["rehearse_cpu"].items()
+                    if k in keys})
+    return out
+
+
+def _merged(config, section, rehearse):
+    """``config[section]`` with the rehearsal's overrides laid over it."""
+    def merge(a, b):
+        out = dict(a)
+        for k, v in b.items():
+            out[k] = merge(a[k], v) if isinstance(v, dict) \
+                and isinstance(a.get(k), dict) else v
+        return out
+    base = config[section]
+    over = config["rehearse_cpu"].get(section, {}) if rehearse else {}
+    return merge(base, over)
+
+
+def model_config(config, rehearse, serving=False):
+    import jax.numpy as jnp
+    from deepspeed_tpu.models.gpt2 import GPT2Config
+    m = _merged(config, "model", rehearse)
+    dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+    cfg = GPT2Config(dtype=dtypes[m["dtype"]],
+                     param_dtype=dtypes[m["param_dtype"]],
+                     scan_layers=m["scan_layers"], remat=m["remat"],
+                     remat_policy=m["remat_policy"],
+                     loss_chunk=m["loss_chunk"], dropout=config["resid_pdrop"],
+                     **sizes(config, rehearse))
+    if serving:
+        cfg = dataclasses.replace(cfg, remat=False, loss_chunk=0)
+    return cfg
+
+
+# ----------------------------------------------------------------- training
+
+def engine_config(config, global_batch, seed, rehearse):
+    return dict(_merged(config, "train", rehearse)["engine"],
+                train_batch_size=global_batch, seed=seed)
+
+
+def build_train(config, global_batch, seed, devices, rehearse):
+    """(engine, initial parameters). The weights are born sharded in one
+    jitted call (``zero.Init``'s functional form) and handed to
+    ``dstpu.initialize`` as ``model_parameters``; the engine adopts those
+    very buffers, so the caller's handle is valid until the first step
+    donates them — long enough for the reference to read them."""
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu as dstpu
+    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel
+    from deepspeed_tpu.parallel.mesh import MeshConfig, make_mesh
+    from deepspeed_tpu.runtime.zero.init import sharded_init
+
+    cfg = model_config(config, rehearse)
+    ds = engine_config(config, global_batch, seed, rehearse)
+    model = GPT2LMHeadModel(cfg)
+    mesh = make_mesh(MeshConfig(data=len(devices)), devices=devices)
+    zero = ds["zero_optimization"]
+    params, _ = sharded_init(
+        model, jax.random.PRNGKey(seed),
+        jnp.zeros((global_batch, cfg.n_positions), jnp.int32), mesh,
+        stage=zero["stage"],
+        param_persistence_threshold=zero.get(
+            "stage3_param_persistence_threshold", 100000))
+    engine, _, _, _ = dstpu.initialize(config=ds, model=model, mesh=mesh,
+                                       model_parameters=params)
+    return engine, params
+
+
+def _reference_view(params, n_layer, device):
+    """(top, layer(i)) in the reference's layout, as float32 on ``device``,
+    from the scan-stacked tree of ``GPT2LMHeadModel`` (or its bf16 cast)."""
+    import jax
+    import jax.numpy as jnp
+
+    def put(x):
+        return jax.device_put(x, device).astype(jnp.float32)
+
+    def pair(d, a, b):
+        return (put(d[a]), put(d[b]))
+
+    blk = params["h"]["blk"]
+    top = {"wte": put(params["wte"]), "wpe": put(params["wpe"]),
+           "ln_f": pair(params["ln_f"], "scale", "bias")}
+
+    def layer(i):
+        at = jax.tree_util.tree_map(lambda x: x[i], blk)
+        return {"ln_1": pair(at["ln_1"], "scale", "bias"),
+                "c_attn": pair(at["attn"]["c_attn"], "kernel", "bias"),
+                "c_proj": pair(at["attn"]["c_proj"], "kernel", "bias"),
+                "ln_2": pair(at["ln_2"], "scale", "bias"),
+                "c_fc": pair(at["mlp"]["c_fc"], "kernel", "bias"),
+                "mlp_proj": pair(at["mlp"]["c_proj"], "kernel", "bias")}
+    return top, layer
+
+
+def reference_train(config, params, batch_ids, devices, rehearse):
+    """(loss, gradient norm) of the plain reference on ``batch_ids`` at the
+    weights ``params`` holds, as Python floats; the batch's sequences are
+    dealt onto ``devices``. Call before the engine's first step (it donates
+    ``params``)."""
+    import jax
+    s = sizes(config, rehearse)
+    top0, layer0 = _reference_view(params, s["n_layer"], devices[0])
+    tops = {d: jax.device_put(top0, d) for d in devices}
+    held = {}
+
+    def layer(i, device):
+        # one layer at a time: gathered and cast once, then copied per chip
+        if held.get("i") != i:
+            held.clear()
+            held.update(i=i, first=layer0(i))
+        if device not in held:
+            held[device] = jax.device_put(held["first"], device)
+        return held[device]
+
+    loss, gnorm = ref.loss_and_grad_norm(
+        tops.__getitem__, layer, s["n_layer"], s["n_head"],
+        s["layer_norm_epsilon"], np.asarray(batch_ids), devices)
+    return float(loss), float(gnorm)
+
+
+def judge_train(config, got_loss, got_gnorm, want_loss, want_gnorm):
+    tol = config["train"]["tolerance"]
+    checks = {
+        "first_loss_matches_reference":
+            abs(got_loss - want_loss) <= tol["loss_abs"],
+        "first_grad_norm_matches_reference":
+            abs(got_gnorm - want_gnorm) <= tol["grad_norm_rel"] * want_gnorm}
+    detail = {"loss": [got_loss, want_loss], "loss_abs_tol": tol["loss_abs"],
+              "grad_norm": [got_gnorm, want_gnorm],
+              "grad_norm_rel_tol": tol["grad_norm_rel"]}
+    return checks, detail
+
+
+# ------------------------------------------------------------------ serving
+
+def build_serving(config, seed, rehearse, registry):
+    """(engine, weights as served). Weights are made and cast to the served
+    dtype in one jitted call on the device; no float32 copy outlives it."""
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu.serving as serving
+    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel
+
+    cfg = model_config(config, rehearse, serving=True)
+    sv = _merged(config, "serve", rehearse)
+    served = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
+        sv["weights_dtype"]]
+    model = GPT2LMHeadModel(cfg)
+
+    @jax.jit
+    def make(key):
+        p = model.init(key, jnp.zeros((1, 8), jnp.int32))["params"]
+        return jax.tree_util.tree_map(lambda a: a.astype(served), p)
+
+    params = make(jax.random.PRNGKey(seed))
+    eng = serving.build_engine("gpt2", cfg, params,
+                               config={"serving": sv["serving"]},
+                               registry=registry)
+    return eng, params
+
+
+def check_serving(config, eng, params, prompts, rehearse, pad_to,
+                  decoded=16):
+    """Serve ``prompts`` for ``decoded + 1`` tokens each on the idle engine
+    and hold two rows of logits per request to the reference's full forward
+    over prompt + generated tokens: the prefill's (which chose the first
+    token) and the last decode step's, ``decoded`` tokens later through the
+    paged cache. Logits, not tokens: with random weights the largest logit
+    changes on rounding. The reference runs every sequence zero-padded to
+    ``pad_to`` tokens — under a causal mask what follows a position cannot
+    reach it — so that it compiles one shape per cell and not one per seed."""
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu.serving as serving
+    from deepspeed_tpu.serving.paged_cache import (TRASH_BLOCK,
+                                                   padded_prefill_inputs)
+    s = sizes(config, rehearse)
+    tol = config["serve"]["tolerance"]["logits_abs"]
+    assert eng.pending == 0 and len(prompts) <= eng.spec.slots
+    # the adapter's prefill program for each prompt (the executable admission
+    # runs), K/V written to the trash block so that no live page is touched
+    P = eng.spec.page_size
+    prefill_logits = []
+    for prompt in prompts:
+        ids, pages = padded_prefill_inputs(
+            prompt, [], P, eng.adapter.max_prompt_len() // P)
+        assert set(pages.tolist()) == {TRASH_BLOCK}
+        eng.cache.pool, got = eng.adapter.prefill(
+            eng.cache.pool, jnp.asarray(ids),
+            jnp.asarray(len(prompt), jnp.int32), jnp.asarray(pages))
+        prefill_logits.append(np.asarray(got, np.float32).reshape(-1))
+    # an idle engine admits FIFO into slots 0..n-1, and with equal budgets
+    # decodes them in one tick of ``decoded`` steps: last_logits[i] is
+    # request i's final step
+    reqs = [serving.Request(("check", i), p, max_new_tokens=decoded + 1)
+            for i, p in enumerate(prompts)]
+    for r in reqs:
+        eng.submit(r)
+    while eng.pending:
+        eng.step()
+    last = np.asarray(eng.last_logits, np.float32)
+
+    device = jax.devices()[0]
+    top, layer = _reference_view(params, s["n_layer"], device)
+    diffs, first_ok = [], []
+    for i, (req, prompt) in enumerate(zip(reqs, prompts)):
+        S = len(prompt)
+        seq = np.zeros(max(pad_to, S + decoded), np.int32)
+        seq[:S] = prompt
+        seq[S:S + decoded] = req.generated[:decoded]
+        want = np.asarray(ref.logits(
+            top, layer, s["n_layer"], s["n_head"], s["layer_norm_epsilon"],
+            jax.device_put(seq, device), [S - 1, S + decoded - 1]))
+        diffs.append([float(np.max(np.abs(prefill_logits[i] - want[0]))),
+                      float(np.max(np.abs(last[i] - want[1])))])
+        first_ok.append(len(req.generated) == decoded + 1 and
+                        int(np.argmax(prefill_logits[i])) == req.generated[0])
+    checks = {
+        "prefill_logits_match_reference": max(d[0] for d in diffs) <= tol,
+        "decode_logits_match_reference": max(d[1] for d in diffs) <= tol,
+        "first_token_is_argmax_of_prefill_logits": all(first_ok)}
+    detail = {"logit_max_abs_diff_prefill_then_decode": diffs,
+              "logits_abs_tol": tol, "prompt_tokens": [len(p) for p in prompts]}
+    return checks, detail
+
+
+# ------------------------------------------------- operations and bytes
+
+def train_flops_per_token(config, seq_len, rehearse=False):
+    s = sizes(config, rehearse)
+    return roofline.dense_train_flops_per_token(
+        s["n_layer"], s["n_embd"], s["vocab_size"], seq_len)
+
+
+def train_attention_flops_per_step(config, batch, seq_len, rehearse=False):
+    """Causal flops of the flash forward and backward kernels in one step."""
+    s = sizes(config, rehearse)
+    return s["n_layer"] * roofline.causal_attention_train_flops(
+        batch, s["n_head"], seq_len, s["n_embd"] // s["n_head"])
+
+
+def decode_kv_bytes(config, contexts, rehearse=False):
+    """Bytes of K and V one decode step must read for slots holding
+    ``contexts`` tokens each, in the cache's served dtype (bf16)."""
+    s = sizes(config, rehearse)
+    return roofline.kv_read_bytes(s["n_layer"], s["n_embd"], contexts, 2)
+
+
+def weight_bytes(config, rehearse=False):
+    s = sizes(config, rehearse)
+    E, L = s["n_embd"], s["n_layer"]
+    n = L * (12 * E * E + 13 * E) + (s["vocab_size"] + s["n_positions"]) * E \
+        + 2 * E
+    return 2 * n
+
+
+def kv_bytes_per_token(config, rehearse=False):
+    s = sizes(config, rehearse)
+    return 2 * s["n_layer"] * s["n_embd"] * 2
