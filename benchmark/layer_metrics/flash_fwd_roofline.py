@@ -1,0 +1,22 @@
+"""flash_fwd_roofline (%), read from device_trace.
+
+The flash forward kernel against its compute roofline: the causal flops it
+needs (QK^T and PV, two of the step's six S x S x D matmuls per head: 1/3 of
+``train_attention_flops_per_step``) over the bf16 peak, over the device time
+of the Pallas custom-calls traced under the scope ``flash_fwd``, on the
+busiest chip. Bound: compute.
+"""
+
+from benchmark import readers, scope_reduce
+
+NAME = "flash_fwd_roofline"
+UNIT = "%"
+LAYER = "attention kernels"
+MOVES = "train_tokens_per_s"
+SOURCE = "device_trace"
+
+
+def read(record):
+    if not readers.traced(record):
+        return None
+    return scope_reduce.kernel_roofline(record, "flash_fwd", 1 / 3)

@@ -26,6 +26,7 @@ import flax.linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
 from deepspeed_tpu.ops.attention import dot_product_attention
+from deepspeed_tpu.telemetry.spans import annotate
 
 
 import functools as _functools
@@ -468,8 +469,9 @@ class GPT2LMHeadModel(nn.Module):
         Numerics are pinned to ``__call__`` by tests/test_prefetch.py."""
         cfg = self.config
         S = input_ids.shape[1]
-        x = _embed_lookup(params["wte"], input_ids).astype(cfg.dtype) \
-            + params["wpe"][:S].astype(cfg.dtype)[None]
+        with annotate("ds_embed"):
+            x = _embed_lookup(params["wte"], input_ids).astype(cfg.dtype) \
+                + params["wpe"][:S].astype(cfg.dtype)[None]
 
         scan_body = ScanBody(cfg)
 
@@ -487,8 +489,9 @@ class GPT2LMHeadModel(nn.Module):
             return chunked_lm_loss(x, params["wte"].astype(cfg.dtype),
                                    labels, cfg.loss_chunk)
         if cfg.tie_word_embeddings:
-            logits = jnp.einsum("bse,ve->bsv", x,
-                                params["wte"].astype(cfg.dtype))
+            with annotate("ds_loss_head"):
+                logits = jnp.einsum("bse,ve->bsv", x,
+                                    params["wte"].astype(cfg.dtype))
         else:
             logits = nn.Dense(cfg.vocab_size, use_bias=False,
                               dtype=cfg.dtype,
@@ -538,7 +541,8 @@ class GPT2LMHeadModel(nn.Module):
                          (cfg.vocab_size, cfg.n_embd), cfg.param_dtype)
         wpe = self.param("wpe", nn.initializers.normal(0.01),
                          (cfg.n_positions, cfg.n_embd), cfg.param_dtype)
-        pos = wpe[:S]
+        with annotate("ds_embed"):
+            pos = wpe[:S]
         mesh = _gspmd_mesh()
         if mesh is not None:
             # pin the position slice replicated AT THE PARAM EDGE (fp32,
@@ -564,7 +568,8 @@ class GPT2LMHeadModel(nn.Module):
             from jax.sharding import NamedSharding, PartitionSpec
             posb = jax.lax.with_sharding_constraint(
                 posb, NamedSharding(mesh, PartitionSpec()))
-        x = _embed_lookup(wte, input_ids).astype(cfg.dtype) + posb
+        with annotate("ds_embed"):
+            x = _embed_lookup(wte, input_ids).astype(cfg.dtype) + posb
         x = _carry_pin(x)
 
         if cfg.scan_layers:
@@ -587,7 +592,8 @@ class GPT2LMHeadModel(nn.Module):
             return chunked_lm_loss(x, wte.astype(cfg.dtype), labels,
                                    cfg.loss_chunk)
         if cfg.tie_word_embeddings:
-            logits = jnp.einsum("bse,ve->bsv", x, wte.astype(cfg.dtype))
+            with annotate("ds_loss_head"):
+                logits = jnp.einsum("bse,ve->bsv", x, wte.astype(cfg.dtype))
         else:
             logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                               param_dtype=cfg.param_dtype, name="lm_head")(x)
@@ -596,6 +602,7 @@ class GPT2LMHeadModel(nn.Module):
         return logits
 
 
+@annotate("ds_loss_head")
 def chunked_lm_loss(hidden, wte, labels, chunk, ignore_index=-100):
     """Fused LM head + next-token cross entropy without a [B, S, V] buffer.
 
@@ -639,6 +646,7 @@ def chunked_lm_loss(hidden, wte, labels, chunk, ignore_index=-100):
     return total / jnp.maximum(count, 1)
 
 
+@annotate("ds_loss_head")
 def lm_loss(logits, labels, ignore_index=-100):
     """Next-token cross entropy in fp32. ``labels`` must be the UNSHIFTED
     token ids (typically ``labels is input_ids``); the shift happens here

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from deepspeed_tpu.telemetry.spans import annotate
+
 NEG_INF = -1e30
 
 # measured scoped-VMEM ceiling for whole-row residency on v5e. The r4
@@ -166,7 +168,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
             return (b, 0, 0)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k, seq_len=S)
-    o, lse = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -183,7 +185,9 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )
+    with annotate("flash_fwd"):
+        o, lse = call(q, k, v)
     return o, lse
 
 
@@ -260,7 +264,7 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, :, None]  # [BH, S, 1]
 
-    dq, dk, dv = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=S),
         grid=(BH, S // block_k),
@@ -283,7 +287,9 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         ],
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )
+    with annotate("flash_bwd"):
+        dq, dk, dv = call(q, k, v, do, lse, delta)
     return dq.astype(q.dtype), dk, dv
 
 
@@ -341,7 +347,7 @@ def _flash_fwd_chunked(q, k, v, scale, causal, block_q, block_k, chunk,
                                causal=causal, block_q=block_q,
                                block_k=block_k, chunk=chunk,
                                n_chunks=n_chunks)
-    o32, lse, _ = pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
         grid=(BH, S // block_q, n_chunks),
         in_specs=[
@@ -360,7 +366,9 @@ def _flash_fwd_chunked(q, k, v, scale, causal, block_q, block_k, chunk,
             jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )
+    with annotate("flash_fwd_chunk"):
+        o32, lse, _ = call(q, k, v)
     return o32.astype(q.dtype), lse
 
 
@@ -461,7 +469,7 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, :, None]
 
-    dq = pl.pallas_call(
+    call_dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel_chunked, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k, chunk=chunk,
                           n_chunks=n_chunks),
@@ -477,9 +485,11 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )
+    with annotate("flash_bwd_dq"):
+        dq = call_dq(q, k, v, do, lse, delta)
 
-    dk, dv = pl.pallas_call(
+    call_dkv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel_chunked, scale=scale,
                           causal=causal, block_q=block_q, block_k=block_k,
                           chunk=chunk, n_chunks=n_chunks),
@@ -501,7 +511,9 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
             jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )
+    with annotate("flash_bwd_dkv"):
+        dk, dv = call_dkv(q, k, v, do, lse, delta)
     return dq.astype(q.dtype), dk.astype(q.dtype), dv.astype(q.dtype)
 
 

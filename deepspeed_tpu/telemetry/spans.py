@@ -83,7 +83,30 @@ def span_fields(span_id, parent_span=None):
 def annotate(tag):
     """Trace-time scope: ops traced inside carry ``tag`` in HLO
     metadata (shows up in xprof/perfetto op names). Zero runtime cost —
-    usable unconditionally inside jitted train fns."""
+    usable unconditionally inside jitted train fns.
+
+    The tag becomes a path element of the ``op_name`` the COMPILED text
+    gives each instruction (the device plane's event names carry no
+    metadata; ``benchmark/scope_reduce.py`` joins the two by instruction
+    name). The names below are the contract the benchmark reads — rename
+    one and its metric goes blind:
+
+    - ``ds_fwd_bwd`` (runtime/engine.py): inside it JAX's own path
+      elements tell the direction — ``jvp(`` is ``train_fwd_ms``,
+      ``transpose(jvp(`` is ``train_bwd_ms``, ``rematted_computation``
+      is ``train_recompute_ms``;
+    - ``ds_optimizer`` (runtime/engine.py): ``train_optimizer_ms``;
+    - ``flash_fwd`` and its long-S variant ``flash_fwd_chunk``
+      (ops/pallas/flash_attention.py, round the ``pallas_call`` itself):
+      ``flash_fwd_roofline``;
+    - ``flash_bwd`` and its long-S variants ``flash_bwd_dq``,
+      ``flash_bwd_dkv`` (same file): ``flash_bwd_roofline``;
+    - ``ds_loss_head`` (``chunked_lm_loss``, ``lm_loss``, the tied-logits
+      einsum) and ``ds_embed`` (the ``wte``/``wpe`` lookup), both
+      models/gpt2.py: rows of the benchmark's detail table.
+
+    The flax module names ``attn``, ``mlp``, ``ln_1``, ``ln_2``, ``ln_f``
+    (models/gpt2.py) are the detail table's remaining tags."""
     import jax
     return jax.named_scope(tag)
 

@@ -289,6 +289,27 @@ def test_engine_scalar_stream_mfu_and_trace_window(tmp_path, monkeypatch):
     assert engine.telemetry_flush(batch)["gauges"]["train/mfu"] > 0
 
 
+def test_train_step_scope_names_reach_the_compiled_text():
+    """The ``annotate`` scopes the benchmark joins device events to
+    (spans.annotate's list) survive a refactor: a scanned, rematted GPT-2
+    step with the chunked loss head carries each in some ``op_name`` of
+    its compiled text (the plain lowered text drops locations)."""
+    import re
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+    cfg = GPT2Config(vocab_size=256, n_positions=32, n_embd=32, n_layer=2,
+                     n_head=2, scan_layers=True, remat=True,
+                     remat_policy="dots_flash_fc_lean", loss_chunk=16)
+    engine, _, _, _ = dstpu.initialize(config=base_config(),
+                                       model=GPT2LMHeadModel(cfg))
+    batch = {"input_ids": np.random.RandomState(0).randint(
+        0, 256, (8, 32)).astype(np.int32)}
+    engine.train_batch(batch)
+    hlo = engine.lower_train_step(batch).compile().as_text()
+    for scope in ("ds_optimizer", "ds_loss_head", "ds_embed",
+                  "transpose(jvp(", "rematted_computation"):
+        assert re.search(r'op_name="[^"]*' + re.escape(scope), hlo), scope
+
+
 def test_engine_without_gates_records_but_never_prices_or_exports():
     """No monitor/profiling config: counters still move (snapshot is
     always available) but no cost-analysis retrace, no exporter, no

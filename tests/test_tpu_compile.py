@@ -289,6 +289,22 @@ def test_train_step_compiles_for_one_chip_with_flash_and_fits(one_chip_step):
                          compiled.as_text())
 
 
+def assert_scope_names(hlo):
+    """The names ``benchmark/scope_reduce.py`` joins device events to
+    (``telemetry.spans.annotate`` lists them): every Pallas call sits under
+    its kernel's scope, and each phase scope reaches some ``op_name``."""
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert kernels and all(
+        re.search(r'op_name="[^"]*/flash_(fwd|bwd)/', ln) for ln in kernels)
+    for scope in ("ds_optimizer", "ds_loss_head", "ds_embed",
+                  "transpose(jvp(", "rematted_computation"):
+        assert re.search(r'op_name="[^"]*' + re.escape(scope), hlo), scope
+
+
+def test_train_step_scope_names_reach_the_compiled_text(one_chip_step):
+    assert_scope_names(one_chip_step[1].as_text())
+
+
 def test_train_step_compiles_for_four_chips_sharded(one_chip_step):
     """ZeRO-3 over data=4: the flash kernel survives partitioning (inside a
     shard_map — GSPMD refuses a bare Mosaic call), every chip holds a quarter
@@ -302,6 +318,7 @@ def test_train_step_compiles_for_four_chips_sharded(one_chip_step):
     assert abs(per_chip - quarter) <= chip_smoke.SPREAD_RTOL * quarter
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and "all-gather" in hlo
+    assert_scope_names(hlo)     # inside the shard_map too
 
 
 # ------------------------------------- optional kernels: known refusals
