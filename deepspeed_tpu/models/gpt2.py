@@ -151,6 +151,27 @@ def _carry_pin(x):
     return _carry_pin_fn()(x)
 
 
+def gather_edge_block(block_cls, parent, name):
+    """``block_cls`` with the ZeRO-3 gather edge on its parameters
+    (runtime/zero/partition.py): under a pinned GSPMD trace of a stage-3
+    engine with a data axis, the leaves of the block ``name`` of module
+    ``parent`` that rest data-sharded are pinned data-replicated where
+    the block reads them, so GSPMD all-gathers the weight instead of
+    re-laying the activations round a sharded one. Wrap BEFORE
+    ``nn.remat``: the pin must sit inside the rematted function, or the
+    gathered weights become the layer scan's saved residuals. Anywhere
+    else (no engine, stages 0-2, one device, an explicit-comm program,
+    ``init``) this is ``block_cls`` itself and nothing is emitted."""
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+    edge = mesh_lib.pinned_gather_edge()
+    if edge is None or parent.is_initializing():
+        return block_cls
+    path = parent.scope.path + (name,)
+    return nn.map_variables(
+        block_cls, "params",
+        trans_in_fn=lambda vs: {**vs, "params": edge(path, vs["params"])})
+
+
 class CollectiveDense(nn.Dense):
     """``nn.Dense`` twin whose kernel GEMM can fuse with the ZeRO-3
     gather ring (ISSUE 8). Outside a fused-gather trace this IS
@@ -425,10 +446,13 @@ def _remat_policy(name):
     raise ValueError(f"unknown remat_policy {name!r}")
 
 
-def _maybe_remat(cfg):
+def _maybe_remat(cfg, parent, name):
+    """The Block class for the child ``name`` of ``parent``: gather edge
+    innermost, remat (when configured) round it."""
+    block = gather_edge_block(Block, parent, name)
     if not cfg.remat:
-        return Block
-    return nn.remat(Block, prevent_cse=False, static_argnums=(2,),
+        return block
+    return nn.remat(block, prevent_cse=False, static_argnums=(2,),
                     policy=_remat_policy(cfg.remat_policy))
 
 
@@ -438,12 +462,19 @@ class ScanBody(nn.Module):
 
     @nn.compact
     def __call__(self, x, deterministic, keep_prob):
-        block = _maybe_remat(self.config)
+        block = _maybe_remat(self.config, self, "blk")
         return block(self.config, name="blk")(x, deterministic, keep_prob), None
 
 
 class GPT2LMHeadModel(nn.Module):
     config: GPT2Config
+
+    @property
+    def layer_stacked_subtree(self):
+        """Top-level params key whose leaves are layer-stacked
+        (``[n_layer, ...]``), or None with unrolled layers: the ZeRO
+        partitioner judges such leaves one layer at a time."""
+        return "h" if self.config.scan_layers else None
 
     @property
     def prefetch_layer_subtree(self):
@@ -453,8 +484,8 @@ class GPT2LMHeadModel(nn.Module):
         subtrees; MoE sows aux losses the functional twin doesn't
         collect; dropout needs per-layer rng plumbing)."""
         cfg = self.config
-        if cfg.scan_layers and not cfg.moe_experts and cfg.dropout == 0:
-            return "h"
+        if not cfg.moe_experts and cfg.dropout == 0:
+            return self.layer_stacked_subtree
         return None
 
     @nn.nowrap
@@ -581,8 +612,8 @@ class GPT2LMHeadModel(nn.Module):
                               unroll=max(1, cfg.scan_unroll))
             x, _ = scanned(cfg, name="h")(x, deterministic, keep_prob)
         else:
-            block = _maybe_remat(cfg)
             for i in range(cfg.n_layer):
+                block = _maybe_remat(cfg, self, f"h_{i}")
                 x = block(cfg, name=f"h_{i}")(x, deterministic, keep_prob)
 
         x = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,

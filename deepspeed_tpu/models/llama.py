@@ -34,7 +34,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from deepspeed_tpu.ops.attention import dot_product_attention
 from deepspeed_tpu.models.gpt2 import (_embed_lookup, _remat_policy,
-                                       chunked_lm_loss, lm_loss)
+                                       chunked_lm_loss, gather_edge_block,
+                                       lm_loss)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -245,10 +246,14 @@ class LlamaBlock(nn.Module):
         return x
 
 
-def _maybe_remat(cfg):
+def _maybe_remat(cfg, parent, name):
+    """The block class for the child ``name`` of ``parent``: the ZeRO-3
+    gather edge innermost (models/gpt2.gather_edge_block), remat round
+    it."""
+    block = gather_edge_block(LlamaBlock, parent, name)
     if not cfg.remat:
-        return LlamaBlock
-    return nn.remat(LlamaBlock, prevent_cse=False,
+        return block
+    return nn.remat(block, prevent_cse=False,
                     policy=_remat_policy(cfg.remat_policy))
 
 
@@ -258,7 +263,7 @@ class _ScanBody(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
-        block = _maybe_remat(self.config)
+        block = _maybe_remat(self.config, self, "blk")
         return block(self.config, self.max_out_tokens,
                      name="blk")(x, positions), None
 
@@ -269,6 +274,12 @@ class LlamaForCausalLM(nn.Module):
     the lm_head kernel)."""
     config: LlamaConfig
     max_out_tokens: int = 0      # >0 → serving mode (KV caches)
+
+    @property
+    def layer_stacked_subtree(self):
+        """Top-level params key whose leaves are layer-stacked, or None
+        with unrolled layers (see GPT2LMHeadModel)."""
+        return "layers" if self.config.scan_layers else None
 
     @nn.compact
     def __call__(self, input_ids, labels=None, deterministic=True,
@@ -291,8 +302,8 @@ class LlamaForCausalLM(nn.Module):
             x, _ = scanned(cfg, self.max_out_tokens,
                            name="layers")(x, positions)
         else:
-            block = _maybe_remat(cfg)
             for i in range(cfg.n_layers):
+                block = _maybe_remat(cfg, self, f"layers_{i}")
                 x = block(cfg, self.max_out_tokens,
                           name=f"layers_{i}")(x, positions)
 

@@ -89,19 +89,25 @@ class layout_pins:
     stale multi-device mesh crashes XLA's CPU compiler (the r4
     full-suite Fatal abort — order-dependent, invisible in isolation).
     Engines enter this around every jitted call with THEIR mesh; any
-    trace outside an engine gets no pins. Re-entrant; inner-most wins."""
+    trace outside an engine gets no pins. Re-entrant; inner-most wins.
+    ``gather_edge`` is the engine's ZeRO-3 gather edge
+    (runtime/zero/partition.GatherEdge) or None: it rides the same scope
+    so the models' blocks pin their parameters with the same lifetime
+    and the same off-switch as every other pin."""
 
-    def __init__(self, mesh):
+    def __init__(self, mesh, gather_edge=None):
         self.mesh = mesh
+        self.gather_edge = gather_edge
         self._prev = None
 
     def __enter__(self):
-        self._prev = _get_pin_mesh()
+        self._prev = (_get_pin_mesh(), getattr(_pin_state, "edge", None))
         _pin_state.mesh = self.mesh
+        _pin_state.edge = self.gather_edge
         return self
 
     def __exit__(self, *exc):
-        _pin_state.mesh = self._prev
+        _pin_state.mesh, _pin_state.edge = self._prev
         return False
 
 
@@ -111,6 +117,15 @@ def pinned_mesh():
     if _pins_disabled_count() > 0:
         return None
     return _get_pin_mesh()
+
+
+def pinned_gather_edge():
+    """The pinned trace's ZeRO-3 gather edge, or None wherever
+    ``pinned_mesh()`` is None, inside an explicit-comm (Manual axes)
+    region, or when the engine has no leaf to gather."""
+    if pinned_mesh() is None or in_manual_region():
+        return None
+    return getattr(_pin_state, "edge", None)
 
 
 class no_layout_pins:

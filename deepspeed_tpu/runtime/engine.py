@@ -426,12 +426,16 @@ class DeepSpeedEngine:
                 raise ValueError(
                     "offload_optimizer device=nvme requires nvme_path")
 
-        # stage3_prefetch decides BEFORE state init: the partitioner must
-        # exclude the layer dim from stacked-leaf sharding so the prefetch
-        # scan (parallel/prefetch.py) can slice whole layers device-locally
-        if self._prefetch_active():
-            self.zero.layer_stacked_prefixes = (
-                self.module.prefetch_layer_subtree,)
+        # BEFORE state init: the partitioner judges a layer-stacked leaf
+        # one layer at a time and never shards its layer dim (the layer
+        # scan, and the stage3_prefetch scan of parallel/prefetch.py,
+        # slice whole layers device-locally)
+        stacked = getattr(self.module, "layer_stacked_subtree", None) or \
+            getattr(self.module, "prefetch_layer_subtree", None)
+        self.zero.layer_stacked_prefixes = (stacked,) if stacked else ()
+        # the ZeRO-3 gather edge of the GSPMD step programs
+        # (zero/partition.GatherEdge), built with the state shardings
+        self._gather_edge = None
 
         self._rng = rng if rng is not None else jax.random.PRNGKey(self._config.seed)
         self.state: Optional[TrainState] = None
@@ -1457,17 +1461,43 @@ class DeepSpeedEngine:
         train_step_memory_stats."""
         mesh = self.mesh
 
-        def call(*args, **kwargs):
-            with mesh_lib.layout_pins(mesh):
-                return jitted(*args, **kwargs)
+        def traced(fn, *args, **kwargs):
+            with mesh_lib.layout_pins(mesh, gather_edge=self._gather_edge):
+                out = fn(*args, **kwargs)
+            self._note_gather_edge()
+            return out
 
-        def lower(*args, **kwargs):
-            with mesh_lib.layout_pins(mesh):
-                return jitted.lower(*args, **kwargs)
-        call.lower = lower
+        call = functools.partial(traced, jitted)
+        call.lower = functools.partial(traced, jitted.lower)
         return call
 
+    def _note_gather_edge(self):
+        """Publish what the trace that just ran (if one did) sent through
+        the ZeRO-3 gather edge: per layer, the leaves that arrived
+        data-sharded and the bytes a chip holds of them once gathered.
+        Gauges plus one flight-recorder breadcrumb per traced program;
+        a cached call finds nothing engaged and returns."""
+        edge = self._gather_edge
+        if edge is None or not edge.engaged:
+            return
+        leaves, nbytes = max(edge.engaged.values())
+        blocks = len(edge.engaged)
+        edge.engaged.clear()
+        self.telemetry.gauge("zero/gather_edge_leaves").set(leaves)
+        self.telemetry.gauge("zero/gather_edge_bytes_per_layer").set(nbytes)
+        self.flight_recorder.record(
+            "zero_gather_edge", leaves=leaves, bytes_per_layer=nbytes,
+            blocks=blocks)
+        log_dist(f"zero stage 3: gather edge pins {leaves} sharded leaves "
+                 f"a layer data-replicated inside the block "
+                 f"({nbytes / 1e6:.1f} MB gathered a chip a layer)",
+                 ranks=[0])
+
     def _build_jit_fns(self):
+        # 0 until a trace sends a sharded leaf through the gather edge
+        # (_note_gather_edge): one chip, stages 0-2, explicit-comm steps
+        self.telemetry.gauge("zero/gather_edge_leaves").set(0)
+        self.telemetry.gauge("zero/gather_edge_bytes_per_layer").set(0)
         loss_fn = self._resolve_loss_fn()
         gas = self.gradient_accumulation_steps()
         batch_sh = mesh_lib.batch_sharding(self.mesh)
@@ -4218,6 +4248,7 @@ class DeepSpeedEngine:
         params, opt_state, scaler = state.params, state.opt_state, \
             state.scaler
         param_sh = self.zero.param_shardings(params)
+        self._gather_edge = self.zero.gather_edge(params)
         opt_sh = self.zero.opt_state_shardings(
             opt_state, params,
             getattr(self.optimizer, "param_like_state_fields", ()))

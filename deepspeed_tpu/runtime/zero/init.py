@@ -43,9 +43,10 @@ def sharded_init(model, rng, example_input, mesh, stage=3, tp_specs=None,
 
     shapes = jax.eval_shape(lambda r, x: model.init(r, x), rng, example_input)
     params_shapes = shapes["params"] if "params" in shapes else shapes
-    part = ZeroPartitioner(mesh, stage, tp_specs=tp_specs,
-                           param_persistence_threshold=param_persistence_threshold)
-    part.layer_stacked_prefixes = tuple(layer_stacked_prefixes)
+    part = ZeroPartitioner(
+        mesh, stage, tp_specs=tp_specs,
+        param_persistence_threshold=param_persistence_threshold,
+        layer_stacked_prefixes=layer_stacked_prefixes)
     shardings = part.param_shardings(params_shapes)
 
     @jax.jit

@@ -15,17 +15,30 @@ expressed declaratively and XLA inserts the collectives:
            backward all-reduce into reduce-scatter (+ all-gather of updated
            params) — the reference's IPG-bucket reduce-scatter
            (stage2.py:614-746).
-  stage 3: + parameters sharded at rest. Forward/backward all-gathers each
-           layer's params just-in-time; with scanned layers XLA overlaps the
-           gather of layer i+1 with compute of layer i — the reference's
-           PartitionedParameterCoordinator prefetch (stage3.py:287-447)
-           falls out of the schedule.
+  stage 3: + parameters sharded at rest. A layer's parameters reach the
+           block's arithmetic through an explicit GATHER EDGE
+           (`GatherEdge`, below): inside the rematted block each leaf
+           that rests data-sharded is pinned to its resting spec with the
+           data axis taken out, so the partitioner may choose only WHEN to
+           all-gather the weight (the forward scan's body, and again the
+           backward's recompute — the gathered copy is never a saved
+           residual), never to keep the weight sharded and re-lay the
+           activations instead. XLA schedules those gathers as async
+           collectives under the neighbouring layer's compute — the
+           reference's PartitionedParameterCoordinator prefetch
+           (stage3.py:287-447). Nothing else is pinned: parameters
+           outside the blocks (embeddings, final norm, head) and every
+           explicit-comm (shard_map) step builder are left as they were.
 
 Sharding choice per tensor: the largest dimension not already occupied by a
 tensor-parallel axis, provided it divides by the data-axis size; otherwise
 the tensor stays replicated (the analog of the reference's
 `param_persistence_threshold` — small tensors aren't worth partitioning,
-stage3.py constants ZERO_PARAM_PERSISTENCE_THRESHOLD).
+stage3.py constants ZERO_PARAM_PERSISTENCE_THRESHOLD). A layer-stacked leaf
+(`[L, ...]` under one of `layer_stacked_prefixes`) is judged as the
+reference judges it, one layer's parameter at a time: the threshold is
+compared with `prod(shape[1:])`, and the layer dim is never the one sharded
+(a scan slices it).
 """
 
 from typing import Optional
@@ -42,20 +55,24 @@ def shard_spec_for_leaf(shape,
                         base_spec: Optional[PartitionSpec] = None,
                         min_size: int = 0,
                         axis_name: str = mesh_lib.DATA_AXIS,
-                        exclude_dims=()) -> PartitionSpec:
+                        layer_stacked: bool = False) -> PartitionSpec:
     """Extend ``base_spec`` (TP sharding) with a data-axis shard on the
     largest free, divisible dimension. Returns base_spec unchanged if no
     dimension qualifies or the tensor is below ``min_size`` elements.
-    ``exclude_dims`` removes dimensions from candidacy — the prefetch
-    pipeline needs layer-stacked leaves whole along their layer dim."""
+    ``layer_stacked`` says dim 0 is the layer dim of a stacked leaf: it
+    is never a candidate (a layer scan, and the prefetch pipeline, slice
+    whole layers device-locally) and ``min_size`` is compared with ONE
+    layer's elements, the unit the threshold was defined on."""
     base = tuple(base_spec) if base_spec is not None else ()
     base = base + (None,) * (len(shape) - len(base))
-    if dp_size <= 1 or int(np.prod(shape or (1,))) < max(min_size, dp_size):
+    first = 1 if layer_stacked else 0
+    if dp_size <= 1 or \
+            int(np.prod(shape[first:] or (1,))) < max(min_size, dp_size):
         return PartitionSpec(*base)
     # candidate dims: unsharded, divisible by dp, largest first
     candidates = sorted(
-        (d for d in range(len(shape))
-         if d not in exclude_dims and base[d] is None
+        (d for d in range(first, len(shape))
+         if base[d] is None
          and shape[d] % dp_size == 0 and shape[d] >= dp_size),
         key=lambda d: shape[d], reverse=True)
     if not candidates:
@@ -66,6 +83,56 @@ def shard_spec_for_leaf(shape,
     return PartitionSpec(*new)
 
 
+def _path_keys(path):
+    """A tree path as a tuple of plain keys (dict key, index or attribute
+    name), the form model code can rebuild from a module's scope path."""
+    return tuple(
+        getattr(p, "key", getattr(p, "idx", getattr(p, "name", None)))
+        for p in path)
+
+
+class GatherEdge:
+    """The stage-3 gather edge: what a model's block needs in order to
+    receive its parameters data-replicated (module docstring). ``specs``
+    maps the path of every parameter leaf that rests data-sharded to its
+    COMPUTE spec — the resting spec with the data axis taken out (tensor-
+    and expert-parallel axes stay), and for a layer-stacked leaf without
+    the layer dim, since the block sees one layer's slice. The engine
+    hands the edge to the trace through ``mesh_lib.layout_pins``; model
+    code calls it on a block's parameter subtree inside the remat."""
+
+    def __init__(self, mesh, specs):
+        self.mesh = mesh
+        self.specs = specs
+        # {block path: (leaves, gathered bytes a chip)} of the blocks the
+        # current trace sent through the edge; the engine reads and
+        # clears it after each compile (zero/gather_edge_* gauges)
+        self.engaged = {}
+
+    def __call__(self, path, tree):
+        """Pin the leaves of ``tree`` (the parameter subtree at ``path``
+        of the engine's params) that rest data-sharded to their compute
+        spec; every other leaf is returned as it is."""
+        path = tuple(path)
+        leaves = nbytes = 0
+
+        def pin(leaf_path, x):
+            nonlocal leaves, nbytes
+            spec = self.specs.get(path + _path_keys(leaf_path))
+            if spec is None:
+                return x
+            sharding = NamedSharding(self.mesh, spec)
+            leaves += 1
+            nbytes += int(np.prod(sharding.shard_shape(x.shape))) \
+                * x.dtype.itemsize
+            return jax.lax.with_sharding_constraint(x, sharding)
+
+        out = jax.tree_util.tree_map_with_path(pin, tree)
+        if leaves:
+            self.engaged[path] = (leaves, nbytes)
+        return out
+
+
 class ZeroPartitioner:
     """Produces NamedShardings for params / grads / optimizer state given the
     configured ZeRO stage. ``tp_specs`` is an optional pytree of
@@ -73,7 +140,7 @@ class ZeroPartitioner:
 
     def __init__(self, mesh: Mesh, stage: int, tp_specs=None,
                  param_persistence_threshold: int = 0,
-                 param_memory_kind=None):
+                 param_memory_kind=None, layer_stacked_prefixes=()):
         assert 0 <= stage <= 3
         self.mesh = mesh
         self.stage = stage
@@ -85,10 +152,10 @@ class ZeroPartitioner:
         # and stream to HBM inside the step via device_put
         self.param_memory_kind = param_memory_kind
         # top-level param-tree keys whose leaves are layer-stacked
-        # ([L, ...]): their dim 0 is never a shard candidate, so the
-        # stage3_prefetch pipeline can slice whole layers device-locally
-        # (the engine sets this when the prefetch path is active)
-        self.layer_stacked_prefixes = ()
+        # ([L, ...]): judged one layer at a time (shard_spec_for_leaf's
+        # ``layer_stacked``). The engine sets this from the model's
+        # ``layer_stacked_subtree`` / ``prefetch_layer_subtree``
+        self.layer_stacked_prefixes = tuple(layer_stacked_prefixes)
 
     # -- spec trees --------------------------------------------------------
     def _base_spec(self, path, leaf):
@@ -97,33 +164,43 @@ class ZeroPartitioner:
         # tp_specs is a matching tree; fetch by path
         sub = self.tp_specs
         try:
-            for p in path:
-                key = getattr(p, "key", None)
-                if key is None:
-                    key = getattr(p, "idx", None)
-                if key is None:
-                    key = getattr(p, "name", None)
+            for key in _path_keys(path):
                 sub = sub[key]
             return sub
         except (KeyError, TypeError, IndexError):
             return None
 
+    def _layer_stacked(self, path):
+        return bool(path) and \
+            _path_keys(path[:1])[0] in self.layer_stacked_prefixes
+
     def _zero_spec(self, path, leaf):
-        base = self._base_spec(path, leaf)
-        exclude = ()
-        if self.layer_stacked_prefixes and path:
-            head = getattr(path[0], "key", getattr(path[0], "name", None))
-            if head in self.layer_stacked_prefixes:
-                exclude = (0,)
-        return shard_spec_for_leaf(leaf.shape, self.dp, base,
+        return shard_spec_for_leaf(leaf.shape, self.dp,
+                                   self._base_spec(path, leaf),
                                    min_size=self.min_size,
-                                   exclude_dims=exclude)
+                                   layer_stacked=self._layer_stacked(path))
 
     def _tp_only_spec(self, path, leaf):
         base = self._base_spec(path, leaf)
         base = tuple(base) if base is not None else ()
         base = base + (None,) * (len(leaf.shape) - len(base))
         return PartitionSpec(*base)
+
+    def gather_edge(self, params) -> Optional[GatherEdge]:
+        """The gather edge for this partitioning of ``params``, or None
+        when no parameter rests data-sharded (stages 0-2, a data axis of
+        one, or every leaf under the persistence threshold)."""
+        if self.stage < 3 or self.dp <= 1:
+            return None
+        specs = {}
+        for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+            rest = self._zero_spec(path, leaf)
+            if mesh_lib.DATA_AXIS in jax.tree_util.tree_leaves(tuple(rest)):
+                compute = tuple(self._tp_only_spec(path, leaf))
+                if self._layer_stacked(path):
+                    compute = compute[1:]
+                specs[_path_keys(path)] = PartitionSpec(*compute)
+        return GatherEdge(self.mesh, specs) if specs else None
 
     def param_specs(self, params):
         """Stage 3 shards params at rest; stages 0-2 keep them replicated

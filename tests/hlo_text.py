@@ -1,0 +1,70 @@
+"""Questions the ZeRO tests ask of a compiled step's HLO text (CPU or TPU
+compiler alike): which computations run inside a ``while`` loop — a layer
+scan's body and whatever it calls — and which collectives sit there."""
+
+import re
+
+_HEAD = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_CALLEE = re.compile(
+    r"(?:body|condition|to_apply|calls|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+
+
+def computations(text):
+    """{computation name: its lines} of an HLO module's text."""
+    out, name = {}, None
+    for line in text.splitlines():
+        head = _HEAD.match(line)
+        if head and not line.startswith(" "):
+            name = head.group(1)
+            out[name] = []
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def _callees(lines):
+    for line in lines:
+        for one, many in _CALLEE.findall(line):
+            if one:
+                yield one
+            for part in many.split(","):
+                if part.strip():
+                    yield part.strip().lstrip("%")
+
+
+def loop_bodies(text):
+    """{while-body name: lines of the body and of every computation it
+    reaches}, one entry per ``while`` instruction of the module."""
+    comps = computations(text)
+    bodies = {}
+    for lines in comps.values():
+        for line in lines:
+            if re.search(r"\bwhile\(", line):
+                body = re.search(r"body=%?([\w.\-]+)", line).group(1)
+                seen, todo = set(), [body]
+                while todo:
+                    name = todo.pop()
+                    if name in seen or name not in comps:
+                        continue
+                    seen.add(name)
+                    todo.extend(_callees(comps[name]))
+                bodies[body] = [ln for name in sorted(seen)
+                                for ln in comps[name]]
+    return bodies
+
+
+def instructions(lines, opcode):
+    """The lines of ``lines`` that are ``opcode`` (or its async
+    ``-start``) instructions."""
+    pat = re.compile(rf"= \S+ {re.escape(opcode)}(?:-start)?\(")
+    return [ln for ln in lines if pat.search(ln)]
+
+
+def result_elements(line):
+    """Element count of an instruction line's (first) result shape."""
+    dims = re.search(r"= \(?\w+\[([\d,]*)\]", line).group(1)
+    n = 1
+    for d in filter(None, dims.split(",")):
+        n *= int(d)
+    return n

@@ -194,6 +194,17 @@ def test_o_direct_metric_names_documented():
         assert name in _package_source(), name
 
 
+def test_zero_gather_edge_metric_names_documented():
+    """The ZeRO-3 gather edge's two gauges (ISSUE 25) stay documented
+    AND emitted."""
+    documented = documented_metric_names()
+    for name in ("zero/gather_edge_leaves",
+                 "zero/gather_edge_bytes_per_layer"):
+        assert name in documented, (
+            f"{name} missing from the docs/observability.md train table")
+        assert name in _package_source(), name
+
+
 # ------------------------------------------------------- prometheus page
 
 # the exposition-format line grammar a real scraper applies

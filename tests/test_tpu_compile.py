@@ -39,6 +39,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,  # noqa: E402
                           SingleDeviceSharding)
 
 import chip_smoke  # noqa: E402  (the model, batch and serving block it runs)
+from tests import hlo_text  # noqa: E402
 
 
 @functools.cache
@@ -305,10 +306,22 @@ def test_train_step_scope_names_reach_the_compiled_text(one_chip_step):
     assert_scope_names(one_chip_step[1].as_text())
 
 
+def test_one_chip_train_step_emits_no_gather_edge(one_chip_step, monkeypatch):
+    """With a data axis of one the ZeRO-3 gather edge does not exist: the
+    step lowers to the same text, so the same instructions per opcode, as
+    with the edge's source (``mesh_lib.pinned_gather_edge``) cut off."""
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+    monkeypatch.setattr(mesh_lib, "pinned_gather_edge", lambda: None)
+    assert lower_train_step(1).as_text() == one_chip_step[0]
+
+
 def test_train_step_compiles_for_four_chips_sharded(one_chip_step):
     """ZeRO-3 over data=4: the flash kernel survives partitioning (inside a
     shard_map — GSPMD refuses a bare Mosaic call), every chip holds a quarter
-    of the state, and the step gathers parameters."""
+    of the state, and the step gathers parameters — the WEIGHTS, through the
+    gather edge: no activation is re-laid (no all-to-all), no sharded small
+    leaf is gathered as update-slice + all-reduce inside a layer scan, and
+    a chip's temporaries stay under the one-chip step's."""
     lowered = lower_train_step(4)
     assert kernel_names(lowered.as_text()) == {"_fwd_kernel",
                                                "_bwd_fused_kernel"}
@@ -316,9 +329,20 @@ def test_train_step_compiles_for_four_chips_sharded(one_chip_step):
     quarter = one_chip_step[1].memory_analysis().argument_size_in_bytes / 4
     per_chip = compiled.memory_analysis().argument_size_in_bytes
     assert abs(per_chip - quarter) <= chip_smoke.SPREAD_RTOL * quarter
+    assert compiled.memory_analysis().temp_size_in_bytes <= \
+        one_chip_step[1].memory_analysis().temp_size_in_bytes
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and "all-gather" in hlo
     assert_scope_names(hlo)     # inside the shard_map too
+    assert not hlo_text.instructions(hlo.splitlines(), "all-to-all")
+    layer_scans = [lines for lines in hlo_text.loop_bodies(hlo).values()
+                   if any("/blk/" in ln for ln in lines)]
+    assert len(layer_scans) == 2        # forward and backward
+    for lines in layer_scans:
+        assert hlo_text.instructions(lines, "all-gather")
+        assert not [ln for ln in hlo_text.instructions(lines, "all-reduce")
+                    if "(%dynamic-update-slice" in ln
+                    and hlo_text.result_elements(ln) < 1e5]
 
 
 # ------------------------------------- optional kernels: known refusals
