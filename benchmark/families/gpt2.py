@@ -6,7 +6,7 @@ from the seed, hands the system under test to a traffic kind through the
 program's normal entry points (``dstpu.initialize``,
 ``serving.build_engine``), maps the system's parameter tree onto the plain
 reference's layout, and decides ``correct``. A later family is a new file
-here with the same functions.
+here with the same members (``benchmark/families/__init__.py`` is the list).
 """
 
 import dataclasses
@@ -15,6 +15,11 @@ import numpy as np
 
 from benchmark import roofline
 from benchmark.reference import gpt2 as ref
+
+WIDTH_KEYS = ("n_embd", "n_head", "n_inner")
+KERNEL_TAGS = ("flash_fwd", "flash_bwd")    # also their long-S variants
+MODULE_TAGS = ("ds_loss_head", "ds_embed", "attn", "mlp", "ln_1", "ln_2",
+               "ln_f")
 
 
 def sizes(config, rehearse):
@@ -27,6 +32,12 @@ def sizes(config, rehearse):
         out.update({k: v for k, v in config["rehearse_cpu"].items()
                     if k in keys})
     return out
+
+
+def traffic_shapes(config, rehearse):
+    s = sizes(config, rehearse)
+    return {"vocab_size": s["vocab_size"], "max_positions": s["n_positions"],
+            "seq_scale": s["n_positions"] / config["n_positions"]}
 
 
 def _merged(config, section, rehearse):
@@ -92,6 +103,48 @@ def build_train(config, global_batch, seed, devices, rehearse):
     engine, _, _, _ = dstpu.initialize(config=ds, model=model, mesh=mesh,
                                        model_parameters=params)
     return engine, params
+
+
+def lower_train_step(config, traffic, devices):
+    """The cell's train step at real size, lowered over abstract state laid
+    out as the engine lays it out on ``devices`` (a plain reshape onto the
+    data axis: described chips have no attached topology to line up)."""
+    import jax
+    import jax.numpy as jnp
+    import deepspeed_tpu as dstpu
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+    from deepspeed_tpu.runtime import precision as prec
+    from deepspeed_tpu.runtime.engine import TrainState
+
+    SDS = jax.ShapeDtypeStruct
+    batch = traffic["global_batch"]
+    mesh = Mesh(np.asarray(devices).reshape((1, len(devices), 1, 1, 1)),
+                mesh_lib.AXIS_ORDER)
+    engine, _, _, _ = dstpu.initialize(
+        config=engine_config(config, batch, 0, False),
+        model=GPT2LMHeadModel(model_config(config, rehearse=False)),
+        mesh=mesh)
+    ids = SDS((batch, traffic["seq_len"]), jnp.int32)
+    params = jax.eval_shape(lambda r, x: engine.module.init(r, x)["params"],
+                            jax.random.PRNGKey(0), ids)
+    state = TrainState(
+        params=params, opt_state=jax.eval_shape(engine.optimizer.init, params),
+        scaler=jax.eval_shape(lambda: prec.init_scaler_state(engine.precision)),
+        global_step=SDS((), jnp.int32), skipped_steps=SDS((), jnp.int32))
+    engine.state_shardings = engine._build_state_shardings(state)
+    engine._build_jit_fns()
+    state = jax.tree_util.tree_map(
+        lambda s, sh: SDS(s.shape, s.dtype, sharding=sh), state,
+        engine.state_shardings)
+    rng = jax.random.PRNGKey(0)
+    return engine._jit_train_batch.lower(
+        state,
+        {"input_ids": SDS(ids.shape, ids.dtype,
+                          sharding=mesh_lib.batch_sharding(mesh))},
+        SDS(rng.shape, rng.dtype,
+            sharding=NamedSharding(mesh, PartitionSpec())))
 
 
 def _reference_view(params, n_layer, device):
@@ -251,6 +304,59 @@ def check_serving(config, eng, params, prompts, rehearse, pad_to,
     detail = {"logit_max_abs_diff_prefill_then_decode": diffs,
               "logits_abs_tol": tol, "prompt_tokens": [len(p) for p in prompts]}
     return checks, detail
+
+
+def lower_serving(config, traffic, device):
+    """(facts, programs): the pool's size, and lazily (name, Lowered) of
+    every tick program (each step count the engine uses) and prefill bucket
+    of the cell, over abstract weights and the configured pool on
+    ``device``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel
+    from deepspeed_tpu.models.gpt2_inference import convert_gpt2_params
+    from deepspeed_tpu.serving import (GPT2ServingAdapter,
+                                       cache_spec_from_config)
+    SDS, I32 = jax.ShapeDtypeStruct, jnp.int32
+    cfg = model_config(config, rehearse=False, serving=True)
+    one = SingleDeviceSharding(device)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda s: SDS(s.shape, s.dtype, sharding=one), tree)
+
+    served = jnp.dtype(config["serve"]["weights_dtype"])
+    ip = jax.eval_shape(
+        lambda r: convert_gpt2_params(jax.tree_util.tree_map(
+            lambda a: a.astype(served), GPT2LMHeadModel(cfg).init(
+                r, jnp.zeros((1, 8), I32))["params"]), cfg),
+        jax.random.PRNGKey(0))
+    spec = cache_spec_from_config(cfg, "gpt2",
+                                  {"serving": config["serve"]["serving"]})
+    nb = spec.resolved_num_blocks()
+    shape = (spec.n_layers, nb, spec.kv_heads, spec.page_size, spec.head_dim)
+    pool = (SDS(shape, spec.dtype), SDS(shape, spec.dtype))
+    adapter = GPT2ServingAdapter(cfg, ip, spec)
+    B, MAXP, Pg = spec.slots, spec.max_pages_per_slot, spec.page_size
+
+    def vec(dt):
+        return SDS((B,), dt)
+
+    def programs():
+        for steps in traffic.get("tick_steps", [1, 2, 4, 8, 16, 32]):
+            yield f"tick x{steps}", adapter._tick_fn(steps).lower(*on_chip((
+                adapter._p, adapter._blk, pool, vec(I32), vec(I32),
+                SDS((B, MAXP), I32), vec(jnp.uint32), vec(I32),
+                vec(jnp.float32))))
+        for pages in traffic["prefill_page_buckets"]:
+            yield f"prefill {pages * Pg}", adapter._prefill_fn(pages).lower(
+                *on_chip((adapter._p, adapter._blk, pool,
+                          SDS((1, pages * Pg), I32), SDS((), I32),
+                          SDS((pages,), I32))))
+
+    return {"pool_blocks": nb, "pool_gb": 2 * np.prod(shape) * 2 / 1e9}, \
+        programs()
 
 
 # ------------------------------------------------- operations and bytes

@@ -41,8 +41,12 @@ def log(*parts):
 
 
 def mark(ctx, what):
-    """Log how long after process start a set-up phase ended."""
-    log(f"t+{time.monotonic() - ctx.t_start:.1f}s {what}")
+    """Log how long after process start a set-up phase ended, and the
+    process's peak of live device bytes so far (which phase set
+    ``*_peak_hbm_gb``; the CPU reports none)."""
+    peak = memory_peak_bytes(ctx.cell["chips"])
+    log(f"t+{time.monotonic() - ctx.t_start:.1f}s {what}"
+        + (f" (peak {peak / 1e9:.3f} GB live)" if peak else ""))
 
 
 def span(name):

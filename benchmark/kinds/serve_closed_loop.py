@@ -15,14 +15,15 @@ from benchmark import serve_loop, traffic
 
 
 class Feeder(serve_loop.Feeder):
-    def __init__(self, ctx, eng, tracker, sizes, scale):
+    def __init__(self, ctx, eng, tracker, shapes):
         import deepspeed_tpu.serving as serving
         super().__init__(eng, tracker)
         self.Request = serving.Request
         n = ctx.traffic["clients"]
         n = eng.spec.slots if n == "slots" else int(n)
         self.clients = [traffic.closed_loop_client(
-            ctx.traffic, ctx.seed, c, sizes["vocab_size"], scale)
+            ctx.traffic, ctx.seed, c, shapes["vocab_size"],
+            shapes["seq_scale"])
             for c in range(n)]
         self.sent = [0] * n
 

@@ -10,7 +10,11 @@ behind, so the device queue never drains. The clock starts on a fence and
 ends on the fence of the last step dispatched before ``--seconds`` ran
 out: ``train_tokens_per_s`` is the tokens of all those steps over that
 span. A traced run adds ``trace_steps`` more steps under the profiler
-after the window.
+after the window, and keeps the step's executable: its text for the scope
+join and its ``memory_analysis()`` for ``train_program_hbm_gb``.
+
+Nothing here reads a model's key: sizes, lengths and the vocabulary come
+from the cell's family (``benchmark/families/__init__.py``).
 """
 
 import time
@@ -49,14 +53,29 @@ def _steps(engine, batches, start, stop_after_s, lag, max_steps=None):
     return losses, done
 
 
+MEMORY_FIELDS = ("argument_size", "output_size", "alias_size", "temp_size",
+                 "generated_code_size", "peak_memory")
+
+
+def step_program(engine, batch_ids):
+    """(compiled text, {field: bytes on one chip}) of the step that ran (an
+    executable-cache hit after the first ``train_batch``). The fields are
+    the compiler's ``memory_analysis()``: buffer assignment, exact, one
+    program's — where ``memory_stats()`` reports the process's live buffers."""
+    compiled = engine.lower_train_step({"input_ids": batch_ids}).compile()
+    analysis = compiled.memory_analysis()
+    return compiled.as_text(), {
+        f: int(getattr(analysis, f + "_in_bytes")) for f in MEMORY_FIELDS}
+
+
 def run(ctx):
     import jax
     p, config, family = ctx.traffic, ctx.config, ctx.family
     chips = ctx.cell["chips"]
     devices = jax.devices()[:chips]
-    s = family.sizes(config, ctx.rehearse)
-    scale = s["n_positions"] / config["n_positions"]
-    batches = traffic.train_batches(p, ctx.seed, s["vocab_size"], scale)
+    shapes = family.traffic_shapes(config, ctx.rehearse)
+    batches = traffic.train_batches(p, ctx.seed, shapes["vocab_size"],
+                                    shapes["seq_scale"])
     tokens_per_step = batches[0].size
 
     with span("bench/build"):
@@ -78,8 +97,8 @@ def run(ctx):
 
     record = harness.Record(**ctx.base)
     if ctx.trace:
-        record.compiled_text = engine.lower_train_step(
-            {"input_ids": batches[0]}).compile().as_text()
+        record.compiled_text, record.extra["step_program_memory"] = \
+            step_program(engine, batches[0])
 
     compiles = ctx.compiles.count
     t0 = time.monotonic()
@@ -97,6 +116,12 @@ def run(ctx):
         len(done) * tokens_per_step / record.window_s
     record.samples["train_tokens_per_s"] = done
     record.samples["step_s"] = list(np.diff([t0] + done))
+    # one number a step: a run that reads far off says here whether one
+    # fence waited (a stalled host, a drained queue) or every step was slow
+    step_s = record.samples["step_s"]
+    detail["window_step_s_min_median_max_slowest"] = [
+        float(np.min(step_s)), float(np.median(step_s)), float(np.max(step_s)),
+        int(np.argmax(step_s))]
     record.attempted, record.failed = len(done), 0
     record.extra.update(tokens_per_step=tokens_per_step,
                         global_batch=p["global_batch"],

@@ -17,6 +17,8 @@ SOURCE = "device_trace"
 
 
 def read(record):
-    if not readers.traced(record):
+    if record.peaks is None:
         return None
-    return scope_reduce.kernel_roofline(record, "flash_bwd", 2 / 3)
+    return scope_reduce.kernel_roofline(
+        record, "flash_bwd", 2 / 3 * readers.attention_flops_per_step(record),
+        record.peaks["bf16_flops_per_s"])

@@ -10,9 +10,15 @@ one per-layer metric sits in a file of its own, found by the name in
     benchmark/families/<family>.py         how a model family is built
     benchmark/layer_metrics/<metric>.py    one reader per per-layer metric
 
-so a later PR adds a cell, a configuration or a metric by adding files and
-an entry, and edits nothing that is there. ``problems()`` holds the manifest
-to the driver's rules; the tests run it.
+so a later PR adds a cell, a configuration, a metric or a model of another
+family by adding files and entries, and edits nothing that is there. A
+family file is the one place that knows a model: its classes, the names of
+its configuration keys (``hidden_size`` or ``n_embd``), its widths, its
+module and kernel scopes, its counts of operations, and how its programs
+are lowered for ``tools/rehearse_compile.py``. The harness reads no model
+key itself; what it asks of a family is listed in
+``benchmark/families/__init__.py``. ``problems()`` holds the manifest to
+the driver's rules; the tests run it.
 """
 
 import copy

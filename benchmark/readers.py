@@ -26,6 +26,14 @@ def peak_hbm_gb(record):
     return peak / 1e9 if peak else None
 
 
+def attention_flops_per_step(record):
+    """Causal attention flops (forward + backward) one chip's step needs,
+    by the family's count."""
+    per_chip = record.extra["global_batch"] // record.cell["chips"]
+    return record.family.train_attention_flops_per_step(
+        record.config, per_chip, record.extra["seq_len"], record.rehearse)
+
+
 def compiles_in_window(record):
     return record.compiles_in_window
 

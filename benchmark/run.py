@@ -3,7 +3,9 @@
     python -m benchmark.run --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Finds the cell in ``BENCHMARK.json``, its configuration, traffic, kind and
-family files by name, refuses to run without the cell's count of TPU
+family files by name (the family file, ``benchmark/families/<family>.py``,
+is what knows the model: neither this command nor the kinds read a model's
+key), refuses to run without the cell's count of TPU
 devices, warms up, measures for ``--seconds`` and prints as the LAST line
 of standard output one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1`` its

@@ -2,17 +2,20 @@
 
 An ``XLA Ops`` event is named by its instruction's text WITHOUT metadata
 (``trace_reduce``'s account of the plane), so the scopes the program traces
-under (``telemetry.spans.annotate``'s list: ``ds_fwd_bwd``, ``ds_optimizer``,
-``ds_loss_head``, ``ds_embed``, ``flash_fwd``, ``flash_bwd``) reach a device
-event only through a JOIN: the event's instruction name (``%fusion.430``) is
+under (``telemetry.spans.annotate``'s list: the engine's ``ds_fwd_bwd`` and
+``ds_optimizer``, and each model's and kernel's own) reach a device event
+only through a JOIN: the event's instruction name (``%fusion.430``) is
 looked up in the compiled step's text, whose ``metadata={op_name="..."}``
 holds the whole path the instruction was traced under —
 
-    jit(train_batch_fn)/ds_fwd_bwd/transpose(jvp(GPT2LMHeadModel))/while/body/
-        closed_call/h/h/checkpoint/rematted_computation/blk/ln_2/mul
+    jit(train_batch_fn)/ds_fwd_bwd/transpose(jvp(<Model>))/while/body/
+        closed_call/h/h/checkpoint/rematted_computation/blk/<module>/mul
 
 — phase (``ds_optimizer``), direction (``jvp(`` / ``transpose(jvp(``),
 recomputation (``rematted_computation``) and the flax module or kernel. The
+phases are the engine's and JAX's own path elements; which modules and which
+kernel scopes a path can hold is the MODEL's, so the tags come from the
+cell's family (``KERNEL_TAGS``, ``MODULE_TAGS``) and none is written here. The
 text is ``record.compiled_text`` (the executable that ran, from the
 executable cache). An instruction the text does not have, or has under
 another opcode or shape, is ``unscoped``: a text that is not the program that
@@ -26,9 +29,6 @@ import re
 from benchmark import readers, roofline, trace_reduce
 
 PHASES = ("forward", "backward", "recompute", "optimizer", "unscoped")
-KERNEL_TAGS = ("flash_fwd", "flash_bwd")    # also their long-S variants
-MODULE_TAGS = ("ds_loss_head", "ds_embed", "attn", "mlp", "ln_1", "ln_2",
-               "ln_f")
 SLOT = "scope_attribution"                  # where record.extra keeps it
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?((%[^\s=]+) = .*)$", re.M)
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -57,24 +57,25 @@ def phase_of(op_name):
     return "unscoped"
 
 
-def tag_of(op_name):
-    """The kernel or module an ``op_name`` path runs through: a kernel's
-    scope first (a path element that starts with it, so ``flash_bwd_dkv``
-    is ``flash_bwd``), then the first listed module that is an element."""
+def tag_of(op_name, family):
+    """The kernel or module an ``op_name`` path runs through: one of the
+    family's kernel scopes first (a path element that STARTS with it, so a
+    kernel's variants share its tag), then the first of the family's module
+    tags that is an element."""
     parts = op_name.split("/")
-    for tag in KERNEL_TAGS:
+    for tag in family.KERNEL_TAGS:
         if any(p.startswith(tag) for p in parts):
             return tag
-    return next((tag for tag in MODULE_TAGS if tag in parts), "-")
+    return next((tag for tag in family.MODULE_TAGS if tag in parts), "-")
 
 
-def place(table, event_name):
+def place(table, event_name, family):
     """(phase, tag, op_name) of a device event; ``unscoped`` where the table
     does not hold the event's instruction under the same opcode and shape."""
     op_name, label = table.get(event_name.partition(" = ")[0], ("", None))
     if label != trace_reduce.label(event_name):
         return "unscoped", "-", ""
-    return phase_of(op_name), tag_of(op_name), op_name
+    return phase_of(op_name), tag_of(op_name, family), op_name
 
 
 def kind_of(event_name):
@@ -83,16 +84,16 @@ def kind_of(event_name):
     return "collective" if trace_reduce.is_collective(event_name) else "op"
 
 
-def chip_attribution(events, table, steps):
+def chip_attribution(events, table, steps, family):
     """One chip's slice, in ms a step: self time (a ``while`` counts only
     what its body does not cover) by (phase, tag, kind), by phase, the
-    Pallas time of each kernel tag, and the heaviest single instructions
-    that are unscoped or collectives."""
+    Pallas time of each of the family's kernel tags, and the heaviest single
+    instructions that are unscoped or collectives."""
     rows = collections.Counter()
     unscoped, collectives = collections.Counter(), collections.Counter()
     for i, ns in trace_reduce.self_times(events).items():
         name = events[i].name
-        phase, tag, op_name = place(table, name)
+        phase, tag, op_name = place(table, name, family)
         kind = kind_of(name)
         rows[phase, tag, kind] += ns
         if phase == "unscoped":
@@ -105,7 +106,7 @@ def chip_attribution(events, table, steps):
         return [[*key, ns / per_step] for key, ns in counter.most_common(n)]
 
     phases = {p: 0.0 for p in PHASES}
-    kernels = {k: 0.0 for k in KERNEL_TAGS}
+    kernels = {k: 0.0 for k in family.KERNEL_TAGS}
     for (phase, tag, kind), ns in rows.items():
         phases[phase] += ns / per_step
         if kind == "pallas" and tag in kernels:
@@ -134,7 +135,7 @@ def attribution(record):
             trace_reduce.ops(record.trace, plane), t0, t1)
         steps = len(trace_reduce.modules(record.trace, plane,
                                          record.extra["step_module"]))
-        chips[plane] = chip_attribution(events, table, steps)
+        chips[plane] = chip_attribution(events, table, steps, record.family)
     busiest = max(chips, key=lambda p: chips[p]["busy_ms"])
     record.extra[SLOT] = {"chip": busiest, "chips": chips}
     return record.extra[SLOT]
@@ -151,15 +152,26 @@ def phase_ms(record, phase):
     return chip["phase_ms"][phase] if chip else None
 
 
-def kernel_roofline(record, tag, share_of_flops):
-    """``share_of_flops`` of the step's causal attention flops over the bf16
-    peak over the Pallas time under ``tag``, on the busiest chip; None where
-    no event carries the tag (a program without the scope)."""
-    chip = busiest_chip(record)
-    if not chip or not chip["kernel_ms"][tag] or record.peaks is None:
+def kernel_ms(record, tags):
+    """{chip: Pallas ms a step under ``tags`` together}; None where no chip
+    ran a kernel under any of them (a program without the scope, a family
+    that does not list it, no trace)."""
+    found = attribution(record)
+    if not found:
         return None
-    per_chip = record.extra["global_batch"] // record.cell["chips"]
-    flops = share_of_flops * record.family.train_attention_flops_per_step(
-        record.config, per_chip, record.extra["seq_len"], record.rehearse)
-    return roofline.share(flops, record.peaks["bf16_flops_per_s"],
-                          chip["kernel_ms"][tag] / 1e3)
+    out = {plane: sum(chip["kernel_ms"].get(t, 0.0) for t in tags)
+           for plane, chip in found["chips"].items()}
+    return out if any(out.values()) else None
+
+
+def kernel_roofline(record, tag, needed, peak_per_s):
+    """A kernel's share of its roofline: ``needed`` operations (or bytes) a
+    step on one chip, counted by the caller from ``roofline.py`` and its
+    family, over ``peak_per_s`` (the row of ``record.peaks`` that bounds the
+    kernel), over the Pallas time under ``tag`` on the busiest chip. None
+    where no event carries the tag."""
+    per_chip = kernel_ms(record, (tag,))
+    if not per_chip:
+        return None
+    busiest = attribution(record)["chip"]
+    return roofline.share(needed, peak_per_s, per_chip[busiest] / 1e3)

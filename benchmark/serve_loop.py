@@ -116,9 +116,9 @@ def build(ctx):
     program warmed, the outputs held to the reference."""
     from deepspeed_tpu.telemetry.registry import MetricsRegistry
     p, config, family = ctx.traffic, ctx.config, ctx.family
-    s = family.sizes(config, ctx.rehearse)
-    scale = s["n_positions"] / config["n_positions"]
-    vocab = min(p["token_below"], s["vocab_size"])
+    shapes = family.traffic_shapes(config, ctx.rehearse)
+    scale = shapes["seq_scale"]
+    vocab = min(p["token_below"], shapes["vocab_size"])
     registry = MetricsRegistry()
     with span("bench/build"):
         eng, params = family.build_serving(config, ctx.seed, ctx.rehearse,
@@ -133,24 +133,25 @@ def build(ctx):
         longest = p["prompt_tokens"].get("max") or p["prompt_tokens"]["value"]
         checks, detail = family.check_serving(
             config, eng, params, prompts, ctx.rehearse,
-            pad_to=min(int(round(longest * scale)) + 16, s["n_positions"]))
+            pad_to=min(int(round(longest * scale)) + 16,
+                       shapes["max_positions"]))
     harness.mark(ctx, "outputs checked against the reference")
     return eng, registry, checks, detail
 
 
 def run(ctx, make_feeder):
-    """Drive one serving cell. ``make_feeder(ctx, eng, tracker, sizes,
-    scale)`` returns the kind's feeder."""
-    p, config, family = ctx.traffic, ctx.config, ctx.family
-    s = family.sizes(config, ctx.rehearse)
-    scale = s["n_positions"] / config["n_positions"]
+    """Drive one serving cell. ``make_feeder(ctx, eng, tracker, shapes)``
+    returns the kind's feeder; ``shapes`` is the family's
+    ``traffic_shapes``."""
+    p = ctx.traffic
     eng, registry, checks, detail = build(ctx)
 
     tracker = Tracker()
     record = harness.Record(**ctx.base)
     warmup_s = p["warmup_s"] * (0.2 if ctx.rehearse else 1.0)
     trace_s = p["trace_s"] if ctx.trace else 0.0
-    feeder = make_feeder(ctx, eng, tracker, s, scale)
+    feeder = make_feeder(ctx, eng, tracker,
+                         ctx.family.traffic_shapes(ctx.config, ctx.rehearse))
     prof = harness.Profiler(ctx.tag) if ctx.trace else None
 
     # every phase changes on a step's return: window_open and t1 are edges

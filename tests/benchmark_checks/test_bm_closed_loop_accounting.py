@@ -44,8 +44,8 @@ def loop():
         traffic = json.load(f)
     ctx = types.SimpleNamespace(traffic=traffic, seed=5)
     eng, tracker = Engine(4), serve_loop.Tracker()
-    feeder = serve_closed_loop.Feeder(ctx, eng, tracker,
-                                      {"vocab_size": 50304}, 1.0)
+    feeder = serve_closed_loop.Feeder(
+        ctx, eng, tracker, {"vocab_size": 50304, "seq_scale": 1.0})
     feeder.start(0.0)
     return eng, tracker, feeder
 

@@ -1,4 +1,12 @@
-"""BENCHMARK.json against the driver's rules, and the files it names."""
+"""BENCHMARK.json against the driver's rules, and the files it names.
+
+Every rule here is held on whatever ``BENCHMARK.json`` and files the imported
+``benchmark`` package sits beside: each configuration against its own file
+and its family, each family file against ``benchmark/families/__init__.py``'s
+list. ``test_bm_rehearsal_runs.py`` runs this file again on a copy of the
+checkout to which a configuration of another family was added as files and
+entries only. What is known of GPT-2 alone sits in the cases that name it.
+"""
 
 import copy
 import importlib
@@ -7,19 +15,32 @@ import os
 
 import pytest
 
-from benchmark import manifest
+from benchmark import families, manifest
 
 BENCH = manifest.load()
+
+
+def _listed(directory, ending):
+    return sorted(f[:-len(ending)] for f in os.listdir(
+        os.path.join(manifest.HERE, directory))
+        if f.endswith(ending) and not f.startswith("_"))
+
+
+def _config_file(entry):
+    with open(os.path.join(manifest.ROOT, entry["file"])) as f:
+        return json.load(f)
 
 
 def test_manifest_breaks_none_of_the_drivers_rules():
     assert manifest.problems(BENCH) == []
 
 
-def test_exactly_one_cell_takes_four_chips_and_says_why():
+def test_the_four_chip_cells_are_within_the_cap_and_each_says_why():
     four = [w for w in BENCH["workloads"] if w["chips"] == 4]
-    assert [w["name"] for w in four] == ["gpt2xl-train-zero3-4chip"]
-    assert "only" in four[0]["why"] and "across chips" in four[0]["why"]
+    assert "gpt2xl-train-zero3-4chip" in [w["name"] for w in four]
+    assert len(four) <= max(1, len(BENCH["workloads"]) // 4)
+    for w in four:
+        assert "only" in w["why"] and "across chips" in w["why"], w["name"]
 
 
 def test_the_full_check_fits_the_drivers_day_at_24_cells():
@@ -46,24 +67,75 @@ def test_each_cell_finds_its_config_traffic_kind_and_family_by_name(cell):
     assert traffic["name"] == cell and traffic["config"] == config["name"]
     assert callable(manifest.kind_module(traffic).run)
     family = manifest.family_module(config)
-    assert family.sizes(config, False)["n_embd"] == config["n_embd"]
+    sizes = family.sizes(config, False)
+    assert sizes and all(config[k] == v for k, v in sizes.items())
+    shapes = family.traffic_shapes(config, False)
+    assert set(shapes) == set(families.TRAFFIC_SHAPES)
+    assert shapes["seq_scale"] == 1 and shapes["vocab_size"] > 1
+    assert 0 < family.traffic_shapes(config, True)["seq_scale"] <= 1
+    if "seq_len" in traffic:
+        assert traffic["seq_len"] <= shapes["max_positions"]
+        assert family.train_flops_per_token(config, traffic["seq_len"]) > 0
     e2e = [m["name"] for m in manifest.metrics_for(BENCH, entry, "end_to_end")]
     assert "setup_s" in e2e and len(e2e) >= 2
     assert manifest.metrics_for(BENCH, entry, "per_layer")
 
 
-def test_configurations_keep_the_published_widths():
-    want = {"gpt2-large-774m": (1280, 36, 20), "gpt2-xl-1558m": (1600, 48, 25)}
-    for c in BENCH["configs"]:
-        with open(os.path.join(manifest.ROOT, c["file"])) as f:
-            body = json.load(f)
-        assert (body["n_embd"], body["n_layer"], body["n_head"]) == \
-            want[c["name"]]
-        assert body["n_embd"] // body["n_head"] == 64
-        assert body["n_positions"] == 1024
-        assert body["published"]["vocab_size"] == 50257
-        assert not any(k.endswith(("_dim", "_rank")) or k in (
-            "n_embd", "n_head", "n_inner") for k in c["reduced"])
+def _is_width(key, family):
+    return key in family.WIDTH_KEYS or key.endswith(("_dim", "_rank"))
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_a_configuration_keeps_its_published_widths(name):
+    """Held to its own file and family: no width key (the family's
+    ``WIDTH_KEYS``, anything ending ``_dim`` / ``_rank``) is listed under
+    ``reduced``, and each equals ``published[key]`` where the file's
+    ``published`` block (the source's value of every changed key) has it."""
+    entry = next(c for c in BENCH["configs"] if c["name"] == name)
+    body = _config_file(entry)
+    family = manifest.family_module(body)
+    assert body["name"] == name and body["source"] == entry["source"]
+    widths = [k for k in body if _is_width(k, family)]
+    assert widths, f"{name} states none of {family.WIDTH_KEYS}"
+    assert not [k for k in entry["reduced"] if _is_width(k, family)]
+    published = body.get("published", {})
+    assert not [k for k in widths if k in published
+                and published[k] != body[k]]
+    assert set(entry["reduced"]) <= set(published), \
+        "a reduced key without its published value"
+
+
+@pytest.mark.parametrize("name,want", [
+    ("gpt2-large-774m", (1280, 36, 20)), ("gpt2-xl-1558m", (1600, 48, 25))])
+def test_the_gpt2_configurations_are_the_published_ones(name, want):
+    body = _config_file(next(c for c in BENCH["configs"]
+                             if c["name"] == name))
+    assert body["family"] == "gpt2"
+    assert (body["n_embd"], body["n_layer"], body["n_head"]) == want
+    assert body["n_embd"] // body["n_head"] == 64
+    assert body["n_positions"] == 1024
+    assert body["published"]["vocab_size"] == 50257
+
+
+@pytest.mark.parametrize("name", _listed("families", ".py"))
+def test_each_family_file_provides_what_the_harness_asks_of_a_family(name):
+    """``benchmark/families/__init__.py`` is the list; serving is all or
+    nothing (a family without a serving block has none of it)."""
+    family = importlib.import_module(f"benchmark.families.{name}")
+    missing = [m for m in families.TRAINING
+               if not callable(getattr(family, m, None))]
+    assert not missing, f"families/{name}.py lacks {missing}"
+    for tags in families.TAGS:
+        value = getattr(family, tags)
+        assert isinstance(value, tuple) and all(
+            isinstance(t, str) and t for t in value), tags
+    assert family.WIDTH_KEYS
+    serving = [m for m in families.SERVING
+               if callable(getattr(family, m, None))]
+    assert serving in ([], list(families.SERVING))
+    assert [c for c in BENCH["configs"]
+            if _config_file(c)["family"] == name], \
+        f"no configuration is of family {name}"
 
 
 @pytest.mark.parametrize("break_it,says", [
@@ -87,9 +159,7 @@ def test_the_checker_catches_what_the_driver_would_refuse(break_it, says):
         manifest.problems(bench)
 
 
-READERS = sorted(f[:-3] for f in os.listdir(
-    os.path.join(manifest.HERE, "layer_metrics"))
-    if f.endswith(".py") and not f.startswith("_"))
+READERS = _listed("layer_metrics", ".py")
 
 
 @pytest.mark.parametrize("metric", READERS)
@@ -105,9 +175,8 @@ def test_every_reader_file_is_named_after_its_metric_and_is_well_formed(
     assert callable(mod.read)
 
 
-CANDIDATES = sorted(
-    f[:-5] for f in os.listdir(os.path.join(manifest.HERE, "workloads"))
-    if f[:-5] not in {w["name"] for w in BENCH["workloads"]})
+CANDIDATES = [f for f in _listed("workloads", ".json")
+              if f not in {w["name"] for w in BENCH["workloads"]}]
 
 
 def test_the_candidates_are_the_ones_perf_md_names():

@@ -1,15 +1,18 @@
 """``scope_reduce``: the join of device events to the compiled text's scopes,
-on a hand-written text and hand-made events. No chip, no compile."""
+on a hand-written text and hand-made events. No chip, no compile. The tags
+are a family's: GPT-2's here, and a stand-in's with a kernel of its own."""
 
 import json
 import os
+import types
 
 import pytest
 
 from benchmark import harness, manifest, scope_reduce as sr
 from benchmark import trace_reduce as tr
 from benchmark.families import gpt2 as family
-from benchmark.layer_metrics import (flash_bwd_roofline, flash_fwd_roofline,
+from benchmark.layer_metrics import (flash_attn_roofline, flash_attn_share,
+                                     flash_bwd_roofline, flash_fwd_roofline,
                                      train_bwd_ms, train_fwd_ms,
                                      train_optimizer_ms, train_recompute_ms,
                                      train_unscoped_share)
@@ -86,7 +89,7 @@ def test_scope_table_reads_every_instruction_line():
     ("", "unscoped", "-"),
 ])
 def test_phase_and_tag_of_a_path(op_name, phase, tag):
-    assert (sr.phase_of(op_name), sr.tag_of(op_name)) == (phase, tag)
+    assert (sr.phase_of(op_name), sr.tag_of(op_name, family)) == (phase, tag)
 
 
 def ev(name, a, b):
@@ -116,7 +119,7 @@ EVENTS = [
 
 
 def test_events_join_the_table_and_nested_time_counts_once():
-    chip = sr.chip_attribution(EVENTS, sr.scope_table(TEXT), steps=1)
+    chip = sr.chip_attribution(EVENTS, sr.scope_table(TEXT), 1, family)
     ms = {tuple(r[:3]): r[3] * 1e6 for r in chip["rows"]}      # back to ns
     assert ms == {
         ("forward", "attn", "op"): 100.0,
@@ -141,7 +144,7 @@ def test_events_join_the_table_and_nested_time_counts_once():
                                  "flash_bwd": pytest.approx(300e-6)}
 
 
-def _record(events_by_plane, modules=2):
+def _record(events_by_plane, modules=2, family=family):
     """A traced training Record of GPT-2 large's cell over hand-made planes:
     each plane runs ``modules`` steps that span its events."""
     with open(os.path.join(manifest.HERE, "configs",
@@ -204,6 +207,65 @@ def test_kernel_rooflines_split_the_attention_flops_one_to_two():
                          tr.is_pallas) / 1e9
     kernels = record.extra[sr.SLOT]["chips"]["/device:TPU:0"]["kernel_ms"]
     assert sum(kernels.values()) / 1e3 == pytest.approx(secs)
+    # forward + backward together: every Pallas event here is a flash kernel,
+    # so selecting by scope reads what selecting every Pallas call read
+    # (the ledger's 16.86 % / 15.93 % of this cell are these 73.9 ms)
+    assert flash_attn_share.read(record) == pytest.approx(100.0)
+    assert flash_attn_roofline.read(record) == pytest.approx(
+        100 * 36 * 64_424_509_440 / 197e12 / secs)
+    assert flash_attn_roofline.read(record) == pytest.approx(15.93, abs=0.01)
+
+
+# a later family: GPT-2's modules under LLaMA-style names, the flash kernels
+# and one Pallas kernel of its own under the scope ``moe_gmm``
+OTHER = types.SimpleNamespace(
+    KERNEL_TAGS=("flash_fwd", "flash_bwd", "moe_gmm"),
+    MODULE_TAGS=("ds_loss_head", "self_attn", "experts", "input_norm"),
+    train_attention_flops_per_step=family.train_attention_flops_per_step)
+P_GMM = (BWD.replace("GPT2LMHeadModel", "Other") + BODY
+         + "/layers/checkpoint/blk/experts/moe_gmm_bwd/pallas_call")
+
+
+def test_a_familys_own_kernel_tag_gets_a_row_and_a_roofline_of_its_count():
+    """What a model_config PR needs: its family lists the scope, the shared
+    reducer gives it ``kernel_ms``, and its reader passes its own count of
+    bytes (or flops) with the peak that bounds the kernel."""
+    assert sr.tag_of(P_GMM, OTHER) == "moe_gmm"
+    assert sr.tag_of(P_GMM, family) == "-"        # GPT-2 does not list it
+    assert sr.tag_of(FWD.replace("GPT2LMHeadModel", "Other") + BODY
+                     + "/layers/blk/input_norm/mul", OTHER) == "input_norm"
+    gmm = ("%moe_gmm_bwd.2 = bf16[64,2048,1024]{2,1,0} custom-call(bf16[8,64] "
+           "%x)" + PALLAS)
+    text = TEXT.replace("%flash_bwd.1 = (f32[4,8,64]", (
+        '%moe_gmm_bwd.2 = bf16[64,2048,1024]{2,1,0} custom-call(%arg), '
+        f'metadata={{op_name="{P_GMM}"}}\n'
+        "  %flash_bwd.1 = (f32[4,8,64]"))
+    events = {"/device:TPU:0": [ev(gmm, 0, 10e6),
+                                ev(EVENTS[3].name, 10e6, 53.3e6)]}
+    record = _record(events, modules=1, family=OTHER)
+    record.compiled_text = text
+    chip = sr.busiest_chip(record)
+    assert chip["kernel_ms"] == {"flash_fwd": 0.0,
+                                 "flash_bwd": pytest.approx(43.3),
+                                 "moe_gmm": pytest.approx(10.0)}
+    assert ["backward", "experts", "pallas",
+            pytest.approx(10.0)] not in chip["rows"]      # the kernel tag wins
+    assert ["backward", "moe_gmm", "pallas", pytest.approx(10.0)] \
+        in chip["rows"]
+    # 4.095 GB moved in 10 ms is half of 819 GB/s
+    assert sr.kernel_roofline(record, "moe_gmm", 4.095e9, 819e9) \
+        == pytest.approx(50.0)
+    assert sr.kernel_roofline(record, "no_such_scope", 1.0, 1.0) is None
+    # the flash readers no longer take every Pallas call for a flash kernel:
+    # 43.3 of the 53.3 busy ms, where ``is_pallas`` would read 100 %
+    assert flash_attn_share.read(record) == pytest.approx(100 * 43.3 / 53.3)
+    assert flash_attn_roofline.read(record) == pytest.approx(
+        100 * 36 * 64_424_509_440 / 197e12 / 0.0433)
+    # under GPT-2's tags the same trace has no ``moe_gmm`` row at all
+    as_gpt2 = _record(events, modules=1)
+    as_gpt2.compiled_text = text
+    assert set(sr.busiest_chip(as_gpt2)["kernel_ms"]) == {"flash_fwd",
+                                                          "flash_bwd"}
 
 
 def test_a_program_without_the_scopes_or_a_run_without_a_plane_reads_none():
@@ -213,9 +275,12 @@ def test_a_program_without_the_scopes_or_a_run_without_a_plane_reads_none():
     record.compiled_text = parent
     assert flash_bwd_roofline.read(record) is None
     assert flash_fwd_roofline.read(record) is None
+    assert flash_attn_share.read(record) is None
+    assert flash_attn_roofline.read(record) is None
     assert train_bwd_ms.read(record) == pytest.approx(210e-6)
     rehearsal = _record({"/device:TPU:0": EVENTS})
     rehearsal.trace = tr.Trace({}, {})
-    for m in (train_fwd_ms, train_unscoped_share, flash_fwd_roofline):
+    for m in (train_fwd_ms, train_unscoped_share, flash_fwd_roofline,
+              flash_attn_share, flash_attn_roofline):
         assert m.read(rehearsal) is None
     assert sr.SLOT not in rehearsal.extra
