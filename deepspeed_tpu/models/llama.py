@@ -36,6 +36,7 @@ from deepspeed_tpu.ops.attention import dot_product_attention
 from deepspeed_tpu.models.gpt2 import (_embed_lookup, _remat_policy,
                                        chunked_lm_loss, gather_edge_block,
                                        lm_loss)
+from deepspeed_tpu.telemetry.spans import annotate
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,6 +59,17 @@ class LlamaConfig:
     sp_backend: str = "ring"         # mesh seq-axis attention backend
     use_flash: Optional[bool] = None
     loss_chunk: int = 0              # fused chunked head+loss (see gpt2)
+    # What OLMoE's published config has and LLaMA's lacks, under its key
+    # names; every default is LLaMA's behaviour.
+    num_experts: int = 0             # >0 → the FFN is a dropless MoE of
+    #                                  num_experts SwiGLU experts, each
+    #                                  intermediate_size wide (moe/dropless)
+    num_experts_per_tok: int = 0     # experts a token is routed to
+    norm_topk_prob: bool = False     # renormalise the top-k probabilities
+    qk_norm: bool = False            # RMSNorm over the whole q and k
+    #                                  projections, before heads and RoPE
+    router_aux_loss_coef: float = 0.01   # load balancing, E·Σ f_e·P_e
+    router_z_loss_coef: float = 0.001    # mean logsumexp(router logits)²
 
     @property
     def kv_heads(self):
@@ -71,7 +83,12 @@ class LlamaConfig:
         E, F, L, V = (self.hidden_size, self.intermediate_size,
                       self.n_layers, self.vocab_size)
         Dkv = self.kv_heads * self.head_dim
-        per_layer = E * E + 2 * E * Dkv + E * E + 3 * E * F + 2 * E
+        ffn = 3 * E * F
+        if self.num_experts:
+            ffn = self.num_experts * ffn + E * self.num_experts   # + router
+        per_layer = E * E + 2 * E * Dkv + E * E + ffn + 2 * E
+        if self.qk_norm:
+            per_layer += E + Dkv
         return 2 * V * E + L * per_layer + E
 
 
@@ -127,6 +144,15 @@ class LlamaAttention(nn.Module):
         # all three projections carry the 'qkv' tag so every GPT2Config
         # remat_policy string (which saves 'qkv' residuals) works
         # unchanged on this model
+        if cfg.qk_norm:
+            # OLMoE: one RMSNorm with a learned weight over the WHOLE q
+            # projection and one over the whole k, not per head
+            with annotate("qk_norm"):
+                norm = lambda name: RMSNorm(  # noqa: E731
+                    eps=cfg.rms_eps, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, name=name)
+                q = norm("q_norm")(q)
+                k = norm("k_norm")(k)
         q = checkpoint_name(q, "qkv")
         k = checkpoint_name(k, "qkv")
         v = checkpoint_name(v, "qkv")
@@ -240,10 +266,26 @@ class LlamaBlock(nn.Module):
         norm = lambda name: RMSNorm(  # noqa: E731
             eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             name=name)
-        x = x + LlamaAttention(cfg, self.max_out_tokens, name="attn")(
+        attn = LlamaAttention(cfg, self.max_out_tokens, name="attn")(
             norm("input_norm")(x), positions)
-        x = x + LlamaMLP(cfg, name="mlp")(norm("post_attn_norm")(x))
-        return x
+        x = x + attn
+        if cfg.num_experts:
+            from deepspeed_tpu.moe.dropless import DroplessMoE
+            ffn = DroplessMoE(
+                cfg.num_experts, cfg.num_experts_per_tok,
+                cfg.intermediate_size, norm_topk_prob=cfg.norm_topk_prob,
+                balance_coeff=cfg.router_aux_loss_coef,
+                z_coeff=cfg.router_z_loss_coef, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, name="mlp")
+        else:
+            ffn = LlamaMLP(cfg, name="mlp")
+        out = ffn(norm("post_attn_norm")(x))
+        if self.is_mutable_collection("intermediates"):
+            # a caller's look at the two branches (the benchmark's check
+            # against its reference); nothing in a training step
+            self.sow("intermediates", "attn_out", attn)
+            self.sow("intermediates", "ffn_out", out)
+        return x + out
 
 
 def _maybe_remat(cfg, parent, name):
@@ -281,6 +323,21 @@ class LlamaForCausalLM(nn.Module):
         with unrolled layers (see GPT2LMHeadModel)."""
         return "layers" if self.config.scan_layers else None
 
+    @property
+    def sown_collections(self):
+        """Collections a training forward sows into, for the engine to
+        make mutable: ``losses`` (terms it adds to the objective as they
+        are — the MoE router's two, already weighted) and ``stats``
+        (scalars it carries out of the step and folds into the gauges
+        ``stat_gauges`` names). None without experts."""
+        return ("losses", "stats") if self.config.num_experts else ()
+
+    @property
+    def stat_gauges(self):
+        """{variable sown into ``stats``: the gauge it is read under}."""
+        from deepspeed_tpu.moe.dropless import STAT_GAUGES
+        return STAT_GAUGES if self.config.num_experts else {}
+
     @nn.compact
     def __call__(self, input_ids, labels=None, deterministic=True,
                  keep_prob=1.0, position_offset=0):
@@ -289,12 +346,16 @@ class LlamaForCausalLM(nn.Module):
         embed = self.param("embed_tokens", nn.initializers.normal(0.02),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
-        x = _embed_lookup(embed, input_ids).astype(cfg.dtype)
+        with annotate("ds_embed"):
+            x = _embed_lookup(embed, input_ids).astype(cfg.dtype)
         positions = position_offset + jnp.arange(S)
 
         if cfg.scan_layers:
+            axes = {"params": 0, "cache": 0}
+            if cfg.num_experts:
+                axes.update(losses=0, stats=0, intermediates=0)
             scanned = nn.scan(_ScanBody,
-                              variable_axes={"params": 0, "cache": 0},
+                              variable_axes=axes,
                               split_rngs={"params": True},
                               in_axes=(nn.broadcast,),
                               length=cfg.n_layers,
@@ -448,6 +509,19 @@ def llama_tiny(**over):
 def llama_7b(**over):
     kw = dict(vocab_size=32000, hidden_size=4096, intermediate_size=11008,
               n_layers=32, n_heads=32, max_seq_len=2048)
+    kw.update(over)
+    return LlamaConfig(**kw)
+
+
+def olmoe_1b_7b(**over):
+    """OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct): 16 layers of MHA
+    at head_dim 128 with QK-norm and a dropless top-8 of 64 experts of
+    width 1024, untied head; 6.9B parameters, 1.3B active a token."""
+    kw = dict(vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+              n_layers=16, n_heads=16, max_seq_len=4096, rope_theta=10000.0,
+              rms_eps=1e-5, num_experts=64, num_experts_per_tok=8,
+              norm_topk_prob=False, qk_norm=True,
+              router_aux_loss_coef=0.01, router_z_loss_coef=0.001)
     kw.update(over)
     return LlamaConfig(**kw)
 

@@ -78,6 +78,16 @@ def _global_norm(tree):
     return jnp.sqrt(sum(jnp.sum(jnp.square(l.astype(jnp.float32))) for l in leaves))
 
 
+def _mean_by_name(collection):
+    """{variable name: mean over every leaf sown under it} of a flax
+    collection (a layer scan stacks a name's values, ``sow`` tuples them)."""
+    by_name = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(collection)[0]:
+        name = [k.key for k in path if hasattr(k, "key")][-1]
+        by_name.setdefault(name, []).append(jnp.mean(leaf))
+    return {n: sum(v) / len(v) for n, v in sorted(by_name.items())}
+
+
 def _tree_where(pred, a, b):
     return jax.tree_util.tree_map(
         lambda x, y: jnp.where(pred, x, y) if hasattr(x, "dtype") else x, a, b)
@@ -1295,13 +1305,26 @@ class DeepSpeedEngine:
         return variables["params"] if "params" in variables else variables
 
     def _resolve_loss_fn(self) -> Callable:
+        """The scalar loss: what the forward-only, explicit-comm and
+        eigenvalue paths evaluate (``_loss_and_stats_fn``'s first value)."""
+        fn = self._loss_and_stats_fn()
+
+        def loss(params, batch, rng, keep_prob):
+            return fn(params, batch, rng, keep_prob)[0]
+        return loss
+
+    def _loss_and_stats_fn(self) -> Callable:
+        """(params, batch, rng, keep_prob) -> (loss, {name: scalar}): the
+        objective and what the model sowed into its ``stats`` collection in
+        the same forward pass ({} for a model that sows none, and for a
+        user's loss function)."""
         if self._loss_fn_user is not None:
             fn = self._loss_fn_user
             n = len(inspect.signature(fn).parameters)
 
             def user_loss(params, batch, rng, keep_prob):
                 args = (params, batch, rng, keep_prob)[:n]
-                return fn(*args)
+                return fn(*args), {}
             return user_loss
 
         model = self.module
@@ -1323,18 +1346,31 @@ class DeepSpeedEngine:
         uses_moe = getattr(model_cfg, "moe_experts", 0) and \
             getattr(model_cfg, "moe_experts", 0) > 0
         moe_aux_coeff = float(getattr(model_cfg, "moe_aux_coeff", 0.01))
+        # a model that sows says so itself (``sown_collections``, a
+        # property of the model as ``layer_stacked_subtree`` is): what it
+        # puts in "losses" is added to the objective AS IT IS, each term
+        # weighted by the model's own coefficient; "stats" leaves the step
+        # beside the loss and is folded into gauges at a steps_per_print
+        # boundary (_telemetry_model_stats)
+        sown = tuple(getattr(model, "sown_collections", ()))
 
         def apply_model(params, inputs, kwargs):
-            """Runs the model; when it carries MoE blocks, collect the sown
-            load-balancing losses so the router actually trains balanced
-            (the aux term of Switch/GShard)."""
+            """(output, auxiliary loss, stats) of the model; when it carries
+            MoE blocks, collect the sown load-balancing losses so the router
+            actually trains balanced (the aux term of Switch/GShard)."""
             if uses_moe:
                 out, vs = model.apply({"params": params}, inputs,
                                       mutable=["losses"], **kwargs)
                 aux = sum(jnp.sum(l) for l in jax.tree_util.tree_leaves(
                     vs.get("losses", {})))
-                return out, moe_aux_coeff * aux
-            return model.apply({"params": params}, inputs, **kwargs), 0.0
+                return out, moe_aux_coeff * aux, {}
+            if sown:
+                out, vs = model.apply({"params": params}, inputs,
+                                      mutable=list(sown), **kwargs)
+                aux = sum(jnp.sum(l) for l in jax.tree_util.tree_leaves(
+                    vs.get("losses", {})))
+                return out, aux, _mean_by_name(vs.get("stats", {}))
+            return model.apply({"params": params}, inputs, **kwargs), 0.0, {}
 
         def default_loss(params, batch, rng, keep_prob):
             from deepspeed_tpu.models.gpt2 import lm_loss
@@ -1348,27 +1384,30 @@ class DeepSpeedEngine:
             if isinstance(batch, dict) and "input_ids" in batch:
                 labels = batch.get("labels", batch["input_ids"])
                 if fused_loss:
-                    loss, aux = apply_model(params, batch["input_ids"],
-                                            {**kwargs, "labels": labels})
-                    return loss + aux
-                logits, aux = apply_model(params, batch["input_ids"], kwargs)
-                return lm_loss(logits, labels) + aux
+                    loss, aux, stats = apply_model(
+                        params, batch["input_ids"],
+                        {**kwargs, "labels": labels})
+                    return loss + aux, stats
+                logits, aux, stats = apply_model(params, batch["input_ids"],
+                                                 kwargs)
+                return lm_loss(logits, labels) + aux, stats
             if isinstance(batch, (tuple, list)) and len(batch) == 2:
                 x, y = batch
-                out, aux = apply_model(params, x, kwargs)
+                out, aux, stats = apply_model(params, x, kwargs)
                 if jnp.issubdtype(jnp.asarray(y).dtype, jnp.integer):
                     logp = jax.nn.log_softmax(out.astype(jnp.float32), axis=-1)
                     ll = jnp.take_along_axis(logp, y[..., None], axis=-1)
-                    return -ll.mean() + aux
+                    return -ll.mean() + aux, stats
                 return jnp.mean(jnp.square(out.astype(jnp.float32) -
-                                           y.astype(jnp.float32))) + aux
+                                           y.astype(jnp.float32))) + aux, stats
             # bare array → LM on itself
             if fused_loss:
-                loss, aux = apply_model(params, batch,
-                                        {**kwargs, "labels": batch})
-                return loss + aux
-            logits, aux = apply_model(params, batch, kwargs)
-            return lm_loss(logits, batch) + aux
+                loss, aux, stats = apply_model(params, batch,
+                                               {**kwargs, "labels": batch})
+                return loss + aux, stats
+            logits, aux, stats = apply_model(params, batch, kwargs)
+            return lm_loss(logits, batch) + aux, stats
+
         return default_loss
 
     # ------------------------------------------------------------------
@@ -1499,6 +1538,7 @@ class DeepSpeedEngine:
         self.telemetry.gauge("zero/gather_edge_leaves").set(0)
         self.telemetry.gauge("zero/gather_edge_bytes_per_layer").set(0)
         loss_fn = self._resolve_loss_fn()
+        loss_stats_fn = self._loss_and_stats_fn()
         gas = self.gradient_accumulation_steps()
         batch_sh = mesh_lib.batch_sharding(self.mesh)
         repl = NamedSharding(self.mesh, PartitionSpec())
@@ -1512,9 +1552,9 @@ class DeepSpeedEngine:
                     lambda x: jax.lax.with_sharding_constraint(x, batch_sh),
                     batch)
                 with annotate("ds_fwd_bwd"):
-                    loss, grads = self._micro_loss_and_grads(
-                        state, batch, rng, loss_fn=loss_fn)
-                return grads, loss
+                    loss, grads, stats = self._micro_loss_and_grads(
+                        state, batch, rng, loss_fn=loss_stats_fn)
+                return grads, loss, stats
             # batch leading dim = gas * micro_global; scan over gas chunks
             def to_chunks(x):
                 assert x.shape[0] % gas == 0, (
@@ -1534,30 +1574,34 @@ class DeepSpeedEngine:
                     lambda x: jax.lax.with_sharding_constraint(x, batch_sh),
                     micro_batch)
                 with annotate("ds_fwd_bwd"):
-                    loss, grads = self._micro_loss_and_grads(
-                        state, micro_batch, r, loss_fn=loss_fn)
+                    loss, grads, stats = self._micro_loss_and_grads(
+                        state, micro_batch, r, loss_fn=loss_stats_fn)
                 acc_g, acc_l = acc
                 acc_g = jax.tree_util.tree_map(
                     lambda a, g: a + g.astype(acc_dtype) / gas, acc_g, grads)
-                return (acc_g, acc_l + loss / gas), None
+                return (acc_g, acc_l + loss / gas), stats
 
             zero_g = jax.tree_util.tree_map(
                 lambda p: jnp.zeros(p.shape, acc_dtype), state.params)
             zero_g = self.zero.constrain_grads(zero_g)
-            (grads, loss), _ = jax.lax.scan(micro, (zero_g, jnp.float32(0.0)),
-                                            (chunked, rngs))
-            return grads, loss
+            (grads, loss), stats = jax.lax.scan(
+                micro, (zero_g, jnp.float32(0.0)), (chunked, rngs))
+            return grads, loss, jax.tree_util.tree_map(
+                lambda s: jnp.mean(s, axis=0), stats)
 
         def train_batch_fn(state, batch, rng):
-            grads, loss = accumulate_grads(state, batch, rng)
+            grads, loss, stats = accumulate_grads(state, batch, rng)
             with annotate("ds_optimizer"):
-                return self._apply_grads(state, grads, loss)
+                new_state, metrics = self._apply_grads(state, grads, loss)
+            if stats:
+                metrics["model_stats"] = stats
+            return new_state, metrics
 
         def grads_batch_fn(state, batch, rng):
             # offload path: grads stay on device; host applies the step.
             # finiteness + norm are computed here so the host only pulls two
             # scalars instead of re-scanning every leaf
-            grads, loss = accumulate_grads(state, batch, rng)
+            grads, loss, _ = accumulate_grads(state, batch, rng)
             finite = prec.grads_finite(grads) if self.precision.fp16 \
                 else jnp.asarray(True)
             return grads, loss, finite, _global_norm(grads)
@@ -1567,8 +1611,8 @@ class DeepSpeedEngine:
         def micro_grads_fn(state, batch, rng):
             batch = jax.tree_util.tree_map(
                 lambda x: jax.lax.with_sharding_constraint(x, batch_sh), batch)
-            loss, grads = self._micro_loss_and_grads(state, batch, rng,
-                                                     loss_fn=loss_fn)
+            loss, grads, _ = self._micro_loss_and_grads(
+                state, batch, rng, loss_fn=loss_stats_fn)
             return loss, grads
 
         def apply_grads_fn(state, grads, loss):
@@ -2742,8 +2786,11 @@ class DeepSpeedEngine:
         return self._jit_explicit_comm(train_fn)
 
     def _micro_loss_and_grads(self, state, micro_batch, rng, loss_fn=None):
+        """(loss, gradients, stats) of one micro batch. ``loss_fn`` returns
+        (loss, stats) as ``_loss_and_stats_fn``'s does: stats is what the
+        model sowed into "stats", {} for a model that sows none."""
         if loss_fn is None:
-            loss_fn = self._resolve_loss_fn()
+            loss_fn = self._loss_and_stats_fn()
         keep_prob = self._keep_prob_fn()(state.global_step)
         scale = state.scaler["loss_scale"]
 
@@ -2759,8 +2806,8 @@ class DeepSpeedEngine:
                 p = jax.tree_util.tree_map(
                     lambda x: x.astype(jnp.bfloat16)
                     if x.dtype == jnp.float32 else x, p)
-            loss = loss_fn(p, micro_batch, rng, keep_prob)
-            return (loss * scale).astype(jnp.float32), loss
+            loss, stats = loss_fn(p, micro_batch, rng, keep_prob)
+            return (loss * scale).astype(jnp.float32), (loss, stats)
 
         params = state.params
         if self._param_offload_host:
@@ -2769,9 +2816,9 @@ class DeepSpeedEngine:
             # partitioned_param_swapper, done by XLA's h2d DMA)
             params = jax.device_put(
                 params, self.zero.device_param_shardings(params))
-        grads, loss = jax.grad(scaled_loss, has_aux=True)(params)
+        grads, (loss, stats) = jax.grad(scaled_loss, has_aux=True)(params)
         grads = self.zero.constrain_grads(grads)
-        return loss, grads
+        return loss, grads, stats
 
     def _micro_loss(self, state, micro_batch, rng, loss_fn=None):
         """Forward-only loss (no grad) — the wall_clock_breakdown forward
@@ -2895,6 +2942,8 @@ class DeepSpeedEngine:
             self._guard_exit()
         self._tel_window_dispatch_s += time.perf_counter() - _t_disp
         self._fence_ref = metrics["loss"]
+        # device scalars, read only at a telemetry fold
+        self._model_stats = metrics.pop("model_stats", None)
         self.tput_timer.stop()
 
         gas = self.gradient_accumulation_steps()
@@ -3742,11 +3791,25 @@ class DeepSpeedEngine:
         self._tel_window_step0 = self.global_steps
         self._tel_window_tokens = 0
         self._telemetry_memory_gauges()
+        self._telemetry_model_stats()
         # open the next window AFTER the fold's own work (the one-time
         # MFU pricing retrace can take seconds — charging it to the
         # next window would corrupt its step-time observation)
         self._tel_window_dispatch_s = 0.0
         self._tel_window_t0 = time.perf_counter()
+
+    def _telemetry_model_stats(self):
+        """The last step's model statistics (what the model sowed into
+        "stats": a MoE router's balance and auxiliary losses) as gauges,
+        each under the name the model's ``stat_gauges`` gives its variable.
+        The caller has fenced."""
+        stats = getattr(self, "_model_stats", None)
+        if not stats:
+            return
+        gauges = self.module.stat_gauges
+        host = jax.device_get(stats)  # sync-ok: telemetry fold, caller fenced
+        for name, value in host.items():
+            self.telemetry.gauge(gauges[name]).set(float(value))
 
     def _telemetry_mfu(self, batch, step_s, price=False):
         """MFU as a first-class logged metric: flops/step from the

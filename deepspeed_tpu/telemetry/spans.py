@@ -105,8 +105,21 @@ def annotate(tag):
       einsum) and ``ds_embed`` (the ``wte``/``wpe`` lookup), both
       models/gpt2.py: rows of the benchmark's detail table.
 
+    - ``moe_gmm``, ``moe_gmm_dlhs``, ``moe_gmm_drhs``
+      (ops/pallas/grouped_matmul.py, round each ``pallas_call``: forward,
+      the rows' gradient, the expert weights' gradient):
+      ``moe_gmm_roofline`` and ``moe_gmm_share`` (one tag, by prefix);
+    - ``moe_router``, ``moe_dispatch``, ``moe_combine`` (moe/dropless.py:
+      logits, softmax, top-k and the two losses; the sort and the row
+      gather; weighting and the rows' way back): ``moe_dispatch_ms``;
+    - ``moe_act`` (moe/dropless.py) and ``qk_norm`` (models/llama.py, the
+      RMSNorms over the whole q and k projections): rows of the detail
+      table.
+
     The flax module names ``attn``, ``mlp``, ``ln_1``, ``ln_2``, ``ln_f``
-    (models/gpt2.py) are the detail table's remaining tags."""
+    (models/gpt2.py) and ``attn``, ``mlp``, ``input_norm``,
+    ``post_attn_norm``, ``norm`` (models/llama.py) are the detail table's
+    remaining tags."""
     import jax
     return jax.named_scope(tag)
 

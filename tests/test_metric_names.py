@@ -205,6 +205,19 @@ def test_zero_gather_edge_metric_names_documented():
         assert name in _package_source(), name
 
 
+def test_moe_metric_names_documented():
+    """The dropless expert layer's four gauges (ISSUE 27) stay documented
+    AND sown: the engine names a gauge after the model's ``stats`` variable
+    (``moe_aux_loss`` -> ``moe/aux_loss``)."""
+    documented = documented_metric_names()
+    sown = (PKG / "moe" / "dropless.py").read_text()
+    for name in ("moe/rows_max_over_mean", "moe/aux_loss", "moe/z_loss",
+                 "moe/dropped_rows"):
+        assert name in documented, (
+            f"{name} missing from the docs/observability.md train table")
+        assert '"' + name.replace("/", "_") + '"' in sown, name
+
+
 # ------------------------------------------------------- prometheus page
 
 # the exposition-format line grammar a real scraper applies

@@ -1,0 +1,394 @@
+"""OLMoE on the normal path (``dstpu.initialize`` -> the engine's step)
+against its plain reference, and the pieces ISSUE 27 added for it: the
+dropless expert layer, the grouped matmul, QK-norm, the engine's ``losses``
+and ``stats`` collections. LLaMA itself must not have moved.
+
+Sizes are the benchmark configuration's rehearsal sizes (hidden 64, 2 layers,
+4 heads, 8 experts top-2 of width 32, 128 positions, vocabulary 512), the
+model in float32 so that system and reference agree to float32 rounding.
+"""
+
+import contextlib
+import copy
+import dataclasses
+import hashlib
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu as dstpu
+from benchmark import manifest
+from benchmark.families import olmoe as family
+from benchmark.reference import olmoe as ref
+from deepspeed_tpu.models import llama
+from deepspeed_tpu.moe import dropless
+from deepspeed_tpu.ops.pallas import grouped_matmul as grouped_matmul_module
+from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+from deepspeed_tpu.parallel.mesh import MeshConfig, make_mesh
+
+with open(os.path.join(manifest.HERE, "configs",
+                       "olmoe-1b-7b-0125-depth1.json")) as f:
+    CONFIG = json.load(f)
+BATCH, SEQ = 8, 128
+
+
+def _ids(seed=0, rows=BATCH):
+    return np.random.default_rng(seed).integers(
+        0, 512, (rows, SEQ), dtype=np.int32)
+
+
+def _model_config(**over):
+    cfg = family.model_config(CONFIG, rehearse=True)
+    return dataclasses.replace(cfg, dtype=jnp.float32, **over)
+
+
+def _engine(cfg, stage, gas=1):
+    ds = {"train_batch_size": BATCH * gas, "gradient_accumulation_steps": gas,
+          "zero_optimization": {"stage": stage,
+                                "stage3_param_persistence_threshold": 0},
+          "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+          "steps_per_print": 10 ** 9, "seed": 3}
+    engine, _, _, _ = dstpu.initialize(
+        config=ds, model=llama.LlamaForCausalLM(cfg),
+        mesh=make_mesh(MeshConfig(data=8)))
+    return engine
+
+
+def _reference(params, ids):
+    """(loss, detail, gradients in the program's tree, their norm)."""
+    loss, detail, gnorm, grads = family.reference_run(
+        CONFIG, params, ids, jax.devices()[0], True)
+    return float(loss), detail, grads, float(gnorm)
+
+
+@pytest.mark.parametrize("stage", [0, 3])
+@pytest.mark.parametrize("scan", [True, False], ids=["scan", "unrolled"])
+def test_the_engines_step_equals_the_reference(scan, stage):
+    """Loss, EVERY gradient leaf and the two auxiliary terms, through
+    ``dstpu.initialize`` on the CPU's 8 devices."""
+    cfg = _model_config(scan_layers=scan)
+    engine = _engine(cfg, stage)
+    batch = {"input_ids": _ids()}
+    loss = float(engine.forward(batch))
+    grads = jax.device_get(engine._pending_micro[1])
+    params = jax.device_get(engine.state.params)
+    want_loss, detail, want, _ = _reference(params, batch["input_ids"])
+    assert abs(loss - want_loss) < 2e-5, (loss, want_loss)
+    want = jax.device_get(want)
+    flat = dict(jax.tree_util.tree_flatten_with_path(grads)[0])
+    flat_want = dict(jax.tree_util.tree_flatten_with_path(want)[0])
+    assert set(flat) == set(flat_want)
+    for path, g in flat.items():
+        w = flat_want[path]
+        assert np.allclose(g, w, rtol=2e-3, atol=2e-6 + 1e-4 * np.abs(w).max()), \
+            (jax.tree_util.keystr(path), np.abs(g - w).max(), np.abs(w).max())
+    # the step's own loss is that sum, and its gauges the two terms
+    assert abs(float(engine.train_batch(batch)) - want_loss) < 2e-5
+    gauges = engine.telemetry_flush()["gauges"]
+    layers = cfg.n_layers
+    assert gauges["moe/aux_loss"] * layers == pytest.approx(
+        float(detail["balance"]), rel=1e-4)
+    assert gauges["moe/z_loss"] * layers == pytest.approx(
+        float(detail["z"]), rel=1e-4)
+    assert gauges["moe/dropped_rows"] == 0
+    assert 1.0 <= gauges["moe/rows_max_over_mean"] <= cfg.num_experts
+    ce = float(detail["ce"])
+    assert want_loss == pytest.approx(
+        ce + 0.01 * float(detail["balance"]) + 0.001 * float(detail["z"]),
+        abs=1e-6)
+
+
+def test_gradient_accumulation_carries_the_models_statistics_too():
+    cfg = _model_config()
+    engine = _engine(cfg, 0, gas=2)
+    loss = float(engine.train_batch({"input_ids": _ids(rows=2 * BATCH)}))
+    assert np.isfinite(loss)
+    gauges = engine.telemetry_flush()["gauges"]
+    assert gauges["moe/dropped_rows"] == 0 and gauges["moe/z_loss"] > 0
+
+
+def test_a_forward_only_loss_leaves_no_tracer_behind():
+    """The statistics leave the loss function as a value: a forward-only
+    caller that drops them leaks nothing."""
+    engine = _engine(_model_config(), 0)
+    batch = {"input_ids": jnp.asarray(_ids())}
+    engine.train_batch(batch)
+    loss_fn = engine._resolve_loss_fn()
+    with jax.checking_leaks():
+        loss = jax.jit(lambda p, b: loss_fn(
+            p, b, jax.random.PRNGKey(0), jnp.float32(1.0)))(
+                engine.state.params, batch)
+    assert np.isfinite(float(loss))
+    _, stats = engine._loss_and_stats_fn()(
+        engine.state.params, batch, jax.random.PRNGKey(0), jnp.float32(1.0))
+    assert set(stats) == set(engine.module.stat_gauges)
+
+
+# --------------------------------------------- the check sees an omission
+
+def _judge(system_config, patch=None):
+    """``judge_train`` of the program's model built from ``system_config``
+    (its loss and gradients as ``system_step`` forms them: cross-entropy plus
+    the sown ``losses``, in the step's precision) against the reference of
+    the TRUE configuration, on the same weights."""
+    ids = _ids(1)
+    model = llama.LlamaForCausalLM(family.model_config(CONFIG, rehearse=True))
+    params = model.init(jax.random.PRNGKey(5), jnp.asarray(ids))["params"]
+    device = jax.devices()[0]
+    with patch or contextlib.nullcontext():
+        system = family.system_step(system_config, params, ids, device, True)
+    want_loss, want_gnorm, differences = family.compare(
+        CONFIG, params, ids, device, True, system)
+    return family.judge_train(
+        CONFIG, float(system[0]), differences["system_grad_norm"], want_loss,
+        want_gnorm, differences)
+
+
+class _drop_token_zero:
+    """One dropped token: token 0 reaches no expert (its routing weights are
+    thrown away), as a full expert buffer would do to it."""
+
+    def __enter__(self):
+        self.route = dropless.route
+
+        def route(logits, k, norm):
+            w, e, p = self.route(logits, k, norm)
+            return w.at[0].set(0.0), e, p
+        dropless.route = route
+        jax.clear_caches()
+
+    def __exit__(self, *exc):
+        dropless.route = self.route
+        jax.clear_caches()
+        return False
+
+
+def _fp8(x):
+    """Rounded to e4m3's grid (4 exponent bits, 3 of mantissa) under one
+    scale a tensor, as an fp8 training path rounds. ``reduce_precision`` and
+    not a cast there and back, which XLA may remove as excess precision."""
+    scale = jnp.max(jnp.abs(x)).astype(jnp.float32) / 224.0
+    return (jax.lax.reduce_precision(x.astype(jnp.float32) / scale, 4, 3)
+            * scale).astype(x.dtype)
+
+
+@contextlib.contextmanager
+def _backward_fault(kind):
+    """The grouped matmul's backward products wrong, its forward untouched:
+    ``zero_drhs`` (no expert weight learns), ``fp8_dout`` (the cotangent
+    rounded to fp8 before both products)."""
+    mb = grouped_matmul_module._mb
+    gmm, tgmm = mb.gmm, mb.tgmm
+
+    def gmm_(lhs, rhs, *args, **kw):
+        if kw.get("transpose_rhs") and kind == "fp8_dout":      # dlhs
+            lhs = _fp8(lhs)
+        return gmm(lhs, rhs, *args, **kw)
+
+    def tgmm_(lhs, dout, *args, **kw):                           # drhs
+        out = tgmm(lhs, _fp8(dout) if kind == "fp8_dout" else dout,
+                   *args, **kw)
+        return jnp.zeros_like(out) if kind == "zero_drhs" else out
+
+    mb.gmm, mb.tgmm = gmm_, tgmm_
+    try:
+        yield
+    finally:
+        mb.gmm, mb.tgmm = gmm, tgmm
+
+
+def _with(**over):
+    out = copy.deepcopy(CONFIG)
+    out.update(over)
+    return out
+
+
+def test_the_program_as_it_is_passes_the_check():
+    checks, info = _judge(CONFIG)
+    assert all(checks.values()), (checks, info)
+
+
+@pytest.mark.parametrize("omission,system,patch,fails", [
+    ("no z-loss", _with(router_z_loss_coef=0.0), None,
+     "first_loss_matches_reference"),
+    ("renormalised top-k", _with(norm_topk_prob=True), None,
+     "expert_branch_matches_reference"),
+    ("one dropped token", CONFIG, _drop_token_zero,
+     "expert_branch_matches_reference"),
+    ("no QK-norm", _with(qk_norm=False), None,
+     "attention_branch_matches_reference"),
+    # the backward pass alone: loss, norm, routing and both branches pass
+    ("no expert weight gradient", CONFIG,
+     lambda: _backward_fault("zero_drhs"),
+     "gradients_match_reference_leaf_by_leaf"),
+    ("the grouped matmul's cotangent in fp8", CONFIG,
+     lambda: _backward_fault("fp8_dout"),
+     "gradients_match_reference_leaf_by_leaf"),
+], ids=["no-z-loss", "renormalised-top-k", "dropped-token", "no-qk-norm",
+        "zero-drhs", "fp8-dout"])
+def test_an_omission_fails_the_familys_check(omission, system, patch, fails):
+    checks, info = _judge(system, patch() if patch else None)
+    assert not checks[fails], (omission, info)
+    if "gradient" in fails:
+        assert all(v for k, v in checks.items() if k != fails), checks
+        assert {"gate", "up", "down"} <= set(
+            info["differences"]["gradient_leaves_over"]), info
+
+
+# ----------------------------------------------------- the dropless layer
+
+def _layer():
+    return dropless.DroplessMoE(num_experts=8, k=2, d_ff=32,
+                                dtype=jnp.float32)
+
+
+def _dense_moe(p, x, k):
+    """Every expert on every token, masked by the top-k weights."""
+    h = x.reshape(-1, x.shape[-1])
+    probs = jax.nn.softmax(h @ p["router"], axis=-1)
+    w, e = jax.lax.top_k(probs, k)
+    y = jnp.zeros_like(h)
+    for i in range(p["router"].shape[1]):
+        out = (jax.nn.silu(h @ p["gate_proj"][i]) * (h @ p["up_proj"][i])) \
+            @ p["down_proj"][i]
+        y = y + jnp.sum(jnp.where(e == i, w, 0.0), axis=1)[:, None] * out
+    return y.reshape(x.shape)
+
+
+def test_every_row_arrives_and_no_routing_pattern_recompiles():
+    """A router forced to send every token to the same two experts: all
+    T x k rows arrive (``moe_dropped_rows`` 0, the fullest expert holds
+    E / k times the mean), the output is the dense computation's, and a
+    second, scattered routing pattern runs the SAME compiled program."""
+    layer = _layer()
+    x = 1.0 + 0.1 * jax.random.normal(jax.random.PRNGKey(0), (2, 64, 64))
+    p = layer.init(jax.random.PRNGKey(1), x)["params"]
+    forced = dict(p, router=jnp.full_like(p["router"], -1.0)
+                  .at[:, 3].set(1.0).at[:, 5].set(0.5))
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, secs, **_: compiles.append(event) if event ==
+        "/jax/core/compile/backend_compile_duration" else None)
+
+    @jax.jit
+    def run(params, x):
+        return layer.apply({"params": params}, x, mutable=["stats"])
+
+    y, vs = run(forced, x)
+    assert compiles, "the listener saw the first pattern's compile"
+    stats = {k: float(v[0]) for k, v in vs["stats"].items()}
+    assert stats["moe_dropped_rows"] == 0
+    assert stats["moe_rows_max_over_mean"] == pytest.approx(8 / 2)
+    assert np.allclose(y, _dense_moe(forced, x, 2), atol=1e-5)
+    x2 = jax.block_until_ready(
+        jax.random.normal(jax.random.PRNGKey(2), x.shape))
+    before = len(compiles)
+    y2, vs2 = jax.block_until_ready(run(p, x2))
+    assert len(compiles) == before and run._cache_size() == 1, \
+        "a routing pattern recompiled the layer"
+    assert float(vs2["stats"]["moe_dropped_rows"][0]) == 0
+    assert float(vs2["stats"]["moe_rows_max_over_mean"][0]) < 8 / 2
+    assert np.allclose(y2, _dense_moe(p, x2, 2), atol=1e-5)
+
+
+def test_the_layers_gradients_are_the_dense_computations():
+    layer = _layer()
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 32, 64))
+    p = layer.init(jax.random.PRNGKey(1), x)["params"]
+    got = jax.grad(lambda p, x: jnp.sum(jnp.sin(
+        layer.apply({"params": p}, x))), argnums=(0, 1))(p, x)
+    want = jax.grad(lambda p, x: jnp.sum(jnp.sin(_dense_moe(p, x, 2))),
+                    argnums=(0, 1))(p, x)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert np.allclose(g, w, atol=1e-5), np.abs(g - w).max()
+
+
+@pytest.mark.parametrize("sizes,rows", [
+    ([5, 0, 20, 7, 0, 0, 1, 7], 40),     # uneven, empty groups
+    ([40, 0, 0, 0, 0, 0, 0, 0], 40),     # everything on one expert
+    ([5, 5, 5, 5, 5, 5, 5, 5], 40),      # even
+    ([3, 9, 0, 4, 0, 4, 11, 6], 37),     # rows not a whole sublane: padded
+], ids=["uneven-empty", "one-expert", "even", "padded"])
+def test_grouped_matmul_matches_a_per_expert_loop(sizes, rows):
+    """Forward and both gradients (the layer always routes exactly as many
+    rows as it hands over: the sizes sum to the rows)."""
+    assert sum(sizes) == rows
+    sizes = np.asarray(sizes, np.int32)
+    lhs = jax.random.normal(jax.random.PRNGKey(0), (rows, 64))
+    rhs = jax.random.normal(jax.random.PRNGKey(1), (8, 64, 32))
+    ends = np.cumsum(sizes)
+    group = np.searchsorted(ends, np.arange(rows), side="right")
+
+    def loop(a, b):
+        out = jnp.zeros((rows, 32))
+        for g in range(8):
+            out = out + jnp.where((group == g)[:, None], a @ b[g], 0.0)
+        return out
+
+    f = lambda a, b: jnp.sum(jnp.sin(grouped_matmul(  # noqa: E731
+        a, b, jnp.asarray(sizes))))
+    g = lambda a, b: jnp.sum(jnp.sin(loop(a, b)))  # noqa: E731
+    assert np.allclose(grouped_matmul(lhs, rhs, jnp.asarray(sizes)),
+                       loop(lhs, rhs), atol=1e-4)
+    for got, want in zip(jax.grad(f, (0, 1))(lhs, rhs),
+                         jax.grad(g, (0, 1))(lhs, rhs)):
+        assert np.allclose(got, want, atol=1e-4), np.abs(got - want).max()
+
+
+# ------------------------------------------------------ LLaMA did not move
+
+# md5 of ``jax.jit(grad of llama_tiny(loss_chunk=32)'s loss).lower(...)
+# .as_text()`` at [2, 64] on the parent commit 9980070 (jax 0.9.0), made by
+# running these very lines there
+LLAMA_TINY_PARENT_MD5 = "e79a50eedb69b233793f1c646ed3a028"
+
+
+def test_llama_tiny_lowers_to_the_parents_text():
+    m = llama.LlamaForCausalLM(llama.llama_tiny(loss_chunk=32))
+    ids = jnp.zeros((2, 64), jnp.int32)
+    p = jax.eval_shape(lambda: m.init(jax.random.PRNGKey(0), ids))
+    text = jax.jit(lambda p, i: jax.grad(
+        lambda pp: m.apply(pp, i, labels=i))(p)).lower(p, ids).as_text()
+    assert hashlib.md5((text + "\n").encode()).hexdigest() == \
+        LLAMA_TINY_PARENT_MD5
+
+
+@pytest.mark.parametrize("preset", [llama.llama_tiny, llama.llama_7b,
+                                    llama.llama3_8b])
+def test_the_llama_presets_keep_llamas_behaviour(preset):
+    cfg = preset()
+    assert (cfg.num_experts, cfg.num_experts_per_tok, cfg.qk_norm,
+            cfg.norm_topk_prob) == (0, 0, False, False)
+    model = llama.LlamaForCausalLM(dataclasses.replace(
+        cfg, n_layers=1, hidden_size=64, intermediate_size=32, n_heads=4,
+        n_kv_heads=min(cfg.kv_heads, 2), vocab_size=128))
+    assert model.sown_collections == ()
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+    assert set(shapes) == {"params"}
+    blk = shapes["params"]["layers"]["blk"]
+    assert set(blk["mlp"]) == {"gate_proj", "up_proj", "down_proj"}
+    assert set(blk["attn"]) == {"q_proj", "k_proj", "v_proj", "o_proj"}
+
+
+def test_the_olmoe_preset_is_the_published_configuration():
+    cfg = llama.olmoe_1b_7b()
+    pub = dict(CONFIG, **CONFIG["published"])
+    assert (cfg.hidden_size, cfg.intermediate_size, cfg.n_layers,
+            cfg.n_heads, cfg.kv_heads, cfg.head_dim) == (
+        pub["hidden_size"], pub["intermediate_size"],
+        pub["num_hidden_layers"], pub["num_attention_heads"],
+        pub["num_key_value_heads"], 128)
+    assert (cfg.num_experts, cfg.num_experts_per_tok, cfg.norm_topk_prob,
+            cfg.qk_norm, cfg.vocab_size, cfg.max_seq_len) == (
+        64, 8, False, True, 50304, 4096)
+    assert (cfg.router_aux_loss_coef, cfg.router_z_loss_coef) == (0.01, 0.001)
+    # 6.9B in all; the benchmark's depth-1 cut is ISSUE 27's 625.6M
+    assert 6.9e9 < cfg.num_params() < 6.93e9
+    one = dataclasses.replace(cfg, n_layers=1).num_params()
+    assert abs(one - 625.6e6) < 0.2e6

@@ -310,6 +310,37 @@ def test_train_step_scope_names_reach_the_compiled_text():
         assert re.search(r'op_name="[^"]*' + re.escape(scope), hlo), scope
 
 
+def test_moe_scope_names_and_gauges_reach_the_step():
+    """ISSUE 27's names: a LLaMA model with experts and QK-norm carries
+    ``moe_router`` / ``moe_dispatch`` / ``moe_gmm*`` / ``moe_combine`` /
+    ``qk_norm`` in its compiled step's ``op_name``s, and the step's
+    ``stats`` collection arrives as the ``moe/*`` gauges at a fold."""
+    import re
+    import jax.numpy as jnp
+    from deepspeed_tpu.models.llama import LlamaForCausalLM, olmoe_1b_7b
+    default_registry().reset()
+    cfg = olmoe_1b_7b(vocab_size=256, hidden_size=32, intermediate_size=16,
+                      n_layers=1, n_heads=2, max_seq_len=32, num_experts=4,
+                      num_experts_per_tok=2, loss_chunk=16,
+                      dtype=jnp.float32)
+    engine, _, _, _ = dstpu.initialize(config=base_config(),
+                                       model=LlamaForCausalLM(cfg))
+    batch = {"input_ids": np.random.RandomState(0).randint(
+        0, 256, (8, 32)).astype(np.int32)}
+    engine.train_batch(batch)
+    assert "moe/aux_loss" not in engine.telemetry_snapshot()["gauges"], \
+        "read back before a fold"
+    gauges = engine.telemetry_flush()["gauges"]
+    assert {"moe/aux_loss", "moe/z_loss", "moe/rows_max_over_mean",
+            "moe/dropped_rows"} <= set(gauges)
+    assert gauges["moe/dropped_rows"] == 0
+    hlo = engine.lower_train_step(batch).compile().as_text()
+    for scope in ("moe_router", "moe_dispatch", "moe_gmm", "moe_gmm_dlhs",
+                  "moe_gmm_drhs", "moe_combine", "qk_norm", "ds_embed",
+                  "ds_loss_head"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+
+
 def test_engine_without_gates_records_but_never_prices_or_exports():
     """No monitor/profiling config: counters still move (snapshot is
     always available) but no cost-analysis retrace, no exporter, no

@@ -115,6 +115,50 @@ def test_flash_attention_fwd_and_grad_compile_at_774m_shape():
     assert kernel_names(text) == {"_fwd_kernel", "_bwd_fused_kernel"}
 
 
+def test_flash_attention_chunked_fwd_and_grad_compile_at_olmoe_shape():
+    """4 x 16 heads x 4096 x head_dim 128, bf16, causal: a row of 1 MB takes
+    the CHUNKED kernels (``_UNCHUNKED_ROW_BYTES`` 256 KB), which no GPT-2
+    cell compiles. Nothing had to change for them (ISSUE 27)."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    qkv = (SDS((4, 16, 4096, 128), BF16),) * 3
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: flash_attention(*a, causal=True)
+                        .astype(F32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text, _ = compile_on_chip(grads, *qkv)
+    assert kernel_names(text) == {"_fwd_kernel_chunked",
+                                  "_bwd_dq_kernel_chunked",
+                                  "_bwd_dkv_kernel_chunked"}
+
+
+def test_grouped_matmul_fwd_and_grads_compile_at_olmoe_shape():
+    """131,072 routed rows x 2048 against 64 experts' [2048, 1024]: the
+    forward product and both gradients (dlhs against the transposed weights,
+    drhs the per-group outer products), three Pallas calls, each under a
+    ``moe_gmm*`` scope; the group sizes are an operand, not a shape."""
+    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    def bank(lhs, rhs, sizes):
+        # a scope round it, as the model's modules are: JAX writes the
+        # transform round the FIRST scope inside it (``jvp(mlp)/moe_gmm``)
+        with jax.named_scope("mlp"):
+            return grouped_matmul(lhs, rhs, sizes).astype(F32).sum()
+
+    def grads(lhs, rhs, sizes):
+        # the product is linear: only its value needs the forward kernel
+        return jax.value_and_grad(lambda a, b: bank(a, b, sizes),
+                                  argnums=(0, 1))(lhs, rhs)
+
+    text, compiled = compile_on_chip(
+        grads, SDS((131072, 2048), BF16), SDS((64, 2048, 1024), BF16),
+        SDS((64,), I32))
+    assert text.count("tpu_custom_call") == 3
+    hlo = compiled.as_text()
+    for scope in ("moe_gmm/", "moe_gmm_dlhs/", "moe_gmm_drhs/"):
+        assert re.search(r'op_name="[^"]*/' + scope, hlo), scope
+
+
 @pytest.mark.parametrize("seq", [64, 128, 256])
 def test_flash_attention_compiles_at_prefill_buckets(seq):
     """The serve phase's page-bucketed prompt lengths, batch 1."""
@@ -343,6 +387,38 @@ def test_train_step_compiles_for_four_chips_sharded(one_chip_step):
         assert not [ln for ln in hlo_text.instructions(lines, "all-reduce")
                     if "(%dynamic-update-slice" in ln
                     and hlo_text.result_elements(ln) < 1e5]
+
+
+def test_olmoe_step_compiles_for_one_chip_with_its_scopes_and_fits():
+    """The WHOLE step of the benchmark's ``olmoe-train-1chip-s4096`` cell
+    (OLMoE-1B-7B at depth 1, 4 x 4096 tokens, ZeRO-3, through the family's
+    ``lower_train_step``) is accepted for a 16 GB chip, its program peaks
+    under the 15.75 GiB a program may use, and every scope the benchmark
+    reads reaches an ``op_name`` of the compiled text."""
+    from benchmark import manifest
+    bench = manifest.load()
+    cell = manifest.cell_of(bench, "olmoe-train-1chip-s4096")
+    config = manifest.config_of(bench, cell)
+    lowered = manifest.family_module(config).lower_train_step(
+        config, manifest.traffic_of(cell), topo().devices[:1])
+    assert kernel_names(lowered.as_text()) == {
+        "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
+        "_bwd_dkv_kernel_chunked", "kernel"}
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    assert 6.0e9 < ma.argument_size_in_bytes < 6.5e9      # 625.6M x 10 B
+    assert 10e9 < ma.peak_memory_in_bytes < 15.75 * 2 ** 30
+    hlo = compiled.as_text()
+    assert not re.search(r"all-gather|all-reduce|reduce-scatter", hlo)
+    kernels = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert kernels and all(re.search(
+        r'op_name="[^"]*/(flash_(fwd|bwd)|moe_gmm)[a-z_]*/', ln)
+        for ln in kernels)
+    for scope in ("moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs", "moe_router",
+                  "moe_dispatch", "moe_combine", "qk_norm", "flash_fwd_chunk",
+                  "flash_bwd_dq", "flash_bwd_dkv", "ds_loss_head", "ds_embed",
+                  "ds_optimizer"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
 
 
 # ------------------------------------- optional kernels: known refusals
