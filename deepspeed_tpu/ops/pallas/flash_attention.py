@@ -4,13 +4,20 @@ ds_transformer_cuda.cpp): one Pallas kernel per pass that never materializes
 the [S, S] score matrix in HBM, with online softmax and a recompute-based
 backward (custom VJP), accumulating in fp32 on the MXU.
 
-Layout: q/k/v as [B, H, S, D] → kernels run on [B*H] × q-block grid. Two
-kernel families share the same per-block math (`_fwd_block_step` /
+Layout: q/k/v as [B, H, S, D] → kernels run on [B*H] × block grid. Two
+kernel families share the same per-tile math (`_fwd_block_step` /
 `_bwd_ds_block`):
 
-- **plain**: K/V (fwd, dq) or Q/dO (dkv) rows for one (batch, head) live
-  whole in VMEM — fastest, used while S·D·itemsize fits the measured
-  ~512 KB row budget (S=4k at D=64 in bf16).
+- **plain** ("whole-row"): K/V (fwd) or Q/dO (bwd) rows for one
+  (batch, head) live whole in VMEM — fastest, used while S·D·itemsize fits
+  `_UNCHUNKED_ROW_BYTES` (S=2048 at D=64, S=1024 at D=128 in bf16). The
+  grid block is up to 1024 rows; the causal structure is finer than that INSIDE
+  the block: its diagonal region goes in sub-blocks of a strip's rows, each
+  up to its own diagonal square (`tile_overcompute`: 1.25 x the needed
+  scores at S 1024, where whole diagonal blocks were 1.50 x), and the
+  tiles wholly off the diagonal run unmasked at full block width. The
+  softmax state and the backward's dq/dk/dv accumulators are fp32 VMEM
+  scratch; lse and delta travel lane-dense; dq leaves as the input dtype.
 - **chunked**: a third grid dimension streams sequence CHUNKS and
   accumulates into revisited fp32 output blocks (forward softmax m/l state
   rides in revisited outputs; normalization happens in-kernel on the last
@@ -18,11 +25,25 @@ kernel families share the same per-block math (`_fwd_block_step` /
   beyond that, sequence parallelism shards S first
   (deepspeed_tpu/parallel/ring_attention.py).
 
-The softmax scale is folded into the [block, D] q-loads (one small VPU
-multiply instead of one per [block_q, block_k] score tile), and causal
-loops split into unmasked below-diagonal blocks + masked diagonal blocks —
-at D < 128 the kernels are VPU-bound, so score-tile passes are the cost
-that matters.
+What a score tile costs beside its two (five, backward) MXU products is
+what these kernels are written around (per 512 x 512 tile at D=64 the
+compiler's own schedule had 1,425 bundles forward for 925 of MXU, the
+single vector-store slot the fullest at 1,019, nearly all of it spills of
+256-vreg tiles; PERF.md, PR 28):
+
+- the products take every row of a tile at once, the chain between them
+  (max, subtract, exp, sum, cast) goes `_CHAIN_ROWS` rows at a time, so a
+  chain's tile fits the 64-vreg file instead of passing through VMEM once
+  per op;
+- row statistics stay replicated across the 128 lanes (`_LANES`): a lane
+  broadcast is a trip through the XLU, which at one max and one sum per
+  8 rows per tile is already the second-fullest unit; the row SUM is kept
+  as per-lane partial sums on the VPU and crosses lanes once per row;
+- the scale goes onto the q rows where it is a power of two (head_dim 64,
+  256: bit-identical scores; `_scale_folds`) and stays on the fp32 scores
+  elsewhere (head_dim 128);
+- a causal mask is one compare against a relative-position tile built
+  once per grid step, on the diagonal squares alone.
 
 On non-TPU backends the kernels run in interpreter mode so unit tests check
 the same code path numerically against the jnp reference (the
@@ -30,25 +51,31 @@ test_cuda_forward.py methodology, SURVEY §4).
 """
 
 import functools
+import math
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.telemetry.registry import default_registry
 from deepspeed_tpu.telemetry.spans import annotate
+from deepspeed_tpu.utils.logging import logger
 
 NEG_INF = -1e30
 
-# measured scoped-VMEM ceiling for whole-row residency on v5e. The r4
-# FUSED backward additionally keeps a fp32 [S, D] dq row resident, which
-# moved the ceiling DOWN: bf16 S=4096, D=64 compiled in a small harness
-# but the same shapes inside a larger program (bench.py's S=4096 dense
-# case, BH=64) overflow scoped vmem by 284 KB — so the unchunked cutoff
-# is now S*D*itemsize <= 256 KB (S=2048 at D=64 bf16) and S=4096 routes
-# to the chunked kernels, whose per-chunk residency is bounded. The
-# chunked kernels use half of this per chunk for pipeline double
-# buffering (chunk 4096 at S=32k overflowed by 0.9 MB; 2048 fits).
+# measured scoped-VMEM ceiling for whole-row residency on v5e. The fused
+# backward keeps a fp32 [S, D] dq row resident (VMEM scratch since PR 28,
+# a fp32 output block before; the bf16 output block beside it is half
+# what that was), which moved the ceiling DOWN: bf16 S=4096, D=64 compiled
+# in a small harness but the same shapes inside a larger program
+# (bench.py's S=4096 dense case, BH=64) overflowed scoped vmem by 284 KB
+# — so the unchunked cutoff is S*D*itemsize <= 256 KB (S=2048 at D=64
+# bf16) and S=4096 routes to the chunked kernels, whose per-chunk
+# residency is bounded. The chunked kernels use half of this per chunk
+# for pipeline double buffering (chunk 4096 at S=32k overflowed by
+# 0.9 MB; 2048 fits).
 _UNCHUNKED_ROW_BYTES = 262144
 # per-chunk budget for the CHUNKED kernels (independent of the unchunked
 # cutoff above — they have no resident dq row): measured on v5e, chunk
@@ -63,53 +90,195 @@ def _interpret_default():
 
 # ------------------------------------------------------ shared block math
 
-def _causal_mask(s, q_pos0, k_pos0, block_q, block_k):
-    q_pos = q_pos0 + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = k_pos0 + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return jnp.where(q_pos >= k_pos, s, NEG_INF)
+# sides of a diagonal square of the whole-row kernels, widest first: the
+# sub-blocks a grid block's diagonal region goes in. Measured on a v5e at
+# [160, 1024, 64] (PERF.md, PR 28): 256 forward 0.433 ms against 0.489
+# at 128 (a sub-block's chain is latency-bound, so fewer and wider ones
+# win over the eighth of the scores 128 would save), backward 0.827 / 0.830
+_STRIPS = (256, 128)
 
 
-def _fwd_block_step(q, k, v, carry, q_pos0, k_pos0, block_q, block_k,
-                    masked, scale):
-    """One k-block of online-softmax forward. q/k/v stay in their native
+def _pick_strip(block):
+    """Sub-block height for a grid block: the widest strip that splits it
+    (1024 and 512 -> 256, 256 -> 128); a block none splits (128, the
+    tests' 64) runs as one strip."""
+    return next((s for s in _STRIPS if block % s == 0 and block > s), block)
+
+
+def _scale_folds(scale):
+    """True where ``scale`` is a power of two (head_dim 64, 256): q·scale
+    is then exact in any float dtype and (q·scale)·kᵀ equals (q·kᵀ)·scale
+    bit for bit, so the multiply moves from the score tile to the q rows.
+    Elsewhere (head_dim 128: 2^-3.5) the float32 scores keep it."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _rel_pos(rows, cols):
+    """row - col over a [rows, cols] tile, built once per grid step: a
+    causal mask is then one compare, ``rel >= k_pos0 - q_pos0``."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+            - jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1))
+
+
+def _block_mask(rel, masked, q_pos0, k_pos0):
+    """Causal mask of the block at (q_pos0, k_pos0) from ``_rel_pos``'s
+    tile; None for a block below the diagonal or a non-causal call."""
+    return rel >= k_pos0 - q_pos0 if masked and rel is not None else None
+
+
+def _scores(a, b, mask, scale):
+    """float32 a·bᵀ; ``scale`` None where q came pre-scaled. ``mask``
+    (bool) covers the bottom-right corner of the tile, whole in one
+    direction: [rows, w] masks the TRAILING w columns of q·kᵀ (what comes
+    before them lies wholly below the diagonal), [w, cols] the trailing w
+    rows of the transposed tile k·qᵀ."""
+    s = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    if scale is not None:
+        s = s * scale
+    if mask is not None:
+        r0, c0 = s.shape[0] - mask.shape[0], s.shape[1] - mask.shape[1]
+        corner = jnp.where(mask, s[r0:, c0:], NEG_INF)
+        if c0:
+            corner = jnp.concatenate([s[:, :c0], corner], axis=1)
+        s = jnp.concatenate([s[:r0], corner], axis=0) if r0 else corner
+    return s
+
+
+# rows of a score tile one softmax chain takes at a time: [128, 512] fp32
+# is the 64-vreg file (64, 128 and 256 measured alike on a v5e; a whole
+# 512-row tile is what spilled a store a bundle: PERF.md, PR 28)
+_CHAIN_ROWS = 128
+# lanes of a vreg: a row statistic (max, sum, lse, delta) lives replicated
+# across them, [rows, _LANES], in VMEM scratch and in the kernels' carries
+_LANES = 128
+
+
+def _cat(parts, axis):
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=axis)
+
+
+def _chains(rows):
+    """Row slices of a tile, one per softmax chain."""
+    step = _CHAIN_ROWS if rows % _CHAIN_ROWS == 0 else rows
+    return [slice(r, r + step) for r in range(0, rows, step)]
+
+
+def _lanes(x, width):
+    """[rows, _LANES] lane-replicated statistic -> [rows, width], for use
+    against a tile of that width: a lane slice or a repeat of whole vregs,
+    never a lane broadcast (which on the TPU is a trip through the XLU)."""
+    if width == x.shape[1]:
+        return x
+    if width < x.shape[1]:
+        return x[:, :width]
+    reps, rest = divmod(width, x.shape[1])
+    return _cat([x] * reps + ([x[:, :rest]] if rest else []), 1)
+
+
+def _lane_sums(p):
+    """[rows, w] -> [rows, _LANES] partial row sums, lane by lane: whole
+    vregs added on the VPU. The one cross-lane reduction a row sum needs
+    is left to whoever reads the total (``_row_total``), once a row and
+    not once a tile."""
+    rows, width = p.shape
+    full = width // _LANES * _LANES
+    parts = [p[:, c:c + _LANES] for c in range(0, full, _LANES)]
+    if full < width:
+        parts.append(jnp.concatenate(
+            [p[:, full:], jnp.zeros((rows, _LANES - (width - full)),
+                                    p.dtype)], axis=1))
+    return functools.reduce(jnp.add, parts)
+
+
+def _row_total(l_acc):
+    """The row sums ``_lane_sums`` has been keeping, [rows, 1]."""
+    return jnp.sum(l_acc, axis=1, keepdims=True)
+
+
+def _stat_piece(block_q, block_k):
+    """Lanes of one piece of the whole-row kernels' row statistics (lse,
+    delta), stored lane-dense as [BH, S / piece, 1, piece]: a [S, 1]
+    column costs a 128-lane tile per 8 values, in VMEM and in HBM alike."""
+    return math.gcd(math.gcd(block_q, block_k), _LANES)
+
+
+def _dense_row(x):
+    """[piece, _LANES] lane-replicated statistic -> its [1, piece] row:
+    the diagonal of the tile, summed down the sublanes."""
+    piece = x.shape[0]
+    eye = _rel_pos(piece, x.shape[1]) == 0
+    return jnp.sum(jnp.where(eye, x, 0.0), axis=0, keepdims=True)[:, :piece]
+
+
+def _stat_row(ref, row0, rows):
+    """[1, rows] of a [BH, S / piece, 1, piece] statistic's block, from
+    row ``row0`` (a multiple of the piece)."""
+    piece = ref.shape[3]
+    return _cat([ref[0, row0 // piece + j] for j in range(rows // piece)], 1)
+
+
+def _fwd_block_step(q, k, v, carry, mask, scale):
+    """One k-tile of online-softmax forward. q/k/v stay in their native
     (typically bf16) dtype so the MXU runs at full rate — fp32 dot inputs
-    run the systolic array at ~1/8 throughput, which made attention ~10%
-    of peak and THE forward bottleneck at S=1k (r4 measurement). All dots
-    accumulate fp32 (preferred_element_type); softmax state is fp32; the
-    scale is applied to the fp32 scores (exactly equivalent to pre-scaled
-    q up to bf16 rounding of q·scale, and independent of D).
-    carry = (o_acc [bq, D] f32, m_acc [bq] f32, l_acc [bq] f32)."""
-    o_acc, m_acc, l_acc = carry
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if masked:
-        s = _causal_mask(s, q_pos0, k_pos0, block_q, block_k)
-    m_new = jnp.maximum(m_acc, jnp.max(s, axis=1))
-    alpha = jnp.exp(m_acc - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_new = l_acc * alpha + jnp.sum(p, axis=1)
-    o_new = o_acc * alpha[:, None] + jax.lax.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    return o_new, m_new, l_new
+    run the systolic array at ~1/8 throughput. All dots accumulate fp32
+    (preferred_element_type); scores and softmax state are fp32.
+    carry = (o_acc [r, D], m_acc [r, _LANES], l_acc [r, _LANES]), fp32:
+    m replicated across the lanes, l as per-lane partial sums
+    (``_lane_sums``); None for a row's first tile.
+
+    The two products take every row at once (the k-tile is pushed into
+    the MXU once); between them the softmax chain — max, subtract, exp,
+    sum, cast — runs ``_CHAIN_ROWS`` rows at a time, one chain's tile
+    small enough for the register file, instead of op by op over the
+    whole tile through VMEM."""
+    s = _scores(q, k, mask, scale)
+    width = s.shape[1]
+    ps, ms, ls, alphas = [], [], [], []
+    for sl in _chains(s.shape[0]):
+        m_new = jnp.max(s[sl], axis=1, keepdims=True)
+        if carry is None:
+            m_new = jnp.broadcast_to(m_new, (m_new.shape[0], _LANES))
+        else:
+            m_new = jnp.maximum(carry[1][sl], m_new)
+            alphas.append(jnp.exp(carry[1][sl] - m_new))
+        p = jnp.exp(s[sl] - _lanes(m_new, width))
+        l_new = _lane_sums(p)
+        if carry is not None:
+            l_new = carry[2][sl] * alphas[-1] + l_new
+        ls.append(l_new)
+        ps.append(p.astype(v.dtype))
+        ms.append(m_new)
+    o_new = jax.lax.dot(_cat(ps, 0), v, preferred_element_type=jnp.float32)
+    if carry is not None:
+        o_new = carry[0] * _lanes(_cat(alphas, 0), o_new.shape[1]) + o_new
+    return o_new, _cat(ms, 0), _cat(ls, 0)
 
 
-def _bwd_ds_block(q, do, lse, delta, k, v, q_pos0, k_pos0, block_q, block_k,
-                  masked, scale):
-    """(p, ds) fp32 for one score tile of the backward; dot inputs stay in
-    the native dtype (see _fwd_block_step). ds is d(loss)/d(s) with
-    s = scale·q·kᵀ, so dq = scale·(ds·k) and dk = scale·(dsᵀ·q) — callers
-    apply the final ·scale once on the accumulated result."""
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if masked:
-        s = _causal_mask(s, q_pos0, k_pos0, block_q, block_k)
-    p = jnp.exp(s - lse[:, None])
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+def _bwd_ds_block(a, da, lse, delta, b, db, mask, scale):
+    """(p, ds) for one score tile of the backward, both already cast to
+    the dtype their products take them in; dot inputs stay in the native
+    dtype (see _fwd_block_step). Either orientation: (q, do, ·, ·, k, v)
+    gives the [q, k] tile with lse/delta as [r, 1] columns (the chunked
+    kernels); (k, v, ·, ·, q, do) gives its TRANSPOSE, [k, q], with
+    lse/delta as [1, n] rows that spread over sublanes for nothing (the
+    whole-row kernel: dv = pᵀ·do and dk = dsᵀ·q are then plain products).
+    ds is d(loss)/d(s) with s = scale·q·kᵀ, so dq = scale·(ds·k) and
+    dk = scale·(dsᵀ·q) — callers apply the final ·scale once on the
+    accumulated result (dk's rides a pre-scaled q where the scale folds).
+    As in the forward, the two products take every row at once and the
+    chain between them (exp, subtract, multiply, cast: fp32) goes
+    ``_CHAIN_ROWS`` rows at a time."""
+    s = _scores(a, b, mask, scale)
+    dp = jax.lax.dot_general(da, db, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None])
-    return p, ds
+    ps, dss = [], []
+    for sl in _chains(s.shape[0]):
+        stat = (lambda x: x if x.shape[0] == 1 else x[sl])
+        p = jnp.exp(s[sl] - stat(lse))
+        dss.append((p * (dp[sl] - stat(delta))).astype(a.dtype))
+        ps.append(p.astype(a.dtype))
+    return _cat(ps, 0), _cat(dss, 0)
 
 
 def _causal_split_loop(lo, full, hi, body, carry):
@@ -121,31 +290,66 @@ def _causal_split_loop(lo, full, hi, body, carry):
 
 # ---------------------------------------------------------------- forward
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale, causal,
-                block_q, block_k, seq_len):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
+                *, scale, causal, block_q, k_tile, strip, seq_len):
+    """Whole-row forward; the softmax state (o, m, l: fp32) lives in VMEM
+    scratch between k-tiles.
+
+    Causal: the block's own diagonal region goes FIRST, from a clean
+    state, in sub-blocks of ``strip`` rows: each takes ONE tile of static
+    width, from the block's first column to its own diagonal square, the
+    mask on the square alone — nothing above a sub-block's diagonal
+    square is computed. Then the k-tiles wholly below the block run
+    unmasked for all its rows at once (a dynamic count; online softmax
+    does not mind the order)."""
     qi = pl.program_id(1)
-    q = q_ref[0]
-    num_kb = seq_len // block_k
+    fold = _scale_folds(scale)
+    s_scale = None if fold else scale
 
-    def body(kb, carry, masked):
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :]
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        return _fwd_block_step(q, k, v, carry, qi * block_q, kb * block_k,
-                               block_q, block_k, masked, scale)
+    def step(rows, cols, first, mask):
+        q = q_ref[0, rows, :] * scale if fold else q_ref[0, rows, :]
+        carry = None if first else (acc_ref[rows, :], m_ref[rows, :],
+                                    l_ref[rows, :])
+        acc_ref[rows, :], m_ref[rows, :], l_ref[rows, :] = _fwd_block_step(
+            q, k_ref[0, cols, :], v_ref[0, cols, :], carry, mask, s_scale)
 
-    carry0 = (jnp.zeros((block_q, q.shape[1]), jnp.float32),
-              jnp.full((block_q,), NEG_INF, jnp.float32),
-              jnp.zeros((block_q,), jnp.float32))
+    def body(t, _):
+        step(slice(None), pl.ds(pl.multiple_of(t * k_tile, k_tile), k_tile),
+             False, None)
+        return _
+
     if causal:
-        num_full = (qi * block_q) // block_k
-        num_active = ((qi + 1) * block_q + block_k - 1) // block_k
-        o, m, l = _causal_split_loop(0, num_full, num_active, body, carry0)
+        tri = _rel_pos(strip, strip) >= 0
+        c0 = pl.multiple_of(qi * block_q, block_q)
+        for qs in range(block_q // strip):
+            step(slice(qs * strip, (qs + 1) * strip),
+                 pl.ds(c0, (qs + 1) * strip), True, tri)
+        jax.lax.fori_loop(0, qi * (block_q // k_tile), body, 0)
     else:
-        o, m, l = _causal_split_loop(0, num_kb, num_kb, body, carry0)
+        step(slice(None), slice(0, k_tile), True, None)
+        jax.lax.fori_loop(1, seq_len // k_tile, body, 0)
+    l_safe = jnp.maximum(_row_total(l_ref[...]), 1e-30)
+    o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+    lse = m_ref[...] + jnp.log(l_safe)
+    piece = lse_ref.shape[3]
+    for j in range(block_q // piece):
+        lse_ref[0, j] = _dense_row(lse[j * piece:(j + 1) * piece])
 
-    l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = (o / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[0, :, 0] = m + jnp.log(l_safe)
+
+# widest off-diagonal tile: [1024, 512] fp32 scores are 2 MB of VMEM
+_MAX_TILE = 512
+
+
+def _plain_tiles(block, other):
+    """(strip, tile) of a whole-row kernel whose grid walks ``block``
+    (q rows forward, k columns backward): sub-blocks of one strip along
+    the block's diagonal (``_pick_strip``), and the off-diagonal walk
+    along the other axis in the widest tile that divides both blocks, at
+    most ``_MAX_TILE``."""
+    tile = math.gcd(block, other)
+    if tile > _MAX_TILE and tile % _MAX_TILE == 0:
+        tile = _MAX_TILE
+    return _pick_strip(block), tile
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
@@ -156,7 +360,6 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
     cache streams once per rep q heads and the full-head K/V is NEVER
     materialized in HBM (the GQA memory promise, models/llama.py)."""
     BH, S, D = q.shape
-    grid = (BH, S // block_q)
     if heads and kv_heads and heads != kv_heads:
         rep = heads // kv_heads
         H = heads
@@ -166,11 +369,14 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
     else:
         def kv_map(b, i):
             return (b, 0, 0)
+    strip, k_tile = _plain_tiles(block_q, block_k)
+    piece = _stat_piece(block_q, block_k)
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k, seq_len=S)
+                               block_q=block_q, k_tile=k_tile, strip=strip,
+                               seq_len=S)
     call = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(BH, S // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, S, D), kv_map),
@@ -178,12 +384,16 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, block_q // piece, 1, piece),
+                         lambda b, i: (b, i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
+            jax.ShapeDtypeStruct((BH, S // piece, 1, piece), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32)],
         interpret=interpret,
     )
     with annotate("flash_fwd"):
@@ -194,103 +404,123 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
 # ---------------------------------------------------------------- backward
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, *, scale, causal, block_q,
-                      block_k, seq_len):
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                      scale, causal, block_k, q_tile, strip, seq_len):
     """Single-pass backward: the grid walks k-blocks; dk/dv accumulate
-    block-locally over the q-blocks of the inner loop, while dq
-    accumulates into a VMEM-resident full row (its index map ignores the
-    k-block grid dim, so Pallas keeps the block resident across grid
-    steps). Each (q-block, k-block) score tile — the dots AND the exp —
-    is computed ONCE, where the split dq/dkv kernels computed everything
-    but the final products twice; the exp on [bq, bk] fp32 tiles is
-    VPU-bound, so halving it is the biggest attention-bwd lever at
-    training shapes (measured 2.4 ms/layer -> target <1.5 at the 774M
-    headline: B*H=160, S=1024, D=64)."""
+    block-locally (fp32 VMEM scratch) over the q rows of the inner loops,
+    while dq accumulates into a VMEM-resident fp32 row that outlives the
+    k-block grid dim and leaves as the input dtype on the last k-block.
+    Each score tile — the dots AND the exp — is computed ONCE, where
+    split dq/dkv kernels compute everything but the final products
+    twice.
+
+    The tile is held TRANSPOSED, [k, q]: lse and delta are then lane-dense
+    rows, and of pᵀ·do, dsᵀ·q and ds·k only the last needs its left
+    operand turned.
+
+    Causal: the k-block's own q rows go first in sub-blocks of ``strip``,
+    each against the block's k rows up to its own diagonal square (a
+    static height), the mask on the square alone; then the q rows past
+    the k-block run ``q_tile`` at a time against the whole block,
+    unmasked (a dynamic count). q rows before the block are not visited
+    and nothing above a sub-block's diagonal square is computed."""
     ki = pl.program_id(1)
     num_kb = seq_len // block_k
-    num_qb = seq_len // block_q
-    k = k_ref[0]   # [block_k, D]
-    v = v_ref[0]
+    fold = _scale_folds(scale)
+    s_scale = None if fold else scale
 
     @pl.when(ki == 0)
     def _init():
-        dq_ref[0] = jnp.zeros_like(dq_ref[0])
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def body(qb, carry, masked):
-        dk_acc, dv_acc = carry
-        q = q_ref[0, pl.ds(qb * block_q, block_q), :]
-        do = do_ref[0, pl.ds(qb * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(qb * block_q, block_q), 0]
-        delta = delta_ref[0, pl.ds(qb * block_q, block_q), 0]
-        p, ds = _bwd_ds_block(q, do, lse, delta, k, v, qb * block_q,
-                              ki * block_k, block_q, block_k, masked,
-                              scale)
-        dsl = ds.astype(q.dtype)
-        dv_new = dv_acc + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_new = dk_acc + jax.lax.dot_general(
-            dsl, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        sl = pl.ds(qb * block_q, block_q)
-        dq_ref[0, sl, :] += jax.lax.dot(
-            dsl, k, preferred_element_type=jnp.float32)
-        return dk_new, dv_new
+    dk_acc[...] = jnp.zeros_like(dk_acc)
+    dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    carry0 = (jnp.zeros(k.shape, jnp.float32),
-              jnp.zeros(v.shape, jnp.float32))
+    def tile(row0, rows, cols, mask):
+        q_rows = pl.ds(row0, rows)
+        q = q_ref[0, q_rows, :]
+        if fold:
+            q = q * scale
+        do = do_ref[0, q_rows, :]
+        k = k_ref[0, cols, :]
+        p, ds = _bwd_ds_block(k, v_ref[0, cols, :],
+                              _stat_row(lse_ref, row0, rows),
+                              _stat_row(delta_ref, row0, rows), q, do, mask,
+                              s_scale)
+        dv_acc[cols, :] += jax.lax.dot(p, do,
+                                       preferred_element_type=jnp.float32)
+        dk_acc[cols, :] += jax.lax.dot(ds, q,
+                                       preferred_element_type=jnp.float32)
+        dq_acc[q_rows, :] += jax.lax.dot_general(
+            ds, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    def body(t, _):
+        tile(pl.multiple_of(t * q_tile, q_tile), q_tile, slice(None), None)
+        return _
+
     if causal:
-        first_active = (ki * block_k) // block_q
-        first_full = ((ki + 1) * block_k + block_q - 1) // block_q
-        carry = jax.lax.fori_loop(
-            first_active, jnp.minimum(first_full, num_qb),
-            lambda qb, c: body(qb, c, True), carry0)
-        dk, dv = jax.lax.fori_loop(
-            first_full, num_qb, lambda qb, c: body(qb, c, False), carry)
+        tri = _rel_pos(strip, strip) <= 0       # [k, q]: q at or past k
+        r0 = ki * block_k
+        for qs in range(block_k // strip):
+            tile(pl.multiple_of(r0 + qs * strip, strip), strip,
+                 slice(0, (qs + 1) * strip), tri)
+        jax.lax.fori_loop((ki + 1) * (block_k // q_tile), seq_len // q_tile,
+                          body, 0)
     else:
-        dk, dv = _causal_split_loop(0, num_qb, num_qb, body, carry0)
-    dk_ref[0] = (dk * scale).astype(dk_ref.dtype)   # dk = scale·Σ dsᵀ·q
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+        jax.lax.fori_loop(0, seq_len // q_tile, body, 0)
+    # dk = scale·Σ dsᵀ·q: a pre-scaled q has carried it
+    dk = dk_acc[...] if fold else dk_acc[...] * scale
+    dk_ref[0] = dk.astype(dk_ref.dtype)
+    dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
     @pl.when(ki == num_kb - 1)
     def _finish():
         # dq = scale·Σ ds·k, applied once after every k-block contributed
-        dq_ref[0] *= scale
+        dq_ref[0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
 def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                interpret):
     BH, S, D = q.shape
+    pieces = lse.shape[1:]                  # (S / piece, 1, piece)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)[:, :, None]  # [BH, S, 1]
+                    axis=-1).reshape(lse.shape)
+    strip, q_tile = _plain_tiles(block_k, block_q)
+
+    def row(b, i):
+        return (b, 0, 0)
+
+    def block(b, i):
+        return (b, i, 0)
 
     call = pl.pallas_call(
         functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=S),
+                          block_k=block_k, q_tile=q_tile, strip=strip,
+                          seq_len=S),
         grid=(BH, S // block_k),
         in_specs=[
-            pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, S, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, S, 1), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, S, D), row),
+            pl.BlockSpec((1, block_k, D), block),
+            pl.BlockSpec((1, block_k, D), block),
+            pl.BlockSpec((1, S, D), row),
+            pl.BlockSpec((1,) + pieces, lambda b, i: (b, 0, 0, 0)),
+            pl.BlockSpec((1,) + pieces, lambda b, i: (b, 0, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, S, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((1, S, D), row),
+            pl.BlockSpec((1, block_k, D), block),
+            pl.BlockSpec((1, block_k, D), block),
         ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
-            jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-            jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        ],
+        out_shape=[jax.ShapeDtypeStruct((BH, S, D), q.dtype)] * 3,
+        scratch_shapes=[pltpu.VMEM((S, D), jnp.float32),
+                        pltpu.VMEM((block_k, D), jnp.float32),
+                        pltpu.VMEM((block_k, D), jnp.float32)],
         interpret=interpret,
     )
     with annotate("flash_bwd"):
         dq, dk, dv = call(q, k, v, do, lse, delta)
-    return dq.astype(q.dtype), dk, dv
+    return dq, dk, dv
 
 
 # ------------------------------------------------- long-S chunked variants
@@ -301,7 +531,10 @@ def _fwd_kernel_chunked(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     qi = pl.program_id(1)
     kc = pl.program_id(2)
     cb = chunk // block_k                      # k-blocks per chunk
-    q = q_ref[0]
+    fold = _scale_folds(scale)
+    s_scale = None if fold else scale
+    q = q_ref[0] * scale if fold else q_ref[0]
+    rel = _rel_pos(block_q, block_k) if causal else None
 
     @pl.when(kc == 0)
     def _init():
@@ -313,10 +546,13 @@ def _fwd_kernel_chunked(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         kb = kc * cb + j                       # global k-block index
         k = k_ref[0, pl.ds(j * block_k, block_k), :]
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        return _fwd_block_step(q, k, v, carry, qi * block_q, kb * block_k,
-                               block_q, block_k, masked, scale)
+        mask = _block_mask(rel, masked, qi * block_q, kb * block_k)
+        return _fwd_block_step(q, k, v, carry, mask, s_scale)
 
-    carry0 = (o_ref[0], m_ref[0, :, 0], l_ref[0, :, 0])
+    stat = (block_q, _LANES)
+    lane0 = jax.lax.broadcasted_iota(jnp.int32, stat, 1) == 0
+    carry0 = (o_ref[0], jnp.broadcast_to(m_ref[0], stat),
+              jnp.where(lane0, l_ref[0], 0.0))
     if causal:
         num_full = (qi * block_q) // block_k
         num_active = ((qi + 1) * block_q + block_k - 1) // block_k
@@ -330,13 +566,11 @@ def _fwd_kernel_chunked(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     # the final softmax state, so normalize in-kernel there — no separate
     # [BH, S, D] normalization pass in HBM
     last = kc == n_chunks - 1
+    l = _row_total(l)
     l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = jnp.where(last,
-                         jnp.where((l > 0)[:, None], o / l_safe[:, None],
-                                   0.0),
-                         o)
-    m_ref[0, :, 0] = jnp.where(last, m + jnp.log(l_safe), m)
-    l_ref[0, :, 0] = l
+    o_ref[0] = jnp.where(last, jnp.where(l > 0, o / l_safe, 0.0), o)
+    m_ref[0] = jnp.where(last, m[:, :1] + jnp.log(l_safe), m[:, :1])
+    l_ref[0] = l
 
 
 def _flash_fwd_chunked(q, k, v, scale, causal, block_q, block_k, chunk,
@@ -378,10 +612,13 @@ def _bwd_dq_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     qi = pl.program_id(1)
     kc = pl.program_id(2)
     cb = chunk // block_k
-    q = q_ref[0]
+    fold = _scale_folds(scale)
+    s_scale = None if fold else scale
+    q = q_ref[0] * scale if fold else q_ref[0]
     do = do_ref[0]
-    lse = lse_ref[0, :, 0]
-    delta = delta_ref[0, :, 0]
+    lse = lse_ref[0]
+    delta = delta_ref[0]
+    rel = _rel_pos(block_q, block_k) if causal else None
 
     @pl.when(kc == 0)
     def _init():
@@ -391,10 +628,9 @@ def _bwd_dq_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         kb = kc * cb + j
         k = k_ref[0, pl.ds(j * block_k, block_k), :]
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        _, ds = _bwd_ds_block(q, do, lse, delta, k, v, qi * block_q,
-                              kb * block_k, block_q, block_k, masked,
-                              scale)
-        return dq_acc + jax.lax.dot(ds.astype(k.dtype), k,
+        mask = _block_mask(rel, masked, qi * block_q, kb * block_k)
+        _, ds = _bwd_ds_block(q, do, lse, delta, k, v, mask, s_scale)
+        return dq_acc + jax.lax.dot(ds, k,
                                     preferred_element_type=jnp.float32)
 
     if causal:
@@ -416,8 +652,11 @@ def _bwd_dkv_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     ki = pl.program_id(1)
     qc = pl.program_id(2)
     cb = chunk // block_q
+    fold = _scale_folds(scale)
+    s_scale = None if fold else scale
     k = k_ref[0]
     v = v_ref[0]
+    rel = _rel_pos(block_q, block_k) if causal else None
 
     @pl.when(qc == 0)
     def _init():
@@ -428,17 +667,18 @@ def _bwd_dkv_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc, dv_acc = carry
         qb = qc * cb + j
         q = q_ref[0, pl.ds(j * block_q, block_q), :]
+        if fold:
+            q = q * scale
         do = do_ref[0, pl.ds(j * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(j * block_q, block_q), 0]
-        delta = delta_ref[0, pl.ds(j * block_q, block_q), 0]
-        p, ds = _bwd_ds_block(q, do, lse, delta, k, v, qb * block_q,
-                              ki * block_k, block_q, block_k, masked,
-                              scale)
+        lse = lse_ref[0, pl.ds(j * block_q, block_q), :]
+        delta = delta_ref[0, pl.ds(j * block_q, block_q), :]
+        mask = _block_mask(rel, masked, qb * block_q, ki * block_k)
+        p, ds = _bwd_ds_block(q, do, lse, delta, k, v, mask, s_scale)
         dv_new = dv_acc + jax.lax.dot_general(
-            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dk_new = dk_acc + jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return dk_new, dv_new
 
@@ -458,7 +698,8 @@ def _bwd_dkv_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk, dv = _causal_split_loop(0, cb, cb, body, carry0)
     # dk accumulates UNscaled across chunk revisits; the folded-scale
     # chain rule (dk = scale·Σ dsᵀ·q) lands once on the final chunk
-    dk_ref[0] = jnp.where(qc == n_chunks - 1, dk * scale, dk)
+    dk_ref[0] = dk if fold else jnp.where(qc == n_chunks - 1, dk * scale,
+                                          dk)
     dv_ref[0] = dv
 
 
@@ -589,6 +830,52 @@ def _flash_attention_bwd(scale, causal, block_q, block_k, chunk, interpret,
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
+def tile_overcompute(S, block_q, block_k, chunk, causal):
+    """Score elements the chosen loops compute over the elements a causal
+    (or full) softmax needs, forward and backward together: 1.0 would be
+    no waste. The whole-row kernels waste half of each diagonal SQUARE of
+    one strip (1.249 at S 1024 with strips of 256; 1.50 when the square
+    was the 512 block); the chunked kernels waste half of each diagonal
+    BLOCK (1.125 at S 4096 / 512)."""
+    if not causal:
+        return 1.0
+    if chunk:
+        def walked(rows, cols):
+            return sum(rows * cols * -(-((i + 1) * rows) // cols)
+                       for i in range(S // rows))
+        computed = walked(block_q, block_k) + walked(block_k, block_q)
+    else:
+        def walked(block):
+            strip = _pick_strip(block)
+            squares = block // strip
+            diagonal = strip * strip * squares * (squares + 1) // 2
+            return sum(diagonal + block * i * block
+                       for i in range(S // block))
+        computed = walked(block_q) + walked(block_k)
+    return computed / (S * (S + 1))
+
+
+_plans_logged = set()
+
+
+def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk):
+    """Trace-time engagement record of one ``flash_attention`` call: the
+    gauge ``attention/flash_tile_overcompute`` and, once per distinct
+    shape, a log line of the loop structure chosen for it."""
+    over = tile_overcompute(S, block_q, block_k, chunk, causal)
+    default_registry().gauge("attention/flash_tile_overcompute").set(over)
+    plan = (S, D, jnp.dtype(dtype).name, causal, block_q, block_k, chunk)
+    if plan not in _plans_logged:
+        _plans_logged.add(plan)
+        strip = 0 if chunk else _pick_strip(block_q)
+        logger.info(
+            f"flash attention S={S} D={D} {plan[2]} causal={causal}: "
+            f"block_q={block_q} block_k={block_k} strip={strip} "
+            f"chunk={chunk} scale "
+            f"{'on q' if _scale_folds(scale) else 'on scores'}"
+            f", computes {over:.3f} x the scores needed")
+
+
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=None, chunk=None):
     """[B, H, S, D] flash attention. Falls back to the jnp reference for
@@ -599,16 +886,22 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
     if interpret is None:
         interpret = _interpret_default()
-    # 512/512 measured fastest on v5e at S=1k-4k, D=64 (27% over 256/256:
-    # fewer grid steps amortize the half-rate D<128 contraction better).
-    # For S not divisible by 512 take the largest power-of-two divisor so
-    # e.g. S=768/1280/2560 keep the flash kernel instead of silently
-    # materializing [S, S] scores in the reference fallback.
+    itemsize = jnp.dtype(q.dtype).itemsize
+    whole_row = chunk is None and S * D * itemsize <= _UNCHUNKED_ROW_BYTES
+    # The widest grid block S allows, up to 1024 rows for the whole-row
+    # kernels and 512 for the chunked ones: a grid step has a fixed cost
+    # (at [160, 1024, 64] blocks of 256 measured 1.8 x the forward time of
+    # blocks of 512, and those 1.2 x blocks of 1024: PERF.md, PR 28), and
+    # the causal structure finer than a block lives INSIDE it
+    # (``_pick_strip``). For S not divisible by 512 take the largest
+    # power-of-two divisor so e.g. S=768/1280/2560 keep the flash kernel
+    # instead of silently materializing [S, S] scores in the reference
+    # fallback.
     def pick_block(requested):
         if requested:
             return requested
-        top = 64 if interpret else 512
-        for cand in (top, 256, 128, 64, 32):
+        top = 64 if interpret else 1024 if whole_row else 512
+        for cand in (1024, 512, 256, 128, 64, 32):
             if cand <= top and S % cand == 0:
                 return cand
         # irregular short sequences (e.g. S=80): one block spanning S keeps
@@ -628,8 +921,7 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
             raise ValueError(
                 f"chunk={chunk} must divide S={S} and be a multiple of "
                 f"block_q={block_q} and block_k={block_k}")
-    itemsize = jnp.dtype(q.dtype).itemsize
-    if chunk is None and S * D * itemsize > _UNCHUNKED_ROW_BYTES:
+    if chunk is None and not whole_row:
         # whole-row residency stops fitting scoped VMEM — stream chunks
         budget = max(_CHUNK_ROW_BYTES // 2 // (D * itemsize), 1)
         for cand in (4096, 2048, 1024, 512, 256, 128, 64):
@@ -642,6 +934,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
             return reference_attention(q, k, v, causal=causal,
                                        scale=scale)
 
+    chunk = int(chunk) if chunk else 0
+    _note_plan(S, D, q.dtype, scale, causal, block_q, block_k, chunk)
     qf = q.reshape(B * H, S, D)
     if chunk and Hkv != H:
         # the chunked kernels keep full-head maps; GQA rides the
@@ -651,7 +945,6 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         Hkv = H
     kf = k.reshape(B * k.shape[1], S, D)
     vf = v.reshape(B * v.shape[1], S, D)
-    o = _flash_attention(qf, kf, vf, scale, causal, block_q, block_k,
-                         int(chunk) if chunk else 0, bool(interpret),
-                         H, Hkv)
+    o = _flash_attention(qf, kf, vf, scale, causal, block_q, block_k, chunk,
+                         bool(interpret), H, Hkv)
     return o.reshape(B, H, S, D)

@@ -119,7 +119,13 @@ def annotate(tag):
     The flax module names ``attn``, ``mlp``, ``ln_1``, ``ln_2``, ``ln_f``
     (models/gpt2.py) and ``attn``, ``mlp``, ``input_norm``,
     ``post_attn_norm``, ``norm`` (models/llama.py) are the detail table's
-    remaining tags."""
+    remaining tags.
+
+    Beside the scopes, the flash kernels leave one trace-time GAUGE in
+    the registry, ``attention/flash_tile_overcompute`` (score elements
+    the chosen loops compute over those the softmax needs): no benchmark
+    metric reads it; it says whether the strip walk engaged for a
+    shape."""
     import jax
     return jax.named_scope(tag)
 

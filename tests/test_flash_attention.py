@@ -230,3 +230,185 @@ def test_flash_gqa_backward_matches_reference():
     for a, b in zip(g_fl, g_ref):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=5e-4, atol=5e-4)
+
+
+# ------------------------------------------------ strip-granular whole-row
+# kernels (ISSUE 28): the diagonal region of a grid block goes in
+# sub-blocks of one strip's rows (256 of a 1024- or 512-row block, 128 of
+# a 256-row one), each up to its own diagonal square
+
+def _fa():
+    """The kernel MODULE (the package exports the function of its name)."""
+    import importlib
+    return importlib.import_module(
+        "deepspeed_tpu.ops.pallas.flash_attention")
+
+
+def _tpu_block(S):
+    """``pick_block``'s choice on a TPU (interpret mode caps it at 64)."""
+    return next(c for c in (1024, 512, 256, 128, 64, 32) if S % c == 0)
+
+
+def _loss_pair(causal, **kw):
+    def loss_flash(q, k, v):
+        o = flash_attention(q, k, v, causal=causal, interpret=True, **kw)
+        return jnp.sum(jnp.sin(o.astype(jnp.float32)))
+
+    def loss_ref(q, k, v):
+        o = reference_attention(q, k, v, causal=causal)
+        return jnp.sum(jnp.sin(o.astype(jnp.float32)))
+    return loss_flash, loss_ref
+
+
+def _assert_fwd_and_grads(shape, dtype, causal, block_q, block_k,
+                          kv_heads=None, seed=0):
+    B, H, S, D = shape
+    q, _, _ = _qkv(shape, seed=seed, dtype=dtype)
+    _, k, v = _qkv((B, kv_heads or H, S, D), seed=seed + 1, dtype=dtype)
+    loss_flash, loss_ref = _loss_pair(causal, block_q=block_q,
+                                      block_k=block_k)
+    out = flash_attention(q, k, v, causal=causal, interpret=True,
+                          block_q=block_q, block_k=block_k)
+    ref = reference_attention(q, k, v, causal=causal)
+    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    # the tolerances the first tests of this file hold: fp32 2e-4 / 2e-5
+    # forward and 5e-3 / 5e-4 gradients, bf16 5e-2
+    f32 = dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref, np.float32),
+        rtol=2e-4 if f32 else 5e-2, atol=2e-5 if f32 else 5e-2)
+    for a, b, name in zip(g_flash, g_ref, "qkv"):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            rtol=5e-3 if f32 else 5e-2, atol=5e-4 if f32 else 5e-2,
+            err_msg=f"d{name} S={S} D={D} {block_q}/{block_k}")
+
+
+@pytest.mark.parametrize("S", [128, 256, 512, 768, 1024, 1280, 2048])
+def test_strip_kernels_causal_at_the_tpu_block_choice(S):
+    """Forward AND gradients at every strip edge: one strip (S 128), a
+    block of two (S 256, 512, 768, 1280), a block of four alone (S 1024)
+    and with a whole block below it (S 2048)."""
+    b = _tpu_block(S)
+    _assert_fwd_and_grads((1, 1, S, 64), jnp.float32, True, b, b)
+
+
+@pytest.mark.parametrize("S", [512, 1024, 2048])
+def test_strip_kernels_causal_block_512_forced(S):
+    """Blocks of 512: two strips of 256 with 0..3 whole blocks below."""
+    _assert_fwd_and_grads((1, 1, S, 64), jnp.float32, True, 512, 512)
+
+
+@pytest.mark.parametrize("S", [512, 1024, 2048])
+def test_strip_kernels_noncausal(S):
+    _assert_fwd_and_grads((1, 1, S, 64), jnp.float32, False, 512, 512)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_strip_kernels_head_dim_128(causal):
+    """2^-3.5 is no power of two: the scale stays on the fp32 scores."""
+    _assert_fwd_and_grads((1, 2, 1024, 128), jnp.float32, causal, 512, 512)
+
+
+@pytest.mark.parametrize("S,D", [(512, 64), (1024, 64), (1024, 128)])
+def test_strip_kernels_bf16(S, D):
+    _assert_fwd_and_grads((1, 2, S, D), jnp.bfloat16, True, 512, 512)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(512, 256), (256, 512),
+                                             (256, 128), (128, 512)])
+def test_strip_kernels_unequal_blocks(block_q, block_k):
+    """The off-diagonal walk takes the widest tile that divides both
+    blocks; a k-block wider than the q-block must leave no gap below the
+    diagonal region."""
+    _assert_fwd_and_grads((1, 1, 1024, 64), jnp.float32, True, block_q,
+                          block_k)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(384, 256), (192, 256)])
+def test_strip_kernels_diagonal_mid_tile(block_q, block_k):
+    """q-blocks that start in the middle of a k-block (384 = 1.5 x 256),
+    and a block 128 does not divide (192: one strip of 192 rows)."""
+    _assert_fwd_and_grads((1, 1, 768, 64), jnp.float32, True, block_q,
+                          block_k)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_strip_kernels_gqa(dtype):
+    """Reduced-head K/V through the strip forward (block 512) and the
+    repeat-and-sum backward."""
+    _assert_fwd_and_grads((1, 4, 1024, 64), dtype, True, 512, 512,
+                          kv_heads=2)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_prescaled_q_is_bit_identical_at_head_dim_64(dtype, monkeypatch):
+    """head_dim 64: scale 0.125 is a power of two, so (q·scale)·kᵀ equals
+    (q·kᵀ)·scale bit for bit — forward and all three gradients — and the
+    kernels move the multiply from the score tile onto q."""
+    fa = _fa()
+    assert fa._scale_folds(64 ** -0.5) and fa._scale_folds(256 ** -0.5)
+    q, k, v = _qkv((1, 2, 1024, 64), seed=5, dtype=dtype)
+    loss, _ = _loss_pair(True, block_q=512, block_k=512)
+
+    def run():
+        return (flash_attention(q, k, v, causal=True, interpret=True,
+                                block_q=512, block_k=512),
+                *jax.grad(loss, argnums=(0, 1, 2))(q, k, v))
+
+    folded = run()
+    monkeypatch.setattr(fa, "_scale_folds", lambda scale: False)
+    on_scores = run()
+    for a, b in zip(folded, on_scores):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_prescaled_q_is_not_taken_at_head_dim_128(monkeypatch):
+    """2^-3.5 rounds in bf16: every kernel of a head_dim-128 call, plain
+    and chunked, forward and backward, keeps the scale on the scores."""
+    fa = _fa()
+    asked = []
+    real = fa._scale_folds
+
+    def spy(scale):
+        asked.append((scale, real(scale)))
+        return asked[-1][1]
+
+    monkeypatch.setattr(fa, "_scale_folds", spy)
+    q, k, v = _qkv((1, 1, 256, 128), seed=6)
+    for chunk in (None, 128):
+        loss, _ = _loss_pair(True, block_q=128, block_k=128, chunk=chunk)
+        jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    assert len(asked) >= 5 and not any(folds for _, folds in asked), asked
+    assert all(abs(scale - 128 ** -0.5) < 1e-12 for scale, _ in asked)
+
+
+@pytest.mark.parametrize("S,block,chunk,expected", [
+    (1024, 1024, None, 1.2488),                 # strips of 256
+    (1024, 512, None, 1.2488),
+    (2048, 512, None, 1.1245),
+    (768, 256, None, 1.1651),                   # strips of 128
+    (4096, 512, 1024, 1.1247),                  # chunked: unchanged
+])
+def test_flash_tile_overcompute_gauge(S, block, chunk, expected):
+    """``attention/flash_tile_overcompute``: computed over needed score
+    elements of the loops chosen for the call. S 1024 was 1.50 when a
+    diagonal block was computed whole; a strip walk holds it <= 1.25."""
+    from deepspeed_tpu.telemetry.registry import default_registry
+    fa = _fa()
+    q = jax.ShapeDtypeStruct((1, 1, S, 16), jnp.float32)
+    jax.eval_shape(lambda a: flash_attention(
+        a, a, a, causal=True, interpret=True, block_q=block, block_k=block,
+        chunk=chunk), q)
+    got = default_registry().peek_gauge("attention/flash_tile_overcompute")
+    assert got == pytest.approx(expected, abs=1e-4)
+    assert got <= 1.25
+    assert fa.tile_overcompute(S, block, block, chunk or 0, False) == 1.0
+    jax.eval_shape(lambda a: flash_attention(
+        a, a, a, causal=False, interpret=True, block_q=block, block_k=block,
+        chunk=chunk), q)
+    assert default_registry().peek_gauge(
+        "attention/flash_tile_overcompute") == 1.0

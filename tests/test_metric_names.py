@@ -205,6 +205,15 @@ def test_zero_gather_edge_metric_names_documented():
         assert name in _package_source(), name
 
 
+def test_flash_tile_overcompute_gauge_documented():
+    """The flash kernels' trace-time engagement gauge (ISSUE 28) stays
+    documented AND emitted."""
+    name = "attention/flash_tile_overcompute"
+    assert name in documented_metric_names(), (
+        f"{name} missing from the docs/observability.md train table")
+    assert name in _package_source(), name
+
+
 def test_moe_metric_names_documented():
     """The dropless expert layer's four gauges (ISSUE 27) stay documented
     AND sown: the engine names a gauge after the model's ``stats`` variable

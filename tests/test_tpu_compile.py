@@ -103,9 +103,16 @@ def kernel_names(text):
 
 # ------------------------------------------------------- main-path kernels
 
-def test_flash_attention_fwd_and_grad_compile_at_774m_shape():
+@pytest.mark.parametrize("shape", [(chip_smoke.BATCH, 20, 1024, 64),
+                                   (4, 25, 1024, 64), (2, 16, 2048, 64)])
+def test_flash_attention_fwd_and_grad_compile_at_774m_shape(shape):
+    """The whole-row kernels with their strip walk (ISSUE 28) at what a
+    chip sees of GPT-2 large (8 x 20 heads) and XL (4 x 25), and at the
+    longest row they take at head_dim 64 (S 2048: 256 KB): lane-dense
+    lse/delta pieces, fp32 VMEM scratch for the softmax state and for
+    dq/dk/dv, the transposed backward tile."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-    qkv = (SDS((chip_smoke.BATCH, 20, 1024, 64), BF16),) * 3
+    qkv = (SDS(shape, BF16),) * 3
 
     def grads(q, k, v):
         return jax.grad(lambda *a: flash_attention(*a, causal=True)
