@@ -86,7 +86,7 @@ class SectionRunner:
 
 
 BENCH_SECTIONS = ("bert", "train", "sparse", "decode", "llama7b", "moe",
-                  "zero3_prefetch", "zero3_hier", "onebit_comm", "aio",
+                  "onebit_comm", "aio",
                   "nvme_param", "nvme_xl",
                   "elastic_ckpt", "fault_recovery", "serving",
                   "serving_prefix", "serving_spec", "serving_elastic",
@@ -235,21 +235,11 @@ def headline_metrics(doc):
                 grab(f"decode.{name}.decode_tokens_per_sec", entry,
                      "decode_tokens_per_sec", +1)
     grab("moe.tokens_per_sec", d.get("moe"), "tokens_per_sec", +1)
-    # ISSUE 8: the tile-granular fused_matmul gather must not regress
-    # vs ring-mode prefetch (CPU-proxy step-time ratio, higher=better)
-    grab("zero3_prefetch.fused_vs_ring", d.get("zero3_prefetch"),
-         "fused_vs_ring", +1)
     # ISSUE 10: the hierarchical exchange must keep the slow-hop
     # bytes-on-wire reduction (static cost-model ratio, >= 4x; a drop
     # means the per-bucket policy stopped compressing the slow axis)
     grab("onebit_comm.bytes_reduction", d.get("onebit_comm"),
          "bytes_reduction", +1)
-    # ISSUE 16: the link-aware ZeRO-3 prefetch stream must keep its
-    # modeled slow-hop reduction vs the FLAT single-ring baseline
-    # (static cost-model ratio, >= 2x at 2x4; a drop means a gather or
-    # grad leg fell off the two-level schedule or stopped compressing)
-    grab("zero3_hier.inter_bytes_reduction", d.get("zero3_hier"),
-         "inter_bytes_reduction", +1)
     grab("nvme_param.steady_step_s", d.get("nvme_param_tier"),
          "steady_step_s", -1)
     # ISSUE 20: the honest NVMe path. max_params_b is the single-chip
@@ -468,10 +458,6 @@ def main(argv=None):
     moe = runner.run(
         "moe", lambda: bench_moe(dstpu, make_mesh, MeshConfig, dev),
         est_s=180)
-    zero3_prefetch = runner.run("zero3_prefetch", bench_zero3_prefetch,
-                                est_s=300)
-    jax.clear_caches()
-    zero3_hier = runner.run("zero3_hier", bench_zero3_hier, est_s=300)
     jax.clear_caches()
     onebit_comm = runner.run("onebit_comm", bench_onebit_comm, est_s=240)
     jax.clear_caches()
@@ -556,20 +542,6 @@ def main(argv=None):
             # expert-parallel MoE training throughput (beyond-reference
             # component; routing einsums regress invisibly without it)
             "moe": moe,
-            # ZeRO-3 layer-wise gather prefetch on vs off (ISSUE 3) and
-            # ring vs tile-granular fused_matmul gather (ISSUE 8, with
-            # the gather-wait/compute exposure breakdown): on a
-            # single-chip harness this is the 8-virtual-device CPU
-            # step-time proxy (see bench_zero3_prefetch); on a slice it
-            # measures the real ICI overlap behind the headline MFU
-            "zero3_prefetch": zero3_prefetch,
-            # link-aware ZeRO-3 prefetch stream (ISSUE 16): modeled
-            # slow-hop byte reduction of the two-level compressed
-            # schedule vs the flat single-ring baseline + step times;
-            # 8-virtual-device synthetic-split proxy (the REAL
-            # process-boundary path is pinned by
-            # tests/test_multiprocess_dist.py)
-            "zero3_hier": zero3_hier,
             # hierarchical link-aware 1-bit gradient exchange (ISSUE
             # 10): slow-hop bytes-on-wire reduction + step times; on a
             # single-host harness the 8-virtual-device synthetic-split
@@ -797,38 +769,6 @@ def _run_proxy_bench(script_relpath, devices=8, timeout=900):
     start = max(i for i, l in enumerate(lines) if l.strip() == "{")
     out = json.loads("\n".join(lines[start:]))
     return {"mesh": f"cpu_virtual_{devices}dev_step_time_proxy", **out}
-
-
-def bench_zero3_prefetch():
-    """``stage3_prefetch`` on vs off (tests/perf/prefetch_bench.py).
-
-    The prefetch pipeline needs a >1-device data axis. On a multi-chip
-    claim it runs in-process against the real mesh; on the usual
-    single-chip harness it spawns the 8-virtual-device CPU proxy in a
-    subprocess — a step-time proxy that exercises the exact train
-    program, honestly labeled."""
-    import jax
-    if len(jax.devices()) > 1:
-        from tests.perf.prefetch_bench import run_prefetch_bench
-        return {"mesh": "real", **run_prefetch_bench()}
-    return _run_proxy_bench("tests/perf/prefetch_bench.py")
-
-
-def bench_zero3_hier():
-    """Link-aware ZeRO-3 prefetch stream (ISSUE 16,
-    tests/perf/zero3_hier_bench.py): flat single-ring stage-3 stream vs
-    the two-level reschedule vs two-level + compressed grad hop, one
-    prefetch engine each on a 2 x (n/2) synthetic split. Headline gate
-    is ``inter_bytes_reduction`` — modeled FLAT-ring slow-hop bytes
-    over the compressed two-level schedule's (acceptance: >= 2x; note
-    the denominator is the flat baseline, not the same-schedule fp32
-    figure onebit_comm uses). Step times recorded for calibration; the
-    wire-byte ledger is the portable claim on this CPU proxy."""
-    import jax
-    if len(jax.devices()) >= 4 and len(jax.devices()) % 2 == 0:
-        from tests.perf.zero3_hier_bench import run_zero3_hier_bench
-        return {"mesh": "real", **run_zero3_hier_bench()}
-    return _run_proxy_bench("tests/perf/zero3_hier_bench.py")
 
 
 def bench_onebit_comm():

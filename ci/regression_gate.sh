@@ -24,12 +24,8 @@
 # BENCH_r09.json or newer to arm it), and since r10
 # ``serving.elastic_recovered_fraction`` (ISSUE 11: every request
 # survives one replica kill + one graceful drain, must stay 1.0) —
-# gate against BENCH_r10.json or newer to arm that one. Since r15 it
-# includes ``zero3_hier.inter_bytes_reduction`` (ISSUE 16: the
-# link-aware ZeRO-3 prefetch stream's modeled slow-hop bytes vs the
-# FLAT single-ring baseline, >= 2x at 2x4 — gate against
-# BENCH_r15.json or newer to arm it). Since r16 it includes
-# ``serving.disagg_xproc_ttft_p99`` (ISSUE 17: TTFT p99 of the
+# gate against BENCH_r10.json or newer to arm that one. Since r16 it
+# includes ``serving.disagg_xproc_ttft_p99`` (ISSUE 17: TTFT p99 of the
 # disaggregated trace with the handoff crossing 2 REAL OS processes as
 # versioned wire frames over the gloo host-bytes collective — gate
 # against BENCH_r16.json or newer to arm it). Since r18 it includes

@@ -158,61 +158,19 @@ class DeepSpeedZeroConfig:
                 f"positive when {C.ZERO_OVERLAP_COMM} is on, got "
                 f"{self.reduce_bucket_size}")
 
+        removed = [k for k in C.ZERO_REMOVED_KEYS if k in zero_dict]
+        if removed:
+            raise DeepSpeedConfigError(
+                f"zero_optimization.{'/'.join(removed)}: no longer an "
+                f"option. The explicit layer-gather prefetch step is gone; "
+                f"ZeRO stage 3 gathers a layer's weights at the block's "
+                f"edge of the GSPMD step and has no switch. Delete the "
+                f"key(s) from the config")
+
         # stage-3 tuning knobs
         self.prefetch_bucket_size = int(
             get_scalar_param(zero_dict, C.ZERO_PREFETCH_BUCKET_SIZE,
                              C.ZERO_PREFETCH_BUCKET_SIZE_DEFAULT))
-        self.stage3_prefetch = bool(
-            get_scalar_param(zero_dict, C.ZERO_STAGE3_PREFETCH,
-                             C.ZERO_STAGE3_PREFETCH_DEFAULT))
-        self.stage3_prefetch_gather = str(
-            get_scalar_param(zero_dict, C.ZERO_STAGE3_PREFETCH_GATHER,
-                             C.ZERO_STAGE3_PREFETCH_GATHER_DEFAULT))
-        if self.stage3_prefetch_gather not in \
-                C.ZERO_STAGE3_PREFETCH_GATHER_MODES:
-            raise DeepSpeedConfigError(
-                f"zero_optimization.{C.ZERO_STAGE3_PREFETCH_GATHER} must "
-                f"be one of {C.ZERO_STAGE3_PREFETCH_GATHER_MODES}, got "
-                f"{self.stage3_prefetch_gather!r}")
-        cm = zero_dict.get(C.ZERO_COLLECTIVE_MATMUL, {}) or {}
-        if not isinstance(cm, dict):
-            raise DeepSpeedConfigError(
-                f"zero_optimization.{C.ZERO_COLLECTIVE_MATMUL} must be a "
-                f"dict of {{{C.CM_BACKEND}, {C.CM_TILE_M}, "
-                f"{C.CM_MIN_SHARD_BYTES}, {C.CM_VMEM_BUDGET}}}, got "
-                f"{cm!r}")
-        self.collective_matmul_backend = str(
-            cm.get(C.CM_BACKEND, C.CM_BACKEND_DEFAULT))
-        if self.collective_matmul_backend not in C.CM_BACKEND_MODES:
-            raise DeepSpeedConfigError(
-                f"zero_optimization.{C.ZERO_COLLECTIVE_MATMUL}."
-                f"{C.CM_BACKEND} must be one of {C.CM_BACKEND_MODES}, "
-                f"got {self.collective_matmul_backend!r}")
-        self.collective_matmul_tile_m = int(
-            cm.get(C.CM_TILE_M, C.CM_TILE_M_DEFAULT))
-        self.collective_matmul_min_shard_bytes = int(
-            cm.get(C.CM_MIN_SHARD_BYTES, C.CM_MIN_SHARD_BYTES_DEFAULT))
-        if self.collective_matmul_tile_m <= 0:
-            raise DeepSpeedConfigError(
-                f"zero_optimization.{C.ZERO_COLLECTIVE_MATMUL}."
-                f"{C.CM_TILE_M} must be positive, got "
-                f"{self.collective_matmul_tile_m}")
-        if self.collective_matmul_min_shard_bytes < 0:
-            raise DeepSpeedConfigError(
-                f"zero_optimization.{C.ZERO_COLLECTIVE_MATMUL}."
-                f"{C.CM_MIN_SHARD_BYTES} must be >= 0, got "
-                f"{self.collective_matmul_min_shard_bytes}")
-        self.collective_matmul_vmem_budget_bytes = int(
-            cm.get(C.CM_VMEM_BUDGET, C.CM_VMEM_BUDGET_DEFAULT))
-        if self.collective_matmul_vmem_budget_bytes <= 0:
-            raise DeepSpeedConfigError(
-                f"zero_optimization.{C.ZERO_COLLECTIVE_MATMUL}."
-                f"{C.CM_VMEM_BUDGET} must be positive, got "
-                f"{self.collective_matmul_vmem_budget_bytes}")
-        if self.stage3_prefetch and self.stage != 3:
-            raise DeepSpeedConfigError(
-                f"zero_optimization.{C.ZERO_STAGE3_PREFETCH} requires "
-                f"stage 3, got stage {self.stage}")
         self.param_persistence_threshold = int(
             get_scalar_param(zero_dict, C.ZERO_PARAM_PERSISTENCE_THRESHOLD,
                              C.ZERO_PARAM_PERSISTENCE_THRESHOLD_DEFAULT))
@@ -240,15 +198,6 @@ class DeepSpeedZeroConfig:
             "allgather_bucket_size": self.allgather_bucket_size,
             "overlap_comm": self.overlap_comm,
             "overlap_reduce": self.overlap_reduce,
-            "stage3_prefetch": self.stage3_prefetch,
-            "stage3_prefetch_gather": self.stage3_prefetch_gather,
-            "collective_matmul": {
-                "backend": self.collective_matmul_backend,
-                "tile_m": self.collective_matmul_tile_m,
-                "min_shard_bytes": self.collective_matmul_min_shard_bytes,
-                "vmem_budget_bytes":
-                    self.collective_matmul_vmem_budget_bytes,
-            },
             "reduce_scatter": self.reduce_scatter,
             "offload_param": self.offload_param.repr_dict(),
             "offload_optimizer": self.offload_optimizer.repr_dict(),
@@ -1551,15 +1500,6 @@ class DeepSpeedConfig:
     def _do_sanity_check(self):
         if self.fp16_enabled and self.bf16_enabled:
             raise DeepSpeedConfigError("fp16 and bf16 cannot both be enabled")
-        hcfg = self.comm_config.hierarchy
-        if hcfg.enabled and self.zero_config.stage3_prefetch \
-                and self.zero_config.stage3_prefetch_gather == "fused":
-            raise DeepSpeedConfigError(
-                "comm.hierarchy composes with zero_optimization."
-                "stage3_prefetch only under explicit collectives "
-                "(stage3_prefetch_gather 'ring' or 'fused_matmul'): "
-                "'fused' hands the gather schedule to XLA, which cannot "
-                "honor the two-level link split")
         if self.zero_enabled and self.optimizer_name is not None:
             if self.optimizer_name not in C.DEEPSPEED_OPTIMIZERS + ["sgd"]:
                 logger.warning(

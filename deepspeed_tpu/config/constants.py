@@ -187,53 +187,17 @@ OFFLOAD_FSYNC_DEFAULT = False
 OFFLOAD_STREAM = "stream"
 OFFLOAD_STREAM_SEGMENTS = "stream_segments"
 
-# stage-3 tuning knobs (reference zero/constants.py)
+# stage-3 tuning knobs (reference zero/constants.py).
+# stage3_prefetch_bucket_size and stage3_max_live_parameters are accepted
+# for the reference's configs and have no effect: XLA schedules the
+# gather edge's all-gathers
 ZERO_PREFETCH_BUCKET_SIZE = "stage3_prefetch_bucket_size"
 ZERO_PREFETCH_BUCKET_SIZE_DEFAULT = 5e7
-# TPU extension: explicit layer-wise parameter-gather prefetch pipeline
-# (parallel/prefetch.py) — the train step becomes a shard_map program
-# whose per-layer param all-gather issues ONE LAYER AHEAD of use
-# (double-buffered, forward and backward), bounding live full params to
-# ~2 layers; the reference's stage3_prefetch_bucket_size /
-# PartitionedParameterCoordinator behavior made structural.
-ZERO_STAGE3_PREFETCH = "stage3_prefetch"
-ZERO_STAGE3_PREFETCH_DEFAULT = False
-# collective implementation for the prefetch gathers and the backward
-# grad reduce-scatter: "ring" (explicit lax.ppermute hops, maximum
-# scheduling freedom), "fused" (lax.all_gather/psum_scatter per layer;
-# XLA picks the algorithm) — the stage-3 twin of overlap_reduce — or
-# "fused_matmul" (ISSUE 8): a layer's dominant projection weights skip
-# the materialized full-param buffer entirely and stream chunk-by-chunk
-# through tile-granularity fused all-gather+matmul /
-# matmul+reduce-scatter kernels (ops/pallas/fused_collective.py);
-# everything else rides the ring. Tuning lives in the
-# ``collective_matmul`` sub-block below.
-ZERO_STAGE3_PREFETCH_GATHER = "stage3_prefetch_gather"
-ZERO_STAGE3_PREFETCH_GATHER_DEFAULT = "ring"
-ZERO_STAGE3_PREFETCH_GATHER_MODES = ("ring", "fused", "fused_matmul")
-# ``zero_optimization.collective_matmul`` sub-block: the fused-kernel
-# knobs (only read when stage3_prefetch_gather == "fused_matmul").
-ZERO_COLLECTIVE_MATMUL = "collective_matmul"
-# "auto" = pallas kernels on TPU, the lax decomposed-ring path
-# elsewhere; "fused" / "lax" force one lowering.
-CM_BACKEND = "backend"
-CM_BACKEND_DEFAULT = "auto"
-CM_BACKEND_MODES = ("auto", "fused", "lax")
-# m-tile of the fused kernel grid (clamped to a divisor of the actual
-# token count)
-CM_TILE_M = "tile_m"
-CM_TILE_M_DEFAULT = 128
-# a weight streams through the fused kernels only when its per-device
-# shard is at least this large; smaller sharded leaves stay on the
-# packed per-layer ring gather (n chunk GEMMs cost more than one small
-# collective)
-CM_MIN_SHARD_BYTES = "min_shard_bytes"
-CM_MIN_SHARD_BYTES_DEFAULT = 1 << 16
-# VMEM ceiling for backend="auto" kernel feasibility: weights whose
-# fused-kernel scratch (full-W stash for contracting shards, ring-carry
-# slots otherwise) exceeds it take the lax ring instead
-CM_VMEM_BUDGET = "vmem_budget_bytes"
-CM_VMEM_BUDGET_DEFAULT = 8 << 20
+# keys of the explicit layer-gather prefetch step, which is gone (the
+# GSPMD step with zero/partition.GatherEdge is the one way stage 3
+# gathers weights): refused by name, not dropped in silence
+ZERO_REMOVED_KEYS = ("stage3_prefetch", "stage3_prefetch_gather",
+                     "collective_matmul")
 ZERO_PARAM_PERSISTENCE_THRESHOLD = "stage3_param_persistence_threshold"
 ZERO_PARAM_PERSISTENCE_THRESHOLD_DEFAULT = 1e5
 ZERO_MAX_LIVE_PARAMETERS = "stage3_max_live_parameters"

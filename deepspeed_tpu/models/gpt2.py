@@ -172,69 +172,6 @@ def gather_edge_block(block_cls, parent, name):
         trans_in_fn=lambda vs: {**vs, "params": edge(path, vs["params"])})
 
 
-class CollectiveDense(nn.Dense):
-    """``nn.Dense`` twin whose kernel GEMM can fuse with the ZeRO-3
-    gather ring (ISSUE 8). Outside a fused-gather trace this IS
-    ``nn.Dense`` — same param tree, same promote/dot/bias numerics, so
-    every GSPMD/serving/inference path is untouched. Inside the
-    prefetch pipeline's ``fused_matmul`` body traces
-    (ops/pallas/fused_collective.gather_scope) the pipeline leaves a
-    layer's dominant projection kernels in the param tree as their
-    RESTING SHARDS; a shard-shaped kernel value routes the GEMM
-    through ``collective_matmul`` — the all-gather decomposed into
-    ring chunks interleaved with the GEMM tiles that consume them,
-    backward dW through matmul+reduce-scatter — so the materialized
-    full weight never exists. Detection is by shape: flax's
-    declared-param check would reject a shard, so the fused path reads
-    the raw variable (``scope.get_variable``) and declares only the
-    bias; full-shaped kernels (leaves the pipeline gathered normally)
-    fall through to the stock Dense path even under an active scope."""
-
-    @nn.compact
-    def __call__(self, inputs):
-        from deepspeed_tpu.ops.pallas import fused_collective as fc
-        cfg = fc.gather_ctx()
-        in_dim = jnp.shape(inputs)[-1]
-        if cfg is not None and self.scope.has_variable("params", "kernel"):
-            raw = self.scope.get_variable("params", "kernel")
-            shard_dim = fc.infer_shard_dim(jnp.shape(raw), in_dim,
-                                           self.features, cfg.axis_size)
-            if shard_dim is not None:
-                from flax.linen.dtypes import promote_dtype
-                bias = self.param("bias", self.bias_init, (self.features,),
-                                  self.param_dtype) if self.use_bias \
-                    else None
-                x, shard, bias = promote_dtype(inputs, raw, bias,
-                                               dtype=self.dtype)
-                y = fc.collective_matmul(
-                    x, shard, shard_dim=shard_dim,
-                    axis_name=cfg.axis_name, axis_size=cfg.axis_size,
-                    cfg=cfg, precision=self.precision,
-                    site="/".join(self.scope.path))
-                if bias is not None:
-                    y = y + jnp.reshape(bias,
-                                        (1,) * (y.ndim - 1) + (-1,))
-                return y
-        # fallthrough: the stock nn.Dense body verbatim (flax 0.10) —
-        # the @compact-wrapped Dense.__call__ cannot be super()-called
-        # from another compact method, and identical numerics (same
-        # promote, same dot_general, same bias broadcast) is the
-        # contract tests/test_prefetch.py pins against model.apply
-        from flax.linen.dtypes import promote_dtype
-        kernel = self.param("kernel", self.kernel_init,
-                            (in_dim, self.features), self.param_dtype)
-        bias = self.param("bias", self.bias_init, (self.features,),
-                          self.param_dtype) if self.use_bias else None
-        inputs, kernel, bias = promote_dtype(inputs, kernel, bias,
-                                             dtype=self.dtype)
-        y = jax.lax.dot_general(
-            inputs, kernel, (((inputs.ndim - 1,), (0,)), ((), ())),
-            precision=self.precision)
-        if bias is not None:
-            y += jnp.reshape(bias, (1,) * (y.ndim - 1) + (-1,))
-        return y
-
-
 @dataclasses.dataclass(frozen=True)
 class GPT2Config:
     vocab_size: int = 50257
@@ -283,10 +220,10 @@ class SelfAttention(nn.Module):
     def __call__(self, x, deterministic=True):
         cfg = self.config
         B, S, E = x.shape
-        qkv = CollectiveDense(3 * E, dtype=cfg.dtype,
-                              param_dtype=cfg.param_dtype,
-                              kernel_init=nn.initializers.normal(0.02),
-                              name="c_attn")(x)
+        qkv = nn.Dense(3 * E, dtype=cfg.dtype,
+                       param_dtype=cfg.param_dtype,
+                       kernel_init=nn.initializers.normal(0.02),
+                       name="c_attn")(x)
         qkv = checkpoint_name(qkv, "qkv")
         q, k, v = jnp.split(qkv, 3, axis=-1)
 
@@ -323,11 +260,11 @@ class SelfAttention(nn.Module):
             out = dot_product_attention(heads(q), heads(k), heads(v),
                                         causal=True, use_flash=cfg.use_flash)
         out = out.transpose(0, 2, 1, 3).reshape(B, S, E)
-        out = CollectiveDense(E, dtype=cfg.dtype,
-                              param_dtype=cfg.param_dtype,
-                              kernel_init=nn.initializers.normal(
-                                  0.02 / np.sqrt(2 * cfg.n_layer)),
-                              name="c_proj")(out)
+        out = nn.Dense(E, dtype=cfg.dtype,
+                       param_dtype=cfg.param_dtype,
+                       kernel_init=nn.initializers.normal(
+                           0.02 / np.sqrt(2 * cfg.n_layer)),
+                       name="c_proj")(out)
         out = checkpoint_name(out, "attn_proj")
         if cfg.dropout > 0:
             out = nn.Dropout(cfg.dropout)(out, deterministic=deterministic)
@@ -340,17 +277,17 @@ class MLP(nn.Module):
     @nn.compact
     def __call__(self, x, deterministic=True):
         cfg = self.config
-        h = CollectiveDense(4 * cfg.n_embd, dtype=cfg.dtype,
-                            param_dtype=cfg.param_dtype,
-                            kernel_init=nn.initializers.normal(0.02),
-                            name="c_fc")(x)
+        h = nn.Dense(4 * cfg.n_embd, dtype=cfg.dtype,
+                     param_dtype=cfg.param_dtype,
+                     kernel_init=nn.initializers.normal(0.02),
+                     name="c_fc")(x)
         h = checkpoint_name(h, "mlp_fc")
         h = nn.gelu(h, approximate=True)
-        h = CollectiveDense(cfg.n_embd, dtype=cfg.dtype,
-                            param_dtype=cfg.param_dtype,
-                            kernel_init=nn.initializers.normal(
-                                0.02 / np.sqrt(2 * cfg.n_layer)),
-                            name="c_proj")(h)
+        h = nn.Dense(cfg.n_embd, dtype=cfg.dtype,
+                     param_dtype=cfg.param_dtype,
+                     kernel_init=nn.initializers.normal(
+                         0.02 / np.sqrt(2 * cfg.n_layer)),
+                     name="c_proj")(h)
         h = checkpoint_name(h, "mlp_proj")
         if cfg.dropout > 0:
             h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
@@ -475,84 +412,6 @@ class GPT2LMHeadModel(nn.Module):
         (``[n_layer, ...]``), or None with unrolled layers: the ZeRO
         partitioner judges such leaves one layer at a time."""
         return "h" if self.config.scan_layers else None
-
-    @property
-    def prefetch_layer_subtree(self):
-        """Name of the layer-stacked params subtree the engine's
-        stage3_prefetch pipeline may drive layer-by-layer, or None when
-        the model can't offer one (unrolled layers have per-layer
-        subtrees; MoE sows aux losses the functional twin doesn't
-        collect; dropout needs per-layer rng plumbing)."""
-        cfg = self.config
-        if not cfg.moe_experts and cfg.dropout == 0:
-            return self.layer_stacked_subtree
-        return None
-
-    @nn.nowrap
-    def prefetch_apply(self, params, input_ids, layer_scan,
-                       deterministic=True, keep_prob=1.0, labels=None):
-        """Functional twin of ``__call__`` (scan_layers path) where the
-        transformer stack runs through ``layer_scan(body, x,
-        params["h"])`` — the engine passes the double-buffered
-        parameter-gather scan (parallel/prefetch.py) so each layer's
-        shards gather one layer ahead of use. ``body(x, layer_params)``
-        applies ONE block from an (unstacked) per-layer param tree.
-        Numerics are pinned to ``__call__`` by tests/test_prefetch.py."""
-        cfg = self.config
-        S = input_ids.shape[1]
-        with annotate("ds_embed"):
-            x = _embed_lookup(params["wte"], input_ids).astype(cfg.dtype) \
-                + params["wpe"][:S].astype(cfg.dtype)[None]
-
-        scan_body = ScanBody(cfg)
-
-        def body(xc, layer_params):
-            y, _ = scan_body.apply({"params": layer_params}, xc,
-                                   deterministic, keep_prob)
-            return y
-
-        x = layer_scan(body, x, params["h"])
-        x = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
-                         param_dtype=cfg.param_dtype).apply(
-            {"params": params["ln_f"]}, x)
-        if labels is not None and cfg.loss_chunk > 0 \
-                and cfg.tie_word_embeddings:
-            return chunked_lm_loss(x, params["wte"].astype(cfg.dtype),
-                                   labels, cfg.loss_chunk)
-        if cfg.tie_word_embeddings:
-            with annotate("ds_loss_head"):
-                logits = jnp.einsum("bse,ve->bsv", x,
-                                    params["wte"].astype(cfg.dtype))
-        else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=False,
-                              dtype=cfg.dtype,
-                              param_dtype=cfg.param_dtype).apply(
-                {"params": params["lm_head"]}, x)
-        if labels is not None:
-            return lm_loss(logits, labels)
-        return logits
-
-    @property
-    def supports_collective_matmul(self):
-        """The Blocks' projection layers (c_attn/c_proj/c_fc/c_proj) are
-        CollectiveDense: under the prefetch pipeline's ``fused_matmul``
-        gather mode they consume ZeRO-3 resting shards through the
-        tile-granular fused kernels instead of a gathered full weight.
-        The engine checks this marker before leaving shards in the
-        layer tree — a model without it would crash on the shard shape."""
-        return True
-
-    @property
-    def collective_matmul_paths(self):
-        """Per-leaf whitelist backing ``supports_collective_matmul``:
-        '/'-joined path SUFFIXES (within a per-layer param tree) of the
-        kernels whose consuming module is CollectiveDense. The engine
-        streams shards ONLY to these leaves — a 3D ``kernel`` param
-        consumed by a plain nn.Dense elsewhere in the block must not be
-        handed a shard (flax's declared-param shape check would reject
-        it at trace time with an opaque error)."""
-        return ("attn/c_attn/kernel", "attn/c_proj/kernel",
-                "mlp/c_fc/kernel", "mlp/c_proj/kernel")
 
     @property
     def sparse_grad_params(self):

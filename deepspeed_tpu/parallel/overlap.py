@@ -203,19 +203,9 @@ def bucketed_allreduce(tree, axis_name: str, n: int, bucket_elems: int,
                   (2(n-1) chunk hops the scheduler can float over compute).
     mode="fused": per bucket, one `lax.psum` (XLA picks the algorithm) —
                   still bucketed, so buckets interleave with backward.
-    mode="fused_matmul": the stage-3 tile-granular gather mode (ISSUE
-                  8). The replicated-leaf tail this bucket stream
-                  carries has no GEMM to fuse into — the weight-grad
-                  GEMMs it used to trail behind now reduce-scatter
-                  INSIDE the fused matmul+RS kernels
-                  (ops/pallas/fused_collective.py), so what is left
-                  here exchanges on the plain ppermute ring.
     """
-    if mode not in ("ring", "fused", "fused_matmul"):
-        raise ValueError(f"mode must be 'ring', 'fused' or "
-                         f"'fused_matmul', got {mode!r}")
-    if mode == "fused_matmul":
-        mode = "ring"
+    if mode not in ("ring", "fused"):
+        raise ValueError(f"mode must be 'ring' or 'fused', got {mode!r}")
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     if not leaves or n == 1:
         return tree
@@ -440,104 +430,6 @@ def hierarchy_wire_bytes(buckets, flags, plan: HierarchyPlan):
         inter_unc += unc
     return {"intra": int(intra), "inter": int(inter),
             "inter_uncompressed": int(inter_unc)}
-
-
-# ---------------------------------------------------------------------------
-# two-level piece-ordered collectives for the ZeRO-3 prefetch stream
-# (ISSUE 16): the stage-3 gathers/scatters move data in NATURAL data-axis
-# order (row i of a [n, c] stack belongs to data index i), and the split
-# mesh is row-major (data index = inter_index * intra + intra_index), so
-# the two-level schedule is: ONE slow-hop collective of the local shard
-# over ``inter_axis``, a fast ring over ``intra_axis`` for the rest, and
-# a transpose to restore natural order. Must run inside shard_map
-# binding both plan axes.
-# ---------------------------------------------------------------------------
-
-def two_level_all_gather(shard, plan: HierarchyPlan):
-    """[c] local shard → [n, c] full stack in natural data order.
-
-    Inter hop FIRST (one ``lax.all_gather`` of just the raw shard —
-    (ni-1)·c elements on the slow wire per device), then the intra ring
-    carries the [ni, c] stacks around the fast links. Intra-first would
-    push k× redundant bytes over the slow hop."""
-    ni, k = plan.inter, plan.intra
-    c = shard.size
-    stacked = jax.lax.all_gather(shard.reshape(-1), plan.inter_axis)
-    full = ring_all_gather(stacked.reshape(-1), plan.intra_axis, k)
-    # rows (t', b') → natural order idx = b'*k + t'
-    return full.reshape(k, ni, c).transpose(1, 0, 2).reshape(ni * k, c)
-
-
-def two_level_reduce_scatter_sum(pieces, plan: HierarchyPlan):
-    """[n, c] piece stack (row i destined for data index i) → [c] SUM of
-    this device's piece over all n devices. Fast intra ring first (fp32
-    partial sums stay on ICI-class links), then ONE exact slow-hop ring
-    reduce-scatter of the [ni, c] partials."""
-    ni, k = plan.inter, plan.intra
-    c = pieces.shape[-1]
-    # row t' of the intra ring buffer carries the ni pieces destined for
-    # intra position t'
-    buf = pieces.reshape(ni, k, c).transpose(1, 0, 2).reshape(-1)
-    mine = ring_reduce_scatter(buf, plan.intra_axis, k)   # [ni*c]
-    return ring_reduce_scatter(mine, plan.inter_axis, ni)
-
-
-def two_level_error_numel(c: int, plan: HierarchyPlan) -> int:
-    """Persistent worker-error length for a compressed two-level RS of
-    [n, c] pieces: the slow-hop buffer is [ni, c8] with each piece padded
-    to the sign-pack quantum."""
-    return plan.inter * (((int(c) + 7) // 8) * 8)
-
-
-def two_level_reduce_scatter_compressed(pieces, worker_error,
-                                        plan: HierarchyPlan):
-    """Like `two_level_reduce_scatter_sum` but the slow hop carries
-    error-compensated sign bits (`compression.compressed_reduce_scatter_
-    sum`) instead of fp32 — the ZeRO-3 grad legs' compressed inter-host
-    hop. ``worker_error`` is the persistent per-device
-    [`two_level_error_numel(c, plan)`] residual. Returns
-    (piece_sum [c], new_worker_error)."""
-    from deepspeed_tpu.parallel import compression as comp
-    ni, k = plan.inter, plan.intra
-    c = pieces.shape[-1]
-    buf = pieces.reshape(ni, k, c).transpose(1, 0, 2).reshape(-1)
-    mine = ring_reduce_scatter(buf, plan.intra_axis, k).reshape(ni, c)
-    c8 = ((c + 7) // 8) * 8
-    if c8 != c:
-        mine = jnp.zeros((ni, c8), jnp.float32).at[:, :c].set(mine)
-    out, new_err = comp.compressed_reduce_scatter_sum(
-        mine.reshape(-1), worker_error, plan.inter_axis)
-    return out[:c], new_err
-
-
-def two_level_gather_wire_bytes(shard_bytes: int, plan: HierarchyPlan):
-    """Per-device wire model of ONE two-level all-gather of a
-    ``shard_bytes`` shard: ``intra``/``inter`` are the actual schedule's
-    per-link-class bytes; ``flat_inter`` is the slow-link bytes the FLAT
-    ring all-gather of the same shard would have paid (average per
-    device: every hop each device forwards one shard-sized chunk on its
-    outgoing edge, ni of the n ring edges cross hosts) — the
-    ``inter_uncompressed`` denominator for the stage-3 stream."""
-    ni, k = plan.inter, plan.intra
-    n = ni * k
-    return {"intra": (k - 1) * ni * shard_bytes,
-            "inter": (ni - 1) * shard_bytes,
-            "flat_inter": (n - 1) * shard_bytes * ni // n}
-
-
-def two_level_rs_wire_bytes(piece_bytes: int, plan: HierarchyPlan,
-                            compressed: bool):
-    """Per-device wire model of ONE two-level reduce-scatter of [n, c]
-    fp32 pieces (``piece_bytes`` = 4c): the compressed slow hop sends
-    (ni-1) sign-packed piece chunks (÷32 vs fp32) + (ni-1) scales;
-    ``flat_inter`` as in `two_level_gather_wire_bytes`."""
-    ni, k = plan.inter, plan.intra
-    n = ni * k
-    inter = (ni - 1) * (piece_bytes // 32 + 4) if compressed \
-        else (ni - 1) * piece_bytes
-    return {"intra": (k - 1) * ni * piece_bytes,
-            "inter": inter,
-            "flat_inter": (n - 1) * piece_bytes * ni // n}
 
 
 def compressed_error_states(params, axis_size: int, bucket_elems: int):

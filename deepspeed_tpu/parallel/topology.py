@@ -185,11 +185,6 @@ def latch_fallback(axis, reason):
     return True
 
 
-def reset_fallback_latch():
-    """Test hook: forget latched fallbacks (process-wide state)."""
-    _FALLBACK_LATCH.clear()
-
-
 def _prime_factors(N):
     """Prime factorization in increasing order (reference topology.py:230)."""
     if N <= 0:

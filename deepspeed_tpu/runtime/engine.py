@@ -438,10 +438,8 @@ class DeepSpeedEngine:
 
         # BEFORE state init: the partitioner judges a layer-stacked leaf
         # one layer at a time and never shards its layer dim (the layer
-        # scan, and the stage3_prefetch scan of parallel/prefetch.py,
-        # slice whole layers device-locally)
-        stacked = getattr(self.module, "layer_stacked_subtree", None) or \
-            getattr(self.module, "prefetch_layer_subtree", None)
+        # scan slices whole layers device-locally)
+        stacked = getattr(self.module, "layer_stacked_subtree", None)
         self.zero.layer_stacked_prefixes = (stacked,) if stacked else ()
         # the ZeRO-3 gather edge of the GSPMD step programs
         # (zero/partition.GatherEdge), built with the state shardings
@@ -562,7 +560,7 @@ class DeepSpeedEngine:
         """True when only the data axis is live — the explicit-comm
         train paths shard_map the data axis alone, so every other mesh
         axis must be trivial (the one shared gate of the 1-bit / CSR /
-        overlap / prefetch dispatch)."""
+        overlap dispatch)."""
         return all(mesh_lib.mesh_axis_size(self.mesh, a) == 1
                    for a in (mesh_lib.PIPE_AXIS, mesh_lib.SEQ_AXIS,
                              mesh_lib.MODEL_AXIS, mesh_lib.EXPERT_AXIS))
@@ -579,8 +577,7 @@ class DeepSpeedEngine:
             return cached
         hier = None
         hcfg = self._config.comm_config.hierarchy
-        if hcfg.enabled and (self._compressed_comm_active()
-                             or self._prefetch_active()):
+        if hcfg.enabled and self._compressed_comm_active():
             from deepspeed_tpu.parallel import topology as topo
             hier, reason = topo.derive_data_hierarchy(
                 self.mesh, slow_axis=hcfg.slow_axis)
@@ -622,85 +619,6 @@ class DeepSpeedEngine:
             compression=hcfg.compression,
             min_bucket_bytes=hcfg.min_bucket_bytes,
             bucket_elems=self._config.zero_config.reduce_bucket_size)
-
-    def _prefetch_hier_plan(self):
-        """The HierarchyPlan for the stage-3 prefetch stream (ISSUE 16):
-        the same resolved slow/fast split as `_comm_plan`, re-bucketed by
-        ``stage3_prefetch_bucket_size`` (the replicated-leaf bucket leg
-        belongs to the prefetch stream, not the 1-bit reduce stream).
-        None when prefetch or the hierarchy is off/unresolvable."""
-        if not self._prefetch_active():
-            return None
-        plan = self._comm_plan()
-        if plan is None:
-            return None
-        import dataclasses
-        return dataclasses.replace(plan, bucket_elems=int(
-            self._config.zero_config.prefetch_bucket_size))
-
-    _PF_ERR_KEYS = ("pf_group_we", "pf_outer_we", "pf_bucket_we",
-                    "pf_bucket_se")
-
-    def _prefetch_error_states(self, params):
-        """Persistent error-feedback opt_state for the hierarchical
-        prefetch stream's compressed slow hops (ISSUE 16), or {} when
-        the stream runs flat. Three legs, mirroring the train program's
-        exchanges: the per-layer packed dtype groups (``pf_group_we`` —
-        [dp, L, E] per group, or None where the policy keeps the hop
-        exact), the step-persistent outer leaves (``pf_outer_we`` —
-        {key: [dp, E] per leaf}), and the replicated-leaf bucket stream
-        (``pf_bucket_we``/``pf_bucket_se`` — the two-level 1-bit
-        exchange's chunk-shaped states). The leading [dp] dim is the
-        per-device copy, sharded over the (split) data axis; the train
-        fn slices ``x[0]`` inside shard_map and re-wraps ``x[None]``,
-        the 1-bit optimizer's pattern."""
-        plan = self._prefetch_hier_plan()
-        if plan is None:
-            return {}
-        from deepspeed_tpu.parallel import overlap
-        from deepspeed_tpu.parallel import prefetch as prefetch_lib
-        tm = jax.tree_util.tree_map
-        subtree = self.module.prefetch_layer_subtree
-        param_spec_tree = self.zero.param_specs(params)
-        layer_plan = self.zero.explicit_shard_plan(
-            params[subtree], specs=param_spec_tree[subtree])
-        full_plan = self.zero.explicit_shard_plan(params,
-                                                  specs=param_spec_tree)
-        n = plan.world
-        mode = self._config.zero_config.stage3_prefetch_gather
-        cast_bf16 = self._config.grad_dtype == "bf16"
-        fused_ids, _ = self._select_fused_matmul_leaves(
-            params[subtree], layer_plan, mode, n, plan.axes, cast_bf16)
-        bump = lambda shape: jnp.zeros((n,) + tuple(shape),  # noqa: E731
-                                       jnp.float32)
-        group_specs = prefetch_lib.plan_group_errors(
-            jax.tree_util.tree_leaves(params[subtree]), layer_plan, n,
-            fused_ids, plan)
-        pf_outer = {}
-        for k in params:
-            if k == subtree:
-                continue
-            op = self.zero.explicit_shard_plan(params[k],
-                                               specs=param_spec_tree[k])
-            errs = []
-            for leaf, e in zip(jax.tree_util.tree_leaves(params[k]), op):
-                if e is None or not prefetch_lib.outer_compress(
-                        leaf.size // n, plan):
-                    errs.append(None)
-                else:
-                    errs.append(bump((prefetch_lib.outer_error_numel(
-                        leaf.size // n, plan),)))
-            pf_outer[k] = errs
-        repl = [leaf for leaf, e in zip(jax.tree_util.tree_leaves(params),
-                                        full_plan) if e is None]
-        bwe, bse = overlap.hierarchical_error_states(repl, plan)
-        return {
-            "pf_group_we": [bump(s) if s is not None else None
-                            for s in group_specs],
-            "pf_outer_we": pf_outer,
-            "pf_bucket_we": [tm(lambda x: bump(x.shape), e) for e in bwe],
-            "pf_bucket_se": [tm(lambda x: bump(x.shape), e) for e in bse],
-        }
 
     # ------------------------------------------------------------------
     # state init
@@ -822,13 +740,6 @@ class DeepSpeedEngine:
                 comm=self._comm_plan())
         else:
             opt_state = self.optimizer.init(params)
-            pf_err = self._prefetch_error_states(params)
-            if pf_err:
-                # the hierarchical prefetch stream's error feedback rides
-                # opt_state (checkpointed + reconciled like the 1-bit
-                # worker/server errors); the train fn pops these around
-                # opt.step, which only knows its own fields
-                opt_state = dict(opt_state, **pf_err)
         scaler = prec.init_scaler_state(self.precision)
         state = TrainState(params=params, opt_state=opt_state, scaler=scaler,
                            global_step=jnp.zeros((), jnp.int32),
@@ -863,11 +774,11 @@ class DeepSpeedEngine:
     def _param_swap_order(self):
         """The per-layer swap schedule: the order param leaves stream
         disk→host→device at unpark, derived from the partitioner's
-        layer-stacked prefixes (the stage3_prefetch layer contract).
+        layer-stacked prefixes (the model's ``layer_stacked_subtree``).
         First-consumed leaves first — outer (embedding-side) leaves, then
-        the stacked transformer blocks the in-jit prefetch pipeline
-        slices layer by layer — so the device assembles inputs in compute
-        order while later groups are still on disk. Pure metadata: any
+        the stacked transformer blocks the layer scan slices layer by
+        layer — so the device assembles inputs in compute order while
+        later groups are still on disk. Pure metadata: any
         permutation is correct; this one pipelines best."""
         order = getattr(self, "_param_swap_order_cache", None)
         if order is not None and len(order) == len(
@@ -876,10 +787,6 @@ class DeepSpeedEngine:
         flat, _ = jax.tree_util.tree_flatten_with_path(
             self.state_shardings.params)
         stacked = set(self.zero.layer_stacked_prefixes or ())
-        if not stacked:
-            sub = getattr(self.module, "prefetch_layer_subtree", None)
-            if sub:
-                stacked = {sub}
 
         def head(path):
             if not path:
@@ -1651,16 +1558,8 @@ class DeepSpeedEngine:
             self._jit_train_batch = self._build_compressed_train_fn(loss_fn)
         elif self._sparse_grad_active():
             self._jit_train_batch = self._build_sparse_train_fn(loss_fn)
-        elif self._prefetch_active():
-            self._jit_train_batch = self._build_prefetch_train_fn()
         elif self._overlap_comm_active():
             self._jit_train_batch = self._build_overlap_train_fn(loss_fn)
-        if not self._prefetch_active():
-            # the live-gathered registry describes the most recently
-            # BUILT train path; a non-prefetch engine must not inherit
-            # a previous engine's prefetch window in see_memory_usage
-            from deepspeed_tpu.utils import memory as memory_lib
-            memory_lib.record_live_gathered_param_bytes(None)
 
         try:
             accepts_det = "deterministic" in inspect.signature(
@@ -1894,9 +1793,8 @@ class DeepSpeedEngine:
         if self.zero_optimization_stage() >= 3:
             log_dist("overlap_comm supports ZeRO stages 0-2 (stage 3 "
                      "shards params at rest, which the explicit path does "
-                     "not re-gather) — for the stage-3 explicit path set "
-                     "zero_optimization.stage3_prefetch; falling back to "
-                     "the fused GSPMD exchange", ranks=[0])
+                     "not re-gather); falling back to the fused GSPMD "
+                     "exchange", ranks=[0])
             return False
         if not getattr(self.optimizer, "elementwise_update", False):
             log_dist(f"overlap_comm needs an elementwise optimizer "
@@ -2026,633 +1924,6 @@ class DeepSpeedEngine:
             return inner(state, batch, rng)
 
         return self._jit_explicit_comm(train_fn)
-
-    def _prefetch_active(self):
-        """True when the train step should run the ZeRO-3 layer-wise
-        parameter-gather prefetch pipeline (parallel/prefetch.py): the
-        explicit-comm stage-3 train path that all-gathers each layer's
-        param shards ONE LAYER AHEAD of use (double-buffered, forward
-        and backward) and reduce-scatters each layer's param grads
-        inside the backward scan — the reference's
-        PartitionedParameterCoordinator prefetch (stage3.py:287-447)
-        made structural. Requires a multi-device pure-DP data axis, an
-        elementwise optimizer, and a model exposing the layered-apply
-        contract (prefetch_apply + prefetch_layer_subtree)."""
-        cached = getattr(self, "_prefetch_cached", None)
-        if cached is None:
-            cached = self._prefetch_cached = self._compute_prefetch()
-        return cached
-
-    def _compute_prefetch(self):
-        zc = self._config.zero_config
-        if not zc.stage3_prefetch:
-            return False
-        if self._offload_cfg.enabled or self._param_offload_host:
-            # the NVMe param tier COMPOSES (its swap schedule streams
-            # disk→host→device before the step; the in-jit pipeline then
-            # gathers layer by layer) — but the optimizer-offload and
-            # pinned-host tiers run the step off-device/off-schedule
-            log_dist("stage3_prefetch: optimizer/pinned-host offload "
-                     "tiers stream state through host memory on their own "
-                     "schedule; falling back to the fused GSPMD stage-3 "
-                     "exchange", ranks=[0])
-            return False
-        if self._compressed_comm_active() or self._sparse_grad_active():
-            return False
-        if mesh_lib.mesh_axis_size(self.mesh, mesh_lib.DATA_AXIS) <= 1:
-            log_dist("stage3_prefetch: single-device data axis — nothing "
-                     "is sharded, the fused path is the whole program",
-                     ranks=[0])
-            return False
-        if not self._pure_dp_mesh():
-            log_dist("stage3_prefetch: non-data mesh axes are live — the "
-                     "prefetch pipeline shard_maps the data axis only; "
-                     "falling back to the fused GSPMD exchange", ranks=[0])
-            return False
-        sub = getattr(self.module, "prefetch_layer_subtree", None)
-        if not sub or not hasattr(self.module, "prefetch_apply"):
-            log_dist(f"stage3_prefetch: {type(self.module).__name__} does "
-                     f"not expose the layered-apply contract "
-                     f"(prefetch_apply + a non-None prefetch_layer_subtree "
-                     f"— scanned layers, no MoE, no dropout); falling back "
-                     f"to the fused GSPMD exchange", ranks=[0])
-            return False
-        if self._loss_fn_user is not None:
-            log_dist("stage3_prefetch: a custom loss_fn drives model.apply "
-                     "itself, which the layered pipeline cannot intercept; "
-                     "falling back to the fused GSPMD exchange", ranks=[0])
-            return False
-        if not getattr(self.optimizer, "elementwise_update", False):
-            log_dist(f"stage3_prefetch needs an elementwise optimizer "
-                     f"(Adam/AdamW/SGD) — the per-shard ZeRO-3 update "
-                     f"slices tensors, which breaks per-tensor statistics "
-                     f"of {type(self.optimizer).__name__}; falling back to "
-                     f"the fused GSPMD exchange", ranks=[0])
-            return False
-        return True
-
-    def prefetch_live_param_stats(self):
-        """Static live-parameter accounting of the prefetch pipeline
-        (populated when the stage3_prefetch train path is built): peak
-        gathered-full-parameter elements/bytes — two layers (current +
-        in-flight) plus the step-persistent outer gathers — the
-        observable behind ``stage3_max_live_parameters``. None when the
-        prefetch path is not active/built."""
-        return getattr(self, "_prefetch_stats", None)
-
-    def _build_prefetch_train_fn(self):
-        """shard_map train step for ZeRO-3 with layer-wise gather
-        prefetch: params/moments stay SHARDED through the whole step
-        (in_specs = out_specs = the stage-3 resting specs — no
-        gather-at-entry, no re-shard at exit). The forward/backward run
-        through parallel/prefetch.make_prefetched_scan (double-buffered
-        per-layer gathers; backward interleaves each layer's re-gather
-        with its grad reduce-scatter); outer leaves (embeddings, final
-        LN, head) gather once per step via gathered-param custom VJPs;
-        below-threshold replicated leaves exchange through the PR-1
-        bucketed allreduce (overlap_comm's machinery) — composing both
-        explicit schedulers in one program.
-
-        With ``comm.hierarchy`` resolved (ISSUE 16) the program
-        shard_maps the data-axis-split view of the same mesh and every
-        stage-3 exchange runs the two-level link-aware schedule: packed
-        per-layer gathers and grad reduce-scatters take ONE inter-host
-        hop per chunk (fp32 partial sums stay on the fast links), the
-        per-bucket policy compresses the slow grad hops to
-        error-compensated sign bits, and the persistent residuals
-        thread through the step as ``pf_*`` opt_state (see
-        `_prefetch_error_states`)."""
-        from deepspeed_tpu.parallel import overlap as overlap_lib
-        from deepspeed_tpu.parallel import prefetch as prefetch_lib
-        cfg = self._config
-        zc = cfg.zero_config
-        n = mesh_lib.mesh_axis_size(self.mesh, mesh_lib.DATA_AXIS)
-        hplan = self._prefetch_hier_plan()
-        if hplan is not None:
-            # metadata-only reshard: same devices, the data axis viewed
-            # as (inter, intra) so the two-level collectives can bind
-            # each level by name
-            mesh = mesh_lib.split_data_axis(self.mesh, hplan.inter)
-            axis = hplan.axes
-        else:
-            mesh = self.mesh
-            axis = mesh_lib.DATA_AXIS
-        lr_fn = self._lr_fn()
-        opt = self.optimizer
-        precision = self.precision
-        model = self.module
-        subtree = model.prefetch_layer_subtree
-        mode = zc.stage3_prefetch_gather
-        cast_bf16 = cfg.grad_dtype == "bf16"
-        bucket_elems = int(zc.prefetch_bucket_size)
-        spec_like = lambda tree, s: jax.tree_util.tree_map(  # noqa: E731
-            lambda _: s, tree)
-        tm = jax.tree_util.tree_map
-
-        params = self.state.params
-        param_spec_tree = self.zero.param_specs(params)
-        full_plan = self.zero.explicit_shard_plan(params,
-                                                  specs=param_spec_tree)
-        layer_plan = self.zero.explicit_shard_plan(
-            params[subtree], specs=param_spec_tree[subtree])
-        outer_keys = [k for k in params if k != subtree]
-        outer_plans = {k: self.zero.explicit_shard_plan(
-            params[k], specs=param_spec_tree[k]) for k in outer_keys}
-
-        fused_ids, fused_cfg = self._select_fused_matmul_leaves(
-            params[subtree], layer_plan, mode, n, axis, cast_bf16)
-
-        self._record_prefetch_stats(params, subtree, layer_plan,
-                                    outer_plans, cast_bf16,
-                                    fused_ids=fused_ids)
-
-        if hplan is not None:
-            # shard_map specs on the split mesh spell the data axis as
-            # the (inter, intra) pair; the device layout is unchanged
-            def _resplit_spec(s):
-                return PartitionSpec(*(
-                    (hplan.inter_axis, hplan.intra_axis)
-                    if p == mesh_lib.DATA_AXIS else p
-                    for p in tuple(s)))
-            sm_param_specs = tm(_resplit_spec, param_spec_tree)
-            self._install_prefetch_wire_model(hplan, params, fused_ids,
-                                              cast_bf16)
-        else:
-            sm_param_specs = param_spec_tree
-
-        def gather_outer(p, oerrs=None):
-            out = {}
-            with annotate("ds_prefetch_outer_gather"):
-                for k in outer_keys:
-                    leaves, tdef = jax.tree_util.tree_flatten(p[k])
-                    errs_k = oerrs[k] if oerrs is not None else \
-                        [None] * len(leaves)
-                    gathered = []
-                    for x, e, er in zip(leaves, outer_plans[k], errs_k):
-                        if e is None:
-                            gathered.append(x)
-                        elif er is not None:
-                            # compressed slow-hop RS in the backward;
-                            # the new residual returns as er's cotangent
-                            gathered.append(
-                                prefetch_lib.make_gathered_param_with_error(
-                                    e, axis, n, mode, hplan)(x, er))
-                        else:
-                            gathered.append(
-                                prefetch_lib.make_gathered_param(
-                                    e, axis, n, mode, hier=hplan)(x))
-                    out[k] = jax.tree_util.tree_unflatten(tdef, gathered)
-            return out
-
-        def micro_loss(p_view, micro, keep_prob, gerrs=None):
-            # the model builds the per-layer body (it closes over
-            # keep_prob) and hands it in through the layer_scan hook
-            def run_layers(body, x, h_shards):
-                fn = prefetch_lib.make_prefetched_scan(
-                    body, layer_plan, axis, n, mode=mode,
-                    fused_ids=fused_ids, fused_cfg=fused_cfg, hier=hplan)
-                return fn(x, h_shards) if hplan is None \
-                    else fn(x, h_shards, gerrs)
-            if isinstance(micro, dict) and "input_ids" in micro:
-                ids = micro["input_ids"]
-                labels = micro.get("labels", micro["input_ids"])
-            else:
-                ids = micro
-                labels = micro
-            return model.prefetch_apply(p_view, ids, run_layers,
-                                        deterministic=True,
-                                        keep_prob=keep_prob, labels=labels)
-
-        gas = self.gradient_accumulation_steps()
-        keep_fn = self._keep_prob_fn()
-
-        def cast_params(p):
-            if not cast_bf16:
-                return p
-            return tm(lambda x: x.astype(jnp.bfloat16)
-                      if x.dtype == jnp.float32 else x, p)
-
-        def accumulate(state, batch, rng, perr=None):
-            """Prefetch-path twin of _local_grad_accumulator. Dropout
-            is gated off, so no per-micro rng plumbing; grads come back
-            fp32 (sharded leaves as SUMS over the axis), loss locally
-            averaged. ``perr`` (hierarchical path) carries the
-            compressed slow hops' persistent residuals
-            ({"groups": ..., "outer": ...}); the updated state returns
-            as the third result — read back through ``jax.grad`` extra
-            argnums, since the exchanges live inside custom VJPs.
-
-            gas == 1 differentiates straight through the gather custom
-            VJPs. gas > 1 hoists the OUTER gathers above the microbatch
-            scan — wte/wpe/head gather once per STEP — and runs the
-            per-micro ``jax.grad`` with the gathered view as an
-            EXPLICIT argument (grad-inside-scan: a custom-VJP call on a
-            tracer closed over INTO a differentiated scan would need
-            the unsupported custom_vjp transpose). Outer cotangents
-            accumulate in gathered space and reduce-scatter ONCE at the
-            end; only the per-layer pipeline (whose per-micro exchange
-            is the point) communicates inside the scan — group
-            residuals therefore thread through the microbatch carry,
-            outer residuals update once at the final reduce-scatter."""
-            del rng
-            scale = state.scaler["loss_scale"]
-            keep_prob = keep_fn(state.global_step)
-
-            if gas == 1:
-                if hplan is None:
-                    def total(p_shard):
-                        p = cast_params(p_shard)
-                        p_view = gather_outer(p)
-                        p_view[subtree] = p[subtree]
-                        loss = micro_loss(p_view, batch, keep_prob)
-                        return (loss * scale).astype(jnp.float32), loss
-                    grads, loss = jax.grad(total, has_aux=True)(
-                        state.params)
-                    return (tm(lambda g: g.astype(jnp.float32), grads),
-                            loss, None)
-
-                def total(p_shard, pe):
-                    p = cast_params(p_shard)
-                    p_view = gather_outer(p, pe["outer"])
-                    p_view[subtree] = p[subtree]
-                    loss = micro_loss(p_view, batch, keep_prob,
-                                      pe["groups"])
-                    return (loss * scale).astype(jnp.float32), loss
-                (grads, new_perr), loss = jax.grad(
-                    total, argnums=(0, 1), has_aux=True)(state.params,
-                                                         perr)
-                return (tm(lambda g: g.astype(jnp.float32), grads),
-                        loss, new_perr)
-
-            p = cast_params(state.params)
-            outer_view = {}
-            for k in outer_keys:
-                leaves, tdef = jax.tree_util.tree_flatten(p[k])
-                outer_view[k] = jax.tree_util.tree_unflatten(tdef, [
-                    prefetch_lib.gather_leaf(x, e, axis, n, mode,
-                                             hier=hplan)
-                    for x, e in zip(leaves, outer_plans[k])])
-            h_shards = p[subtree]
-
-            def micro_grads(view, hs, ge, micro):
-                def f(v, h, e):
-                    pv = dict(v)
-                    pv[subtree] = h
-                    loss = micro_loss(pv, micro, keep_prob, e)
-                    return (loss * scale).astype(jnp.float32), loss
-                if hplan is None:
-                    (gv, gh), loss = jax.grad(
-                        f, argnums=(0, 1), has_aux=True)(view, hs, ge)
-                    return (gv, gh, ge), loss
-                return jax.grad(f, argnums=(0, 1, 2), has_aux=True)(
-                    view, hs, ge)
-
-            chunked = tm(lambda x: x.reshape(
-                (gas, x.shape[0] // gas) + x.shape[1:]), batch)
-
-            def body(acc, micro):
-                acc_v, acc_h, acc_l, ge = acc
-                (gv, gh, ge2), loss = micro_grads(outer_view, h_shards,
-                                                  ge, micro)
-                add = lambda a, g: a + g.astype(jnp.float32) / gas  # noqa: E731
-                return (tm(add, acc_v, gv), tm(add, acc_h, gh),
-                        acc_l + loss / gas, ge2), None
-
-            zeros = lambda t: tm(  # noqa: E731
-                lambda x: jnp.zeros(x.shape, jnp.float32), t)
-            ge0 = perr["groups"] if hplan is not None else ()
-            (g_view, g_h, loss, ge_fin), _ = jax.lax.scan(
-                body, (zeros(outer_view), zeros(h_shards),
-                       jnp.float32(0.0), ge0), chunked)
-
-            # manual outer backward: the accumulated gathered-space
-            # cotangents reduce-scatter once (SUM over the axis, like
-            # the gas==1 custom-VJP path); replicated leaves stay local
-            grads = {subtree: g_h}
-            new_oerrs = {}
-            for k in outer_keys:
-                leaves, tdef = jax.tree_util.tree_flatten(g_view[k])
-                errs_k = perr["outer"][k] if hplan is not None else \
-                    [None] * len(leaves)
-                outs, ne = [], []
-                for x, e, er in zip(leaves, outer_plans[k], errs_k):
-                    if e is not None and er is not None:
-                        piece, er2 = prefetch_lib.scatter_grad_with_error(
-                            x, e, n, er, hplan)
-                        outs.append(piece)
-                        ne.append(er2)
-                    else:
-                        outs.append(prefetch_lib.scatter_grad(
-                            x, e, axis, n, mode, hier=hplan))
-                        ne.append(er)
-                grads[k] = jax.tree_util.tree_unflatten(tdef, outs)
-                new_oerrs[k] = ne
-            new_perr = {"groups": ge_fin, "outer": new_oerrs} \
-                if hplan is not None else None
-            return grads, loss, new_perr
-
-        opt_specs = {
-            k: sm_param_specs
-            if k in getattr(opt, "param_like_state_fields", ())
-            else spec_like(v, PartitionSpec(axis))
-            if k in self._PF_ERR_KEYS
-            else spec_like(v, PartitionSpec())
-            for k, v in self.state.opt_state.items()}
-        state_specs = TrainState(
-            params=sm_param_specs,
-            opt_state=opt_specs,
-            scaler=spec_like(self.state.scaler, PartitionSpec()),
-            global_step=PartitionSpec(),
-            skipped_steps=PartitionSpec())
-        takes_gscale = "grad_scale" in inspect.signature(opt.step).parameters
-        inv_n = np.float32(1.0 / n)
-
-        def train_fn(state, batch, rng):
-            batch_specs = spec_like(batch, PartitionSpec(axis))
-
-            @functools.partial(
-                jax.shard_map, mesh=mesh,
-                in_specs=(state_specs, batch_specs, PartitionSpec()),
-                out_specs=(state_specs, spec_like(
-                    {"loss": 0, "grad_norm": 0, "lr": 0, "overflow": 0,
-                     "loss_scale": 0}, PartitionSpec())),
-                check_vma=False)
-            def inner(state, batch, rng):
-                opt_local = dict(state.opt_state)
-                if hplan is not None:
-                    # per-device residuals: slice the leading [dp] copy
-                    # (re-wrapped [None] below — the 1-bit pattern)
-                    slice0 = lambda t: tm(lambda x: x[0], t)  # noqa: E731
-                    perr = {
-                        "groups": tuple(
-                            slice0(e)
-                            for e in opt_local.pop("pf_group_we")),
-                        "outer": {k: [slice0(e) for e in v]
-                                  for k, v in
-                                  opt_local.pop("pf_outer_we").items()}}
-                    bwe = [slice0(e) for e in opt_local.pop("pf_bucket_we")]
-                    bse = [slice0(e) for e in opt_local.pop("pf_bucket_se")]
-                else:
-                    perr = None
-                with annotate("ds_fwd_bwd_prefetch"):
-                    grads, loss, new_perr = accumulate(state, batch, rng,
-                                                       perr)
-                loss = jax.lax.pmean(loss, axis)
-                # sharded-leaf grads came back reduce-scattered as SUMS
-                # over the axis (the custom VJPs); scale to the mean.
-                # Replicated (below-threshold) leaves are LOCAL — they
-                # mean-exchange through the PR-1 bucket stream (under
-                # the hierarchy: the two-level policy-compressed bucket
-                # exchange with its own persistent error feedback).
-                g_leaves, g_tdef = jax.tree_util.tree_flatten(grads)
-                g_leaves = [g * inv_n if e is not None else g
-                            for g, e in zip(g_leaves, full_plan)]
-                repl_ids = [i for i, e in enumerate(full_plan)
-                            if e is None]
-                if repl_ids:
-                    with annotate("ds_overlap_bucket_sync"):
-                        if hplan is not None:
-                            red, bwe, bse = overlap_lib.\
-                                bucketed_hierarchical_compressed_allreduce(
-                                    [g_leaves[i] for i in repl_ids],
-                                    bwe, bse, hplan)
-                        else:
-                            red = overlap_lib.bucketed_allreduce(
-                                [g_leaves[i] for i in repl_ids], axis, n,
-                                bucket_elems, mode=mode, mean=True)
-                    for i, g in zip(repl_ids, red):
-                        g_leaves[i] = g
-                grads = jax.tree_util.tree_unflatten(g_tdef, g_leaves)
-
-                scale = state.scaler["loss_scale"]
-                inv = 1.0 / scale
-                local_finite = prec.grads_finite(grads) if precision.fp16 \
-                    else jnp.asarray(True)
-                finite = jax.lax.pmin(
-                    local_finite.astype(jnp.int32), axis) > 0
-                # exact global norm: sharded leaves partition the full
-                # tensor across the axis (psum of shard norms covers each
-                # element once); replicated grads are identical everywhere
-                shard_sq = sum(
-                    jnp.sum(jnp.square(g.astype(jnp.float32)))
-                    for g, e in zip(g_leaves, full_plan) if e is not None)
-                repl_sq = sum(
-                    jnp.sum(jnp.square(g_leaves[i].astype(jnp.float32)))
-                    for i in repl_ids)
-                grad_norm = jnp.sqrt(
-                    jax.lax.psum(jnp.float32(shard_sq), axis)
-                    + jnp.float32(repl_sq))
-                gscale = inv
-                if cfg.gradient_clipping and cfg.gradient_clipping > 0:
-                    gscale = inv * jnp.minimum(
-                        1.0, cfg.gradient_clipping /
-                        (grad_norm * inv + 1e-6))
-                lr = lr_fn(state.global_step)
-
-                # ZeRO-3 update runs entirely on local shards: params and
-                # moments already rest in the shard layout — no slicing,
-                # no post-update gather (params stay sharded at rest)
-                with annotate("ds_optimizer"):
-                    if takes_gscale:
-                        new_params, new_opt = opt.step(
-                            state.params, grads, opt_local, lr,
-                            grad_scale=gscale)
-                    else:
-                        grads = tm(lambda g: g * gscale, grads)
-                        new_params, new_opt = opt.step(state.params, grads,
-                                                       opt_local, lr)
-                if hplan is not None:
-                    # re-attach the updated residuals (opt.step only
-                    # keeps its own fields); an overflow step reverts
-                    # them with the rest of the state in
-                    # _finish_explicit_state — the discarded grads'
-                    # compression error must not compensate a future
-                    # exchange
-                    bump = lambda t: tm(lambda x: x[None], t)  # noqa: E731
-                    new_opt = dict(new_opt)
-                    new_opt["pf_group_we"] = [bump(e) for e in
-                                              new_perr["groups"]]
-                    new_opt["pf_outer_we"] = {
-                        k: [bump(e) for e in v]
-                        for k, v in new_perr["outer"].items()}
-                    new_opt["pf_bucket_we"] = [bump(e) for e in bwe]
-                    new_opt["pf_bucket_se"] = [bump(e) for e in bse]
-                new_state = self._finish_explicit_state(
-                    state, new_params, new_opt, finite, precision)
-                return new_state, {
-                    "loss": loss, "grad_norm": grad_norm * inv, "lr": lr,
-                    "overflow": ~finite,
-                    "loss_scale": new_state.scaler["loss_scale"]}
-
-            return inner(state, batch, rng)
-
-        return self._jit_explicit_comm(train_fn)
-
-    def _select_fused_matmul_leaves(self, layer_subtree, layer_plan,
-                                    mode, n, axis, cast_bf16):
-        """Which layer-stacked leaves stream through the tile-granular
-        fused matmul+collective kernels (ISSUE 8) when
-        ``stage3_prefetch_gather: fused_matmul``: the dominant 2D
-        projection kernels — sharded, per-layer matrices named
-        ``kernel``, shard at least ``collective_matmul.min_shard_bytes``
-        — consumed by the model's CollectiveDense layers as resting
-        shards. Everything else (biases, LN scales, below-threshold
-        weights) keeps the packed per-layer ring gather. Returns
-        ``(fused_ids, CollectiveMatmulConfig)`` — ``((), None)`` in
-        other gather modes or when the pipeline must fall back, with
-        ``_prefetch_active``-style logging of the reason."""
-        if mode != "fused_matmul":
-            return (), None
-        from deepspeed_tpu.ops.pallas import fused_collective as fc
-        from deepspeed_tpu.telemetry.registry import default_registry
-        zc = self._config.zero_config
-        if not getattr(self.module, "supports_collective_matmul", False):
-            log_dist(
-                f"stage3_prefetch_gather=fused_matmul: "
-                f"{type(self.module).__name__} does not mark "
-                f"supports_collective_matmul (its dense layers would "
-                f"reject shard-shaped kernels); falling back to the "
-                f"ring gather", ranks=[0])
-            return (), None
-        # the per-leaf contract: only leaves the model DECLARES as
-        # CollectiveDense-consumed may receive shards — a 3D "kernel"
-        # under a plain nn.Dense would trip flax's declared-param shape
-        # check at trace time with an opaque error
-        cm_paths = tuple(getattr(self.module, "collective_matmul_paths",
-                                 ()))
-        if not cm_paths:
-            log_dist(
-                f"stage3_prefetch_gather=fused_matmul: "
-                f"{type(self.module).__name__} declares no "
-                f"collective_matmul_paths; falling back to the ring "
-                f"gather", ranks=[0])
-            return (), None
-        min_bytes = int(zc.collective_matmul_min_shard_bytes)
-        flat, _ = jax.tree_util.tree_flatten_with_path(layer_subtree)
-        fused, skipped_small, skipped_shape = [], 0, 0
-        for i, ((path, leaf), e) in enumerate(zip(flat, layer_plan)):
-            if e is None:
-                continue
-            name = getattr(path[-1], "key", None)
-            joined = "/".join(str(getattr(k, "key", k)) for k in path)
-            if leaf.ndim != 3 or name != "kernel" or \
-                    not any(joined == p or joined.endswith("/" + p)
-                            for p in cm_paths):
-                skipped_shape += 1
-                continue
-            itemsize = 2 if (cast_bf16 and leaf.dtype == jnp.float32) \
-                else jnp.dtype(leaf.dtype).itemsize
-            shard_bytes = int(np.prod(leaf.shape[1:])) // n * itemsize
-            if shard_bytes < min_bytes:
-                skipped_small += 1
-                continue
-            fused.append(i)
-        reg = default_registry()
-        reg.gauge("comm/zero3_prefetch/fused_leaves").set(len(fused))
-        reg.gauge("comm/zero3_prefetch/ring_leaves").set(
-            skipped_shape + skipped_small)
-        if not fused:
-            log_dist(
-                f"stage3_prefetch_gather=fused_matmul: no layer leaf "
-                f"qualifies for fused streaming ({skipped_small} sharded "
-                f"kernels below min_shard_bytes={min_bytes}, "
-                f"{skipped_shape} non-2D/non-kernel leaves); the gather "
-                f"behaves as ring", ranks=[0])
-            return (), None
-        log_dist(
-            f"stage3_prefetch_gather=fused_matmul: {len(fused)} "
-            f"projection kernels/layer stream through fused "
-            f"all-gather+matmul / matmul+reduce-scatter "
-            f"(backend={zc.collective_matmul_backend}, "
-            f"tile_m={zc.collective_matmul_tile_m}); {skipped_small} "
-            f"below-threshold + {skipped_shape} non-matrix leaves ride "
-            f"the packed ring gather", ranks=[0])
-        hier = self._prefetch_hier_plan()
-        cfg = fc.CollectiveMatmulConfig(
-            axis_name=axis, axis_size=n,
-            backend=zc.collective_matmul_backend,
-            tile_m=zc.collective_matmul_tile_m,
-            min_shard_bytes=min_bytes,
-            vmem_budget_bytes=zc.collective_matmul_vmem_budget_bytes,
-            hierarchy=fc.RingHierarchy(
-                inter_axis=hier.inter_axis, intra_axis=hier.intra_axis,
-                inter=hier.inter, intra=hier.intra)
-            if hier is not None else None)
-        return tuple(fused), cfg
-
-    def _record_prefetch_stats(self, params, subtree, layer_plan,
-                               outer_plans, cast_bf16, fused_ids=()):
-        """Static live-gathered-parameter accounting (the
-        ``stage3_max_live_parameters`` observable, utils/memory.py)."""
-        from deepspeed_tpu.utils import memory as memory_lib
-
-        def leaf_bytes_per_el(leaf):
-            return 2 if (cast_bf16 and leaf.dtype == jnp.float32) \
-                else jnp.dtype(leaf.dtype).itemsize
-
-        layer_leaves = jax.tree_util.tree_leaves(params[subtree])
-        per_layer_elems = per_layer_bytes = 0
-        fused_stream_elems = fused_stream_bytes = 0
-        persistent_elems = persistent_bytes = 0
-        n_ring = mesh_lib.mesh_axis_size(self.mesh, mesh_lib.DATA_AXIS)
-        for i, (leaf, e) in enumerate(zip(layer_leaves, layer_plan)):
-            full = int(np.prod(leaf.shape[1:] or (1,)))
-            if e is None:
-                # below-persistence-threshold stacked leaves stay FULLY
-                # replicated (all layers resident) — persistent share
-                persistent_elems += full * leaf.shape[0]
-                persistent_bytes += full * leaf.shape[0] * \
-                    leaf_bytes_per_el(leaf)
-                continue
-            if i in fused_ids:
-                # fused-streamed weights are never materialized full:
-                # live footprint is ~2 ring chunks (current + in-flight)
-                fused_stream_elems += 2 * (full // max(n_ring, 1))
-                fused_stream_bytes += 2 * (full // max(n_ring, 1)) * \
-                    leaf_bytes_per_el(leaf)
-                continue
-            per_layer_elems += full
-            per_layer_bytes += full * leaf_bytes_per_el(leaf)
-        outer_elems = outer_bytes = 0
-        for k, plan in outer_plans.items():
-            for leaf, e in zip(jax.tree_util.tree_leaves(params[k]), plan):
-                full = int(np.prod(leaf.shape or (1,)))
-                if e is None:
-                    persistent_elems += full
-                    persistent_bytes += full * leaf_bytes_per_el(leaf)
-                    continue
-                outer_elems += full
-                outer_bytes += full * leaf_bytes_per_el(leaf)
-        n_layers = layer_leaves[0].shape[0] if layer_leaves else 0
-        stats = {
-            # double buffer (computing layer + in-flight gather) + the
-            # step-persistent full leaves: outer gathers AND replicated
-            # below-threshold leaves (always resident) — the full live
-            # window stage3_max_live_parameters governs. Fused-streamed
-            # weights (ISSUE 8) count only their ~2 live ring chunks —
-            # in BOTH the element and byte totals.
-            "live_param_elements": 2 * per_layer_elems + outer_elems
-            + persistent_elems + fused_stream_elems,
-            "live_param_bytes": 2 * per_layer_bytes + outer_bytes
-            + persistent_bytes + fused_stream_bytes,
-            "per_layer_gather_bytes": per_layer_bytes,
-            "fused_stream_bytes": fused_stream_bytes,
-            "fused_leaves_per_layer": len(fused_ids),
-            "outer_gather_bytes": outer_bytes,
-            "persistent_replicated_bytes": persistent_bytes,
-            "layers": int(n_layers),
-        }
-        self._prefetch_stats = stats
-        memory_lib.record_live_gathered_param_bytes(
-            stats["live_param_bytes"])
-        max_live = int(self._config.zero_config.max_live_parameters)
-        if max_live and stats["live_param_elements"] > max_live:
-            logger.warning(
-                f"stage3_prefetch: the 2-layer double buffer holds "
-                f"{stats['live_param_elements']} full-parameter elements "
-                f"live, above stage3_max_live_parameters={max_live}; the "
-                f"pipeline depth is structural (one layer ahead) — raise "
-                f"the knob or shrink the layer")
 
     def _sparse_grad_active(self):
         """True when the train step should exchange embedding gradients
@@ -2928,8 +2199,7 @@ class DeepSpeedEngine:
                 elif self.wall_clock_breakdown() and not (
                         self._compressed_comm_active()
                         or self._sparse_grad_active()
-                        or self._overlap_comm_active()
-                        or self._prefetch_active()):
+                        or self._overlap_comm_active()):
                     # (1-bit / CSR / overlap paths keep their fused
                     # shard_map programs — their comm scheduling lives
                     # inside the step and cannot be split into phase
@@ -3485,115 +2755,6 @@ class DeepSpeedEngine:
         }
         self.comm_hierarchy = plan
 
-    def _install_prefetch_wire_model(self, plan, params, fused_ids,
-                                     cast_bf16):
-        """Trace-time per-device, per-step bytes-on-wire model for the
-        hierarchical stage-3 prefetch stream (ISSUE 16) — single phase
-        (the stream has no warmup). Sums the four legs of one step:
-        packed per-layer group gathers (forward + backward re-gather)
-        and grad reduce-scatters, the step-persistent outer exchanges,
-        the fused collective-matmul streams, and the replicated-leaf
-        bucket leg. ``inter_uncompressed`` here is the slow-link bytes
-        the FLAT single-ring schedule would have paid for the same
-        exchanges (ni of the n ring edges cross hosts) — the honest
-        reduction denominator for this stream, unlike the 1-bit model
-        whose denominator is the same two-level schedule uncompressed
-        (see docs/observability.md)."""
-        from deepspeed_tpu.parallel import overlap
-        from deepspeed_tpu.parallel import prefetch as prefetch_lib
-        subtree = self.module.prefetch_layer_subtree
-        n = plan.world
-        gas = self.gradient_accumulation_steps()
-        param_spec_tree = self.zero.param_specs(params)
-        layer_plan = self.zero.explicit_shard_plan(
-            params[subtree], specs=param_spec_tree[subtree])
-        full_plan = self.zero.explicit_shard_plan(params,
-                                                  specs=param_spec_tree)
-        intra = inter = flat_inter = 0
-
-        def add(w, times=1):
-            nonlocal intra, inter, flat_inter
-            intra += times * w["intra"]
-            inter += times * w["inter"]
-            flat_inter += times * w["flat_inter"]
-
-        def isz(dt):
-            return 2 if (cast_bf16 and jnp.dtype(dt) == jnp.float32) \
-                else jnp.dtype(dt).itemsize
-
-        # per-layer packed dtype groups: 2(L+1) gathers (forward +
-        # backward, each with one redundant edge gather) and L grad RS
-        # per group per microbatch
-        stacked = jax.tree_util.tree_leaves(params[subtree])
-        fused = set(fused_ids)
-        groups = {}
-        for i, (leaf, entry) in enumerate(zip(stacked, layer_plan)):
-            if entry is None or i in fused:
-                continue
-            groups.setdefault(jnp.dtype(leaf.dtype), []).append(i)
-        gerrs = prefetch_lib.plan_group_errors(stacked, layer_plan, n,
-                                               fused_ids, plan)
-        L = int(stacked[0].shape[0]) if stacked else 0
-        for (dt, ids), err in zip(groups.items(), gerrs):
-            m = sum(int(np.prod(stacked[i].shape[1:])) // n for i in ids)
-            add(overlap.two_level_gather_wire_bytes(m * isz(dt), plan),
-                times=gas * 2 * (L + 1))
-            add(overlap.two_level_rs_wire_bytes(m * 4, plan,
-                                                err is not None),
-                times=gas * L)
-        # fused collective-matmul leaves: per layer per microbatch, two
-        # all-gather+matmul streams (forward + dx) and one exact
-        # matmul+reduce-scatter (dw)
-        for i in fused_ids:
-            leaf = stacked[i]
-            m = int(np.prod(leaf.shape[1:])) // n
-            add(overlap.two_level_gather_wire_bytes(
-                m * isz(leaf.dtype), plan), times=gas * 2 * L)
-            add(overlap.two_level_rs_wire_bytes(m * 4, plan, False),
-                times=gas * L)
-        # step-persistent outer leaves: one gather + one grad RS per step
-        # (gas > 1 hoists the gathers; gas == 1 is one microbatch)
-        for k in params:
-            if k == subtree:
-                continue
-            op = self.zero.explicit_shard_plan(params[k],
-                                               specs=param_spec_tree[k])
-            for leaf, e in zip(jax.tree_util.tree_leaves(params[k]), op):
-                if e is None:
-                    continue
-                m = leaf.size // n
-                add(overlap.two_level_gather_wire_bytes(
-                    m * isz(leaf.dtype), plan))
-                add(overlap.two_level_rs_wire_bytes(
-                    m * 4, plan, prefetch_lib.outer_compress(m, plan)))
-        # replicated-leaf bucket leg: the ISSUE-10 two-level exchange,
-        # once per step; flat baseline = ring allreduce (RS + AG)
-        repl_shapes = [l.shape for l, e in zip(
-            jax.tree_util.tree_leaves(params), full_plan) if e is None]
-        compressed_buckets = 0
-        if repl_shapes:
-            buckets = overlap.plan_buckets(repl_shapes, plan.bucket_elems,
-                                           n)
-            flags = overlap.plan_bucket_compression(buckets, plan)
-            compressed_buckets = int(sum(flags))
-            w = overlap.hierarchy_wire_bytes(buckets, flags, plan)
-            intra += w["intra"]
-            inter += w["inter"]
-            flat_inter += sum(2 * (n - 1) * (b.padded // n) * 4
-                              * plan.inter // n for b in buckets)
-        self._pf_wire_model = {
-            "intra": int(intra), "inter": int(inter),
-            "inter_uncompressed": int(flat_inter)}
-        self.flight_recorder.record(
-            "comm_hierarchy_plan", stream="zero3_prefetch",
-            groups=len(groups),
-            compressed=sum(1 for e in gerrs if e is not None)
-            + compressed_buckets,
-            inter=plan.inter, intra=plan.intra,
-            policy=plan.compression,
-            min_bucket_bytes=plan.min_bucket_bytes)
-        self.comm_hierarchy = plan
-
     def _comm_wire_step(self):
         """Per-step comm accounting for the compressed train paths: the
         onebit_freeze ring event at the warmup→compressed transition,
@@ -3605,29 +2766,20 @@ class DeepSpeedEngine:
         ``self.skipped_steps`` (the steps_per_print-boundary-synced
         mirror) corrects for them, so the mirror can misattribute at
         most the steps between an overflow and the next boundary.
-        The hierarchical stage-3 prefetch stream (ISSUE 16) advances
-        the same counters from its own single-phase model (no warmup
-        — the policy is static from step one).
         Returns the step's byte dict or None."""
-        if self._compressed_comm_active():
-            freeze = int(getattr(self.optimizer, "freeze_step", 0) or 0)
-            frozen = (self.global_steps - self.skipped_steps) > freeze
-            if frozen and not getattr(self, "_onebit_freeze_recorded",
-                                      False):
-                self._onebit_freeze_recorded = True
-                self.flight_recorder.record(
-                    "onebit_freeze", step=self.global_steps,
-                    freeze_step=freeze,
-                    hierarchical=getattr(self, "_comm_wire_model", None)
-                    is not None)
-            model = getattr(self, "_comm_wire_model", None)
-            if model is None:
-                return None
-            w = model["compressed" if frozen else "warmup"]
-        else:
-            w = getattr(self, "_pf_wire_model", None)
-            if w is None:
-                return None
+        if not self._compressed_comm_active():
+            return None
+        freeze = int(getattr(self.optimizer, "freeze_step", 0) or 0)
+        frozen = (self.global_steps - self.skipped_steps) > freeze
+        model = getattr(self, "_comm_wire_model", None)
+        if frozen and not getattr(self, "_onebit_freeze_recorded", False):
+            self._onebit_freeze_recorded = True
+            self.flight_recorder.record(
+                "onebit_freeze", step=self.global_steps,
+                freeze_step=freeze, hierarchical=model is not None)
+        if model is None:
+            return None
+        w = model["compressed" if frozen else "warmup"]
         reg = self.telemetry
         reg.counter("comm/bytes_on_wire/intra").inc(w["intra"])
         reg.counter("comm/bytes_on_wire/inter").inc(w["inter"])
@@ -3843,23 +2995,12 @@ class DeepSpeedEngine:
             reg.gauge("train/mfu").set(flops / step_s / peak)
 
     def _telemetry_memory_gauges(self):
-        """Satellite of the scalar stream: live-gathered-parameter bytes
-        of the stage3_prefetch pipeline (utils/memory.py — previously
-        only warned), the prefetch window breakdown, and host RSS."""
+        """Satellite of the scalar stream: host RSS and, where the
+        backend exposes it, per-device HBM (utils/memory.py)."""
         from deepspeed_tpu.utils import memory as memory_lib
         reg = self.telemetry
-        # host RSS, live-gathered window, per-device HBM where the
-        # backend exposes it — one canonical observable list
         for k, v in memory_lib.memory_metrics().items():
             reg.gauge(f"memory/{k}").set(v)
-        stats = self.prefetch_live_param_stats()
-        if stats:
-            reg.gauge("memory/prefetch_live_param_elements").set(
-                stats["live_param_elements"])
-            reg.gauge("memory/prefetch_per_layer_gather_bytes").set(
-                stats["per_layer_gather_bytes"])
-            reg.gauge("memory/prefetch_outer_gather_bytes").set(
-                stats["outer_gather_bytes"])
 
     def _telemetry_exporters(self):
         mc = self._config.monitor_config
@@ -4163,8 +3304,6 @@ class DeepSpeedEngine:
         the positions the train program's per-bucket zip expects."""
         if not isinstance(template.opt_state, dict):
             return template
-        if self._prefetch_active():
-            return self._restore_prefetch_error_state(template)
         plan = self._comm_plan()
         if plan is None:
             return self._restore_flat_error_trees(template)
@@ -4244,65 +3383,6 @@ class DeepSpeedEngine:
         opt_state["server_error"] = bump(se)
         return template.replace(opt_state=opt_state)
 
-    def _restore_prefetch_error_state(self, template: TrainState):
-        """Checkpoint reconciliation for the hierarchical prefetch
-        stream's ``pf_*`` residuals (ISSUE 16), riding the PR-10 rules:
-        the serializer digit-keys the per-group/per-bucket lists and
-        drops None entries, and the checkpoint may have been written
-        under a different hierarchy/compression policy — rebuild
-        canonical zero state for the CURRENT policy and keep only
-        shape-matching residuals (reset or drop the rest, warned)."""
-        canon = self._prefetch_error_states(template.params)
-        opt_state = dict(template.opt_state)
-        stale = [k for k in self._PF_ERR_KEYS
-                 if k in opt_state and k not in canon]
-        if stale:
-            logger.warning(
-                f"checkpoint carries prefetch error state {stale} but "
-                f"the engine runs the flat stage-3 stream — dropped")
-            for k in stale:
-                del opt_state[k]
-        if not canon:
-            return template.replace(opt_state=opt_state) if stale \
-                else template
-
-        def fit_list(key, zeros, loaded):
-            if isinstance(loaded, list):
-                return loaded      # live state kept as-is (keep_live_opt)
-            ld = loaded if isinstance(loaded, dict) and loaded \
-                and all(s.isdigit() for s in loaded) else {}
-            out = []
-            for i, z in enumerate(zeros):
-                lv = ld.get(str(i))
-                if z is None:
-                    if lv is not None:
-                        logger.warning(
-                            f"{key}[{i}]: slow hop is exact under the "
-                            f"current comm.hierarchy policy — "
-                            f"checkpointed residual dropped")
-                    out.append(None)
-                elif lv is not None and tuple(np.shape(lv)) == z.shape:
-                    out.append(lv)
-                else:
-                    if lv is not None:
-                        logger.warning(
-                            f"{key}[{i}]: checkpointed residual shape "
-                            f"{np.shape(lv)} does not match the current "
-                            f"plan ({z.shape}) — reset to zero")
-                    out.append(jnp.zeros(z.shape, z.dtype))
-            return out
-
-        def fit(key, zeros, loaded):
-            if isinstance(zeros, dict):
-                src = loaded if isinstance(loaded, dict) else {}
-                return {k: fit(f"{key}.{k}", v, src.get(k))
-                        for k, v in zeros.items()}
-            return fit_list(key, zeros, loaded)
-
-        for key, zeros in canon.items():
-            opt_state[key] = fit(key, zeros, opt_state.get(key))
-        return template.replace(opt_state=opt_state)
-
     def _build_state_shardings(self, state: TrainState) -> TrainState:
         """Shardings for a full TrainState per ZeRO stage + the
         compressed-comm special cases — shared by _init_state and the
@@ -4345,29 +3425,6 @@ class DeepSpeedEngine:
                 if key in opt_state:
                     opt_sh[key] = jax.tree_util.tree_map(
                         lambda _: err_sh, opt_state[key])
-        elif self._prefetch_active():
-            plan = self._prefetch_hier_plan()
-            if plan is not None:
-                # hierarchical stage-3 stream (ISSUE 16): same
-                # metadata-only split-mesh rest as the 1-bit path, plus
-                # the pf_* residuals' per-device [dp] leading axis
-                state_mesh = mesh_lib.split_data_axis(self.mesh, plan.inter)
-
-                def resplit(s):
-                    spec = tuple(
-                        (plan.inter_axis, plan.intra_axis)
-                        if p == mesh_lib.DATA_AXIS else p
-                        for p in tuple(s.spec))
-                    return NamedSharding(state_mesh, PartitionSpec(*spec))
-                param_sh = jax.tree_util.tree_map(resplit, param_sh)
-                opt_sh = jax.tree_util.tree_map(resplit, opt_sh)
-                err_sh = NamedSharding(
-                    state_mesh,
-                    PartitionSpec((plan.inter_axis, plan.intra_axis)))
-                for key in self._PF_ERR_KEYS:
-                    if key in opt_state:
-                        opt_sh[key] = jax.tree_util.tree_map(
-                            lambda _: err_sh, opt_state[key])
         repl = NamedSharding(state_mesh, PartitionSpec())
         scaler_sh = jax.tree_util.tree_map(lambda _: repl, scaler)
         return TrainState(params=param_sh, opt_state=opt_sh,

@@ -50,6 +50,24 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 from deepspeed_tpu.parallel import mesh as mesh_lib
 
 
+def plan_from_specs(leaves, specs, axis_name: str, n: int):
+    """Per-leaf shard plan from a PartitionSpec tree: ``(dim, shard_size)``
+    where ``dim`` (in the leaf's own coordinates) carries ``axis_name``,
+    or None for leaves the spec leaves replicated over the axis — the
+    contract of ``ZeroPartitioner.explicit_shard_plan``, usable on any
+    params subtree."""
+    plan = []
+    for leaf, spec in zip(leaves, specs):
+        entry = None
+        for d, ax in enumerate(spec):
+            axes = ax if isinstance(ax, tuple) else (ax,)
+            if axis_name in axes:
+                entry = (d, leaf.shape[d] // n)
+                break
+        plan.append(entry)
+    return plan
+
+
 def shard_spec_for_leaf(shape,
                         dp_size: int,
                         base_spec: Optional[PartitionSpec] = None,
@@ -60,8 +78,8 @@ def shard_spec_for_leaf(shape,
     largest free, divisible dimension. Returns base_spec unchanged if no
     dimension qualifies or the tensor is below ``min_size`` elements.
     ``layer_stacked`` says dim 0 is the layer dim of a stacked leaf: it
-    is never a candidate (a layer scan, and the prefetch pipeline, slice
-    whole layers device-locally) and ``min_size`` is compared with ONE
+    is never a candidate (a layer scan slices whole layers
+    device-locally) and ``min_size`` is compared with ONE
     layer's elements, the unit the threshold was defined on."""
     base = tuple(base_spec) if base_spec is not None else ()
     base = base + (None,) * (len(shape) - len(base))
@@ -154,7 +172,7 @@ class ZeroPartitioner:
         # top-level param-tree keys whose leaves are layer-stacked
         # ([L, ...]): judged one layer at a time (shard_spec_for_leaf's
         # ``layer_stacked``). The engine sets this from the model's
-        # ``layer_stacked_subtree`` / ``prefetch_layer_subtree``
+        # ``layer_stacked_subtree``
         self.layer_stacked_prefixes = tuple(layer_stacked_prefixes)
 
     # -- spec trees --------------------------------------------------------
@@ -254,7 +272,7 @@ class ZeroPartitioner:
                     lambda _: NamedSharding(self.mesh, PartitionSpec()), sub)
         return out
 
-    def explicit_shard_plan(self, params, specs=None):
+    def explicit_shard_plan(self, params):
         """Per-leaf update ownership for the explicit-comm (shard_map)
         overlap train path: a list aligned with ``tree_leaves(params)`` of
         ``(dim, shard_size)`` — the data-axis dim the stage>=1 optimizer
@@ -263,15 +281,11 @@ class ZeroPartitioner:
         update redundantly, which is exact). Inside shard_map the owner
         device updates params[dim slice] with its local moment shard and
         the slices all-gather back (the stage-1/2 updated-param all-gather,
-        stage2.py:~1470, made explicit). ``specs`` overrides the moment
-        spec tree (the stage3_prefetch path passes its param specs so
-        the plan matches the resting layout exactly)."""
-        from deepspeed_tpu.parallel.prefetch import plan_from_specs
+        stage2.py:~1470, made explicit)."""
         leaves = jax.tree_util.tree_leaves(params)
-        if specs is None:
-            specs = self.opt_param_like_specs(params)
         spec_leaves = jax.tree_util.tree_leaves(
-            specs, is_leaf=lambda x: isinstance(x, PartitionSpec))
+            self.opt_param_like_specs(params),
+            is_leaf=lambda x: isinstance(x, PartitionSpec))
         return plan_from_specs(leaves, spec_leaves, mesh_lib.DATA_AXIS,
                                self.dp)
 

@@ -46,7 +46,7 @@ Layout:
 
 from deepspeed_tpu.telemetry.registry import (     # noqa: F401
     Counter, Gauge, Histogram, MetricsRegistry, default_registry,
-    JsonlExporter, SummaryBridge, prometheus_text, record_comm_exposure)
+    JsonlExporter, SummaryBridge, prometheus_text)
 from deepspeed_tpu.telemetry.spans import (        # noqa: F401
     span, annotate, TraceWindow)
 from deepspeed_tpu.telemetry.recorder import (     # noqa: F401

@@ -32,8 +32,8 @@ recorder's current training-step context when one is set. Kinds in use
   ``window`` (step_s, steps) — engine step lifecycle;
 - ``swap_out`` / ``swap_in`` / ``swap_drain`` — swap-tier I/O
   (runtime/swap_tensor/swapper.py);
-- ``overlap_bucket_plan`` / ``prefetch_layer_plan`` — trace-time bucket
-  planning (parallel/overlap.py, parallel/prefetch.py);
+- ``overlap_bucket_plan`` — trace-time bucket planning
+  (parallel/overlap.py);
 - ``admit`` / ``prefill`` / ``tick`` / ``finish`` / ``pool_exhausted``
   — serving request lifecycle (serving/engine.py);
 - ``ckpt_begin`` / ``ckpt_commit`` / ``ckpt_abort`` / ``ckpt_corrupt``

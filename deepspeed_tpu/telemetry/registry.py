@@ -233,18 +233,6 @@ def default_registry() -> MetricsRegistry:
     return _default
 
 
-def record_comm_exposure(site, exposed_s, hidden_s, registry=None):
-    """Per-site communication-exposure counters (ISSUE 8):
-    ``comm/<site>/exposed_s`` is wall time a step spent WAITING on
-    collectives (comm the schedule failed to hide), ``hidden_s`` is
-    collective stream time that overlapped compute. Fed by measurement
-    harnesses (tests/perf/prefetch_bench.py's gather-wait vs compute
-    decomposition) — host floats only, never a device sync."""
-    r = registry or default_registry()
-    r.counter(f"comm/{site}/exposed_s").inc(max(0.0, exposed_s))
-    r.counter(f"comm/{site}/hidden_s").inc(max(0.0, hidden_s))
-
-
 # ---------------------------------------------------------------- export
 
 class JsonlExporter:

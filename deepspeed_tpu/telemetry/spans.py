@@ -13,7 +13,7 @@ against execution when wrapped around hot-loop phases (the reference's
   ``steps_per_print``-boundary fence the caller already pays.
 - ``annotate("tag")`` (trace time) is ``jax.named_scope``: ops traced
   under it carry the tag in their HLO metadata, so device-side phase
-  attribution (forward / backward / bucket-sync / prefetch-gather)
+  attribution (forward / backward / bucket-sync)
   lands in perfetto/xprof without any runtime cost.
 - ``TraceWindow`` wraps ``jax.profiler.start_trace/stop_trace`` around
   a configured step range (``profiling.trace_dir`` +

@@ -604,7 +604,6 @@ def render(paths, tail_events=0):
     render_swap(events, out)
     plans = [ev for ev in events
              if ev.get("kind") in ("overlap_bucket_plan",
-                                   "prefetch_layer_plan",
                                    "comm_hierarchy_plan",
                                    "comm_hierarchy_fallback")]
     if plans:

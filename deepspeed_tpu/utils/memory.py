@@ -8,26 +8,6 @@ import resource
 
 from deepspeed_tpu.utils.logging import logger
 
-# peak live gathered-parameter bytes of the stage3_prefetch pipeline
-# (parallel/prefetch.py): STATIC accounting from the layer plan — two
-# gathered layers (current + in-flight double buffer) plus the
-# persistent (outer + below-threshold) full leaves. Recorded by the
-# engine when it builds the prefetch train path, so
-# ``stage3_max_live_parameters`` is observable/assertable instead of
-# on-faith. None until a prefetch engine has been built.
-_live_gathered_param_bytes = None
-
-
-def record_live_gathered_param_bytes(nbytes):
-    global _live_gathered_param_bytes
-    _live_gathered_param_bytes = int(nbytes) if nbytes is not None else None
-
-
-def live_gathered_param_bytes():
-    """Peak live gathered-parameter bytes of the most recently built
-    stage3_prefetch train path (None when no prefetch engine exists)."""
-    return _live_gathered_param_bytes
-
 
 def _device_memory_stats():
     try:
@@ -50,11 +30,9 @@ def host_max_rss_mb():
 
 def memory_metrics():
     """One flat dict of the memory observables, for the telemetry
-    scalar stream: host RSS, per-device HBM in use where the backend
-    exposes it, and the stage3_prefetch live-gathered window."""
+    scalar stream: host RSS and per-device HBM in use where the backend
+    exposes it."""
     out = {"host_max_rss_mb": host_max_rss_mb()}
-    if _live_gathered_param_bytes is not None:
-        out["live_gathered_param_bytes"] = _live_gathered_param_bytes
     for i, (_, in_use, limit) in enumerate(_device_memory_stats()):
         out[f"device{i}_bytes_in_use"] = in_use
         out[f"device{i}_bytes_limit"] = limit
@@ -68,7 +46,4 @@ def see_memory_usage(message, force=False):
     lines = [message, f"Host MaxRSS {rss_mb:.1f} MB"]
     for name, in_use, limit in _device_memory_stats():
         lines.append(f"{name}: HBM in use {in_use / 2**30:.2f} GB / {limit / 2**30:.2f} GB")
-    if _live_gathered_param_bytes is not None:
-        lines.append(f"stage3_prefetch live gathered params "
-                     f"{_live_gathered_param_bytes / 2**20:.1f} MB")
     logger.info(" | ".join(lines))

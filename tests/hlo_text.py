@@ -61,10 +61,15 @@ def instructions(lines, opcode):
     return [ln for ln in lines if pat.search(ln)]
 
 
+def result_shape(line):
+    """An instruction line's (first) result shape, as a tuple."""
+    dims = re.search(r"= \(?\w+\[([\d,]*)\]", line).group(1)
+    return tuple(int(d) for d in filter(None, dims.split(",")))
+
+
 def result_elements(line):
     """Element count of an instruction line's (first) result shape."""
-    dims = re.search(r"= \(?\w+\[([\d,]*)\]", line).group(1)
     n = 1
-    for d in filter(None, dims.split(",")):
-        n *= int(d)
+    for d in result_shape(line):
+        n *= d
     return n

@@ -164,9 +164,9 @@ def test_dp_zero_matches_single_device():
 
     Slow (ISSUE 8 tier-1 wall consolidation): 4 engine compiles,
     ~21 s. Tier-1 keeps the same subsystem pinned by
-    tests/test_zero.py::test_zero_stage_matches_stage0 (dp-mesh stage
-    parity per stage) and tests/test_prefetch.py's dp8 engine-parity
-    pins; the single-device-vs-dp4 drift bound re-runs with -m slow."""
+    tests/test_zero_matrix.py::test_stage_trajectory_matches_stage0
+    (dp-mesh stage parity per family and stage); the
+    single-device-vs-dp4 drift bound re-runs with -m slow."""
     if len(jax.devices()) < 4:
         pytest.skip("need 4 devices")
     base = _train(MeshConfig(data=1), zero_stage=0)

@@ -413,129 +413,6 @@ def test_hierarchical_compressed_allreduce_two_processes(tmp_path):
             < 0.5 * max(abs(l_exact), 0.1) + 0.3, (l_onebit, l_exact)
 
 
-_PF_HIER_WORKER = textwrap.dedent("""
-    import json
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-    from deepspeed_tpu.utils.distributed import init_distributed
-    init_distributed()
-
-    import numpy as np
-    import jax.numpy as jnp
-    import deepspeed_tpu as dstpu
-    from deepspeed_tpu.parallel.mesh import make_mesh, MeshConfig
-    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
-
-    assert jax.process_count() == 2
-    assert len(jax.devices()) == 2            # 1 local x 2 processes
-    mesh = make_mesh(MeshConfig(data=2))
-    cfg = {
-        "train_batch_size": 8,
-        "zero_optimization": {"stage": 3, "stage3_prefetch": True,
-                              "stage3_prefetch_gather": "ring",
-                              "stage3_param_persistence_threshold": 0},
-        "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
-        # slow_axis 0 = auto: the split must come from the REAL process
-        # boundaries; "always" because the tiny model's per-layer RS
-        # buffers are far below the auto policy's byte floor
-        "comm": {"hierarchy": {"slow_axis": 0, "compression": "always"}},
-        "steps_per_print": 1000,
-    }
-    model = GPT2LMHeadModel(GPT2Config(
-        vocab_size=512, n_positions=64, n_embd=64, n_layer=2, n_head=2,
-        dtype=jnp.float32, param_dtype=jnp.float32, scan_layers=True))
-    engine, _, _, _ = dstpu.initialize(config=cfg, model=model, mesh=mesh)
-    assert engine._prefetch_active()
-    plan = engine._prefetch_hier_plan()
-    assert (plan.inter, plan.intra) == (2, 1), plan
-    hier, _ = __import__(
-        "deepspeed_tpu.parallel.topology",
-        fromlist=["derive_data_hierarchy"]).derive_data_hierarchy(mesh)
-    assert hier is not None and hier.source == "process", hier
-
-    batch = {"input_ids": np.random.RandomState(0).randint(
-        0, 512, (8, 64)).astype(np.int32)}   # identical on every process
-    losses = [float(engine.train_batch(batch)) for _ in range(3)]
-    snap = engine.telemetry.snapshot("comm/")["counters"]
-    print("PFHIER", jax.process_index(), json.dumps({
-        "losses": losses,
-        "wire": engine._pf_wire_model,
-        "counters": snap,
-    }), flush=True)
-""")
-
-
-@pytest.mark.slow
-def test_stage3_prefetch_hierarchy_two_processes(tmp_path):
-    """The ISSUE 16 proof leg: 2 real processes run the two-level ZeRO-3
-    prefetch stream with the slow axis derived from the ACTUAL
-    jax.distributed process boundary, grad reduce-scatters carrying sign
-    bits on the inter-process hop. One virtual device per process — the
-    multi-device-per-process GSPMD-over-gloo interleave flake (ROADMAP
-    standing backlog, found by PR 15: ≥2 independent cross-process
-    collectives nondeterministically abort with ``gloo EnforceNotMet``)
-    rules out wider local meshes; the 2x4 split is covered by the
-    synthetic-override tests instead. Pins (a) both ranks observe the
-    identical loss trajectory, (b) the trajectory matches the SAME
-    config run in one process (synthetic 2x1 override), (c) the modeled
-    inter-host bytes sit below the flat-ring baseline post-compression
-    and the per-link-class counters advanced."""
-    import json as _json
-    import re
-    outs = spawn_workers(2, _PF_HIER_WORKER, tmp_path, local_devices=1,
-                         timeout=300)
-    results = {}
-    for out in outs:
-        m = re.search(r"PFHIER (\d+) (\{.*\})", out)
-        assert m, out
-        results[int(m.group(1))] = _json.loads(m.group(2))
-    # (a) identical trajectory on both ranks (replicated out-shardings)
-    assert results[0]["losses"] == results[1]["losses"]
-
-    # (c) modeled inter-host bytes down vs the flat-ring baseline, and
-    # the ledger advanced per link class
-    wire = results[0]["wire"]
-    assert 0 < wire["inter"] < wire["inter_uncompressed"], wire
-    ctr = results[0]["counters"]
-    assert ctr["comm/bytes_on_wire/inter"] > 0
-    assert ctr["comm/bytes_on_wire/inter_uncompressed"] > \
-        ctr["comm/bytes_on_wire/inter"]
-
-    # (b) parity vs the same recipe in ONE process: synthetic 2x1 split
-    # over 2 local devices reproduces the process-derived schedule
-    import jax
-    if len(jax.devices()) >= 2:
-        import numpy as np
-        import jax.numpy as jnp
-        import deepspeed_tpu as dstpu
-        from deepspeed_tpu.parallel.mesh import make_mesh, MeshConfig
-        from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
-        cfg = {
-            "train_batch_size": 8,
-            "zero_optimization": {
-                "stage": 3, "stage3_prefetch": True,
-                "stage3_prefetch_gather": "ring",
-                "stage3_param_persistence_threshold": 0},
-            "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
-            "comm": {"hierarchy": {"slow_axis": 2,
-                                   "compression": "always"}},
-            "steps_per_print": 1000,
-        }
-        model = GPT2LMHeadModel(GPT2Config(
-            vocab_size=512, n_positions=64, n_embd=64, n_layer=2,
-            n_head=2, dtype=jnp.float32, param_dtype=jnp.float32,
-            scan_layers=True))
-        engine, _, _, _ = dstpu.initialize(
-            config=cfg, model=model,
-            mesh=make_mesh(MeshConfig(data=2), devices=jax.devices()[:2]))
-        batch = {"input_ids": np.random.RandomState(0).randint(
-            0, 512, (8, 64)).astype(np.int32)}
-        ref = [float(engine.train_batch(batch)) for _ in range(3)]
-        np.testing.assert_allclose(results[0]["losses"], ref,
-                                   rtol=2e-5, atol=1e-5)
-        assert engine._pf_wire_model == wire
-
-
 _STRAGGLER_WORKER = textwrap.dedent("""
     import json, os, sys, time
     import jax

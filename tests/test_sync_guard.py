@@ -12,7 +12,7 @@ source, and a NEW unannotated one — the easy way to silently serialize
 dispatch against execution — fails CI instead of landing.
 
 Scope (the per-step hot paths):
-- ``deepspeed_tpu/parallel/*.py`` (overlap buckets, prefetch pipeline,
+- ``deepspeed_tpu/parallel/*.py`` (overlap buckets,
   mesh/attention helpers traced into train steps),
 - ``deepspeed_tpu/serving/*.py`` (the continuous-batching scheduler,
   including its watchdog hooks; ISSUE 9 grows this glob's coverage to
@@ -60,17 +60,13 @@ HOT_GLOBS = ("parallel/*.py", "serving/*.py", "telemetry/*.py",
              # ISSUE 7: the elastic snapshot layer runs at step
              # boundaries — staging copies and swap-file reads are
              # deliberate host work, device readbacks must be annotated
-             "runtime/elastic/*.py",
-             # ISSUE 8: the fused matmul+collective kernels trace into
-             # every fused_matmul-mode train step — dispatch must stay
-             # sync-free (breadcrumbs/counters are trace-time host work)
-             "ops/pallas/fused_collective.py")
+             "runtime/elastic/*.py")
 
 # engine units scanned via inspect (robust to line moves)
 HOT_ENGINE_METHODS = (
     "train_batch", "forward", "backward", "step",
     "_build_jit_fns", "_build_overlap_train_fn",
-    "_build_prefetch_train_fn", "_build_compressed_train_fn",
+    "_build_compressed_train_fn",
     "_build_sparse_train_fn", "_local_grad_accumulator",
     "_apply_grads", "_telemetry_step", "_telemetry_fold",
     "_telemetry_mfu", "_telemetry_memory_gauges", "_telemetry_export",

@@ -13,10 +13,10 @@ chip and on four, the serving engine's prefill and decode tick, and
 Nothing runs, so nothing here says a result is right or how long it takes; a
 compile that passes is not a chip run.
 
-The optional kernels (grouped quantize, the fused matmul+collective ring) do
-not build for a TPU on the installed jax. They are strict xfails that quote
-the compiler, so the day one starts to compile its test says so; nothing on
-the main path may select them (ROADMAP S2).
+The optional grouped-quantize kernel does not build for a TPU on the
+installed jax. It is a strict xfail that quotes the compiler, so the day it
+starts to compile its test says so; nothing on the main path may select it
+(ROADMAP S2).
 """
 
 import contextlib
@@ -434,8 +434,6 @@ TILING_RULE = ("The Pallas TPU lowering currently requires that the last two "
                "dimensions of your block shape are divisible by 8 and 128 "
                "respectively, or be equal to the respective dimensions of "
                "the overall array.")
-BARRIER_RULE = ("collective_id has to be unspecified or None when not using "
-                "a custom barrier")
 
 
 @contextlib.contextmanager
@@ -462,45 +460,3 @@ def test_grouped_quantize_kernel_compiles():
                        "Blocked(block_size=1), Blocked(block_size=1280)"):
         compile_on_chip(lambda x: quantize(x, groups=1280, interpret=False),
                         SDS((1280, 1280), BF16))
-
-
-def fused_all_gather_matmul_on_four_chips(shard_dim):
-    """x[1024,1280] @ W[1280,5120], W sharded four ways on ``shard_dim``,
-    through the Pallas ring kernel (backend forced — ``auto`` would send the
-    unaligned row-sharded case to the lax ring)."""
-    from deepspeed_tpu.ops.pallas import fused_collective as fc
-    n = len(topo().devices)
-    mesh = Mesh(np.asarray(topo().devices), ("data",))
-    cfg = fc.CollectiveMatmulConfig(axis_name="data", axis_size=n,
-                                    backend="fused", interpret=False)
-    w_spec = P("data", None) if shard_dim == 0 else P(None, "data")
-
-    def body(x, w):
-        return fc.all_gather_matmul(x, w, shard_dim=shard_dim,
-                                    axis_name="data", axis_size=n, cfg=cfg)
-    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), w_spec),
-                       out_specs=P(), check_vma=False)
-    jax.jit(fn).lower(
-        on_chip(SDS((1024, 1280), BF16), NamedSharding(mesh, P())),
-        on_chip(SDS((1280, 5120), BF16), NamedSharding(mesh, w_spec))
-    ).compile()
-
-
-@pytest.mark.xfail(
-    strict=True, raises=ValueError,
-    reason="fused all-gather+matmul, W row-sharded over 4 chips, does not "
-           "build for a TPU: " + TILING_RULE + " [block (128, 320): the "
-           "1280/4 chunk is not lane-aligned]")
-def test_fused_all_gather_matmul_row_sharded_compiles():
-    with refusing_with(TILING_RULE, "fused_collective.py",
-                       "Blocked(block_size=128), Blocked(block_size=320)"):
-        fused_all_gather_matmul_on_four_chips(shard_dim=0)
-
-
-@pytest.mark.xfail(
-    strict=True, raises=ValueError,
-    reason="fused all-gather+matmul, W column-sharded over 4 chips, does "
-           "not build for a TPU on jax 0.9.0: " + BARRIER_RULE)
-def test_fused_all_gather_matmul_column_sharded_compiles():
-    with refusing_with(BARRIER_RULE):
-        fused_all_gather_matmul_on_four_chips(shard_dim=1)
