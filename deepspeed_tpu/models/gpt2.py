@@ -25,7 +25,8 @@ import flax.linen as nn
 
 from jax.ad_checkpoint import checkpoint_name
 
-from deepspeed_tpu.ops.attention import dot_product_attention
+from deepspeed_tpu.ops.attention import (from_head_major,
+                                         fused_qkv_attention, to_head_major)
 from deepspeed_tpu.telemetry.spans import annotate
 
 
@@ -225,11 +226,6 @@ class SelfAttention(nn.Module):
                        kernel_init=nn.initializers.normal(0.02),
                        name="c_attn")(x)
         qkv = checkpoint_name(qkv, "qkv")
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-
-        def heads(t):
-            return t.reshape(B, S, cfg.n_head, cfg.head_dim).transpose(0, 2, 1, 3)
-
         # sequence parallelism: when the active mesh has a seq axis, run
         # ring or Ulysses attention over it instead of letting GSPMD gather
         # full K/V
@@ -248,18 +244,20 @@ class SelfAttention(nn.Module):
                     f"sp_backend='ulysses' needs n_head ({cfg.n_head}) "
                     f"divisible by the seq axis ({sp}); falling back to "
                     f"ring attention")
+            q, k, v = (to_head_major(t, cfg.n_head)
+                       for t in jnp.split(qkv, 3, axis=-1))
             if cfg.sp_backend == "ulysses" and cfg.n_head % sp == 0:
                 from deepspeed_tpu.parallel.ulysses import ulysses_attention
-                out = ulysses_attention(heads(q), heads(k), heads(v), mesh,
-                                        causal=True)
+                out = ulysses_attention(q, k, v, mesh, causal=True)
             else:
                 from deepspeed_tpu.parallel.ring_attention import ring_attention
-                out = ring_attention(heads(q), heads(k), heads(v), mesh,
-                                     causal=True)
+                out = ring_attention(q, k, v, mesh, causal=True)
+            out = from_head_major(out)
         else:
-            out = dot_product_attention(heads(q), heads(k), heads(v),
-                                        causal=True, use_flash=cfg.use_flash)
-        out = out.transpose(0, 2, 1, 3).reshape(B, S, E)
+            # the fused projection as it is: the flash kernels read q, k, v
+            # out of it and write [B, S, E], no head-major copy in between
+            out = fused_qkv_attention(qkv, cfg.n_head, causal=True,
+                                      use_flash=cfg.use_flash)
         out = nn.Dense(E, dtype=cfg.dtype,
                        param_dtype=cfg.param_dtype,
                        kernel_init=nn.initializers.normal(

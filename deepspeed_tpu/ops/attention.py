@@ -4,6 +4,12 @@ This is the TPU answer to the reference's fused softmax/attention CUDA kernels
 (csrc/transformer/softmax_kernels.cu and the attention-score path of
 ds_transformer_cuda.cpp): one fused kernel that never materializes the
 [S, S] score matrix in HBM.
+
+Two entries, one per operand layout: ``dot_product_attention`` takes
+head-major [B, H, S, D] q, k, v (grouped-query K/V, bias and segment ids
+included); ``fused_qkv_attention`` takes a model's fused projection
+[B, S, 3*H*D] and returns [B, S, H*D], which on the flash path spares every
+transpose between the two layouts.
 """
 
 import functools
@@ -41,43 +47,106 @@ def reference_attention(q, k, v, causal=False, bias=None, scale=None,
     return jnp.einsum("bhst,bhtd->bhsd", probs.astype(q.dtype), v)
 
 
-def _flash(q, k, v, causal, scale):
-    """The Pallas flash kernel, placed on the engine's mesh. GSPMD cannot
-    partition a Mosaic kernel ("Mosaic kernels cannot be automatically
-    partitioned"), so under a multi-device engine trace the kernel runs
-    per device inside a shard_map over the engine's mesh: batch on the
-    batch axes (ZeRO data parallelism), heads on the model axis (TP),
-    each when it divides. One device, no engine mesh, or a region that
-    is already manual: the kernel is called as is."""
-    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+def to_head_major(t, heads):
+    """[B, S, H*D] -> [B, H, S, D]."""
+    B, S, E = t.shape
+    return t.reshape(B, S, heads, E // heads).transpose(0, 2, 1, 3)
+
+
+def from_head_major(t):
+    """[B, H, S, D] -> [B, S, H*D]."""
+    B, H, S, D = t.shape
+    return t.transpose(0, 2, 1, 3).reshape(B, S, H * D)
+
+
+def _device_axes(batch, heads):
+    """(mesh, batch axes, model axis) to run a Pallas kernel per device.
+    GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned"), so under a multi-device engine trace the
+    kernel runs inside a shard_map over the engine's mesh: ``batch`` rows
+    on the batch axes (ZeRO data parallelism), ``heads`` on the model
+    axis (TP), each when it divides, else None. Mesh None — one device,
+    no engine mesh, or a region that is already manual: the kernel is
+    called as is."""
     from deepspeed_tpu.parallel import mesh as mesh_lib
-    kernel = functools.partial(flash_attention, causal=causal, scale=scale)
     mesh = mesh_lib.pinned_mesh()
     if mesh is None or mesh.size == 1 or mesh_lib.in_manual_region():
-        return kernel(q, k, v)
-    batch_axes = mesh_lib.batch_sharding(mesh).spec[0]
+        return None, None, None
     n_batch = mesh_lib.dp_world_size(mesh)
     n_model = mesh_lib.mesh_axis_size(mesh, mesh_lib.MODEL_AXIS)
-    spec = jax.sharding.PartitionSpec(
-        batch_axes if n_batch > 1 and q.shape[0] % n_batch == 0 else None,
-        mesh_lib.MODEL_AXIS if n_model > 1 and q.shape[1] % n_model == 0
-        and k.shape[1] % n_model == 0 else None)
+    return (mesh,
+            mesh_lib.batch_sharding(mesh).spec[0]
+            if n_batch > 1 and batch % n_batch == 0 else None,
+            mesh_lib.MODEL_AXIS if n_model > 1 and heads % n_model == 0
+            else None)
+
+
+def _flash(q, k, v, causal, scale):
+    """The head-major Pallas flash kernel ([B, H, S, D]), placed on the
+    engine's mesh (``_device_axes``)."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    kernel = functools.partial(flash_attention, causal=causal, scale=scale)
+    mesh, batch_axes, model_axis = _device_axes(
+        q.shape[0], np.gcd(q.shape[1], k.shape[1]))
+    if mesh is None:
+        return kernel(q, k, v)
+    spec = jax.sharding.PartitionSpec(batch_axes, model_axis)
     return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
 
 
+def _flash_fused_qkv(qkv, heads, causal, scale):
+    """The Pallas flash kernels on the fused projection [B, S, 3*H*D]
+    (``flash_attention_bse``), placed on the engine's mesh. With heads on
+    a model axis the thirds are split first — a device's share of each is
+    a column range of its own — and the kernel sees three [B, S, H*D / tp]
+    arrays and its own head count."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_bse
+    mesh, batch_axes, model_axis = _device_axes(qkv.shape[0], heads)
+    operands = (qkv,)
+    if model_axis is not None:
+        operands = tuple(jnp.split(qkv, 3, axis=-1))
+        heads //= mesh.shape[model_axis]
+    kernel = functools.partial(flash_attention_bse, heads=heads,
+                               causal=causal, scale=scale)
+    if mesh is None:
+        return kernel(*operands)
+    spec = jax.sharding.PartitionSpec(batch_axes, None, model_axis)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec,) * len(operands),
+                         out_specs=spec, check_vma=False)(*operands)
+
+
 def dot_product_attention(q, k, v, causal=False, bias=None, scale=None,
                           segment_ids=None, use_flash=None):
-    """[B, H, S, D] attention. ``use_flash=None`` auto-selects the Pallas
-    flash kernel on TPU for flash-compatible shapes. K/V may carry
-    Hkv < H heads (grouped-query): the flash kernel streams the reduced
-    cache directly via Hkv-aware block maps — full-head K/V is never
-    materialized in the forward. A flash kernel that fails to lower
-    raises: nothing here drops to the O(S^2) reference behind the
-    caller's back."""
+    """[B, H, S, D] (head-major) attention. ``use_flash=None``
+    auto-selects the Pallas flash kernel on TPU for flash-compatible
+    shapes. K/V may carry Hkv < H heads (grouped-query): the flash kernel
+    streams the reduced cache directly via Hkv-aware block maps —
+    full-head K/V is never materialized in the forward. A flash kernel
+    that fails to lower raises: nothing here drops to the O(S^2)
+    reference behind the caller's back. A model whose q, k, v are one
+    fused projection has ``fused_qkv_attention``."""
     if use_flash is None:
         use_flash = is_tpu_backend() and bias is None and segment_ids is None
     if use_flash:
         return _flash(q, k, v, causal, scale)
     return reference_attention(q, k, v, causal=causal, bias=bias, scale=scale,
                                segment_ids=segment_ids)
+
+
+def fused_qkv_attention(qkv, heads, causal=False, scale=None,
+                        use_flash=None):
+    """Attention on the model's own layout: ``qkv`` [B, S, 3*H*D], the
+    fused projection (q, k, v its thirds), in; [B, S, H*D] out, ready for
+    the output projection. No bias, no segment ids (the flash-compatible
+    arguments). On the flash path the whole-row kernels read the thirds
+    in place and write o, dq, dk, dv in this layout, so no head-major
+    copy of any of them exists; a shape those kernels do not take goes
+    through [B, H, S, D] as ``dot_product_attention`` would have it."""
+    if use_flash is None:
+        use_flash = is_tpu_backend()
+    if use_flash:
+        return _flash_fused_qkv(qkv, heads, causal, scale)
+    q, k, v = (to_head_major(t, heads) for t in jnp.split(qkv, 3, axis=-1))
+    return from_head_major(reference_attention(q, k, v, causal=causal,
+                                               scale=scale))

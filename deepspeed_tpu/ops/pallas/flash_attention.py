@@ -4,8 +4,22 @@ ds_transformer_cuda.cpp): one Pallas kernel per pass that never materializes
 the [S, S] score matrix in HBM, with online softmax and a recompute-based
 backward (custom VJP), accumulating in fp32 on the MXU.
 
-Layout: q/k/v as [B, H, S, D] → kernels run on [B*H] × block grid. Two
-kernel families share the same per-tile math (`_fwd_block_step` /
+Two entries, one per operand layout. `flash_attention` takes head-major
+q/k/v [B, H, S, D] (kernels on a [B*H] × block grid) and reaches every kernel
+family, grouped-query K/V included. `flash_attention_bse` takes the model's
+own [B, S, H*D] arrays — q, k, v apart or one fused projection [B, S, 3*H*D]
+read in place — and runs the whole-row kernels on 128-lane COLUMN blocks of
+them, 128 // D heads a block (grid [B] × column blocks × blocks): no
+head-major copy of q, k, v, o, dq, dk or dv is ever built, and at head_dim 64
+no operand is padded from 64 to 128 lanes in HBM (XLA built 20 such copies a
+GPT-2 layer round the head-major kernels: PERF.md, PR 30). A head of a column
+block takes the same tiles with the other heads' lanes zeroed, so every
+product keeps its MXU cost (a contraction over 128 lanes where head-major
+contracts over 64 of a 128-deep array; 128 output columns where 64 of 128
+were idle); shapes it does not take (long rows, grouped-query, head widths
+that do not tile 128 lanes) it transposes into `flash_attention`.
+
+Two kernel families share the same per-tile math (`_fwd_block_step` /
 `_bwd_ds_block`):
 
 - **plain** ("whole-row"): K/V (fwd) or Q/dO (bwd) rows for one
@@ -211,11 +225,33 @@ def _dense_row(x):
     return jnp.sum(jnp.where(eye, x, 0.0), axis=0, keepdims=True)[:, :piece]
 
 
-def _stat_row(ref, row0, rows):
-    """[1, rows] of a [BH, S / piece, 1, piece] statistic's block, from
-    row ``row0`` (a multiple of the piece)."""
-    piece = ref.shape[3]
-    return _cat([ref[0, row0 // piece + j] for j in range(rows // piece)], 1)
+def _stat_row(ref, head, row0, rows):
+    """[1, rows] of a [.., S / piece, 1, piece] statistic's block, from row
+    ``row0`` (a multiple of the piece) of the head at index ``head`` — (0,)
+    in a head-major block, (0, h) or (h,) in a column block."""
+    piece = ref.shape[-1]
+    return _cat([ref[head + (row0 // piece + j,)]
+                 for j in range(rows // piece)], 1)
+
+
+def _own_lanes(x, h, width):
+    """``x`` [rows, lanes] with every lane outside head ``h``'s ``width``
+    zeroed: what lets a product contract over a whole 128-lane block of
+    ``[B, S, H*D]`` and still see one head. A select, not a multiply: the
+    lanes past the last head of a ragged block are undefined."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= h * width) & (lane < (h + 1) * width), x,
+                     jnp.zeros_like(x))
+
+
+def _pack_heads(pack, h, run):
+    """Run head ``h``'s share of a grid step; in a column block, a head
+    past the last one (an odd head count's ragged last block) is skipped."""
+    g, _, heads = pack or (1, 0, 0)
+    if pack and h >= (heads % g or g):
+        pl.when(pl.program_id(1) * g + h < heads)(run)
+    else:
+        run()
 
 
 def _fwd_block_step(q, k, v, carry, mask, scale):
@@ -291,7 +327,8 @@ def _causal_split_loop(lo, full, hi, body, carry):
 # ---------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, scale, causal, block_q, k_tile, strip, seq_len):
+                *, scale, causal, block_q, k_tile, strip, seq_len,
+                pack=None):
     """Whole-row forward; the softmax state (o, m, l: fp32) lives in VMEM
     scratch between k-tiles.
 
@@ -301,39 +338,74 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
     mask on the square alone — nothing above a sub-block's diagonal
     square is computed. Then the k-tiles wholly below the block run
     unmasked for all its rows at once (a dynamic count; online softmax
-    does not mind the order)."""
-    qi = pl.program_id(1)
+    does not mind the order).
+
+    ``pack`` None: one head a grid step, blocks of [B*H, S, D], grid
+    (B*H, q blocks). ``pack`` = (g, D, H): a grid step holds a 128-lane
+    COLUMN block of [B, S, H*D] — g heads of width D side by side — on
+    grid (B, column blocks, q blocks). Each head runs the same loops on
+    the whole block with the other heads' lanes of q zeroed, so q·kᵀ
+    contracts over 128 lanes where a head-major block contracts over D of
+    a 128-deep array, and p·v fills 128 columns where it filled D; a
+    head's state has the block's width and its own lanes of it leave."""
+    g, D, heads = pack or (1, 0, 0)
+    qi = pl.program_id(2 if pack else 1)
     fold = _scale_folds(scale)
     s_scale = None if fold else scale
+    piece = lse_ref.shape[-1]
+    tri = _rel_pos(strip, strip) >= 0 if causal else None
 
-    def step(rows, cols, first, mask):
-        q = q_ref[0, rows, :] * scale if fold else q_ref[0, rows, :]
-        carry = None if first else (acc_ref[rows, :], m_ref[rows, :],
-                                    l_ref[rows, :])
-        acc_ref[rows, :], m_ref[rows, :], l_ref[rows, :] = _fwd_block_step(
-            q, k_ref[0, cols, :], v_ref[0, cols, :], carry, mask, s_scale)
+    def state(h):
+        return ((acc_ref.at[h], m_ref.at[h], l_ref.at[h]) if pack
+                else (acc_ref, m_ref, l_ref))
 
-    def body(t, _):
-        step(slice(None), pl.ds(pl.multiple_of(t * k_tile, k_tile), k_tile),
-             False, None)
-        return _
+    def step(h, rows, cols, first, mask):
+        acc, m, l = state(h)
+        q, k = q_ref[0, rows, :], k_ref[0, cols, :]
+        if g > 1:
+            q = _own_lanes(q, h, D)
+            if heads % g:
+                # a block can be ragged: 0 x undefined is undefined
+                k = _own_lanes(k, h, D)
+        if fold:
+            q = q * scale
+        carry = None if first else (acc[rows, :], m[rows, :], l[rows, :])
+        acc[rows, :], m[rows, :], l[rows, :] = _fwd_block_step(
+            q, k, v_ref[0, cols, :], carry, mask, s_scale)
 
-    if causal:
-        tri = _rel_pos(strip, strip) >= 0
-        c0 = pl.multiple_of(qi * block_q, block_q)
-        for qs in range(block_q // strip):
-            step(slice(qs * strip, (qs + 1) * strip),
-                 pl.ds(c0, (qs + 1) * strip), True, tri)
-        jax.lax.fori_loop(0, qi * (block_q // k_tile), body, 0)
-    else:
-        step(slice(None), slice(0, k_tile), True, None)
-        jax.lax.fori_loop(1, seq_len // k_tile, body, 0)
-    l_safe = jnp.maximum(_row_total(l_ref[...]), 1e-30)
-    o_ref[0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-    lse = m_ref[...] + jnp.log(l_safe)
-    piece = lse_ref.shape[3]
-    for j in range(block_q // piece):
-        lse_ref[0, j] = _dense_row(lse[j * piece:(j + 1) * piece])
+    def head(h):
+        def body(t, _):
+            step(h, slice(None),
+                 pl.ds(pl.multiple_of(t * k_tile, k_tile), k_tile), False,
+                 None)
+            return _
+
+        if causal:
+            c0 = pl.multiple_of(qi * block_q, block_q)
+            for qs in range(block_q // strip):
+                step(h, slice(qs * strip, (qs + 1) * strip),
+                     pl.ds(c0, (qs + 1) * strip), True, tri)
+            jax.lax.fori_loop(0, qi * (block_q // k_tile), body, 0)
+        else:
+            step(h, slice(None), slice(0, k_tile), True, None)
+            jax.lax.fori_loop(1, seq_len // k_tile, body, 0)
+
+    out = None
+    for h in range(g):
+        _pack_heads(pack, h, functools.partial(head, h))
+        acc, m, l = state(h)
+        l_safe = jnp.maximum(_row_total(l[...]), 1e-30)
+        o_h = acc[...] / l_safe
+        lse = m[...] + jnp.log(l_safe)
+        for j in range(block_q // piece):
+            lse_ref[(0, h, j) if pack else (0, j)] = _dense_row(
+                lse[j * piece:(j + 1) * piece])
+        if out is None:
+            out = o_h
+        else:       # the head's own lanes of the block (and those after)
+            lane = jax.lax.broadcasted_iota(jnp.int32, out.shape, 1)
+            out = jnp.where(lane >= h * D, o_h, out)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 # widest off-diagonal tile: [1024, 512] fp32 scores are 2 MB of VMEM
@@ -404,8 +476,9 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
 # ---------------------------------------------------------------- backward
 
 def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                      scale, causal, block_k, q_tile, strip, seq_len):
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+                      delta_acc=None, *, scale, causal, block_k, q_tile,
+                      strip, seq_len, pack=None):
     """Single-pass backward: the grid walks k-blocks; dk/dv accumulate
     block-locally (fp32 VMEM scratch) over the q rows of the inner loops,
     while dq accumulates into a VMEM-resident fp32 row that outlives the
@@ -423,8 +496,20 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     static height), the mask on the square alone; then the q rows past
     the k-block run ``q_tile`` at a time against the whole block,
     unmasked (a dynamic count). q rows before the block are not visited
-    and nothing above a sub-block's diagonal square is computed."""
-    ki = pl.program_id(1)
+    and nothing above a sub-block's diagonal square is computed.
+
+    ``pack`` as in ``_fwd_kernel``: with (g, D, H) a grid step holds g
+    heads side by side in a 128-lane column block of [B, S, H*D]. Each
+    head takes its tiles with the other heads' lanes zeroed in all four
+    operands: k·qᵀ and v·doᵀ contract over the block's 128 lanes, and
+    p·do, ds·q and dsᵀ·k land in the head's own lanes of the ONE
+    dv / dk / dq accumulator the block has, as exact zeros elsewhere.
+    ``delta_ref`` is then o itself and delta = rowsum(do·o) is taken here,
+    once a head, into ``delta_acc`` (fp32, lane-dense like lse): from
+    [B, S, H*D] operands XLA builds it through a transposed fp32 copy of
+    do·o."""
+    g, D, heads = pack or (1, 0, 0)
+    ki = pl.program_id(2 if pack else 1)
     num_kb = seq_len // block_k
     fold = _scale_folds(scale)
     s_scale = None if fold else scale
@@ -432,20 +517,39 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(ki == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        if pack:
+            piece = delta_acc.shape[-1]
+            for j in range(seq_len // piece):
+                rows = slice(j * piece, (j + 1) * piece)
+                prod = (do_ref[0, rows, :].astype(jnp.float32)
+                        * delta_ref[0, rows, :].astype(jnp.float32))
+                for h in range(g):
+                    total = jnp.sum(prod[:, h * D:(h + 1) * D], axis=1,
+                                    keepdims=True)
+                    delta_acc[h, j] = _dense_row(
+                        jnp.broadcast_to(total, (piece, _LANES)))
 
     dk_acc[...] = jnp.zeros_like(dk_acc)
     dv_acc[...] = jnp.zeros_like(dv_acc)
+    tri = _rel_pos(strip, strip) <= 0 if causal else None   # [k, q]
 
-    def tile(row0, rows, cols, mask):
+    def tile(h, row0, rows, cols, mask):
+        def take(ref, at):
+            x = ref[0, at, :]
+            return _own_lanes(x, h, D) if g > 1 else x
+
         q_rows = pl.ds(row0, rows)
-        q = q_ref[0, q_rows, :]
+        q = take(q_ref, q_rows)
         if fold:
             q = q * scale
-        do = do_ref[0, q_rows, :]
-        k = k_ref[0, cols, :]
-        p, ds = _bwd_ds_block(k, v_ref[0, cols, :],
-                              _stat_row(lse_ref, row0, rows),
-                              _stat_row(delta_ref, row0, rows), q, do, mask,
+        do = take(do_ref, q_rows)
+        k = take(k_ref, cols)
+        # the head's lse, a block [1, g, ..], and the delta taken above,
+        # [g, ..] — or both the head-major [1, ..]
+        lse = _stat_row(lse_ref, (0, h) if pack else (0,), row0, rows)
+        delta = (_stat_row(delta_acc, (h,), row0, rows) if pack
+                 else _stat_row(delta_ref, (0,), row0, rows))
+        p, ds = _bwd_ds_block(k, take(v_ref, cols), lse, delta, q, do, mask,
                               s_scale)
         dv_acc[cols, :] += jax.lax.dot(p, do,
                                        preferred_element_type=jnp.float32)
@@ -455,20 +559,24 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    def body(t, _):
-        tile(pl.multiple_of(t * q_tile, q_tile), q_tile, slice(None), None)
-        return _
+    def head(h):
+        def body(t, _):
+            tile(h, pl.multiple_of(t * q_tile, q_tile), q_tile, slice(None),
+                 None)
+            return _
 
-    if causal:
-        tri = _rel_pos(strip, strip) <= 0       # [k, q]: q at or past k
-        r0 = ki * block_k
-        for qs in range(block_k // strip):
-            tile(pl.multiple_of(r0 + qs * strip, strip), strip,
-                 slice(0, (qs + 1) * strip), tri)
-        jax.lax.fori_loop((ki + 1) * (block_k // q_tile), seq_len // q_tile,
-                          body, 0)
-    else:
-        jax.lax.fori_loop(0, seq_len // q_tile, body, 0)
+        if causal:
+            r0 = ki * block_k
+            for qs in range(block_k // strip):
+                tile(h, pl.multiple_of(r0 + qs * strip, strip), strip,
+                     slice(0, (qs + 1) * strip), tri)
+            jax.lax.fori_loop((ki + 1) * (block_k // q_tile),
+                              seq_len // q_tile, body, 0)
+        else:
+            jax.lax.fori_loop(0, seq_len // q_tile, body, 0)
+
+    for h in range(g):
+        _pack_heads(pack, h, functools.partial(head, h))
     # dk = scale·Σ dsᵀ·q: a pre-scaled q has carried it
     dk = dk_acc[...] if fold else dk_acc[...] * scale
     dk_ref[0] = dk.astype(dk_ref.dtype)
@@ -521,6 +629,147 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     with annotate("flash_bwd"):
         dq, dk, dv = call(q, k, v, do, lse, delta)
     return dq, dk, dv
+
+
+# ----------------------------- the whole-row kernels on [B, S, H*D] operands
+
+def _column_plan(E, heads):
+    """(g, D, width) of the column blocks of a [B, S, H*D] operand: g
+    heads of width D share a block of ``width`` = max(D, 128) lanes (2 at
+    head_dim 64, 1 at 128 or 256). None where heads do not tile lane
+    blocks (neither 128 % D nor D % 128 is 0) or fill not even one."""
+    D = E // heads
+    if D * heads != E or (_LANES % D and D % _LANES) or E < _LANES:
+        return None
+    width = max(D, _LANES)
+    return width // D, D, width
+
+
+def _columns(operands, heads):
+    """((q, k, v) arrays, their first column blocks, E, ``_column_plan``):
+    a fused projection [B, S, 3*E] is read IN PLACE as three views of
+    itself, E / width column blocks apart; three [B, S, E] arrays each
+    start at block 0."""
+    fused = len(operands) == 1
+    E = operands[0].shape[-1] // (3 if fused else 1)
+    plan = _column_plan(E, heads)
+    step = E // plan[2] if fused and plan else 0
+    return operands * (3 if fused else 1), (0, step, 2 * step), E, plan
+
+
+def _flash_fwd_cols(operands, heads, scale, causal, block_q, block_k,
+                    interpret):
+    """``_fwd_kernel`` over column blocks: q, k, v and o stay [B, S, H*D]
+    — dense 128-lane tiles in HBM, where [B*H, S, 64] pads every row to
+    128 lanes — and lse is [B, H, S / piece, 1, piece]."""
+    B, S, _ = operands[0].shape
+    (q, k, v), (q0, k0, v0), E, (g, D, width) = _columns(operands, heads)
+    strip, k_tile = _plain_tiles(block_q, block_k)
+    piece = _stat_piece(block_q, block_k)
+    call = pl.pallas_call(
+        functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, k_tile=k_tile, strip=strip,
+                          seq_len=S, pack=(g, D, heads)),
+        grid=(B, pl.cdiv(heads, g), S // block_q),
+        in_specs=[
+            pl.BlockSpec((1, block_q, width), lambda b, j, i: (b, i, q0 + j)),
+            pl.BlockSpec((1, S, width), lambda b, j, i: (b, 0, k0 + j)),
+            pl.BlockSpec((1, S, width), lambda b, j, i: (b, 0, v0 + j)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, width), lambda b, j, i: (b, i, j)),
+            pl.BlockSpec((1, g, block_q // piece, 1, piece),
+                         lambda b, j, i: (b, j, i, 0, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((B, S, E), q.dtype),
+            jax.ShapeDtypeStruct((B, heads, S // piece, 1, piece),
+                                 jnp.float32),
+        ],
+        scratch_shapes=[pltpu.VMEM((g, block_q, width), jnp.float32),
+                        pltpu.VMEM((g, block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((g, block_q, _LANES), jnp.float32)],
+        interpret=interpret,
+    )
+    with annotate("flash_fwd"):
+        o, lse = call(q, k, v)
+    return o, lse
+
+
+def _flash_bwd_cols(operands, heads, o, lse, do, scale, causal, block_q,
+                    block_k, interpret):
+    """``_bwd_fused_kernel`` over column blocks: dq, dk, dv leave as three
+    [B, S, H*D] arrays."""
+    B, S, _ = o.shape
+    (q, k, v), (q0, k0, v0), E, (g, D, width) = _columns(operands, heads)
+    stats = lse.shape[2:]                   # (S / piece, 1, piece)
+    strip, q_tile = _plain_tiles(block_k, block_q)
+
+    def row(b, j, i):
+        return (b, 0, j)
+
+    call = pl.pallas_call(
+        functools.partial(_bwd_fused_kernel, scale=scale, causal=causal,
+                          block_k=block_k, q_tile=q_tile, strip=strip,
+                          seq_len=S, pack=(g, D, heads)),
+        grid=(B, pl.cdiv(heads, g), S // block_k),
+        in_specs=[
+            pl.BlockSpec((1, S, width), lambda b, j, i: (b, 0, q0 + j)),
+            pl.BlockSpec((1, block_k, width), lambda b, j, i: (b, i, k0 + j)),
+            pl.BlockSpec((1, block_k, width), lambda b, j, i: (b, i, v0 + j)),
+            pl.BlockSpec((1, S, width), row),
+            pl.BlockSpec((1, g) + stats, lambda b, j, i: (b, j, 0, 0, 0)),
+            pl.BlockSpec((1, S, width), row),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, S, width), row),
+            pl.BlockSpec((1, block_k, width), lambda b, j, i: (b, i, j)),
+            pl.BlockSpec((1, block_k, width), lambda b, j, i: (b, i, j)),
+        ],
+        out_shape=[jax.ShapeDtypeStruct((B, S, E), o.dtype)] * 3,
+        scratch_shapes=[pltpu.VMEM((S, width), jnp.float32),
+                        pltpu.VMEM((block_k, width), jnp.float32),
+                        pltpu.VMEM((block_k, width), jnp.float32),
+                        pltpu.VMEM((g,) + stats, jnp.float32)],
+        interpret=interpret,
+    )
+    with annotate("flash_bwd"):
+        dq, dk, dv = call(q, k, v, do, lse, o)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
+def _flash_attention_cols(operands, heads, scale, causal, block_q, block_k,
+                          interpret):
+    """``operands``: (qkv [B, S, 3*E],) read in place, or (q, k, v)."""
+    return _flash_fwd_cols(operands, heads, scale, causal, block_q, block_k,
+                           interpret)[0]
+
+
+def _flash_attention_cols_fwd(operands, heads, scale, causal, block_q,
+                              block_k, interpret):
+    o, lse = _flash_fwd_cols(operands, heads, scale, causal, block_q,
+                             block_k, interpret)
+    # the names the remat policies keep (see _flash_attention_fwd); the
+    # other residual is the projection itself, in place
+    from jax.ad_checkpoint import checkpoint_name
+    o = checkpoint_name(o, "flash_o")
+    lse = checkpoint_name(lse, "flash_lse")
+    return o, (operands, o, lse)
+
+
+def _flash_attention_cols_bwd(heads, scale, causal, block_q, block_k,
+                              interpret, residuals, do):
+    operands, o, lse = residuals
+    grads = _flash_bwd_cols(operands, heads, o, lse, do, scale, causal,
+                            block_q, block_k, interpret)
+    if len(operands) == 1:
+        return ((jnp.concatenate(grads, axis=-1),),)
+    return (grads,)
+
+
+_flash_attention_cols.defvjp(_flash_attention_cols_fwd,
+                             _flash_attention_cols_bwd)
 
 
 # ------------------------------------------------- long-S chunked variants
@@ -858,57 +1107,72 @@ def tile_overcompute(S, block_q, block_k, chunk, causal):
 _plans_logged = set()
 
 
-def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk):
-    """Trace-time engagement record of one ``flash_attention`` call: the
-    gauge ``attention/flash_tile_overcompute`` and, once per distinct
-    shape, a log line of the loop structure chosen for it."""
+def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
+               heads_per_block=0):
+    """Trace-time engagement record of one flash call: the gauges
+    ``attention/flash_tile_overcompute`` and
+    ``attention/flash_heads_per_block`` (heads a 128-lane column block of
+    [B, S, H*D] operands; 0 for a head-major call) and, once per distinct
+    shape, a log line of the layout and loop structure chosen for it."""
     over = tile_overcompute(S, block_q, block_k, chunk, causal)
     default_registry().gauge("attention/flash_tile_overcompute").set(over)
-    plan = (S, D, jnp.dtype(dtype).name, causal, block_q, block_k, chunk)
+    default_registry().gauge("attention/flash_heads_per_block").set(
+        heads_per_block)
+    plan = (S, D, jnp.dtype(dtype).name, causal, block_q, block_k, chunk,
+            heads_per_block)
     if plan not in _plans_logged:
         _plans_logged.add(plan)
         strip = 0 if chunk else _pick_strip(block_q)
+        layout = (f"[B, S, H*D] column blocks of {heads_per_block} heads"
+                  if heads_per_block else "[B*H, S, D] head-major")
         logger.info(
             f"flash attention S={S} D={D} {plan[2]} causal={causal}: "
+            f"layout {layout}, "
             f"block_q={block_q} block_k={block_k} strip={strip} "
             f"chunk={chunk} scale "
             f"{'on q' if _scale_folds(scale) else 'on scores'}"
             f", computes {over:.3f} x the scores needed")
 
 
+def _pick_block(S, requested, interpret, whole_row):
+    """The widest grid block S allows, up to 1024 rows for the whole-row
+    kernels and 512 for the chunked ones: a grid step has a fixed cost
+    (at [160, 1024, 64] blocks of 256 measured 1.8 x the forward time of
+    blocks of 512, and those 1.2 x blocks of 1024: PERF.md, PR 28), and
+    the causal structure finer than a block lives INSIDE it
+    (``_pick_strip``). For S not divisible by 512 take the largest
+    power-of-two divisor so e.g. S=768/1280/2560 keep the flash kernel
+    instead of silently materializing [S, S] scores in the reference
+    fallback. 0: no block tiles S."""
+    if requested:
+        return requested
+    top = 64 if interpret else 1024 if whole_row else 512
+    for cand in (1024, 512, 256, 128, 64, 32):
+        if cand <= top and S % cand == 0:
+            return cand
+    # irregular short sequences (e.g. S=80): one block spanning S keeps
+    # the kernel path, matching the old min(block, S) behavior
+    return S if S <= top else 0
+
+
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=None, chunk=None):
-    """[B, H, S, D] flash attention. Falls back to the jnp reference for
+    """[B, H, S, D] (head-major) flash attention: every kernel family,
+    grouped-query K/V included. Falls back to the jnp reference for
     shapes the kernel can't tile (tiny S/D in unit tests). ``chunk``
     forces the long-S chunked kernels (auto-selected past the VMEM row
-    budget); it must divide S and be a multiple of both block sizes."""
+    budget); it must divide S and be a multiple of both block sizes.
+    A caller whose q, k, v are columns of [B, S, H*D] arrays — a fused
+    projection — has ``flash_attention_bse``, which spares the
+    transposes into this layout where the whole-row kernels run."""
     B, H, S, D = q.shape
     scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
     if interpret is None:
         interpret = _interpret_default()
     itemsize = jnp.dtype(q.dtype).itemsize
     whole_row = chunk is None and S * D * itemsize <= _UNCHUNKED_ROW_BYTES
-    # The widest grid block S allows, up to 1024 rows for the whole-row
-    # kernels and 512 for the chunked ones: a grid step has a fixed cost
-    # (at [160, 1024, 64] blocks of 256 measured 1.8 x the forward time of
-    # blocks of 512, and those 1.2 x blocks of 1024: PERF.md, PR 28), and
-    # the causal structure finer than a block lives INSIDE it
-    # (``_pick_strip``). For S not divisible by 512 take the largest
-    # power-of-two divisor so e.g. S=768/1280/2560 keep the flash kernel
-    # instead of silently materializing [S, S] scores in the reference
-    # fallback.
-    def pick_block(requested):
-        if requested:
-            return requested
-        top = 64 if interpret else 1024 if whole_row else 512
-        for cand in (1024, 512, 256, 128, 64, 32):
-            if cand <= top and S % cand == 0:
-                return cand
-        # irregular short sequences (e.g. S=80): one block spanning S keeps
-        # the kernel path, matching the old min(block, S) behavior
-        return S if S <= top else 0
-    block_q = pick_block(block_q)
-    block_k = pick_block(block_k)
+    block_q = _pick_block(S, block_q, interpret, whole_row)
+    block_k = _pick_block(S, block_k, interpret, whole_row)
     Hkv = k.shape[1]
     assert v.shape[1] == Hkv and H % Hkv == 0, (q.shape, k.shape)
 
@@ -948,3 +1212,45 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     o = _flash_attention(qf, kf, vf, scale, causal, block_q, block_k, chunk,
                          bool(interpret), H, Hkv)
     return o.reshape(B, H, S, D)
+
+
+def flash_attention_bse(q, k=None, v=None, *, heads, causal=False,
+                        scale=None, block_q=None, block_k=None,
+                        interpret=None):
+    """Flash attention on the model's own layout: q, k, v [B, S, H*D] in,
+    [B, S, H*D] out — or, with k and v None, ``q`` the fused projection
+    [B, S, 3*H*D], whose thirds are read IN PLACE where they start on a
+    lane block (H*D % 128 == 0; split here otherwise).
+
+    Where the whole-row kernels take the shape (a row within
+    ``_UNCHUNKED_ROW_BYTES``, 128 % D == 0 or D % 128 == 0) they address
+    heads as 128-lane column blocks of these arrays, 128 // D heads a
+    block, and no head-major copy of q, k, v, o or of their gradients
+    exists. Every other shape is transposed into ``flash_attention``'s
+    [B, H, S, D] and back. Which it was: the plan's log line and the gauge
+    ``attention/flash_heads_per_block`` (0: head-major)."""
+    from deepspeed_tpu.ops.attention import from_head_major, to_head_major
+    operands = (q,) if k is None else (q, k, v)
+    S = q.shape[1]
+    _, _, E, plan = _columns(operands, heads)
+    D = E // heads
+    if interpret is None:
+        interpret = _interpret_default()
+    itemsize = jnp.dtype(q.dtype).itemsize
+    blocks = [_pick_block(S, b, interpret, True) for b in (block_q, block_k)]
+    # a column block is max(D, 128) lanes wide whatever D is: the row the
+    # kernels hold whole may not outgrow what a padded D=64 row takes
+    columns = plan and S * D * itemsize <= _UNCHUNKED_ROW_BYTES \
+        and S * plan[2] * itemsize <= 2 * _UNCHUNKED_ROW_BYTES \
+        and all(b and S % b == 0 for b in blocks)
+    if k is None and not (columns and E % plan[2] == 0):
+        operands = tuple(jnp.split(q, 3, axis=-1))
+    if not columns:
+        return from_head_major(flash_attention(
+            *(to_head_major(t, heads) for t in operands), causal=causal,
+            scale=scale, block_q=block_q, block_k=block_k,
+            interpret=interpret))
+    scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
+    _note_plan(S, D, q.dtype, scale, causal, *blocks, 0, plan[0])
+    return _flash_attention_cols(operands, heads, scale, causal, *blocks,
+                                 bool(interpret))

@@ -121,11 +121,13 @@ def annotate(tag):
     ``post_attn_norm``, ``norm`` (models/llama.py) are the detail table's
     remaining tags.
 
-    Beside the scopes, the flash kernels leave one trace-time GAUGE in
+    Beside the scopes, the flash kernels leave two trace-time GAUGES in
     the registry, ``attention/flash_tile_overcompute`` (score elements
-    the chosen loops compute over those the softmax needs): no benchmark
-    metric reads it; it says whether the strip walk engaged for a
-    shape."""
+    the chosen loops compute over those the softmax needs: whether the
+    strip walk engaged for a shape) and
+    ``attention/flash_heads_per_block`` (heads a 128-lane column block of
+    the model's own [B, S, H*D] operands, 0 for a head-major call): no
+    benchmark metric reads them."""
     import jax
     return jax.named_scope(tag)
 

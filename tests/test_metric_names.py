@@ -205,10 +205,12 @@ def test_zero_gather_edge_metric_names_documented():
         assert name in _package_source(), name
 
 
-def test_flash_tile_overcompute_gauge_documented():
-    """The flash kernels' trace-time engagement gauge (ISSUE 28) stays
-    documented AND emitted."""
-    name = "attention/flash_tile_overcompute"
+@pytest.mark.parametrize("name", ["attention/flash_tile_overcompute",
+                                  "attention/flash_heads_per_block"])
+def test_flash_engagement_gauges_documented(name):
+    """The flash kernels' trace-time engagement gauges (ISSUE 28: the
+    loops' overcompute; ISSUE 30: heads a column block, 0 head-major)
+    stay documented AND emitted."""
     assert name in documented_metric_names(), (
         f"{name} missing from the docs/observability.md train table")
     assert name in _package_source(), name

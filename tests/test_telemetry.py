@@ -310,6 +310,47 @@ def test_train_step_scope_names_reach_the_compiled_text():
         assert re.search(r'op_name="[^"]*' + re.escape(scope), hlo), scope
 
 
+@pytest.mark.parametrize("n_embd,n_head,per_block,layout", [
+    (256, 4, 2, "[B, S, H*D] column blocks of 2 heads"),    # head_dim 64
+    (256, 2, 1, "[B, S, H*D] column blocks of 1 heads"),    # head_dim 128
+    (192, 4, 0, "[B*H, S, D] head-major"),                  # head_dim 48
+], ids=["head_dim64", "head_dim128", "head_dim48-head_major"])
+def test_flash_heads_per_block_gauge_and_plan_line(n_embd, n_head, per_block,
+                                                   layout):
+    """``attention/flash_heads_per_block`` (ISSUE 30) says at trace time
+    whether a GPT-2 forward took the column-block flash path — heads a
+    128-lane block of the model's own [B, S, H*D] arrays — or went
+    head-major (0), and the plan's log line names the layout. (The package
+    logger does not propagate: a handler of its own, not caplog.)"""
+    import importlib
+    import logging
+    import jax.numpy as jnp
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+    from deepspeed_tpu.utils.logging import logger as dlog
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    fa._plans_logged.clear()
+    cfg = GPT2Config(vocab_size=256, n_positions=128, n_embd=n_embd,
+                     n_layer=1, n_head=n_head, use_flash=True,
+                     dtype=jnp.float32)
+    model = GPT2LMHeadModel(cfg)
+    ids = jax.ShapeDtypeStruct((2, 128), jnp.int32)
+    records = []
+    handler = logging.Handler()
+    handler.emit = records.append
+    dlog.addHandler(handler)
+    try:
+        params = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)
+        default_registry().gauge("attention/flash_heads_per_block").set(-1)
+        jax.eval_shape(model.apply, params, ids)
+    finally:
+        dlog.removeHandler(handler)
+    assert default_registry().peek_gauge(
+        "attention/flash_heads_per_block") == per_block
+    plans = [r.getMessage() for r in records
+             if r.getMessage().startswith("flash attention S=128")]
+    assert plans and all(f"layout {layout}," in p for p in plans), plans
+
+
 def test_moe_scope_names_and_gauges_reach_the_step():
     """ISSUE 27's names: a LLaMA model with experts and QK-norm carries
     ``moe_router`` / ``moe_dispatch`` / ``moe_gmm*`` / ``moe_combine`` /

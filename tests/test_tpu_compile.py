@@ -101,6 +101,20 @@ def kernel_names(text):
     return set(re.findall(r'kernel_name = "([^"]+)"', text))
 
 
+def flash_calls(hlo):
+    """The compiled text's Pallas calls, cut before their serialized
+    bodies: result shapes, operands and their layout constraints."""
+    return [ln.split("backend_config=")[0] for ln in hlo.splitlines()
+            if "tpu_custom_call" in ln]
+
+
+def head_major_operands(calls):
+    """Shapes [.., S, 64] among the calls' operands and results: a
+    head-major block of head_dim 64, padded to 128 lanes in HBM."""
+    return [shape for ln in calls
+            for shape in re.findall(r"\w+\[[\d,]*,64\]", ln)]
+
+
 # ------------------------------------------------------- main-path kernels
 
 @pytest.mark.parametrize("shape", [(chip_smoke.BATCH, 20, 1024, 64),
@@ -120,6 +134,37 @@ def test_flash_attention_fwd_and_grad_compile_at_774m_shape(shape):
 
     text, _ = compile_on_chip(grads, *qkv)
     assert kernel_names(text) == {"_fwd_kernel", "_bwd_fused_kernel"}
+
+
+@pytest.mark.parametrize("operands,heads", [
+    ((SDS((chip_smoke.BATCH, 1024, 3840), BF16),), 20),
+    ((SDS((4, 1024, 1600), BF16),) * 3, 25),
+    ((SDS((2, 2048, 3840), BF16),), 20),
+    ((SDS((4, 1024, 6144), BF16),), 16),
+], ids=["large-qkv-in-place", "xl-q-k-v-25-heads", "s2048", "head_dim128"])
+def test_flash_attention_column_blocks_compile_at_gpt2_shapes(operands,
+                                                              heads):
+    """The same two kernels on the model's own layout (ISSUE 30): heads as
+    128-lane column blocks of [B, S, H*D]. GPT-2 large's fused projection
+    read in place (E = 10 lane blocks: three views of one array), XL's q,
+    k, v apart with 25 heads (E = 12.5 blocks: the last block ragged, its
+    second head skipped), the longest row, and a head a block at head_dim
+    128. No operand or result is head-major: nothing of [.., 1024, 64]."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_bse
+    from deepspeed_tpu.telemetry.registry import default_registry
+
+    def grads(*a):
+        return jax.grad(lambda *b: flash_attention_bse(
+            *b, heads=heads, causal=True).astype(F32).sum(),
+            argnums=tuple(range(len(a))))(*a)
+
+    text, compiled = compile_on_chip(grads, *operands)
+    assert kernel_names(text) == {"_fwd_kernel", "_bwd_fused_kernel"}
+    width = operands[0].shape[-1] // (3 if len(operands) == 1 else 1)
+    assert default_registry().peek_gauge(
+        "attention/flash_heads_per_block") == 128 // min(width // heads, 128)
+    calls = flash_calls(compiled.as_text())
+    assert len(calls) == 2 and not head_major_operands(calls)
 
 
 def test_flash_attention_chunked_fwd_and_grad_compile_at_olmoe_shape():
@@ -357,6 +402,31 @@ def test_train_step_scope_names_reach_the_compiled_text(one_chip_step):
     assert_scope_names(one_chip_step[1].as_text())
 
 
+def assert_attention_in_the_models_layout(hlo):
+    """ISSUE 30 in a compiled step of GPT-2 large: the flash calls take
+    [B, S, H*D] operands (nothing head-major), and inside the two layer
+    scans XLA builds NO ``copy`` under ``blk/attn/`` (20 a layer on one
+    chip and 11 on four before: q, k, v, o, do, dq, dk, dv into head-major
+    and back) and no ``split`` — E = 10 lane blocks, so the fused
+    projection is read in place."""
+    calls = flash_calls(hlo)
+    assert len(calls) == 2 and not head_major_operands(calls), calls
+    layer_scans = [lines for lines in hlo_text.loop_bodies(hlo).values()
+                   if any("/blk/" in ln for ln in lines)]
+    assert len(layer_scans) == 2        # forward and backward
+    for lines in layer_scans:
+        attn = [ln for ln in lines
+                if re.search(r'op_name="[^"]*/blk/attn/', ln)]
+        assert attn
+        assert not hlo_text.instructions(attn, "copy")
+        assert not [ln for ln in attn if re.search(
+            r'op_name="[^"]*/blk/attn/[^"]*split', ln)]
+
+
+def test_one_chip_step_keeps_attention_in_the_models_layout(one_chip_step):
+    assert_attention_in_the_models_layout(one_chip_step[1].as_text())
+
+
 def test_one_chip_train_step_emits_no_gather_edge(one_chip_step, monkeypatch):
     """With a data axis of one the ZeRO-3 gather edge does not exist: the
     step lowers to the same text, so the same instructions per opcode, as
@@ -385,6 +455,8 @@ def test_train_step_compiles_for_four_chips_sharded(one_chip_step):
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo and "all-gather" in hlo
     assert_scope_names(hlo)     # inside the shard_map too
+    # ... and there the column-block path engaged, two heads a block
+    assert_attention_in_the_models_layout(hlo)
     assert not hlo_text.instructions(hlo.splitlines(), "all-to-all")
     layer_scans = [lines for lines in hlo_text.loop_bodies(hlo).values()
                    if any("/blk/" in ln for ln in lines)]
@@ -411,6 +483,10 @@ def test_olmoe_step_compiles_for_one_chip_with_its_scopes_and_fits():
     assert kernel_names(lowered.as_text()) == {
         "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
         "_bwd_dkv_kernel_chunked", "kernel"}
+    # head-major, from ``models/llama.py``: ISSUE 30's path is bypassed
+    from deepspeed_tpu.telemetry.registry import default_registry
+    assert default_registry().peek_gauge(
+        "attention/flash_heads_per_block") == 0
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     assert 6.0e9 < ma.argument_size_in_bytes < 6.5e9      # 625.6M x 10 B
