@@ -26,6 +26,29 @@ Switch load-balancing loss ``E * sum_e f_e P_e``; ``moe_z``: the mean of
 ``logsumexp(logits)^2``); their unweighted values and the routing's balance
 go to the ``stats`` collection, which the engine carries out of the step and
 folds into the ``moe/*`` gauges at a ``steps_per_print`` boundary.
+
+**One rank's share of an expert-parallel layer** (``experts_held`` /
+``expert_share``): the layer holds experts ``[held * share, held * (share +
+1))`` of ``num_experts``. The router is as wide as ever and the top-k is
+renormalised over the k chosen; only the assignments whose expert is held
+are rows here, sorted to the front (every other assignment carries the
+sentinel key ``held``), and the output is the PARTIAL sum over the held
+experts — nothing stands in for the absent experts, their rows or an
+all-to-all; the shares of all ranks add up to the whole layer. Rows held
+are data, so the row arrays are SLABS of a static ``_HELD_ROWS_SLACK`` times
+the mean share: the rows held fit the first slab unless the routing sends
+more than that here; then a ``lax.cond`` takes a second slab, and past two a
+scan takes as many further slabs as the rows fill, one at a time, every slab
+past the first recomputed in the backward pass so that none of them keeps
+anything (exact for every routing, all ``T x k`` rows included;
+the grouped matmul stops at the sum of a slab's group sizes;
+``moe_held_slabs`` in ``stats`` says how many a layer took).
+With fewer than ``T x k`` rows the two row moves are no permutations any
+more: ``tokens_to_rows`` is a gather and its transpose ``rows_to_tokens`` a
+segmented sum over rows sorted by token (a second sort, shifted adds over
+runs of at most k rows, one gather of the runs' last rows) — each the other's
+backward pass, neither a scatter-add. A ``shared_d_ff`` adds one SwiGLU
+expert every token passes through, under a sigmoid gate (``moe_shared``).
 """
 
 import functools
@@ -45,15 +68,31 @@ from deepspeed_tpu.telemetry.spans import annotate
 STAT_GAUGES = {"moe_aux_loss": "moe/aux_loss", "moe_z_loss": "moe/z_loss",
                "moe_rows_max_over_mean": "moe/rows_max_over_mean",
                "moe_dropped_rows": "moe/dropped_rows"}
+# ... and by a layer that holds a share of its experts
+HELD_STAT_GAUGES = dict(STAT_GAUGES,
+                        moe_rows_held_share="moe/rows_held_share",
+                        moe_held_slabs="moe/held_slabs")
+# static length of a held layer's row arrays over the mean rows held (4 did
+# not fit the one cell that holds a share: PERF.md Findings PR 31)
+_HELD_ROWS_SLACK = 2
 
 
-def route(logits, k, norm_topk_prob):
+def route(logits, k, norm_topk_prob, pin_choice=False):
     """(weights [T, k] float32, experts [T, k] int32, probabilities [T, E])
     of float32 router logits [T, E]: softmax over the experts, the k
     largest probabilities as they are (renormalised to sum to one only when
-    ``norm_topk_prob``)."""
+    ``norm_topk_prob``). ``pin_choice``: the experts carry the checkpoint
+    name ``moe_experts`` and the weights are the probabilities AT them, so
+    that a remat policy which saves that name makes a recomputed forward
+    pass route as the first one did (a tie between the k-th and the
+    (k+1)-th probability can break the other way when XLA fuses the
+    recomputation differently, and the backward pass would then be another
+    routing's)."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     top_w, top_e = jax.lax.top_k(probs, k)
+    if pin_choice:
+        top_e = checkpoint_name(top_e, "moe_experts")
+        top_w = jnp.take_along_axis(probs, top_e, axis=1)
     if norm_topk_prob:
         top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
     return top_w, top_e.astype(jnp.int32), probs
@@ -108,10 +147,79 @@ def _unsort_rows_bwd(order, g):
 unsort_rows.defvjp(_unsort_rows_fwd, _unsort_rows_bwd)
 
 
+def _shift_rows(t, d, fill):
+    """Row i takes row i - d; the first d rows take ``fill``."""
+    head = jnp.full((d,) + t.shape[1:], fill, t.dtype)
+    return jnp.concatenate([head, t[:-d]], axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def tokens_to_rows(x, tok, k):
+    """Token rows [T, H] -> rows [M, H]: row r is token ``tok[r]``, zero
+    where ``tok[r] == T`` (no token: a row past the rows held). A token
+    appears in at most ``k`` rows."""
+    return jnp.take(x, tok, axis=0, mode="fill", fill_value=0)
+
+
+def _tokens_to_rows_fwd(x, tok, k):
+    return tokens_to_rows(x, tok, k), (tok, x.shape[0])
+
+
+def _tokens_to_rows_bwd(k, saved, g):
+    tok, T = saved
+    return rows_to_tokens(g, tok, T, k), None
+
+
+tokens_to_rows.defvjp(_tokens_to_rows_fwd, _tokens_to_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def rows_to_tokens(rows, tok, T, k):
+    """Rows [M, H] -> token rows [T, H]: token t is the sum of the rows r
+    with ``tok[r] == t`` (at most ``k`` of them; rows with ``tok[r] == T``
+    go nowhere). The transpose of ``tokens_to_rows`` without a scatter-add:
+    the rows sorted by token, an inclusive segmented sum by shifted adds
+    (log2 k of them), and a gather of each token's last row."""
+    by_tok = jnp.argsort(tok, stable=True)
+    seg = jnp.take(tok, by_tok)
+    z = jnp.take(rows.astype(jnp.float32), by_tok, axis=0)
+    d = 1
+    while d < k:
+        same = (seg == _shift_rows(seg, d, -1))[:, None]
+        z = z + jnp.where(same, _shift_rows(z, d, 0.0), 0.0)
+        d *= 2
+    tokens = jnp.arange(T, dtype=seg.dtype)
+    # all T x M comparisons, fused into their row sums: a binary search is
+    # log2(M) dependent gathers of T scalars, 26 ms a call on a v5e
+    last = jnp.searchsorted(seg, tokens, side="right",
+                            method="compare_all").astype(jnp.int32) - 1
+    has = jnp.take(seg, jnp.maximum(last, 0)) == tokens
+    out = jnp.take(z, jnp.maximum(last, 0), axis=0)
+    return jnp.where(has[:, None], out, 0.0).astype(rows.dtype)
+
+
+def _rows_to_tokens_fwd(rows, tok, T, k):
+    return rows_to_tokens(rows, tok, T, k), tok
+
+
+def _rows_to_tokens_bwd(T, k, tok, g):
+    return tokens_to_rows(g, tok, k), None
+
+
+rows_to_tokens.defvjp(_rows_to_tokens_fwd, _rows_to_tokens_bwd)
+
+
 class DroplessMoE(nn.Module):
     """[B, S, H] -> [B, S, H] through ``num_experts`` SwiGLU experts of
     width ``d_ff``, ``k`` a token. ``balance_coeff`` / ``z_coeff`` weight
-    the two auxiliary losses sown into ``losses``."""
+    the two auxiliary losses sown into ``losses``. ``experts_held`` > 0:
+    this layer holds that many experts, the ``expert_share``-th such group
+    of the ``num_experts`` the router chooses among, and returns their
+    partial sum (module docstring). ``shared_d_ff`` > 0: plus one shared
+    expert of that width under a sigmoid gate, in full. ``pin_choice``: a
+    caller that recomputes this layer under a remat policy which saves the
+    name ``moe_experts`` asks for it (``route``); whether a share is held
+    has nothing to do with it."""
     num_experts: int
     k: int
     d_ff: int
@@ -120,17 +228,22 @@ class DroplessMoE(nn.Module):
     z_coeff: float = 0.001
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    experts_held: int = 0            # 0: all of them
+    expert_share: int = 0
+    shared_d_ff: int = 0
+    pin_choice: bool = False
 
     @nn.compact
     def __call__(self, x):
         B, S, H = x.shape
         E, K, F = self.num_experts, self.k, self.d_ff
+        held = self.experts_held or E
         T = B * S
         init = nn.initializers.normal(0.02)
         wg = self.param("router", init, (H, E), self.param_dtype)
-        w_gate = self.param("gate_proj", init, (E, H, F), self.param_dtype)
-        w_up = self.param("up_proj", init, (E, H, F), self.param_dtype)
-        w_down = self.param("down_proj", init, (E, F, H), self.param_dtype)
+        w_gate = self.param("gate_proj", init, (held, H, F), self.param_dtype)
+        w_up = self.param("up_proj", init, (held, H, F), self.param_dtype)
+        w_down = self.param("down_proj", init, (held, F, H), self.param_dtype)
         xt = x.reshape(T, H)
 
         with annotate("moe_router"):
@@ -140,39 +253,147 @@ class DroplessMoE(nn.Module):
             # can tie to bf16 rounding
             logits = jnp.dot(xt.astype(jnp.float32), wg.astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
-            top_w, top_e, probs = route(logits, K, self.norm_topk_prob)
+            # (the default keeps OLMoE's call and program)
+            top_w, top_e, probs = route(
+                logits, K, self.norm_topk_prob,
+                **({"pin_choice": True} if self.pin_choice else {}))
             chosen = jax.nn.one_hot(top_e, E, dtype=jnp.float32).sum(axis=1)
             group_sizes = chosen.sum(axis=0).astype(jnp.int32)      # [E]
             balance = load_balance_loss(probs, chosen)
             z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
 
-        with annotate("moe_dispatch"):
-            order, inverse = sort_by_expert(top_e)
-            xs = gather_rows(xt, order, inverse, K)                 # [T*K, H]
         dt = self.dtype
-        gate = grouped_matmul(xs, w_gate.astype(dt), group_sizes)
-        up = grouped_matmul(xs, w_up.astype(dt), group_sizes)
-        with annotate("moe_act"):
-            h = checkpoint_name(nn.silu(gate) * up, "mlp_fc")
-        ys = grouped_matmul(h, w_down.astype(dt), group_sizes)
-        with annotate("moe_combine"):
-            ya = unsort_rows(ys, order, inverse).reshape(T, K, H)
-            y = jnp.sum(ya.astype(jnp.float32) * top_w[:, :, None], axis=1)
+        weights = tuple(w.astype(dt) for w in (w_gate, w_up, w_down))
+        if held == E:
+            with annotate("moe_dispatch"):
+                order, inverse = sort_by_expert(top_e)
+                xs = gather_rows(xt, order, inverse, K)             # [T*K, H]
+            ys = self._experts(xs, weights, group_sizes)
+            with annotate("moe_combine"):
+                ya = unsort_rows(ys, order, inverse).reshape(T, K, H)
+                y = jnp.sum(ya.astype(jnp.float32) * top_w[:, :, None],
+                            axis=1)
+                y = y.astype(dt).reshape(B, S, H)
+        else:
+            lo = held * self.expert_share
+            group_sizes = group_sizes[lo:lo + held]
+            rows_held = jnp.sum(group_sizes)
+            with annotate("moe_dispatch"):
+                local = top_e - lo
+                key = jnp.where((local >= 0) & (local < held), local, held)
+                order = jnp.argsort(key.reshape(-1),
+                                    stable=True).astype(jnp.int32)
+            cap = _HELD_ROWS_SLACK * T * K * held // E
+            cap = min(T * K, -(-max(cap, 1) // 8) * 8)     # whole sublanes
+            slabs = -(-T * K // cap)
+            order = jnp.pad(order, (0, slabs * cap - T * K))
+            operands = (xt, weights, group_sizes, order, top_w, rows_held)
+            slab = functools.partial(self._held_rows, cap)
+            # the first slab always; the rows held fit it unless the
+            # routing sends more than _HELD_ROWS_SLACK times its share here
+            y = slab(0, *operands)
+            if slabs > 1:
+                # ... then a second slab, recomputed in the backward pass
+                # (nothing of it is kept): a router that drifts towards the
+                # held experts pays one slab more and nothing for the rest
+                y = jax.lax.cond(
+                    cap < rows_held,
+                    lambda y, *operands: y + jax.checkpoint(
+                        functools.partial(slab, cap))(*operands),
+                    lambda y, *operands: y, y, *operands)
+            if slabs > 2:
+                # ... and past twice that as many further slabs as the rows
+                # fill, one at a time, the rest skipped (all T x k rows
+                # included)
+                def further(y, *operands):
+                    # the checkpoint round the cond, the operands closed
+                    # over: what the backward pass keeps of a slab is its
+                    # start (a cond's own residuals would be stacked once a
+                    # slab, the weights among them)
+                    @jax.checkpoint
+                    def one(start):
+                        return jax.lax.cond(
+                            start < rows_held,
+                            lambda: slab(start, *operands),
+                            lambda: jnp.zeros((T, H), jnp.float32))
+
+                    return jax.lax.scan(
+                        lambda y, start: (y + one(start), None), y,
+                        jnp.arange(2, slabs, dtype=jnp.int32) * cap)[0]
+
+                y = jax.lax.cond(2 * cap < rows_held, further,
+                                 lambda y, *operands: y, y, *operands)
             y = y.astype(dt).reshape(B, S, H)
+
+        if self.shared_d_ff:
+            Fs = self.shared_d_ff
+            s_gate = self.param("shared_gate_proj", init, (H, Fs),
+                                self.param_dtype)
+            s_up = self.param("shared_up_proj", init, (H, Fs),
+                              self.param_dtype)
+            s_down = self.param("shared_down_proj", init, (Fs, H),
+                                self.param_dtype)
+            w_sg = self.param("shared_expert_gate", init, (H, 1),
+                              self.param_dtype)
+            with annotate("moe_shared"):
+                hs = nn.silu(x @ s_gate.astype(dt)) * (x @ s_up.astype(dt))
+                open_ = jax.nn.sigmoid(
+                    (x @ w_sg.astype(dt)).astype(jnp.float32))
+                y = y + (open_ * (hs @ s_down.astype(dt))).astype(dt)
 
         if self.is_mutable_collection("losses"):
             self.sow("losses", "moe_balance", self.balance_coeff * balance)
             self.sow("losses", "moe_z", self.z_coeff * z)
         if self.is_mutable_collection("stats"):
             rows = group_sizes.astype(jnp.float32)
-            for name, value in (
-                    ("moe_aux_loss", balance), ("moe_z_loss", z),
-                    ("moe_rows_max_over_mean",
-                     jnp.max(rows) * E / (T * K)),
-                    # rows routed less rows the grouped matmuls computed
-                    ("moe_dropped_rows", T * K - jnp.sum(rows))):
+            # rows routed (here) less rows the grouped matmuls computed
+            routed = T * K if held == E else rows_held
+            stats = [("moe_aux_loss", balance), ("moe_z_loss", z),
+                     ("moe_rows_max_over_mean", jnp.max(rows) * E / (T * K)
+                      if held == E else
+                      jnp.max(rows) * held / jnp.maximum(jnp.sum(rows), 1.0)),
+                     ("moe_dropped_rows", routed - jnp.sum(rows))]
+            if held != E:
+                stats += [("moe_rows_held_share", jnp.sum(rows) / (T * K)),
+                          # slabs of rows this layer took (1: they fit the
+                          # first)
+                          ("moe_held_slabs", jnp.maximum(
+                              -(-rows_held // cap), 1).astype(jnp.float32))]
+            for name, value in stats:
                 self.sow("stats", name, jax.lax.stop_gradient(value))
         if self.is_mutable_collection("intermediates"):
             self.sow("intermediates", "top_e", top_e)
         return checkpoint_name(y, "mlp_proj")
 
+    def _experts(self, xs, weights, group_sizes):
+        """Rows in expert order through their experts' SwiGLU."""
+        w_gate, w_up, w_down = weights
+        gate = grouped_matmul(xs, w_gate, group_sizes)
+        up = grouped_matmul(xs, w_up, group_sizes)
+        with annotate("moe_act"):
+            h = checkpoint_name(nn.silu(gate) * up, "mlp_fc")
+        return grouped_matmul(h, w_down, group_sizes)
+
+    def _held_rows(self, M, start, xt, weights, group_sizes, order, top_w,
+                   rows_held):
+        """The held experts' partial sum [T, H] float32 over the ``M``
+        sorted assignments from ``start`` on: each group's rows within the
+        slab; rows past the rows held belong to no token, reach no expert
+        (the grouped matmul stops at the sum of its group sizes) and are
+        zeroed on the way back."""
+        T, K = top_w.shape
+        with annotate("moe_dispatch"):
+            rows = jax.lax.dynamic_slice(order, (start,), (M,))
+            valid = start + jnp.arange(M, dtype=jnp.int32) < rows_held
+            tok = jnp.where(valid, rows // K, T)
+            ends = jnp.cumsum(group_sizes)
+            group_sizes = jnp.clip(ends, start, start + M) \
+                - jnp.clip(ends - group_sizes, start, start + M)
+            xs = tokens_to_rows(xt, tok, K)                         # [M, H]
+        ys = self._experts(xs, weights, group_sizes)
+        with annotate("moe_combine"):
+            w_row = jnp.take(top_w.reshape(-1), rows)
+            # zeroed BEFORE the product: what the grouped matmul left
+            # undefined must reach neither the sum nor w_row's cotangent
+            ys = jnp.where(valid[:, None], ys.astype(jnp.float32), 0.0)
+            return rows_to_tokens(ys * w_row[:, None], tok, T, K)

@@ -1,0 +1,188 @@
+"""The gated delta rule, chunked: the recurrence of a Gated DeltaNet layer.
+
+A value head keeps a state ``S`` in R^(Dk x Dv) and reads it token by token
+(Gated Delta Networks, arXiv:2412.06464):
+
+    S <- exp(g_t) * S
+    S <- S + k_t (beta_t * (v_t - S^T k_t))^T
+    o_t = S^T q_t
+
+``gated_delta_rule`` computes the same outputs in CHUNKS of ``CHUNK`` tokens
+(the paper's WY / UT form, as HF's ``torch_chunk_gated_delta_rule`` has it).
+Inside a chunk, with ``G`` the running sum of ``g``, ``D_ij = exp(G_i - G_j)``
+and ``L`` the strictly lower part of ``(beta k) k^T * D``:
+
+    T = (I + L)^-1               ``unit_lower_inverse``
+    u = T (beta v),   w = T (beta k exp(G))
+
+and from chunk to chunk, with ``S`` the state the chunk starts from:
+
+    v' = u - w S
+    o  = (q exp(G)) S + tril(q k^T * D) v'
+    S <- exp(G_last) S + (k exp(G_last - G))^T v'
+
+Everything but that last three-line loop is computed for all chunks at once
+in batched matmuls; the loop is a ``lax.scan`` over the chunks, three small
+matmuls a step. The per-token recurrence is never run. The state, the gates
+and ``T`` are float32; the matmul operands are the inputs' dtype (bf16 in a
+training step) with float32 accumulation. The backward pass is JAX's own
+through the scan, except for the inverse, whose cotangent is the closed form
+``-T^T dT T^T``.
+
+Measured on a v5e and written down in PERF.md (Findings, PR 31): what the
+scan and its pieces cost at 2 x 8192 tokens, 32 value heads of 128 x 128.
+"""
+
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.telemetry.spans import annotate
+
+CHUNK = 64          # tokens a chunk (a power of two: the inverse doubles)
+_GROUP = 8          # chunks between two states kept for the backward pass
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _mm(a, b, precision=None):
+    return jnp.matmul(a, b, precision=precision,
+                      preferred_element_type=jnp.float32)
+
+
+@jax.custom_vjp
+def unit_lower_inverse(lower):
+    """``(I + L)^-1`` for strictly lower-triangular ``L`` [..., C, C]
+    (float32, C a power of two), by block forward substitution: with ``X``
+    the inverse of the block diagonal at block size s and ``C_s`` the lower
+    left s x s blocks of the 2s blocks, ``X - X C_s X`` is the inverse at
+    2s — exactly, since ``(C_s X)^2 = 0``. log2(C) steps of two batched
+    C x C matmuls; as stable as forward substitution, whose operations it
+    regroups (a Neumann product of powers of L is not: its terms grow
+    where neighbouring keys are alike)."""
+    C = lower.shape[-1]
+    assert C & (C - 1) == 0, C
+    row = jnp.arange(C)[:, None]
+    col = jnp.arange(C)[None, :]
+    x = jnp.broadcast_to(jnp.eye(C, dtype=lower.dtype), lower.shape)
+    s = 1
+    while s < C:
+        corner = (row // (2 * s) == col // (2 * s)) \
+            & (row % (2 * s) >= s) & (col % (2 * s) < s)
+        c_s = jnp.where(corner, lower, 0.0)
+        x = x - _mm(x, _mm(c_s, x, _HIGHEST), _HIGHEST)
+        s *= 2
+    return x
+
+
+def _unit_lower_inverse_fwd(lower):
+    x = unit_lower_inverse(lower)
+    return x, x
+
+
+def _unit_lower_inverse_bwd(x, dx):
+    xt = jnp.swapaxes(x, -1, -2)
+    return (-_mm(xt, _mm(dx, xt, _HIGHEST), _HIGHEST),)
+
+
+unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+
+
+def _chunks(t, n):
+    """[B, S, H, ...] -> [N, B, H, C, ...]: chunk-major for the scan."""
+    B, S, H = t.shape[:3]
+    t = t.reshape(B, n, S // n, H, *t.shape[3:])
+    return jnp.moveaxis(jnp.moveaxis(t, 1, 0), 3, 2)
+
+
+def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
+    """o [B, S, Hv, Dv] of the gated delta rule from a zero state.
+
+    q, k [B, S, Hk, Dk] (k L2-normalised, q normalised and scaled, as the
+    layer does before calling); v [B, S, Hv, Dv]; g [B, S, Hv] float32, the
+    log of the decay (<= 0); beta [B, S, Hv] float32 in (0, 1). Key head i
+    serves value heads [i * Hv / Hk, (i + 1) * Hv / Hk). Any S: the tail
+    of a last, short chunk is padded with tokens that write nothing."""
+    B, S, Hv, Dv = v.shape
+    dt = v.dtype
+    rep = Hv // k.shape[2]
+    pad = (-S) % chunk
+    if pad:
+        q, k, v, g, beta = (jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (
+            t.ndim - 2)) for t in (q, k, v, g, beta))
+    n = (S + pad) // chunk
+    f32 = jnp.float32
+
+    with annotate("gdn_scan_prep"):
+        if rep > 1:
+            q = jnp.repeat(q, rep, axis=2)
+            k = jnp.repeat(k, rep, axis=2)
+        q, k, v = (_chunks(t, n) for t in (q, k, v))       # [N, B, H, C, D]
+        g, beta = (_chunks(t.astype(f32), n) for t in (g, beta))  # [N,B,H,C]
+        G = jnp.cumsum(g, axis=-1)
+        row = jnp.arange(chunk)[:, None]
+        col = jnp.arange(chunk)[None, :]
+        # exp of a masked difference: nothing above the diagonal is formed
+        decay = jnp.exp(jnp.where(row >= col,
+                                  G[..., :, None] - G[..., None, :], -jnp.inf))
+        kb = (k.astype(f32) * beta[..., None]).astype(dt)
+        kt = jnp.swapaxes(k, -1, -2)
+        lower = jnp.where(row > col, _mm(kb, kt) * decay, 0.0)
+        T = unit_lower_inverse(lower).astype(dt)
+        u = _mm(T, (v.astype(f32) * beta[..., None]).astype(dt)).astype(dt)
+        eG = jnp.exp(G)[..., None]
+        w = _mm(T, (kb.astype(f32) * eG).astype(dt)).astype(dt)
+        qg = (q.astype(f32) * eG).astype(dt)
+        attn = jnp.where(row >= col, _mm(q, kt) * decay, 0.0).astype(dt)
+        G_last = G[..., -1:]
+        kd = (k.astype(f32) * jnp.exp(G_last - G)[..., None]).astype(dt)
+        d_last = jnp.exp(G_last)[..., None]                 # [N, B, H, 1, 1]
+
+    def step(S_, xs):
+        w_c, u_c, qg_c, attn_c, kd_c, dl = xs
+        Sb = S_.astype(dt)
+        v_new = (u_c - _mm(w_c, Sb)).astype(dt)
+        o = _mm(qg_c, Sb) + _mm(attn_c, v_new)
+        S_ = S_ * dl + _mm(jnp.swapaxes(kd_c, -1, -2), v_new)
+        return S_, o.astype(dt)
+
+    # the backward pass keeps the state at the start of every GROUP of
+    # chunks and runs a group's steps again (one state a chunk would be
+    # 512 MB a layer at 2 x 8192 tokens, 32 heads of 128 x 128)
+    group = next(g for g in (_GROUP, 4, 2, 1) if n % g == 0)
+
+    @jax.checkpoint
+    def steps(S_, xs):
+        return jax.lax.scan(step, S_, xs)
+
+    with annotate("gdn_scan"):
+        S0 = jnp.zeros((B, Hv, k.shape[-1], Dv), f32)
+        xs = tuple(t.reshape(n // group, group, *t.shape[1:])
+                   for t in (w, u, qg, attn, kd, d_last))
+        _, o = jax.lax.scan(steps, S0, xs)
+        o = o.reshape(n, *o.shape[2:])
+    with annotate("gdn_scan_prep"):
+        o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)       # [B, N, C, H, Dv]
+        return o.reshape(B, n * chunk, Hv, Dv)[:, :S]
+
+
+def gated_delta_recurrence(q, k, v, g, beta):
+    """The same outputs by the recurrence as written, token by token, in
+    float32: what the tests hold ``gated_delta_rule`` to. Not a path of
+    the program."""
+    f32 = jnp.float32
+    rep = v.shape[2] // k.shape[2]
+    q, k = (jnp.repeat(t.astype(f32), rep, axis=2) for t in (q, k))
+    v, g, beta = (t.astype(f32) for t in (v, g, beta))
+    B, S, H, Dv = v.shape
+
+    def step(S_, xs):
+        q_t, k_t, v_t, g_t, b_t = xs                       # [B, H, ...]
+        S_ = S_ * jnp.exp(g_t)[..., None, None]
+        read = jnp.einsum("bhkv,bhk->bhv", S_, k_t, precision=_HIGHEST)
+        delta = b_t[..., None] * (v_t - read)
+        S_ = S_ + k_t[..., :, None] * delta[..., None, :]
+        return S_, jnp.einsum("bhkv,bhk->bhv", S_, q_t, precision=_HIGHEST)
+
+    xs = tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta))
+    _, o = jax.lax.scan(step, jnp.zeros((B, H, k.shape[-1], Dv), f32), xs)
+    return jnp.moveaxis(o, 0, 1)

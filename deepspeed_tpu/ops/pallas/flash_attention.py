@@ -37,7 +37,16 @@ Two kernel families share the same per-tile math (`_fwd_block_step` /
   rides in revisited outputs; normalization happens in-kernel on the last
   chunk). This is how single-chip attention training reaches 32k context;
   beyond that, sequence parallelism shards S first
-  (deepspeed_tpu/parallel/ring_attention.py).
+  (deepspeed_tpu/parallel/ring_attention.py). Grouped-query K/V
+  ([B, Hkv, S, D], Hkv < H) go into all three chunked kernels AS THEY ARE
+  since PR 31: the K/V index maps fold a query head onto its group's row
+  (`_kv_row`), so K and V are never repeated in HBM, forward or backward;
+  dk and dv still leave the dkv kernel per QUERY head (fp32) and are
+  summed over a group's heads after it. Measured on a v5e at
+  (S 4096, head_dim 128, 16 / 16 heads: OLMoE's cell, chunk 1024) and at
+  (S 8192, head_dim 256, 16 query / 2 KV heads: Qwen3-Next's cell, chunk
+  512 = one block a grid step, `_CHUNK_ROW_BYTES` as it was); PERF.md
+  Findings PR 27 and PR 31 have the numbers.
 
 What a score tile costs beside its two (five, backward) MXU products is
 what these kernels are written around (per 512 x 512 tile at D=64 the
@@ -774,6 +783,17 @@ _flash_attention_cols.defvjp(_flash_attention_cols_fwd,
 
 # ------------------------------------------------- long-S chunked variants
 
+def _kv_row(heads, kv_heads):
+    """Row of the [B * kv_heads, S, D] K/V arrays that grid row ``b`` of
+    [B * heads] reads: its own under multi-head attention, its group's under
+    grouped-query attention (query heads are grouped consecutively per KV
+    head), so K and V are never repeated in HBM."""
+    if heads and kv_heads and heads != kv_heads:
+        rep = heads // kv_heads
+        return lambda b: (b // heads) * kv_heads + (b % heads) // rep
+    return lambda b: b
+
+
 def _fwd_kernel_chunked(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
                         *, scale, causal, block_q, block_k, chunk,
                         n_chunks):
@@ -823,9 +843,10 @@ def _fwd_kernel_chunked(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
 
 def _flash_fwd_chunked(q, k, v, scale, causal, block_q, block_k, chunk,
-                       interpret):
+                       interpret, heads=0, kv_heads=0):
     BH, S, D = q.shape
     n_chunks = S // chunk
+    kv = _kv_row(heads, kv_heads)
     kernel = functools.partial(_fwd_kernel_chunked, scale=scale,
                                causal=causal, block_q=block_q,
                                block_k=block_k, chunk=chunk,
@@ -835,8 +856,8 @@ def _flash_fwd_chunked(q, k, v, scale, causal, block_q, block_k, chunk,
         grid=(BH, S // block_q, n_chunks),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, chunk, D), lambda b, i, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, D), lambda b, i, c: (b, c, 0)),
+            pl.BlockSpec((1, chunk, D), lambda b, i, c: (kv(b), c, 0)),
+            pl.BlockSpec((1, chunk, D), lambda b, i, c: (kv(b), c, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
@@ -953,9 +974,13 @@ def _bwd_dkv_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
-                       chunk, interpret):
+                       chunk, interpret, heads=0, kv_heads=0):
+    """K and V may be [B * kv_heads, S, D] (grouped-query): both kernels
+    read a query head's group through ``_kv_row``; dk and dv still come
+    back per QUERY head ([B * heads, S, D]) for the caller to sum."""
     BH, S, D = q.shape
     n_chunks = S // chunk
+    kv = _kv_row(heads, kv_heads)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, :, None]
 
@@ -966,8 +991,8 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         grid=(BH, S // block_q, n_chunks),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, chunk, D), lambda b, i, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, D), lambda b, i, c: (b, c, 0)),
+            pl.BlockSpec((1, chunk, D), lambda b, i, c: (kv(b), c, 0)),
+            pl.BlockSpec((1, chunk, D), lambda b, i, c: (kv(b), c, 0)),
             pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
@@ -986,8 +1011,8 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
         grid=(BH, S // block_k, n_chunks),
         in_specs=[
             pl.BlockSpec((1, chunk, D), lambda b, i, c: (b, c, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, i, c: (kv(b), i, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, i, c: (kv(b), i, 0)),
             pl.BlockSpec((1, chunk, D), lambda b, i, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, 1), lambda b, i, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, 1), lambda b, i, c: (b, c, 0)),
@@ -1012,10 +1037,11 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
 def _dispatch_fwd(q, k, v, scale, causal, block_q, block_k, chunk,
                   interpret, heads=0, kv_heads=0):
     if chunk:
-        assert not (heads and kv_heads and heads != kv_heads), \
-            "GQA rides the unchunked kernel (caller repeats for chunked)"
+        # the head counts only where K/V carry fewer heads than q
+        gqa = (heads, kv_heads) if heads and kv_heads \
+            and heads != kv_heads else ()
         return _flash_fwd_chunked(q, k, v, scale, causal, block_q, block_k,
-                                  chunk, interpret)
+                                  chunk, interpret, *gqa)
     return _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
                       heads=heads, kv_heads=kv_heads)
 
@@ -1047,27 +1073,28 @@ def _flash_attention_bwd(scale, causal, block_q, block_k, chunk, interpret,
     q, k, v, o, lse = residuals
     gqa = bool(heads and kv_heads and heads != kv_heads)
     if gqa:
-        # backward still runs the full-head kernels: K/V repeat to
-        # [B*H, S, D] HERE (transient, bwd-only) and dk/dv sum back over
-        # the rep query heads sharing each KV head. A dk/dv-accumulating
-        # GQA backward kernel would remove this transient — the forward
-        # and prefill (the steady-state memory) no longer materialize it.
         B = q.shape[0] // heads
         rep = heads // kv_heads
         S, D = k.shape[1], k.shape[2]
-
-        def rep_kv(t):
-            return jnp.repeat(t.reshape(B, kv_heads, S, D), rep,
-                              axis=1).reshape(B * heads, S, D)
-        k = rep_kv(k)
-        v = rep_kv(v)
     if chunk:
+        # grouped-query K/V are read in place (``_kv_row``)
         dq, dk, dv = _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal,
-                                        block_q, block_k, chunk, interpret)
+                                        block_q, block_k, chunk, interpret,
+                                        heads, kv_heads)
     else:
+        if gqa:
+            # the whole-row backward runs the full-head kernel: K/V repeat
+            # to [B*H, S, D] HERE (transient, bwd-only)
+            def rep_kv(t):
+                return jnp.repeat(t.reshape(B, kv_heads, S, D), rep,
+                                  axis=1).reshape(B * heads, S, D)
+            k = rep_kv(k)
+            v = rep_kv(v)
         dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, scale, causal,
                                 block_q, block_k, interpret)
     if gqa:
+        # dk/dv come back per query head: summed over the rep query heads
+        # sharing each KV head
         def sum_rep(t):
             return t.reshape(B, kv_heads, rep, S, D).sum(axis=2) \
                 .astype(t.dtype).reshape(B * kv_heads, S, D)
@@ -1201,12 +1228,6 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     chunk = int(chunk) if chunk else 0
     _note_plan(S, D, q.dtype, scale, causal, block_q, block_k, chunk)
     qf = q.reshape(B * H, S, D)
-    if chunk and Hkv != H:
-        # the chunked kernels keep full-head maps; GQA rides the
-        # unchunked kernel — repeat here for the long-S streaming path
-        k = jnp.repeat(k, H // Hkv, axis=1)
-        v = jnp.repeat(v, H // Hkv, axis=1)
-        Hkv = H
     kf = k.reshape(B * k.shape[1], S, D)
     vf = v.reshape(B * v.shape[1], S, D)
     o = _flash_attention(qf, kf, vf, scale, causal, block_q, block_k, chunk,

@@ -116,10 +116,23 @@ def annotate(tag):
       RMSNorms over the whole q and k projections): rows of the detail
       table.
 
+    - ``gdn_scan`` and the all-chunks preparation ``gdn_scan_prep``
+      (ops/gated_delta.py, the chunked gated delta rule; a kernel would go
+      under ``gdn_scan_fwd`` / ``gdn_scan_bwd``): ``gdn_scan_share`` and
+      ``gdn_scan_roofline`` (one tag, by prefix);
+    - ``gdn_conv``, ``gdn_gates``, ``gdn_out_norm`` (models/qwen3_next.py:
+      the causal depthwise convolution and its SiLU; beta, the decay and
+      the L2 norms of q and k; the gated RMSNorm of the output): with
+      ``gdn_scan*`` and the module name ``linear_attn``, ``gdn_layer_ms``;
+    - ``attn_gate`` (models/qwen3_next.py, the attention output times
+      ``sigmoid(gate)``) and ``moe_shared`` (moe/dropless.py, the gated
+      shared expert): rows of the detail table.
+
     The flax module names ``attn``, ``mlp``, ``ln_1``, ``ln_2``, ``ln_f``
-    (models/gpt2.py) and ``attn``, ``mlp``, ``input_norm``,
-    ``post_attn_norm``, ``norm`` (models/llama.py) are the detail table's
-    remaining tags.
+    (models/gpt2.py), ``attn``, ``mlp``, ``input_norm``,
+    ``post_attn_norm``, ``norm`` (models/llama.py) and ``linear_attn``,
+    ``attn``, ``mlp`` and the same three norms (models/qwen3_next.py) are
+    the detail table's remaining tags.
 
     Beside the scopes, the flash kernels leave two trace-time GAUGES in
     the registry, ``attention/flash_tile_overcompute`` (score elements

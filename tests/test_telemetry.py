@@ -382,6 +382,41 @@ def test_moe_scope_names_and_gauges_reach_the_step():
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
 
 
+def test_qwen3_next_scope_names_and_gauges_reach_the_step():
+    """ISSUE 31's names: a Qwen3-Next model carries the DeltaNet scopes
+    (``gdn_conv`` / ``gdn_gates`` / ``gdn_scan*`` / ``gdn_out_norm`` under the
+    module ``linear_attn``), ``attn_gate`` and ``qk_norm`` under ``attn``,
+    ``moe_shared`` beside the ``moe_*`` scopes under ``mlp`` in its compiled
+    step's ``op_name``s, and a layer that holds a share of its experts sows
+    ``moe/rows_held_share`` and ``moe/held_slabs`` beside the four gauges every
+    dropless layer has."""
+    import re
+    from deepspeed_tpu.models.qwen3_next import (Qwen3NextForCausalLM,
+                                                 qwen3_next_tiny)
+    default_registry().reset()
+    cfg = qwen3_next_tiny(num_hidden_layers=4, experts_held=4, loss_chunk=16)
+    engine, _, _, _ = dstpu.initialize(config=base_config(),
+                                       model=Qwen3NextForCausalLM(cfg))
+    batch = {"input_ids": np.random.RandomState(0).randint(
+        0, 256, (8, 32)).astype(np.int32)}
+    engine.train_batch(batch)
+    gauges = engine.telemetry_flush()["gauges"]
+    assert {"moe/aux_loss", "moe/z_loss", "moe/rows_max_over_mean",
+            "moe/dropped_rows", "moe/rows_held_share",
+            "moe/held_slabs"} <= set(gauges)
+    assert gauges["moe/dropped_rows"] == 0
+    assert 0 < gauges["moe/rows_held_share"] < 1
+    assert gauges["moe/held_slabs"] >= 1
+    hlo = engine.lower_train_step(batch).compile().as_text()
+    for scope in ("linear_attn/gdn_conv", "linear_attn/gdn_gates",
+                  "linear_attn/gdn_scan_prep", "linear_attn/gdn_scan",
+                  "linear_attn/gdn_out_norm", "attn/qk_norm",
+                  "attn/attn_gate", "mlp/moe_shared", "mlp/moe_router",
+                  "moe_dispatch", "moe_gmm", "moe_gmm_dlhs",
+                  "moe_gmm_drhs", "moe_combine", "ds_embed", "ds_loss_head"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+
+
 def test_engine_without_gates_records_but_never_prices_or_exports():
     """No monitor/profiling config: counters still move (snapshot is
     always available) but no cost-analysis retrace, no exporter, no

@@ -504,6 +504,85 @@ def test_olmoe_step_compiles_for_one_chip_with_its_scopes_and_fits():
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
 
 
+def test_flash_attention_chunked_gqa_compiles_at_qwen3_next_shape():
+    """2 x 16 query / 2 KV heads x 8192 x head_dim 256, bf16, causal: a row of
+    4 MB takes the chunked kernels at chunk 512 (``_CHUNK_ROW_BYTES`` / 2 over
+    a 512-byte row), and K and V go in at their 2 heads: the kernels' index
+    maps fold a query head onto its group (``_kv_row``), nothing is repeated
+    in HBM, dk and dv are summed over a group's 8 query heads after."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: flash_attention(*a, causal=True)
+                        .astype(F32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text, compiled = compile_on_chip(
+        grads, SDS((2, 16, 8192, 256), BF16), SDS((2, 2, 8192, 256), BF16),
+        SDS((2, 2, 8192, 256), BF16))
+    assert kernel_names(text) == {"_fwd_kernel_chunked",
+                                  "_bwd_dq_kernel_chunked",
+                                  "_bwd_dkv_kernel_chunked"}
+    # every Pallas call reads K and V at 4 = 2 x 2 rows, none at 32
+    calls = flash_calls(compiled.as_text())
+    assert len(calls) == 3 and all("bf16[4,8192,256]" in c for c in calls)
+
+
+def test_gated_delta_rule_fwd_and_grad_compile_at_qwen3_next_shape():
+    """2 x 8192 tokens, 16 key / 32 value heads of 128 x 128, bf16 operands
+    and float32 gates: the chunked scan and its backward pass under the
+    scopes the benchmark's ``gdn_scan_*`` readers sum."""
+    from deepspeed_tpu.ops.gated_delta import gated_delta_rule
+
+    def scan(*a):
+        # a scope round it, as the model's module is: JAX writes the
+        # transform round the FIRST scope inside it
+        with jax.named_scope("linear_attn"):
+            return gated_delta_rule(*a).astype(F32).sum()
+
+    def grads(q, k, v, g, beta):
+        return jax.grad(scan, argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    _, compiled = compile_on_chip(
+        grads, SDS((2, 8192, 16, 128), BF16), SDS((2, 8192, 16, 128), BF16),
+        SDS((2, 8192, 32, 128), BF16), SDS((2, 8192, 32), F32),
+        SDS((2, 8192, 32), F32))
+    hlo = compiled.as_text()
+    for scope in ("gdn_scan_prep/", "gdn_scan/"):
+        assert re.search(r'op_name="[^"]*/' + scope, hlo), scope
+    assert compiled.memory_analysis().peak_memory_in_bytes < 8e9
+
+
+@pytest.mark.slow
+def test_qwen3_next_step_compiles_for_one_chip_with_its_scopes_and_fits():
+    """The WHOLE step of the benchmark's ``qwen3next-train-1chip-s8192`` cell
+    (one period of Qwen3-Next as one of 16 expert-parallel ranks, 2 x 8192
+    tokens, ZeRO-3, through the family's ``lower_train_step``) is accepted
+    for a 16 GB chip, and every scope the benchmark reads reaches an
+    ``op_name`` of the compiled text. ~2 minutes: slow-marked."""
+    from benchmark import manifest
+    bench = manifest.load()
+    cell = manifest.cell_of(bench, "qwen3next-train-1chip-s8192")
+    config = manifest.config_of(bench, cell)
+    lowered = manifest.family_module(config).lower_train_step(
+        config, manifest.traffic_of(cell), topo().devices[:1])
+    assert kernel_names(lowered.as_text()) == {
+        "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
+        "_bwd_dkv_kernel_chunked", "kernel"}
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    assert 6.0e9 < ma.argument_size_in_bytes < 6.5e9      # 625.7M x 10 B
+    assert 10e9 < ma.peak_memory_in_bytes < 15.75 * 2 ** 30
+    hlo = compiled.as_text()
+    assert not re.search(r"all-gather|all-reduce|reduce-scatter", hlo)
+    for scope in ("gdn_conv", "gdn_gates", "gdn_scan_prep", "gdn_scan",
+                  "gdn_out_norm", "attn_gate", "qk_norm", "moe_shared",
+                  "moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs", "moe_router",
+                  "moe_dispatch", "moe_combine", "flash_fwd_chunk",
+                  "flash_bwd_dq", "flash_bwd_dkv", "linear_attn", "attn",
+                  "mlp", "ds_loss_head", "ds_embed", "ds_optimizer"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+
+
 # ------------------------------------- optional kernels: known refusals
 
 TILING_RULE = ("The Pallas TPU lowering currently requires that the last two "
