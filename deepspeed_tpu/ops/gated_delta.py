@@ -21,23 +21,52 @@ and from chunk to chunk, with ``S`` the state the chunk starts from:
     o  = (q exp(G)) S + tril(q k^T * D) v'
     S <- exp(G_last) S + (k exp(G_last - G))^T v'
 
-Everything but that last three-line loop is computed for all chunks at once
-in batched matmuls; the loop is a ``lax.scan`` over the chunks, three small
-matmuls a step. The per-token recurrence is never run. The state, the gates
-and ``T`` are float32; the matmul operands are the inputs' dtype (bf16 in a
-training step) with float32 accumulation. The backward pass is JAX's own
-through the scan, except for the inverse, whose cotangent is the closed form
-``-T^T dT T^T``.
+``gated_delta_rule`` decides at trace time, from the head sizes and the
+backend (``ops.pallas.gated_delta.takes_kernel``), which of two forms of
+that one algorithm runs:
 
-Measured on a v5e and written down in PERF.md (Findings, PR 31): what the
-scan and its pieces cost at 2 x 8192 tokens, 32 value heads of 128 x 128.
+- **the Pallas kernels** (``ops/pallas/gated_delta.py``) where both head
+  sizes are multiples of 128 lanes on a TPU (and in the interpreter, at any
+  head size, on every other backend): a program a (batch row, key head and
+  the value heads it serves) walks the chunks in order with ``S`` in VMEM
+  and builds the whole preparation above per chunk in VMEM from the q, k,
+  v, G, beta tiles, read as column blocks of the model's own [B, S, H*D]
+  arrays. For the backward pass the forward rule keeps the state every
+  chunk STARTS from, in the inputs' dtype, and the backward kernel walks
+  the chunks in reverse, builds the preparation again and takes every
+  cotangent in VMEM (``dL = -T^T dT T^T`` in float32);
+- **the XLA form** below (``gated_delta_rule_xla``) for every other head
+  size: everything but the three-line loop is computed for all chunks at
+  once in batched matmuls; the loop is a ``lax.scan`` over the chunks,
+  three small matmuls a step; states are kept every ``_GROUP`` chunks and a
+  group's steps run again in the backward pass, which is JAX's own through
+  the scan except for the inverse (``unit_lower_inverse``), whose
+  cotangent is the closed form. It is also the kernels' second oracle,
+  beside ``gated_delta_recurrence``.
+
+No option selects a form. Which one took a call: the trace-time gauges
+``linear_attn/gdn_kernel_heads_per_step`` (value heads a grid step; 0 = the
+XLA form) and ``linear_attn/gdn_states_kept_every`` (chunks between kept
+states), and the kernels' log line. The per-token recurrence is never run.
+In both forms the state, the gates and ``T`` are float32 and the matmul
+operands the inputs' dtype (bf16 in a training step) with float32
+accumulation.
+
+Measured on a v5e and written down in PERF.md (Findings, PR 31 for the XLA
+form, PR 32 for the kernels): what each form and its pieces cost at
+2 x 8192 tokens, 32 value heads of 128 x 128.
 """
 
+
+import functools
 
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.ops.attention import _device_axes
+from deepspeed_tpu.telemetry.registry import default_registry
 from deepspeed_tpu.telemetry.spans import annotate
+from deepspeed_tpu.utils.platform import is_tpu_backend
 
 CHUNK = 64          # tokens a chunk (a power of two: the inverse doubles)
 _GROUP = 8          # chunks between two states kept for the backward pass
@@ -101,7 +130,33 @@ def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
     layer does before calling); v [B, S, Hv, Dv]; g [B, S, Hv] float32, the
     log of the decay (<= 0); beta [B, S, Hv] float32 in (0, 1). Key head i
     serves value heads [i * Hv / Hk, (i + 1) * Hv / Hk). Any S: the tail
-    of a last, short chunk is padded with tokens that write nothing."""
+    of a last, short chunk is padded with tokens that write nothing.
+
+    Which form runs is decided here from the operands' shapes and the
+    backend (``ops.pallas.gated_delta.takes_kernel``): the Pallas kernels
+    where the heads are lane-aligned on a TPU, and in the interpreter on
+    any other backend; ``gated_delta_rule_xla`` for other head sizes."""
+    from deepspeed_tpu.ops.pallas import gated_delta as kernels
+    tpu = is_tpu_backend()
+    if not kernels.takes_kernel(k.shape[-1], v.shape[-1], tpu):
+        return gated_delta_rule_xla(q, k, v, g, beta, chunk)
+    rule = functools.partial(kernels.gated_delta_rule_kernel, chunk=chunk,
+                             interpret=not tpu)
+    mesh, batch_axes, model_axis = _device_axes(q.shape[0], k.shape[2])
+    if mesh is None:
+        return rule(q, k, v, g, beta)
+    heads = jax.sharding.PartitionSpec(batch_axes, None, model_axis)
+    return jax.shard_map(rule, mesh=mesh, in_specs=(heads,) * 5,
+                         out_specs=heads, check_vma=False)(q, k, v, g, beta)
+
+
+def gated_delta_rule_xla(q, k, v, g, beta, chunk=CHUNK):
+    """``gated_delta_rule`` as XLA ops: the preparation for all chunks at
+    once in batched matmuls and a ``lax.scan`` over the chunks. The path
+    of head sizes the kernels do not take, and their second oracle beside
+    ``gated_delta_recurrence``."""
+    default_registry().gauge("linear_attn/gdn_kernel_heads_per_step").set(0)
+    default_registry().gauge("linear_attn/gdn_states_kept_every").set(_GROUP)
     B, S, Hv, Dv = v.shape
     dt = v.dtype
     rep = Hv // k.shape[2]

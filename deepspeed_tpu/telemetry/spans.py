@@ -116,10 +116,13 @@ def annotate(tag):
       RMSNorms over the whole q and k projections): rows of the detail
       table.
 
-    - ``gdn_scan`` and the all-chunks preparation ``gdn_scan_prep``
-      (ops/gated_delta.py, the chunked gated delta rule; a kernel would go
-      under ``gdn_scan_fwd`` / ``gdn_scan_bwd``): ``gdn_scan_share`` and
-      ``gdn_scan_roofline`` (one tag, by prefix);
+    - ``gdn_scan_fwd`` and ``gdn_scan_bwd`` (ops/pallas/gated_delta.py,
+      round the gated delta rule's two ``pallas_call``s), ``gdn_scan_prep``
+      (the XLA ops left round them: the gates' re-layout and running
+      sums) and, for head sizes the kernels do not take, ``gdn_scan`` with
+      its all-chunks ``gdn_scan_prep`` (ops/gated_delta.py, the XLA
+      form): ``gdn_scan_share`` and ``gdn_scan_roofline`` (one tag, by
+      prefix);
     - ``gdn_conv``, ``gdn_gates``, ``gdn_out_norm`` (models/qwen3_next.py:
       the causal depthwise convolution and its SiLU; beta, the decay and
       the L2 norms of q and k; the gated RMSNorm of the output): with
@@ -139,8 +142,12 @@ def annotate(tag):
     the chosen loops compute over those the softmax needs: whether the
     strip walk engaged for a shape) and
     ``attention/flash_heads_per_block`` (heads a 128-lane column block of
-    the model's own [B, S, H*D] operands, 0 for a head-major call): no
-    benchmark metric reads them."""
+    the model's own [B, S, H*D] operands, 0 for a head-major call), and
+    the gated delta rule two, ``linear_attn/gdn_kernel_heads_per_step``
+    (value heads a grid step of its kernels; 0: the XLA form took the
+    call) and ``linear_attn/gdn_states_kept_every`` (chunks between the
+    states kept for the backward pass): no benchmark metric reads
+    them."""
     import jax
     return jax.named_scope(tag)
 

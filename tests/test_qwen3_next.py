@@ -1,6 +1,7 @@
 """Qwen3-Next on the CPU at small sizes: the program's model against the
 benchmark's plain reference (``benchmark/reference/qwen3_next.py``) for
-every layer kind and every gradient leaf, the chunked gated delta rule
+every layer kind and every gradient leaf, the chunked gated delta rule (its
+XLA form here; the model's layers run its Pallas kernels in the interpreter)
 against the recurrence as written, the expert layer told which experts it
 holds (the shares add up to the uncut layer; all rows held), and each named
 omission failing the benchmark's check. Two periods, seeded weights, float32.
@@ -20,9 +21,13 @@ from benchmark.families import qwen3_next as fam
 from benchmark.reference import qwen3_next as ref
 from deepspeed_tpu.moe.dropless import (DroplessMoE, rows_to_tokens,
                                         tokens_to_rows)
+# the XLA chunked form, whatever the backend: the Pallas kernels that take
+# lane-aligned heads on a TPU (and every head size in the interpreter) have
+# the same tests in tests/test_gated_delta_kernel.py
 from deepspeed_tpu.ops.gated_delta import (CHUNK, gated_delta_recurrence,
-                                           gated_delta_rule,
                                            unit_lower_inverse)
+from deepspeed_tpu.ops.gated_delta import \
+    gated_delta_rule_xla as gated_delta_rule
 
 with open(os.path.join(manifest.HERE, "configs",
                        "qwen3-next-80b-a3b-ep16-depth4.json")) as f:

@@ -407,9 +407,15 @@ def test_qwen3_next_scope_names_and_gauges_reach_the_step():
     assert gauges["moe/dropped_rows"] == 0
     assert 0 < gauges["moe/rows_held_share"] < 1
     assert gauges["moe/held_slabs"] >= 1
+    # the delta rule's kernels took the call (the interpreter, off the TPU)
+    assert gauges["linear_attn/gdn_kernel_heads_per_step"] > 0
+    assert gauges["linear_attn/gdn_states_kept_every"] == 1
     hlo = engine.lower_train_step(batch).compile().as_text()
     for scope in ("linear_attn/gdn_conv", "linear_attn/gdn_gates",
-                  "linear_attn/gdn_scan_prep", "linear_attn/gdn_scan",
+                  # per device inside a shard_map on this mesh of eight
+                  "linear_attn/shard_map/gdn_scan_prep",
+                  "linear_attn/shard_map/gdn_scan_fwd",
+                  "linear_attn/shard_map/gdn_scan_bwd",
                   "linear_attn/gdn_out_norm", "attn/qk_norm",
                   "attn/attn_gate", "mlp/moe_shared", "mlp/moe_router",
                   "moe_dispatch", "moe_gmm", "moe_gmm_dlhs",

@@ -527,11 +527,20 @@ def test_flash_attention_chunked_gqa_compiles_at_qwen3_next_shape():
     assert len(calls) == 3 and all("bf16[4,8192,256]" in c for c in calls)
 
 
-def test_gated_delta_rule_fwd_and_grad_compile_at_qwen3_next_shape():
-    """2 x 8192 tokens, 16 key / 32 value heads of 128 x 128, bf16 operands
-    and float32 gates: the chunked scan and its backward pass under the
-    scopes the benchmark's ``gdn_scan_*`` readers sum."""
+@pytest.mark.parametrize("D,kernels,scopes", [
+    (128, {"_gdn_fwd_kernel", "_gdn_bwd_kernel"},
+     ("gdn_scan_prep/", "gdn_scan_fwd/", "gdn_scan_bwd/")),
+    (64, set(), ("gdn_scan_prep/", "gdn_scan/"))])
+def test_gated_delta_rule_fwd_and_grad_compile_at_qwen3_next_shape(
+        D, kernels, scopes):
+    """2 x 8192 tokens, 16 key / 32 value heads, bf16 operands and float32
+    gates, forward and backward under the scopes the benchmark's
+    ``gdn_scan_*`` readers sum: heads of 128 x 128 (the model's) take the
+    Pallas kernels on the model's own [B, S, H*D] arrays, four value heads
+    a grid step; heads of 64 are not lane-aligned column blocks and keep the
+    XLA chunked form."""
     from deepspeed_tpu.ops.gated_delta import gated_delta_rule
+    from deepspeed_tpu.telemetry.registry import default_registry
 
     def scan(*a):
         # a scope round it, as the model's module is: JAX writes the
@@ -542,14 +551,22 @@ def test_gated_delta_rule_fwd_and_grad_compile_at_qwen3_next_shape():
     def grads(q, k, v, g, beta):
         return jax.grad(scan, argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
 
-    _, compiled = compile_on_chip(
-        grads, SDS((2, 8192, 16, 128), BF16), SDS((2, 8192, 16, 128), BF16),
-        SDS((2, 8192, 32, 128), BF16), SDS((2, 8192, 32), F32),
+    text, compiled = compile_on_chip(
+        grads, SDS((2, 8192, 16, D), BF16), SDS((2, 8192, 16, D), BF16),
+        SDS((2, 8192, 32, D), BF16), SDS((2, 8192, 32), F32),
         SDS((2, 8192, 32), F32))
+    assert kernel_names(text) == kernels
+    assert default_registry().peek_gauge(
+        "linear_attn/gdn_kernel_heads_per_step") == (4 if kernels else 0)
     hlo = compiled.as_text()
-    for scope in ("gdn_scan_prep/", "gdn_scan/"):
+    for scope in scopes:
         assert re.search(r'op_name="[^"]*/' + scope, hlo), scope
-    assert compiled.memory_analysis().peak_memory_in_bytes < 8e9
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == len(kernels) and all(re.search(
+        r'op_name="[^"]*/gdn_scan_(fwd|bwd)/', ln) for ln in calls)
+    # the forward rule's states (268 MB) and nothing of [N, B, H, C, D]
+    assert compiled.memory_analysis().peak_memory_in_bytes < (
+        2e9 if kernels else 8e9)
 
 
 @pytest.mark.slow
@@ -567,15 +584,16 @@ def test_qwen3_next_step_compiles_for_one_chip_with_its_scopes_and_fits():
         config, manifest.traffic_of(cell), topo().devices[:1])
     assert kernel_names(lowered.as_text()) == {
         "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
-        "_bwd_dkv_kernel_chunked", "kernel"}
+        "_bwd_dkv_kernel_chunked", "kernel", "_gdn_fwd_kernel",
+        "_gdn_bwd_kernel"}
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     assert 6.0e9 < ma.argument_size_in_bytes < 6.5e9      # 625.7M x 10 B
     assert 10e9 < ma.peak_memory_in_bytes < 15.75 * 2 ** 30
     hlo = compiled.as_text()
     assert not re.search(r"all-gather|all-reduce|reduce-scatter", hlo)
-    for scope in ("gdn_conv", "gdn_gates", "gdn_scan_prep", "gdn_scan",
-                  "gdn_out_norm", "attn_gate", "qk_norm", "moe_shared",
+    for scope in ("gdn_conv", "gdn_gates", "gdn_scan_prep", "gdn_scan_fwd",
+                  "gdn_scan_bwd", "gdn_out_norm", "attn_gate", "qk_norm", "moe_shared",
                   "moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs", "moe_router",
                   "moe_dispatch", "moe_combine", "flash_fwd_chunk",
                   "flash_bwd_dq", "flash_bwd_dkv", "linear_attn", "attn",
