@@ -77,11 +77,13 @@ HELD_STAT_GAUGES = dict(STAT_GAUGES,
 _HELD_ROWS_SLACK = 2
 
 
-def route(logits, k, norm_topk_prob, pin_choice=False):
+def route(logits, k, norm_topk_prob, pin_choice=False, routed_scale=1.0):
     """(weights [T, k] float32, experts [T, k] int32, probabilities [T, E])
     of float32 router logits [T, E]: softmax over the experts, the k
     largest probabilities as they are (renormalised to sum to one only when
-    ``norm_topk_prob``). ``pin_choice``: the experts carry the checkpoint
+    ``norm_topk_prob``), times ``routed_scale`` where it is not one (a
+    published scaling factor on the routed experts' output).
+    ``pin_choice``: the experts carry the checkpoint
     name ``moe_experts`` and the weights are the probabilities AT them, so
     that a remat policy which saves that name makes a recomputed forward
     pass route as the first one did (a tie between the k-th and the
@@ -95,6 +97,8 @@ def route(logits, k, norm_topk_prob, pin_choice=False):
         top_w = jnp.take_along_axis(probs, top_e, axis=1)
     if norm_topk_prob:
         top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    if routed_scale != 1.0:
+        top_w = top_w * routed_scale
     return top_w, top_e.astype(jnp.int32), probs
 
 
@@ -216,7 +220,9 @@ class DroplessMoE(nn.Module):
     this layer holds that many experts, the ``expert_share``-th such group
     of the ``num_experts`` the router chooses among, and returns their
     partial sum (module docstring). ``shared_d_ff`` > 0: plus one shared
-    expert of that width under a sigmoid gate, in full. ``pin_choice``: a
+    expert of that width under a sigmoid gate, in full. ``routed_scale``:
+    a factor on the routed experts' weights (``route``), not on the shared
+    expert. ``pin_choice``: a
     caller that recomputes this layer under a remat policy which saves the
     name ``moe_experts`` asks for it (``route``); whether a share is held
     has nothing to do with it."""
@@ -232,6 +238,7 @@ class DroplessMoE(nn.Module):
     expert_share: int = 0
     shared_d_ff: int = 0
     pin_choice: bool = False
+    routed_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x):
@@ -253,10 +260,12 @@ class DroplessMoE(nn.Module):
             # can tie to bf16 rounding
             logits = jnp.dot(xt.astype(jnp.float32), wg.astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
-            # (the default keeps OLMoE's call and program)
+            # (the defaults keep OLMoE's call and program)
             top_w, top_e, probs = route(
                 logits, K, self.norm_topk_prob,
-                **({"pin_choice": True} if self.pin_choice else {}))
+                **({"pin_choice": True} if self.pin_choice else {}),
+                **({"routed_scale": self.routed_scale}
+                   if self.routed_scale != 1.0 else {}))
             chosen = jax.nn.one_hot(top_e, E, dtype=jnp.float32).sum(axis=1)
             group_sizes = chosen.sum(axis=0).astype(jnp.int32)      # [E]
             balance = load_balance_loss(probs, chosen)

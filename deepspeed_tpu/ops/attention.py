@@ -22,11 +22,13 @@ from deepspeed_tpu.utils.platform import is_tpu_backend
 
 
 def reference_attention(q, k, v, causal=False, bias=None, scale=None,
-                        segment_ids=None):
+                        segment_ids=None, window=None):
     """Pure-XLA attention on [B, H, S, D] tensors. Numerically the ground
     truth for the Pallas kernels (the test methodology of the reference's
     test_cuda_forward.py, SURVEY §4). K/V may carry Hkv < H heads
-    (grouped-query); the reference repeats them (the kernels do not)."""
+    (grouped-query); the reference repeats them (the kernels do not).
+    ``window`` (with ``causal``): key j is visible to query i iff
+    ``0 <= i - j < window``."""
     B, H, S, D = q.shape
     if k.shape[1] != H:
         rep = H // k.shape[1]
@@ -39,6 +41,8 @@ def reference_attention(q, k, v, causal=False, bias=None, scale=None,
     neg = jnp.float32(-1e30)
     if causal:
         causal_mask = jnp.tril(jnp.ones((S, k.shape[2]), dtype=bool))
+        if window is not None:
+            causal_mask &= ~jnp.tril(jnp.ones_like(causal_mask), -window)
         scores = jnp.where(causal_mask[None, None], scores, neg)
     if segment_ids is not None:
         seg_mask = segment_ids[:, None, :, None] == segment_ids[:, None, None, :]
@@ -81,11 +85,12 @@ def _device_axes(batch, heads):
             else None)
 
 
-def _flash(q, k, v, causal, scale):
+def _flash(q, k, v, causal, scale, window=None):
     """The head-major Pallas flash kernel ([B, H, S, D]), placed on the
     engine's mesh (``_device_axes``)."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-    kernel = functools.partial(flash_attention, causal=causal, scale=scale)
+    kernel = functools.partial(flash_attention, causal=causal, scale=scale,
+                               window=window)
     mesh, batch_axes, model_axis = _device_axes(
         q.shape[0], np.gcd(q.shape[1], k.shape[1]))
     if mesh is None:
@@ -117,7 +122,7 @@ def _flash_fused_qkv(qkv, heads, causal, scale):
 
 
 def dot_product_attention(q, k, v, causal=False, bias=None, scale=None,
-                          segment_ids=None, use_flash=None):
+                          segment_ids=None, use_flash=None, window=None):
     """[B, H, S, D] (head-major) attention. ``use_flash=None``
     auto-selects the Pallas flash kernel on TPU for flash-compatible
     shapes. K/V may carry Hkv < H heads (grouped-query): the flash kernel
@@ -125,13 +130,17 @@ def dot_product_attention(q, k, v, causal=False, bias=None, scale=None,
     full-head K/V is never materialized in the forward. A flash kernel
     that fails to lower raises: nothing here drops to the O(S^2)
     reference behind the caller's back. A model whose q, k, v are one
-    fused projection has ``fused_qkv_attention``."""
+    fused projection has ``fused_qkv_attention``. ``window`` (with
+    ``causal``): a query sees itself and the ``window - 1`` keys before
+    it; on the flash path the window kernels walk that band alone."""
+    if window is not None and not causal:
+        raise ValueError("a window is a causal band: pass causal=True")
     if use_flash is None:
         use_flash = is_tpu_backend() and bias is None and segment_ids is None
     if use_flash:
-        return _flash(q, k, v, causal, scale)
+        return _flash(q, k, v, causal, scale, window)
     return reference_attention(q, k, v, causal=causal, bias=bias, scale=scale,
-                               segment_ids=segment_ids)
+                               segment_ids=segment_ids, window=window)
 
 
 def fused_qkv_attention(qkv, heads, causal=False, scale=None,

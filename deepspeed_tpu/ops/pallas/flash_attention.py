@@ -19,7 +19,7 @@ contracts over 64 of a 128-deep array; 128 output columns where 64 of 128
 were idle); shapes it does not take (long rows, grouped-query, head widths
 that do not tile 128 lanes) it transposes into `flash_attention`.
 
-Two kernel families share the same per-tile math (`_fwd_block_step` /
+Three kernel families share the same per-tile math (`_fwd_block_step` /
 `_bwd_ds_block`):
 
 - **plain** ("whole-row"): K/V (fwd) or Q/dO (bwd) rows for one
@@ -47,6 +47,23 @@ Two kernel families share the same per-tile math (`_fwd_block_step` /
   (S 8192, head_dim 256, 16 query / 2 KV heads: Qwen3-Next's cell, chunk
   512 = one block a grid step, `_CHUNK_ROW_BYTES` as it was); PERF.md
   Findings PR 27 and PR 31 have the numbers.
+
+- **window** (a causal band of ``window`` keys, ``flash_attention(...,
+  window=W)`` with W < S; PR 33): the chunked family's grid with the third
+  dimension walking only the chunks a block's band touches — a STATIC
+  count, ``ceil((block + W - 1) / chunk)`` (+ 1 where a band can straddle a
+  chunk's edge: ``_band_extent``), never S / chunk — through K/V (forward,
+  dq) and Q/dO/lse/delta (dkv) index maps RELATIVE to the block's own
+  position and clamped at the sequence's ends, so neither compute nor DMA
+  is spent outside the band. Blocks wholly inside the band run unmasked;
+  the edge blocks take the causal and the lower-bound compare
+  (``_band_mask``). Grouped-query K/V are read in place, and the dkv
+  kernel's third dimension also walks the KV head's group of query heads,
+  so dk and dv leave per KV head. Scopes ``swa_fwd`` / ``swa_bwd_dq`` /
+  ``swa_bwd_dkv``, gauge ``attention/window_tile_overcompute``. A shape
+  the family does not take raises. Measured on a v5e at (S 16,384,
+  head_dim 128, 64 / 8 heads, W 512: Laguna's cell): PERF.md Findings
+  PR 33.
 
 What a score tile costs beside its two (five, backward) MXU products is
 what these kernels are written around (per 512 x 512 tile at D=64 the
@@ -1032,6 +1049,342 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     return dq.astype(q.dtype), dk.astype(q.dtype), dv.astype(q.dtype)
 
 
+# ------------------------------------------ sliding-window (band) variants
+
+def _band_mask(rel, q_pos0, k_pos0, window):
+    """Mask of an EDGE block of a window band from ``_rel_pos``'s tile: key j
+    is visible to query i iff ``0 <= i - j < window`` — the causal compare of
+    ``_block_mask`` and the band's lower bound beside it (a block narrower
+    than the window needs one of the two, one wider can need both)."""
+    return _block_mask(rel, True, q_pos0, k_pos0) \
+        & (rel < window + k_pos0 - q_pos0)
+
+
+def _band_extent(S, block, chunk, window, keys):
+    """STATIC count of sequence chunks a grid block's band touches, the most
+    over the blocks: the third grid extent of the window kernels. ``keys``:
+    the block is ``block`` query rows and the band the keys
+    ``[p0 - window + 1, p0 + block)`` it sees (forward, dq); else the block
+    is key rows and the band the queries ``[p0, p0 + block + window - 1)``
+    that see it (dkv). ``ceil((block + window - 1) / chunk)``, one more
+    where a band can straddle a chunk's edge."""
+    most = 0
+    for p0 in range(0, S, block):
+        lo, hi = ((max(p0 - window + 1, 0), p0 + block - 1) if keys
+                  else (p0, min(p0 + block + window - 2, S - 1)))
+        most = max(most, hi // chunk - lo // chunk + 1)
+    return most
+
+
+def _band_k_ranges(q0, kc, block_q, block_k, cb, window):
+    """(j_lo, j_a, j_b, j_hi) within key chunk ``kc`` (``cb`` blocks of
+    ``block_k``; ``kc`` < 0: a chunk before the sequence, every range
+    empty) for the query block at ``q0``: blocks [j_lo, j_a) hold the
+    band's lower edge, [j_a, j_b) lie wholly inside it, [j_b, j_hi) hold
+    the diagonal."""
+    lo = jnp.maximum(q0 - window + 1, 0) // block_k
+    hi = (q0 + block_q - 1) // block_k + 1
+    a = jnp.clip((jnp.maximum(q0 + block_q - window, 0) + block_k - 1)
+                 // block_k, lo, hi)
+    b = jnp.clip((q0 + 1) // block_k, a, hi)
+    return tuple(jnp.clip(x - kc * cb, 0, cb) for x in (lo, a, b, hi))
+
+
+def _band_q_ranges(k0, qc, block_q, block_k, cb, window, seq_len):
+    """``_band_k_ranges`` for the key block at ``k0`` over query chunk
+    ``qc`` (``qc`` past the last chunk: every range empty): blocks
+    [j_lo, j_a) hold the diagonal, [j_a, j_b) lie wholly inside the band,
+    [j_b, j_hi) hold its lower edge."""
+    lo = k0 // block_q
+    hi = jnp.minimum((k0 + block_k + window - 2) // block_q + 1,
+                     seq_len // block_q)
+    a = jnp.clip((k0 + block_k + block_q - 2) // block_q, lo, hi)
+    b = jnp.clip((k0 + window) // block_q, a, hi)
+    return tuple(jnp.clip(x - qc * cb, 0, cb) for x in (lo, a, b, hi))
+
+
+def _band_loop(ranges, body, carry):
+    """fori_loop over a chunk's blocks: edge (masked), inside (unmasked),
+    edge (masked)."""
+    j_lo, j_a, j_b, j_hi = ranges
+    carry = jax.lax.fori_loop(j_lo, j_a, lambda j, c: body(j, c, True),
+                              carry)
+    carry = jax.lax.fori_loop(j_a, j_b, lambda j, c: body(j, c, False),
+                              carry)
+    return jax.lax.fori_loop(j_b, j_hi, lambda j, c: body(j, c, True), carry)
+
+
+def _band_first_chunk(i, block_q, chunk, n_band):
+    """Key chunk of the first of query block ``i``'s ``n_band`` grid steps,
+    RELATIVE to the block: the last step is the chunk that holds the block's
+    diagonal. Negative for the first blocks (no such chunk: the index map
+    clamps it, the kernel skips it)."""
+    return ((i + 1) * block_q - 1) // chunk - (n_band - 1)
+
+
+def _band_kv_map(kv, block_q, chunk, n_band):
+    """K/V index map of the forward and dq kernels: grid step ``c`` of
+    query block ``i`` reads chunk ``_band_first_chunk + c`` of the head's
+    KV row (``kv``: ``_kv_row``), chunk 0 where there is none."""
+    return lambda b, i, c: (kv(b), jnp.maximum(
+        _band_first_chunk(i, block_q, chunk, n_band) + c, 0), 0)
+
+
+def _swa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *, scale,
+                    window, block_q, block_k, chunk, n_band):
+    qi = pl.program_id(1)
+    c = pl.program_id(2)
+    kc = _band_first_chunk(qi, block_q, chunk, n_band) + c
+    cb = chunk // block_k
+    fold = _scale_folds(scale)
+    s_scale = None if fold else scale
+    q = q_ref[0] * scale if fold else q_ref[0]
+    rel = _rel_pos(block_q, block_k)
+    q0 = qi * block_q
+
+    @pl.when(c == 0)
+    def _init():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+        m_ref[0] = jnp.full_like(m_ref[0], NEG_INF)
+        l_ref[0] = jnp.zeros_like(l_ref[0])
+
+    def body(j, carry, masked):
+        k0 = (kc * cb + j) * block_k
+        k = k_ref[0, pl.ds(j * block_k, block_k), :]
+        v = v_ref[0, pl.ds(j * block_k, block_k), :]
+        mask = _band_mask(rel, q0, k0, window) if masked else None
+        return _fwd_block_step(q, k, v, carry, mask, s_scale)
+
+    stat = (block_q, _LANES)
+    lane0 = jax.lax.broadcasted_iota(jnp.int32, stat, 1) == 0
+    carry0 = (o_ref[0], jnp.broadcast_to(m_ref[0], stat),
+              jnp.where(lane0, l_ref[0], 0.0))
+    o, m, l = _band_loop(
+        _band_k_ranges(q0, kc, block_q, block_k, cb, window), body, carry0)
+    # as ``_fwd_kernel_chunked``: raw (o, m, l) between a block's steps, the
+    # last step (the diagonal's chunk) normalises in the kernel. A row its
+    # band's first block hides whole takes exp(0) there; the next visible
+    # key's alpha = exp(NEG_INF - m) = 0 wipes it, and the diagonal is
+    # always visible and always last
+    last = c == n_band - 1
+    l = _row_total(l)
+    l_safe = jnp.maximum(l, 1e-30)
+    o_ref[0] = jnp.where(last, jnp.where(l > 0, o / l_safe, 0.0), o)
+    m_ref[0] = jnp.where(last, m[:, :1] + jnp.log(l_safe), m[:, :1])
+    l_ref[0] = l
+
+
+def _swa_fwd(q, k, v, scale, window, block_q, block_k, chunk, interpret,
+             heads, kv_heads):
+    BH, S, D = q.shape
+    n_band = _band_extent(S, block_q, chunk, window, keys=True)
+    band = _band_kv_map(_kv_row(heads, kv_heads), block_q, chunk, n_band)
+    call = pl.pallas_call(
+        functools.partial(_swa_fwd_kernel, scale=scale, window=window,
+                          block_q=block_q, block_k=block_k, chunk=chunk,
+                          n_band=n_band),
+        grid=(BH, S // block_q, n_band),
+        in_specs=[
+            pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, chunk, D), band),
+            pl.BlockSpec((1, chunk, D), band),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
+            jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
+            jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
+        ],
+        interpret=interpret,
+    )
+    with annotate("swa_fwd"):
+        o32, lse, _ = call(q, k, v)
+    return o32.astype(q.dtype), lse
+
+
+def _swa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dq_ref, *, scale, window, block_q, block_k, chunk,
+                       n_band):
+    qi = pl.program_id(1)
+    c = pl.program_id(2)
+    kc = _band_first_chunk(qi, block_q, chunk, n_band) + c
+    cb = chunk // block_k
+    fold = _scale_folds(scale)
+    s_scale = None if fold else scale
+    q = q_ref[0] * scale if fold else q_ref[0]
+    do = do_ref[0]
+    lse = lse_ref[0]
+    delta = delta_ref[0]
+    rel = _rel_pos(block_q, block_k)
+    q0 = qi * block_q
+
+    @pl.when(c == 0)
+    def _init():
+        dq_ref[0] = jnp.zeros_like(dq_ref[0])
+
+    def body(j, dq_acc, masked):
+        k0 = (kc * cb + j) * block_k
+        k = k_ref[0, pl.ds(j * block_k, block_k), :]
+        v = v_ref[0, pl.ds(j * block_k, block_k), :]
+        mask = _band_mask(rel, q0, k0, window) if masked else None
+        _, ds = _bwd_ds_block(q, do, lse, delta, k, v, mask, s_scale)
+        return dq_acc + jax.lax.dot(ds, k,
+                                    preferred_element_type=jnp.float32)
+
+    dq = _band_loop(_band_k_ranges(q0, kc, block_q, block_k, cb, window),
+                    body, dq_ref[0])
+    dq_ref[0] = jnp.where(c == n_band - 1, dq * scale, dq)
+
+
+def _swa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                        dk_ref, dv_ref, *, scale, window, block_q, block_k,
+                        chunk, n_band, rep, seq_len):
+    """One KV head's block: the third grid dimension walks its group's
+    ``rep`` query heads, ``n_band`` query chunks each, and dk, dv of the KV
+    head accumulate over all of them in the revisited output block (the
+    causal chunked kernel leaves them per QUERY head, for XLA to sum)."""
+    ki = pl.program_id(1)
+    t = pl.program_id(2)
+    k0 = ki * block_k
+    qc = k0 // chunk + t % n_band
+    cb = chunk // block_q
+    fold = _scale_folds(scale)
+    s_scale = None if fold else scale
+    k = k_ref[0]
+    v = v_ref[0]
+    rel = _rel_pos(block_q, block_k)
+
+    @pl.when(t == 0)
+    def _init():
+        dk_ref[0] = jnp.zeros_like(dk_ref[0])
+        dv_ref[0] = jnp.zeros_like(dv_ref[0])
+
+    def body(j, carry, masked):
+        dk_acc, dv_acc = carry
+        q0 = (qc * cb + j) * block_q
+        q = q_ref[0, pl.ds(j * block_q, block_q), :]
+        if fold:
+            q = q * scale
+        do = do_ref[0, pl.ds(j * block_q, block_q), :]
+        lse = lse_ref[0, pl.ds(j * block_q, block_q), :]
+        delta = delta_ref[0, pl.ds(j * block_q, block_q), :]
+        mask = _band_mask(rel, q0, k0, window) if masked else None
+        p, ds = _bwd_ds_block(q, do, lse, delta, k, v, mask, s_scale)
+        dv_new = dv_acc + jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_new = dk_acc + jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return dk_new, dv_new
+
+    dk, dv = _band_loop(
+        _band_q_ranges(k0, qc, block_q, block_k, cb, window, seq_len), body,
+        (dk_ref[0], dv_ref[0]))
+    dk_ref[0] = dk if fold else jnp.where(t == rep * n_band - 1, dk * scale,
+                                          dk)
+    dv_ref[0] = dv
+
+
+def _swa_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k, chunk,
+             interpret, heads, kv_heads):
+    """(dq [B * heads, S, D], dk, dv [B * kv_heads, S, D])."""
+    BH, S, D = q.shape
+    BHkv = k.shape[0]
+    rep = BH // BHkv
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1)[:, :, None]
+    n_band = _band_extent(S, block_q, chunk, window, keys=True)
+    band = _band_kv_map(_kv_row(heads, kv_heads), block_q, chunk, n_band)
+    call_dq = pl.pallas_call(
+        functools.partial(_swa_bwd_dq_kernel, scale=scale, window=window,
+                          block_q=block_q, block_k=block_k, chunk=chunk,
+                          n_band=n_band),
+        grid=(BH, S // block_q, n_band),
+        in_specs=[
+            pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, chunk, D), band),
+            pl.BlockSpec((1, chunk, D), band),
+            pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
+            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
+        interpret=interpret,
+    )
+    with annotate("swa_bwd_dq"):
+        dq = call_dq(q, k, v, do, lse, delta)
+
+    n_band = _band_extent(S, block_k, chunk, window, keys=False)
+    last = S // chunk - 1
+
+    def band_q(b, i, t):
+        # KV row b's group: query heads [b * rep, (b + 1) * rep) (``_kv_row``
+        # the other way round), head t // n_band of them
+        return (b * rep + t // n_band,
+                jnp.minimum(i * block_k // chunk + t % n_band, last), 0)
+
+    call_dkv = pl.pallas_call(
+        functools.partial(_swa_bwd_dkv_kernel, scale=scale, window=window,
+                          block_q=block_q, block_k=block_k, chunk=chunk,
+                          n_band=n_band, rep=rep, seq_len=S),
+        grid=(BHkv, S // block_k, rep * n_band),
+        in_specs=[
+            pl.BlockSpec((1, chunk, D), band_q),
+            pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
+            pl.BlockSpec((1, chunk, D), band_q),
+            pl.BlockSpec((1, chunk, 1), band_q),
+            pl.BlockSpec((1, chunk, 1), band_q),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
+            pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((BHkv, S, D), jnp.float32),
+            jax.ShapeDtypeStruct((BHkv, S, D), jnp.float32),
+        ],
+        interpret=interpret,
+    )
+    with annotate("swa_bwd_dkv"):
+        dk, dv = call_dkv(q, k, v, do, lse, delta)
+    return dq.astype(q.dtype), dk.astype(q.dtype), dv.astype(q.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
+def _flash_attention_swa(q, k, v, scale, window, block_q, block_k, chunk,
+                         interpret, heads, kv_heads):
+    return _swa_fwd(q, k, v, scale, window, block_q, block_k, chunk,
+                    interpret, heads, kv_heads)[0]
+
+
+def _flash_attention_swa_fwd(q, k, v, scale, window, block_q, block_k, chunk,
+                             interpret, heads, kv_heads):
+    from jax.ad_checkpoint import checkpoint_name
+    o, lse = _swa_fwd(q, k, v, scale, window, block_q, block_k, chunk,
+                      interpret, heads, kv_heads)
+    o = checkpoint_name(o, "flash_o")
+    lse = checkpoint_name(lse, "flash_lse")
+    return o, (q, k, v, o, lse)
+
+
+def _flash_attention_swa_bwd(scale, window, block_q, block_k, chunk,
+                             interpret, heads, kv_heads, residuals, do):
+    q, k, v, o, lse = residuals
+    return _swa_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k,
+                    chunk, interpret, heads, kv_heads)
+
+
+_flash_attention_swa.defvjp(_flash_attention_swa_fwd,
+                            _flash_attention_swa_bwd)
+
+
 # ---------------------------------------------------------------- public op
 
 def _dispatch_fwd(q, k, v, scale, causal, block_q, block_k, chunk,
@@ -1131,16 +1484,53 @@ def tile_overcompute(S, block_q, block_k, chunk, causal):
     return computed / (S * (S + 1))
 
 
+def window_tile_overcompute(S, block_q, block_k, window):
+    """``tile_overcompute`` for the window kernels: score elements of the
+    blocks the band's walks touch (a walk over the query blocks — forward,
+    dq — and one over the key blocks — dkv) over the ``S*W - W(W-1)/2``
+    elements a head's band holds, twice. Blocks of 512 at W 512 compute
+    2.0 x, 256 1.5 x, 128 1.25 x."""
+    window = min(window, S)
+    tile = block_q * block_k
+    over_q = sum(((q0 + block_q - 1) // block_k
+                  - max(q0 - window + 1, 0) // block_k + 1) * tile
+                 for q0 in range(0, S, block_q))
+    over_k = sum((min((k0 + block_k + window - 2) // block_q + 1,
+                      S // block_q) - k0 // block_q) * tile
+                 for k0 in range(0, S, block_k))
+    return (over_q + over_k) / (2 * (S * window - window * (window - 1) // 2))
+
+
 _plans_logged = set()
 
 
 def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
-               heads_per_block=0):
+               heads_per_block=0, window=0):
     """Trace-time engagement record of one flash call: the gauges
     ``attention/flash_tile_overcompute`` and
     ``attention/flash_heads_per_block`` (heads a 128-lane column block of
     [B, S, H*D] operands; 0 for a head-major call) and, once per distinct
-    shape, a log line of the layout and loop structure chosen for it."""
+    shape, a log line of the layout and loop structure chosen for it.
+    ``window``: a call of the window kernels — the gauge
+    ``attention/window_tile_overcompute`` and the band's plan instead."""
+    if window:
+        over = window_tile_overcompute(S, block_q, block_k, window)
+        default_registry().gauge("attention/window_tile_overcompute").set(
+            over)
+        plan = (S, D, jnp.dtype(dtype).name, window, block_q, block_k, chunk)
+        if plan not in _plans_logged:
+            _plans_logged.add(plan)
+            logger.info(
+                f"flash attention S={S} D={D} {plan[2]} window={window}: "
+                f"layout [B*H, S, D] head-major, block_q={block_q} "
+                f"block_k={block_k} chunk={chunk}, a query block walks "
+                f"{_band_extent(S, block_q, chunk, window, True)} of "
+                f"{S // chunk} key chunks, a key block "
+                f"{_band_extent(S, block_k, chunk, window, False)} query "
+                f"chunks a head of its group, scale "
+                f"{'on q' if _scale_folds(scale) else 'on scores'}"
+                f", computes {over:.3f} x the band's scores")
+        return
     over = tile_overcompute(S, block_q, block_k, chunk, causal)
     default_registry().gauge("attention/flash_tile_overcompute").set(over)
     default_registry().gauge("attention/flash_heads_per_block").set(
@@ -1182,8 +1572,45 @@ def _pick_block(S, requested, interpret, whole_row):
     return S if S <= top else 0
 
 
+# The window kernels take the chunked family's grid blocks (``_pick_block``:
+# up to 512 rows), one block a chunk where the caller names none. Measured
+# on a v5e at [64 / 8, 16384, 128] bf16, W 512 (tests/perf/swa_bench.py;
+# PERF.md Findings PR 33): a grid step's fixed cost outweighs what a finer
+# tiling saves — blocks of 512 compute 2.0 x the band's scores and ran
+# 13.8 ms forward / 36.5 forward + backward, 256 (1.5 x) 19.3 / 48.2
+# (16.2 / 42.1 at two blocks a chunk), 128 (1.25 x) 37.5 / 92.6
+
+
+def _flash_attention_window(q, k, v, scale, window, block_q, block_k, chunk,
+                            interpret):
+    """``flash_attention``'s window branch: the band kernels or a raise,
+    never [S, S] scores."""
+    B, H, S, D = q.shape
+    Hkv = k.shape[1]
+    block_q, block_k = (_pick_block(S, b, interpret, False)
+                        for b in (block_q, block_k))
+    if not block_q or not block_k or S % block_q or S % block_k:
+        raise ValueError(
+            f"window attention (window={window}) over S={S}: no block "
+            f"tiles the sequence (block_q={block_q}, block_k={block_k}) and "
+            "a window layer never falls back to [S, S] scores")
+    if chunk is None:
+        chunk = max(block_q, block_k)
+    if S % chunk or chunk % block_q or chunk % block_k:
+        raise ValueError(
+            f"chunk={chunk} must divide S={S} and be a multiple of "
+            f"block_q={block_q} and block_k={block_k}")
+    _note_plan(S, D, q.dtype, scale, True, block_q, block_k, chunk,
+               window=window)
+    o = _flash_attention_swa(
+        q.reshape(B * H, S, D), k.reshape(B * Hkv, S, D),
+        v.reshape(B * Hkv, S, D), scale, int(window), block_q, block_k,
+        int(chunk), bool(interpret), H, Hkv)
+    return o.reshape(B, H, S, D)
+
+
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, interpret=None, chunk=None):
+                    block_k=None, interpret=None, chunk=None, window=None):
     """[B, H, S, D] (head-major) flash attention: every kernel family,
     grouped-query K/V included. Falls back to the jnp reference for
     shapes the kernel can't tile (tiny S/D in unit tests). ``chunk``
@@ -1191,11 +1618,26 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     budget); it must divide S and be a multiple of both block sizes.
     A caller whose q, k, v are columns of [B, S, H*D] arrays — a fused
     projection — has ``flash_attention_bse``, which spares the
-    transposes into this layout where the whole-row kernels run."""
+    transposes into this layout where the whole-row kernels run.
+
+    ``window`` (with ``causal``): key j is visible to query i iff
+    ``0 <= i - j < window``. A window shorter than the sequence takes the
+    window kernels, whose grid walks only the chunks a block's band touches
+    (a shape they do not take RAISES); one that covers it is causal
+    attention."""
     B, H, S, D = q.shape
     scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
     if interpret is None:
         interpret = _interpret_default()
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("a window is a causal band of >= 1 keys: "
+                             f"causal={causal}, window={window}")
+        assert v.shape[1] == k.shape[1] and H % k.shape[1] == 0, \
+            (q.shape, k.shape)
+        if window < S:
+            return _flash_attention_window(q, k, v, scale, window, block_q,
+                                           block_k, chunk, interpret)
     itemsize = jnp.dtype(q.dtype).itemsize
     whole_row = chunk is None and S * D * itemsize <= _UNCHUNKED_ROW_BYTES
     block_q = _pick_block(S, block_q, interpret, whole_row)

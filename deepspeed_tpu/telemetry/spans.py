@@ -127,15 +127,24 @@ def annotate(tag):
       the causal depthwise convolution and its SiLU; beta, the decay and
       the L2 norms of q and k; the gated RMSNorm of the output): with
       ``gdn_scan*`` and the module name ``linear_attn``, ``gdn_layer_ms``;
-    - ``attn_gate`` (models/qwen3_next.py, the attention output times
-      ``sigmoid(gate)``) and ``moe_shared`` (moe/dropless.py, the gated
+    - ``attn_gate`` (models/qwen3_next.py, models/laguna.py: the
+      attention output times ``sigmoid(gate)``, element-wise there, one
+      scalar a head here) and ``moe_shared`` (moe/dropless.py, the gated
       shared expert): rows of the detail table.
+
+    - ``swa_fwd``, ``swa_bwd_dq``, ``swa_bwd_dkv``
+      (ops/pallas/flash_attention.py, round the three ``pallas_call``s of
+      the window kernels): ``swa_attn_share``, ``swa_fwd_roofline`` and
+      ``swa_bwd_roofline`` (the two backward scopes one tag, by prefix);
+    - ``dense_mlp`` (models/laguna.py, the leading layer's SwiGLU): a row
+      of the detail table.
 
     The flax module names ``attn``, ``mlp``, ``ln_1``, ``ln_2``, ``ln_f``
     (models/gpt2.py), ``attn``, ``mlp``, ``input_norm``,
     ``post_attn_norm``, ``norm`` (models/llama.py) and ``linear_attn``,
-    ``attn``, ``mlp`` and the same three norms (models/qwen3_next.py) are
-    the detail table's remaining tags.
+    ``attn``, ``mlp`` and the same three norms (models/qwen3_next.py;
+    models/laguna.py has ``attn``, ``mlp`` and the three norms) are the
+    detail table's remaining tags.
 
     Beside the scopes, the flash kernels leave two trace-time GAUGES in
     the registry, ``attention/flash_tile_overcompute`` (score elements
@@ -147,7 +156,10 @@ def annotate(tag):
     (value heads a grid step of its kernels; 0: the XLA form took the
     call) and ``linear_attn/gdn_states_kept_every`` (chunks between the
     states kept for the backward pass): no benchmark metric reads
-    them."""
+    them. The window kernels leave one,
+    ``attention/window_tile_overcompute`` (score elements their tiles
+    compute over those the band holds, forward and backward):
+    ``swa_tile_overcompute`` reads it."""
     import jax
     return jax.named_scope(tag)
 

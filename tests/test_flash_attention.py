@@ -557,3 +557,164 @@ def test_fused_qkv_attention_runs_per_device_on_the_engine_mesh(axes):
         functools.partial(loss, use_flash=False), has_aux=True)(qkv)
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-5)
     np.testing.assert_allclose(grad, ref_grad, rtol=5e-3, atol=5e-4)
+
+
+# ------------------------------------------------------------------------
+# the window kernels (ISSUE 33): a causal band of ``window`` keys, the third
+# grid dimension walking only the chunks a block's band touches
+
+def _window_case(S, H, Hkv, W, block_q, block_k, chunk, dtype=jnp.float32,
+                 D=32):
+    """((out, dq, dk, dv) of the window kernels, of the masked reference)."""
+    ks = jax.random.split(jax.random.PRNGKey(S + H + W), 4)
+    q = jax.random.normal(ks[0], (1, H, S, D), jnp.float32).astype(dtype)
+    k, v = (jax.random.normal(key, (1, Hkv, S, D), jnp.float32).astype(dtype)
+            for key in ks[1:3])
+    g = jax.random.normal(ks[3], (1, H, S, D), jnp.float32)
+
+    def both(attend):
+        out = attend(q, k, v)
+        return (out,) + jax.grad(
+            lambda *a: jnp.sum(attend(*a).astype(jnp.float32) * g),
+            argnums=(0, 1, 2))(q, k, v)
+
+    return (both(functools.partial(
+        flash_attention, causal=True, window=W, block_q=block_q,
+        block_k=block_k, chunk=chunk, interpret=True)),
+        both(functools.partial(reference_attention, causal=True, window=W)))
+
+
+@pytest.mark.parametrize("S,H,Hkv,W,block_q,block_k,chunk", [
+    (256, 2, 2, 32, 64, 64, None),      # W smaller than the block
+    (256, 2, 1, 64, 64, 64, None),      # W equal to the block
+    (256, 2, 1, 128, 64, 64, None),     # W a multiple of the block
+    (256, 2, 1, 100, 64, 64, None),     # W no multiple of the block
+    (256, 2, 1, 255, 64, 64, None),     # all but the first key of the last
+    (256, 4, 2, 100, 32, 64, 64),       # unequal blocks
+    (256, 2, 1, 16, 64, 32, 128),       # several blocks a chunk
+    (512, 6, 1, 130, 64, 64, 128),      # GQA 6:1, band across chunk edges
+    (256, 8, 1, 48, 64, 64, None),      # GQA 8:1
+    (192, 3, 1, 40, 64, 64, None),      # S no power of two, odd head count
+], ids=lambda v: str(v))
+def test_window_kernels_match_the_masked_reference(S, H, Hkv, W, block_q,
+                                                   block_k, chunk):
+    got, want = _window_case(S, H, Hkv, W, block_q, block_k, chunk)
+    for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4,
+                                   atol=5e-5, err_msg=name)
+
+
+def test_window_kernels_bf16():
+    got, want = _window_case(256, 4, 1, 64, 64, 64, None, dtype=jnp.bfloat16)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), rtol=5e-2,
+                                   atol=5e-2)
+
+
+@pytest.mark.parametrize("W", [256, 300])
+def test_a_window_that_covers_the_sequence_is_causal_attention(W):
+    """W >= S: the causal kernels, bit for bit (no window kernel runs)."""
+    q, k, v = _qkv(shape=(1, 2, 256, 32))
+    kw = dict(causal=True, interpret=True, block_q=64, block_k=64)
+    np.testing.assert_array_equal(
+        np.asarray(flash_attention(q, k, v, window=W, **kw)),
+        np.asarray(flash_attention(q, k, v, **kw)))
+
+
+@pytest.mark.parametrize("S,W,block,chunk,fwd,dkv", [
+    (1024, 64, 64, 64, 2, 2),           # ceil((64 + 63) / 64)
+    (1024, 128, 64, 64, 3, 3),
+    (1024, 100, 64, 64, 3, 3),
+    (1024, 64, 64, 256, 2, 2),          # + 1: a band straddles a chunk edge
+    (1024, 512, 64, 256, 3, 3),         # ceil(575 / 256) = 3
+    (16384, 512, 256, 256, 3, 3),       # the cell's: 3 of 64 chunks
+    (16384, 512, 128, 128, 5, 5),
+    (16384, 512, 512, 512, 2, 2),
+])
+def test_window_grid_walks_the_static_band_count(S, W, block, chunk, fwd,
+                                                 dkv):
+    """The third grid extent of the three ``pallas_call``s is the band's
+    static chunk count (x the group's query heads for dk / dv), never
+    S / chunk; and the gauge is the band's overcompute."""
+    from deepspeed_tpu.telemetry.registry import default_registry
+    fa = _fa()
+    assert fa._band_extent(S, block, chunk, W, keys=True) == fwd
+    assert fa._band_extent(S, block, chunk, W, keys=False) == dkv
+    H, Hkv = 4, 2
+    q = jax.ShapeDtypeStruct((1, H, S, 16), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, Hkv, S, 16), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, window=W, block_q=block, block_k=block,
+        chunk=chunk, interpret=True)), argnums=(0, 1, 2)))(q, kv, kv)
+    grids = sorted(
+        eqn.params["grid_mapping"].grid for eqn in jaxpr.eqns
+        if eqn.primitive.name == "pallas_call")
+    assert grids == sorted([
+        (H, S // block, fwd), (H, S // block, fwd),
+        (Hkv, S // block, (H // Hkv) * dkv)]), grids
+    assert all(g[2] < S // chunk for g in grids if S // chunk > 8)
+    over = default_registry().peek_gauge("attention/window_tile_overcompute")
+    assert over == pytest.approx(
+        fa.window_tile_overcompute(S, block, block, W))
+    if (S, W) == (16384, 512):
+        assert over == pytest.approx({512: 2.0, 256: 1.5, 128: 1.25}[block],
+                                     abs=0.02)
+
+
+def test_window_overcompute_counts_blocks_over_the_band():
+    fa = _fa()
+    # one block a band row but the first: 4 x 4 blocks of 64 x 64 touched
+    # twice (the causal and the lower edge), over 256 x 64 - 64 x 63 / 2
+    assert fa.window_tile_overcompute(256, 64, 64, 64) == pytest.approx(
+        (4 + 3) * 64 * 64 / (256 * 64 - 64 * 63 // 2))
+    assert fa.window_tile_overcompute(256, 64, 64, 1) == pytest.approx(
+        4 * 64 * 64 / 256)
+
+
+def test_a_window_shape_no_kernel_takes_raises():
+    """Never [S, S] scores behind the caller's back: an S no block tiles,
+    a chunk that is no multiple of the blocks, a window without causal."""
+    q, k, v = _qkv(shape=(1, 1, 100, 16))
+    with pytest.raises(ValueError, match="never falls back"):
+        flash_attention(q, k, v, causal=True, window=8, interpret=True)
+    q, k, v = _qkv(shape=(1, 1, 256, 16))
+    with pytest.raises(ValueError, match="chunk=96"):
+        flash_attention(q, k, v, causal=True, window=8, interpret=True,
+                        block_q=64, block_k=64, chunk=96)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=8, interpret=True)
+    from deepspeed_tpu.ops.attention import dot_product_attention
+    with pytest.raises(ValueError, match="causal"):
+        dot_product_attention(q, k, v, causal=False, window=8)
+
+
+def test_dot_product_attention_passes_the_window_through_its_shard_map():
+    """``ops.attention._flash`` under an engine's pinned mesh: the window
+    kernels run per device inside the shard_map, ``window`` handed through
+    exactly as ``causal`` is."""
+    from deepspeed_tpu.ops.attention import dot_product_attention
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+    from deepspeed_tpu.parallel.mesh import MeshConfig, make_mesh
+    if len(jax.devices()) < 4:
+        pytest.skip("need 4 devices")
+    mesh = make_mesh(MeshConfig(data=2, model=2), devices=jax.devices()[:4])
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (2, 4, 128, 32))
+    k, v = (jax.random.normal(key, (2, 2, 128, 32)) for key in ks[1:])
+
+    def loss(q, k, v, use_flash):
+        o = dot_product_attention(q, k, v, causal=True, window=24,
+                                  use_flash=use_flash)
+        return jnp.sum(jnp.sin(o)), o
+
+    with mesh_lib.layout_pins(mesh):
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            functools.partial(loss, use_flash=True), argnums=(0, 1, 2),
+            has_aux=True))(q, k, v)
+    (_, ref), ref_grads = jax.value_and_grad(
+        functools.partial(loss, use_flash=False), argnums=(0, 1, 2),
+        has_aux=True)(q, k, v)
+    np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-5)
+    for a, b in zip(grads, ref_grads):
+        np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-4)

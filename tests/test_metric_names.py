@@ -206,7 +206,8 @@ def test_zero_gather_edge_metric_names_documented():
 
 
 @pytest.mark.parametrize("name", ["attention/flash_tile_overcompute",
-                                  "attention/flash_heads_per_block"])
+                                  "attention/flash_heads_per_block",
+                                  "attention/window_tile_overcompute"])
 def test_flash_engagement_gauges_documented(name):
     """The flash kernels' trace-time engagement gauges (ISSUE 28: the
     loops' overcompute; ISSUE 30: heads a column block, 0 head-major)

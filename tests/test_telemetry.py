@@ -423,6 +423,38 @@ def test_qwen3_next_scope_names_and_gauges_reach_the_step():
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
 
 
+def test_laguna_scope_names_and_gauges_reach_the_step():
+    """ISSUE 33's names: a Laguna model whose sliding layers take the window
+    kernels (``use_flash``: the interpreter here) carries ``swa_fwd`` /
+    ``swa_bwd_dq`` / ``swa_bwd_dkv`` and ``attn_gate`` under ``attn``,
+    ``dense_mlp`` under the leading block's ``mlp``, the ``moe_*`` scopes
+    under the others', in its compiled step's ``op_name``s, and leaves the
+    gauge ``attention/window_tile_overcompute``."""
+    import re
+    from deepspeed_tpu.models.laguna import LagunaForCausalLM, laguna_tiny
+    default_registry().reset()
+    cfg = laguna_tiny(num_hidden_layers=5, experts_held=4, loss_chunk=16,
+                      use_flash=True)
+    engine, _, _, _ = dstpu.initialize(config=base_config(),
+                                       model=LagunaForCausalLM(cfg))
+    batch = {"input_ids": np.random.RandomState(0).randint(
+        0, 256, (8, 64)).astype(np.int32)}
+    engine.train_batch(batch)
+    gauges = engine.telemetry_flush()["gauges"]
+    assert {"moe/dropped_rows", "moe/rows_held_share",
+            "moe/held_slabs"} <= set(gauges)
+    # S 64, window 16, blocks of 64 in the interpreter: one block a band
+    assert gauges["attention/window_tile_overcompute"] == pytest.approx(
+        64 * 64 / (64 * 16 - 16 * 15 // 2))
+    hlo = engine.lower_train_step(batch).compile().as_text()
+    for scope in ("attn/shard_map/swa_fwd", "attn/shard_map/swa_bwd_dq",
+                  "attn/shard_map/swa_bwd_dkv", "attn/attn_gate",
+                  "lead_0/mlp/dense_mlp", "mlp/moe_shared", "mlp/moe_router",
+                  "moe_dispatch", "moe_gmm", "moe_combine", "ds_embed",
+                  "ds_loss_head"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+
+
 def test_engine_without_gates_records_but_never_prices_or_exports():
     """No monitor/profiling config: counters still move (snapshot is
     always available) but no cost-analysis retrace, no exporter, no

@@ -601,6 +601,76 @@ def test_qwen3_next_step_compiles_for_one_chip_with_its_scopes_and_fits():
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
 
 
+def test_window_kernels_fwd_and_grad_compile_at_laguna_shape():
+    """1 x 64 query / 8 KV heads x 16,384 x head_dim 128, bf16, window 512:
+    the three window kernels, K and V read at their 8 heads and dk, dv
+    written at 8 (summed over a group's query heads inside the kernel), the
+    third grid extent the band's 2 chunks of 512 and never 32, no [S, S]
+    array."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    def attend(*a):
+        # a scope round it, as the model's module is: JAX writes the
+        # transform round the FIRST scope inside it
+        with jax.named_scope("attn"):
+            return flash_attention(*a, causal=True,
+                                   window=512).astype(F32).sum()
+
+    def grads(q, k, v):
+        return jax.grad(attend, argnums=(0, 1, 2))(q, k, v)
+
+    text, compiled = compile_on_chip(
+        grads, SDS((1, 64, 16384, 128), BF16), SDS((1, 8, 16384, 128), BF16),
+        SDS((1, 8, 16384, 128), BF16))
+    assert kernel_names(text) == {"_swa_fwd_kernel", "_swa_bwd_dq_kernel",
+                                  "_swa_bwd_dkv_kernel"}
+    hlo = compiled.as_text()
+    calls = flash_calls(hlo)
+    assert len(calls) == 3 and all("bf16[8,16384,128]" in c for c in calls)
+    assert sum("f32[8,16384,128]" in c.split(" custom-call(")[0]
+               for c in calls) == 1                 # dk, dv at the KV heads
+    for scope in ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+    assert "16384,16384" not in hlo
+    # q, k, v, o, their gradients and the fp32 kernel outputs: under 2.5 GB
+    assert compiled.memory_analysis().peak_memory_in_bytes < 2.5e9
+
+
+@pytest.mark.slow
+def test_laguna_step_compiles_for_one_chip_with_its_scopes_and_fits():
+    """The WHOLE step of the benchmark's ``laguna-train-1chip-s16384`` cell
+    (Laguna-XS.2's layers 0-4 as one of 8 expert-parallel ranks, 1 x 16,384
+    tokens, ZeRO-3, through the family's ``lower_train_step``) is accepted
+    for a 16 GB chip with the three window kernels beside the causal chunked
+    ones, and every scope the benchmark reads reaches an ``op_name`` of the
+    compiled text. ~2 minutes: slow-marked (the kernels alone: the test
+    above)."""
+    from benchmark import manifest
+    bench = manifest.load()
+    cell = manifest.cell_of(bench, "laguna-train-1chip-s16384")
+    config = manifest.config_of(bench, cell)
+    lowered = manifest.family_module(config).lower_train_step(
+        config, manifest.traffic_of(cell), topo().devices[:1])
+    assert kernel_names(lowered.as_text()) == {
+        "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
+        "_bwd_dkv_kernel_chunked", "kernel", "_swa_fwd_kernel",
+        "_swa_bwd_dq_kernel", "_swa_bwd_dkv_kernel"}
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    assert 6.8e9 < ma.argument_size_in_bytes < 7.0e9      # 691.6M x 10 B
+    # between 25 % and 100 % of the chip
+    assert 0.25 * HBM_BYTES < ma.peak_memory_in_bytes < 15.75 * 2 ** 30
+    hlo = compiled.as_text()
+    assert not re.search(r"all-gather|all-reduce|reduce-scatter", hlo)
+    assert "16384,16384" not in hlo                       # no [S, S] array
+    for scope in ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv", "flash_fwd_chunk",
+                  "flash_bwd_dq", "flash_bwd_dkv", "attn_gate", "dense_mlp",
+                  "moe_shared", "moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs",
+                  "moe_router", "moe_dispatch", "moe_combine", "attn", "mlp",
+                  "ds_loss_head", "ds_embed", "ds_optimizer"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+
+
 # ------------------------------------- optional kernels: known refusals
 
 TILING_RULE = ("The Pallas TPU lowering currently requires that the last two "
