@@ -381,6 +381,28 @@ def _remat_policy(name):
     raise ValueError(f"unknown remat_policy {name!r}")
 
 
+# what a block under remat keeps whatever else it is asked to keep: the
+# router's choice (``moe/dropless.route``: the recomputed forward pass must
+# route as the first one did) and what its attention kernel produced —
+# one [B, S, H*D] output a layer and a lane-dense log-sum-exp 1/64 of it
+# (``ops/pallas/flash_attention.py`` names both in every VJP). Per byte
+# kept they are the dearest thing a block recomputes: without them every
+# forward attention kernel runs twice a step (PERF.md, PR 34)
+REMAT_BASE_NAMES = ("moe_experts", "flash_o", "flash_lse")
+
+
+def block_remat_policy(name=None):
+    """Policy of the blocks that are rematted one by one
+    (``models/laguna.remat_block``, ``models/qwen3_next._Period``):
+    ``REMAT_BASE_NAMES``, joined with the named policy ``name`` where the
+    config gives one (None: nothing further kept)."""
+    base = jax.checkpoint_policies.save_only_these_names(*REMAT_BASE_NAMES)
+    if name is None:
+        return base
+    return jax.checkpoint_policies.save_from_both_policies(
+        _remat_policy(name), base)
+
+
 def _maybe_remat(cfg, parent, name):
     """The Block class for the child ``name`` of ``parent``: gather edge
     innermost, remat (when configured) round it."""

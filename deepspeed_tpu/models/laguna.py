@@ -43,7 +43,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
-from deepspeed_tpu.models.gpt2 import (_embed_lookup, _remat_policy,
+from deepspeed_tpu.models.gpt2 import (_embed_lookup, block_remat_policy,
                                        chunked_lm_loss, gather_edge_block,
                                        lm_loss)
 from deepspeed_tpu.models.llama import RMSNorm, apply_rope, rope_angles
@@ -305,17 +305,15 @@ class LagunaBlock(nn.Module):
 def remat_block(cfg, parent, name):
     """``LagunaBlock`` under its own ZeRO-3 gather edge (innermost) and,
     where the config asks, its own remat. Whatever the policy keeps, it
-    keeps the router's choice (``moe/dropless.route``); ``prevent_cse``
+    keeps the router's choice and the attention kernel's outputs
+    (``models/gpt2.block_remat_policy``); ``prevent_cse``
     because several rematted blocks share one scan body and a scan of ONE
     period is no loop once XLA has simplified it
     (``models/qwen3_next._Period``)."""
     block = gather_edge_block(LagunaBlock, parent, name)
     if cfg.remat:
-        policy = jax.checkpoint_policies.save_only_these_names("moe_experts")
-        if cfg.remat_policy is not None:
-            policy = jax.checkpoint_policies.save_from_both_policies(
-                _remat_policy(cfg.remat_policy), policy)
-        block = nn.remat(block, prevent_cse=True, policy=policy)
+        block = nn.remat(block, prevent_cse=True,
+                         policy=block_remat_policy(cfg.remat_policy))
     return block
 
 

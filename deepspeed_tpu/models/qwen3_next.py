@@ -34,7 +34,7 @@ layers (``l0`` .. ``l3``), each with its own ZeRO-3 gather edge innermost
 and its own remat round it, as ``models/llama.py`` has for its one block;
 the parameters of period p's j-th layer are slice p of the leaves under
 ``layers/l<j>``. Shares with the other models: ``_embed_lookup``,
-``chunked_lm_loss``, ``gather_edge_block``, ``_remat_policy``
+``chunked_lm_loss``, ``gather_edge_block``, ``block_remat_policy``
 (models/gpt2.py), ``rope_angles`` / ``apply_rope`` (models/llama.py).
 The multi-token-prediction module of the published model is not here.
 """
@@ -47,7 +47,7 @@ import jax.numpy as jnp
 import flax.linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
-from deepspeed_tpu.models.gpt2 import (_embed_lookup, _remat_policy,
+from deepspeed_tpu.models.gpt2 import (_embed_lookup, block_remat_policy,
                                        chunked_lm_loss, gather_edge_block,
                                        lm_loss)
 from deepspeed_tpu.models.llama import apply_rope, rope_angles
@@ -299,18 +299,14 @@ class _Period(nn.Module):
             block = gather_edge_block(Qwen3NextBlock, self, f"l{j}")
             if cfg.remat:
                 # whatever the policy keeps, it keeps the router's choice
-                # (``moe/dropless.route``): the recomputed forward pass
-                # routes as the first one did.
+                # and the attention kernel's outputs
+                # (``models/gpt2.block_remat_policy``).
                 # prevent_cse: several rematted blocks share one scan body,
                 # and a scan of ONE period is no loop at all once XLA has
                 # simplified it: without the barrier the recomputation is
                 # merged back into the forward pass and everything is kept
-                policy = jax.checkpoint_policies.save_only_these_names(
-                    "moe_experts")
-                if cfg.remat_policy is not None:
-                    policy = jax.checkpoint_policies.save_from_both_policies(
-                        _remat_policy(cfg.remat_policy), policy)
-                block = nn.remat(block, prevent_cse=True, policy=policy)
+                block = nn.remat(block, prevent_cse=True,
+                                 policy=block_remat_policy(cfg.remat_policy))
             x = block(cfg, kind, name=f"l{j}")(x, positions)
         return x, None
 

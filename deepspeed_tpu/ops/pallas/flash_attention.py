@@ -33,9 +33,14 @@ Three kernel families share the same per-tile math (`_fwd_block_step` /
   softmax state and the backward's dq/dk/dv accumulators are fp32 VMEM
   scratch; lse and delta travel lane-dense; dq leaves as the input dtype.
 - **chunked**: a third grid dimension streams sequence CHUNKS and
-  accumulates into revisited fp32 output blocks (forward softmax m/l state
-  rides in revisited outputs; normalization happens in-kernel on the last
-  chunk). This is how single-chip attention training reaches 32k context;
+  accumulates into revisited fp32 output blocks (the forward's softmax
+  m/l state in fp32 VMEM scratch; normalization happens in-kernel on the
+  last chunk, which also writes lse lane-dense, [BH, S / 128, 1, 128] as
+  the whole-row kernels store it — a [BH, S, 1] column is 128 x its
+  values' size in HBM, which kept a rematted block from holding it:
+  PERF.md, PR 34; the backward kernels turn a block's rows of lse and
+  delta back into columns in VMEM, ``_stat_col``). This is how
+  single-chip attention training reaches 32k context;
   beyond that, sequence parallelism shards S first
   (deepspeed_tpu/parallel/ring_attention.py). Grouped-query K/V
   ([B, Hkv, S, D], Hkv < H) go into all three chunked kernels AS THEY ARE
@@ -258,6 +263,26 @@ def _stat_row(ref, head, row0, rows):
     piece = ref.shape[-1]
     return _cat([ref[head + (row0 // piece + j,)]
                  for j in range(rows // piece)], 1)
+
+
+def _stat_col(ref, head, row0, rows):
+    """``_stat_row``'s values as a [rows, 1] column, for a score tile held
+    [q, k] (the chunked and the window backward kernels): each piece's row
+    spread down the sublanes, its diagonal kept and summed along the lanes
+    — ``_dense_row`` the other way round."""
+    piece = ref.shape[-1]
+    eye = _rel_pos(piece, piece) == 0
+    return _cat([jnp.sum(jnp.where(eye, ref[head + (row0 // piece + j,)],
+                                   0.0), axis=1, keepdims=True)
+                 for j in range(rows // piece)], 0)
+
+
+def _stat_spec(rows, piece, index):
+    """BlockSpec of ``rows`` rows of a [BH, S / piece, 1, piece] statistic:
+    ``index`` maps the grid to the (row of BH, block of ``rows``) it reads
+    or writes."""
+    return pl.BlockSpec((1, rows // piece, 1, piece),
+                        lambda *g: tuple(index(*g)) + (0, 0))
 
 
 def _own_lanes(x, h, width):
@@ -764,6 +789,35 @@ def _flash_bwd_cols(operands, heads, o, lse, do, scale, causal, block_q,
     return dq, dk, dv
 
 
+# MB the forward rules of the differentiation being traced have named so
+# far, and whether a backward rule has been traced since (the next forward
+# rule then belongs to another program's trace and starts a new sum)
+_named = {"mb": 0.0, "closed": False}
+
+
+def _name_residuals(o, lse):
+    """(o, lse) under the names a remat policy keeps them by — ``flash_o``,
+    ``flash_lse``: with both saved the backward kernels run without the
+    forward kernel being run again (``models/gpt2.py``: the ``dots_flash*``
+    policies and ``block_remat_policy``) — and the gauge
+    ``attention/flash_residual_mb``: decimal MB of HBM the pairs named by
+    one differentiation's forward rules take, a minor dimension counted as
+    the 128-lane tiles it is stored in (a [BH, S, 1] statistic reads 128 x
+    its values' size; every kernel here writes lse lane-dense, 1/64 of a
+    bf16 o at head_dim 128). A forward rule is traced once a call site,
+    all of them before the first backward rule: a scanned layer counts
+    once."""
+    from jax.ad_checkpoint import checkpoint_name
+    if _named["closed"]:
+        _named.update(mb=0.0, closed=False)
+    _named["mb"] += sum(
+        math.prod(x.shape[:-1]) * -(-x.shape[-1] // _LANES) * _LANES
+        * x.dtype.itemsize for x in (o, lse)) / 1e6
+    default_registry().gauge("attention/flash_residual_mb").set(
+        _named["mb"])
+    return checkpoint_name(o, "flash_o"), checkpoint_name(lse, "flash_lse")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
 def _flash_attention_cols(operands, heads, scale, causal, block_q, block_k,
                           interpret):
@@ -776,17 +830,15 @@ def _flash_attention_cols_fwd(operands, heads, scale, causal, block_q,
                               block_k, interpret):
     o, lse = _flash_fwd_cols(operands, heads, scale, causal, block_q,
                              block_k, interpret)
-    # the names the remat policies keep (see _flash_attention_fwd); the
-    # other residual is the projection itself, in place
-    from jax.ad_checkpoint import checkpoint_name
-    o = checkpoint_name(o, "flash_o")
-    lse = checkpoint_name(lse, "flash_lse")
+    # the other residual is the projection itself, in place
+    o, lse = _name_residuals(o, lse)
     return o, (operands, o, lse)
 
 
 def _flash_attention_cols_bwd(heads, scale, causal, block_q, block_k,
                               interpret, residuals, do):
     operands, o, lse = residuals
+    _named["closed"] = True
     grads = _flash_bwd_cols(operands, heads, o, lse, do, scale, causal,
                             block_q, block_k, interpret)
     if len(operands) == 1:
@@ -811,7 +863,32 @@ def _kv_row(heads, kv_heads):
     return lambda b: b
 
 
-def _fwd_kernel_chunked(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
+def _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l, last):
+    """What a grid step of the chunked and the window forward leaves: the
+    raw (o, m, l) while a query block's walk goes on — o in its revisited
+    float32 output block, m (lane-replicated) and l (per-lane partial sums)
+    in VMEM scratch — and on the walk's ``last`` step the normalised o and
+    lse = m + log l, lane-dense through ``_dense_row``: no separate
+    [BH, S, D] normalisation pass, and no [BH, S, 1] statistic, in HBM."""
+    piece = lse_ref.shape[-1]
+
+    @pl.when(jnp.logical_not(last))
+    def _carry():
+        o_ref[0] = o
+        m_ref[...] = m
+        l_ref[...] = l
+
+    @pl.when(last)
+    def _finish():
+        total = _row_total(l)
+        l_safe = jnp.maximum(total, 1e-30)
+        o_ref[0] = jnp.where(total > 0, o / l_safe, 0.0)
+        lse = m + jnp.log(l_safe)
+        for j in range(lse.shape[0] // piece):
+            lse_ref[0, j] = _dense_row(lse[j * piece:(j + 1) * piece])
+
+
+def _fwd_kernel_chunked(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                         *, scale, causal, block_q, block_k, chunk,
                         n_chunks):
     qi = pl.program_id(1)
@@ -825,8 +902,8 @@ def _fwd_kernel_chunked(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     @pl.when(kc == 0)
     def _init():
         o_ref[0] = jnp.zeros_like(o_ref[0])
-        m_ref[0] = jnp.full_like(m_ref[0], NEG_INF)
-        l_ref[0] = jnp.zeros_like(l_ref[0])
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
     def body(j, carry, masked):
         kb = kc * cb + j                       # global k-block index
@@ -835,10 +912,7 @@ def _fwd_kernel_chunked(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         mask = _block_mask(rel, masked, qi * block_q, kb * block_k)
         return _fwd_block_step(q, k, v, carry, mask, s_scale)
 
-    stat = (block_q, _LANES)
-    lane0 = jax.lax.broadcasted_iota(jnp.int32, stat, 1) == 0
-    carry0 = (o_ref[0], jnp.broadcast_to(m_ref[0], stat),
-              jnp.where(lane0, l_ref[0], 0.0))
+    carry0 = (o_ref[0], m_ref[...], l_ref[...])
     if causal:
         num_full = (qi * block_q) // block_k
         num_active = ((qi + 1) * block_q + block_k - 1) // block_k
@@ -847,16 +921,25 @@ def _fwd_kernel_chunked(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         o, m, l = _causal_split_loop(0, j_full, j_hi, body, carry0)
     else:
         o, m, l = _causal_split_loop(0, cb, cb, body, carry0)
+    _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l,
+                        kc == n_chunks - 1)
 
-    # accumulate raw (o, m, l) across chunk revisits; the last chunk holds
-    # the final softmax state, so normalize in-kernel there — no separate
-    # [BH, S, D] normalization pass in HBM
-    last = kc == n_chunks - 1
-    l = _row_total(l)
-    l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = jnp.where(last, jnp.where(l > 0, o / l_safe, 0.0), o)
-    m_ref[0] = jnp.where(last, m[:, :1] + jnp.log(l_safe), m[:, :1])
-    l_ref[0] = l
+
+def _chunked_fwd_outputs(q, block_q, block_k):
+    """(out_specs, out_shape, scratch_shapes) of the chunked and the window
+    forward on grid (BH, q blocks, chunks): o [BH, S, D] float32, revisited
+    over a block's walk; lse [BH, S / piece, 1, piece] float32, lane-dense
+    as the whole-row kernels store it (``_stat_piece``), written on the
+    walk's last step; the running m and l, [block_q, 128] VMEM scratch."""
+    BH, S, D = q.shape
+    piece = _stat_piece(block_q, block_k)
+    return (
+        [pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
+         _stat_spec(block_q, piece, lambda b, i, c: (b, i))],
+        [jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
+         jax.ShapeDtypeStruct((BH, S // piece, 1, piece), jnp.float32)],
+        [pltpu.VMEM((block_q, _LANES), jnp.float32),
+         pltpu.VMEM((block_q, _LANES), jnp.float32)])
 
 
 def _flash_fwd_chunked(q, k, v, scale, causal, block_q, block_k, chunk,
@@ -864,6 +947,7 @@ def _flash_fwd_chunked(q, k, v, scale, causal, block_q, block_k, chunk,
     BH, S, D = q.shape
     n_chunks = S // chunk
     kv = _kv_row(heads, kv_heads)
+    out_specs, out_shape, scratch = _chunked_fwd_outputs(q, block_q, block_k)
     kernel = functools.partial(_fwd_kernel_chunked, scale=scale,
                                causal=causal, block_q=block_q,
                                block_k=block_k, chunk=chunk,
@@ -876,20 +960,13 @@ def _flash_fwd_chunked(q, k, v, scale, causal, block_q, block_k, chunk,
             pl.BlockSpec((1, chunk, D), lambda b, i, c: (kv(b), c, 0)),
             pl.BlockSpec((1, chunk, D), lambda b, i, c: (kv(b), c, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
-            jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
-            jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
     )
     with annotate("flash_fwd_chunk"):
-        o32, lse, _ = call(q, k, v)
+        o32, lse = call(q, k, v)
     return o32.astype(q.dtype), lse
 
 
@@ -903,8 +980,8 @@ def _bwd_dq_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     s_scale = None if fold else scale
     q = q_ref[0] * scale if fold else q_ref[0]
     do = do_ref[0]
-    lse = lse_ref[0]
-    delta = delta_ref[0]
+    lse = _stat_col(lse_ref, (0,), 0, block_q)
+    delta = _stat_col(delta_ref, (0,), 0, block_q)
     rel = _rel_pos(block_q, block_k) if causal else None
 
     @pl.when(kc == 0)
@@ -957,8 +1034,8 @@ def _bwd_dkv_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if fold:
             q = q * scale
         do = do_ref[0, pl.ds(j * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(j * block_q, block_q), :]
-        delta = delta_ref[0, pl.ds(j * block_q, block_q), :]
+        lse = _stat_col(lse_ref, (0,), j * block_q, block_q)
+        delta = _stat_col(delta_ref, (0,), j * block_q, block_q)
         mask = _block_mask(rel, masked, qb * block_q, ki * block_k)
         p, ds = _bwd_ds_block(q, do, lse, delta, k, v, mask, s_scale)
         dv_new = dv_acc + jax.lax.dot_general(
@@ -999,7 +1076,8 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     n_chunks = S // chunk
     kv = _kv_row(heads, kv_heads)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)[:, :, None]
+                    axis=-1).reshape(lse.shape)
+    piece = lse.shape[-1]
 
     call_dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel_chunked, scale=scale, causal=causal,
@@ -1011,9 +1089,7 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
             pl.BlockSpec((1, chunk, D), lambda b, i, c: (kv(b), c, 0)),
             pl.BlockSpec((1, chunk, D), lambda b, i, c: (kv(b), c, 0)),
             pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
-        ],
+        ] + [_stat_spec(block_q, piece, lambda b, i, c: (b, i))] * 2,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
         interpret=interpret,
@@ -1031,9 +1107,7 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
             pl.BlockSpec((1, block_k, D), lambda b, i, c: (kv(b), i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, c: (kv(b), i, 0)),
             pl.BlockSpec((1, chunk, D), lambda b, i, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, i, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, i, c: (b, c, 0)),
-        ],
+        ] + [_stat_spec(chunk, piece, lambda b, i, c: (b, c))] * 2,
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, i, c: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, c: (b, i, 0)),
@@ -1130,8 +1204,8 @@ def _band_kv_map(kv, block_q, chunk, n_band):
         _band_first_chunk(i, block_q, chunk, n_band) + c, 0), 0)
 
 
-def _swa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *, scale,
-                    window, block_q, block_k, chunk, n_band):
+def _swa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, *,
+                    scale, window, block_q, block_k, chunk, n_band):
     qi = pl.program_id(1)
     c = pl.program_id(2)
     kc = _band_first_chunk(qi, block_q, chunk, n_band) + c
@@ -1145,8 +1219,8 @@ def _swa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *, scale,
     @pl.when(c == 0)
     def _init():
         o_ref[0] = jnp.zeros_like(o_ref[0])
-        m_ref[0] = jnp.full_like(m_ref[0], NEG_INF)
-        l_ref[0] = jnp.zeros_like(l_ref[0])
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
     def body(j, carry, masked):
         k0 = (kc * cb + j) * block_k
@@ -1155,23 +1229,16 @@ def _swa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *, scale,
         mask = _band_mask(rel, q0, k0, window) if masked else None
         return _fwd_block_step(q, k, v, carry, mask, s_scale)
 
-    stat = (block_q, _LANES)
-    lane0 = jax.lax.broadcasted_iota(jnp.int32, stat, 1) == 0
-    carry0 = (o_ref[0], jnp.broadcast_to(m_ref[0], stat),
-              jnp.where(lane0, l_ref[0], 0.0))
     o, m, l = _band_loop(
-        _band_k_ranges(q0, kc, block_q, block_k, cb, window), body, carry0)
+        _band_k_ranges(q0, kc, block_q, block_k, cb, window), body,
+        (o_ref[0], m_ref[...], l_ref[...]))
     # as ``_fwd_kernel_chunked``: raw (o, m, l) between a block's steps, the
     # last step (the diagonal's chunk) normalises in the kernel. A row its
     # band's first block hides whole takes exp(0) there; the next visible
     # key's alpha = exp(NEG_INF - m) = 0 wipes it, and the diagonal is
     # always visible and always last
-    last = c == n_band - 1
-    l = _row_total(l)
-    l_safe = jnp.maximum(l, 1e-30)
-    o_ref[0] = jnp.where(last, jnp.where(l > 0, o / l_safe, 0.0), o)
-    m_ref[0] = jnp.where(last, m[:, :1] + jnp.log(l_safe), m[:, :1])
-    l_ref[0] = l
+    _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l,
+                        c == n_band - 1)
 
 
 def _swa_fwd(q, k, v, scale, window, block_q, block_k, chunk, interpret,
@@ -1179,6 +1246,7 @@ def _swa_fwd(q, k, v, scale, window, block_q, block_k, chunk, interpret,
     BH, S, D = q.shape
     n_band = _band_extent(S, block_q, chunk, window, keys=True)
     band = _band_kv_map(_kv_row(heads, kv_heads), block_q, chunk, n_band)
+    out_specs, out_shape, scratch = _chunked_fwd_outputs(q, block_q, block_k)
     call = pl.pallas_call(
         functools.partial(_swa_fwd_kernel, scale=scale, window=window,
                           block_q=block_q, block_k=block_k, chunk=chunk,
@@ -1189,20 +1257,13 @@ def _swa_fwd(q, k, v, scale, window, block_q, block_k, chunk, interpret,
             pl.BlockSpec((1, chunk, D), band),
             pl.BlockSpec((1, chunk, D), band),
         ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
-            jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
-            jax.ShapeDtypeStruct((BH, S, 1), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
     )
     with annotate("swa_fwd"):
-        o32, lse, _ = call(q, k, v)
+        o32, lse = call(q, k, v)
     return o32.astype(q.dtype), lse
 
 
@@ -1217,8 +1278,8 @@ def _swa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     s_scale = None if fold else scale
     q = q_ref[0] * scale if fold else q_ref[0]
     do = do_ref[0]
-    lse = lse_ref[0]
-    delta = delta_ref[0]
+    lse = _stat_col(lse_ref, (0,), 0, block_q)
+    delta = _stat_col(delta_ref, (0,), 0, block_q)
     rel = _rel_pos(block_q, block_k)
     q0 = qi * block_q
 
@@ -1270,8 +1331,8 @@ def _swa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         if fold:
             q = q * scale
         do = do_ref[0, pl.ds(j * block_q, block_q), :]
-        lse = lse_ref[0, pl.ds(j * block_q, block_q), :]
-        delta = delta_ref[0, pl.ds(j * block_q, block_q), :]
+        lse = _stat_col(lse_ref, (0,), j * block_q, block_q)
+        delta = _stat_col(delta_ref, (0,), j * block_q, block_q)
         mask = _band_mask(rel, q0, k0, window) if masked else None
         p, ds = _bwd_ds_block(q, do, lse, delta, k, v, mask, s_scale)
         dv_new = dv_acc + jax.lax.dot_general(
@@ -1297,7 +1358,8 @@ def _swa_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k, chunk,
     BHkv = k.shape[0]
     rep = BH // BHkv
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1)[:, :, None]
+                    axis=-1).reshape(lse.shape)
+    piece = lse.shape[-1]
     n_band = _band_extent(S, block_q, chunk, window, keys=True)
     band = _band_kv_map(_kv_row(heads, kv_heads), block_q, chunk, n_band)
     call_dq = pl.pallas_call(
@@ -1310,9 +1372,7 @@ def _swa_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k, chunk,
             pl.BlockSpec((1, chunk, D), band),
             pl.BlockSpec((1, chunk, D), band),
             pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, c: (b, i, 0)),
-        ],
+        ] + [_stat_spec(block_q, piece, lambda b, i, c: (b, i))] * 2,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
         interpret=interpret,
@@ -1339,9 +1399,7 @@ def _swa_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k, chunk,
             pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
             pl.BlockSpec((1, chunk, D), band_q),
-            pl.BlockSpec((1, chunk, 1), band_q),
-            pl.BlockSpec((1, chunk, 1), band_q),
-        ],
+        ] + [_stat_spec(chunk, piece, lambda *g: band_q(*g)[:2])] * 2,
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
@@ -1366,17 +1424,16 @@ def _flash_attention_swa(q, k, v, scale, window, block_q, block_k, chunk,
 
 def _flash_attention_swa_fwd(q, k, v, scale, window, block_q, block_k, chunk,
                              interpret, heads, kv_heads):
-    from jax.ad_checkpoint import checkpoint_name
-    o, lse = _swa_fwd(q, k, v, scale, window, block_q, block_k, chunk,
-                      interpret, heads, kv_heads)
-    o = checkpoint_name(o, "flash_o")
-    lse = checkpoint_name(lse, "flash_lse")
+    o, lse = _name_residuals(*_swa_fwd(
+        q, k, v, scale, window, block_q, block_k, chunk, interpret, heads,
+        kv_heads))
     return o, (q, k, v, o, lse)
 
 
 def _flash_attention_swa_bwd(scale, window, block_q, block_k, chunk,
                              interpret, heads, kv_heads, residuals, do):
     q, k, v, o, lse = residuals
+    _named["closed"] = True
     return _swa_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k,
                     chunk, interpret, heads, kv_heads)
 
@@ -1410,20 +1467,16 @@ def _flash_attention(q, k, v, scale, causal, block_q, block_k, chunk,
 
 def _flash_attention_fwd(q, k, v, scale, causal, block_q, block_k, chunk,
                          interpret, heads=0, kv_heads=0):
-    o, lse = _dispatch_fwd(q, k, v, scale, causal, block_q, block_k, chunk,
-                           interpret, heads, kv_heads)
-    # name the residuals so remat policies can elect to keep them: saving
-    # o (+tiny lse) lets the backward kernels run without re-executing the
-    # forward kernel under rematerialization (models/gpt2.py "dots_flash")
-    from jax.ad_checkpoint import checkpoint_name
-    o = checkpoint_name(o, "flash_o")
-    lse = checkpoint_name(lse, "flash_lse")
+    o, lse = _name_residuals(*_dispatch_fwd(
+        q, k, v, scale, causal, block_q, block_k, chunk, interpret, heads,
+        kv_heads))
     return o, (q, k, v, o, lse)
 
 
 def _flash_attention_bwd(scale, causal, block_q, block_k, chunk, interpret,
                          heads, kv_heads, residuals, do):
     q, k, v, o, lse = residuals
+    _named["closed"] = True
     gqa = bool(heads and kv_heads and heads != kv_heads)
     if gqa:
         B = q.shape[0] // heads
@@ -1510,9 +1563,11 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
     ``attention/flash_tile_overcompute`` and
     ``attention/flash_heads_per_block`` (heads a 128-lane column block of
     [B, S, H*D] operands; 0 for a head-major call) and, once per distinct
-    shape, a log line of the layout and loop structure chosen for it.
+    shape, a log line of the layout (the operands' and the log-sum-exp's)
+    and loop structure chosen for it.
     ``window``: a call of the window kernels — the gauge
     ``attention/window_tile_overcompute`` and the band's plan instead."""
+    piece = _stat_piece(block_q, block_k)
     if window:
         over = window_tile_overcompute(S, block_q, block_k, window)
         default_registry().gauge("attention/window_tile_overcompute").set(
@@ -1522,7 +1577,8 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
             _plans_logged.add(plan)
             logger.info(
                 f"flash attention S={S} D={D} {plan[2]} window={window}: "
-                f"layout [B*H, S, D] head-major, block_q={block_q} "
+                f"layout [B*H, S, D] head-major, lse [B*H, S/{piece}, 1, "
+                f"{piece}], block_q={block_q} "
                 f"block_k={block_k} chunk={chunk}, a query block walks "
                 f"{_band_extent(S, block_q, chunk, window, True)} of "
                 f"{S // chunk} key chunks, a key block "
@@ -1540,8 +1596,10 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
     if plan not in _plans_logged:
         _plans_logged.add(plan)
         strip = 0 if chunk else _pick_strip(block_q)
-        layout = (f"[B, S, H*D] column blocks of {heads_per_block} heads"
-                  if heads_per_block else "[B*H, S, D] head-major")
+        layout = ((f"[B, S, H*D] column blocks of {heads_per_block} heads"
+                   f", lse [B, H, S/{piece}, 1, {piece}]")
+                  if heads_per_block else
+                  f"[B*H, S, D] head-major, lse [B*H, S/{piece}, 1, {piece}]")
         logger.info(
             f"flash attention S={S} D={D} {plan[2]} causal={causal}: "
             f"layout {layout}, "

@@ -159,7 +159,16 @@ def annotate(tag):
     them. The window kernels leave one,
     ``attention/window_tile_overcompute`` (score elements their tiles
     compute over those the band holds, forward and backward):
-    ``swa_tile_overcompute`` reads it."""
+    ``swa_tile_overcompute`` reads it. Every flash VJP's forward rule
+    leaves ``attention/flash_residual_mb`` (decimal MB of HBM the
+    ``flash_o`` / ``flash_lse`` pairs one differentiation names take, a
+    minor dimension counted in 128-lane tiles: what a remat policy that
+    keeps the names holds, ~1,227 on the Laguna cell's step; whether
+    remat DID keep them is the compiled step's to say — no forward
+    attention scope under ``rematted_computation``,
+    ``tests/hlo_text.rematted_forward_attention`` — and on the chip
+    ``train_recompute_ms`` and the forward rooflines): no benchmark
+    metric reads it."""
     import jax
     return jax.named_scope(tag)
 

@@ -1,6 +1,7 @@
 """Questions the ZeRO tests ask of a compiled step's HLO text (CPU or TPU
 compiler alike): which computations run inside a ``while`` loop — a layer
-scan's body and whatever it calls — and which collectives sit there."""
+scan's body and whatever it calls — and which collectives sit there; and
+of any step under remat: which forward attention kernels it runs again."""
 
 import re
 
@@ -73,3 +74,47 @@ def result_elements(line):
     for d in result_shape(line):
         n *= d
     return n
+
+
+# the scopes round the forward attention kernels' ``pallas_call``s
+# (ops/pallas/flash_attention.py: whole-row, chunked, window)
+FORWARD_ATTENTION_SCOPES = ("flash_fwd", "flash_fwd_chunk", "swa_fwd")
+
+
+def rematted_forward_attention(text):
+    """The forward attention kernel calls a step runs a SECOND time, inside
+    a rematted block's recomputation: the distinct scope paths of ``text``
+    — a compiled step's (``op_name="..."``) or a lowered one's with
+    ``debug_info`` (``loc("...")``) — that hold JAX's
+    ``rematted_computation`` and, below it, one of
+    ``FORWARD_ATTENTION_SCOPES``, cut at that scope: one path a call site,
+    on the TPU one Pallas call (the scope holds nothing else), on the CPU
+    the interpreter's many instructions under it. Empty where the blocks
+    keep ``flash_o`` / ``flash_lse``."""
+    sites = set()
+    for path in re.findall(r'"([^"]*rematted_computation[^"]*)"', text):
+        parts = path.split("/")
+        if "rematted_computation" not in parts:
+            continue
+        start = parts.index("rematted_computation")
+        for i in range(start, len(parts)):
+            if parts[i] in FORWARD_ATTENTION_SCOPES:
+                # from the recomputation down: XLA leaves the path's head
+                # off some of a call site's instructions
+                sites.add("/".join(parts[start:i + 1]))
+                break
+    return sorted(sites)
+
+
+def remat_report(loss, params, capsys):
+    """Of ``loss(params)``'s gradient program under the model's own remat:
+    (``rematted_forward_attention`` of its lowered text, the words of
+    ``jax.ad_checkpoint.print_saved_residuals`` — what the backward pass is
+    handed, a kept name as ``named 'flash_lse'`` — and the lowered
+    program, for who wants its gradients)."""
+    import jax
+    lowered = jax.jit(jax.grad(loss)).lower(params)
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(loss, params)
+    return (rematted_forward_attention(lowered.as_text(debug_info=True)),
+            capsys.readouterr().out, lowered)

@@ -4,10 +4,14 @@
 16,384 x head_dim 128, bf16, window 512 — forward and forward + backward,
 over grid blocks and chunks, beside full causal attention of the same shape
 (what a window layer would cost under a mask) and against the masked
-float32 reference at a shorter sequence. Not part of the benchmark:
-PERF.md's Findings quote it.
+float32 reference at a shorter sequence; since PR 34 also the causal chunked
+kernels at the cell's full layers' shape (48 query / 8 KV heads) and what a
+re-layout of a ``[BH, S, 1]`` log-sum-exp to 128 dense lanes and back costs
+in XLA (the form PR 34 did not take). ``--tree`` times another checkout's
+kernels (the parent's, unpacked in a git-ignored directory) with this
+harness. Not part of the benchmark: PERF.md's Findings quote it.
 
-    chiprun -- python tests/perf/swa_bench.py [--out NAME]
+    chiprun -- python tests/perf/swa_bench.py [--out NAME] [--tree DIR]
 """
 
 import argparse
@@ -17,8 +21,10 @@ import statistics
 import sys
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__)))))
+HERE = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path.insert(0, next((os.path.abspath(a.split("=", 1)[1])
+                         for a in sys.argv if a.startswith("--tree=")), HERE))
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -69,13 +75,18 @@ def main():
     ap.add_argument("--heads", type=int, default=64)
     ap.add_argument("--seq", type=int, default=16384)
     ap.add_argument("--window", type=int, default=512)
+    ap.add_argument("--causal-heads", type=int, default=48)
+    ap.add_argument("--tiles", choices=("all", "default"), default="all",
+                    help="default: only the tiling the dispatch picks")
+    ap.add_argument("--tree", default=HERE,
+                    help="--tree=DIR: the checkout whose kernels are timed")
     args = ap.parse_args()
     H, Hkv, S, D, W = args.heads, 8, args.seq, 128, args.window
     (q, k, v), cot = inputs(H, Hkv, S, D)
     band = S * W - W * (W - 1) // 2
     product = 2 * H * band * D
     rows = []
-    for bq, bk, chunk in TILES:
+    for bq, bk, chunk in TILES if args.tiles == "all" else ((512, 512, 512),):
         attend = lambda q, k, v: flash_attention(  # noqa: E731
             q, k, v, causal=True, window=W, block_q=bq, block_k=bk,
             chunk=chunk)
@@ -99,6 +110,24 @@ def main():
                                                           causal=True))
     causal = {"causal_fwd_ms": timed(fwd, q, k, v, reps=5),
               "causal_fwd_bwd_ms": timed(grads, q, k, v, cot, reps=5)}
+    # the cell's FULL layers: 48 query heads, causal, the chunked kernels
+    Hc = args.causal_heads
+    (qc, kc, vc), cotc = inputs(Hc, Hkv, S, D, seed=2)
+    f_ms, g_ms = timed(fwd, qc, kc, vc, reps=5), timed(grads, qc, kc, vc,
+                                                       cotc, reps=5)
+    needed = 2 * Hc * (S * (S + 1) // 2) * D        # one product, causal
+    causal.update({
+        "causal48_heads": Hc, "causal48_fwd_ms": f_ms,
+        "causal48_fwd_bwd_ms": g_ms,
+        "causal48_fwd_roofline_pct": 100 * 2 * needed / PEAK / (f_ms / 1e3),
+        "causal48_bwd_roofline_pct": 100 * 4 * needed / PEAK
+        / ((g_ms - f_ms) / 1e3)})
+    # the form not taken: a [BH, S, 1] statistic re-laid to dense lanes
+    column = jnp.zeros((H, S, 1), jnp.float32)
+    dense = jax.jit(lambda x: x.reshape(H, S // 128, 1, 128))
+    back = jax.jit(lambda x: x.reshape(H, S, 1))
+    causal.update({"lse_column_to_dense_ms": timed(dense, column),
+                   "lse_dense_to_column_ms": timed(back, dense(column))})
     print(json.dumps(causal), flush=True)
 
     # accuracy at a length the [S, S] reference fits, bf16 in, f32 compared
@@ -112,7 +141,8 @@ def main():
             f32(q), f32(k), f32(v))
     rel = [float(jnp.linalg.norm(f32(a) - b) / jnp.linalg.norm(b))
            for a, b in zip(got, want)]
-    out = {"shape": [1, H, Hkv, S, D], "window": W, "tiles": rows,
+    out = {"tree": os.path.relpath(args.tree, HERE),
+           "shape": [1, H, Hkv, S, D], "window": W, "tiles": rows,
            "device": jax.devices()[0].device_kind, **causal,
            "grad_rel_vs_f32_reference_dq_dk_dv": rel}
     print(json.dumps({"grad_rel_vs_f32_reference_dq_dk_dv": rel}))

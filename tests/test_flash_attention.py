@@ -718,3 +718,138 @@ def test_dot_product_attention_passes_the_window_through_its_shard_map():
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-5)
     for a, b in zip(grads, ref_grads):
         np.testing.assert_allclose(a, b, rtol=5e-3, atol=5e-4)
+
+
+# ------------------------------------------------------------------------
+# the log-sum-exp the chunked and the window kernels hand the backward pass
+# (ISSUE 34): lane-dense, so that a rematted block can afford to keep it
+
+def _named(jaxpr, found=None):
+    """{checkpoint name: [avals]} of a jaxpr and every jaxpr inside it."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "name":
+            found.setdefault(eqn.params["name"], []).append(
+                eqn.outvars[0].aval)
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _named(inner, found)
+    return found
+
+
+def _lse_reference(q, k, causal, window):
+    rep = q.shape[1] // k.shape[1]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, jnp.repeat(k, rep, axis=1),
+                   precision="highest") / np.sqrt(q.shape[-1])
+    rel = jnp.arange(q.shape[2])[:, None] - jnp.arange(q.shape[2])[None]
+    seen = (rel >= 0) if causal else jnp.ones_like(rel, bool)
+    if window:
+        seen &= rel < window
+    return jax.nn.logsumexp(jnp.where(seen, s, -jnp.inf), axis=-1)
+
+
+@pytest.mark.parametrize("H,Hkv,D,window", [
+    (2, 2, 128, None), (2, 1, 128, None),       # chunked causal, MHA / GQA
+    (2, 2, 256, None), (4, 1, 256, None),
+    (2, 2, 128, 100), (4, 1, 128, 160),         # the window kernels
+], ids=lambda v: str(v))
+def test_chunked_and_window_lse_is_lane_dense(H, Hkv, D, window):
+    """What the VJP names ``flash_lse`` is float32 [B*H, S / 128, 1, 128]
+    — 128 real values a row, the reference's log-sum-exp, no trailing 1 —
+    and forward, dq, dk, dv hold the float32 reference's within the limits
+    the parity tests above hold."""
+    fa = _fa()
+    S, block, chunk = 384, 128, 128
+    q, _, _ = _qkv((1, H, S, D), seed=H + D)
+    _, k, v = _qkv((1, Hkv, S, D), seed=H + D + 1)
+    attend = functools.partial(flash_attention, causal=True, window=window,
+                               block_q=block, block_k=block, chunk=chunk,
+                               interpret=True)
+    named = _named(jax.make_jaxpr(
+        lambda *a: jax.vjp(attend, *a)[1](a[0]))(q, k, v).jaxpr)
+    (lse,), (o,) = named["flash_lse"], named["flash_o"]
+    assert lse.shape == (H, S // 128, 1, 128) and lse.dtype == jnp.float32
+    assert o.shape == (H, S, D)
+
+    if window:
+        _, got = fa._swa_fwd(q[0], k[0], v[0], D ** -0.5, window, block,
+                             block, chunk, True, H, Hkv)
+    else:
+        _, got = fa._flash_fwd_chunked(q[0], k[0], v[0], D ** -0.5, True,
+                                       block, block, chunk, True, H, Hkv)
+    np.testing.assert_allclose(got.reshape(H, S),
+                               _lse_reference(q, k, True, window)[0],
+                               rtol=2e-5, atol=2e-5)
+
+    def both(f):
+        return (f(q, k, v),) + jax.grad(lambda *a: jnp.sum(jnp.sin(f(*a))),
+                                        argnums=(0, 1, 2))(q, k, v)
+    want = both(functools.partial(reference_attention, causal=True,
+                                  window=window))
+    for a, b, name in zip(both(attend), want, ("out", "dq", "dk", "dv")):
+        assert a.shape == b.shape
+        fwd = name == "out"
+        np.testing.assert_allclose(a, b, rtol=2e-4 if fwd else 5e-3,
+                                   atol=2e-5 if fwd else 5e-4, err_msg=name)
+
+
+def test_flash_residual_gauge_counts_hbm_tiles_of_one_differentiation():
+    """``attention/flash_residual_mb``: MB of the (o, lse) pairs one
+    differentiation's forward rules name, a minor dimension counted in
+    128-lane tiles — a padded [BH, S, 1] statistic could not hide in it —
+    and a second differentiation starts from nothing."""
+    from deepspeed_tpu.telemetry.registry import default_registry
+    fa = _fa()
+    q = jnp.zeros((1, 2, 256, 128), jnp.bfloat16)
+
+    def two_layers(x):
+        for window in (None, 64):
+            x = flash_attention(x, x, x, causal=True, window=window,
+                                block_q=128, block_k=128, chunk=128,
+                                interpret=True)
+        return jnp.sum(x.astype(jnp.float32))
+
+    one = (2 * 256 * 128 * 2 + 2 * 256 * 4) / 1e6       # bf16 o + f32 lse
+    for _ in range(2):
+        jax.make_jaxpr(jax.grad(two_layers))(q)
+        assert default_registry().peek_gauge(
+            "attention/flash_residual_mb") == pytest.approx(2 * one)
+    column = jax.ShapeDtypeStruct((2, 256, 1), jnp.float32)
+    fa._name_residuals(jax.ShapeDtypeStruct((2, 256, 128), jnp.bfloat16),
+                       column)
+    # ... and a [BH, S, 1] column reads the 128 lanes a value it is stored in
+    assert default_registry().peek_gauge("attention/flash_residual_mb") \
+        == pytest.approx((2 * 256 * 128 * 2 + 2 * 256 * 128 * 4) / 1e6)
+
+
+def test_gpt2_dots_flash_fc_lean_is_unchanged_by_the_block_policy(
+        monkeypatch):
+    """GPT-2's blocks take their named policy as before
+    (``_maybe_remat``), and joining ``block_remat_policy``'s base set to
+    ``dots_flash_fc_lean`` would change nothing there: the policy keeps
+    both flash names already and GPT-2 names no ``moe_experts`` — the
+    gradient jaxpr is the same but for the policy function's name."""
+    import re
+    from deepspeed_tpu.models import gpt2
+    cfg = gpt2.GPT2Config(vocab_size=128, n_positions=64, n_embd=64,
+                          n_layer=2, n_head=2, scan_layers=True, remat=True,
+                          remat_policy="dots_flash_fc_lean", use_flash=True,
+                          dtype=jnp.float32)
+    model = gpt2.GPT2LMHeadModel(cfg)
+    ids = jnp.zeros((1, 64), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+
+    def jaxpr():
+        text = str(jax.make_jaxpr(jax.grad(lambda p: model.apply(
+            {"params": p}, ids, labels=ids)))(params))
+        assert "flash_lse" in text
+        return re.sub(r"policy=[^\n]*", "policy=", text)
+
+    named = jaxpr()
+    monkeypatch.setattr(gpt2, "_maybe_remat", lambda cfg, parent, name: (
+        gpt2.nn.remat(gpt2.gather_edge_block(gpt2.Block, parent, name),
+                      prevent_cse=False, static_argnums=(2,),
+                      policy=gpt2.block_remat_policy(cfg.remat_policy))))
+    assert jaxpr() == named

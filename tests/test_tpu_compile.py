@@ -39,6 +39,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,  # noqa: E402
                           SingleDeviceSharding)
 
 import chip_smoke  # noqa: E402  (the model, batch and serving block it runs)
+from deepspeed_tpu.telemetry.registry import default_registry  # noqa: E402
 from tests import hlo_text  # noqa: E402
 
 
@@ -115,6 +116,27 @@ def head_major_operands(calls):
             for shape in re.findall(r"\w+\[[\d,]*,64\]", ln)]
 
 
+def rematted(attend):
+    """``attend`` as a block under remat has it: ``jax.checkpoint`` with the
+    policy of ``models/gpt2.block_remat_policy``."""
+    from deepspeed_tpu.models.gpt2 import block_remat_policy
+    return jax.checkpoint(attend, prevent_cse=True,
+                          policy=block_remat_policy())
+
+
+def assert_dense_lse_kept(hlo, calls, dense):
+    """A rematted call's gradient program holds the forward kernel ONCE
+    (``calls``: forward, dq, dkv — ``flash_o`` / ``flash_lse`` are kept), the
+    forward kernel writes lse as ``dense`` ([BH, S / 128, 1, 128]: 128 real
+    lanes), both backward kernels read it so, and no [.., S, 1] column, 128 x
+    the size in HBM, is anywhere in the step."""
+    assert len(calls) == 3, calls
+    assert hlo_text.rematted_forward_attention(hlo) == []
+    assert all(dense in c for c in calls), (dense, calls)
+    assert sum(dense in c.split(" custom-call(")[0] for c in calls) == 1
+    assert not re.search(r"f32\[\d+,\d{4,},1\]", hlo)
+
+
 # ------------------------------------------------------- main-path kernels
 
 @pytest.mark.parametrize("shape", [(chip_smoke.BATCH, 20, 1024, 64),
@@ -132,7 +154,7 @@ def test_flash_attention_fwd_and_grad_compile_at_774m_shape(shape):
         return jax.grad(lambda *a: flash_attention(*a, causal=True)
                         .astype(F32).sum(), argnums=(0, 1, 2))(q, k, v)
 
-    text, _ = compile_on_chip(grads, *qkv)
+    text, compiled = compile_on_chip(grads, *qkv)
     assert kernel_names(text) == {"_fwd_kernel", "_bwd_fused_kernel"}
 
 
@@ -151,7 +173,6 @@ def test_flash_attention_column_blocks_compile_at_gpt2_shapes(operands,
     second head skipped), the longest row, and a head a block at head_dim
     128. No operand or result is head-major: nothing of [.., 1024, 64]."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention_bse
-    from deepspeed_tpu.telemetry.registry import default_registry
 
     def grads(*a):
         return jax.grad(lambda *b: flash_attention_bse(
@@ -178,10 +199,12 @@ def test_flash_attention_chunked_fwd_and_grad_compile_at_olmoe_shape():
         return jax.grad(lambda *a: flash_attention(*a, causal=True)
                         .astype(F32).sum(), argnums=(0, 1, 2))(q, k, v)
 
-    text, _ = compile_on_chip(grads, *qkv)
+    text, compiled = compile_on_chip(grads, *qkv)
     assert kernel_names(text) == {"_fwd_kernel_chunked",
                                   "_bwd_dq_kernel_chunked",
                                   "_bwd_dkv_kernel_chunked"}
+    hlo = compiled.as_text()
+    assert_dense_lse_kept(hlo, flash_calls(hlo), "f32[64,32,1,128]")
 
 
 def test_grouped_matmul_fwd_and_grads_compile_at_olmoe_shape():
@@ -484,7 +507,6 @@ def test_olmoe_step_compiles_for_one_chip_with_its_scopes_and_fits():
         "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
         "_bwd_dkv_kernel_chunked", "kernel"}
     # head-major, from ``models/llama.py``: ISSUE 30's path is bypassed
-    from deepspeed_tpu.telemetry.registry import default_registry
     assert default_registry().peek_gauge(
         "attention/flash_heads_per_block") == 0
     compiled = lowered.compile()
@@ -513,8 +535,8 @@ def test_flash_attention_chunked_gqa_compiles_at_qwen3_next_shape():
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
     def grads(q, k, v):
-        return jax.grad(lambda *a: flash_attention(*a, causal=True)
-                        .astype(F32).sum(), argnums=(0, 1, 2))(q, k, v)
+        return jax.grad(rematted(lambda *a: flash_attention(
+            *a, causal=True).astype(F32).sum()), argnums=(0, 1, 2))(q, k, v)
 
     text, compiled = compile_on_chip(
         grads, SDS((2, 16, 8192, 256), BF16), SDS((2, 2, 8192, 256), BF16),
@@ -523,8 +545,11 @@ def test_flash_attention_chunked_gqa_compiles_at_qwen3_next_shape():
                                   "_bwd_dq_kernel_chunked",
                                   "_bwd_dkv_kernel_chunked"}
     # every Pallas call reads K and V at 4 = 2 x 2 rows, none at 32
-    calls = flash_calls(compiled.as_text())
+    hlo = compiled.as_text()
+    calls = flash_calls(hlo)
     assert len(calls) == 3 and all("bf16[4,8192,256]" in c for c in calls)
+    # under the blocks' remat policy, as the cell's attention layer is
+    assert_dense_lse_kept(hlo, calls, "f32[32,64,1,128]")
 
 
 @pytest.mark.parametrize("D,kernels,scopes", [
@@ -592,6 +617,10 @@ def test_qwen3_next_step_compiles_for_one_chip_with_its_scopes_and_fits():
     assert 10e9 < ma.peak_memory_in_bytes < 15.75 * 2 ** 30
     hlo = compiled.as_text()
     assert not re.search(r"all-gather|all-reduce|reduce-scatter", hlo)
+    # the attention layer's forward kernel runs once: its o and lse are kept
+    assert hlo_text.rematted_forward_attention(hlo) == []
+    assert default_registry().peek_gauge(
+        "attention/flash_residual_mb") == pytest.approx(135.3, abs=0.1)
     for scope in ("gdn_conv", "gdn_gates", "gdn_scan_prep", "gdn_scan_fwd",
                   "gdn_scan_bwd", "gdn_out_norm", "attn_gate", "qk_norm", "moe_shared",
                   "moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs", "moe_router",
@@ -617,7 +646,7 @@ def test_window_kernels_fwd_and_grad_compile_at_laguna_shape():
                                    window=512).astype(F32).sum()
 
     def grads(q, k, v):
-        return jax.grad(attend, argnums=(0, 1, 2))(q, k, v)
+        return jax.grad(rematted(attend), argnums=(0, 1, 2))(q, k, v)
 
     text, compiled = compile_on_chip(
         grads, SDS((1, 64, 16384, 128), BF16), SDS((1, 8, 16384, 128), BF16),
@@ -632,6 +661,8 @@ def test_window_kernels_fwd_and_grad_compile_at_laguna_shape():
     for scope in ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv"):
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
     assert "16384,16384" not in hlo
+    # under the blocks' remat policy, as the cell's window layers are
+    assert_dense_lse_kept(hlo, calls, "f32[64,128,1,128]")
     # q, k, v, o, their gradients and the fp32 kernel outputs: under 2.5 GB
     assert compiled.memory_analysis().peak_memory_in_bytes < 2.5e9
 
@@ -663,6 +694,10 @@ def test_laguna_step_compiles_for_one_chip_with_its_scopes_and_fits():
     hlo = compiled.as_text()
     assert not re.search(r"all-gather|all-reduce|reduce-scatter", hlo)
     assert "16384,16384" not in hlo                       # no [S, S] array
+    # the five layers' forward kernels run once: o and lse are kept
+    assert hlo_text.rematted_forward_attention(hlo) == []
+    assert default_registry().peek_gauge(
+        "attention/flash_residual_mb") == pytest.approx(1226.8, abs=0.1)
     for scope in ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv", "flash_fwd_chunk",
                   "flash_bwd_dq", "flash_bwd_dkv", "attn_gate", "dense_mlp",
                   "moe_shared", "moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs",
