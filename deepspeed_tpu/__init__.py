@@ -106,6 +106,21 @@ def initialize(args=None,
         A tuple ``(engine, optimizer, training_dataloader, lr_scheduler)``
         exactly like the reference.
     """
+    # start-up's timeline (telemetry/spans.py): every compile of the
+    # process named from here on, and the whole of this call one span —
+    # config parse, mesh, optimizer, and the state's placement when
+    # ``model_parameters`` are handed in
+    from deepspeed_tpu.telemetry.spans import span, watch_compiles
+    watch_compiles()
+    with span("startup/engine_init"):
+        return _initialize(args, model, optimizer, model_parameters,
+                           training_data, lr_scheduler, mesh, mpu,
+                           collate_fn, config, config_params, rng, loss_fn)
+
+
+def _initialize(args, model, optimizer, model_parameters, training_data,
+                lr_scheduler, mesh, mpu, collate_fn, config, config_params,
+                rng, loss_fn):
     # local imports: global-name lookup inside a function bypasses the
     # module-level lazy __getattr__, and initialize() is where the
     # heavy (jax-importing) machinery genuinely becomes necessary

@@ -2136,10 +2136,15 @@ class DeepSpeedEngine:
         return jax.tree_util.tree_map(globalize, batch)
 
     def _ensure_ready(self, batch):
+        # one-time branches: start-up's spans (telemetry/spans.span) cost
+        # the steady-state path nothing
         if self.state is None:
-            self._init_state(example_batch=self._example_from_batch(batch))
+            with tel_span("startup/state_init", self.telemetry):
+                self._init_state(
+                    example_batch=self._example_from_batch(batch))
         if self._jit_train_batch is None:
-            self._build_jit_fns()
+            with tel_span("startup/build_fns", self.telemetry):
+                self._build_jit_fns()
         self._maybe_auto_resume()
 
     def _next_rng(self):
@@ -2289,28 +2294,28 @@ class DeepSpeedEngine:
         the production path. The backward phase is reported as (grads
         program − forward program) since XLA computes fwd+bwd fused."""
         rng = self._next_rng()
-        t0 = time.perf_counter()
+        t0 = t_fwd = time.monotonic()
         lval = self._jit_loss_batch(self.state, batch, rng)
         float(jax.device_get(lval))  # fence: the readback waits for the value
-        fwd_s = time.perf_counter() - t0
+        fwd_s = time.monotonic() - t0
 
-        t0 = time.perf_counter()
+        t0 = t_bwd = time.monotonic()
         grads, loss, _, _ = self._jit_grads_batch(self.state, batch, rng)
         float(jax.device_get(loss))
-        fwdbwd_s = time.perf_counter() - t0
+        fwdbwd_s = time.monotonic() - t0
 
-        t0 = time.perf_counter()
+        t0 = t_opt = time.monotonic()
         self.state, metrics = self._jit_apply_grads(self.state, grads, loss)
         float(jax.device_get(metrics["grad_norm"]))
-        step_s = time.perf_counter() - t0
+        step_s = time.monotonic() - t0
 
         # each phase fence pays one scalar device-to-host readback on top
         # of the wait, so phases are reported NET of it. metrics["lr"] is
         # already computed once the grad_norm fence returns, so reading it
         # measures the readback alone.
-        t0 = time.perf_counter()
+        t0 = t_fence = time.monotonic()
         float(jax.device_get(metrics["lr"]))
-        fence_s = time.perf_counter() - t0
+        fence_s = time.monotonic() - t0
 
         self.timers(FORWARD_GLOBAL_TIMER).elapsed_ += \
             max(fwd_s - fence_s, 0.0)
@@ -2324,13 +2329,18 @@ class DeepSpeedEngine:
         # the instrumented phases are REAL device measurements (each one
         # fenced) — feed them to the span histograms so the telemetry
         # stream carries per-phase times whenever this mode is on
+        # (t0_mono: the start of the PROGRAM the phase was timed in, on
+        # span()'s clock; dur_s is the derived figure, so backward's
+        # event is shorter than the fwd+bwd program it starts with)
         reg = self.telemetry
-        for tag, dur in (("train/forward", max(fwd_s - fence_s, 0.0)),
-                         ("train/backward", max(fwdbwd_s - fwd_s, 0.0)),
-                         ("train/optimizer", max(step_s - fence_s, 0.0)),
-                         ("train/fence", fence_s)):
+        for tag, dur, t0_mono in (
+                ("train/forward", max(fwd_s - fence_s, 0.0), t_fwd),
+                ("train/backward", max(fwdbwd_s - fwd_s, 0.0), t_bwd),
+                ("train/optimizer", max(step_s - fence_s, 0.0), t_opt),
+                ("train/fence", fence_s, t_fence)):
             reg.histogram(f"span/{tag}").observe(dur)
-            self.flight_recorder.record("span", tag=tag, dur_s=dur)
+            self.flight_recorder.record("span", tag=tag, dur_s=dur,
+                                        t0_mono=t0_mono)
 
         if self.global_steps % self.steps_per_print() == 0:
             # per-step means over the print interval (reference resets each

@@ -28,8 +28,10 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from deepspeed_tpu.runtime.zero.partition import ZeroPartitioner
 from deepspeed_tpu.parallel import mesh as mesh_lib
+from deepspeed_tpu.telemetry.spans import span, watch_compiles
 
 
+@span("startup/sharded_init")
 def sharded_init(model, rng, example_input, mesh, stage=3, tp_specs=None,
                  param_persistence_threshold=0, layer_stacked_prefixes=()):
     """Initialize a flax model with every parameter born sharded.
@@ -39,6 +41,7 @@ def sharded_init(model, rng, example_input, mesh, stage=3, tp_specs=None,
     specs as out_shardings — XLA emits per-device shard initialization only.
     """
     import jax.numpy as jnp
+    watch_compiles()
     example_input = jnp.asarray(example_input)
 
     shapes = jax.eval_shape(lambda r, x: model.init(r, x), rng, example_input)

@@ -30,6 +30,13 @@ per-tick decode on rank N, finish — even though every leg landed in a
 different per-role dump file. Minting is stdlib + a lock; nothing here
 touches jax (the jax-free viewer contract covers the exporter that
 consumes these ids).
+
+ISSUE 35 gives START-UP a timeline: the engine's one-time phases run
+under ``span("startup/...")``, every ``span`` event carries its start on
+``time.monotonic()`` (``t0_mono``), and ``watch_compiles()`` names,
+times and counts every trace, lowering and compile-or-cache-fetch of the
+process (one ``compile`` event a phase; ``compile/cache_misses``,
+``compile/after_first_step``).
 """
 
 import contextlib
@@ -182,7 +189,21 @@ def span(tag, registry=None, annotation=True, recorder=None):
     a jitted call this measures dispatch, by design (sync discipline,
     docs/observability.md). Async-safe: state lives on the stack, the
     registry/recorder lock per record; concurrent spans from other
-    threads (e.g. the serving scheduler) interleave correctly."""
+    threads (e.g. the serving scheduler) interleave correctly.
+
+    The event's ``ts`` is the recorder's wall clock at the span's END;
+    ``t0_mono`` is its START on ``time.monotonic()`` — the clock a
+    harness stamps its own phases with, so spans, ``compile`` events and
+    such stamps lie on one axis (``dur_s`` is taken on the same clock).
+
+    Start-up's one-time phases are spans too: ``startup/sharded_init``
+    (runtime/zero/init.py), ``startup/engine_init`` (``initialize``,
+    entry to return), ``startup/state_init`` and ``startup/build_fns``
+    (the engine's first ``train_batch``); step 0's
+    ``train/step_dispatch`` holds the step's trace, lowering and
+    compile-or-fetch. ``benchmark/setup_reduce.py`` lays them on
+    ``setup_s`` (``setup_engine_init_s``, ``setup_first_step_s``,
+    ``setup_outside_program_s``)."""
     reg = registry or default_registry()
     rec = recorder if recorder is not None else default_recorder()
     ann = None
@@ -193,15 +214,120 @@ def span(tag, registry=None, annotation=True, recorder=None):
             ann.__enter__()
         except Exception:   # profiler backends are optional
             ann = None
-    t0 = time.perf_counter()
+    t0 = time.monotonic()
     try:
         yield
     finally:
-        dt = time.perf_counter() - t0
+        dt = time.monotonic() - t0
         if ann is not None:
             ann.__exit__(None, None, None)
         reg.histogram(f"span/{tag}").observe(dt)
-        rec.record("span", tag=tag, dur_s=dt)
+        rec.record("span", tag=tag, dur_s=dt, t0_mono=t0)
+
+
+# ------------------------------------------------------- compile events
+#
+# JAX announces every trace, lowering and backend compile on
+# jax.monitoring with the function's name (jax/_src/dispatch.py,
+# LogElapsedTimeContextManager), and the persistent cache its hits and
+# misses (jax/_src/compiler.py). A backend "compile" that the cache
+# answers is announced all the same: its event says ``cache: "hit"``.
+
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+}
+_CACHE_VERDICTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+_FIRST_STEP_SPAN = "span/train/step_dispatch"
+# An eager jnp call is a trace too, of microseconds, that finds its
+# program compiled: a float32 reference run op by op makes thousands, and
+# the ring holds 4,096 events. A trace this short leaves no event.
+_SHORT_TRACE_S = 0.01
+
+_watching_compiles = False
+_compiled_late = set()      # function names already logged
+# .verdict: what the persistent cache said inside the backend phase now
+# running on this thread
+_cache = threading.local()
+
+
+def _on_compile_phase(event, start, end, fun_name=None, **_):
+    phase = _COMPILE_PHASES.get(event)
+    if phase is None or (phase == "trace" and end - start < _SHORT_TRACE_S):
+        return
+    # start/end are time.time() values: carry the start over to the
+    # monotonic clock by its distance from now
+    fields = dict(fun_name=fun_name, phase=phase, dur_s=end - start,
+                  t0_mono=time.monotonic() - (time.time() - start))
+    if phase != "backend":
+        default_recorder().record("compile", **fields)
+        return
+    verdict, _cache.verdict = getattr(_cache, "verdict", None), None
+    default_recorder().record("compile", cache=verdict, **fields)
+    reg = default_registry()
+    if reg.peek_histogram_count(_FIRST_STEP_SPAN):
+        reg.counter("compile/after_first_step").inc()
+        if fun_name not in _compiled_late:
+            _compiled_late.add(fun_name)
+            logger.info(f"[telemetry] {fun_name} compiled (or was fetched) "
+                        f"after the first optimizer step had returned")
+
+
+def _on_cache_event(event, **_):
+    verdict = _CACHE_VERDICTS.get(event)
+    if verdict is not None:
+        _cache.verdict = verdict
+        if verdict == "miss":
+            default_registry().counter("compile/cache_misses").inc()
+
+
+def watch_compiles():
+    """Name and time every compile of this process from here on: one
+    registration on ``jax.monitoring`` however often it is called
+    (``sharded_init`` and ``initialize`` both call it; two engines in a
+    process share it). Always on, like ``train/step_dispatch``: a
+    callback of microseconds a compile phase, nothing a step. What the
+    process compiled BEFORE the first of those two calls (a caller's
+    ``jax.random.PRNGKey``, its example input) is not seen.
+
+    Into the flight recorder: one ``compile`` event a phase JAX announces
+    (a trace under 10 ms, an eager call finding its program, leaves
+    none) with ``fun_name``, ``phase`` (``trace`` / ``lower`` /
+    ``backend``; a jitted function called inside a trace has its own
+    trace event inside the outer one's interval), ``dur_s``, ``t0_mono``
+    (its start on ``time.monotonic()``, as a ``span`` event's) and, on a
+    backend phase, ``cache`` (``hit``, ``miss``, or None where no
+    persistent cache was asked; a fetch is announced as the backend
+    phase it replaces). The ring is the timeline of the last 4,096
+    events: ``benchmark/setup_reduce.py`` reads it for
+    ``setup_compile_s``, ``setup_programs_compiled`` and
+    ``setup_cache_misses``. Into ``default_registry()``, which does not
+    forget: counter ``compile/cache_misses`` (JAX counts a miss when it
+    WRITES the entry) — 0 says this restart was a warm one.
+
+    A backend compile that ends once ``span/train/step_dispatch`` holds
+    an observation — a first optimizer step has returned — also counts in
+    ``compile/after_first_step`` and logs one line a function name: in
+    steady state that is the silent loss to look for (a new shape, a
+    weak type, a changed static argument). A program first NEEDED later
+    shows there too and is no fault: an eval or checkpoint program, the
+    throughput timer's first device sync on step 1 (``jit(<lambda>)`` of
+    ``utils/timer._sync_device``: the one name every benchmark run
+    logs). ``lower_train_step(...).compile()`` after a step, as the
+    benchmark's traced run calls it to keep the step's text, is served
+    by jit's own caches and announces nothing."""
+    global _watching_compiles
+    with _span_lock:
+        if _watching_compiles:
+            return
+        import jax.monitoring as monitoring
+        monitoring.register_event_time_span_listener(_on_compile_phase)
+        monitoring.register_event_listener(_on_cache_event)
+        _watching_compiles = True
 
 
 class TraceWindow:

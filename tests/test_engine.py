@@ -5,6 +5,8 @@ behaves, checkpoint roundtrips."""
 import numpy as np
 import jax
 import jax.numpy as jnp
+import time
+
 import pytest
 
 import deepspeed_tpu as dstpu
@@ -263,6 +265,16 @@ def test_wall_clock_breakdown_fused_path():
     assert times["forward"] > 0 and times["step"] > 0
     # uninstrumented engine reports no phase timers
     assert e_fused.wall_clock_times() == {}
+    # the phases' span events lie on span()'s clock like every other: the
+    # start of the program each was timed in, in the order they ran
+    phases = [e for e in e_inst.flight_recorder.events()
+              if e["kind"] == "span" and e["tag"] in (
+                  "train/forward", "train/backward", "train/optimizer",
+                  "train/fence")][-4:]
+    assert [e["tag"].split("/")[1] for e in phases] == [
+        "forward", "backward", "optimizer", "fence"]
+    starts = [e["t0_mono"] for e in phases]
+    assert starts == sorted(starts) and starts[-1] <= time.monotonic()
 
 
 class _FakeMpu:

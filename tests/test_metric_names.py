@@ -219,6 +219,25 @@ def test_flash_engagement_gauges_documented(name):
     assert name in _package_source(), name
 
 
+@pytest.mark.parametrize("name", [
+    "span/startup/sharded_init", "span/startup/engine_init",
+    "span/startup/state_init", "span/startup/build_fns",
+    "compile/cache_misses", "compile/after_first_step"])
+def test_startup_and_compile_names_documented(name):
+    """Start-up's spans and the compile listener's two counters (ISSUE 35)
+    stay documented AND emitted: a span's histogram is
+    ``span/<tag>``, so the code holds the tag; the ``compile/*`` names are
+    literals of ``telemetry/spans.py``. The ``compile`` event and the
+    ``t0_mono`` field are rows of the flight recorder's table."""
+    assert name in documented_metric_names(), (
+        f"{name} missing from the docs/observability.md train table")
+    literal = name[len("span/"):] if name.startswith("span/") else name
+    assert f'"{literal}"' in _package_source(), name
+    doc = DOCS.read_text()
+    assert "| `compile` | `spans.watch_compiles()`" in doc
+    assert "`t0_mono`" in doc.split("| `span` | `span()` exit")[1][:200]
+
+
 def test_moe_metric_names_documented():
     """The dropless expert layer's four gauges (ISSUE 27) stay documented
     AND sown: the engine names a gauge after the model's ``stats`` variable

@@ -455,6 +455,206 @@ def test_laguna_scope_names_and_gauges_reach_the_step():
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
 
 
+# ----------------------------------- start-up spans and compile events
+
+STARTUP_TAGS = ("sharded_init", "engine_init", "state_init", "build_fns")
+
+
+def _tiny_gpt2_engine():
+    """ZeRO-3 with no parameters handed in: the first ``train_batch`` runs
+    ``_init_state``, which makes the weights through ``sharded_init``."""
+    from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+    cfg = GPT2Config(vocab_size=256, n_positions=32, n_embd=32, n_layer=2,
+                     n_head=2, scan_layers=True)
+    engine, _, _, _ = dstpu.initialize(
+        config=base_config(zero_optimization={"stage": 3}),
+        model=GPT2LMHeadModel(cfg))
+    batch = {"input_ids": np.random.RandomState(0).randint(
+        0, 256, (8, 32)).astype(np.int32)}
+    return engine, batch
+
+
+def _events_since(seq, *kinds):
+    from deepspeed_tpu.telemetry import default_recorder
+    return [e for e in default_recorder().events()
+            if e["seq"] > seq and e["kind"] in kinds]
+
+
+def _last_seq():
+    from deepspeed_tpu.telemetry import default_recorder
+    events = default_recorder().events()
+    return events[-1]["seq"] if events else 0
+
+
+def _logged_while(call):
+    """Messages the package logger (which does not propagate) emitted
+    during ``call()``."""
+    import logging
+    from deepspeed_tpu.utils.logging import logger as dlog
+    records = []
+    handler = logging.Handler()
+    handler.emit = records.append
+    dlog.addHandler(handler)
+    try:
+        call()
+    finally:
+        dlog.removeHandler(handler)
+    return [r.getMessage() for r in records]
+
+
+def test_startup_spans_fire_once_each_on_the_harness_clock():
+    """ISSUE 35: ``startup/{sharded_init,engine_init,state_init,build_fns}``
+    are one-time branches — one observation each after ``initialize`` and two
+    steps, none added by the second step — and every ``span`` and ``compile``
+    event carries ``t0_mono``, its start on ``time.monotonic()``, so that
+    set-up's spans, each step's ``train/step_dispatch`` and a harness's own
+    stamps lie on one axis."""
+    import time
+    reg = default_registry()
+    reg.reset()
+    seq, t_before = _last_seq(), time.monotonic()
+    engine, batch = _tiny_gpt2_engine()
+    engine.train_batch(batch)
+    counts = {t: reg.peek_histogram_count(f"span/startup/{t}")
+              for t in STARTUP_TAGS}
+    assert counts == dict.fromkeys(STARTUP_TAGS, 1)
+    jax.block_until_ready(engine.train_batch(batch))
+    t_after = time.monotonic()
+    assert counts == {t: reg.peek_histogram_count(f"span/startup/{t}")
+                      for t in STARTUP_TAGS}
+    assert reg.peek_histogram_count("span/train/step_dispatch") == 2
+
+    events = _events_since(seq, "span", "compile")
+    assert {e["kind"] for e in events} == {"span", "compile"}
+    for e in events:
+        assert t_before <= e["t0_mono"], e
+        assert e["t0_mono"] + e["dur_s"] <= t_after + 1e-3, e
+    start = {e["tag"]: e["t0_mono"] for e in events if e["kind"] == "span"
+             and e["tag"].startswith("startup/")}
+    end = {e["tag"]: e["t0_mono"] + e["dur_s"] for e in events
+           if e["kind"] == "span" and e["tag"].startswith("startup/")}
+    assert set(start) == {"startup/" + t for t in STARTUP_TAGS}
+    # in order: initialize returns, then the first train_batch makes the
+    # state (the weights through sharded_init, inside it) and the step
+    assert end["startup/engine_init"] <= start["startup/state_init"] \
+        <= start["startup/sharded_init"] <= end["startup/sharded_init"] \
+        <= end["startup/state_init"] <= start["startup/build_fns"]
+    steps = [e for e in events if e.get("tag") == "train/step_dispatch"]
+    assert [e["step"] for e in steps] == [0, 1]
+    assert end["startup/build_fns"] <= steps[0]["t0_mono"]
+    # step 0's dispatch holds the step's compile: its backend phase began
+    # and ended inside the span
+    inside = [e for e in events if e["kind"] == "compile"
+              and e["phase"] == "backend"
+              and "train_batch_fn" in e["fun_name"]]
+    assert len(inside) == 1
+    assert steps[0]["t0_mono"] <= inside[0]["t0_mono"] and \
+        inside[0]["t0_mono"] + inside[0]["dur_s"] <= \
+        steps[0]["t0_mono"] + steps[0]["dur_s"] + 1e-3
+
+
+def test_watch_compiles_registers_once_and_names_each_phase():
+    """Two engines in a process (``watch_compiles()`` twice) leave ONE
+    listener a kind, and one call of a fresh jitted function is one program:
+    a ``compile`` event a phase with the function's name and a start between
+    the stamps taken round the call — twice registered it would be two. A
+    trace under 10 ms (as a rule the jitted function called INSIDE the
+    trace; on a loaded host it may take longer, and is then an event of its
+    own inside the outer one's interval) leaves no event."""
+    import time
+    from jax._src import monitoring
+    from deepspeed_tpu.telemetry import spans
+    spans.watch_compiles()
+    spans.watch_compiles()
+    assert monitoring.get_event_time_span_listeners().count(
+        spans._on_compile_phase) == 1
+    assert monitoring.get_event_listeners().count(
+        spans._on_cache_event) == 1
+
+    @jax.jit
+    def _probe_inner(x):
+        return x + 1
+
+    def _probe_outer(x):
+        time.sleep(0.02)            # at trace time: a trace worth an event
+        return _probe_inner(x) * 3
+
+    seq, t0 = _last_seq(), time.monotonic()
+    # (a numpy operand: ``jnp.ones`` would be a program of its own)
+    jax.block_until_ready(jax.jit(_probe_outer)(np.ones((7,), np.float32)))
+    t1 = time.monotonic()
+    probes = [e for e in _events_since(seq, "compile")
+              if "_probe" in (e["fun_name"] or "")]
+    mine = [e for e in probes if "_probe_outer" in e["fun_name"]]
+    assert sorted(e["phase"] for e in mine) == ["backend", "lower", "trace"]
+    for e in mine:
+        assert t0 <= e["t0_mono"] and e["t0_mono"] + e["dur_s"] <= t1 + 1e-3
+        assert e["dur_s"] > 0
+    assert next(e for e in mine if e["phase"] == "backend")["cache"] is None
+    outer = next(e for e in mine if e["phase"] == "trace")
+    for e in probes:
+        if e not in mine:           # the inner function, traced slowly
+            assert e["phase"] == "trace" and \
+                spans._SHORT_TRACE_S <= e["dur_s"] <= outer["dur_s"]
+    # a trace of microseconds (an eager call that finds its program) leaves
+    # the ring to the programs: the listener fed by hand, on either side
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    seq, now = _last_seq(), time.time()
+    spans._on_compile_phase(trace, now - 0.005, now, fun_name="_fed_short")
+    spans._on_compile_phase(trace, now - 0.02, now, fun_name="_fed_long")
+    spans._on_compile_phase("/jax/core/compile/jaxpr_to_mlir_module_duration",
+                            now - 0.001, now, fun_name="_fed_short")
+    assert [(e["fun_name"], e["phase"])
+            for e in _events_since(seq, "compile")] == [
+        ("_fed_long", "trace"), ("_fed_short", "lower")]
+
+
+def test_a_compile_after_the_first_step_is_counted_and_named_once():
+    """A backend compile once a first optimizer step has returned counts in
+    ``compile/after_first_step`` and logs its function's name ONCE — the
+    operator's sign of a recompile in steady state."""
+    reg = default_registry()
+    reg.reset()
+    engine, batch = _tiny_gpt2_engine()
+    jax.block_until_ready(engine.train_batch(batch))
+    # everything up to here compiled BEFORE a first step had returned
+    assert reg.counter("compile/after_first_step").value == 0
+
+    def _late_probe(x):
+        return x * 2 - 1
+
+    late = jax.jit(_late_probe)
+    lines = _logged_while(lambda: [late(np.ones((n,), np.float32))
+                                   for n in (3, 5)])    # two programs
+    assert reg.counter("compile/after_first_step").value == 2
+    named = [m for m in lines if "_late_probe" in m]
+    assert len(named) == 1 and "after the first optimizer step" in named[0]
+
+
+def test_cache_verdicts_are_counted_from_jax_monitoring_events():
+    """Hits and misses come from the events JAX's persistent cache records
+    (fed by hand here: no dependence on a CPU cache): the backend phase they
+    fell in carries the verdict, and ``compile/cache_misses`` keeps the
+    misses past the ring's turnover."""
+    import time
+    from jax import monitoring
+    from deepspeed_tpu.telemetry import spans
+    spans.watch_compiles()
+    reg = default_registry()
+    reg.reset()
+    seq = _last_seq()
+    backend = "/jax/core/compile/backend_compile_duration"
+    for verdict in ("cache_hits", "cache_misses", "cache_hits"):
+        now = time.time()
+        monitoring.record_event("/jax/compilation_cache/" + verdict)
+        monitoring.record_event_time_span(backend, now, now + 1.0,
+                                          fun_name="jit(fed_by_hand)")
+    monitoring.record_event("/jax/compilation_cache/tasks_using_cache")
+    assert reg.counter("compile/cache_misses").value == 1
+    assert [e["cache"] for e in _events_since(seq, "compile")] == [
+        "hit", "miss", "hit"]
+
+
 def test_engine_without_gates_records_but_never_prices_or_exports():
     """No monitor/profiling config: counters still move (snapshot is
     always available) but no cost-analysis retrace, no exporter, no
