@@ -45,10 +45,14 @@ the grouped matmul stops at the sum of a slab's group sizes;
 ``moe_held_slabs`` in ``stats`` says how many a layer took).
 With fewer than ``T x k`` rows the two row moves are no permutations any
 more: ``tokens_to_rows`` is a gather and its transpose ``rows_to_tokens`` a
-segmented sum over rows sorted by token (a second sort, shifted adds over
-runs of at most k rows, one gather of the runs' last rows) — each the other's
-backward pass, neither a scatter-add. A ``shared_d_ff`` adds one SwiGLU
-expert every token passes through, under a sigmoid gate (``moe_shared``).
+segmented sum — the rows brought into token order (a sort of the slab's keys
+and one row gather), then ONE kernel pass over the rows that have a token
+(``ops/pallas/rows_to_tokens.py``: each block of tokens walks its contiguous
+run of sorted rows and sums it in float32; rows past the rows held are not
+read, ``moe_combine_rows_walked`` in ``stats`` is rows read over rows held) —
+each the other's backward pass, neither a scatter-add. A ``shared_d_ff`` adds
+one SwiGLU expert every token passes through, under a sigmoid gate
+(``moe_shared``).
 """
 
 import functools
@@ -61,6 +65,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from deepspeed_tpu.moe.layer import load_balance_loss
 from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+from deepspeed_tpu.ops.pallas.rows_to_tokens import (rows_walked,
+                                                     sum_rows_by_token)
 from deepspeed_tpu.telemetry.spans import annotate
 
 
@@ -71,7 +77,8 @@ STAT_GAUGES = {"moe_aux_loss": "moe/aux_loss", "moe_z_loss": "moe/z_loss",
 # ... and by a layer that holds a share of its experts
 HELD_STAT_GAUGES = dict(STAT_GAUGES,
                         moe_rows_held_share="moe/rows_held_share",
-                        moe_held_slabs="moe/held_slabs")
+                        moe_held_slabs="moe/held_slabs",
+                        moe_combine_rows_walked="moe/combine_rows_walked")
 # static length of a held layer's row arrays over the mean rows held (4 did
 # not fit the one cell that holds a share: PERF.md Findings PR 31)
 _HELD_ROWS_SLACK = 2
@@ -151,12 +158,6 @@ def _unsort_rows_bwd(order, g):
 unsort_rows.defvjp(_unsort_rows_fwd, _unsort_rows_bwd)
 
 
-def _shift_rows(t, d, fill):
-    """Row i takes row i - d; the first d rows take ``fill``."""
-    head = jnp.full((d,) + t.shape[1:], fill, t.dtype)
-    return jnp.concatenate([head, t[:-d]], axis=0)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def tokens_to_rows(x, tok, k):
     """Token rows [T, H] -> rows [M, H]: row r is token ``tok[r]``, zero
@@ -179,27 +180,12 @@ tokens_to_rows.defvjp(_tokens_to_rows_fwd, _tokens_to_rows_bwd)
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
 def rows_to_tokens(rows, tok, T, k):
-    """Rows [M, H] -> token rows [T, H]: token t is the sum of the rows r
-    with ``tok[r] == t`` (at most ``k`` of them; rows with ``tok[r] == T``
-    go nowhere). The transpose of ``tokens_to_rows`` without a scatter-add:
-    the rows sorted by token, an inclusive segmented sum by shifted adds
-    (log2 k of them), and a gather of each token's last row."""
-    by_tok = jnp.argsort(tok, stable=True)
-    seg = jnp.take(tok, by_tok)
-    z = jnp.take(rows.astype(jnp.float32), by_tok, axis=0)
-    d = 1
-    while d < k:
-        same = (seg == _shift_rows(seg, d, -1))[:, None]
-        z = z + jnp.where(same, _shift_rows(z, d, 0.0), 0.0)
-        d *= 2
-    tokens = jnp.arange(T, dtype=seg.dtype)
-    # all T x M comparisons, fused into their row sums: a binary search is
-    # log2(M) dependent gathers of T scalars, 26 ms a call on a v5e
-    last = jnp.searchsorted(seg, tokens, side="right",
-                            method="compare_all").astype(jnp.int32) - 1
-    has = jnp.take(seg, jnp.maximum(last, 0)) == tokens
-    out = jnp.take(z, jnp.maximum(last, 0), axis=0)
-    return jnp.where(has[:, None], out, 0.0).astype(rows.dtype)
+    """Rows [M, H] -> token rows [T, H]: token t is the float32 sum of the
+    rows r with ``tok[r] == t`` (at most ``k`` of them; rows with ``tok[r] ==
+    T`` go nowhere), in ``rows.dtype``. The transpose of ``tokens_to_rows``
+    without a scatter-add: the rows sorted by token, and one kernel pass over
+    the rows that have a token (``ops/pallas/rows_to_tokens.py``)."""
+    return sum_rows_by_token(rows, tok, T)
 
 
 def _rows_to_tokens_fwd(rows, tok, T, k):
@@ -367,7 +353,12 @@ class DroplessMoE(nn.Module):
                           # slabs of rows this layer took (1: they fit the
                           # first)
                           ("moe_held_slabs", jnp.maximum(
-                              -(-rows_held // cap), 1).astype(jnp.float32))]
+                              -(-rows_held // cap), 1).astype(jnp.float32)),
+                          # rows the combine's kernel read over rows held (1:
+                          # none but them, up to a row tile's rounding)
+                          ("moe_combine_rows_walked",
+                           self._rows_walked(cap, slabs, rows_held)
+                           / jnp.maximum(rows_held, 1))]
             for name, value in stats:
                 self.sow("stats", name, jax.lax.stop_gradient(value))
         if self.is_mutable_collection("intermediates"):
@@ -382,6 +373,15 @@ class DroplessMoE(nn.Module):
         with annotate("moe_act"):
             h = checkpoint_name(nn.silu(gate) * up, "mlp_fc")
         return grouped_matmul(h, w_down, group_sizes)
+
+    @staticmethod
+    def _rows_walked(cap, slabs, rows_held):
+        """Rows ``rows_to_tokens`` read in the slabs a layer took (the first
+        always, a further one where rows reach it)."""
+        starts = jnp.arange(slabs, dtype=jnp.int32) * cap
+        walked = rows_walked(jnp.clip(rows_held - starts, 0, cap))
+        return jnp.sum(jnp.where(starts < jnp.maximum(rows_held, 1),
+                                 walked, 0))
 
     def _held_rows(self, M, start, xt, weights, group_sizes, order, top_w,
                    rows_held):

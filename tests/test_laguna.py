@@ -442,6 +442,7 @@ def test_trains_through_the_engine_under_zero3_with_remat(depth):
     assert gauges["moe/dropped_rows"] == 0
     assert 0.05 < gauges["moe/rows_held_share"] < 0.6      # 1/4 at uniform
     assert gauges["moe/held_slabs"] >= 1.0
+    assert gauges["moe/combine_rows_walked"] >= 1.0
 
 
 def test_the_window_layers_run_the_window_kernels_where_flash_is_on():

@@ -43,7 +43,7 @@ def test_tpu_rule_is_the_default_backend_and_errors_propagate(monkeypatch):
     from deepspeed_tpu.utils.platform import is_tpu_backend
     kernels = [importlib.import_module(f"deepspeed_tpu.ops.pallas.{m}")
                for m in ("blocksparse", "decode", "quantize",
-                         "flash_attention")]
+                         "flash_attention", "rows_to_tokens")]
     assert not is_tpu_backend()
     assert all(m._interpret_default() for m in kernels)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")

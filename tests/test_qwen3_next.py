@@ -343,15 +343,30 @@ def test_every_row_held_takes_the_uncut_arrays_and_is_exact():
     np.testing.assert_allclose(none.reshape(-1, H), shared, atol=3e-5)
 
 
-def test_rows_to_tokens_is_the_transpose_of_tokens_to_rows():
-    T, k, M = 10, 3, 16
-    tok = jnp.asarray([0, 0, 0, 2, 2, 9, 5, 5, 5, 7, 1, 1, T, T, T, T])
-    x = jax.random.normal(jax.random.PRNGKey(0), (T, 4))
+def _by_hand():
+    return 10, 3, 4, [0, 0, 0, 2, 2, 9, 5, 5, 5, 7, 1, 1, 10, 10, 10, 10]
+
+
+def _drawn(T, k, M, width, held):
+    """``held`` of a random routing's T x k assignments, M rows long."""
+    picked = np.random.default_rng(0).permutation(T * k)[:held] // k
+    return T, k, width, picked.tolist() + [T] * (M - held)
+
+
+@pytest.mark.parametrize("case", [
+    _by_hand(), _drawn(200, 10, 136, 256, 102), _drawn(32, 1, 24, 128, 0)],
+    ids=["by_hand", "T200_k10_three_quarters_held", "T32_k1_no_row"])
+def test_rows_to_tokens_is_the_transpose_of_tokens_to_rows(case):
+    T, k, width, tok = case
+    tok = jnp.asarray(tok)
+    M, held = tok.shape[0], int(jnp.sum(tok < T))
+    x = jax.random.normal(jax.random.PRNGKey(0), (T, width))
     rows = tokens_to_rows(x, tok, k)
-    assert rows.shape == (M, 4) and not np.any(rows[12:])
-    np.testing.assert_array_equal(rows[3], x[2])
-    r = jax.random.normal(jax.random.PRNGKey(1), (M, 4))
-    want = jnp.zeros((T + 1, 4)).at[tok].add(r)[:T]
+    assert rows.shape == (M, width) and not np.any(rows[held:])
+    if held:
+        np.testing.assert_array_equal(rows[held - 1], x[tok[held - 1]])
+    r = jax.random.normal(jax.random.PRNGKey(1), (M, width))
+    want = jnp.zeros((T + 1, width)).at[tok].add(r)[:T]
     np.testing.assert_allclose(rows_to_tokens(r, tok, T, k), want, atol=1e-6)
     # <P x, r> == <x, P^T r>, through the custom VJPs both ways
     np.testing.assert_allclose(jax.grad(
@@ -474,4 +489,5 @@ def test_trains_through_the_engine_under_zero3_with_remat():
     assert gauges["moe/dropped_rows"] == 0
     assert 0.05 < gauges["moe/rows_held_share"] < 0.6      # 1/4 at uniform
     assert gauges["moe/held_slabs"] >= 1.0
+    assert gauges["moe/combine_rows_walked"] >= 1.0
     assert gauges["moe/rows_max_over_mean"] >= 1.0

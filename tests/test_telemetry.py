@@ -388,8 +388,8 @@ def test_qwen3_next_scope_names_and_gauges_reach_the_step():
     module ``linear_attn``), ``attn_gate`` and ``qk_norm`` under ``attn``,
     ``moe_shared`` beside the ``moe_*`` scopes under ``mlp`` in its compiled
     step's ``op_name``s, and a layer that holds a share of its experts sows
-    ``moe/rows_held_share`` and ``moe/held_slabs`` beside the four gauges every
-    dropless layer has."""
+    ``moe/rows_held_share``, ``moe/held_slabs`` and ``moe/combine_rows_walked``
+    beside the four gauges every dropless layer has."""
     import re
     from deepspeed_tpu.models.qwen3_next import (Qwen3NextForCausalLM,
                                                  qwen3_next_tiny)
@@ -403,7 +403,7 @@ def test_qwen3_next_scope_names_and_gauges_reach_the_step():
     gauges = engine.telemetry_flush()["gauges"]
     assert {"moe/aux_loss", "moe/z_loss", "moe/rows_max_over_mean",
             "moe/dropped_rows", "moe/rows_held_share",
-            "moe/held_slabs"} <= set(gauges)
+            "moe/held_slabs", "moe/combine_rows_walked"} <= set(gauges)
     assert gauges["moe/dropped_rows"] == 0
     assert 0 < gauges["moe/rows_held_share"] < 1
     assert gauges["moe/held_slabs"] >= 1
@@ -442,7 +442,7 @@ def test_laguna_scope_names_and_gauges_reach_the_step():
     engine.train_batch(batch)
     gauges = engine.telemetry_flush()["gauges"]
     assert {"moe/dropped_rows", "moe/rows_held_share",
-            "moe/held_slabs"} <= set(gauges)
+            "moe/held_slabs", "moe/combine_rows_walked"} <= set(gauges)
     # S 64, window 16, blocks of 64 in the interpreter: one block a band
     assert gauges["attention/window_tile_overcompute"] == pytest.approx(
         64 * 64 / (64 * 16 - 16 * 15 // 2))

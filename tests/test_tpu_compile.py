@@ -234,6 +234,54 @@ def test_grouped_matmul_fwd_and_grads_compile_at_olmoe_shape():
         assert re.search(r'op_name="[^"]*/' + scope, hlo), scope
 
 
+def assert_rows_reach_tokens_in_one_pass(hlo, M, T):
+    """What the form before ISSUE 36 left in a step: the all-pairs search of
+    T tokens among M sorted rows and the shifted float32 copies of the slab."""
+    assert f"[{T},{M}]" not in hlo and f"[{M},{T}]" not in hlo
+    # (XLA writes a shift as a pad that carries the concatenate's op_name)
+    assert not re.search(
+        rf'= f32\[{M},2048\][^\n]*op_name="[^"]*/concatenate"', hlo)
+
+
+@pytest.mark.parametrize("M,k", [(20480, 10), (32768, 8)],
+                         ids=["qwen3next", "laguna"])
+def test_rows_to_tokens_kernel_compiles_at_the_held_cells_shapes(M, k):
+    """The held experts' rows back to 16,384 tokens at the two cells' slabs
+    ([20480, 2048], k 10 and [32768, 2048], k 8): the float32 call of the
+    forward pass under ``moe_combine`` and the bfloat16 one that is the
+    backward pass of ``tokens_to_rows`` under ``moe_dispatch``, each ONE
+    Pallas call under its caller's scope and a ``rows_to_tokens`` of its own
+    (no kernel tag's prefix); no [T, M] compare and no shifted slab."""
+    from deepspeed_tpu.moe.dropless import rows_to_tokens, tokens_to_rows
+    from deepspeed_tpu.telemetry.spans import annotate
+    T = 16384
+
+    def layer(rows, x, tok):
+        with jax.named_scope("mlp"):
+            with annotate("moe_dispatch"):
+                xs = tokens_to_rows(x, tok, k)
+            with annotate("moe_combine"):
+                y = rows_to_tokens(rows * xs.astype(F32), tok, T, k)
+            return jnp.sum(y * y)
+
+    text, compiled = compile_on_chip(
+        jax.value_and_grad(layer, argnums=(0, 1)), SDS((M, 2048), F32),
+        SDS((T, 2048), BF16), SDS((M,), I32))
+    assert kernel_names(text) == {"_rows_to_tokens_kernel"}
+    hlo = compiled.as_text()
+    calls = flash_calls(hlo)
+    assert len(calls) == 2, calls
+    for scope, result in (("moe_combine", f"f32[{T},2048]"),
+                          ("moe_dispatch", f"bf16[{T},2048]")):
+        assert sum(bool(re.search(
+            rf'op_name="[^"]*/{scope}/rows_to_tokens/', c))
+            and result in c.split(" custom-call(")[0] for c in calls) == 1
+    assert_rows_reach_tokens_in_one_pass(hlo, M, T)
+    # the rows in token order once (float32: M x 8 KB) beside operands and
+    # results; the shifted-add form held three such slabs
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.2 * M * 2048 * 4
+
+
 @pytest.mark.parametrize("seq", [64, 128, 256])
 def test_flash_attention_compiles_at_prefill_buckets(seq):
     """The serve phase's page-bucketed prompt lengths, batch 1."""
@@ -610,7 +658,7 @@ def test_qwen3_next_step_compiles_for_one_chip_with_its_scopes_and_fits():
     assert kernel_names(lowered.as_text()) == {
         "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
         "_bwd_dkv_kernel_chunked", "kernel", "_gdn_fwd_kernel",
-        "_gdn_bwd_kernel"}
+        "_gdn_bwd_kernel", "_rows_to_tokens_kernel"}
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     assert 6.0e9 < ma.argument_size_in_bytes < 6.5e9      # 625.7M x 10 B
@@ -626,8 +674,11 @@ def test_qwen3_next_step_compiles_for_one_chip_with_its_scopes_and_fits():
                   "moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs", "moe_router",
                   "moe_dispatch", "moe_combine", "flash_fwd_chunk",
                   "flash_bwd_dq", "flash_bwd_dkv", "linear_attn", "attn",
-                  "mlp", "ds_loss_head", "ds_embed", "ds_optimizer"):
+                  "mlp", "ds_loss_head", "ds_embed", "ds_optimizer",
+                  # the held rows' way back, forward and backward: one kernel
+                  "moe_combine/rows_to_tokens", "moe_dispatch/rows_to_tokens"):
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+    assert_rows_reach_tokens_in_one_pass(hlo, 20480, 16384)
 
 
 def test_window_kernels_fwd_and_grad_compile_at_laguna_shape():
@@ -685,7 +736,8 @@ def test_laguna_step_compiles_for_one_chip_with_its_scopes_and_fits():
     assert kernel_names(lowered.as_text()) == {
         "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
         "_bwd_dkv_kernel_chunked", "kernel", "_swa_fwd_kernel",
-        "_swa_bwd_dq_kernel", "_swa_bwd_dkv_kernel"}
+        "_swa_bwd_dq_kernel", "_swa_bwd_dkv_kernel",
+        "_rows_to_tokens_kernel"}
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     assert 6.8e9 < ma.argument_size_in_bytes < 7.0e9      # 691.6M x 10 B
@@ -702,8 +754,11 @@ def test_laguna_step_compiles_for_one_chip_with_its_scopes_and_fits():
                   "flash_bwd_dq", "flash_bwd_dkv", "attn_gate", "dense_mlp",
                   "moe_shared", "moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs",
                   "moe_router", "moe_dispatch", "moe_combine", "attn", "mlp",
-                  "ds_loss_head", "ds_embed", "ds_optimizer"):
+                  "ds_loss_head", "ds_embed", "ds_optimizer",
+                  # the held rows' way back, forward and backward: one kernel
+                  "moe_combine/rows_to_tokens", "moe_dispatch/rows_to_tokens"):
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+    assert_rows_reach_tokens_in_one_pass(hlo, 32768, 16384)
 
 
 # ------------------------------------- optional kernels: known refusals
