@@ -8,7 +8,11 @@ reducer, the readers, ``tools/rehearse_compile.py`` and the tests — reads no
 model key itself: it asks the family. A later family is a new file here with
 the members below, its plain reference under ``reference/`` and its
 configuration files; ``tests/benchmark_checks/test_bm_manifest_rules.py``
-holds every file in this directory to this list.
+holds every file in this directory to this list, but for ``HELPERS``:
+``common.py`` is the training recipe the families share (``merged``,
+``engine_config``, ``build_train(model, ...)``, ``lower_train_step(model,
+...)``), which a family calls with its own model; no family imports another
+family's private name.
 
 Every family (``config`` is the configuration file as a dict; ``rehearse``
 selects the tiny sizes the file carries under ``rehearse_cpu``):
@@ -67,3 +71,4 @@ TAGS = ("WIDTH_KEYS", "KERNEL_TAGS", "MODULE_TAGS")
 SERVING = ("build_serving", "check_serving", "lower_serving",
            "decode_kv_bytes")
 TRAFFIC_SHAPES = ("vocab_size", "max_positions", "seq_scale")
+HELPERS = ("common",)           # files of this directory that are no family

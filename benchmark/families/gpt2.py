@@ -14,6 +14,7 @@ import dataclasses
 import numpy as np
 
 from benchmark import roofline
+from benchmark.families import common
 from benchmark.reference import gpt2 as ref
 
 WIDTH_KEYS = ("n_embd", "n_head", "n_inner")
@@ -40,23 +41,10 @@ def traffic_shapes(config, rehearse):
             "seq_scale": s["n_positions"] / config["n_positions"]}
 
 
-def _merged(config, section, rehearse):
-    """``config[section]`` with the rehearsal's overrides laid over it."""
-    def merge(a, b):
-        out = dict(a)
-        for k, v in b.items():
-            out[k] = merge(a[k], v) if isinstance(v, dict) \
-                and isinstance(a.get(k), dict) else v
-        return out
-    base = config[section]
-    over = config["rehearse_cpu"].get(section, {}) if rehearse else {}
-    return merge(base, over)
-
-
 def model_config(config, rehearse, serving=False):
     import jax.numpy as jnp
     from deepspeed_tpu.models.gpt2 import GPT2Config
-    m = _merged(config, "model", rehearse)
+    m = common.merged(config, "model", rehearse)
     dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
     cfg = GPT2Config(dtype=dtypes[m["dtype"]],
                      param_dtype=dtypes[m["param_dtype"]],
@@ -71,80 +59,24 @@ def model_config(config, rehearse, serving=False):
 
 # ----------------------------------------------------------------- training
 
-def engine_config(config, global_batch, seed, rehearse):
-    return dict(_merged(config, "train", rehearse)["engine"],
-                train_batch_size=global_batch, seed=seed)
+def _model(config, rehearse):
+    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel
+    return GPT2LMHeadModel(model_config(config, rehearse))
 
 
 def build_train(config, global_batch, seed, devices, rehearse):
-    """(engine, initial parameters). The weights are born sharded in one
-    jitted call (``zero.Init``'s functional form) and handed to
-    ``dstpu.initialize`` as ``model_parameters``; the engine adopts those
-    very buffers, so the caller's handle is valid until the first step
-    donates them — long enough for the reference to read them."""
-    import jax
-    import jax.numpy as jnp
-    import deepspeed_tpu as dstpu
-    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel
-    from deepspeed_tpu.parallel.mesh import MeshConfig, make_mesh
-    from deepspeed_tpu.runtime.zero.init import sharded_init
-
-    cfg = model_config(config, rehearse)
-    ds = engine_config(config, global_batch, seed, rehearse)
-    model = GPT2LMHeadModel(cfg)
-    mesh = make_mesh(MeshConfig(data=len(devices)), devices=devices)
-    zero = ds["zero_optimization"]
-    params, _ = sharded_init(
-        model, jax.random.PRNGKey(seed),
-        jnp.zeros((global_batch, cfg.n_positions), jnp.int32), mesh,
-        stage=zero["stage"],
-        param_persistence_threshold=zero.get(
-            "stage3_param_persistence_threshold", 100000))
-    engine, _, _, _ = dstpu.initialize(config=ds, model=model, mesh=mesh,
-                                       model_parameters=params)
-    return engine, params
+    """(engine, initial parameters): ``common.build_train``'s recipe over
+    ``GPT2LMHeadModel``, the weights made from a full-length example."""
+    model = _model(config, rehearse)
+    return common.build_train(model, config, global_batch, seed, devices,
+                              rehearse, example_len=model.config.n_positions)
 
 
 def lower_train_step(config, traffic, devices):
-    """The cell's train step at real size, lowered over abstract state laid
-    out as the engine lays it out on ``devices`` (a plain reshape onto the
-    data axis: described chips have no attached topology to line up)."""
-    import jax
-    import jax.numpy as jnp
-    import deepspeed_tpu as dstpu
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel
-    from deepspeed_tpu.parallel import mesh as mesh_lib
-    from deepspeed_tpu.runtime import precision as prec
-    from deepspeed_tpu.runtime.engine import TrainState
-
-    SDS = jax.ShapeDtypeStruct
-    batch = traffic["global_batch"]
-    mesh = Mesh(np.asarray(devices).reshape((1, len(devices), 1, 1, 1)),
-                mesh_lib.AXIS_ORDER)
-    engine, _, _, _ = dstpu.initialize(
-        config=engine_config(config, batch, 0, False),
-        model=GPT2LMHeadModel(model_config(config, rehearse=False)),
-        mesh=mesh)
-    ids = SDS((batch, traffic["seq_len"]), jnp.int32)
-    params = jax.eval_shape(lambda r, x: engine.module.init(r, x)["params"],
-                            jax.random.PRNGKey(0), ids)
-    state = TrainState(
-        params=params, opt_state=jax.eval_shape(engine.optimizer.init, params),
-        scaler=jax.eval_shape(lambda: prec.init_scaler_state(engine.precision)),
-        global_step=SDS((), jnp.int32), skipped_steps=SDS((), jnp.int32))
-    engine.state_shardings = engine._build_state_shardings(state)
-    engine._build_jit_fns()
-    state = jax.tree_util.tree_map(
-        lambda s, sh: SDS(s.shape, s.dtype, sharding=sh), state,
-        engine.state_shardings)
-    rng = jax.random.PRNGKey(0)
-    return engine._jit_train_batch.lower(
-        state,
-        {"input_ids": SDS(ids.shape, ids.dtype,
-                          sharding=mesh_lib.batch_sharding(mesh))},
-        SDS(rng.shape, rng.dtype,
-            sharding=NamedSharding(mesh, PartitionSpec())))
+    """The cell's train step at real size, lowered over abstract state on
+    ``devices`` (described chips)."""
+    return common.lower_train_step(_model(config, rehearse=False), config,
+                                   traffic, devices)
 
 
 def _reference_view(params, n_layer, device):
@@ -224,7 +156,7 @@ def build_serving(config, seed, rehearse, registry):
     from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel
 
     cfg = model_config(config, rehearse, serving=True)
-    sv = _merged(config, "serve", rehearse)
+    sv = common.merged(config, "serve", rehearse)
     served = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[
         sv["weights_dtype"]]
     model = GPT2LMHeadModel(cfg)

@@ -35,11 +35,10 @@ import functools
 import numpy as np
 
 from benchmark import roofline
-from benchmark.families import olmoe as shared
-from benchmark.families.gpt2 import _merged, engine_config
-from benchmark.families.olmoe import _at
-from benchmark.families.qwen3_next import (_rel, _routing_differs,
-                                           stream_add_differences)
+from benchmark.families import common, olmoe as shared
+from benchmark.families.common import (at as _at, rel as _rel,
+                                       routing_differs as _routing_differs)
+from benchmark.families.qwen3_next import stream_add_differences
 from benchmark.reference import laguna as ref
 
 WIDTH_KEYS = ("hidden_size", "intermediate_size", "moe_intermediate_size",
@@ -99,7 +98,7 @@ def traffic_shapes(config, rehearse):
 def model_config(config, rehearse):
     import jax.numpy as jnp
     from deepspeed_tpu.models.laguna import LagunaConfig
-    s, m = sizes(config, rehearse), _merged(config, "model", rehearse)
+    s, m = sizes(config, rehearse), common.merged(config, "model", rehearse)
     dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
     return LagunaConfig(
         **{k: s[k] for k in _SIZE_KEYS
@@ -121,28 +120,12 @@ def _model(config, rehearse):
 
 
 def build_train(config, global_batch, seed, devices, rehearse):
-    """(engine, initial parameters), as the other families build them: the
-    weights born sharded in one jitted call and adopted by
-    ``dstpu.initialize``."""
-    # first, so that a program without this model fails before any work
-    import deepspeed_tpu.models.laguna  # noqa: F401
-    import jax
-    import jax.numpy as jnp
-    import deepspeed_tpu as dstpu
-    from deepspeed_tpu.parallel.mesh import MeshConfig, make_mesh
-    from deepspeed_tpu.runtime.zero.init import sharded_init
-
-    model = _model(config, rehearse)
-    ds = engine_config(config, global_batch, seed, rehearse)
-    mesh = make_mesh(MeshConfig(data=len(devices)), devices=devices)
-    zero = ds["zero_optimization"]
-    params, _ = sharded_init(
-        model, jax.random.PRNGKey(seed),
-        jnp.zeros((global_batch, 64), jnp.int32), mesh, stage=zero["stage"],
-        param_persistence_threshold=zero.get(
-            "stage3_param_persistence_threshold", 100000))
-    engine, _, _, _ = dstpu.initialize(config=ds, model=model, mesh=mesh,
-                                       model_parameters=params)
+    """(engine, initial parameters): ``common.build_train``'s recipe over
+    ``LagunaForCausalLM`` (a program without this model fails at ``_model``,
+    before any work), the weights made from 64 example positions."""
+    engine, params = common.build_train(
+        _model(config, rehearse), config, global_batch, seed, devices,
+        rehearse, example_len=64)
     _LIVE["engine"] = engine         # ``judge_train`` folds its gauges
     return engine, params
 
@@ -155,41 +138,9 @@ def program_gauges():
 
 def lower_train_step(config, traffic, devices):
     """The cell's train step at real size, lowered over abstract state on
-    ``devices`` (described chips; the GPT-2 family's recipe)."""
-    import jax
-    import jax.numpy as jnp
-    import deepspeed_tpu as dstpu
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-    from deepspeed_tpu.parallel import mesh as mesh_lib
-    from deepspeed_tpu.runtime import precision as prec
-    from deepspeed_tpu.runtime.engine import TrainState
-
-    SDS = jax.ShapeDtypeStruct
-    batch = traffic["global_batch"]
-    mesh = Mesh(np.asarray(devices).reshape((1, len(devices), 1, 1, 1)),
-                mesh_lib.AXIS_ORDER)
-    engine, _, _, _ = dstpu.initialize(
-        config=engine_config(config, batch, 0, False),
-        model=_model(config, rehearse=False), mesh=mesh)
-    ids = SDS((batch, traffic["seq_len"]), jnp.int32)
-    params = jax.eval_shape(lambda r, x: engine.module.init(r, x)["params"],
-                            jax.random.PRNGKey(0), ids)
-    state = TrainState(
-        params=params, opt_state=jax.eval_shape(engine.optimizer.init, params),
-        scaler=jax.eval_shape(lambda: prec.init_scaler_state(engine.precision)),
-        global_step=SDS((), jnp.int32), skipped_steps=SDS((), jnp.int32))
-    engine.state_shardings = engine._build_state_shardings(state)
-    engine._build_jit_fns()
-    state = jax.tree_util.tree_map(
-        lambda s, sh: SDS(s.shape, s.dtype, sharding=sh), state,
-        engine.state_shardings)
-    rng = jax.random.PRNGKey(0)
-    return engine._jit_train_batch.lower(
-        state,
-        {"input_ids": SDS(ids.shape, ids.dtype,
-                          sharding=mesh_lib.batch_sharding(mesh))},
-        SDS(rng.shape, rng.dtype,
-            sharding=NamedSharding(mesh, PartitionSpec())))
+    ``devices`` (described chips)."""
+    return common.lower_train_step(_model(config, rehearse=False), config,
+                                   traffic, devices)
 
 
 # what the reference calls each leaf of a layer, by the program's path
@@ -258,7 +209,7 @@ def reference_sizes(config, rehearse):
 
 
 def _bf16_grads(config, rehearse):
-    return _merged(config, "train", rehearse)["engine"].get(
+    return common.merged(config, "train", rehearse)["engine"].get(
         "data_types", {}).get("grad_dtype") == "bf16"
 
 

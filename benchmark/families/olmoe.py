@@ -28,7 +28,8 @@ import functools
 import numpy as np
 
 from benchmark import roofline
-from benchmark.families.gpt2 import _merged, engine_config
+from benchmark.families import common
+from benchmark.families.common import at as _at
 from benchmark.reference import olmoe as ref
 
 WIDTH_KEYS = ("hidden_size", "intermediate_size", "num_attention_heads",
@@ -69,7 +70,7 @@ def traffic_shapes(config, rehearse):
 def model_config(config, rehearse):
     import jax.numpy as jnp
     from deepspeed_tpu.models.llama import olmoe_1b_7b
-    s, m = sizes(config, rehearse), _merged(config, "model", rehearse)
+    s, m = sizes(config, rehearse), common.merged(config, "model", rehearse)
     dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
     heads, kv = s["num_attention_heads"], s["num_key_value_heads"]
     return olmoe_1b_7b(
@@ -91,31 +92,19 @@ def model_config(config, rehearse):
 
 # ----------------------------------------------------------------- training
 
-def build_train(config, global_batch, seed, devices, rehearse):
-    """(engine, initial parameters), as the GPT-2 family builds them: the
-    weights born sharded in one jitted call and adopted by
-    ``dstpu.initialize``."""
-    # first, so that a program without this model fails before any work
-    from deepspeed_tpu.models.llama import LlamaForCausalLM, olmoe_1b_7b  # noqa: F401
-    import jax
-    import jax.numpy as jnp
-    import deepspeed_tpu as dstpu
-    from deepspeed_tpu.parallel.mesh import MeshConfig, make_mesh
-    from deepspeed_tpu.runtime.zero.init import sharded_init
+def _model(config, rehearse):
+    from deepspeed_tpu.models.llama import LlamaForCausalLM
+    return LlamaForCausalLM(model_config(config, rehearse))
 
-    cfg = model_config(config, rehearse)
-    ds = engine_config(config, global_batch, seed, rehearse)
-    model = LlamaForCausalLM(cfg)
-    mesh = make_mesh(MeshConfig(data=len(devices)), devices=devices)
-    zero = ds["zero_optimization"]
-    params, _ = sharded_init(
-        model, jax.random.PRNGKey(seed),
-        jnp.zeros((global_batch, cfg.max_seq_len), jnp.int32), mesh,
-        stage=zero["stage"],
-        param_persistence_threshold=zero.get(
-            "stage3_param_persistence_threshold", 100000))
-    engine, _, _, _ = dstpu.initialize(config=ds, model=model, mesh=mesh,
-                                       model_parameters=params)
+
+def build_train(config, global_batch, seed, devices, rehearse):
+    """(engine, initial parameters): ``common.build_train``'s recipe over
+    ``LlamaForCausalLM`` (a program without this model fails at ``_model``,
+    before any work), the weights made from a full-length example."""
+    model = _model(config, rehearse)
+    engine, params = common.build_train(
+        model, config, global_batch, seed, devices, rehearse,
+        example_len=model.config.max_seq_len)
     _LIVE["engine"] = engine
     return engine, params
 
@@ -129,43 +118,9 @@ def program_gauges():
 
 def lower_train_step(config, traffic, devices):
     """The cell's train step at real size, lowered over abstract state on
-    ``devices`` (described chips; the GPT-2 family's recipe)."""
-    import jax
-    import jax.numpy as jnp
-    import deepspeed_tpu as dstpu
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-    from deepspeed_tpu.models.llama import LlamaForCausalLM
-    from deepspeed_tpu.parallel import mesh as mesh_lib
-    from deepspeed_tpu.runtime import precision as prec
-    from deepspeed_tpu.runtime.engine import TrainState
-
-    SDS = jax.ShapeDtypeStruct
-    batch = traffic["global_batch"]
-    mesh = Mesh(np.asarray(devices).reshape((1, len(devices), 1, 1, 1)),
-                mesh_lib.AXIS_ORDER)
-    engine, _, _, _ = dstpu.initialize(
-        config=engine_config(config, batch, 0, False),
-        model=LlamaForCausalLM(model_config(config, rehearse=False)),
-        mesh=mesh)
-    ids = SDS((batch, traffic["seq_len"]), jnp.int32)
-    params = jax.eval_shape(lambda r, x: engine.module.init(r, x)["params"],
-                            jax.random.PRNGKey(0), ids)
-    state = TrainState(
-        params=params, opt_state=jax.eval_shape(engine.optimizer.init, params),
-        scaler=jax.eval_shape(lambda: prec.init_scaler_state(engine.precision)),
-        global_step=SDS((), jnp.int32), skipped_steps=SDS((), jnp.int32))
-    engine.state_shardings = engine._build_state_shardings(state)
-    engine._build_jit_fns()
-    state = jax.tree_util.tree_map(
-        lambda s, sh: SDS(s.shape, s.dtype, sharding=sh), state,
-        engine.state_shardings)
-    rng = jax.random.PRNGKey(0)
-    return engine._jit_train_batch.lower(
-        state,
-        {"input_ids": SDS(ids.shape, ids.dtype,
-                          sharding=mesh_lib.batch_sharding(mesh))},
-        SDS(rng.shape, rng.dtype,
-            sharding=NamedSharding(mesh, PartitionSpec())))
+    ``devices`` (described chips)."""
+    return common.lower_train_step(_model(config, rehearse=False), config,
+                                   traffic, devices)
 
 
 # what the reference calls each leaf of a layer, by the program's path
@@ -178,12 +133,6 @@ _LAYER_LEAVES = {
     "k_norm": ("attn", "k_norm", "scale"),
     "router": ("mlp", "router"), "gate": ("mlp", "gate_proj"),
     "up": ("mlp", "up_proj"), "down": ("mlp", "down_proj")}
-
-
-def _at(tree, path):
-    for key in path:
-        tree = tree[key]
-    return tree
 
 
 def reference_view(params, n_layers):
@@ -229,7 +178,7 @@ def system_step(config, params, batch_ids, device, rehearse):
     from deepspeed_tpu.models.llama import LlamaForCausalLM
     cfg = model_config(config, rehearse)
     model = LlamaForCausalLM(cfg)
-    bf16 = _merged(config, "train", rehearse)["engine"].get(
+    bf16 = common.merged(config, "train", rehearse)["engine"].get(
         "data_types", {}).get("grad_dtype") == "bf16"
 
     def loss_fn(p, ids):

@@ -31,9 +31,9 @@ import functools
 import numpy as np
 
 from benchmark import roofline
-from benchmark.families import olmoe as shared
-from benchmark.families.gpt2 import _merged, engine_config
-from benchmark.families.olmoe import _at
+from benchmark.families import common, olmoe as shared
+from benchmark.families.common import (at as _at, rel as _rel,
+                                       routing_differs as _routing_differs)
 from benchmark.reference import qwen3_next as ref
 
 WIDTH_KEYS = ("hidden_size", "intermediate_size", "moe_intermediate_size",
@@ -92,7 +92,7 @@ def traffic_shapes(config, rehearse):
 def model_config(config, rehearse):
     import jax.numpy as jnp
     from deepspeed_tpu.models.qwen3_next import Qwen3NextConfig
-    s, m = sizes(config, rehearse), _merged(config, "model", rehearse)
+    s, m = sizes(config, rehearse), common.merged(config, "model", rehearse)
     dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
     own = {k: s[k] for k in _SIZE_KEYS if k not in (
         "num_experts", "expert_parallel_size", "expert_parallel_rank",
@@ -115,28 +115,13 @@ def _model(config, rehearse):
 
 
 def build_train(config, global_batch, seed, devices, rehearse):
-    """(engine, initial parameters), as the other families build them: the
-    weights born sharded in one jitted call and adopted by
-    ``dstpu.initialize``."""
-    # first, so that a program without this model fails before any work
-    import deepspeed_tpu.models.qwen3_next  # noqa: F401
-    import jax
-    import jax.numpy as jnp
-    import deepspeed_tpu as dstpu
-    from deepspeed_tpu.parallel.mesh import MeshConfig, make_mesh
-    from deepspeed_tpu.runtime.zero.init import sharded_init
-
-    model = _model(config, rehearse)
-    ds = engine_config(config, global_batch, seed, rehearse)
-    mesh = make_mesh(MeshConfig(data=len(devices)), devices=devices)
-    zero = ds["zero_optimization"]
-    params, _ = sharded_init(
-        model, jax.random.PRNGKey(seed),
-        jnp.zeros((global_batch, 64), jnp.int32), mesh, stage=zero["stage"],
-        param_persistence_threshold=zero.get(
-            "stage3_param_persistence_threshold", 100000))
-    engine, _, _, _ = dstpu.initialize(config=ds, model=model, mesh=mesh,
-                                       model_parameters=params)
+    """(engine, initial parameters): ``common.build_train``'s recipe over
+    ``Qwen3NextForCausalLM`` (a program without this model fails at
+    ``_model``, before any work), the weights made from 64 example
+    positions."""
+    engine, params = common.build_train(
+        _model(config, rehearse), config, global_batch, seed, devices,
+        rehearse, example_len=64)
     _LIVE["engine"] = engine         # ``judge_train`` folds its gauges
     return engine, params
 
@@ -149,41 +134,9 @@ def program_gauges():
 
 def lower_train_step(config, traffic, devices):
     """The cell's train step at real size, lowered over abstract state on
-    ``devices`` (described chips; the GPT-2 family's recipe)."""
-    import jax
-    import jax.numpy as jnp
-    import deepspeed_tpu as dstpu
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec
-    from deepspeed_tpu.parallel import mesh as mesh_lib
-    from deepspeed_tpu.runtime import precision as prec
-    from deepspeed_tpu.runtime.engine import TrainState
-
-    SDS = jax.ShapeDtypeStruct
-    batch = traffic["global_batch"]
-    mesh = Mesh(np.asarray(devices).reshape((1, len(devices), 1, 1, 1)),
-                mesh_lib.AXIS_ORDER)
-    engine, _, _, _ = dstpu.initialize(
-        config=engine_config(config, batch, 0, False),
-        model=_model(config, rehearse=False), mesh=mesh)
-    ids = SDS((batch, traffic["seq_len"]), jnp.int32)
-    params = jax.eval_shape(lambda r, x: engine.module.init(r, x)["params"],
-                            jax.random.PRNGKey(0), ids)
-    state = TrainState(
-        params=params, opt_state=jax.eval_shape(engine.optimizer.init, params),
-        scaler=jax.eval_shape(lambda: prec.init_scaler_state(engine.precision)),
-        global_step=SDS((), jnp.int32), skipped_steps=SDS((), jnp.int32))
-    engine.state_shardings = engine._build_state_shardings(state)
-    engine._build_jit_fns()
-    state = jax.tree_util.tree_map(
-        lambda s, sh: SDS(s.shape, s.dtype, sharding=sh), state,
-        engine.state_shardings)
-    rng = jax.random.PRNGKey(0)
-    return engine._jit_train_batch.lower(
-        state,
-        {"input_ids": SDS(ids.shape, ids.dtype,
-                          sharding=mesh_lib.batch_sharding(mesh))},
-        SDS(rng.shape, rng.dtype,
-            sharding=NamedSharding(mesh, PartitionSpec())))
+    ``devices`` (described chips)."""
+    return common.lower_train_step(_model(config, rehearse=False), config,
+                                   traffic, devices)
 
 
 # what the reference calls each leaf of a layer, by the program's path
@@ -260,7 +213,7 @@ def system_step(config, params, batch_ids, device, rehearse):
     import jax.numpy as jnp
     model = _model(config, rehearse)
     s = sizes(config, rehearse)
-    bf16 = _merged(config, "train", rehearse)["engine"].get(
+    bf16 = common.merged(config, "train", rehearse)["engine"].get(
         "data_types", {}).get("grad_dtype") == "bf16"
 
     def loss_fn(p, ids):
@@ -289,18 +242,6 @@ def system_step(config, params, batch_ids, device, rehearse):
                        "mixer_out": blk["mixer_out"][0][i // interval],
                        "ffn_out": blk["ffn_out"][0][i // interval]})
     return loss, layers, grads
-
-
-def _rel(a, b):
-    import jax.numpy as jnp
-    a, b = (t.astype(jnp.float32) for t in (a, b))
-    return jnp.linalg.norm(a - b) / jnp.linalg.norm(b)
-
-
-def _routing_differs(got, want):
-    """Assignments of ``want`` [T, k] that ``got`` [T, k] did not choose."""
-    import jax.numpy as jnp
-    return jnp.sum(jnp.all(want[:, :, None] != got[:, None, :], axis=2))
 
 
 def own_stream_differences(system, reference, kinds):

@@ -47,23 +47,15 @@ None and every reader gives None. It is also None when the ring has pushed
 anything out (its oldest ``seq`` is not 1): a sum that may be short is not
 given. ``of`` logs which of the cases it was, once a run.
 
-The six metrics are CANDIDATES: their readers are in the tree, their entries
-are not in ``BENCHMARK.json`` (``test_bm_laguna.py`` holds the last four
-entries of ``per_layer`` to be the ``swa_*`` four, and a PR that changes the
-program may add entries only at the end: PERF.md §7 has the edit a
-``benchmark`` PR makes). ``with_entries`` gives the manifest as it reads
-with the six pasted at the end, and until they are admitted
-
-    python -m benchmark.setup_reduce --workload <cell> --seed <n> [--seconds <s>]
-
-is ``benchmark.run --trace 1`` over that manifest: the line carries the six
-values and the detail file the timeline. The driver never runs it.
+The six metrics of ``METRICS`` are in ``BENCHMARK.json`` since PR 37, in
+every cell that reports ``train_tokens_per_s`` (the window's opening is read
+from a training run's samples), each entry as its reader states it: every
+traced line carries them, and the detail file (``benchmark/out/<tag>.json``)
+the timeline under ``extra.setup_attribution``. A later training cell adds
+its name to their ``workloads``.
 """
 
-import copy
-import sys
-
-from benchmark import harness, manifest
+from benchmark import harness
 
 SLOT = "setup_attribution"                  # where record.extra keeps it
 ROWS = ("before_first_span", "sharded_init", "engine_init", "reference",
@@ -71,27 +63,6 @@ ROWS = ("before_first_span", "sharded_init", "engine_init", "reference",
 METRICS = ("setup_engine_init_s", "setup_first_step_s",
            "setup_outside_program_s", "setup_compile_s",
            "setup_programs_compiled", "setup_cache_misses")
-
-
-def with_entries(bench):
-    """``bench`` with the six metrics' entries at the end of ``per_layer``,
-    each as its reader states it, in every training cell (the window's
-    opening is read from a training run's samples); ``bench`` itself where
-    it has them."""
-    have = {m["name"] for m in bench["per_layer"]}
-    if have >= set(METRICS):
-        return bench
-    cells = [w["name"] for w in bench["workloads"] if any(
-        m["name"] == "train_tokens_per_s"
-        for m in manifest.metrics_for(bench, w, "end_to_end"))]
-    out = copy.deepcopy(bench)
-    for name in METRICS:
-        reader = manifest.metric_module(name)
-        out["per_layer"].append({
-            "name": name, "unit": reader.UNIT, "better": "lower",
-            "source": reader.SOURCE, "layer": reader.LAYER,
-            "moves": reader.MOVES, "workloads": list(cells)})
-    return out
 
 
 def union_s(intervals):
@@ -215,9 +186,3 @@ def metric(record, key):
     found = of(record)
     return None if found is None else found[key]
 
-
-if __name__ == "__main__":
-    from benchmark import run
-    load = manifest.load
-    manifest.load = lambda: with_entries(load())
-    sys.exit(run.main(sys.argv[1:] + ["--trace", "1"]))

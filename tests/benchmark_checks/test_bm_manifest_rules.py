@@ -6,12 +6,19 @@ and its family, each family file against ``benchmark/families/__init__.py``'s
 list. ``test_bm_rehearsal_runs.py`` runs this file again on a copy of the
 checkout to which a configuration of another family was added as files and
 entries only. What is known of GPT-2 alone sits in the cases that name it.
+
+Since PR 37 it also holds the CHECKS to what they ask of a later PR: an
+addition goes at the end of a list and onto the ``workloads`` of a metric
+that is there, so no file of this directory may hold the manifest to a
+position or a count (the last two cases).
 """
 
+import ast
 import copy
 import importlib
 import json
 import os
+import shutil
 
 import pytest
 
@@ -117,10 +124,12 @@ def test_the_gpt2_configurations_are_the_published_ones(name, want):
     assert body["published"]["vocab_size"] == 50257
 
 
-@pytest.mark.parametrize("name", _listed("families", ".py"))
+@pytest.mark.parametrize("name", [f for f in _listed("families", ".py")
+                                  if f not in families.HELPERS])
 def test_each_family_file_provides_what_the_harness_asks_of_a_family(name):
     """``benchmark/families/__init__.py`` is the list; serving is all or
-    nothing (a family without a serving block has none of it)."""
+    nothing (a family without a serving block has none of it).
+    ``families.HELPERS`` (the recipe they share) are no families."""
     family = importlib.import_module(f"benchmark.families.{name}")
     missing = [m for m in families.TRAINING
                if not callable(getattr(family, m, None))]
@@ -207,3 +216,154 @@ def test_a_candidate_is_admitted_by_pasting_the_entries_its_file_carries(
     assert "setup_s" in e2e and len(e2e) >= 2
     # an admitted cell is left as it is
     assert manifest.with_candidate(merged, cell) is merged
+
+
+# ------------------------------------------- the checks take additions (PR 37)
+
+# each family's "the cell is the one its issue names", which takes the manifest
+FAMILY_CHECKS = {"test_bm_olmoe": "the_cell_is_the_one_issue_27_names",
+                 "test_bm_qwen3_next": "the_cell_is_the_one_issue_31_names",
+                 "test_bm_laguna": "the_cell_is_the_one_issue_33_names"}
+LATER_READER = '''"""later_steps: a later PR's metric, added as a file."""
+NAME, UNIT, LAYER = "later_steps", "count", "train step program"
+MOVES, SOURCE = "train_tokens_per_s", "program_counter"
+
+
+def read(record):
+    return record.extra.get("steps")
+'''
+
+
+def with_a_later_prs_addition(bench, root, like="laguna-train-1chip-s16384"):
+    """``bench`` as a later ``model_config`` PR would leave it, its files
+    under ``root``: a configuration, a cell and a per-layer metric appended
+    at the END (copies of ``like``'s files under new names), and the cell's
+    name appended to the lists of the metrics every training cell has."""
+    here = os.path.join(root, "benchmark")
+    shutil.copytree(manifest.HERE, here,
+                    ignore=shutil.ignore_patterns("out", "__pycache__"))
+    later = copy.deepcopy(bench)
+    old = manifest.cell_of(bench, like)
+    entry = next(c for c in bench["configs"] if c["name"] == old["config"])
+    cell = dict(old, name="later-train-1chip", config="later-model",
+                traffic="pretrain-later")
+    config = dict(_config_file(entry), name="later-model")
+    traffic = dict(manifest.traffic_of(old),
+                   **{k: cell[k] for k in ("name", "config", "traffic")})
+    for path, text in (
+            ("configs/later-model.json", json.dumps(config)),
+            ("workloads/later-train-1chip.json", json.dumps(traffic)),
+            ("layer_metrics/later_steps.py", LATER_READER)):
+        with open(os.path.join(here, path), "w") as f:
+            f.write(text)
+    later["configs"].append(dict(
+        entry, name="later-model", file="benchmark/configs/later-model.json"))
+    later["workloads"].append(cell)
+    training = set(next(m for m in bench["end_to_end"]
+                        if m["name"] == "train_tokens_per_s")["workloads"])
+    for m in later["end_to_end"] + later["per_layer"]:
+        if training <= set(m.get("workloads", ())):
+            m["workloads"].append(cell["name"])
+    later["per_layer"].append({
+        "name": "later_steps", "unit": "count", "better": "higher",
+        "source": "program_counter", "layer": "train step program",
+        "moves": "train_tokens_per_s", "workloads": [cell["name"]]})
+    return later
+
+
+def test_an_addition_at_the_end_breaks_no_rule_and_no_familys_check(tmp_path):
+    """Rule (a) of ISSUE 37: a sixth cell, its configuration and a metric of
+    its own, appended where the driver's check wants every addition, leave
+    ``manifest.problems`` empty and every family's own check true."""
+    later = with_a_later_prs_addition(BENCH, str(tmp_path))
+    assert manifest.load() == BENCH, "the addition edited its argument"
+    assert manifest.problems(later, root=str(tmp_path)) == []
+    added = manifest.cell_of(later, "later-train-1chip")
+    names = {m["name"] for m in manifest.metrics_for(later, added,
+                                                     "per_layer")}
+    assert {"later_steps", "train_program_hbm_gb"} <= names
+    for module, check in FAMILY_CHECKS.items():
+        getattr(importlib.import_module(module), check)(later)
+
+
+SECTIONS = ("workloads", "configs", "per_layer", "end_to_end")
+
+
+def _is_a_section(node):
+    """``BENCH["<section>"]``, the loaded manifest under either name."""
+    return isinstance(node, ast.Subscript) \
+        and isinstance(node.value, ast.Name) \
+        and node.value.id in ("BENCH", "bench") \
+        and isinstance(node.slice, ast.Constant) \
+        and node.slice.value in SECTIONS
+
+
+def _holds(node, test):
+    return any(test(n) for n in ast.walk(node))
+
+
+def pins(source):
+    """Lines of ``source`` that hold the manifest to a position or a count:
+    a section of it indexed by a number or a slice; a ``workloads`` list
+    compared with ``[CELL]``; the ``len`` of a section, or of a name made
+    from one, compared with a number."""
+    tree = ast.parse(source)
+    made_from_it = {t.id for n in ast.walk(tree) if isinstance(n, ast.Assign)
+                    and _holds(n.value, _is_a_section)
+                    for t in n.targets if isinstance(t, ast.Name)}
+
+    def a_count(n):
+        return isinstance(n, ast.Call) and getattr(n.func, "id", "") == "len" \
+            and (_holds(n.args[0], _is_a_section) or getattr(
+                n.args[0], "id", None) in made_from_it)
+
+    def a_number(n):
+        return isinstance(n, ast.Constant) and isinstance(n.value, int)
+
+    def only_the_cell(n):
+        return isinstance(n, ast.List) and len(n.elts) == 1 \
+            and getattr(n.elts[0], "id", "") == "CELL"
+
+    def its_workloads(n):
+        return isinstance(n, ast.Subscript) and isinstance(
+            n.slice, ast.Constant) and n.slice.value == "workloads"
+
+    out = []
+    for n in ast.walk(tree):
+        if isinstance(n, ast.Subscript) and _is_a_section(n.value) \
+                and not isinstance(n.slice, ast.Name):
+            out.append(n.lineno)
+        if isinstance(n, ast.Compare):
+            sides = [n.left] + n.comparators
+            if any(map(a_count, sides)) and any(map(a_number, sides)):
+                out.append(n.lineno)
+            if _holds(n, only_the_cell) and _holds(n, its_workloads):
+                out.append(n.lineno)
+    return sorted(set(out))
+
+
+@pytest.mark.parametrize("source", [
+    'assert BENCH["workloads"][-1]["name"] == CELL',
+    'assert BENCH["configs"][-1]["name"] == cell["config"]',
+    'assert [m["name"] for m in BENCH["per_layer"][-4:]] == FOUR',
+    'for m in BENCH["per_layer"]:\n    assert m["workloads"] == [CELL]',
+    'cells = [w["name"] for w in BENCH["workloads"]]\n'
+    'assert len(cells) == 5',
+    'assert len(bench["end_to_end"]) == 2'],
+    ids=["last_cell", "last_config", "last_four_metrics", "this_cell_alone",
+         "five_cells", "two_metrics"])
+def test_the_rule_finds_the_pins_issue_37_found(source):
+    assert pins(source)
+
+
+@pytest.mark.parametrize("name", sorted(
+    f for f in os.listdir(os.path.dirname(os.path.abspath(__file__)))
+    if f.startswith("test_") and f.endswith(".py")))
+def test_no_check_holds_the_manifest_to_a_position_or_a_count(name):
+    """Rule (b) of ISSUE 37, one case a file of this directory: entries are
+    held by NAME (``manifest.cell_of``, ``next(m for m in ... if m["name"]
+    == ...)``, ``CELL in m["workloads"]``), so a later PR's appended entries
+    fail no accepted check."""
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           name)) as f:
+        assert pins(f.read()) == [], name

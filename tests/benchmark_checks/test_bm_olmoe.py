@@ -47,8 +47,10 @@ def test_kernel_flops_a_step():
         6 * 4 * 16 * 4096 * 4096 * 128 == 824_633_720_832
 
 
-def test_the_cell_is_the_one_issue_27_names():
-    cell = manifest.cell_of(BENCH, CELL)
+def the_cell_is_the_one_issue_27_names(bench):
+    """Held on ``bench`` by name (``test_bm_manifest_rules.py`` runs it over
+    the manifest with a cell, a configuration and a metric appended)."""
+    cell = manifest.cell_of(bench, CELL)
     traffic = manifest.traffic_of(cell)
     assert (cell["config"], cell["chips"], cell["traffic"]) == (
         "olmoe-1b-7b-0125-depth1", 1, "pretrain-b4x4096")
@@ -58,7 +60,7 @@ def test_the_cell_is_the_one_issue_27_names():
         "kind": "train_steps", "global_batch": 4, "seq_len": 4096,
         "batch_pool": 16, "token_below": 50304, "warmup_steps": 3,
         "fence_lag_steps": 2, "trace_steps": 3}
-    entry = next(c for c in BENCH["configs"] if c["name"] == cell["config"])
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
     assert entry["reduced"] == ["num_hidden_layers"]
     assert (CONFIG["num_hidden_layers"],
             CONFIG["published"]["num_hidden_layers"]) == (1, 16)
@@ -66,13 +68,17 @@ def test_the_cell_is_the_one_issue_27_names():
         assert CONFIG[key], key
     assert {"intermediate_size", "router_aux_loss_coef",
             "router_z_loss_coef"} <= set(CONFIG["assumed"])
-    names = {m["name"] for m in manifest.metrics_for(BENCH, cell, "per_layer")}
+    names = {m["name"] for m in manifest.metrics_for(bench, cell, "per_layer")}
     assert {"moe_gmm_roofline", "moe_gmm_share", "moe_dispatch_ms",
             "moe_rows_max_over_mean", "flash_attn_share",
             "flash_attn_roofline", "flash_fwd_roofline", "flash_bwd_roofline",
             "train_mfu", "train_step_ms", "train_program_hbm_gb",
             "train_unscoped_share"} <= names
     assert not names & {"collective_exposed_share", "collectives_per_step"}
+
+
+def test_the_cell_is_the_one_issue_27_names():
+    the_cell_is_the_one_issue_27_names(BENCH)
 
 
 def test_the_catalogs_numbers_are_the_files():

@@ -232,10 +232,13 @@ def test_a_second_config_cell_and_metric_are_added_as_files_only(tmp_path):
     root = tmp_path / "checkout"
     ignore = shutil.ignore_patterns("out", "__pycache__")
     shutil.copytree(manifest.HERE, root / "benchmark", ignore=ignore)
-    rules = "tests/benchmark_checks/test_bm_manifest_rules.py"
-    (root / rules).parent.mkdir(parents=True)
-    for name in ("BENCHMARK.json", rules):
-        shutil.copy(os.path.join(manifest.ROOT, name), root / name)
+    # the whole directory of checks: the rule file runs each family's own
+    # check over the manifest with a further cell appended
+    checks = "tests/benchmark_checks"
+    rules = checks + "/test_bm_manifest_rules.py"
+    shutil.copytree(os.path.join(manifest.ROOT, checks), root / checks,
+                    ignore=ignore)
+    shutil.copy(os.path.join(manifest.ROOT, "BENCHMARK.json"), root)
     before = {p: p.read_bytes() for p in root.rglob("*")
               if p.is_file() and p.name != "BENCHMARK.json"}
     add_standin(root)
@@ -265,7 +268,8 @@ def test_a_second_config_cell_and_metric_are_added_as_files_only(tmp_path):
     assert held.returncode == 0, held.stdout[-3000:] + held.stderr[-2000:]
     for case in ("keeps_its_published_widths[standin-tiny]",
                  "asks_of_a_family[standin]", "by_name[standin-train]",
-                 "declares_the_same[standin_gmm_roofline]"):
+                 "declares_the_same[standin_gmm_roofline]",
+                 "breaks_no_rule_and_no_familys_check"):
         assert case + " PASSED" in held.stdout, case
     after = {p: p.read_bytes() for p in before}
     assert after == before, "the addition edited a file that was there"

@@ -234,47 +234,39 @@ def test_the_window_opens_where_the_runs_own_samples_say():
     assert setup_reduce.window_opening(record) == (505.0, 530.0)
 
 
-def test_the_six_are_candidates_admitted_by_pasting_their_entries_at_the_end():
-    """``BENCHMARK.json`` does not list them (``test_bm_laguna.py`` holds
-    its last four entries to be the ``swa_*`` four, and a PR that changes the
-    program adds entries at the end only); laid over it as the driver wants
-    an addition, they break none of its rules and change nothing that was
-    there."""
+def test_the_six_are_the_ones_setup_reduce_names():
     assert tuple(METRICS) == setup_reduce.METRICS
-    assert manifest.problems(BENCH) == []
-    assert not [m for m in BENCH["per_layer"] if m["moves"] == "setup_s"]
-    merged = setup_reduce.with_entries(BENCH)
-    assert manifest.load() == BENCH, "with_entries edited its argument"
-    assert manifest.problems(merged) == []
-    assert {k: v for k, v in merged.items() if k != "per_layer"} == \
-        {k: v for k, v in BENCH.items() if k != "per_layer"}
-    assert merged["per_layer"][:-6] == BENCH["per_layer"]
-    cells = [w["name"] for w in BENCH["workloads"]]
-    assert len(cells) == 5
-    for m, name in zip(merged["per_layer"][-6:], METRICS):
-        reader = manifest.metric_module(name)
-        assert (m["name"], m["moves"], m["better"]) == \
-            (name, "setup_s", "lower")
-        assert (m["unit"], m["source"], m["layer"]) == \
-            (reader.UNIT, reader.SOURCE, reader.LAYER)
-        assert m["workloads"] == cells
-    # admitted once, it is left as it is
-    assert setup_reduce.with_entries(merged) is merged
+    assert set(METRICS) <= {m["name"] for m in BENCH["per_layer"]
+                            if m["moves"] == "setup_s"}
+
+
+@pytest.mark.parametrize("metric", sorted(METRICS))
+def test_each_of_the_six_is_in_the_manifest_as_its_reader_states_it(metric):
+    """Admitted in PR 37, by name: the entry is the reader's own words, and
+    every cell that reports ``train_tokens_per_s`` (the window's opening is
+    read from a training run's samples) is listed."""
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == metric)
+    reader = manifest.metric_module(metric)
+    assert (entry["moves"], entry["better"]) == ("setup_s", "lower")
+    assert (entry["unit"], entry["source"], entry["layer"]) == \
+        (reader.UNIT, reader.SOURCE, reader.LAYER)
+    training = next(m for m in BENCH["end_to_end"]
+                    if m["name"] == "train_tokens_per_s")["workloads"]
+    assert training and set(training) <= set(entry["workloads"])
 
 
 def test_a_rehearsal_lists_the_six_and_writes_the_timeline(tmp_path):
-    """One process, one cell, as the driver would run it with the six
-    admitted (``python -m benchmark.setup_reduce``: ``benchmark.run --trace
-    1`` over ``with_entries``; the ring and the registry are the process's
-    own): the traced CPU rehearsal names the six metrics, and the detail file
-    holds the timeline, whose rows add up."""
+    """One process, one cell, as the driver runs it (``python -m
+    benchmark.run ... --trace 1``; the ring and the registry are the
+    process's own): the traced CPU rehearsal names the six metrics, and the
+    detail file holds the timeline, whose rows add up."""
     seed = 3535
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=manifest.ROOT)
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     done = subprocess.run(
-        [sys.executable, "-m", "benchmark.setup_reduce", "--workload",
+        [sys.executable, "-m", "benchmark.run", "--workload",
          "gpt2l-train-1chip", "--seed", str(seed), "--seconds", "1",
-         "--rehearse-cpu"],
+         "--trace", "1", "--rehearse-cpu"],
         cwd=manifest.ROOT, env=env, capture_output=True, text=True,
         timeout=600)
     assert done.returncode == 0, done.stderr[-2000:]
