@@ -197,10 +197,15 @@ def _dense(cfg, n, name):
 
 def rope_tables(cfg, positions):
     """{layer type: (cos, sin)} for the layer types the config has: each
-    type's own rotary width, base and scaling, computed once a step."""
+    type's own rotary width, base and scaling, computed once a step. A type
+    whose parameter set is ``None`` carries no position encoding: its table
+    is ``None`` and ``LagunaAttention`` leaves q and k as projected."""
     out = {}
     for kind in sorted(set(cfg.layer_types)):
         p = cfg.rope_of(kind)
+        if p is None:
+            out[kind] = None
+            continue
         rot = int(cfg.head_dim * p.get("partial_rotary_factor", 1.0))
         if p.get("rope_type", "default") == "yarn":
             out[kind] = yarn_rope_angles(
@@ -214,6 +219,12 @@ def rope_tables(cfg, positions):
 
 
 class LagunaAttention(nn.Module):
+    """The attention branch of the module docstring. ``config`` is a
+    ``LagunaConfig`` or any config with the attributes read here
+    (``hidden_size``, ``num_key_value_heads``, ``head_dim``,
+    ``sliding_window``, ``gating``, ``use_flash``, ``dtype``,
+    ``param_dtype``): ``models/smallthinker.SmallThinkerConfig`` is one.
+    ``rope[layer_type]`` ``None``: no rotation (a NoPE layer)."""
     config: LagunaConfig
     layer_type: str
     heads: int
@@ -228,11 +239,12 @@ class LagunaAttention(nn.Module):
         v = _dense(cfg, Hkv * D, "v_proj")(x).reshape(B, S, Hkv, D)
         q, k, v = (checkpoint_name(t, "qkv").transpose(0, 2, 1, 3)
                    for t in (q, k, v))                      # [B, H, S, D]
-        cos, sin = rope[self.layer_type]
-        rot = 2 * cos.shape[-1]
-        q, k = (apply_rope(t, cos, sin) if rot == D else jnp.concatenate(
-            [apply_rope(t[..., :rot], cos, sin), t[..., rot:]], axis=-1)
-            for t in (q, k))
+        if rope[self.layer_type] is not None:
+            cos, sin = rope[self.layer_type]
+            rot = 2 * cos.shape[-1]
+            q, k = (apply_rope(t, cos, sin) if rot == D else jnp.concatenate(
+                [apply_rope(t[..., :rot], cos, sin), t[..., rot:]], axis=-1)
+                for t in (q, k))
         out = dot_product_attention(
             q, k, v, causal=True, use_flash=cfg.use_flash,
             window=cfg.sliding_window if self.layer_type == SLIDING else None)
@@ -302,15 +314,17 @@ class LagunaBlock(nn.Module):
         return x + out
 
 
-def remat_block(cfg, parent, name):
-    """``LagunaBlock`` under its own ZeRO-3 gather edge (innermost) and,
+def remat_block(cfg, parent, name, block=None):
+    """``block`` (``LagunaBlock`` where not given; another model's block of
+    the same calling convention: ``models/smallthinker.py``) under its own
+    ZeRO-3 gather edge (innermost) and,
     where the config asks, its own remat. Whatever the policy keeps, it
     keeps the router's choice and the attention kernel's outputs
     (``models/gpt2.block_remat_policy``); ``prevent_cse``
     because several rematted blocks share one scan body and a scan of ONE
     period is no loop once XLA has simplified it
     (``models/qwen3_next._Period``)."""
-    block = gather_edge_block(LagunaBlock, parent, name)
+    block = gather_edge_block(block or LagunaBlock, parent, name)
     if cfg.remat:
         block = nn.remat(block, prevent_cse=True,
                          policy=block_remat_policy(cfg.remat_policy))

@@ -9,7 +9,7 @@ expert and the expert bank is one grouped matmul per projection
 
     router (float32): logits = h Wg, softmax, top-k          ``moe_router``
     stable argsort of the T*k expert ids, the row gather     ``moe_dispatch``
-    gate / up grouped matmuls, silu * up, down               ``moe_gmm*``
+    gate / up grouped matmuls, act(gate) * up, down          ``moe_gmm*``
     rows back to token order, weighted by the routing
     probability, summed over each token's k                  ``moe_combine``
 
@@ -53,6 +53,13 @@ read, ``moe_combine_rows_walked`` in ``stats`` is rows read over rows held) —
 each the other's backward pass, neither a scatter-add. A ``shared_d_ff`` adds
 one SwiGLU expert every token passes through, under a sigmoid gate
 (``moe_shared``).
+
+Two things a model may say otherwise, and no more: the experts' gate
+non-linearity (``act``: ``silu``, or ``relu`` — a ReGLU expert) and the
+tensor the ROUTER reads (``__call__(x, router_x=...)``: a router that sits
+ahead of the mixer reads the block's input while the experts read the
+normed stream after it; its gradient then enters the stream before the
+mixer). The defaults are one input and ``silu``.
 """
 
 import functools
@@ -79,6 +86,8 @@ HELD_STAT_GAUGES = dict(STAT_GAUGES,
                         moe_rows_held_share="moe/rows_held_share",
                         moe_held_slabs="moe/held_slabs",
                         moe_combine_rows_walked="moe/combine_rows_walked")
+# the experts' gate non-linearity, by the name a model gives ``act``
+_ACTS = {"silu": nn.silu, "relu": nn.relu}
 # static length of a held layer's row arrays over the mean rows held (4 did
 # not fit the one cell that holds a share: PERF.md Findings PR 31)
 _HELD_ROWS_SLACK = 2
@@ -211,7 +220,10 @@ class DroplessMoE(nn.Module):
     expert. ``pin_choice``: a
     caller that recomputes this layer under a remat policy which saves the
     name ``moe_experts`` asks for it (``route``); whether a share is held
-    has nothing to do with it."""
+    has nothing to do with it. ``act``: the experts' gate non-linearity,
+    ``act(gate) * up`` (``silu``: SwiGLU; ``relu``: ReGLU). ``router_x`` of
+    ``__call__``: the tensor the router reads where it is not ``x`` (same
+    shape; the experts still read ``x``)."""
     num_experts: int
     k: int
     d_ff: int
@@ -225,9 +237,10 @@ class DroplessMoE(nn.Module):
     shared_d_ff: int = 0
     pin_choice: bool = False
     routed_scale: float = 1.0
+    act: str = "silu"
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_x=None):
         B, S, H = x.shape
         E, K, F = self.num_experts, self.k, self.d_ff
         held = self.experts_held or E
@@ -238,13 +251,14 @@ class DroplessMoE(nn.Module):
         w_up = self.param("up_proj", init, (held, H, F), self.param_dtype)
         w_down = self.param("down_proj", init, (held, F, H), self.param_dtype)
         xt = x.reshape(T, H)
+        rt = xt if router_x is None else router_x.reshape(T, H)
 
         with annotate("moe_router"):
             # float32 from a float32 cast of the hidden state, at full
             # precision (a TPU's default float32 matmul rounds its
             # operands to bf16): the 8th and 9th probabilities of a token
             # can tie to bf16 rounding
-            logits = jnp.dot(xt.astype(jnp.float32), wg.astype(jnp.float32),
+            logits = jnp.dot(rt.astype(jnp.float32), wg.astype(jnp.float32),
                              precision=jax.lax.Precision.HIGHEST)
             # (the defaults keep OLMoE's call and program)
             top_w, top_e, probs = route(
@@ -366,12 +380,12 @@ class DroplessMoE(nn.Module):
         return checkpoint_name(y, "mlp_proj")
 
     def _experts(self, xs, weights, group_sizes):
-        """Rows in expert order through their experts' SwiGLU."""
+        """Rows in expert order through their experts' gated unit."""
         w_gate, w_up, w_down = weights
         gate = grouped_matmul(xs, w_gate, group_sizes)
         up = grouped_matmul(xs, w_up, group_sizes)
         with annotate("moe_act"):
-            h = checkpoint_name(nn.silu(gate) * up, "mlp_fc")
+            h = checkpoint_name(_ACTS[self.act](gate) * up, "mlp_fc")
         return grouped_matmul(h, w_down, group_sizes)
 
     @staticmethod

@@ -14,7 +14,10 @@ The kernels are jax 0.9.0's ``jax.experimental.pallas.ops.tpu.megablox``
 repo's: the custom VJP (each of the three products under a scope of its own,
 ``moe_gmm`` / ``moe_gmm_dlhs`` / ``moe_gmm_drhs``, so the device plane names
 the kernels and the benchmark's ``moe_gmm_*`` readers find them by prefix),
-the tilings, and padding M up to the row tile. On other backends the same
+the tilings (caps under which each side takes the largest multiple of 128
+that divides it: ``_divisor``; powers of two and 3 or 5 times one alike;
+all three products swept on the chip, my chip runs, PR 27 and PR 38), and
+padding M up to the row tile. On other backends the same
 kernels run in Pallas interpret mode, as the flash kernels do.
 
 Backward: ``dlhs = gmm(dout, rhs^T)`` (a grouped matmul against the
@@ -35,14 +38,34 @@ from deepspeed_tpu.telemetry.spans import annotate
 _mb = importlib.import_module(
     "jax.experimental.pallas.ops.tpu.megablox.gmm")
 
-# (rows, contraction, columns) tiles of the three products at the sizes the
-# OLMoE cell runs (M 131072, K 2048 / 1024, N 1024 / 2048); each is clipped
-# to the problem. The forward tile is from a sweep on a v5e (PERF.md Findings
-# PR 27: the whole contraction keeps a group's weight tile resident; 512 rows
-# with it is refused for scoped VMEM); the two backward tiles were not swept.
-TILE_FWD = (256, 2048, 1024)
-TILE_DLHS = (512, 1024, 1024)
-TILE_DRHS = (512, 1024, 1024)
+# (rows, contraction, columns) CAPS of the three products' tiles; each side
+# takes the largest multiple of 128 under its cap that divides the problem
+# (``_divisor``). At powers of two — the OLMoE, Qwen3-Next and Laguna cells:
+# K, N of 2048 / 1024 / 512 — the caps give (256, 2048, 1024) forward and
+# (512, 1024, 1024) backward: the forward tile is from a sweep on a v5e
+# (PERF.md Findings PR 27: the whole contraction keeps a group's weight tile
+# resident; 512 rows with it is refused for scoped VMEM). The caps themselves
+# are from a sweep at widths that are NO powers of two (SmallThinker's
+# experts, (K, N) = (2560, 768) and (768, 2560), a slab of 49,152 rows of
+# which 24,576 are filled over 16 experts; device ms a call, share of the
+# bf16 peak; tests/perf/gmm_tile_bench.py, my chip runs, PR 38), chosen
+# among the caps that leave every power of two its tile:
+#   forward  (2560, 768): (256, 2560, 768) 0.587 ms, 83.5 % — halving gave
+#            (256, 512, 768) 0.823, a cap of 2048 (256, 1280, 768) 0.751;
+#            (768, 2560): (256, 768, 1280) 0.652, 75.3 % — (256, 768, 512)
+#            0.802, (256, 768, 640) 0.737. 512 rows are faster still at these
+#            widths ((512, 1280, 768) 0.568, (512, 768, 1280) 0.593) and
+#            refused at OLMoE's: the row cap stays.
+#   dlhs     dout [M, N] x rhs^T: (512, 768, 1280) 0.591, 83.0 % and
+#            (512, 1280, 768) 0.574, 85.5 % — caps of 1024 gave (512, 768,
+#            640) 0.690 and (512, 640, 768) 0.612; 1,024 rows 0.73-0.74.
+#   drhs     lhs^T x dout: (512, 1280, 768) 0.589, 83.3 % and (512, 768,
+#            1280) 0.591, 83.0 % — caps of 1024 gave (512, 640, 768) 0.661
+#            and (512, 768, 640) 0.666; 1,024 rows x 1,280 and a whole side
+#            of 2,560 are refused for scoped VMEM.
+TILE_FWD = (256, 2560, 1280)
+TILE_DLHS = (512, 1280, 1280)
+TILE_DRHS = (512, 1280, 1280)
 
 
 def _interpret_default():
@@ -50,16 +73,26 @@ def _interpret_default():
     return not is_tpu_backend()
 
 
+def _divisor(cap, d):
+    """The largest multiple of 128 no larger than ``cap`` that divides
+    ``d``: 2,560 under 2,560 itself and under 1,280 that, 768 under 1,280
+    itself, where halving from 2,048 and 1,024 stopped at 512 and 256. A
+    power of two gets the largest power of two under the cap. Where no
+    multiple of 128 divides (a test's sizes), ``cap`` halved until it
+    does."""
+    for t in range(min(cap, d) // 128 * 128, 0, -128):
+        if d % t == 0:
+            return t
+    t = min(cap, d)
+    while d % t:
+        t //= 2
+    return t
+
+
 def _clip(tile, m, k, n):
     """``tile`` no larger than the problem, each side dividing its
-    dimension (tiles and padded sizes are powers of two times 8)."""
-    out = []
-    for t, d in zip(tile, (m, k, n)):
-        t = min(t, d)
-        while d % t:
-            t //= 2
-        out.append(t)
-    return tuple(out)
+    dimension (``_divisor``; the rows are padded to whole row tiles)."""
+    return tuple(_divisor(t, d) for t, d in zip(tile, (m, k, n)))
 
 
 def _fwd(lhs, rhs, group_sizes, interpret):

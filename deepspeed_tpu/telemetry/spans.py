@@ -118,13 +118,19 @@ def annotate(tag):
       ``moe_gmm_roofline`` and ``moe_gmm_share`` (one tag, by prefix);
     - ``moe_router``, ``moe_dispatch``, ``moe_combine`` (moe/dropless.py:
       logits, softmax, top-k and the two losses; the sort and the row
-      gather; weighting and the rows' way back): ``moe_dispatch_ms``.
+      gather; weighting and the rows' way back): ``moe_dispatch_ms``;
+      ``moe_router`` alone: ``moe_router_ms`` (in a block whose router
+      reads the block's INPUT — ``DroplessMoE(x, router_x=...)``,
+      models/smallthinker.py — its operations depend on nothing of the
+      mixer and XLA may run them ahead of it; the scope's path stays
+      ``.../mlp/moe_router``).
       ``rows_to_tokens`` (ops/pallas/rows_to_tokens.py: the held rows'
       sort, gather and ``pallas_call``) lies INSIDE ``moe_combine`` in the
       forward pass and ``moe_dispatch`` in the backward pass and is counted
       with them; it must not start with a kernel tag (``moe_gmm``,
       ``flash_``, ``gdn_scan``, ``swa_``: matched by prefix);
-    - ``moe_act`` (moe/dropless.py) and ``qk_norm`` (models/llama.py, the
+    - ``moe_act`` (moe/dropless.py: ``act(gate) * up``, silu or relu)
+      and ``qk_norm`` (models/llama.py, the
       RMSNorms over the whole q and k projections): rows of the detail
       table.
 

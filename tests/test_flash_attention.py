@@ -120,6 +120,29 @@ def test_chunked_kernels_match_reference():
                                        err_msg=f"causal={causal} d{name}")
 
 
+def test_chunked_causal_kernels_take_a_kv_group_of_seven():
+    """28 / 4 heads' ratio (SmallThinker): the chunked causal kernels with
+    seven query heads a KV head, forward and all three gradients, q and k
+    handed over as projected (no rotation in front)."""
+    from deepspeed_tpu.ops.attention import reference_attention
+    q, _, _ = _qkv((1, 14, 256, 32), seed=7)
+    _, k, v = _qkv((1, 2, 256, 32), seed=8)
+
+    def both(attend):
+        return jax.value_and_grad(
+            lambda *a: jnp.sum(jnp.sin(attend(*a))), argnums=(0, 1, 2))(
+            q, k, v)
+
+    v1, g1 = both(functools.partial(flash_attention, causal=True, block_q=64,
+                                    block_k=64, chunk=128, interpret=True))
+    v2, g2 = both(functools.partial(reference_attention, causal=True))
+    np.testing.assert_allclose(v1, v2, rtol=2e-5, atol=2e-5)
+    assert g1[1].shape == (1, 2, 256, 32)
+    for a, b, name in zip(g1, g2, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-4,
+                                   atol=2e-5, err_msg=f"d{name}")
+
+
 def test_auto_chunk_dispatch(monkeypatch):
     """The S*D*itemsize budget dispatch really selects the chunked path
     (and its chunk satisfies the divisibility constraints) — exercised in
@@ -595,6 +618,8 @@ def _window_case(S, H, Hkv, W, block_q, block_k, chunk, dtype=jnp.float32,
     (512, 6, 1, 130, 64, 64, 128),      # GQA 6:1, band across chunk edges
     (256, 8, 1, 48, 64, 64, None),      # GQA 8:1
     (192, 3, 1, 40, 64, 64, None),      # S no power of two, odd head count
+    (512, 7, 1, 288, 64, 64, 64),       # GQA 7:1, a band of 5-6 chunks
+    (384, 14, 2, 200, 64, 64, 128),     # 2 KV heads x 7, band over 3 chunks
 ], ids=lambda v: str(v))
 def test_window_kernels_match_the_masked_reference(S, H, Hkv, W, block_q,
                                                    block_k, chunk):
@@ -631,6 +656,7 @@ def test_a_window_that_covers_the_sequence_is_causal_attention(W):
     (16384, 512, 256, 256, 3, 3),       # the cell's: 3 of 64 chunks
     (16384, 512, 128, 128, 5, 5),
     (16384, 512, 512, 512, 2, 2),
+    (16384, 4096, 512, 512, 9, 9),      # SmallThinker's: 9 of 32 chunks
 ])
 def test_window_grid_walks_the_static_band_count(S, W, block, chunk, fwd,
                                                  dkv):
@@ -660,6 +686,8 @@ def test_window_grid_walks_the_static_band_count(S, W, block, chunk, fwd,
     if (S, W) == (16384, 512):
         assert over == pytest.approx({512: 2.0, 256: 1.5, 128: 1.25}[block],
                                      abs=0.02)
+    if (S, W) == (16384, 4096):
+        assert over == pytest.approx(1.125, abs=0.005)
 
 
 def test_window_overcompute_counts_blocks_over_the_band():

@@ -207,11 +207,18 @@ def test_flash_attention_chunked_fwd_and_grad_compile_at_olmoe_shape():
     assert_dense_lse_kept(hlo, flash_calls(hlo), "f32[64,32,1,128]")
 
 
-def test_grouped_matmul_fwd_and_grads_compile_at_olmoe_shape():
+@pytest.mark.parametrize("m,g,k,n", [
+    (131072, 64, 2048, 1024),       # OLMoE's layer
+    (49152, 16, 2560, 768),         # SmallThinker's slab, gate / up ...
+    (49152, 16, 768, 2560),         # ... and down: no powers of two
+], ids=["olmoe", "smallthinker_up", "smallthinker_down"])
+def test_grouped_matmul_fwd_and_grads_compile_at_olmoe_shape(m, g, k, n):
     """131,072 routed rows x 2048 against 64 experts' [2048, 1024]: the
     forward product and both gradients (dlhs against the transposed weights,
     drhs the per-group outer products), three Pallas calls, each under a
-    ``moe_gmm*`` scope; the group sizes are an operand, not a shape."""
+    ``moe_gmm*`` scope; the group sizes are an operand, not a shape. And at
+    widths of 5 x 512 and 3 x 256, with the divisor tiles ``_clip`` gives
+    them (PR 38)."""
     from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
     def bank(lhs, rhs, sizes):
@@ -226,8 +233,7 @@ def test_grouped_matmul_fwd_and_grads_compile_at_olmoe_shape():
                                   argnums=(0, 1))(lhs, rhs)
 
     text, compiled = compile_on_chip(
-        grads, SDS((131072, 2048), BF16), SDS((64, 2048, 1024), BF16),
-        SDS((64,), I32))
+        grads, SDS((m, k), BF16), SDS((g, k, n), BF16), SDS((g,), I32))
     assert text.count("tpu_custom_call") == 3
     hlo = compiled.as_text()
     for scope in ("moe_gmm/", "moe_gmm_dlhs/", "moe_gmm_drhs/"):
