@@ -32,11 +32,24 @@ Three kernel families share the same per-tile math (`_fwd_block_step` /
   tiles wholly off the diagonal run unmasked at full block width. The
   softmax state and the backward's dq/dk/dv accumulators are fp32 VMEM
   scratch; lse and delta travel lane-dense; dq leaves as the input dtype.
-- **chunked**: a third grid dimension streams sequence CHUNKS and
-  accumulates into revisited fp32 output blocks (the forward's softmax
-  m/l state in fp32 VMEM scratch; normalization happens in-kernel on the
-  last chunk, which also writes lse lane-dense, [BH, S / 128, 1, 128] as
-  the whole-row kernels store it — a [BH, S, 1] column is 128 x its
+- **chunked**: the grid is (B*H, PAIRS) — its second dimension walks a
+  list of the (grid block, sequence chunk) pairs that hold work, built with
+  numpy at trace time (``_pair_walk``: two int32 arrays, scalar-prefetch
+  operands that every index map reads its block and chunk from), a block's
+  pairs consecutive and its chunks ascending, so each step streams one
+  CHUNK and accumulates into a revisited fp32 output block. Under a causal
+  mask the list leaves out the pairs wholly above the diagonal (forward and
+  dq: chunks past the query block's own; dkv: query chunks before the key
+  block), which on a rectangular (S / block, S / chunk) grid were steps
+  with empty loops that still fetched their chunk, 0.64-1.5 us each on a
+  v5e: 272 pairs where 512 steps stood at S 16,384 / 512 / 1,024, 136 of
+  256 at S 8,192 / 512 / 512, 20 of 32 at S 4,096 (PERF.md Findings PR 39;
+  gauge ``attention/flash_grid_steps_walked_share``); a call that is not
+  causal walks the rectangle, and a band would be a third list for the
+  same kernels. The forward's softmax m/l state lives in fp32 VMEM
+  scratch; normalization happens in-kernel on a block's last chunk, which
+  also writes lse lane-dense ([BH, S / 128, 1, 128] as the whole-row
+  kernels store it — a [BH, S, 1] column is 128 x its
   values' size in HBM, which kept a rematted block from holding it:
   PERF.md, PR 34; the backward kernels turn a block's rows of lse and
   delta back into columns in VMEM, ``_stat_col``). This is how
@@ -888,18 +901,60 @@ def _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l, last):
             lse_ref[0, j] = _dense_row(lse[j * piece:(j + 1) * piece])
 
 
-def _fwd_kernel_chunked(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                        *, scale, causal, block_q, block_k, chunk,
-                        n_chunks):
-    qi = pl.program_id(1)
-    kc = pl.program_id(2)
+def _walk_ends(i, block, chunk, n_chunks, causal, keys):
+    """(first, last) sequence chunk of grid block ``i``'s walk in the chunked
+    kernels. ``keys``: the block is ``block`` query rows and the walk the key
+    chunks it sees (forward, dq) — under a causal mask from chunk 0 to the
+    one that holds the block's diagonal; else the block is key rows and the
+    walk the query chunks that see it (dkv) — from the block's own chunk to
+    the last. Every chunk where nothing is masked. ``i`` a Python int
+    (``_pair_walk`` builds the grid from this) or traced (the kernels tell a
+    walk's first and last grid step by it)."""
+    if not causal:
+        return 0, n_chunks - 1
+    if keys:
+        return 0, ((i + 1) * block - 1) // chunk
+    return (i * block) // chunk, n_chunks - 1
+
+
+@functools.lru_cache(maxsize=None)
+def _pair_walk(S, block, chunk, causal, keys):
+    """The second grid dimension of a chunked kernel: the (block, chunk)
+    pairs that hold work, as two int32 arrays (``i_of``, ``c_of``) indexed
+    by grid step, in the order a rectangular (S / block, S / chunk) grid
+    visits them — a block's pairs consecutive and its chunks ascending
+    (``_walk_ends``), so a revisited output block and the VMEM scratch
+    accumulate over one unbroken run of steps. A causal call leaves out the
+    pairs wholly above the diagonal: 272 of 512 at S 16,384 with blocks of
+    512 and chunks of 1,024, 136 of 256 at S 8,192 / 512 / 512, 20 of 32 at
+    S 4,096 / 512 / 1,024 (a grid step with an empty loop still fetched its
+    chunk and cost 0.6-1 us: PERF.md, PR 39); a call that is not causal
+    walks the rectangle. Built with numpy at trace time, once a plan, and
+    handed to the call as scalar-prefetch operands."""
+    blocks, chunks = [], []
+    for i in range(S // block):
+        first, last = _walk_ends(i, block, chunk, S // chunk, causal, keys)
+        blocks += [i] * (last - first + 1)
+        chunks += range(first, last + 1)
+    walk = np.asarray(blocks, np.int32), np.asarray(chunks, np.int32)
+    for x in walk:              # cached: shared by every call of the plan
+        x.flags.writeable = False
+    return walk
+
+
+def _fwd_kernel_chunked(i_of, c_of, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                        m_ref, l_ref, *, scale, causal, block_q, block_k,
+                        chunk, n_chunks):
+    t = pl.program_id(1)
+    qi, kc = i_of[t], c_of[t]
+    first, last = _walk_ends(qi, block_q, chunk, n_chunks, causal, True)
     cb = chunk // block_k                      # k-blocks per chunk
     fold = _scale_folds(scale)
     s_scale = None if fold else scale
     q = q_ref[0] * scale if fold else q_ref[0]
     rel = _rel_pos(block_q, block_k) if causal else None
 
-    @pl.when(kc == 0)
+    @pl.when(kc == first)
     def _init():
         o_ref[0] = jnp.zeros_like(o_ref[0])
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
@@ -921,60 +976,90 @@ def _fwd_kernel_chunked(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         o, m, l = _causal_split_loop(0, j_full, j_hi, body, carry0)
     else:
         o, m, l = _causal_split_loop(0, cb, cb, body, carry0)
-    _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l,
-                        kc == n_chunks - 1)
+    _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l, kc == last)
 
 
-def _chunked_fwd_outputs(q, block_q, block_k):
+def _chunked_fwd_outputs(q, block_q, block_k, block_of):
     """(out_specs, out_shape, scratch_shapes) of the chunked and the window
-    forward on grid (BH, q blocks, chunks): o [BH, S, D] float32, revisited
-    over a block's walk; lse [BH, S / piece, 1, piece] float32, lane-dense
-    as the whole-row kernels store it (``_stat_piece``), written on the
-    walk's last step; the running m and l, [block_q, 128] VMEM scratch."""
+    forward, whose grid steps walk a query block's chunks (``block_of``: the
+    grid's arguments -> (row of BH, query block)): o [BH, S, D] float32,
+    revisited over a block's walk; lse [BH, S / piece, 1, piece] float32,
+    lane-dense as the whole-row kernels store it (``_stat_piece``), written
+    on the walk's last step; the running m and l, [block_q, 128] VMEM
+    scratch."""
     BH, S, D = q.shape
     piece = _stat_piece(block_q, block_k)
     return (
-        [pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-         _stat_spec(block_q, piece, lambda b, i, c: (b, i))],
+        [_rows_spec(block_q, D, block_of),
+         _stat_spec(block_q, piece, block_of)],
         [jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
          jax.ShapeDtypeStruct((BH, S // piece, 1, piece), jnp.float32)],
         [pltpu.VMEM((block_q, _LANES), jnp.float32),
          pltpu.VMEM((block_q, _LANES), jnp.float32)])
 
 
+def _of_block(b, t, i_of, c_of):
+    """(row of BH, grid block) of step ``t`` of a ``_pair_walk`` grid."""
+    return b, i_of[t]
+
+
+def _of_chunk(b, t, i_of, c_of):
+    """(row of BH, sequence chunk) of step ``t`` of a ``_pair_walk`` grid."""
+    return b, c_of[t]
+
+
+def _rows_spec(rows, D, index, row=lambda b: b):
+    """BlockSpec of ``rows`` rows of a [BH, S, D] operand: ``index`` maps the
+    grid to (row of BH, block of ``rows``), ``row`` that row of BH to the
+    operand's own (``_kv_row`` for K and V)."""
+    def at(*g):
+        b, i = index(*g)
+        return row(b), i, 0
+    return pl.BlockSpec((1, rows, D), at)
+
+
+def _pair_call(kernel, walk, BH, in_specs, out_specs, out_shape, scratch,
+               interpret):
+    """``pallas_call`` of a chunked kernel on grid (BH, pairs of ``walk``):
+    ``_pair_walk``'s two arrays are the call's first two operands, and every
+    index map reads its (block, chunk) from them — ``(b, t, i_of, c_of)``."""
+    call = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(BH, len(walk[0])),
+            in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
+        out_shape=out_shape,
+        interpret=interpret,
+    )
+    return functools.partial(call, *walk)
+
+
 def _flash_fwd_chunked(q, k, v, scale, causal, block_q, block_k, chunk,
                        interpret, heads=0, kv_heads=0):
     BH, S, D = q.shape
-    n_chunks = S // chunk
     kv = _kv_row(heads, kv_heads)
-    out_specs, out_shape, scratch = _chunked_fwd_outputs(q, block_q, block_k)
+    out_specs, out_shape, scratch = _chunked_fwd_outputs(q, block_q, block_k,
+                                                         _of_block)
     kernel = functools.partial(_fwd_kernel_chunked, scale=scale,
                                causal=causal, block_q=block_q,
                                block_k=block_k, chunk=chunk,
-                               n_chunks=n_chunks)
-    call = pl.pallas_call(
-        kernel,
-        grid=(BH, S // block_q, n_chunks),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, chunk, D), lambda b, i, c: (kv(b), c, 0)),
-            pl.BlockSpec((1, chunk, D), lambda b, i, c: (kv(b), c, 0)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )
+                               n_chunks=S // chunk)
+    call = _pair_call(
+        kernel, _pair_walk(S, block_q, chunk, causal, True), BH,
+        [_rows_spec(block_q, D, _of_block)]
+        + [_rows_spec(chunk, D, _of_chunk, kv)] * 2,
+        out_specs, out_shape, scratch, interpret)
     with annotate("flash_fwd_chunk"):
         o32, lse = call(q, k, v)
     return o32.astype(q.dtype), lse
 
 
-def _bwd_dq_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           dq_ref, *, scale, causal, block_q, block_k,
-                           chunk, n_chunks):
-    qi = pl.program_id(1)
-    kc = pl.program_id(2)
+def _bwd_dq_kernel_chunked(i_of, c_of, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                           delta_ref, dq_ref, *, scale, causal, block_q,
+                           block_k, chunk, n_chunks):
+    t = pl.program_id(1)
+    qi, kc = i_of[t], c_of[t]
+    first, last = _walk_ends(qi, block_q, chunk, n_chunks, causal, True)
     cb = chunk // block_k
     fold = _scale_folds(scale)
     s_scale = None if fold else scale
@@ -984,7 +1069,7 @@ def _bwd_dq_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     delta = _stat_col(delta_ref, (0,), 0, block_q)
     rel = _rel_pos(block_q, block_k) if causal else None
 
-    @pl.when(kc == 0)
+    @pl.when(kc == first)
     def _init():
         dq_ref[0] = jnp.zeros_like(dq_ref[0])
 
@@ -1005,16 +1090,17 @@ def _bwd_dq_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq = _causal_split_loop(0, j_full, j_hi, body, dq_ref[0])
     else:
         dq = _causal_split_loop(0, cb, cb, body, dq_ref[0])
-    # accumulate UNscaled across chunk revisits; apply the folded-scale
-    # chain rule once on the final chunk (dq = scale · Σ ds·k)
-    dq_ref[0] = jnp.where(pl.program_id(2) == n_chunks - 1, dq * scale, dq)
+    # accumulate UNscaled across a block's walk; apply the folded-scale
+    # chain rule once on its last chunk (dq = scale · Σ ds·k)
+    dq_ref[0] = jnp.where(kc == last, dq * scale, dq)
 
 
-def _bwd_dkv_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                            dk_ref, dv_ref, *, scale, causal, block_q,
-                            block_k, chunk, n_chunks):
-    ki = pl.program_id(1)
-    qc = pl.program_id(2)
+def _bwd_dkv_kernel_chunked(i_of, c_of, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                            delta_ref, dk_ref, dv_ref, *, scale, causal,
+                            block_q, block_k, chunk, n_chunks):
+    t = pl.program_id(1)
+    ki, qc = i_of[t], c_of[t]
+    first, last = _walk_ends(ki, block_k, chunk, n_chunks, causal, False)
     cb = chunk // block_q
     fold = _scale_folds(scale)
     s_scale = None if fold else scale
@@ -1022,7 +1108,7 @@ def _bwd_dkv_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     v = v_ref[0]
     rel = _rel_pos(block_q, block_k) if causal else None
 
-    @pl.when(qc == 0)
+    @pl.when(qc == first)
     def _init():
         dk_ref[0] = jnp.zeros_like(dk_ref[0])
         dv_ref[0] = jnp.zeros_like(dv_ref[0])
@@ -1060,10 +1146,9 @@ def _bwd_dkv_kernel_chunked(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             j_mid, cb, lambda j, c: body(j, c, False), carry)
     else:
         dk, dv = _causal_split_loop(0, cb, cb, body, carry0)
-    # dk accumulates UNscaled across chunk revisits; the folded-scale
-    # chain rule (dk = scale·Σ dsᵀ·q) lands once on the final chunk
-    dk_ref[0] = dk if fold else jnp.where(qc == n_chunks - 1, dk * scale,
-                                          dk)
+    # dk accumulates UNscaled across a block's walk; the folded-scale
+    # chain rule (dk = scale·Σ dsᵀ·q) lands once on its last chunk
+    dk_ref[0] = dk if fold else jnp.where(qc == last, dk * scale, dk)
     dv_ref[0] = dv
 
 
@@ -1073,51 +1158,32 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     read a query head's group through ``_kv_row``; dk and dv still come
     back per QUERY head ([B * heads, S, D]) for the caller to sum."""
     BH, S, D = q.shape
-    n_chunks = S // chunk
     kv = _kv_row(heads, kv_heads)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1).reshape(lse.shape)
     piece = lse.shape[-1]
-
-    call_dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel_chunked, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, chunk=chunk,
-                          n_chunks=n_chunks),
-        grid=(BH, S // block_q, n_chunks),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, chunk, D), lambda b, i, c: (kv(b), c, 0)),
-            pl.BlockSpec((1, chunk, D), lambda b, i, c: (kv(b), c, 0)),
-            pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-        ] + [_stat_spec(block_q, piece, lambda b, i, c: (b, i))] * 2,
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
-        interpret=interpret,
-    )
+    static = dict(scale=scale, causal=causal, block_q=block_q,
+                  block_k=block_k, chunk=chunk, n_chunks=S // chunk)
+    grads = jax.ShapeDtypeStruct((BH, S, D), jnp.float32)
+    call_dq = _pair_call(
+        functools.partial(_bwd_dq_kernel_chunked, **static),
+        _pair_walk(S, block_q, chunk, causal, True), BH,
+        [_rows_spec(block_q, D, _of_block)]
+        + [_rows_spec(chunk, D, _of_chunk, kv)] * 2
+        + [_rows_spec(block_q, D, _of_block)]
+        + [_stat_spec(block_q, piece, _of_block)] * 2,
+        _rows_spec(block_q, D, _of_block), grads, (), interpret)
     with annotate("flash_bwd_dq"):
         dq = call_dq(q, k, v, do, lse, delta)
 
-    call_dkv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel_chunked, scale=scale,
-                          causal=causal, block_q=block_q, block_k=block_k,
-                          chunk=chunk, n_chunks=n_chunks),
-        grid=(BH, S // block_k, n_chunks),
-        in_specs=[
-            pl.BlockSpec((1, chunk, D), lambda b, i, c: (b, c, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, c: (kv(b), i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, c: (kv(b), i, 0)),
-            pl.BlockSpec((1, chunk, D), lambda b, i, c: (b, c, 0)),
-        ] + [_stat_spec(chunk, piece, lambda b, i, c: (b, c))] * 2,
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, c: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
-            jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
-        ],
-        interpret=interpret,
-    )
+    call_dkv = _pair_call(
+        functools.partial(_bwd_dkv_kernel_chunked, **static),
+        _pair_walk(S, block_k, chunk, causal, False), BH,
+        [_rows_spec(chunk, D, _of_chunk)]
+        + [_rows_spec(block_k, D, _of_block, kv)] * 2
+        + [_rows_spec(chunk, D, _of_chunk)]
+        + [_stat_spec(chunk, piece, _of_chunk)] * 2,
+        [_rows_spec(block_k, D, _of_block)] * 2, [grads] * 2, (), interpret)
     with annotate("flash_bwd_dkv"):
         dk, dv = call_dkv(q, k, v, do, lse, delta)
     return dq.astype(q.dtype), dk.astype(q.dtype), dv.astype(q.dtype)
@@ -1246,7 +1312,8 @@ def _swa_fwd(q, k, v, scale, window, block_q, block_k, chunk, interpret,
     BH, S, D = q.shape
     n_band = _band_extent(S, block_q, chunk, window, keys=True)
     band = _band_kv_map(_kv_row(heads, kv_heads), block_q, chunk, n_band)
-    out_specs, out_shape, scratch = _chunked_fwd_outputs(q, block_q, block_k)
+    out_specs, out_shape, scratch = _chunked_fwd_outputs(
+        q, block_q, block_k, lambda b, i, c: (b, i))
     call = pl.pallas_call(
         functools.partial(_swa_fwd_kernel, scale=scale, window=window,
                           block_q=block_q, block_k=block_k, chunk=chunk,
@@ -1554,17 +1621,31 @@ def window_tile_overcompute(S, block_q, block_k, window):
     return (over_q + over_k) / (2 * (S * window - window * (window - 1) // 2))
 
 
+def grid_steps_walked(S, block_q, block_k, chunk, causal):
+    """(grid steps a head of the three chunked kernels of one call —
+    forward, dq, dkv: ``_pair_walk`` — and of the rectangular
+    (S / block, S / chunk) grids those would be): 816 of 1,536 at S 16,384
+    with blocks of 512 and chunks of 1,024."""
+    over_keys = len(_pair_walk(S, block_q, chunk, causal, True)[0])
+    over_queries = len(_pair_walk(S, block_k, chunk, causal, False)[0])
+    return (2 * over_keys + over_queries,
+            (2 * (S // block_q) + S // block_k) * (S // chunk))
+
+
 _plans_logged = set()
 
 
 def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
                heads_per_block=0, window=0):
     """Trace-time engagement record of one flash call: the gauges
-    ``attention/flash_tile_overcompute`` and
+    ``attention/flash_tile_overcompute``,
     ``attention/flash_heads_per_block`` (heads a 128-lane column block of
-    [B, S, H*D] operands; 0 for a head-major call) and, once per distinct
-    shape, a log line of the layout (the operands' and the log-sum-exp's)
-    and loop structure chosen for it.
+    [B, S, H*D] operands; 0 for a head-major call) and, for a chunked call,
+    ``attention/flash_grid_steps_walked_share`` (``grid_steps_walked``: grid
+    steps of its three kernels over those of the rectangular grid — 0.531
+    causal at S 16,384 / 512 / 1,024, 1.0 where nothing is masked) and,
+    once per distinct shape, a log line of the layout (the operands' and
+    the log-sum-exp's) and loop structure chosen for it.
     ``window``: a call of the window kernels — the gauge
     ``attention/window_tile_overcompute`` and the band's plan instead."""
     piece = _stat_piece(block_q, block_k)
@@ -1591,6 +1672,14 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
     default_registry().gauge("attention/flash_tile_overcompute").set(over)
     default_registry().gauge("attention/flash_heads_per_block").set(
         heads_per_block)
+    walked = ""
+    if chunk:
+        steps, rectangle = grid_steps_walked(S, block_q, block_k, chunk,
+                                             causal)
+        default_registry().gauge(
+            "attention/flash_grid_steps_walked_share").set(steps / rectangle)
+        walked = (f" ({steps} of {rectangle} (block, chunk) pairs walked, "
+                  "forward + dq + dkv)")
     plan = (S, D, jnp.dtype(dtype).name, causal, block_q, block_k, chunk,
             heads_per_block)
     if plan not in _plans_logged:
@@ -1604,7 +1693,7 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
             f"flash attention S={S} D={D} {plan[2]} causal={causal}: "
             f"layout {layout}, "
             f"block_q={block_q} block_k={block_k} strip={strip} "
-            f"chunk={chunk} scale "
+            f"chunk={chunk}{walked} scale "
             f"{'on q' if _scale_folds(scale) else 'on scores'}"
             f", computes {over:.3f} x the scores needed")
 

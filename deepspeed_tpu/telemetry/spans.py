@@ -164,12 +164,15 @@ def annotate(tag):
     models/laguna.py has ``attn``, ``mlp`` and the three norms) are the
     detail table's remaining tags.
 
-    Beside the scopes, the flash kernels leave two trace-time GAUGES in
-    the registry, ``attention/flash_tile_overcompute`` (score elements
+    Beside the scopes, the flash kernels leave trace-time GAUGES in
+    the registry: ``attention/flash_tile_overcompute`` (score elements
     the chosen loops compute over those the softmax needs: whether the
     strip walk engaged for a shape) and
     ``attention/flash_heads_per_block`` (heads a 128-lane column block of
-    the model's own [B, S, H*D] operands, 0 for a head-major call), and
+    the model's own [B, S, H*D] operands, 0 for a head-major call), the
+    chunked kernels a third, ``attention/flash_grid_steps_walked_share``
+    (grid steps of their (block, chunk) pair lists over the rectangular
+    grid's: 0.531 causal at S 16,384, 1.0 where nothing is masked), and
     the gated delta rule two, ``linear_attn/gdn_kernel_heads_per_step``
     (value heads a grid step of its kernels; 0: the XLA form took the
     call) and ``linear_attn/gdn_states_kept_every`` (chunks between the

@@ -1,9 +1,12 @@
 """Questions the ZeRO tests ask of a compiled step's HLO text (CPU or TPU
 compiler alike): which computations run inside a ``while`` loop — a layer
 scan's body and whatever it calls — and which collectives sit there; and
-of any step under remat: which forward attention kernels it runs again."""
+of any step under remat: which forward attention kernels it runs again.
+And one question of a jaxpr: the grids its Pallas calls run on."""
 
 import re
+
+import jax
 
 _HEAD = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
 _CALLEE = re.compile(
@@ -118,3 +121,16 @@ def remat_report(loss, params, capsys):
     jax.ad_checkpoint.print_saved_residuals(loss, params)
     return (rematted_forward_attention(lowered.as_text(debug_info=True)),
             capsys.readouterr().out, lowered)
+
+
+def pallas_grids(jaxpr):
+    """The grid of every ``pallas_call`` of a jaxpr and of the jaxprs inside
+    it (a remat, a custom VJP's rules), in program order."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                grids += pallas_grids(sub)
+    return grids

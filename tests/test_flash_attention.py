@@ -13,6 +13,7 @@ from deepspeed_tpu.ops.attention import (from_head_major,
                                          reference_attention, to_head_major)
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.blocksparse import blocksparse_attention
+from tests.hlo_text import pallas_grids
 
 
 def _qkv(shape=(2, 2, 128, 32), seed=0, dtype=jnp.float32):
@@ -673,9 +674,7 @@ def test_window_grid_walks_the_static_band_count(S, W, block, chunk, fwd,
     jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, causal=True, window=W, block_q=block, block_k=block,
         chunk=chunk, interpret=True)), argnums=(0, 1, 2)))(q, kv, kv)
-    grids = sorted(
-        eqn.params["grid_mapping"].grid for eqn in jaxpr.eqns
-        if eqn.primitive.name == "pallas_call")
+    grids = sorted(pallas_grids(jaxpr.jaxpr))
     assert grids == sorted([
         (H, S // block, fwd), (H, S // block, fwd),
         (Hkv, S // block, (H // Hkv) * dkv)]), grids
@@ -749,6 +748,178 @@ def test_dot_product_attention_passes_the_window_through_its_shard_map():
 
 
 # ------------------------------------------------------------------------
+# the chunked kernels' grid (ISSUE 39): (B*H, pairs) — the (block, chunk)
+# pairs that hold work, read by the index maps from two scalar-prefetch
+# arrays; a causal call leaves out the pairs above the diagonal
+
+@pytest.mark.parametrize("H,Hkv,S,D,causal,block_q,block_k,chunk", [
+    (2, 2, 256, 16, True, 64, 64, 128),     # block < chunk: two tiles a step
+    (2, 2, 256, 16, True, 64, 64, 64),      # block == chunk: Qwen3-Next's
+    (2, 2, 256, 16, True, 64, 32, 128),     # block_q != block_k
+    (2, 2, 256, 16, True, 32, 64, 64),
+    (2, 2, 256, 16, False, 64, 64, 128),    # nothing masked: the rectangle
+    (2, 2, 256, 16, False, 32, 64, 64),
+    (6, 1, 256, 16, True, 64, 64, 128),     # Laguna's full layers' groups
+    (7, 1, 256, 16, True, 64, 64, 64),      # SmallThinker's
+    (8, 1, 256, 16, True, 64, 64, 128),     # Qwen3-Next's
+    (8, 2, 128, 32, False, 32, 32, 64),
+], ids=lambda v: str(v))
+def test_pair_list_kernels_match_reference(H, Hkv, S, D, causal, block_q,
+                                           block_k, chunk):
+    """Forward and all three gradients of the chunked kernels on their
+    pair-list grid against the reference, over the grid's forms (several
+    tiles a step, one, unequal blocks, masked and not) and the cells'
+    grouped-query ratios."""
+    q, _, _ = _qkv((1, H, S, D), seed=H + S)
+    _, k, v = _qkv((1, Hkv, S, D), seed=H + S + 1)
+
+    def both(attend):
+        return (attend(q, k, v),) + jax.grad(
+            lambda *a: jnp.sum(jnp.sin(attend(*a))), argnums=(0, 1, 2))(
+            q, k, v)
+
+    got = both(functools.partial(flash_attention, causal=causal,
+                                 block_q=block_q, block_k=block_k,
+                                 chunk=chunk, interpret=True))
+    want = both(functools.partial(reference_attention, causal=causal))
+    for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        assert a.shape == b.shape
+        fwd = name == "out"
+        np.testing.assert_allclose(a, b, rtol=2e-4 if fwd else 5e-3,
+                                   atol=2e-5 if fwd else 5e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("dtype,block_q,block_k,chunk", [
+    (jnp.float32, 64, 64, 128), (jnp.bfloat16, 64, 32, 128),
+    (jnp.float32, 64, 64, 64)], ids=lambda v: str(v))
+def test_pair_list_skips_only_steps_that_did_nothing(dtype, block_q, block_k,
+                                                     chunk, monkeypatch):
+    """o, dq, dk, dv of a causal call are BIT-equal to the same tile math
+    walked over the whole rectangle (what the grid was before ISSUE 39: the
+    steps above the diagonal run empty loops, a walk's first and last step
+    are where they were), so the pair list changes no arithmetic and no
+    order of accumulation."""
+    fa = _fa()
+    q, _, _ = _qkv((1, 4, 256, 32), seed=39, dtype=dtype)
+    _, k, v = _qkv((1, 2, 256, 32), seed=40, dtype=dtype)
+
+    def run():
+        o, vjp = jax.vjp(functools.partial(
+            flash_attention, causal=True, block_q=block_q, block_k=block_k,
+            chunk=chunk, interpret=True), q, k, v)
+        return (o,) + vjp(jnp.cos(o.astype(jnp.float32)).astype(dtype))
+
+    got = run()
+    pairs = fa._pair_walk
+    monkeypatch.setattr(fa, "_pair_walk", lambda S, block, chunk, causal,
+                        keys: pairs(S, block, chunk, False, keys))
+    rectangle = run()
+    assert len(fa._pair_walk(256, block_q, chunk, True, True)[0]) \
+        == (256 // block_q) * (256 // chunk)
+    for a, b, name in zip(got, rectangle, ("out", "dq", "dk", "dv")):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32), err_msg=name)
+
+
+@pytest.mark.parametrize("S,block,chunk,causal,pairs", [
+    (16384, 512, 1024, True, 272),      # Laguna, SmallThinker: of 512
+    (8192, 512, 512, True, 136),        # Qwen3-Next: of 256
+    (4096, 512, 1024, True, 20),        # OLMoE: of 32
+    (16384, 512, 1024, False, 512),     # nothing masked: the rectangle
+    (8192, 512, 512, False, 256),
+    (4096, 512, 1024, False, 32),
+])
+def test_chunked_grid_is_the_pair_list(S, block, chunk, causal, pairs):
+    """The three chunked ``pallas_call``s run on grid (B*H, pairs) — two
+    dimensions, the second the cells' 272 / 136 / 20 pairs under a causal
+    mask and the rectangle's count without one — and the gauge
+    ``attention/flash_grid_steps_walked_share`` is their sum over the
+    rectangle's."""
+    from deepspeed_tpu.telemetry.registry import default_registry
+    H, Hkv = 4, 2
+    q = jax.ShapeDtypeStruct((1, H, S, 16), jnp.float32)
+    kv = jax.ShapeDtypeStruct((1, Hkv, S, 16), jnp.float32)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=causal, block_q=block, block_k=block, chunk=chunk,
+        interpret=True)), argnums=(0, 1, 2)))(q, kv, kv)
+    assert pallas_grids(jaxpr.jaxpr) == [(H, pairs)] * 3
+    rectangle = (S // block) * (S // chunk)
+    assert default_registry().peek_gauge(
+        "attention/flash_grid_steps_walked_share") == pytest.approx(
+        pairs / rectangle)
+    assert _fa().grid_steps_walked(S, block, block, chunk, causal) \
+        == (3 * pairs, 3 * rectangle)
+
+
+@pytest.mark.parametrize("S,block,chunk", [
+    (256, 64, 128), (256, 64, 64), (256, 32, 128), (512, 128, 256),
+    (384, 128, 128), (16384, 512, 1024)])
+@pytest.mark.parametrize("keys", [True, False], ids=["fwd_dq", "dkv"])
+def test_pair_walk_holds_every_pair_with_a_visible_score(S, block, chunk,
+                                                         keys):
+    """``_pair_walk``'s causal list: a (block, chunk) pair is in it exactly
+    when some query of the pair sees some key of it, a block's pairs are
+    consecutive with chunks ascending from ``_walk_ends``'s first to its
+    last, and blocks ascend; without a mask it is the rectangle in the
+    rectangular grid's order."""
+    fa = _fa()
+    i_of, c_of = fa._pair_walk(S, block, chunk, True, keys)
+    assert i_of.dtype == c_of.dtype == np.int32
+    walked = list(zip(i_of.tolist(), c_of.tolist()))
+    visible = set()
+    for i in range(S // block):
+        for c in range(S // chunk):
+            # the block's rows [i * block, (i + 1) * block) are queries and
+            # the chunk's keys (forward, dq), or the other way round (dkv)
+            rows = range(i * block, (i + 1) * block)
+            span = range(c * chunk, (c + 1) * chunk)
+            queries, seen = (rows, span) if keys else (span, rows)
+            if queries[-1] >= seen[0]:  # the last query sees the first key
+                visible.add((i, c))
+    assert set(walked) == visible and len(walked) == len(visible)
+    assert walked == sorted(walked)     # blocks ascend, chunks within them
+    for i in range(S // block):
+        mine = [c for b, c in walked if b == i]
+        first, last = fa._walk_ends(i, block, chunk, S // chunk, True, keys)
+        assert mine == list(range(first, last + 1)) and mine
+    full = fa._pair_walk(S, block, chunk, False, keys)
+    assert list(zip(*map(np.ndarray.tolist, full))) == [
+        (i, c) for i in range(S // block) for c in range(S // chunk)]
+
+
+def test_pair_walk_is_built_once_a_plan_and_logged(caplog):
+    """The lists are cached per (S, block, chunk, causal, walk) — a second
+    trace of a plan builds nothing — and the plan's log line names the pairs
+    walked beside ``chunk=``."""
+    import logging
+    fa = _fa()
+    fa._pair_walk.cache_clear()
+    fa._plans_logged.clear()
+    q = jax.ShapeDtypeStruct((1, 2, 512, 16), jnp.float32)
+
+    def trace():
+        jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, block_q=64, block_k=64, chunk=128,
+            interpret=True)), argnums=(0, 1, 2)))(q, q, q)
+
+    from deepspeed_tpu.utils.logging import logger
+    logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.INFO, logger=logger.name):
+            trace()
+            built = fa._pair_walk.cache_info().misses
+            trace()
+    finally:
+        logger.removeHandler(caplog.handler)
+    assert built == 2                   # the keys' walk and the queries'
+    assert fa._pair_walk.cache_info().misses == built
+    lines = [r.getMessage() for r in caplog.records
+             if "flash attention S=512" in r.getMessage()]
+    assert len(lines) == 1, lines
+    # 8 blocks x 4 chunks: 2 x (1 + 1 + 2 + 2 + 3 + 3 + 4 + 4) = 20 of 32
+    assert "chunk=128 (60 of 96 (block, chunk) pairs walked" in lines[0]
+
+
 # the log-sum-exp the chunked and the window kernels hand the backward pass
 # (ISSUE 34): lane-dense, so that a rematted block can afford to keep it
 

@@ -102,6 +102,11 @@ def kernel_names(text):
     return set(re.findall(r'kernel_name = "([^"]+)"', text))
 
 
+def pallas_grids(fn, *shapes):
+    """The grid of every ``pallas_call`` that tracing ``fn`` reaches."""
+    return hlo_text.pallas_grids(jax.make_jaxpr(fn)(*shapes).jaxpr)
+
+
 def flash_calls(hlo):
     """The compiled text's Pallas calls, cut before their serialized
     bodies: result shapes, operands and their layout constraints."""
@@ -203,6 +208,9 @@ def test_flash_attention_chunked_fwd_and_grad_compile_at_olmoe_shape():
     assert kernel_names(text) == {"_fwd_kernel_chunked",
                                   "_bwd_dq_kernel_chunked",
                                   "_bwd_dkv_kernel_chunked"}
+    # two grid dimensions: the heads' rows, and the 20 (block, chunk) pairs
+    # of 8 x 4 that a causal row needs (ISSUE 39)
+    assert pallas_grids(grads, *qkv) == [(64, 20)] * 3
     hlo = compiled.as_text()
     assert_dense_lse_kept(hlo, flash_calls(hlo), "f32[64,32,1,128]")
 
@@ -592,18 +600,55 @@ def test_flash_attention_chunked_gqa_compiles_at_qwen3_next_shape():
         return jax.grad(rematted(lambda *a: flash_attention(
             *a, causal=True).astype(F32).sum()), argnums=(0, 1, 2))(q, k, v)
 
-    text, compiled = compile_on_chip(
-        grads, SDS((2, 16, 8192, 256), BF16), SDS((2, 2, 8192, 256), BF16),
-        SDS((2, 2, 8192, 256), BF16))
+    shapes = (SDS((2, 16, 8192, 256), BF16), SDS((2, 2, 8192, 256), BF16),
+              SDS((2, 2, 8192, 256), BF16))
+    text, compiled = compile_on_chip(grads, *shapes)
     assert kernel_names(text) == {"_fwd_kernel_chunked",
                                   "_bwd_dq_kernel_chunked",
                                   "_bwd_dkv_kernel_chunked"}
+    # 136 of the 16 x 16 (block, chunk) pairs, at one block a chunk
+    assert pallas_grids(grads, *shapes) == [(32, 136)] * 3
     # every Pallas call reads K and V at 4 = 2 x 2 rows, none at 32
     hlo = compiled.as_text()
     calls = flash_calls(hlo)
     assert len(calls) == 3 and all("bf16[4,8192,256]" in c for c in calls)
     # under the blocks' remat policy, as the cell's attention layer is
     assert_dense_lse_kept(hlo, calls, "f32[32,64,1,128]")
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(48, 8), (28, 4)],
+                         ids=["laguna", "smallthinker"])
+def test_flash_attention_chunked_compiles_at_the_s16384_cells_shapes(
+        heads, kv_heads):
+    """1 x 48 / 8 and 28 / 4 heads x 16,384 x head_dim 128, bf16, causal
+    (the full-attention layers of the Laguna and SmallThinker cells): the
+    three chunked kernels under their scopes on grid (heads, 272) — the
+    (block, chunk) pairs a causal row needs of 32 x 16 — K and V read at
+    their own heads, under the blocks' remat policy."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    def attend(*a):
+        with jax.named_scope("attn"):
+            return flash_attention(*a, causal=True).astype(F32).sum()
+
+    def grads(q, k, v):
+        return jax.grad(rematted(attend), argnums=(0, 1, 2))(q, k, v)
+
+    shapes = (SDS((1, heads, 16384, 128), BF16),
+              SDS((1, kv_heads, 16384, 128), BF16),
+              SDS((1, kv_heads, 16384, 128), BF16))
+    text, compiled = compile_on_chip(grads, *shapes)
+    assert kernel_names(text) == {"_fwd_kernel_chunked",
+                                  "_bwd_dq_kernel_chunked",
+                                  "_bwd_dkv_kernel_chunked"}
+    assert pallas_grids(grads, *shapes) == [(heads, 272)] * 3
+    hlo = compiled.as_text()
+    calls = flash_calls(hlo)
+    assert all(f"bf16[{kv_heads},16384,128]" in c for c in calls)
+    for scope in ("flash_fwd_chunk", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+    assert "16384,16384" not in hlo
+    assert_dense_lse_kept(hlo, calls, f"f32[{heads},128,1,128]")
 
 
 @pytest.mark.parametrize("D,kernels,scopes", [
