@@ -54,12 +54,20 @@ each the other's backward pass, neither a scatter-add. A ``shared_d_ff`` adds
 one SwiGLU expert every token passes through, under a sigmoid gate
 (``moe_shared``).
 
-Two things a model may say otherwise, and no more: the experts' gate
-non-linearity (``act``: ``silu``, or ``relu`` — a ReGLU expert) and the
-tensor the ROUTER reads (``__call__(x, router_x=...)``: a router that sits
-ahead of the mixer reads the block's input while the experts read the
-normed stream after it; its gradient then enters the stream before the
-mixer). The defaults are one input and ``silu``.
+What a model may say otherwise, and no more: the experts' non-linearity
+(``act``: ``silu``, ``relu`` — a ReGLU expert — or ``relu2``, its square);
+whether an expert is a GATED unit at all (``gated=False``: two matrices,
+``down(act(up(x)))``, and the shared expert likewise); whether the shared
+expert sits under a sigmoid gate (``shared_gate=False``: it is added as it
+is); the router's score (``score="sigmoid"``: each expert's own sigmoid in
+place of the softmax over all) and a selection bias (``choice_bias``: a
+vector added to the scores for the CHOICE of the k experts only, the
+weights being the scores at the chosen experts without it); and the tensor
+the ROUTER reads (``__call__(x, router_x=...)``: a router that sits ahead
+of the mixer reads the block's input while the experts read the normed
+stream after it; its gradient then enters the stream before the mixer). The
+defaults are one input, a softmax, gated ``silu`` experts and a gated
+shared expert. With both coefficients zero no auxiliary term is traced.
 """
 
 import functools
@@ -71,7 +79,8 @@ import flax.linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
 from deepspeed_tpu.moe.layer import load_balance_loss
-from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+from deepspeed_tpu.ops.pallas.grouped_matmul import (grouped_matmul,
+                                                     lane_padded)
 from deepspeed_tpu.ops.pallas.rows_to_tokens import (rows_walked,
                                                      sum_rows_by_token)
 from deepspeed_tpu.telemetry.spans import annotate
@@ -87,18 +96,27 @@ HELD_STAT_GAUGES = dict(STAT_GAUGES,
                         moe_held_slabs="moe/held_slabs",
                         moe_combine_rows_walked="moe/combine_rows_walked")
 # the experts' gate non-linearity, by the name a model gives ``act``
-_ACTS = {"silu": nn.silu, "relu": nn.relu}
+_ACTS = {"silu": nn.silu, "relu": nn.relu,
+         "relu2": lambda x: jnp.square(nn.relu(x))}
+# the name of a layer's selection bias leaf: a model whose layers carry one
+# lists it in its ``buffer_leaves`` (``DroplessMoE``'s docstring)
+CHOICE_BIAS = "e_score_correction_bias"
 # static length of a held layer's row arrays over the mean rows held (4 did
 # not fit the one cell that holds a share: PERF.md Findings PR 31)
 _HELD_ROWS_SLACK = 2
 
 
-def route(logits, k, norm_topk_prob, pin_choice=False, routed_scale=1.0):
-    """(weights [T, k] float32, experts [T, k] int32, probabilities [T, E])
-    of float32 router logits [T, E]: softmax over the experts, the k
-    largest probabilities as they are (renormalised to sum to one only when
+def route(logits, k, norm_topk_prob, pin_choice=False, routed_scale=1.0,
+          score="softmax", choice_bias=None):
+    """(weights [T, k] float32, experts [T, k] int32, scores [T, E])
+    of float32 router logits [T, E]: softmax over the experts (``score``
+    ``"sigmoid"``: each expert's own sigmoid), the k
+    largest scores as they are (renormalised to sum to one only when
     ``norm_topk_prob``), times ``routed_scale`` where it is not one (a
     published scaling factor on the routed experts' output).
+    ``choice_bias`` [E]: added to the scores for the CHOICE alone — the k
+    experts are the largest of ``scores + choice_bias``, their weights the
+    scores at them without it (so no gradient reaches the bias).
     ``pin_choice``: the experts carry the checkpoint
     name ``moe_experts`` and the weights are the probabilities AT them, so
     that a remat policy which saves that name makes a recomputed forward
@@ -106,10 +124,17 @@ def route(logits, k, norm_topk_prob, pin_choice=False, routed_scale=1.0):
     (k+1)-th probability can break the other way when XLA fuses the
     recomputation differently, and the backward pass would then be another
     routing's)."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, k)
+    logits = logits.astype(jnp.float32)
+    probs = jax.nn.sigmoid(logits) if score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    if choice_bias is None:
+        top_w, top_e = jax.lax.top_k(probs, k)
+    else:
+        _, top_e = jax.lax.top_k(
+            probs + choice_bias.astype(jnp.float32), k)
     if pin_choice:
         top_e = checkpoint_name(top_e, "moe_experts")
+    if pin_choice or choice_bias is not None:
         top_w = jnp.take_along_axis(probs, top_e, axis=1)
     if norm_topk_prob:
         top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
@@ -220,8 +245,21 @@ class DroplessMoE(nn.Module):
     expert. ``pin_choice``: a
     caller that recomputes this layer under a remat policy which saves the
     name ``moe_experts`` asks for it (``route``); whether a share is held
-    has nothing to do with it. ``act``: the experts' gate non-linearity,
-    ``act(gate) * up`` (``silu``: SwiGLU; ``relu``: ReGLU). ``router_x`` of
+    has nothing to do with it. ``act``: the experts' non-linearity,
+    ``act(gate) * up`` (``silu``: SwiGLU; ``relu``: ReGLU; ``relu2``:
+    ``relu(.)^2``). ``gated=False``: an expert is ``down(act(up(x)))`` — no
+    ``gate_proj`` leaf exists, here or in the shared expert.
+    ``shared_gate=False``: the shared expert is added as it is (no
+    ``shared_expert_gate`` leaf). ``score`` / ``choice_bias``: ``route``'s;
+    the bias is the leaf ``e_score_correction_bias`` [E], zero at
+    initialisation (``choice_bias_init`` says otherwise) — a BUFFER that a balancing rule outside the loss moves:
+    it enters only the choice of the experts, so its gradient is exactly
+    zero, and a model that carries one lists the leaf's name in its
+    ``buffer_leaves``, for which the engine's ``_apply_grads`` hands the
+    leaf back as it came (no gradient step, no weight decay).
+    ``balance_coeff`` and ``z_coeff`` BOTH zero: the layer has no auxiliary
+    loss and neither term is computed or sown (one of them zero keeps its
+    term's value in ``stats``). ``router_x`` of
     ``__call__``: the tensor the router reads where it is not ``x`` (same
     shape; the experts still read ``x``)."""
     num_experts: int
@@ -238,6 +276,11 @@ class DroplessMoE(nn.Module):
     pin_choice: bool = False
     routed_scale: float = 1.0
     act: str = "silu"
+    gated: bool = True
+    shared_gate: bool = True
+    score: str = "softmax"
+    choice_bias: bool = False
+    choice_bias_init: Any = nn.initializers.zeros
 
     @nn.compact
     def __call__(self, x, router_x=None):
@@ -247,9 +290,12 @@ class DroplessMoE(nn.Module):
         T = B * S
         init = nn.initializers.normal(0.02)
         wg = self.param("router", init, (H, E), self.param_dtype)
-        w_gate = self.param("gate_proj", init, (held, H, F), self.param_dtype)
+        w_gate = self.param("gate_proj", init, (held, H, F),
+                            self.param_dtype) if self.gated else None
         w_up = self.param("up_proj", init, (held, H, F), self.param_dtype)
         w_down = self.param("down_proj", init, (held, F, H), self.param_dtype)
+        bias = self.param(CHOICE_BIAS, self.choice_bias_init, (E,),
+                          self.param_dtype) if self.choice_bias else None
         xt = x.reshape(T, H)
         rt = xt if router_x is None else router_x.reshape(T, H)
 
@@ -265,14 +311,31 @@ class DroplessMoE(nn.Module):
                 logits, K, self.norm_topk_prob,
                 **({"pin_choice": True} if self.pin_choice else {}),
                 **({"routed_scale": self.routed_scale}
-                   if self.routed_scale != 1.0 else {}))
+                   if self.routed_scale != 1.0 else {}),
+                **({"score": self.score} if self.score != "softmax" else {}),
+                **({"choice_bias": bias} if self.choice_bias else {}))
             chosen = jax.nn.one_hot(top_e, E, dtype=jnp.float32).sum(axis=1)
             group_sizes = chosen.sum(axis=0).astype(jnp.int32)      # [E]
-            balance = load_balance_loss(probs, chosen)
-            z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+            # both coefficients zero: the layer has no auxiliary loss, and
+            # nothing of either term is traced
+            aux = bool(self.balance_coeff or self.z_coeff)
+            if aux:
+                balance = load_balance_loss(probs, chosen)
+                z = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
 
         dt = self.dtype
-        weights = tuple(w.astype(dt) for w in (w_gate, w_up, w_down))
+        weights = tuple(w if w is None else w.astype(dt)
+                        for w in (w_gate, w_up, w_down))
+        if lane_padded(F) != F:
+            # an expert width no multiple of 128 divides (1,856): padded
+            # ONCE a layer, here, with zero columns of gate / up and zero
+            # rows of down — every ``act`` is 0 at 0, so the padded lanes
+            # add nothing, exactly — and the three grouped matmuls run at
+            # the padded width with nothing cut or copied between them
+            more = (0, lane_padded(F) - F)
+            columns, rows = ((0, 0), (0, 0), more), ((0, 0), more, (0, 0))
+            weights = tuple(w if w is None else jnp.pad(w, where) for w, where
+                            in zip(weights, (columns, columns, rows)))
         if held == E:
             with annotate("moe_dispatch"):
                 order, inverse = sort_by_expert(top_e)
@@ -337,31 +400,39 @@ class DroplessMoE(nn.Module):
         if self.shared_d_ff:
             Fs = self.shared_d_ff
             s_gate = self.param("shared_gate_proj", init, (H, Fs),
-                                self.param_dtype)
+                                self.param_dtype) if self.gated else None
             s_up = self.param("shared_up_proj", init, (H, Fs),
                               self.param_dtype)
             s_down = self.param("shared_down_proj", init, (Fs, H),
                                 self.param_dtype)
             w_sg = self.param("shared_expert_gate", init, (H, 1),
-                              self.param_dtype)
+                              self.param_dtype) if self.shared_gate else None
             with annotate("moe_shared"):
-                hs = nn.silu(x @ s_gate.astype(dt)) * (x @ s_up.astype(dt))
-                open_ = jax.nn.sigmoid(
-                    (x @ w_sg.astype(dt)).astype(jnp.float32))
-                y = y + (open_ * (hs @ s_down.astype(dt))).astype(dt)
+                if self.gated:
+                    hs = _ACTS[self.act](x @ s_gate.astype(dt)) \
+                        * (x @ s_up.astype(dt))
+                else:
+                    hs = _ACTS[self.act](x @ s_up.astype(dt))
+                hs = hs @ s_down.astype(dt)
+                if self.shared_gate:
+                    open_ = jax.nn.sigmoid(
+                        (x @ w_sg.astype(dt)).astype(jnp.float32))
+                    hs = (open_ * hs).astype(dt)
+                y = y + hs
 
-        if self.is_mutable_collection("losses"):
+        if aux and self.is_mutable_collection("losses"):
             self.sow("losses", "moe_balance", self.balance_coeff * balance)
             self.sow("losses", "moe_z", self.z_coeff * z)
         if self.is_mutable_collection("stats"):
             rows = group_sizes.astype(jnp.float32)
             # rows routed (here) less rows the grouped matmuls computed
             routed = T * K if held == E else rows_held
-            stats = [("moe_aux_loss", balance), ("moe_z_loss", z),
-                     ("moe_rows_max_over_mean", jnp.max(rows) * E / (T * K)
+            stats = [("moe_aux_loss", balance), ("moe_z_loss", z)] \
+                if aux else []
+            stats += [("moe_rows_max_over_mean", jnp.max(rows) * E / (T * K)
                       if held == E else
                       jnp.max(rows) * held / jnp.maximum(jnp.sum(rows), 1.0)),
-                     ("moe_dropped_rows", routed - jnp.sum(rows))]
+                      ("moe_dropped_rows", routed - jnp.sum(rows))]
             if held != E:
                 stats += [("moe_rows_held_share", jnp.sum(rows) / (T * K)),
                           # slabs of rows this layer took (1: they fit the
@@ -380,12 +451,18 @@ class DroplessMoE(nn.Module):
         return checkpoint_name(y, "mlp_proj")
 
     def _experts(self, xs, weights, group_sizes):
-        """Rows in expert order through their experts' gated unit."""
+        """Rows in expert order through their experts' unit (gated, or
+        ``down(act(up))``)."""
         w_gate, w_up, w_down = weights
-        gate = grouped_matmul(xs, w_gate, group_sizes)
+        if self.gated:
+            gate = grouped_matmul(xs, w_gate, group_sizes)
         up = grouped_matmul(xs, w_up, group_sizes)
-        with annotate("moe_act"):
-            h = checkpoint_name(_ACTS[self.act](gate) * up, "mlp_fc")
+        if self.gated:
+            with annotate("moe_act"):
+                h = checkpoint_name(_ACTS[self.act](gate) * up, "mlp_fc")
+        else:
+            with annotate("moe_act"):
+                h = checkpoint_name(_ACTS[self.act](up), "mlp_fc")
         return grouped_matmul(h, w_down, group_sizes)
 
     @staticmethod

@@ -16,8 +16,9 @@ repo's: the custom VJP (each of the three products under a scope of its own,
 the kernels and the benchmark's ``moe_gmm_*`` readers find them by prefix),
 the tilings (caps under which each side takes the largest multiple of 128
 that divides it: ``_divisor``; powers of two and 3 or 5 times one alike;
-all three products swept on the chip, my chip runs, PR 27 and PR 38), and
-padding M up to the row tile. On other backends the same
+all three products swept on the chip, my chip runs, PR 27 and PR 38),
+padding M up to the row tile, and padding a width that no multiple of 128
+divides up to the next one (``lane_padded``: 1,856 -> 1,920, PR 40). On other backends the same
 kernels run in Pallas interpret mode, as the flash kernels do.
 
 Backward: ``dlhs = gmm(dout, rhs^T)`` (a grouped matmul against the
@@ -63,6 +64,28 @@ _mb = importlib.import_module(
 #            1280) 0.591, 83.0 % — caps of 1024 gave (512, 640, 768) 0.661
 #            and (512, 768, 640) 0.666; 1,024 rows x 1,280 and a whole side
 #            of 2,560 are refused for scoped VMEM.
+# A width that NO multiple of 128 divides (Nemotron-3-Nano's experts, 1,856 =
+# 29 x 64 against 2,688 = 21 x 128; a slab of 12,288 rows of which 6,144 are
+# filled over 8 experts) is PADDED with zeros to the next multiple, 1,920
+# (``lane_padded``; 3.4 % more tile work, exact: zero columns of up meet zero
+# rows of down), not taken as one whole-dimension block of 1,856: device ms a
+# call at the tiles ``_clip`` gives these caps, padded against whole
+# (tests/perf/gmm_tile_bench.py --set nemotron, my chip run, PR 40; the share
+# of the bf16 peak counts the flops of the 1,856 on both sides) —
+#   up   (2688 -> 1,920 | 1,856): forward (256, 896, 640) 0.549, 56.6 % |
+#        (256, 896, 1856) 0.632 and (256, 2688, 1856) 0.614; dlhs (512, 640,
+#        896) 0.533 | (512, 1856, 896) 0.724, (256, ...) 0.619; drhs (512, 896,
+#        640) 0.614 | (512, 896, 1856) 0.763, (256, ...) 0.662.
+#   down (1,920 | 1,856 -> 2688): forward (256, 1920, 896) 0.428, 72.7 % |
+#        (256, 1856, 896) 0.458; dlhs (512, 896, 640) 0.527 | (512, 896, 1856)
+#        0.537, (256, ...) 0.456; drhs (512, 640, 896) 0.602 | (512, 1856, 896)
+#        REFUSED for scoped VMEM, (256, 1856, 896) 0.538.
+# Padded wins five of six at the committed caps (a ragged last lane tile costs
+# more than the 64 zero lanes). The caps stay: 1,920 alone would take more
+# from them — forward (256, 896, 1920) 0.425 and (256, 2688, 640) 0.441, dlhs
+# (256, 1920, 896) 0.428 — but 256 rows backward and a whole side of 1,920 or
+# 2,688 are slower or refused at the other cells' widths, and the six products
+# are 2.6 % of that cell's step.
 TILE_FWD = (256, 2560, 1280)
 TILE_DLHS = (512, 1280, 1280)
 TILE_DRHS = (512, 1280, 1280)
@@ -87,6 +110,15 @@ def _divisor(cap, d):
     while d % t:
         t //= 2
     return t
+
+
+def lane_padded(d):
+    """``d``, or — where ``d`` is at least 128 and no multiple of 128
+    divides it (1,856 = 29 x 64) — the next multiple of 128 (1,920): the
+    width a side is PADDED to, with zeros, before it is tiled. ``_divisor``
+    would halve such a side down to a tile of 64 lanes or fewer (1,856 from
+    a cap of 1,280: a tile of 2)."""
+    return d if d < 128 or d % 128 == 0 else -(-d // 128) * 128
 
 
 def _clip(tile, m, k, n):
@@ -137,7 +169,18 @@ def grouped_matmul(lhs, rhs, group_sizes, interpret=None):
     are undefined). Differentiable in ``lhs`` and ``rhs``."""
     if interpret is None:
         interpret = _interpret_default()
-    m = lhs.shape[0]
+    m, k = lhs.shape
+    n = rhs.shape[2]
+    if (lane_padded(k), lane_padded(n)) != (k, n):
+        # zero columns of lhs against zero rows of rhs add nothing; zero
+        # columns of rhs are cut from the result. A caller with such a
+        # width between two products pads its WEIGHTS once and calls with
+        # whole widths (``moe/dropless.DroplessMoE``): nothing is copied
+        # between its products then
+        lhs = jnp.pad(lhs, ((0, 0), (0, lane_padded(k) - k)))
+        rhs = jnp.pad(rhs, ((0, 0), (0, lane_padded(k) - k),
+                            (0, lane_padded(n) - n)))
+        return grouped_matmul(lhs, rhs, group_sizes, interpret)[:, :n]
     # whole row tiles; a problem smaller than one tile, whole sublanes
     unit = max(TILE_FWD[0], TILE_DLHS[0], TILE_DRHS[0])
     pad = (-m) % (unit if m >= unit else 8)

@@ -1374,6 +1374,15 @@ class DeepSpeedEngine:
                 lambda g: g.astype(jnp.float32) * gscale, grads)
             new_params, new_opt = self.optimizer.step(params, grads,
                                                       state.opt_state, lr)
+        buffers = tuple(getattr(self.module, "buffer_leaves", ()))
+        if buffers:
+            # leaves the model holds as BUFFERS (a router's selection bias,
+            # moved by a rule outside the loss): handed back as they came —
+            # no gradient step, no weight decay
+            new_params = jax.tree_util.tree_map_with_path(
+                lambda path, new, old: old if getattr(
+                    path[-1], "key", None) in buffers else new,
+                new_params, params)
         # skip-on-overflow (reference fused_optimizer.py:194-246); done
         # before moving back so both branches live in device memory
         new_params = _tree_where(finite, new_params, params)

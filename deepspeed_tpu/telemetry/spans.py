@@ -128,8 +128,9 @@ def annotate(tag):
       sort, gather and ``pallas_call``) lies INSIDE ``moe_combine`` in the
       forward pass and ``moe_dispatch`` in the backward pass and is counted
       with them; it must not start with a kernel tag (``moe_gmm``,
-      ``flash_``, ``gdn_scan``, ``swa_``: matched by prefix);
-    - ``moe_act`` (moe/dropless.py: ``act(gate) * up``, silu or relu)
+      ``flash_``, ``gdn_scan``, ``ssd_scan``, ``swa_``: matched by prefix);
+    - ``moe_act`` (moe/dropless.py: ``act(gate) * up``, silu, relu or
+      relu^2, or ``act(up)`` alone for an ungated expert)
       and ``qk_norm`` (models/llama.py, the
       RMSNorms over the whole q and k projections): rows of the detail
       table.
@@ -149,6 +150,17 @@ def annotate(tag):
       attention output times ``sigmoid(gate)``, element-wise there, one
       scalar a head here) and ``moe_shared`` (moe/dropless.py, the gated
       shared expert): rows of the detail table.
+
+    - ``ssd_scan_fwd`` and ``ssd_scan_bwd`` (ops/pallas/ssd.py, round the
+      state-space scan's two ``pallas_call``s), ``ssd_scan_prep`` (the XLA
+      ops left round them: the gates' re-layout and running sum) and, for
+      the shapes the kernels do not take, ``ssd_scan`` (ops/ssd.py, the XLA
+      chunked form): ``ssd_scan_share`` and ``ssd_scan_roofline`` (one tag,
+      by prefix);
+    - ``ssm_conv``, ``ssm_gates``, ``ssm_norm`` (models/nemotron_h.py: the
+      causal depthwise convolution with bias and its SiLU; the softplus of
+      the steps and the decay; the gate and the grouped RMSNorm): with
+      ``ssd_scan*`` and the module name ``mamba``, ``ssm_layer_ms``.
 
     - ``swa_fwd``, ``swa_bwd_dq``, ``swa_bwd_dkv``
       (ops/pallas/flash_attention.py, round the three ``pallas_call``s of

@@ -9,6 +9,13 @@ alone (``gmm`` forward, ``gmm`` against the transposed weights for ``dlhs``,
 written beside ``ops/pallas/grouped_matmul.TILE_*``.
 
     chiprun -- python tests/perf/gmm_tile_bench.py [--out NAME]
+
+``--set nemotron`` (PR 40): a width that no multiple of 128 divides —
+Nemotron-3-Nano's experts, 1,856 = 29 x 64 against 2,688 = 21 x 128, a slab
+of 12,288 rows of which 6,144 are filled over 8 experts — taken two ways:
+PADDED with zeros to 1,920 and tiled by divisors of that, or as it is with
+ONE whole-dimension block of 1,856 on that side. ``mxu_pct`` counts the
+flops of the 1,856 in both.
 """
 
 import argparse
@@ -50,20 +57,55 @@ DRHS = {(2560, 768): [(512, 640, 768), (512, 1280, 768), (1024, 640, 768),
                       (512, 768, 2560), (512, 384, 1280)]}
 
 
+# the second set: (K, N) as the arrays are handed over — 1,920 is the padded
+# 1,856, 1,856 the width as it is (one whole-dimension block on that side)
+NEMOTRON = dict(M=12288, G=8, ROWS=6144, real={1920: 1856})
+NEMOTRON_FWD = {
+    (2688, 1920): [(256, 896, 640), (256, 2688, 640), (256, 896, 1920),
+                   (256, 2688, 384), (512, 896, 640), (256, 1344, 640)],
+    (2688, 1856): [(256, 896, 1856), (256, 2688, 1856), (512, 896, 1856),
+                   (256, 384, 1856)],
+    (1920, 2688): [(256, 1920, 896), (256, 640, 896), (256, 1920, 384),
+                   (512, 1920, 896), (256, 1920, 2688), (256, 960, 896)],
+    (1856, 2688): [(256, 1856, 896), (256, 1856, 384), (512, 1856, 896),
+                   (256, 1856, 2688)]}
+NEMOTRON_DLHS = {       # dout [M, N] x rhs^T: contraction N, columns K
+    (2688, 1920): [(512, 640, 896), (512, 1920, 896), (256, 1920, 896),
+                   (512, 640, 384), (512, 960, 896)],
+    (2688, 1856): [(512, 1856, 896), (256, 1856, 896), (512, 1856, 384)],
+    (1920, 2688): [(512, 896, 640), (512, 896, 1920), (512, 384, 640),
+                   (256, 896, 1920), (512, 1344, 640)],
+    (1856, 2688): [(512, 896, 1856), (256, 896, 1856), (512, 384, 1856)]}
+NEMOTRON_DRHS = {       # lhs^T [K, M] x dout [M, N]
+    (2688, 1920): [(512, 896, 640), (512, 896, 1920), (512, 384, 640),
+                   (1024, 896, 640), (512, 1344, 640)],
+    (2688, 1856): [(512, 896, 1856), (512, 384, 1856), (256, 896, 1856)],
+    (1920, 2688): [(512, 640, 896), (512, 1920, 896), (512, 640, 384),
+                   (1024, 640, 896), (512, 960, 896)],
+    (1856, 2688): [(512, 1856, 896), (512, 1856, 384), (256, 1856, 896)]}
+
+
 def main():
+    global M, G, ROWS, FWD, DLHS, DRHS
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="gmm_tile_bench")
+    ap.add_argument("--set", default="smallthinker",
+                    choices=("smallthinker", "nemotron"))
     args = ap.parse_args()
+    real = {}
+    if args.set == "nemotron":
+        M, G, ROWS, real = (NEMOTRON[k] for k in ("M", "G", "ROWS", "real"))
+        FWD, DLHS, DRHS = NEMOTRON_FWD, NEMOTRON_DLHS, NEMOTRON_DRHS
     sizes = jnp.full((G,), ROWS // G, jnp.int32)
     mb = gm._mb
     out = []
-    for (k, n) in ((2560, 768), (768, 2560)):
+    for (k, n) in FWD:
         ks = jax.random.split(jax.random.PRNGKey(k), 3)
         lhs = jax.random.normal(ks[0], (M, k), jnp.bfloat16)
         rhs = (0.02 * jax.random.normal(ks[1], (G, k, n))).astype(
             jnp.bfloat16)
         dout = jax.random.normal(ks[2], (M, n), jnp.bfloat16)
-        flops = 2 * ROWS * k * n
+        flops = 2 * ROWS * real.get(k, k) * real.get(n, n)
         products = {
             "fwd": (FWD, lambda t: jax.jit(lambda a, b: mb.gmm(
                 a, b, sizes, a.dtype, t)), (lhs, rhs)),

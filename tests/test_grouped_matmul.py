@@ -114,3 +114,79 @@ def test_kernels_at_widths_that_are_no_powers_of_two(k, n, monkeypatch):
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
     for a, b in zip(g1, g2):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------- a width no multiple of 128 divides (PR 40)
+
+@pytest.mark.parametrize("d,want", [
+    (1856, 1920), (3712, 3712), (2688, 2688), (1920, 1920), (192, 256),
+    (2560, 2560), (768, 768), (2048, 2048), (512, 512), (128, 128),
+    (64, 64), (24, 24), (96, 96), (200, 256),
+])
+def test_only_a_width_that_128_does_not_divide_is_padded(d, want):
+    """1,856 = 29 x 64 goes to 1,920; powers of two, 768, 2,560, 2,688 and
+    3,712 = 29 x 128 stay; a side under 128 (a test's sizes) is left to
+    ``_divisor``'s halving."""
+    assert gm.lane_padded(d) == want
+    assert want % 128 == 0 or want == d < 128
+
+
+def test_nemotrons_widths_get_whole_tiles_and_every_old_tile_stands():
+    """(K, N) = (2688, 1920) and (1920, 2688) — 1,856 padded — at the cell's
+    slab of 12,288 rows: every tile is a multiple of 128 that divides its
+    side (the unpadded 1,856 would have been halved from 1,280 down to a
+    tile of 2); and the tiles of every cell the benchmark had are the ones
+    they were."""
+    m = 12288
+    assert gm._divisor(1280, 1856) == 2                  # why it is padded
+    for k, n in ((2688, 1920), (1920, 2688)):
+        for tile, dims in ((gm.TILE_FWD, (m, k, n)),
+                           (gm.TILE_DLHS, (m, n, k)),
+                           (gm.TILE_DRHS, (m, k, n))):
+            got = gm._clip(tile, *dims)
+            assert all(d % t == 0 and t % 128 == 0
+                       for t, d in zip(got, dims)), (got, dims)
+    assert gm._clip(gm.TILE_FWD, m, 2688, 1920) == (256, 896, 640)
+    assert gm._clip(gm.TILE_FWD, m, 1920, 2688) == (256, 1920, 896)
+    assert gm._clip(gm.TILE_DLHS, m, 1920, 2688) == (512, 640, 896)
+    assert gm._clip(gm.TILE_DRHS, m, 2688, 1920) == (512, 896, 640)
+    # pinned: the accepted cells' tiles
+    assert gm._clip(gm.TILE_FWD, 131072, 2048, 1024) == (256, 2048, 1024)
+    assert gm._clip(gm.TILE_DLHS, 131072, 1024, 2048) == (512, 1024, 1024)
+    assert gm._clip(gm.TILE_DRHS, 131072, 2048, 1024) == (512, 1024, 1024)
+    assert gm._clip(gm.TILE_FWD, 8192, 2048, 512) == (256, 2048, 512)
+    assert gm._clip(gm.TILE_FWD, 32768, 512, 2048) == (256, 512, 1024)
+    assert gm._clip(gm.TILE_FWD, 49152, 2560, 768) == (256, 2560, 768)
+    assert gm._clip(gm.TILE_FWD, 49152, 768, 2560) == (256, 768, 1280)
+    assert gm._clip(gm.TILE_DLHS, 49152, 768, 2560) == (512, 768, 1280)
+    assert gm._clip(gm.TILE_DRHS, 49152, 2560, 768) == (512, 1280, 768)
+
+
+@pytest.mark.parametrize("k,n", [(256, 1856), (1856, 256)],
+                         ids=["N_1856", "K_1856"])
+def test_kernels_at_a_width_of_1856(k, n):
+    """The published expert width on the columns and on the contraction:
+    forward, dlhs and drhs against a per-expert loop, the operands and the
+    gradients at 1,856 (the padding is the call's own and is cut again)."""
+    sizes = jnp.asarray([100, 0, 156], jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(k), 2)
+    lhs = jax.random.normal(ks[0], (256, k), jnp.float32)
+    rhs = 0.1 * jax.random.normal(ks[1], (3, k, n), jnp.float32)
+
+    def loop(lhs, rhs):
+        return jnp.concatenate([lhs[:100] @ rhs[0], lhs[100:] @ rhs[2]])
+
+    def loss(f):
+        return lambda a, b: jnp.sum(jnp.sin(f(a, b)))
+
+    with jax.default_matmul_precision("highest"):
+        got = grouped_matmul(lhs, rhs, sizes)
+        g1 = jax.grad(loss(lambda a, b: grouped_matmul(a, b, sizes)),
+                      argnums=(0, 1))(lhs, rhs)
+        want, g2 = loop(lhs, rhs), jax.grad(loss(loop), argnums=(0, 1))(
+            lhs, rhs)
+    assert got.shape == (256, n)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=2e-4)
+    for a, b in zip(g1, g2):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=2e-4)

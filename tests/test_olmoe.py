@@ -308,6 +308,207 @@ def test_the_layers_gradients_are_the_dense_computations():
         assert np.allclose(g, w, atol=1e-5), np.abs(g - w).max()
 
 
+# what a model may say otherwise (PR 40): the router's score and selection
+# bias, an ungated expert, relu^2, an ungated shared expert
+
+def _dense_variant(p, x, k, score="softmax", bias=None, gated=True,
+                   act=jax.nn.silu, shared_gate=True, scale=1.0):
+    """``_dense_moe`` with each of ``DroplessMoE``'s later options, written
+    out: every expert on every token, masked by the chosen weights."""
+    h = x.reshape(-1, x.shape[-1])
+    logits = h @ p["router"]
+    s = jax.nn.sigmoid(logits) if score == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    _, e = jax.lax.top_k(s if bias is None else s + bias, k)
+    w = jnp.take_along_axis(s, e, axis=1) * scale
+
+    def unit(up, down, gate=None):
+        u = h @ up
+        return (act(u) if gate is None else act(h @ gate) * u) @ down
+
+    y = jnp.zeros_like(h)
+    for i in range(p["router"].shape[1]):
+        out = unit(p["up_proj"][i], p["down_proj"][i],
+                   p["gate_proj"][i] if gated else None)
+        y = y + jnp.sum(jnp.where(e == i, w, 0.0), axis=1)[:, None] * out
+    if "shared_up_proj" in p:
+        ys = unit(p["shared_up_proj"], p["shared_down_proj"],
+                  p["shared_gate_proj"] if gated else None)
+        if shared_gate:
+            ys = ys * jax.nn.sigmoid(h @ p["shared_expert_gate"])
+        y = y + ys
+    return y.reshape(x.shape)
+
+
+_RELU2 = lambda t: jnp.square(jax.nn.relu(t))  # noqa: E731
+VARIANTS = {
+    "sigmoid_score": (dict(score="sigmoid"), dict(score="sigmoid")),
+    "selection_bias": (dict(score="sigmoid", choice_bias=True),
+                       dict(score="sigmoid", bias=True)),
+    "bias_over_softmax": (dict(choice_bias=True), dict(bias=True)),
+    "ungated_relu2": (dict(gated=False, act="relu2"),
+                      dict(gated=False, act=_RELU2)),
+    "gated_relu2": (dict(act="relu2"), dict(act=_RELU2)),
+    "shared_ungated_both_ways": (
+        dict(gated=False, act="relu2", shared_d_ff=48, shared_gate=False),
+        dict(gated=False, act=_RELU2, shared_gate=False)),
+    "shared_gated_relu": (dict(act="relu", shared_d_ff=48),
+                          dict(act=jax.nn.relu)),
+    "all_of_nemotrons": (
+        dict(score="sigmoid", choice_bias=True, gated=False, act="relu2",
+             shared_d_ff=48, shared_gate=False, routed_scale=2.5,
+             balance_coeff=0.0, z_coeff=0.0),
+        dict(score="sigmoid", bias=True, gated=False, act=_RELU2,
+             shared_gate=False, scale=2.5)),
+}
+
+
+@pytest.mark.parametrize("name", VARIANTS, ids=str)
+def test_each_option_of_the_layer_is_the_dense_computation(name):
+    """Values and every gradient of the layer under each option against the
+    computation written out; the selection bias moves the CHOICE (the
+    output differs from the layer without it) and takes no gradient."""
+    layer_kw, dense_kw = VARIANTS[name]
+    layer = dropless.DroplessMoE(num_experts=8, k=2, d_ff=32,
+                                 dtype=jnp.float32, **layer_kw)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 32, 64))
+    p = layer.init(jax.random.PRNGKey(1), x)["params"]
+    p = jax.tree_util.tree_map(lambda w: 5.0 * w, p)     # far from uniform
+    assert ("gate_proj" in p) == layer_kw.get("gated", True)
+    assert ("shared_expert_gate" in p) == (
+        "shared_d_ff" in layer_kw and layer_kw.get("shared_gate", True))
+    if dense_kw.get("bias"):
+        assert float(jnp.abs(p["e_score_correction_bias"]).max()) == 0.0
+        p["e_score_correction_bias"] = 0.3 * jax.random.normal(
+            jax.random.PRNGKey(2), (8,))
+        dense_kw = dict(dense_kw, bias=p["e_score_correction_bias"])
+
+    def dense(p, x):
+        return _dense_variant(p, x, 2, **dense_kw)
+
+    got = layer.apply({"params": p}, x)
+    np.testing.assert_allclose(got, dense(p, x), atol=2e-5)
+    g1 = jax.grad(lambda p, x: jnp.sum(jnp.sin(
+        layer.apply({"params": p}, x))), argnums=(0, 1))(p, x)
+    g2 = jax.grad(lambda p, x: jnp.sum(jnp.sin(dense(p, x))),
+                  argnums=(0, 1))(p, x)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(g1),
+                            jax.tree_util.tree_leaves(g2)):
+        assert np.allclose(g, w, atol=2e-5), (path, np.abs(g - w).max())
+    if dense_kw.get("bias") is not None:
+        assert float(jnp.abs(g1[0]["e_score_correction_bias"]).max()) == 0.0
+        unbiased = layer.apply({"params": dict(
+            p, e_score_correction_bias=jnp.zeros((8,)))}, x)
+        assert float(jnp.abs(unbiased - got).max()) > 1e-3
+
+
+def test_route_scores_chooses_and_weighs_as_it_is_told():
+    """``route`` by hand on one token: the softmax default; a sigmoid's own
+    scores; a bias that moves the choice and stays out of the weights; and
+    that the defaults trace what they traced before the options existed."""
+    logits = jnp.asarray([[2.0, 1.0, 0.0, -1.0]])
+    bias = jnp.asarray([-5.0, 0.0, 0.0, 5.0])
+    w, e, probs = dropless.route(logits, 2, False)
+    np.testing.assert_allclose(probs, jax.nn.softmax(logits))
+    assert e.tolist() == [[0, 1]]
+    w, e, s = dropless.route(logits, 2, False, score="sigmoid")
+    np.testing.assert_allclose(s, jax.nn.sigmoid(logits))
+    np.testing.assert_allclose(w, s[:, :2])
+    w, e, s = dropless.route(logits, 2, True, score="sigmoid",
+                             choice_bias=bias, routed_scale=2.5)
+    assert e.tolist() == [[3, 1]]           # 0.27 + 5, 0.73: not expert 0
+    want = s[0, jnp.asarray([3, 1])]
+    np.testing.assert_allclose(w[0], 2.5 * want / want.sum(), rtol=1e-6)
+    text = lambda **kw: str(jax.make_jaxpr(  # noqa: E731
+        lambda x: dropless.route(x, 2, True, **kw))(logits))
+    assert text() == text(score="softmax", choice_bias=None) \
+        != text(score="sigmoid")
+
+
+def test_both_coefficients_zero_trace_no_auxiliary_term():
+    """With no auxiliary loss the layer computes neither term: no
+    ``logsumexp`` in the program, nothing in ``losses``, no such statistic;
+    ONE zero coefficient keeps both values in ``stats`` as before."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 16, 64))
+
+    def sown(**kw):
+        layer = dropless.DroplessMoE(num_experts=8, k=2, d_ff=32,
+                                     dtype=jnp.float32, **kw)
+        p = layer.init(jax.random.PRNGKey(1), x)["params"]
+        _, vs = layer.apply({"params": p}, x, mutable=["losses", "stats"])
+        text = str(jax.make_jaxpr(lambda p: layer.apply(
+            {"params": p}, x, mutable=["losses", "stats"]))(p))
+        return vs, text
+
+    vs, text = sown(balance_coeff=0.0, z_coeff=0.0)
+    assert "losses" not in vs or not vs["losses"]
+    assert set(vs["stats"]) == {"moe_rows_max_over_mean", "moe_dropped_rows"}
+    assert "logsumexp" not in text and "reduce_logsumexp" not in text
+    vs, text = sown(z_coeff=0.0)
+    assert set(vs["losses"]) == {"moe_balance", "moe_z"}
+    assert float(vs["losses"]["moe_z"][0]) == 0.0
+    assert float(vs["stats"]["moe_z_loss"][0]) > 0.0
+
+
+def test_a_width_no_multiple_of_128_divides_is_padded_once_in_the_layer():
+    """An expert width of 3 x 64 = 192: the layer pads its weights to 256
+    lanes (one ``pad`` a matrix, none on the rows), the three grouped
+    matmuls run at 256, and the output and gradients are the dense
+    computation's at 192."""
+    layer = dropless.DroplessMoE(num_experts=4, k=2, d_ff=192, gated=False,
+                                 act="relu2", dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 64, 128))
+    p = layer.init(jax.random.PRNGKey(1), x)["params"]
+    p = jax.tree_util.tree_map(lambda w: 3.0 * w, p)
+    assert p["up_proj"].shape == (4, 128, 192)
+    text = str(jax.make_jaxpr(lambda p: layer.apply({"params": p}, x))(p))
+    assert text.count(" pad[") == 2 and "256" in text
+
+    def dense(p, x):
+        return _dense_variant(p, x, 2, gated=False, act=_RELU2)
+
+    np.testing.assert_allclose(layer.apply({"params": p}, x), dense(p, x),
+                               atol=2e-5)
+    g1 = jax.grad(lambda p: jnp.sum(jnp.sin(layer.apply({"params": p}, x))))(
+        p)
+    g2 = jax.grad(lambda p: jnp.sum(jnp.sin(dense(p, x))))(p)
+    for name in g2:
+        assert g1[name].shape == p[name].shape
+        np.testing.assert_allclose(g1[name], g2[name], atol=2e-5)
+
+
+def test_a_buffer_leaf_is_unmoved_by_the_engines_step():
+    """A model that lists a leaf in ``buffer_leaves`` gets it back from
+    AdamW with weight decay as it went in (no gradient step, no decay),
+    while its neighbours move; a model that lists none traces no such
+    selection."""
+    from deepspeed_tpu.models.nemotron_h import (NemotronHForCausalLM,
+                                                 nemotron_h_tiny)
+    model = NemotronHForCausalLM(nemotron_h_tiny(
+        hybrid_override_pattern="ME"))
+    assert model.buffer_leaves == (dropless.CHOICE_BIAS,)
+    ids = np.random.default_rng(0).integers(0, 256, (2, 32)).astype(np.int32)
+    engine, _, _, _ = dstpu.initialize(
+        config={"train_batch_size": 2, "seed": 0,
+                "optimizer": {"type": "AdamW", "params": {
+                    "lr": 0.01, "weight_decay": 0.1}},
+                "steps_per_print": 10 ** 9},
+        model=model, mesh=make_mesh(MeshConfig(data=1),
+                                    devices=jax.devices()[:1]))
+    engine.train_batch({"input_ids": ids})
+    before = jax.tree_util.tree_map(np.asarray, engine.state.params)
+    assert np.abs(before["layer_1"]["mixer"][dropless.CHOICE_BIAS]).max() > 0
+    for _ in range(2):
+        engine.train_batch({"input_ids": ids})
+    after = engine.state.params["layer_1"]["mixer"]
+    np.testing.assert_array_equal(np.asarray(after[dropless.CHOICE_BIAS]),
+                                  before["layer_1"]["mixer"][
+                                      dropless.CHOICE_BIAS])
+    assert np.abs(np.asarray(after["router"])
+                  - before["layer_1"]["mixer"]["router"]).max() > 1e-4
+    assert not hasattr(llama.LlamaForCausalLM, "buffer_leaves")
+
+
 @pytest.mark.parametrize("sizes,rows", [
     ([5, 0, 20, 7, 0, 0, 1, 7], 40),     # uneven, empty groups
     ([40, 0, 0, 0, 0, 0, 0, 0], 40),     # everything on one expert

@@ -1,0 +1,136 @@
+"""The state-space scan (``ops/ssd.py``): the XLA chunked form and the Pallas
+kernels (in the interpreter) against the token-by-token recurrence — values
+and the gradient of every input — at chunk boundaries, at a sequence that
+is one chunk, at a ragged tail, with B / C shared by a group of heads, with
+two heads side by side in a lane block, under strong and weak decay, with
+``D`` and a ``dt_bias`` in front of the softplus. Float32."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.ops import ssd
+from deepspeed_tpu.ops.pallas import ssd as kernels
+
+
+def _inputs(B, S, H, P, G, N, A, seed=0, dt_bias=0.5):
+    """x, dt (softplus of a projection plus ``dt_bias``: steps of 0.05-3),
+    A = -``A`` for every head, B, C, D: the operands as a Mamba-2 layer
+    hands them over."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(ks[0], (B, S, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)) - 2.0 + dt_bias)
+    a = -A * (1.0 + 0.1 * jax.random.uniform(ks[2], (H,)))
+    Bm = jax.random.normal(ks[3], (B, S, G, N))
+    Cm = jax.random.normal(ks[4], (B, S, G, N))
+    D = 1.0 + jax.random.normal(ks[5], (H,))
+    return x, dt, a, Bm, Cm, D
+
+
+def _xla(chunk):
+    return lambda *a: ssd.ssd_scan_xla(*a, chunk=chunk)
+
+
+def _kernel(chunk):
+    return lambda *a: kernels.ssd_scan_kernel(*a, chunk=chunk,
+                                              interpret=True)
+
+
+FORMS = {"xla": _xla, "kernel": _kernel}
+# (B, S, H, P, G, N, chunk): what each case is for
+SHAPES = {
+    "three_chunks_groups_of_two": (2, 48, 4, 8, 2, 16, 16),
+    "one_chunk": (1, 16, 2, 8, 1, 8, 16),
+    "ragged_tail": (1, 40, 4, 8, 2, 16, 16),
+    "two_heads_a_lane_block": (1, 32, 4, 64, 2, 16, 16),
+    "one_group_serves_every_head": (1, 32, 4, 8, 1, 16, 8),
+}
+
+
+def _rel(a, b):
+    return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
+
+
+@pytest.mark.parametrize("A", [1.0, 16.0], ids=["weak_decay", "strong_decay"])
+@pytest.mark.parametrize("shape", SHAPES, ids=str)
+@pytest.mark.parametrize("form", FORMS, ids=str)
+def test_values_and_every_gradient_match_the_recurrence(form, shape, A):
+    B, S, H, P, G, N, chunk = SHAPES[shape]
+    args = _inputs(B, S, H, P, G, N, A)
+    scan = FORMS[form](chunk)
+    want = ssd.ssd_recurrence(*args)
+    got = scan(*args)
+    assert got.shape == want.shape == (B, S, H, P)
+    assert _rel(got, want) < 2e-6
+    w = jax.random.normal(jax.random.PRNGKey(7), want.shape)
+
+    def grads(fn):
+        return jax.grad(lambda *a: jnp.sum(fn(*a) * w),
+                        argnums=tuple(range(6)))(*args)
+
+    for name, a, b in zip("x dt A B C D".split(), grads(scan),
+                          grads(ssd.ssd_recurrence)):
+        assert a.shape == b.shape
+        assert _rel(a, b) < 1e-4, (name, _rel(a, b))
+
+
+@pytest.mark.parametrize("form", FORMS, ids=str)
+def test_a_token_sees_the_chunks_before_it_and_none_after(form):
+    """Changing a token in the LAST chunk leaves every earlier output as it
+    was; changing one in the FIRST moves outputs two chunks on (weak decay:
+    the state carries)."""
+    args = _inputs(1, 48, 2, 8, 1, 8, 0.05, seed=3)
+    scan = FORMS[form](16)
+    base = scan(*args)
+    x = args[0]
+    late = scan(x.at[0, 40].add(1.0), *args[1:])
+    np.testing.assert_array_equal(late[0, :40], base[0, :40])
+    early = scan(x.at[0, 3].add(1.0), *args[1:])
+    assert float(jnp.abs(early[0, 40:] - base[0, 40:]).max()) > 1e-3
+
+
+def test_strong_decay_neither_overflows_nor_leaks_across_chunks():
+    """A = -16 at steps near 6: exp(dt A) underflows to 0 inside a chunk.
+    Every exponent the chunked forms take is <= 0, so nothing overflows,
+    and the outputs are the skip and the token's own write alone."""
+    x, dt, a, Bm, Cm, D = _inputs(1, 32, 2, 8, 1, 8, 16.0, dt_bias=8.0)
+    for form in FORMS.values():
+        got = form(16)(x, dt, a, Bm, Cm, D)
+        assert bool(jnp.all(jnp.isfinite(got)))
+        own = jnp.einsum("bsgn,bsgn->bsg", Cm, Bm)[..., None] \
+            * (dt[..., None] * x) + D[:, None] * x
+        np.testing.assert_allclose(got, own, atol=1e-4)
+
+
+def test_the_dispatcher_takes_the_kernel_off_the_tpu_and_gauges_it():
+    """``ssd_scan`` off a TPU runs the kernels in the interpreter at any
+    shape whose heads divide into the groups; ``takes_kernel`` says what a
+    TPU takes: whole-vreg lane blocks (a head of 128, or two of 64), a
+    state of whole vregs, a chunk of 128."""
+    from deepspeed_tpu.telemetry.registry import default_registry
+    args = _inputs(1, 32, 4, 8, 2, 16, 1.0)
+    got = ssd.ssd_scan(*args, chunk=16)
+    assert default_registry().peek_gauge("ssm/ssd_kernel_heads_per_step") == 2
+    np.testing.assert_allclose(got, ssd.ssd_recurrence(*args), atol=2e-5)
+    ssd.ssd_scan_xla(*args, chunk=16)
+    assert default_registry().peek_gauge("ssm/ssd_kernel_heads_per_step") == 0
+    takes = kernels.takes_kernel
+    assert takes(64, 64, 8, 128, 128, tpu=True)         # the published layer
+    assert takes(8, 128, 8, 128, 128, tpu=True)
+    assert not takes(64, 64, 64, 128, 128, tpu=True)    # one head of 64
+    assert not takes(64, 64, 8, 64, 128, tpu=True)      # half a vreg of state
+    assert not takes(64, 64, 8, 128, 64, tpu=True)
+    assert not takes(64, 32, 8, 128, 128, tpu=True)
+    assert takes(4, 8, 2, 16, 16, tpu=False)
+    assert not takes(6, 8, 4, 16, 16, tpu=False)        # 6 heads, 4 groups
+
+
+def test_the_plan_packs_two_heads_of_64_and_keeps_a_state_a_chunk():
+    plan = kernels._plan_for(1, 16384, 64, 8, 64, 128, 128)
+    assert (plan.hg, plan.pack, plan.W, plan.blocks) == (8, 2, 128, 4)
+    assert (plan.cb, plan.nb) == (8, 16)
+    kept = kernels._states_shape(plan)
+    assert kept.shape == (1, 32, 128, 128, 128) and kept.dtype == jnp.float32
+    assert np.prod(kept.shape) * 4 == 268_435_456      # 268 MB a layer
+    assert kernels._plan_for(1, 40, 4, 2, 8, 16, 16).pack == 1

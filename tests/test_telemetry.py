@@ -423,6 +423,45 @@ def test_qwen3_next_scope_names_and_gauges_reach_the_step():
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
 
 
+def test_nemotron_h_scope_names_and_gauges_reach_the_step():
+    """ISSUE 40's names: a Nemotron-H model carries the Mamba-2 scopes
+    (``ssm_conv`` / ``ssm_gates`` / ``ssd_scan*`` / ``ssm_norm`` under the
+    module ``mamba``) and the expert scopes under ``mixer`` in its compiled
+    step's ``op_name``s; the scan's kernels took the call; and a layer
+    without an auxiliary loss sows neither ``moe/aux_loss`` nor
+    ``moe/z_loss``."""
+    import re
+    from deepspeed_tpu.models.nemotron_h import (NemotronHForCausalLM,
+                                                 nemotron_h_tiny)
+    default_registry().reset()
+    cfg = nemotron_h_tiny(hybrid_override_pattern="ME*", experts_held=4,
+                          loss_chunk=16)
+    engine, _, _, _ = dstpu.initialize(config=base_config(),
+                                       model=NemotronHForCausalLM(cfg))
+    batch = {"input_ids": np.random.RandomState(0).randint(
+        0, 256, (8, 32)).astype(np.int32)}
+    engine.train_batch(batch)
+    gauges = engine.telemetry_flush()["gauges"]
+    assert {"moe/rows_max_over_mean", "moe/dropped_rows",
+            "moe/rows_held_share", "moe/held_slabs",
+            "moe/combine_rows_walked"} <= set(gauges)
+    assert not {"moe/aux_loss", "moe/z_loss"} & set(gauges)
+    assert gauges["moe/dropped_rows"] == 0
+    # the scan's kernels took the call (the interpreter, off the TPU): a
+    # group's two heads a grid step
+    assert gauges["ssm/ssd_kernel_heads_per_step"] == 2
+    hlo = engine.lower_train_step(batch).compile().as_text()
+    for scope in ("mamba/ssm_conv", "mamba/ssm_gates",
+                  # per device inside a shard_map on this mesh of eight
+                  "mamba/shard_map/ssd_scan_prep",
+                  "mamba/shard_map/ssd_scan_fwd",
+                  "mamba/shard_map/ssd_scan_bwd", "mamba/ssm_norm",
+                  "mixer/moe_shared", "mixer/moe_router", "moe_dispatch",
+                  "moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs", "moe_combine",
+                  "mixer/q_proj", "ds_embed", "ds_loss_head"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+
+
 def test_laguna_scope_names_and_gauges_reach_the_step():
     """ISSUE 33's names: a Laguna model whose sliding layers take the window
     kernels (``use_flash``: the interpreter here) carries ``swa_fwd`` /

@@ -219,14 +219,18 @@ def test_flash_attention_chunked_fwd_and_grad_compile_at_olmoe_shape():
     (131072, 64, 2048, 1024),       # OLMoE's layer
     (49152, 16, 2560, 768),         # SmallThinker's slab, gate / up ...
     (49152, 16, 768, 2560),         # ... and down: no powers of two
-], ids=["olmoe", "smallthinker_up", "smallthinker_down"])
+    (6144, 8, 2688, 1856),          # Nemotron-3-Nano's up: 1,856 = 29 x 64,
+    (6144, 8, 1856, 2688),          # ... and down, padded to 1,920 lanes
+], ids=["olmoe", "smallthinker_up", "smallthinker_down", "nemotron_up",
+        "nemotron_down"])
 def test_grouped_matmul_fwd_and_grads_compile_at_olmoe_shape(m, g, k, n):
     """131,072 routed rows x 2048 against 64 experts' [2048, 1024]: the
     forward product and both gradients (dlhs against the transposed weights,
     drhs the per-group outer products), three Pallas calls, each under a
     ``moe_gmm*`` scope; the group sizes are an operand, not a shape. And at
     widths of 5 x 512 and 3 x 256, with the divisor tiles ``_clip`` gives
-    them (PR 38)."""
+    them (PR 38); and at a width no multiple of 128 divides, which the call
+    pads to the next one (PR 40)."""
     from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
     def bank(lhs, rhs, sizes):
@@ -691,6 +695,39 @@ def test_gated_delta_rule_fwd_and_grad_compile_at_qwen3_next_shape(
     # the forward rule's states (268 MB) and nothing of [N, B, H, C, D]
     assert compiled.memory_analysis().peak_memory_in_bytes < (
         2e9 if kernels else 8e9)
+
+
+def test_ssd_scan_fwd_and_grad_compile_at_nemotron_h_shape():
+    """1 x 16,384 tokens, 64 heads of 64 in 8 groups, state 128, chunk 128,
+    bf16 operands and float32 gates (the ``nemotron3nano-train-1chip-s16384``
+    cell's Mamba-2 layer), forward and backward under the scopes the
+    benchmark's ``ssd_scan_*`` readers sum: two heads side by side a lane
+    block, a group's eight heads a grid step; what is kept for the backward
+    pass is a float32 state a chunk (268 MB) and nothing of [c, c] size."""
+    from deepspeed_tpu.ops.ssd import ssd_scan
+
+    def scan(*a):
+        with jax.named_scope("mamba"):
+            return ssd_scan(*a).astype(F32).sum()
+
+    def grads(*a):
+        return jax.grad(scan, argnums=tuple(range(6)))(*a)
+
+    text, compiled = compile_on_chip(
+        grads, SDS((1, 16384, 64, 64), BF16), SDS((1, 16384, 64), F32),
+        SDS((64,), F32), SDS((1, 16384, 8, 128), BF16),
+        SDS((1, 16384, 8, 128), BF16), SDS((64,), F32))
+    assert kernel_names(text) == {"_ssd_fwd_kernel", "_ssd_bwd_kernel"}
+    assert default_registry().peek_gauge(
+        "ssm/ssd_kernel_heads_per_step") == 8
+    hlo = compiled.as_text()
+    for scope in ("ssd_scan_prep/", "ssd_scan_fwd/", "ssd_scan_bwd/"):
+        assert re.search(r'op_name="[^"]*/' + scope, hlo), scope
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 2 and all(re.search(
+        r'op_name="[^"]*/ssd_scan_(fwd|bwd)/', ln) for ln in calls)
+    assert "f32[1,32,128,128,128]" in hlo       # the kept states, and ...
+    assert compiled.memory_analysis().peak_memory_in_bytes < 1.2e9
 
 
 @pytest.mark.slow
