@@ -9,12 +9,16 @@ here). The published NVIDIA-Nemotron-3-Nano-30B-A3B is 52 such layers, 23 /
   (``d_inner = mamba_num_heads x mamba_head_dim``; ``xBC`` is ``d_inner + 2
   n_groups ssm_state_size`` wide); ``xBC = silu(conv(xBC) + bias)``, a causal
   depthwise convolution of ``conv_kernel`` taps over all of it
-  (``ssm_conv``); ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)`` one
+  (``ssm_conv``: ``ops/mixer_elementwise.conv_act``, which reads xBC's
+  columns where they lie in the projection's output and hands x, B and C
+  back as the scan takes them); ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)`` one
   scalar a head, float32 (``ssm_gates``); the state-space scan
   ``ops/ssd.ssd_scan`` over x [heads x head_dim] with B, C [groups x state]
   shared by a group's heads and the skip ``D x`` (``ssd_scan*``); ``y *
   silu(z)`` FIRST and then an RMSNorm over groups of ``d_inner / n_groups``
-  channels (``ssm_norm``); ``out_proj``. No bias but the convolution's.
+  channels (``ssm_norm``: ``ops/mixer_elementwise.gated_group_norm`` with
+  the gate before the norm, z read out of the projection's output);
+  ``out_proj``. No bias but the convolution's.
 - **Experts** (``mixer`` of an ``E`` layer, ``moe/dropless.DroplessMoE``):
   a float32 router scored by each expert's own SIGMOID; the
   ``num_experts_per_tok`` experts are the largest of ``score +
@@ -51,9 +55,9 @@ from jax.ad_checkpoint import checkpoint_name
 from deepspeed_tpu.models.gpt2 import _embed_lookup, chunked_lm_loss, lm_loss
 from deepspeed_tpu.models.laguna import FULL, LagunaAttention, remat_block
 from deepspeed_tpu.models.llama import RMSNorm
-from deepspeed_tpu.models.qwen3_next import causal_depthwise_conv
 from deepspeed_tpu.moe.dropless import (CHOICE_BIAS, HELD_STAT_GAUGES,
                                         STAT_GAUGES, DroplessMoE)
+from deepspeed_tpu.ops.mixer_elementwise import conv_act, gated_group_norm
 from deepspeed_tpu.ops.ssd import ssd_scan
 from deepspeed_tpu.telemetry.spans import annotate
 
@@ -200,6 +204,10 @@ def _conv_init(cfg):
 
 
 class Mamba2Mixer(nn.Module):
+    """The Mamba-2 branch: two projections round ``ssd_scan``, and round
+    the scan the two elementwise stages of ``ops/mixer_elementwise.py``
+    (convolution + SiLU before it, gate + grouped RMS norm after it), each
+    one pass over HBM where the kernels take the shapes."""
     config: NemotronHConfig
 
     @nn.compact
@@ -210,8 +218,6 @@ class Mamba2Mixer(nn.Module):
         G, N = cfg.n_groups, cfg.ssm_state_size
         d_inner, f32 = cfg.d_inner, jnp.float32
         zxbcdt = _dense(cfg, d_inner + cfg.conv_dim + H, "in_proj")(x)
-        z = zxbcdt[..., :d_inner]
-        xBC = zxbcdt[..., d_inner:d_inner + cfg.conv_dim]
         dt = zxbcdt[..., d_inner + cfg.conv_dim:]
         taps = self.param("conv", _conv_init(cfg),
                           (cfg.conv_kernel, cfg.conv_dim), cfg.param_dtype)
@@ -220,32 +226,28 @@ class Mamba2Mixer(nn.Module):
                              cfg.param_dtype)
         skip = self.param("D", nn.initializers.ones, (H,), cfg.param_dtype)
         with annotate("ssm_conv"):
-            xBC = causal_depthwise_conv(xBC, taps.astype(cfg.dtype))
-            if cfg.use_conv_bias:
-                xBC = xBC + self.param(
+            # x | B | C out of the projection's output by column offset (z
+            # lies before them), each as the scan reads it
+            xs, Bm, Cm = conv_act(
+                zxbcdt, taps, self.param(
                     "conv_bias", _conv_init(cfg), (cfg.conv_dim,),
-                    cfg.param_dtype).astype(cfg.dtype)
-            xBC = nn.silu(xBC)
+                    cfg.param_dtype) if cfg.use_conv_bias else None,
+                offset=d_inner, runs=((d_inner, None), (G * N, None),
+                                      (G * N, None)))
         with annotate("ssm_gates"):
             dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
             A = -jnp.exp(a_log.astype(f32))
-        xs = xBC[..., :d_inner].reshape(B, S, H, P)
-        Bm = xBC[..., d_inner:d_inner + G * N].reshape(B, S, G, N)
-        Cm = xBC[..., d_inner + G * N:].reshape(B, S, G, N)
-        y = ssd_scan(xs, dt, A, Bm, Cm, skip.astype(f32),
+        y = ssd_scan(xs.reshape(B, S, H, P), dt, A, Bm.reshape(B, S, G, N),
+                     Cm.reshape(B, S, G, N), skip.astype(f32),
                      chunk=cfg.chunk_size)
         w = self.param("norm", nn.initializers.ones, (d_inner,),
                        cfg.param_dtype)
         with annotate("ssm_norm"):
-            # the gate BEFORE the norm; the norm over each group's channels
-            # (a group a ROW of a two-dimensional array: over [B, S, G, 512]
-            # XLA lays the groups out ahead of the tokens and copies back,
-            # 268 MB a copy, three a layer: my chip run, PR 40)
-            yf = y.reshape(B, S, d_inner).astype(f32) * nn.silu(z.astype(f32))
-            yf = yf.reshape(B * S * G, d_inner // G)
-            yf = yf * jax.lax.rsqrt(jnp.mean(yf * yf, axis=-1, keepdims=True)
-                                    + cfg.layer_norm_epsilon)
-            y = (yf.reshape(B, S, d_inner) * w.astype(f32)).astype(cfg.dtype)
+            # the gate z (the projection's first columns, read where they
+            # lie) BEFORE the norm; the norm over each group's channels
+            y = gated_group_norm(
+                y.reshape(B, S, d_inner), zxbcdt, w, group=d_inner // G,
+                eps=cfg.layer_norm_epsilon, gate_first=True)
         return checkpoint_name(_dense(cfg, cfg.hidden_size, "out_proj")(y),
                                "attn_proj")
 

@@ -11,11 +11,16 @@ ZERO-CENTRED: ``x / rms(x) * (1 + w)``, ``w`` zero at initialisation.
 
 - **Gated DeltaNet** (``linear_attn``): one projection to q, k, v, z and one
   to b, a; a causal depthwise convolution of ``linear_conv_kernel_dim`` taps
-  and SiLU over q | k | v (``gdn_conv``); ``beta = sigmoid(b)``, ``g =
-  -exp(A_log) softplus(a + dt_bias)`` in float32 and the L2 norms of q and k
-  (``gdn_gates``); the chunked gated delta rule (``ops/gated_delta.py``,
-  ``gdn_scan*``); an RMSNorm over each head's output times ``silu(z)``
-  (``gdn_out_norm``); the output projection. The fused projection's columns
+  and SiLU over q | k | v, with the L2 norm of every head of q and k (and
+  q's ``Dk^-0.5``) in the same pass (``gdn_conv``:
+  ``ops/mixer_elementwise.conv_act``, which reads the columns where they
+  lie in the projection's output and hands q, k and v back as the scan
+  takes them); ``beta = sigmoid(b)``, ``g = -exp(A_log) softplus(a +
+  dt_bias)`` in float32 (``gdn_gates``); the chunked gated delta rule
+  (``ops/gated_delta.py``, ``gdn_scan*``); an RMSNorm over each head's
+  output times ``silu(z)`` (``gdn_out_norm``:
+  ``ops/mixer_elementwise.gated_group_norm`` with the gate after the norm,
+  z read out of the projection's output); the output projection. The fused projection's columns
   are q | k | v | z, each head-major (HF groups them per key head: q, k,
   its value heads' v, their z; a relabelling of columns).
 - **Gated attention** (``attn``): ``q_proj`` gives query and gate per head;
@@ -55,6 +60,7 @@ from deepspeed_tpu.moe.dropless import (HELD_STAT_GAUGES, STAT_GAUGES,
                                         DroplessMoE)
 from deepspeed_tpu.ops.attention import dot_product_attention
 from deepspeed_tpu.ops.gated_delta import gated_delta_rule
+from deepspeed_tpu.ops.mixer_elementwise import conv_act, gated_group_norm
 from deepspeed_tpu.telemetry.spans import annotate
 
 
@@ -151,20 +157,6 @@ def _dense(cfg, n, name):
                     kernel_init=nn.initializers.normal(0.02), name=name)
 
 
-def causal_depthwise_conv(x, taps):
-    """[B, S, C] through a causal depthwise convolution, ``taps`` [W, C]:
-    ``y_t = sum_j taps[j] * x_(t - W + 1 + j)``, zeros before the start."""
-    W = taps.shape[0]
-    S = x.shape[1]
-    xp = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))
-    return sum(xp[:, j:j + S] * taps[j] for j in range(W))
-
-
-def _l2_normalise(x, eps=1e-6):
-    xf = x.astype(jnp.float32)
-    return xf * jax.lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True) + eps)
-
-
 def _a_log_init(key, shape, dtype):
     # HF: A = uniform(0, 16); A_log = log(A)
     return jnp.log(jax.random.uniform(key, shape, jnp.float32, 0.0,
@@ -172,6 +164,11 @@ def _a_log_init(key, shape, dtype):
 
 
 class GatedDeltaNet(nn.Module):
+    """The linear-attention branch: three projections round
+    ``gated_delta_rule``, and round the rule the two elementwise stages of
+    ``ops/mixer_elementwise.py`` (convolution + SiLU + q / k L2 norm before
+    it, per-head RMS norm + gate after it), each one pass over HBM where
+    the kernels take the shapes."""
     config: Qwen3NextConfig
 
     @nn.compact
@@ -181,7 +178,6 @@ class GatedDeltaNet(nn.Module):
         Hk, Dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
         Hv, Dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
         key, val = Hk * Dk, Hv * Dv
-        dt = cfg.dtype
         qkvz = _dense(cfg, 2 * key + 2 * val, "in_proj_qkvz")(x)
         ba = _dense(cfg, 2 * Hv, "in_proj_ba")(x)
         taps = self.param("conv", nn.initializers.normal(0.02),
@@ -190,27 +186,26 @@ class GatedDeltaNet(nn.Module):
         a_log = self.param("A_log", _a_log_init, (Hv,), cfg.param_dtype)
         dt_bias = self.param("dt_bias", nn.initializers.ones, (Hv,),
                              cfg.param_dtype)
-        qkv, z = qkvz[..., :2 * key + val], qkvz[..., 2 * key + val:]
         with annotate("gdn_conv"):
-            qkv = nn.silu(causal_depthwise_conv(qkv, taps.astype(dt)))
+            # q | k | v out of the projection's output by column offset,
+            # each as the scan reads it: q and k leave L2-normalised a head
+            q, k, v = conv_act(
+                qkvz, taps, head_width=Dk,
+                runs=((key, Dk ** -0.5), (key, 1.0), (val, None)))
         with annotate("gdn_gates"):
             b, a = (t.astype(jnp.float32) for t in jnp.split(ba, 2, axis=-1))
             beta = jax.nn.sigmoid(b)
             g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
                 a + dt_bias.astype(jnp.float32))
-            q = qkv[..., :key].reshape(B, S, Hk, Dk)
-            k = qkv[..., key:2 * key].reshape(B, S, Hk, Dk)
-            v = qkv[..., 2 * key:].reshape(B, S, Hv, Dv)
-            q = (_l2_normalise(q) * Dk ** -0.5).astype(dt)
-            k = _l2_normalise(k).astype(dt)
-        o = gated_delta_rule(q, k, v, g, beta)              # [B, S, Hv, Dv]
+        o = gated_delta_rule(q.reshape(B, S, Hk, Dk), k.reshape(B, S, Hk, Dk),
+                             v.reshape(B, S, Hv, Dv), g, beta)
         w = self.param("norm", nn.initializers.ones, (Dv,), cfg.param_dtype)
         with annotate("gdn_out_norm"):
-            of = o.astype(jnp.float32)
-            of = of * jax.lax.rsqrt(jnp.mean(of * of, axis=-1, keepdims=True)
-                                    + cfg.rms_norm_eps)
-            of = of * w.astype(jnp.float32) * nn.silu(z.reshape(B, S, Hv, Dv).astype(jnp.float32))
-            o = of.astype(dt).reshape(B, S, val)
+            # a head's RMS norm, one weight for every head, then the gate z
+            # (the projection's last columns, read where they lie)
+            o = gated_group_norm(
+                o.reshape(B, S, val), qkvz, w, group=Dv,
+                eps=cfg.rms_norm_eps, gate_first=False, offset=2 * key + val)
         return checkpoint_name(_dense(cfg, cfg.hidden_size, "out_proj")(o),
                                "attn_proj")
 

@@ -44,6 +44,13 @@ that one algorithm runs:
   cotangent is the closed form. It is also the kernels' second oracle,
   beside ``gated_delta_recurrence``.
 
+What a Gated DeltaNet layer does to q, k, v before the rule (a causal
+depthwise convolution, SiLU, and the L2 norm of every head of q and k) and
+to o after it (a head's RMS norm and the gate) is
+``ops/mixer_elementwise.py``: its kernels write q, k and v as the
+[B, S, H*D] column blocks the rule's kernels read, normalised, and read o
+as they write it — no copy or transpose stands between them.
+
 No option selects a form. Which one took a call: the trace-time gauges
 ``linear_attn/gdn_kernel_heads_per_step`` (value heads a grid step; 0 = the
 XLA form) and ``linear_attn/gdn_states_kept_every`` (chunks between kept

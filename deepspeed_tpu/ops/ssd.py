@@ -34,6 +34,12 @@ algorithm runs is decided at trace time from the shapes and the backend
   inter-chunk term; its backward pass is JAX's own. It is also the kernels'
   second oracle beside ``ssd_recurrence``.
 
+What a Mamba-2 layer does to x, B, C before the scan (a causal depthwise
+convolution and SiLU) and to y after it (the gate and a grouped RMS norm)
+is ``ops/mixer_elementwise.py``: its kernels write x, B and C as the
+[B, S, H*P] / [B, S, G*N] column blocks the scan's kernels read, and read y
+as they write it — no copy or transpose stands between them.
+
 No option selects a form; the trace-time gauge ``ssm/ssd_kernel_heads_per_step``
 (heads a grid step; 0 = the XLA form) and the kernels' log line say which
 one took a call. State and gates are float32 in both; matmul operands are

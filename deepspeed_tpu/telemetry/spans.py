@@ -143,8 +143,8 @@ def annotate(tag):
       form): ``gdn_scan_share`` and ``gdn_scan_roofline`` (one tag, by
       prefix);
     - ``gdn_conv``, ``gdn_gates``, ``gdn_out_norm`` (models/qwen3_next.py:
-      the causal depthwise convolution and its SiLU; beta, the decay and
-      the L2 norms of q and k; the gated RMSNorm of the output): with
+      the causal depthwise convolution, its SiLU and the L2 norms of q and
+      k; beta and the decay; the gated RMSNorm of the output): with
       ``gdn_scan*`` and the module name ``linear_attn``, ``gdn_layer_ms``;
     - ``attn_gate`` (models/qwen3_next.py, models/laguna.py: the
       attention output times ``sigmoid(gate)``, element-wise there, one
@@ -160,7 +160,13 @@ def annotate(tag):
     - ``ssm_conv``, ``ssm_gates``, ``ssm_norm`` (models/nemotron_h.py: the
       causal depthwise convolution with bias and its SiLU; the softplus of
       the steps and the decay; the gate and the grouped RMSNorm): with
-      ``ssd_scan*`` and the module name ``mamba``, ``ssm_layer_ms``.
+      ``ssd_scan*`` and the module name ``mamba``, ``ssm_layer_ms``;
+    - ``mixer_conv_fwd``, ``mixer_conv_bwd``, ``mixer_norm_fwd``,
+      ``mixer_norm_bwd`` (ops/pallas/mixer_elementwise.py, round the four
+      ``pallas_call``s of the mixers' elementwise stages, INSIDE
+      ``gdn_conv`` / ``ssm_conv`` and ``gdn_out_norm`` / ``ssm_norm``): no
+      metric reads them by name — they start with no kernel tag, so their
+      time stays under the module scope round them.
 
     - ``swa_fwd``, ``swa_bwd_dq``, ``swa_bwd_dkv``
       (ops/pallas/flash_attention.py, round the three ``pallas_call``s of

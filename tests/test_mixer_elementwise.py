@@ -1,0 +1,239 @@
+"""The mixers' elementwise kernels (``ops/mixer_elementwise.py``,
+``ops/pallas/mixer_elementwise.py``) in the interpreter: forward and every
+gradient against the XLA forms that stand beside them and against float32
+references written here, and the fall to the XLA forms."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.ops import mixer_elementwise as entry
+from deepspeed_tpu.ops.pallas import mixer_elementwise as kernels
+from deepspeed_tpu.telemetry.registry import default_registry
+
+F32 = jnp.float32
+
+
+def conv_reference(x, taps, bias, offset, l2_scale, eps=entry.L2_EPS):
+    """float32, written out: the convolution as a loop over the taps of a
+    padded array, SiLU, the L2 norm over heads of 128."""
+    W, C = taps.shape
+    xf = x[..., offset:offset + C].astype(F32)
+    S = xf.shape[1]
+    xp = jnp.concatenate([jnp.zeros_like(xf[:, :W - 1]), xf], axis=1)
+    p = sum(xp[:, j:j + S] * taps[j].astype(F32) for j in range(W))
+    if bias is not None:
+        p = p + bias.astype(F32)
+    y = p * jax.nn.sigmoid(p)
+    if l2_scale is not None:
+        h = y.reshape(*y.shape[:2], C // 128, 128)
+        h = h / jnp.sqrt(jnp.sum(h * h, axis=-1, keepdims=True) + eps)
+        y = (h * l2_scale).reshape(y.shape)
+    return y
+
+
+def norm_reference(y, z, w, offset, group, eps, gate_first):
+    B, S, D = y.shape
+    gate = jax.nn.silu(z[..., offset:offset + D].astype(F32))
+    u = y.astype(F32) * gate if gate_first else y.astype(F32)
+    g = u.reshape(B, S, D // group, group)
+    g = g / jnp.sqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+    out = g.reshape(B, S, D) * jnp.tile(w.astype(F32), D // w.shape[0])
+    return out if gate_first else out * gate
+
+
+def rel(a, b):
+    a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+    return float(np.linalg.norm(a - b) / (np.linalg.norm(b) + 1e-30))
+
+
+def draw(seed, *shapes, dtype=F32):
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
+    return [jax.random.normal(k, s, F32).astype(dtype)
+            for k, s in zip(keys, shapes)]
+
+
+# bf16 rounds a result to 2^-9; a float32 kernel differs from the float32
+# reference by the order of its sums alone
+LIMIT = {"float32": 2e-5, "bfloat16": 6e-3}
+
+
+@pytest.mark.parametrize("dtype,B,S,rows,bias,l2,offset,total,C", [
+    ("float32", 1, 256, 128, False, None, 0, 256, 256),  # 2 blocks x 2 tiles
+    # history resets at a sequence's start; an offset; a ragged wide array
+    ("float32", 2, 128, 64, True, None, 128, 600, 256),
+    ("float32", 2, 128, 64, False, 0.5, 0, 384, 256),    # q: norm and scale
+    ("bfloat16", 2, 128, 64, True, None, 128, 600, 256),
+    ("bfloat16", 1, 128, 64, True, 1.0, 256, 640, 128),  # bias, norm, offset
+])
+def test_conv_kernel_against_xla_and_reference(dtype, B, S, rows, bias, l2,
+                                               offset, total, C):
+    dt = jnp.dtype(dtype)
+    x, dy = draw(1, (B, S, total), (B, S, C), dtype=dt)
+    taps, b = draw(2, (4, C), (C,))
+    taps, b = taps * 0.5, (b * 0.3 if bias else None)
+    assert kernels.conv_takes(S, C, total, offset, 4, l2 and 128, rows)
+
+    def kernel(x, taps, b):
+        return kernels.conv_act_kernel(x, taps, b, offset=offset,
+                                       l2_scale=l2, eps=entry.L2_EPS,
+                                       interpret=True, block_rows=rows)
+
+    def xla(x, taps, b):
+        return entry.conv_act_xla(x, taps, b, offset=offset,
+                                  runs=((C, l2),), head_width=128)[0]
+
+    def reference(x, taps, b):
+        return conv_reference(x, taps, b, offset, l2)
+
+    def grads(fn):
+        def loss(x, taps, b):
+            out = fn(x, taps, b)
+            return jnp.sum(out.astype(F32) * dy.astype(F32)), out
+        (_, out), g = jax.value_and_grad(
+            loss, argnums=(0, 1, 2) if bias else (0, 1), has_aux=True)(
+            x, taps, b)
+        return out, g
+
+    (got, g_kernel), (want, g_ref) = grads(kernel), grads(reference)
+    from_xla, g_xla = grads(xla)
+    assert got.dtype == dt and got.shape == (B, S, C)
+    assert rel(got, want) < LIMIT[dtype]
+    # the XLA form multiplies and sums in the inputs' dtype: no nearer to
+    # the reference than the kernel
+    assert rel(got, want) <= rel(from_xla, want) + LIMIT[dtype] / 10
+    for name, a, r, o in zip(("dx", "dtaps", "dbias"), g_kernel, g_ref,
+                             g_xla):
+        assert a.shape == r.shape and a.dtype == r.dtype, name
+        assert rel(a, r) < 2 * LIMIT[dtype], (name, rel(a, r))
+        assert rel(a, r) <= rel(o, r) + LIMIT[dtype], (name, rel(o, r))
+    # nothing flows into the columns the call does not read
+    dx = np.asarray(g_kernel[0], np.float32)
+    assert not dx[..., :offset].any() and not dx[..., offset + C:].any()
+
+
+@pytest.mark.parametrize(
+    "dtype,B,S,rows,group,gate_first,offset,total,D,shared", [
+        ("float32", 1, 128, 64, 128, False, 256, 512, 256, True),  # Qwen3-Next
+        ("float32", 2, 128, 64, 512, True, 0, 600, 512, False),    # Nemotron
+        ("float32", 1, 256, 128, 128, True, 0, 128, 128, False),   # two tiles
+        ("bfloat16", 1, 128, 64, 128, False, 256, 512, 256, True),
+        ("bfloat16", 2, 128, 64, 512, True, 0, 600, 512, False),
+    ])
+def test_norm_kernel_against_xla_and_reference(dtype, B, S, rows, group,
+                                               gate_first, offset, total, D,
+                                               shared):
+    dt = jnp.dtype(dtype)
+    y, z, do = draw(3, (B, S, D), (B, S, total), (B, S, D), dtype=dt)
+    w = 1.0 + 0.2 * draw(4, (group if shared else D,))[0]
+    eps = 1e-5
+    assert kernels.norm_takes(S, D, total, offset, group, rows)
+
+    def kernel(y, z, w):
+        return kernels.gated_group_norm_kernel(
+            y, z, jnp.tile(w, D // w.shape[0]), group=group, eps=eps,
+            gate_first=gate_first, offset=offset, interpret=True,
+            block_rows=rows)
+
+    def xla(y, z, w):
+        return entry.gated_group_norm_xla(
+            y, z, w, group=group, eps=eps, gate_first=gate_first,
+            offset=offset)
+
+    def reference(y, z, w):
+        return norm_reference(y, z, w, offset, group, eps, gate_first)
+
+    def grads(fn):
+        def loss(y, z, w):
+            out = fn(y, z, w)
+            return jnp.sum(out.astype(F32) * do.astype(F32)), out
+        (_, out), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                         has_aux=True)(y, z, w)
+        return out, g
+
+    (got, g_kernel), (want, g_ref) = grads(kernel), grads(reference)
+    from_xla, g_xla = grads(xla)
+    assert got.dtype == dt and got.shape == (B, S, D)
+    assert rel(got, want) < LIMIT[dtype]
+    assert rel(from_xla, want) < LIMIT[dtype]
+    for name, a, r, o in zip(("dy", "dz", "dw"), g_kernel, g_ref, g_xla):
+        assert a.shape == r.shape and a.dtype == r.dtype, name
+        assert rel(a, r) < 2 * LIMIT[dtype], (name, rel(a, r))
+        assert rel(o, r) < 2 * LIMIT[dtype], (name, rel(o, r))
+
+
+def sites():
+    snap = default_registry().snapshot(prefix="mixer/")["gauges"]
+    return {k.split("/")[1]: v for k, v in snap.items()}
+
+
+def test_entries_take_the_kernels_and_cut_the_runs():
+    """The entries as the two models call them: three runs out of a wide
+    projection (q and k normalised), one weight shared by every head."""
+    B, S, key, val = 2, 64, 256, 512
+    qkvz, = draw(5, (B, S, 2 * key + 2 * val), dtype=jnp.bfloat16)
+    taps, w = draw(6, (4, 2 * key + val), (128,))
+    runs = ((key, 128 ** -0.5), (key, 1.0), (val, None))
+    before = sites()
+    q, k, v = entry.conv_act(qkvz, taps, runs=runs, head_width=128)
+    oq, ok, ov = entry.conv_act_xla(qkvz, taps, runs=runs, head_width=128)
+    assert [t.shape[-1] for t in (q, k, v)] == [key, key, val]
+    for a, o in ((q, oq), (k, ok), (v, ov)):
+        assert a.dtype == jnp.bfloat16 and rel(a, o) < 1e-2
+    out = entry.gated_group_norm(v, qkvz, w, group=128, eps=1e-6,
+                                 gate_first=False, offset=2 * key + val)
+    want = entry.gated_group_norm_xla(v, qkvz, w, group=128, eps=1e-6,
+                                      gate_first=False,
+                                      offset=2 * key + val)
+    assert rel(out, want) < 1e-2
+    after = sites()
+    assert after["conv_kernel_sites"] == before.get("conv_kernel_sites",
+                                                    0) + 1
+    assert after["norm_kernel_sites"] == before.get("norm_kernel_sites",
+                                                    0) + 1
+    assert after["conv_xla_sites"] == before.get("conv_xla_sites", 0)
+    assert after["norm_xla_sites"] == before.get("norm_xla_sites", 0)
+
+
+@pytest.mark.parametrize("what,kw", [
+    ("width no multiple of 128", dict(C=192)),
+    ("offset no multiple of 128", dict(offset=64)),
+    ("no row block divides S", dict(S=96)),
+    ("heads of 64 under the L2 norm", dict(head=64)),
+])
+def test_conv_refused_shape_takes_the_xla_form(what, kw):
+    S, C, offset, head = (kw.get(k, d) for k, d in (
+        ("S", 64), ("C", 256), ("offset", 0), ("head", 128)))
+    x, = draw(7, (1, S, offset + C + 128))
+    taps, = draw(8, (4, C))
+    runs = ((C, 1.0),) if "head" in kw else ((C, None),)
+    before = sites()
+    got, = entry.conv_act(x, taps, offset=offset, runs=runs,
+                          head_width=head)
+    want, = entry.conv_act_xla(x, taps, offset=offset, runs=runs,
+                               head_width=head)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    after = sites()
+    assert after["conv_xla_sites"] == before.get("conv_xla_sites", 0) + 1
+    assert after["conv_kernel_sites"] == before.get("conv_kernel_sites", 0)
+
+
+@pytest.mark.parametrize("what,kw", [
+    ("group no multiple of 128", dict(group=64)),
+    ("gate at an offset no block divides", dict(offset=64)),
+    ("no row block divides S", dict(S=96)),
+])
+def test_norm_refused_shape_takes_the_xla_form(what, kw):
+    S, group, offset = (kw.get(k, d) for k, d in (
+        ("S", 64), ("group", 128), ("offset", 0)))
+    y, z = draw(9, (1, S, 256), (1, S, 256 + offset))
+    w, = draw(10, (256,))
+    args = dict(group=group, eps=1e-5, gate_first=True, offset=offset)
+    before = sites()
+    got = entry.gated_group_norm(y, z, w, **args)
+    want = entry.gated_group_norm_xla(y, z, w, **args)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    after = sites()
+    assert after["norm_xla_sites"] == before.get("norm_xla_sites", 0) + 1
+    assert after["norm_kernel_sites"] == before.get("norm_kernel_sites", 0)

@@ -730,6 +730,124 @@ def test_ssd_scan_fwd_and_grad_compile_at_nemotron_h_shape():
     assert compiled.memory_analysis().peak_memory_in_bytes < 1.2e9
 
 
+def mixer_sites():
+    """The ``mixer/*_sites`` gauges by their short names."""
+    return {name: default_registry().peek_gauge(f"mixer/{name}_sites") or 0
+            for name in ("conv_kernel", "conv_xla", "norm_kernel",
+                         "norm_xla")}
+
+
+def mixer_calls(hlo):
+    """{the scope round a mixer kernel's call: the kernels' scopes under
+    it}, from the compiled text's Pallas calls."""
+    found = {}
+    for ln in hlo.splitlines():
+        m = "tpu_custom_call" in ln and re.search(
+            r'op_name="[^"]*/(\w+)/(mixer_\w+)/pallas_call', ln)
+        if m:
+            found.setdefault(m.group(1), set()).add(m.group(2))
+    return found
+
+
+@pytest.mark.parametrize("stage,scope,shapes,kw", [
+    ("conv", "gdn_conv", (SDS((2, 8192, 12288), BF16), SDS((4, 8192), F32)),
+     dict(runs=((2048, 128 ** -0.5), (2048, 1.0), (4096, None)),
+          head_width=128)),
+    ("conv", "ssm_conv", (SDS((1, 16384, 10304), BF16), SDS((4, 6144), F32),
+                          SDS((6144,), F32)),
+     dict(offset=4096, runs=((4096, None), (1024, None), (1024, None)))),
+    ("norm", "gdn_out_norm", (SDS((2, 8192, 4096), BF16),
+                              SDS((2, 8192, 12288), BF16), SDS((128,), F32)),
+     dict(group=128, eps=1e-6, gate_first=False, offset=8192)),
+    ("norm", "ssm_norm", (SDS((1, 16384, 4096), BF16),
+                          SDS((1, 16384, 10304), BF16), SDS((4096,), F32)),
+     dict(group=512, eps=1e-5, gate_first=True)),
+], ids=["qwen3_next_conv", "nemotron_conv", "qwen3_next_norm",
+        "nemotron_norm"])
+def test_mixer_elementwise_fwd_and_grad_compile_at_the_cells_shapes(
+        stage, scope, shapes, kw):
+    """The two recurrent mixers' elementwise stages at the shapes of the
+    ``qwen3next-train-1chip-s8192`` and ``nemotron3nano-train-1chip-s16384``
+    cells, bf16 in and out, forward and every gradient: the Pallas kernels
+    take the call (columns read by offset out of the wide projection, no
+    slice of it formed), under the scope the model puts round them."""
+    from deepspeed_tpu.ops import mixer_elementwise as mixer
+    entry = mixer.conv_act if stage == "conv" else mixer.gated_group_norm
+    before = mixer_sites()
+
+    def total(*a):
+        # a scope round the stage's, as the model's module is: JAX writes
+        # the transform round the FIRST scope inside it
+        with jax.named_scope("layer"), jax.named_scope(scope):
+            out = entry(*a, **kw)
+        return sum(t.astype(F32).sum() for t in jax.tree_util.tree_leaves(
+            out))
+
+    def both(*a):
+        return jax.value_and_grad(total, argnums=tuple(range(len(a))))(*a)
+
+    text, compiled = compile_on_chip(both, *shapes)
+    assert kernel_names(text) == {f"_mixer_{stage}_fwd_kernel",
+                                  f"_mixer_{stage}_bwd_kernel"}
+    after = mixer_sites()
+    assert after[f"{stage}_kernel"] == before[f"{stage}_kernel"] + 1
+    assert after[f"{stage}_xla"] == before[f"{stage}_xla"]
+    hlo = compiled.as_text()
+    assert mixer_calls(hlo) == {scope: {f"mixer_{stage}_fwd",
+                                        f"mixer_{stage}_bwd"}}
+    # no slice of the wide array (the projection's output, the operand with
+    # most columns): only Pallas calls read it
+    columns = max(shapes, key=lambda t: t.shape[-1] * (len(t.shape) == 3))
+    wide = re.escape(f"bf16[{','.join(map(str, columns.shape))}]")
+    readers = [ln for ln in hlo.splitlines() if re.search(
+        r"= [^=]*\b(fusion|slice|copy)\(.*" + wide, ln)]
+    assert readers == [], readers[:2]
+    # bf16 blocks in, bf16 blocks out, float32 only the parameters' sums
+    assert compiled.memory_analysis().peak_memory_in_bytes < 1.6e9
+
+
+@pytest.mark.parametrize("cell,module,scopes", [
+    ("qwen3next-train-1chip-s8192", "GatedDeltaNet",
+     {"gdn_conv": "conv", "gdn_out_norm": "norm"}),
+    ("nemotron3nano-train-1chip-s16384", "Mamba2Mixer",
+     {"ssm_conv": "conv", "ssm_norm": "norm"})])
+def test_recurrent_mixer_layer_compiles_with_the_mixer_kernels_in_its_scopes(
+        cell, module, scopes):
+    """One recurrent mixer of each cell at the cell's configuration and
+    batch (the benchmark's own file, through its family), forward and
+    gradient under remat as a block runs it: the four kernel names sit under
+    the scopes whose rows the benchmark sums into ``gdn_layer_ms`` /
+    ``ssm_layer_ms``, and no site fell to the XLA forms."""
+    import importlib
+    from benchmark import manifest
+    bench = manifest.load()
+    entry = manifest.cell_of(bench, cell)
+    config = manifest.config_of(bench, entry)
+    family = manifest.family_module(config)
+    cfg = family.model_config(config, False)
+    traffic = manifest.traffic_of(entry)
+    models = importlib.import_module(family._model(config, False).__module__)
+    layer = getattr(models, module)(cfg)
+    x = SDS((traffic["global_batch"], traffic["seq_len"], cfg.hidden_size),
+            BF16)
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                            SDS((1, 128, cfg.hidden_size), BF16))
+    before = mixer_sites()
+
+    def total(params, x):
+        return rematted(lambda p, x: layer.apply(p, x).astype(F32).sum())(
+            params, x)
+
+    _, compiled = compile_on_chip(jax.grad(total, argnums=(0, 1)), params, x)
+    after = mixer_sites()
+    for stage in ("conv", "norm"):
+        assert after[f"{stage}_kernel"] > before[f"{stage}_kernel"]
+        assert after[f"{stage}_xla"] == before[f"{stage}_xla"]
+    assert mixer_calls(compiled.as_text()) == {
+        scope: {f"mixer_{stage}_fwd", f"mixer_{stage}_bwd"}
+        for scope, stage in scopes.items()}
+
+
 @pytest.mark.slow
 def test_qwen3_next_step_compiles_for_one_chip_with_its_scopes_and_fits():
     """The WHOLE step of the benchmark's ``qwen3next-train-1chip-s8192`` cell
@@ -741,12 +859,20 @@ def test_qwen3_next_step_compiles_for_one_chip_with_its_scopes_and_fits():
     bench = manifest.load()
     cell = manifest.cell_of(bench, "qwen3next-train-1chip-s8192")
     config = manifest.config_of(bench, cell)
+    before = mixer_sites()
     lowered = manifest.family_module(config).lower_train_step(
         config, manifest.traffic_of(cell), topo().devices[:1])
     assert kernel_names(lowered.as_text()) == {
         "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
         "_bwd_dkv_kernel_chunked", "kernel", "_gdn_fwd_kernel",
-        "_gdn_bwd_kernel", "_rows_to_tokens_kernel"}
+        "_gdn_bwd_kernel", "_rows_to_tokens_kernel",
+        "_mixer_conv_fwd_kernel", "_mixer_conv_bwd_kernel",
+        "_mixer_norm_fwd_kernel", "_mixer_norm_bwd_kernel"}
+    sites = mixer_sites()
+    assert (sites["conv_xla"], sites["norm_xla"]) == (
+        before["conv_xla"], before["norm_xla"])
+    assert sites["conv_kernel"] > before["conv_kernel"]
+    assert sites["norm_kernel"] > before["norm_kernel"]
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     assert 6.0e9 < ma.argument_size_in_bytes < 6.5e9      # 625.7M x 10 B
@@ -766,6 +892,9 @@ def test_qwen3_next_step_compiles_for_one_chip_with_its_scopes_and_fits():
                   # the held rows' way back, forward and backward: one kernel
                   "moe_combine/rows_to_tokens", "moe_dispatch/rows_to_tokens"):
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+    assert mixer_calls(hlo) == {
+        "gdn_conv": {"mixer_conv_fwd", "mixer_conv_bwd"},
+        "gdn_out_norm": {"mixer_norm_fwd", "mixer_norm_bwd"}}
     assert_rows_reach_tokens_in_one_pass(hlo, 20480, 16384)
 
 
