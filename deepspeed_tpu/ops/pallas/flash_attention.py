@@ -67,21 +67,29 @@ Three kernel families share the same per-tile math (`_fwd_block_step` /
   Findings PR 27 and PR 31 have the numbers.
 
 - **window** (a causal band of ``window`` keys, ``flash_attention(...,
-  window=W)`` with W < S; PR 33): the chunked family's grid with the third
-  dimension walking only the chunks a block's band touches — a STATIC
-  count, ``ceil((block + W - 1) / chunk)`` (+ 1 where a band can straddle a
-  chunk's edge: ``_band_extent``), never S / chunk — through K/V (forward,
-  dq) and Q/dO/lse/delta (dkv) index maps RELATIVE to the block's own
-  position and clamped at the sequence's ends, so neither compute nor DMA
-  is spent outside the band. Blocks wholly inside the band run unmasked;
-  the edge blocks take the causal and the lower-bound compare
-  (``_band_mask``). Grouped-query K/V are read in place, and the dkv
-  kernel's third dimension also walks the KV head's group of query heads,
-  so dk and dv leave per KV head. Scopes ``swa_fwd`` / ``swa_bwd_dq`` /
-  ``swa_bwd_dkv``, gauge ``attention/window_tile_overcompute``. A shape
-  the family does not take raises. Measured on a v5e at (S 16,384,
-  head_dim 128, 64 / 8 heads, W 512: Laguna's cell): PERF.md Findings
-  PR 33.
+  window=W)`` with W < S; PR 33): grid (B*H, S / block, steps) where a grid
+  block's whole BAND is one operand block (PR 43) — the
+  ``round_up(block + W - 1, block)`` rows of K and V that END at a query
+  block's last row (forward, dq), of Q, dO, lse and delta that START at a
+  key block's first (dkv), read at an element offset (``pl.Element``: a
+  block index times the block, so the compiler sees it lie on a tile's
+  edge) clamped at the sequence's ends — so ``steps`` is 1 and the band's
+  tiles are walked by the loops inside the step, where one aligned chunk a
+  step cost 2.1-3.1 us for a 512 x 512 x 128 tile (PERF.md Findings PR
+  43): 4,608 rows at W 4,096, 1,024 at W 512. A band whose rows pass
+  ``_BAND_BYTES`` (or the caller's ``chunk=`` cap) goes in the FEWEST equal
+  steps that fit (``_band_plan``), through the raw (o, m, l) carry of the
+  chunked family; neither compute nor DMA is spent outside the band.
+  Blocks wholly inside the band run unmasked; the edge blocks take the
+  causal and the lower-bound compare (``_band_mask``). Grouped-query K/V
+  are read in place, and the dkv kernel's third dimension walks the KV
+  head's group of query heads, so dk and dv leave per KV head. Scopes
+  ``swa_fwd`` / ``swa_bwd_dq`` / ``swa_bwd_dkv``, gauges
+  ``attention/window_tile_overcompute`` and
+  ``attention/window_tiles_per_grid_step``. A shape the family does not
+  take raises. Measured on a v5e at (S 16,384, head_dim 128, 64 / 8 heads,
+  W 512: Laguna's cell; 28 / 4 heads, W 4,096: SmallThinker's): PERF.md
+  Findings PR 33 and PR 43.
 
 What a score tile costs beside its two (five, backward) MXU products is
 what these kernels are written around (per 512 x 512 tile at D=64 the
@@ -882,10 +890,12 @@ def _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l, last):
     float32 output block, m (lane-replicated) and l (per-lane partial sums)
     in VMEM scratch — and on the walk's ``last`` step the normalised o and
     lse = m + log l, lane-dense through ``_dense_row``: no separate
-    [BH, S, D] normalisation pass, and no [BH, S, 1] statistic, in HBM."""
+    [BH, S, D] normalisation pass, and no [BH, S, 1] statistic, in HBM.
+    ``last`` Python's own True (``_walk_phase``): a walk of one step, which
+    carries nothing and has no ``m_ref`` / ``l_ref``."""
     piece = lse_ref.shape[-1]
 
-    @pl.when(jnp.logical_not(last))
+    @pl.when(False if last is True else jnp.logical_not(last))
     def _carry():
         o_ref[0] = o
         m_ref[...] = m
@@ -1200,39 +1210,87 @@ def _band_mask(rel, q_pos0, k_pos0, window):
         & (rel < window + k_pos0 - q_pos0)
 
 
-def _band_extent(S, block, chunk, window, keys):
-    """STATIC count of sequence chunks a grid block's band touches, the most
-    over the blocks: the third grid extent of the window kernels. ``keys``:
-    the block is ``block`` query rows and the band the keys
-    ``[p0 - window + 1, p0 + block)`` it sees (forward, dq); else the block
-    is key rows and the band the queries ``[p0, p0 + block + window - 1)``
-    that see it (dkv). ``ceil((block + window - 1) / chunk)``, one more
-    where a band can straddle a chunk's edge."""
+# bytes of ONE band operand's block (K or V forward and dq, Q or dO dkv) a
+# grid step of the window kernels may hold: two such operands, double
+# buffered, lie beside the score tiles in scoped VMEM. 4,608 rows of
+# head_dim 128 in bf16 (W 4,096 under blocks of 512: 1.18 MB, 4.7 MB in all)
+# fit whole; a band past the budget goes in the fewest steps that fit
+_BAND_BYTES = 2 * 2 ** 20
+
+
+def _band_tiles(S, block, tile, window, keys):
+    """STATIC count of ``tile``-row blocks a grid block's band touches, the
+    most over the blocks. ``keys``: the block is ``block`` query rows and
+    the band the keys ``[p0 - window + 1, p0 + block)`` it sees (forward,
+    dq); else the block is key rows and the band the queries
+    ``[p0, p0 + block + window - 1)`` that see it (dkv).
+    ``ceil((block + window - 1) / tile)``, one more where a band can
+    straddle a tile's edge, and never more than the sequence holds."""
     most = 0
     for p0 in range(0, S, block):
         lo, hi = ((max(p0 - window + 1, 0), p0 + block - 1) if keys
                   else (p0, min(p0 + block + window - 2, S - 1)))
-        most = max(most, hi // chunk - lo // chunk + 1)
+        most = max(most, hi // tile - lo // tile + 1)
     return most
 
 
-def _band_k_ranges(q0, kc, block_q, block_k, cb, window):
-    """(j_lo, j_a, j_b, j_hi) within key chunk ``kc`` (``cb`` blocks of
-    ``block_k``; ``kc`` < 0: a chunk before the sequence, every range
-    empty) for the query block at ``q0``: blocks [j_lo, j_a) hold the
-    band's lower edge, [j_a, j_b) lie wholly inside it, [j_b, j_hi) hold
-    the diagonal."""
+def _band_plan(S, block_q, block_k, window, row_bytes, rep=1, chunk=0):
+    """((tiles a grid step, grid steps) of a query block's walk over its
+    band of keys — forward, dq — and (tiles, steps, heads a step) of a key
+    block's over its band of queries — dkv): the band is ONE operand block
+    where its rows fit ``_BAND_BYTES`` (``chunk``: the caller's own cap, in
+    rows), else the fewest equal steps that do; and a dkv step takes as many
+    of the KV head's ``rep`` query heads as fit it with the band whole (the
+    most that divide ``rep``)."""
+    def walk(tiles, tile):
+        cap = max((chunk or _BAND_BYTES // row_bytes) // tile, 1)
+        steps = -(-tiles // cap)
+        return -(-tiles // steps), steps
+    per, steps = walk(_band_tiles(S, block_k, block_q, window, False),
+                      block_q)
+    heads = 1 if steps > 1 else max(
+        h for h in range(1, rep + 1) if rep % h == 0
+        and (h == 1 or h * per * block_q * row_bytes <= _BAND_BYTES))
+    return (walk(_band_tiles(S, block_q, block_k, window, True), block_k),
+            (per, steps, heads))
+
+
+def _band_k_first(i, c, block_q, block_k, walk):
+    """Key block that grid step ``c`` of query block ``i``'s walk starts at:
+    the steps END at the block that holds the block's diagonal, so this is
+    negative for the sequence's first blocks (the operand then starts at
+    key 0, and the kernel walks what of the step lies in the sequence)."""
+    per, steps = walk
+    return ((i + 1) * block_q - 1) // block_k + 1 - (steps - c) * per
+
+
+def _band_q_first(i, c, block_q, block_k, per):
+    """Query block that grid step ``c`` (of ``per`` blocks) of key block
+    ``i``'s walk starts at: the steps START at the block that holds the
+    block's diagonal, so the last blocks' steps run past the sequence (the
+    operand then ends at its last query)."""
+    return (i * block_k) // block_q + c * per
+
+
+def _band_k_ranges(q0, first, per, block_q, block_k, window):
+    """(operand's first key block, (j_lo, j_a, j_b, j_hi) within it) for the
+    query block at ``q0`` and a grid step of ``per`` key blocks from
+    ``first`` (``_band_k_first``): blocks [j_lo, j_a) hold the band's lower
+    edge, [j_a, j_b) lie wholly inside it, [j_b, j_hi) hold the
+    diagonal."""
     lo = jnp.maximum(q0 - window + 1, 0) // block_k
     hi = (q0 + block_q - 1) // block_k + 1
     a = jnp.clip((jnp.maximum(q0 + block_q - window, 0) + block_k - 1)
                  // block_k, lo, hi)
     b = jnp.clip((q0 + 1) // block_k, a, hi)
-    return tuple(jnp.clip(x - kc * cb, 0, cb) for x in (lo, a, b, hi))
+    at = jnp.maximum(first, 0)
+    return at, tuple(jnp.clip(x, at, jnp.maximum(first + per, at)) - at
+                     for x in (lo, a, b, hi))
 
 
-def _band_q_ranges(k0, qc, block_q, block_k, cb, window, seq_len):
-    """``_band_k_ranges`` for the key block at ``k0`` over query chunk
-    ``qc`` (``qc`` past the last chunk: every range empty): blocks
+def _band_q_ranges(k0, first, per, block_q, block_k, window, seq_len):
+    """``_band_k_ranges`` for the key block at ``k0`` and a grid step of
+    ``per`` query blocks from ``first`` (``_band_q_first``): blocks
     [j_lo, j_a) hold the diagonal, [j_a, j_b) lie wholly inside the band,
     [j_b, j_hi) hold its lower edge."""
     lo = k0 // block_q
@@ -1240,11 +1298,13 @@ def _band_q_ranges(k0, qc, block_q, block_k, cb, window, seq_len):
                      seq_len // block_q)
     a = jnp.clip((k0 + block_k + block_q - 2) // block_q, lo, hi)
     b = jnp.clip((k0 + window) // block_q, a, hi)
-    return tuple(jnp.clip(x - qc * cb, 0, cb) for x in (lo, a, b, hi))
+    at = jnp.minimum(first, seq_len // block_q - per)
+    return at, tuple(jnp.clip(x, first, first + per) - at
+                     for x in (lo, a, b, hi))
 
 
 def _band_loop(ranges, body, carry):
-    """fori_loop over a chunk's blocks: edge (masked), inside (unmasked),
+    """fori_loop over a step's blocks: edge (masked), inside (unmasked),
     edge (masked)."""
     j_lo, j_a, j_b, j_hi = ranges
     carry = jax.lax.fori_loop(j_lo, j_a, lambda j, c: body(j, c, True),
@@ -1254,79 +1314,112 @@ def _band_loop(ranges, body, carry):
     return jax.lax.fori_loop(j_b, j_hi, lambda j, c: body(j, c, True), carry)
 
 
-def _band_first_chunk(i, block_q, chunk, n_band):
-    """Key chunk of the first of query block ``i``'s ``n_band`` grid steps,
-    RELATIVE to the block: the last step is the chunk that holds the block's
-    diagonal. Negative for the first blocks (no such chunk: the index map
-    clamps it, the kernel skips it)."""
-    return ((i + 1) * block_q - 1) // chunk - (n_band - 1)
+def _band_spec(rows, D, at, heads=1):
+    """BlockSpec of the ``rows`` rows of ``heads`` consecutive rows of a
+    [BH, S, D] operand that hold a grid step's band: ``at`` maps the grid to
+    (first row of BH, first row of S) — ELEMENT offsets, the sequence's a
+    block times its size so the compiler sees it lie on a tile's edge — and
+    the kernel's ref is [heads, rows, D]."""
+    return pl.BlockSpec((pl.Element(heads), pl.Element(rows), pl.Element(D)),
+                        lambda *g: tuple(at(*g)) + (0,))
 
 
-def _band_kv_map(kv, block_q, chunk, n_band):
-    """K/V index map of the forward and dq kernels: grid step ``c`` of
-    query block ``i`` reads chunk ``_band_first_chunk + c`` of the head's
-    KV row (``kv``: ``_kv_row``), chunk 0 where there is none."""
-    return lambda b, i, c: (kv(b), jnp.maximum(
-        _band_first_chunk(i, block_q, chunk, n_band) + c, 0), 0)
+def _band_keys_spec(walk, block_q, block_k, D, kv):
+    """K / V operand of the forward and dq calls, grid (b, i, c): the rows of
+    step ``c`` of query block ``i``'s ``walk``, of KV row ``kv(b)``."""
+    return _band_spec(walk[0] * block_k, D, lambda b, i, c: (
+        kv(b), jnp.maximum(_band_k_first(i, c, block_q, block_k, walk), 0)
+        * block_k))
 
 
-def _swa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, *,
-                    scale, window, block_q, block_k, chunk, n_band):
+def _band_stat_spec(rows, piece, at, heads=1):
+    """``_band_spec`` of a [BH, S / piece, 1, piece] statistic (``at``'s
+    first row in rows of the sequence, a multiple of the piece's): the
+    kernel's ref is [heads, rows / piece, 1, piece]."""
+    def index(*g):
+        b, row = at(*g)
+        return b, row // piece, 0, 0
+    return pl.BlockSpec((pl.Element(heads), pl.Element(rows // piece),
+                         pl.Element(1), pl.Element(piece)), index)
+
+
+def _walk_phase(c, steps):
+    """(first, last) grid step of a walk of ``steps``: Python's own True
+    for a band in one step, whose kernel then has no carry to read, zero
+    or write back."""
+    return (True, True) if steps == 1 else (c == 0, c == steps - 1)
+
+
+def _walk_carry(first, slots):
+    """What a grid step of a walk starts from. ``slots``: (ref, index,
+    shape, fill) of each float32 accumulator; they are filled on the walk's
+    first step and read on every step, or — a walk of one step — stay
+    untouched (the refs may be None) and the fills are the carry."""
+    fills = [jnp.full(shape, fill, jnp.float32)
+             for _, _, shape, fill in slots]
+    if first is True:
+        return tuple(fills)
+
+    @pl.when(first)
+    def _init():
+        for (ref, at, _, _), fill in zip(slots, fills):
+            ref[at] = fill
+
+    return tuple(ref[at] for ref, at, _, _ in slots)
+
+
+def _swa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state, scale,
+                    window, block_q, block_k, walk):
+    m_ref, l_ref = state or (None, None)    # none for a band in one step
     qi = pl.program_id(1)
     c = pl.program_id(2)
-    kc = _band_first_chunk(qi, block_q, chunk, n_band) + c
-    cb = chunk // block_k
+    first, last = _walk_phase(c, walk[1])
     fold = _scale_folds(scale)
     s_scale = None if fold else scale
     q = q_ref[0] * scale if fold else q_ref[0]
     rel = _rel_pos(block_q, block_k)
     q0 = qi * block_q
-
-    @pl.when(c == 0)
-    def _init():
-        o_ref[0] = jnp.zeros_like(o_ref[0])
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    at, ranges = _band_k_ranges(
+        q0, _band_k_first(qi, c, block_q, block_k, walk), walk[0], block_q,
+        block_k, window)
 
     def body(j, carry, masked):
-        k0 = (kc * cb + j) * block_k
         k = k_ref[0, pl.ds(j * block_k, block_k), :]
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        mask = _band_mask(rel, q0, k0, window) if masked else None
+        mask = (_band_mask(rel, q0, (at + j) * block_k, window) if masked
+                else None)
         return _fwd_block_step(q, k, v, carry, mask, s_scale)
 
-    o, m, l = _band_loop(
-        _band_k_ranges(q0, kc, block_q, block_k, cb, window), body,
-        (o_ref[0], m_ref[...], l_ref[...]))
+    stat = (block_q, _LANES)
+    o, m, l = _band_loop(ranges, body, _walk_carry(first, (
+        (o_ref, 0, q.shape, 0.0), (m_ref, ..., stat, NEG_INF),
+        (l_ref, ..., stat, 0.0))))
     # as ``_fwd_kernel_chunked``: raw (o, m, l) between a block's steps, the
-    # last step (the diagonal's chunk) normalises in the kernel. A row its
-    # band's first block hides whole takes exp(0) there; the next visible
-    # key's alpha = exp(NEG_INF - m) = 0 wipes it, and the diagonal is
-    # always visible and always last
-    _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l,
-                        c == n_band - 1)
+    # last step (the diagonal's) normalises in the kernel. A row its band's
+    # first block hides whole takes exp(0) there; the next visible key's
+    # alpha = exp(NEG_INF - m) = 0 wipes it, and the diagonal is always
+    # visible and always last
+    _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l, last)
 
 
-def _swa_fwd(q, k, v, scale, window, block_q, block_k, chunk, interpret,
+def _swa_fwd(q, k, v, scale, window, block_q, block_k, band, interpret,
              heads, kv_heads):
+    """``band``: ``_band_plan``'s two walks; the forward takes the first."""
     BH, S, D = q.shape
-    n_band = _band_extent(S, block_q, chunk, window, keys=True)
-    band = _band_kv_map(_kv_row(heads, kv_heads), block_q, chunk, n_band)
+    walk = _, steps = band[0]
+    keys = _band_keys_spec(walk, block_q, block_k, D,
+                           _kv_row(heads, kv_heads))
     out_specs, out_shape, scratch = _chunked_fwd_outputs(
         q, block_q, block_k, lambda b, i, c: (b, i))
     call = pl.pallas_call(
         functools.partial(_swa_fwd_kernel, scale=scale, window=window,
-                          block_q=block_q, block_k=block_k, chunk=chunk,
-                          n_band=n_band),
-        grid=(BH, S // block_q, n_band),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, chunk, D), band),
-            pl.BlockSpec((1, chunk, D), band),
-        ],
+                          block_q=block_q, block_k=block_k, walk=walk),
+        grid=(BH, S // block_q, steps),
+        in_specs=[pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
+                  keys, keys],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=scratch,
+        scratch_shapes=scratch if steps > 1 else (),
         interpret=interpret,
     )
     with annotate("swa_fwd"):
@@ -1335,12 +1428,10 @@ def _swa_fwd(q, k, v, scale, window, block_q, block_k, chunk, interpret,
 
 
 def _swa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dq_ref, *, scale, window, block_q, block_k, chunk,
-                       n_band):
+                       dq_ref, *, scale, window, block_q, block_k, walk):
     qi = pl.program_id(1)
     c = pl.program_id(2)
-    kc = _band_first_chunk(qi, block_q, chunk, n_band) + c
-    cb = chunk // block_k
+    first, last = _walk_phase(c, walk[1])
     fold = _scale_folds(scale)
     s_scale = None if fold else scale
     q = q_ref[0] * scale if fold else q_ref[0]
@@ -1349,58 +1440,60 @@ def _swa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     delta = _stat_col(delta_ref, (0,), 0, block_q)
     rel = _rel_pos(block_q, block_k)
     q0 = qi * block_q
+    at, ranges = _band_k_ranges(
+        q0, _band_k_first(qi, c, block_q, block_k, walk), walk[0], block_q,
+        block_k, window)
 
-    @pl.when(c == 0)
-    def _init():
-        dq_ref[0] = jnp.zeros_like(dq_ref[0])
-
-    def body(j, dq_acc, masked):
-        k0 = (kc * cb + j) * block_k
+    def body(j, carry, masked):
         k = k_ref[0, pl.ds(j * block_k, block_k), :]
         v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        mask = _band_mask(rel, q0, k0, window) if masked else None
+        mask = (_band_mask(rel, q0, (at + j) * block_k, window) if masked
+                else None)
         _, ds = _bwd_ds_block(q, do, lse, delta, k, v, mask, s_scale)
-        return dq_acc + jax.lax.dot(ds, k,
-                                    preferred_element_type=jnp.float32)
+        return carry[0] + jax.lax.dot(ds, k,
+                                      preferred_element_type=jnp.float32),
 
-    dq = _band_loop(_band_k_ranges(q0, kc, block_q, block_k, cb, window),
-                    body, dq_ref[0])
-    dq_ref[0] = jnp.where(c == n_band - 1, dq * scale, dq)
+    dq, = _band_loop(ranges, body,
+                     _walk_carry(first, ((dq_ref, 0, q.shape, 0.0),)))
+    # unscaled across a block's walk, the folded-scale chain rule once on
+    # its last step
+    dq_ref[0] = dq * scale if last is True else jnp.where(last, dq * scale,
+                                                          dq)
 
 
 def _swa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                         dk_ref, dv_ref, *, scale, window, block_q, block_k,
-                        chunk, n_band, rep, seq_len):
+                        walk, rep, seq_len):
     """One KV head's block: the third grid dimension walks its group's
-    ``rep`` query heads, ``n_band`` query chunks each, and dk, dv of the KV
-    head accumulate over all of them in the revisited output block (the
-    causal chunked kernel leaves them per QUERY head, for XLA to sum)."""
+    ``rep`` query heads, ``heads`` of them and one of the band's steps a
+    grid step, and dk, dv of the KV head accumulate over all of them — in
+    the carry inside a step, in the revisited output block between steps
+    (the causal chunked kernel leaves them per QUERY head, for XLA to
+    sum)."""
     ki = pl.program_id(1)
     t = pl.program_id(2)
+    per, steps, heads = walk
+    first, last = _walk_phase(t, rep // heads * steps)
     k0 = ki * block_k
-    qc = k0 // chunk + t % n_band
-    cb = chunk // block_q
     fold = _scale_folds(scale)
     s_scale = None if fold else scale
     k = k_ref[0]
     v = v_ref[0]
     rel = _rel_pos(block_q, block_k)
+    at, ranges = _band_q_ranges(
+        k0, _band_q_first(ki, t % steps, block_q, block_k, per), per,
+        block_q, block_k, window, seq_len)
 
-    @pl.when(t == 0)
-    def _init():
-        dk_ref[0] = jnp.zeros_like(dk_ref[0])
-        dv_ref[0] = jnp.zeros_like(dv_ref[0])
-
-    def body(j, carry, masked):
+    def body(h, j, carry, masked):
         dk_acc, dv_acc = carry
-        q0 = (qc * cb + j) * block_q
-        q = q_ref[0, pl.ds(j * block_q, block_q), :]
+        q = q_ref[h, pl.ds(j * block_q, block_q), :]
         if fold:
             q = q * scale
-        do = do_ref[0, pl.ds(j * block_q, block_q), :]
-        lse = _stat_col(lse_ref, (0,), j * block_q, block_q)
-        delta = _stat_col(delta_ref, (0,), j * block_q, block_q)
-        mask = _band_mask(rel, q0, k0, window) if masked else None
+        do = do_ref[h, pl.ds(j * block_q, block_q), :]
+        lse = _stat_col(lse_ref, (h,), j * block_q, block_q)
+        delta = _stat_col(delta_ref, (h,), j * block_q, block_q)
+        mask = (_band_mask(rel, (at + j) * block_q, k0, window) if masked
+                else None)
         p, ds = _bwd_ds_block(q, do, lse, delta, k, v, mask, s_scale)
         dv_new = dv_acc + jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
@@ -1410,15 +1503,19 @@ def _swa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)
         return dk_new, dv_new
 
-    dk, dv = _band_loop(
-        _band_q_ranges(k0, qc, block_q, block_k, cb, window, seq_len), body,
-        (dk_ref[0], dv_ref[0]))
-    dk_ref[0] = dk if fold else jnp.where(t == rep * n_band - 1, dk * scale,
-                                          dk)
+    carry = _walk_carry(first, (
+        (dk_ref, 0, k.shape, 0.0), (dv_ref, 0, k.shape, 0.0)))
+    if heads == 1:
+        dk, dv = _band_loop(ranges, functools.partial(body, 0), carry)
+    else:
+        dk, dv = jax.lax.fori_loop(0, heads, lambda h, c: _band_loop(
+            ranges, functools.partial(body, h), c), carry)
+    dk_ref[0] = (dk if fold else dk * scale if last is True
+                 else jnp.where(last, dk * scale, dk))
     dv_ref[0] = dv
 
 
-def _swa_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k, chunk,
+def _swa_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k, band,
              interpret, heads, kv_heads):
     """(dq [B * heads, S, D], dk, dv [B * kv_heads, S, D])."""
     BH, S, D = q.shape
@@ -1427,17 +1524,16 @@ def _swa_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k, chunk,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1).reshape(lse.shape)
     piece = lse.shape[-1]
-    n_band = _band_extent(S, block_q, chunk, window, keys=True)
-    band = _band_kv_map(_kv_row(heads, kv_heads), block_q, chunk, n_band)
+    walk = _, steps = band[0]
+    keys = _band_keys_spec(walk, block_q, block_k, D,
+                           _kv_row(heads, kv_heads))
     call_dq = pl.pallas_call(
         functools.partial(_swa_bwd_dq_kernel, scale=scale, window=window,
-                          block_q=block_q, block_k=block_k, chunk=chunk,
-                          n_band=n_band),
-        grid=(BH, S // block_q, n_band),
+                          block_q=block_q, block_k=block_k, walk=walk),
+        grid=(BH, S // block_q, steps),
         in_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-            pl.BlockSpec((1, chunk, D), band),
-            pl.BlockSpec((1, chunk, D), band),
+            keys, keys,
             pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
         ] + [_stat_spec(block_q, piece, lambda b, i, c: (b, i))] * 2,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
@@ -1447,26 +1543,27 @@ def _swa_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k, chunk,
     with annotate("swa_bwd_dq"):
         dq = call_dq(q, k, v, do, lse, delta)
 
-    n_band = _band_extent(S, block_k, chunk, window, keys=False)
-    last = S // chunk - 1
+    walk = per, steps, heads = band[1]
 
-    def band_q(b, i, t):
+    def queries(b, i, t):
         # KV row b's group: query heads [b * rep, (b + 1) * rep) (``_kv_row``
-        # the other way round), head t // n_band of them
-        return (b * rep + t // n_band,
-                jnp.minimum(i * block_k // chunk + t % n_band, last), 0)
+        # the other way round), ``heads`` of them from t // steps on
+        return (b * rep + t // steps * heads, jnp.minimum(
+            _band_q_first(i, t % steps, block_q, block_k, per),
+            S // block_q - per) * block_q)
 
+    rows = per * block_q
     call_dkv = pl.pallas_call(
         functools.partial(_swa_bwd_dkv_kernel, scale=scale, window=window,
-                          block_q=block_q, block_k=block_k, chunk=chunk,
-                          n_band=n_band, rep=rep, seq_len=S),
-        grid=(BHkv, S // block_k, rep * n_band),
+                          block_q=block_q, block_k=block_k, walk=walk,
+                          rep=rep, seq_len=S),
+        grid=(BHkv, S // block_k, rep // heads * steps),
         in_specs=[
-            pl.BlockSpec((1, chunk, D), band_q),
+            _band_spec(rows, D, queries, heads),
             pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
-            pl.BlockSpec((1, chunk, D), band_q),
-        ] + [_stat_spec(chunk, piece, lambda *g: band_q(*g)[:2])] * 2,
+            _band_spec(rows, D, queries, heads),
+        ] + [_band_stat_spec(rows, piece, queries, heads)] * 2,
         out_specs=[
             pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
@@ -1483,26 +1580,26 @@ def _swa_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k, chunk,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
-def _flash_attention_swa(q, k, v, scale, window, block_q, block_k, chunk,
+def _flash_attention_swa(q, k, v, scale, window, block_q, block_k, band,
                          interpret, heads, kv_heads):
-    return _swa_fwd(q, k, v, scale, window, block_q, block_k, chunk,
+    return _swa_fwd(q, k, v, scale, window, block_q, block_k, band,
                     interpret, heads, kv_heads)[0]
 
 
-def _flash_attention_swa_fwd(q, k, v, scale, window, block_q, block_k, chunk,
+def _flash_attention_swa_fwd(q, k, v, scale, window, block_q, block_k, band,
                              interpret, heads, kv_heads):
     o, lse = _name_residuals(*_swa_fwd(
-        q, k, v, scale, window, block_q, block_k, chunk, interpret, heads,
+        q, k, v, scale, window, block_q, block_k, band, interpret, heads,
         kv_heads))
     return o, (q, k, v, o, lse)
 
 
-def _flash_attention_swa_bwd(scale, window, block_q, block_k, chunk,
+def _flash_attention_swa_bwd(scale, window, block_q, block_k, band,
                              interpret, heads, kv_heads, residuals, do):
     q, k, v, o, lse = residuals
     _named["closed"] = True
     return _swa_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k,
-                    chunk, interpret, heads, kv_heads)
+                    band, interpret, heads, kv_heads)
 
 
 _flash_attention_swa.defvjp(_flash_attention_swa_fwd,
@@ -1604,6 +1701,16 @@ def tile_overcompute(S, block_q, block_k, chunk, causal):
     return computed / (S * (S + 1))
 
 
+def _window_tiles(S, block_q, block_k, window):
+    """Score tiles a head's band takes: (over its query blocks' walks —
+    the forward's, and dq's again — , over its key blocks' — dkv's)."""
+    return (sum((q0 + block_q - 1) // block_k
+                - max(q0 - window + 1, 0) // block_k + 1
+                for q0 in range(0, S, block_q)),
+            sum(min((k0 + block_k + window - 2) // block_q + 1, S // block_q)
+                - k0 // block_q for k0 in range(0, S, block_k)))
+
+
 def window_tile_overcompute(S, block_q, block_k, window):
     """``tile_overcompute`` for the window kernels: score elements of the
     blocks the band's walks touch (a walk over the query blocks — forward,
@@ -1611,14 +1718,21 @@ def window_tile_overcompute(S, block_q, block_k, window):
     elements a head's band holds, twice. Blocks of 512 at W 512 compute
     2.0 x, 256 1.5 x, 128 1.25 x."""
     window = min(window, S)
-    tile = block_q * block_k
-    over_q = sum(((q0 + block_q - 1) // block_k
-                  - max(q0 - window + 1, 0) // block_k + 1) * tile
-                 for q0 in range(0, S, block_q))
-    over_k = sum((min((k0 + block_k + window - 2) // block_q + 1,
-                      S // block_q) - k0 // block_q) * tile
-                 for k0 in range(0, S, block_k))
-    return (over_q + over_k) / (2 * (S * window - window * (window - 1) // 2))
+    return sum(_window_tiles(S, block_q, block_k, window)) * block_q \
+        * block_k / (2 * (S * window - window * (window - 1) // 2))
+
+
+def window_tiles_per_grid_step(S, block_q, block_k, window, band):
+    """Score tiles the three window calls of a head compute over the grid
+    steps they take under ``band`` (``_band_plan``): a band in one step
+    reads its tile count less what the sequence's first blocks clip —
+    7.9 of 9 at S 16,384 / W 4,096 / 512, 1.97 of 2 at W 512 — where one
+    tile a step read under 1."""
+    over_keys, over_queries = _window_tiles(S, block_q, block_k,
+                                            min(window, S))
+    (_, steps_k), (_, steps_q, heads) = band
+    return (2 * over_keys + over_queries) / (
+        2 * (S // block_q) * steps_k + (S // block_k) * steps_q / heads)
 
 
 def grid_steps_walked(S, block_q, block_k, chunk, causal):
@@ -1636,7 +1750,7 @@ _plans_logged = set()
 
 
 def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
-               heads_per_block=0, window=0):
+               heads_per_block=0, window=0, band=None):
     """Trace-time engagement record of one flash call: the gauges
     ``attention/flash_tile_overcompute``,
     ``attention/flash_heads_per_block`` (heads a 128-lane column block of
@@ -1646,25 +1760,30 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
     causal at S 16,384 / 512 / 1,024, 1.0 where nothing is masked) and,
     once per distinct shape, a log line of the layout (the operands' and
     the log-sum-exp's) and loop structure chosen for it.
-    ``window``: a call of the window kernels — the gauge
-    ``attention/window_tile_overcompute`` and the band's plan instead."""
+    ``window``: a call of the window kernels under ``band``
+    (``_band_plan``) — the gauges ``attention/window_tile_overcompute`` and
+    ``attention/window_tiles_per_grid_step`` and the band's plan instead."""
     piece = _stat_piece(block_q, block_k)
     if window:
         over = window_tile_overcompute(S, block_q, block_k, window)
+        tiles = window_tiles_per_grid_step(S, block_q, block_k, window, band)
         default_registry().gauge("attention/window_tile_overcompute").set(
             over)
-        plan = (S, D, jnp.dtype(dtype).name, window, block_q, block_k, chunk)
+        default_registry().gauge("attention/window_tiles_per_grid_step").set(
+            tiles)
+        plan = (S, D, jnp.dtype(dtype).name, window, block_q, block_k, band)
         if plan not in _plans_logged:
             _plans_logged.add(plan)
+            (per_k, steps_k), (per_q, steps_q, heads) = band
             logger.info(
                 f"flash attention S={S} D={D} {plan[2]} window={window}: "
                 f"layout [B*H, S, D] head-major, lse [B*H, S/{piece}, 1, "
-                f"{piece}], block_q={block_q} "
-                f"block_k={block_k} chunk={chunk}, a query block walks "
-                f"{_band_extent(S, block_q, chunk, window, True)} of "
-                f"{S // chunk} key chunks, a key block "
-                f"{_band_extent(S, block_k, chunk, window, False)} query "
-                f"chunks a head of its group, scale "
+                f"{piece}], block_q={block_q} block_k={block_k}, a query "
+                f"block's band is {steps_k} grid step(s) of "
+                f"{per_k * block_k} keys, a key block's {steps_q} of "
+                f"{per_q * block_q} queries for {heads} head(s) of its "
+                f"group, "
+                f"{tiles:.2f} score tiles a grid step, scale "
                 f"{'on q' if _scale_folds(scale) else 'on scores'}"
                 f", computes {over:.3f} x the band's scores")
         return
@@ -1720,12 +1839,13 @@ def _pick_block(S, requested, interpret, whole_row):
 
 
 # The window kernels take the chunked family's grid blocks (``_pick_block``:
-# up to 512 rows), one block a chunk where the caller names none. Measured
-# on a v5e at [64 / 8, 16384, 128] bf16, W 512 (tests/perf/swa_bench.py;
-# PERF.md Findings PR 33): a grid step's fixed cost outweighs what a finer
-# tiling saves — blocks of 512 compute 2.0 x the band's scores and ran
-# 13.8 ms forward / 36.5 forward + backward, 256 (1.5 x) 19.3 / 48.2
-# (16.2 / 42.1 at two blocks a chunk), 128 (1.25 x) 37.5 / 92.6
+# up to 512 rows) and a block's whole band in one grid step (``_band_plan``).
+# Measured on a v5e at [64 / 8, 16384, 128] bf16, W 512
+# (tests/perf/swa_bench.py; PERF.md Findings PR 33, at one aligned chunk a
+# step): a grid step's fixed cost outweighs what a finer tiling saves —
+# blocks of 512 compute 2.0 x the band's scores and ran 13.8 ms forward /
+# 36.5 forward + backward, 256 (1.5 x) 19.3 / 48.2 (16.2 / 42.1 at two
+# blocks a chunk), 128 (1.25 x) 37.5 / 92.6
 
 
 def _flash_attention_window(q, k, v, scale, window, block_q, block_k, chunk,
@@ -1741,18 +1861,19 @@ def _flash_attention_window(q, k, v, scale, window, block_q, block_k, chunk,
             f"window attention (window={window}) over S={S}: no block "
             f"tiles the sequence (block_q={block_q}, block_k={block_k}) and "
             "a window layer never falls back to [S, S] scores")
-    if chunk is None:
-        chunk = max(block_q, block_k)
-    if S % chunk or chunk % block_q or chunk % block_k:
+    if chunk and (chunk % block_q or chunk % block_k):
         raise ValueError(
-            f"chunk={chunk} must divide S={S} and be a multiple of "
-            f"block_q={block_q} and block_k={block_k}")
-    _note_plan(S, D, q.dtype, scale, True, block_q, block_k, chunk,
-               window=window)
+            f"chunk={chunk} (the most rows of a band a grid step holds) must "
+            f"be a multiple of block_q={block_q} and block_k={block_k}")
+    band = _band_plan(S, block_q, block_k, int(window),
+                      max(D, _LANES) * jnp.dtype(q.dtype).itemsize, H // Hkv,
+                      int(chunk or 0))
+    _note_plan(S, D, q.dtype, scale, True, block_q, block_k, 0,
+               window=window, band=band)
     o = _flash_attention_swa(
         q.reshape(B * H, S, D), k.reshape(B * Hkv, S, D),
         v.reshape(B * Hkv, S, D), scale, int(window), block_q, block_k,
-        int(chunk), bool(interpret), H, Hkv)
+        band, bool(interpret), H, Hkv)
     return o.reshape(B, H, S, D)
 
 
@@ -1769,8 +1890,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 
     ``window`` (with ``causal``): key j is visible to query i iff
     ``0 <= i - j < window``. A window shorter than the sequence takes the
-    window kernels, whose grid walks only the chunks a block's band touches
-    (a shape they do not take RAISES); one that covers it is causal
+    window kernels, whose grid step holds a block's whole band (``chunk``
+    there: a cap on the band's rows a step holds, default the kernels' VMEM
+    budget; a shape they do not take RAISES); one that covers it is causal
     attention."""
     B, H, S, D = q.shape
     scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))

@@ -195,10 +195,14 @@ def annotate(tag):
     (value heads a grid step of its kernels; 0: the XLA form took the
     call) and ``linear_attn/gdn_states_kept_every`` (chunks between the
     states kept for the backward pass): no benchmark metric reads
-    them. The window kernels leave one,
+    them. The window kernels leave two:
     ``attention/window_tile_overcompute`` (score elements their tiles
-    compute over those the band holds, forward and backward):
-    ``swa_tile_overcompute`` reads it. Every flash VJP's forward rule
+    compute over those the band holds, forward and backward), which
+    ``swa_tile_overcompute`` reads, and
+    ``attention/window_tiles_per_grid_step`` (score tiles of the three
+    calls over their grid steps: ~the band's tile count where a block's
+    whole band is one operand block, under 1 at a tile a step), which no
+    benchmark metric reads. Every flash VJP's forward rule
     leaves ``attention/flash_residual_mb`` (decimal MB of HBM the
     ``flash_o`` / ``flash_lse`` pairs one differentiation names take, a
     minor dimension counted in 128-lane tiles: what a remat policy that

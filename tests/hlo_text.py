@@ -123,6 +123,24 @@ def remat_report(loss, params, capsys):
             capsys.readouterr().out, lowered)
 
 
+def pallas_element_rows(jaxpr):
+    """Rows of every [rows, D] operand block a ``pallas_call`` under a jaxpr
+    reads at an ELEMENT offset (``pl.Element``: the window kernels' bands),
+    in program order."""
+    from jax.experimental import pallas as pl
+    rows = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            rows += [bm.block_shape[1].block_size
+                     for bm in eqn.params["grid_mapping"].block_mappings
+                     if len(bm.block_shape) == 3
+                     and isinstance(bm.block_shape[1], pl.Element)]
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                rows += pallas_element_rows(sub)
+    return rows
+
+
 def pallas_grids(jaxpr):
     """The grid of every ``pallas_call`` of a jaxpr and of the jaxprs inside
     it (a remat, a custom VJP's rules), in program order."""

@@ -13,7 +13,7 @@ from deepspeed_tpu.ops.attention import (from_head_major,
                                          reference_attention, to_head_major)
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.blocksparse import blocksparse_attention
-from tests.hlo_text import pallas_grids
+from tests.hlo_text import pallas_element_rows, pallas_grids
 
 
 def _qkv(shape=(2, 2, 128, 32), seed=0, dtype=jnp.float32):
@@ -584,8 +584,9 @@ def test_fused_qkv_attention_runs_per_device_on_the_engine_mesh(axes):
 
 
 # ------------------------------------------------------------------------
-# the window kernels (ISSUE 33): a causal band of ``window`` keys, the third
-# grid dimension walking only the chunks a block's band touches
+# the window kernels (ISSUE 33): a causal band of ``window`` keys; since
+# ISSUE 43 a block's whole band is ONE operand block at an element offset
+# (``chunk=``: a cap on its rows, which puts a band into several grid steps)
 
 def _window_case(S, H, Hkv, W, block_q, block_k, chunk, dtype=jnp.float32,
                  D=32):
@@ -619,8 +620,22 @@ def _window_case(S, H, Hkv, W, block_q, block_k, chunk, dtype=jnp.float32,
     (512, 6, 1, 130, 64, 64, 128),      # GQA 6:1, band across chunk edges
     (256, 8, 1, 48, 64, 64, None),      # GQA 8:1
     (192, 3, 1, 40, 64, 64, None),      # S no power of two, odd head count
-    (512, 7, 1, 288, 64, 64, 64),       # GQA 7:1, a band of 5-6 chunks
-    (384, 14, 2, 200, 64, 64, 128),     # 2 KV heads x 7, band over 3 chunks
+    (512, 7, 1, 288, 64, 64, 64),       # GQA 7:1, a band of 6 one-tile steps
+    (384, 14, 2, 200, 64, 64, 128),     # 2 KV heads x 7, band over 3 steps
+    # ISSUE 43: the band as one operand block of round_up(block + W - 1)
+    # rows, clamped at the sequence's start (forward, dq) and end (dkv)
+    (512, 2, 1, 200, 64, 64, None),     # 5 tiles a step, 4 blocks clamped
+    (256, 2, 1, 255, 32, 32, None),     # a band as wide as the sequence
+    (128, 2, 2, 127, 64, 64, None),     # round_up(64 + 126) = 192 rows > S
+    (512, 2, 1, 200, 64, 64, 192),      # a small budget: 5 tiles in 2 steps
+    (512, 2, 1, 300, 64, 64, 256),      # 6 tiles in 2 steps of 3
+    (256, 2, 1, 100, 32, 64, None),     # unequal blocks, the band in a step
+    (256, 2, 1, 100, 64, 32, None),     # ... and the other way round
+    (512, 4, 2, 130, 128, 64, None),    # block_q twice block_k, GQA 2:1
+    (512, 7, 1, 288, 64, 64, None),     # GQA 7:1, the band in one step
+    (384, 14, 2, 200, 64, 64, None),    # 2 KV heads x 7, one step
+    (256, 8, 1, 100, 64, 64, None),     # GQA 8:1, one step of 3 tiles
+    (512, 8, 1, 200, 64, 64, 128),      # GQA 8:1, 5 tiles in 3 steps
 ], ids=lambda v: str(v))
 def test_window_kernels_match_the_masked_reference(S, H, Hkv, W, block_q,
                                                    block_k, chunk):
@@ -648,45 +663,78 @@ def test_a_window_that_covers_the_sequence_is_causal_attention(W):
         np.asarray(flash_attention(q, k, v, **kw)))
 
 
-@pytest.mark.parametrize("S,W,block,chunk,fwd,dkv", [
-    (1024, 64, 64, 64, 2, 2),           # ceil((64 + 63) / 64)
-    (1024, 128, 64, 64, 3, 3),
-    (1024, 100, 64, 64, 3, 3),
-    (1024, 64, 64, 256, 2, 2),          # + 1: a band straddles a chunk edge
-    (1024, 512, 64, 256, 3, 3),         # ceil(575 / 256) = 3
-    (16384, 512, 256, 256, 3, 3),       # the cell's: 3 of 64 chunks
-    (16384, 512, 128, 128, 5, 5),
-    (16384, 512, 512, 512, 2, 2),
-    (16384, 4096, 512, 512, 9, 9),      # SmallThinker's: 9 of 32 chunks
+@pytest.mark.parametrize("S,W,block,chunk,rows,steps,tiles", [
+    (1024, 64, 64, None, 128, 1, 2.325),     # round_up(64 + 63, 64) rows
+    (1024, 128, 64, None, 192, 1, 3.375),
+    (1024, 100, 64, None, 192, 1, 3.375),
+    (1024, 64, 64, 64, 64, 2, 0.969),        # a cap of one tile: 2 steps
+    (1024, 512, 64, 256, 192, 3, 2.25),     # 9 tiles under a cap of 4: 3 x 3
+    (16384, 512, 256, None, 768, 1, 3.544),  # Laguna's window at blocks of 256
+    (16384, 512, 128, None, 640, 1, 5.906),
+    (16384, 512, 512, None, 1024, 1, 2.3625),     # the Laguna cell's, 2 heads
+    (16384, 4096, 512, None, 4608, 1, 7.875),    # the SmallThinker cell's
 ])
-def test_window_grid_walks_the_static_band_count(S, W, block, chunk, fwd,
-                                                 dkv):
-    """The third grid extent of the three ``pallas_call``s is the band's
-    static chunk count (x the group's query heads for dk / dv), never
-    S / chunk; and the gauge is the band's overcompute."""
+def test_window_grid_walks_the_static_band_count(S, W, block, chunk, rows,
+                                                 steps, tiles):
+    """A block's band is one operand block of ``rows`` rows: the third grid
+    extent of the forward and dq ``pallas_call``s is the band's step count —
+    1 where the rows fit the budget (or the caller's ``chunk=`` cap) — and
+    of the dkv call that times the group's query heads, never S / block;
+    the two gauges say what the tiles compute and how many a step takes."""
     from deepspeed_tpu.telemetry.registry import default_registry
     fa = _fa()
-    assert fa._band_extent(S, block, chunk, W, keys=True) == fwd
-    assert fa._band_extent(S, block, chunk, W, keys=False) == dkv
     H, Hkv = 4, 2
-    q = jax.ShapeDtypeStruct((1, H, S, 16), jnp.float32)
-    kv = jax.ShapeDtypeStruct((1, Hkv, S, 16), jnp.float32)
+    band = fa._band_plan(S, block, block, W, 128 * 2, H // Hkv,
+                         chunk or 0)
+    # both of a group's heads a dkv step where 2 x the band's rows fit
+    heads = 2 if steps == 1 and 2 * rows * 256 <= fa._BAND_BYTES else 1
+    assert band == ((rows // block, steps), (rows // block, steps, heads))
+    if not chunk:
+        assert rows == -(-(block + W - 1) // block) * block
+    q = jax.ShapeDtypeStruct((1, H, S, 128), jnp.bfloat16)   # the cells'
+    kv = jax.ShapeDtypeStruct((1, Hkv, S, 128), jnp.bfloat16)
     jaxpr = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
         q, k, v, causal=True, window=W, block_q=block, block_k=block,
-        chunk=chunk, interpret=True)), argnums=(0, 1, 2)))(q, kv, kv)
+        chunk=chunk, interpret=True).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(q, kv, kv)
     grids = sorted(pallas_grids(jaxpr.jaxpr))
     assert grids == sorted([
-        (H, S // block, fwd), (H, S // block, fwd),
-        (Hkv, S // block, (H // Hkv) * dkv)]), grids
-    assert all(g[2] < S // chunk for g in grids if S // chunk > 8)
+        (H, S // block, steps), (H, S // block, steps),
+        (Hkv, S // block, H // Hkv // heads * steps)]), grids
+    # the band's operands: K, V (forward, dq) and Q, dO (dkv)
+    assert pallas_element_rows(jaxpr.jaxpr) == [rows] * 6
     over = default_registry().peek_gauge("attention/window_tile_overcompute")
     assert over == pytest.approx(
         fa.window_tile_overcompute(S, block, block, W))
+    assert default_registry().peek_gauge(
+        "attention/window_tiles_per_grid_step") == pytest.approx(
+        fa.window_tiles_per_grid_step(S, block, block, W, band)) \
+        == pytest.approx(tiles, abs=0.001)
     if (S, W) == (16384, 512):
         assert over == pytest.approx({512: 2.0, 256: 1.5, 128: 1.25}[block],
                                      abs=0.02)
     if (S, W) == (16384, 4096):
         assert over == pytest.approx(1.125, abs=0.005)
+
+
+@pytest.mark.parametrize("budget,band", [
+    (2 ** 21, ((5, 1), (5, 1, 2))),     # the module's: 320 rows fit whole
+    (320 * 512, ((5, 1), (5, 1, 1))),   # ... for one head of the two
+    (200 * 512, ((3, 2), (3, 2, 1))),   # 200 rows of 128 float32 lanes
+    (64 * 512, ((1, 5), (1, 5, 1))),    # one tile: the parent's step count
+])
+def test_a_band_past_the_budget_goes_in_the_fewest_steps_that_fit(
+        monkeypatch, budget, band):
+    """No knob: the band's rows follow from window, block, head_dim and
+    dtype against ``_BAND_BYTES``, and the steps are the fewest equal ones
+    that fit — out, dq, dk, dv are the reference's either way."""
+    fa = _fa()
+    monkeypatch.setattr(fa, "_BAND_BYTES", budget)
+    assert fa._band_plan(512, 64, 64, 200, 128 * 4, 2) == band
+    got, want = _window_case(512, 4, 2, 200, 64, 64, None)
+    for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4,
+                                   atol=5e-5, err_msg=name)
 
 
 def test_window_overcompute_counts_blocks_over_the_band():
@@ -960,7 +1008,7 @@ def test_chunked_and_window_lse_is_lane_dense(H, Hkv, D, window):
     and forward, dq, dk, dv hold the float32 reference's within the limits
     the parity tests above hold."""
     fa = _fa()
-    S, block, chunk = 384, 128, 128
+    S, block, chunk = 384, 128, 128         # the window: 2-3 steps a band
     q, _, _ = _qkv((1, H, S, D), seed=H + D)
     _, k, v = _qkv((1, Hkv, S, D), seed=H + D + 1)
     attend = functools.partial(flash_attention, causal=True, window=window,
@@ -973,8 +1021,11 @@ def test_chunked_and_window_lse_is_lane_dense(H, Hkv, D, window):
     assert o.shape == (H, S, D)
 
     if window:
+        band = fa._band_plan(S, block, block, window, D * 4, H // Hkv,
+                             chunk)
+        assert band[0][1] > 1              # a walk with a carry
         _, got = fa._swa_fwd(q[0], k[0], v[0], D ** -0.5, window, block,
-                             block, chunk, True, H, Hkv)
+                             block, band, True, H, Hkv)
     else:
         _, got = fa._flash_fwd_chunked(q[0], k[0], v[0], D ** -0.5, True,
                                        block, block, chunk, True, H, Hkv)

@@ -898,41 +898,68 @@ def test_qwen3_next_step_compiles_for_one_chip_with_its_scopes_and_fits():
     assert_rows_reach_tokens_in_one_pass(hlo, 20480, 16384)
 
 
-def test_window_kernels_fwd_and_grad_compile_at_laguna_shape():
-    """1 x 64 query / 8 KV heads x 16,384 x head_dim 128, bf16, window 512:
-    the three window kernels, K and V read at their 8 heads and dk, dv
-    written at 8 (summed over a group's query heads inside the kernel), the
-    third grid extent the band's 2 chunks of 512 and never 32, no [S, S]
-    array."""
+def _window_kernels_compile(H, Hkv, W, band_rows, heads, tiles, peak):
+    """1 x ``H`` query / ``Hkv`` KV heads x 16,384 x head_dim 128, bf16,
+    window ``W``: the three window kernels inside the default scoped VMEM, K
+    and V read at their ``Hkv`` heads and dk, dv written there (summed over
+    a group's query heads inside the kernel), a block's whole band ONE
+    operand block of ``band_rows`` rows — the third grid extent 1 forward
+    and dq, the group's query heads over the ``heads`` a step takes dkv,
+    never the band's tile count and never 32 — and no [S, S] array."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    S = 16384
 
     def attend(*a):
         # a scope round it, as the model's module is: JAX writes the
         # transform round the FIRST scope inside it
         with jax.named_scope("attn"):
             return flash_attention(*a, causal=True,
-                                   window=512).astype(F32).sum()
+                                   window=W).astype(F32).sum()
 
     def grads(q, k, v):
         return jax.grad(rematted(attend), argnums=(0, 1, 2))(q, k, v)
 
-    text, compiled = compile_on_chip(
-        grads, SDS((1, 64, 16384, 128), BF16), SDS((1, 8, 16384, 128), BF16),
-        SDS((1, 8, 16384, 128), BF16))
+    shapes = (SDS((1, H, S, 128), BF16), SDS((1, Hkv, S, 128), BF16),
+              SDS((1, Hkv, S, 128), BF16))
+    jaxpr = jax.make_jaxpr(grads)(*shapes).jaxpr
+    assert sorted(hlo_text.pallas_grids(jaxpr)) == sorted(
+        [(H, 32, 1), (H, 32, 1), (Hkv, 32, H // Hkv // heads)])
+    # the band's operands: K, V (forward, dq) and Q, dO (dkv)
+    assert hlo_text.pallas_element_rows(jaxpr) == [band_rows] * 6
+    assert default_registry().peek_gauge(
+        "attention/window_tiles_per_grid_step") == pytest.approx(tiles,
+                                                                 abs=0.005)
+    text, compiled = compile_on_chip(grads, *shapes)
     assert kernel_names(text) == {"_swa_fwd_kernel", "_swa_bwd_dq_kernel",
                                   "_swa_bwd_dkv_kernel"}
     hlo = compiled.as_text()
     calls = flash_calls(hlo)
-    assert len(calls) == 3 and all("bf16[8,16384,128]" in c for c in calls)
-    assert sum("f32[8,16384,128]" in c.split(" custom-call(")[0]
+    assert len(calls) == 3 and all(f"bf16[{Hkv},16384,128]" in c
+                                   for c in calls)
+    assert sum(f"f32[{Hkv},16384,128]" in c.split(" custom-call(")[0]
                for c in calls) == 1                 # dk, dv at the KV heads
     for scope in ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv"):
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
     assert "16384,16384" not in hlo
     # under the blocks' remat policy, as the cell's window layers are
-    assert_dense_lse_kept(hlo, calls, "f32[64,128,1,128]")
-    # q, k, v, o, their gradients and the fp32 kernel outputs: under 2.5 GB
-    assert compiled.memory_analysis().peak_memory_in_bytes < 2.5e9
+    assert_dense_lse_kept(hlo, calls, f"f32[{H},128,1,128]")
+    # q, k, v, o, their gradients and the fp32 kernel outputs
+    assert compiled.memory_analysis().peak_memory_in_bytes < peak
+
+
+def test_window_kernels_fwd_and_grad_compile_at_laguna_shape():
+    """64 / 8 heads, window 512: a band of 1,024 rows, 2 tiles a forward or
+    dq step (1.97 with the first block's one) and the group's 8 heads a dkv
+    step (Q and dO 2 MiB each), under 2.5 GB as before PR 43."""
+    _window_kernels_compile(64, 8, 512, 1024, 8, 2.78, 2.5e9)
+
+
+def test_window_kernels_fwd_and_grad_compile_at_smallthinker_shape():
+    """28 / 4 heads, window 4,096: a band of 4,608 rows (1.18 MB a bf16
+    operand, K and V double-buffered 4.7 MB), 9 tiles a step less what the
+    first eight blocks clip; one head a dkv step (two would pass the
+    budget)."""
+    _window_kernels_compile(28, 4, 4096, 4608, 1, 7.88, 1.2e9)
 
 
 @pytest.mark.slow
