@@ -43,8 +43,8 @@ import traceback
 
 import numpy as np
 
-# GPT-2 large as bench.py's headline configures it (published widths, full
-# depth), and the rehearsal's shrunken stand-in
+# GPT-2 large at its published widths and full depth, and the rehearsal's
+# shrunken stand-in
 MODEL = dict(vocab_size=50304, n_positions=1024, n_embd=1280, n_layer=36,
              n_head=20, remat=True, remat_policy="dots_flash_fc_lean",
              loss_chunk=1024, scan_layers=True)

@@ -6,8 +6,8 @@
 # heartbeats or loses the crash-loop bound, a rendezvous retry that
 # started retrying config errors — gate in seconds without an engine
 # compile or a 2-process rendezvous. Wire it next to
-# ci/regression_gate.sh (measured numbers) and ci/telemetry_gate.sh
-# (instrumentation): this script gates the RECOVERY machinery. The
+# ci/telemetry_gate.sh (instrumentation): this script gates the
+# RECOVERY machinery. The
 # slow 2-process acceptance legs (SIGKILL auto-recovery with the loss
 # trajectory preserved; in-collective hang detection) live in
 # tests/test_fault_tolerance.py -m slow and ride the full suite.

@@ -1,10 +1,9 @@
 #!/usr/bin/env bash
 # NVMe swap-tier fast gate (ISSUE 20 satellite): the O_DIRECT alignment
 # layer, the buffered-fallback latch, and the swapper contracts that
-# ride on them — gated in <10 s without an accelerator or a bench run.
-# Wire it next to ci/telemetry_gate.sh (instrumentation) and
-# ci/regression_gate.sh (measured headlines); this script gates the
-# I/O-path CORRECTNESS those headlines depend on.
+# ride on them — gated in <10 s without an accelerator.
+# Wire it next to ci/telemetry_gate.sh (instrumentation); this script
+# gates the I/O-path CORRECTNESS of the swap tier.
 #
 # Usage:
 #   ci/swap_gate.sh

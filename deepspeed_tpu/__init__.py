@@ -26,8 +26,8 @@ logger = _logging.logger
 
 # The public surface resolves LAZILY (PEP 562): importing the bare
 # package must not drag in jax — the stdlib-only tooling (the flight
-# dump viewer `python -m deepspeed_tpu.telemetry.view`, bench.py's
-# --candidate compare path, ci/telemetry_gate.sh) runs on machines
+# dump viewer `python -m deepspeed_tpu.telemetry.view`,
+# ci/telemetry_gate.sh) runs on machines
 # where jax does not exist, and tests/test_metric_names.py pins that
 # with a poisoned-jax import. Everything below behaves exactly like
 # the old eager imports: `dstpu.DeepSpeedEngine`, `dstpu.zero`,

@@ -85,7 +85,7 @@ def quantize_llama_serving_params(sparams):
 
 
 def random_int8_serving_params(cfg: LlamaConfig, seed=0):
-    """Random int8 packed serving tree — bench/verify harnesses read
+    """Random int8 packed serving tree — a harness reads
     exactly the bytes a converted checkpoint would without
     materializing the bf16 model first (13.5 GB at 7B)."""
     rs = np.random.RandomState(seed)

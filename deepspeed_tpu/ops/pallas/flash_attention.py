@@ -136,7 +136,7 @@ NEG_INF = -1e30
 # a fp32 output block before; the bf16 output block beside it is half
 # what that was), which moved the ceiling DOWN: bf16 S=4096, D=64 compiled
 # in a small harness but the same shapes inside a larger program
-# (bench.py's S=4096 dense case, BH=64) overflowed scoped vmem by 284 KB
+# (a dense S=4096 train step, BH=64) overflowed scoped vmem by 284 KB
 # — so the unchunked cutoff is S*D*itemsize <= 256 KB (S=2048 at D=64
 # bf16) and S=4096 routes to the chunked kernels, whose per-chunk
 # residency is bounded. The chunked kernels use half of this per chunk

@@ -413,7 +413,7 @@ def hierarchy_wire_bytes(buckets, flags, plan: HierarchyPlan):
     both ways (all_to_all + server all-gather, (ni-1)/ni·pn/(8k) bytes
     each) plus 2(ni-1) fp32 scales. ``inter_uncompressed`` is the
     would-have-been fp32 cost of the same slow hop — the compression
-    denominator the bench's bytes_reduction headline divides by."""
+    denominator of the compression's reduction in bytes."""
     from deepspeed_tpu.parallel import compression as comp
     k, ni = plan.intra, plan.inter
     intra = inter = inter_unc = 0

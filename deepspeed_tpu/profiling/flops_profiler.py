@@ -8,8 +8,6 @@ and it includes fusion effects. Per-module breakdown comes from a jaxpr walk
 with flax module path annotations.
 """
 
-import time
-
 import jax
 import numpy as np
 
@@ -18,8 +16,7 @@ from deepspeed_tpu.utils.logging import logger
 
 # per-chip dense bf16 peak FLOPS keyed by ``device.device_kind`` exactly
 # as JAX reports it (Google Cloud TPU documentation, per-chip figures) —
-# the denominator of MFU. The single source of truth: bench.py and the
-# engine's telemetry MFU gauge both resolve through peak_device_flops().
+# the denominator of the engine's ``train/mfu`` gauge.
 PEAK_BF16_FLOPS = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,   # v5e
@@ -28,41 +25,6 @@ PEAK_BF16_FLOPS = {
     "TPU v5": 459e12,
     "TPU v6 lite": 918e12,   # v6e
 }
-
-
-def peak_device_flops(device=None):
-    """Dense bf16 peak of ``device`` (default: jax.devices()[0]). A
-    device kind that is not in the table (a CPU included) raises: an MFU
-    against a guessed peak is not a measurement."""
-    if device is None:
-        device = jax.devices()[0]
-    kind = device.device_kind
-    if kind not in PEAK_BF16_FLOPS:
-        raise ValueError(
-            f"no bf16 peak recorded for device_kind {kind!r}; known kinds: "
-            f"{sorted(PEAK_BF16_FLOPS)}")
-    return PEAK_BF16_FLOPS[kind]
-
-
-def model_flops_per_token(cfg):
-    """Analytic GPT-2-family train flops per token: the standard 6·N
-    weight-matmul accounting (fwd 2N + bwd 4N) plus the attention
-    scores/context term (12·L·S·E per token, fwd+bwd). ``cfg`` needs
-    n_layer / n_embd / vocab_size / n_positions."""
-    matmul_params = cfg.n_layer * 12 * cfg.n_embd * cfg.n_embd \
-        + cfg.vocab_size * cfg.n_embd
-    flops = 6 * matmul_params
-    flops += 12 * cfg.n_layer * cfg.n_positions * cfg.n_embd
-    return flops
-
-
-def mfu(flops_per_step, step_time_s, device=None, n_devices=1):
-    """Model flops utilization: achieved flops/s over the peak of
-    ``n_devices`` chips. Returns a fraction in [0, ~1]."""
-    if step_time_s <= 0:
-        return 0.0
-    return flops_per_step / step_time_s / (
-        peak_device_flops(device) * max(n_devices, 1))
 
 
 def flops_of_jitted(fn, *args, **kwargs):
@@ -150,17 +112,6 @@ class FlopsProfiler:
             return float(cost.get("flops", 0.0)), cost
         except Exception:
             return 0.0, {}
-
-
-def duration_of(fn, *args, warmup=1, iters=3):
-    for _ in range(warmup):
-        out = fn(*args)
-    jax.block_until_ready(out)
-    t0 = time.perf_counter()
-    for _ in range(iters):
-        out = fn(*args)
-    jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / iters
 
 
 def module_breakdown(model, example_input, depth=2, rng=None):

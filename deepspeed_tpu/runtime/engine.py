@@ -3065,7 +3065,7 @@ class DeepSpeedEngine:
 
     def telemetry_flush(self, batch=None):
         """Fence, fold the open window, export, and return the
-        snapshot — a programmatic steps_per_print boundary for bench /
+        snapshot — a programmatic steps_per_print boundary for harness /
         notebook use off the print cadence. Pass the current batch to
         (lazily) price MFU."""
         if self.state is not None:

@@ -25,7 +25,7 @@ kept open without ``O_TRUNC`` across steps, so steady-state writes reuse
 extents instead of reallocating them, and swap-in issues an
 ``fadvise(WILLNEED)`` readahead pass before reading — the first-epoch
 read path runs at steady-state bandwidth instead of the 5x-slower
-cold-file rate (bench.py ``aio_disk.first_read_mbps``).
+cold-file rate.
 
 All swap-path telemetry is sync-free (host wall timers + byte counters
 into the process registry): ``swap/bytes_read``, ``swap/bytes_written``,

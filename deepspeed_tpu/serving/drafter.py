@@ -9,7 +9,7 @@ engine. Two proposers:
 - ``NGramDrafter`` — self-drafting / prompt-lookup: match the request's
   trailing n-gram against its own history (prompt + generated) and
   propose the continuation of the most recent earlier occurrence. Pure
-  host numpy, no second checkpoint, no device work — the bench's
+  host numpy, no second checkpoint, no device work — the
   default. Wins exactly when generation is repetitive (greedy decode
   loops, structured output, quote-the-prompt tasks); on novel text the
   accept rate collapses toward 0 and each round degenerates to one

@@ -985,7 +985,7 @@ class ContinuousBatcher:
         """Run the scheduler until every request completes. With
         ``respect_arrival_times`` the queue honours each request's
         ``arrival_time`` against a wall clock started on entry —
-        the Poisson-workload mode the serving bench drives."""
+        the mode of a Poisson-arrival workload."""
         for r in sorted(requests, key=lambda r: r.arrival_time):
             self.submit(r)
         done: Dict[Any, Request] = {}

@@ -4,7 +4,7 @@ watchdog-driven autoscaling (ISSUE 11).
 One :class:`ReplicaPool` runs N in-process
 :class:`~deepspeed_tpu.serving.engine.ContinuousBatcher` replicas
 (sharing one adapter's compiled programs — the long-lived-server shape
-the serving bench measures) and owns the request ledger above them:
+of serving) and owns the request ledger above them:
 
 - **dispatch**: arrivals go to the least-loaded live replica;
 - **recovery**: a replica that dies (an injected ``SimulatedCrash``
@@ -98,7 +98,7 @@ def percentile_summary(vals):
 
 def merged_reservoir(engines, name):
     """Concatenate one histogram's raw values across engines, counting
-    a SHARED registry once (the bench's merged-stream case)."""
+    a SHARED registry once (the merged-stream case)."""
     vals, seen = [], set()
     for cb in engines:
         if id(cb.metrics) in seen:
@@ -595,7 +595,7 @@ class ReplicaPool:
         the MERGED raw reservoirs (averaging per-replica percentiles
         would be wrong under skewed load), per-replica slot
         utilization / queue depth, and the pool's lost / retried /
-        recovered counters — the document the serving bench embeds and
+        recovered counters — the document
         a disaggregated router would schedule on."""
         per_replica = {}
         active = slots = queued = 0

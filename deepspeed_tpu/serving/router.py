@@ -3,8 +3,8 @@
 
 Mixed traffic head-of-line blocks a colocated engine: its slots are
 decode residency, so an arriving prompt waits for some long request to
-FINISH before it can even prefill (BENCH_r08: TTFT p99 4.96 s vs p50
-3.05 s), and symmetrically a long prefill dispatch sits between two
+FINISH before it can even prefill, and symmetrically a long prefill
+dispatch sits between two
 decode ticks of every in-flight request. The split:
 
 - **prefill-role engines** (``ContinuousBatcher(role="prefill")``)
@@ -612,8 +612,8 @@ class DisaggRouter:
     # --------------------------------------------------------- telemetry
 
     def metrics_snapshot(self) -> Dict[str, Any]:
-        """Router + per-role aggregation (the document the serving
-        bench embeds): merged TTFT/breakdown percentiles over the
+        """Router + per-role aggregation:
+        merged TTFT/breakdown percentiles over the
         prefill engines' raw reservoirs, per-engine role rows, the
         reservation/queue state and the handoff counters."""
         from deepspeed_tpu.serving.replica_pool import (

@@ -528,8 +528,8 @@ class DecodeNode:
                      "generated": len(req.generated)},
                     src=self.endpoint.rank, dst=0)))
         # slot-utilization denominator counts the FULL decode budget of
-        # the tick (idle ticks show as low utilization — the bench's
-        # honesty signal), busy time only what actually stepped
+        # the tick (idle ticks show as low utilization,
+        # honestly), busy time only what actually stepped
         self.stats["slot_cap_ticks"] += len(cb.slots) * self.decode_ticks
         if stepped:
             self.stats["decode_busy_s"] += time.thread_time() - t_busy

@@ -55,24 +55,26 @@ _PROVENANCE = None
 def _provenance_doc():
     """Cached host/build stamp for dump headers (ISSUE 19 satellite):
     a dump read days later off a shared scratch dir must answer "which
-    box, which sha, which restart epoch" without archaeology. Reuses
-    ``bench.provenance()`` when the repo-root module is importable
-    (the git subprocess runs ONCE per process, not per dump); degrades
-    to the same shape inline when it is not (installed package, no
-    repo checkout)."""
+    box, which sha, which restart epoch" without archaeology. The git
+    subprocess runs ONCE per process, not per dump; an installed package
+    with no checkout round it reads ``git_sha: "unknown"``."""
     global _PROVENANCE
     if _PROVENANCE is None:
+        import platform
+        import socket
+        import subprocess
         try:
-            from bench import provenance
-            _PROVENANCE = provenance()
+            sha = subprocess.check_output(
+                ["git", "rev-parse", "--short", "HEAD"],
+                cwd=os.path.dirname(os.path.abspath(__file__)),
+                stderr=subprocess.DEVNULL).decode().strip()
         except Exception:
-            import platform
-            import socket
-            _PROVENANCE = {"git_sha": "unknown",
-                           "hostname": socket.gethostname(),
-                           "cpu_count": os.cpu_count(),
-                           "jax_version": "unknown",
-                           "python_version": platform.python_version()}
+            sha = "unknown"
+        _PROVENANCE = {"git_sha": sha,
+                       "hostname": socket.gethostname(),
+                       "cpu_count": os.cpu_count(),
+                       "jax_version": "unknown",
+                       "python_version": platform.python_version()}
     return _PROVENANCE
 
 

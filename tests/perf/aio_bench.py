@@ -5,7 +5,7 @@ submit mode against libaio).
 Measures MB/s for write + read of a tensor-sized file through each backend
 (io_uring ring vs pread/pwrite thread pool) across queue depths and block
 sizes. Run directly for the sweep table, or import `quick_throughput` for
-the single-point number bench.py reports.
+the pinned single point (median of several passes, and the O_DIRECT leg).
 
 Usage: python tests/perf/aio_bench.py [--mb 512] [--dir /tmp]
 """
@@ -47,7 +47,7 @@ def _run_case(handle, arr, path, write_first=True):
 
 def quick_throughput(mb=256, directory=None, queue_depth=32,
                      block_size=1 << 20, trials=3):
-    """Pinned-methodology MB/s point for bench.py.
+    """Pinned-methodology MB/s point.
 
     Round-3 postmortem: a single write+read pass is measuring LUCK on a
     virtualized disk — the guest-side fadvise(DONTNEED) drops the guest
@@ -146,9 +146,13 @@ def sweep(mb, directory):
     return rows
 
 
-if __name__ == "__main__":
+def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--mb", type=int, default=512)
     ap.add_argument("--dir", default=None)
     args = ap.parse_args()
     sweep(args.mb, args.dir)
+
+
+if __name__ == "__main__":
+    main()

@@ -80,16 +80,10 @@ def programs(attend, argnums=(0, 1, 2)):
 
 def grid_steps(H, Hkv, W, bq, bk, chunk):
     """Grid steps of the (forward or dq, dkv) call and the score tiles each
-    computes, of this tree's kernels: PR 43's ``_band_plan``, or the
-    parent's one aligned chunk a step."""
-    if hasattr(fa, "_band_plan"):
-        (_, steps_k), (_, steps_q, heads) = fa._band_plan(
-            S, bq, bk, W, D * 2, H // Hkv, chunk or 0)
-        steps_q /= heads
-    else:
-        chunk = chunk or max(bq, bk)
-        steps_k = fa._band_extent(S, bq, chunk, W, keys=True)
-        steps_q = fa._band_extent(S, bk, chunk, W, keys=False)
+    computes, from the kernels' own ``_band_plan``."""
+    (_, steps_k), (_, steps_q, heads) = fa._band_plan(
+        S, bq, bk, W, D * 2, H // Hkv, chunk or 0)
+    steps_q /= heads
     over_k = sum((q0 + bq - 1) // bk - max(q0 - W + 1, 0) // bk + 1
                  for q0 in range(0, S, bq))
     over_q = sum(min((k0 + bk + W - 2) // bq + 1, S // bq) - k0 // bq

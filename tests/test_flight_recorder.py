@@ -320,8 +320,7 @@ def test_serving_events_render_request_timelines(tmp_path):
 
 def test_recorder_disabled_engine_records_nothing(tmp_path):
     """monitor.flight_recorder.enabled=false: the hot-path record()
-    calls all no-op (the recorder-off cost is one branch — the bench's
-    <1% overhead contract)."""
+    calls all no-op (the recorder-off cost is one branch)."""
     default_recorder().clear()
     cfg = base_config(steps_per_print=1)
     cfg["monitor"] = {"enabled": False,

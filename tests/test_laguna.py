@@ -3,22 +3,20 @@ benchmark's plain reference (``benchmark/reference/laguna.py``) for every
 layer kind and every gradient leaf, with all experts held and with a share;
 the eight shares adding up to the uncut layer; each named omission failing
 the benchmark's check; the layer plan of the published depth and of the
-cut; YaRN's angles against the formula written out by hand; the model on
-the engine under ZeRO-3 and remat. Seeded weights, float32.
+cut; YaRN's angles against the formula written out by hand. Seeded weights,
+float32. Remat and the router's choice: ``tests/test_laguna_remat.py``; the
+model on the engine: ``tests/test_laguna_engine.py``.
 """
 
 import copy
 import dataclasses
-import json
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import manifest
 from benchmark.families import laguna as fam
 from benchmark.reference import laguna as ref
 from deepspeed_tpu.models.laguna import (FULL, SLIDING, LagunaConfig,
@@ -27,10 +25,9 @@ from deepspeed_tpu.models.laguna import (FULL, SLIDING, LagunaConfig,
                                          yarn_rope_angles)
 from deepspeed_tpu.models.llama import rope_angles
 from deepspeed_tpu.moe.dropless import DroplessMoE
+from tests.cell_config import config_file
 
-with open(os.path.join(manifest.HERE, "configs",
-                       "laguna-xs2-33b-a3b-ep8-depth5.json")) as f:
-    FILE = json.load(f)
+FILE = config_file("laguna-xs2-33b-a3b-ep8-depth5")
 
 
 def _published(**over):
@@ -169,56 +166,6 @@ def test_each_omission_fails_the_check(tiny, monkeypatch, omission, override,
     if branch == "swa_out_rel":
         # the leading full-attention layer runs under it and is still right
         assert diffs["by_layer"][0][0] < 1e-5
-
-
-def test_remat_on_and_off_agree_and_keep_the_routers_choice():
-    ids = jnp.asarray(np.random.default_rng(2).integers(0, 256, (2, 64)),
-                      jnp.int32)
-
-    def grads(remat):
-        model = LagunaForCausalLM(laguna_tiny(experts_held=4, remat=remat))
-        params = model.init(jax.random.PRNGKey(0), ids)["params"]
-        fn = jax.grad(lambda p: model.apply({"params": p}, ids, labels=ids))
-        return fn(params), str(jax.make_jaxpr(fn)(params))
-
-    (want, plain), (got, rematted) = grads(False), grads(True)
-    assert "moe_experts" in rematted and "moe_experts" not in plain
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-4)
-
-
-@pytest.mark.parametrize("base,again", [(None, 0), (("moe_experts",), 5)],
-                         ids=["kept", "control"])
-def test_rematted_blocks_keep_what_their_attention_kernels_produced(
-        base, again, monkeypatch, capsys):
-    """Under remat a block keeps ``flash_o`` / ``flash_lse``
-    (``models/gpt2.block_remat_policy``): the backward pass is handed them,
-    no forward attention kernel — causal or window — sits under
-    ``rematted_computation`` in the compiled step, and the gradients are the
-    unrematted ones. The control cuts the base set back to the router's
-    choice: all five layers' forward kernels are then run again."""
-    from deepspeed_tpu.models import gpt2
-    from tests import hlo_text
-    if base:
-        monkeypatch.setattr(gpt2, "REMAT_BASE_NAMES", base)
-    ids = jnp.asarray(np.random.default_rng(4).integers(0, 256, (1, 128)),
-                      jnp.int32)
-    cfg = laguna_tiny(num_hidden_layers=5, experts_held=4, use_flash=True)
-    params = LagunaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids)["params"]
-
-    def loss(remat):
-        model = LagunaForCausalLM(dataclasses.replace(cfg, remat=remat))
-        return lambda p: model.apply({"params": p}, ids, labels=ids)
-
-    sites, handed, step = hlo_text.remat_report(loss(True), params, capsys)
-    assert len(sites) == again, sites
-    assert ("named 'flash_lse'" in handed) == (base is None)
-    if base is None:
-        want = jax.jit(jax.grad(loss(False)))(params)
-        for a, b in zip(jax.tree_util.tree_leaves(step.compile()(params)),
-                        jax.tree_util.tree_leaves(want)):
-            np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-4)
 
 
 # ------------------------------------------------------- the expert layer
@@ -408,64 +355,3 @@ def test_yarn_at_factor_one_is_plain_rope():
     for a, b in zip(got, want):
         # float32 angles up to 300 rad: the blend's rounding, no more
         np.testing.assert_allclose(a, b, atol=2e-5)
-
-
-# ------------------------------------------------ the model on the engine
-
-@pytest.mark.parametrize("depth", [5, 12], ids=["the_cut", "1+2x4+3"])
-def test_trains_through_the_engine_under_zero3_with_remat(depth):
-    """``dstpu.initialize`` over two devices, ZeRO-3, every block under its
-    gather edge and remat — the cut (1 + 4) and a depth with a tail outside
-    the scan (1 + 2 x 4 + 3, as the published 40 = 1 + 9 x 4 + 3): the loss
-    falls on a repeated batch, the first loss is the system step's, and the
-    ``moe/*`` gauges are folded."""
-    config = copy.deepcopy(FILE)
-    config["rehearse_cpu"]["model"].update(remat=True)
-    config["rehearse_cpu"].update(
-        num_hidden_layers=depth,
-        layer_types=[FULL if i % 4 == 0 else SLIDING for i in range(depth)],
-        mlp_layer_types=["dense"] + ["sparse"] * (depth - 1),
-        num_attention_heads_per_layer=[3 if i % 4 == 0 else 4
-                                       for i in range(depth)])
-    ids = np.random.default_rng(1).integers(0, 512, (2, 64)).astype(np.int32)
-    engine, params = fam.build_train(config, 2, 0, jax.devices()[:2], True)
-    assert engine.zero.layer_stacked_prefixes == ("layers",)
-    assert fam.model_config(config, True).plan == (1, 4, (depth - 1) // 4,
-                                                   (depth - 1) % 4)
-    want = float(fam.system_step(config, params, ids, jax.devices()[0],
-                                 True)[0])
-    losses = [float(engine.train_batch({"input_ids": ids}))
-              for _ in range(6)]
-    assert losses[0] == pytest.approx(want, abs=0.02)
-    assert losses[-1] < losses[0] - 0.02
-    gauges = engine.telemetry_flush()["gauges"]
-    assert gauges["moe/dropped_rows"] == 0
-    assert 0.05 < gauges["moe/rows_held_share"] < 0.6      # 1/4 at uniform
-    assert gauges["moe/held_slabs"] >= 1.0
-    assert gauges["moe/combine_rows_walked"] >= 1.0
-
-
-def test_the_window_layers_run_the_window_kernels_where_flash_is_on():
-    """``use_flash=True`` (the TPU's choice) sends a sliding layer through
-    the window kernels — here in the interpreter — and a full layer through
-    the causal ones; the outputs are the reference path's."""
-    ids = jnp.asarray(np.random.default_rng(4).integers(0, 256, (1, 128)),
-                      jnp.int32)
-    cfg = laguna_tiny(num_hidden_layers=5, experts_held=4)
-    params = LagunaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids)["params"]
-
-    def run(use_flash):
-        import dataclasses
-        model = LagunaForCausalLM(dataclasses.replace(cfg,
-                                                      use_flash=use_flash))
-        fn = lambda p: model.apply({"params": p}, ids, labels=ids)  # noqa
-        return fn(params), jax.grad(fn)(params), str(jax.make_jaxpr(fn)(
-            params))
-
-    (want, want_g, plain), (got, got_g, flash) = run(False), run(True)
-    assert "_flash_attention_swa" in flash \
-        and "_flash_attention_swa" not in plain
-    assert float(got) == pytest.approx(float(want), abs=1e-5)
-    for a, b in zip(jax.tree_util.tree_leaves(got_g),
-                    jax.tree_util.tree_leaves(want_g)):
-        np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-3)

@@ -1,12 +1,10 @@
 """Nothing on the main path may hide that the device did not do the work
 (ISSUE 21): a flash kernel that fails raises, the TPU rule is the default
 backend and an initialisation error is not "no TPU", a device mesh that cannot
-be built on a TPU raises, bench.py fails when a section throws and has no CPU
-route to a device metric, and the compile cache goes where the environment
+be built on a TPU raises, and the compile cache goes where the environment
 says."""
 
 import importlib
-import json
 import os
 import subprocess
 import sys
@@ -71,62 +69,6 @@ def test_make_mesh_raises_on_tpu_and_reshapes_only_on_cpu(monkeypatch):
     assert mesh.shape["data"] == 4
 
 
-# ------------------------------------------------------------------ bench
-
-def test_section_runner_records_errors_and_refuses_device_sections_on_cpu():
-    import bench
-    r = bench.SectionRunner(on_tpu=False)
-    assert r.run("aio", lambda: {"mb_s": 1}) == {"mb_s": 1}
-
-    def boom():
-        raise RuntimeError("kernel did not lower")
-    assert "kernel did not lower" in r.run("serving", boom)["error"]
-    assert "needs a TPU" in r.run("train", lambda: {"mfu_pct": 1})["error"]
-    assert sorted(r.failed) == ["serving", "train"] and not r.skipped
-    assert bench.DEVICE_SECTIONS <= set(bench.BENCH_SECTIONS)
-    # deselected is a skip, not a failure
-    r = bench.SectionRunner(("aio",), on_tpu=False)
-    assert "skipped" in r.run("train", boom) and not r.failed
-
-
-@pytest.fixture
-def bench_on_cpu(monkeypatch):
-    import bench
-    from deepspeed_tpu.utils import platform
-    # bench.main would place the process-wide compile cache; not in a test
-    monkeypatch.setattr(platform, "enable_compile_cache", lambda: None)
-    return bench
-
-
-def test_bench_exits_nonzero_when_a_selected_section_throws(
-        bench_on_cpu, monkeypatch, capsys):
-    monkeypatch.setenv("DSTPU_BENCH_ALLOW_CPU", "1")
-
-    def boom():
-        raise RuntimeError("supervisor lost a rank")
-    monkeypatch.setattr(bench_on_cpu, "bench_fault_recovery", boom)
-    assert bench_on_cpu.main(["--sections", "fault_recovery"]) == 1
-    out = capsys.readouterr().out
-    result = json.loads([l for l in out.splitlines()
-                         if l.startswith('{"meta"')][-1])
-    assert "supervisor lost a rank" in \
-        result["detail"]["sections_failed"]["fault_recovery"]
-    assert result["detail"]["fault_recovery"]["error"]
-
-
-def test_bench_has_no_cpu_route_to_a_device_metric(bench_on_cpu,
-                                                   monkeypatch, capsys):
-    monkeypatch.delenv("DSTPU_BENCH_ALLOW_CPU", raising=False)
-    with pytest.raises(RuntimeError, match="needs a TPU"):
-        bench_on_cpu.main(["--sections", "train"])
-    monkeypatch.setenv("DSTPU_BENCH_ALLOW_CPU", "1")
-    assert bench_on_cpu.main(["--sections", "train"]) == 1
-    result = json.loads([l for l in capsys.readouterr().out.splitlines()
-                         if l.startswith('{"meta"')][-1])
-    assert result["value"] is None
-    assert "needs a TPU" in result["detail"]["sections_failed"]["train"]
-
-
 # ---------------------------------------------------------- compile cache
 
 def _cache_dir_after_enable(**env):
@@ -151,6 +93,5 @@ def test_compile_cache_is_placed_from_outside_or_at_the_fixed_path(tmp_path):
     src = open(os.path.join(repo, "deepspeed_tpu", "utils",
                             "platform.py")).read()
     assert src.count('"jax_compilation_cache_dir"') == 1
-    for path in ("bench.py", "chip_smoke.py"):
-        assert "jax_compilation_cache_dir" not in open(
-            os.path.join(repo, path)).read()
+    assert "jax_compilation_cache_dir" not in open(
+        os.path.join(repo, "chip_smoke.py")).read()

@@ -1,0 +1,56 @@
+"""The probes under tests/perf/ run only in chip sessions, and they cite the
+package's kernels and helpers by name. Between sessions this holds them to the
+tree: each script imports on the CPU backend, every ``from x import y``
+anywhere in it and every ``module.name`` it reads off a module it bound at
+import resolves, and a script with a command line parses ``--help``."""
+
+import ast
+import glob
+import importlib
+import os
+import sys
+import types
+
+import pytest
+
+import deepspeed_tpu  # noqa: F401  (before a script puts its own path first)
+
+PERF = os.path.join(os.path.dirname(os.path.abspath(__file__)), "perf")
+SCRIPTS = sorted(os.path.basename(p)[:-3]
+                 for p in glob.glob(os.path.join(PERF, "*.py"))
+                 if not p.endswith("__init__.py"))
+
+
+def test_the_scripts_that_stay_are_the_nine():
+    """A probe is added here by name, with the Finding or the comment that
+    cites it: 22 one-off probes had piled up before PR 46."""
+    assert SCRIPTS == [
+        "adam_test", "aio_bench", "blocksparse_sweep", "flash_chunked_bench",
+        "gdn_scan_bench", "gmm_tile_bench", "mixer_elementwise_bench",
+        "rows_to_tokens_bench", "swa_bench"]
+
+
+@pytest.mark.parametrize("name", SCRIPTS)
+def test_perf_script_imports_and_names_what_exists(name, monkeypatch, capsys):
+    script = os.path.join(PERF, name + ".py")
+    monkeypatch.setattr("sys.argv", [script, "--help"])
+    monkeypatch.setattr("sys.path", list(sys.path))     # scripts prepend
+    mod = importlib.import_module("tests.perf." + name)
+    tree = ast.parse(open(script).read())
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom):
+            src = importlib.import_module(node.module)
+            for alias in node.names:
+                if not hasattr(src, alias.name):    # a submodule, or gone
+                    importlib.import_module(f"{node.module}.{alias.name}")
+        elif isinstance(node, ast.Attribute) and isinstance(node.value,
+                                                            ast.Name):
+            bound = getattr(mod, node.value.id, None)
+            if isinstance(bound, types.ModuleType):
+                assert hasattr(bound, node.attr), \
+                    f"{name}: {bound.__name__} has no {node.attr}"
+    if "argparse" in vars(mod):
+        with pytest.raises(SystemExit) as done:
+            mod.main()
+        assert done.value.code == 0
+        assert "usage:" in capsys.readouterr().out

@@ -5,18 +5,17 @@ XLA form here; the model's layers run its Pallas kernels in the interpreter)
 against the recurrence as written, the expert layer told which experts it
 holds (the shares add up to the uncut layer; all rows held), and each named
 omission failing the benchmark's check. Two periods, seeded weights, float32.
+Remat and the router's choice: ``tests/test_qwen3_next_remat.py``; the model
+on the engine: ``tests/test_qwen3_next_engine.py``.
 """
 
 import copy
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import manifest
 from benchmark.families import qwen3_next as fam
 from benchmark.reference import qwen3_next as ref
 from deepspeed_tpu.moe.dropless import (DroplessMoE, rows_to_tokens,
@@ -28,10 +27,9 @@ from deepspeed_tpu.ops.gated_delta import (CHUNK, gated_delta_recurrence,
                                            unit_lower_inverse)
 from deepspeed_tpu.ops.gated_delta import \
     gated_delta_rule_xla as gated_delta_rule
+from tests.cell_config import config_file
 
-with open(os.path.join(manifest.HERE, "configs",
-                       "qwen3-next-80b-a3b-ep16-depth4.json")) as f:
-    FILE = json.load(f)
+FILE = config_file("qwen3-next-80b-a3b-ep16-depth4")
 
 
 def _float32(config):
@@ -374,120 +372,3 @@ def test_rows_to_tokens_is_the_transpose_of_tokens_to_rows(case):
     np.testing.assert_allclose(jax.grad(
         lambda r: jnp.sum(rows_to_tokens(r, tok, T, k) * x))(r), rows,
         atol=1e-6)
-
-
-# ------------------------------------------------ the model on the engine
-
-def test_builds_at_the_published_depth_abstractly():
-    from deepspeed_tpu.models.qwen3_next import (Qwen3NextForCausalLM,
-                                                 qwen3_next_80b_a3b)
-    cfg = qwen3_next_80b_a3b(experts_held=32)
-    shapes = jax.eval_shape(
-        lambda r, x: Qwen3NextForCausalLM(cfg).init(r, x)["params"],
-        jax.random.PRNGKey(0), jnp.zeros((1, 128), jnp.int32))
-    layers = shapes["layers"]
-    assert sorted(layers) == ["l0", "l1", "l2", "l3"]
-    assert layers["l0"]["linear_attn"]["in_proj_qkvz"]["kernel"].shape == (
-        12, 2048, 12288)
-    assert layers["l3"]["attn"]["q_proj"]["kernel"].shape == (12, 2048, 8192)
-    assert layers["l3"]["mlp"]["router"].shape == (12, 2048, 512)
-    assert layers["l3"]["mlp"]["gate_proj"].shape == (12, 32, 2048, 512)
-    count = sum(int(np.prod(x.shape))
-                for x in jax.tree_util.tree_leaves(shapes))
-    assert count == cfg.num_params()
-    # all 512 experts held: the published model, 80B by this count
-    assert qwen3_next_80b_a3b().num_params() == pytest.approx(79.67e9,
-                                                              rel=1e-3)
-
-
-@pytest.mark.parametrize("held", [0, 4], ids=["all_experts", "a_share"])
-def test_remat_keeps_the_routers_choice_whatever_is_held(held):
-    """A rematted block recomputes its forward pass in the backward pass; the
-    policy saves the router's choice under the name ``moe_experts``, and the
-    expert layer carries that name because the MODEL recomputes, whether or
-    not it holds a share: the gradients are those of the step without
-    remat, and the name is among what the backward pass is handed."""
-    from deepspeed_tpu.models.qwen3_next import (Qwen3NextForCausalLM,
-                                                 qwen3_next_tiny)
-    ids = jnp.asarray(np.random.default_rng(2).integers(0, 256, (2, 64)),
-                      jnp.int32)
-
-    def grads(remat):
-        model = Qwen3NextForCausalLM(qwen3_next_tiny(
-            num_hidden_layers=4, experts_held=held, remat=remat))
-        params = model.init(jax.random.PRNGKey(0), ids)["params"]
-        fn = jax.grad(lambda p: model.apply({"params": p}, ids, labels=ids))
-        return fn(params), str(jax.make_jaxpr(fn)(params))
-
-    (want, plain), (got, rematted) = grads(False), grads(True)
-    assert "moe_experts" in rematted and "moe_experts" not in plain
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-4)
-
-
-@pytest.mark.parametrize("base,again", [(None, 0), (("moe_experts",), 1)],
-                         ids=["kept", "control"])
-def test_rematted_blocks_keep_what_their_attention_kernel_produced(
-        base, again, monkeypatch, capsys):
-    """As ``tests/test_laguna.py``'s test of the same name: under remat the
-    period's attention layer keeps ``flash_o`` / ``flash_lse``, its forward
-    kernel is not under ``rematted_computation`` in the compiled step, and
-    the gradients are the unrematted ones; with the base set cut back to
-    the router's choice it is."""
-    from deepspeed_tpu.models import gpt2
-    from deepspeed_tpu.models.qwen3_next import (Qwen3NextForCausalLM,
-                                                 qwen3_next_tiny)
-    from tests import hlo_text
-    if base:
-        monkeypatch.setattr(gpt2, "REMAT_BASE_NAMES", base)
-    # the delta rule's form is not what is asked about: its kernels in the
-    # interpreter take most of a minute to lower
-    monkeypatch.setattr("deepspeed_tpu.models.qwen3_next.gated_delta_rule",
-                        gated_delta_rule)      # this file's: the XLA form
-    ids = jnp.asarray(np.random.default_rng(2).integers(0, 256, (1, 64)),
-                      jnp.int32)
-
-    def loss(remat):
-        model = Qwen3NextForCausalLM(qwen3_next_tiny(
-            num_hidden_layers=4, experts_held=4, use_flash=True,
-            remat=remat))
-        return lambda p: model.apply({"params": p}, ids, labels=ids)
-
-    params = Qwen3NextForCausalLM(qwen3_next_tiny(
-        num_hidden_layers=4, experts_held=4)).init(
-        jax.random.PRNGKey(0), ids)["params"]
-    sites, handed, step = hlo_text.remat_report(loss(True), params, capsys)
-    assert len(sites) == again, sites
-    # the layer scan hands its blocks' residuals on stacked, their names
-    # gone: lse is [periods, B * H, S / 64, 1, 64] (blocks of 64 on the CPU)
-    assert ("f32[1,4,1,1,64] output of scan" in handed) == (base is None)
-    if base is None:
-        want = jax.jit(jax.grad(loss(False)))(params)
-        for a, b in zip(jax.tree_util.tree_leaves(step.compile()(params)),
-                        jax.tree_util.tree_leaves(want)):
-            np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-4)
-
-
-def test_trains_through_the_engine_under_zero3_with_remat():
-    """``dstpu.initialize`` over two devices, ZeRO-3, every block under its
-    gather edge and remat: the loss falls on a repeated batch, the first
-    loss is the system step's, and the ``moe/*`` gauges are folded."""
-    config = copy.deepcopy(FILE)
-    config["rehearse_cpu"]["model"].update(remat=True)
-    config["rehearse_cpu"]["num_hidden_layers"] = 8
-    ids = np.random.default_rng(1).integers(0, 512, (2, 64)).astype(np.int32)
-    engine, params = fam.build_train(config, 2, 0, jax.devices()[:2], True)
-    assert engine.zero.layer_stacked_prefixes == ("layers",)
-    want = float(fam.system_step(config, params, ids, jax.devices()[0],
-                                 True)[0])
-    losses = [float(engine.train_batch({"input_ids": ids}))
-              for _ in range(6)]
-    assert losses[0] == pytest.approx(want, abs=0.02)
-    assert losses[-1] < losses[0] - 0.02
-    gauges = engine.telemetry_flush()["gauges"]
-    assert gauges["moe/dropped_rows"] == 0
-    assert 0.05 < gauges["moe/rows_held_share"] < 0.6      # 1/4 at uniform
-    assert gauges["moe/held_slabs"] >= 1.0
-    assert gauges["moe/combine_rows_walked"] >= 1.0
-    assert gauges["moe/rows_max_over_mean"] >= 1.0

@@ -14,6 +14,8 @@ Covers the acceptance surface of the paged-KV subsystem:
   block's validation.
 """
 
+import time
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -284,9 +286,14 @@ def test_gpt2_paged_serving_int8_matches_generate(rs, gpt2_serving):
     np.testing.assert_array_equal(res[0].tokens(), ref)
 
 
-def test_gpt2_more_requests_than_slots(rs, gpt2_serving):
+@pytest.mark.parametrize("arrivals", [None, (0.0, 0.0, 0.01, 0.03, 0.6)],
+                         ids=["all_queued", "on_an_arrival_clock"])
+def test_gpt2_more_requests_than_slots(rs, gpt2_serving, arrivals):
     """5 requests through 2 slots: freed slots re-admit mid-flight and
-    every request still matches a solo run. The oracle is a fresh paged
+    every request still matches a solo run — queued together, or each due
+    at its ``arrival_time`` (``respect_arrival_times``: the last arrives
+    after the engine has gone idle; none is lost, and the run lasts at
+    least until the last is due). The oracle is a fresh paged
     engine serving each request ALONE (dense-path parity is pinned by
     test_gpt2_paged_serving_matches_generate; the property here is
     scheduler correctness under slot contention — and the solo engine
@@ -298,9 +305,15 @@ def test_gpt2_more_requests_than_slots(rs, gpt2_serving):
     news = (9, 2, 6, 11, 4)
     prompts = [rs.randint(0, 256, size=(s,)).astype(np.int32)
                for s in lens]
-    res = eng.serve([serving.Request(i, p, max_new_tokens=n)
-                     for i, (p, n) in enumerate(zip(prompts, news))])
+    reqs = [serving.Request(i, p, max_new_tokens=n,
+                            arrival_time=arrivals[i] if arrivals else 0.0)
+            for i, (p, n) in enumerate(zip(prompts, news))]
+    t0 = time.monotonic()
+    res = eng.serve(reqs, respect_arrival_times=arrivals is not None)
     assert len(res) == 5
+    if arrivals:
+        assert time.monotonic() - t0 >= arrivals[-1]
+        assert eng.metrics_snapshot()["ttft_s"]["count"] == 5
     # a second batcher over the SAME adapter shares its compiled
     # tick/prefill programs (fresh cache, fresh scheduler state)
     solo = serving.ContinuousBatcher(eng.adapter)

@@ -753,8 +753,7 @@ def test_restored_and_replayed_requests_keep_their_trace_id(
 def test_pool_metrics_snapshot_aggregates_replicas(gpt2_el, tmp_path):
     """ReplicaPool.metrics_snapshot(): pool TTFT percentiles over the
     replicas' merged raw reservoirs, per-replica utilization rows, and
-    the lost/retried/recovered counters (what the serving bench embeds
-    as pool_telemetry)."""
+    the lost/retried/recovered counters."""
     _cfg, _params, make = gpt2_el
     pool = ReplicaPool(_pool_factory(make, tmp_path, interval_ticks=0),
                        n_replicas=2, min_replicas=1, max_replicas=2,

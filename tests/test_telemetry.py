@@ -193,49 +193,6 @@ def test_jsonl_exporter_rotation_off_by_default(tmp_path):
     assert sorted(os.listdir(tmp_path)) == ["m.jsonl"]
 
 
-# --------------------------------------------------------------- MFU math
-
-def test_model_flops_per_token_known_shape():
-    from deepspeed_tpu.profiling.flops_profiler import model_flops_per_token
-    from deepspeed_tpu.models.gpt2 import GPT2Config
-    cfg = GPT2Config(vocab_size=512, n_positions=128, n_embd=64,
-                     n_layer=2, n_head=2)
-    # 6 * (L*12*E^2 + V*E) + 12*L*S*E, by hand:
-    expected = 6 * (2 * 12 * 64 * 64 + 512 * 64) + 12 * 2 * 128 * 64
-    assert model_flops_per_token(cfg) == expected
-    # bench.py must resolve through the same canonical copy
-    import bench
-    assert bench.model_flops_per_token(cfg) == expected
-
-
-def test_mfu_math_and_peak_table():
-    import types
-    from deepspeed_tpu.profiling.flops_profiler import (
-        mfu, peak_device_flops, PEAK_BF16_FLOPS)
-    v5e = types.SimpleNamespace(device_kind="TPU v5 lite")
-    peak = peak_device_flops(v5e)
-    assert peak == PEAK_BF16_FLOPS["TPU v5 lite"] == 197e12
-    assert mfu(peak / 2.0, 1.0, device=v5e) == pytest.approx(0.5)
-    assert mfu(peak, 2.0, device=v5e) == pytest.approx(0.5)
-    assert mfu(peak, 1.0, device=v5e, n_devices=4) == pytest.approx(0.25)
-    assert mfu(peak, 0.0, device=v5e) == 0.0
-
-
-def test_peak_flops_raises_on_unknown_device_kind():
-    """No default peak: the CPU backend and a TPU kind outside the table
-    both raise, naming the kind."""
-    import types
-    import jax
-    from deepspeed_tpu.profiling.flops_profiler import mfu, peak_device_flops
-    cpu_kind = jax.devices()[0].device_kind
-    with pytest.raises(ValueError, match=cpu_kind):
-        peak_device_flops()
-    with pytest.raises(ValueError, match="TPU v5 litex"):
-        peak_device_flops(types.SimpleNamespace(device_kind="TPU v5 litex"))
-    with pytest.raises(ValueError, match=cpu_kind):
-        mfu(1e12, 1.0)
-
-
 # ------------------------------------------------- engine + trace window
 
 def test_engine_scalar_stream_mfu_and_trace_window(tmp_path, monkeypatch):

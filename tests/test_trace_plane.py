@@ -377,7 +377,5 @@ def test_watchdog_dump_header_carries_provenance(tmp_path, monkeypatch):
     assert header["kind"] == "dump_header"
     assert header["restart_epoch"] == 3
     prov = header["provenance"]
-    # the full stamp shape, whichever path (bench.provenance or the
-    # inline fallback) produced it
     assert set(prov) >= {"git_sha", "hostname", "python_version"}
     assert prov["hostname"]

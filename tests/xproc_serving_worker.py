@@ -7,11 +7,9 @@ rank one decode engine (:class:`DecodeNode`). The SAME module backs
 - the 2-real-process acceptance tests (tests/test_serving_transport.py,
   launched through the PR-10 ``spawn_workers`` harness),
 - the supervisor SIGKILL fault acceptance (launched as the
-  ``Supervisor`` worker command with ``roles={0: "prefill", ...}``),
-- the bench xproc leg (tests/perf/serving_bench.py
-  ``run_disagg_xproc_bench``).
+  ``Supervisor`` worker command with ``roles={0: "prefill", ...}``).
 
-Stdout protocol (machine-parsed by all three callers), one line each::
+Stdout protocol (machine-parsed by both callers), one line each::
 
     RES <rid> <json done-doc>    per finished request   (rank 0 only)
     MET <json>                   final stats + metric summaries
@@ -37,12 +35,12 @@ supervisor's ``DSTPU_RESTART_EPOCH`` / ``DSTPU_HEARTBEAT_DIR`` /
 ``kill_after >= 0`` arms a RANK-1 decode self-SIGKILL after that many
 deliveries, EPOCH 0 ONLY (the fault under test; pinned to rank 1 so a
 D>=2 world loses exactly one decode rank). ``slots``/``num_blocks``
-size the engine geometry per leg (ISSUE 18: the default 2-slot pool
-made the bench TTFT tail pure queue wait — benches must say which
-geometry they measured); ``addressing`` picks the wire mode
+size the engine geometry per leg (ISSUE 18: with the default 2-slot pool
+a TTFT tail is pure queue wait — a caller says which geometry it
+drives); ``addressing`` picks the wire mode
 (targeted|broadcast); ``tick_cap > 0`` overrides
-``serving.router.decode_tick_cap`` (the scale-out bench uses 1 so
-streams stay resident long enough to saturate every rank's slots).
+``serving.router.decode_tick_cap`` (1 keeps
+streams resident long enough to saturate every rank's slots).
 """
 
 import json
