@@ -51,6 +51,16 @@ def reference_attention(q, k, v, causal=False, bias=None, scale=None,
     return jnp.einsum("bhst,bhtd->bhsd", probs.astype(q.dtype), v)
 
 
+def check_qkv_shapes(q, k, v):
+    """Raise, with the shapes, where k is not as wide as q or v is not one
+    value a key (v's own width is free: the output takes it)."""
+    if q.shape[-1] != k.shape[-1] or k.shape[:-1] != v.shape[:-1]:
+        raise ValueError(
+            "attention contracts q with k over one head width and takes one "
+            f"value a key: q {tuple(q.shape)}, k {tuple(k.shape)}, "
+            f"v {tuple(v.shape)}")
+
+
 def to_head_major(t, heads):
     """[B, S, H*D] -> [B, H, S, D]."""
     B, S, E = t.shape
@@ -132,11 +142,23 @@ def dot_product_attention(q, k, v, causal=False, bias=None, scale=None,
     reference behind the caller's back. A model whose q, k, v are one
     fused projection has ``fused_qkv_attention``. ``window`` (with
     ``causal``): a query sees itself and the ``window - 1`` keys before
-    it; on the flash path the window kernels walk that band alone."""
+    it; on the flash path the window kernels walk that band alone.
+
+    ``v`` may be [B, Hkv, S, Dv] with Dv != D (latent attention: q·k over
+    192, values of 128): the output is Dv wide; on the flash path that is
+    the chunked kernels' alone, so with a ``window`` it raises here, with
+    the shapes, and not as a block-shape error deep in a kernel."""
     if window is not None and not causal:
         raise ValueError("a window is a causal band: pass causal=True")
+    check_qkv_shapes(q, k, v)
     if use_flash is None:
         use_flash = is_tpu_backend() and bias is None and segment_ids is None
+    if use_flash and window is not None and v.shape[-1] != q.shape[-1]:
+        raise ValueError(
+            f"window={window} with a q·k width of {q.shape[-1]} and a value "
+            f"width of {v.shape[-1]}: unequal widths run in the chunked flash "
+            f"kernels, which take no window (q {tuple(q.shape)}, "
+            f"v {tuple(v.shape)})")
     if use_flash:
         return _flash(q, k, v, causal, scale, window)
     return reference_attention(q, k, v, causal=causal, bias=bias, scale=scale,

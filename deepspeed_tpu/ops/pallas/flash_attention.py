@@ -64,7 +64,16 @@ Three kernel families share the same per-tile math (`_fwd_block_step` /
   (S 4096, head_dim 128, 16 / 16 heads: OLMoE's cell, chunk 1024) and at
   (S 8192, head_dim 256, 16 query / 2 KV heads: Qwen3-Next's cell, chunk
   512 = one block a grid step, `_CHUNK_ROW_BYTES` as it was); PERF.md
-  Findings PR 27 and PR 31 have the numbers.
+  Findings PR 27 and PR 31 have the numbers. Since PR 47 the chunked
+  family — and it ALONE — takes a q·k width that is not the value width
+  (latent attention: q and k [.., S, 192] = 128 + the 64 rotated, v
+  [.., S, 128]): the score contracts over q's width, o, do and dv are as
+  wide as v, dq and dk as wide as q, and V is never padded to the score's
+  width in HBM. ``flash_attention`` sends such a call here at EVERY S
+  (chunks of up to ``_UNEQUAL_CHUNK_ROWS`` rows) and raises, with the
+  shapes, where no block tiles S; the whole-row, the column-block and the
+  window kernels refuse unequal widths by name
+  (``_refuse_unequal_widths``). For equal widths every call is what it was.
 
 - **window** (a causal band of ``window`` keys, ``flash_attention(...,
   window=W)`` with W < S; PR 33): grid (B*H, S / block, steps) where a grid
@@ -147,11 +156,33 @@ _UNCHUNKED_ROW_BYTES = 262144
 # cutoff above — they have no resident dq row): measured on v5e, chunk
 # 4096 at S=32k overflowed by 0.9 MB; 2048 fits
 _CHUNK_ROW_BYTES = 524288
+# rows of a chunk of the CHUNKED kernels where the q·k width is not the value
+# width (bf16; half as many in float32), measured on a v5e at [32, 16384, 192
+# / 128] bf16 causal, blocks of 512 (tests/perf/mla_flash_bench.py; PERF.md
+# Findings PR 47): forward + dq + dkv 162.8 ms a layer at chunks of 512 (what
+# the budget above gives a row of 192 lanes = two lane tiles), 131.7 at
+# 1,024, 116.1 at 2,048, 109.2 at 4,096 — a grid step's fixed cost again,
+# 528 / 272 / 144 / 80 steps a head; the cell's whole step compiles with
+# 4,096 inside it (tests/test_tpu_compile.py) and ran. The equal-width cells
+# keep their budget: theirs to re-measure
+_UNEQUAL_CHUNK_ROWS = 4096
 
 
 def _interpret_default():
     from deepspeed_tpu.utils.platform import is_tpu_backend
     return not is_tpu_backend()
+
+
+def _refuse_unequal_widths(family, q, k, v):
+    """The whole-row, column-block and window families hold q, k, v and o at
+    ONE head width; a q·k width that is not the value width (latent
+    attention: 192 / 128) is the chunked family's alone."""
+    if not q.shape[-1] == k.shape[-1] == v.shape[-1]:
+        raise ValueError(
+            f"the {family} flash kernels take one head width for q, k and v:"
+            f" q {tuple(q.shape)}, k {tuple(k.shape)}, v {tuple(v.shape)}; "
+            "unequal q·k and value widths go to the chunked kernels "
+            "(flash_attention without window=)")
 
 
 # ------------------------------------------------------ shared block math
@@ -503,6 +534,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
     index maps fold the q head onto its KV head, so the reduced-head
     cache streams once per rep q heads and the full-head K/V is NEVER
     materialized in HBM (the GQA memory promise, models/llama.py)."""
+    _refuse_unequal_widths("whole-row", q, k, v)
     BH, S, D = q.shape
     if heads and kv_heads and heads != kv_heads:
         rep = heads // kv_heads
@@ -662,6 +694,7 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                interpret):
+    _refuse_unequal_widths("whole-row", q, k, v)
     BH, S, D = q.shape
     pieces = lse.shape[1:]                  # (S / piece, 1, piece)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
@@ -723,6 +756,8 @@ def _columns(operands, heads):
     itself, E / width column blocks apart; three [B, S, E] arrays each
     start at block 0."""
     fused = len(operands) == 1
+    if not fused:
+        _refuse_unequal_widths("column-block", *operands)
     E = operands[0].shape[-1] // (3 if fused else 1)
     plan = _column_plan(E, heads)
     step = E // plan[2] if fused and plan else 0
@@ -989,15 +1024,17 @@ def _fwd_kernel_chunked(i_of, c_of, q_ref, k_ref, v_ref, o_ref, lse_ref,
     _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l, kc == last)
 
 
-def _chunked_fwd_outputs(q, block_q, block_k, block_of):
+def _chunked_fwd_outputs(q, block_q, block_k, block_of, width=None):
     """(out_specs, out_shape, scratch_shapes) of the chunked and the window
     forward, whose grid steps walk a query block's chunks (``block_of``: the
-    grid's arguments -> (row of BH, query block)): o [BH, S, D] float32,
-    revisited over a block's walk; lse [BH, S / piece, 1, piece] float32,
+    grid's arguments -> (row of BH, query block)): o [BH, S, D] float32
+    (D the VALUE width, ``width``, where it is not q's), revisited over a
+    block's walk; lse [BH, S / piece, 1, piece] float32,
     lane-dense as the whole-row kernels store it (``_stat_piece``), written
     on the walk's last step; the running m and l, [block_q, 128] VMEM
     scratch."""
     BH, S, D = q.shape
+    D = width or D
     piece = _stat_piece(block_q, block_k)
     return (
         [_rows_spec(block_q, D, block_of),
@@ -1046,18 +1083,24 @@ def _pair_call(kernel, walk, BH, in_specs, out_specs, out_shape, scratch,
 
 def _flash_fwd_chunked(q, k, v, scale, causal, block_q, block_k, chunk,
                        interpret, heads=0, kv_heads=0):
+    """q and k [.., S, D], v [.., S, Dv] and o as wide as v: the score
+    contracts over D, the output over the keys, and nothing in the kernel
+    ties the two widths (latent attention: D 192 = 128 + the 64 rotated,
+    Dv 128). Equal widths are the same call as before."""
     BH, S, D = q.shape
+    Dv = v.shape[-1]
     kv = _kv_row(heads, kv_heads)
-    out_specs, out_shape, scratch = _chunked_fwd_outputs(q, block_q, block_k,
-                                                         _of_block)
+    out_specs, out_shape, scratch = _chunked_fwd_outputs(
+        q, block_q, block_k, _of_block, Dv)
     kernel = functools.partial(_fwd_kernel_chunked, scale=scale,
                                causal=causal, block_q=block_q,
                                block_k=block_k, chunk=chunk,
                                n_chunks=S // chunk)
     call = _pair_call(
         kernel, _pair_walk(S, block_q, chunk, causal, True), BH,
-        [_rows_spec(block_q, D, _of_block)]
-        + [_rows_spec(chunk, D, _of_chunk, kv)] * 2,
+        [_rows_spec(block_q, D, _of_block),
+         _rows_spec(chunk, D, _of_chunk, kv),
+         _rows_spec(chunk, Dv, _of_chunk, kv)],
         out_specs, out_shape, scratch, interpret)
     with annotate("flash_fwd_chunk"):
         o32, lse = call(q, k, v)
@@ -1166,21 +1209,26 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                        chunk, interpret, heads=0, kv_heads=0):
     """K and V may be [B * kv_heads, S, D] (grouped-query): both kernels
     read a query head's group through ``_kv_row``; dk and dv still come
-    back per QUERY head ([B * heads, S, D]) for the caller to sum."""
+    back per QUERY head ([B * heads, S, D]) for the caller to sum. v, o, do
+    and dv are ``Dv`` wide where the value width is not the q·k width
+    (``_flash_fwd_chunked``)."""
     BH, S, D = q.shape
+    Dv = v.shape[-1]
     kv = _kv_row(heads, kv_heads)
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1).reshape(lse.shape)
     piece = lse.shape[-1]
     static = dict(scale=scale, causal=causal, block_q=block_q,
                   block_k=block_k, chunk=chunk, n_chunks=S // chunk)
-    grads = jax.ShapeDtypeStruct((BH, S, D), jnp.float32)
+    grads, grads_v = (jax.ShapeDtypeStruct((BH, S, w), jnp.float32)
+                      for w in (D, Dv))
     call_dq = _pair_call(
         functools.partial(_bwd_dq_kernel_chunked, **static),
         _pair_walk(S, block_q, chunk, causal, True), BH,
-        [_rows_spec(block_q, D, _of_block)]
-        + [_rows_spec(chunk, D, _of_chunk, kv)] * 2
-        + [_rows_spec(block_q, D, _of_block)]
+        [_rows_spec(block_q, D, _of_block),
+         _rows_spec(chunk, D, _of_chunk, kv),
+         _rows_spec(chunk, Dv, _of_chunk, kv),
+         _rows_spec(block_q, Dv, _of_block)]
         + [_stat_spec(block_q, piece, _of_block)] * 2,
         _rows_spec(block_q, D, _of_block), grads, (), interpret)
     with annotate("flash_bwd_dq"):
@@ -1189,14 +1237,17 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     call_dkv = _pair_call(
         functools.partial(_bwd_dkv_kernel_chunked, **static),
         _pair_walk(S, block_k, chunk, causal, False), BH,
-        [_rows_spec(chunk, D, _of_chunk)]
-        + [_rows_spec(block_k, D, _of_block, kv)] * 2
-        + [_rows_spec(chunk, D, _of_chunk)]
+        [_rows_spec(chunk, D, _of_chunk),
+         _rows_spec(block_k, D, _of_block, kv),
+         _rows_spec(block_k, Dv, _of_block, kv),
+         _rows_spec(chunk, Dv, _of_chunk)]
         + [_stat_spec(chunk, piece, _of_chunk)] * 2,
-        [_rows_spec(block_k, D, _of_block)] * 2, [grads] * 2, (), interpret)
+        [_rows_spec(block_k, D, _of_block),
+         _rows_spec(block_k, Dv, _of_block)], [grads, grads_v], (),
+        interpret)
     with annotate("flash_bwd_dkv"):
         dk, dv = call_dkv(q, k, v, do, lse, delta)
-    return dq.astype(q.dtype), dk.astype(q.dtype), dv.astype(q.dtype)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 # ------------------------------------------ sliding-window (band) variants
@@ -1645,7 +1696,7 @@ def _flash_attention_bwd(scale, causal, block_q, block_k, chunk, interpret,
     if gqa:
         B = q.shape[0] // heads
         rep = heads // kv_heads
-        S, D = k.shape[1], k.shape[2]
+        S = k.shape[1]
     if chunk:
         # grouped-query K/V are read in place (``_kv_row``)
         dq, dk, dv = _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal,
@@ -1656,8 +1707,8 @@ def _flash_attention_bwd(scale, causal, block_q, block_k, chunk, interpret,
             # the whole-row backward runs the full-head kernel: K/V repeat
             # to [B*H, S, D] HERE (transient, bwd-only)
             def rep_kv(t):
-                return jnp.repeat(t.reshape(B, kv_heads, S, D), rep,
-                                  axis=1).reshape(B * heads, S, D)
+                return jnp.repeat(t.reshape(B, kv_heads, S, -1), rep,
+                                  axis=1).reshape(B * heads, S, -1)
             k = rep_kv(k)
             v = rep_kv(v)
         dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, scale, causal,
@@ -1666,8 +1717,8 @@ def _flash_attention_bwd(scale, causal, block_q, block_k, chunk, interpret,
         # dk/dv come back per query head: summed over the rep query heads
         # sharing each KV head
         def sum_rep(t):
-            return t.reshape(B, kv_heads, rep, S, D).sum(axis=2) \
-                .astype(t.dtype).reshape(B * kv_heads, S, D)
+            return t.reshape(B, kv_heads, rep, S, t.shape[-1]).sum(axis=2) \
+                .astype(t.dtype).reshape(B * kv_heads, S, t.shape[-1])
         dk = sum_rep(dk)
         dv = sum_rep(dv)
     return dq, dk, dv
@@ -1750,7 +1801,7 @@ _plans_logged = set()
 
 
 def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
-               heads_per_block=0, window=0, band=None):
+               heads_per_block=0, window=0, band=None, value_dim=0):
     """Trace-time engagement record of one flash call: the gauges
     ``attention/flash_tile_overcompute``,
     ``attention/flash_heads_per_block`` (heads a 128-lane column block of
@@ -1762,7 +1813,10 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
     the log-sum-exp's) and loop structure chosen for it.
     ``window``: a call of the window kernels under ``band``
     (``_band_plan``) — the gauges ``attention/window_tile_overcompute`` and
-    ``attention/window_tiles_per_grid_step`` and the band's plan instead."""
+    ``attention/window_tiles_per_grid_step`` and the band's plan instead.
+    ``value_dim``: the value width of a call whose q·k width ``D`` is another
+    (latent attention) — the gauges ``attention/mla_qk_dim`` and
+    ``attention/mla_v_dim``, the widths as the kernels saw them."""
     piece = _stat_piece(block_q, block_k)
     if window:
         over = window_tile_overcompute(S, block_q, block_k, window)
@@ -1799,8 +1853,12 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
             "attention/flash_grid_steps_walked_share").set(steps / rectangle)
         walked = (f" ({steps} of {rectangle} (block, chunk) pairs walked, "
                   "forward + dq + dkv)")
+    if value_dim:
+        default_registry().gauge("attention/mla_qk_dim").set(D)
+        default_registry().gauge("attention/mla_v_dim").set(value_dim)
+        walked += f", values and output {value_dim} wide"
     plan = (S, D, jnp.dtype(dtype).name, causal, block_q, block_k, chunk,
-            heads_per_block)
+            heads_per_block, value_dim)
     if plan not in _plans_logged:
         _plans_logged.add(plan)
         strip = 0 if chunk else _pick_strip(block_q)
@@ -1852,6 +1910,7 @@ def _flash_attention_window(q, k, v, scale, window, block_q, block_k, chunk,
                             interpret):
     """``flash_attention``'s window branch: the band kernels or a raise,
     never [S, S] scores."""
+    _refuse_unequal_widths("window", q, k, v)
     B, H, S, D = q.shape
     Hkv = k.shape[1]
     block_q, block_k = (_pick_block(S, b, interpret, False)
@@ -1895,6 +1954,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     budget; a shape they do not take RAISES); one that covers it is causal
     attention."""
     B, H, S, D = q.shape
+    Dv = v.shape[-1]
+    from deepspeed_tpu.ops.attention import (check_qkv_shapes,
+                                             reference_attention)
+    check_qkv_shapes(q, k, v)
     scale = float(scale) if scale is not None else 1.0 / float(np.sqrt(D))
     if interpret is None:
         interpret = _interpret_default()
@@ -1908,16 +1971,27 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
             return _flash_attention_window(q, k, v, scale, window, block_q,
                                            block_k, chunk, interpret)
     itemsize = jnp.dtype(q.dtype).itemsize
-    whole_row = chunk is None and S * D * itemsize <= _UNCHUNKED_ROW_BYTES
+    # a q·k width that is not the value width is the chunked family's at
+    # EVERY S: one path, and nothing of it falls to [S, S] scores
+    unequal = Dv != D
+    whole_row = chunk is None and not unequal \
+        and S * D * itemsize <= _UNCHUNKED_ROW_BYTES
     block_q = _pick_block(S, block_q, interpret, whole_row)
     block_k = _pick_block(S, block_k, interpret, whole_row)
     Hkv = k.shape[1]
     assert v.shape[1] == Hkv and H % Hkv == 0, (q.shape, k.shape)
 
-    if not block_q or not block_k or S % block_q or S % block_k:
-        from deepspeed_tpu.ops.attention import reference_attention
+    def no_tiling(why):
+        if unequal:
+            raise ValueError(
+                f"flash attention with a q·k width of {D} and a value width "
+                f"of {Dv} runs in the chunked kernels alone, and {why}: "
+                f"q {tuple(q.shape)}, v {tuple(v.shape)}")
         # reference_attention repeats reduced-head K/V itself
         return reference_attention(q, k, v, causal=causal, scale=scale)
+
+    if not block_q or not block_k or S % block_q or S % block_k:
+        return no_tiling(f"no block tiles S={S}")
     if chunk is not None:
         if S % chunk or chunk % block_q or chunk % block_k:
             raise ValueError(
@@ -1925,25 +1999,26 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                 f"block_q={block_q} and block_k={block_k}")
     if chunk is None and not whole_row:
         # whole-row residency stops fitting scoped VMEM — stream chunks
-        budget = max(_CHUNK_ROW_BYTES // 2 // (D * itemsize), 1)
-        for cand in (4096, 2048, 1024, 512, 256, 128, 64):
+        budget = _UNEQUAL_CHUNK_ROWS * 2 // itemsize if unequal \
+            else max(_CHUNK_ROW_BYTES // 2 // (D * itemsize), 1)
+        for cand in (4096, 2048, 1024, 512, 256, 128, 64) \
+                + ((S,) if unequal else ()):
             if cand <= budget and S % cand == 0 \
                     and cand % block_q == 0 and cand % block_k == 0:
                 chunk = cand
                 break
         else:
-            from deepspeed_tpu.ops.attention import reference_attention
-            return reference_attention(q, k, v, causal=causal,
-                                       scale=scale)
+            return no_tiling(f"no chunk of <= {budget} rows tiles S={S}")
 
     chunk = int(chunk) if chunk else 0
-    _note_plan(S, D, q.dtype, scale, causal, block_q, block_k, chunk)
+    _note_plan(S, D, q.dtype, scale, causal, block_q, block_k, chunk,
+               value_dim=Dv if unequal else 0)
     qf = q.reshape(B * H, S, D)
     kf = k.reshape(B * k.shape[1], S, D)
-    vf = v.reshape(B * v.shape[1], S, D)
+    vf = v.reshape(B * v.shape[1], S, Dv)
     o = _flash_attention(qf, kf, vf, scale, causal, block_q, block_k, chunk,
                          bool(interpret), H, Hkv)
-    return o.reshape(B, H, S, D)
+    return o.reshape(B, H, S, Dv)
 
 
 def flash_attention_bse(q, k=None, v=None, *, heads, causal=False,

@@ -172,14 +172,23 @@ def annotate(tag):
       (ops/pallas/flash_attention.py, round the three ``pallas_call``s of
       the window kernels): ``swa_attn_share``, ``swa_fwd_roofline`` and
       ``swa_bwd_roofline`` (the two backward scopes one tag, by prefix);
-    - ``dense_mlp`` (models/laguna.py, the leading layer's SwiGLU): a row
-      of the detail table.
+    - ``dense_mlp`` (models/laguna.py, models/deepseek_v3.py: the leading
+      layer's SwiGLU): a row of the detail table.
+
+    - ``mla_latent``, ``mla_expand``, ``mla_rope`` (models/deepseek_v3.py,
+      inside the module ``mla_attn``: the down-projection to latent +
+      rotated key and the latent's RMS norm; the up-projection into every
+      head's key without position and value, and the kernels' K operand —
+      the concat with the broadcast rotated key; the de-interleaving
+      rotation of q_rope and of the shared key): ``mla_expand_ms``, and
+      with ``flash_*`` and the module name ``mla_attn``, ``mla_layer_ms``.
 
     The flax module names ``attn``, ``mlp``, ``ln_1``, ``ln_2``, ``ln_f``
     (models/gpt2.py), ``attn``, ``mlp``, ``input_norm``,
     ``post_attn_norm``, ``norm`` (models/llama.py) and ``linear_attn``,
     ``attn``, ``mlp`` and the same three norms (models/qwen3_next.py;
-    models/laguna.py has ``attn``, ``mlp`` and the three norms) are the
+    models/laguna.py has ``attn``, ``mlp`` and the three norms;
+    models/deepseek_v3.py ``mla_attn``, ``mlp`` and the three norms) are the
     detail table's remaining tags.
 
     Beside the scopes, the flash kernels leave trace-time GAUGES in
@@ -202,7 +211,11 @@ def annotate(tag):
     ``attention/window_tiles_per_grid_step`` (score tiles of the three
     calls over their grid steps: ~the band's tile count where a block's
     whole band is one operand block, under 1 at a tile a step), which no
-    benchmark metric reads. Every flash VJP's forward rule
+    benchmark metric reads. A chunked call whose q·k width is not its value
+    width (latent attention) leaves ``attention/mla_qk_dim`` and
+    ``attention/mla_v_dim``, the two widths as the kernels saw them (192 /
+    128 on the Kanana-2 cell; untouched by every equal-width call).
+    Every flash VJP's forward rule
     leaves ``attention/flash_residual_mb`` (decimal MB of HBM the
     ``flash_o`` / ``flash_lse`` pairs one differentiation names take, a
     minor dimension counted in 128-lane tiles: what a remat policy that

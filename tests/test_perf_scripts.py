@@ -23,11 +23,14 @@ SCRIPTS = sorted(os.path.basename(p)[:-3]
 
 def test_the_scripts_that_stay_are_the_nine():
     """A probe is added here by name, with the Finding or the comment that
-    cites it: 22 one-off probes had piled up before PR 46."""
+    cites it: 22 one-off probes had piled up before PR 46. Ten since PR 47:
+    ``mla_flash_bench`` (the chunked kernels at latent attention's two
+    widths over (block, chunk) plans) is what ``_UNEQUAL_CHUNK_ROWS``'s
+    comment and PERF.md's Findings PR 47 quote."""
     assert SCRIPTS == [
         "adam_test", "aio_bench", "blocksparse_sweep", "flash_chunked_bench",
         "gdn_scan_bench", "gmm_tile_bench", "mixer_elementwise_bench",
-        "rows_to_tokens_bench", "swa_bench"]
+        "mla_flash_bench", "rows_to_tokens_bench", "swa_bench"]
 
 
 @pytest.mark.parametrize("name", SCRIPTS)

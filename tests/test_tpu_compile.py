@@ -655,6 +655,42 @@ def test_flash_attention_chunked_compiles_at_the_s16384_cells_shapes(
     assert_dense_lse_kept(hlo, calls, f"f32[{heads},128,1,128]")
 
 
+def test_flash_attention_chunked_compiles_at_the_latent_attention_shape():
+    """1 x 32 heads x 16,384, a q·k head of 192 (128 + the 64 rotated) and
+    a value head of 128, bf16, causal (every layer of the Kanana-2 cell):
+    the three chunked kernels under their scopes, K read 192 wide and V 128
+    wide — V is not padded to 192 in HBM — o and dv 128 wide, dq and dk
+    192, no [S, S] scores, under the blocks' remat policy."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    def attend(*a):
+        with jax.named_scope("attn"):
+            return flash_attention(*a, causal=True).astype(F32).sum()
+
+    def grads(q, k, v):
+        return jax.grad(rematted(attend), argnums=(0, 1, 2))(q, k, v)
+
+    shapes = (SDS((1, 32, 16384, 192), BF16), SDS((1, 32, 16384, 192), BF16),
+              SDS((1, 32, 16384, 128), BF16))
+    text, compiled = compile_on_chip(grads, *shapes)
+    assert kernel_names(text) == {"_fwd_kernel_chunked",
+                                  "_bwd_dq_kernel_chunked",
+                                  "_bwd_dkv_kernel_chunked"}
+    hlo = compiled.as_text()
+    calls = flash_calls(hlo)
+    assert len(calls) == 3
+    assert all("bf16[32,16384,192]" in c and "bf16[32,16384,128]" in c
+               for c in calls)
+    fwd, dq, dkv = calls
+    assert "f32[32,16384,128]" in fwd and "f32[32,16384,192]" not in fwd
+    assert "f32[32,16384,192]" in dq
+    assert "f32[32,16384,192]" in dkv and "f32[32,16384,128]" in dkv
+    for scope in ("flash_fwd_chunk", "flash_bwd_dq", "flash_bwd_dkv"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+    assert "16384,16384" not in hlo
+    assert_dense_lse_kept(hlo, calls, "f32[32,128,1,128]")
+
+
 @pytest.mark.parametrize("D,kernels,scopes", [
     (128, {"_gdn_fwd_kernel", "_gdn_bwd_kernel"},
      ("gdn_scan_prep/", "gdn_scan_fwd/", "gdn_scan_bwd/")),
@@ -1003,6 +1039,48 @@ def test_laguna_step_compiles_for_one_chip_with_its_scopes_and_fits():
                   "moe_combine/rows_to_tokens", "moe_dispatch/rows_to_tokens"):
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
     assert_rows_reach_tokens_in_one_pass(hlo, 32768, 16384)
+
+
+def test_kanana2_step_compiles_for_one_chip_with_its_scopes_and_fits():
+    """The WHOLE step of the benchmark's ``kanana2-train-1chip-s16384`` cell
+    (Kanana-2's layers 0-5 as one of 8 expert-parallel ranks, 1 x 16,384
+    tokens, ZeRO-3, through the family's ``lower_train_step``) is accepted
+    for a 16 GB chip: latent attention in the three chunked causal kernels
+    with K 192 wide and V 128 wide in every layer, nothing [S, S], every
+    scope the benchmark reads in an ``op_name`` of the compiled text. ~1
+    minute."""
+    from benchmark import manifest
+    bench = manifest.load()
+    cell = manifest.cell_of(bench, "kanana2-train-1chip-s16384")
+    config = manifest.config_of(bench, cell)
+    lowered = manifest.family_module(config).lower_train_step(
+        config, manifest.traffic_of(cell), topo().devices[:1])
+    assert kernel_names(lowered.as_text()) == {
+        "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
+        "_bwd_dkv_kernel_chunked", "kernel", "_rows_to_tokens_kernel"}
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    assert 6.8e9 < ma.argument_size_in_bytes < 6.95e9     # 687.5M x 10 B
+    # between 25 % and 100 % of the chip
+    assert 0.25 * HBM_BYTES < ma.peak_memory_in_bytes < 15.75 * 2 ** 30
+    hlo = compiled.as_text()
+    assert not re.search(r"all-gather|all-reduce|reduce-scatter", hlo)
+    assert "16384,16384" not in hlo                       # no [S, S] array
+    # six layers x (forward, dq, dkv): the forward kernels run once, o and
+    # lse are kept; K goes in 192 wide, V 128 wide, never padded to 192
+    flash = [c for c in flash_calls(hlo) if "bf16[32,16384,192]" in c]
+    assert len(flash) == 18
+    assert all("bf16[32,16384,128]" in c for c in flash)
+    assert hlo_text.rematted_forward_attention(hlo) == []
+    assert default_registry().peek_gauge("attention/mla_qk_dim") == 192
+    assert default_registry().peek_gauge("attention/mla_v_dim") == 128
+    for scope in ("flash_fwd_chunk", "flash_bwd_dq", "flash_bwd_dkv",
+                  "mla_attn", "mla_latent", "mla_expand", "mla_rope",
+                  "dense_mlp", "moe_shared", "moe_gmm", "moe_gmm_dlhs",
+                  "moe_gmm_drhs", "moe_router", "moe_dispatch", "moe_combine",
+                  "mlp", "ds_loss_head", "ds_embed", "ds_optimizer",
+                  "moe_combine/rows_to_tokens", "moe_dispatch/rows_to_tokens"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
 
 
 # ------------------------------------- optional kernels: known refusals
