@@ -42,11 +42,18 @@ Three kernel families share the same per-tile math (`_fwd_block_step` /
   dq: chunks past the query block's own; dkv: query chunks before the key
   block), which on a rectangular (S / block, S / chunk) grid were steps
   with empty loops that still fetched their chunk, 0.64-1.5 us each on a
-  v5e: 272 pairs where 512 steps stood at S 16,384 / 512 / 1,024, 136 of
-  256 at S 8,192 / 512 / 512, 20 of 32 at S 4,096 (PERF.md Findings PR 39;
-  gauge ``attention/flash_grid_steps_walked_share``); a call that is not
+  v5e (PERF.md Findings PR 39; gauge
+  ``attention/flash_grid_steps_walked_share``); a call that is not
   causal walks the rectangle, and a band would be a third list for the
-  same kernels. The forward's softmax m/l state lives in fp32 VMEM
+  same kernels. A step that DOES work has a fixed cost too (the (o, m, l)
+  carry read and written back, q re-scaled, the relative-position tile
+  rebuilt, the pipeline's turn-over), so a chunk is as many rows as
+  ``_CHUNK_BYTES`` of K + V allow (``_pick_chunk``; gauge
+  ``attention/flash_chunk_rows``): 80 pairs a head of 32 x 4 at S 16,384
+  with blocks of 512 and chunks of 4,096 (272 of 32 x 16 at the 1,024 rows
+  they had before PR 48), 40 of 16 x 4 at S 8,192 / head_dim 256 / 2,048
+  (136), 8 at S 4,096 / one chunk (20). The forward's softmax m/l state
+  lives in fp32 VMEM
   scratch; normalization happens in-kernel on a block's last chunk, which
   also writes lse lane-dense ([BH, S / 128, 1, 128] as the whole-row
   kernels store it — a [BH, S, 1] column is 128 x its
@@ -61,16 +68,15 @@ Three kernel families share the same per-tile math (`_fwd_block_step` /
   (`_kv_row`), so K and V are never repeated in HBM, forward or backward;
   dk and dv still leave the dkv kernel per QUERY head (fp32) and are
   summed over a group's heads after it. Measured on a v5e at
-  (S 4096, head_dim 128, 16 / 16 heads: OLMoE's cell, chunk 1024) and at
-  (S 8192, head_dim 256, 16 query / 2 KV heads: Qwen3-Next's cell, chunk
-  512 = one block a grid step, `_CHUNK_ROW_BYTES` as it was); PERF.md
-  Findings PR 27 and PR 31 have the numbers. Since PR 47 the chunked
+  (S 4096, head_dim 128, 16 / 16 heads: OLMoE's cell) and at
+  (S 8192, head_dim 256, 16 query / 2 KV heads: Qwen3-Next's cell); PERF.md
+  Findings PR 27, PR 31 and PR 48 have the numbers. Since PR 47 the chunked
   family — and it ALONE — takes a q·k width that is not the value width
   (latent attention: q and k [.., S, 192] = 128 + the 64 rotated, v
   [.., S, 128]): the score contracts over q's width, o, do and dv are as
   wide as v, dq and dk as wide as q, and V is never padded to the score's
   width in HBM. ``flash_attention`` sends such a call here at EVERY S
-  (chunks of up to ``_UNEQUAL_CHUNK_ROWS`` rows) and raises, with the
+  (its chunks under the one budget, ``_pick_chunk``) and raises, with the
   shapes, where no block tiles S; the whole-row, the column-block and the
   window kernels refuse unequal widths by name
   (``_refuse_unequal_widths``). For equal widths every call is what it was.
@@ -148,24 +154,36 @@ NEG_INF = -1e30
 # (a dense S=4096 train step, BH=64) overflowed scoped vmem by 284 KB
 # — so the unchunked cutoff is S*D*itemsize <= 256 KB (S=2048 at D=64
 # bf16) and S=4096 routes to the chunked kernels, whose per-chunk
-# residency is bounded. The chunked kernels use half of this per chunk
-# for pipeline double buffering (chunk 4096 at S=32k overflowed by
-# 0.9 MB; 2048 fits).
+# residency is bounded (``_CHUNK_BYTES``).
 _UNCHUNKED_ROW_BYTES = 262144
-# per-chunk budget for the CHUNKED kernels (independent of the unchunked
-# cutoff above — they have no resident dq row): measured on v5e, chunk
-# 4096 at S=32k overflowed by 0.9 MB; 2048 fits
-_CHUNK_ROW_BYTES = 524288
-# rows of a chunk of the CHUNKED kernels where the q·k width is not the value
-# width (bf16; half as many in float32), measured on a v5e at [32, 16384, 192
-# / 128] bf16 causal, blocks of 512 (tests/perf/mla_flash_bench.py; PERF.md
-# Findings PR 47): forward + dq + dkv 162.8 ms a layer at chunks of 512 (what
-# the budget above gives a row of 192 lanes = two lane tiles), 131.7 at
-# 1,024, 116.1 at 2,048, 109.2 at 4,096 — a grid step's fixed cost again,
-# 528 / 272 / 144 / 80 steps a head; the cell's whole step compiles with
-# 4,096 inside it (tests/test_tpu_compile.py) and ran. The equal-width cells
-# keep their budget: theirs to re-measure
-_UNEQUAL_CHUNK_ROWS = 4096
+# what a grid step of the CHUNKED kernels may stream: the lane-padded bytes
+# of a chunk's two streamed operands (K + V forward and dq, Q + dO dkv; the
+# pipeline double-buffers them). ONE budget for equal and unequal q·k / value
+# widths, measured on a v5e at blocks of 512, bf16 causal, forward + dq + dkv
+# a call (tests/perf/flash_chunked_bench.py --plans, mla_flash_bench.py;
+# PERF.md Findings PR 48 and PR 47) — a grid step's fixed cost, not its tile,
+# is what these kernels pay:
+#   [48 / 8, 16384, 128]   chunk 512 / 1,024 / 2,048 / 4,096 (528 / 272 / 144
+#                          / 80 steps a head): 175.1 / 140.6 / 123.3 / 115.2 ms
+#   [2 x 16 / 2, 8192, 256]  512 / 1,024 / 2,048 (136 / 72 / 40): 51.5 / 42.5
+#                          / 38.0 ms; 4,096 (4 MiB of K + V) is REFUSED: the
+#                          dkv kernel runs out of scoped VMEM
+#   [4 x 16, 4096, 128]    512 / 1,024 / 2,048 / 4,096 = S (36 / 20 / 12 / 8):
+#                          16.2 / 13.4 / 12.0 / 11.3 ms
+#   [16, 32768, 64]        1,024 / 2,048 / 4,096: 179.1 / 156.1 / 145.2 ms
+#                          (the shape whose overflow at 4,096 rows set PR 27's
+#                          budget, in kernels PR 28 / 34 / 39 rewrote since)
+#   [32, 16384, 192 / 128] 512 / 1,024 / 2,048 / 4,096: 162.8 / 131.7 / 116.1
+#                          / 109.2 ms (3 MiB: the widest step that runs)
+# 3 MiB is the widest a step has run with; every cell's whole step compiles
+# with the plan it gives (tests/test_tpu_compile.py). float32 operands take
+# half the rows (their times are bf16's: the MXU takes them in bf16 passes);
+# 4,096 float32 rows at head_dim 128 (4 MiB) measured 5 % under 2,048 alone
+# and are left out with head_dim 256's.
+_CHUNK_BYTES = 3 * 2 ** 20
+# rows a chunk may have, widest first: ``_pick_chunk`` takes the first that
+# fits ``_CHUNK_BYTES`` and tiles the sequence in whole blocks
+_CHUNK_ROWS = (4096, 2048, 1024, 512, 256, 128, 64)
 
 
 def _interpret_default():
@@ -970,12 +988,13 @@ def _pair_walk(S, block, chunk, causal, keys):
     visits them — a block's pairs consecutive and its chunks ascending
     (``_walk_ends``), so a revisited output block and the VMEM scratch
     accumulate over one unbroken run of steps. A causal call leaves out the
-    pairs wholly above the diagonal: 272 of 512 at S 16,384 with blocks of
-    512 and chunks of 1,024, 136 of 256 at S 8,192 / 512 / 512, 20 of 32 at
-    S 4,096 / 512 / 1,024 (a grid step with an empty loop still fetched its
-    chunk and cost 0.6-1 us: PERF.md, PR 39); a call that is not causal
-    walks the rectangle. Built with numpy at trace time, once a plan, and
-    handed to the call as scalar-prefetch operands."""
+    pairs wholly above the diagonal: 80 of 128 at S 16,384 with blocks of
+    512 and chunks of 4,096, 40 of 64 at S 8,192 / 512 / 2,048, all 8 at
+    S 4,096 / 512 / 4,096 (272 of 512, 136 of 256 and 20 of 32 at the chunks
+    of 1,024 / 512 / 1,024 rows before PR 48; a grid step with an empty loop
+    still fetched its chunk and cost 0.6-1 us: PERF.md, PR 39); a call that
+    is not causal walks the rectangle. Built with numpy at trace time, once
+    a plan, and handed to the call as scalar-prefetch operands."""
     blocks, chunks = [], []
     for i in range(S // block):
         first, last = _walk_ends(i, block, chunk, S // chunk, causal, keys)
@@ -1789,8 +1808,8 @@ def window_tiles_per_grid_step(S, block_q, block_k, window, band):
 def grid_steps_walked(S, block_q, block_k, chunk, causal):
     """(grid steps a head of the three chunked kernels of one call —
     forward, dq, dkv: ``_pair_walk`` — and of the rectangular
-    (S / block, S / chunk) grids those would be): 816 of 1,536 at S 16,384
-    with blocks of 512 and chunks of 1,024."""
+    (S / block, S / chunk) grids those would be): 240 of 384 at S 16,384
+    with blocks of 512 and chunks of 4,096."""
     over_keys = len(_pair_walk(S, block_q, chunk, causal, True)[0])
     over_queries = len(_pair_walk(S, block_k, chunk, causal, False)[0])
     return (2 * over_keys + over_queries,
@@ -1807,10 +1826,11 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
     ``attention/flash_heads_per_block`` (heads a 128-lane column block of
     [B, S, H*D] operands; 0 for a head-major call) and, for a chunked call,
     ``attention/flash_grid_steps_walked_share`` (``grid_steps_walked``: grid
-    steps of its three kernels over those of the rectangular grid — 0.531
-    causal at S 16,384 / 512 / 1,024, 1.0 where nothing is masked) and,
-    once per distinct shape, a log line of the layout (the operands' and
-    the log-sum-exp's) and loop structure chosen for it.
+    steps of its three kernels over those of the rectangular grid — 0.625
+    causal at S 16,384 / 512 / 4,096, 1.0 where nothing is masked) and
+    ``attention/flash_chunk_rows`` (sequence rows a grid step holds: the
+    chunk) and, once per distinct shape, a log line of the layout (the
+    operands' and the log-sum-exp's) and loop structure chosen for it.
     ``window``: a call of the window kernels under ``band``
     (``_band_plan``) — the gauges ``attention/window_tile_overcompute`` and
     ``attention/window_tiles_per_grid_step`` and the band's plan instead.
@@ -1851,6 +1871,7 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
                                              causal)
         default_registry().gauge(
             "attention/flash_grid_steps_walked_share").set(steps / rectangle)
+        default_registry().gauge("attention/flash_chunk_rows").set(chunk)
         walked = (f" ({steps} of {rectangle} (block, chunk) pairs walked, "
                   "forward + dq + dkv)")
     if value_dim:
@@ -1894,6 +1915,24 @@ def _pick_block(S, requested, interpret, whole_row):
     # irregular short sequences (e.g. S=80): one block spanning S keeps
     # the kernel path, matching the old min(block, S) behavior
     return S if S <= top else 0
+
+
+def _pick_chunk(S, D, Dv, itemsize, block_q, block_k):
+    """Rows of a sequence chunk of the chunked kernels where the caller names
+    none: the widest of ``_CHUNK_ROWS`` whose two streamed operands — K
+    [chunk, D] and V [chunk, Dv] forward and in dq, Q and dO in dkv, each
+    padded to whole 128-lane tiles as VMEM holds them — fit ``_CHUNK_BYTES``
+    and that tiles S in whole blocks: 4,096 rows in bf16 at head_dim 64 and
+    128 (2 MiB) and at latent attention's 192 / 128 (3 MiB), 2,048 at
+    head_dim 256, half as many in float32. A q·k width that is not the value
+    width, which no other family takes, may also go as ONE chunk of S rows.
+    0: nothing tiles S."""
+    row = (-(-D // _LANES) + -(-Dv // _LANES)) * _LANES * itemsize
+    for cand in _CHUNK_ROWS + ((S,) if Dv != D else ()):
+        if cand * row <= _CHUNK_BYTES and S % cand == 0 \
+                and cand % block_q == 0 and cand % block_k == 0:
+            return cand
+    return 0
 
 
 # The window kernels take the chunked family's grid blocks (``_pick_block``:
@@ -1999,16 +2038,10 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                 f"block_q={block_q} and block_k={block_k}")
     if chunk is None and not whole_row:
         # whole-row residency stops fitting scoped VMEM — stream chunks
-        budget = _UNEQUAL_CHUNK_ROWS * 2 // itemsize if unequal \
-            else max(_CHUNK_ROW_BYTES // 2 // (D * itemsize), 1)
-        for cand in (4096, 2048, 1024, 512, 256, 128, 64) \
-                + ((S,) if unequal else ()):
-            if cand <= budget and S % cand == 0 \
-                    and cand % block_q == 0 and cand % block_k == 0:
-                chunk = cand
-                break
-        else:
-            return no_tiling(f"no chunk of <= {budget} rows tiles S={S}")
+        chunk = _pick_chunk(S, D, Dv, itemsize, block_q, block_k)
+        if not chunk:
+            return no_tiling(f"no chunk within {_CHUNK_BYTES} bytes of K "
+                             f"and V tiles S={S}")
 
     chunk = int(chunk) if chunk else 0
     _note_plan(S, D, q.dtype, scale, causal, block_q, block_k, chunk,

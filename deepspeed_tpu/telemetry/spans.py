@@ -199,7 +199,9 @@ def annotate(tag):
     the model's own [B, S, H*D] operands, 0 for a head-major call), the
     chunked kernels a third, ``attention/flash_grid_steps_walked_share``
     (grid steps of their (block, chunk) pair lists over the rectangular
-    grid's: 0.531 causal at S 16,384, 1.0 where nothing is masked), and
+    grid's: 0.625 causal at S 16,384, 1.0 where nothing is masked), and a
+    fourth, ``attention/flash_chunk_rows`` (sequence rows a grid step
+    holds: 4,096 at head_dim 128 in bf16), and
     the gated delta rule two, ``linear_attn/gdn_kernel_heads_per_step``
     (value heads a grid step of its kernels; 0: the XLA form took the
     call) and ``linear_attn/gdn_states_kept_every`` (chunks between the

@@ -160,10 +160,11 @@ def test_auto_chunk_dispatch(monkeypatch):
                     interpret)
 
     monkeypatch.setattr(fa, "_flash_fwd_chunked", spy)
-    # dispatch cutoff shrunk so S=512 routes to the chunked path, and
-    # chunk budget/2 // (D*itemsize) = 128 rows -> candidate 128 picked
+    # dispatch cutoff shrunk so S=512 routes to the chunked path, and the
+    # one chunk budget to 128 rows of K + V, each a 128-lane float32 tile
+    # wide in VMEM whatever D is -> candidate 128 picked
     monkeypatch.setattr(fa, "_UNCHUNKED_ROW_BYTES", 128 * 2 * 16 * 4)
-    monkeypatch.setattr(fa, "_CHUNK_ROW_BYTES", 128 * 2 * 16 * 4)
+    monkeypatch.setattr(fa, "_CHUNK_BYTES", 128 * 2 * 128 * 4)
     from deepspeed_tpu.ops.attention import reference_attention
     rng = np.random.RandomState(1)
     q = jnp.asarray(rng.randn(1, 2, 512, 16), jnp.float32)
@@ -173,6 +174,46 @@ def test_auto_chunk_dispatch(monkeypatch):
     ref = reference_attention(q, q, q, causal=True)
     np.testing.assert_allclose(np.asarray(o), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("S,H,Hkv,D,Dv,dtype,chunk", [
+    (16384, 6, 1, 128, 128, jnp.bfloat16, 4096),    # Laguna, SmallThinker,
+    (16384, 32, 32, 192, 128, jnp.bfloat16, 4096),  # Nemotron; Kanana-2
+    (8192, 8, 1, 256, 256, jnp.bfloat16, 2048),     # Qwen3-Next
+    (4096, 2, 2, 128, 128, jnp.bfloat16, 4096),     # OLMoE: chunk = S
+    (32768, 2, 2, 64, 64, jnp.bfloat16, 4096),      # 64 lanes pad to 128
+    (16384, 2, 2, 128, 128, jnp.float32, 2048),     # float32: half the rows
+    (16384, 2, 2, 192, 128, jnp.float32, 2048),
+    (8192, 2, 2, 256, 256, jnp.float32, 1024),
+    (1024, 2, 2, 192, 128, jnp.bfloat16, 1024),     # unequal at a short S
+    (6144, 2, 2, 128, 128, jnp.bfloat16, 2048),     # 4,096 does not tile S
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_one_budget_picks_the_chunk(S, H, Hkv, D, Dv, dtype, chunk,
+                                    monkeypatch):
+    """Where the caller names no chunk, ONE rule picks it for equal and
+    unequal widths alike (ISSUE 48): the widest of ``_CHUNK_ROWS`` whose K +
+    V rows, lane-padded, fit ``_CHUNK_BYTES`` and that tiles S — the plans
+    the sweep timed and each cell's whole step compiled with (PERF.md
+    Findings PR 48), Kanana-2's what ``_UNEQUAL_CHUNK_ROWS`` gave it. The
+    gauge ``attention/flash_chunk_rows`` says which."""
+    from deepspeed_tpu.telemetry.registry import default_registry
+    fa = _fa()
+    seen = {}
+    real = fa._flash_attention
+
+    def spy(q, k, v, scale, causal, block_q, block_k, chunk, *rest):
+        seen.update(block=(block_q, block_k), chunk=chunk)
+        return real(q, k, v, scale, causal, block_q, block_k, chunk, *rest)
+
+    monkeypatch.setattr(fa, "_flash_attention", spy)
+    jax.eval_shape(
+        lambda *a: fa.flash_attention(*a, causal=True, interpret=False),
+        jax.ShapeDtypeStruct((1, H, S, D), dtype),
+        jax.ShapeDtypeStruct((1, Hkv, S, D), dtype),
+        jax.ShapeDtypeStruct((1, Hkv, S, Dv), dtype))
+    assert seen == {"block": (512, 512), "chunk": chunk}
+    assert default_registry().peek_gauge("attention/flash_chunk_rows") \
+        == chunk
 
 
 def test_user_chunk_validation():
@@ -809,6 +850,9 @@ def test_dot_product_attention_passes_the_window_through_its_shard_map():
     (2, 2, 256, 16, False, 32, 64, 64),
     (6, 1, 256, 16, True, 64, 64, 128),     # Laguna's full layers' groups
     (7, 1, 256, 16, True, 64, 64, 64),      # SmallThinker's
+    (4, 2, 512, 16, True, 64, 64, 256),     # four blocks a chunk: a block's
+    (4, 2, 512, 16, True, 32, 64, 256),     # diagonal falls mid-chunk (PR 48)
+    (4, 2, 512, 16, True, 64, 64, 512),     # one chunk: chunk = S, OLMoE's
     (8, 1, 256, 16, True, 64, 64, 128),     # Qwen3-Next's
     (8, 2, 128, 32, False, 32, 32, 64),
 ], ids=lambda v: str(v))
@@ -870,19 +914,23 @@ def test_pair_list_skips_only_steps_that_did_nothing(dtype, block_q, block_k,
 
 
 @pytest.mark.parametrize("S,block,chunk,causal,pairs", [
-    (16384, 512, 1024, True, 272),      # Laguna, SmallThinker: of 512
-    (8192, 512, 512, True, 136),        # Qwen3-Next: of 256
-    (4096, 512, 1024, True, 20),        # OLMoE: of 32
+    (16384, 512, 4096, True, 80),       # Laguna, SmallThinker, Nemotron,
+    (8192, 512, 2048, True, 40),        # Kanana-2: of 128; Qwen3-Next: of 64
+    (4096, 512, 4096, True, 8),         # OLMoE: chunk = S, of 8
+    (16384, 512, 1024, True, 272),      # the plans before PR 48: of 512
+    (8192, 512, 512, True, 136),        # of 256
+    (4096, 512, 1024, True, 20),        # of 32
     (16384, 512, 1024, False, 512),     # nothing masked: the rectangle
     (8192, 512, 512, False, 256),
     (4096, 512, 1024, False, 32),
 ])
 def test_chunked_grid_is_the_pair_list(S, block, chunk, causal, pairs):
     """The three chunked ``pallas_call``s run on grid (B*H, pairs) — two
-    dimensions, the second the cells' 272 / 136 / 20 pairs under a causal
-    mask and the rectangle's count without one — and the gauge
+    dimensions, the second the cells' 80 / 40 / 8 pairs under a causal
+    mask (272 / 136 / 20 at the chunks they had before PR 48) and the
+    rectangle's count without one — and the gauge
     ``attention/flash_grid_steps_walked_share`` is their sum over the
-    rectangle's."""
+    rectangle's, ``attention/flash_chunk_rows`` the chunk."""
     from deepspeed_tpu.telemetry.registry import default_registry
     H, Hkv = 4, 2
     q = jax.ShapeDtypeStruct((1, H, S, 16), jnp.float32)
@@ -895,6 +943,8 @@ def test_chunked_grid_is_the_pair_list(S, block, chunk, causal, pairs):
     assert default_registry().peek_gauge(
         "attention/flash_grid_steps_walked_share") == pytest.approx(
         pairs / rectangle)
+    assert default_registry().peek_gauge("attention/flash_chunk_rows") \
+        == chunk
     assert _fa().grid_steps_walked(S, block, block, chunk, causal) \
         == (3 * pairs, 3 * rectangle)
 

@@ -25,8 +25,9 @@ def test_the_scripts_that_stay_are_the_nine():
     """A probe is added here by name, with the Finding or the comment that
     cites it: 22 one-off probes had piled up before PR 46. Ten since PR 47:
     ``mla_flash_bench`` (the chunked kernels at latent attention's two
-    widths over (block, chunk) plans) is what ``_UNEQUAL_CHUNK_ROWS``'s
-    comment and PERF.md's Findings PR 47 quote."""
+    widths over (block, chunk) plans) is what ``_CHUNK_BYTES``'s comment and
+    PERF.md's Findings PR 47 quote, beside ``flash_chunked_bench --plans``
+    (the equal-width cells' shapes: Findings PR 48)."""
     assert SCRIPTS == [
         "adam_test", "aio_bench", "blocksparse_sweep", "flash_chunked_bench",
         "gdn_scan_bench", "gmm_tile_bench", "mixer_elementwise_bench",
@@ -57,3 +58,40 @@ def test_perf_script_imports_and_names_what_exists(name, monkeypatch, capsys):
             mod.main()
         assert done.value.code == 0
         assert "usage:" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("plans,kernels,skipped", [
+    (None, [("64x128", 20)] * 3, []),           # the plan the script derives
+    ("64x128,64x256,64x1024", [("64x128", 20)] * 3 + [("64x256", 12)] * 3,
+     ["64x1024"]),                              # the sweep; one tiles no S
+], ids=["default", "plans"])
+def test_flash_chunked_bench_rehearses_its_plans_on_the_cpu(
+        plans, kernels, skipped, monkeypatch, tmp_path, capsys):
+    """``flash_chunked_bench --rehearse-cpu [--plans BxC,...]``: the sweep's
+    control flow in the interpreter at S 512 — one line a kernel and a plan
+    with the grid steps a head walks, a ``skipped`` line for a plan that does
+    not tile S, no time read off the chip, the lines in
+    ``chiprun_out/<out>.jsonl`` — and the plan ``flash_attention`` picks at
+    a cell's shape, which is what the script times without ``--plans``."""
+    import json
+    script = os.path.join(PERF, "flash_chunked_bench.py")
+    argv = [script, "--rehearse-cpu", "--shapes", "laguna", "--out", "swept"]
+    monkeypatch.setattr("sys.argv", argv + (["--plans", plans] if plans
+                                            else []))
+    monkeypatch.setattr("sys.path", list(sys.path))
+    monkeypatch.chdir(tmp_path)
+    mod = importlib.import_module("tests.perf.flash_chunked_bench")
+    mod.main()
+    with open(tmp_path / "chiprun_out" / "swept.jsonl") as f:
+        lines = [json.loads(ln) for ln in f]
+    assert [json.loads(ln) for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith("{")] == lines
+    assert lines[0]["dtype"] == "bfloat16" and lines[0]["platform"] == "cpu"
+    timed = [ln for ln in lines[1:] if "kernel" in ln]
+    assert [(ln["plan"], ln["grid_steps_a_head"]) for ln in timed] == kernels
+    assert [ln["kernel"] for ln in timed] == ["fwd", "dq", "dkv"] * (
+        len(kernels) // 3)
+    assert all("ms" not in ln and ln["S"] == 512 for ln in timed)
+    assert [ln["plan"] for ln in lines[1:] if "skipped" in ln] == skipped
+    B, H, Hkv, S, D = mod.SHAPES["laguna"]
+    assert mod.picked_plan(B, H, Hkv, S, D, "bfloat16") == (512, 4096)

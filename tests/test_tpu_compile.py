@@ -208,9 +208,10 @@ def test_flash_attention_chunked_fwd_and_grad_compile_at_olmoe_shape():
     assert kernel_names(text) == {"_fwd_kernel_chunked",
                                   "_bwd_dq_kernel_chunked",
                                   "_bwd_dkv_kernel_chunked"}
-    # two grid dimensions: the heads' rows, and the 20 (block, chunk) pairs
-    # of 8 x 4 that a causal row needs (ISSUE 39)
-    assert pallas_grids(grads, *qkv) == [(64, 20)] * 3
+    # two grid dimensions: the heads' rows, and the 8 (block, chunk) pairs
+    # of 8 x 1 — one chunk of S rows, 2 MiB of K + V a step (ISSUE 48; 20
+    # of 8 x 4 at chunks of 1,024 before it, ISSUE 39)
+    assert pallas_grids(grads, *qkv) == [(64, 8)] * 3
     hlo = compiled.as_text()
     assert_dense_lse_kept(hlo, flash_calls(hlo), "f32[64,32,1,128]")
 
@@ -594,8 +595,9 @@ def test_olmoe_step_compiles_for_one_chip_with_its_scopes_and_fits():
 
 def test_flash_attention_chunked_gqa_compiles_at_qwen3_next_shape():
     """2 x 16 query / 2 KV heads x 8192 x head_dim 256, bf16, causal: a row of
-    4 MB takes the chunked kernels at chunk 512 (``_CHUNK_ROW_BYTES`` / 2 over
-    a 512-byte row), and K and V go in at their 2 heads: the kernels' index
+    4 MB takes the chunked kernels at chunk 2,048 (``_CHUNK_BYTES``: 2 MiB of
+    K + V a step; 4,096 rows are 4 MiB, which the compiler refuses in the dkv
+    kernel), and K and V go in at their 2 heads: the kernels' index
     maps fold a query head onto its group (``_kv_row``), nothing is repeated
     in HBM, dk and dv are summed over a group's 8 query heads after."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
@@ -610,8 +612,9 @@ def test_flash_attention_chunked_gqa_compiles_at_qwen3_next_shape():
     assert kernel_names(text) == {"_fwd_kernel_chunked",
                                   "_bwd_dq_kernel_chunked",
                                   "_bwd_dkv_kernel_chunked"}
-    # 136 of the 16 x 16 (block, chunk) pairs, at one block a chunk
-    assert pallas_grids(grads, *shapes) == [(32, 136)] * 3
+    # 40 of the 16 x 4 (block, chunk) pairs, at four blocks a chunk (136 of
+    # 16 x 16 at one block a chunk before ISSUE 48)
+    assert pallas_grids(grads, *shapes) == [(32, 40)] * 3
     # every Pallas call reads K and V at 4 = 2 x 2 rows, none at 32
     hlo = compiled.as_text()
     calls = flash_calls(hlo)
@@ -620,15 +623,16 @@ def test_flash_attention_chunked_gqa_compiles_at_qwen3_next_shape():
     assert_dense_lse_kept(hlo, calls, "f32[32,64,1,128]")
 
 
-@pytest.mark.parametrize("heads,kv_heads", [(48, 8), (28, 4)],
-                         ids=["laguna", "smallthinker"])
+@pytest.mark.parametrize("heads,kv_heads", [(48, 8), (28, 4), (32, 2)],
+                         ids=["laguna", "smallthinker", "nemotron"])
 def test_flash_attention_chunked_compiles_at_the_s16384_cells_shapes(
         heads, kv_heads):
-    """1 x 48 / 8 and 28 / 4 heads x 16,384 x head_dim 128, bf16, causal
-    (the full-attention layers of the Laguna and SmallThinker cells): the
-    three chunked kernels under their scopes on grid (heads, 272) — the
-    (block, chunk) pairs a causal row needs of 32 x 16 — K and V read at
-    their own heads, under the blocks' remat policy."""
+    """1 x 48 / 8, 28 / 4 and 32 / 2 heads x 16,384 x head_dim 128, bf16,
+    causal (the full-attention layers of the Laguna, SmallThinker and
+    Nemotron cells): the three chunked kernels under their scopes on grid
+    (heads, 80) — the (block, chunk) pairs a causal row needs of 32 x 4, at
+    chunks of 4,096 rows (ISSUE 48; 272 of 32 x 16 before it) — K and V read
+    at their own heads, under the blocks' remat policy."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
     def attend(*a):
@@ -645,7 +649,7 @@ def test_flash_attention_chunked_compiles_at_the_s16384_cells_shapes(
     assert kernel_names(text) == {"_fwd_kernel_chunked",
                                   "_bwd_dq_kernel_chunked",
                                   "_bwd_dkv_kernel_chunked"}
-    assert pallas_grids(grads, *shapes) == [(heads, 272)] * 3
+    assert pallas_grids(grads, *shapes) == [(heads, 80)] * 3
     hlo = compiled.as_text()
     calls = flash_calls(hlo)
     assert all(f"bf16[{kv_heads},16384,128]" in c for c in calls)
@@ -653,6 +657,32 @@ def test_flash_attention_chunked_compiles_at_the_s16384_cells_shapes(
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
     assert "16384,16384" not in hlo
     assert_dense_lse_kept(hlo, calls, f"f32[{heads},128,1,128]")
+
+
+@pytest.mark.parametrize("dtype,pairs", [(BF16, 288), (F32, 544)],
+                         ids=["bf16-chunk4096", "f32-chunk2048"])
+def test_flash_attention_chunked_compiles_where_the_budget_was_first_met(
+        dtype, pairs):
+    """16 heads x 32,768 x head_dim 64, causal, forward + backward: the shape
+    at which a 4,096-row chunk overflowed scoped VMEM by 0.9 MB in the kernels
+    of PR 27 and set the first chunk budget. The kernels since (the (o, m, l)
+    carry, the lane-dense statistics, the pair list) take it: 64 lanes are a
+    128-lane tile in VMEM, so the one budget gives this shape what it gives
+    head_dim 128 — 4,096 rows in bf16 (288 pairs a head of 64 x 8), 2,048 in
+    float32 (544 of 64 x 16)."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    qkv = (SDS((1, 16, 32768, 64), dtype),) * 3
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: flash_attention(*a, causal=True)
+                        .astype(F32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    text, compiled = compile_on_chip(grads, *qkv)
+    assert kernel_names(text) == {"_fwd_kernel_chunked",
+                                  "_bwd_dq_kernel_chunked",
+                                  "_bwd_dkv_kernel_chunked"}
+    assert pallas_grids(grads, *qkv) == [(16, pairs)] * 3
+    assert "32768,32768" not in compiled.as_text()
 
 
 def test_flash_attention_chunked_compiles_at_the_latent_attention_shape():
