@@ -33,14 +33,26 @@ Three kernel families share the same per-tile math (`_fwd_block_step` /
   softmax state and the backward's dq/dk/dv accumulators are fp32 VMEM
   scratch; lse and delta travel lane-dense; dq leaves as the input dtype.
 - **chunked**: the grid is (B*H, PAIRS) — its second dimension walks a
-  list of the (grid block, sequence chunk) pairs that hold work, built with
+  list of the (query block, key chunk) pairs that hold work, built with
   numpy at trace time (``_pair_walk``: two int32 arrays, scalar-prefetch
-  operands that every index map reads its block and chunk from), a block's
-  pairs consecutive and its chunks ascending, so each step streams one
-  CHUNK and accumulates into a revisited fp32 output block. Under a causal
-  mask the list leaves out the pairs wholly above the diagonal (forward and
-  dq: chunks past the query block's own; dkv: query chunks before the key
-  block), which on a rectangular (S / block, S / chunk) grid were steps
+  operands that every index map reads its block and chunk from). The
+  FORWARD walks them a block's pairs consecutive and its chunks ascending,
+  so each step streams one CHUNK of K and V and accumulates into a revisited
+  fp32 output block. The BACKWARD is ONE kernel too (``_bwd_kernel_chunked``,
+  PR 49; a dq and a dkv kernel before it, which computed every score tile —
+  two of seven products and the exp chain — twice): it walks the same pairs
+  a CHUNK's together, the chunk's K and V resident and its dk and dv in
+  fp32 VMEM scratch while the query blocks that see it stream by, computes
+  each tile once — five products, held [k, q] as the whole-row kernel holds
+  it — and writes dk and dv once a chunk, in the operands' dtype. dq, which
+  accumulates ACROSS chunks (a whole fp32 row is 8 MiB a head at S 16,384),
+  leaves as fp32 partials, one [block_q, D] block a pair — a slab a chunk —
+  and one XLA pass adds a block's partials, scales and casts
+  (``_sum_dq_slabs``; scopes ``flash_bwd_chunk`` / ``flash_bwd_dq_sum``,
+  gauges ``attention/flash_bwd_products_per_tile`` and
+  ``attention/flash_bwd_dq_slabs``); a plan of one chunk has nothing to add.
+  Under a causal mask the list leaves out the pairs wholly above the
+  diagonal, which on a rectangular (S / block, S / chunk) grid were steps
   with empty loops that still fetched their chunk, 0.64-1.5 us each on a
   v5e (PERF.md Findings PR 39; gauge
   ``attention/flash_grid_steps_walked_share``); a call that is not
@@ -63,10 +75,10 @@ Three kernel families share the same per-tile math (`_fwd_block_step` /
   single-chip attention training reaches 32k context;
   beyond that, sequence parallelism shards S first
   (deepspeed_tpu/parallel/ring_attention.py). Grouped-query K/V
-  ([B, Hkv, S, D], Hkv < H) go into all three chunked kernels AS THEY ARE
+  ([B, Hkv, S, D], Hkv < H) go into both chunked kernels AS THEY ARE
   since PR 31: the K/V index maps fold a query head onto its group's row
   (`_kv_row`), so K and V are never repeated in HBM, forward or backward;
-  dk and dv still leave the dkv kernel per QUERY head (fp32) and are
+  dk and dv still leave the backward kernel per QUERY head and are
   summed over a group's heads after it. Measured on a v5e at
   (S 4096, head_dim 128, 16 / 16 heads: OLMoE's cell) and at
   (S 8192, head_dim 256, 16 query / 2 KV heads: Qwen3-Next's cell); PERF.md
@@ -156,11 +168,12 @@ NEG_INF = -1e30
 # bf16) and S=4096 routes to the chunked kernels, whose per-chunk
 # residency is bounded (``_CHUNK_BYTES``).
 _UNCHUNKED_ROW_BYTES = 262144
-# what a grid step of the CHUNKED kernels may stream: the lane-padded bytes
-# of a chunk's two streamed operands (K + V forward and dq, Q + dO dkv; the
-# pipeline double-buffers them). ONE budget for equal and unequal q·k / value
-# widths, measured on a v5e at blocks of 512, bf16 causal, forward + dq + dkv
-# a call (tests/perf/flash_chunked_bench.py --plans, mla_flash_bench.py;
+# what a grid step of the CHUNKED kernels may hold of a sequence chunk: the
+# lane-padded bytes of its K + V (streamed a step forward, the pipeline
+# double-buffering them; resident over the chunk's run of steps backward).
+# ONE budget for equal and unequal q·k / value widths, measured on a v5e at
+# blocks of 512, bf16 causal, in the kernels of PR 48 — forward + dq + dkv a
+# call (tests/perf/flash_chunked_bench.py --plans, mla_flash_bench.py;
 # PERF.md Findings PR 48 and PR 47) — a grid step's fixed cost, not its tile,
 # is what these kernels pay:
 #   [48 / 8, 16384, 128]   chunk 512 / 1,024 / 2,048 / 4,096 (528 / 272 / 144
@@ -179,7 +192,13 @@ _UNCHUNKED_ROW_BYTES = 262144
 # with the plan it gives (tests/test_tpu_compile.py). float32 operands take
 # half the rows (their times are bf16's: the MXU takes them in bf16 passes);
 # 4,096 float32 rows at head_dim 128 (4 MiB) measured 5 % under 2,048 alone
-# and are left out with head_dim 256's.
+# and are left out with head_dim 256's. The single-pass backward of PR 49
+# takes the forward's chunk (one plan a call) and at it, kernel + the XLA
+# passes round it, where dq + dkv + theirs stood (PERF.md Findings PR 49):
+#   [48 / 8, 16384, 128] 53.2 for 86.6 ms; [2 x 16 / 2, 8192, 256] 19.5 for
+#   30.0; [4 x 16, 4096, 128] 5.0 for 9.1; [16, 32768, 64] 69.7 for 108.9;
+#   [32, 16384, 192 / 128] 60.5 for 87.9; without a mask 97.1 for 155.7 at
+#   the first shape; in float32 59.2 for 88.4 (chunk 2,048).
 _CHUNK_BYTES = 3 * 2 ** 20
 # rows a chunk may have, widest first: ``_pick_chunk`` takes the first that
 # fits ``_CHUNK_BYTES`` and tiles the sequence in whole blocks
@@ -964,43 +983,45 @@ def _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l, last):
             lse_ref[0, j] = _dense_row(lse[j * piece:(j + 1) * piece])
 
 
-def _walk_ends(i, block, chunk, n_chunks, causal, keys):
-    """(first, last) sequence chunk of grid block ``i``'s walk in the chunked
-    kernels. ``keys``: the block is ``block`` query rows and the walk the key
-    chunks it sees (forward, dq) — under a causal mask from chunk 0 to the
-    one that holds the block's diagonal; else the block is key rows and the
-    walk the query chunks that see it (dkv) — from the block's own chunk to
-    the last. Every chunk where nothing is masked. ``i`` a Python int
-    (``_pair_walk`` builds the grid from this) or traced (the kernels tell a
-    walk's first and last grid step by it)."""
+def _walk_ends(i, block, chunk, n_chunks, causal):
+    """(first, last) key chunk that query block ``i`` (``block`` rows) sees:
+    under a causal mask from chunk 0 to the one that holds the block's
+    diagonal, every chunk where nothing is masked. ``i`` a Python int
+    (``_pair_walk`` builds the grid from this) or traced (the forward kernel
+    tells a walk's first and last grid step by it)."""
     if not causal:
         return 0, n_chunks - 1
-    if keys:
-        return 0, ((i + 1) * block - 1) // chunk
-    return (i * block) // chunk, n_chunks - 1
+    return 0, ((i + 1) * block - 1) // chunk
 
 
 @functools.lru_cache(maxsize=None)
-def _pair_walk(S, block, chunk, causal, keys):
-    """The second grid dimension of a chunked kernel: the (block, chunk)
-    pairs that hold work, as two int32 arrays (``i_of``, ``c_of``) indexed
-    by grid step, in the order a rectangular (S / block, S / chunk) grid
-    visits them — a block's pairs consecutive and its chunks ascending
-    (``_walk_ends``), so a revisited output block and the VMEM scratch
-    accumulate over one unbroken run of steps. A causal call leaves out the
-    pairs wholly above the diagonal: 80 of 128 at S 16,384 with blocks of
-    512 and chunks of 4,096, 40 of 64 at S 8,192 / 512 / 2,048, all 8 at
-    S 4,096 / 512 / 4,096 (272 of 512, 136 of 256 and 20 of 32 at the chunks
-    of 1,024 / 512 / 1,024 rows before PR 48; a grid step with an empty loop
-    still fetched its chunk and cost 0.6-1 us: PERF.md, PR 39); a call that
-    is not causal walks the rectangle. Built with numpy at trace time, once
-    a plan, and handed to the call as scalar-prefetch operands."""
+def _pair_walk(S, block, chunk, causal, by_chunk):
+    """The second grid dimension of a chunked kernel: the (query block, key
+    chunk) pairs that hold work, as two int32 arrays (``i_of``, ``c_of``)
+    indexed by grid step. The forward's order is the one a rectangular
+    (S / block, S / chunk) grid visits them in — a block's pairs consecutive
+    and its chunks ascending (``_walk_ends``), so its revisited output block
+    and its softmax state accumulate over one unbroken run of steps;
+    ``by_chunk`` gives the SAME pairs a chunk's together and its blocks
+    ascending, the backward's order: a key chunk's K and V stay where they
+    are and its dk and dv accumulate in VMEM while the query blocks that see
+    it stream by. A causal call leaves out the pairs wholly above the
+    diagonal: 80 of 128 at S 16,384 with blocks of 512 and chunks of 4,096,
+    40 of 64 at S 8,192 / 512 / 2,048, all 8 at S 4,096 / 512 / 4,096 (272
+    of 512, 136 of 256 and 20 of 32 at the chunks of 1,024 / 512 / 1,024 rows
+    before PR 48; a grid step with an empty loop still fetched its chunk and
+    cost 0.6-1 us: PERF.md, PR 39); a call that is not causal walks the
+    rectangle. Built with numpy at trace time, once a plan, and handed to
+    the call as scalar-prefetch operands."""
     blocks, chunks = [], []
     for i in range(S // block):
-        first, last = _walk_ends(i, block, chunk, S // chunk, causal, keys)
+        first, last = _walk_ends(i, block, chunk, S // chunk, causal)
         blocks += [i] * (last - first + 1)
         chunks += range(first, last + 1)
     walk = np.asarray(blocks, np.int32), np.asarray(chunks, np.int32)
+    if by_chunk:
+        order = np.argsort(walk[1], kind="stable")
+        walk = tuple(np.ascontiguousarray(x[order]) for x in walk)
     for x in walk:              # cached: shared by every call of the plan
         x.flags.writeable = False
     return walk
@@ -1011,7 +1032,7 @@ def _fwd_kernel_chunked(i_of, c_of, q_ref, k_ref, v_ref, o_ref, lse_ref,
                         chunk, n_chunks):
     t = pl.program_id(1)
     qi, kc = i_of[t], c_of[t]
-    first, last = _walk_ends(qi, block_q, chunk, n_chunks, causal, True)
+    first, last = _walk_ends(qi, block_q, chunk, n_chunks, causal)
     cb = chunk // block_k                      # k-blocks per chunk
     fold = _scale_folds(scale)
     s_scale = None if fold else scale
@@ -1085,10 +1106,12 @@ def _rows_spec(rows, D, index, row=lambda b: b):
 
 
 def _pair_call(kernel, walk, BH, in_specs, out_specs, out_shape, scratch,
-               interpret):
+               interpret, vmem_limit=None):
     """``pallas_call`` of a chunked kernel on grid (BH, pairs of ``walk``):
     ``_pair_walk``'s two arrays are the call's first two operands, and every
-    index map reads its (block, chunk) from them — ``(b, t, i_of, c_of)``."""
+    index map reads its (block, chunk) from them — ``(b, t, i_of, c_of)``.
+    ``vmem_limit``: bytes of scoped VMEM the compiled call may take, where
+    the default 16 MiB does not hold its blocks."""
     call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1096,7 +1119,8 @@ def _pair_call(kernel, walk, BH, in_specs, out_specs, out_shape, scratch,
             in_specs=in_specs, out_specs=out_specs, scratch_shapes=scratch),
         out_shape=out_shape,
         interpret=interpret,
-    )
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)
+        if vmem_limit and not interpret else None)
     return functools.partial(call, *walk)
 
 
@@ -1116,7 +1140,7 @@ def _flash_fwd_chunked(q, k, v, scale, causal, block_q, block_k, chunk,
                                block_k=block_k, chunk=chunk,
                                n_chunks=S // chunk)
     call = _pair_call(
-        kernel, _pair_walk(S, block_q, chunk, causal, True), BH,
+        kernel, _pair_walk(S, block_q, chunk, causal, False), BH,
         [_rows_spec(block_q, D, _of_block),
          _rows_spec(chunk, D, _of_chunk, kv),
          _rows_spec(chunk, Dv, _of_chunk, kv)],
@@ -1126,108 +1150,117 @@ def _flash_fwd_chunked(q, k, v, scale, causal, block_q, block_k, chunk,
     return o32.astype(q.dtype), lse
 
 
-def _bwd_dq_kernel_chunked(i_of, c_of, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                           delta_ref, dq_ref, *, scale, causal, block_q,
-                           block_k, chunk, n_chunks):
+# scoped VMEM the chunked backward may take: a chunk's K, V, dk and dv blocks
+# (double-buffered) and its two float32 accumulators are 12 MiB at 4,096 rows
+# of head_dim 128 in bf16 and 18 MiB at latent attention's 192 / 128, beside
+# the tile's own temporaries; a v5e core has 128 MiB
+_BWD_VMEM_BYTES = 64 * 2 ** 20
+
+
+def _bwd_kernel_chunked(i_of, c_of, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                        delta_ref, dq_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
+                        scale, causal, block_q, block_k, chunk, dq_leaves):
+    """Single-pass chunked backward: a grid step is one (query block, key
+    chunk) pair of the walk ``_pair_walk`` orders BY CHUNK, and each score
+    tile of it — the two dots AND the exp — is computed ONCE for all three
+    gradients (five MXU products a tile, where a dq and a dkv kernel ran
+    seven and the softmax chain twice), as ``_bwd_fused_kernel`` does for a
+    whole row. The chunk's K and V stay in VMEM over its run of steps and
+    its dk and dv accumulate in float32 scratch, leaving once — in the
+    operands' dtype — on the run's last step; what a whole [S, D] float32
+    dq row would take does not fit (8 MiB a head at S 16,384), so a step's
+    dq — the block's rows against THIS chunk's keys, float32, unscaled —
+    leaves as block ``t`` of a [BH, pairs, block_q, D] array and
+    ``_sum_dq_slabs`` adds a block's partials of every chunk. ``dq_leaves``
+    (a plan of ONE chunk): nothing is left to add, and dq leaves scaled in
+    the operands' dtype.
+
+    The tile is held TRANSPOSED, [k, q], as the whole-row kernel holds it:
+    lse and delta are lane-dense rows as they are stored, and of p·do, ds·q
+    and dsᵀ·k only the last needs its left operand turned."""
     t = pl.program_id(1)
+    steps = pl.num_programs(1)
     qi, kc = i_of[t], c_of[t]
-    first, last = _walk_ends(qi, block_q, chunk, n_chunks, causal, True)
+    # the chunk's run of steps, told by the walk itself
+    first = jnp.logical_or(t == 0, c_of[jnp.maximum(t - 1, 0)] != kc)
+    last = jnp.logical_or(t == steps - 1,
+                          c_of[jnp.minimum(t + 1, steps - 1)] != kc)
     cb = chunk // block_k
     fold = _scale_folds(scale)
     s_scale = None if fold else scale
     q = q_ref[0] * scale if fold else q_ref[0]
     do = do_ref[0]
-    lse = _stat_col(lse_ref, (0,), 0, block_q)
-    delta = _stat_col(delta_ref, (0,), 0, block_q)
-    rel = _rel_pos(block_q, block_k) if causal else None
+    lse = _stat_row(lse_ref, (0,), 0, block_q)
+    delta = _stat_row(delta_ref, (0,), 0, block_q)
+    rel = -_rel_pos(block_k, block_q) if causal else None   # query - key
 
-    @pl.when(kc == first)
+    @pl.when(first)
     def _init():
-        dq_ref[0] = jnp.zeros_like(dq_ref[0])
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def body(j, dq_acc, masked):
+    def body(j, dq, masked):
         kb = kc * cb + j
-        k = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, pl.ds(j * block_k, block_k), :]
+        rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+        k = k_ref[0, rows, :]
         mask = _block_mask(rel, masked, qi * block_q, kb * block_k)
-        _, ds = _bwd_ds_block(q, do, lse, delta, k, v, mask, s_scale)
-        return dq_acc + jax.lax.dot(ds, k,
-                                    preferred_element_type=jnp.float32)
+        p, ds = _bwd_ds_block(k, v_ref[0, rows, :], lse, delta, q, do, mask,
+                              s_scale)
+        dv_acc[rows, :] += jax.lax.dot(p, do,
+                                       preferred_element_type=jnp.float32)
+        dk_acc[rows, :] += jax.lax.dot(ds, q,
+                                       preferred_element_type=jnp.float32)
+        return dq + jax.lax.dot_general(ds, k, (((0,), (0,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
 
+    dq0 = jnp.zeros(q.shape, jnp.float32)
     if causal:
         num_full = (qi * block_q) // block_k
         num_active = ((qi + 1) * block_q + block_k - 1) // block_k
         j_full = jnp.clip(num_full - kc * cb, 0, cb)
         j_hi = jnp.clip(num_active - kc * cb, 0, cb)
-        dq = _causal_split_loop(0, j_full, j_hi, body, dq_ref[0])
+        dq = _causal_split_loop(0, j_full, j_hi, body, dq0)
     else:
-        dq = _causal_split_loop(0, cb, cb, body, dq_ref[0])
-    # accumulate UNscaled across a block's walk; apply the folded-scale
-    # chain rule once on its last chunk (dq = scale · Σ ds·k)
-    dq_ref[0] = jnp.where(kc == last, dq * scale, dq)
+        dq = _causal_split_loop(0, cb, cb, body, dq0)
+    # dq = scale · Σ ds·k: once, where a block's partials have been added
+    dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype) if dq_leaves else dq
+
+    @pl.when(last)
+    def _leave():
+        # dk = scale · Σ dsᵀ·q: a pre-scaled q has carried it
+        dk = dk_acc[...] if fold else dk_acc[...] * scale
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_dkv_kernel_chunked(i_of, c_of, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                            delta_ref, dk_ref, dv_ref, *, scale, causal,
-                            block_q, block_k, chunk, n_chunks):
-    t = pl.program_id(1)
-    ki, qc = i_of[t], c_of[t]
-    first, last = _walk_ends(ki, block_k, chunk, n_chunks, causal, False)
+def _sum_dq_slabs(parts, walk, S, chunk, scale, dtype):
+    """dq [BH, S, D] from the single-pass backward's partials [BH, pairs,
+    block_q, D] (float32, unscaled): pair ``t`` holds query block
+    ``i_of[t]``'s rows against chunk ``c_of[t]``'s keys, a chunk's pairs one
+    SLAB of consecutive blocks (``_pair_walk`` by chunk: under a causal mask
+    a slab starts at its chunk's own rows — 10 of 16 block-rows at four
+    chunks). A chunk's rows of dq are the float32 sum of the slabs that
+    hold them, times the scale, cast once: one XLA pass where the split
+    kernels' float32 dq took one to be cast."""
+    BH, _, block_q, D = parts.shape
+    i_of, c_of = walk
     cb = chunk // block_q
-    fold = _scale_folds(scale)
-    s_scale = None if fold else scale
-    k = k_ref[0]
-    v = v_ref[0]
-    rel = _rel_pos(block_q, block_k) if causal else None
-
-    @pl.when(qc == first)
-    def _init():
-        dk_ref[0] = jnp.zeros_like(dk_ref[0])
-        dv_ref[0] = jnp.zeros_like(dv_ref[0])
-
-    def body(j, carry, masked):
-        dk_acc, dv_acc = carry
-        qb = qc * cb + j
-        q = q_ref[0, pl.ds(j * block_q, block_q), :]
-        if fold:
-            q = q * scale
-        do = do_ref[0, pl.ds(j * block_q, block_q), :]
-        lse = _stat_col(lse_ref, (0,), j * block_q, block_q)
-        delta = _stat_col(delta_ref, (0,), j * block_q, block_q)
-        mask = _block_mask(rel, masked, qb * block_q, ki * block_k)
-        p, ds = _bwd_ds_block(q, do, lse, delta, k, v, mask, s_scale)
-        dv_new = dv_acc + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_new = dk_acc + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk_new, dv_new
-
-    carry0 = (dk_ref[0], dv_ref[0])
-    if causal:
-        # within this q-chunk: blocks before the diagonal skip entirely,
-        # blocks straddling it run masked, strictly-after blocks unmasked
-        first_active = (ki * block_k) // block_q
-        first_full = ((ki + 1) * block_k + block_q - 1) // block_q
-        j_lo = jnp.clip(first_active - qc * cb, 0, cb)
-        j_mid = jnp.clip(first_full - qc * cb, 0, cb)
-        carry = jax.lax.fori_loop(
-            j_lo, j_mid, lambda j, c: body(j, c, True), carry0)
-        dk, dv = jax.lax.fori_loop(
-            j_mid, cb, lambda j, c: body(j, c, False), carry)
-    else:
-        dk, dv = _causal_split_loop(0, cb, cb, body, carry0)
-    # dk accumulates UNscaled across a block's walk; the folded-scale
-    # chain rule (dk = scale·Σ dsᵀ·q) lands once on its last chunk
-    dk_ref[0] = dk if fold else jnp.where(qc == last, dk * scale, dk)
-    dv_ref[0] = dv
+    # slab c: pairs [at[c], at[c + 1]), query blocks from lead[c] on
+    at = np.searchsorted(c_of, np.arange(S // chunk + 1))
+    lead = i_of[at[:-1]]
+    rows = []
+    for r in range(S // chunk):
+        held = [parts[:, at[c] + r * cb - lead[c]:
+                      at[c] + (r + 1) * cb - lead[c]]
+                for c in range(S // chunk) if lead[c] <= r * cb]
+        rows.append(functools.reduce(jnp.add, held))
+    return (_cat(rows, 1) * scale).astype(dtype).reshape(BH, S, D)
 
 
 def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                        chunk, interpret, heads=0, kv_heads=0):
-    """K and V may be [B * kv_heads, S, D] (grouped-query): both kernels
-    read a query head's group through ``_kv_row``; dk and dv still come
+    """K and V may be [B * kv_heads, S, D] (grouped-query): the kernel
+    reads a query head's group through ``_kv_row``; dk and dv still come
     back per QUERY head ([B * heads, S, D]) for the caller to sum. v, o, do
     and dv are ``Dv`` wide where the value width is not the q·k width
     (``_flash_fwd_chunked``)."""
@@ -1237,36 +1270,35 @@ def _flash_bwd_chunked(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1).reshape(lse.shape)
     piece = lse.shape[-1]
-    static = dict(scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, chunk=chunk, n_chunks=S // chunk)
-    grads, grads_v = (jax.ShapeDtypeStruct((BH, S, w), jnp.float32)
-                      for w in (D, Dv))
-    call_dq = _pair_call(
-        functools.partial(_bwd_dq_kernel_chunked, **static),
-        _pair_walk(S, block_q, chunk, causal, True), BH,
+    walk = _pair_walk(S, block_q, chunk, causal, True)
+    one = chunk == S                # one slab: dq leaves the kernel whole
+    call = _pair_call(
+        functools.partial(_bwd_kernel_chunked, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k, chunk=chunk,
+                          dq_leaves=one),
+        walk, BH,
         [_rows_spec(block_q, D, _of_block),
          _rows_spec(chunk, D, _of_chunk, kv),
          _rows_spec(chunk, Dv, _of_chunk, kv),
          _rows_spec(block_q, Dv, _of_block)]
         + [_stat_spec(block_q, piece, _of_block)] * 2,
-        _rows_spec(block_q, D, _of_block), grads, (), interpret)
-    with annotate("flash_bwd_dq"):
-        dq = call_dq(q, k, v, do, lse, delta)
-
-    call_dkv = _pair_call(
-        functools.partial(_bwd_dkv_kernel_chunked, **static),
-        _pair_walk(S, block_k, chunk, causal, False), BH,
-        [_rows_spec(chunk, D, _of_chunk),
-         _rows_spec(block_k, D, _of_block, kv),
-         _rows_spec(block_k, Dv, _of_block, kv),
-         _rows_spec(chunk, Dv, _of_chunk)]
-        + [_stat_spec(chunk, piece, _of_chunk)] * 2,
-        [_rows_spec(block_k, D, _of_block),
-         _rows_spec(block_k, Dv, _of_block)], [grads, grads_v], (),
-        interpret)
-    with annotate("flash_bwd_dkv"):
-        dk, dv = call_dkv(q, k, v, do, lse, delta)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+        [pl.BlockSpec((1, 1, block_q, D), lambda b, t, *_: (b, t, 0, 0)),
+         _rows_spec(chunk, D, _of_chunk),
+         _rows_spec(chunk, Dv, _of_chunk)],
+        [jax.ShapeDtypeStruct((BH, len(walk[0]), block_q, D),
+                              q.dtype if one else jnp.float32),
+         jax.ShapeDtypeStruct((BH, S, D), k.dtype),
+         jax.ShapeDtypeStruct((BH, S, Dv), v.dtype)],
+        [pltpu.VMEM((chunk, D), jnp.float32),
+         pltpu.VMEM((chunk, Dv), jnp.float32)],
+        interpret, _BWD_VMEM_BYTES)
+    with annotate("flash_bwd_chunk"):
+        dq, dk, dv = call(q, k, v, do, lse, delta)
+    if one:
+        return dq.reshape(BH, S, D), dk, dv
+    with annotate("flash_bwd_dq_sum"):
+        dq = _sum_dq_slabs(dq, walk, S, chunk, scale, q.dtype)
+    return dq, dk, dv
 
 
 # ------------------------------------------ sliding-window (band) variants
@@ -1759,7 +1791,7 @@ def tile_overcompute(S, block_q, block_k, chunk, causal):
         def walked(rows, cols):
             return sum(rows * cols * -(-((i + 1) * rows) // cols)
                        for i in range(S // rows))
-        computed = walked(block_q, block_k) + walked(block_k, block_q)
+        computed = 2 * walked(block_q, block_k)
     else:
         def walked(block):
             strip = _pick_strip(block)
@@ -1806,14 +1838,12 @@ def window_tiles_per_grid_step(S, block_q, block_k, window, band):
 
 
 def grid_steps_walked(S, block_q, block_k, chunk, causal):
-    """(grid steps a head of the three chunked kernels of one call —
-    forward, dq, dkv: ``_pair_walk`` — and of the rectangular
-    (S / block, S / chunk) grids those would be): 240 of 384 at S 16,384
+    """(grid steps a head of the two chunked kernels of one call — forward
+    and the single-pass backward: ``_pair_walk`` — and of the rectangular
+    (S / block, S / chunk) grids those would be): 160 of 256 at S 16,384
     with blocks of 512 and chunks of 4,096."""
-    over_keys = len(_pair_walk(S, block_q, chunk, causal, True)[0])
-    over_queries = len(_pair_walk(S, block_k, chunk, causal, False)[0])
-    return (2 * over_keys + over_queries,
-            (2 * (S // block_q) + S // block_k) * (S // chunk))
+    pairs = len(_pair_walk(S, block_q, chunk, causal, False)[0])
+    return 2 * pairs, 2 * (S // block_q) * (S // chunk)
 
 
 _plans_logged = set()
@@ -1826,10 +1856,14 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
     ``attention/flash_heads_per_block`` (heads a 128-lane column block of
     [B, S, H*D] operands; 0 for a head-major call) and, for a chunked call,
     ``attention/flash_grid_steps_walked_share`` (``grid_steps_walked``: grid
-    steps of its three kernels over those of the rectangular grid — 0.625
+    steps of its two kernels over those of the rectangular grid — 0.625
     causal at S 16,384 / 512 / 4,096, 1.0 where nothing is masked) and
     ``attention/flash_chunk_rows`` (sequence rows a grid step holds: the
-    chunk) and, once per distinct shape, a log line of the layout (the
+    chunk), for every call ``attention/flash_bwd_products_per_tile`` (MXU
+    products a score tile of its backward takes: 5, each tile computed once)
+    and ``attention/flash_bwd_dq_slabs`` (float32 dq slabs its backward
+    leaves to be added: one a key chunk, 0 for a whole-row call) and, once
+    per distinct shape, a log line of the layout (the
     operands' and the log-sum-exp's) and loop structure chosen for it.
     ``window``: a call of the window kernels under ``band``
     (``_band_plan``) — the gauges ``attention/window_tile_overcompute`` and
@@ -1866,6 +1900,13 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
     default_registry().gauge("attention/flash_heads_per_block").set(
         heads_per_block)
     walked = ""
+    # every family's backward computes a score tile once (five products:
+    # k·qᵀ, v·doᵀ, p·do, ds·q, dsᵀ·k); a chunked call's dq leaves as one
+    # float32 slab a key chunk for ``_sum_dq_slabs`` (a single slab leaves
+    # whole: nothing is added), a whole row's is VMEM-resident (0 slabs)
+    slabs = S // chunk if chunk else 0
+    default_registry().gauge("attention/flash_bwd_products_per_tile").set(5)
+    default_registry().gauge("attention/flash_bwd_dq_slabs").set(slabs)
     if chunk:
         steps, rectangle = grid_steps_walked(S, block_q, block_k, chunk,
                                              causal)
@@ -1873,7 +1914,8 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
             "attention/flash_grid_steps_walked_share").set(steps / rectangle)
         default_registry().gauge("attention/flash_chunk_rows").set(chunk)
         walked = (f" ({steps} of {rectangle} (block, chunk) pairs walked, "
-                  "forward + dq + dkv)")
+                  f"forward + backward; backward 5 products a tile, dq in "
+                  f"{slabs} slab(s))")
     if value_dim:
         default_registry().gauge("attention/mla_qk_dim").set(D)
         default_registry().gauge("attention/mla_v_dim").set(value_dim)

@@ -106,8 +106,13 @@ def annotate(tag):
     - ``flash_fwd`` and its long-S variant ``flash_fwd_chunk``
       (ops/pallas/flash_attention.py, round the ``pallas_call`` itself):
       ``flash_fwd_roofline``;
-    - ``flash_bwd`` and its long-S variants ``flash_bwd_dq``,
-      ``flash_bwd_dkv`` (same file): ``flash_bwd_roofline``;
+    - ``flash_bwd`` and its long-S variant ``flash_bwd_chunk`` (same
+      file; the single-pass chunked backward since PR 49, a
+      ``flash_bwd_dq`` and a ``flash_bwd_dkv`` call before it):
+      ``flash_bwd_roofline``; ``flash_bwd_dq_sum`` beside it, the XLA pass
+      that adds that kernel's float32 dq slabs, scales and casts — no
+      Pallas call, so in ``train_bwd_ms`` and the module's row and in no
+      roofline;
     - ``ds_loss_head`` (``chunked_lm_loss``, ``lm_loss``, the tied-logits
       einsum) and ``ds_embed`` (the ``wte``/``wpe`` lookup), both
       models/gpt2.py: rows of the benchmark's detail table.
@@ -201,7 +206,11 @@ def annotate(tag):
     (grid steps of their (block, chunk) pair lists over the rectangular
     grid's: 0.625 causal at S 16,384, 1.0 where nothing is masked), and a
     fourth, ``attention/flash_chunk_rows`` (sequence rows a grid step
-    holds: 4,096 at head_dim 128 in bf16), and
+    holds: 4,096 at head_dim 128 in bf16), every flash call
+    ``attention/flash_bwd_products_per_tile`` (MXU products a score tile
+    of its backward takes: 5, each tile computed once) and
+    ``attention/flash_bwd_dq_slabs`` (float32 dq slabs a chunked backward
+    leaves to be added, one a key chunk; 0 for a whole-row call), and
     the gated delta rule two, ``linear_attn/gdn_kernel_heads_per_step``
     (value heads a grid step of its kernels; 0: the XLA form took the
     call) and ``linear_attn/gdn_states_kept_every`` (chunks between the

@@ -1,15 +1,21 @@
-"""Microbenchmark of the causal CHUNKED flash kernels alone on the chip
-(``ops/pallas/flash_attention.py``: ``_fwd_kernel_chunked``,
-``_bwd_dq_kernel_chunked``, ``_bwd_dkv_kernel_chunked``), one line a kernel
-and a (block, chunk) plan, at the shapes of the cells that run them —
+"""Microbenchmark of the CHUNKED flash kernels alone on the chip
+(``ops/pallas/flash_attention.py``: ``_fwd_kernel_chunked`` and the
+single-pass ``_bwd_kernel_chunked``; in a tree from before PR 49
+``_bwd_dq_kernel_chunked`` and ``_bwd_dkv_kernel_chunked``), one line a kernel
+and a (block, chunk) plan — the backward's Pallas calls a line each (``bwd``,
+or ``dq`` and ``dkv``) and ``bwd_xla`` the XLA passes round them (delta; the
+sum of the dq slabs, scale and cast, or the three casts of float32 dq / dk /
+dv), so that a tree's whole backward is the sum of its lines other than
+``fwd`` — at the shapes of the cells that run them —
 ``laguna-train-1chip-s16384``'s full layers (48 query / 8 KV heads x 16,384 x
 head_dim 128, bf16), ``qwen3next-train-1chip-s8192`` (2 x 16 / 2 heads x
 8,192 x 256) and ``olmoe-train-1chip-s4096`` (4 x 16 / 16 x 4,096 x 128), and
 at ``d64s32k`` (16 heads x 32,768 x 64: the shape whose VMEM overflow set the
 first chunk budget) — with the grid steps a head walks and the us a step:
-every time a DEVICE time of the Pallas custom call from a profiler trace.
-``--plans`` sweeps (block, chunk) pairs (a plan that does not tile a shape's
-S is a ``skipped`` line, one the compiler refuses a ``refused`` line with
+every time a DEVICE time from a profiler trace. ``--full`` drops the causal
+mask (ring / Ulysses attention's calls: the rectangle). ``--plans`` sweeps
+(block, chunk) pairs (a plan that does not tile a shape's S is a ``skipped``
+line, one the compiler refuses a ``refused`` line with
 the refusal's first line); without it the one plan is what
 ``flash_attention`` of the tree under test picks. ``--tree=DIR`` times
 another checkout's kernels (the parent's, unpacked in a git-ignored
@@ -54,18 +60,25 @@ SHAPES = {"laguna": (1, 48, 8, 16384, 128),
           "qwen3next": (2, 16, 2, 8192, 256),
           "olmoe": (4, 16, 16, 4096, 128),
           "d64s32k": (1, 16, 16, 32768, 64)}
-# products a score tile takes in each kernel (q·kᵀ, p·v | q·kᵀ, do·vᵀ, ds·k |
-# q·kᵀ, do·vᵀ, pᵀ·do, dsᵀ·q)
-PRODUCTS = {"fwd": 2, "dq": 3, "dkv": 4}
+# products a score tile takes in each kernel (q·kᵀ, p·v | k·qᵀ, v·doᵀ, p·do,
+# ds·q, dsᵀ·k | before PR 49 q·kᵀ, do·vᵀ, ds·k | q·kᵀ, do·vᵀ, pᵀ·do, dsᵀ·q)
+PRODUCTS = {"fwd": 2, "bwd": 5, "dq": 3, "dkv": 4}
+# the backward's Pallas calls in the tree under test, and the scope each runs
+# under: the device plane names a call after its scope (``%flash_bwd_dkv.3``)
+BWD_KERNELS = (("bwd",) if hasattr(fa, "_bwd_kernel_chunked")
+               else ("dq", "dkv"))
+SCOPES = {"fwd": "flash_fwd_chunk", "bwd": "flash_bwd_chunk",
+          "dq": "flash_bwd_dq", "dkv": "flash_bwd_dkv"}
 
 
-def kernel_ms(fn, *args, reps=5):
-    """Device ms a call of the ONE Pallas custom call of the jitted ``fn``
-    (a profiler trace: no dispatch, fence or XLA cast beside it). Off the
-    chip (``--rehearse-cpu``) the call is only run: no time is read."""
+def device_ms(fn, *args, reps=5):
+    """({kernel: device ms a call of its Pallas custom call}, device ms a
+    call of every other operation together) of the jitted ``fn``, from a
+    profiler trace: no dispatch and no fence in either. Off the chip
+    (``--rehearse-cpu``) the call is only run: no time is read."""
     jax.block_until_ready(fn(*args))
     if jax.default_backend() != "tpu":
-        return None
+        return None, None
     where = tempfile.mkdtemp()
     try:
         with jax.profiler.trace(where):
@@ -75,18 +88,48 @@ def kernel_ms(fn, *args, reps=5):
     finally:
         shutil.rmtree(where, ignore_errors=True)
     plane = sorted(trace.devices)[0]
-    calls = [e for e in trace_reduce.ops(trace, plane)
-             if trace_reduce.is_pallas(e.name)]
-    assert len(calls) == reps, [e.name[:80] for e in calls]
-    return sum(e.end - e.start for e in calls) / reps / 1e6
+    events = trace_reduce.ops(trace, plane)
+    calls, rest = {}, 0.0
+    for i, ns in trace_reduce.self_times(events).items():
+        name = events[i].name
+        if not trace_reduce.is_pallas(name):
+            rest += ns
+            continue
+        kernel = next(k for k, scope in SCOPES.items() if scope in name)
+        calls.setdefault(kernel, []).append(ns)
+    assert all(len(ns) == reps for ns in calls.values()), {
+        k: len(ns) for k, ns in calls.items()}
+    return ({k: sum(ns) / reps / 1e6 for k, ns in calls.items()},
+            rest / reps / 1e6)
 
 
-def steps_a_head(S, block, chunk, keys):
-    """Grid steps a (batch, head) row of a causal chunked kernel walks in
-    the tree under test: its pair list, or the rectangle it had before."""
-    if hasattr(fa, "_pair_walk"):
-        return len(fa._pair_walk(S, block, chunk, True, keys)[0])
-    return (S // block) * (S // chunk)
+def kernel_ms(fn, *args, reps=5):
+    """Device ms a call of the ONE Pallas custom call of ``fn``."""
+    calls, _ = device_ms(fn, *args, reps=reps)
+    if calls is None:
+        return None
+    (ms,) = calls.values()
+    return ms
+
+
+def bwd_times(bwd, *args):
+    """[(line's name, ms)] of a jitted whole ``_flash_bwd_chunked``: its
+    Pallas calls (``BWD_KERNELS``) and ``bwd_xla``, everything round them."""
+    calls, rest = device_ms(bwd, *args)
+    if calls is None:
+        return [(name, None) for name in BWD_KERNELS + ("bwd_xla",)]
+    return [(name, calls[name]) for name in BWD_KERNELS] + [("bwd_xla", rest)]
+
+
+def steps_a_head(S, block, chunk, causal, kernel):
+    """Grid steps a (batch, head) row of a chunked kernel walks in the tree
+    under test: its pair list, or the rectangle it had before."""
+    if not hasattr(fa, "_pair_walk"):
+        return (S // block) * (S // chunk)
+    # before PR 49 the last argument told the key blocks' walk (dkv's)
+    # from the query blocks'; since, the backward's order from the forward's
+    last = kernel == "bwd" if "bwd" in BWD_KERNELS else kernel != "dkv"
+    return len(fa._pair_walk(S, block, chunk, causal, last)[0])
 
 
 def picked_plan(B, H, Hkv, S, D, dtype):
@@ -121,6 +164,8 @@ def main():
                          "flash_attention picks for the shape)")
     ap.add_argument("--dtype", default="bfloat16",
                     choices=("bfloat16", "float32"))
+    ap.add_argument("--full", action="store_true",
+                    help="no causal mask: the pairs' rectangle")
     ap.add_argument("--tree", default=HERE,
                     help="--tree=DIR: the checkout whose kernels are timed")
     ap.add_argument("--rehearse-cpu", action="store_true",
@@ -140,7 +185,8 @@ def main():
         sys.exit("no TPU here: a kernel's time comes only from the chip")
     dtype = jnp.dtype(args.dtype)
     lines = [{"device": dev.device_kind, "platform": dev.platform,
-              "tree": os.path.relpath(args.tree, HERE), "dtype": dtype.name}]
+              "tree": os.path.relpath(args.tree, HERE), "dtype": dtype.name,
+              "causal": not args.full}]
     print(json.dumps(lines[0]), flush=True)
 
     def note(line):
@@ -169,33 +215,33 @@ def main():
                 note({"shape": name, "plan": plan,
                       "skipped": f"does not tile S={S} in chunks of blocks"})
                 continue
-            static = (D ** -0.5, True, block, block, chunk,
+            causal = not args.full
+            static = (D ** -0.5, causal, block, block, chunk,
                       args.rehearse_cpu, H, Hkv)
             fwd = jax.jit(
                 lambda q, k, v: fa._flash_fwd_chunked(q, k, v, *static))
-            # dq or dk, dv alone: XLA drops the call whose results nobody
-            # reads
-            bwd = {"dq": jax.jit(
-                lambda *a: fa._flash_bwd_chunked(*a, *static)[0]),
-                "dkv": jax.jit(
-                    lambda *a: fa._flash_bwd_chunked(*a, *static)[1:])}
+            bwd = jax.jit(lambda *a: fa._flash_bwd_chunked(*a, *static))
             try:
                 o, lse = fwd(q, k, v)
-                times = (("fwd", kernel_ms(fwd, q, k, v)),
-                         ("dq", kernel_ms(bwd["dq"], q, k, v, o, lse, do)),
-                         ("dkv", kernel_ms(bwd["dkv"], q, k, v, o, lse, do)))
+                times = [("fwd", kernel_ms(fwd, q, k, v))] + bwd_times(
+                    bwd, q, k, v, o, lse, do)
             except Exception as e:  # noqa: BLE001 — the compiler's refusal
                 note({"shape": name, "plan": plan,
                       "refused": str(e).splitlines()[0][:300]})
                 continue
-            tiles = (S // block) * (S // block + 1) // 2    # a head
+            n = S // block
+            tiles = n * (n + 1) // 2 if causal else n * n   # a head
+            scores = S * (S + 1) // 2 if causal else S * S
             for kernel, ms in times:
-                steps = steps_a_head(S, block, chunk, kernel != "dkv")
-                flops = 2 * PRODUCTS[kernel] * B * H * (S * (S + 1) // 2) * D
                 line = {"shape": name, "plan": plan, "kernel": kernel,
                         "rows": B * H, "S": S, "D": D, "block": block,
-                        "chunk": chunk, "grid_steps_a_head": steps,
-                        "tiles_a_head": tiles}
+                        "chunk": chunk}
+                if kernel == "bwd_xla":     # no grid and no products
+                    note(dict(line, **({} if ms is None else {"ms": ms})))
+                    continue
+                steps = steps_a_head(S, block, chunk, causal, kernel)
+                flops = 2 * PRODUCTS[kernel] * B * H * scores * D
+                line.update(grid_steps_a_head=steps, tiles_a_head=tiles)
                 if ms is not None:
                     line.update(
                         ms=ms, us_a_head=ms * 1e3 / (B * H),

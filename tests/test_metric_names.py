@@ -211,14 +211,17 @@ def test_zero_gather_edge_metric_names_documented():
                                   "attention/window_tiles_per_grid_step",
                                   "attention/flash_residual_mb",
                                   "attention/flash_grid_steps_walked_share",
-                                  "attention/flash_chunk_rows"])
+                                  "attention/flash_chunk_rows",
+                                  "attention/flash_bwd_products_per_tile",
+                                  "attention/flash_bwd_dq_slabs"])
 def test_flash_engagement_gauges_documented(name):
     """The flash kernels' trace-time engagement gauges (ISSUE 28: the
     loops' overcompute; ISSUE 30: heads a column block, 0 head-major;
     ISSUE 34: MB of the residuals a differentiation names; ISSUE 39: the
     chunked kernels' grid steps over the rectangle's; ISSUE 43: the window
     kernels' score tiles a grid step; ISSUE 48: the chunked kernels' rows a
-    grid step) stay documented AND emitted."""
+    grid step; ISSUE 49: the backward's products a score tile and its dq
+    slabs) stay documented AND emitted."""
     assert name in documented_metric_names(), (
         f"{name} missing from the docs/observability.md train table")
     assert name in _package_source(), name

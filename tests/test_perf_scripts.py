@@ -61,15 +61,17 @@ def test_perf_script_imports_and_names_what_exists(name, monkeypatch, capsys):
 
 
 @pytest.mark.parametrize("plans,kernels,skipped", [
-    (None, [("64x128", 20)] * 3, []),           # the plan the script derives
-    ("64x128,64x256,64x1024", [("64x128", 20)] * 3 + [("64x256", 12)] * 3,
+    (None, [("64x128", 20)] * 2, []),           # the plan the script derives
+    ("64x128,64x256,64x1024", [("64x128", 20)] * 2 + [("64x256", 12)] * 2,
      ["64x1024"]),                              # the sweep; one tiles no S
 ], ids=["default", "plans"])
 def test_flash_chunked_bench_rehearses_its_plans_on_the_cpu(
         plans, kernels, skipped, monkeypatch, tmp_path, capsys):
     """``flash_chunked_bench --rehearse-cpu [--plans BxC,...]``: the sweep's
     control flow in the interpreter at S 512 — one line a kernel and a plan
-    with the grid steps a head walks, a ``skipped`` line for a plan that does
+    with the grid steps a head walks (``fwd``, the single-pass ``bwd``) and
+    one, ``bwd_xla``, for the XLA passes round the backward's kernel (delta,
+    the dq slabs' sum), a ``skipped`` line for a plan that does
     not tile S, no time read off the chip, the lines in
     ``chiprun_out/<out>.jsonl`` — and the plan ``flash_attention`` picks at
     a cell's shape, which is what the script times without ``--plans``."""
@@ -88,10 +90,11 @@ def test_flash_chunked_bench_rehearses_its_plans_on_the_cpu(
             if ln.startswith("{")] == lines
     assert lines[0]["dtype"] == "bfloat16" and lines[0]["platform"] == "cpu"
     timed = [ln for ln in lines[1:] if "kernel" in ln]
-    assert [(ln["plan"], ln["grid_steps_a_head"]) for ln in timed] == kernels
-    assert [ln["kernel"] for ln in timed] == ["fwd", "dq", "dkv"] * (
-        len(kernels) // 3)
+    assert [ln["kernel"] for ln in timed] == ["fwd", "bwd", "bwd_xla"] * (
+        len(kernels) // 2)
     assert all("ms" not in ln and ln["S"] == 512 for ln in timed)
+    timed = [ln for ln in timed if ln["kernel"] != "bwd_xla"]   # no grid
+    assert [(ln["plan"], ln["grid_steps_a_head"]) for ln in timed] == kernels
     assert [ln["plan"] for ln in lines[1:] if "skipped" in ln] == skipped
     B, H, Hkv, S, D = mod.SHAPES["laguna"]
     assert mod.picked_plan(B, H, Hkv, S, D, "bfloat16") == (512, 4096)

@@ -450,8 +450,7 @@ def test_deepseek_v3_scope_names_and_gauges_reach_the_step():
                   "mla_attn/mla_rope", "mla_attn/q_proj", "mla_attn/o_proj",
                   # per device inside a shard_map on this mesh of eight
                   "mla_attn/shard_map/flash_fwd_chunk",
-                  "mla_attn/shard_map/flash_bwd_dq",
-                  "mla_attn/shard_map/flash_bwd_dkv",
+                  "mla_attn/shard_map/flash_bwd_chunk",
                   "layer_0/mlp/dense_mlp", "mlp/moe_shared", "mlp/moe_router",
                   "moe_dispatch", "moe_gmm", "moe_combine", "ds_embed",
                   "ds_loss_head"):

@@ -131,11 +131,12 @@ def rematted(attend):
 
 def assert_dense_lse_kept(hlo, calls, dense):
     """A rematted call's gradient program holds the forward kernel ONCE
-    (``calls``: forward, dq, dkv — ``flash_o`` / ``flash_lse`` are kept), the
+    (``calls``: the forward and the single-pass backward — ``flash_o`` /
+    ``flash_lse`` are kept; the window family's forward, dq and dkv), the
     forward kernel writes lse as ``dense`` ([BH, S / 128, 1, 128]: 128 real
-    lanes), both backward kernels read it so, and no [.., S, 1] column, 128 x
-    the size in HBM, is anywhere in the step."""
-    assert len(calls) == 3, calls
+    lanes), every backward kernel reads it so, and no [.., S, 1] column,
+    128 x the size in HBM, is anywhere in the step."""
+    assert len(calls) in (2, 3), calls
     assert hlo_text.rematted_forward_attention(hlo) == []
     assert all(dense in c for c in calls), (dense, calls)
     assert sum(dense in c.split(" custom-call(")[0] for c in calls) == 1
@@ -206,12 +207,11 @@ def test_flash_attention_chunked_fwd_and_grad_compile_at_olmoe_shape():
 
     text, compiled = compile_on_chip(grads, *qkv)
     assert kernel_names(text) == {"_fwd_kernel_chunked",
-                                  "_bwd_dq_kernel_chunked",
-                                  "_bwd_dkv_kernel_chunked"}
+                                  "_bwd_kernel_chunked"}
     # two grid dimensions: the heads' rows, and the 8 (block, chunk) pairs
     # of 8 x 1 — one chunk of S rows, 2 MiB of K + V a step (ISSUE 48; 20
     # of 8 x 4 at chunks of 1,024 before it, ISSUE 39)
-    assert pallas_grids(grads, *qkv) == [(64, 8)] * 3
+    assert pallas_grids(grads, *qkv) == [(64, 8)] * 2
     hlo = compiled.as_text()
     assert_dense_lse_kept(hlo, flash_calls(hlo), "f32[64,32,1,128]")
 
@@ -571,8 +571,7 @@ def test_olmoe_step_compiles_for_one_chip_with_its_scopes_and_fits():
     lowered = manifest.family_module(config).lower_train_step(
         config, manifest.traffic_of(cell), topo().devices[:1])
     assert kernel_names(lowered.as_text()) == {
-        "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
-        "_bwd_dkv_kernel_chunked", "kernel"}
+        "_fwd_kernel_chunked", "_bwd_kernel_chunked", "kernel"}
     # head-major, from ``models/llama.py``: ISSUE 30's path is bypassed
     assert default_registry().peek_gauge(
         "attention/flash_heads_per_block") == 0
@@ -588,7 +587,8 @@ def test_olmoe_step_compiles_for_one_chip_with_its_scopes_and_fits():
         for ln in kernels)
     for scope in ("moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs", "moe_router",
                   "moe_dispatch", "moe_combine", "qk_norm", "flash_fwd_chunk",
-                  "flash_bwd_dq", "flash_bwd_dkv", "ds_loss_head", "ds_embed",
+                  # one chunk: dq leaves the kernel whole, no slabs' sum
+                  "flash_bwd_chunk", "ds_loss_head", "ds_embed",
                   "ds_optimizer"):
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
 
@@ -596,8 +596,8 @@ def test_olmoe_step_compiles_for_one_chip_with_its_scopes_and_fits():
 def test_flash_attention_chunked_gqa_compiles_at_qwen3_next_shape():
     """2 x 16 query / 2 KV heads x 8192 x head_dim 256, bf16, causal: a row of
     4 MB takes the chunked kernels at chunk 2,048 (``_CHUNK_BYTES``: 2 MiB of
-    K + V a step; 4,096 rows are 4 MiB, which the compiler refuses in the dkv
-    kernel), and K and V go in at their 2 heads: the kernels' index
+    K + V a step; 4,096 rows are 4 MiB, which the compiler refused in the dkv
+    kernel of PR 48), and K and V go in at their 2 heads: the kernels' index
     maps fold a query head onto its group (``_kv_row``), nothing is repeated
     in HBM, dk and dv are summed over a group's 8 query heads after."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
@@ -610,15 +610,14 @@ def test_flash_attention_chunked_gqa_compiles_at_qwen3_next_shape():
               SDS((2, 2, 8192, 256), BF16))
     text, compiled = compile_on_chip(grads, *shapes)
     assert kernel_names(text) == {"_fwd_kernel_chunked",
-                                  "_bwd_dq_kernel_chunked",
-                                  "_bwd_dkv_kernel_chunked"}
+                                  "_bwd_kernel_chunked"}
     # 40 of the 16 x 4 (block, chunk) pairs, at four blocks a chunk (136 of
     # 16 x 16 at one block a chunk before ISSUE 48)
-    assert pallas_grids(grads, *shapes) == [(32, 40)] * 3
+    assert pallas_grids(grads, *shapes) == [(32, 40)] * 2
     # every Pallas call reads K and V at 4 = 2 x 2 rows, none at 32
     hlo = compiled.as_text()
     calls = flash_calls(hlo)
-    assert len(calls) == 3 and all("bf16[4,8192,256]" in c for c in calls)
+    assert len(calls) == 2 and all("bf16[4,8192,256]" in c for c in calls)
     # under the blocks' remat policy, as the cell's attention layer is
     assert_dense_lse_kept(hlo, calls, "f32[32,64,1,128]")
 
@@ -629,7 +628,7 @@ def test_flash_attention_chunked_compiles_at_the_s16384_cells_shapes(
         heads, kv_heads):
     """1 x 48 / 8, 28 / 4 and 32 / 2 heads x 16,384 x head_dim 128, bf16,
     causal (the full-attention layers of the Laguna, SmallThinker and
-    Nemotron cells): the three chunked kernels under their scopes on grid
+    Nemotron cells): the two chunked kernels under their scopes on grid
     (heads, 80) — the (block, chunk) pairs a causal row needs of 32 x 4, at
     chunks of 4,096 rows (ISSUE 48; 272 of 32 x 16 before it) — K and V read
     at their own heads, under the blocks' remat policy."""
@@ -647,13 +646,12 @@ def test_flash_attention_chunked_compiles_at_the_s16384_cells_shapes(
               SDS((1, kv_heads, 16384, 128), BF16))
     text, compiled = compile_on_chip(grads, *shapes)
     assert kernel_names(text) == {"_fwd_kernel_chunked",
-                                  "_bwd_dq_kernel_chunked",
-                                  "_bwd_dkv_kernel_chunked"}
-    assert pallas_grids(grads, *shapes) == [(heads, 80)] * 3
+                                  "_bwd_kernel_chunked"}
+    assert pallas_grids(grads, *shapes) == [(heads, 80)] * 2
     hlo = compiled.as_text()
     calls = flash_calls(hlo)
     assert all(f"bf16[{kv_heads},16384,128]" in c for c in calls)
-    for scope in ("flash_fwd_chunk", "flash_bwd_dq", "flash_bwd_dkv"):
+    for scope in ("flash_fwd_chunk", "flash_bwd_chunk", "flash_bwd_dq_sum"):
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
     assert "16384,16384" not in hlo
     assert_dense_lse_kept(hlo, calls, f"f32[{heads},128,1,128]")
@@ -679,18 +677,18 @@ def test_flash_attention_chunked_compiles_where_the_budget_was_first_met(
 
     text, compiled = compile_on_chip(grads, *qkv)
     assert kernel_names(text) == {"_fwd_kernel_chunked",
-                                  "_bwd_dq_kernel_chunked",
-                                  "_bwd_dkv_kernel_chunked"}
-    assert pallas_grids(grads, *qkv) == [(16, pairs)] * 3
+                                  "_bwd_kernel_chunked"}
+    assert pallas_grids(grads, *qkv) == [(16, pairs)] * 2
     assert "32768,32768" not in compiled.as_text()
 
 
 def test_flash_attention_chunked_compiles_at_the_latent_attention_shape():
     """1 x 32 heads x 16,384, a q·k head of 192 (128 + the 64 rotated) and
     a value head of 128, bf16, causal (every layer of the Kanana-2 cell):
-    the three chunked kernels under their scopes, K read 192 wide and V 128
-    wide — V is not padded to 192 in HBM — o and dv 128 wide, dq and dk
-    192, no [S, S] scores, under the blocks' remat policy."""
+    the two chunked kernels under their scopes, K read 192 wide and V 128
+    wide — V is not padded to 192 in HBM — o and dv 128 wide, dq's float32
+    partials and dk 192 (dk and dv leave the backward kernel in bf16), no
+    [S, S] scores, under the blocks' remat policy."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
     def attend(*a):
@@ -704,18 +702,18 @@ def test_flash_attention_chunked_compiles_at_the_latent_attention_shape():
               SDS((1, 32, 16384, 128), BF16))
     text, compiled = compile_on_chip(grads, *shapes)
     assert kernel_names(text) == {"_fwd_kernel_chunked",
-                                  "_bwd_dq_kernel_chunked",
-                                  "_bwd_dkv_kernel_chunked"}
+                                  "_bwd_kernel_chunked"}
     hlo = compiled.as_text()
     calls = flash_calls(hlo)
-    assert len(calls) == 3
+    assert len(calls) == 2
     assert all("bf16[32,16384,192]" in c and "bf16[32,16384,128]" in c
                for c in calls)
-    fwd, dq, dkv = calls
+    fwd, bwd = calls
     assert "f32[32,16384,128]" in fwd and "f32[32,16384,192]" not in fwd
-    assert "f32[32,16384,192]" in dq
-    assert "f32[32,16384,192]" in dkv and "f32[32,16384,128]" in dkv
-    for scope in ("flash_fwd_chunk", "flash_bwd_dq", "flash_bwd_dkv"):
+    # the 80 pairs' dq partials, and dk / dv in the operands' dtype
+    results = bwd.split(" custom-call(")[0]
+    assert "f32[32,80,512,192]" in results and "f32[32,16384" not in results
+    for scope in ("flash_fwd_chunk", "flash_bwd_chunk", "flash_bwd_dq_sum"):
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
     assert "16384,16384" not in hlo
     assert_dense_lse_kept(hlo, calls, "f32[32,128,1,128]")
@@ -929,9 +927,8 @@ def test_qwen3_next_step_compiles_for_one_chip_with_its_scopes_and_fits():
     lowered = manifest.family_module(config).lower_train_step(
         config, manifest.traffic_of(cell), topo().devices[:1])
     assert kernel_names(lowered.as_text()) == {
-        "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
-        "_bwd_dkv_kernel_chunked", "kernel", "_gdn_fwd_kernel",
-        "_gdn_bwd_kernel", "_rows_to_tokens_kernel",
+        "_fwd_kernel_chunked", "_bwd_kernel_chunked", "kernel",
+        "_gdn_fwd_kernel", "_gdn_bwd_kernel", "_rows_to_tokens_kernel",
         "_mixer_conv_fwd_kernel", "_mixer_conv_bwd_kernel",
         "_mixer_norm_fwd_kernel", "_mixer_norm_bwd_kernel"}
     sites = mixer_sites()
@@ -950,10 +947,11 @@ def test_qwen3_next_step_compiles_for_one_chip_with_its_scopes_and_fits():
     assert default_registry().peek_gauge(
         "attention/flash_residual_mb") == pytest.approx(135.3, abs=0.1)
     for scope in ("gdn_conv", "gdn_gates", "gdn_scan_prep", "gdn_scan_fwd",
-                  "gdn_scan_bwd", "gdn_out_norm", "attn_gate", "qk_norm", "moe_shared",
-                  "moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs", "moe_router",
-                  "moe_dispatch", "moe_combine", "flash_fwd_chunk",
-                  "flash_bwd_dq", "flash_bwd_dkv", "linear_attn", "attn",
+                  "gdn_scan_bwd", "gdn_out_norm", "attn_gate", "qk_norm",
+                  "moe_shared", "moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs",
+                  "moe_router", "moe_dispatch", "moe_combine",
+                  "flash_fwd_chunk",
+                  "flash_bwd_chunk", "flash_bwd_dq_sum", "linear_attn", "attn",
                   "mlp", "ds_loss_head", "ds_embed", "ds_optimizer",
                   # the held rows' way back, forward and backward: one kernel
                   "moe_combine/rows_to_tokens", "moe_dispatch/rows_to_tokens"):
@@ -1044,9 +1042,8 @@ def test_laguna_step_compiles_for_one_chip_with_its_scopes_and_fits():
     lowered = manifest.family_module(config).lower_train_step(
         config, manifest.traffic_of(cell), topo().devices[:1])
     assert kernel_names(lowered.as_text()) == {
-        "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
-        "_bwd_dkv_kernel_chunked", "kernel", "_swa_fwd_kernel",
-        "_swa_bwd_dq_kernel", "_swa_bwd_dkv_kernel",
+        "_fwd_kernel_chunked", "_bwd_kernel_chunked", "kernel",
+        "_swa_fwd_kernel", "_swa_bwd_dq_kernel", "_swa_bwd_dkv_kernel",
         "_rows_to_tokens_kernel"}
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
@@ -1061,9 +1058,10 @@ def test_laguna_step_compiles_for_one_chip_with_its_scopes_and_fits():
     assert default_registry().peek_gauge(
         "attention/flash_residual_mb") == pytest.approx(1226.8, abs=0.1)
     for scope in ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv", "flash_fwd_chunk",
-                  "flash_bwd_dq", "flash_bwd_dkv", "attn_gate", "dense_mlp",
-                  "moe_shared", "moe_gmm", "moe_gmm_dlhs", "moe_gmm_drhs",
-                  "moe_router", "moe_dispatch", "moe_combine", "attn", "mlp",
+                  "flash_bwd_chunk", "flash_bwd_dq_sum", "attn_gate",
+                  "dense_mlp", "moe_shared", "moe_gmm", "moe_gmm_dlhs",
+                  "moe_gmm_drhs", "moe_router", "moe_dispatch",
+                  "moe_combine", "attn", "mlp",
                   "ds_loss_head", "ds_embed", "ds_optimizer",
                   # the held rows' way back, forward and backward: one kernel
                   "moe_combine/rows_to_tokens", "moe_dispatch/rows_to_tokens"):
@@ -1075,7 +1073,7 @@ def test_kanana2_step_compiles_for_one_chip_with_its_scopes_and_fits():
     """The WHOLE step of the benchmark's ``kanana2-train-1chip-s16384`` cell
     (Kanana-2's layers 0-5 as one of 8 expert-parallel ranks, 1 x 16,384
     tokens, ZeRO-3, through the family's ``lower_train_step``) is accepted
-    for a 16 GB chip: latent attention in the three chunked causal kernels
+    for a 16 GB chip: latent attention in the two chunked causal kernels
     with K 192 wide and V 128 wide in every layer, nothing [S, S], every
     scope the benchmark reads in an ``op_name`` of the compiled text. ~1
     minute."""
@@ -1086,8 +1084,8 @@ def test_kanana2_step_compiles_for_one_chip_with_its_scopes_and_fits():
     lowered = manifest.family_module(config).lower_train_step(
         config, manifest.traffic_of(cell), topo().devices[:1])
     assert kernel_names(lowered.as_text()) == {
-        "_fwd_kernel_chunked", "_bwd_dq_kernel_chunked",
-        "_bwd_dkv_kernel_chunked", "kernel", "_rows_to_tokens_kernel"}
+        "_fwd_kernel_chunked", "_bwd_kernel_chunked", "kernel",
+        "_rows_to_tokens_kernel"}
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     assert 6.8e9 < ma.argument_size_in_bytes < 6.95e9     # 687.5M x 10 B
@@ -1096,15 +1094,15 @@ def test_kanana2_step_compiles_for_one_chip_with_its_scopes_and_fits():
     hlo = compiled.as_text()
     assert not re.search(r"all-gather|all-reduce|reduce-scatter", hlo)
     assert "16384,16384" not in hlo                       # no [S, S] array
-    # six layers x (forward, dq, dkv): the forward kernels run once, o and
+    # six layers x (forward, backward): the forward kernels run once, o and
     # lse are kept; K goes in 192 wide, V 128 wide, never padded to 192
     flash = [c for c in flash_calls(hlo) if "bf16[32,16384,192]" in c]
-    assert len(flash) == 18
+    assert len(flash) == 12
     assert all("bf16[32,16384,128]" in c for c in flash)
     assert hlo_text.rematted_forward_attention(hlo) == []
     assert default_registry().peek_gauge("attention/mla_qk_dim") == 192
     assert default_registry().peek_gauge("attention/mla_v_dim") == 128
-    for scope in ("flash_fwd_chunk", "flash_bwd_dq", "flash_bwd_dkv",
+    for scope in ("flash_fwd_chunk", "flash_bwd_chunk", "flash_bwd_dq_sum",
                   "mla_attn", "mla_latent", "mla_expand", "mla_rope",
                   "dense_mlp", "moe_shared", "moe_gmm", "moe_gmm_dlhs",
                   "moe_gmm_drhs", "moe_router", "moe_dispatch", "moe_combine",
