@@ -224,10 +224,14 @@ class LagunaAttention(nn.Module):
     (``hidden_size``, ``num_key_value_heads``, ``head_dim``,
     ``sliding_window``, ``gating``, ``use_flash``, ``dtype``,
     ``param_dtype``): ``models/smallthinker.SmallThinkerConfig`` is one.
-    ``rope[layer_type]`` ``None``: no rotation (a NoPE layer)."""
+    ``rope[layer_type]`` ``None``: no rotation (a NoPE layer). ``scale``:
+    what the scores are multiplied by ahead of the softmax (None: the
+    habit, ``head_dim ** -0.5``; granite-4.0-h's ``attention_multiplier``
+    is ``1 / head_dim``)."""
     config: LagunaConfig
     layer_type: str
     heads: int
+    scale: Optional[float] = None
 
     @nn.compact
     def __call__(self, x, rope):
@@ -246,7 +250,7 @@ class LagunaAttention(nn.Module):
                 [apply_rope(t[..., :rot], cos, sin), t[..., rot:]], axis=-1)
                 for t in (q, k))
         out = dot_product_attention(
-            q, k, v, causal=True, use_flash=cfg.use_flash,
+            q, k, v, causal=True, scale=self.scale, use_flash=cfg.use_flash,
             window=cfg.sliding_window if self.layer_type == SLIDING else None)
         out = out.transpose(0, 2, 1, 3)                     # [B, S, H, D]
         if cfg.gating:

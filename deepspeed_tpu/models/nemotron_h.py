@@ -207,7 +207,10 @@ class Mamba2Mixer(nn.Module):
     """The Mamba-2 branch: two projections round ``ssd_scan``, and round
     the scan the two elementwise stages of ``ops/mixer_elementwise.py``
     (convolution + SiLU before it, gate + grouped RMS norm after it), each
-    one pass over HBM where the kernels take the shapes."""
+    one pass over HBM where the kernels take the shapes. ``config`` is a
+    ``NemotronHConfig`` or any config with the attributes read here
+    (``models/granite_hybrid.GraniteHybridConfig`` is one); one that has an
+    ``a_log_init`` draws ``A_log`` with it."""
     config: NemotronHConfig
 
     @nn.compact
@@ -221,7 +224,8 @@ class Mamba2Mixer(nn.Module):
         dt = zxbcdt[..., d_inner + cfg.conv_dim:]
         taps = self.param("conv", _conv_init(cfg),
                           (cfg.conv_kernel, cfg.conv_dim), cfg.param_dtype)
-        a_log = self.param("A_log", _a_log_init, (H,), cfg.param_dtype)
+        a_log = self.param("A_log", getattr(cfg, "a_log_init", _a_log_init),
+                           (H,), cfg.param_dtype)
         dt_bias = self.param("dt_bias", _dt_bias_init(cfg), (H,),
                              cfg.param_dtype)
         skip = self.param("D", nn.initializers.ones, (H,), cfg.param_dtype)

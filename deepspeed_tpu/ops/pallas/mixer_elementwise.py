@@ -28,7 +28,13 @@ its own inputs:
   column offset from the projection's output, the gate BEFORE the norm
   (``norm(y silu(z)) w``) or AFTER it (``norm(y) w silu(z)``). Rows stay
   rows: a group is ``group / 128`` vreg columns of a row tile. The backward
-  kernel gives dy, dz and, resident across the row axis, dw.
+  kernel gives dy, dz and, resident across the row axis, dw. A group WIDER
+  than the widest column block (granite-4.0-h: one group of all 4,096
+  channels) is its own column block, of fewer rows, and a row tile walks
+  it in slabs TWICE: once for the row's sums (of squares; backward also of
+  the cotangent times the value), once more for the results, the gate
+  formed again from VMEM — a tile of 64 rows x 4,096 float32 columns is
+  1 MB, sixteen times the vreg file.
 
 Operands and results are the model's dtype in HBM; every product, sum,
 rsqrt and sigmoid is float32 in VMEM. The taps', bias's and norm weight's
@@ -71,6 +77,13 @@ class NormPlan(collections.namedtuple(
         "NormPlan", "B S D total offset group gate_first eps bs bc")):
     """A norm call's shapes: D columns in groups of ``group``, the gate the
     D columns at ``offset`` of ``total``."""
+
+    @property
+    def slab(self):
+        """Columns a row tile takes at once: a whole group, or the widest
+        column block that divides a group wider than any."""
+        return self.group if self.group <= _COLUMN_BLOCKS[0] \
+            else column_block(self.group)
 
 
 def row_block(S, limit=None):
@@ -322,22 +335,43 @@ def _gate(z):
     return z * s, s * (1.0 + z * (1.0 - s))
 
 
+def _group_slabs(group, plan):
+    """The column slices a row tile takes a group in: the group itself, or
+    the slabs of a group wider than any column block."""
+    return [slice(group.start + s.start, group.start + s.stop)
+            for s in _slabs(plan.group, plan.slab)]
+
+
+def _same(value, cols):
+    del cols
+    return value
+
+
 def _mixer_norm_fwd_kernel(y_ref, z_ref, w_ref, o_ref, *, plan):
     R = _ROWS
 
+    def gated(rows, cols):
+        """(the value the norm reads, the gate) of a slab."""
+        u = y_ref[0, rows, cols].astype(_F32)
+        gate, _ = _gate(z_ref[0, rows, cols].astype(_F32))
+        return (u * gate if plan.gate_first else u), gate
+
     def tile(t, carry):
         rows = pl.ds(pl.multiple_of(t * R, R), R)
-        for cols in _slabs(plan.bc, plan.group):
-            u = y_ref[0, rows, cols].astype(_F32)
-            gate, _ = _gate(z_ref[0, rows, cols].astype(_F32))
-            if plan.gate_first:
-                u = u * gate
-            r = jax.lax.rsqrt(jnp.mean(u * u, axis=1, keepdims=True)
-                              + plan.eps)
-            o = u * r * w_ref[:, cols]
-            if not plan.gate_first:
-                o = o * gate
-            o_ref[0, rows, cols] = o.astype(o_ref.dtype)
+        for group in _slabs(plan.bc, plan.group):
+            slabs = _group_slabs(group, plan)
+            load = functools.partial(gated, rows)
+            if len(slabs) == 1:     # its values stay in vregs between passes
+                load = functools.partial(_same, load(slabs[0]))
+            squares = sum(jnp.sum(u * u, axis=1, keepdims=True)
+                          for u, _ in map(load, slabs))
+            r = jax.lax.rsqrt(squares / plan.group + plan.eps)
+            for cols in slabs:
+                u, gate = load(cols)
+                o = u * r * w_ref[:, cols]
+                if not plan.gate_first:
+                    o = o * gate
+                o_ref[0, rows, cols] = o.astype(o_ref.dtype)
         return carry
 
     jax.lax.fori_loop(0, plan.bs // R, tile, 0)
@@ -351,28 +385,44 @@ def _mixer_norm_bwd_kernel(y_ref, z_ref, w_ref, do_ref, dy_ref, dz_ref,
     def _():
         dw_ref[...] = jnp.zeros_like(dw_ref)
 
+    def slab(rows, cols):
+        """(y, the gate and its derivative, do, w, the value the norm
+        reads, the cotangent of its result) of a slab."""
+        y = y_ref[0, rows, cols].astype(_F32)
+        do = do_ref[0, rows, cols].astype(_F32)
+        gate, dgate = _gate(z_ref[0, rows, cols].astype(_F32))
+        w = w_ref[:, cols]
+        if plan.gate_first:
+            return y, gate, dgate, do, w, y * gate, do * w
+        return y, gate, dgate, do, w, y, do * w * gate
+
     def tile(t, carry):
         rows = pl.ds(pl.multiple_of(t * R, R), R)
-        for cols in _slabs(plan.bc, plan.group):
-            y = y_ref[0, rows, cols].astype(_F32)
-            z = z_ref[0, rows, cols].astype(_F32)
-            do = do_ref[0, rows, cols].astype(_F32)
-            gate, dgate = _gate(z)
-            w = w_ref[:, cols]
-            u = y * gate if plan.gate_first else y
-            r = jax.lax.rsqrt(jnp.mean(u * u, axis=1, keepdims=True)
-                              + plan.eps)
-            n = u * r
-            # a: the cotangent of n;  du = r (a - n mean(a n))
-            a = do * w if plan.gate_first else do * w * gate
-            du = r * (a - n * jnp.mean(a * n, axis=1, keepdims=True))
-            if plan.gate_first:
-                dy, dz, dw = du * gate, du * y * dgate, do * n
-            else:
-                dy, dz, dw = du, do * w * n * dgate, do * n * gate
-            dy_ref[0, rows, cols] = dy.astype(dy_ref.dtype)
-            dz_ref[0, rows, cols] = dz.astype(dz_ref.dtype)
-            dw_ref[0, :, cols] += _fold(dw)
+        for group in _slabs(plan.bc, plan.group):
+            slabs = _group_slabs(group, plan)
+            load = functools.partial(slab, rows)
+            if len(slabs) == 1:     # its values stay in vregs between passes
+                load = functools.partial(_same, load(slabs[0]))
+            # the row's sums over the whole group: u^2 and a u
+            squares = dots = 0.0
+            for *_, u, a in map(load, slabs):
+                squares += jnp.sum(u * u, axis=1, keepdims=True)
+                dots += jnp.sum(a * u, axis=1, keepdims=True)
+            r = jax.lax.rsqrt(squares / plan.group + plan.eps)
+            # mean(a n) r = mean(a u) r^2, n = u r the norm's result
+            back = dots / plan.group * (r * r)
+            for cols in slabs:
+                y, gate, dgate, do, w, u, a = load(cols)
+                n = u * r
+                # a: the cotangent of n;  du = r (a - n mean(a n))
+                du = r * a - n * back
+                if plan.gate_first:
+                    dy, dz, dw = du * gate, du * y * dgate, do * n
+                else:
+                    dy, dz, dw = du, do * w * n * dgate, do * n * gate
+                dy_ref[0, rows, cols] = dy.astype(dy_ref.dtype)
+                dz_ref[0, rows, cols] = dz.astype(dz_ref.dtype)
+                dw_ref[0, :, cols] += _fold(dw)
         return carry
 
     jax.lax.fori_loop(0, plan.bs // R, tile, 0)
@@ -432,21 +482,38 @@ def _norm_rule(plan, interpret):
     return rule
 
 
+# elements of a norm call's block at most: the widest column block at the
+# longest row block, which a wider group's block keeps with fewer rows
+_NORM_BLOCK = _ROW_BLOCKS[0] * _COLUMN_BLOCKS[0]
+
+
 def norm_block(D, offset, group):
     """The widest column block of whole groups that divides D and the
-    gate's offset (None: no block does)."""
+    gate's offset; a group wider than every column block is its own block
+    (None: no block does)."""
+    if group > _COLUMN_BLOCKS[0]:
+        return group if D % group == 0 and offset % group == 0 else None
     return next((b for b in _COLUMN_BLOCKS
                  if group and b % group == 0 and D % b == 0
                  and offset % b == 0), None)
 
 
+def norm_row_block(S, bc, limit=None):
+    """The row block of a norm call whose column block is ``bc``: the
+    largest that divides S and keeps the block within ``_NORM_BLOCK``."""
+    rows = _NORM_BLOCK // bc
+    return row_block(S, rows if limit is None else min(rows, limit))
+
+
 def norm_takes(S, D, total, offset, group, block_rows=None):
-    """Whether the norm kernels take the call: groups of whole vreg columns
-    inside one column block, whole column blocks at the gate's offset, a
-    row block that divides S."""
-    return (row_block(S, block_rows) is not None and group % LANES == 0
-            and norm_block(D, offset, group) is not None
-            and offset + D <= total)
+    """Whether the norm kernels take the call: groups of whole vreg
+    columns, each inside one column block or a column block itself, whole
+    column blocks at the gate's offset, a row block that divides S."""
+    if not group or group % LANES:
+        return False
+    bc = norm_block(D, offset, group)
+    return (bc is not None and offset + D <= total
+            and norm_row_block(S, bc, block_rows) is not None)
 
 
 def gated_group_norm_kernel(y, z, w, *, group, eps, gate_first, offset=0,
@@ -456,8 +523,8 @@ def gated_group_norm_kernel(y, z, w, *, group, eps, gate_first, offset=0,
     applied to y before the norm (``gate_first``) or to its result; y's
     dtype."""
     B, S, D = y.shape
+    bc = norm_block(D, offset, group)
     plan = NormPlan(B, S, D, z.shape[2], offset, group, bool(gate_first),
-                    float(eps), row_block(S, block_rows),
-                    norm_block(D, offset, group))
+                    float(eps), norm_row_block(S, bc, block_rows), bc)
     return _norm_rule(plan, bool(interpret))(
         y, z, w.astype(_F32).reshape(1, D))

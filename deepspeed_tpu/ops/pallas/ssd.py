@@ -2,15 +2,20 @@
 forward and backward.
 
 ``ops/ssd.py`` has the recurrence, the chunked form and its XLA oracle. Here
-one program owns a (batch row, GROUP of heads) and walks that sequence's
-chunks in order with the state of each of the group's heads in float32 VMEM
-scratch. Per chunk it forms ``C B^T`` once for the group, and for every head
-the decay mask ``L`` [c, c] in VMEM from the chunk's gates; nothing of
-[c, c] size goes to HBM.
+one program owns a (batch row, group, HEAD BLOCK: at most ``_BLOCK_HEADS``
+of the group's heads) and walks that sequence's chunks in order with the
+state of each of its heads in float32 VMEM scratch. Per chunk it forms
+``C B^T`` once for the head block, and for every head the decay mask ``L``
+[c, c] in VMEM from the chunk's gates; nothing of [c, c] size goes to HBM.
+A group of 8 heads (Nemotron-3-Nano: 8 groups of 8) is ONE head block; a
+group of all 64 heads (granite-4.0-h: ``mamba_n_groups 1``) is 8, each
+reading the group's one B and C, so that a grid step's operand blocks are
+the size they are at 8 heads whatever the group's width.
 
 - **Layout.** x, y [B, S, H*P] and B, C [B, S, G*N] are the model's own
   arrays (a reshape of [B, S, H, P]); a program takes the lane columns of
-  its group. Heads narrower than a vreg's 128 lanes sit SIDE BY SIDE:
+  its head block and of its group's B and C. Heads narrower than a vreg's
+  128 lanes sit SIDE BY SIDE:
   ``pack = 128 // P`` heads make one lane block (two at P = 64), the state
   of a block is [N, pack * P], and the three products that are not masked a
   head — ``C S``, ``B^T (dt x)``, and their transposes backward — run at
@@ -18,8 +23,9 @@ the decay mask ``L`` [c, c] in VMEM from the chunk's gates; nothing of
   ``(L o C B^T) (dt x)`` runs once a head against the block with the other
   heads' lanes zeroed. The gates are laid out first, a token a lane:
   ``dt`` and ``a`` (the running sum of ``dt A`` inside each chunk) as
-  [B, G, n, H / G, c] float32 (4 MB each at 16,384 tokens, 64 heads); a
-  chunk's tile is transposed in VMEM where a token a sublane is needed.
+  [B, head blocks, n, heads a block, c] float32 (4 MB each at 16,384
+  tokens, 64 heads); a chunk's tile is transposed in VMEM where a token a
+  sublane is needed.
 - **Roundings** are the XLA form's: state, gates and ``L`` float32; the
   masked ``C B^T``, ``dt x`` and the state cast to the inputs' dtype before
   their matmuls, float32 accumulation.
@@ -30,8 +36,10 @@ the decay mask ``L`` [c, c] in VMEM from the chunk's gates; nothing of
   tokens, 64 heads of 64 x 128), alive from a layer's recomputation to its
   backward pass, and nothing else. The backward kernel walks the chunks in
   REVERSE with ``dS`` in VMEM, forms the chunk's masks again and writes dx,
-  dB, dC (summed over a group's heads in float32), ddt, da and, accumulated
-  over the chunks, a head's ``sum dy x`` for dD.
+  dB, dC (summed over the head block's heads in float32; where a group is
+  several head blocks each writes its own float32 part, [B, head blocks a
+  group, S, G*N], and the parts are added under ``ssd_scan_prep``), ddt, da
+  and, accumulated over the chunks, a head's ``sum dy x`` for dD.
 
 The XLA ops left round the kernels (the gates' re-layout and the running
 sum) are traced under ``ssd_scan_prep``.
@@ -51,17 +59,17 @@ from deepspeed_tpu.utils.logging import logger
 
 # chunks a grid step
 _BLOCK_CHUNKS = 8
+# heads a grid step at most: one float32 tile's sublanes of the gates, and
+# the operand blocks the plan was measured with (Nemotron's groups of 8)
+_BLOCK_HEADS = 8
 _NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 _F32 = jnp.float32
 
 
-class _Plan(collections.namedtuple("_Plan", "B H G P N C cb nb pack")):
+class _Plan(collections.namedtuple("_Plan", "B H G P N C cb nb pack hg")):
     """What a call's shapes decide: chunk C, cb chunks a grid step, nb grid
-    steps a sequence, pack heads a lane block."""
-
-    @property
-    def hg(self):
-        return self.H // self.G          # heads a group (a grid step)
+    steps a sequence, pack heads a lane block, hg heads a grid step (a
+    group's, or a head block of them)."""
 
     @property
     def W(self):
@@ -69,29 +77,42 @@ class _Plan(collections.namedtuple("_Plan", "B H G P N C cb nb pack")):
 
     @property
     def blocks(self):
-        return self.hg // self.pack      # lane blocks a group
+        return self.hg // self.pack      # lane blocks a grid step
+
+    @property
+    def head_blocks(self):
+        return self.H // self.G // self.hg   # head blocks a group
+
+    @property
+    def programs(self):
+        return self.G * self.head_blocks     # (group, head block) pairs
 
 
 def _plan_for(B, S, H, G, P, N, C):
     n = -(-S // C)
     cb = next(c for c in (_BLOCK_CHUNKS, 4, 2, 1) if n % c == 0)
-    pack = 2 if 2 * P == 128 and (H // G) % 2 == 0 else 1
-    return _Plan(B, H, G, P, N, C, cb, n // cb, pack)
+    heads = H // G
+    pack = 2 if 2 * P == 128 and heads % 2 == 0 else 1
+    # a group of up to ``_BLOCK_HEADS`` heads is one head block; a wider one
+    # is cut into the widest blocks of whole lane blocks that divide it
+    hg = heads if heads <= _BLOCK_HEADS else next(
+        h for h in range(_BLOCK_HEADS, 0, -1)
+        if heads % h == 0 and h % pack == 0)
+    return _Plan(B, H, G, P, N, C, cb, n // cb, pack, hg)
 
 
 def takes_kernel(H, P, G, N, chunk, tpu):
     """Whether the kernels take ``H`` heads of ``P`` channels in ``G``
     groups with a state of ``N``: on a TPU backend a lane block (a head, or
-    two heads of 64) and the state must be whole vregs wide, a group's
-    heads fit the sublanes of the gates' tile and the chunk is square with
-    a vreg's lanes; the interpreter (any other backend) takes any shape
-    whose heads divide into the groups."""
+    two heads of 64) and the state must be whole vregs wide and the chunk
+    square with a vreg's lanes (a group of any width is cut into head
+    blocks, ``_plan_for``); the interpreter (any other backend) takes any
+    shape whose heads divide into the groups."""
     if H % G:
         return False
-    hg = H // G
     return not tpu or (
-        N % 128 == 0 and chunk == 128 and hg <= chunk
-        and (P % 128 == 0 or (P == 64 and hg % 2 == 0)))
+        N % 128 == 0 and chunk == 128
+        and (P % 128 == 0 or (P == 64 and (H // G) % 2 == 0)))
 
 
 def _dot(a, b, dims=_NN):
@@ -282,8 +303,11 @@ def _ssd_bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, d_ref, st_ref, dy_ref,
             dx_ref[0, rows, lanes] = (dxdt * dt + dyf
                                       * d_ref[:, lanes]).astype(dtype)
         dCB_b = dCB.astype(dtype)
-        db_ref[0, rows, :] = (dB + _dot(dCB_b, Cm, _TN)).astype(dtype)
-        dc_ref[0, rows, :] = (dC + _dot(dCB_b, Bm)).astype(dtype)
+        # the inputs' dtype where the group is this one head block, else
+        # this block's float32 part of the group's sum
+        db_ref[0, 0, rows, :] = (dB + _dot(dCB_b, Cm, _TN)).astype(
+            db_ref.dtype)
+        dc_ref[0, 0, rows, :] = (dC + _dot(dCB_b, Bm)).astype(dc_ref.dtype)
         ddt_ref[0, 0, i] = _rows_of(ddt_cols, hg)
         da_ref[0, 0, i] = _rows_of(da_cols, hg) \
             - jnp.concatenate(da_rows, axis=0)
@@ -296,26 +320,33 @@ def _ssd_bwd_kernel(x_ref, b_ref, c_ref, dt_ref, a_ref, d_ref, st_ref, dy_ref,
 
 def _specs(plan, reverse=False):
     """BlockSpecs of (x or y, B or C, a gate array, D, the kept states, the
-    dD accumulator) for a grid of (batch row x group, block of chunks)."""
-    G, C, cb, nb = plan.G, plan.C, plan.cb, plan.nb
+    dD accumulator, dB or dC) for a grid of (batch row x group x head
+    block, block of chunks). Program p of a batch row owns head block
+    ``p % head_blocks`` of group ``p // head_blocks``: the heads' lane
+    columns [p * hg * P, (p + 1) * hg * P) — a group's heads lie side by
+    side — and the group's columns of B and C."""
+    C, cb, nb, Q, HB = plan.C, plan.cb, plan.nb, plan.programs, \
+        plan.head_blocks
 
     def at(n):
         return nb - 1 - n if reverse else n
 
     def rows(p, n):
-        return p // G, at(n), p % G
+        return p // Q, at(n), p % Q
 
     def heads(p, n):
-        return p // G, p % G, at(n), 0, 0
+        return p // Q, p % Q, at(n), 0, 0
 
     return (pl.BlockSpec((1, cb * C, plan.hg * plan.P), rows),
-            pl.BlockSpec((1, cb * C, plan.N), rows),
+            pl.BlockSpec((1, cb * C, plan.N),
+                         lambda p, n: (p // Q, at(n), p % Q // HB)),
             pl.BlockSpec((1, 1, cb, plan.hg, C), heads),
-            pl.BlockSpec((1, plan.hg * plan.P), lambda p, n: (0, p % G)),
-            pl.BlockSpec((1, plan.blocks, cb, plan.N, plan.W),
-                         lambda p, n: (p // G, p % G, at(n), 0, 0)),
+            pl.BlockSpec((1, plan.hg * plan.P), lambda p, n: (0, p % Q)),
+            pl.BlockSpec((1, plan.blocks, cb, plan.N, plan.W), heads),
             pl.BlockSpec((1, 1, plan.hg * plan.P),
-                         lambda p, n: (p // G, 0, p % G)))
+                         lambda p, n: (p // Q, 0, p % Q)),
+            pl.BlockSpec((1, 1, cb * C, plan.N),
+                         lambda p, n: (p // Q, p % HB, at(n), p % Q // HB)))
 
 
 def _call(kernel, plan, interpret, **kw):
@@ -325,7 +356,7 @@ def _call(kernel, plan, interpret, **kw):
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=64 * 2 ** 20)}
     return pl.pallas_call(
-        kernel, grid=(plan.B * plan.G, plan.nb),
+        kernel, grid=(plan.B * plan.programs, plan.nb),
         scratch_shapes=[pltpu.VMEM((plan.blocks, plan.N, plan.W), _F32)],
         **how, **kw)
 
@@ -338,7 +369,7 @@ def _states_shape(plan):
 
 def _forward(x, Bm, Cm, dt, a, D, plan, interpret, keep):
     """y, or with ``keep`` (y, every chunk's starting states)."""
-    xy, bc, gate, d, states, _ = _specs(plan)
+    xy, bc, gate, d, states, _, _ = _specs(plan)
     shapes = (jax.ShapeDtypeStruct(x.shape, x.dtype), _states_shape(plan))
     with annotate("ssd_scan_fwd"):
         return _call(
@@ -349,20 +380,25 @@ def _forward(x, Bm, Cm, dt, a, D, plan, interpret, keep):
 
 
 def _backward(x, Bm, Cm, dt, a, D, states, dy, plan, interpret):
-    xy, bc, gate, d, st, dd = _specs(plan, reverse=True)
+    xy, bc, gate, d, st, dd, dbc = _specs(plan, reverse=True)
     like = lambda t, dtype=None: jax.ShapeDtypeStruct(  # noqa: E731
         t.shape, dtype or t.dtype)
+    # a head block's part of dB / dC: float32 where a group has several
+    parts = jax.ShapeDtypeStruct(
+        (plan.B, plan.head_blocks) + Bm.shape[1:],
+        Bm.dtype if plan.head_blocks == 1 else _F32)
     with annotate("ssd_scan_bwd"):
         dx, dB, dC, ddt, da, dDx = _call(
             functools.partial(_ssd_bwd_kernel, plan=plan), plan, interpret,
             in_specs=[xy, bc, bc, gate, gate, d, st, xy],
-            out_specs=(xy, bc, bc, gate, gate, dd),
-            out_shape=(like(x), like(Bm), like(Cm), like(dt), like(a),
+            out_specs=(xy, dbc, dbc, gate, gate, dd),
+            out_shape=(like(x), parts, parts, like(dt), like(a),
                        jax.ShapeDtypeStruct((plan.B, 1, plan.H * plan.P),
                                             _F32)))(
             x, Bm, Cm, dt, a, D, states, dy)
     with annotate("ssd_scan_prep"):
         dD = jnp.sum(dDx, axis=0)       # over the batch rows; D is a lane
+        dB, dC = (jnp.sum(t, axis=1).astype(Bm.dtype) for t in (dB, dC))
     return dx, dB, dC, ddt, da, dD
 
 
@@ -390,10 +426,12 @@ _plans_logged = set()
 
 
 def _note_plan(plan, dtype, interpret):
-    """Trace-time engagement record: the gauge
-    ``ssm/ssd_kernel_heads_per_step`` and, once per distinct shape, a log
-    line."""
+    """Trace-time engagement record: the gauges
+    ``ssm/ssd_kernel_heads_per_step`` and ``ssm/ssd_head_blocks_per_group``
+    and, once per distinct shape, a log line."""
     default_registry().gauge("ssm/ssd_kernel_heads_per_step").set(plan.hg)
+    default_registry().gauge("ssm/ssd_head_blocks_per_group").set(
+        plan.head_blocks)
     key = (plan, jnp.dtype(dtype).name, interpret)
     if key not in _plans_logged:
         _plans_logged.add(key)
@@ -401,18 +439,20 @@ def _note_plan(plan, dtype, interpret):
             f"state-space scan S={plan.nb * plan.cb * plan.C} H={plan.H} "
             f"P={plan.P} G={plan.G} N={plan.N} {key[1]}: Pallas kernels on "
             f"[B, S, H*P] column blocks, chunk={plan.C}, {plan.hg} heads a "
-            f"grid step ({plan.pack} a lane block), {plan.cb} chunks a grid "
+            f"grid step ({plan.pack} a lane block, {plan.head_blocks} head "
+            f"block(s) a group), {plan.cb} chunks a grid "
             f"step, a float32 state kept every chunk for the backward pass"
             f"{' (interpreter)' if interpret else ''}")
 
 
 def gate_layout(t, plan):
-    """[B, S, H] -> [B, G, n, H / G, C] float32, S padded to whole chunks
-    (with zeros: a padded token's ``dt`` is 0)."""
+    """[B, S, H] -> [B, H / hg, n, hg, C] float32 (a head block's heads
+    together), S padded to whole chunks (with zeros: a padded token's
+    ``dt`` is 0)."""
     B, S, H = t.shape
     n = plan.nb * plan.cb
     t = jnp.pad(t.astype(_F32), ((0, 0), (0, n * plan.C - S), (0, 0)))
-    t = t.transpose(0, 2, 1).reshape(B, plan.G, plan.hg, n, plan.C)
+    t = t.transpose(0, 2, 1).reshape(B, plan.programs, plan.hg, n, plan.C)
     return t.transpose(0, 1, 3, 2, 4)
 
 
@@ -431,8 +471,8 @@ def ssd_scan_kernel(x, dt, A, B, C, D, chunk, interpret):
         x = x.reshape(Bt, padded, H * P)
         B, C = (t.reshape(Bt, padded, G * N).astype(x.dtype) for t in (B, C))
         dt = gate_layout(dt, plan)
-        a = jnp.cumsum(dt * A.astype(_F32).reshape(1, G, 1, plan.hg, 1),
-                       axis=-1)
+        a = jnp.cumsum(dt * A.astype(_F32).reshape(
+            1, plan.programs, 1, plan.hg, 1), axis=-1)
         D = jnp.repeat(D.astype(_F32), P)[None]             # [1, H*P]
     y = _rule(plan, bool(interpret))(x, B, C, dt, a, D)
     with annotate("ssd_scan_prep"):

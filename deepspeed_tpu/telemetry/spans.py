@@ -166,6 +166,11 @@ def annotate(tag):
       causal depthwise convolution with bias and its SiLU; the softplus of
       the steps and the decay; the gate and the grouped RMSNorm): with
       ``ssd_scan*`` and the module name ``mamba``, ``ssm_layer_ms``;
+      ``ssm_norm`` alone (models/granite_hybrid.py runs the same mixer with
+      ONE group of all channels), ``ssm_norm_roofline``;
+    - ``shared_mlp`` (models/granite_hybrid.py: a flax module name, the
+      dense SwiGLU of every layer; the model's four multipliers fold into
+      the operations beside them and have no scope): ``dense_mlp_ms``;
     - ``mixer_conv_fwd``, ``mixer_conv_bwd``, ``mixer_norm_fwd``,
       ``mixer_norm_bwd`` (ops/pallas/mixer_elementwise.py, round the four
       ``pallas_call``s of the mixers' elementwise stages, INSIDE

@@ -120,6 +120,16 @@ def test_conv_kernel_against_xla_and_reference(dtype, B, S, rows, bias, l2,
         ("float32", 1, 256, 128, 128, True, 0, 128, 128, False),   # two tiles
         ("bfloat16", 1, 128, 64, 128, False, 256, 512, 256, True),
         ("bfloat16", 2, 128, 64, 512, True, 0, 600, 512, False),
+        # a group wider than the widest column block is its own block, its
+        # row tile walked in slabs of 512 twice: granite-4.0-h's ONE group
+        # of all 4,096 channels, gate first, z the first columns of 8,512
+        ("float32", 1, 128, 64, 4096, True, 0, 8512, 4096, False),
+        ("bfloat16", 1, 128, 64, 4096, True, 0, 8512, 4096, False),
+        # two groups of 1,024 (two column blocks), the gate at an offset
+        ("float32", 2, 128, 128, 1024, True, 2048, 4224, 2048, False),
+        # the gate after the norm, a group of 640: slabs of 128
+        ("float32", 1, 64, 64, 640, False, 640, 1280, 640, True),
+        ("bfloat16", 1, 64, 64, 1024, False, 1024, 2048, 1024, True),
     ])
 def test_norm_kernel_against_xla_and_reference(dtype, B, S, rows, group,
                                                gate_first, offset, total, D,
@@ -217,6 +227,29 @@ def test_conv_refused_shape_takes_the_xla_form(what, kw):
     after = sites()
     assert after["conv_xla_sites"] == before.get("conv_xla_sites", 0) + 1
     assert after["conv_kernel_sites"] == before.get("conv_kernel_sites", 0)
+
+
+def test_a_wide_group_is_its_own_column_block_of_fewer_rows():
+    """The plan at granite-4.0-h-micro's shape (one group of 4,096 at 16,384
+    tokens) against Nemotron's (8 groups of 512), which stays as it was."""
+    assert kernels.norm_block(4096, 0, 512) == 512
+    assert kernels.norm_row_block(16384, 512) == 1024
+    assert kernels.norm_block(4096, 0, 4096) == 4096
+    assert kernels.norm_row_block(16384, 4096) == 128
+    assert kernels.norm_block(2048, 2048, 1024) == 1024
+    assert kernels.norm_row_block(16384, 1024) == 512
+    assert kernels.norm_takes(16384, 4096, 8512, 0, 4096)
+    assert kernels.norm_takes(16384, 4096, 10304, 0, 512)
+    plan = kernels.NormPlan(1, 16384, 4096, 8512, 0, 4096, True, 1e-5,
+                            128, 4096)
+    assert plan.slab == 512
+    assert kernels.NormPlan(1, 64, 640, 1280, 640, 640, False, 1e-5, 64,
+                            640).slab == 128
+    # a group of three vreg columns inside a column block: one pass, whole
+    assert kernels.NormPlan(1, 64, 384, 384, 0, 384, True, 1e-5, 64,
+                            384).slab == 384
+    # a gate at an offset that is no multiple of the wide group
+    assert not kernels.norm_takes(16384, 4096, 8512, 512, 4096)
 
 
 @pytest.mark.parametrize("what,kw", [

@@ -395,6 +395,10 @@ def test_trains_through_the_engine_under_zero3_with_remat():
     # moves nothing in five
     del config["train"]["engine"]["scheduler"]
     ids = np.random.default_rng(1).integers(0, 512, (2, 48)).astype(np.int32)
+    # the registry is the process's: another file's model in this worker
+    # may have left a gauge this model must not set
+    from deepspeed_tpu.telemetry.registry import default_registry
+    default_registry().reset()
     engine, params = fam.build_train(config, 2, 0, jax.devices()[:2], True)
     bias = np.asarray(params["layer_1"]["mixer"]["e_score_correction_bias"])
     router = np.asarray(params["layer_1"]["mixer"]["router"])

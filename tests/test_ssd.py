@@ -45,6 +45,11 @@ SHAPES = {
     "ragged_tail": (1, 40, 4, 8, 2, 16, 16),
     "two_heads_a_lane_block": (1, 32, 4, 64, 2, 16, 16),
     "one_group_serves_every_head": (1, 32, 4, 8, 1, 16, 8),
+    # granite-4.0-h's ``mamba_n_groups 1``: ONE B, C for all heads, the
+    # group cut into head blocks of 8 whose dB / dC parts are summed
+    "one_group_of_64_heads_in_8_head_blocks": (1, 32, 64, 8, 1, 16, 16),
+    "one_group_of_16_heads_two_a_lane_block": (2, 24, 16, 64, 1, 8, 8),
+    "two_groups_of_12_heads_in_blocks_of_6": (1, 16, 24, 8, 2, 8, 8),
 }
 
 
@@ -117,6 +122,7 @@ def test_the_dispatcher_takes_the_kernel_off_the_tpu_and_gauges_it():
     assert default_registry().peek_gauge("ssm/ssd_kernel_heads_per_step") == 0
     takes = kernels.takes_kernel
     assert takes(64, 64, 8, 128, 128, tpu=True)         # the published layer
+    assert takes(64, 64, 1, 128, 128, tpu=True)         # granite: one group
     assert takes(8, 128, 8, 128, 128, tpu=True)
     assert not takes(64, 64, 64, 128, 128, tpu=True)    # one head of 64
     assert not takes(64, 64, 8, 64, 128, tpu=True)      # half a vreg of state
@@ -134,3 +140,30 @@ def test_the_plan_packs_two_heads_of_64_and_keeps_a_state_a_chunk():
     assert kept.shape == (1, 32, 128, 128, 128) and kept.dtype == jnp.float32
     assert np.prod(kept.shape) * 4 == 268_435_456      # 268 MB a layer
     assert kernels._plan_for(1, 40, 4, 2, 8, 16, 16).pack == 1
+    # one head block a group: the grid and the blocks of before the head
+    # blocks existed
+    assert (plan.head_blocks, plan.programs) == (1, 8)
+
+
+@pytest.mark.parametrize("heads,want", [
+    (64, (8, 8, 8)), (16, (8, 2, 2)), (8, (8, 1, 1)), (24, (8, 3, 3)),
+    (12, (6, 2, 2)), (14, (2, 7, 7))], ids=lambda v: str(v))
+def test_a_wide_group_is_cut_into_head_blocks(heads, want):
+    """One group of ``heads`` heads of 64 (two a lane block): (heads a grid
+    step, head blocks a group, programs a batch row). granite-4.0-h-micro
+    is the first row: the operand blocks of Nemotron's 8 groups of 8."""
+    plan = kernels._plan_for(1, 16384, heads, 1, 64, 128, 128)
+    assert (plan.hg, plan.head_blocks, plan.programs) == want
+    assert plan.hg % plan.pack == 0 and plan.hg <= 8
+    assert kernels._states_shape(plan).shape == (
+        1, heads // 2, 128, 128, 128)
+
+
+def test_the_head_blocks_of_one_group_are_gauged():
+    from deepspeed_tpu.telemetry.registry import default_registry
+    args = _inputs(1, 16, 16, 8, 1, 8, 1.0)
+    got = ssd.ssd_scan(*args, chunk=8)
+    peek = default_registry().peek_gauge
+    assert peek("ssm/ssd_kernel_heads_per_step") == 8
+    assert peek("ssm/ssd_head_blocks_per_group") == 2
+    np.testing.assert_allclose(got, ssd.ssd_recurrence(*args), atol=2e-5)

@@ -761,13 +761,19 @@ def test_gated_delta_rule_fwd_and_grad_compile_at_qwen3_next_shape(
         2e9 if kernels else 8e9)
 
 
-def test_ssd_scan_fwd_and_grad_compile_at_nemotron_h_shape():
+@pytest.mark.parametrize("groups,head_blocks", [(8, 1), (1, 8)],
+                         ids=["nemotron_h_8_groups", "granite_one_group"])
+def test_ssd_scan_fwd_and_grad_compile_at_nemotron_h_shape(groups,
+                                                           head_blocks):
     """1 x 16,384 tokens, 64 heads of 64 in 8 groups, state 128, chunk 128,
     bf16 operands and float32 gates (the ``nemotron3nano-train-1chip-s16384``
     cell's Mamba-2 layer), forward and backward under the scopes the
     benchmark's ``ssd_scan_*`` readers sum: two heads side by side a lane
     block, a group's eight heads a grid step; what is kept for the backward
-    pass is a float32 state a chunk (268 MB) and nothing of [c, c] size."""
+    pass is a float32 state a chunk (268 MB) and nothing of [c, c] size.
+    And the same heads in ONE group (``granite4hmicro-train-1chip-s16384``):
+    eight head blocks of 8 read the group's one B and C, and their float32
+    parts of dB and dC (8 x 8 MB each) are summed outside the kernel."""
     from deepspeed_tpu.ops.ssd import ssd_scan
 
     def scan(*a):
@@ -779,11 +785,18 @@ def test_ssd_scan_fwd_and_grad_compile_at_nemotron_h_shape():
 
     text, compiled = compile_on_chip(
         grads, SDS((1, 16384, 64, 64), BF16), SDS((1, 16384, 64), F32),
-        SDS((64,), F32), SDS((1, 16384, 8, 128), BF16),
-        SDS((1, 16384, 8, 128), BF16), SDS((64,), F32))
+        SDS((64,), F32), SDS((1, 16384, groups, 128), BF16),
+        SDS((1, 16384, groups, 128), BF16), SDS((64,), F32))
     assert kernel_names(text) == {"_ssd_fwd_kernel", "_ssd_bwd_kernel"}
     assert default_registry().peek_gauge(
         "ssm/ssd_kernel_heads_per_step") == 8
+    assert default_registry().peek_gauge(
+        "ssm/ssd_head_blocks_per_group") == head_blocks
+    grids = pallas_grids(grads, *(SDS(*a) for a in (
+        ((1, 16384, 64, 64), BF16), ((1, 16384, 64), F32), ((64,), F32),
+        ((1, 16384, groups, 128), BF16), ((1, 16384, groups, 128), BF16),
+        ((64,), F32))))
+    assert set(grids) == {(8, 16)}, grids       # 8 programs x 16 steps
     hlo = compiled.as_text()
     for scope in ("ssd_scan_prep/", "ssd_scan_fwd/", "ssd_scan_bwd/"):
         assert re.search(r'op_name="[^"]*/' + scope, hlo), scope
@@ -826,13 +839,21 @@ def mixer_calls(hlo):
     ("norm", "ssm_norm", (SDS((1, 16384, 4096), BF16),
                           SDS((1, 16384, 10304), BF16), SDS((4096,), F32)),
      dict(group=512, eps=1e-5, gate_first=True)),
+    ("conv", "ssm_conv", (SDS((1, 16384, 8512), BF16), SDS((4, 4352), F32),
+                          SDS((4352,), F32)),
+     dict(offset=4096, runs=((4096, None), (128, None), (128, None)))),
+    ("norm", "ssm_norm", (SDS((1, 16384, 4096), BF16),
+                          SDS((1, 16384, 8512), BF16), SDS((4096,), F32)),
+     dict(group=4096, eps=1e-5, gate_first=True)),
 ], ids=["qwen3_next_conv", "nemotron_conv", "qwen3_next_norm",
-        "nemotron_norm"])
+        "nemotron_norm", "granite_conv", "granite_norm_one_group_of_4096"])
 def test_mixer_elementwise_fwd_and_grad_compile_at_the_cells_shapes(
         stage, scope, shapes, kw):
     """The two recurrent mixers' elementwise stages at the shapes of the
     ``qwen3next-train-1chip-s8192`` and ``nemotron3nano-train-1chip-s16384``
-    cells, bf16 in and out, forward and every gradient: the Pallas kernels
+    cells (and of ``granite4hmicro-train-1chip-s16384``: ONE B and C of
+    128 columns, the norm over one group of all 4,096 channels), bf16 in
+    and out, forward and every gradient: the Pallas kernels
     take the call (columns read by offset out of the wide projection, no
     slice of it formed), under the scope the model puts round them."""
     from deepspeed_tpu.ops import mixer_elementwise as mixer
