@@ -517,10 +517,14 @@ def chunked_lm_loss(hidden, wte, labels, chunk, ignore_index=-100):
     """Fused LM head + next-token cross entropy without a [B, S, V] buffer.
 
     Scans over chunks of ``chunk`` tokens; each chunk projects [C, E] @
-    [E, V] and reduces to per-token nll immediately. The chunk body is
-    rematerialized, so backward recomputes each chunk's logits instead of
-    saving them — one extra head matmul per step (~1-2% of model flops)
-    buys back >1 GB of f32 logsumexp temporaries at GPT-2 vocab sizes.
+    [E, V] and reduces to per-token nll immediately, so no [C, V] logits
+    outlive their chunk. Differentiated, the scan forms its gradient where
+    it forms its logits (``_chunk_scan_fwd``): cross entropy's gradient with
+    respect to the logits is ``softmax - onehot``, known the moment the
+    logits are, so the same chunk computes dlogits, ``dlogits @ wte`` and
+    ``dlogitsᵀ @ h`` and hands them to the backward pass, which only scales
+    them by the loss's cotangent. The head costs three matmuls a step (the
+    mathematics' own count) and the backward pass derives no logits again.
 
     Matches ``lm_loss(logits, labels)`` to fp32 rounding: same shift, same
     ignore_index masking, same mean normalization.
@@ -535,25 +539,85 @@ def chunked_lm_loss(hidden, wte, labels, chunk, ignore_index=-100):
         tgt = jnp.pad(tgt, (0, pad), constant_values=ignore_index)
     xs = xs.reshape(-1, chunk, E)
     tgt = tgt.reshape(-1, chunk)
+    return _chunk_scan_loss(xs, wte, tgt, ignore_index)
 
-    @jax.checkpoint
-    def chunk_nll(h, t):
-        logits = (h @ wte.T).astype(jnp.float32)       # [C, V]
-        valid = t != ignore_index
-        t0 = jnp.where(valid, t, 0)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        g = jnp.take_along_axis(logits, t0[:, None], axis=-1)[:, 0]
-        return (jnp.sum(jnp.where(valid, lse - g, 0.0)),
-                jnp.sum(valid.astype(jnp.int32)))
 
-    def body(carry, xt):
-        total, count = carry
-        ds, dc = chunk_nll(*xt)
-        return (total + ds, count + dc), None
+def _chunk_logits(h, t, wte, ignore_index):
+    """One chunk's float32 logits [C, V], their log-sum-exp, the valid mask,
+    the targets with the ignored ones at 0 and the chunk's nll sum."""
+    logits = (h @ wte.T).astype(jnp.float32)
+    valid = t != ignore_index
+    t0 = jnp.where(valid, t, 0)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    g = jnp.take_along_axis(logits, t0[:, None], axis=-1)[:, 0]
+    return logits, lse, valid, t0, jnp.sum(jnp.where(valid, lse - g, 0.0))
 
-    (total, count), _ = jax.lax.scan(
-        body, (jnp.float32(0.0), jnp.int32(0)), (xs, tgt))
-    return total / jnp.maximum(count, 1)
+
+@_functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _chunk_scan_loss(xs, wte, tgt, ignore_index):
+    """Mean nll over the valid targets of ``xs`` [chunks, C, E] against
+    ``wte`` [V, E]; undifferentiated (evaluation, scoring) it is the plain
+    scan: one head matmul, nothing kept."""
+    def body(total, ht):
+        return total + _chunk_logits(*ht, wte, ignore_index)[-1], None
+
+    total, _ = jax.lax.scan(body, jnp.float32(0.0), (xs, tgt))
+    return total / jnp.maximum(jnp.sum(tgt != ignore_index), 1)
+
+
+def _chunk_scan_fwd(xs, wte, tgt, ignore_index):
+    """The loss, and as residuals its gradients for a cotangent of 1: ``dh``
+    [chunks, C, E] and ``dW`` [V, E], in the operands' dtype.
+
+    Every width is the transposed scan's of before: float32 logits and
+    softmax, dlogits cast to the operands' dtype as ``astype``'s transpose
+    casts them, both products in that dtype, ``dW`` carried through the scan
+    in ``wte``'s dtype as a scan's cotangent carry is (on a TPU the add
+    fuses into the matmul's float32 accumulator: one pass over [V, E] a
+    chunk) and in the transposed scan's order of chunks. The mean's 1 / count goes into dlogits in float32 ahead of the
+    cast — except under float16, whose smallest normal (6e-5) is above a
+    softmax over a vocabulary divided by a batch's tokens and whose loss
+    scale is known only to the backward pass: there the residuals stay
+    unnormalised (dlogits in [-1, 1]) and the third residual, 1 elsewhere,
+    is the 1 / count that ``_chunk_scan_bwd`` applies with the cotangent.
+    """
+    count = jnp.maximum(jnp.sum(tgt != ignore_index), 1)
+    inv = 1.0 / count.astype(jnp.float32)
+    late = jnp.finfo(xs.dtype).minexp > jnp.finfo(jnp.float32).minexp
+
+    def body(carry, ht):
+        total, dw = carry
+        h, t = ht
+        logits, lse, valid, t0, nll = _chunk_logits(h, t, wte, ignore_index)
+        onehot = jax.nn.one_hot(t0, logits.shape[-1], dtype=jnp.float32)
+        row = jnp.where(valid, 1.0 if late else inv, 0.0)[:, None]
+        d = ((jnp.exp(logits - lse[:, None]) - onehot) * row).astype(h.dtype)
+        return ((total + nll, dw + (d.T @ h).astype(dw.dtype)),
+                (d @ wte).astype(h.dtype))
+
+    # the barrier ties dW's zeros to the hidden states: without it the
+    # compiler allots the [V, E] buffer ahead of the layers' forward pass
+    xs, dw0 = jax.lax.optimization_barrier((xs, jnp.zeros_like(wte)))
+    # last chunk first, the order the transposed scan walked: dW's carry
+    # rounds once a chunk at the partial sum's size, and a causal model's
+    # first tokens (one shared direction under attention) are its largest
+    # term — added last they meet small partial sums, added first every
+    # later chunk rounds at their size
+    (total, dw), dh = jax.lax.scan(
+        body, (jnp.float32(0.0), dw0), (xs, tgt), reverse=True)
+    return total / count, (dh, dw, inv if late else jnp.float32(1.0))
+
+
+def _chunk_scan_bwd(ignore_index, res, g):
+    """``g`` (1, the loss scale, 1 / accumulation steps) times the
+    residuals, a float32 product rounded once: no logits, no matmul."""
+    dh, dw, left = res
+    g = g * left
+    return ((g * dh.astype(jnp.float32)).astype(dh.dtype),
+            (g * dw.astype(jnp.float32)).astype(dw.dtype), None)
+
+
+_chunk_scan_loss.defvjp(_chunk_scan_fwd, _chunk_scan_bwd)
 
 
 @annotate("ds_loss_head")

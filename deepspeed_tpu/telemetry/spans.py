@@ -114,8 +114,11 @@ def annotate(tag):
       Pallas call, so in ``train_bwd_ms`` and the module's row and in no
       roofline;
     - ``ds_loss_head`` (``chunked_lm_loss``, ``lm_loss``, the tied-logits
-      einsum) and ``ds_embed`` (the ``wte``/``wpe`` lookup), both
-      models/gpt2.py: rows of the benchmark's detail table.
+      einsum): ``loss_head_ms``, every phase's rows together (the chunked
+      head forms its gradient in its forward rule: its matmuls are
+      ``train_fwd_ms``, its backward rule one scaling); ``ds_embed`` (the
+      ``wte``/``wpe`` lookup): rows of the benchmark's detail table; both
+      models/gpt2.py.
 
     - ``moe_gmm``, ``moe_gmm_dlhs``, ``moe_gmm_drhs``
       (ops/pallas/grouped_matmul.py, round each ``pallas_call``: forward,

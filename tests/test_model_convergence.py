@@ -144,6 +144,164 @@ def test_chunked_lm_loss_matches_full():
             err_msg=k)
 
 
+def _checkpointed_chunked_lm_loss(hidden, wte, labels, chunk,
+                                  ignore_index=-100):
+    """``chunked_lm_loss`` as it stood before PR 51 (each chunk under
+    ``jax.checkpoint``, JAX's own transpose of the scan): what the new
+    rule's reduced-precision gradients are held to."""
+    import jax.numpy as jnp
+    E = hidden.shape[-1]
+    xs = hidden[:, :-1, :].reshape(-1, E)
+    tgt = labels[:, 1:].reshape(-1)
+    pad = (-xs.shape[0]) % chunk
+    xs = jnp.pad(xs, ((0, pad), (0, 0))).reshape(-1, chunk, E)
+    tgt = jnp.pad(tgt, (0, pad), constant_values=ignore_index).reshape(
+        -1, chunk)
+
+    @jax.checkpoint
+    def chunk_nll(h, t):
+        logits = (h @ wte.T).astype(jnp.float32)
+        valid = t != ignore_index
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        g = jnp.take_along_axis(
+            logits, jnp.where(valid, t, 0)[:, None], axis=-1)[:, 0]
+        return (jnp.sum(jnp.where(valid, lse - g, 0.0)),
+                jnp.sum(valid.astype(jnp.int32)))
+
+    def body(carry, xt):
+        ds, dc = chunk_nll(*xt)
+        return (carry[0] + ds, carry[1] + dc), None
+
+    (total, count), _ = jax.lax.scan(
+        body, (jnp.float32(0.0), jnp.int32(0)), (xs, tgt))
+    return total / jnp.maximum(count, 1)
+
+
+# rows = 4 * 32 = 128; a case names what differs from
+# (float32, chunk 32, a tenth of the labels ignored, g = 1, untied, no mesh)
+_HEAD_RULE_CASES = {
+    "f32": {},
+    "f32_chunk_does_not_divide": {"chunk": 40},
+    "f32_one_chunk_all_ignored": {"ignore": "chunk"},
+    "f32_every_label_ignored": {"ignore": "all"},
+    "f32_times_3": {"scale": 3.0},
+    "f32_two_step_accumulation": {"accum": 2},
+    "f32_tied_head": {"tied": True},
+    "f32_tied_head_times_3_pad": {"tied": True, "scale": 3.0, "chunk": 24},
+    "f32_mesh_4_batch_sharded": {"mesh": 4},
+    "bf16": {"dtype": "bfloat16"},
+    "bf16_chunk_does_not_divide_times_3": {"dtype": "bfloat16", "chunk": 40,
+                                           "scale": 3.0},
+    "bf16_two_step_accumulation": {"dtype": "bfloat16", "accum": 2},
+    "bf16_tied_head": {"dtype": "bfloat16", "tied": True},
+    "bf16_mesh_4_batch_sharded": {"dtype": "bfloat16", "mesh": 4},
+    # float16's smallest normal is above softmax / count: the loss scale
+    # reaches the gradient before the narrow cast, as it did
+    "f16_loss_scale_4096": {"dtype": "float16", "scale": 4096.0},
+}
+
+
+@pytest.mark.parametrize("case", list(_HEAD_RULE_CASES))
+def test_chunked_lm_loss_forms_its_gradient_in_the_forward_chunk(case):
+    """PR 51's rule: value AND the gradients of ``hidden`` and ``wte``.
+    float32 against ``lm_loss`` on the full logits to 1e-6; bfloat16 and
+    float16 against the checkpointed form of before, at the dtype's
+    rounding, and against float32's ``lm_loss`` at a few of its roundings."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.models.gpt2 import chunked_lm_loss, lm_loss
+    c = {"dtype": "float32", "chunk": 32, "ignore": "tenth", "scale": 1.0,
+         "accum": 1, "tied": False, "mesh": 0, **_HEAD_RULE_CASES[case]}
+    dtype = jnp.dtype(c["dtype"])
+    B, S, E, V = 4, 33, 16, 96
+    rng = np.random.RandomState(3)
+    ids = rng.randint(0, V, (B, S)).astype(np.int32)
+    labels = np.where(rng.rand(B, S) < 0.1, -100, ids).astype(np.int32)
+    if c["ignore"] == "chunk":      # rows 32 .. 63 are batch row 1's targets
+        labels[1, :] = -100
+    elif c["ignore"] == "all":
+        labels[:] = -100
+    h0 = jnp.asarray(rng.randn(B, S, E), dtype)
+    w0 = jnp.asarray(rng.randn(V, E) * 0.3, dtype)
+
+    def loss(head, h, w, rows=slice(None), wide=False):
+        # a tied head also embeds: wte's gradient sums both uses
+        hid = (h + w[ids] if c["tied"] else h)[rows]
+        if head is lm_loss:
+            if wide:
+                hid, w = hid.astype(jnp.float32), w.astype(jnp.float32)
+            out = lm_loss(jnp.einsum("bse,ve->bsv", hid, w), labels[rows])
+        else:
+            out = head(hid, w, labels[rows], c["chunk"])
+        return out * c["scale"] / c["accum"]
+
+    def value_and_grads(head, h, w, **kw):
+        """Summed over the micro-batches, as an accumulating step sums."""
+        outs = [jax.value_and_grad(
+            lambda hh, ww: loss(head, hh, ww, rows, **kw), (0, 1))(h, w)
+            for rows in np.split(np.arange(B), c["accum"])]
+        return [sum(np.asarray(x, np.float32) for x in xs)
+                for xs in zip(*((v, *g) for v, g in outs))]
+
+    if c["mesh"]:
+        devs = jax.devices()[:c["mesh"]]
+        if len(devs) < c["mesh"]:
+            pytest.skip(f"need {c['mesh']} devices")
+        mesh = Mesh(np.array(devs), ("data",))
+        h0 = jax.device_put(h0, NamedSharding(mesh, P("data")))
+        w0 = jax.device_put(w0, NamedSharding(mesh, P()))
+        got_v, (got_h, got_w) = jax.jit(jax.value_and_grad(
+            lambda hh, ww: loss(chunked_lm_loss, hh, ww), (0, 1)))(h0, w0)
+        got = (np.float32(got_v), np.asarray(got_h, np.float32),
+               np.asarray(got_w, np.float32))
+    else:
+        got = value_and_grads(chunked_lm_loss, h0, w0)
+    if c["ignore"] == "all":        # count 0: a loss of 0 that moves nothing
+        assert got[0] == 0 and not got[1].any() and not got[2].any()
+    full = value_and_grads(lm_loss, h0, w0, wide=True)
+    if dtype == jnp.float32:
+        for a, b in zip(got, full):
+            np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-6)
+        return
+    # one rounding of the dtype on dlogits, the products and the sum's carry
+    eps = float(jnp.finfo(dtype).eps)
+    before = value_and_grads(_checkpointed_chunked_lm_loss, h0, w0)
+    for a, b, f in zip(got, before, full):
+        np.testing.assert_allclose(a, b, rtol=2 * eps,
+                                   atol=eps * np.abs(b).max())
+        np.testing.assert_allclose(a, f, rtol=0,
+                                   atol=8 * eps * np.abs(f).max())
+
+
+def test_chunked_lm_loss_sums_dw_in_the_transposed_scans_order():
+    """``dW`` is carried through the chunks in the operands' dtype, one
+    rounding a chunk at the partial sum's size; a causal model's first
+    tokens share a direction (attention's running mean), so theirs is the
+    largest term. The transposed scan of before added it last; the forward
+    rule walks the chunks in that order (PR 51: first chunk first,
+    Kanana-2's head leaf read 0.599 % off its float32 reference on the chip
+    where the limit is 0.59 and this order reads 0.566)."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.models.gpt2 import chunked_lm_loss, lm_loss
+    rng = np.random.RandomState(3)
+    S, E, V = 257, 64, 96
+    t = np.arange(1, S + 1, dtype=np.float32)[None, :, None]
+    h = jnp.asarray(rng.randn(1, S, E) + 20 / np.sqrt(t) * rng.randn(1, 1, E),
+                    jnp.bfloat16)
+    w = jnp.asarray(rng.randn(V, E) * 0.05, jnp.bfloat16)
+    labels = rng.randint(0, V, (1, S)).astype(np.int32)
+    want = np.asarray(jax.grad(lambda ww: lm_loss(jnp.einsum(
+        "bse,ve->bsv", h.astype(jnp.float32), ww), labels))(
+            w.astype(jnp.float32)))
+
+    def off(head):
+        got = jax.grad(lambda ww: head(h, ww, labels, 32))(w)
+        return np.linalg.norm(np.asarray(got, np.float32) - want) \
+            / np.linalg.norm(want)
+
+    assert off(chunked_lm_loss) <= 1.01 * off(_checkpointed_chunked_lm_loss)
+
+
 @pytest.mark.slow
 def test_zero_stages_match_single_device():
     base = _train(MeshConfig(data=1), zero_stage=0)

@@ -492,6 +492,56 @@ def test_train_step_scope_names_reach_the_compiled_text(one_chip_step):
     assert_scope_names(one_chip_step[1].as_text())
 
 
+def head_matmuls(hlo, vocab):
+    """The compiled text's matmuls (``dot`` / ``convolution``) traced under
+    ``ds_loss_head`` that have the vocabulary among the dimensions of
+    their result or operands, as (instruction line, op_name)."""
+    dims = {name: shape.split(",") for name, shape in re.findall(
+        r"(%[\w.\-]+) = \(?\w+\[([\d,]*)\]", hlo)}
+    lines = hlo.splitlines()
+    out = []
+    for ln in (hlo_text.instructions(lines, "dot")
+               + hlo_text.instructions(lines, "convolution")):
+        op_name = re.search(r'op_name="([^"]*ds_loss_head[^"]*)"', ln)
+        names = re.findall(r"%[\w.\-]+", ln.split("metadata=")[0])
+        if op_name and any(str(vocab) in dims.get(n, ()) for n in names):
+            out.append((ln, op_name.group(1)))
+    return out
+
+
+def test_the_loss_head_derives_its_logits_once_a_step():
+    """PR 51: ``chunked_lm_loss`` forms dlogits, dhidden and dW in the
+    forward chunk. The differentiated step of a small GPT-2 under remat
+    holds the three matmuls the mathematics has (logits, dlogits @ wte,
+    dlogitsᵀ @ h), all in the forward scan; nothing of the head's scope is
+    computed again in the backward pass; a step that only scores holds
+    the logits' matmul alone."""
+    from deepspeed_tpu.models.gpt2 import GPT2LMHeadModel, gpt2_tiny
+    vocab = 640                     # no other dimension of the model
+    model = GPT2LMHeadModel(gpt2_tiny(
+        vocab_size=vocab, loss_chunk=32, remat=True, dtype=BF16))
+    ids = SDS((2, 64), I32)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros(ids.shape, I32)))
+
+    def score(p, i):
+        return model.apply(p, i, labels=i)
+
+    _, scored = compile_on_chip(score, params, ids)
+    assert len(head_matmuls(scored.as_text(), vocab)) == 1
+    _, stepped = compile_on_chip(jax.value_and_grad(score), params, ids)
+    hlo = stepped.as_text()
+    matmuls = head_matmuls(hlo, vocab)
+    assert len(matmuls) == 3, matmuls
+    assert all("transpose(jvp(" not in op_name and "/while/body/" in op_name
+               for _, op_name in matmuls), matmuls
+    # the layers' remat is there; the head's is not
+    assert re.search(r'op_name="[^"]*rematted_computation', hlo)
+    assert not re.search(
+        r'op_name="[^"]*(ds_loss_head[^"]*rematted_computation|'
+        r'rematted_computation[^"]*ds_loss_head)', hlo)
+
+
 def assert_attention_in_the_models_layout(hlo):
     """ISSUE 30 in a compiled step of GPT-2 large: the flash calls take
     [B, S, H*D] operands (nothing head-major), and inside the two layer
