@@ -5,6 +5,26 @@ The reference spawns N processes with real NCCL for every distributed test
 CPU backend to expose 8 virtual devices, so every mesh/sharding/collective
 path runs in-process (SURVEY §4 'lesson for the TPU rebuild'). This must run
 before jax initializes, hence module-level in conftest.
+
+The budget (ROADMAP.md D5, PR 52). The driver runs tier-1 under ``-n 6 --dist
+loadfile`` with a limit of 1,470 s on a machine at least 1.28 x slower than the
+builder's, and a run the clock cuts is counted where it stopped. So on the
+builder's machine the whole run takes at most 950 s, and no FILE more than
+200 s of its cases' junit seconds: ``loadfile`` deals a file to ONE worker, so
+the wall is (all files' seconds) / 6 plus the tail the last long file leaves
+while five workers idle — a long file costs more than its seconds. A file
+past 200 s is cut at a seam it already has, what its parts share in an
+importable module beside them (``tests/flash_cases.py``,
+``tests/described_chip.py``, ``tests/zero_matrix.py``,
+``tests/model_cases.py``). And the suite is compile-bound, so a test
+differentiates or runs a whole model under ``jax.jit`` — one program, where
+the same call unjitted dispatches and compiles every primitive of the forward
+and backward pass on its own — takes a jaxpr's text from that one trace
+(``tests/hlo_text.run_with_jaxpr``), and builds what a file's cases share
+(a model, its weights, a reference's gradients) once a module. A kernel
+with a ``custom_vjp`` has two forward programs — the primal call and the
+forward rule a gradient program runs in its place — so a test that compares
+values calls the kernel undifferentiated too (its own small ``jax.jit``).
 """
 
 import os

@@ -2,7 +2,10 @@
 compiler alike): which computations run inside a ``while`` loop — a layer
 scan's body and whatever it calls — and which collectives sit there; and
 of any step under remat: which forward attention kernels it runs again.
-And one question of a jaxpr: the grids its Pallas calls run on."""
+And one question of a jaxpr: the grids its Pallas calls run on. And how a
+test comes by a jaxpr's text without a second trace: ``run_with_jaxpr`` runs a
+function as one compiled program and gives the text that program was traced
+to."""
 
 import re
 
@@ -121,6 +124,14 @@ def remat_report(loss, params, capsys):
     jax.ad_checkpoint.print_saved_residuals(loss, params)
     return (rematted_forward_attention(lowered.as_text(debug_info=True)),
             capsys.readouterr().out, lowered)
+
+
+def run_with_jaxpr(fn, *args):
+    """(``fn(*args)`` run as ONE compiled program, the text of the jaxpr
+    that program was traced to): a whole model is traced once for both, and
+    none of its primitives is dispatched and compiled on its own."""
+    traced = jax.jit(fn).trace(*args)
+    return traced.lower().compile()(*args), str(traced.jaxpr)
 
 
 def pallas_element_rows(jaxpr):

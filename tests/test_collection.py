@@ -11,12 +11,18 @@
    drops out of tier-1 entirely — coverage evaporating one decorator at
    a time, with the suite still green.
 
-Both guards read ONE subprocess collection (`--collect-only -q -m 'not
+3. What tier-1's wall rests on (tests/conftest.py): the flash kernels'
+   tests stay dealt over their ``test_flash_*.py`` files with no case lost
+   or run twice, and no whole-step compile of a routed cell joins tier-1
+   unnoticed (each is minutes of one compile that nothing can share).
+
+All guards read ONE subprocess collection (`--collect-only -q -m 'not
 slow'`): it fails loudly on any collection error, reports the total
 collected count (before deselection), and lists the surviving fast node
 ids per file.
 """
 
+import collections
 import functools
 import os
 import re
@@ -29,9 +35,10 @@ REPO = os.path.dirname(TESTS_DIR)
 
 @functools.lru_cache(maxsize=1)
 def _collect_fast():
-    """(total_collected, {file -> fast node count}) from one subprocess
-    collection — shared by both guards (a full re-collect costs ~35 s of
-    suite imports, and the tier-1 wall is a real budget)."""
+    """(total_collected, {file -> its fast tests' ids, file name cut off})
+    from one subprocess collection — shared by every guard (a full
+    re-collect costs ~35 s of suite imports, and the tier-1 wall is a real
+    budget)."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
@@ -51,8 +58,8 @@ def _collect_fast():
     fast_per_file = {}
     for line in proc.stdout.splitlines():
         if "::" in line:
-            fname = line.split("::", 1)[0].split("/")[-1]
-            fast_per_file[fname] = fast_per_file.get(fname, 0) + 1
+            path, case = line.split("::", 1)
+            fast_per_file.setdefault(path.split("/")[-1], []).append(case)
     return total, fast_per_file
 
 
@@ -76,3 +83,31 @@ def test_slow_marked_files_keep_fast_coverage():
         f"these files slow-mark tests and no longer collect ANY fast "
         f"test — tier-1 lost them entirely: {orphaned}. Keep (or add) a "
         f"fast sibling test per file, or un-mark something.")
+
+
+# the whole steps tier-1 compiles for a described chip: the dense cell's and
+# the first routed cell's (under a minute each); every later cell's is
+# slow-marked, its kernels compiled alone at the cell's shapes
+WHOLE_STEP_COMPILES = [
+    "test_train_step_compiles_for_one_chip_with_flash_and_fits",
+    "test_olmoe_step_compiles_for_one_chip_with_its_scopes_and_fits",
+]
+
+
+def test_tier1_keeps_its_flash_cases_and_takes_no_new_whole_step_compile():
+    _, fast_per_file = _collect_fast()
+    flash = {f: cases for f, cases in fast_per_file.items()
+             if f.startswith("test_flash_")}
+    cases = collections.Counter(c for found in flash.values() for c in found)
+    assert sum(cases.values()) == 219, {f: len(c) for f, c in flash.items()}
+    twice = sorted(c for c, n in cases.items() if n > 1)
+    assert not twice, f"under two of {sorted(flash)}: {twice}"
+    joined = sorted({
+        name for found in fast_per_file.values()
+        for name in (c.split("[")[0] for c in found)
+        if re.search(r"_step_compiles_for_one_chip_with_\w+_and_fits$", name)
+    } - set(WHOLE_STEP_COMPILES))
+    assert not joined, (
+        f"a whole-step compile joined tier-1: {joined}; a routed cell's "
+        f"takes minutes on one worker — slow-mark it beside its siblings in "
+        f"tests/test_tpu_compile.py")

@@ -1,47 +1,20 @@
 """The gated delta rule's Pallas kernels (``ops/pallas/gated_delta.py``) on
 the CPU, in the interpreter: against the recurrence as written, forward and
 for all five gradients, in float32 at the limits the XLA chunked form is held
-to (``tests/test_qwen3_next.py``); in bf16 against that form; where keys are
-alike; under ``jax.checkpoint`` (a block's remat runs the primal call, the
-forward rule and the backward kernel); and the rule that says which head
-sizes take the kernels, with the gauges that say which form took a call.
+to (``tests/test_qwen3_next_delta_rule.py``). In bf16, where keys are alike,
+under ``jax.checkpoint``, and which head sizes take the kernels:
+``tests/test_gated_delta_kernel_forms.py``.
 """
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from deepspeed_tpu.ops.gated_delta import (CHUNK, gated_delta_recurrence,
-                                           gated_delta_rule,
-                                           gated_delta_rule_xla)
-from deepspeed_tpu.ops.pallas import gated_delta as kernels
+                                           gated_delta_rule)
 from deepspeed_tpu.telemetry.registry import default_registry
+from tests.gated_delta_cases import _grads, _inputs, _out_and_grads, _worst
 
 HEADS = "linear_attn/gdn_kernel_heads_per_step"
-KEPT = "linear_attn/gdn_states_kept_every"
-
-
-def _inputs(S, rep=2, D=16, B=2, Hk=2, dtype=jnp.float32, seed=0):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
-    q = unit(jax.random.normal(ks[0], (B, S, Hk, D))) * D ** -0.5
-    k = unit(jax.random.normal(ks[1], (B, S, Hk, D)))
-    v = jax.random.normal(ks[2], (B, S, Hk * rep, D))
-    g = -2.0 * jax.nn.softplus(jax.random.normal(ks[3], (B, S, Hk * rep)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, Hk * rep)))
-    return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
-
-
-def _grads(fn, args):
-    return jax.grad(lambda *a: jnp.sum(jnp.sin(3.0 * fn(*a).astype(
-        jnp.float32))), argnums=(0, 1, 2, 3, 4))(*args)
-
-
-def _worst(got, want):
-    return {name: float(jnp.abs(a.astype(jnp.float32) - b).max()
-                        / jnp.abs(b).max())
-            for name, a, b in zip("q k v g beta".split(), got, want)}
 
 
 @pytest.mark.parametrize("D", [16, 128])
@@ -55,114 +28,8 @@ def test_kernels_are_the_recurrence_forward_and_backward(S, rep, D):
     args = _inputs(S, rep, D, B=1 if D == 128 else 2)
     got = gated_delta_rule(*args)
     assert default_registry().peek_gauge(HEADS) == 2 * rep
-    want = gated_delta_recurrence(*args)
+    want, want_grads = _out_and_grads(gated_delta_recurrence, args)
     assert got.shape == want.shape and got.dtype == args[2].dtype
     np.testing.assert_allclose(got, want, atol=5e-6)
-    worst = _worst(_grads(gated_delta_rule, args),
-                   _grads(gated_delta_recurrence, args))
+    worst = _worst(_grads(gated_delta_rule, args), want_grads)
     assert max(worst.values()) < 2e-5, worst
-
-
-@pytest.mark.parametrize("D,rep", [(16, 2), (128, 2), (16, 1)])
-def test_kernels_in_bf16_round_as_the_xla_form_does(D, rep):
-    """bf16 operands: both forms stand as far from the float32 recurrence,
-    and as near each other as two bf16 orders of operation do."""
-    args = _inputs(2 * CHUNK, rep, D, B=1, dtype=jnp.bfloat16)
-    exact = tuple(a.astype(jnp.float32) for a in args)
-    want = gated_delta_recurrence(*exact)
-    got, xla = gated_delta_rule(*args), gated_delta_rule_xla(*args)
-    assert got.dtype == jnp.bfloat16
-
-    def rel(a, b):
-        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
-        return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
-
-    assert rel(got, want) < 1.2 * rel(xla, want) + 1e-3 < 0.02
-    assert rel(got, xla) < 0.01
-    g_got, g_xla = _grads(gated_delta_rule, args), _grads(
-        gated_delta_rule_xla, args)
-    g_want = _grads(gated_delta_recurrence, exact)
-    for name, a, b, c in zip("q k v g beta".split(), g_got, g_xla, g_want):
-        assert a.dtype == c.dtype or name in "qkv", name
-        assert rel(a, c) < 1.2 * rel(b, c) + 2e-3 < 0.03, name
-
-
-def test_kernels_without_writes_read_nothing_and_keys_alike_are_stable():
-    q, k, v, g, beta = _inputs(2 * CHUNK)
-    assert not np.any(gated_delta_rule(q, k, v, g, jnp.zeros_like(beta)))
-    # every key the same, no decay, beta near one: a Neumann series of L
-    # would overflow float32 here; substitution is exact
-    k = jnp.broadcast_to(k[:, :1], k.shape)
-    g, beta = jnp.zeros_like(g), jnp.full_like(beta, 0.999)
-    np.testing.assert_allclose(gated_delta_rule(q, k, v, g, beta),
-                               gated_delta_recurrence(q, k, v, g, beta),
-                               atol=2e-5)
-
-
-@pytest.mark.parametrize("sub", [8, 64])
-def test_the_inverse_is_the_same_at_every_split_of_substitution_and_rounds(
-        monkeypatch, sub):
-    """``_SUB`` moves work between the VPU's substitution and the block
-    rounds' matmuls: 8 leaves three rounds, 64 none."""
-    lower = jnp.tril(jax.random.normal(jax.random.PRNGKey(0), (CHUNK, CHUNK)),
-                     -1) * 0.3
-    monkeypatch.setattr(kernels, "_SUB", sub)
-    inv = kernels._unit_lower_inverse(lower)
-    np.testing.assert_allclose(inv @ (jnp.eye(CHUNK) + lower), jnp.eye(CHUNK),
-                               atol=1e-4)
-
-
-def test_primal_forward_rule_and_backward_agree_under_checkpoint():
-    """``jax.checkpoint`` round the rule, as the block's remat is: the
-    forward pass, the recomputation and the backward kernel give the
-    gradients of the plain call, and the outputs of the primal call."""
-    args = _inputs(3 * CHUNK)
-    loss = lambda fn: (lambda *a: jnp.sum(jnp.sin(3.0 * fn(*a))))  # noqa: E731
-    plain = jax.value_and_grad(loss(gated_delta_rule),
-                               argnums=(0, 1, 2, 3, 4))(*args)
-    remat = jax.jit(jax.value_and_grad(
-        loss(jax.checkpoint(gated_delta_rule)), argnums=(0, 1, 2, 3, 4)))
-    again = remat(*args)
-    np.testing.assert_allclose(again[0], plain[0], rtol=1e-6)
-    for a, b in zip(again[1], plain[1]):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
-    # the differentiated program holds the kernels of all three: the primal
-    # call (the forward pass: o only), the forward rule (the recomputation:
-    # o, the states and the inverses) and the backward kernel
-    calls = [eqn for eqn in _eqns(jax.make_jaxpr(remat)(*args).jaxpr)
-             if eqn.primitive.name == "pallas_call"]
-    outs = sorted(len(eqn.outvars) for eqn in calls)
-    assert outs == [1, 3, 5], outs
-
-
-def _eqns(jaxpr):
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _eqns(sub)
-
-
-@pytest.mark.parametrize("D,tpu,kernel", [
-    (16, True, False), (64, True, False), (128, True, True),
-    (256, True, True), (16, False, True), (64, False, True)])
-def test_which_head_sizes_take_the_kernels(D, tpu, kernel):
-    assert kernels.takes_kernel(D, D, tpu) is kernel
-    # both head sizes must be lane-aligned
-    assert kernels.takes_kernel(D, 128, tpu) is kernel
-    assert kernels.takes_kernel(128, D, tpu) is kernel
-
-
-def test_gauges_say_which_form_took_the_call(monkeypatch):
-    args = _inputs(CHUNK, B=1)
-    gauge = default_registry().peek_gauge
-    want = gated_delta_rule(*args)                 # the interpreter: kernels
-    assert gauge(HEADS) == 4 and gauge(KEPT) == 1
-    # a TPU backend and heads of 16: the XLA form
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    got = gated_delta_rule(*args)
-    assert gauge(HEADS) == 0 and gauge(KEPT) == 8
-    np.testing.assert_allclose(got, want, atol=5e-6)
-    monkeypatch.undo()
-    # a key head that serves four value heads goes alone
-    gated_delta_rule(*_inputs(CHUNK, rep=4, B=1, Hk=3))
-    assert gauge(HEADS) == 4 and gauge(KEPT) == 1

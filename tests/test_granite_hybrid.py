@@ -58,8 +58,8 @@ def tiny():
     vocab = fam.sizes(config, True)["vocab_size"]
     ids = np.random.default_rng(0).integers(0, vocab, (2, 80)).astype(
         np.int32)
-    params = fam._model(config, True).init(jax.random.PRNGKey(0),
-                                           jnp.asarray(ids))["params"]
+    params = jax.jit(fam._model(config, True).init)(
+        jax.random.PRNGKey(0), jnp.asarray(ids))["params"]
     keys = iter(jax.random.split(jax.random.PRNGKey(1), 1000))
     params = jax.tree_util.tree_map(
         lambda x: x + 0.1 * jax.random.normal(next(keys), x.shape)
@@ -120,8 +120,8 @@ def test_the_walked_gradients_are_the_pinned_losss_gradients(tiny):
             return ref.head_loss(x, top, ids, eps=sizes["eps"],
                                  logits_scaling=sizes["logits_scaling"])
 
-    want_loss, (want_top, want_layers) = jax.value_and_grad(
-        whole, argnums=(0, 1))(top, layers)
+    want_loss, (want_top, want_layers) = jax.jit(jax.value_and_grad(
+        whole, argnums=(0, 1)))(top, layers)
     loss, got_layers, got_top = ref.pinned_backward(
         top, layers, ids, rows, lambda i, kind, g, *outs: g, **sizes)
     assert float(loss) == pytest.approx(float(want_loss), abs=1e-6)
@@ -137,8 +137,8 @@ def test_logits_match_the_reference(tiny):
     through its norm and the embedding, over ``logits_scaling``, one
     column an id of the SLICE."""
     config, params, ids, _ = tiny
-    logits = fam._model(config, True).apply({"params": params},
-                                            jnp.asarray(ids))
+    logits = jax.jit(fam._model(config, True).apply)({"params": params},
+                                                     jnp.asarray(ids))
     sizes = fam.reference_sizes(config, True)
     want = ref.logits(params, jnp.asarray(ids),
                       lambda w: fam.reference_view(w, sizes["layer_types"]),
@@ -238,8 +238,8 @@ def test_the_tied_embeddings_gradient_is_the_sum_of_both_uses(tiny):
                                  eps=sizes["eps"],
                                  logits_scaling=sizes["logits_scaling"])
 
-    as_rows, as_head = jax.grad(loss, argnums=(0, 1))(top["embed"],
-                                                      top["embed"])
+    as_rows, as_head = jax.jit(jax.grad(loss, argnums=(0, 1)))(
+        top["embed"], top["embed"])
     got = grads["embed_tokens"]
     size = float(jnp.linalg.norm(got))
     assert float(jnp.linalg.norm(got - (as_rows + as_head))) < 2e-4 * size
@@ -263,16 +263,16 @@ def test_ids_stay_inside_the_slice_and_the_loss_runs_over_it():
     model = GraniteHybridForCausalLM(cfg)
     ids = jnp.asarray(np.random.default_rng(0).integers(0, 96, (2, 32)),
                       jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
-    logits = model.apply({"params": params}, ids)
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)["params"]
+    logits = jax.jit(model.apply)({"params": params}, ids)
     assert logits.shape == (2, 32, 96)
     logp = jax.nn.log_softmax(logits[:, :-1].astype(jnp.float32), axis=-1)
     want = -jnp.mean(jnp.take_along_axis(logp, ids[:, 1:, None], axis=-1))
-    got = model.apply({"params": params}, ids, labels=ids)
+    got = jax.jit(model.apply)({"params": params}, ids, labels=ids)
     assert float(got) == pytest.approx(float(want), abs=1e-5)
-    chunked = GraniteHybridForCausalLM(granite_hybrid_tiny(
-        vocab_size=96, loss_chunk=16)).apply({"params": params}, ids,
-                                             labels=ids)
+    chunked = jax.jit(GraniteHybridForCausalLM(granite_hybrid_tiny(
+        vocab_size=96, loss_chunk=16)).apply)({"params": params}, ids,
+                                              labels=ids)
     assert float(chunked) == pytest.approx(float(want), abs=1e-5)
 
 
@@ -316,7 +316,7 @@ def test_the_model_reuses_the_mixers_and_rotates_nothing():
     cfg = granite_hybrid_tiny()
     model = GraniteHybridForCausalLM(cfg)
     ids = jnp.zeros((1, 32), jnp.int32)
-    params = model.init(jax.random.PRNGKey(0), ids)["params"]
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)["params"]
     primitives = set()
 
     def walk(jaxpr):

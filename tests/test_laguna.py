@@ -1,11 +1,13 @@
 """Laguna on the CPU at small sizes: the program's model against the
 benchmark's plain reference (``benchmark/reference/laguna.py``) for every
 layer kind and every gradient leaf, with all experts held and with a share;
-the eight shares adding up to the uncut layer; each named omission failing
-the benchmark's check; the layer plan of the published depth and of the
-cut; YaRN's angles against the formula written out by hand. Seeded weights,
-float32. Remat and the router's choice: ``tests/test_laguna_remat.py``; the
-model on the engine: ``tests/test_laguna_engine.py``.
+each named omission failing the benchmark's check; the layer plan of the
+published depth and of the cut; YaRN's angles against the formula written
+out by hand. Seeded weights, float32. The eight shares adding up to the
+uncut layer, and the window kernels where flash is on:
+``tests/test_laguna_layers.py``; remat and the router's choice:
+``tests/test_laguna_remat.py``; the model on the engine:
+``tests/test_laguna_engine.py``.
 """
 
 import copy
@@ -24,7 +26,6 @@ from deepspeed_tpu.models.laguna import (FULL, SLIDING, LagunaConfig,
                                          laguna_tiny,
                                          yarn_rope_angles)
 from deepspeed_tpu.models.llama import rope_angles
-from deepspeed_tpu.moe.dropless import DroplessMoE
 from tests.cell_config import config_file
 
 FILE = config_file("laguna-xs2-33b-a3b-ep8-depth5")
@@ -59,8 +60,8 @@ def _tiny(config, seed=0, seq=96):
     vocab = fam.sizes(config, True)["vocab_size"]
     ids = np.random.default_rng(seed).integers(0, vocab, (2, seq)).astype(
         np.int32)
-    params = fam._model(config, True).init(jax.random.PRNGKey(seed),
-                                           jnp.asarray(ids))["params"]
+    params = jax.jit(fam._model(config, True).init)(
+        jax.random.PRNGKey(seed), jnp.asarray(ids))["params"]
     keys = iter(jax.random.split(jax.random.PRNGKey(1), 1000))
     params = jax.tree_util.tree_map(
         lambda x: x + 0.1 * jax.random.normal(next(keys), x.shape)
@@ -122,12 +123,13 @@ def test_logits_match_the_reference(tiny):
     """Without labels the model gives logits: the reference's final stream
     through its norm and head."""
     config, params, ids, _ = tiny
-    logits = fam._model(config, True).apply({"params": params},
-                                            jnp.asarray(ids))
+    logits = jax.jit(fam._model(config, True).apply)({"params": params},
+                                                     jnp.asarray(ids))
     top, layers = fam.reference_view(params, config, True)
     sizes = fam.reference_sizes(config, True)
     with jax.default_matmul_precision("highest"):
-        _, detail = ref.forward(top, layers, jnp.asarray(ids), **sizes)
+        _, detail = jax.jit(lambda *a: ref.forward(*a, **sizes))(
+            top, layers, jnp.asarray(ids))
         last = detail["layers"][-1]
         x = last["x_mid"] + last["ffn_out"]
         want = ref.norm(x, top["norm"], sizes["eps"]) @ top["lm_head"].T
@@ -166,82 +168,6 @@ def test_each_omission_fails_the_check(tiny, monkeypatch, omission, override,
     if branch == "swa_out_rel":
         # the leading full-attention layer runs under it and is still right
         assert diffs["by_layer"][0][0] < 1e-5
-
-
-# ------------------------------------------------------- the expert layer
-
-H, E, K, F, RANKS = 32, 32, 4, 16, 8
-SCALE = 2.5
-
-
-def _layer_weights(seed=0):
-    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
-    n = lambda k, *s: 0.3 * jax.random.normal(k, s)  # noqa: E731
-    return {"router": n(ks[0], H, E), "gate": n(ks[1], E, H, F),
-            "up": n(ks[2], E, H, F), "down": n(ks[3], E, F, H),
-            "shared_gate": n(ks[4], H, F), "shared_up": n(ks[5], H, F),
-            "shared_down": n(ks[6], F, H),
-            "shared_expert_gate": n(ks[7], H, 1)}
-
-
-def _share(p, x, rank, held=E // RANKS, shared=False, scale=SCALE):
-    layer = DroplessMoE(E, K, F, norm_topk_prob=True, dtype=jnp.float32,
-                        experts_held=held, expert_share=rank,
-                        shared_d_ff=F if shared else 0, routed_scale=scale)
-    lo = rank * held
-    params = {"router": p["router"], "gate_proj": p["gate"][lo:lo + held],
-              "up_proj": p["up"][lo:lo + held],
-              "down_proj": p["down"][lo:lo + held]}
-    if shared:
-        params.update({f"shared_{n}_proj": p[f"shared_{n}"]
-                       for n in ("gate", "up", "down")},
-                      shared_expert_gate=p["shared_expert_gate"])
-    out, vs = layer.apply({"params": params}, x, mutable=["stats"])
-    return out, {k: float(v[0]) for k, v in vs["stats"].items()}
-
-
-def test_the_eight_shares_and_the_shared_expert_once_are_the_whole_layer():
-    """The parts all 8 ranks give (each its 4 experts' rows, scaled by 2.5),
-    the shared expert counted ONCE, add up to the uncut reference's layer."""
-    p = _layer_weights()
-    x = jax.random.normal(jax.random.PRNGKey(9), (2, 24, H))
-    h = x.reshape(-1, H)
-    with jax.default_matmul_precision("highest"):
-        whole = ref.moe(h, p, K, 0, SCALE)[0]
-        shared = ref.moe(h, p, K, 0, 0.0)[0]        # routed weights x 0
-        parts, held = [], 0.0
-        for rank in range(RANKS):
-            out, stats = _share(p, x, rank)
-            parts.append(out)
-            held += stats["moe_rows_held_share"]
-            assert stats["moe_dropped_rows"] == 0
-        with_shared, _ = _share(p, x, 3, shared=True)
-    assert held == pytest.approx(1.0)       # every routed row is somewhere
-    np.testing.assert_allclose(sum(parts).reshape(-1, H) + shared, whole,
-                               atol=5e-5)
-    # a rank's own output carries the shared expert in full, unscaled
-    np.testing.assert_allclose(with_shared.reshape(-1, H),
-                               parts[3].reshape(-1, H) + shared, atol=5e-5)
-    # and the factor is on the routed part alone: 2.5 x the part at 1.0
-    with jax.default_matmul_precision("highest"):
-        plain, _ = _share(p, x, 3, scale=1.0)
-    np.testing.assert_allclose(parts[3], SCALE * plain, atol=5e-5)
-
-
-@pytest.mark.parametrize("pin", [False, True], ids=["own_choice", "pinned"])
-def test_routed_scale_multiplies_the_renormalised_weights(pin):
-    from deepspeed_tpu.moe.dropless import route
-    logits = jax.random.normal(jax.random.PRNGKey(0), (12, E))
-    w1, e1, p1 = route(logits, K, True, pin_choice=pin)
-    w2, e2, p2 = route(logits, K, True, pin_choice=pin, routed_scale=SCALE)
-    np.testing.assert_array_equal(e1, e2)
-    np.testing.assert_array_equal(p1, p2)
-    np.testing.assert_allclose(w2, SCALE * w1, rtol=1e-6)
-    np.testing.assert_allclose(jnp.sum(w2, axis=1), SCALE, rtol=1e-5)
-    # the default leaves the traced program as it was: no multiply
-    text = lambda **kw: str(jax.make_jaxpr(  # noqa: E731
-        lambda x: route(x, K, True, **kw)[0])(logits))
-    assert text() == text(routed_scale=1.0) != text(routed_scale=SCALE)
 
 
 # --------------------------------------------------------- the layer plan

@@ -1,19 +1,18 @@
 """Laguna on the engine, on the CPU at small sizes: ``dstpu.initialize``
 steps under ZeRO-3 with remat over two devices, at the cut's depth and at one
-with a tail outside the scan, and the window layers on the window kernels
-where flash is on. The blocks against the reference: ``tests/test_laguna.py``.
+with a tail outside the scan. The window layers on the window kernels where
+flash is on: ``tests/test_laguna_layers.py``; the blocks against the
+reference: ``tests/test_laguna.py``.
 """
 
 import copy
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from benchmark.families import laguna as fam
-from deepspeed_tpu.models.laguna import (FULL, SLIDING, LagunaForCausalLM,
-                                         laguna_tiny)
+from deepspeed_tpu.models.laguna import FULL, SLIDING
 from tests.cell_config import config_file
 
 FILE = config_file("laguna-xs2-33b-a3b-ep8-depth5")
@@ -50,29 +49,3 @@ def test_trains_through_the_engine_under_zero3_with_remat(depth):
     assert 0.05 < gauges["moe/rows_held_share"] < 0.6      # 1/4 at uniform
     assert gauges["moe/held_slabs"] >= 1.0
     assert gauges["moe/combine_rows_walked"] >= 1.0
-
-
-def test_the_window_layers_run_the_window_kernels_where_flash_is_on():
-    """``use_flash=True`` (the TPU's choice) sends a sliding layer through
-    the window kernels — here in the interpreter — and a full layer through
-    the causal ones; the outputs are the reference path's."""
-    ids = jnp.asarray(np.random.default_rng(4).integers(0, 256, (1, 128)),
-                      jnp.int32)
-    cfg = laguna_tiny(num_hidden_layers=5, experts_held=4)
-    params = LagunaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids)["params"]
-
-    def run(use_flash):
-        import dataclasses
-        model = LagunaForCausalLM(dataclasses.replace(cfg,
-                                                      use_flash=use_flash))
-        fn = lambda p: model.apply({"params": p}, ids, labels=ids)  # noqa
-        return fn(params), jax.grad(fn)(params), str(jax.make_jaxpr(fn)(
-            params))
-
-    (want, want_g, plain), (got, got_g, flash) = run(False), run(True)
-    assert "_flash_attention_swa" in flash \
-        and "_flash_attention_swa" not in plain
-    assert float(got) == pytest.approx(float(want), abs=1e-5)
-    for a, b in zip(jax.tree_util.tree_leaves(got_g),
-                    jax.tree_util.tree_leaves(want_g)):
-        np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-3)

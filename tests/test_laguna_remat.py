@@ -12,19 +12,18 @@ import numpy as np
 import pytest
 
 from deepspeed_tpu.models.laguna import LagunaForCausalLM, laguna_tiny
+from tests import hlo_text, model_cases
 
 
 def test_remat_on_and_off_agree_and_keep_the_routers_choice():
     ids = jnp.asarray(np.random.default_rng(2).integers(0, 256, (2, 64)),
                       jnp.int32)
 
-    def grads(remat):
-        model = LagunaForCausalLM(laguna_tiny(experts_held=4, remat=remat))
-        params = model.init(jax.random.PRNGKey(0), ids)["params"]
-        fn = jax.grad(lambda p: model.apply({"params": p}, ids, labels=ids))
-        return fn(params), str(jax.make_jaxpr(fn)(params))
+    def model_of(remat):
+        return LagunaForCausalLM(laguna_tiny(experts_held=4, remat=remat))
 
-    (want, plain), (got, rematted) = grads(False), grads(True)
+    (want, plain), (got, rematted) = \
+        model_cases.gradients_without_and_with_remat(model_of, ids)
     assert "moe_experts" in rematted and "moe_experts" not in plain
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
@@ -42,13 +41,13 @@ def test_rematted_blocks_keep_what_their_attention_kernels_produced(
     unrematted ones. The control cuts the base set back to the router's
     choice: all five layers' forward kernels are then run again."""
     from deepspeed_tpu.models import gpt2
-    from tests import hlo_text
     if base:
         monkeypatch.setattr(gpt2, "REMAT_BASE_NAMES", base)
     ids = jnp.asarray(np.random.default_rng(4).integers(0, 256, (1, 128)),
                       jnp.int32)
     cfg = laguna_tiny(num_hidden_layers=5, experts_held=4, use_flash=True)
-    params = LagunaForCausalLM(cfg).init(jax.random.PRNGKey(0), ids)["params"]
+    params = jax.jit(LagunaForCausalLM(cfg).init)(jax.random.PRNGKey(0),
+                                                  ids)["params"]
 
     def loss(remat):
         model = LagunaForCausalLM(dataclasses.replace(cfg, remat=remat))

@@ -80,6 +80,7 @@ def _cache_dir_after_enable(**env):
             if k != "JAX_COMPILATION_CACHE_DIR"}
     out = subprocess.run([sys.executable, "-c", code], env={**base, **env},
                          capture_output=True, text=True, check=True,
+                         timeout=120,
                          cwd=os.path.dirname(os.path.dirname(__file__)))
     return out.stdout.split()
 

@@ -3,8 +3,8 @@ benchmark's plain reference (``benchmark/reference/smallthinker.py``) for
 both layer kinds and every gradient leaf, with all experts held and with a
 share; the four shares adding up to the uncut layer; each named omission
 failing the benchmark's check; the layer plan of the published depth and of
-the cut; the model on the engine under ZeRO-3 and remat. Seeded weights,
-float32.
+the cut. Seeded weights, float32. The model on the engine under ZeRO-3 and
+remat: ``tests/test_smallthinker_engine.py``.
 """
 
 import copy
@@ -51,8 +51,8 @@ def _tiny(config, seed=0, seq=96):
     vocab = fam.sizes(config, True)["vocab_size"]
     ids = np.random.default_rng(seed).integers(0, vocab, (2, seq)).astype(
         np.int32)
-    params = fam._model(config, True).init(jax.random.PRNGKey(seed),
-                                           jnp.asarray(ids))["params"]
+    params = jax.jit(fam._model(config, True).init)(
+        jax.random.PRNGKey(seed), jnp.asarray(ids))["params"]
     keys = iter(jax.random.split(jax.random.PRNGKey(1), 1000))
     params = jax.tree_util.tree_map(
         lambda x: x + 0.1 * jax.random.normal(next(keys), x.shape)
@@ -115,12 +115,13 @@ def test_logits_match_the_reference(tiny):
     """Without labels the model gives logits: the reference's final stream
     through its norm and head."""
     config, params, ids, _ = tiny
-    logits = fam._model(config, True).apply({"params": params},
-                                            jnp.asarray(ids))
+    logits = jax.jit(fam._model(config, True).apply)({"params": params},
+                                                     jnp.asarray(ids))
     top, layers = fam.reference_view(params, config, True)
     sizes = fam.reference_sizes(config, True)
     with jax.default_matmul_precision("highest"):
-        _, detail = ref.forward(top, layers, jnp.asarray(ids), **sizes)
+        _, detail = jax.jit(lambda *a: ref.forward(*a, **sizes))(
+            top, layers, jnp.asarray(ids))
         last = detail["layers"][-1]
         x = last["x_mid"] + last["ffn_out"]
         want = ref.norm(x, top["norm"], sizes["eps"]) @ top["lm_head"].T
@@ -183,25 +184,6 @@ def test_each_omission_fails_the_check(tiny, monkeypatch, omission, override,
         assert diffs["by_layer"][layer][0] > 3 * tol[reading]
         if layer:
             assert diffs["by_layer"][0][0] < 1e-5
-
-
-def test_remat_on_and_off_agree_and_keep_the_routers_choice():
-    ids = jnp.asarray(np.random.default_rng(2).integers(0, 256, (1, 48)),
-                      jnp.int32)
-
-    def grads(remat):
-        model = SmallThinkerForCausalLM(smallthinker_tiny(
-            num_hidden_layers=2, sliding_window_layout=[0, 1],
-            rope_layout=[0, 1], experts_held=4, remat=remat))
-        params = model.init(jax.random.PRNGKey(0), ids)["params"]
-        fn = jax.grad(lambda p: model.apply({"params": p}, ids, labels=ids))
-        return fn(params), str(jax.make_jaxpr(fn)(params))
-
-    (want, plain), (got, rematted) = grads(False), grads(True)
-    assert "moe_experts" in rematted and "moe_experts" not in plain
-    for a, b in zip(jax.tree_util.tree_leaves(got),
-                    jax.tree_util.tree_leaves(want)):
-        np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-4)
 
 
 def test_a_layer_without_rope_gets_no_table_and_no_rotation():
@@ -369,64 +351,3 @@ def test_the_plan_follows_the_lists_and_nothing_else():
         smallthinker_tiny(num_hidden_layers=4, rope_layout=[0, 1, 0, 1])
     with pytest.raises(TypeError, match="rope_layout"):
         SmallThinkerConfig(num_hidden_layers=1, sliding_window_layout=[0])
-
-
-# ------------------------------------------------ the model on the engine
-
-@pytest.mark.parametrize("depth", [4, 5], ids=["2_periods", "2x2+1"])
-def test_trains_through_the_engine_under_zero3_with_remat(depth):
-    """``dstpu.initialize`` over two devices, ZeRO-3, every block under its
-    gather edge and remat — whole periods (of two layers, full and sliding)
-    and a depth with a tail outside the scan: the loss falls on a repeated
-    batch, the first loss is the system step's, and the ``moe/*`` gauges are
-    folded."""
-    config = copy.deepcopy(FILE)
-    config["rehearse_cpu"]["model"].update(remat=True)
-    layout = [i % 2 for i in range(depth)]
-    config["rehearse_cpu"].update(num_hidden_layers=depth,
-                                  sliding_window_layout=layout,
-                                  rope_layout=layout)
-    ids = np.random.default_rng(1).integers(0, 512, (2, 48)).astype(np.int32)
-    engine, params = fam.build_train(config, 2, 0, jax.devices()[:2], True)
-    assert engine.zero.layer_stacked_prefixes == ("layers",)
-    assert fam.model_config(config, True).plan == (2, 2, depth - 4)
-    want = float(fam.system_step(config, params, ids, jax.devices()[0],
-                                 True)[0])
-    losses = [float(engine.train_batch({"input_ids": ids}))
-              for _ in range(5)]
-    assert losses[0] == pytest.approx(want, abs=0.02)
-    assert losses[-1] < losses[0] - 0.02
-    gauges = engine.telemetry_flush()["gauges"]
-    assert gauges["moe/dropped_rows"] == 0
-    assert 0.05 < gauges["moe/rows_held_share"] < 0.6      # 1/4 at uniform
-    assert gauges["moe/held_slabs"] >= 1.0
-    assert gauges["moe/combine_rows_walked"] >= 1.0
-
-
-def test_the_layers_run_the_kernels_where_flash_is_on():
-    """``use_flash=True`` (the TPU's choice) sends a sliding layer through
-    the window kernels — here in the interpreter — and the full layer
-    through the causal ones, at a KV group of 3 query heads; the outputs are
-    the reference path's."""
-    import dataclasses
-    ids = jnp.asarray(np.random.default_rng(4).integers(0, 256, (1, 128)),
-                      jnp.int32)
-    cfg = smallthinker_tiny(num_hidden_layers=2, sliding_window_layout=[0, 1],
-                            rope_layout=[0, 1], experts_held=4)
-    params = SmallThinkerForCausalLM(cfg).init(jax.random.PRNGKey(0),
-                                               ids)["params"]
-
-    def run(use_flash):
-        model = SmallThinkerForCausalLM(dataclasses.replace(
-            cfg, use_flash=use_flash))
-        fn = lambda p: model.apply({"params": p}, ids, labels=ids)  # noqa
-        return fn(params), jax.grad(fn)(params), str(jax.make_jaxpr(fn)(
-            params))
-
-    (want, want_g, plain), (got, got_g, flash) = run(False), run(True)
-    assert "_flash_attention_swa" in flash \
-        and "_flash_attention_swa" not in plain
-    assert float(got) == pytest.approx(float(want), abs=1e-5)
-    for a, b in zip(jax.tree_util.tree_leaves(got_g),
-                    jax.tree_util.tree_leaves(want_g)):
-        np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-3)

@@ -5,6 +5,8 @@ is one chunk, at a ragged tail, with B / C shared by a group of heads, with
 two heads side by side in a lane block, under strong and weak decay, with
 ``D`` and a ``dt_bias`` in front of the softplus. Float32."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -57,25 +59,57 @@ def _rel(a, b):
     return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
 
+@functools.lru_cache(maxsize=None)
+def _values_and_gradients(form, chunk):
+    """``(w, *args) -> (fn(*args), the gradient of its ``w``-weighted sum in
+    each of ``args``)`` as ONE jitted program; ``fn`` the recurrence
+    (``form`` None) or a form at a chunk. Cached, so the two decays of a shape
+    share the compile."""
+    fn = FORMS[form](chunk) if form else ssd.ssd_recurrence
+
+    def weighted(w, *args):
+        out = fn(*args)
+        return jnp.sum(out * w), out
+
+    @jax.jit
+    def run(w, *args):
+        (_, out), grads = jax.value_and_grad(
+            weighted, argnums=tuple(range(1, 7)), has_aux=True)(w, *args)
+        return out, grads
+    return run
+
+
+@functools.lru_cache(maxsize=None)
+def _primal(form, chunk):
+    """A form's UNDIFFERENTIATED call as its own jitted program: under the
+    kernels' ``custom_vjp`` the program of ``_values_and_gradients`` runs
+    the forward rule (it keeps the states), never the primal kernel call
+    with its own refs and ``out_specs``."""
+    return jax.jit(FORMS[form](chunk))
+
+
+@functools.lru_cache(maxsize=None)
+def _expected(shape, A):
+    """(inputs, weights, the recurrence's output, its six gradients) of a
+    case: the expected side does not depend on the form under test."""
+    B, S, H, P, G, N, _ = SHAPES[shape]
+    args = _inputs(B, S, H, P, G, N, A)
+    w = jax.random.normal(jax.random.PRNGKey(7), (B, S, H, P))
+    return (args, w) + _values_and_gradients(None, None)(w, *args)
+
+
 @pytest.mark.parametrize("A", [1.0, 16.0], ids=["weak_decay", "strong_decay"])
 @pytest.mark.parametrize("shape", SHAPES, ids=str)
 @pytest.mark.parametrize("form", FORMS, ids=str)
 def test_values_and_every_gradient_match_the_recurrence(form, shape, A):
     B, S, H, P, G, N, chunk = SHAPES[shape]
-    args = _inputs(B, S, H, P, G, N, A)
-    scan = FORMS[form](chunk)
-    want = ssd.ssd_recurrence(*args)
-    got = scan(*args)
+    args, w, want, want_grads = _expected(shape, A)
+    got = _primal(form, chunk)(*args)
     assert got.shape == want.shape == (B, S, H, P)
     assert _rel(got, want) < 2e-6
-    w = jax.random.normal(jax.random.PRNGKey(7), want.shape)
-
-    def grads(fn):
-        return jax.grad(lambda *a: jnp.sum(fn(*a) * w),
-                        argnums=tuple(range(6)))(*args)
-
-    for name, a, b in zip("x dt A B C D".split(), grads(scan),
-                          grads(ssd.ssd_recurrence)):
+    kept, grads = _values_and_gradients(form, chunk)(w, *args)
+    assert _rel(kept, want) < 2e-6
+    for name, a, b in zip("x dt A B C D".split(), grads, want_grads):
         assert a.shape == b.shape
         assert _rel(a, b) < 1e-4, (name, _rel(a, b))
 

@@ -51,7 +51,7 @@ def test_zero_stage_trains(stage):
 def test_zero_stage_matches_stage0(stage):
     """Loss and PARAMETERS after five Adam steps agree with stage 0 on the
     plain MLP. The transformer families are held in
-    tests/test_zero_matrix_fp32.py and tests/test_zero_matrix_bf16.py by
+    tests/test_zero_matrix_fp32*.py and tests/test_zero_matrix_bf16*.py by
     one step's GRADIENTS, leaf by leaf, then losses and the gradient norm
     (there Adam turns the rounding noise of the key bias's zero gradient
     into full-size updates, so parameters are no yardstick), and the

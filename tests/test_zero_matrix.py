@@ -5,9 +5,10 @@ The partition invariants read metadata only (``jax.eval_shape`` trees, no
 engine). The collective census compiles the stage-3 step on the CPU's eight
 devices, family by family. The trajectory parity against stage 0 builds
 two engines a case (``tests/zero_matrix.py``) and has a file for each
-precision, ``tests/test_zero_matrix_fp32.py`` and
-``tests/test_zero_matrix_bf16.py``, so that no file holds an xdist worker
-for much over two minutes.
+precision and model class, ``tests/test_zero_matrix_fp32*.py`` and
+``tests/test_zero_matrix_bf16*.py``, so that no file holds an xdist worker
+for much over two minutes: they are the last files ``--dist loadfile``
+deals, and the last file's seconds are the run's tail.
 """
 
 import functools

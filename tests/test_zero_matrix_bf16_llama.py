@@ -1,7 +1,8 @@
 """Trajectory parity against stage 0 (``tests/zero_matrix.py``) under bf16
-compute with bf16 gradients over two micro-batches: GPT-2 in its two layer
-layouts at stages 1, 2 and 3. LLaMA and OLMoE:
-``tests/test_zero_matrix_bf16_llama.py``."""
+compute with bf16 gradients over two micro-batches: LLaMA and OLMoE at stages
+1, 2 and 3. GPT-2: ``tests/test_zero_matrix_bf16.py`` (a file a model class:
+these are the last files ``--dist loadfile`` deals, and what the last file
+takes is the run's tail)."""
 
 import pytest
 
@@ -10,6 +11,6 @@ from tests import zero_matrix
 
 @pytest.mark.parametrize("stage", [1, 2, 3])
 @pytest.mark.parametrize("family", [
-    f for f in zero_matrix.FAMILIES if f.startswith("gpt2")])
+    f for f in zero_matrix.FAMILIES if not f.startswith("gpt2")])
 def test_stage_trajectory_matches_stage0_bf16(family, stage):
     zero_matrix.assert_trajectory_matches_stage0(family, stage, "bf16-gas2")

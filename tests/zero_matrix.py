@@ -1,8 +1,8 @@
 """The engine cases of the ZeRO matrices (``tests/test_zero_matrix.py``'s
-census, ``tests/test_zero_matrix_fp32.py``, ``tests/test_zero_matrix_bf16.py``):
-tiny models of every family the repo trains, an engine over the CPU's
-eight devices, and one step's gradients and a three-step trajectory held
-against stage 0's."""
+census, ``tests/test_zero_matrix_fp32*.py``,
+``tests/test_zero_matrix_bf16*.py``): tiny models of every family the repo
+trains, an engine over the CPU's eight devices, and one step's gradients and
+a three-step trajectory held against stage 0's."""
 
 import functools
 
