@@ -70,8 +70,8 @@ Three kernel families share the same per-tile math (`_fwd_block_step` /
   also writes lse lane-dense ([BH, S / 128, 1, 128] as the whole-row
   kernels store it — a [BH, S, 1] column is 128 x its
   values' size in HBM, which kept a rematted block from holding it:
-  PERF.md, PR 34; the backward kernels turn a block's rows of lse and
-  delta back into columns in VMEM, ``_stat_col``). This is how
+  PERF.md, PR 34; the backward kernels hold the score tile [k, q], where a
+  block's rows of lse and delta are rows as stored, ``_stat_row``). This is how
   single-chip attention training reaches 32k context;
   beyond that, sequence parallelism shards S first
   (deepspeed_tpu/parallel/ring_attention.py). Grouped-query K/V
@@ -94,29 +94,41 @@ Three kernel families share the same per-tile math (`_fwd_block_step` /
   (``_refuse_unequal_widths``). For equal widths every call is what it was.
 
 - **window** (a causal band of ``window`` keys, ``flash_attention(...,
-  window=W)`` with W < S; PR 33): grid (B*H, S / block, steps) where a grid
-  block's whole BAND is one operand block (PR 43) — the
+  window=W)`` with W < S; PR 33): grid (B*H, S / block, steps) forward,
+  where a grid block's whole BAND is one operand block (PR 43) — the
   ``round_up(block + W - 1, block)`` rows of K and V that END at a query
-  block's last row (forward, dq), of Q, dO, lse and delta that START at a
-  key block's first (dkv), read at an element offset (``pl.Element``: a
-  block index times the block, so the compiler sees it lie on a tile's
-  edge) clamped at the sequence's ends — so ``steps`` is 1 and the band's
-  tiles are walked by the loops inside the step, where one aligned chunk a
-  step cost 2.1-3.1 us for a 512 x 512 x 128 tile (PERF.md Findings PR
-  43): 4,608 rows at W 4,096, 1,024 at W 512. A band whose rows pass
+  block's last row, read at an element offset (``pl.Element``: a block
+  index times the block, so the compiler sees it lie on a tile's edge)
+  clamped at the sequence's start — so ``steps`` is 1 and the band's tiles
+  are walked by the loops inside the step, where one aligned chunk a step
+  cost 2.1-3.1 us for a 512 x 512 x 128 tile (PERF.md Findings PR 43):
+  4,608 rows at W 4,096, 1,024 at W 512. A band whose rows pass
   ``_BAND_BYTES`` (or the caller's ``chunk=`` cap) goes in the FEWEST equal
   steps that fit (``_band_plan``), through the raw (o, m, l) carry of the
   chunked family; neither compute nor DMA is spent outside the band.
   Blocks wholly inside the band run unmasked; the edge blocks take the
-  causal and the lower-bound compare (``_band_mask``). Grouped-query K/V
-  are read in place, and the dkv kernel's third dimension walks the KV
-  head's group of query heads, so dk and dv leave per KV head. Scopes
-  ``swa_fwd`` / ``swa_bwd_dq`` / ``swa_bwd_dkv``, gauges
-  ``attention/window_tile_overcompute`` and
-  ``attention/window_tiles_per_grid_step``. A shape the family does not
+  causal and the lower-bound compare (``_band_mask``). The BACKWARD is ONE
+  kernel (``_swa_bwd_kernel``, PR 53; a dq and a dkv kernel before it, which
+  computed every score tile twice): grid (B * kv_heads, S / block_q + lag,
+  head groups x steps). A step holds the KV head's band ONCE and loops its
+  group's query heads' q and dO blocks against it (all 7 or 8 of the cells'
+  groups; fewer where their blocks pass ``_BAND_BYTES``), each tile held
+  [k, q] and computed once — five products — for dq, dk and dv. dq of the
+  step's heads is whole when the band's walk ends and leaves scaled, in the
+  operands' dtype; dk and dv of the KV head accumulate over the query blocks
+  that see a key and over the group's heads in a float32 VMEM RING of the
+  band's rows (``_band_ring``), from which key block ``e`` leaves once, in
+  the operands' dtype, on step ``e + lag`` (``lag = ceil((W - 1) /
+  block_q)``: every query that sees it is done) — so the grid runs ``lag``
+  steps past the last query block, which compute nothing — and no
+  per-query-head dk / dv, no float32 gradient and no per-tile partial
+  reaches HBM. Grouped-query K/V are read in place. Scopes ``swa_fwd`` /
+  ``swa_bwd``, gauges ``attention/window_tile_overcompute``,
+  ``attention/window_tiles_per_grid_step`` and
+  ``attention/window_bwd_tiles_per_grid_step``. A shape the family does not
   take raises. Measured on a v5e at (S 16,384, head_dim 128, 64 / 8 heads,
   W 512: Laguna's cell; 28 / 4 heads, W 4,096: SmallThinker's): PERF.md
-  Findings PR 33 and PR 43.
+  Findings PR 33, PR 43 and PR 53.
 
 What a score tile costs beside its two (five, backward) MXU products is
 what these kernels are written around (per 512 x 512 tile at D=64 the
@@ -354,18 +366,6 @@ def _stat_row(ref, head, row0, rows):
                  for j in range(rows // piece)], 1)
 
 
-def _stat_col(ref, head, row0, rows):
-    """``_stat_row``'s values as a [rows, 1] column, for a score tile held
-    [q, k] (the chunked and the window backward kernels): each piece's row
-    spread down the sublanes, its diagonal kept and summed along the lanes
-    — ``_dense_row`` the other way round."""
-    piece = ref.shape[-1]
-    eye = _rel_pos(piece, piece) == 0
-    return _cat([jnp.sum(jnp.where(eye, ref[head + (row0 // piece + j,)],
-                                   0.0), axis=1, keepdims=True)
-                 for j in range(rows // piece)], 0)
-
-
 def _stat_spec(rows, piece, index):
     """BlockSpec of ``rows`` rows of a [BH, S / piece, 1, piece] statistic:
     ``index`` maps the grid to the (row of BH, block of ``rows``) it reads
@@ -435,10 +435,10 @@ def _bwd_ds_block(a, da, lse, delta, b, db, mask, scale):
     """(p, ds) for one score tile of the backward, both already cast to
     the dtype their products take them in; dot inputs stay in the native
     dtype (see _fwd_block_step). Either orientation: (q, do, ·, ·, k, v)
-    gives the [q, k] tile with lse/delta as [r, 1] columns (the chunked
-    kernels); (k, v, ·, ·, q, do) gives its TRANSPOSE, [k, q], with
-    lse/delta as [1, n] rows that spread over sublanes for nothing (the
-    whole-row kernel: dv = pᵀ·do and dk = dsᵀ·q are then plain products).
+    gives the [q, k] tile with lse/delta as [r, 1] columns; (k, v, ·, ·,
+    q, do) gives its TRANSPOSE, [k, q], with lse/delta as [1, n] rows that
+    spread over sublanes for nothing (every backward kernel of the file: dv
+    = pᵀ·do and dk = dsᵀ·q are then plain products).
     ds is d(loss)/d(s) with s = scale·q·kᵀ, so dq = scale·(ds·k) and
     dk = scale·(dsᵀ·q) — callers apply the final ·scale once on the
     accumulated result (dk's rides a pre-scaled q where the scale folds).
@@ -1312,49 +1312,68 @@ def _band_mask(rel, q_pos0, k_pos0, window):
         & (rel < window + k_pos0 - q_pos0)
 
 
-# bytes of ONE band operand's block (K or V forward and dq, Q or dO dkv) a
-# grid step of the window kernels may hold: two such operands, double
+# bytes of ONE band operand's block a grid step of the window kernels may
+# hold — the band's rows of K or V, forward and backward; the ``heads`` query
+# heads' [block_q, D] blocks of Q or dO, backward: two such operands, double
 # buffered, lie beside the score tiles in scoped VMEM. 4,608 rows of
 # head_dim 128 in bf16 (W 4,096 under blocks of 512: 1.18 MB, 4.7 MB in all)
 # fit whole; a band past the budget goes in the fewest steps that fit
 _BAND_BYTES = 2 * 2 ** 20
 
 
-def _band_tiles(S, block, tile, window, keys):
-    """STATIC count of ``tile``-row blocks a grid block's band touches, the
-    most over the blocks. ``keys``: the block is ``block`` query rows and
-    the band the keys ``[p0 - window + 1, p0 + block)`` it sees (forward,
-    dq); else the block is key rows and the band the queries
-    ``[p0, p0 + block + window - 1)`` that see it (dkv).
-    ``ceil((block + window - 1) / tile)``, one more where a band can
-    straddle a tile's edge, and never more than the sequence holds."""
-    most = 0
-    for p0 in range(0, S, block):
-        lo, hi = ((max(p0 - window + 1, 0), p0 + block - 1) if keys
-                  else (p0, min(p0 + block + window - 2, S - 1)))
-        most = max(most, hi // tile - lo // tile + 1)
-    return most
+def _band_tile_counts(S, block_q, block_k, window):
+    """``block_k``-row key blocks the band of each query block touches: the
+    block is ``block_q`` query rows from ``p0`` and the band the keys
+    ``[p0 - window + 1, p0 + block_q)`` it sees."""
+    return [(p0 + block_q - 1) // block_k
+            - max(p0 - window + 1, 0) // block_k + 1
+            for p0 in range(0, S, block_q)]
+
+
+def _band_tiles(S, block_q, block_k, window):
+    """STATIC count of key blocks a query block's band touches, the most
+    over the blocks: ``ceil((block_q + window - 1) / block_k)``, one more
+    where a band can straddle a tile's edge, and never more than the
+    sequence holds."""
+    return max(_band_tile_counts(S, block_q, block_k, window))
+
+
+def _band_ring(S, block_q, block_k, window, lag):
+    """Rows of the backward's dk / dv ring: the widest span of key rows a
+    query block's step can touch — from the oldest block that has not left
+    (``lag`` query blocks back), or the band's first key tile where that
+    starts lower, to the end of the tile that holds the diagonal — in whole
+    blocks of both sizes, so that neither a key tile nor a leaving block
+    wraps; a ring as long as the sequence does not turn at all."""
+    most = max(
+        -(-(i + 1) * block_q // block_k) * block_k
+        - min(max(i - lag, 0) * block_q,
+              max(i * block_q - window + 1, 0) // block_k * block_k)
+        for i in range(S // block_q))
+    unit = math.lcm(block_q, block_k)
+    return min(-(-most // unit) * unit, S)
 
 
 def _band_plan(S, block_q, block_k, window, row_bytes, rep=1, chunk=0):
     """((tiles a grid step, grid steps) of a query block's walk over its
-    band of keys — forward, dq — and (tiles, steps, heads a step) of a key
-    block's over its band of queries — dkv): the band is ONE operand block
-    where its rows fit ``_BAND_BYTES`` (``chunk``: the caller's own cap, in
-    rows), else the fewest equal steps that do; and a dkv step takes as many
-    of the KV head's ``rep`` query heads as fit it with the band whole (the
-    most that divide ``rep``)."""
-    def walk(tiles, tile):
-        cap = max((chunk or _BAND_BYTES // row_bytes) // tile, 1)
-        steps = -(-tiles // cap)
-        return -(-tiles // steps), steps
-    per, steps = walk(_band_tiles(S, block_k, block_q, window, False),
-                      block_q)
-    heads = 1 if steps > 1 else max(
-        h for h in range(1, rep + 1) if rep % h == 0
-        and (h == 1 or h * per * block_q * row_bytes <= _BAND_BYTES))
-    return (walk(_band_tiles(S, block_q, block_k, window, True), block_k),
-            (per, steps, heads))
+    band of keys — forward and backward alike — and (lag, ring rows, heads a
+    step) of the backward): the band is ONE operand block where its rows fit
+    ``_BAND_BYTES`` (``chunk``: the caller's own cap, in rows), else the
+    fewest equal steps that do. The backward walks the query blocks too, a KV
+    head's ``rep`` query heads inside a step — as many as fit the budget
+    with their [block_q, D] blocks (the most that divide ``rep``) — with dk
+    and dv in a float32 ring of ``_band_ring`` rows: key block ``e`` (of
+    ``block_q`` rows) has been seen by every query once query block
+    ``e + lag`` is done, ``lag = ceil((window - 1) / block_q)``, and leaves
+    then, so the grid runs ``lag`` steps past the sequence's last block."""
+    tiles = _band_tiles(S, block_q, block_k, window)
+    cap = max((chunk or _BAND_BYTES // row_bytes) // block_k, 1)
+    steps = -(-tiles // cap)
+    lag = -(-(window - 1) // block_q)
+    heads = max(h for h in range(1, rep + 1) if rep % h == 0
+                and (h == 1 or h * block_q * row_bytes <= _BAND_BYTES))
+    return ((-(-tiles // steps), steps),
+            (lag, _band_ring(S, block_q, block_k, window, lag), heads))
 
 
 def _band_k_first(i, c, block_q, block_k, walk):
@@ -1364,14 +1383,6 @@ def _band_k_first(i, c, block_q, block_k, walk):
     key 0, and the kernel walks what of the step lies in the sequence)."""
     per, steps = walk
     return ((i + 1) * block_q - 1) // block_k + 1 - (steps - c) * per
-
-
-def _band_q_first(i, c, block_q, block_k, per):
-    """Query block that grid step ``c`` (of ``per`` blocks) of key block
-    ``i``'s walk starts at: the steps START at the block that holds the
-    block's diagonal, so the last blocks' steps run past the sequence (the
-    operand then ends at its last query)."""
-    return (i * block_k) // block_q + c * per
 
 
 def _band_k_ranges(q0, first, per, block_q, block_k, window):
@@ -1390,21 +1401,6 @@ def _band_k_ranges(q0, first, per, block_q, block_k, window):
                      for x in (lo, a, b, hi))
 
 
-def _band_q_ranges(k0, first, per, block_q, block_k, window, seq_len):
-    """``_band_k_ranges`` for the key block at ``k0`` and a grid step of
-    ``per`` query blocks from ``first`` (``_band_q_first``): blocks
-    [j_lo, j_a) hold the diagonal, [j_a, j_b) lie wholly inside the band,
-    [j_b, j_hi) hold its lower edge."""
-    lo = k0 // block_q
-    hi = jnp.minimum((k0 + block_k + window - 2) // block_q + 1,
-                     seq_len // block_q)
-    a = jnp.clip((k0 + block_k + block_q - 2) // block_q, lo, hi)
-    b = jnp.clip((k0 + window) // block_q, a, hi)
-    at = jnp.minimum(first, seq_len // block_q - per)
-    return at, tuple(jnp.clip(x, first, first + per) - at
-                     for x in (lo, a, b, hi))
-
-
 def _band_loop(ranges, body, carry):
     """fori_loop over a step's blocks: edge (masked), inside (unmasked),
     edge (masked)."""
@@ -1416,33 +1412,18 @@ def _band_loop(ranges, body, carry):
     return jax.lax.fori_loop(j_b, j_hi, lambda j, c: body(j, c, True), carry)
 
 
-def _band_spec(rows, D, at, heads=1):
-    """BlockSpec of the ``rows`` rows of ``heads`` consecutive rows of a
-    [BH, S, D] operand that hold a grid step's band: ``at`` maps the grid to
-    (first row of BH, first row of S) — ELEMENT offsets, the sequence's a
-    block times its size so the compiler sees it lie on a tile's edge — and
-    the kernel's ref is [heads, rows, D]."""
-    return pl.BlockSpec((pl.Element(heads), pl.Element(rows), pl.Element(D)),
-                        lambda *g: tuple(at(*g)) + (0,))
-
-
-def _band_keys_spec(walk, block_q, block_k, D, kv):
-    """K / V operand of the forward and dq calls, grid (b, i, c): the rows of
-    step ``c`` of query block ``i``'s ``walk``, of KV row ``kv(b)``."""
-    return _band_spec(walk[0] * block_k, D, lambda b, i, c: (
-        kv(b), jnp.maximum(_band_k_first(i, c, block_q, block_k, walk), 0)
-        * block_k))
-
-
-def _band_stat_spec(rows, piece, at, heads=1):
-    """``_band_spec`` of a [BH, S / piece, 1, piece] statistic (``at``'s
-    first row in rows of the sequence, a multiple of the piece's): the
-    kernel's ref is [heads, rows / piece, 1, piece]."""
+def _band_keys_spec(walk, block_q, block_k, D, at):
+    """K / V operand of the window calls: the rows of one step of a query
+    block's ``walk``, ``at`` mapping the grid to (KV row, query block, step
+    of the walk). The block sits at ELEMENT offsets — the sequence's a key
+    block's index times its size, so the compiler sees it lie on a tile's
+    edge — and the kernel's ref is [1, rows, D]."""
     def index(*g):
-        b, row = at(*g)
-        return b, row // piece, 0, 0
-    return pl.BlockSpec((pl.Element(heads), pl.Element(rows // piece),
-                         pl.Element(1), pl.Element(piece)), index)
+        row, i, c = at(*g)
+        return row, jnp.maximum(
+            _band_k_first(i, c, block_q, block_k, walk), 0) * block_k, 0
+    return pl.BlockSpec((pl.Element(1), pl.Element(walk[0] * block_k),
+                         pl.Element(D)), index)
 
 
 def _walk_phase(c, steps):
@@ -1509,8 +1490,9 @@ def _swa_fwd(q, k, v, scale, window, block_q, block_k, band, interpret,
     """``band``: ``_band_plan``'s two walks; the forward takes the first."""
     BH, S, D = q.shape
     walk = _, steps = band[0]
+    kv = _kv_row(heads, kv_heads)
     keys = _band_keys_spec(walk, block_q, block_k, D,
-                           _kv_row(heads, kv_heads))
+                           lambda b, i, c: (kv(b), i, c))
     out_specs, out_shape, scratch = _chunked_fwd_outputs(
         q, block_q, block_k, lambda b, i, c: (b, i))
     call = pl.pallas_call(
@@ -1529,156 +1511,164 @@ def _swa_fwd(q, k, v, scale, window, block_q, block_k, band, interpret,
     return o32.astype(q.dtype), lse
 
 
-def _swa_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                       dq_ref, *, scale, window, block_q, block_k, walk):
-    qi = pl.program_id(1)
-    c = pl.program_id(2)
-    first, last = _walk_phase(c, walk[1])
-    fold = _scale_folds(scale)
-    s_scale = None if fold else scale
-    q = q_ref[0] * scale if fold else q_ref[0]
-    do = do_ref[0]
-    lse = _stat_col(lse_ref, (0,), 0, block_q)
-    delta = _stat_col(delta_ref, (0,), 0, block_q)
-    rel = _rel_pos(block_q, block_k)
-    q0 = qi * block_q
-    at, ranges = _band_k_ranges(
-        q0, _band_k_first(qi, c, block_q, block_k, walk), walk[0], block_q,
-        block_k, window)
+def _swa_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                    dk_ref, dv_ref, dk_ring, dv_ring, *dq_acc, scale, window,
+                    block_q, block_k, walk, plan, groups, seq_len):
+    """Single-pass window backward: grid (KV head, query block, head group x
+    step of the band), and each score tile of the band — the two dots AND the
+    exp — is computed ONCE for all three gradients (five MXU products a tile,
+    where a dq and a dkv kernel ran seven and the softmax chain twice), as
+    ``_bwd_kernel_chunked`` does for a causal chunk. A step holds one step of
+    the KV head's band of K and V and loops ``heads`` of its group's query
+    heads' [block_q, D] q and dO blocks against it; a head's dq is whole
+    when the band's walk ends and leaves scaled, in the operands' dtype. dk
+    and dv of the KV head accumulate — over the query blocks that see a key
+    and over the group's heads — in two float32 rings of ``ring`` rows, key
+    row r in slot r mod ring: after query block ``i`` every key below
+    ``(i + 1) * block_q - window + 1`` is final, so block ``i - lag`` (of
+    ``block_q`` rows) leaves, once, in the operands' dtype, and its slot is
+    zeroed for the rows that take it next; the ``lag`` steps past the
+    sequence's last query block run no product and only let the last
+    blocks out.
 
-    def body(j, carry, masked):
-        k = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        mask = (_band_mask(rel, q0, (at + j) * block_k, window) if masked
-                else None)
-        _, ds = _bwd_ds_block(q, do, lse, delta, k, v, mask, s_scale)
-        return carry[0] + jax.lax.dot(ds, k,
-                                      preferred_element_type=jnp.float32),
-
-    dq, = _band_loop(ranges, body,
-                     _walk_carry(first, ((dq_ref, 0, q.shape, 0.0),)))
-    # unscaled across a block's walk, the folded-scale chain rule once on
-    # its last step
-    dq_ref[0] = dq * scale if last is True else jnp.where(last, dq * scale,
-                                                          dq)
-
-
-def _swa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        dk_ref, dv_ref, *, scale, window, block_q, block_k,
-                        walk, rep, seq_len):
-    """One KV head's block: the third grid dimension walks its group's
-    ``rep`` query heads, ``heads`` of them and one of the band's steps a
-    grid step, and dk, dv of the KV head accumulate over all of them — in
-    the carry inside a step, in the revisited output block between steps
-    (the causal chunked kernel leaves them per QUERY head, for XLA to
-    sum)."""
-    ki = pl.program_id(1)
+    The tile is held TRANSPOSED, [k, q], as the chunked and the whole-row
+    kernels hold it: lse and delta are the lane-dense rows they are stored
+    as, and of p·do, ds·q and dsᵀ·k only the last needs its left operand
+    turned."""
+    i = pl.program_id(1)
     t = pl.program_id(2)
-    per, steps, heads = walk
-    first, last = _walk_phase(t, rep // heads * steps)
-    k0 = ki * block_k
+    per, steps = walk
+    lag, ring, heads = plan
+    dq_acc, = dq_acc or (None,)         # none for a band in one step
+    c = 0 if steps == 1 else t % steps
+    first, last = _walk_phase(c, steps)
     fold = _scale_folds(scale)
     s_scale = None if fold else scale
-    k = k_ref[0]
-    v = v_ref[0]
-    rel = _rel_pos(block_q, block_k)
-    at, ranges = _band_q_ranges(
-        k0, _band_q_first(ki, t % steps, block_q, block_k, per), per,
-        block_q, block_k, window, seq_len)
+    rel = -_rel_pos(block_k, block_q)               # query - key
+    q0 = i * block_q
 
-    def body(h, j, carry, masked):
-        dk_acc, dv_acc = carry
-        q = q_ref[h, pl.ds(j * block_q, block_q), :]
-        if fold:
-            q = q * scale
-        do = do_ref[h, pl.ds(j * block_q, block_q), :]
-        lse = _stat_col(lse_ref, (h,), j * block_q, block_q)
-        delta = _stat_col(delta_ref, (h,), j * block_q, block_q)
-        mask = (_band_mask(rel, (at + j) * block_q, k0, window) if masked
-                else None)
-        p, ds = _bwd_ds_block(q, do, lse, delta, k, v, mask, s_scale)
-        dv_new = dv_acc + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_new = dk_acc + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk_new, dv_new
+    @pl.when((i == 0) & (t == 0))
+    def _clear():
+        dk_ring[...] = jnp.zeros_like(dk_ring)
+        dv_ring[...] = jnp.zeros_like(dv_ring)
 
-    carry = _walk_carry(first, (
-        (dk_ref, 0, k.shape, 0.0), (dv_ref, 0, k.shape, 0.0)))
-    if heads == 1:
-        dk, dv = _band_loop(ranges, functools.partial(body, 0), carry)
-    else:
-        dk, dv = jax.lax.fori_loop(0, heads, lambda h, c: _band_loop(
-            ranges, functools.partial(body, h), c), carry)
-    dk_ref[0] = (dk if fold else dk * scale if last is True
-                 else jnp.where(last, dk * scale, dk))
-    dv_ref[0] = dv
+    @pl.when(i < seq_len // block_q)
+    def _tiles():
+        at, ranges = _band_k_ranges(
+            q0, _band_k_first(i, c, block_q, block_k, walk), per, block_q,
+            block_k, window)
+        if first is not True:
+            # dq of the step's heads, between the steps of a band's walk
+            @pl.when(first)
+            def _start():
+                dq_acc[...] = jnp.zeros_like(dq_acc)
+
+        def head(h, _):
+            q = q_ref[h] * scale if fold else q_ref[h]
+            do = do_ref[h]
+            lse = _stat_row(lse_ref, (h,), 0, block_q)
+            delta = _stat_row(delta_ref, (h,), 0, block_q)
+
+            def body(j, dq, masked):
+                rows = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+                k = k_ref[0, rows, :]
+                mask = (_band_mask(rel, q0, (at + j) * block_k, window)
+                        if masked else None)
+                p, ds = _bwd_ds_block(k, v_ref[0, rows, :], lse, delta, q, do,
+                                      mask, s_scale)
+                slot = pl.ds(pl.multiple_of(((at + j) * block_k) % ring,
+                                            block_k), block_k)
+                dv_ring[slot, :] += jax.lax.dot(
+                    p, do, preferred_element_type=jnp.float32)
+                dk_ring[slot, :] += jax.lax.dot(
+                    ds, q, preferred_element_type=jnp.float32)
+                return dq + jax.lax.dot_general(
+                    ds, k, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+
+            dq = _band_loop(ranges, body, jnp.zeros(q.shape, jnp.float32)
+                            if first is True else dq_acc[h])
+
+            # dq = scale · Σ ds·k: once, where the band's walk ends
+            def _whole():
+                dq_ref[h] = (dq * scale).astype(dq_ref.dtype)
+
+            if last is True:
+                _whole()
+            else:
+                dq_acc[h] = dq
+                pl.when(last)(_whole)
+            return 0
+
+        jax.lax.fori_loop(0, heads, head, 0)
+
+    @pl.when((i >= lag) & (t == groups * steps - 1))
+    def _leave():
+        slot = pl.ds(pl.multiple_of(((i - lag) * block_q) % ring, block_q),
+                     block_q)
+        # dk = scale · Σ dsᵀ·q: a pre-scaled q has carried it
+        dk = dk_ring[slot, :] if fold else dk_ring[slot, :] * scale
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv_ring[slot, :].astype(dv_ref.dtype)
+        dk_ring[slot, :] = jnp.zeros((block_q, dk_ring.shape[1]), jnp.float32)
+        dv_ring[slot, :] = jnp.zeros((block_q, dv_ring.shape[1]), jnp.float32)
 
 
 def _swa_bwd(q, k, v, o, lse, do, scale, window, block_q, block_k, band,
              interpret, heads, kv_heads):
-    """(dq [B * heads, S, D], dk, dv [B * kv_heads, S, D])."""
+    """(dq [B * heads, S, D], dk, dv [B * kv_heads, S, D]), all in the
+    operands' dtype, from ONE call. ``band``: ``_band_plan``'s pair."""
     BH, S, D = q.shape
     BHkv = k.shape[0]
-    rep = BH // BHkv
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1).reshape(lse.shape)
     piece = lse.shape[-1]
-    walk = _, steps = band[0]
+    walk, plan = band
+    steps = walk[1]
+    lag, ring, per_step = plan
+    groups = BH // BHkv // per_step
+    nq = S // block_q
+    if 2 * ring * max(D, _LANES) * 4 > _BWD_VMEM_BYTES // 2:
+        raise ValueError(
+            f"window attention (window={window}) over S={S}, head_dim {D}: "
+            f"the backward's dk / dv ring of {ring} rows passes half the "
+            f"{_BWD_VMEM_BYTES} bytes of scoped VMEM the kernel may take; "
+            "shard the sequence first (parallel/ring_attention.py)")
+
+    def held(b, i, t):
+        """(head group, query block, step of the band) of a grid step; the
+        flush steps stay on the last of each, so nothing is fetched."""
+        live = i < nq
+        return (b * groups + jnp.where(live, t // steps, groups - 1),
+                jnp.minimum(i, nq - 1), jnp.where(live, t % steps, steps - 1))
+
     keys = _band_keys_spec(walk, block_q, block_k, D,
-                           _kv_row(heads, kv_heads))
-    call_dq = pl.pallas_call(
-        functools.partial(_swa_bwd_dq_kernel, scale=scale, window=window,
-                          block_q=block_q, block_k=block_k, walk=walk),
-        grid=(BH, S // block_q, steps),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-            keys, keys,
-            pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-        ] + [_stat_spec(block_q, piece, lambda b, i, c: (b, i))] * 2,
-        out_specs=pl.BlockSpec((1, block_q, D), lambda b, i, c: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, S, D), jnp.float32),
-        interpret=interpret,
-    )
-    with annotate("swa_bwd_dq"):
-        dq = call_dq(q, k, v, do, lse, delta)
-
-    walk = per, steps, heads = band[1]
-
-    def queries(b, i, t):
-        # KV row b's group: query heads [b * rep, (b + 1) * rep) (``_kv_row``
-        # the other way round), ``heads`` of them from t // steps on
-        return (b * rep + t // steps * heads, jnp.minimum(
-            _band_q_first(i, t % steps, block_q, block_k, per),
-            S // block_q - per) * block_q)
-
-    rows = per * block_q
-    call_dkv = pl.pallas_call(
-        functools.partial(_swa_bwd_dkv_kernel, scale=scale, window=window,
+                           lambda b, i, t: (b,) + held(b, i, t)[1:])
+    rows = pl.BlockSpec((per_step, block_q, D),
+                        lambda b, i, t: held(b, i, t)[:2] + (0,))
+    stat = pl.BlockSpec((per_step, block_q // piece, 1, piece),
+                        lambda b, i, t: held(b, i, t)[:2] + (0, 0))
+    left = pl.BlockSpec((1, block_q, D),
+                        lambda b, i, t: (b, jnp.maximum(i - lag, 0), 0))
+    call = pl.pallas_call(
+        functools.partial(_swa_bwd_kernel, scale=scale, window=window,
                           block_q=block_q, block_k=block_k, walk=walk,
-                          rep=rep, seq_len=S),
-        grid=(BHkv, S // block_k, rep // heads * steps),
-        in_specs=[
-            _band_spec(rows, D, queries, heads),
-            pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
-            _band_spec(rows, D, queries, heads),
-        ] + [_band_stat_spec(rows, piece, queries, heads)] * 2,
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, i, t: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BHkv, S, D), jnp.float32),
-            jax.ShapeDtypeStruct((BHkv, S, D), jnp.float32),
-        ],
+                          plan=plan, groups=groups, seq_len=S),
+        grid=(BHkv, nq + lag, groups * steps),
+        in_specs=[rows, keys, keys, rows, stat, stat],
+        out_specs=[rows, left, left],
+        out_shape=[jax.ShapeDtypeStruct((BH, S, D), q.dtype),
+                   jax.ShapeDtypeStruct((BHkv, S, D), k.dtype),
+                   jax.ShapeDtypeStruct((BHkv, S, D), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((ring, D), jnp.float32)] * 2
+        + ([pltpu.VMEM((per_step, block_q, D), jnp.float32)]
+           if steps > 1 else []),
         interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            vmem_limit_bytes=_BWD_VMEM_BYTES),
     )
-    with annotate("swa_bwd_dkv"):
-        dk, dv = call_dkv(q, k, v, do, lse, delta)
-    return dq.astype(q.dtype), dk.astype(q.dtype), dv.astype(q.dtype)
+    with annotate("swa_bwd"):
+        return tuple(call(q, k, v, do, lse, delta))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
@@ -1804,37 +1794,33 @@ def tile_overcompute(S, block_q, block_k, chunk, causal):
 
 
 def _window_tiles(S, block_q, block_k, window):
-    """Score tiles a head's band takes: (over its query blocks' walks —
-    the forward's, and dq's again — , over its key blocks' — dkv's)."""
-    return (sum((q0 + block_q - 1) // block_k
-                - max(q0 - window + 1, 0) // block_k + 1
-                for q0 in range(0, S, block_q)),
-            sum(min((k0 + block_k + window - 2) // block_q + 1, S // block_q)
-                - k0 // block_q for k0 in range(0, S, block_k)))
+    """Score tiles a head's band takes in one walk over its query blocks:
+    the forward's, and the single-pass backward's again."""
+    return sum(_band_tile_counts(S, block_q, block_k, window))
 
 
 def window_tile_overcompute(S, block_q, block_k, window):
     """``tile_overcompute`` for the window kernels: score elements of the
-    blocks the band's walks touch (a walk over the query blocks — forward,
-    dq — and one over the key blocks — dkv) over the ``S*W - W(W-1)/2``
-    elements a head's band holds, twice. Blocks of 512 at W 512 compute
-    2.0 x, 256 1.5 x, 128 1.25 x."""
+    blocks the band's two walks touch (forward and backward, each over the
+    query blocks) over the ``S*W - W(W-1)/2`` elements a head's band holds,
+    twice. Blocks of 512 at W 512 compute 2.0 x, 256 1.5 x, 128 1.25 x."""
     window = min(window, S)
-    return sum(_window_tiles(S, block_q, block_k, window)) * block_q \
-        * block_k / (2 * (S * window - window * (window - 1) // 2))
+    return _window_tiles(S, block_q, block_k, window) * block_q * block_k \
+        / (S * window - window * (window - 1) // 2)
 
 
 def window_tiles_per_grid_step(S, block_q, block_k, window, band):
-    """Score tiles the three window calls of a head compute over the grid
-    steps they take under ``band`` (``_band_plan``): a band in one step
-    reads its tile count less what the sequence's first blocks clip —
-    7.9 of 9 at S 16,384 / W 4,096 / 512, 1.97 of 2 at W 512 — where one
-    tile a step read under 1."""
-    over_keys, over_queries = _window_tiles(S, block_q, block_k,
-                                            min(window, S))
-    (_, steps_k), (_, steps_q, heads) = band
-    return (2 * over_keys + over_queries) / (
-        2 * (S // block_q) * steps_k + (S // block_k) * steps_q / heads)
+    """Score tiles the two window calls of a head compute over the grid
+    steps they take under ``band`` (``_band_plan``): a forward step is a
+    query block's band — its tile count less what the sequence's first
+    blocks clip, 7.9 of 9 at S 16,384 / W 4,096 / 512 — and a backward step
+    that of the ``heads`` query heads it holds, over the ``lag`` steps more
+    that let the last key blocks out: 13.4 a step at that shape with 7
+    heads, 3.5 at W 512 with 8, where one tile a step read under 1."""
+    (_, steps), (lag, _, heads) = band
+    blocks = S // block_q
+    return 2 * _window_tiles(S, block_q, block_k, min(window, S)) / (
+        blocks * steps + (blocks + lag) * steps / heads)
 
 
 def grid_steps_walked(S, block_q, block_k, chunk, causal):
@@ -1866,8 +1852,12 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
     per distinct shape, a log line of the layout (the
     operands' and the log-sum-exp's) and loop structure chosen for it.
     ``window``: a call of the window kernels under ``band``
-    (``_band_plan``) — the gauges ``attention/window_tile_overcompute`` and
-    ``attention/window_tiles_per_grid_step`` and the band's plan instead.
+    (``_band_plan``) — the gauges ``attention/window_tile_overcompute``,
+    ``attention/window_tiles_per_grid_step`` and
+    ``attention/window_bwd_tiles_per_grid_step`` (query heads a backward
+    step holds x tiles of the band's step: 63 at W 4,096 / 7 heads, 16 at
+    W 512 / 8; a plan that fell to fewer heads or more steps reads lower)
+    and the band's plan instead.
     ``value_dim``: the value width of a call whose q·k width ``D`` is another
     (latent attention) — the gauges ``attention/mla_qk_dim`` and
     ``attention/mla_v_dim``, the widths as the kernels saw them."""
@@ -1879,18 +1869,20 @@ def _note_plan(S, D, dtype, scale, causal, block_q, block_k, chunk,
             over)
         default_registry().gauge("attention/window_tiles_per_grid_step").set(
             tiles)
+        (per, steps), (lag, ring, heads) = band
+        default_registry().gauge(
+            "attention/window_bwd_tiles_per_grid_step").set(heads * per)
         plan = (S, D, jnp.dtype(dtype).name, window, block_q, block_k, band)
         if plan not in _plans_logged:
             _plans_logged.add(plan)
-            (per_k, steps_k), (per_q, steps_q, heads) = band
             logger.info(
                 f"flash attention S={S} D={D} {plan[2]} window={window}: "
                 f"layout [B*H, S, D] head-major, lse [B*H, S/{piece}, 1, "
                 f"{piece}], block_q={block_q} block_k={block_k}, a query "
-                f"block's band is {steps_k} grid step(s) of "
-                f"{per_k * block_k} keys, a key block's {steps_q} of "
-                f"{per_q * block_q} queries for {heads} head(s) of its "
-                f"group, "
+                f"block's band is {steps} grid step(s) of "
+                f"{per * block_k} keys; the backward holds {heads} head(s) "
+                f"of a group a step, 5 products a tile, dk and dv in a ring "
+                f"of {ring} rows that lets a block out {lag} step(s) on, "
                 f"{tiles:.2f} score tiles a grid step, scale "
                 f"{'on q' if _scale_folds(scale) else 'on scores'}"
                 f", computes {over:.3f} x the band's scores")

@@ -134,32 +134,31 @@ def run_with_jaxpr(fn, *args):
     return traced.lower().compile()(*args), str(traced.jaxpr)
 
 
+def pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr and of the jaxprs inside it
+    (a remat, a custom VJP's rules), in program order."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from pallas_calls(sub)
+
+
 def pallas_element_rows(jaxpr):
     """Rows of every [rows, D] operand block a ``pallas_call`` under a jaxpr
     reads at an ELEMENT offset (``pl.Element``: the window kernels' bands),
     in program order."""
     from jax.experimental import pallas as pl
-    rows = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            rows += [bm.block_shape[1].block_size
-                     for bm in eqn.params["grid_mapping"].block_mappings
-                     if len(bm.block_shape) == 3
-                     and isinstance(bm.block_shape[1], pl.Element)]
-        else:
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                rows += pallas_element_rows(sub)
-    return rows
+    return [bm.block_shape[1].block_size
+            for eqn in pallas_calls(jaxpr)
+            for bm in eqn.params["grid_mapping"].block_mappings
+            if len(bm.block_shape) == 3
+            and isinstance(bm.block_shape[1], pl.Element)]
 
 
 def pallas_grids(jaxpr):
     """The grid of every ``pallas_call`` of a jaxpr and of the jaxprs inside
-    it (a remat, a custom VJP's rules), in program order."""
-    grids = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            grids.append(tuple(eqn.params["grid_mapping"].grid))
-        else:
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                grids += pallas_grids(sub)
-    return grids
+    it, in program order."""
+    return [tuple(eqn.params["grid_mapping"].grid)
+            for eqn in pallas_calls(jaxpr)]

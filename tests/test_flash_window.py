@@ -10,14 +10,16 @@ import pytest
 
 from deepspeed_tpu.ops.attention import reference_attention
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
-from tests.hlo_text import pallas_element_rows, pallas_grids
+from tests.hlo_text import pallas_calls, pallas_element_rows, pallas_grids
 from tests.flash_cases import _fa, _qkv
 
 
 # ------------------------------------------------------------------------
 # the window kernels (ISSUE 33): a causal band of ``window`` keys; since
 # ISSUE 43 a block's whole band is ONE operand block at an element offset
-# (``chunk=``: a cap on its rows, which puts a band into several grid steps)
+# (``chunk=``: a cap on its rows, which puts a band into several grid steps);
+# since ISSUE 53 the backward is ONE call that computes a score tile once,
+# a KV head's query heads inside a step and its dk / dv in a float32 ring
 
 def _window_case(S, H, Hkv, W, block_q, block_k, chunk, dtype=jnp.float32,
                  D=32):
@@ -94,32 +96,90 @@ def test_a_window_that_covers_the_sequence_is_causal_attention(W):
         np.asarray(flash_attention(q, k, v, **kw)))
 
 
-@pytest.mark.parametrize("S,W,block,chunk,rows,steps,tiles", [
-    (1024, 64, 64, None, 128, 1, 2.325),     # round_up(64 + 63, 64) rows
-    (1024, 128, 64, None, 192, 1, 3.375),
-    (1024, 100, 64, None, 192, 1, 3.375),
-    (1024, 64, 64, 64, 64, 2, 0.969),        # a cap of one tile: 2 steps
-    (1024, 512, 64, 256, 192, 3, 2.25),     # 9 tiles under a cap of 4: 3 x 3
-    (16384, 512, 256, None, 768, 1, 3.544),  # Laguna's window at blocks of 256
-    (16384, 512, 128, None, 640, 1, 5.906),
-    (16384, 512, 512, None, 1024, 1, 2.3625),     # the Laguna cell's, 2 heads
-    (16384, 4096, 512, None, 4608, 1, 7.875),    # the SmallThinker cell's
+def _reference_window_grads(q, k, v, do, W):
+    """float32 (dq [H, S, D], dk, dv [Hkv, S, D]) of the masked reference:
+    the group's query heads summed at their KV head."""
+    _, vjp = jax.vjp(lambda *a: functools.partial(
+        reference_attention, causal=True, window=W)(*(t[None] for t in a))[0],
+        q, k, v)
+    return vjp(do)
+
+
+@pytest.mark.parametrize("S,H,Hkv,W,block_q,block_k,chunk,band", [
+    # (tiles a step, steps), (lag, ring rows, heads a step)
+    (512, 7, 1, 288, 64, 64, None, ((6, 1), (5, 384, 7))),   # a group of 7
+    (512, 8, 1, 200, 64, 64, None, ((5, 1), (4, 320, 8))),   # ... and of 8
+    (512, 8, 1, 200, 64, 64, 128, ((2, 3), (4, 320, 8))),    # a band in 3
+    (384, 14, 2, 200, 64, 64, 64, ((1, 5), (4, 320, 7))),    # 2 x 7, 5 steps
+    (512, 4, 2, 130, 128, 64, None, ((5, 1), (2, 384, 2))),  # block_q 2 x
+    (512, 4, 2, 130, 64, 128, None, ((3, 1), (3, 384, 2))),  # block_k 2 x
+    (256, 2, 1, 255, 64, 64, None, ((4, 1), (4, 256, 2))),   # lag: every block
+], ids=lambda v: str(v))
+@pytest.mark.parametrize("where", ["first", "last"])
+def test_a_key_block_leaves_once_with_its_groups_sum(S, H, Hkv, W, block_q,
+                                                     block_k, chunk, band,
+                                                     where):
+    """``_swa_bwd`` itself: ONE call whose dk and dv come back at the KV
+    heads, in the operands' dtype, a group's query heads already summed —
+    block by block the masked reference's at the sequence's FIRST blocks
+    (whose band the sequence's start clips, and which leave while the ring
+    still fills) and at its LAST (which the flush steps past the last query
+    block let out); a block that left twice, early, or from another's slot
+    would read wrong there. dq is whole beside them."""
+    fa = _fa()
+    D = 32
+    assert fa._band_plan(S, block_q, block_k, W, 128 * 4, H // Hkv,
+                         chunk or 0) == band
+    ks = jax.random.split(jax.random.PRNGKey(S + H + W), 4)
+    q, do = (jax.random.normal(key, (H, S, D)) for key in (ks[0], ks[3]))
+    k, v = (jax.random.normal(key, (Hkv, S, D)) for key in ks[1:3])
+    scale = D ** -0.5
+    o, lse = fa._swa_fwd(q, k, v, scale, W, block_q, block_k, band, True, H,
+                         Hkv)
+    got = fa._swa_bwd(q, k, v, o, lse, do, scale, W, block_q, block_k, band,
+                      True, H, Hkv)
+    want = _reference_window_grads(q, k, v, do, W)
+    lag = band[1][0]
+    rows = slice(0, (lag + 1) * block_q) if where == "first" \
+        else slice(S - (lag + 1) * block_q, S)
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        assert a.shape == b.shape and a.dtype == q.dtype, name
+        for r in range(rows.start, rows.stop, block_q):
+            np.testing.assert_allclose(
+                np.asarray(a[:, r:r + block_q]),
+                np.asarray(b[:, r:r + block_q]), rtol=5e-4, atol=5e-5,
+                err_msg=f"{name} rows {r}..{r + block_q}")
+
+
+@pytest.mark.parametrize("S,W,block,chunk,rows,steps,lag,tiles", [
+    (1024, 64, 64, None, 128, 1, 1, 2.531),     # round_up(64 + 63, 64) rows
+    (1024, 128, 64, None, 192, 1, 2, 3.6),
+    (1024, 100, 64, None, 192, 1, 2, 3.6),
+    (1024, 64, 64, 64, 64, 2, 1, 1.266),        # a cap of one tile: 2 steps
+    (1024, 512, 64, 256, 192, 3, 8, 2.571),    # 9 tiles under a cap of 4: 3 x 3
+    (16384, 512, 256, None, 768, 1, 2, 3.897),  # Laguna's window at 256
+    (16384, 512, 128, None, 640, 1, 4, 6.495),
+    (16384, 512, 512, None, 1024, 1, 1, 2.598),    # the Laguna cell's, 2 heads
+    (16384, 4096, 512, None, 4608, 1, 8, 9.692),   # the SmallThinker cell's
 ])
 def test_window_grid_walks_the_static_band_count(S, W, block, chunk, rows,
-                                                 steps, tiles):
+                                                 steps, lag, tiles):
     """A block's band is one operand block of ``rows`` rows: the third grid
-    extent of the forward and dq ``pallas_call``s is the band's step count —
-    1 where the rows fit the budget (or the caller's ``chunk=`` cap) — and
-    of the dkv call that times the group's query heads, never S / block;
-    the two gauges say what the tiles compute and how many a step takes."""
+    extent of the forward ``pallas_call`` is the band's step count — 1 where
+    the rows fit the budget (or the caller's ``chunk=`` cap) — and of the
+    ONE backward call that times the group's query heads over those a step
+    holds; its second runs ``lag`` steps past S / block, for the last key
+    blocks to leave the ring of ``(lag + 1) * block`` rows, and is never a
+    tile count; the three gauges say what the tiles compute and how many a
+    step takes."""
     from deepspeed_tpu.telemetry.registry import default_registry
     fa = _fa()
     H, Hkv = 4, 2
     band = fa._band_plan(S, block, block, W, 128 * 2, H // Hkv,
                          chunk or 0)
-    # both of a group's heads a dkv step where 2 x the band's rows fit
-    heads = 2 if steps == 1 and 2 * rows * 256 <= fa._BAND_BYTES else 1
-    assert band == ((rows // block, steps), (rows // block, steps, heads))
+    # both of a group's heads a backward step: 2 x block rows fit
+    assert band == ((rows // block, steps), (lag, (lag + 1) * block, 2))
+    assert lag == -(-(W - 1) // block)
     if not chunk:
         assert rows == -(-(block + W - 1) // block) * block
     q = jax.ShapeDtypeStruct((1, H, S, 128), jnp.bfloat16)   # the cells'
@@ -128,12 +188,16 @@ def test_window_grid_walks_the_static_band_count(S, W, block, chunk, rows,
         q, k, v, causal=True, window=W, block_q=block, block_k=block,
         chunk=chunk, interpret=True).astype(jnp.float32)),
         argnums=(0, 1, 2)))(q, kv, kv)
-    grids = sorted(pallas_grids(jaxpr.jaxpr))
-    assert grids == sorted([
-        (H, S // block, steps), (H, S // block, steps),
-        (Hkv, S // block, H // Hkv // heads * steps)]), grids
-    # the band's operands: K, V (forward, dq) and Q, dO (dkv)
-    assert pallas_element_rows(jaxpr.jaxpr) == [rows] * 6
+    grids = pallas_grids(jaxpr.jaxpr)
+    assert grids == [(H, S // block, steps),
+                     (Hkv, S // block + lag, steps)], grids
+    # the band's operands: K and V, forward and backward
+    assert pallas_element_rows(jaxpr.jaxpr) == [rows] * 4
+    # dq, dk and dv leave the one call in the operands' dtype, dk and dv at
+    # the KV heads; the ring and nothing else is float32 scratch
+    bwd = list(pallas_calls(jaxpr.jaxpr))[-1]
+    assert [(v.aval.shape, v.aval.dtype) for v in bwd.outvars] == [
+        ((H, S, 128), jnp.bfloat16)] + [((Hkv, S, 128), jnp.bfloat16)] * 2
     over = default_registry().peek_gauge("attention/window_tile_overcompute")
     assert over == pytest.approx(
         fa.window_tile_overcompute(S, block, block, W))
@@ -141,6 +205,8 @@ def test_window_grid_walks_the_static_band_count(S, W, block, chunk, rows,
         "attention/window_tiles_per_grid_step") == pytest.approx(
         fa.window_tiles_per_grid_step(S, block, block, W, band)) \
         == pytest.approx(tiles, abs=0.001)
+    assert default_registry().peek_gauge(
+        "attention/window_bwd_tiles_per_grid_step") == 2 * rows // block
     if (S, W) == (16384, 512):
         assert over == pytest.approx({512: 2.0, 256: 1.5, 128: 1.25}[block],
                                      abs=0.02)
@@ -149,16 +215,17 @@ def test_window_grid_walks_the_static_band_count(S, W, block, chunk, rows,
 
 
 @pytest.mark.parametrize("budget,band", [
-    (2 ** 21, ((5, 1), (5, 1, 2))),     # the module's: 320 rows fit whole
-    (320 * 512, ((5, 1), (5, 1, 1))),   # ... for one head of the two
-    (200 * 512, ((3, 2), (3, 2, 1))),   # 200 rows of 128 float32 lanes
-    (64 * 512, ((1, 5), (1, 5, 1))),    # one tile: the parent's step count
+    (2 ** 21, ((5, 1), (4, 320, 2))),     # the module's: 320 rows fit whole
+    (320 * 512, ((5, 1), (4, 320, 2))),   # ... and so do two heads' blocks
+    (200 * 512, ((3, 2), (4, 320, 2))),   # 200 rows of 128 float32 lanes
+    (64 * 512, ((1, 5), (4, 320, 1))),    # one tile a step, one head's block
 ])
 def test_a_band_past_the_budget_goes_in_the_fewest_steps_that_fit(
         monkeypatch, budget, band):
-    """No knob: the band's rows follow from window, block, head_dim and
-    dtype against ``_BAND_BYTES``, and the steps are the fewest equal ones
-    that fit — out, dq, dk, dv are the reference's either way."""
+    """No knob: the band's rows and the heads a backward step holds follow
+    from window, block, head_dim and dtype against ``_BAND_BYTES``, the
+    steps are the fewest equal ones that fit and the ring stays whole —
+    out, dq, dk, dv are the reference's either way."""
     fa = _fa()
     monkeypatch.setattr(fa, "_BAND_BYTES", budget)
     assert fa._band_plan(512, 64, 64, 200, 128 * 4, 2) == band
@@ -166,6 +233,28 @@ def test_a_band_past_the_budget_goes_in_the_fewest_steps_that_fit(
     for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-4,
                                    atol=5e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("S,block_q,block_k,W,ring", [
+    (16384, 512, 512, 4096, 4608), (16384, 512, 512, 512, 1024),
+    (16384, 512, 512, 300, 1024),            # W no multiple of the block
+    (16384, 256, 512, 4096, 4608), (16384, 512, 256, 4096, 4608),
+    (32768, 512, 512, 16384, 16896),
+    (256, 64, 64, 255, 256),                 # never longer than the sequence
+    (256, 32, 64, 100, 192),                 # a key tile's rows past the lag
+])
+def test_the_ring_holds_every_row_a_step_touches(S, block_q, block_k, W, ring):
+    """The dk / dv ring: whole blocks of both sizes (no tile and no leaving
+    block wraps), at least the rows between the oldest block that has not
+    left and the end of the diagonal's key tile, for every query block."""
+    fa = _fa()
+    lag = -(-(W - 1) // block_q)
+    assert fa._band_ring(S, block_q, block_k, W, lag) == ring
+    assert ring % block_q == 0 and ring % block_k == 0
+    for i in range(S // block_q):
+        lo = min(max(i - lag, 0) * block_q,
+                 max(i * block_q - W + 1, 0) // block_k * block_k)
+        assert -(-(i + 1) * block_q // block_k) * block_k - lo <= ring
 
 
 def test_window_overcompute_counts_blocks_over_the_band():
@@ -190,6 +279,15 @@ def test_a_window_shape_no_kernel_takes_raises():
                         block_q=64, block_k=64, chunk=96)
     with pytest.raises(ValueError, match="causal"):
         flash_attention(q, k, v, causal=False, window=8, interpret=True)
+    # a band whose float32 dk / dv ring no kernel's VMEM holds: the backward
+    # says so when it is traced (the forward alone takes the shape)
+    wide = jax.ShapeDtypeStruct((1, 1, 65536, 256), jnp.bfloat16)
+    attend = functools.partial(flash_attention, causal=True, window=40000,
+                               interpret=True, block_q=512, block_k=512)
+    assert jax.eval_shape(attend, wide, wide, wide).shape == wide.shape
+    with pytest.raises(ValueError, match="ring of 40960 rows"):
+        jax.eval_shape(jax.grad(lambda *a: attend(*a).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2)), wide, wide, wide)
     from deepspeed_tpu.ops.attention import dot_product_attention
     with pytest.raises(ValueError, match="causal"):
         dot_product_attention(q, k, v, causal=False, window=8)

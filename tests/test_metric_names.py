@@ -209,6 +209,7 @@ def test_zero_gather_edge_metric_names_documented():
                                   "attention/flash_heads_per_block",
                                   "attention/window_tile_overcompute",
                                   "attention/window_tiles_per_grid_step",
+                                  "attention/window_bwd_tiles_per_grid_step",
                                   "attention/flash_residual_mb",
                                   "attention/flash_grid_steps_walked_share",
                                   "attention/flash_chunk_rows",
@@ -221,7 +222,7 @@ def test_flash_engagement_gauges_documented(name):
     chunked kernels' grid steps over the rectangle's; ISSUE 43: the window
     kernels' score tiles a grid step; ISSUE 48: the chunked kernels' rows a
     grid step; ISSUE 49: the backward's products a score tile and its dq
-    slabs) stay documented AND emitted."""
+    slabs; ISSUE 53: the window backward's heads x tiles a grid step) stay documented AND emitted."""
     assert name in documented_metric_names(), (
         f"{name} missing from the docs/observability.md train table")
     assert name in _package_source(), name

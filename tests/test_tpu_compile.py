@@ -624,14 +624,23 @@ def test_qwen3_next_step_compiles_for_one_chip_with_its_scopes_and_fits():
     assert_rows_reach_tokens_in_one_pass(hlo, 20480, 16384)
 
 
-def _window_kernels_compile(H, Hkv, W, band_rows, heads, tiles, peak):
+def _window_backward_results(calls):
+    """[(dtype, dims)] of what the ONE window backward call of a compiled
+    text's Pallas ``calls`` returns."""
+    bwd, = (c.split(" custom-call(")[0] for c in calls if "swa_bwd" in c)
+    return re.findall(r"(\w+)\[([\d,]+)\]", bwd.split("=", 1)[1])
+
+
+def _window_kernels_compile(H, Hkv, W, band_rows, lag, tiles, peak):
     """1 x ``H`` query / ``Hkv`` KV heads x 16,384 x head_dim 128, bf16,
-    window ``W``: the three window kernels inside the default scoped VMEM, K
-    and V read at their ``Hkv`` heads and dk, dv written there (summed over
-    a group's query heads inside the kernel), a block's whole band ONE
-    operand block of ``band_rows`` rows — the third grid extent 1 forward
-    and dq, the group's query heads over the ``heads`` a step takes dkv,
-    never the band's tile count and never 32 — and no [S, S] array."""
+    window ``W``: the two window kernels — the backward ONE call (PR 53) that
+    asks for its scoped VMEM — with K and V read at their ``Hkv`` heads and
+    dk, dv written there in the operands' dtype (summed over a group's query
+    heads inside the kernel: no float32 gradient array for XLA to cast or
+    sum), a block's whole band ONE operand block of ``band_rows`` rows — the
+    third grid extent 1, the whole group's heads a backward step, the second
+    ``lag`` steps past the 32 blocks for the ring's last key blocks to leave
+    — and no [S, S] array."""
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
     S = 16384
 
@@ -648,44 +657,92 @@ def _window_kernels_compile(H, Hkv, W, band_rows, heads, tiles, peak):
     shapes = (SDS((1, H, S, 128), BF16), SDS((1, Hkv, S, 128), BF16),
               SDS((1, Hkv, S, 128), BF16))
     jaxpr = jax.make_jaxpr(grads)(*shapes).jaxpr
-    assert sorted(hlo_text.pallas_grids(jaxpr)) == sorted(
-        [(H, 32, 1), (H, 32, 1), (Hkv, 32, H // Hkv // heads)])
-    # the band's operands: K, V (forward, dq) and Q, dO (dkv)
-    assert hlo_text.pallas_element_rows(jaxpr) == [band_rows] * 6
+    assert hlo_text.pallas_grids(jaxpr) == [(H, 32, 1), (Hkv, 32 + lag, 1)]
+    # the band's operands: K and V, forward and backward
+    assert hlo_text.pallas_element_rows(jaxpr) == [band_rows] * 4
     assert default_registry().peek_gauge(
         "attention/window_tiles_per_grid_step") == pytest.approx(tiles,
                                                                  abs=0.005)
+    assert default_registry().peek_gauge(
+        "attention/window_bwd_tiles_per_grid_step") == \
+        H // Hkv * band_rows // 512
     text, compiled = compile_on_chip(grads, *shapes)
-    assert kernel_names(text) == {"_swa_fwd_kernel", "_swa_bwd_dq_kernel",
-                                  "_swa_bwd_dkv_kernel"}
+    assert kernel_names(text) == {"_swa_fwd_kernel", "_swa_bwd_kernel"}
     hlo = compiled.as_text()
     calls = flash_calls(hlo)
-    assert len(calls) == 3 and all(f"bf16[{Hkv},16384,128]" in c
+    assert len(calls) == 2 and all(f"bf16[{Hkv},16384,128]" in c
                                    for c in calls)
-    assert sum(f"f32[{Hkv},16384,128]" in c.split(" custom-call(")[0]
-               for c in calls) == 1                 # dk, dv at the KV heads
-    for scope in ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv"):
+    # dq at the query heads, dk and dv at the KV heads, all bf16: no float32
+    # gradient leaves the call, and none is a per-(block, tile) partial
+    assert _window_backward_results(calls) == [
+        ("bf16", f"{H},16384,128")] + [("bf16", f"{Hkv},16384,128")] * 2
+    for scope in ("swa_fwd", "swa_bwd"):
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+    assert not re.search(r"swa_bwd_d(q|kv)", hlo)
     assert "16384,16384" not in hlo
     # under the blocks' remat policy, as the cell's window layers are
     assert_dense_lse_kept(hlo, calls, f"f32[{H},128,1,128]")
-    # q, k, v, o, their gradients and the fp32 kernel outputs
+    # q, k, v, o, their gradients and the forward's fp32 output
     assert compiled.memory_analysis().peak_memory_in_bytes < peak
 
 
 def test_window_kernels_fwd_and_grad_compile_at_laguna_shape():
-    """64 / 8 heads, window 512: a band of 1,024 rows, 2 tiles a forward or
-    dq step (1.97 with the first block's one) and the group's 8 heads a dkv
-    step (Q and dO 2 MiB each), under 2.5 GB as before PR 43."""
-    _window_kernels_compile(64, 8, 512, 1024, 8, 2.78, 2.5e9)
+    """64 / 8 heads, window 512: a band of 1,024 rows, 2 tiles a forward
+    step (1.97 with the first block's one) and the group's 8 heads a
+    backward step (Q, dO and dq 1 MiB each, the ring 2 x 0.5 MiB), under
+    2.5 GB as before PR 43."""
+    _window_kernels_compile(64, 8, 512, 1024, 1, 3.49, 2.5e9)
 
 
 def test_window_kernels_fwd_and_grad_compile_at_smallthinker_shape():
     """28 / 4 heads, window 4,096: a band of 4,608 rows (1.18 MB a bf16
     operand, K and V double-buffered 4.7 MB), 9 tiles a step less what the
-    first eight blocks clip; one head a dkv step (two would pass the
-    budget)."""
-    _window_kernels_compile(28, 4, 4096, 4608, 1, 7.88, 1.2e9)
+    first eight blocks clip; the group's 7 heads a backward step (which the
+    split dkv kernel's per-head Q / dO bands could not hold) and a ring of
+    2 x 2.36 MB."""
+    _window_kernels_compile(28, 4, 4096, 4608, 8, 13.36, 1.2e9)
+
+
+@pytest.mark.parametrize("H,Hkv,S,D,W,dtype,blocks,band", [
+    # W 4,096 at head_dim 256: the band in two steps, the ring whole
+    (16, 2, 16384, 256, 4096, BF16, {}, ((5, 2), (8, 4608, 8))),
+    # float32 operands: two steps, four of the group's heads a step
+    (8, 2, 16384, 128, 4096, F32, {}, ((5, 2), (8, 4608, 4))),
+    # W 16,384 at S 32,768: three steps of 11 tiles, a ring of 16,896 rows
+    (8, 2, 32768, 128, 16384, BF16, {}, ((11, 3), (32, 16896, 4))),
+    # W no multiple of the block
+    (8, 2, 16384, 128, 300, BF16, {}, ((2, 1), (1, 1024, 4))),
+    (8, 2, 16384, 128, 4096, BF16, dict(block_q=256, block_k=512),
+     ((9, 1), (16, 4608, 4))),
+    (8, 2, 16384, 128, 4096, BF16, dict(block_q=512, block_k=256),
+     ((18, 1), (8, 4608, 4))),
+], ids=lambda v: str(v))
+def test_window_backward_compiles_at_the_shapes_the_split_kernels_took(
+        H, Hkv, S, D, W, dtype, blocks, band):
+    """The shapes PR 43 showed to lower in the dq / dkv pair lower in the
+    single-pass backward, under the plan ``_band_plan`` gives each (no knob
+    selects): one forward and one backward call, gradients in the operands'
+    dtype, dk and dv at the KV heads."""
+    from tests.flash_cases import _fa
+    fa = _fa()
+    bq, bk = blocks.get("block_q", 512), blocks.get("block_k", 512)
+    assert fa._band_plan(S, bq, bk, W, max(D, 128) * jnp.dtype(dtype).itemsize,
+                         H // Hkv) == band
+
+    def grads(q, k, v):
+        return jax.grad(lambda *a: fa.flash_attention(
+            *a, causal=True, window=W, **blocks).astype(F32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    shapes = (SDS((1, H, S, D), dtype), SDS((1, Hkv, S, D), dtype),
+              SDS((1, Hkv, S, D), dtype))
+    text, compiled = compile_on_chip(grads, *shapes)
+    assert kernel_names(text) == {"_swa_fwd_kernel", "_swa_bwd_kernel"}
+    calls = flash_calls(compiled.as_text())
+    assert len(calls) == 2
+    name = "bf16" if dtype == BF16 else "f32"
+    assert _window_backward_results(calls) == [
+        (name, f"{H},{S},{D}")] + [(name, f"{Hkv},{S},{D}")] * 2
 
 
 @pytest.mark.slow
@@ -693,7 +750,7 @@ def test_laguna_step_compiles_for_one_chip_with_its_scopes_and_fits():
     """The WHOLE step of the benchmark's ``laguna-train-1chip-s16384`` cell
     (Laguna-XS.2's layers 0-4 as one of 8 expert-parallel ranks, 1 x 16,384
     tokens, ZeRO-3, through the family's ``lower_train_step``) is accepted
-    for a 16 GB chip with the three window kernels beside the causal chunked
+    for a 16 GB chip with the two window kernels beside the causal chunked
     ones, and every scope the benchmark reads reaches an ``op_name`` of the
     compiled text. ~2 minutes: slow-marked (the kernels alone: the test
     above)."""
@@ -705,8 +762,7 @@ def test_laguna_step_compiles_for_one_chip_with_its_scopes_and_fits():
         config, manifest.traffic_of(cell), topo().devices[:1])
     assert kernel_names(lowered.as_text()) == {
         "_fwd_kernel_chunked", "_bwd_kernel_chunked", "kernel",
-        "_swa_fwd_kernel", "_swa_bwd_dq_kernel", "_swa_bwd_dkv_kernel",
-        "_rows_to_tokens_kernel"}
+        "_swa_fwd_kernel", "_swa_bwd_kernel", "_rows_to_tokens_kernel"}
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
     assert 6.8e9 < ma.argument_size_in_bytes < 7.0e9      # 691.6M x 10 B
@@ -719,7 +775,7 @@ def test_laguna_step_compiles_for_one_chip_with_its_scopes_and_fits():
     assert hlo_text.rematted_forward_attention(hlo) == []
     assert default_registry().peek_gauge(
         "attention/flash_residual_mb") == pytest.approx(1226.8, abs=0.1)
-    for scope in ("swa_fwd", "swa_bwd_dq", "swa_bwd_dkv", "flash_fwd_chunk",
+    for scope in ("swa_fwd", "swa_bwd", "flash_fwd_chunk",
                   "flash_bwd_chunk", "flash_bwd_dq_sum", "attn_gate",
                   "dense_mlp", "moe_shared", "moe_gmm", "moe_gmm_dlhs",
                   "moe_gmm_drhs", "moe_router", "moe_dispatch",
