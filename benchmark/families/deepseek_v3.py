@@ -133,62 +133,17 @@ def build_train(config, global_batch, seed, devices, rehearse):
 
 
 def balanced_selection_bias(config, params, global_batch, seed, rehearse):
-    """(``params`` with every expert layer's ``e_score_correction_bias``
-    moved until the router's loads are level, {"rows_max_over_mean": the
-    worst expert's rows over the mean, a layer, at the first and the last
-    round, and the worst layer's at every round}):
-    ``families/nemotron_h.balanced_selection_bias``'s rule (its
-    docstring and the configuration's ``train.selection_bias_balance.why``
-    say why set-up runs it) on THIS model's tree (the expert layer's module
-    is ``mlp`` here). A family file may not edit another, so the routine
-    stands twice until a ``benchmark`` PR lifts it into
-    ``families/common.py`` (ROADMAP's benchmark queue)."""
-    import jax
-    import jax.numpy as jnp
-    from deepspeed_tpu.moe.dropless import CHOICE_BIAS
+    """``common.balanced_selection_bias`` (its docstring says what the rule
+    is and why set-up runs it) over this model's expert layers: module
+    ``mlp`` of every sparse ``layer_<i>``, as
+    ``train.selection_bias_balance`` sets the rounds and rates."""
     s = sizes(config, rehearse)
-    how = common.merged(config, "train", rehearse)["selection_bias_balance"]
-    model = _model(config, rehearse)
-    names = [f"layer_{i}" for i, kind
-             in enumerate(_kinds(config, rehearse)) if kind == "sparse"]
-    rates = jnp.asarray(np.geomspace(how["rate_first"], how["rate_last"],
-                                     how["rounds"]), jnp.float32)
-    keys = jax.random.split(
-        jax.random.fold_in(jax.random.PRNGKey(seed), 1), how["rounds"])
-
-    def with_biases(p, biases):
-        return {**p, **{n: {**p[n], "mlp": {**p[n]["mlp"], CHOICE_BIAS: b}}
-                        for n, b in biases.items()}}
-
-    @jax.jit
-    def run(p):
-        def one_round(biases, key_and_rate):
-            key, rate = key_and_rate
-            ids = jax.random.randint(key, (global_batch, how["seq_len"]), 0,
-                                     s["vocab_size"])
-            _, seen = model.apply({"params": with_biases(p, biases)}, ids,
-                                  mutable=["intermediates"])
-            moved, worst = {}, []
-            for n, bias in biases.items():
-                top_e = seen["intermediates"][n]["mlp"]["top_e"][0]
-                rows = jax.nn.one_hot(top_e, bias.shape[0],
-                                      dtype=jnp.float32).sum(axis=(0, 1))
-                over = rows / jnp.mean(rows) - 1.0
-                moved[n] = bias - rate * jnp.clip(over, -1.0, 1.0)
-                worst.append(jnp.max(over) + 1.0)
-            return moved, jnp.stack(worst)
-
-        return jax.lax.scan(
-            one_round, {n: p[n]["mlp"][CHOICE_BIAS] for n in names},
-            (keys, rates))
-
-    biases, worst = run(params)
-    biases = {n: jax.device_put(b, params[n]["mlp"][CHOICE_BIAS].sharding)
-              for n, b in biases.items()}
-    worst = np.asarray(worst)
-    return with_biases(params, biases), {"rows_max_over_mean": {
-        "first_round": worst[0].tolist(), "last_round": worst[-1].tolist(),
-        "worst_layer_by_round": worst.max(axis=1).tolist()}}
+    return common.balanced_selection_bias(
+        _model(config, rehearse), params, "mlp",
+        [f"layer_{i}" for i, kind
+         in enumerate(_kinds(config, rehearse)) if kind == "sparse"],
+        common.merged(config, "train", rehearse)["selection_bias_balance"],
+        global_batch, s["vocab_size"], seed)
 
 
 def program_gauges():

@@ -45,8 +45,9 @@ WIDTH_KEYS = ("hidden_size", "intermediate_size", "moe_intermediate_size",
               "shared_expert_intermediate_size", "num_attention_heads",
               "num_key_value_heads", "head_dim", "num_experts_per_tok",
               "sliding_window")
-# ``tag_of`` matches a kernel tag by prefix, the first listed first:
-# ``swa_bwd`` takes ``swa_bwd_dq`` and ``swa_bwd_dkv``
+# ``tag_of`` matches a kernel tag by prefix, the first listed first
+# (``flash_bwd`` takes ``flash_bwd_chunk``; the window backward is the ONE
+# scope ``swa_bwd`` since PR 53)
 KERNEL_TAGS = ("swa_fwd", "swa_bwd", "flash_fwd", "flash_bwd", "moe_gmm")
 MODULE_TAGS = ("ds_loss_head", "ds_embed", "moe_router", "moe_dispatch",
                "moe_act", "moe_combine", "moe_shared", "attn_gate",

@@ -138,73 +138,17 @@ def build_train(config, global_batch, seed, devices, rehearse):
 
 
 def balanced_selection_bias(config, params, global_batch, seed, rehearse):
-    """(``params`` with every expert layer's ``e_score_correction_bias``
-    moved until the router's loads are level, {"rows_max_over_mean": the
-    worst expert's rows over the mean, a layer, at the first and the last
-    round}). The bias exists to level the loads: the published recipe moves
-    it during training by a rule outside the loss (the auxiliary-loss-free
-    rule, arXiv 2408.15664: down where an expert is over the mean, up where
-    under), and a checkpoint brings the values that rule left. A random
-    router over a random stream is far from level (the worst of 128 experts
-    takes 3-5.5 x the mean) and how many of the hot experts are among the 8
-    held here is the seed's luck, so seeded weights with a DRAWN bias make
-    the rows held, and with them the step's time, the seed's
-    (``train.selection_bias_balance.why`` has the readings). So set-up runs
-    the rule from the drawn bias on: ``rounds`` forward passes of the
-    program's model, each on a fresh batch of ``seq_len`` uniform token ids
-    drawn from the seed (the traffic's distribution), every expert layer's
-    bias moved after each by ``rate x clip(rows / mean - 1, -1, 1)``, the
-    rate falling geometrically from ``rate_first`` to ``rate_last`` — a
-    step proportional to the error where the published rule takes a fixed
-    1e-3 of its sign, so that dozens of rounds do what thousands of training
-    steps do. One jitted scan; nothing of it runs after set-up: the bias
-    stays a buffer held fixed over the window."""
-    import jax
-    import jax.numpy as jnp
-    from deepspeed_tpu.moe.dropless import CHOICE_BIAS
+    """``common.balanced_selection_bias`` (its docstring says what the rule
+    is and why set-up runs it) over this model's expert layers: module
+    ``mixer`` of every ``layer_<i>`` whose kind is ``E``, as
+    ``train.selection_bias_balance`` sets the rounds and rates."""
     s = sizes(config, rehearse)
-    how = common.merged(config, "train", rehearse)["selection_bias_balance"]
-    model = _model(config, rehearse)
-    names = [f"layer_{i}" for i, kind
-             in enumerate(s["hybrid_override_pattern"]) if kind == EXPERTS]
-    rates = jnp.asarray(np.geomspace(how["rate_first"], how["rate_last"],
-                                     how["rounds"]), jnp.float32)
-    keys = jax.random.split(
-        jax.random.fold_in(jax.random.PRNGKey(seed), 1), how["rounds"])
-
-    def with_biases(p, biases):
-        return {**p, **{n: {**p[n], "mixer": {**p[n]["mixer"],
-                                              CHOICE_BIAS: b}}
-                        for n, b in biases.items()}}
-
-    @jax.jit
-    def run(p):
-        def one_round(biases, key_and_rate):
-            key, rate = key_and_rate
-            ids = jax.random.randint(key, (global_batch, how["seq_len"]), 0,
-                                     s["vocab_size"])
-            _, seen = model.apply({"params": with_biases(p, biases)}, ids,
-                                  mutable=["intermediates"])
-            moved, worst = {}, []
-            for n, bias in biases.items():
-                top_e = seen["intermediates"][n]["mixer"]["top_e"][0]
-                rows = jax.nn.one_hot(top_e, bias.shape[0],
-                                      dtype=jnp.float32).sum(axis=(0, 1))
-                over = rows / jnp.mean(rows) - 1.0
-                moved[n] = bias - rate * jnp.clip(over, -1.0, 1.0)
-                worst.append(jnp.max(over) + 1.0)
-            return moved, jnp.stack(worst)
-
-        return jax.lax.scan(
-            one_round, {n: p[n]["mixer"][CHOICE_BIAS] for n in names},
-            (keys, rates))
-
-    biases, worst = run(params)
-    biases = {n: jax.device_put(b, params[n]["mixer"][CHOICE_BIAS].sharding)
-              for n, b in biases.items()}
-    worst = np.asarray(worst)
-    return with_biases(params, biases), {"rows_max_over_mean": {
-        "first_round": worst[0].tolist(), "last_round": worst[-1].tolist()}}
+    return common.balanced_selection_bias(
+        _model(config, rehearse), params, "mixer",
+        [f"layer_{i}" for i, kind
+         in enumerate(s["hybrid_override_pattern"]) if kind == EXPERTS],
+        common.merged(config, "train", rehearse)["selection_bias_balance"],
+        global_batch, s["vocab_size"], seed)
 
 
 def program_gauges():

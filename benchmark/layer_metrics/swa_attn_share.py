@@ -1,7 +1,7 @@
 """swa_attn_share (%), read from device_trace.
 
 Device time of the window kernels — the Pallas custom-calls traced under the
-scopes ``swa_fwd`` and ``swa_bwd`` (``swa_bwd_dq``, ``swa_bwd_dkv``:
+scopes ``swa_fwd`` and ``swa_bwd`` (ONE backward call since PR 53:
 ``ops/pallas/flash_attention.py``'s sliding-window family), forward,
 backward and recomputation — over the slice's busy time, on the busiest
 chip. The full-attention layers' kernels are ``flash_attn_share``'s. None

@@ -282,10 +282,9 @@ OPS = [
      FWD + SCAN + "/l0/attn/swa_fwd/pallas_call", 20e6),
     ("%swa_fwd.2 = f32[64,16384,128] custom-call(%a)" + PALLAS,
      REMAT + "/attn/swa_fwd/pallas_call", 20e6),
-    ("%swa_bwd_dq.3 = f32[64,16384,128] custom-call(%a)" + PALLAS,
-     BWD + SCAN + "/l0/attn/swa_bwd_dq/pallas_call", 30e6),
-    ("%swa_bwd_dkv.4 = f32[8,16384,128] custom-call(%a)" + PALLAS,
-     BWD + SCAN + "/l0/attn/swa_bwd_dkv/pallas_call", 50e6),
+    # ONE backward call since PR 53: dq, dk and dv from one kernel
+    ("%swa_bwd.3 = f32[64,16384,128] custom-call(%a)" + PALLAS,
+     BWD + SCAN + "/l0/attn/swa_bwd/pallas_call", 80e6),
     ("%flash_fwd_chunk.5 = f32[48,16384,128] custom-call(%a)" + PALLAS,
      FWD + "/lead_0/attn/flash_fwd_chunk/pallas_call", 100e6),
     ("%flash_bwd_dq.6 = f32[48,16384,128] custom-call(%a)" + PALLAS,

@@ -154,7 +154,7 @@ def test_each_family_file_provides_what_the_harness_asks_of_a_family(name):
     (lambda b: b["end_to_end"][0].update(bound=0.5), "bound"),
     (lambda b: b["per_layer"][0].update(moves="nothing"), "moves"),
     (lambda b: b["per_layer"][0].update(why="x"), "has keys"),
-    (lambda b: [w.update(chips=4) for w in b["workloads"][:2]],
+    (lambda b: [w.update(chips=4) for w in b["workloads"]],
      "ask for 4 chips"),
     (lambda b: b.update(run_seconds=52), "run_seconds"),
     (lambda b: b["configs"].append(dict(b["configs"][0], name="unused",

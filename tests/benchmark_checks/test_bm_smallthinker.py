@@ -275,10 +275,9 @@ PALLAS = ', custom_call_target="tpu_custom_call"'
 OPS = [
     ("%swa_fwd.1 = f32[28,16384,128] custom-call(%a)" + PALLAS,
      FWD + SCAN + "/l1/attn/swa_fwd/pallas_call", 60e6),
-    ("%swa_bwd_dq.2 = f32[28,16384,128] custom-call(%a)" + PALLAS,
-     BWD + SCAN + "/l1/attn/swa_bwd_dq/pallas_call", 60e6),
-    ("%swa_bwd_dkv.3 = f32[4,16384,128] custom-call(%a)" + PALLAS,
-     BWD + SCAN + "/l1/attn/swa_bwd_dkv/pallas_call", 60e6),
+    # ONE backward call since PR 53: dq, dk and dv from one kernel
+    ("%swa_bwd.2 = f32[28,16384,128] custom-call(%a)" + PALLAS,
+     BWD + SCAN + "/l1/attn/swa_bwd/pallas_call", 120e6),
     ("%flash_fwd_chunk.4 = f32[28,16384,128] custom-call(%a)" + PALLAS,
      FWD + SCAN + "/l0/attn/flash_fwd_chunk/pallas_call", 20e6),
     # the router's logits ahead of the mixer, its softmax / top-k, and its
