@@ -1,26 +1,37 @@
-"""DeepSeek-V3-style decoder (``model_type: deepseek_v3``; Kanana-2 is one):
-multi-head LATENT attention in every layer, a leading dense layer and expert
-layers with a sigmoid router after it.
+"""DeepSeek-V3-style decoder (``model_type: deepseek_v3``; Kanana-2 is one,
+Xing4.0 — ``model_type: xing4_0`` — another): multi-head LATENT attention in
+every layer, a leading dense layer and expert layers with a sigmoid router
+after it.
 
 Every layer is pre-norm residual with a plain RMSNorm (weight one at
 initialisation): ``x += attn(norm(x)); x += ffn(norm(x))``; a final RMSNorm;
-an untied head; no bias anywhere.
+an untied head; no bias anywhere. With ``hc_mult`` n > 1 the residual path is
+n streams and each of the two adds becomes a read and a write through learned
+mixes (``models/hyper_connections.py``); ``hc_mult`` 1 is the one stream of
+the line above, letter for letter.
 
-- **Latent attention** (``mla_attn``), ``H`` heads. Queries are projected
-  whole (``q_lora_rank`` null: no query compression), ``H x (qk_nope_head_dim
-  + qk_rope_head_dim)``. Keys and values come from ONE down-projection of
+- **Latent attention** (``mla_attn``), ``H`` heads. Queries are ``H x
+  (qk_nope_head_dim + qk_rope_head_dim)`` wide: projected whole (``q_proj``)
+  where ``q_lora_rank`` is null, else COMPRESSED — ``q_a_proj`` down to
+  ``q_lora_rank`` numbers, an RMSNorm over them (``q_a_norm``), ``q_b_proj``
+  up. Keys and values come from ONE down-projection of
   the token to ``kv_lora_rank + qk_rope_head_dim`` numbers (``kv_a_proj``):
   the first ``kv_lora_rank`` are the latent, RMS-normed (``kv_a_norm``) and
   expanded by ``kv_b_proj`` into every head's key WITHOUT position
   (``qk_nope_head_dim``) and value (``v_head_dim``); the last
   ``qk_rope_head_dim`` are ONE rotated key a token that all heads share.
-  RoPE (``rope_theta``, no scaling) turns the pairs ``(2i, 2i+1)`` of the
+  RoPE (``rope_theta``) turns the pairs ``(2i, 2i+1)`` of the
   query's last ``qk_rope_head_dim`` columns and of the shared key
   (``rope_interleave``; ``rope_pairs`` says how). A head's score is
   ``(q_nope·k_nope + q_rope·k_rope) / sqrt(qk_nope_head_dim +
   qk_rope_head_dim)`` under a causal softmax and its output ``v_head_dim``
-  wide. In TRAINING that is multi-head attention with a q·k head wider than
-  the value head (192 / 128): each head's key is materialised as ``[k_nope
+  wide. ``rope_scaling`` null is plain RoPE; type ``yarn`` is DeepSeek-V3's
+  form (``rope_tables``): blended inverse frequencies
+  (``models/laguna.yarn_rope_angles``), cos and sin times ``yarn_mscale(factor,
+  mscale) / yarn_mscale(factor, mscale_all_dim)`` and the softmax scale times
+  ``yarn_mscale(factor, mscale_all_dim)^2``. In TRAINING that is multi-head
+  attention with a q·k head wider than the value head (192 / 128): each
+  head's key is materialised as ``[k_nope
   ; k_rope]`` and the three CHUNKED flash kernels take the two widths as
   they are (``ops/pallas/flash_attention.py``: V is never padded to the
   score's width, nothing ``[S, S]`` exists). The latent form pays off in a
@@ -45,21 +56,34 @@ time (the benchmark's float32 reference) a copy of every slice — 6 GB at the
 benchmark's six layers, which a 16 GB chip does not have beside the engine's
 state (PERF.md Findings PR 47); a deployment of 48 layers that wants the
 scan's compile time back brings it with a reader that takes stacked leaves.
-No multi-token-prediction module and no auxiliary loss (the published
-config has a key for neither).
+No auxiliary loss.
+
+**Multi-token prediction** (``num_nextn_predict_layers`` 1; DeepSeek-V3,
+arXiv 2412.19437 eq. 21-25 at depth 1), a TRAINING loss term: ``h'_i =
+[RMSNorm_h(h_i) ; RMSNorm_e(Emb(t_{i+1}))] M`` with ``h_i`` the trunk's state
+BEFORE the final norm (the summed streams) and ``M`` ``mtp_eh_proj`` [2C, C];
+one more expert block (``mtp_layer``, its own stream mixers, the copy into the
+streams and their sum at its ends), its own head norm (``mtp_norm``), the
+SHARED ``lm_head`` and ``embed_tokens``; ``loss = loss_main +
+mtp_loss_weight x CE(head(h^1_i), t_{i+2})``, the second term sown into
+``stats`` as ``mtp_loss``. Everything of it runs under the scope ``mtp``.
+Without ``labels`` the module is not run (in serving it is a drafter, which
+this repo does not have: ROADMAP R8).
 """
 
 import dataclasses
 import math
 from typing import Any, Optional
 
+import jax
 import jax.numpy as jnp
 import flax.linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
+from deepspeed_tpu.models import hyper_connections as hc
 from deepspeed_tpu.models.gpt2 import (_embed_lookup, chunked_lm_loss,
                                        lm_loss)
-from deepspeed_tpu.models.laguna import remat_block
+from deepspeed_tpu.models.laguna import remat_block, yarn_rope_angles
 from deepspeed_tpu.models.llama import RMSNorm, rope_angles
 from deepspeed_tpu.moe.dropless import (CHOICE_BIAS, HELD_STAT_GAUGES,
                                         STAT_GAUGES, DroplessMoE)
@@ -87,6 +111,10 @@ class DeepseekV3Config:
     max_position_embeddings: int = 32768
     rope_theta: float = 1000000.0
     rope_interleave: bool = True
+    # None, or the published dict of type "yarn": factor,
+    # original_max_position_embeddings, beta_fast, beta_slow, mscale,
+    # mscale_all_dim (kept as a tuple of items)
+    rope_scaling: Any = None
     first_k_dense_replace: int = 1
     # experts
     n_routed_experts: int = 128
@@ -101,6 +129,22 @@ class DeepseekV3Config:
     e_score_correction_bias_std: float = 0.0
     experts_held: int = 0            # 0: all; else one rank's share ...
     expert_share: int = 0            # ... experts [held * share, ... + held)
+    # residual streams (models/hyper_connections.py); 1: the one stream
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
+    # a configuration's way to DRAW the stream mixers' leaves (a checkpoint
+    # brings trained values): phi normal, the three gates round a mean, the
+    # offsets round zero
+    hc_phi_std: float = 0.02
+    hc_gate_mean: float = 1.0
+    hc_gate_std: float = 0.0
+    hc_bias_std: float = 0.0
+    # multi-token prediction: 0 or 1 module, its loss's weight
+    num_nextn_predict_layers: int = 0
+    mtp_loss_weight: float = 0.3
     rms_norm_eps: float = 1e-6
     initializer_range: float = 0.02
     dtype: Any = jnp.bfloat16
@@ -111,11 +155,20 @@ class DeepseekV3Config:
     loss_chunk: int = 0
 
     def __post_init__(self):
-        if self.q_lora_rank is not None:
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(self, "rope_scaling",
+                               tuple(sorted(self.rope_scaling.items())))
+        if self.rope_scaling is not None:
+            kind = dict(self.rope_scaling).get(
+                "type", dict(self.rope_scaling).get("rope_type"))
+            if kind != "yarn":
+                raise NotImplementedError(
+                    f"rope_scaling type {kind!r}: only null and 'yarn' are "
+                    "written")
+        if self.num_nextn_predict_layers not in (0, 1):
             raise NotImplementedError(
-                f"q_lora_rank={self.q_lora_rank}: query compression is not "
-                "written (the configurations this model runs project "
-                "queries whole)")
+                f"num_nextn_predict_layers={self.num_nextn_predict_layers}: "
+                "one multi-token-prediction module (depth 1) is written")
         if self.n_group != 1 or self.topk_group != 1:
             raise NotImplementedError(
                 f"n_group={self.n_group}, topk_group={self.topk_group}: "
@@ -126,28 +179,65 @@ class DeepseekV3Config:
     def qk_head_dim(self):
         return self.qk_nope_head_dim + self.qk_rope_head_dim
 
+    @property
+    def yarn(self):
+        """The ``rope_scaling`` dict (None: plain RoPE)."""
+        return None if self.rope_scaling is None else dict(self.rope_scaling)
+
+    @property
+    def softmax_scale(self):
+        """1 / sqrt(q·k head) — under YaRN times ``yarn_mscale(factor,
+        mscale_all_dim)^2`` (DeepSeek-V3's attention; 2.0047 at factor 64)."""
+        scale = 1.0 / math.sqrt(self.qk_head_dim)
+        if self.yarn and self.yarn.get("mscale_all_dim", 0):
+            scale *= yarn_mscale(self.yarn["factor"],
+                                 self.yarn["mscale_all_dim"]) ** 2
+        return scale
+
     def attention_params(self):
-        """Matmul parameters of one attention module + its latent norm."""
+        """Matmul parameters of one attention module + its latent norms."""
         H, n = self.hidden_size, self.num_attention_heads
-        R = self.kv_lora_rank
-        return H * n * self.qk_head_dim \
-            + H * (R + self.qk_rope_head_dim) \
+        R, Q = self.kv_lora_rank, self.q_lora_rank
+        q = H * n * self.qk_head_dim if Q is None \
+            else H * Q + Q + Q * n * self.qk_head_dim
+        return q + H * (R + self.qk_rope_head_dim) \
             + R * n * (self.qk_nope_head_dim + self.v_head_dim) \
             + n * self.v_head_dim * H + R
 
-    def num_params(self):
-        """Parameters held here (``experts_held`` experts an expert layer;
-        the selection bias counted: it is a leaf of the tree)."""
-        H, L = self.hidden_size, self.num_hidden_layers
+    def stream_mixer_params(self):
+        """One branch's stream mixer: phi, its offsets, three gates (0 with
+        one stream)."""
+        n = self.hc_mult
+        width = 2 * n + n * n
+        return 0 if n == 1 else n * self.hidden_size * width + width + 3
+
+    def layer_params(self, sparse):
+        """One layer: attention, the two block norms, the two stream mixers
+        and its FFN (``experts_held`` experts an expert layer; the selection
+        bias counted: it is a leaf of the tree)."""
+        H = self.hidden_size
         held = self.experts_held or self.n_routed_experts
-        dense = 3 * H * self.intermediate_size
-        sparse = H * self.n_routed_experts + self.n_routed_experts \
+        ffn = 3 * H * self.intermediate_size if not sparse \
+            else H * self.n_routed_experts + self.n_routed_experts \
             + 3 * held * H * self.moe_intermediate_size \
             + 3 * H * self.n_shared_experts * self.moe_intermediate_size
+        return self.attention_params() + 2 * H \
+            + 2 * self.stream_mixer_params() + ffn
+
+    def mtp_params(self):
+        """The prediction module's own leaves: ``mtp_eh_proj``, three norms
+        and one expert block (embedding and head are the trunk's)."""
+        H = self.hidden_size
+        return self.num_nextn_predict_layers * (
+            2 * H * H + 3 * H + self.layer_params(True))
+
+    def num_params(self):
+        """Parameters held here."""
+        H, L = self.hidden_size, self.num_hidden_layers
         lead = self.first_k_dense_replace
         return 2 * self.vocab_size * H + H \
-            + L * (self.attention_params() + 2 * H) \
-            + lead * dense + (L - lead) * sparse
+            + lead * self.layer_params(False) \
+            + (L - lead) * self.layer_params(True) + self.mtp_params()
 
 
 def _dense(cfg, n, name):
@@ -155,6 +245,11 @@ def _dense(cfg, n, name):
                     param_dtype=cfg.param_dtype,
                     kernel_init=nn.initializers.normal(cfg.initializer_range),
                     name=name)
+
+
+def _norm(cfg, name):
+    return RMSNorm(eps=cfg.rms_norm_eps, dtype=cfg.dtype,
+                   param_dtype=cfg.param_dtype, name=name)
 
 
 def rope_pairs(x, cos, sin, interleaved=True):
@@ -179,6 +274,30 @@ def rope_pairs(x, cos, sin, interleaved=True):
                            axis=-1).astype(x.dtype)
 
 
+def yarn_mscale(factor, mscale):
+    """DeepSeek's ``yarn_get_mscale``: ``0.1 mscale ln factor + 1`` (1 for a
+    factor of at most 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_tables(cfg, positions):
+    """(cos, sin) [S, qk_rope_head_dim / 2] float32: plain RoPE, or under
+    ``rope_scaling`` of type yarn the blended frequencies with cos and sin
+    times ``yarn_mscale(factor, mscale) / yarn_mscale(factor,
+    mscale_all_dim)`` (the score's share of the scaling is
+    ``DeepseekV3Config.softmax_scale``)."""
+    y = cfg.yarn
+    if y is None:
+        return rope_angles(positions, cfg.qk_rope_head_dim, cfg.rope_theta)
+    factor = float(y["factor"])
+    return yarn_rope_angles(
+        positions, cfg.qk_rope_head_dim, float(cfg.rope_theta), factor,
+        y["original_max_position_embeddings"],
+        float(y.get("beta_fast", 32.0)), float(y.get("beta_slow", 1.0)),
+        attention_factor=yarn_mscale(factor, y.get("mscale", 1))
+        / yarn_mscale(factor, y.get("mscale_all_dim", 0)))
+
+
 class MLAttention(nn.Module):
     """The latent-attention branch of the module docstring. ``rope``:
     (cos, sin) [S, qk_rope_head_dim / 2]."""
@@ -191,12 +310,17 @@ class MLAttention(nn.Module):
         H, R = cfg.num_attention_heads, cfg.kv_lora_rank
         Dn, Dr, Dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                       cfg.v_head_dim)
-        q = _dense(cfg, H * (Dn + Dr), "q_proj")(x).reshape(B, S, H, Dn + Dr)
+        if cfg.q_lora_rank is None:
+            q = _dense(cfg, H * (Dn + Dr), "q_proj")(x)
+        else:
+            with annotate("mla_latent"):
+                c_q = _norm(cfg, "q_a_norm")(
+                    _dense(cfg, cfg.q_lora_rank, "q_a_proj")(x))
+            q = _dense(cfg, H * (Dn + Dr), "q_b_proj")(c_q)
+        q = q.reshape(B, S, H, Dn + Dr)
         with annotate("mla_latent"):
             down = _dense(cfg, R + Dr, "kv_a_proj")(x)
-            latent = RMSNorm(eps=cfg.rms_norm_eps, dtype=cfg.dtype,
-                             param_dtype=cfg.param_dtype,
-                             name="kv_a_norm")(down[..., :R])
+            latent = _norm(cfg, "kv_a_norm")(down[..., :R])
         with annotate("mla_expand"):
             kv = _dense(cfg, H * (Dn + Dv), "kv_b_proj")(latent).reshape(
                 B, S, H, Dn + Dv)
@@ -214,9 +338,9 @@ class MLAttention(nn.Module):
             v = kv[..., Dn:]
         q, k, v = (checkpoint_name(t, "qkv").transpose(0, 2, 1, 3)
                    for t in (q, k, v))
-        # scale 1 / sqrt(Dn + Dr): ``rope_scaling`` null, so no mscale
+        # 1 / sqrt(Dn + Dr), under YaRN times its mscale squared
         out = dot_product_attention(q, k, v, causal=True,
-                                    scale=1.0 / math.sqrt(Dn + Dr),
+                                    scale=cfg.softmax_scale,
                                     use_flash=cfg.use_flash)
         out = out.transpose(0, 2, 1, 3).reshape(B, S, H * Dv)
         return checkpoint_name(_dense(cfg, cfg.hidden_size, "o_proj")(out),
@@ -244,29 +368,12 @@ class DeepseekV3Block(nn.Module):
     @nn.compact
     def __call__(self, x, rope):
         cfg = self.config
-        norm = lambda name: RMSNorm(  # noqa: E731
-            eps=cfg.rms_norm_eps, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, name=name)
-        mixed = MLAttention(cfg, name="mla_attn")(norm("input_norm")(x), rope)
+        if cfg.hc_mult > 1:
+            return self._streams(x, rope)
+        mixed = MLAttention(cfg, name="mla_attn")(
+            _norm(cfg, "input_norm")(x), rope)
         x = x + mixed
-        h = norm("post_attn_norm")(x)
-        if not self.sparse:
-            out = DenseMLP(cfg, name="mlp")(h)
-        else:
-            std = cfg.e_score_correction_bias_std
-            out = DroplessMoE(
-                cfg.n_routed_experts, cfg.num_experts_per_tok,
-                cfg.moe_intermediate_size,
-                norm_topk_prob=cfg.norm_topk_prob, balance_coeff=0.0,
-                z_coeff=0.0, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
-                experts_held=cfg.experts_held, expert_share=cfg.expert_share,
-                shared_d_ff=cfg.n_shared_experts * cfg.moe_intermediate_size,
-                routed_scale=cfg.routed_scaling_factor, shared_gate=False,
-                score="sigmoid", choice_bias=True,
-                choice_bias_init=nn.initializers.normal(std) if std
-                else nn.initializers.zeros,
-                # ``remat_block``'s policy saves the router's choice
-                pin_choice=cfg.remat, name="mlp")(h)
+        out = self._ffn(_norm(cfg, "post_attn_norm")(x))
         if self.is_mutable_collection("intermediates"):
             # a caller's look at the stream after the mixer and at the two
             # branches (the benchmark's check against its reference);
@@ -275,6 +382,59 @@ class DeepseekV3Block(nn.Module):
             self.sow("intermediates", "mixer_out", mixed)
             self.sow("intermediates", "ffn_out", out)
         return x + out
+
+    @nn.nowrap
+    def _streams(self, x, rope):
+        """The block over ``hc_mult`` streams, ``x`` [B, S, n C]: each
+        branch reads ``u = H_pre X`` and writes ``H_res X + H_post y``."""
+        cfg = self.config
+        look = self.is_mutable_collection("intermediates")
+
+        def branch(x, name, f):
+            coeff = hc.StreamMixer(
+                n=cfg.hc_mult, sinkhorn_iters=cfg.hc_sinkhorn_iters,
+                eps=cfg.hc_eps,
+                clamp=(cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max),
+                phi_std=cfg.hc_phi_std, gate_mean=cfg.hc_gate_mean,
+                gate_std=cfg.hc_gate_std, bias_std=cfg.hc_bias_std,
+                param_dtype=cfg.param_dtype, name=name)(x)
+            h_pre, h_post, h_res = coeff
+            y = f(hc.read(x, h_pre))
+            if look:
+                self.sow("intermediates", name + "_coeff", coeff)
+            return hc.write(x, y, h_post, h_res), y
+
+        x, mixed = branch(x, "attn_hc", lambda u: MLAttention(
+            cfg, name="mla_attn")(_norm(cfg, "input_norm")(u), rope))
+        if look:
+            self.sow("intermediates", "x_mid", x)
+            self.sow("intermediates", "mixer_out", mixed)
+        x, out = branch(x, "ffn_hc", lambda u: self._ffn(
+            _norm(cfg, "post_attn_norm")(u)))
+        if look:
+            self.sow("intermediates", "ffn_out", out)
+            self.sow("intermediates", "x_out", x)
+        return x
+
+    @nn.nowrap
+    def _ffn(self, h):
+        cfg = self.config
+        if not self.sparse:
+            return DenseMLP(cfg, name="mlp")(h)
+        std = cfg.e_score_correction_bias_std
+        return DroplessMoE(
+            cfg.n_routed_experts, cfg.num_experts_per_tok,
+            cfg.moe_intermediate_size,
+            norm_topk_prob=cfg.norm_topk_prob, balance_coeff=0.0,
+            z_coeff=0.0, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            experts_held=cfg.experts_held, expert_share=cfg.expert_share,
+            shared_d_ff=cfg.n_shared_experts * cfg.moe_intermediate_size,
+            routed_scale=cfg.routed_scaling_factor, shared_gate=False,
+            score="sigmoid", choice_bias=True,
+            choice_bias_init=nn.initializers.normal(std) if std
+            else nn.initializers.zeros,
+            # ``remat_block``'s policy saves the router's choice
+            pin_choice=cfg.remat, name="mlp")(h)
 
 
 class DeepseekV3ForCausalLM(nn.Module):
@@ -287,39 +447,105 @@ class DeepseekV3ForCausalLM(nn.Module):
     # selection bias (``moe/dropless.DroplessMoE``)
     buffer_leaves = (CHOICE_BIAS,)
 
+    # of ``stats``, what the engine folds as the LARGEST value sown in the
+    # step where it folds the others' mean
+    stat_maxima = tuple(hc.STAT_GAUGES)
+
     @property
     def stat_gauges(self):
         """{variable sown into ``stats``: the gauge it is read under}."""
-        return HELD_STAT_GAUGES if self.config.experts_held else STAT_GAUGES
+        cfg = self.config
+        return {**(HELD_STAT_GAUGES if cfg.experts_held else STAT_GAUGES),
+                **(hc.STAT_GAUGES if cfg.hc_mult > 1 else {}),
+                **({"mtp_loss": "mtp/loss"}
+                   if cfg.num_nextn_predict_layers else {})}
 
     @nn.compact
     def __call__(self, input_ids, labels=None):
         cfg = self.config
-        lead = cfg.first_k_dense_replace
+        lead, n = cfg.first_k_dense_replace, cfg.hc_mult
         embed = self.param("embed_tokens",
                            nn.initializers.normal(cfg.initializer_range),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
         with annotate("ds_embed"):
             x = _embed_lookup(embed, input_ids).astype(cfg.dtype)
-        rope = rope_angles(jnp.arange(input_ids.shape[1]),
-                           cfg.qk_rope_head_dim, cfg.rope_theta)
-        for i in range(cfg.num_hidden_layers):
-            x = remat_block(cfg, self, f"layer_{i}", DeepseekV3Block)(
-                cfg, i >= lead, name=f"layer_{i}")(x, rope)
-        x = RMSNorm(eps=cfg.rms_norm_eps, dtype=cfg.dtype,
-                    param_dtype=cfg.param_dtype, name="norm")(x)
+        rope = rope_tables(cfg, jnp.arange(input_ids.shape[1]))
+
+        def layers(x, names, sparse):
+            """``x`` [B, S, C] through the blocks ``names``: copied into the
+            streams ahead of them and the streams summed after, where there
+            are several."""
+            if n > 1:
+                with annotate("mhc_write"):
+                    x = hc.spread(x, n)
+            for name, kind in zip(names, sparse):
+                x = remat_block(cfg, self, name, DeepseekV3Block)(
+                    cfg, kind, name=name)(x, rope)
+            if n > 1:
+                with annotate("mhc_read"):
+                    x = hc.merge(x, n)
+            return x
+
+        depth = range(cfg.num_hidden_layers)
+        trunk = layers(x, [f"layer_{i}" for i in depth],
+                       [i >= lead for i in depth])
+        x = _norm(cfg, "norm")(trunk)
         head = self.param("lm_head",
                           nn.initializers.normal(cfg.initializer_range),
                           (cfg.vocab_size, cfg.hidden_size),
                           cfg.param_dtype)
-        if labels is not None and cfg.loss_chunk > 0:
-            return chunked_lm_loss(x, head.astype(cfg.dtype), labels,
-                                   cfg.loss_chunk)
-        logits = jnp.einsum("bse,ve->bsv", x, head.astype(cfg.dtype))
-        if labels is not None:
-            return lm_loss(logits, labels)
-        return logits
+
+        def loss_of(x, offset):
+            if cfg.loss_chunk > 0:
+                return chunked_lm_loss(x, head.astype(cfg.dtype), labels,
+                                       cfg.loss_chunk, offset=offset)
+            return lm_loss(jnp.einsum("bse,ve->bsv", x,
+                                      head.astype(cfg.dtype)), labels,
+                           offset=offset)
+
+        predicted = None
+        if cfg.num_nextn_predict_layers and (labels is not None
+                                             or self.is_initializing()):
+            with annotate("mtp"):
+                # position i joins the trunk's state with the embedding of
+                # token i + 1 and is scored against token i + 2; the roll's
+                # wrapped last column lies behind every scored position
+                with annotate("ds_embed"):
+                    nxt = _embed_lookup(
+                        embed, jnp.roll(input_ids, -1, axis=1)).astype(
+                            cfg.dtype)
+                joined = _dense(cfg, cfg.hidden_size, "mtp_eh_proj")(
+                    jnp.concatenate([_norm(cfg, "mtp_hnorm")(trunk),
+                                     _norm(cfg, "mtp_enorm")(nxt)], axis=-1))
+                if self.is_mutable_collection("intermediates"):
+                    self.sow("intermediates", "mtp_joined", joined)
+                predicted = _norm(cfg, "mtp_norm")(
+                    layers(joined, ["mtp_layer"], [True]))
+        if labels is None:
+            return jnp.einsum("bse,ve->bsv", x, head.astype(cfg.dtype))
+        loss = loss_of(x, 1)
+        if predicted is not None:
+            with annotate("mtp"):
+                mtp_loss = loss_of(predicted, 2)
+            self.sow("stats", "mtp_loss", jax.lax.stop_gradient(mtp_loss))
+            loss = loss + cfg.mtp_loss_weight * mtp_loss
+        return loss
+
+
+def xing4_tiny(**over):
+    """``deepseek_v3_tiny`` with what Xing4.0 adds: compressed queries (a
+    query latent of 40), YaRN, four residual streams with drawn mixers and
+    the multi-token-prediction module."""
+    kw = dict(q_lora_rank=40, rope_theta=10000.0,
+              rope_scaling={"type": "yarn", "factor": 64.0,
+                            "original_max_position_embeddings": 16,
+                            "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                            "mscale_all_dim": 1},
+              hc_mult=4, hc_phi_std=0.1, hc_gate_mean=0.5, hc_gate_std=0.1,
+              hc_bias_std=0.5, num_nextn_predict_layers=1)
+    kw.update(over)
+    return deepseek_v3_tiny(**kw)
 
 
 def deepseek_v3_tiny(**over):

@@ -513,8 +513,11 @@ class GPT2LMHeadModel(nn.Module):
 
 
 @annotate("ds_loss_head")
-def chunked_lm_loss(hidden, wte, labels, chunk, ignore_index=-100):
-    """Fused LM head + next-token cross entropy without a [B, S, V] buffer.
+def chunked_lm_loss(hidden, wte, labels, chunk, ignore_index=-100,
+                    offset=1):
+    """Fused LM head + cross entropy without a [B, S, V] buffer: position i
+    is scored against token ``i + offset`` (1: next-token; 2: a depth-1
+    multi-token-prediction module's, ``models/deepseek_v3.py``).
 
     Scans over chunks of ``chunk`` tokens; each chunk projects [C, E] @
     [E, V] and reduces to per-token nll immediately, so no [C, V] logits
@@ -526,12 +529,12 @@ def chunked_lm_loss(hidden, wte, labels, chunk, ignore_index=-100):
     them by the loss's cotangent. The head costs three matmuls a step (the
     mathematics' own count) and the backward pass derives no logits again.
 
-    Matches ``lm_loss(logits, labels)`` to fp32 rounding: same shift, same
-    ignore_index masking, same mean normalization.
+    Matches ``lm_loss(logits, labels, offset=offset)`` to fp32 rounding: same
+    shift, same ignore_index masking, same mean normalization.
     """
     B, S, E = hidden.shape
-    xs = hidden[:, :-1, :].reshape(-1, E)
-    tgt = labels[:, 1:].reshape(-1)
+    xs = hidden[:, :-offset, :].reshape(-1, E)
+    tgt = labels[:, offset:].reshape(-1)
     n = xs.shape[0]
     pad = (-n) % chunk
     if pad:
@@ -621,12 +624,13 @@ _chunk_scan_loss.defvjp(_chunk_scan_fwd, _chunk_scan_bwd)
 
 
 @annotate("ds_loss_head")
-def lm_loss(logits, labels, ignore_index=-100):
+def lm_loss(logits, labels, ignore_index=-100, offset=1):
     """Next-token cross entropy in fp32. ``labels`` must be the UNSHIFTED
     token ids (typically ``labels is input_ids``); the shift happens here
-    (logits[:, :-1] vs labels[:, 1:]). Do not pre-shift."""
-    logits = logits[:, :-1].astype(jnp.float32)
-    targets = labels[:, 1:]
+    (logits[:, :-1] vs labels[:, 1:]). Do not pre-shift. ``offset`` 2 scores
+    position i against token i + 2 (``chunked_lm_loss``)."""
+    logits = logits[:, :-offset].astype(jnp.float32)
+    targets = labels[:, offset:]
     valid = targets != ignore_index
     targets = jnp.where(valid, targets, 0)
     # -log p(target) = logsumexp(logits) - logits[target]; this form never

@@ -78,14 +78,18 @@ def _global_norm(tree):
     return jnp.sqrt(sum(jnp.sum(jnp.square(l.astype(jnp.float32))) for l in leaves))
 
 
-def _mean_by_name(collection):
+def _mean_by_name(collection, maxima=()):
     """{variable name: mean over every leaf sown under it} of a flax
-    collection (a layer scan stacks a name's values, ``sow`` tuples them)."""
+    collection (a layer scan stacks a name's values, ``sow`` tuples them);
+    of a name among ``maxima`` (a model's ``stat_maxima``) the LARGEST value
+    sown, not the mean."""
     by_name = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(collection)[0]:
         name = [k.key for k in path if hasattr(k, "key")][-1]
-        by_name.setdefault(name, []).append(jnp.mean(leaf))
-    return {n: sum(v) / len(v) for n, v in sorted(by_name.items())}
+        by_name.setdefault(name, []).append(
+            jnp.max(leaf) if name in maxima else jnp.mean(leaf))
+    return {n: jnp.max(jnp.stack(v)) if n in maxima else sum(v) / len(v)
+            for n, v in sorted(by_name.items())}
 
 
 def _tree_where(pred, a, b):
@@ -1260,6 +1264,7 @@ class DeepSpeedEngine:
         # beside the loss and is folded into gauges at a steps_per_print
         # boundary (_telemetry_model_stats)
         sown = tuple(getattr(model, "sown_collections", ()))
+        stat_maxima = tuple(getattr(model, "stat_maxima", ()))
 
         def apply_model(params, inputs, kwargs):
             """(output, auxiliary loss, stats) of the model; when it carries
@@ -1276,7 +1281,8 @@ class DeepSpeedEngine:
                                       mutable=list(sown), **kwargs)
                 aux = sum(jnp.sum(l) for l in jax.tree_util.tree_leaves(
                     vs.get("losses", {})))
-                return out, aux, _mean_by_name(vs.get("stats", {}))
+                return out, aux, _mean_by_name(vs.get("stats", {}),
+                                               stat_maxima)
             return model.apply({"params": params}, inputs, **kwargs), 0.0, {}
 
         def default_loss(params, batch, rng, keep_prob):

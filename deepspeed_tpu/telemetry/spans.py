@@ -191,11 +191,24 @@ def annotate(tag):
 
     - ``mla_latent``, ``mla_expand``, ``mla_rope`` (models/deepseek_v3.py,
       inside the module ``mla_attn``: the down-projection to latent +
-      rotated key and the latent's RMS norm; the up-projection into every
+      rotated key and the latent's RMS norm — and, where queries are
+      compressed, their down-projection and its norm; the up-projection into every
       head's key without position and value, and the kernels' K operand —
       the concat with the broadcast rotated key; the de-interleaving
       rotation of q_rope and of the shared key): ``mla_expand_ms``, and
       with ``flash_*`` and the module name ``mla_attn``, ``mla_layer_ms``.
+    - ``mhc_coeff``, ``mhc_read``, ``mhc_write``
+      (models/hyper_connections.py, in a block of several residual streams,
+      beside its modules: the stream's norm, its projection onto the
+      coefficients, the sigmoids and Sinkhorn's rounds; ``u = H_pre X`` and
+      the streams' sum at a chain's end; ``X_new = H_res X + H_post y`` and
+      the copy into the streams at its start): ``mhc_stream_ms`` and
+      ``mhc_stream_roofline``;
+    - ``mtp`` (models/deepseek_v3.py, round the whole multi-token-prediction
+      module: the next token's embedding, the join, its block, its head norm
+      and its pass through the shared head — which stays ``ds_loss_head``
+      inside it, as its attention stays ``mla_*``): ``mtp_ms``, which reads
+      the path element itself and not a row's tag.
 
     The flax module names ``attn``, ``mlp``, ``ln_1``, ``ln_2``, ``ln_f``
     (models/gpt2.py), ``attn``, ``mlp``, ``input_norm``,

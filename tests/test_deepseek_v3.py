@@ -288,8 +288,12 @@ def test_the_published_shapes_count_30_67_b_and_the_cut_688_m():
                for x in jax.tree_util.tree_leaves(tree)) == tiny.num_params()
 
 
-def test_query_compression_and_group_limits_are_refused_by_name():
-    with pytest.raises(NotImplementedError, match="q_lora_rank=1536"):
-        DeepseekV3Config(q_lora_rank=1536)
+def test_group_limits_are_refused_by_name_and_query_compression_counts():
+    """Group-limited routing is not written and says so; query compression
+    is (ISSUE 56: ``tests/test_xing4.py`` holds it to the reference) and
+    counts its three leaves in place of ``q_proj``."""
     with pytest.raises(NotImplementedError, match="n_group=8"):
         DeepseekV3Config(n_group=8, topk_group=4)
+    whole, cut = DeepseekV3Config(), DeepseekV3Config(q_lora_rank=1536)
+    assert cut.attention_params() - whole.attention_params() \
+        == 2048 * 1536 + 1536 + 1536 * 32 * 192 - 2048 * 32 * 192
