@@ -81,8 +81,8 @@ import flax.linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
 from deepspeed_tpu.models import hyper_connections as hc
-from deepspeed_tpu.models.gpt2 import (_embed_lookup, chunked_lm_loss,
-                                       lm_loss)
+from deepspeed_tpu.models.gpt2 import (_embed_lookup, block_remat_policy,
+                                       chunked_lm_loss, lm_loss)
 from deepspeed_tpu.models.laguna import remat_block, yarn_rope_angles
 from deepspeed_tpu.models.llama import RMSNorm, rope_angles
 from deepspeed_tpu.moe.dropless import (CHOICE_BIAS, HELD_STAT_GAUGES,
@@ -391,15 +391,18 @@ class DeepseekV3Block(nn.Module):
         look = self.is_mutable_collection("intermediates")
 
         def branch(x, name, f):
-            coeff = hc.StreamMixer(
+            # the coefficients and ``u`` from one pass over the stream; the
+            # stream comes back for ``write`` so that its cotangent joins
+            # the others inside the mixer's backward rule
+            u, coeff, x = hc.mix(hc.StreamMixer(
                 n=cfg.hc_mult, sinkhorn_iters=cfg.hc_sinkhorn_iters,
                 eps=cfg.hc_eps,
                 clamp=(cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max),
                 phi_std=cfg.hc_phi_std, gate_mean=cfg.hc_gate_mean,
                 gate_std=cfg.hc_gate_std, bias_std=cfg.hc_bias_std,
-                param_dtype=cfg.param_dtype, name=name)(x)
-            h_pre, h_post, h_res = coeff
-            y = f(hc.read(x, h_pre))
+                param_dtype=cfg.param_dtype, name=name), x)
+            _, h_post, h_res = coeff
+            y = f(u)
             if look:
                 self.sow("intermediates", name + "_coeff", coeff)
             return hc.write(x, y, h_post, h_res), y
@@ -472,6 +475,13 @@ class DeepseekV3ForCausalLM(nn.Module):
             x = _embed_lookup(embed, input_ids).astype(cfg.dtype)
         rope = rope_tables(cfg, jnp.arange(input_ids.shape[1]))
 
+        # several streams: ONE remat policy object for all the blocks. JAX
+        # keys what its partial evaluation makes of a ``jax.jit`` function
+        # inside a rematted block on the policy OBJECT: under one object the
+        # blocks call one copy of each stream kernel in the lowered module,
+        # each under its own a copy a block. One stream: as before
+        policy = block_remat_policy(cfg.remat_policy) if n > 1 else None
+
         def layers(x, names, sparse):
             """``x`` [B, S, C] through the blocks ``names``: copied into the
             streams ahead of them and the streams summed after, where there
@@ -480,7 +490,7 @@ class DeepseekV3ForCausalLM(nn.Module):
                 with annotate("mhc_write"):
                     x = hc.spread(x, n)
             for name, kind in zip(names, sparse):
-                x = remat_block(cfg, self, name, DeepseekV3Block)(
+                x = remat_block(cfg, self, name, DeepseekV3Block, policy)(
                     cfg, kind, name=name)(x, rope)
             if n > 1:
                 with annotate("mhc_read"):

@@ -318,20 +318,20 @@ class LagunaBlock(nn.Module):
         return x + out
 
 
-def remat_block(cfg, parent, name, block=None):
+def remat_block(cfg, parent, name, block=None, policy=None):
     """``block`` (``LagunaBlock`` where not given; another model's block of
     the same calling convention: ``models/smallthinker.py``) under its own
-    ZeRO-3 gather edge (innermost) and,
-    where the config asks, its own remat. Whatever the policy keeps, it
-    keeps the router's choice and the attention kernel's outputs
-    (``models/gpt2.block_remat_policy``); ``prevent_cse``
-    because several rematted blocks share one scan body and a scan of ONE
-    period is no loop once XLA has simplified it
+    ZeRO-3 gather edge (innermost) and, where the config asks, its own remat.
+    Whatever the policy keeps, it keeps the router's choice and the attention
+    kernel's outputs (``models/gpt2.block_remat_policy``: ``policy`` is ONE
+    such object shared by a caller's blocks, None each block's own);
+    ``prevent_cse`` because several rematted blocks share one scan body and
+    a scan of ONE period is no loop once XLA has simplified it
     (``models/qwen3_next._Period``)."""
     block = gather_edge_block(block or LagunaBlock, parent, name)
     if cfg.remat:
-        block = nn.remat(block, prevent_cse=True,
-                         policy=block_remat_policy(cfg.remat_policy))
+        policy = policy or block_remat_policy(cfg.remat_policy)
+        block = nn.remat(block, prevent_cse=True, policy=policy)
     return block
 
 

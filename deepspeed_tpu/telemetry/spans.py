@@ -202,8 +202,8 @@ def annotate(tag):
       beside its modules: the stream's norm, its projection onto the
       coefficients, the sigmoids and Sinkhorn's rounds; ``u = H_pre X`` and
       the streams' sum at a chain's end; ``X_new = H_res X + H_post y`` and
-      the copy into the streams at its start): ``mhc_stream_ms`` and
-      ``mhc_stream_roofline``;
+      the copy into the streams at its start; the kernel form gives ``u``
+      in ``mhc_coeff``'s pass): ``mhc_stream_ms``, ``mhc_stream_roofline``;
     - ``mtp`` (models/deepseek_v3.py, round the whole multi-token-prediction
       module: the next token's embedding, the join, its block, its head norm
       and its pass through the shared head — which stays ``ds_loss_head``

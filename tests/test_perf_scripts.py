@@ -27,11 +27,16 @@ def test_the_scripts_that_stay_are_the_nine():
     ``mla_flash_bench`` (the chunked kernels at latent attention's two
     widths over (block, chunk) plans) is what ``_CHUNK_BYTES``'s comment and
     PERF.md's Findings PR 47 quote, beside ``flash_chunked_bench --plans``
-    (the equal-width cells' shapes: Findings PR 48)."""
+    (the equal-width cells' shapes: Findings PR 48). Eleven since PR 58:
+    ``mhc_stream_bench`` (the residual streams' passes at the Xing4.0 cell's
+    shape, looped against unrolled bodies, a branch against the ``jnp``
+    form) is what ``ROW_TILE``'s and ``SLABS_A_TURN``'s comments in
+    ``ops/pallas/mhc_stream.py`` and PERF.md's Findings PR 58 quote."""
     assert SCRIPTS == [
         "adam_test", "aio_bench", "blocksparse_sweep", "flash_chunked_bench",
-        "gdn_scan_bench", "gmm_tile_bench", "mixer_elementwise_bench",
-        "mla_flash_bench", "rows_to_tokens_bench", "swa_bench"]
+        "gdn_scan_bench", "gmm_tile_bench", "mhc_stream_bench",
+        "mixer_elementwise_bench", "mla_flash_bench", "rows_to_tokens_bench",
+        "swa_bench"]
 
 
 @pytest.mark.parametrize("name", SCRIPTS)

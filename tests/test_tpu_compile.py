@@ -19,6 +19,7 @@ starts to compile its test says so; nothing on the main path may select it
 (ROADMAP S2).
 """
 
+import collections
 import contextlib
 import functools
 import re
@@ -831,6 +832,211 @@ def test_kanana2_step_compiles_for_one_chip_with_its_scopes_and_fits():
                   "moe_gmm_drhs", "moe_router", "moe_dispatch", "moe_combine",
                   "mlp", "ds_loss_head", "ds_embed", "ds_optimizer",
                   "moe_combine/rows_to_tokens", "moe_dispatch/rows_to_tokens"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+
+
+# ------------------------------------------------- the residual streams
+
+MHC_KERNELS = {
+    "mixer": {"_mhc_mix_kernel", "_mhc_mix_bwd_kernel"},
+    "write": {"_mhc_write_kernel", "_mhc_write_bwd_kernel"}}
+MHC_KERNELS["branch"] = MHC_KERNELS["mixer"] | MHC_KERNELS["write"]
+
+
+def mhc_calls(hlo):
+    """Counter of (phase, tag) over the compiled text's Pallas calls, as the
+    benchmark places them (``benchmark/scope_reduce.py`` with the Xing4.0
+    family's tags)."""
+    import collections
+    from benchmark import scope_reduce
+    from benchmark.families import xing4
+    return collections.Counter(
+        (scope_reduce.phase_of(op_name), scope_reduce.tag_of(op_name, xing4))
+        for ln in hlo.splitlines() if "tpu_custom_call" in ln
+        for op_name in re.findall(r'op_name="([^"]*)"', ln))
+
+
+MHC_KERNELS["ends"] = {"_mhc_tile_kernel", "_mhc_sum_kernel"}
+
+
+@pytest.mark.parametrize("entry", ["mixer", "write", "branch", "ends"])
+def test_residual_stream_kernels_fwd_and_grad_compile_at_the_xing4_shape(
+        entry):
+    """The stream mixers at the ``xing4-train-1chip-s4096`` cell's shape —
+    a stream of [1, 4096, 4 x 3584] bf16, ``phi`` [14336, 24] float32 —
+    value and every gradient, each entry alone, a branch's three together
+    and a trunk's two ends: all six passes, with their loops over the column
+    slabs and over Sinkhorn's rounds, are accepted by Mosaic; whole rows of
+    14,336 columns fit the scoped VMEM the calls ask for; the kernels take
+    the call under the benchmark's scopes, each defined ONCE in the module
+    (a trunk's ends twice: the call and the other end's transpose)."""
+    import flax.linen as nn
+    from deepspeed_tpu.models import hyper_connections as hc
+    mixer = hc.StreamMixer(n=4, phi_std=0.02, gate_mean=0.25, gate_std=0.05,
+                           bias_std=0.5)
+    x, y = SDS((1, 4096, 14336), BF16), SDS((1, 4096, 3584), BF16)
+    params = jax.eval_shape(mixer.init, jax.random.PRNGKey(0), x)["params"]
+    assert (params["phi"].shape, params["phi"].dtype) == ((14336, 24), F32)
+    coeff = (SDS((4, 4096), F32), SDS((4, 4096), F32),
+             SDS((4, 4, 4096), F32))
+
+    def run(params, x, y, coeff):
+        with jax.named_scope("layer"):
+            if entry == "write":
+                return (hc.write(x, y, *coeff[1:]),)
+            if entry == "mixer":
+                return mixer.apply({"params": params}, x,
+                                   mutable=["stats"])[0]
+            if entry == "ends":
+                with jax.named_scope("mhc_write"):
+                    spread = hc.spread(y, 4)
+                with jax.named_scope("mhc_read"):
+                    return (hc.merge(x + spread, 4),)
+            (u, (_, post, res), through), _ = nn.apply(
+                hc.mix, mixer, mutable=["stats"])({"params": params}, x)
+            return (hc.write(through, u + y, post, res),)
+
+    def total(*a):
+        return sum(t.astype(F32).sum() for t in run(*a))
+
+    before = [default_registry().peek_gauge(f"mhc/{form}_sites") or 0
+              for form in ("kernel", "xla")]
+    text, compiled = compile_on_chip(
+        jax.value_and_grad(total, argnums=(0, 1, 2, 3)), params, x, y, coeff)
+    assert kernel_names(text) == MHC_KERNELS[entry]
+    definitions = re.findall(r'kernel_name = "([^"]+)"', text)
+    assert len(definitions) == len(MHC_KERNELS[entry]) * (
+        2 if entry == "ends" else 1), definitions
+    # the scoped VMEM each call asks for, and is built inside
+    scoped = set(re.findall(r'size\\22: (\d+)', text))
+    assert scoped == {str(96 * 2 ** 20)}, scoped
+    after = [default_registry().peek_gauge(f"mhc/{form}_sites")
+             for form in ("kernel", "xla")]
+    assert after[0] > before[0] and after[1] == before[1]
+    hlo = compiled.as_text()
+    want = {"mixer": {("forward", "mhc_coeff"): 1,
+                      ("backward", "mhc_coeff"): 1},
+            "write": {("forward", "mhc_write"): 1,
+                      ("backward", "mhc_write"): 1},
+            "ends": {("forward", "mhc_write"): 1, ("forward", "mhc_read"): 1,
+                     ("backward", "mhc_write"): 1,
+                     ("backward", "mhc_read"): 1}}
+    want["branch"] = {**want["mixer"], **want["write"]}
+    assert mhc_calls(hlo) == want[entry]
+    # the stream, its cotangents and a branch's few [4096, 3584] arrays
+    assert compiled.memory_analysis().peak_memory_in_bytes < 1.2e9
+
+
+def _xing4_block(remat=True):
+    """(one dense ``DeepseekV3Block`` of the Xing4.0 cell's configuration
+    under ``remat_block`` as the model runs it, its config)."""
+    import dataclasses
+    import flax.linen as nn
+    from benchmark import manifest
+    from deepspeed_tpu.models import deepseek_v3 as v3
+    from deepspeed_tpu.models.laguna import remat_block
+    bench = manifest.load()
+    config = manifest.config_of(
+        bench, manifest.cell_of(bench, "xing4-train-1chip-s4096"))
+    cfg = dataclasses.replace(
+        manifest.family_module(config).model_config(config, False),
+        remat=remat)
+
+    class OneBlock(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            rope = v3.rope_tables(cfg, jnp.arange(x.shape[1]))
+            return remat_block(cfg, self, "layer_0", v3.DeepseekV3Block)(
+                cfg, False, name="layer_0")(x, rope)
+
+    return OneBlock(), cfg
+
+
+def test_xing4_block_compiles_with_the_stream_kernels_in_its_scopes():
+    """ONE block of the ``xing4-train-1chip-s4096`` cell (the leading dense
+    layer: four streams of 3,584, latent attention, a SwiGLU) at the cell's
+    batch under ``remat_block``, forward and gradient: ``mhc_coeff`` and
+    ``mhc_write`` are path elements of Pallas calls in the forward, the
+    recomputed and the backward instructions — what ``mhc_stream_ms`` sums
+    (``mhc_read`` holds a trunk's ``merge``, not a branch's pass: ``u`` comes
+    from ``mix``) — and the recomputation runs ``mix`` twice and
+    ``write`` once (the second branch's result is the block's); no float32
+    copy of the stream is formed anywhere."""
+    block, cfg = _xing4_block()
+    assert (cfg.hc_mult, cfg.hidden_size, cfg.remat) == (4, 3584, True)
+    x = SDS((1, 4096, 4 * 3584), BF16)
+    params = jax.eval_shape(block.init, jax.random.PRNGKey(0),
+                            SDS((1, 128, 4 * 3584), BF16))["params"]
+
+    def total(params, x):
+        # a scalar of 128 columns: the test forms no float32 stream either
+        out = block.apply({"params": params}, x, mutable=["stats"])[0]
+        return out[..., :128].astype(F32).sum()
+
+    # the value too: a gradient alone leaves nothing of the forward pass
+    text, compiled = compile_on_chip(
+        jax.value_and_grad(total, argnums=(0, 1)), params, x)
+    assert kernel_names(text) >= MHC_KERNELS["branch"]
+    hlo = compiled.as_text()
+    calls = mhc_calls(hlo)
+    assert {k: v for k, v in calls.items() if k[1].startswith("mhc")} == {
+        ("forward", "mhc_coeff"): 2, ("forward", "mhc_write"): 2,
+        ("recompute", "mhc_coeff"): 2, ("recompute", "mhc_write"): 1,
+        ("backward", "mhc_write"): 2, ("backward", "mhc_coeff"): 2}
+    assert not re.search(r"f32\[(1,)?4096,14336\]", hlo)
+
+
+@pytest.mark.slow
+def test_xing4_step_compiles_for_one_chip_with_its_scopes_and_fits():
+    """The WHOLE step of the benchmark's ``xing4-train-1chip-s4096`` cell
+    (Xing4.0's layers 0 and 2-5 + the prediction module as one of 8
+    expert-parallel ranks, 1 x 4,096 tokens, ZeRO-3, through the family's
+    ``lower_train_step``) is accepted for a 16 GB chip with the residual
+    streams' six kernels in it: ``mhc_coeff`` / ``mhc_read`` /
+    ``mhc_write`` are path elements of Pallas calls in forward, recomputed
+    and backward instructions (twelve branches: six blocks of two), each
+    kernel defined once or twice in the module for all of them, every
+    scope the benchmark reads is in an ``op_name``, and the program stays
+    under the chip's 16.911 GB. ~2.5 minutes: slow-marked (the kernels alone
+    and one block are the tier-1 cases above)."""
+    from benchmark import manifest
+    bench = manifest.load()
+    cell = manifest.cell_of(bench, "xing4-train-1chip-s4096")
+    config = manifest.config_of(bench, cell)
+    lowered = manifest.family_module(config).lower_train_step(
+        config, manifest.traffic_of(cell), topo().devices[:1])
+    text = lowered.as_text()
+    assert kernel_names(text) == MHC_KERNELS["branch"] | MHC_KERNELS[
+        "ends"] | {"_fwd_kernel_chunked", "_bwd_kernel_chunked", "kernel",
+                   "_rows_to_tokens_kernel"}
+    # the 74 sites below call ONE function a backward pass and two a forward
+    # pass (the forward's and the recomputation's)
+    defined = collections.Counter(re.findall(r'kernel_name = "([^"]+)"', text))
+    assert {k: v for k, v in defined.items() if k.startswith("_mhc")} == {
+        "_mhc_mix_kernel": 2, "_mhc_write_kernel": 2, "_mhc_tile_kernel": 2,
+        "_mhc_sum_kernel": 2, "_mhc_mix_bwd_kernel": 1,
+        "_mhc_write_bwd_kernel": 1}
+    compiled = lowered.compile()
+    ma = compiled.memory_analysis()
+    assert 0.25 * HBM_BYTES < ma.peak_memory_in_bytes < 15.75 * 2 ** 30
+    assert ma.peak_memory_in_bytes < 14.3e9       # 14.255 before the kernels
+    hlo = compiled.as_text()
+    assert not re.search(r"all-gather|all-reduce|reduce-scatter", hlo)
+    calls = mhc_calls(hlo)
+    # a trunk's two ends beside them (the trunk's and the prediction
+    # module's: ``spread`` under ``mhc_write``, ``merge`` under ``mhc_read``,
+    # each the other's backward)
+    assert {k: v for k, v in calls.items() if k[1].startswith("mhc")} == {
+        ("forward", "mhc_coeff"): 12, ("forward", "mhc_write"): 12 + 2,
+        ("forward", "mhc_read"): 2,
+        ("recompute", "mhc_coeff"): 12, ("recompute", "mhc_write"): 6,
+        ("backward", "mhc_write"): 12 + 2, ("backward", "mhc_read"): 2,
+        ("backward", "mhc_coeff"): 12}
+    for scope in ("mhc_coeff", "mhc_read", "mhc_write", "mtp", "mla_attn",
+                  "mla_latent", "mla_expand", "mla_rope", "flash_fwd_chunk",
+                  "flash_bwd_chunk", "dense_mlp", "moe_shared", "moe_gmm",
+                  "moe_router", "moe_dispatch", "moe_combine", "ds_loss_head",
+                  "ds_embed", "ds_optimizer"):
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
 
 
