@@ -514,10 +514,17 @@ class GPT2LMHeadModel(nn.Module):
 
 @annotate("ds_loss_head")
 def chunked_lm_loss(hidden, wte, labels, chunk, ignore_index=-100,
-                    offset=1):
+                    offset=1, weights=None, normalizer=None):
     """Fused LM head + cross entropy without a [B, S, V] buffer: position i
     is scored against token ``i + offset`` (1: next-token; 2: a depth-1
-    multi-token-prediction module's, ``models/deepseek_v3.py``).
+    multi-token-prediction module's, ``models/deepseek_v3.py``; 0: the
+    token at the position itself, a denoising objective's).
+
+    ``weights`` (float32 [B, S], with ``normalizer``, a count fixed by the
+    caller): the loss is ``sum_i weights_i nll_i / normalizer`` — a
+    block-diffusion step's 1 / t over its masked rows, ``models/llama.py`` —
+    through a second ``custom_vjp`` of the same shape (``_weighted_scan``);
+    None is the program of before, op for op.
 
     Scans over chunks of ``chunk`` tokens; each chunk projects [C, E] @
     [E, V] and reduces to per-token nll immediately, so no [C, V] logits
@@ -533,7 +540,7 @@ def chunked_lm_loss(hidden, wte, labels, chunk, ignore_index=-100,
     shift, same ignore_index masking, same mean normalization.
     """
     B, S, E = hidden.shape
-    xs = hidden[:, :-offset, :].reshape(-1, E)
+    xs = hidden[:, :S - offset, :].reshape(-1, E)
     tgt = labels[:, offset:].reshape(-1)
     n = xs.shape[0]
     pad = (-n) % chunk
@@ -542,7 +549,11 @@ def chunked_lm_loss(hidden, wte, labels, chunk, ignore_index=-100,
         tgt = jnp.pad(tgt, (0, pad), constant_values=ignore_index)
     xs = xs.reshape(-1, chunk, E)
     tgt = tgt.reshape(-1, chunk)
-    return _chunk_scan_loss(xs, wte, tgt, ignore_index)
+    if weights is None:
+        return _chunk_scan_loss(xs, wte, tgt, ignore_index)
+    w = jnp.pad(weights[:, offset:].reshape(-1).astype(jnp.float32),
+                (0, pad)).reshape(-1, chunk)
+    return _weighted_scan(xs, wte, tgt, w, 1.0 / float(normalizer))
 
 
 def _chunk_logits(h, t, wte, ignore_index):
@@ -621,6 +632,58 @@ def _chunk_scan_bwd(ignore_index, res, g):
 
 
 _chunk_scan_loss.defvjp(_chunk_scan_fwd, _chunk_scan_bwd)
+
+
+def _weighted_chunk(h, t, wt, wte):
+    """One chunk's float32 logits [C, V], their log-sum-exp and its weighted
+    nll sum."""
+    logits = (h @ wte.T).astype(jnp.float32)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    g = jnp.take_along_axis(logits, t[:, None], axis=-1)[:, 0]
+    return logits, lse, jnp.sum(wt * (lse - g))
+
+
+@_functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _weighted_scan(xs, wte, tgt, w, inv):
+    """``inv`` x the sum of ``w`` [chunks, C] x the nll of ``xs`` [chunks, C,
+    E] against ``wte`` [V, E] at the targets ``tgt`` (every one a token):
+    ``_chunk_scan_loss`` with a weight a row where that has a valid mask, and
+    a normaliser the caller fixes where that counts the valid rows."""
+    def body(total, htw):
+        return total + _weighted_chunk(*htw, wte)[-1], None
+
+    total, _ = jax.lax.scan(body, jnp.float32(0.0), (xs, tgt, w))
+    return total * inv
+
+
+def _weighted_scan_fwd(xs, wte, tgt, w, inv):
+    """``_chunk_scan_fwd`` with the row's factor ``w x inv`` where that has
+    1 / count: the gradient in the chunk that forms the logits, dlogits cast
+    once, ``dW`` carried in ``wte``'s dtype, last chunk first. (float16's
+    late normalisation is not carried over: no float16 caller.)"""
+    def body(carry, htw):
+        total, dw = carry
+        h, t, wt = htw
+        logits, lse, nll = _weighted_chunk(h, t, wt, wte)
+        onehot = jax.nn.one_hot(t, logits.shape[-1], dtype=jnp.float32)
+        d = ((jnp.exp(logits - lse[:, None]) - onehot)
+             * (wt * inv)[:, None]).astype(h.dtype)
+        return ((total + nll, dw + (d.T @ h).astype(dw.dtype)),
+                (d @ wte).astype(h.dtype))
+
+    xs, dw0 = jax.lax.optimization_barrier((xs, jnp.zeros_like(wte)))
+    (total, dw), dh = jax.lax.scan(
+        body, (jnp.float32(0.0), dw0), (xs, tgt, w), reverse=True)
+    return total * inv, (dh, dw)
+
+
+def _weighted_scan_bwd(inv, res, g):
+    dh, dw = res
+    return ((g * dh.astype(jnp.float32)).astype(dh.dtype),
+            (g * dw.astype(jnp.float32)).astype(dw.dtype), None, None)
+
+
+_weighted_scan.defvjp(_weighted_scan_fwd, _weighted_scan_bwd)
 
 
 @annotate("ds_loss_head")

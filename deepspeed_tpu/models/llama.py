@@ -34,8 +34,8 @@ from jax.ad_checkpoint import checkpoint_name
 
 from deepspeed_tpu.ops.attention import dot_product_attention
 from deepspeed_tpu.models.gpt2 import (_embed_lookup, _remat_policy,
-                                       chunked_lm_loss, gather_edge_block,
-                                       lm_loss)
+                                       block_remat_policy, chunked_lm_loss,
+                                       gather_edge_block, lm_loss)
 from deepspeed_tpu.telemetry.spans import annotate
 
 
@@ -66,10 +66,34 @@ class LlamaConfig:
     #                                  intermediate_size wide (moe/dropless)
     num_experts_per_tok: int = 0     # experts a token is routed to
     norm_topk_prob: bool = False     # renormalise the top-k probabilities
-    qk_norm: bool = False            # RMSNorm over the whole q and k
+    qk_norm: Any = False             # True: RMSNorm over the whole q and k
     #                                  projections, before heads and RoPE
+    #                                  (OLMoE); "head": one RMSNorm a HEAD,
+    #                                  a weight of head_dim shared by the
+    #                                  heads, before RoPE
     router_aux_loss_coef: float = 0.01   # load balancing, E·Σ f_e·P_e
     router_z_loss_coef: float = 0.001    # mean logsumexp(router logits)²
+    head_width: int = 0              # the published ``head_dim`` where it is
+    #                                  a key of its own; 0 → hidden / heads
+    experts_held: int = 0            # >0 → this rank holds that many of the
+    expert_share: int = 0            #   num_experts, the share-th such group
+    #                                  (moe/dropless.DroplessMoE)
+    # Block-diffusion training (BD3-LMs, arXiv 2503.09573): 0 → next-token
+    # training, every program as before. >0 → a forward with ``labels``
+    # noises the sequence block by block (``block_diffusion_noise``), runs
+    # the layers over [noised, clean] rows under the block-diffusion mask
+    # and scores the noised half against the tokens themselves, weighted 1/t
+    block_length: int = 0
+    mask_token_id: int = 0           # the id a noised position carries
+    noise_eps: float = 1e-3          # t = eps + (1 - eps) u, a block
+
+    def __post_init__(self):
+        if self.block_length > 0 and self.loss_chunk <= 0:
+            # the engine hands ``labels`` to a model only with the fused head
+            # (``runtime/engine.default_loss``): without it the step would be
+            # a causal next-token one, silently
+            raise ValueError("block_length > 0 (block-diffusion training) "
+                             "needs the chunked head: set loss_chunk > 0")
 
     @property
     def kv_heads(self):
@@ -77,18 +101,21 @@ class LlamaConfig:
 
     @property
     def head_dim(self):
-        return self.hidden_size // self.n_heads
+        return self.head_width or self.hidden_size // self.n_heads
 
     def num_params(self):
         E, F, L, V = (self.hidden_size, self.intermediate_size,
                       self.n_layers, self.vocab_size)
-        Dkv = self.kv_heads * self.head_dim
+        Dq, Dkv = (h * self.head_dim for h in (self.n_heads, self.kv_heads))
         ffn = 3 * E * F
         if self.num_experts:
-            ffn = self.num_experts * ffn + E * self.num_experts   # + router
-        per_layer = E * E + 2 * E * Dkv + E * E + ffn + 2 * E
-        if self.qk_norm:
-            per_layer += E + Dkv
+            ffn = (self.experts_held or self.num_experts) * ffn \
+                + E * self.num_experts                            # + router
+        per_layer = 2 * E * Dq + 2 * E * Dkv + ffn + 2 * E
+        if self.qk_norm == "head":
+            per_layer += 2 * self.head_dim
+        elif self.qk_norm:
+            per_layer += Dq + Dkv
         return 2 * V * E + L * per_layer + E
 
 
@@ -129,6 +156,8 @@ def apply_rope(x, cos, sin):
 class LlamaAttention(nn.Module):
     config: LlamaConfig
     max_out_tokens: int = 0      # >0 → serving mode with a KV cache
+    diffusion: bool = False      # the rows are [noised, clean] halves of a
+    #                              block-diffusion step: its mask, not causal
 
     @nn.compact
     def __call__(self, x, positions):
@@ -146,13 +175,19 @@ class LlamaAttention(nn.Module):
         # unchanged on this model
         if cfg.qk_norm:
             # OLMoE: one RMSNorm with a learned weight over the WHOLE q
-            # projection and one over the whole k, not per head
+            # projection and one over the whole k, not per head; "head":
+            # over each head's head_dim, one weight for all heads
             with annotate("qk_norm"):
                 norm = lambda name: RMSNorm(  # noqa: E731
                     eps=cfg.rms_eps, dtype=cfg.dtype,
                     param_dtype=cfg.param_dtype, name=name)
-                q = norm("q_norm")(q)
-                k = norm("k_norm")(k)
+                if cfg.qk_norm == "head":
+                    q = norm("q_norm")(q.reshape(B, S, H, D)).reshape(q.shape)
+                    k = norm("k_norm")(k.reshape(B, S, Hkv, D)) \
+                        .reshape(k.shape)
+                else:
+                    q = norm("q_norm")(q)
+                    k = norm("k_norm")(k)
         q = checkpoint_name(q, "qkv")
         k = checkpoint_name(k, "qkv")
         v = checkpoint_name(v, "qkv")
@@ -213,7 +248,11 @@ class LlamaAttention(nn.Module):
 
         from deepspeed_tpu.parallel import mesh as mesh_lib
         mesh = mesh_lib.current_mesh()
-        if mesh is not None and mesh.shape.get(mesh_lib.SEQ_AXIS, 1) > 1 \
+        if self.diffusion:
+            from deepspeed_tpu.ops.attention import block_diffusion_attention
+            out = block_diffusion_attention(qh, kh, vh, cfg.block_length,
+                                            use_flash=cfg.use_flash)
+        elif mesh is not None and mesh.shape.get(mesh_lib.SEQ_AXIS, 1) > 1 \
                 and S % mesh.shape[mesh_lib.SEQ_AXIS] == 0:
             # the SP backends shard/rotate K/V across the seq axis at
             # full head count — repeat for them only
@@ -259,6 +298,7 @@ class LlamaMLP(nn.Module):
 class LlamaBlock(nn.Module):
     config: LlamaConfig
     max_out_tokens: int = 0
+    diffusion: bool = False
 
     @nn.compact
     def __call__(self, x, positions):
@@ -266,8 +306,8 @@ class LlamaBlock(nn.Module):
         norm = lambda name: RMSNorm(  # noqa: E731
             eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             name=name)
-        attn = LlamaAttention(cfg, self.max_out_tokens, name="attn")(
-            norm("input_norm")(x), positions)
+        attn = LlamaAttention(cfg, self.max_out_tokens, self.diffusion,
+                              name="attn")(norm("input_norm")(x), positions)
         x = x + attn
         if cfg.num_experts:
             from deepspeed_tpu.moe.dropless import DroplessMoE
@@ -276,7 +316,11 @@ class LlamaBlock(nn.Module):
                 cfg.intermediate_size, norm_topk_prob=cfg.norm_topk_prob,
                 balance_coeff=cfg.router_aux_loss_coef,
                 z_coeff=cfg.router_z_loss_coef, dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype, name="mlp")
+                param_dtype=cfg.param_dtype, experts_held=cfg.experts_held,
+                expert_share=cfg.expert_share,
+                # the "block" remat policy saves the router's choice
+                pin_choice=cfg.remat and cfg.remat_policy == "block",
+                name="mlp")
         else:
             ffn = LlamaMLP(cfg, name="mlp")
         out = ffn(norm("post_attn_norm")(x))
@@ -295,25 +339,59 @@ def _maybe_remat(cfg, parent, name):
     block = gather_edge_block(LlamaBlock, parent, name)
     if not cfg.remat:
         return block
-    return nn.remat(block, prevent_cse=False,
-                    policy=_remat_policy(cfg.remat_policy))
+    # "block": what ``models/gpt2.block_remat_policy`` keeps (the router's
+    # choice, the attention kernel's output and log-sum-exp), nothing else
+    policy = block_remat_policy() if cfg.remat_policy == "block" \
+        else _remat_policy(cfg.remat_policy)
+    return nn.remat(block, prevent_cse=False, policy=policy)
 
 
 class _ScanBody(nn.Module):
     config: LlamaConfig
     max_out_tokens: int = 0
+    diffusion: bool = False
 
     @nn.compact
     def __call__(self, x, positions):
         block = _maybe_remat(self.config, self, "blk")
-        return block(self.config, self.max_out_tokens,
+        return block(self.config, self.max_out_tokens, self.diffusion,
                      name="blk")(x, positions), None
+
+
+# what a block-diffusion forward sows into ``stats``, and the gauges
+DIFFUSION_STAT_GAUGES = {"diffusion_masked_share": "diffusion/masked_share",
+                         "diffusion_weight_max": "diffusion/weight_max"}
+
+
+def block_diffusion_noise(key, input_ids, block_length, eps, mask_token_id):
+    """(noisy_ids [B, L] int, masked [B, L] bool, t_row [B, L] float32) of
+    ``input_ids`` [B, L], L a whole number of blocks of ``block_length``: the
+    forward process of block-diffusion training (BD3-LMs, arXiv 2503.09573;
+    linear schedule, one noise level a BLOCK). A pure function of ``key``, in
+    this order: ``key_t, key_v = split(key)``; ``u`` uniform [B, L /
+    block_length) from ``key_t`` and ``t = eps + (1 - eps) u``, a block;
+    ``v`` uniform [B, L) from ``key_v``; position i is masked where ``v_i <
+    t`` of its block and then carries ``mask_token_id``. ``t_row`` is each
+    position's own block's t (the loss weighs a masked row by 1 / t)."""
+    B, L = input_ids.shape
+    if L % block_length:
+        raise ValueError(f"a sequence of {L} tokens is no whole number of "
+                         f"diffusion blocks of {block_length}")
+    key_t, key_v = jax.random.split(key)
+    u = jax.random.uniform(key_t, (B, L // block_length), jnp.float32)
+    t_row = jnp.repeat(eps + (1.0 - eps) * u, block_length, axis=1)
+    masked = jax.random.uniform(key_v, (B, L), jnp.float32) < t_row
+    noisy = jnp.where(masked, jnp.asarray(mask_token_id, input_ids.dtype),
+                      input_ids)
+    return noisy, masked, t_row
 
 
 class LlamaForCausalLM(nn.Module):
     """Decoder-only LLaMA LM. ``labels`` triggers the fused chunked
     head+loss (models/gpt2.chunked_lm_loss works for any untied head via
-    the lm_head kernel)."""
+    the lm_head kernel). With ``config.block_length`` > 0 a forward with
+    ``labels`` is a block-diffusion training step (``LlamaConfig``) and
+    draws its noise from the rng stream ``diffusion``."""
     config: LlamaConfig
     max_out_tokens: int = 0      # >0 → serving mode (KV caches)
 
@@ -330,13 +408,29 @@ class LlamaForCausalLM(nn.Module):
         are — the MoE router's two, already weighted) and ``stats``
         (scalars it carries out of the step and folds into the gauges
         ``stat_gauges`` names). None without experts."""
-        return ("losses", "stats") if self.config.num_experts else ()
+        return ("losses", "stats") if self.config.num_experts \
+            else ("stats",) if self.config.block_length else ()
 
     @property
     def stat_gauges(self):
         """{variable sown into ``stats``: the gauge it is read under}."""
-        from deepspeed_tpu.moe.dropless import STAT_GAUGES
-        return STAT_GAUGES if self.config.num_experts else {}
+        from deepspeed_tpu.moe.dropless import HELD_STAT_GAUGES, STAT_GAUGES
+        cfg = self.config
+        gauges = {} if not cfg.num_experts else \
+            HELD_STAT_GAUGES if cfg.experts_held else STAT_GAUGES
+        return dict(gauges, **DIFFUSION_STAT_GAUGES) if cfg.block_length \
+            else gauges
+
+    @property
+    def stat_maxima(self):
+        """The ``stats`` folded as the largest value sown, not the mean."""
+        return ("diffusion_weight_max",) if self.config.block_length else ()
+
+    @property
+    def rng_streams(self):
+        """The rng streams a training forward draws from: the engine hands
+        the step's key under each (``runtime/engine.py``)."""
+        return ("diffusion",) if self.config.block_length else ()
 
     @nn.compact
     def __call__(self, input_ids, labels=None, deterministic=True,
@@ -346,9 +440,26 @@ class LlamaForCausalLM(nn.Module):
         embed = self.param("embed_tokens", nn.initializers.normal(0.02),
                            (cfg.vocab_size, cfg.hidden_size),
                            cfg.param_dtype)
+        diffusion = cfg.block_length > 0 and labels is not None
+        if diffusion:
+            # the rows of every layer: the noised sequence, then the clean
+            # one, each at positions 0 .. S-1
+            with annotate("bd_noise"):
+                noisy, masked, t_row = block_diffusion_noise(
+                    self.make_rng("diffusion"), input_ids, cfg.block_length,
+                    cfg.noise_eps, cfg.mask_token_id)
+                input_ids = jnp.concatenate([noisy, input_ids], axis=1)
+                weights = jnp.where(masked, 1.0 / t_row, 0.0)
+            self.sow("stats", "diffusion_masked_share",
+                     jnp.mean(masked.astype(jnp.float32)))
+            self.sow("stats", "diffusion_weight_max", jnp.max(weights))
+            if self.is_mutable_collection("intermediates"):
+                self.sow("intermediates", "bd_noise", (noisy, masked, t_row))
         with annotate("ds_embed"):
             x = _embed_lookup(embed, input_ids).astype(cfg.dtype)
         positions = position_offset + jnp.arange(S)
+        if diffusion:
+            positions = jnp.concatenate([positions, positions])
 
         if cfg.scan_layers:
             axes = {"params": 0, "cache": 0}
@@ -360,19 +471,28 @@ class LlamaForCausalLM(nn.Module):
                               in_axes=(nn.broadcast,),
                               length=cfg.n_layers,
                               unroll=max(1, cfg.scan_unroll))
-            x, _ = scanned(cfg, self.max_out_tokens,
+            x, _ = scanned(cfg, self.max_out_tokens, diffusion,
                            name="layers")(x, positions)
         else:
             for i in range(cfg.n_layers):
                 block = _maybe_remat(cfg, self, f"layers_{i}")
-                x = block(cfg, self.max_out_tokens,
+                x = block(cfg, self.max_out_tokens, diffusion,
                           name=f"layers_{i}")(x, positions)
 
+        if diffusion:
+            with annotate("bd_noise"):
+                x = x[:, :S]              # the head reads the noised half
         x = RMSNorm(eps=cfg.rms_eps, dtype=cfg.dtype,
                     param_dtype=cfg.param_dtype, name="norm")(x)
         head = self.param("lm_head", nn.initializers.normal(0.02),
                           (cfg.vocab_size, cfg.hidden_size),
                           cfg.param_dtype)
+        if diffusion:
+            # row i of the noised half predicts token i itself: no shift, a
+            # masked row weighs 1 / t of its block, the sum over B x S tokens
+            return chunked_lm_loss(x, head.astype(cfg.dtype), labels,
+                                   cfg.loss_chunk, offset=0, weights=weights,
+                                   normalizer=B * S)
         if labels is not None and cfg.loss_chunk > 0:
             return chunked_lm_loss(x, head.astype(cfg.dtype), labels,
                                    cfg.loss_chunk)

@@ -181,3 +181,50 @@ def fused_qkv_attention(qkv, heads, causal=False, scale=None,
     q, k, v = (to_head_major(t, heads) for t in jnp.split(qkv, 3, axis=-1))
     return from_head_major(reference_attention(q, k, v, causal=causal,
                                                scale=scale))
+
+
+def block_diffusion_mask(L, block_length):
+    """bool [2L, 2L]: may query row r see key row s, over the L noised rows
+    then the L clean rows of a block-diffusion training step (BD3-LMs,
+    arXiv 2503.09573). With blk(r) = (r mod L) // block_length: a noised
+    query sees its own block among the noised rows (both directions) and the
+    clean rows of strictly earlier blocks; a clean query the clean rows of
+    its own and earlier blocks."""
+    r = jnp.arange(2 * L)
+    clean, blk = r >= L, (r % L) // block_length
+    cq, ck, bq, bk = clean[:, None], clean[None], blk[:, None], blk[None]
+    return (~cq & ~ck & (bk == bq)) | (~cq & ck & (bk < bq)) \
+        | (cq & ck & (bk <= bq))
+
+
+def reference_block_diffusion_attention(q, k, v, block_length, scale=None):
+    """``block_diffusion_attention`` with the dense mask, in plain XLA: the
+    oracle of the kernels (``ops/pallas/block_diffusion_attention.py``), and
+    the one place a [2L, 2L] array is built."""
+    bias = jnp.where(block_diffusion_mask(q.shape[2] // 2, block_length),
+                     0.0, -1e30)
+    return reference_attention(q, k, v, bias=bias[None, None], scale=scale)
+
+
+def block_diffusion_attention(q, k, v, block_length, scale=None,
+                              use_flash=None):
+    """[B, H, 2L, D] attention of a block-diffusion training step: the rows
+    are a sequence's L noised tokens, then its L clean ones, and the mask
+    (``block_diffusion_mask``) is given by ``block_length`` and the rows'
+    count alone, never as an array. The Pallas kernels run on every backend
+    (the interpreter off a TPU); ``use_flash=False`` is the dense-mask
+    oracle. K/V may carry Hkv < H heads."""
+    check_qkv_shapes(q, k, v)
+    if use_flash is not None and not use_flash:
+        return reference_block_diffusion_attention(q, k, v, block_length,
+                                                   scale)
+    from deepspeed_tpu.ops.pallas.block_diffusion_attention import \
+        block_diffusion_attention as kernel
+    kernel = functools.partial(kernel, block_length=block_length, scale=scale)
+    mesh, batch_axes, model_axis = _device_axes(
+        q.shape[0], np.gcd(q.shape[1], k.shape[1]))
+    if mesh is None:
+        return kernel(q, k, v)
+    spec = jax.sharding.PartitionSpec(batch_axes, model_axis)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)

@@ -1257,14 +1257,14 @@ class DeepSpeedEngine:
         uses_moe = getattr(model_cfg, "moe_experts", 0) and \
             getattr(model_cfg, "moe_experts", 0) > 0
         moe_aux_coeff = float(getattr(model_cfg, "moe_aux_coeff", 0.01))
-        # a model that sows says so itself (``sown_collections``, a
-        # property of the model as ``layer_stacked_subtree`` is): what it
-        # puts in "losses" is added to the objective AS IT IS, each term
-        # weighted by the model's own coefficient; "stats" leaves the step
-        # beside the loss and is folded into gauges at a steps_per_print
-        # boundary (_telemetry_model_stats)
+        # a model that sows says so itself (``sown_collections``, a property
+        # of the model): "losses" is added to the objective AS IT IS, "stats"
+        # leaves the step beside the loss and is folded into gauges at a
+        # steps_per_print boundary (_telemetry_model_stats); one that DRAWS in
+        # training names its ``rng_streams``, each handed the step's key
         sown = tuple(getattr(model, "sown_collections", ()))
         stat_maxima = tuple(getattr(model, "stat_maxima", ()))
+        rng_names = _rng_names(model, has_dropout)
 
         def apply_model(params, inputs, kwargs):
             """(output, auxiliary loss, stats) of the model; when it carries
@@ -1292,8 +1292,8 @@ class DeepSpeedEngine:
                 kwargs["keep_prob"] = keep_prob
             if accepts_deterministic:
                 kwargs["deterministic"] = not has_dropout
-            if has_dropout:
-                kwargs["rngs"] = {"dropout": rng}
+            if rng_names:
+                kwargs["rngs"] = dict.fromkeys(rng_names, rng)
             if isinstance(batch, dict) and "input_ids" in batch:
                 labels = batch.get("labels", batch["input_ids"])
                 if fused_loss:
@@ -3476,3 +3476,12 @@ class DeepSpeedEngine:
         self._ensure_params_resident()
         os.makedirs(save_dir, exist_ok=True)
         ckpt.save_tree(os.path.join(save_dir, save_filename), self.state.params)
+
+
+def _rng_names(model, has_dropout):
+    """The rng streams a training forward is handed the step's key under (a
+    micro-batch's own split under accumulation): ``dropout`` where the
+    model's config has it, and what the model itself names in
+    ``rng_streams`` (a block-diffusion step's ``diffusion``)."""
+    return (("dropout",) if has_dropout else ()) \
+        + tuple(getattr(model, "rng_streams", ()))

@@ -181,13 +181,13 @@ def annotate(tag):
       metric reads them by name — they start with no kernel tag, so their
       time stays under the module scope round them.
 
-    - ``swa_fwd``, ``swa_bwd``
-      (ops/pallas/flash_attention.py, round the two ``pallas_call``s of
-      the window kernels, the backward ONE since PR 53): ``swa_attn_share``,
-      ``swa_fwd_roofline`` and ``swa_bwd_roofline`` (a tag matches by
-      prefix: ``swa_bwd`` read the split ``swa_bwd_dq`` / ``swa_bwd_dkv``);
-    - ``dense_mlp`` (models/laguna.py, models/deepseek_v3.py: the leading
-      layer's SwiGLU): a row of the detail table.
+    - ``swa_fwd``, ``swa_bwd`` (ops/pallas/flash_attention.py, round the
+      window kernels' two ``pallas_call``s): ``swa_attn_share`` and
+      ``swa_*_roofline``; ``bd_fwd``, ``bd_bwd`` (ops/pallas/
+      block_diffusion_attention.py, the same for the block-diffusion mask;
+      ``bd_bwd_dq_sum`` the XLA sum of dq's partials): ``bd_attn_share``,
+      ``bd_*_roofline``; ``bd_noise`` (models/llama.py): ``bd_noise_ms``;
+    - ``dense_mlp`` (laguna.py, deepseek_v3.py: the leading SwiGLU): a row.
 
     - ``mla_latent``, ``mla_expand``, ``mla_rope`` (models/deepseek_v3.py,
       inside the module ``mla_attn``: the down-projection to latent +
@@ -237,16 +237,16 @@ def annotate(tag):
     (value heads a grid step of its kernels; 0: the XLA form took the
     call) and ``linear_attn/gdn_states_kept_every`` (chunks between the
     states kept for the backward pass): no benchmark metric reads
-    them. The window kernels leave three:
-    ``attention/window_tile_overcompute`` (score elements their tiles
-    compute over those the band holds, forward and backward), which
-    ``swa_tile_overcompute`` reads,
-    ``attention/window_tiles_per_grid_step`` (score tiles of the two
-    calls over their grid steps: the band's tile count forward, that
-    times the query heads a step holds backward, under 1 at a tile a
-    step) and ``attention/window_bwd_tiles_per_grid_step`` (query heads a
-    step of the single-pass backward holds x tiles of the band's step),
-    which no benchmark metric reads. A chunked call whose q·k width is not its value
+    them. The window kernels leave ``attention/window_tile_overcompute``
+    (score elements their tiles compute over those the band holds), which
+    ``swa_tile_overcompute`` reads, ``attention/window_tiles_per_grid_step``
+    and ``attention/window_bwd_tiles_per_grid_step`` (score tiles a grid
+    step of the two calls, and of the backward's), which no metric reads;
+    the block-diffusion kernels ``attention/bd_tile_overcompute`` (read by
+    ``bd_tile_overcompute``) and ``attention/bd_tiles_per_grid_step``, and
+    their model's step ``diffusion/masked_share`` and
+    ``diffusion/weight_max`` (the largest 1 / t that met a masked row).
+    A chunked call whose q·k width is not its value
     width (latent attention) leaves ``attention/mla_qk_dim`` and
     ``attention/mla_v_dim``, the two widths as the kernels saw them (192 /
     128 on the Kanana-2 cell; untouched by every equal-width call).

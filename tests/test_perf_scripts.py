@@ -31,9 +31,14 @@ def test_the_scripts_that_stay_are_the_nine():
     ``mhc_stream_bench`` (the residual streams' passes at the Xing4.0 cell's
     shape, looped against unrolled bodies, a branch against the ``jnp``
     form) is what ``ROW_TILE``'s and ``SLABS_A_TURN``'s comments in
-    ``ops/pallas/mhc_stream.py`` and PERF.md's Findings PR 58 quote."""
+    ``ops/pallas/mhc_stream.py`` and PERF.md's Findings PR 58 quote. Twelve
+    since PR 60: ``bd_attention_bench`` (the block-diffusion mask kernels at
+    the SDAR cell's shape over (tile, chunk) plans) is what ``_CHUNK_ROWS``'s
+    comment in ``ops/pallas/block_diffusion_attention.py`` and PERF.md's
+    Findings PR 60 quote."""
     assert SCRIPTS == [
-        "adam_test", "aio_bench", "blocksparse_sweep", "flash_chunked_bench",
+        "adam_test", "aio_bench", "bd_attention_bench", "blocksparse_sweep",
+        "flash_chunked_bench",
         "gdn_scan_bench", "gmm_tile_bench", "mhc_stream_bench",
         "mixer_elementwise_bench", "mla_flash_bench", "rows_to_tokens_bench",
         "swa_bench"]

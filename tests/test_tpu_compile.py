@@ -1072,3 +1072,42 @@ def test_grouped_quantize_kernel_compiles():
                        "Blocked(block_size=1), Blocked(block_size=1280)"):
         compile_on_chip(lambda x: quantize(x, groups=1280, interpret=False),
                         SDS((1280, 1280), BF16))
+
+
+@pytest.mark.parametrize("L,heads,kv_heads,block_length,grid", [
+    (8192, 32, 4, 4, 64),       # sdar-train-1chip-s8192's layer
+    (1280, 6, 2, 32, 35),       # an odd one: tiles of 256, 5 a half
+], ids=["sdar", "L1280-b32"])
+def test_block_diffusion_attention_compiles_at_the_sdar_cells_shape(
+        L, heads, kv_heads, block_length, grid):
+    """1 x 32 / 4 heads x 2 x 8,192 rows x head_dim 128, bf16, block length 4
+    (``sdar-train-1chip-s8192``), forward and the single-pass backward under
+    the blocks' remat policy: the two mask kernels under their scopes on grid
+    (heads, 64) — the (query block, key chunk) pairs that hold an allowed
+    score at tiles of 512 and chunks of 4,096, of the 32 x 4 a dense walk
+    would take — K and V read at their own heads, the forward kernel once,
+    the log-sum-exp lane-dense, and no [2L, 2L] array of any dtype."""
+    from deepspeed_tpu.ops.pallas.block_diffusion_attention import \
+        block_diffusion_attention
+
+    def attend(*a):
+        with jax.named_scope("attn"):
+            return block_diffusion_attention(
+                *a, block_length=block_length).astype(F32).sum()
+
+    def grads(q, k, v):
+        return jax.grad(rematted(attend), argnums=(0, 1, 2))(q, k, v)
+
+    shapes = (SDS((1, heads, 2 * L, 128), BF16),
+              SDS((1, kv_heads, 2 * L, 128), BF16),
+              SDS((1, kv_heads, 2 * L, 128), BF16))
+    text, compiled = compile_on_chip(grads, *shapes)
+    assert kernel_names(text) == {"_bd_fwd_kernel", "_bd_bwd_kernel"}
+    assert pallas_grids(grads, *shapes) == [(heads, grid)] * 2
+    hlo = compiled.as_text()
+    calls = flash_calls(hlo)
+    assert all(f"bf16[{kv_heads},{2 * L},128]" in c for c in calls)
+    for scope in ("bd_fwd", "bd_bwd", "bd_bwd_dq_sum"):
+        assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
+    assert f"{2 * L},{2 * L}" not in hlo
+    assert_dense_lse_kept(hlo, calls, f"f32[{heads},{2 * L // 128},1,128]")
