@@ -71,6 +71,7 @@ Without ``labels`` the module is not run (in serving it is a drafter, which
 this repo does not have: ROADMAP R8).
 """
 
+import collections
 import dataclasses
 import math
 from typing import Any, Optional
@@ -81,12 +82,13 @@ import flax.linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
 from deepspeed_tpu.models import hyper_connections as hc
-from deepspeed_tpu.models.gpt2 import (_embed_lookup, block_remat_policy,
-                                       chunked_lm_loss, lm_loss)
-from deepspeed_tpu.models.laguna import remat_block, yarn_rope_angles
+from deepspeed_tpu.models.gpt2 import _embed_lookup, chunked_lm_loss, lm_loss
+from deepspeed_tpu.models.laguna import (remat_block, stack_remat_policy,
+                                         yarn_rope_angles)
 from deepspeed_tpu.models.llama import RMSNorm, rope_angles
 from deepspeed_tpu.moe.dropless import (CHOICE_BIAS, HELD_STAT_GAUGES,
                                         STAT_GAUGES, DroplessMoE)
+from deepspeed_tpu.moe.dropless import remat_row_bytes as moe_row_bytes
 from deepspeed_tpu.ops.attention import dot_product_attention
 from deepspeed_tpu.telemetry.spans import annotate
 
@@ -314,12 +316,14 @@ class MLAttention(nn.Module):
             q = _dense(cfg, H * (Dn + Dr), "q_proj")(x)
         else:
             with annotate("mla_latent"):
-                c_q = _norm(cfg, "q_a_norm")(
-                    _dense(cfg, cfg.q_lora_rank, "q_a_proj")(x))
+                c_q = _norm(cfg, "q_a_norm")(checkpoint_name(
+                    _dense(cfg, cfg.q_lora_rank, "q_a_proj")(x), "qkv"))
             q = _dense(cfg, H * (Dn + Dr), "q_b_proj")(c_q)
         q = q.reshape(B, S, H, Dn + Dr)
         with annotate("mla_latent"):
-            down = _dense(cfg, R + Dr, "kv_a_proj")(x)
+            # ``qkv``: what the backward pass reads of the projections, the
+            # latents ahead of their norms here, the kernels' operands below
+            down = checkpoint_name(_dense(cfg, R + Dr, "kv_a_proj")(x), "qkv")
             latent = _norm(cfg, "kv_a_norm")(down[..., :R])
         with annotate("mla_expand"):
             kv = _dense(cfg, H * (Dn + Dv), "kv_b_proj")(latent).reshape(
@@ -354,9 +358,10 @@ class DenseMLP(nn.Module):
     def __call__(self, x):
         cfg = self.config
         with annotate("dense_mlp"):
-            h = nn.silu(_dense(cfg, cfg.intermediate_size, "gate_proj")(x)) \
-                * _dense(cfg, cfg.intermediate_size, "up_proj")(x)
-            h = checkpoint_name(h, "mlp_fc")
+            # ``mlp_fc`` names what the activation's backward pass reads
+            pre = lambda name: checkpoint_name(  # noqa: E731
+                _dense(cfg, cfg.intermediate_size, name)(x), "mlp_fc")
+            h = nn.silu(pre("gate_proj")) * pre("up_proj")
             return checkpoint_name(
                 _dense(cfg, cfg.hidden_size, "down_proj")(h), "mlp_proj")
 
@@ -440,6 +445,31 @@ class DeepseekV3Block(nn.Module):
             pin_choice=cfg.remat, name="mlp")(h)
 
 
+def remat_row_bytes(cfg):
+    """{checkpoint name: bytes a row, summed over the blocks that carry it
+    — the layers and the prediction module's}: what
+    ``models/laguna.stack_remat_policy`` weighs against its budget."""
+    b = jnp.dtype(cfg.dtype).itemsize
+    heads = cfg.num_attention_heads
+    attention = {
+        "qkv": b * (heads * (2 * cfg.qk_head_dim + cfg.v_head_dim)
+                    + cfg.kv_lora_rank + cfg.qk_rope_head_dim
+                    + (cfg.q_lora_rank or 0)),
+        "attn_proj": b * cfg.hidden_size,
+        # several streams: ``hc.write``'s backward pass reads the branch it
+        # adds, the FFN's as the mixer's (one stream: nothing reads it)
+        "mlp_proj": b * cfg.hidden_size * (cfg.hc_mult > 1)}
+    experts = moe_row_bytes(
+        cfg.n_routed_experts,
+        cfg.n_shared_experts * cfg.moe_intermediate_size, itemsize=b)
+    total = collections.Counter()
+    for i in range(cfg.num_hidden_layers + cfg.num_nextn_predict_layers):
+        total.update(attention)
+        total.update({"mlp_fc": 2 * b * cfg.intermediate_size}
+                     if i < cfg.first_k_dense_replace else experts)
+    return total
+
+
 class DeepseekV3ForCausalLM(nn.Module):
     """Decoder-only LM; ``labels`` with ``loss_chunk`` takes the fused
     chunked head + loss (``models/gpt2.chunked_lm_loss``)."""
@@ -475,12 +505,12 @@ class DeepseekV3ForCausalLM(nn.Module):
             x = _embed_lookup(embed, input_ids).astype(cfg.dtype)
         rope = rope_tables(cfg, jnp.arange(input_ids.shape[1]))
 
-        # several streams: ONE remat policy object for all the blocks. JAX
-        # keys what its partial evaluation makes of a ``jax.jit`` function
-        # inside a rematted block on the policy OBJECT: under one object the
-        # blocks call one copy of each stream kernel in the lowered module,
-        # each under its own a copy a block. One stream: as before
-        policy = block_remat_policy(cfg.remat_policy) if n > 1 else None
+        # ONE remat policy object for all the blocks, the prediction
+        # module's among them; a block's input is its n streams
+        policy = stack_remat_policy(
+            cfg, input_ids.size,
+            cfg.num_hidden_layers + cfg.num_nextn_predict_layers,
+            remat_row_bytes(cfg), streams=n)
 
         def layers(x, names, sparse):
             """``x`` [B, S, C] through the blocks ``names``: copied into the

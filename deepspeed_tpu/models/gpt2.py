@@ -381,26 +381,26 @@ def _remat_policy(name):
     raise ValueError(f"unknown remat_policy {name!r}")
 
 
-# what a block under remat keeps whatever else it is asked to keep: the
-# router's choice (``moe/dropless.route``: the recomputed forward pass must
-# route as the first one did) and what its attention kernel produced —
-# one [B, S, H*D] output a layer and a lane-dense log-sum-exp 1/64 of it
-# (``ops/pallas/flash_attention.py`` names both in every VJP). Per byte
-# kept they are the dearest thing a block recomputes: without them every
-# forward attention kernel runs twice a step (PERF.md, PR 34)
+# what a block under remat keeps whatever the memory: the router's choice
+# (``moe/dropless.route``) and its attention kernel's output and log-sum-exp
 REMAT_BASE_NAMES = ("moe_experts", "flash_o", "flash_lse")
+# ... and what it keeps besides while the bytes fit, dearest a byte first
+REMAT_CANDIDATES = ("moe_scores", "attn_proj", "mlp_proj", "qkv", "mixer_in",
+                    "mlp_fc")
 
 
-def block_remat_policy(name=None):
-    """Policy of the blocks that are rematted one by one
-    (``models/laguna.remat_block``, ``models/qwen3_next._Period``):
-    ``REMAT_BASE_NAMES``, joined with the named policy ``name`` where the
-    config gives one (None: nothing further kept)."""
-    base = jax.checkpoint_policies.save_only_these_names(*REMAT_BASE_NAMES)
-    if name is None:
-        return base
-    return jax.checkpoint_policies.save_from_both_policies(
-        _remat_policy(name), base)
+def block_remat_policy(name=None, **stack):
+    """Policy of the blocks that are rematted one by one: a named policy
+    ``name`` joined with ``REMAT_BASE_NAMES``; with none, these and the
+    candidates that fit the trace's byte budget by ``stack``, the caller's
+    figures (``runtime/remat_budget.keep_for_stack``; none: the base names)."""
+    from deepspeed_tpu.runtime.remat_budget import keep_for_stack
+    base = jax.checkpoint_policies.save_only_these_names(
+        *REMAT_BASE_NAMES, *(keep_for_stack(REMAT_CANDIDATES, **stack)
+                             if stack and name is None else ()))
+    return base if name is None else \
+        jax.checkpoint_policies.save_from_both_policies(
+            _remat_policy(name), base)
 
 
 def _maybe_remat(cfg, parent, name):

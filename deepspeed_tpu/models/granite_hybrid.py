@@ -37,6 +37,7 @@ what a scan's stacked leaves cost the benchmark's float32 reference; ten
 blocks trace in seconds).
 """
 
+import collections
 import dataclasses
 from typing import Any, Optional
 
@@ -46,9 +47,10 @@ from jax.ad_checkpoint import checkpoint_name
 
 from deepspeed_tpu.models.gpt2 import _embed_lookup, chunked_lm_loss, lm_loss
 from deepspeed_tpu.models.laguna import (FULL, LagunaAttention, _dense,
-                                         remat_block)
+                                         qkv_row_bytes, remat_block,
+                                         stack_remat_policy)
 from deepspeed_tpu.models.llama import RMSNorm
-from deepspeed_tpu.models.nemotron_h import Mamba2Mixer
+from deepspeed_tpu.models.nemotron_h import Mamba2Mixer, mixer_in_row_bytes
 from deepspeed_tpu.telemetry.spans import annotate
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -158,8 +160,9 @@ class GraniteSharedMLP(nn.Module):
     def __call__(self, x):
         cfg = self.config
         F = cfg.shared_intermediate_size
-        gp = _dense(cfg, 2 * F, "input_linear")(x)
-        h = checkpoint_name(nn.silu(gp[..., :F]) * gp[..., F:], "mlp_fc")
+        # ``mlp_fc`` names what the activation's backward pass reads
+        gp = checkpoint_name(_dense(cfg, 2 * F, "input_linear")(x), "mlp_fc")
+        h = nn.silu(gp[..., :F]) * gp[..., F:]
         return checkpoint_name(
             _dense(cfg, cfg.hidden_size, "output_linear")(h), "mlp_proj")
 
@@ -203,6 +206,21 @@ class GraniteHybridBlock(nn.Module):
         return _add_branch(mid, cfg.residual_multiplier, out)
 
 
+def remat_row_bytes(cfg):
+    """{checkpoint name: bytes a row, summed over the layers that carry
+    it}: what ``models/laguna.stack_remat_policy`` weighs against its
+    budget."""
+    b = jnp.dtype(cfg.dtype).itemsize
+    each = {MAMBA: {"mixer_in": mixer_in_row_bytes(cfg)},
+            ATTENTION: {"qkv": qkv_row_bytes(cfg, cfg.num_attention_heads)}}
+    total = collections.Counter()
+    for kind in cfg.layer_types:
+        total.update(each[kind])
+        total.update({"attn_proj": b * cfg.hidden_size,
+                      "mlp_fc": 2 * b * cfg.shared_intermediate_size})
+    return total
+
+
 class GraniteHybridForCausalLM(nn.Module):
     """Decoder-only LM whose head is its embedding; ``labels`` with
     ``loss_chunk`` takes the fused chunked head + loss
@@ -219,9 +237,12 @@ class GraniteHybridForCausalLM(nn.Module):
         with annotate("ds_embed"):
             x = (_embed_lookup(embed, input_ids)
                  * cfg.embedding_multiplier).astype(cfg.dtype)
+        policy = stack_remat_policy(cfg, input_ids.size,
+                                    len(cfg.layer_types),
+                                    remat_row_bytes(cfg))
         for i, kind in enumerate(cfg.layer_types):
-            x = remat_block(cfg, self, f"layer_{i}", GraniteHybridBlock)(
-                cfg, kind, name=f"layer_{i}")(x)
+            x = remat_block(cfg, self, f"layer_{i}", GraniteHybridBlock,
+                            policy)(cfg, kind, name=f"layer_{i}")(x)
         x = RMSNorm(eps=cfg.rms_norm_eps, dtype=cfg.dtype,
                     param_dtype=cfg.param_dtype, name="norm")(x)
         x = (x / cfg.logits_scaling).astype(cfg.dtype)

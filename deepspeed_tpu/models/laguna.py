@@ -34,6 +34,7 @@ left over after the last whole period is a tail outside the scan
 RoPE tables are computed once a step and broadcast into the scan.
 """
 
+import collections
 import dataclasses
 import math
 from typing import Any, Optional
@@ -49,6 +50,7 @@ from deepspeed_tpu.models.gpt2 import (_embed_lookup, block_remat_policy,
 from deepspeed_tpu.models.llama import RMSNorm, apply_rope, rope_angles
 from deepspeed_tpu.moe.dropless import (HELD_STAT_GAUGES, STAT_GAUGES,
                                         DroplessMoE)
+from deepspeed_tpu.moe.dropless import remat_row_bytes as moe_row_bytes
 from deepspeed_tpu.ops.attention import dot_product_attention
 from deepspeed_tpu.telemetry.spans import annotate
 
@@ -241,14 +243,16 @@ class LagunaAttention(nn.Module):
         q = _dense(cfg, H * D, "q_proj")(x).reshape(B, S, H, D)
         k = _dense(cfg, Hkv * D, "k_proj")(x).reshape(B, S, Hkv, D)
         v = _dense(cfg, Hkv * D, "v_proj")(x).reshape(B, S, Hkv, D)
-        q, k, v = (checkpoint_name(t, "qkv").transpose(0, 2, 1, 3)
-                   for t in (q, k, v))                      # [B, H, S, D]
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B, H, S, D]
         if rope[self.layer_type] is not None:
             cos, sin = rope[self.layer_type]
             rot = 2 * cos.shape[-1]
             q, k = (apply_rope(t, cos, sin) if rot == D else jnp.concatenate(
                 [apply_rope(t[..., :rot], cos, sin), t[..., rot:]], axis=-1)
                 for t in (q, k))
+        # ``qkv`` names what the backward pass reads, the kernels' operands:
+        # kept, neither a projection nor the rotation is run again
+        q, k, v = (checkpoint_name(t, "qkv") for t in (q, k, v))
         out = dot_product_attention(
             q, k, v, causal=True, scale=self.scale, use_flash=cfg.use_flash,
             window=cfg.sliding_window if self.layer_type == SLIDING else None)
@@ -257,7 +261,7 @@ class LagunaAttention(nn.Module):
             # per HEAD, from the block's normed input (the config says
             # ``gating: true`` and no more: the configuration file's
             # ``assumed`` has the evidence)
-            gate = _dense(cfg, H, "g_proj")(x)
+            gate = checkpoint_name(_dense(cfg, H, "g_proj")(x), "qkv")
             with annotate("attn_gate"):
                 out = (out.astype(jnp.float32) * jax.nn.sigmoid(
                     gate.astype(jnp.float32))[..., None]).astype(cfg.dtype)
@@ -272,9 +276,10 @@ class LagunaDenseMLP(nn.Module):
     def __call__(self, x):
         cfg = self.config
         with annotate("dense_mlp"):
-            h = nn.silu(_dense(cfg, cfg.intermediate_size, "gate_proj")(x)) \
-                * _dense(cfg, cfg.intermediate_size, "up_proj")(x)
-            h = checkpoint_name(h, "mlp_fc")
+            # ``mlp_fc`` names what the activation's backward pass reads
+            pre = lambda name: checkpoint_name(  # noqa: E731
+                _dense(cfg, cfg.intermediate_size, name)(x), "mlp_fc")
+            h = nn.silu(pre("gate_proj")) * pre("up_proj")
             return checkpoint_name(
                 _dense(cfg, cfg.hidden_size, "down_proj")(h), "mlp_proj")
 
@@ -318,13 +323,53 @@ class LagunaBlock(nn.Module):
         return x + out
 
 
+def qkv_row_bytes(cfg, heads):
+    """Bytes a row one ``LagunaAttention`` layer of ``heads`` query heads
+    holds under the name ``qkv``: q, k, v as the kernel reads them and the
+    gate's projection."""
+    return jnp.dtype(cfg.dtype).itemsize * (
+        (heads + 2 * cfg.num_key_value_heads) * cfg.head_dim
+        + (heads if cfg.gating else 0))
+
+
+def remat_row_bytes(cfg):
+    """{checkpoint name: bytes a row, summed over the layers that carry
+    it}: what ``stack_remat_policy`` weighs against its budget."""
+    b = jnp.dtype(cfg.dtype).itemsize
+    total = collections.Counter()
+    for _, heads, mlp in cfg.layer_kinds:
+        total.update({"qkv": qkv_row_bytes(cfg, heads),
+                      "attn_proj": b * cfg.hidden_size})
+        total.update(
+            {"mlp_fc": 2 * b * cfg.intermediate_size} if mlp == DENSE
+            else moe_row_bytes(
+                cfg.num_experts, cfg.shared_expert_intermediate_size,
+                itemsize=b))
+    return total
+
+
+def stack_remat_policy(cfg, rows, layers, row_bytes, streams=1):
+    """The ONE policy object a stack's rematted blocks share (None without
+    remat): ``models/gpt2.block_remat_policy`` over the stack's figures —
+    ``rows`` in flight through ``layers`` blocks whose input is ``streams``
+    residual streams of ``cfg.hidden_size``, ``row_bytes`` a model's
+    ``remat_row_bytes``. One object, so a name is kept for all its layers
+    or none, and JAX makes one copy of a ``jax.jit`` function the blocks
+    call (it keys that on the policy OBJECT: PERF.md Findings PR 58)."""
+    if not cfg.remat:
+        return None
+    return block_remat_policy(
+        cfg.remat_policy, rows=rows, hidden=cfg.hidden_size, layers=layers,
+        itemsize=jnp.dtype(cfg.dtype).itemsize, row_bytes=row_bytes,
+        streams=streams)
+
+
 def remat_block(cfg, parent, name, block=None, policy=None):
     """``block`` (``LagunaBlock`` where not given; another model's block of
     the same calling convention: ``models/smallthinker.py``) under its own
-    ZeRO-3 gather edge (innermost) and, where the config asks, its own remat.
-    Whatever the policy keeps, it keeps the router's choice and the attention
-    kernel's outputs (``models/gpt2.block_remat_policy``: ``policy`` is ONE
-    such object shared by a caller's blocks, None each block's own);
+    ZeRO-3 gather edge (innermost) and, where the config asks, its own remat
+    under ``policy``, a stack's ``stack_remat_policy`` (None: the base names
+    of ``models/gpt2.block_remat_policy``, this block's own object);
     ``prevent_cse`` because several rematted blocks share one scan body and
     a scan of ONE period is no loop once XLA has simplified it
     (``models/qwen3_next._Period``)."""
@@ -338,13 +383,14 @@ def remat_block(cfg, parent, name, block=None, policy=None):
 class _Period(nn.Module):
     """The layer scan's body: one period of unlike blocks."""
     config: LagunaConfig
+    policy: Any = None               # the stack's ``stack_remat_policy``
 
     @nn.compact
     def __call__(self, x, rope):
         cfg = self.config
         lead, period, _, _ = cfg.plan
         for j, kind in enumerate(cfg.layer_kinds[lead:lead + period]):
-            x = remat_block(cfg, self, f"l{j}")(
+            x = remat_block(cfg, self, f"l{j}", policy=self.policy)(
                 cfg, *kind, name=f"l{j}")(x, rope)
         return x, None
 
@@ -375,8 +421,10 @@ class LagunaForCausalLM(nn.Module):
         with annotate("ds_embed"):
             x = _embed_lookup(embed, input_ids).astype(cfg.dtype)
         rope = rope_tables(cfg, jnp.arange(input_ids.shape[1]))
+        policy = stack_remat_policy(cfg, input_ids.size, len(kinds),
+                                    remat_row_bytes(cfg))
         for i in range(lead):
-            x = remat_block(cfg, self, f"lead_{i}")(
+            x = remat_block(cfg, self, f"lead_{i}", policy=policy)(
                 cfg, *kinds[i], name=f"lead_{i}")(x, rope)
         if n_periods:
             scanned = nn.scan(
@@ -385,9 +433,9 @@ class LagunaForCausalLM(nn.Module):
                                "intermediates": 0},
                 split_rngs={"params": True}, in_axes=(nn.broadcast,),
                 length=n_periods)
-            x, _ = scanned(cfg, name="layers")(x, rope)
+            x, _ = scanned(cfg, policy, name="layers")(x, rope)
         for j in range(tail):
-            x = remat_block(cfg, self, f"tail_{j}")(
+            x = remat_block(cfg, self, f"tail_{j}", policy=policy)(
                 cfg, *kinds[len(kinds) - tail + j], name=f"tail_{j}")(x, rope)
         x = RMSNorm(eps=cfg.rms_norm_eps, dtype=cfg.dtype,
                     param_dtype=cfg.param_dtype, name="norm")(x)

@@ -43,6 +43,7 @@ the pattern's segments between attention layers are of unequal length (6,
 carries them. A final ``norm_f`` and an untied head.
 """
 
+import collections
 import dataclasses
 import math
 from typing import Any, Optional
@@ -53,10 +54,13 @@ import flax.linen as nn
 from jax.ad_checkpoint import checkpoint_name
 
 from deepspeed_tpu.models.gpt2 import _embed_lookup, chunked_lm_loss, lm_loss
-from deepspeed_tpu.models.laguna import FULL, LagunaAttention, remat_block
+from deepspeed_tpu.models.laguna import (FULL, LagunaAttention,
+                                         qkv_row_bytes, remat_block,
+                                         stack_remat_policy)
 from deepspeed_tpu.models.llama import RMSNorm
 from deepspeed_tpu.moe.dropless import (CHOICE_BIAS, HELD_STAT_GAUGES,
                                         STAT_GAUGES, DroplessMoE)
+from deepspeed_tpu.moe.dropless import remat_row_bytes as moe_row_bytes
 from deepspeed_tpu.ops.mixer_elementwise import conv_act, gated_group_norm
 from deepspeed_tpu.ops.ssd import ssd_scan
 from deepspeed_tpu.telemetry.spans import annotate
@@ -220,7 +224,10 @@ class Mamba2Mixer(nn.Module):
         H, P = cfg.mamba_num_heads, cfg.mamba_head_dim
         G, N = cfg.n_groups, cfg.ssm_state_size
         d_inner, f32 = cfg.d_inner, jnp.float32
-        zxbcdt = _dense(cfg, d_inner + cfg.conv_dim + H, "in_proj")(x)
+        # ``mixer_in``: kept by a rematted block that has the bytes
+        # (``runtime/remat_budget.py``), the projection is not run again
+        zxbcdt = checkpoint_name(
+            _dense(cfg, d_inner + cfg.conv_dim + H, "in_proj")(x), "mixer_in")
         dt = zxbcdt[..., d_inner + cfg.conv_dim:]
         taps = self.param("conv", _conv_init(cfg),
                           (cfg.conv_kernel, cfg.conv_dim), cfg.param_dtype)
@@ -254,6 +261,32 @@ class Mamba2Mixer(nn.Module):
                 eps=cfg.layer_norm_epsilon, gate_first=True)
         return checkpoint_name(_dense(cfg, cfg.hidden_size, "out_proj")(y),
                                "attn_proj")
+
+
+def mixer_in_row_bytes(cfg):
+    """Bytes a row one ``Mamba2Mixer`` layer holds under the name
+    ``mixer_in``: ``in_proj``'s output."""
+    return jnp.dtype(cfg.dtype).itemsize * (
+        cfg.d_inner + cfg.conv_dim + cfg.mamba_num_heads)
+
+
+def remat_row_bytes(cfg):
+    """{checkpoint name: bytes a row, summed over the layers that carry
+    it}: what ``models/laguna.stack_remat_policy`` weighs against its
+    budget."""
+    b = jnp.dtype(cfg.dtype).itemsize
+    # a layer is ONE branch: nothing in its backward pass reads the
+    # branch's output (``attn_proj``), kept or not
+    each = {MAMBA: {"mixer_in": mixer_in_row_bytes(cfg)},
+            ATTENTION: {"qkv": qkv_row_bytes(cfg, cfg.num_attention_heads)},
+            EXPERTS: moe_row_bytes(
+                cfg.n_routed_experts, cfg.n_shared_experts
+                * cfg.moe_shared_expert_intermediate_size, gated=False,
+                itemsize=b)}
+    total = collections.Counter()
+    for kind in cfg.plan:
+        total.update(each[kind])
+    return total
 
 
 class NemotronHBlock(nn.Module):
@@ -318,8 +351,10 @@ class NemotronHForCausalLM(nn.Module):
                            cfg.param_dtype)
         with annotate("ds_embed"):
             x = _embed_lookup(embed, input_ids).astype(cfg.dtype)
+        policy = stack_remat_policy(cfg, input_ids.size, len(cfg.plan),
+                                    remat_row_bytes(cfg))
         for i, kind in enumerate(cfg.plan):
-            x = remat_block(cfg, self, f"layer_{i}", NemotronHBlock)(
+            x = remat_block(cfg, self, f"layer_{i}", NemotronHBlock, policy)(
                 cfg, kind, name=f"layer_{i}")(x)
         x = RMSNorm(eps=cfg.layer_norm_epsilon, dtype=cfg.dtype,
                     param_dtype=cfg.param_dtype, name="norm_f")(x)

@@ -44,6 +44,7 @@ the parameters of period p's j-th layer are slice p of the leaves under
 The multi-token-prediction module of the published model is not here.
 """
 
+import collections
 import dataclasses
 from typing import Any, Optional
 
@@ -55,9 +56,11 @@ from jax.ad_checkpoint import checkpoint_name
 from deepspeed_tpu.models.gpt2 import (_embed_lookup, block_remat_policy,
                                        chunked_lm_loss, gather_edge_block,
                                        lm_loss)
+from deepspeed_tpu.models.laguna import stack_remat_policy
 from deepspeed_tpu.models.llama import apply_rope, rope_angles
 from deepspeed_tpu.moe.dropless import (HELD_STAT_GAUGES, STAT_GAUGES,
                                         DroplessMoE)
+from deepspeed_tpu.moe.dropless import remat_row_bytes as moe_row_bytes
 from deepspeed_tpu.ops.attention import dot_product_attention
 from deepspeed_tpu.ops.gated_delta import gated_delta_rule
 from deepspeed_tpu.ops.mixer_elementwise import conv_act, gated_group_norm
@@ -178,8 +181,11 @@ class GatedDeltaNet(nn.Module):
         Hk, Dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
         Hv, Dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
         key, val = Hk * Dk, Hv * Dv
-        qkvz = _dense(cfg, 2 * key + 2 * val, "in_proj_qkvz")(x)
-        ba = _dense(cfg, 2 * Hv, "in_proj_ba")(x)
+        # ``mixer_in``: kept by a rematted block that has the bytes
+        # (``runtime/remat_budget.py``), the projections are not run again
+        qkvz = checkpoint_name(
+            _dense(cfg, 2 * key + 2 * val, "in_proj_qkvz")(x), "mixer_in")
+        ba = checkpoint_name(_dense(cfg, 2 * Hv, "in_proj_ba")(x), "mixer_in")
         taps = self.param("conv", nn.initializers.normal(0.02),
                           (cfg.linear_conv_kernel_dim, 2 * key + val),
                           cfg.param_dtype)
@@ -219,9 +225,14 @@ class GatedAttention(nn.Module):
         B, S, _ = x.shape
         H, Hkv, D = (cfg.num_attention_heads, cfg.num_key_value_heads,
                      cfg.head_dim)
-        qg = _dense(cfg, 2 * H * D, "q_proj")(x).reshape(B, S, H, 2, D)
+        # ``qkv`` names what the backward pass reads: the projections as the
+        # head norms read them (q and the gate one array), and below q and k
+        # normed and rotated beside v, the kernel's operands
+        qg = checkpoint_name(_dense(cfg, 2 * H * D, "q_proj")(x),
+                             "qkv").reshape(B, S, H, 2, D)
         q, gate = qg[..., 0, :], qg[..., 1, :]
-        k = _dense(cfg, Hkv * D, "k_proj")(x).reshape(B, S, Hkv, D)
+        k = checkpoint_name(_dense(cfg, Hkv * D, "k_proj")(x),
+                            "qkv").reshape(B, S, Hkv, D)
         v = _dense(cfg, Hkv * D, "v_proj")(x).reshape(B, S, Hkv, D)
         with annotate("qk_norm"):
             norm = lambda name: ZeroCentredRMSNorm(  # noqa: E731
@@ -229,12 +240,12 @@ class GatedAttention(nn.Module):
                 param_dtype=cfg.param_dtype, name=name)
             q = norm("q_norm")(q)
             k = norm("k_norm")(k)
-        q, k, v = (checkpoint_name(t, "qkv").transpose(0, 2, 1, 3)
-                   for t in (q, k, v))                      # [B, H, S, D]
+        q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))  # [B, H, S, D]
         rot = int(D * cfg.partial_rotary_factor)
         cos, sin = rope_angles(positions, rot, cfg.rope_theta)
         q, k = (jnp.concatenate([apply_rope(t[..., :rot], cos, sin),
                                  t[..., rot:]], axis=-1) for t in (q, k))
+        q, k, v = (checkpoint_name(t, "qkv") for t in (q, k, v))
         out = dot_product_attention(q, k, v, causal=True,
                                     use_flash=cfg.use_flash)
         out = out.transpose(0, 2, 1, 3)                     # [B, S, H, D]
@@ -281,10 +292,35 @@ class Qwen3NextBlock(nn.Module):
         return x + out
 
 
+def remat_row_bytes(cfg):
+    """{checkpoint name: bytes a row, summed over the layers that carry
+    it}: what ``models/laguna.stack_remat_policy`` weighs against its
+    budget."""
+    b = jnp.dtype(cfg.dtype).itemsize
+    key = cfg.linear_num_key_heads * cfg.linear_key_head_dim
+    val = cfg.linear_num_value_heads * cfg.linear_value_head_dim
+    q = cfg.num_attention_heads * cfg.head_dim
+    kv = cfg.num_key_value_heads * cfg.head_dim
+    each = {"linear": {"mixer_in": b * (2 * key + 2 * val
+                                        + 2 * cfg.linear_num_value_heads)},
+            # q with its gate and k as projected, q, k and v as the kernel
+            # reads them
+            "attention": {"qkv": b * (3 * q + 3 * kv)}}
+    total = collections.Counter()
+    for kind in cfg.layer_kinds:
+        total.update(each[kind])
+        total.update({"attn_proj": b * cfg.hidden_size})
+        total.update(moe_row_bytes(
+            cfg.num_experts, cfg.shared_expert_intermediate_size,
+            itemsize=b))
+    return total
+
+
 class _Period(nn.Module):
     """The layer scan's body: ``full_attention_interval`` unlike layers,
     each under its own gather edge (innermost) and remat."""
     config: Qwen3NextConfig
+    policy: Any = None               # the stack's ``stack_remat_policy``
 
     @nn.compact
     def __call__(self, x, positions):
@@ -293,15 +329,15 @@ class _Period(nn.Module):
                 cfg.layer_kinds[:cfg.full_attention_interval]):
             block = gather_edge_block(Qwen3NextBlock, self, f"l{j}")
             if cfg.remat:
-                # whatever the policy keeps, it keeps the router's choice
-                # and the attention kernel's outputs
+                # whatever the stack's policy keeps, it keeps the router's
+                # choice and the attention kernel's outputs
                 # (``models/gpt2.block_remat_policy``).
                 # prevent_cse: several rematted blocks share one scan body,
                 # and a scan of ONE period is no loop at all once XLA has
                 # simplified it: without the barrier the recomputation is
                 # merged back into the forward pass and everything is kept
-                block = nn.remat(block, prevent_cse=True,
-                                 policy=block_remat_policy(cfg.remat_policy))
+                block = nn.remat(block, prevent_cse=True, policy=self.policy
+                                 or block_remat_policy(cfg.remat_policy))
             x = block(cfg, kind, name=f"l{j}")(x, positions)
         return x, None
 
@@ -336,7 +372,10 @@ class Qwen3NextForCausalLM(nn.Module):
                            "intermediates": 0},
             split_rngs={"params": True}, in_axes=(nn.broadcast,),
             length=cfg.n_periods)
-        x, _ = scanned(cfg, name="layers")(x, positions)
+        policy = stack_remat_policy(cfg, input_ids.size,
+                                    cfg.num_hidden_layers,
+                                    remat_row_bytes(cfg))
+        x, _ = scanned(cfg, policy, name="layers")(x, positions)
         x = ZeroCentredRMSNorm(eps=cfg.rms_norm_eps, dtype=cfg.dtype,
                                param_dtype=cfg.param_dtype, name="norm")(x)
         head = self.param("lm_head", nn.initializers.normal(0.02),

@@ -38,10 +38,12 @@ import flax.linen as nn
 
 from deepspeed_tpu.models.gpt2 import _embed_lookup, chunked_lm_loss, lm_loss
 from deepspeed_tpu.models.laguna import (FULL, SLIDING, LagunaAttention,
-                                         remat_block, rope_tables)
+                                         qkv_row_bytes, remat_block,
+                                         rope_tables, stack_remat_policy)
 from deepspeed_tpu.models.llama import RMSNorm
 from deepspeed_tpu.moe.dropless import (HELD_STAT_GAUGES, STAT_GAUGES,
                                         DroplessMoE)
+from deepspeed_tpu.moe.dropless import remat_row_bytes as moe_row_bytes
 from deepspeed_tpu.telemetry.spans import annotate
 
 
@@ -174,16 +176,27 @@ class SmallThinkerBlock(nn.Module):
         return x + out
 
 
+def remat_row_bytes(cfg):
+    """{checkpoint name: bytes a row, summed over the layers}: what
+    ``models/laguna.stack_remat_policy`` weighs against its budget."""
+    b = jnp.dtype(cfg.dtype).itemsize
+    layer = {"qkv": qkv_row_bytes(cfg, cfg.num_attention_heads),
+             "attn_proj": b * cfg.hidden_size,
+             **moe_row_bytes(cfg.moe_num_primary_experts, itemsize=b)}
+    return {name: cfg.num_hidden_layers * v for name, v in layer.items()}
+
+
 class _Period(nn.Module):
     """The layer scan's body: one period of unlike blocks."""
     config: SmallThinkerConfig
+    policy: Any = None               # the stack's ``stack_remat_policy``
 
     @nn.compact
     def __call__(self, x, rope):
         cfg = self.config
         for j, kind in enumerate(cfg.layer_types[:cfg.plan[0]]):
-            x = remat_block(cfg, self, f"l{j}", SmallThinkerBlock)(
-                cfg, kind, name=f"l{j}")(x, rope)
+            x = remat_block(cfg, self, f"l{j}", SmallThinkerBlock,
+                            self.policy)(cfg, kind, name=f"l{j}")(x, rope)
         return x, None
 
 
@@ -217,9 +230,11 @@ class SmallThinkerForCausalLM(nn.Module):
                            "intermediates": 0},
             split_rngs={"params": True}, in_axes=(nn.broadcast,),
             length=n_periods)
-        x, _ = scanned(cfg, name="layers")(x, rope)
+        policy = stack_remat_policy(cfg, input_ids.size, len(kinds),
+                                    remat_row_bytes(cfg))
+        x, _ = scanned(cfg, policy, name="layers")(x, rope)
         for j in range(tail):
-            x = remat_block(cfg, self, f"tail_{j}", SmallThinkerBlock)(
+            x = remat_block(cfg, self, f"tail_{j}", SmallThinkerBlock, policy)(
                 cfg, kinds[len(kinds) - tail + j], name=f"tail_{j}")(x, rope)
         x = RMSNorm(eps=cfg.rms_norm_eps, dtype=cfg.dtype,
                     param_dtype=cfg.param_dtype, name="norm")(x)

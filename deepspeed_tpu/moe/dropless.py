@@ -304,9 +304,9 @@ class DroplessMoE(nn.Module):
             # precision (a TPU's default float32 matmul rounds its
             # operands to bf16): the 8th and 9th probabilities of a token
             # can tie to bf16 rounding
-            logits = jnp.dot(rt.astype(jnp.float32), wg.astype(jnp.float32),
-                             precision=jax.lax.Precision.HIGHEST)
-            # (the defaults keep OLMoE's call and program)
+            logits = _router_logits(rt, wg, named=self.pin_choice)
+            # (the defaults keep OLMoE's call and program; ``moe_scores``, the
+            # logits' name under ``pin_choice``: ``_router_logits``)
             top_w, top_e, probs = route(
                 logits, K, self.norm_topk_prob,
                 **({"pin_choice": True} if self.pin_choice else {}),
@@ -408,11 +408,11 @@ class DroplessMoE(nn.Module):
             w_sg = self.param("shared_expert_gate", init, (H, 1),
                               self.param_dtype) if self.shared_gate else None
             with annotate("moe_shared"):
-                if self.gated:
-                    hs = _ACTS[self.act](x @ s_gate.astype(dt)) \
-                        * (x @ s_up.astype(dt))
-                else:
-                    hs = _ACTS[self.act](x @ s_up.astype(dt))
+                # ``mlp_fc``: what the activation's backward pass reads
+                pre = lambda w: checkpoint_name(  # noqa: E731
+                    x @ w.astype(dt), "mlp_fc")
+                hs = _ACTS[self.act](pre(s_gate)) * pre(s_up) \
+                    if self.gated else _ACTS[self.act](pre(s_up))
                 hs = hs @ s_down.astype(dt)
                 if self.shared_gate:
                     open_ = jax.nn.sigmoid(
@@ -459,10 +459,10 @@ class DroplessMoE(nn.Module):
         up = grouped_matmul(xs, w_up, group_sizes)
         if self.gated:
             with annotate("moe_act"):
-                h = checkpoint_name(_ACTS[self.act](gate) * up, "mlp_fc")
+                h = checkpoint_name(_ACTS[self.act](gate) * up, "moe_act")
         else:
             with annotate("moe_act"):
-                h = checkpoint_name(_ACTS[self.act](up), "mlp_fc")
+                h = checkpoint_name(_ACTS[self.act](up), "moe_act")
         return grouped_matmul(h, w_down, group_sizes)
 
     @staticmethod
@@ -497,3 +497,28 @@ class DroplessMoE(nn.Module):
             # undefined must reach neither the sum nor w_row's cotangent
             ys = jnp.where(valid[:, None], ys.astype(jnp.float32), 0.0)
             return rows_to_tokens(ys * w_row[:, None], tok, T, K)
+
+
+def _router_logits(rt, wg, named):
+    """float32 logits [T, E] of the router's input ``rt`` [T, H] at full
+    precision; ``named``: under the checkpoint name ``moe_scores``, so that
+    a rematted block which keeps it (``runtime/remat_budget.py``) does not
+    run the matmul again — named HERE, where every reader (``route``, the z
+    term) takes them from, not on a copy."""
+    logits = jnp.dot(rt.astype(jnp.float32), wg.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    return checkpoint_name(logits, "moe_scores") if named else logits
+
+
+def remat_row_bytes(num_experts, shared_d_ff=0, gated=True, itemsize=2):
+    """{checkpoint name: bytes a token} one ``DroplessMoE`` layer holds under
+    the names a rematted block may keep (``runtime/remat_budget.py``): the
+    router's float32 logits, and the shared expert's pre-activations (two
+    where ``gated``). The routed experts' rows carry no name a block keeps:
+    their product ``act(gate) * up`` is named ``moe_act`` (``mlp_fc`` before
+    PR 61) and saves no matmul — the activation's backward pass reads
+    ``gate`` and ``up`` — and those two kept for a slab of 49,152 rows cost
+    SmallThinker's step more in its row gather than their grouped matmuls
+    took (PERF.md Findings PR 61)."""
+    return {"moe_scores": 4 * num_experts,
+            "mlp_fc": (2 if gated else 1) * itemsize * shared_d_ff}

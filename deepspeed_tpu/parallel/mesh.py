@@ -93,22 +93,34 @@ class layout_pins:
     ``gather_edge`` is the engine's ZeRO-3 gather edge
     (runtime/zero/partition.GatherEdge) or None: it rides the same scope
     so the models' blocks pin their parameters with the same lifetime
-    and the same off-switch as every other pin."""
+    and the same off-switch as every other pin. ``remat_free_bytes``
+    rides it too: what the engine says a chip has left for the names a
+    rematted block keeps (``runtime/remat_budget.py``; 0: the base set)."""
 
-    def __init__(self, mesh, gather_edge=None):
+    def __init__(self, mesh, gather_edge=None, remat_free_bytes=0):
         self.mesh = mesh
         self.gather_edge = gather_edge
+        self.remat_free_bytes = remat_free_bytes
         self._prev = None
 
     def __enter__(self):
-        self._prev = (_get_pin_mesh(), getattr(_pin_state, "edge", None))
+        self._prev = (_get_pin_mesh(), getattr(_pin_state, "edge", None),
+                      pinned_remat_free_bytes())
         _pin_state.mesh = self.mesh
         _pin_state.edge = self.gather_edge
+        _pin_state.remat_free_bytes = self.remat_free_bytes
         return self
 
     def __exit__(self, *exc):
-        _pin_state.mesh, _pin_state.edge = self._prev
+        (_pin_state.mesh, _pin_state.edge,
+         _pin_state.remat_free_bytes) = self._prev
         return False
+
+
+def pinned_remat_free_bytes():
+    """The pinned trace's free bytes for kept names; 0 outside an engine's
+    trace."""
+    return getattr(_pin_state, "remat_free_bytes", 0)
 
 
 def pinned_mesh():

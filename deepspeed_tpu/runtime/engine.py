@@ -1423,8 +1423,8 @@ class DeepSpeedEngine:
         mesh = self.mesh
 
         def traced(fn, *args, **kwargs):
-            with mesh_lib.layout_pins(mesh, gather_edge=self._gather_edge):
-                out = fn(*args, **kwargs)
+            # (the pins' scope, and the fall-back of what rematted blocks keep)
+            out = self._run_pinned(mesh, fn, args, kwargs)
             self._note_gather_edge()
             return out
 
@@ -3452,9 +3452,61 @@ class DeepSpeedEngine:
                         lambda _: err_sh, opt_state[key])
         repl = NamedSharding(state_mesh, PartitionSpec())
         scaler_sh = jax.tree_util.tree_map(lambda _: repl, scaler)
-        return TrainState(params=param_sh, opt_state=opt_sh,
-                          scaler=scaler_sh, global_step=repl,
-                          skipped_steps=repl)
+        shardings = TrainState(params=param_sh, opt_state=opt_sh,
+                               scaler=scaler_sh, global_step=repl,
+                               skipped_steps=repl)
+        from deepspeed_tpu.runtime import remat_budget
+        self._remat_free_bytes = remat_budget.free_bytes(
+            self.mesh.devices.flat[0].device_kind,
+            self._held_bytes(state, shardings))
+        return shardings
+
+    # what a chip has left for the names a rematted block keeps beside its
+    # base names (``runtime/remat_budget.py``), handed to every trace
+    _remat_free_bytes = 0
+
+    def _held_bytes(self, state, shardings):
+        """Bytes ONE chip holds through a step beside its activations: its
+        shard of the state, and of the parameters once more in the
+        gradients' dtype and (where the step casts the tree) the compute
+        dtype's. Exact on abstract state too: shapes and shardings alone."""
+        def shard_bytes(x, sh, itemsize=None):
+            return int(np.prod(sh.shard_shape(jnp.shape(x)))) * (
+                itemsize or jnp.result_type(x).itemsize)
+        bf16 = self._config.grad_dtype == "bf16"
+        return sum(jax.tree_util.tree_leaves(jax.tree_util.tree_map(
+            shard_bytes, state, shardings))) + sum(jax.tree_util.tree_leaves(
+                jax.tree_util.tree_map(
+                    lambda x, sh: shard_bytes(x, sh, 4 if bf16 else 8)
+                    if jnp.issubdtype(jnp.result_type(x), jnp.floating)
+                    else 0, state.params, shardings.params)))
+
+    def _run_pinned(self, mesh, fn, args, kwargs):
+        """``fn(*args, **kwargs)`` under the models' trace-time scope
+        (``_pinned``). A step the compiler refuses for memory with names kept
+        is built ONCE more with the base set: the estimate behind
+        ``_remat_free_bytes`` was wrong, and a shape that trained before the
+        rule must train with it."""
+        def run():
+            with mesh_lib.layout_pins(
+                    mesh, gather_edge=self._gather_edge,
+                    remat_free_bytes=self._remat_free_bytes):
+                return fn(*args, **kwargs)
+        try:
+            return run()
+        except Exception as e:  # boundary: the compiler's refusal
+            words = str(e)
+            if not self._remat_free_bytes or not (
+                    "RESOURCE_EXHAUSTED" in words
+                    or "Ran out of memory" in words):
+                raise
+            self._remat_free_bytes = 0
+            self.telemetry.counter("remat/fell_back_to_base").inc()
+            log_dist("the step did not fit with what its rematted blocks "
+                     "kept: built again with their base names "
+                     f"({words.splitlines()[0][:200]})", ranks=[0])
+            jax.clear_caches()
+            return run()
 
     def _adopt_loaded_state_offload(self, template: TrainState):
         self._host_runner = self._make_offload_runner(template.params)

@@ -112,6 +112,17 @@ def rematted_forward_attention(text):
     return sorted(sites)
 
 
+def rematted_matmuls(text):
+    """The matmuls a step runs a SECOND time, inside a rematted block's
+    recomputation: the distinct scope paths, from ``rematted_computation``
+    down, of every ``dot_general`` in a lowered text with ``debug_info``
+    (``l0/attn/q_proj/dot_general``). A block that keeps what its backward
+    pass reads of a projection has none of that projection's."""
+    return sorted({path.split("rematted_computation/")[-1] for path in
+                   re.findall(r'loc\("([^"]*rematted_computation/[^"]*'
+                              r'dot_general[^"]*)"', text)})
+
+
 def remat_report(loss, params, capsys):
     """Of ``loss(params)``'s gradient program under the model's own remat:
     (``rematted_forward_attention`` of its lowered text, the words of
@@ -124,6 +135,17 @@ def remat_report(loss, params, capsys):
     jax.ad_checkpoint.print_saved_residuals(loss, params)
     return (rematted_forward_attention(lowered.as_text(debug_info=True)),
             capsys.readouterr().out, lowered)
+
+
+def kept_under_budget(loss, params, capsys, free_bytes):
+    """``remat_report`` with ``free_bytes`` handed to the trace as an engine
+    hands them (``parallel/mesh.layout_pins``), and the step's
+    ``rematted_matmuls`` in place of its attention sites."""
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+    with mesh_lib.layout_pins(None, remat_free_bytes=free_bytes):
+        _, handed, lowered = remat_report(loss, params, capsys)
+    return (rematted_matmuls(lowered.as_text(debug_info=True)), handed,
+            lowered)
 
 
 def run_with_jaxpr(fn, *args):

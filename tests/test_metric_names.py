@@ -205,6 +205,18 @@ def test_zero_gather_edge_metric_names_documented():
         assert name in _package_source(), name
 
 
+@pytest.mark.parametrize("name", ["remat/kept_names", "remat/kept_mb",
+                                  "remat/budget_mb",
+                                  "remat/fell_back_to_base"])
+def test_remat_budget_metric_names_documented(name):
+    """What a rematted block keeps beside its base names (ISSUE 61): the
+    three trace-time gauges and the fall-back's counter stay documented AND
+    emitted."""
+    assert name in documented_metric_names(), (
+        f"{name} missing from the docs/observability.md train table")
+    assert name in _package_source(), name
+
+
 @pytest.mark.parametrize("name", ["attention/flash_tile_overcompute",
                                   "attention/flash_heads_per_block",
                                   "attention/window_tile_overcompute",

@@ -23,7 +23,14 @@ def test_rematted_blocks_keep_what_their_attention_kernel_produced(
     period's attention layer keeps ``flash_o`` / ``flash_lse``, its forward
     kernel is not under ``rematted_computation`` in the compiled step, and
     the gradients are the unrematted ones; with the base set cut back to
-    the router's choice it is."""
+    the router's choice it is. The "kept" case runs with free bytes handed
+    to the trace (``runtime/remat_budget.py``; the base set alone:
+    ``tests/test_laguna_remat.py``): the period — three Gated DeltaNet
+    layers, one gated attention layer — then keeps all five candidates its
+    layers carry, and no projection matmul (DeltaNet's two, the attention
+    layer's with its gate) sits under ``rematted_computation``; the delta
+    rule's own preparation and the shared expert's gated output alone are
+    formed again."""
     from deepspeed_tpu.models import gpt2
     from deepspeed_tpu.models.qwen3_next import (Qwen3NextForCausalLM,
                                                  qwen3_next_tiny)
@@ -45,8 +52,21 @@ def test_rematted_blocks_keep_what_their_attention_kernel_produced(
     params = jax.jit(Qwen3NextForCausalLM(qwen3_next_tiny(
         num_hidden_layers=4, experts_held=4)).init)(
         jax.random.PRNGKey(0), ids)["params"]
-    sites, handed, step = hlo_text.remat_report(loss(True), params, capsys)
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+    from deepspeed_tpu.telemetry.registry import default_registry
+    with mesh_lib.layout_pins(None, remat_free_bytes=0 if base else 10 ** 8):
+        sites, handed, step = hlo_text.remat_report(loss(True), params,
+                                                    capsys)
     assert len(sites) == again, sites
+    matmuls = hlo_text.rematted_matmuls(step.as_text(debug_info=True))
+    if base is None:
+        assert [m for m in matmuls if "moe_shared" not in m
+                and "gdn_scan" not in m] == [], matmuls
+        # moe_scores, attn_proj, qkv, mlp_fc, mixer_in
+        assert default_registry().peek_gauge("remat/kept_names") == 5
+    else:
+        for part in ("in_proj_qkvz", "in_proj_ba", "q_proj", "out_proj"):
+            assert any(f"/{part}/" in m for m in matmuls), (part, matmuls)
     # the layer scan hands its blocks' residuals on stacked, their names
     # gone: lse is [periods, B * H, S / 64, 1, 64] (blocks of 64 on the CPU)
     assert ("f32[1,4,1,1,64] output of scan" in handed) == (base is None)
@@ -55,3 +75,4 @@ def test_rematted_blocks_keep_what_their_attention_kernel_produced(
         for a, b in zip(jax.tree_util.tree_leaves(step.compile()(params)),
                         jax.tree_util.tree_leaves(want)):
             np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-4)
+
