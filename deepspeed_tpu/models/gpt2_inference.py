@@ -283,6 +283,160 @@ def shard_inference_params(iparams, mesh):
 
 
 
+def _is_converted(params):
+    """True for a `convert_gpt2_params` tree (fused blocks under h/blk),
+    False for the training `GPT2LMHeadModel` tree."""
+    return "attn_qkvw" in params.get("h", {}).get("blk", {})
+
+
+def _ln_x(x, w, b, eps):
+    from deepspeed_tpu.ops.pallas.decode import _ln
+    return _ln(x, w, b, eps).astype(x.dtype)
+
+
+# ------------------------------------------------ paged-serving layer math
+#
+# GPT-2 as serving/adapters.PagedServingAdapter sees it (docs/serving.md
+# "Adding a family"): geometry, the params a program takes, and the
+# embedding, qkv half, out+FFN half and head — once for decode ROWS
+# ([N, E], one row a token, the stacked fused kernels) and once for a
+# whole PROMPT ([1, S, E], de-quantised XLA matmuls). None of them sees
+# the KV pool, the page table or the sampler.
+
+def serving_geometry(cfg: GPT2Config):
+    assert cfg.n_embd % cfg.n_head == 0
+    return dict(n_layers=cfg.n_layer, kv_heads=cfg.n_head,
+                head_dim=cfg.n_embd // cfg.n_head, dtype=cfg.dtype,
+                max_prompt_len=cfg.n_positions, vocab_size=cfg.vocab_size)
+
+
+def serving_params(cfg: GPT2Config, params, quantize_bits: int = 0):
+    """(p, blk) a program takes, from the training tree or the converted
+    (optionally int8) inference tree; ``quantize_bits=8`` quantises a
+    full-precision tree to the int8 serving storage here, at build."""
+    assert cfg.tie_word_embeddings, \
+        "paged GPT-2 serving assumes the tied-embedding LM head"
+    iparams = params if _is_converted(params) \
+        else convert_gpt2_params(params, cfg)
+    if quantize_bits == 8 \
+            and "kernel_q" not in iparams["h"]["blk"]["attn_qkvw"]:
+        iparams = quantize_gpt2_inference_params(iparams)
+    return ({"wte": iparams["wte"], "wpe": iparams["wpe"],
+             "ln_f": iparams["ln_f"]}, iparams["h"]["blk"])
+
+
+def serving_row_weights(cfg: GPT2Config, p, blk):
+    """What a decode program hoists out of its scans. Every per-layer
+    parameter stays STACKED — the kernels fetch their own layer's
+    LN/bias tiles via layer-indexed block maps and read the per-tensor
+    scales from SMEM prefetch vectors (13 per-layer xs cost ~15-20 us
+    of slice/copy EACH per layer — r5 b32 device trace) — reshaped
+    [Lyr, 1, cols] ONCE here, not per layer call (layout copy). bf16
+    stacks run the same kernels with scale 1."""
+    Lyr = cfg.n_layer
+    q8 = "kernel_q" in blk["attn_qkvw"]
+
+    def r3(a):
+        return a.reshape(Lyr, 1, a.shape[-1])
+
+    def proj(sub):
+        scale = sub["kernel_scale"].reshape(Lyr) if q8 \
+            else jnp.ones((Lyr,), jnp.float32)
+        return sub["kernel_q" if q8 else "kernel"], scale, r3(sub["bias"])
+
+    def norm(sub):
+        return r3(sub["scale"]), r3(sub["bias"])
+
+    return {"wte": jnp.asarray(p["wte"]).astype(cfg.dtype),
+            "wpe": jnp.asarray(p["wpe"]).astype(cfg.dtype),
+            "ln_f": p["ln_f"],
+            "ln1": norm(blk["attn_nw"]), "ln2": norm(blk["norm_w"]),
+            "qkv": proj(blk["attn_qkvw"]), "o": proj(blk["attn_ow"]),
+            "fc": proj(blk["inter_w"]), "out": proj(blk["output_w"])}
+
+
+def serving_row_embed(cfg: GPT2Config, w, toks, pos):
+    return w["wte"][toks] + w["wpe"][jnp.clip(pos, 0, cfg.n_positions - 1)]
+
+
+def serving_row_qkv(cfg: GPT2Config, w, x, l, pos):
+    """x [N, E] -> q, k, v [N, H, D] at layer ``l`` (positions live in
+    the embedding, so ``pos`` is unused)."""
+    from deepspeed_tpu.ops.pallas.decode import ln_qkv_int8_stacked
+    E, H = cfg.n_embd, cfg.n_head
+    qkv = ln_qkv_int8_stacked(x, *w["ln1"], *w["qkv"], l,
+                              eps=cfg.layer_norm_epsilon)
+    return tuple(qkv[:, i * E:(i + 1) * E].reshape(-1, H, E // H)
+                 for i in range(3))
+
+
+def serving_row_out_ffn(cfg: GPT2Config, w, ctx, x, l):
+    from deepspeed_tpu.ops.pallas.decode import out_ffn_int8_stacked
+    return out_ffn_int8_stacked(ctx, x, *w["o"], *w["ln2"], *w["fc"],
+                                *w["out"], l, act="gelu_tanh",
+                                eps=cfg.layer_norm_epsilon)
+
+
+def serving_row_head(cfg: GPT2Config, w, x):
+    return jnp.einsum(
+        "be,ve->bv", _ln_x(x, w["ln_f"]["scale"], w["ln_f"]["bias"],
+                           cfg.layer_norm_epsilon), w["wte"])
+
+
+def serving_prompt_weights(cfg: GPT2Config, p, blk, positions):
+    """What a prompt pass hoists out of its layer scan; ``positions``
+    [S] are the rows' absolute positions."""
+    return {"wte": jnp.asarray(p["wte"]).astype(cfg.dtype),
+            "wpe": jnp.asarray(p["wpe"]).astype(cfg.dtype),
+            "ln_f": p["ln_f"], "blk": blk, "positions": positions}
+
+
+def _prompt_dense(cfg, sub, l, u):
+    """u @ layer ``l`` of a stacked projection, de-quantised on the fly
+    (the bias is the caller's: where it is added fixes the rounding)."""
+    if "kernel_q" in sub:
+        scale = sub["kernel_scale"].reshape(cfg.n_layer)[l]
+        return u @ (sub["kernel_q"][l].astype(jnp.float32)
+                    * scale).astype(cfg.dtype)
+    return u @ sub["kernel"][l].astype(cfg.dtype)
+
+
+def serving_prompt_embed(cfg: GPT2Config, w, ids):
+    pos = jnp.clip(w["positions"], 0, cfg.n_positions - 1)
+    return w["wte"][ids] + w["wpe"][pos][None]             # [1, S, E]
+
+
+def serving_prompt_qkv(cfg: GPT2Config, w, x, l):
+    """x [B, S, E] -> q, k, v [B, H, S, D] at layer ``l``."""
+    blk, E, H = w["blk"], cfg.n_embd, cfg.n_head
+    B, S = x.shape[:2]
+    u = _ln_x(x, blk["attn_nw"]["scale"][l], blk["attn_nw"]["bias"][l],
+              cfg.layer_norm_epsilon)
+    qkv = _prompt_dense(cfg, blk["attn_qkvw"], l, u) \
+        + blk["attn_qkvw"]["bias"][l].astype(cfg.dtype)
+    return tuple(qkv[..., i * E:(i + 1) * E].reshape(B, S, H, E // H)
+                 .transpose(0, 2, 1, 3) for i in range(3))
+
+
+def serving_prompt_out_ffn(cfg: GPT2Config, w, ctx, x, l):
+    blk = w["blk"]
+    x = x + _prompt_dense(cfg, blk["attn_ow"], l, ctx) \
+        + blk["attn_ow"]["bias"][l].astype(cfg.dtype)
+    u = _ln_x(x, blk["norm_w"]["scale"][l], blk["norm_w"]["bias"][l],
+              cfg.layer_norm_epsilon)
+    h = jax.nn.gelu(_prompt_dense(cfg, blk["inter_w"], l, u)
+                    + blk["inter_w"]["bias"][l].astype(cfg.dtype),
+                    approximate=True)
+    return x + _prompt_dense(cfg, blk["output_w"], l, h) \
+        + blk["output_w"]["bias"][l].astype(cfg.dtype)
+
+
+def serving_prompt_head(cfg: GPT2Config, w, xl):
+    """Logits of ONE row xl [E] (the prompt's last position)."""
+    return _ln_x(xl, w["ln_f"]["scale"], w["ln_f"]["bias"],
+                 cfg.layer_norm_epsilon) @ w["wte"].T
+
+
 def _supports_fast_decode(cfg: GPT2Config, B, quantize_bits,
                           quantize_groups, kv_cache_bits, mp_size):
     """Gate for the fused manual serving loop. Any combination of
@@ -316,57 +470,17 @@ def _fast_decode_scan_fn(cfg: GPT2Config, max_out: int,
     if key in _STEP_CACHE:
         return _STEP_CACHE[key]
     from deepspeed_tpu.ops.pallas.decode import (
-        ln_qkv_int8_stacked, kv_quant_int8, decode_attention_int8_stacked,
-        decode_attention_fp_stacked, out_ffn_int8_stacked)
+        kv_quant_int8, decode_attention_int8_stacked,
+        decode_attention_fp_stacked)
     E, H = cfg.n_embd, cfg.n_head
     D = E // H
     Lyr = cfg.n_layer
-    eps = cfg.layer_norm_epsilon
-
-    def _ln_f(x, w, b):
-        xf = x.astype(jnp.float32)
-        mu = jnp.mean(xf, axis=-1, keepdims=True)
-        var = jnp.mean((xf - mu) ** 2, axis=-1, keepdims=True)
-        y = (xf - mu) * jax.lax.rsqrt(var + eps)
-        return (y * w.astype(jnp.float32)
-                + b.astype(jnp.float32)).astype(x.dtype)
-
-    wkey = "kernel_q" if weights_q8 else "kernel"
-
-    def _wscale(proj):
-        if weights_q8:
-            return proj["kernel_scale"].reshape(Lyr)
-        return jnp.ones((Lyr,), jnp.float32)
 
     @functools.partial(jax.jit, static_argnums=(4,),
                        donate_argnums=(2,))
     def fast_scan(p, blk, caches, first_tok, steps, start, rngs,
                   temperature):
-        wte = jnp.asarray(p["wte"]).astype(cfg.dtype)
-        wpe = jnp.asarray(p["wpe"]).astype(cfg.dtype)
-        lnf_w, lnf_b = p["ln_f"]["scale"], p["ln_f"]["bias"]
-        Wq = blk["attn_qkvw"][wkey]
-        Wp = blk["attn_ow"][wkey]
-        W1 = blk["inter_w"][wkey]
-        W2 = blk["output_w"][wkey]
-        # every per-layer parameter stays STACKED — the kernels fetch
-        # their own layer's LN/bias tiles via layer-indexed block maps
-        # and read the per-tensor scales from SMEM prefetch vectors.
-        # (13 per-layer xs here cost ~15-20 us of slice/copy overhead
-        # EACH per layer on this target — r5 b32 device trace.)
-        # [Lyr, 1, cols] so the kernels' per-layer blocks are (1,1,cols)
-        # — reshaped ONCE here, not per layer call (layout copy)
-        r3 = lambda a: a.reshape(Lyr, 1, a.shape[-1])
-        ln1_w, ln1_b = r3(blk["attn_nw"]["scale"]), r3(blk["attn_nw"]["bias"])
-        ln2_w, ln2_b = r3(blk["norm_w"]["scale"]), r3(blk["norm_w"]["bias"])
-        bq = r3(blk["attn_qkvw"]["bias"])
-        bp = r3(blk["attn_ow"]["bias"])
-        b1 = r3(blk["inter_w"]["bias"])
-        b2 = r3(blk["output_w"]["bias"])
-        sq = _wscale(blk["attn_qkvw"])
-        sp_ = _wscale(blk["attn_ow"])
-        s1 = _wscale(blk["inter_w"])
-        s2 = _wscale(blk["output_w"])
+        w = serving_row_weights(cfg, p, blk)
         B = first_tok.shape[0]
         L_cache = caches[0].shape[3]
         if cache_q8:
@@ -380,7 +494,8 @@ def _fast_decode_scan_fn(cfg: GPT2Config, max_out: int,
 
         def tick(carry, r):
             caches, tok, offset = carry
-            x = wte[tok] + wpe[offset][None]         # [B, E]
+            x = serving_row_embed(cfg, w, tok,
+                                  jnp.broadcast_to(offset, tok.shape))
             # overflow: clamped row writes would silently serve stale
             # context — poison, same contract as the flax path
             x = jnp.where(offset >= L_cache,
@@ -388,13 +503,9 @@ def _fast_decode_scan_fn(cfg: GPT2Config, max_out: int,
 
             def layer(car, l):
                 x, caches = car
-                qkv = ln_qkv_int8_stacked(x, ln1_w, ln1_b, Wq, sq, bq, l,
-                                          eps=eps)
-                q = qkv[:, :E]
-                k3 = qkv[:, E:2 * E].reshape(B, H, D)
-                v3 = qkv[:, 2 * E:].reshape(B, H, D)
+                q3, k3, v3 = serving_row_qkv(cfg, w, x, l, None)
                 dus = jax.lax.dynamic_update_slice
-                qh = q.reshape(B, 1, H, D).transpose(0, 2, 1, 3)
+                qh = q3[:, :, None, :]
                 if cache_q8:
                     kc, ks, vc, vs = caches
                     kq8, ksc, vq8, vsc = kv_quant_int8(k3, v3)
@@ -420,15 +531,12 @@ def _fast_decode_scan_fn(cfg: GPT2Config, max_out: int,
                         qh, kc, vc, offset, l, scale=1.0 / np.sqrt(D))
                     caches = (kc, vc)
                 ctx2 = ctx.transpose(0, 2, 1, 3).reshape(B, E)
-                x = out_ffn_int8_stacked(
-                    ctx2, x, Wp, sp_, bp, ln2_w, ln2_b, W1, s1, b1, W2,
-                    s2, b2, l,
-                    act="gelu_tanh", eps=eps)
-                return (x, caches), None
+                return (serving_row_out_ffn(cfg, w, ctx2, x, l),
+                        caches), None
 
             (x, caches), _ = jax.lax.scan(
                 layer, (x, caches), jnp.arange(Lyr, dtype=jnp.int32))
-            logits = jnp.einsum("be,ve->bv", _ln_f(x, lnf_w, lnf_b), wte)
+            logits = serving_row_head(cfg, w, x)
             nxt = jax.lax.cond(
                 temperature > 0,
                 lambda: jax.random.categorical(
@@ -486,9 +594,8 @@ def generate(cfg: GPT2Config, params, input_ids, max_new_tokens=20,
     prompt_pass, decode_step, decode_scan = _compiled_steps(
         cfg, max_out, quantize_bits, quantize_groups, kv_cache_bits,
         mp_size)
-    converted = "h" in params and "blk" in params.get("h", {}) and \
-        any(k in params["h"]["blk"] for k in ("attn_qkvw",))
-    iparams = params if converted else convert_gpt2_params(params, cfg)
+    iparams = params if _is_converted(params) \
+        else convert_gpt2_params(params, cfg)
     if mp_size > 1:
         iparams = shard_inference_params(iparams, mesh)
 

@@ -127,11 +127,156 @@ def _rms_x(x, w, eps):
     return _rms(x, w, eps).astype(x.dtype)
 
 
-def _rope_one(x, pos, theta):
-    """RoPE on [B, Hx, D] rows at a single (traced) position."""
-    B, H, D = x.shape
-    cos, sin = rope_angles(pos.reshape(1), D, theta)   # [1, D//2]
-    return apply_rope(x[:, :, None, :], cos, sin).reshape(B, H, D)
+def _rope_rows(x, pos, theta):
+    """RoPE on [N, Hx, D] rows at PER-ROW positions ``pos`` [N]
+    (continuous batching decodes every slot at its own offset)."""
+    N, H, D = x.shape
+    inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
+    ang = pos.astype(jnp.float32)[:, None] * inv[None]    # [N, D//2]
+    cos = jnp.cos(ang)[:, None].astype(x.dtype)           # [N, 1, D//2]
+    sin = jnp.sin(ang)[:, None].astype(x.dtype)
+    half = D // 2
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin,
+                            x2 * cos + x1 * sin], axis=-1)
+
+
+# ------------------------------------------------ paged-serving layer math
+#
+# LLaMA as serving/adapters.PagedServingAdapter sees it (docs/serving.md
+# "Adding a family"), over the PACKED serving tree: geometry, the params
+# a program takes, and the embedding, qkv half, out+FFN half and head —
+# once for decode ROWS ([N, E], the stacked fused kernels) and once for
+# a whole PROMPT ([B, S, E], de-quantised XLA matmuls). GQA: k and v
+# come back at Hkv heads, q at H. None of them sees the KV pool, the
+# page table or the sampler.
+
+def serving_geometry(cfg: LlamaConfig):
+    return dict(n_layers=cfg.n_layers, kv_heads=cfg.kv_heads,
+                head_dim=cfg.head_dim, dtype=cfg.dtype,
+                max_prompt_len=cfg.max_seq_len, vocab_size=cfg.vocab_size)
+
+
+def serving_params(cfg: LlamaConfig, sparams, quantize_bits: int = 0):
+    """(p, blk) a program takes from the packed tree; ``quantize_bits=8``
+    quantises a full-precision tree to the int8 storage here, at build."""
+    if quantize_bits == 8 and "kernel_q" not in sparams["blk"]["qkv_w"]:
+        sparams = quantize_llama_serving_params(sparams)
+    return ({k: v for k, v in sparams.items() if k != "blk"},
+            sparams["blk"])
+
+
+_PROJECTIONS = ("qkv_w", "o_w", "gate_w", "up_w", "down_w")
+
+
+def serving_row_weights(cfg: LlamaConfig, p, blk):
+    """What a decode program hoists out of its scans: the embeddings in
+    compute dtype, each projection's (stack, scale) and the norms as
+    [Lyr, 1, E] for the kernels' per-layer blocks."""
+    Lyr, E = cfg.n_layers, cfg.hidden_size
+    w = {name: _weights(blk, name, Lyr) for name in _PROJECTIONS}
+    w.update(embed=p["embed"].astype(cfg.dtype),
+             head=p["head"].astype(cfg.dtype),
+             norm_scale=p["norm_scale"],
+             norm1=blk["norm1"].reshape(Lyr, 1, E),
+             norm2=blk["norm2"].reshape(Lyr, 1, E))
+    return w
+
+
+def serving_row_embed(cfg: LlamaConfig, w, toks, pos):
+    return w["embed"][toks]
+
+
+def serving_row_qkv(cfg: LlamaConfig, w, x, l, pos):
+    """x [N, E] -> q [N, H, D], k, v [N, Hkv, D] at layer ``l``, q and
+    k rotated to the rows' positions ``pos`` [N]."""
+    from deepspeed_tpu.ops.pallas.decode import ln_qkv_int8_stacked
+    H, Hkv, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    qkv = ln_qkv_int8_stacked(x, w["norm1"], None, *w["qkv_w"], None, l,
+                              eps=cfg.rms_eps, norm="rms")
+    q = qkv[:, :H * D].reshape(-1, H, D)
+    k = qkv[:, H * D:(H + Hkv) * D].reshape(-1, Hkv, D)
+    v = qkv[:, (H + Hkv) * D:].reshape(-1, Hkv, D)
+    return (_rope_rows(q, pos, cfg.rope_theta),
+            _rope_rows(k, pos, cfg.rope_theta), v)
+
+
+def serving_row_out_ffn(cfg: LlamaConfig, w, ctx, x, l):
+    from deepspeed_tpu.ops.pallas.decode import (out_ffn_int8_stacked,
+                                                 matvec_int8_stacked)
+    E = cfg.hidden_size
+    (Wo, so), (Wg, sg), (Wu, su), (Wd, sd) = (
+        w[n] for n in ("o_w", "gate_w", "up_w", "down_w"))
+    # whole-[E,E] o_proj blocks blow scoped VMEM past E~2048; split it
+    # onto the tiled stacked matvec there
+    if E * E * Wo.dtype.itemsize <= (6 << 20):
+        return out_ffn_int8_stacked(
+            ctx, x, Wo, so, None, w["norm2"], None, Wg, sg, None, Wd, sd,
+            None, l, act="swiglu", eps=cfg.rms_eps, norm="rms",
+            w1b_stack=Wu, s1b=su)
+    x1 = x + matvec_int8_stacked(ctx, Wo, so, l)
+    return out_ffn_int8_stacked(
+        None, x1, None, None, None, w["norm2"], None, Wg, sg, None, Wd,
+        sd, None, l, act="swiglu", eps=cfg.rms_eps, norm="rms",
+        w1b_stack=Wu, s1b=su, fuse_proj=False)
+
+
+def serving_row_head(cfg: LlamaConfig, w, x):
+    return jnp.einsum("be,ve->bv",
+                      _rms_x(x, w["norm_scale"], cfg.rms_eps), w["head"])
+
+
+def serving_prompt_weights(cfg: LlamaConfig, p, blk, positions):
+    """What a prompt pass hoists out of its layer scan; ``positions``
+    [S] are the rows' absolute positions (the RoPE tables)."""
+    w = {name: _weights(blk, name, cfg.n_layers) for name in _PROJECTIONS}
+    w.update(embed=p["embed"], head=p["head"], norm_scale=p["norm_scale"],
+             norm1=blk["norm1"], norm2=blk["norm2"],
+             rope=rope_angles(positions, cfg.head_dim, cfg.rope_theta))
+    return w
+
+
+def _prompt_dense(cfg, stack_scale, l, u):
+    """u @ layer ``l`` of a (stack, scale) pair, de-quantised on the
+    fly."""
+    stack, scale = stack_scale
+    if stack.dtype == jnp.int8:
+        return u @ (stack[l].astype(jnp.float32)
+                    * scale[l]).astype(cfg.dtype)
+    return u @ stack[l].astype(cfg.dtype)
+
+
+def serving_prompt_embed(cfg: LlamaConfig, w, ids):
+    return w["embed"][ids].astype(cfg.dtype)               # [B, S, E]
+
+
+def serving_prompt_qkv(cfg: LlamaConfig, w, x, l):
+    """x [B, S, E] -> q [B, H, S, D], k, v [B, Hkv, S, D] at layer
+    ``l``, q and k rotated."""
+    H, Hkv, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    B, S = x.shape[:2]
+    u = _rms_x(x, w["norm1"][l], cfg.rms_eps)
+    qkv = _prompt_dense(cfg, w["qkv_w"], l, u)
+    q = qkv[..., :H * D].reshape(B, S, H, D).transpose(0, 2, 1, 3)
+    k = qkv[..., H * D:(H + Hkv) * D] \
+        .reshape(B, S, Hkv, D).transpose(0, 2, 1, 3)
+    v = qkv[..., (H + Hkv) * D:] \
+        .reshape(B, S, Hkv, D).transpose(0, 2, 1, 3)
+    return apply_rope(q, *w["rope"]), apply_rope(k, *w["rope"]), v
+
+
+def serving_prompt_out_ffn(cfg: LlamaConfig, w, ctx, x, l):
+    x = x + _prompt_dense(cfg, w["o_w"], l, ctx)
+    u = _rms_x(x, w["norm2"][l], cfg.rms_eps)
+    h = jax.nn.silu(_prompt_dense(cfg, w["gate_w"], l, u)) \
+        * _prompt_dense(cfg, w["up_w"], l, u)
+    return x + _prompt_dense(cfg, w["down_w"], l, h)
+
+
+def serving_prompt_head(cfg: LlamaConfig, w, xl):
+    """Logits of ONE row xl [E] (the prompt's last position)."""
+    return _rms_x(xl, w["norm_scale"], cfg.rms_eps) \
+        @ w["head"].astype(cfg.dtype).T
 
 
 # ------------------------------------------------------------- fast loop
@@ -160,21 +305,13 @@ def _fast_fns(cfg: LlamaConfig, max_out: int, weights_q8: bool,
     if key in _STEP_CACHE:
         return _STEP_CACHE[key]
     from deepspeed_tpu.ops.pallas.decode import (
-        ln_qkv_int8_stacked, kv_quant_int8, decode_attention_int8_stacked,
-        decode_attention_fp_stacked, out_ffn_int8_stacked,
-        matvec_int8_stacked)
-    E, H, Hkv, D = (cfg.hidden_size, cfg.n_heads, cfg.kv_heads,
-                    cfg.head_dim)
-    F, Lyr = cfg.intermediate_size, cfg.n_layers
+        kv_quant_int8, decode_attention_int8_stacked,
+        decode_attention_fp_stacked)
+    H, Hkv, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+    Lyr = cfg.n_layers
     rep = H // Hkv
     eps = cfg.rms_eps
     L_cache = max_out
-
-    def deq(stack, scale, l):
-        w = stack[l]
-        if stack.dtype == jnp.int8:
-            return (w.astype(jnp.float32) * scale[l]).astype(cfg.dtype)
-        return w.astype(cfg.dtype)
 
     @functools.partial(jax.jit, donate_argnums=())
     def prompt(p, ids):
@@ -187,16 +324,10 @@ def _fast_fns(cfg: LlamaConfig, max_out: int, weights_q8: bool,
         # 3.8 GB at 7B/b8 (the r5 OOM). Causal masking makes the tail
         # padding inert for every real position.
         Sp = -(-S // 128) * 128
-        x = p["embed"][ids].astype(cfg.dtype)
+        w = serving_prompt_weights(cfg, p, blk, jnp.arange(Sp))
+        x = serving_prompt_embed(cfg, w, ids)
         if Sp != S:
             x = jnp.pad(x, [(0, 0), (0, Sp - S), (0, 0)])
-        positions = jnp.arange(Sp)
-        cos, sin = rope_angles(positions, D, cfg.rope_theta)
-        Wq, sq = _weights(blk, "qkv_w", Lyr)
-        Wo, so = _weights(blk, "o_w", Lyr)
-        Wg, sg = _weights(blk, "gate_w", Lyr)
-        Wu, su = _weights(blk, "up_w", Lyr)
-        Wd, sd = _weights(blk, "down_w", Lyr)
 
         def quant_rows(t):
             # per-(b, head, pos) symmetric int8 — INSIDE the layer scan
@@ -210,22 +341,10 @@ def _fast_fns(cfg: LlamaConfig, max_out: int, weights_q8: bool,
             return codes, sc
 
         def layer(x, l):
-            u = _rms_x(x, blk["norm1"][l], eps)
-            qkv = u @ deq(Wq, sq, l)
-            q = qkv[..., :H * D].reshape(B, Sp, H, D) \
-                .transpose(0, 2, 1, 3)
-            k = qkv[..., H * D:(H + Hkv) * D] \
-                .reshape(B, Sp, Hkv, D).transpose(0, 2, 1, 3)
-            v = qkv[..., (H + Hkv) * D:] \
-                .reshape(B, Sp, Hkv, D).transpose(0, 2, 1, 3)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            q, k, v = serving_prompt_qkv(cfg, w, x, l)
             ctx = dot_product_attention(q, k, v, causal=True)
             ctx = ctx.transpose(0, 2, 1, 3).reshape(B, Sp, H * D)
-            x = x + ctx @ deq(Wo, so, l)
-            u2 = _rms_x(x, blk["norm2"][l], eps)
-            h = jax.nn.silu(u2 @ deq(Wg, sg, l)) * (u2 @ deq(Wu, su, l))
-            x = x + h @ deq(Wd, sd, l)
+            x = serving_prompt_out_ffn(cfg, w, ctx, x, l)
             if cache_q8:
                 kcod, ksc = quant_rows(k)
                 vcod, vsc = quant_rows(v)
@@ -259,33 +378,19 @@ def _fast_fns(cfg: LlamaConfig, max_out: int, weights_q8: bool,
     @functools.partial(jax.jit, static_argnums=(4,), donate_argnums=(2,))
     def fast_scan(p, blk, caches, first_tok, steps, start, rngs,
                   temperature):
-        embed = p["embed"].astype(cfg.dtype)
-        head = p["head"].astype(cfg.dtype)
-        norm_scale = p["norm_scale"]
-        Wq, sq = _weights(blk, "qkv_w", Lyr)
-        Wo, so = _weights(blk, "o_w", Lyr)
-        Wg, sg = _weights(blk, "gate_w", Lyr)
-        Wu, su = _weights(blk, "up_w", Lyr)
-        Wd, sd = _weights(blk, "down_w", Lyr)
-        n1 = blk["norm1"].reshape(Lyr, 1, E)
-        n2 = blk["norm2"].reshape(Lyr, 1, E)
+        w = serving_row_weights(cfg, p, blk)
         B = first_tok.shape[0]
 
         def tick(carry, r):
             caches, tok, offset = carry
-            x = embed[tok]                            # [B, E]
+            x = serving_row_embed(cfg, w, tok, None)  # [B, E]
             x = jnp.where(offset >= L_cache,
                           jnp.float32(jnp.nan).astype(x.dtype), x)
 
             def layer(car, l):
                 x, caches = car
-                qkv = ln_qkv_int8_stacked(x, n1, None, Wq, sq, None, l,
-                                          eps=eps, norm="rms")
-                q3 = qkv[:, :H * D].reshape(B, H, D)
-                k3 = qkv[:, H * D:(H + Hkv) * D].reshape(B, Hkv, D)
-                v3 = qkv[:, (H + Hkv) * D:].reshape(B, Hkv, D)
-                q3 = _rope_one(q3, offset, cfg.rope_theta)
-                k3 = _rope_one(k3, offset, cfg.rope_theta)
+                q3, k3, v3 = serving_row_qkv(
+                    cfg, w, x, l, jnp.broadcast_to(offset, (B,)))
                 qg = q3.reshape(B, Hkv, rep, D)
                 dus = jax.lax.dynamic_update_slice
                 if cache_q8:
@@ -313,26 +418,12 @@ def _fast_fns(cfg: LlamaConfig, max_out: int, weights_q8: bool,
                         qg, kc, vc, offset, l, scale=1.0 / np.sqrt(D))
                     caches = (kc, vc)
                 ctx2 = ctx.reshape(B, H * D)
-                # whole-[E,E] o_proj blocks blow scoped VMEM past
-                # E~2048; split it onto the tiled stacked matvec there
-                if E * E * Wo.dtype.itemsize <= (6 << 20):
-                    x = out_ffn_int8_stacked(
-                        ctx2, x, Wo, so, None, n2, None, Wg, sg, None,
-                        Wd, sd, None, l, act="swiglu", eps=eps,
-                        norm="rms", w1b_stack=Wu, s1b=su)
-                else:
-                    x1 = x + matvec_int8_stacked(ctx2, Wo, so, l)
-                    x = out_ffn_int8_stacked(
-                        None, x1, None, None, None, n2, None, Wg, sg,
-                        None, Wd, sd, None, l, act="swiglu", eps=eps,
-                        norm="rms", w1b_stack=Wu, s1b=su,
-                        fuse_proj=False)
-                return (x, caches), None
+                return (serving_row_out_ffn(cfg, w, ctx2, x, l),
+                        caches), None
 
             (x, caches), _ = jax.lax.scan(
                 layer, (x, caches), jnp.arange(Lyr, dtype=jnp.int32))
-            logits = jnp.einsum("be,ve->bv",
-                                _rms_x(x, norm_scale, eps), head)
+            logits = serving_row_head(cfg, w, x)
             nxt = jax.lax.cond(
                 temperature > 0,
                 lambda: jax.random.categorical(

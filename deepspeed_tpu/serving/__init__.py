@@ -1,6 +1,6 @@
 """Continuous-batching serving engine with a paged KV cache.
 
-Entry point for both supported model families::
+Entry point for every model family in ``FAMILIES``::
 
     import deepspeed_tpu.serving as serving
 
@@ -21,13 +21,28 @@ from deepspeed_tpu.serving.paged_cache import (   # noqa: F401
 from deepspeed_tpu.serving.engine import (        # noqa: F401
     ContinuousBatcher, Request)
 from deepspeed_tpu.serving.adapters import (      # noqa: F401
-    GPT2ServingAdapter, LlamaServingAdapter)
+    GPT2ServingAdapter, LlamaServingAdapter, PagedServingAdapter)
 from deepspeed_tpu.serving.elastic import (       # noqa: F401
     ElasticServingController, capture_state, load_latest_serving,
     load_serving_snapshot, restore_serving, snapshot_serving)
 from deepspeed_tpu.serving.replica_pool import ReplicaPool  # noqa: F401
 from deepspeed_tpu.serving.router import (        # noqa: F401
     DisaggRouter, HandoffPacket, deliver_handoff, extract_handoff)
+
+
+# The families that serve: name -> the paged-serving skeleton bound to the
+# family's layer math (docs/serving.md "Adding a family"). Every builder
+# below reads this table and nothing else about a family.
+FAMILIES = {"gpt2": GPT2ServingAdapter, "llama": LlamaServingAdapter}
+
+_POOL_GEOMETRY = ("n_layers", "kv_heads", "head_dim", "dtype")
+
+
+def _family(family: str):
+    if family not in FAMILIES:
+        raise ValueError(f"unknown serving family {family!r} "
+                         f"(expected one of {sorted(FAMILIES)})")
+    return FAMILIES[family]
 
 
 def _param_dict(config):
@@ -58,20 +73,19 @@ def cache_spec_from_config(model_config, family: str, config=None,
         raise TypeError(f"unknown serving override(s) {sorted(unknown)}; "
                         f"valid: {list(known) + ['quantize_bits']}")
     fields = {k: overrides.get(k, getattr(sc, k)) for k in known}
-    if family == "gpt2":
-        geom = dict(n_layers=model_config.n_layer,
-                    kv_heads=model_config.n_head,
-                    head_dim=model_config.n_embd // model_config.n_head,
-                    dtype=model_config.dtype)
-    elif family == "llama":
-        geom = dict(n_layers=model_config.n_layers,
-                    kv_heads=model_config.kv_heads,
-                    head_dim=model_config.head_dim,
-                    dtype=model_config.dtype)
-    else:
-        raise ValueError(f"unknown serving family {family!r} "
-                         "(expected 'gpt2' or 'llama')")
-    return PagedCacheSpec(**geom, **fields)
+    geom = _family(family).math().serving_geometry(model_config)
+    return PagedCacheSpec(**{k: geom[k] for k in _POOL_GEOMETRY}, **fields)
+
+
+def _adapter_from_config(family: str, model_config, params, pd,
+                         **overrides) -> PagedServingAdapter:
+    """The family's adapter over the cache spec ``pd`` + overrides size.
+    serving.quantize_bits = 8 quantizes full-precision param trees to
+    the int8 serving storage at build time; trees that already carry
+    int8 codes ("kernel_q") serve as-is either way."""
+    spec = cache_spec_from_config(model_config, family, pd, **overrides)
+    qb = overrides.get("quantize_bits", _serving_section(pd).quantize_bits)
+    return _family(family)(model_config, params, spec, quantize_bits=qb)
 
 
 def build_engine(family: str, model_config, params, config=None,
@@ -114,18 +128,8 @@ def build_engine(family: str, model_config, params, config=None,
             "serving.speculative.drafter='model' needs "
             "drafter_model_config= and drafter_params= (a smaller "
             "checkpoint of the SAME family)")
-    spec = cache_spec_from_config(model_config, family, pd, **overrides)
-    # serving.quantize_bits = 8 quantizes full-precision param trees to
-    # the int8 serving storage at build time; trees that already carry
-    # int8 codes ("kernel_q") serve as-is either way
-    qb = overrides.get("quantize_bits",
-                       _serving_section(pd).quantize_bits)
-    if family == "gpt2":
-        adapter = GPT2ServingAdapter(model_config, params, spec,
-                                     quantize_bits=qb)
-    else:
-        adapter = LlamaServingAdapter(model_config, params, spec,
-                                      quantize_bits=qb)
+    adapter = _adapter_from_config(family, model_config, params, pd,
+                                   **overrides)
     mc = None
     if C.MONITOR in pd:
         from deepspeed_tpu.config.config import MonitorConfig
@@ -154,22 +158,12 @@ def build_engine(family: str, model_config, params, config=None,
         from deepspeed_tpu.serving.drafter import (NGramDrafter,
                                                    ModelDrafter)
         if sc.speculative.drafter == "model":
-            dspec = cache_spec_from_config(drafter_model_config, family,
-                                           pd, num_blocks=0, **{
-                                               k: v for k, v in
-                                               overrides.items()
-                                               if k != "num_blocks"})
-            if family == "gpt2":
-                dadapter = GPT2ServingAdapter(drafter_model_config,
-                                              drafter_params, dspec,
-                                              quantize_bits=qb)
-            else:
-                dadapter = LlamaServingAdapter(drafter_model_config,
-                                               drafter_params, dspec,
-                                               quantize_bits=qb)
+            dadapter = _adapter_from_config(
+                family, drafter_model_config, drafter_params, pd,
+                **{**overrides, "num_blocks": 0})
             drafter = ModelDrafter(dadapter)
         else:
-            drafter = NGramDrafter(spec.slots,
+            drafter = NGramDrafter(adapter.spec.slots,
                                    ngram_max=sc.speculative.ngram_max,
                                    ngram_min=sc.speculative.ngram_min)
     # registry: pass telemetry.default_registry() to merge the serving
@@ -233,14 +227,8 @@ def build_router(family: str, model_config, params, config=None,
             "builds its own role node with "
             "serving.build_transport_node(...) (build_router builds "
             "the in-process fabric only)")
-    spec = cache_spec_from_config(model_config, family, pd, **overrides)
-    qb = overrides.get("quantize_bits", sc.quantize_bits)
-    if family == "gpt2":
-        adapter = GPT2ServingAdapter(model_config, params, spec,
-                                     quantize_bits=qb)
-    else:
-        adapter = LlamaServingAdapter(model_config, params, spec,
-                                      quantize_bits=qb)
+    adapter = _adapter_from_config(family, model_config, params, pd,
+                                   **overrides)
     disagg = dg.enabled and dg.decode_replicas > 0
 
     def mk(role, prefix_on):
@@ -316,6 +304,8 @@ def build_transport_node(family: str, model_config, params, config=None,
     pd = _param_dict(config)
     sc = _serving_section(pd)
     dg, rt = sc.disaggregation, sc.router
+    adapter = _adapter_from_config(family, model_config, params, pd,
+                                   **overrides)
     mc = None
     if C.MONITOR in pd:
         from deepspeed_tpu.config.config import MonitorConfig
@@ -329,14 +319,6 @@ def build_transport_node(family: str, model_config, params, config=None,
     assert endpoint.world >= 2, (
         f"the process transport needs >= 2 ranks (prefill + decode), "
         f"got world={endpoint.world}")
-    spec = cache_spec_from_config(model_config, family, pd, **overrides)
-    qb = overrides.get("quantize_bits", sc.quantize_bits)
-    if family == "gpt2":
-        adapter = GPT2ServingAdapter(model_config, params, spec,
-                                     quantize_bits=qb)
-    else:
-        adapter = LlamaServingAdapter(model_config, params, spec,
-                                      quantize_bits=qb)
     if endpoint.rank == 0:
         prefills = []
         for i in range(max(dg.prefill_replicas, 1)):

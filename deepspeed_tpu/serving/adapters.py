@@ -1,27 +1,40 @@
-"""Model adapters for the continuous-batching serving engine.
+"""The paged-serving skeleton: ONE adapter class for every model family.
 
-Each adapter compiles exactly TWO kinds of programs per model/storage
+``PagedServingAdapter`` owns every touch of the paged KV pool and
+everything a family's programs repeat; a FAMILY is its layer math — the
+``serving_*`` functions of ``models/gpt2_inference.py`` /
+``models/llama_inference.py`` (geometry, params, embedding, qkv half,
+out+FFN half, head), none of which sees the pool, the page table or the
+sampler. ``serving.FAMILIES`` names the families; docs/serving.md
+"Adding a family" lists what a new one writes.
+
+An adapter compiles four KINDS of programs per model/storage
 combination, so arbitrary request arrival patterns replay a small fixed
 set of executables instead of retracing per request:
 
-- ``tick``: ONE decode step over the whole slot set — [B_slots] tokens
-  at per-slot positions, paged-attention reads through the page table,
-  donated pool, idle slots masked by ``pos[b] < 0``. Compiled once per
-  engine.
+- ``tick``: decode steps over the whole slot set — [B_slots] tokens at
+  per-slot positions, paged-attention reads through the page table,
+  donated pool, idle slots masked by ``pos[b] < 0``. One program per
+  step count.
+- ``verify``: a K-token speculative window per slot in one dispatch —
+  the same decode rows, K to a slot, in the kernel's multi-query mode.
 - ``prefill``: one request's prompt pass at a BUCKETED padded length
   (pages rounded up to the next power of two), writing K/V straight
   into the slot's assigned pool pages and returning last-position
   logits. Compiled once per bucket — log2(max_pages) programs total.
+- ``prefill_suffix``: the prefix-cache-hit prompt pass — only positions
+  past the shared prefix, which is read back through the page table.
 
-The decode tick reuses the stacked fused kernels the dense fast path
-serves through (ops/pallas/decode.py): ``ln_qkv_int8_stacked`` /
-``out_ffn_int8_stacked`` for the projections (dtype-agnostic — bf16
-stacks run with scale 1) and ``decode_attention_paged`` for the
-cached-attention read. Appends are XLA scatters into the donated pool:
-row ``pos[b] % page`` of block ``page_table[b, pos[b] // page]``.
+Decode rows run the stacked fused kernels the dense fast path serves
+through (ops/pallas/decode.py): the family's ``ln_qkv_int8_stacked`` /
+``out_ffn_int8_stacked`` halves (dtype-agnostic — bf16 stacks run with
+scale 1) round ``decode_attention_paged``, the cached-attention read.
+Appends are XLA scatters into the donated pool: row ``pos[b] % page``
+of block ``page_table[b, pos[b] // page]``.
 """
 
 import functools
+import importlib
 
 import numpy as np
 import jax
@@ -162,11 +175,11 @@ def _suffix_attn_bias(start, pos_q, n_prefix_rows):
     return jnp.where(mask, 0.0, -1e30).astype(jnp.float32)[None, None]
 
 
-def _verify_append_ids(pos, pt, K, page, maxp):
-    """(block ids, row offsets) [B*K] for appending the verification
-    rows of a K-token speculative window at positions pos[b]..pos[b]+K-1
-    per slot. Idle slots (pos < 0) resolve inside their all-trash table
-    rows, same as _gather_blocks."""
+def _append_ids(pos, pt, K, page, maxp):
+    """(block ids, row offsets, positions) [B*K] for appending K rows a
+    slot at positions pos[b]..pos[b]+K-1 (K = 1: a decode step; K > 1: a
+    speculative window). Idle slots (pos < 0) resolve inside their
+    all-trash table rows."""
     B = pos.shape[0]
     posf = (pos[:, None]
             + jnp.arange(K, dtype=jnp.int32)[None, :]).reshape(B * K)
@@ -221,434 +234,229 @@ def sample_token(logits32, seed, idx, temperature):
     return int(tok[0])   # sync-ok: the scheduler consumes the sample
 
 
-def _gather_blocks(pt, pos, page):
-    """(block ids, row offsets) for appending each slot's next row.
-    Idle slots (pos < 0) resolve inside their all-trash table rows."""
-    maxp = pt.shape[1]
-    idx = jnp.clip(pos // page, 0, maxp - 1)
-    blk_ids = jnp.take_along_axis(pt, idx[:, None], axis=1)[:, 0]
-    rows = pos % page
-    return blk_ids, rows
+def _attend_rows(pool, cache_q8, q, pos, pt, l, rows_per_step):
+    """The cached-attention read of the decode rows q [B, Hkv, R, D]
+    through the page table at layer ``l``."""
+    from deepspeed_tpu.ops.pallas.decode import decode_attention_paged
+    scale = 1.0 / np.sqrt(q.shape[-1])
+    if cache_q8:
+        kc, ks, vc, vs = pool
+        return decode_attention_paged(
+            q, kc, vc, pos, pt, l, k_scale=ks, v_scale=vs, scale=scale,
+            rows_per_step=rows_per_step)
+    kc, vc = pool
+    return decode_attention_paged(q, kc, vc, pos, pt, l, scale=scale,
+                                  rows_per_step=rows_per_step)
 
 
-# ------------------------------------------------------------- GPT-2
+def _program(build):
+    """Method decorator: ``build(self, *static)`` returns one program
+    kind's function of (p, blk, pool, ...); the decorated method returns
+    it jitted with the pool donated, built once per ``static`` ON THE
+    ADAPTER — the closures hold the adapter, so a module-global cache
+    would pin every model's weights for process lifetime; here they
+    free with the engine."""
+    @functools.wraps(build)
+    def get(self, *static):
+        key = (build.__name__,) + static
+        if key not in self._fns:
+            self._fns[key] = jax.jit(build(self, *static),
+                                     donate_argnums=(2,))
+        return self._fns[key]
+    return get
 
-class GPT2ServingAdapter:
-    """Paged serving over converted (optionally int8) GPT-2 inference
-    params — the scan-stacked tree `convert_gpt2_params` produces."""
+
+class PagedServingAdapter:
+    """Paged serving of one model: the pool, the page table, the scans,
+    the sampler and the program cache. ``layer_math`` — bound by the
+    subclasses below — names the module holding the model family's
+    ``serving_*`` functions."""
+
+    layer_math = None
+
+    @classmethod
+    def math(cls):
+        """The family's module, imported on first use: a process that
+        only routes requests or moves frames never loads a model file."""
+        return importlib.import_module(cls.layer_math)
 
     def __init__(self, cfg, params, spec: PagedCacheSpec,
                  quantize_bits: int = 0):
-        from deepspeed_tpu.models.gpt2_inference import (
-            convert_gpt2_params, quantize_gpt2_inference_params)
-        assert cfg.tie_word_embeddings, \
-            "paged GPT-2 serving assumes the tied-embedding LM head"
-        assert cfg.n_embd % cfg.n_head == 0
-        converted = "h" in params and "blk" in params.get("h", {}) and \
-            "attn_qkvw" in params["h"]["blk"]
-        self.iparams = params if converted \
-            else convert_gpt2_params(params, cfg)
-        if quantize_bits == 8 \
-                and "kernel_q" not in self.iparams["h"]["blk"]["attn_qkvw"]:
-            # serving.quantize_bits: quantize a full-precision tree to
-            # the int8 serving storage at build time
-            self.iparams = quantize_gpt2_inference_params(self.iparams)
         self.cfg = cfg
         self.spec = spec
-        self.weights_q8 = "kernel_q" in self.iparams["h"]["blk"]["attn_qkvw"]
         self.cache_q8 = spec.kv_cache_bits == 8
-        assert spec.n_layers == cfg.n_layer
-        assert spec.kv_heads == cfg.n_head
-        assert spec.head_dim == cfg.n_embd // cfg.n_head
-        self._p = {"wte": self.iparams["wte"], "wpe": self.iparams["wpe"],
-                   "ln_f": self.iparams["ln_f"]}
-        self._blk = self.iparams["h"]["blk"]
-        # per-ADAPTER compiled-fn cache: the closures capture the params
-        # tree, so a module-global cache would pin every model's weights
-        # for process lifetime; here they free with the engine
+        self._geom = self.math().serving_geometry(cfg)
+        for k in ("n_layers", "kv_heads", "head_dim"):
+            assert getattr(spec, k) == self._geom[k], (k, spec, self._geom)
+        self._p, self._blk = self.math().serving_params(cfg, params,
+                                                        quantize_bits)
         self._fns = {}
-
-    @property
-    def eos_default(self):
-        return None
 
     def make_cache(self) -> PagedKVCache:
         return PagedKVCache(self.spec)
 
     def max_prompt_len(self):
-        return self.cfg.n_positions
+        return self._geom["max_prompt_len"]
+
+    # -- the two passes every program is made of ---------------------------
+
+    def _decode_rows(self, w, pool, toks, pos, pt, window=None):
+        """K rows a slot — toks [B, K] ([B] when K = 1) at positions
+        pos[b]..pos[b]+K-1 — through the layer stack with the pool in the carry: each layer
+        appends the rows' K/V, then attends through the page table.
+        ``window=None`` is a decode step (K = 1, the kernel's
+        single-position mask); ``window=K`` a speculative window (its
+        multi-query mode). Returns (pool, logits [B*K, V])."""
+        fam, cfg, spec = self.math(), self.cfg, self.spec
+        B, K = pos.shape[0], window or 1
+        Hkv, D = spec.kv_heads, spec.head_dim
+        blk_ids, rows, posf = _append_ids(pos, pt, K, spec.page_size,
+                                          spec.max_pages_per_slot)
+        x = fam.serving_row_embed(cfg, w, toks.reshape(B * K), posf)
+
+        def layer(car, l):
+            x, pool = car
+            q, k, v = fam.serving_row_qkv(cfg, w, x, l, posf)
+            rep = q.shape[1] // Hkv     # GQA: rep query rows a KV head
+            # STEP-major query rows: row j = step * rep + r
+            qg = q.reshape(B, K, Hkv, rep, D) \
+                .transpose(0, 2, 1, 3, 4).reshape(B, Hkv, K * rep, D)
+            pool = _append_rows(pool, self.cache_q8, l, blk_ids, rows,
+                                k, v)
+            ctx = _attend_rows(pool, self.cache_q8, qg, pos, pt, l,
+                               rows_per_step=rep if window else None)
+            ctx = ctx.reshape(B, Hkv, K, rep, D) \
+                .transpose(0, 2, 1, 3, 4).reshape(B * K, Hkv * rep * D)
+            return (fam.serving_row_out_ffn(cfg, w, ctx, x, l), pool), None
+
+        (x, pool), _ = jax.lax.scan(
+            layer, (x, pool), jnp.arange(spec.n_layers, dtype=jnp.int32))
+        return pool, fam.serving_row_head(cfg, w, x)
+
+    def _prompt_rows(self, w, ids, attend):
+        """One request's prompt rows ids [1, S] through the layer stack;
+        ``attend(q, k, v, l)`` is the caller's attention over the rows
+        (and whatever prefix it reads). Returns (x [1, S, E], K, V
+        [Lyr, Hkv, S, D]) — the caller writes them to the pool."""
+        fam, cfg = self.math(), self.cfg
+        S = ids.shape[1]
+
+        def layer(x, l):
+            q, k, v = fam.serving_prompt_qkv(cfg, w, x, l)
+            ctx = attend(q, k, v, l).transpose(0, 2, 1, 3).reshape(1, S, -1)
+            return fam.serving_prompt_out_ffn(cfg, w, ctx, x, l), \
+                (k[0], v[0])
+
+        x, (ks, vs) = jax.lax.scan(
+            layer, fam.serving_prompt_embed(cfg, w, ids),
+            jnp.arange(self.spec.n_layers, dtype=jnp.int32))
+        return x, ks, vs
 
     # -- compiled programs -------------------------------------------------
 
-    def _tick_fn(self, steps: int = 1):
-        cfg, spec = self.cfg, self.spec
-        key = ("tick", steps)
-        if key in self._fns:
-            return self._fns[key]
-        from deepspeed_tpu.ops.pallas.decode import (
-            ln_qkv_int8_stacked, decode_attention_paged,
-            out_ffn_int8_stacked)
-        E, H = cfg.n_embd, cfg.n_head
-        D = E // H
-        Lyr = cfg.n_layer
-        P = spec.page_size
-        eps = cfg.layer_norm_epsilon
-        cache_q8 = self.cache_q8
-        wkey = "kernel_q" if self.weights_q8 else "kernel"
+    @_program
+    def _tick_fn(self, steps):
+        fam, cfg = self.math(), self.cfg
 
-        def _wscale(proj):
-            if self.weights_q8:
-                return proj["kernel_scale"].reshape(Lyr)
-            return jnp.ones((Lyr,), jnp.float32)
-
-        def _ln_f(x, w, b):
-            xf = x.astype(jnp.float32)
-            mu = jnp.mean(xf, axis=-1, keepdims=True)
-            var = jnp.mean((xf - mu) ** 2, axis=-1, keepdims=True)
-            y = (xf - mu) * jax.lax.rsqrt(var + eps)
-            return (y * w.astype(jnp.float32)
-                    + b.astype(jnp.float32)).astype(x.dtype)
-
-        @functools.partial(jax.jit, donate_argnums=(2,))
         def tick(p, blk, pool, toks, pos, pt, seeds, idxs0, temps):
-            wte = jnp.asarray(p["wte"]).astype(cfg.dtype)
-            wpe = jnp.asarray(p["wpe"]).astype(cfg.dtype)
-            Wq, Wp = blk["attn_qkvw"][wkey], blk["attn_ow"][wkey]
-            W1, W2 = blk["inter_w"][wkey], blk["output_w"][wkey]
-            r3 = lambda a: a.reshape(Lyr, 1, a.shape[-1])  # noqa: E731
-            ln1_w = r3(blk["attn_nw"]["scale"])
-            ln1_b = r3(blk["attn_nw"]["bias"])
-            ln2_w = r3(blk["norm_w"]["scale"])
-            ln2_b = r3(blk["norm_w"]["bias"])
-            bq = r3(blk["attn_qkvw"]["bias"])
-            bp = r3(blk["attn_ow"]["bias"])
-            b1 = r3(blk["inter_w"]["bias"])
-            b2 = r3(blk["output_w"]["bias"])
-            sq, sp_ = _wscale(blk["attn_qkvw"]), _wscale(blk["attn_ow"])
-            s1, s2 = _wscale(blk["inter_w"]), _wscale(blk["output_w"])
-            B = toks.shape[0]
+            w = fam.serving_row_weights(cfg, p, blk)
 
             def one(carry, t):
                 pool, toks, pos, _ = carry
-                x = wte[toks] + wpe[jnp.clip(pos, 0,
-                                             cfg.n_positions - 1)]
-                blk_ids, rows = _gather_blocks(pt, pos, P)
-
-                def layer(car, l):
-                    x, pool = car
-                    qkv = ln_qkv_int8_stacked(x, ln1_w, ln1_b, Wq, sq,
-                                              bq, l, eps=eps)
-                    qh = qkv[:, :E].reshape(B, H, 1, D)
-                    k3 = qkv[:, E:2 * E].reshape(B, H, D)
-                    v3 = qkv[:, 2 * E:].reshape(B, H, D)
-                    pool = _append_rows(pool, cache_q8, l, blk_ids,
-                                        rows, k3, v3)
-                    if cache_q8:
-                        kc, ks, vc, vs = pool
-                        ctx = decode_attention_paged(
-                            qh, kc, vc, pos, pt, l, k_scale=ks,
-                            v_scale=vs, scale=1.0 / np.sqrt(D))
-                    else:
-                        kc, vc = pool
-                        ctx = decode_attention_paged(
-                            qh, kc, vc, pos, pt, l,
-                            scale=1.0 / np.sqrt(D))
-                    ctx2 = ctx.reshape(B, E)
-                    x = out_ffn_int8_stacked(
-                        ctx2, x, Wp, sp_, bp, ln2_w, ln2_b, W1, s1, b1,
-                        W2, s2, b2, l, act="gelu_tanh", eps=eps)
-                    return (x, pool), None
-
-                (x, pool), _ = jax.lax.scan(
-                    layer, (x, pool), jnp.arange(Lyr, dtype=jnp.int32))
-                logits = jnp.einsum(
-                    "be,ve->bv",
-                    _ln_f(x, p["ln_f"]["scale"], p["ln_f"]["bias"]), wte)
+                pool, logits = self._decode_rows(w, pool, toks, pos, pt)
                 nxt, logits32 = _pick_next(logits, seeds, idxs0 + t,
                                            temps)
                 return (pool, nxt, pos + 1, logits32), nxt
 
-            logits0 = jnp.zeros((B, cfg.vocab_size), jnp.float32)
+            logits0 = jnp.zeros((toks.shape[0], self._geom["vocab_size"]),
+                                jnp.float32)
             (pool, _, _, logits32), toks_seq = jax.lax.scan(
                 one, (pool, toks, pos, logits0),
                 jnp.arange(steps, dtype=jnp.int32))
             return pool, toks_seq, logits32
 
-        self._fns[key] = tick
         return tick
 
-    def _prefill_fn(self, n_pages: int):
-        cfg, spec = self.cfg, self.spec
-        key = ("prefill", n_pages)
-        if key in self._fns:
-            return self._fns[key]
+    @_program
+    def _verify_fn(self, n_rows):
+        """Speculative verification: feed ``n_rows`` tokens per slot
+        (the pending token + n_rows-1 drafts) in ONE dispatch; every
+        drafted position attends through the page table at its own
+        offset. Returns (pool, greedy [B, n_rows], logits32
+        [B, n_rows, V])."""
+        fam, cfg = self.math(), self.cfg
+
+        def verify(p, blk, pool, toks, pos, pt):
+            w = fam.serving_row_weights(cfg, p, blk)
+            pool, logits = self._decode_rows(w, pool, toks, pos, pt,
+                                             window=n_rows)
+            logits32 = logits.astype(jnp.float32)
+            greedy = jnp.argmax(logits32, axis=-1).astype(jnp.int32)
+            B = toks.shape[0]
+            return (pool, greedy.reshape(B, n_rows),
+                    logits32.reshape(B, n_rows, -1))
+
+        return verify
+
+    @_program
+    def _prefill_fn(self, n_pages):
         from deepspeed_tpu.ops.attention import dot_product_attention
-        E, H = cfg.n_embd, cfg.n_head
-        D = E // H
-        Lyr = cfg.n_layer
-        P = spec.page_size
+        fam, cfg = self.math(), self.cfg
+        P = self.spec.page_size
         Sp = n_pages * P
-        assert Sp <= cfg.n_positions, (
-            f"prefill bucket {Sp} exceeds n_positions {cfg.n_positions}")
-        eps = cfg.layer_norm_epsilon
-        cache_q8 = self.cache_q8
-        wkey = "kernel_q" if self.weights_q8 else "kernel"
+        assert Sp <= self.max_prompt_len(), (
+            f"prefill bucket {Sp} exceeds the {self.max_prompt_len()}"
+            "-position budget")
 
-        def deq(sub, l):
-            w = sub[wkey][l]
-            if self.weights_q8:
-                s = sub["kernel_scale"].reshape(Lyr)[l]
-                return (w.astype(jnp.float32) * s).astype(cfg.dtype)
-            return w.astype(cfg.dtype)
-
-        def _ln(x, w, b):
-            xf = x.astype(jnp.float32)
-            mu = jnp.mean(xf, axis=-1, keepdims=True)
-            var = jnp.mean((xf - mu) ** 2, axis=-1, keepdims=True)
-            y = (xf - mu) * jax.lax.rsqrt(var + eps)
-            return (y * w.astype(jnp.float32)
-                    + b.astype(jnp.float32)).astype(x.dtype)
-
-        @functools.partial(jax.jit, donate_argnums=(2,))
         def prefill(p, blk, pool, ids, length, pages):
-            wte = jnp.asarray(p["wte"]).astype(cfg.dtype)
-            wpe = jnp.asarray(p["wpe"]).astype(cfg.dtype)
-            x = wte[ids] + wpe[:Sp][None]            # [1, Sp, E]
-
-            def layer(x, l):
-                u = _ln(x, blk["attn_nw"]["scale"][l],
-                        blk["attn_nw"]["bias"][l])
-                qkv = u @ deq(blk["attn_qkvw"], l) \
-                    + blk["attn_qkvw"]["bias"][l].astype(cfg.dtype)
-                q = qkv[..., :E].reshape(1, Sp, H, D).transpose(0, 2, 1, 3)
-                k = qkv[..., E:2 * E].reshape(1, Sp, H, D) \
-                    .transpose(0, 2, 1, 3)
-                v = qkv[..., 2 * E:].reshape(1, Sp, H, D) \
-                    .transpose(0, 2, 1, 3)
-                ctx = dot_product_attention(q, k, v, causal=True)
-                ctx = ctx.transpose(0, 2, 1, 3).reshape(1, Sp, E)
-                x = x + ctx @ deq(blk["attn_ow"], l) \
-                    + blk["attn_ow"]["bias"][l].astype(cfg.dtype)
-                u2 = _ln(x, blk["norm_w"]["scale"][l],
-                         blk["norm_w"]["bias"][l])
-                h = jax.nn.gelu(
-                    u2 @ deq(blk["inter_w"], l)
-                    + blk["inter_w"]["bias"][l].astype(cfg.dtype),
-                    approximate=True)
-                x = x + h @ deq(blk["output_w"], l) \
-                    + blk["output_w"]["bias"][l].astype(cfg.dtype)
-                return x, (k[0], v[0])
-
-            x, (ks, vs) = jax.lax.scan(
-                layer, x, jnp.arange(Lyr, dtype=jnp.int32))
-            pool = _write_prompt_pages(pool, cache_q8, ks, vs, pages, P)
-            xl = x[0, length - 1]
-            xf = xl.astype(jnp.float32)
-            mu = jnp.mean(xf, keepdims=True)
-            var = jnp.mean((xf - mu) ** 2, keepdims=True)
-            y = (xf - mu) * jax.lax.rsqrt(var + eps)
-            y = y * p["ln_f"]["scale"].astype(jnp.float32) \
-                + p["ln_f"]["bias"].astype(jnp.float32)
-            logits = y.astype(cfg.dtype) @ wte.T
+            w = fam.serving_prompt_weights(cfg, p, blk, jnp.arange(Sp))
+            x, ks, vs = self._prompt_rows(
+                w, ids, lambda q, k, v, l: dot_product_attention(
+                    q, k, v, causal=True))
+            pool = _write_prompt_pages(pool, self.cache_q8, ks, vs,
+                                       pages, P)
+            logits = fam.serving_prompt_head(cfg, w, x[0, length - 1])
             return pool, logits.astype(jnp.float32)
 
-        self._fns[key] = prefill
         return prefill
 
-    def _prefill_suffix_fn(self, n_suf_pages: int, n_pre_pages: int):
+    @_program
+    def _prefill_suffix_fn(self, n_suf_pages, n_pre_pages):
         """Suffix-only prefill for prefix-cache hits: computes (and
         writes) K/V ONLY for prompt positions >= ``start``, reading the
         shared-prefix K/V back through the slot's page table. One
         compiled program per (suffix-pages, prefix-pages) pow2 bucket
         pair."""
-        cfg, spec = self.cfg, self.spec
-        key = ("prefill_sfx", n_suf_pages, n_pre_pages)
-        if key in self._fns:
-            return self._fns[key]
         from deepspeed_tpu.ops.attention import dot_product_attention
-        E, H = cfg.n_embd, cfg.n_head
-        D = E // H
-        Lyr = cfg.n_layer
-        P = spec.page_size
-        MAXP = spec.max_pages_per_slot
+        fam, cfg, spec = self.math(), self.cfg, self.spec
+        P, MAXP = spec.page_size, spec.max_pages_per_slot
         Ssuf = n_suf_pages * P
-        LPRE = n_pre_pages * P
-        eps = cfg.layer_norm_epsilon
-        cache_q8 = self.cache_q8
-        wkey = "kernel_q" if self.weights_q8 else "kernel"
 
-        def deq(sub, l):
-            w = sub[wkey][l]
-            if self.weights_q8:
-                s = sub["kernel_scale"].reshape(Lyr)[l]
-                return (w.astype(jnp.float32) * s).astype(cfg.dtype)
-            return w.astype(cfg.dtype)
-
-        def _ln(x, w, b):
-            xf = x.astype(jnp.float32)
-            mu = jnp.mean(xf, axis=-1, keepdims=True)
-            var = jnp.mean((xf - mu) ** 2, axis=-1, keepdims=True)
-            y = (xf - mu) * jax.lax.rsqrt(var + eps)
-            return (y * w.astype(jnp.float32)
-                    + b.astype(jnp.float32)).astype(x.dtype)
-
-        @functools.partial(jax.jit, donate_argnums=(2,))
         def prefill_sfx(p, blk, pool, ids, length, start, pt_row):
-            wte = jnp.asarray(p["wte"]).astype(cfg.dtype)
-            wpe = jnp.asarray(p["wpe"]).astype(cfg.dtype)
             pos_q = start + jnp.arange(Ssuf, dtype=jnp.int32)
-            x = wte[ids] + wpe[jnp.clip(pos_q, 0,
-                                        cfg.n_positions - 1)][None]
+            w = fam.serving_prompt_weights(cfg, p, blk, pos_q)
             pre_ids = pt_row[:n_pre_pages]
-            bias = _suffix_attn_bias(start, pos_q, LPRE)
+            bias = _suffix_attn_bias(start, pos_q, n_pre_pages * P)
 
-            def layer(x, l):
-                u = _ln(x, blk["attn_nw"]["scale"][l],
-                        blk["attn_nw"]["bias"][l])
-                qkv = u @ deq(blk["attn_qkvw"], l) \
-                    + blk["attn_qkvw"]["bias"][l].astype(cfg.dtype)
-                q = qkv[..., :E].reshape(1, Ssuf, H, D) \
-                    .transpose(0, 2, 1, 3)
-                k = qkv[..., E:2 * E].reshape(1, Ssuf, H, D) \
-                    .transpose(0, 2, 1, 3)
-                v = qkv[..., 2 * E:].reshape(1, Ssuf, H, D) \
-                    .transpose(0, 2, 1, 3)
-                kpre, vpre = _gather_prefix_kv(pool, cache_q8, l,
-                                               pre_ids, cfg.dtype)
+            def attend(q, k, v, l):
+                kpre, vpre = _gather_prefix_kv(
+                    pool, self.cache_q8, l, pre_ids, self._geom["dtype"])
                 ka = jnp.concatenate([kpre[None], k], axis=2)
                 va = jnp.concatenate([vpre[None], v], axis=2)
-                ctx = dot_product_attention(q, ka, va, bias=bias)
-                ctx = ctx.transpose(0, 2, 1, 3).reshape(1, Ssuf, E)
-                x = x + ctx @ deq(blk["attn_ow"], l) \
-                    + blk["attn_ow"]["bias"][l].astype(cfg.dtype)
-                u2 = _ln(x, blk["norm_w"]["scale"][l],
-                         blk["norm_w"]["bias"][l])
-                h = jax.nn.gelu(
-                    u2 @ deq(blk["inter_w"], l)
-                    + blk["inter_w"]["bias"][l].astype(cfg.dtype),
-                    approximate=True)
-                x = x + h @ deq(blk["output_w"], l) \
-                    + blk["output_w"]["bias"][l].astype(cfg.dtype)
-                return x, (k[0], v[0])
+                return dot_product_attention(q, ka, va, bias=bias)
 
-            x, (ks, vs) = jax.lax.scan(
-                layer, x, jnp.arange(Lyr, dtype=jnp.int32))
+            x, ks, vs = self._prompt_rows(w, ids, attend)
             valid = pos_q < length
             blks = jnp.where(
                 valid, pt_row[jnp.clip(pos_q // P, 0, MAXP - 1)],
                 jnp.int32(0))
-            pool_out = _write_suffix_rows(pool, cache_q8, ks, vs,
+            pool_out = _write_suffix_rows(pool, self.cache_q8, ks, vs,
                                           blks, pos_q % P)
-            xl = x[0, length - 1 - start]
-            xf = xl.astype(jnp.float32)
-            mu = jnp.mean(xf, keepdims=True)
-            var = jnp.mean((xf - mu) ** 2, keepdims=True)
-            y = (xf - mu) * jax.lax.rsqrt(var + eps)
-            y = y * p["ln_f"]["scale"].astype(jnp.float32) \
-                + p["ln_f"]["bias"].astype(jnp.float32)
-            logits = y.astype(cfg.dtype) @ wte.T
+            logits = fam.serving_prompt_head(cfg, w,
+                                             x[0, length - 1 - start])
             return pool_out, logits.astype(jnp.float32)
 
-        self._fns[key] = prefill_sfx
         return prefill_sfx
-
-    def _verify_fn(self, n_rows: int):
-        """Speculative verification: feed ``n_rows`` tokens per slot
-        (the pending token + n_rows-1 drafts) in ONE dispatch; the
-        paged attention runs in multi-query mode so every drafted
-        position attends through the page table at its own offset.
-        Returns (pool, greedy [B, n_rows], logits32 [B, n_rows, V])."""
-        cfg, spec = self.cfg, self.spec
-        key = ("verify", n_rows)
-        if key in self._fns:
-            return self._fns[key]
-        from deepspeed_tpu.ops.pallas.decode import (
-            ln_qkv_int8_stacked, decode_attention_paged,
-            out_ffn_int8_stacked)
-        E, H = cfg.n_embd, cfg.n_head
-        D = E // H
-        Lyr = cfg.n_layer
-        P = spec.page_size
-        MAXP = spec.max_pages_per_slot
-        K = n_rows
-        eps = cfg.layer_norm_epsilon
-        cache_q8 = self.cache_q8
-        wkey = "kernel_q" if self.weights_q8 else "kernel"
-
-        def _wscale(proj):
-            if self.weights_q8:
-                return proj["kernel_scale"].reshape(Lyr)
-            return jnp.ones((Lyr,), jnp.float32)
-
-        def _ln_f(x, w, b):
-            xf = x.astype(jnp.float32)
-            mu = jnp.mean(xf, axis=-1, keepdims=True)
-            var = jnp.mean((xf - mu) ** 2, axis=-1, keepdims=True)
-            y = (xf - mu) * jax.lax.rsqrt(var + eps)
-            return (y * w.astype(jnp.float32)
-                    + b.astype(jnp.float32)).astype(x.dtype)
-
-        @functools.partial(jax.jit, donate_argnums=(2,))
-        def verify(p, blk, pool, toks, pos, pt):
-            wte = jnp.asarray(p["wte"]).astype(cfg.dtype)
-            wpe = jnp.asarray(p["wpe"]).astype(cfg.dtype)
-            Wq, Wp = blk["attn_qkvw"][wkey], blk["attn_ow"][wkey]
-            W1, W2 = blk["inter_w"][wkey], blk["output_w"][wkey]
-            r3 = lambda a: a.reshape(Lyr, 1, a.shape[-1])  # noqa: E731
-            ln1_w = r3(blk["attn_nw"]["scale"])
-            ln1_b = r3(blk["attn_nw"]["bias"])
-            ln2_w = r3(blk["norm_w"]["scale"])
-            ln2_b = r3(blk["norm_w"]["bias"])
-            bq = r3(blk["attn_qkvw"]["bias"])
-            bp = r3(blk["attn_ow"]["bias"])
-            b1 = r3(blk["inter_w"]["bias"])
-            b2 = r3(blk["output_w"]["bias"])
-            sq, sp_ = _wscale(blk["attn_qkvw"]), _wscale(blk["attn_ow"])
-            s1, s2 = _wscale(blk["inter_w"]), _wscale(blk["output_w"])
-            B = toks.shape[0]
-            blk_ids, rows, posf = _verify_append_ids(pos, pt, K, P, MAXP)
-            x = (wte[toks]
-                 + wpe[jnp.clip(posf.reshape(B, K), 0,
-                                cfg.n_positions - 1)]).reshape(B * K, E)
-
-            def layer(car, l):
-                x, pool = car
-                qkv = ln_qkv_int8_stacked(x, ln1_w, ln1_b, Wq, sq,
-                                          bq, l, eps=eps)
-                qh = qkv[:, :E].reshape(B, K, H, D).transpose(0, 2, 1, 3)
-                k3 = qkv[:, E:2 * E].reshape(B * K, H, D)
-                v3 = qkv[:, 2 * E:].reshape(B * K, H, D)
-                pool = _append_rows(pool, cache_q8, l, blk_ids,
-                                    rows, k3, v3)
-                if cache_q8:
-                    kc, ks, vc, vs = pool
-                    ctx = decode_attention_paged(
-                        qh, kc, vc, pos, pt, l, k_scale=ks,
-                        v_scale=vs, scale=1.0 / np.sqrt(D),
-                        rows_per_step=1)
-                else:
-                    kc, vc = pool
-                    ctx = decode_attention_paged(
-                        qh, kc, vc, pos, pt, l,
-                        scale=1.0 / np.sqrt(D), rows_per_step=1)
-                ctx2 = ctx.transpose(0, 2, 1, 3).reshape(B * K, E)
-                x = out_ffn_int8_stacked(
-                    ctx2, x, Wp, sp_, bp, ln2_w, ln2_b, W1, s1, b1,
-                    W2, s2, b2, l, act="gelu_tanh", eps=eps)
-                return (x, pool), None
-
-            (x, pool), _ = jax.lax.scan(
-                layer, (x, pool), jnp.arange(Lyr, dtype=jnp.int32))
-            logits = jnp.einsum(
-                "be,ve->bv",
-                _ln_f(x, p["ln_f"]["scale"], p["ln_f"]["bias"]), wte)
-            logits32 = logits.astype(jnp.float32)
-            greedy = jnp.argmax(logits32, axis=-1).astype(jnp.int32)
-            return (pool, greedy.reshape(B, K),
-                    logits32.reshape(B, K, -1))
-
-        self._fns[key] = verify
-        return verify
 
     # -- engine-facing calls -----------------------------------------------
 
@@ -687,431 +495,15 @@ class GPT2ServingAdapter:
                                 jnp.asarray(dst, jnp.int32))
 
 
-# ------------------------------------------------------------- LLaMA
-
-def _rope_rows(x, pos, theta):
-    """RoPE on [B, Hx, D] rows at PER-SLOT positions ``pos`` [B] (the
-    dense fast loop's _rope_one takes one shared scalar position —
-    continuous batching decodes every slot at its own offset)."""
-    B, H, D = x.shape
-    inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
-    ang = pos.astype(jnp.float32)[:, None] * inv[None]    # [B, D//2]
-    cos = jnp.cos(ang)[:, None].astype(x.dtype)           # [B, 1, D//2]
-    sin = jnp.sin(ang)[:, None].astype(x.dtype)
-    half = D // 2
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin,
-                            x2 * cos + x1 * sin], axis=-1)
+class GPT2ServingAdapter(PagedServingAdapter):
+    """The skeleton over GPT-2: ``params`` is the training
+    ``GPT2LMHeadModel`` tree or the converted (optionally int8) inference
+    tree `convert_gpt2_params` produces."""
+    layer_math = "deepspeed_tpu.models.gpt2_inference"
 
 
-class LlamaServingAdapter:
-    """Paged serving over PACKED LLaMA serving params (the tree
-    convert_llama_serving_params / quantize_llama_serving_params /
-    random_int8_serving_params produce). GQA: the pool holds Hkv heads;
-    the paged attention kernel takes rep = H/Hkv query rows per head."""
-
-    def __init__(self, cfg, sparams, spec: PagedCacheSpec,
-                 quantize_bits: int = 0):
-        if quantize_bits == 8 \
-                and "kernel_q" not in sparams["blk"]["qkv_w"]:
-            from deepspeed_tpu.models.llama_inference import \
-                quantize_llama_serving_params
-            sparams = quantize_llama_serving_params(sparams)
-        self.cfg = cfg
-        self.sparams = sparams
-        self.spec = spec
-        self.weights_q8 = "kernel_q" in sparams["blk"]["qkv_w"]
-        self.cache_q8 = spec.kv_cache_bits == 8
-        assert spec.n_layers == cfg.n_layers
-        assert spec.kv_heads == cfg.kv_heads
-        assert spec.head_dim == cfg.head_dim
-        self._p = {k: v for k, v in sparams.items() if k != "blk"}
-        self._blk = sparams["blk"]
-        self._fns = {}    # per-adapter compiled-fn cache (see GPT-2)
-
-    @property
-    def eos_default(self):
-        return None
-
-    def make_cache(self) -> PagedKVCache:
-        return PagedKVCache(self.spec)
-
-    def max_prompt_len(self):
-        return self.cfg.max_seq_len
-
-    def _tick_fn(self, steps: int = 1):
-        cfg, spec = self.cfg, self.spec
-        key = ("tick", steps)
-        if key in self._fns:
-            return self._fns[key]
-        from deepspeed_tpu.ops.pallas.decode import (
-            ln_qkv_int8_stacked, decode_attention_paged,
-            out_ffn_int8_stacked, matvec_int8_stacked)
-        from deepspeed_tpu.models.llama_inference import _weights
-        E, H, Hkv, D = (cfg.hidden_size, cfg.n_heads, cfg.kv_heads,
-                        cfg.head_dim)
-        Lyr = cfg.n_layers
-        rep = H // Hkv
-        P = spec.page_size
-        eps = cfg.rms_eps
-        cache_q8 = self.cache_q8
-
-        def _rms(x, w):
-            xf = x.astype(jnp.float32)
-            n = xf * jax.lax.rsqrt(
-                jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-            return (n * w.astype(jnp.float32)).astype(x.dtype)
-
-        @functools.partial(jax.jit, donate_argnums=(2,))
-        def tick(p, blk, pool, toks, pos, pt, seeds, idxs0, temps):
-            embed = p["embed"].astype(cfg.dtype)
-            head = p["head"].astype(cfg.dtype)
-            Wq, sq = _weights(blk, "qkv_w", Lyr)
-            Wo, so = _weights(blk, "o_w", Lyr)
-            Wg, sg = _weights(blk, "gate_w", Lyr)
-            Wu, su = _weights(blk, "up_w", Lyr)
-            Wd, sd = _weights(blk, "down_w", Lyr)
-            n1 = blk["norm1"].reshape(Lyr, 1, E)
-            n2 = blk["norm2"].reshape(Lyr, 1, E)
-            B = toks.shape[0]
-
-            def one(carry, t):
-                pool, toks, pos, _ = carry
-                x = embed[toks]
-                blk_ids, rows = _gather_blocks(pt, pos, P)
-
-                def layer(car, l):
-                    x, pool = car
-                    qkv = ln_qkv_int8_stacked(x, n1, None, Wq, sq, None,
-                                              l, eps=eps, norm="rms")
-                    q3 = qkv[:, :H * D].reshape(B, H, D)
-                    k3 = qkv[:, H * D:(H + Hkv) * D].reshape(B, Hkv, D)
-                    v3 = qkv[:, (H + Hkv) * D:].reshape(B, Hkv, D)
-                    q3 = _rope_rows(q3, pos, cfg.rope_theta)
-                    k3 = _rope_rows(k3, pos, cfg.rope_theta)
-                    qg = q3.reshape(B, Hkv, rep, D)
-                    pool = _append_rows(pool, cache_q8, l, blk_ids,
-                                        rows, k3, v3)
-                    if cache_q8:
-                        kc, ks, vc, vs = pool
-                        ctx = decode_attention_paged(
-                            qg, kc, vc, pos, pt, l, k_scale=ks,
-                            v_scale=vs, scale=1.0 / np.sqrt(D))
-                    else:
-                        kc, vc = pool
-                        ctx = decode_attention_paged(
-                            qg, kc, vc, pos, pt, l,
-                            scale=1.0 / np.sqrt(D))
-                    ctx2 = ctx.reshape(B, H * D)
-                    if E * E * Wo.dtype.itemsize <= (6 << 20):
-                        x = out_ffn_int8_stacked(
-                            ctx2, x, Wo, so, None, n2, None, Wg, sg,
-                            None, Wd, sd, None, l, act="swiglu",
-                            eps=eps, norm="rms", w1b_stack=Wu, s1b=su)
-                    else:
-                        x1 = x + matvec_int8_stacked(ctx2, Wo, so, l)
-                        x = out_ffn_int8_stacked(
-                            None, x1, None, None, None, n2, None, Wg,
-                            sg, None, Wd, sd, None, l, act="swiglu",
-                            eps=eps, norm="rms", w1b_stack=Wu, s1b=su,
-                            fuse_proj=False)
-                    return (x, pool), None
-
-                (x, pool), _ = jax.lax.scan(
-                    layer, (x, pool), jnp.arange(Lyr, dtype=jnp.int32))
-                logits = jnp.einsum("be,ve->bv",
-                                    _rms(x, p["norm_scale"]), head)
-                nxt, logits32 = _pick_next(logits, seeds, idxs0 + t,
-                                           temps)
-                return (pool, nxt, pos + 1, logits32), nxt
-
-            logits0 = jnp.zeros((B, cfg.vocab_size), jnp.float32)
-            (pool, _, _, logits32), toks_seq = jax.lax.scan(
-                one, (pool, toks, pos, logits0),
-                jnp.arange(steps, dtype=jnp.int32))
-            return pool, toks_seq, logits32
-
-        self._fns[key] = tick
-        return tick
-
-    def _prefill_fn(self, n_pages: int):
-        cfg, spec = self.cfg, self.spec
-        key = ("prefill", n_pages)
-        if key in self._fns:
-            return self._fns[key]
-        from deepspeed_tpu.ops.attention import dot_product_attention
-        from deepspeed_tpu.models.llama import rope_angles, apply_rope
-        from deepspeed_tpu.models.llama_inference import _weights
-        E, H, Hkv, D = (cfg.hidden_size, cfg.n_heads, cfg.kv_heads,
-                        cfg.head_dim)
-        Lyr = cfg.n_layers
-        P = spec.page_size
-        Sp = n_pages * P
-        eps = cfg.rms_eps
-        cache_q8 = self.cache_q8
-
-        def _rms(x, w):
-            xf = x.astype(jnp.float32)
-            n = xf * jax.lax.rsqrt(
-                jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-            return (n * w.astype(jnp.float32)).astype(x.dtype)
-
-        @functools.partial(jax.jit, donate_argnums=(2,))
-        def prefill(p, blk, pool, ids, length, pages):
-            x = p["embed"][ids].astype(cfg.dtype)    # [1, Sp, E]
-            positions = jnp.arange(Sp)
-            cos, sin = rope_angles(positions, D, cfg.rope_theta)
-            Wq, sq = _weights(blk, "qkv_w", Lyr)
-            Wo, so = _weights(blk, "o_w", Lyr)
-            Wg, sg = _weights(blk, "gate_w", Lyr)
-            Wu, su = _weights(blk, "up_w", Lyr)
-            Wd, sd = _weights(blk, "down_w", Lyr)
-
-            def deq(stack, scale, l):
-                w = stack[l]
-                if stack.dtype == jnp.int8:
-                    return (w.astype(jnp.float32)
-                            * scale[l]).astype(cfg.dtype)
-                return w.astype(cfg.dtype)
-
-            def layer(x, l):
-                u = _rms(x, blk["norm1"][l])
-                qkv = u @ deq(Wq, sq, l)
-                q = qkv[..., :H * D].reshape(1, Sp, H, D) \
-                    .transpose(0, 2, 1, 3)
-                k = qkv[..., H * D:(H + Hkv) * D] \
-                    .reshape(1, Sp, Hkv, D).transpose(0, 2, 1, 3)
-                v = qkv[..., (H + Hkv) * D:] \
-                    .reshape(1, Sp, Hkv, D).transpose(0, 2, 1, 3)
-                q = apply_rope(q, cos, sin)
-                k = apply_rope(k, cos, sin)
-                ctx = dot_product_attention(q, k, v, causal=True)
-                ctx = ctx.transpose(0, 2, 1, 3).reshape(1, Sp, H * D)
-                x = x + ctx @ deq(Wo, so, l)
-                u2 = _rms(x, blk["norm2"][l])
-                h = jax.nn.silu(u2 @ deq(Wg, sg, l)) \
-                    * (u2 @ deq(Wu, su, l))
-                x = x + h @ deq(Wd, sd, l)
-                return x, (k[0], v[0])
-
-            x, (ks, vs) = jax.lax.scan(
-                layer, x, jnp.arange(Lyr, dtype=jnp.int32))
-            pool = _write_prompt_pages(pool, cache_q8, ks, vs, pages, P)
-            xl = x[0, length - 1]
-            logits = _rms(xl, p["norm_scale"]) \
-                @ p["head"].astype(cfg.dtype).T
-            return pool, logits.astype(jnp.float32)
-
-        self._fns[key] = prefill
-        return prefill
-
-    def _prefill_suffix_fn(self, n_suf_pages: int, n_pre_pages: int):
-        """Suffix-only prefill (prefix-cache hits) — LLaMA twin of the
-        GPT-2 variant: RoPE at absolute positions, RMS norms, GQA
-        attention over [shared prefix ++ suffix] K/V."""
-        cfg, spec = self.cfg, self.spec
-        key = ("prefill_sfx", n_suf_pages, n_pre_pages)
-        if key in self._fns:
-            return self._fns[key]
-        from deepspeed_tpu.ops.attention import dot_product_attention
-        from deepspeed_tpu.models.llama import rope_angles, apply_rope
-        from deepspeed_tpu.models.llama_inference import _weights
-        E, H, Hkv, D = (cfg.hidden_size, cfg.n_heads, cfg.kv_heads,
-                        cfg.head_dim)
-        Lyr = cfg.n_layers
-        P = spec.page_size
-        MAXP = spec.max_pages_per_slot
-        Ssuf = n_suf_pages * P
-        LPRE = n_pre_pages * P
-        eps = cfg.rms_eps
-        cache_q8 = self.cache_q8
-
-        def _rms(x, w):
-            xf = x.astype(jnp.float32)
-            n = xf * jax.lax.rsqrt(
-                jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-            return (n * w.astype(jnp.float32)).astype(x.dtype)
-
-        @functools.partial(jax.jit, donate_argnums=(2,))
-        def prefill_sfx(p, blk, pool, ids, length, start, pt_row):
-            x = p["embed"][ids].astype(cfg.dtype)    # [1, Ssuf, E]
-            pos_q = start + jnp.arange(Ssuf, dtype=jnp.int32)
-            cos, sin = rope_angles(pos_q, D, cfg.rope_theta)
-            Wq, sq = _weights(blk, "qkv_w", Lyr)
-            Wo, so = _weights(blk, "o_w", Lyr)
-            Wg, sg = _weights(blk, "gate_w", Lyr)
-            Wu, su = _weights(blk, "up_w", Lyr)
-            Wd, sd = _weights(blk, "down_w", Lyr)
-            pre_ids = pt_row[:n_pre_pages]
-            bias = _suffix_attn_bias(start, pos_q, LPRE)
-
-            def deq(stack, scale, l):
-                w = stack[l]
-                if stack.dtype == jnp.int8:
-                    return (w.astype(jnp.float32)
-                            * scale[l]).astype(cfg.dtype)
-                return w.astype(cfg.dtype)
-
-            def layer(x, l):
-                u = _rms(x, blk["norm1"][l])
-                qkv = u @ deq(Wq, sq, l)
-                q = qkv[..., :H * D].reshape(1, Ssuf, H, D) \
-                    .transpose(0, 2, 1, 3)
-                k = qkv[..., H * D:(H + Hkv) * D] \
-                    .reshape(1, Ssuf, Hkv, D).transpose(0, 2, 1, 3)
-                v = qkv[..., (H + Hkv) * D:] \
-                    .reshape(1, Ssuf, Hkv, D).transpose(0, 2, 1, 3)
-                q = apply_rope(q, cos, sin)
-                k = apply_rope(k, cos, sin)
-                kpre, vpre = _gather_prefix_kv(pool, cache_q8, l,
-                                               pre_ids, cfg.dtype)
-                ka = jnp.concatenate([kpre[None], k], axis=2)
-                va = jnp.concatenate([vpre[None], v], axis=2)
-                ctx = dot_product_attention(q, ka, va, bias=bias)
-                ctx = ctx.transpose(0, 2, 1, 3).reshape(1, Ssuf, H * D)
-                x = x + ctx @ deq(Wo, so, l)
-                u2 = _rms(x, blk["norm2"][l])
-                h = jax.nn.silu(u2 @ deq(Wg, sg, l)) \
-                    * (u2 @ deq(Wu, su, l))
-                x = x + h @ deq(Wd, sd, l)
-                return x, (k[0], v[0])
-
-            x, (ks, vs) = jax.lax.scan(
-                layer, x, jnp.arange(Lyr, dtype=jnp.int32))
-            valid = pos_q < length
-            blks = jnp.where(
-                valid, pt_row[jnp.clip(pos_q // P, 0, MAXP - 1)],
-                jnp.int32(0))
-            pool_out = _write_suffix_rows(pool, cache_q8, ks, vs,
-                                          blks, pos_q % P)
-            xl = x[0, length - 1 - start]
-            logits = _rms(xl, p["norm_scale"]) \
-                @ p["head"].astype(cfg.dtype).T
-            return pool_out, logits.astype(jnp.float32)
-
-        self._fns[key] = prefill_sfx
-        return prefill_sfx
-
-    def _verify_fn(self, n_rows: int):
-        """Speculative verification — LLaMA twin: GQA query rows ride
-        the multi-query paged kernel STEP-major (row = step * rep + r,
-        rows_per_step = rep)."""
-        cfg, spec = self.cfg, self.spec
-        key = ("verify", n_rows)
-        if key in self._fns:
-            return self._fns[key]
-        from deepspeed_tpu.ops.pallas.decode import (
-            ln_qkv_int8_stacked, decode_attention_paged,
-            out_ffn_int8_stacked, matvec_int8_stacked)
-        from deepspeed_tpu.models.llama_inference import _weights
-        E, H, Hkv, D = (cfg.hidden_size, cfg.n_heads, cfg.kv_heads,
-                        cfg.head_dim)
-        Lyr = cfg.n_layers
-        rep = H // Hkv
-        P = spec.page_size
-        MAXP = spec.max_pages_per_slot
-        K = n_rows
-        eps = cfg.rms_eps
-        cache_q8 = self.cache_q8
-
-        def _rms(x, w):
-            xf = x.astype(jnp.float32)
-            n = xf * jax.lax.rsqrt(
-                jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-            return (n * w.astype(jnp.float32)).astype(x.dtype)
-
-        @functools.partial(jax.jit, donate_argnums=(2,))
-        def verify(p, blk, pool, toks, pos, pt):
-            embed = p["embed"].astype(cfg.dtype)
-            head = p["head"].astype(cfg.dtype)
-            Wq, sq = _weights(blk, "qkv_w", Lyr)
-            Wo, so = _weights(blk, "o_w", Lyr)
-            Wg, sg = _weights(blk, "gate_w", Lyr)
-            Wu, su = _weights(blk, "up_w", Lyr)
-            Wd, sd = _weights(blk, "down_w", Lyr)
-            n1 = blk["norm1"].reshape(Lyr, 1, E)
-            n2 = blk["norm2"].reshape(Lyr, 1, E)
-            B = toks.shape[0]
-            blk_ids, rows, posf = _verify_append_ids(pos, pt, K, P, MAXP)
-            x = embed[toks].reshape(B * K, E)
-
-            def layer(car, l):
-                x, pool = car
-                qkv = ln_qkv_int8_stacked(x, n1, None, Wq, sq, None,
-                                          l, eps=eps, norm="rms")
-                q3 = qkv[:, :H * D].reshape(B * K, H, D)
-                k3 = qkv[:, H * D:(H + Hkv) * D].reshape(B * K, Hkv, D)
-                v3 = qkv[:, (H + Hkv) * D:].reshape(B * K, Hkv, D)
-                q3 = _rope_rows(q3, posf, cfg.rope_theta)
-                k3 = _rope_rows(k3, posf, cfg.rope_theta)
-                # STEP-major multi-query rows: row j = step * rep + r
-                qg = q3.reshape(B, K, Hkv, rep, D) \
-                    .transpose(0, 2, 1, 3, 4).reshape(B, Hkv, K * rep, D)
-                pool = _append_rows(pool, cache_q8, l, blk_ids,
-                                    rows, k3, v3)
-                if cache_q8:
-                    kc, ks, vc, vs = pool
-                    ctx = decode_attention_paged(
-                        qg, kc, vc, pos, pt, l, k_scale=ks,
-                        v_scale=vs, scale=1.0 / np.sqrt(D),
-                        rows_per_step=rep)
-                else:
-                    kc, vc = pool
-                    ctx = decode_attention_paged(
-                        qg, kc, vc, pos, pt, l,
-                        scale=1.0 / np.sqrt(D), rows_per_step=rep)
-                ctx2 = ctx.reshape(B, Hkv, K, rep, D) \
-                    .transpose(0, 2, 1, 3, 4).reshape(B * K, H * D)
-                if E * E * Wo.dtype.itemsize <= (6 << 20):
-                    x = out_ffn_int8_stacked(
-                        ctx2, x, Wo, so, None, n2, None, Wg, sg,
-                        None, Wd, sd, None, l, act="swiglu",
-                        eps=eps, norm="rms", w1b_stack=Wu, s1b=su)
-                else:
-                    x1 = x + matvec_int8_stacked(ctx2, Wo, so, l)
-                    x = out_ffn_int8_stacked(
-                        None, x1, None, None, None, n2, None, Wg,
-                        sg, None, Wd, sd, None, l, act="swiglu",
-                        eps=eps, norm="rms", w1b_stack=Wu, s1b=su,
-                        fuse_proj=False)
-                return (x, pool), None
-
-            (x, pool), _ = jax.lax.scan(
-                layer, (x, pool), jnp.arange(Lyr, dtype=jnp.int32))
-            logits = jnp.einsum("be,ve->bv",
-                                _rms(x, p["norm_scale"]), head)
-            logits32 = logits.astype(jnp.float32)
-            greedy = jnp.argmax(logits32, axis=-1).astype(jnp.int32)
-            return (pool, greedy.reshape(B, K),
-                    logits32.reshape(B, K, -1))
-
-        self._fns[key] = verify
-        return verify
-
-    def tick(self, pool, toks, pos, pt, seeds, idxs, temps, steps=1):
-        """Run ``steps`` decode steps in ONE dispatch (see the GPT-2
-        twin for the seeds/idxs sampling contract). Returns
-        (pool, tokens [steps, B], last-step logits [B, V])."""
-        return self._tick_fn(steps)(self._p, self._blk, pool, toks, pos,
-                                    pt, seeds, idxs, temps)
-
-    def prefill(self, pool, ids, length, pages):
-        return self._prefill_fn(ids.shape[1] // self.spec.page_size)(
-            self._p, self._blk, pool, ids, length, pages)
-
-    def prefill_suffix(self, pool, ids, length, start, n_pre_pages,
-                       pt_row):
-        return self._prefill_suffix_fn(
-            ids.shape[1] // self.spec.page_size, n_pre_pages)(
-            self._p, self._blk, pool, ids,
-            jnp.asarray(length, jnp.int32), jnp.asarray(start, jnp.int32),
-            jnp.asarray(pt_row))
-
-    def verify(self, pool, toks, pos, pt):
-        return self._verify_fn(toks.shape[1])(
-            self._p, self._blk, pool, jnp.asarray(toks),
-            jnp.asarray(pos), jnp.asarray(pt))
-
-    def copy_block(self, pool, src, dst):
-        return _copy_pool_block(pool, jnp.asarray(src, jnp.int32),
-                                jnp.asarray(dst, jnp.int32))
+class LlamaServingAdapter(PagedServingAdapter):
+    """The skeleton over LLaMA: ``params`` is the PACKED serving tree
+    (convert_llama_serving_params / quantize_llama_serving_params /
+    random_int8_serving_params). GQA: the pool holds Hkv heads."""
+    layer_math = "deepspeed_tpu.models.llama_inference"
