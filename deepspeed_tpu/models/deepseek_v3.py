@@ -88,8 +88,12 @@ from deepspeed_tpu.models.laguna import (remat_block, stack_remat_policy,
 from deepspeed_tpu.models.llama import RMSNorm, rope_angles
 from deepspeed_tpu.moe.dropless import (CHOICE_BIAS, HELD_STAT_GAUGES,
                                         STAT_GAUGES, DroplessMoE)
+from deepspeed_tpu.moe.dropless import inflight_row_bytes as moe_inflight
 from deepspeed_tpu.moe.dropless import remat_row_bytes as moe_row_bytes
 from deepspeed_tpu.ops.attention import dot_product_attention
+from deepspeed_tpu.ops.pallas.flash_attention import bwd_dq_slab_rows
+from deepspeed_tpu.runtime.remat_budget import (attention_inflight,
+                                                mlp_inflight)
 from deepspeed_tpu.telemetry.spans import annotate
 
 
@@ -470,6 +474,28 @@ def remat_row_bytes(cfg):
     return total
 
 
+def remat_inflight_row_bytes(cfg, seq_len):
+    """Bytes a row the widest branch of a block holds between its
+    recomputation and the end of its backward: what
+    ``models/laguna.stack_remat_policy`` reserves beside the block inputs.
+    Latent attention's k and v stand expanded, a head each, as q does."""
+    b = jnp.dtype(cfg.dtype).itemsize
+    heads = cfg.num_attention_heads
+    q, v = heads * cfg.qk_head_dim, heads * cfg.v_head_dim
+    blocks = cfg.num_hidden_layers + cfg.num_nextn_predict_layers
+    return max(
+        attention_inflight(q, v, q + v, b, bwd_dq_slab_rows(
+            seq_len, cfg.qk_head_dim, cfg.v_head_dim, b)),
+        mlp_inflight(cfg.intermediate_size, b)
+        if cfg.first_k_dense_replace else 0,
+        moe_inflight(
+            cfg.hidden_size, cfg.moe_intermediate_size,
+            cfg.num_experts_per_tok, cfg.n_routed_experts,
+            cfg.experts_held or cfg.n_routed_experts,
+            cfg.n_shared_experts * cfg.moe_intermediate_size, itemsize=b)
+        if cfg.first_k_dense_replace < blocks else 0)
+
+
 class DeepseekV3ForCausalLM(nn.Module):
     """Decoder-only LM; ``labels`` with ``loss_chunk`` takes the fused
     chunked head + loss (``models/gpt2.chunked_lm_loss``)."""
@@ -510,7 +536,8 @@ class DeepseekV3ForCausalLM(nn.Module):
         policy = stack_remat_policy(
             cfg, input_ids.size,
             cfg.num_hidden_layers + cfg.num_nextn_predict_layers,
-            remat_row_bytes(cfg), streams=n)
+            remat_row_bytes(cfg),
+            remat_inflight_row_bytes(cfg, input_ids.shape[1]), streams=n)
 
         def layers(x, names, sparse):
             """``x`` [B, S, C] through the blocks ``names``: copied into the

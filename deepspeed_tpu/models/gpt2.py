@@ -386,7 +386,7 @@ def _remat_policy(name):
 REMAT_BASE_NAMES = ("moe_experts", "flash_o", "flash_lse")
 # ... and what it keeps besides while the bytes fit, dearest a byte first
 REMAT_CANDIDATES = ("moe_scores", "attn_proj", "mlp_proj", "qkv", "mixer_in",
-                    "mlp_fc")
+                    "scan_states", "mlp_fc")
 
 
 def block_remat_policy(name=None, **stack):

@@ -47,10 +47,14 @@ from jax.ad_checkpoint import checkpoint_name
 
 from deepspeed_tpu.models.gpt2 import _embed_lookup, chunked_lm_loss, lm_loss
 from deepspeed_tpu.models.laguna import (FULL, LagunaAttention, _dense,
+                                         attention_inflight_row_bytes,
                                          qkv_row_bytes, remat_block,
                                          stack_remat_policy)
 from deepspeed_tpu.models.llama import RMSNorm
-from deepspeed_tpu.models.nemotron_h import Mamba2Mixer, mixer_in_row_bytes
+from deepspeed_tpu.models.nemotron_h import (Mamba2Mixer,
+                                             mixer_inflight_row_bytes,
+                                             mixer_row_bytes)
+from deepspeed_tpu.runtime.remat_budget import mlp_inflight
 from deepspeed_tpu.telemetry.spans import annotate
 
 MAMBA, ATTENTION = "mamba", "attention"
@@ -211,7 +215,7 @@ def remat_row_bytes(cfg):
     it}: what ``models/laguna.stack_remat_policy`` weighs against its
     budget."""
     b = jnp.dtype(cfg.dtype).itemsize
-    each = {MAMBA: {"mixer_in": mixer_in_row_bytes(cfg)},
+    each = {MAMBA: mixer_row_bytes(cfg),
             ATTENTION: {"qkv": qkv_row_bytes(cfg, cfg.num_attention_heads)}}
     total = collections.Counter()
     for kind in cfg.layer_types:
@@ -219,6 +223,18 @@ def remat_row_bytes(cfg):
         total.update({"attn_proj": b * cfg.hidden_size,
                       "mlp_fc": 2 * b * cfg.shared_intermediate_size})
     return total
+
+
+def remat_inflight_row_bytes(cfg, seq_len):
+    """Bytes a row the widest branch of the widest layer holds between its
+    recomputation and the end of its backward: what
+    ``models/laguna.stack_remat_policy`` reserves beside the block inputs."""
+    each = {MAMBA: mixer_inflight_row_bytes(cfg),
+            ATTENTION: attention_inflight_row_bytes(
+                cfg, cfg.num_attention_heads, seq_len)}
+    return max(mlp_inflight(cfg.shared_intermediate_size,
+                            jnp.dtype(cfg.dtype).itemsize),
+               *(each[kind] for kind in cfg.layer_types))
 
 
 class GraniteHybridForCausalLM(nn.Module):
@@ -237,9 +253,9 @@ class GraniteHybridForCausalLM(nn.Module):
         with annotate("ds_embed"):
             x = (_embed_lookup(embed, input_ids)
                  * cfg.embedding_multiplier).astype(cfg.dtype)
-        policy = stack_remat_policy(cfg, input_ids.size,
-                                    len(cfg.layer_types),
-                                    remat_row_bytes(cfg))
+        policy = stack_remat_policy(
+            cfg, input_ids.size, len(cfg.layer_types), remat_row_bytes(cfg),
+            remat_inflight_row_bytes(cfg, input_ids.shape[1]))
         for i, kind in enumerate(cfg.layer_types):
             x = remat_block(cfg, self, f"layer_{i}", GraniteHybridBlock,
                             policy)(cfg, kind, name=f"layer_{i}")(x)

@@ -50,8 +50,12 @@ from deepspeed_tpu.models.gpt2 import (_embed_lookup, block_remat_policy,
 from deepspeed_tpu.models.llama import RMSNorm, apply_rope, rope_angles
 from deepspeed_tpu.moe.dropless import (HELD_STAT_GAUGES, STAT_GAUGES,
                                         DroplessMoE)
+from deepspeed_tpu.moe.dropless import inflight_row_bytes as moe_inflight
 from deepspeed_tpu.moe.dropless import remat_row_bytes as moe_row_bytes
 from deepspeed_tpu.ops.attention import dot_product_attention
+from deepspeed_tpu.ops.pallas.flash_attention import bwd_dq_slab_rows
+from deepspeed_tpu.runtime.remat_budget import (attention_inflight,
+                                                mlp_inflight)
 from deepspeed_tpu.telemetry.spans import annotate
 
 FULL, SLIDING = "full_attention", "sliding_attention"
@@ -348,20 +352,55 @@ def remat_row_bytes(cfg):
     return total
 
 
-def stack_remat_policy(cfg, rows, layers, row_bytes, streams=1):
+def attention_inflight_row_bytes(cfg, heads, seq_len, windowed=False):
+    """Bytes a row the backward of one ``LagunaAttention`` layer of
+    ``heads`` query heads holds in flight over ``seq_len`` rows
+    (``runtime/remat_budget.attention_inflight``); ``windowed``: a layer
+    whose window is shorter than the sequence runs the window kernels,
+    which leave no float32 dq partials."""
+    b = jnp.dtype(cfg.dtype).itemsize
+    D = cfg.head_dim
+    return attention_inflight(
+        heads * D, heads * D, 2 * cfg.num_key_value_heads * D, b,
+        0.0 if windowed else bwd_dq_slab_rows(seq_len, D, D, b),
+        gated=cfg.gating)
+
+
+def remat_inflight_row_bytes(cfg, seq_len):
+    """Bytes a row the widest branch of the widest layer holds between its
+    recomputation and the end of its backward: what ``stack_remat_policy``
+    reserves beside the block inputs."""
+    b = jnp.dtype(cfg.dtype).itemsize
+    return max(max(
+        attention_inflight_row_bytes(
+            cfg, heads, seq_len,
+            kind == SLIDING and cfg.sliding_window < seq_len),
+        mlp_inflight(cfg.intermediate_size, b) if mlp == DENSE
+        else moe_inflight(
+            cfg.hidden_size, cfg.moe_intermediate_size,
+            cfg.num_experts_per_tok, cfg.num_experts,
+            cfg.experts_held or cfg.num_experts,
+            cfg.shared_expert_intermediate_size, itemsize=b))
+        for kind, heads, mlp in cfg.layer_kinds)
+
+
+def stack_remat_policy(cfg, rows, layers, row_bytes, inflight_row_bytes,
+                       streams=1):
     """The ONE policy object a stack's rematted blocks share (None without
     remat): ``models/gpt2.block_remat_policy`` over the stack's figures —
     ``rows`` in flight through ``layers`` blocks whose input is ``streams``
     residual streams of ``cfg.hidden_size``, ``row_bytes`` a model's
-    ``remat_row_bytes``. One object, so a name is kept for all its layers
-    or none, and JAX makes one copy of a ``jax.jit`` function the blocks
-    call (it keys that on the policy OBJECT: PERF.md Findings PR 58)."""
+    ``remat_row_bytes`` and ``inflight_row_bytes`` its
+    ``remat_inflight_row_bytes``. One object, so a name is kept for all its
+    layers or none, and JAX makes one copy of a ``jax.jit`` function the
+    blocks call (it keys that on the policy OBJECT: PERF.md Findings PR
+    58)."""
     if not cfg.remat:
         return None
     return block_remat_policy(
         cfg.remat_policy, rows=rows, hidden=cfg.hidden_size, layers=layers,
         itemsize=jnp.dtype(cfg.dtype).itemsize, row_bytes=row_bytes,
-        streams=streams)
+        inflight_row_bytes=inflight_row_bytes, streams=streams)
 
 
 def remat_block(cfg, parent, name, block=None, policy=None):
@@ -421,8 +460,9 @@ class LagunaForCausalLM(nn.Module):
         with annotate("ds_embed"):
             x = _embed_lookup(embed, input_ids).astype(cfg.dtype)
         rope = rope_tables(cfg, jnp.arange(input_ids.shape[1]))
-        policy = stack_remat_policy(cfg, input_ids.size, len(kinds),
-                                    remat_row_bytes(cfg))
+        policy = stack_remat_policy(
+            cfg, input_ids.size, len(kinds), remat_row_bytes(cfg),
+            remat_inflight_row_bytes(cfg, input_ids.shape[1]))
         for i in range(lead):
             x = remat_block(cfg, self, f"lead_{i}", policy=policy)(
                 cfg, *kinds[i], name=f"lead_{i}")(x, rope)

@@ -55,14 +55,19 @@ from jax.ad_checkpoint import checkpoint_name
 
 from deepspeed_tpu.models.gpt2 import _embed_lookup, chunked_lm_loss, lm_loss
 from deepspeed_tpu.models.laguna import (FULL, LagunaAttention,
+                                         attention_inflight_row_bytes,
                                          qkv_row_bytes, remat_block,
                                          stack_remat_policy)
 from deepspeed_tpu.models.llama import RMSNorm
 from deepspeed_tpu.moe.dropless import (CHOICE_BIAS, HELD_STAT_GAUGES,
                                         STAT_GAUGES, DroplessMoE)
+from deepspeed_tpu.moe.dropless import inflight_row_bytes as moe_inflight
 from deepspeed_tpu.moe.dropless import remat_row_bytes as moe_row_bytes
 from deepspeed_tpu.ops.mixer_elementwise import conv_act, gated_group_norm
+from deepspeed_tpu.ops.pallas.scan_residuals import SCAN_NAME
+from deepspeed_tpu.ops.pallas.ssd import kept_row_bytes as scan_kept_row_bytes
 from deepspeed_tpu.ops.ssd import ssd_scan
+from deepspeed_tpu.runtime.remat_budget import projection_inflight
 from deepspeed_tpu.telemetry.spans import annotate
 
 MAMBA, EXPERTS, ATTENTION = "M", "E", "*"
@@ -270,6 +275,22 @@ def mixer_in_row_bytes(cfg):
         cfg.d_inner + cfg.conv_dim + cfg.mamba_num_heads)
 
 
+def mixer_row_bytes(cfg):
+    """{checkpoint name: bytes a row} of one ``Mamba2Mixer`` layer: its
+    input projection, and what the scan's forward rule writes."""
+    return {"mixer_in": mixer_in_row_bytes(cfg),
+            SCAN_NAME: scan_kept_row_bytes(
+                cfg.mamba_num_heads, cfg.mamba_head_dim, cfg.ssm_state_size,
+                cfg.chunk_size, jnp.dtype(cfg.dtype).itemsize)}
+
+
+def mixer_inflight_row_bytes(cfg):
+    """Bytes a row one ``Mamba2Mixer`` layer's backward holds in flight:
+    ``in_proj``'s output and its cotangent."""
+    b = jnp.dtype(cfg.dtype).itemsize
+    return projection_inflight(mixer_in_row_bytes(cfg) // b, b)
+
+
 def remat_row_bytes(cfg):
     """{checkpoint name: bytes a row, summed over the layers that carry
     it}: what ``models/laguna.stack_remat_policy`` weighs against its
@@ -277,7 +298,7 @@ def remat_row_bytes(cfg):
     b = jnp.dtype(cfg.dtype).itemsize
     # a layer is ONE branch: nothing in its backward pass reads the
     # branch's output (``attn_proj``), kept or not
-    each = {MAMBA: {"mixer_in": mixer_in_row_bytes(cfg)},
+    each = {MAMBA: mixer_row_bytes(cfg),
             ATTENTION: {"qkv": qkv_row_bytes(cfg, cfg.num_attention_heads)},
             EXPERTS: moe_row_bytes(
                 cfg.n_routed_experts, cfg.n_shared_experts
@@ -287,6 +308,23 @@ def remat_row_bytes(cfg):
     for kind in cfg.plan:
         total.update(each[kind])
     return total
+
+
+def remat_inflight_row_bytes(cfg, seq_len):
+    """Bytes a row the widest layer holds between its recomputation and the
+    end of its backward: what ``models/laguna.stack_remat_policy`` reserves
+    beside the block inputs."""
+    each = {MAMBA: mixer_inflight_row_bytes(cfg),
+            ATTENTION: attention_inflight_row_bytes(
+                cfg, cfg.num_attention_heads, seq_len),
+            EXPERTS: moe_inflight(
+                cfg.hidden_size, cfg.moe_intermediate_size,
+                cfg.num_experts_per_tok, cfg.n_routed_experts,
+                cfg.experts_held or cfg.n_routed_experts,
+                cfg.n_shared_experts
+                * cfg.moe_shared_expert_intermediate_size, gated=False,
+                itemsize=jnp.dtype(cfg.dtype).itemsize)}
+    return max(each[kind] for kind in cfg.plan)
 
 
 class NemotronHBlock(nn.Module):
@@ -351,8 +389,9 @@ class NemotronHForCausalLM(nn.Module):
                            cfg.param_dtype)
         with annotate("ds_embed"):
             x = _embed_lookup(embed, input_ids).astype(cfg.dtype)
-        policy = stack_remat_policy(cfg, input_ids.size, len(cfg.plan),
-                                    remat_row_bytes(cfg))
+        policy = stack_remat_policy(
+            cfg, input_ids.size, len(cfg.plan), remat_row_bytes(cfg),
+            remat_inflight_row_bytes(cfg, input_ids.shape[1]))
         for i, kind in enumerate(cfg.plan):
             x = remat_block(cfg, self, f"layer_{i}", NemotronHBlock, policy)(
                 cfg, kind, name=f"layer_{i}")(x)

@@ -60,9 +60,16 @@ from deepspeed_tpu.models.laguna import stack_remat_policy
 from deepspeed_tpu.models.llama import apply_rope, rope_angles
 from deepspeed_tpu.moe.dropless import (HELD_STAT_GAUGES, STAT_GAUGES,
                                         DroplessMoE)
+from deepspeed_tpu.moe.dropless import inflight_row_bytes as moe_inflight
 from deepspeed_tpu.moe.dropless import remat_row_bytes as moe_row_bytes
 from deepspeed_tpu.ops.attention import dot_product_attention
-from deepspeed_tpu.ops.gated_delta import gated_delta_rule
+from deepspeed_tpu.ops.gated_delta import CHUNK, gated_delta_rule
+from deepspeed_tpu.ops.pallas.flash_attention import bwd_dq_slab_rows
+from deepspeed_tpu.ops.pallas.gated_delta import \
+    kept_row_bytes as scan_kept_row_bytes
+from deepspeed_tpu.ops.pallas.scan_residuals import SCAN_NAME
+from deepspeed_tpu.runtime.remat_budget import (attention_inflight,
+                                                projection_inflight)
 from deepspeed_tpu.ops.mixer_elementwise import conv_act, gated_group_norm
 from deepspeed_tpu.telemetry.spans import annotate
 
@@ -292,17 +299,24 @@ class Qwen3NextBlock(nn.Module):
         return x + out
 
 
+def _mixer_in_cols(cfg):
+    """Columns ``GatedDeltaNet``'s two input projections write a row."""
+    return 2 * cfg.linear_num_key_heads * cfg.linear_key_head_dim \
+        + 2 * cfg.linear_num_value_heads * (cfg.linear_value_head_dim + 1)
+
+
 def remat_row_bytes(cfg):
     """{checkpoint name: bytes a row, summed over the layers that carry
     it}: what ``models/laguna.stack_remat_policy`` weighs against its
     budget."""
     b = jnp.dtype(cfg.dtype).itemsize
-    key = cfg.linear_num_key_heads * cfg.linear_key_head_dim
-    val = cfg.linear_num_value_heads * cfg.linear_value_head_dim
     q = cfg.num_attention_heads * cfg.head_dim
     kv = cfg.num_key_value_heads * cfg.head_dim
-    each = {"linear": {"mixer_in": b * (2 * key + 2 * val
-                                        + 2 * cfg.linear_num_value_heads)},
+    each = {"linear": {"mixer_in": b * _mixer_in_cols(cfg),
+                       SCAN_NAME: scan_kept_row_bytes(
+                           cfg.linear_num_value_heads,
+                           cfg.linear_key_head_dim,
+                           cfg.linear_value_head_dim, CHUNK, b)},
             # q with its gate and k as projected, q, k and v as the kernel
             # reads them
             "attention": {"qkv": b * (3 * q + 3 * kv)}}
@@ -314,6 +328,26 @@ def remat_row_bytes(cfg):
             cfg.num_experts, cfg.shared_expert_intermediate_size,
             itemsize=b))
     return total
+
+
+def remat_inflight_row_bytes(cfg, seq_len):
+    """Bytes a row the widest branch of the widest layer holds between its
+    recomputation and the end of its backward: what
+    ``models/laguna.stack_remat_policy`` reserves beside the block inputs.
+    The gated attention's q is projected with its gate (an output gate)."""
+    b = jnp.dtype(cfg.dtype).itemsize
+    q = cfg.num_attention_heads * cfg.head_dim
+    each = {"linear": projection_inflight(_mixer_in_cols(cfg), b),
+            "attention": attention_inflight(
+                q, q, 2 * cfg.num_key_value_heads * cfg.head_dim, b,
+                bwd_dq_slab_rows(seq_len, cfg.head_dim, cfg.head_dim, b),
+                gated=True)}
+    return max(
+        moe_inflight(cfg.hidden_size, cfg.moe_intermediate_size,
+                     cfg.num_experts_per_tok, cfg.num_experts,
+                     cfg.experts_held or cfg.num_experts,
+                     cfg.shared_expert_intermediate_size, itemsize=b),
+        *(each[kind] for kind in cfg.layer_kinds))
 
 
 class _Period(nn.Module):
@@ -372,9 +406,9 @@ class Qwen3NextForCausalLM(nn.Module):
                            "intermediates": 0},
             split_rngs={"params": True}, in_axes=(nn.broadcast,),
             length=cfg.n_periods)
-        policy = stack_remat_policy(cfg, input_ids.size,
-                                    cfg.num_hidden_layers,
-                                    remat_row_bytes(cfg))
+        policy = stack_remat_policy(
+            cfg, input_ids.size, cfg.num_hidden_layers, remat_row_bytes(cfg),
+            remat_inflight_row_bytes(cfg, input_ids.shape[1]))
         x, _ = scanned(cfg, policy, name="layers")(x, positions)
         x = ZeroCentredRMSNorm(eps=cfg.rms_norm_eps, dtype=cfg.dtype,
                                param_dtype=cfg.param_dtype, name="norm")(x)

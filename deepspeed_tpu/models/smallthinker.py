@@ -38,11 +38,13 @@ import flax.linen as nn
 
 from deepspeed_tpu.models.gpt2 import _embed_lookup, chunked_lm_loss, lm_loss
 from deepspeed_tpu.models.laguna import (FULL, SLIDING, LagunaAttention,
+                                         attention_inflight_row_bytes,
                                          qkv_row_bytes, remat_block,
                                          rope_tables, stack_remat_policy)
 from deepspeed_tpu.models.llama import RMSNorm
 from deepspeed_tpu.moe.dropless import (HELD_STAT_GAUGES, STAT_GAUGES,
                                         DroplessMoE)
+from deepspeed_tpu.moe.dropless import inflight_row_bytes as moe_inflight
 from deepspeed_tpu.moe.dropless import remat_row_bytes as moe_row_bytes
 from deepspeed_tpu.telemetry.spans import annotate
 
@@ -186,6 +188,22 @@ def remat_row_bytes(cfg):
     return {name: cfg.num_hidden_layers * v for name, v in layer.items()}
 
 
+def remat_inflight_row_bytes(cfg, seq_len):
+    """Bytes a row the widest branch of a layer holds between its
+    recomputation and the end of its backward: what
+    ``models/laguna.stack_remat_policy`` reserves beside the block inputs."""
+    return max(
+        moe_inflight(cfg.hidden_size, cfg.moe_ffn_hidden_size,
+                     cfg.moe_num_active_primary_experts,
+                     cfg.moe_num_primary_experts,
+                     cfg.experts_held or cfg.moe_num_primary_experts,
+                     itemsize=jnp.dtype(cfg.dtype).itemsize),
+        *(attention_inflight_row_bytes(
+            cfg, cfg.num_attention_heads, seq_len,
+            kind == SLIDING and cfg.sliding_window < seq_len)
+          for kind in cfg.layer_types))
+
+
 class _Period(nn.Module):
     """The layer scan's body: one period of unlike blocks."""
     config: SmallThinkerConfig
@@ -230,8 +248,9 @@ class SmallThinkerForCausalLM(nn.Module):
                            "intermediates": 0},
             split_rngs={"params": True}, in_axes=(nn.broadcast,),
             length=n_periods)
-        policy = stack_remat_policy(cfg, input_ids.size, len(kinds),
-                                    remat_row_bytes(cfg))
+        policy = stack_remat_policy(
+            cfg, input_ids.size, len(kinds), remat_row_bytes(cfg),
+            remat_inflight_row_bytes(cfg, input_ids.shape[1]))
         x, _ = scanned(cfg, policy, name="layers")(x, rope)
         for j in range(tail):
             x = remat_block(cfg, self, f"tail_{j}", SmallThinkerBlock, policy)(

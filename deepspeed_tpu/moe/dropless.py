@@ -522,3 +522,18 @@ def remat_row_bytes(num_experts, shared_d_ff=0, gated=True, itemsize=2):
     took (PERF.md Findings PR 61)."""
     return {"moe_scores": 4 * num_experts,
             "mlp_fc": (2 if gated else 1) * itemsize * shared_d_ff}
+
+
+def inflight_row_bytes(hidden, d_ff, top_k, num_experts, held, shared_d_ff=0,
+                       gated=True, itemsize=2):
+    """Bytes a token one ``DroplessMoE`` layer's backward holds in flight
+    (what ``runtime/remat_budget.reserve_bytes`` counts beside the kept
+    names): the one slab of held rows — ``_HELD_ROWS_SLACK`` x the mean
+    share of the ``top_k`` assignments a token, all of them where every
+    expert is held — as gathered and as the experts return it (the second
+    in float32 for the weighted sum), with the rows' pre-activations and
+    the activation's cotangent, and the shared expert's the same."""
+    per = (3 if gated else 2) * itemsize
+    rows = top_k * min(1.0, _HELD_ROWS_SLACK * held / num_experts)
+    return int(rows * ((itemsize + 4) * hidden + per * d_ff)) \
+        + per * shared_d_ff

@@ -2128,3 +2128,20 @@ def flash_attention_bse(q, k=None, v=None, *, heads, causal=False,
     _note_plan(S, D, q.dtype, scale, causal, *blocks, 0, plan[0])
     return _flash_attention_cols(operands, heads, scale, causal, *blocks,
                                  bool(interpret))
+
+
+def bwd_dq_slab_rows(S, D, Dv, itemsize):
+    """Float32 rows of dq partials a causal backward over ``S`` rows leaves
+    in HBM for every sequence row of a head, until ``_sum_dq_slabs`` has
+    added them: the (block, chunk) pairs the chunked backward walks x the
+    block's rows over S — 2.5 at S 16,384 in chunks of 4,096 — and 0 where
+    the row is VMEM-resident whole or the plan is one chunk (dq leaves the
+    kernel in the operands' dtype). What ``runtime/remat_budget.py`` counts
+    of an attention branch in flight; the plan is ``flash_attention``'s."""
+    if Dv == D and S * D * itemsize <= _UNCHUNKED_ROW_BYTES:
+        return 0.0
+    block = _pick_block(S, None, False, False)
+    chunk = block and _pick_chunk(S, D, Dv, itemsize, block, block)
+    if not chunk or chunk == S:
+        return 0.0
+    return len(_pair_walk(S, block, chunk, True, True)[0]) * block / S

@@ -27,14 +27,17 @@ the XLA form lays out in HBM for all chunks at once — the decay mask,
   multiplies ``k k^T`` after the matmul, in float32 (two value heads share
   one key head's ``k k^T`` and ``q k^T``), where the XLA form rounds
   ``beta k`` to the inputs' dtype first.
-- **Backward.** The forward RULE (what a block's remat runs as the
-  recomputation; the forward pass under remat runs the primal call, which
-  writes o alone: ``optimize_remat``) also writes the state every chunk
+- **Backward.** The forward RULE also writes the state every chunk
   starts from, in the inputs' dtype ([B, Hv, N, Dk, Dv]: 268 MB a layer
   in bf16 at the sizes above), and every chunk's ``T`` in float32 (268 MB
-  as padded tiles), alive from a layer's recomputation to its backward
-  pass. The backward kernel walks the chunks in REVERSE with ``dS`` in
-  VMEM: it builds the chunk's preparation again from the tiles and the
+  as padded tiles), and names them and o ``scan_states``
+  (``scan_residuals.py``). A rematted block that does not keep the name
+  runs the rule as its recomputation — its forward pass runs the primal
+  call, which writes o alone — and the two are alive from a layer's
+  recomputation to its backward pass; one that keeps it
+  (``runtime/remat_budget.py``, where the bytes fit) runs the rule's
+  kernel once, in its forward pass. The backward kernel walks the chunks
+  in REVERSE with ``dS`` in VMEM: it builds the chunk's preparation again from the tiles and the
   kept ``T`` (it never inverts), takes the loop's cotangents, then the
   preparation's (``dT``, ``dL = -T^T dT T^T``, the decay's, the gates')
   while the tiles are resident, and writes dq, dk (summed over a key
@@ -53,6 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.pallas.scan_residuals import named_forward
 from deepspeed_tpu.telemetry.registry import default_registry
 from deepspeed_tpu.telemetry.spans import annotate
 from deepspeed_tpu.utils.logging import logger
@@ -399,21 +403,25 @@ def _backward(q, k, v, G, beta, states, inverses, do, plan, interpret):
 @functools.lru_cache(maxsize=None)
 def _rule(plan, interpret):
     """The custom VJP for one plan: the primal call writes o only, the
-    forward rule also the state every chunk starts from and its T."""
+    forward rule also the state every chunk starts from and its T, the
+    three under ``SCAN_NAME`` (``scan_residuals.named_forward``: what a
+    rematted block that does not keep the name runs twice, and one that
+    keeps it once)."""
+    forward = named_forward(functools.partial(
+        _forward, plan=plan, interpret=interpret))
 
     @jax.custom_vjp
     def rule(q, k, v, G, beta):
         return _forward(q, k, v, G, beta, plan, interpret, keep=False)
 
     def fwd(q, k, v, G, beta):
-        o, states, inverses = _forward(q, k, v, G, beta, plan, interpret,
-                                       keep=True)
+        o, states, inverses = forward(q, k, v, G, beta)
         return o, (q, k, v, G, beta, states, inverses)
 
     def bwd(res, do):
         return _backward(*res, do, plan, interpret)
 
-    rule.defvjp(fwd, bwd, optimize_remat=True)
+    rule.defvjp(fwd, bwd)
     return rule
 
 
@@ -480,3 +488,15 @@ def gated_delta_rule_kernel(q, k, v, g, beta, chunk, interpret):
     o = _rule(plan, bool(interpret))(q, k, v, G, beta)
     with annotate("gdn_scan_prep"):
         return o.reshape(B, padded, Hv, Dv)[:, :S]
+
+
+def kept_row_bytes(value_heads, key_dim, value_dim, chunk, itemsize):
+    """Bytes a token one layer's forward rule writes under ``SCAN_NAME``
+    (what a rematted block that keeps the name holds from its forward pass
+    to its backward): o and a state [key_dim, value_dim] a value head every
+    ``chunk`` tokens in the inputs' dtype, and a float32 ``T`` [chunk,
+    chunk] a head and chunk as the 128-lane tiles it is stored in — 134 +
+    268 + 268 MB a layer at 2 x 8192 tokens, 32 heads of 128 x 128, chunks
+    of 64."""
+    return value_heads * (itemsize * (value_dim + key_dim * value_dim // chunk)
+                          + 4 * max(chunk, 128))

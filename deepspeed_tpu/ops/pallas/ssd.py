@@ -29,12 +29,16 @@ the size they are at 8 heads whatever the group's width.
 - **Roundings** are the XLA form's: state, gates and ``L`` float32; the
   masked ``C B^T``, ``dt x`` and the state cast to the inputs' dtype before
   their matmuls, float32 accumulation.
-- **Backward.** The forward RULE (what a block's remat runs as the
-  recomputation; the primal call writes y alone: ``optimize_remat``) also
-  writes the state every chunk STARTS from, in float32 ([B, H / pack, n, N,
-  pack * P]: 128 chunks x 64 heads x 32 KB = 268 MB a layer at 16,384
-  tokens, 64 heads of 64 x 128), alive from a layer's recomputation to its
-  backward pass, and nothing else. The backward kernel walks the chunks in
+- **Backward.** The forward RULE also writes the state every chunk STARTS
+  from, in float32 ([B, H / pack, n, N, pack * P]: 128 chunks x 64 heads x
+  32 KB = 268 MB a layer at 16,384 tokens, 64 heads of 64 x 128), and
+  nothing else, and names it and y ``scan_states`` (``scan_residuals.py``).
+  A rematted block that does not keep the name runs the rule as its
+  recomputation — its forward pass runs the primal call, which writes y
+  alone — and the states are alive from a layer's recomputation to its
+  backward pass; one that keeps it (``runtime/remat_budget.py``, where the
+  bytes fit) runs the rule's kernel once, in its forward pass. The
+  backward kernel walks the chunks in
   REVERSE with ``dS`` in VMEM, forms the chunk's masks again and writes dx,
   dB, dC (summed over the head block's heads in float32; where a group is
   several head blocks each writes its own float32 part, [B, head blocks a
@@ -53,6 +57,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.pallas.scan_residuals import named_forward
 from deepspeed_tpu.telemetry.registry import default_registry
 from deepspeed_tpu.telemetry.spans import annotate
 from deepspeed_tpu.utils.logging import logger
@@ -405,20 +410,24 @@ def _backward(x, Bm, Cm, dt, a, D, states, dy, plan, interpret):
 @functools.lru_cache(maxsize=None)
 def _rule(plan, interpret):
     """The custom VJP for one plan: the primal call writes y only, the
-    forward rule also the state every chunk starts from."""
+    forward rule also the state every chunk starts from, the two under
+    ``SCAN_NAME`` (``scan_residuals.named_forward``: what a rematted block
+    that does not keep the name runs twice, and one that keeps it once)."""
+    forward = named_forward(functools.partial(
+        _forward, plan=plan, interpret=interpret))
 
     @jax.custom_vjp
     def rule(x, Bm, Cm, dt, a, D):
         return _forward(x, Bm, Cm, dt, a, D, plan, interpret, keep=False)
 
     def fwd(x, Bm, Cm, dt, a, D):
-        y, states = _forward(x, Bm, Cm, dt, a, D, plan, interpret, keep=True)
+        y, states = forward(x, Bm, Cm, dt, a, D)
         return y, (x, Bm, Cm, dt, a, D, states)
 
     def bwd(res, dy):
         return _backward(*res, dy, plan, interpret)
 
-    rule.defvjp(fwd, bwd, optimize_remat=True)
+    rule.defvjp(fwd, bwd)
     return rule
 
 
@@ -477,3 +486,12 @@ def ssd_scan_kernel(x, dt, A, B, C, D, chunk, interpret):
     y = _rule(plan, bool(interpret))(x, B, C, dt, a, D)
     with annotate("ssd_scan_prep"):
         return y.reshape(Bt, padded, H, P)[:, :S]
+
+
+def kept_row_bytes(heads, head_dim, state, chunk, itemsize):
+    """Bytes a token one layer's forward rule writes under ``SCAN_NAME``
+    (what a rematted block that keeps the name holds from its forward pass
+    to its backward): y in the inputs' dtype and a float32 state
+    [state, head_dim] a head every ``chunk`` tokens — 402 MB a layer at
+    16,384 tokens, 64 heads of 64 x 128, chunks of 128."""
+    return heads * head_dim * itemsize + 4 * heads * state * head_dim // chunk

@@ -12,19 +12,38 @@ can see before it compiles:
   to the trace under ``parallel/mesh.layout_pins``. No engine round the
   model, or a device kind ``PROGRAM_HBM_BYTES`` does not know (the CPU):
   zero, and the program is the base set's.
-- **the reserve**: what the program needs beside them and the kept names —
-  every block's input, the base names and the working set of the one block
-  whose backward is in flight with the head's chunk — as ``rows x hidden x
-  itemsize x (layers x streams + RESERVE_BLOCK_WIDTHS)``.
+- **the reserve**: what the program needs beside them and the kept names,
+  counted from the stack's own shapes (``reserve_bytes``): every block's
+  input (``layers x streams`` widths of the residual stream), the ends of
+  the one block whose backward is in flight (``BLOCK_END_WIDTHS`` widths a
+  stream: its input as recomputed and as normed, the cotangent that comes
+  in and the one that goes out), and what the WIDEST branch of the widest
+  block holds between its recomputation and the end of its backward — the
+  model's ``remat_inflight_row_bytes``, put together from the counts below:
+  an attention branch's q, o, their cotangents, dk and dv a query head and
+  the float32 dq partials the chunked backward leaves to be summed
+  (``attention_inflight``), a dense MLP's pre-activations and the
+  activation's cotangent (``mlp_inflight``), a mixer's input projection and
+  its cotangent (``projection_inflight``), the expert layer's slab
+  (``moe/dropless.inflight_row_bytes``). A block's branches follow one
+  another in its backward, so the widest counts, not their sum; the loss
+  head's chunk is gone before the first block's backward starts and is
+  narrower than any of them. Calibrated against the compiled peaks of the
+  seven cells that call with figures (PERF.md Findings PR 64: the count is
+  1.04 ... 2.1 x what the compiler's peak leaves unexplained — 6.9 x on
+  Xing4.0, whose four streams' ends it counts — and never under).
 - **a name's bytes**: the rows in flight times the bytes a row the layers
   that carry the name hold under it (a model's ``remat_row_bytes``).
 
 A name is kept for all its layers or none (the blocks of a stack share one
 policy object). No process state enters: a cell lowers to the same text in
-every process. The gauges ``remat/kept_names``, ``remat/kept_mb`` and
-``remat/budget_mb`` say what the last stack traced took.
+every process. The gauges ``remat/kept_names``, ``remat/kept_mb``,
+``remat/budget_mb`` and ``remat/reserve_mb`` say what the last stack traced
+took, ``remat/scan_states_kept`` whether the scan kernels' forward-rule
+outputs (``ops/pallas/scan_residuals.py``) were among it.
 """
 
+from deepspeed_tpu.ops.pallas.scan_residuals import SCAN_NAME
 from deepspeed_tpu.parallel import mesh as mesh_lib
 from deepspeed_tpu.telemetry.registry import default_registry
 from deepspeed_tpu.utils.logging import log_dist
@@ -35,13 +54,10 @@ from deepspeed_tpu.utils.logging import log_dist
 PROGRAM_HBM_BYTES = {"TPU v5 lite": 16_911_433_728}
 # left over under that once the names are kept
 HEADROOM_BYTES = 1_000_000_000
-# block-widths (rows x hidden x itemsize) a program holds at its peak beside
-# the engine's bytes, one block input a layer and what its blocks keep:
-# calibrated on the compiled peaks of the eight cells that remat block by
-# block, names kept (PERF.md Findings PR 61: 3.4 ... 46.1, the worst covered;
-# a block of attention heads four times the hidden size with float32 dq
-# slabs, or a layer scan's stacked copies, is what it has to hold)
-RESERVE_BLOCK_WIDTHS = 47
+# widths of the residual stream (a stream) a block's backward holds at its
+# ends: the block's input as recomputed and as its norm left it, the
+# cotangent that comes in and the one that goes out
+BLOCK_END_WIDTHS = 4
 
 
 def free_bytes(device_kind, held_bytes):
@@ -52,12 +68,39 @@ def free_bytes(device_kind, held_bytes):
                - int(held_bytes))
 
 
-def reserve_bytes(rows, hidden, layers, itemsize, streams=1):
-    """What the base program holds at its peak beside the engine's: a block
-    input a layer (``streams`` residual streams wide) and
-    ``RESERVE_BLOCK_WIDTHS`` block-widths more."""
-    return rows * hidden * itemsize * (layers * streams
-                                       + RESERVE_BLOCK_WIDTHS)
+def attention_inflight(q_cols, v_cols, kv_cols, itemsize, dq_slab_rows=0.0,
+                       gated=False):
+    """Bytes a row an attention branch's backward holds: q, its cotangent
+    and dk a query head (``q_cols`` = query heads x the q.k width each), o,
+    its cotangent and dv a query head (``v_cols`` = query heads x the value
+    width; a second o and cotangent where an output gate multiplies it), k
+    and v as stored (``kv_cols``), and the float32 dq partials of
+    ``dq_slab_rows`` rows a sequence row
+    (``ops/pallas/flash_attention.bwd_dq_slab_rows``)."""
+    return itemsize * (3 * q_cols + (5 if gated else 3) * v_cols + kv_cols) \
+        + int(4 * dq_slab_rows * q_cols)
+
+
+def mlp_inflight(d_ff, itemsize, gated=True):
+    """Bytes a row a dense MLP's backward holds: its pre-activations (two
+    where ``gated``) and the activation's cotangent."""
+    return itemsize * d_ff * (3 if gated else 2)
+
+
+def projection_inflight(cols, itemsize):
+    """Bytes a row a mixer's input projection ``cols`` wide and its
+    cotangent take."""
+    return 2 * itemsize * cols
+
+
+def reserve_bytes(rows, hidden, layers, itemsize, inflight_row_bytes,
+                  streams=1):
+    """What the program holds at its peak beside the engine's and the kept
+    names: a block input a layer and the ends of the block in flight
+    (``streams`` residual streams wide), and ``inflight_row_bytes`` a row of
+    that block's widest branch."""
+    return rows * (hidden * itemsize * streams * (layers + BLOCK_END_WIDTHS)
+                   + inflight_row_bytes)
 
 
 def name_bytes(rows, row_bytes):
@@ -82,12 +125,13 @@ def kept_names(candidates, bytes_by_name, budget):
 
 
 def keep_for_stack(candidates, rows, hidden, layers, itemsize, row_bytes,
-                   streams=1):
+                   inflight_row_bytes, streams=1):
     """``kept_names`` for the stack being traced: the scope's free bytes
-    less the stack's reserve is the budget. Sets the three gauges."""
+    less the stack's reserve is the budget. Sets the five gauges."""
     free = mesh_lib.pinned_remat_free_bytes()
-    budget = max(0, free - reserve_bytes(rows, hidden, layers, itemsize,
-                                         streams))
+    reserve = reserve_bytes(rows, hidden, layers, itemsize,
+                            inflight_row_bytes, streams)
+    budget = max(0, free - reserve)
     sizes = name_bytes(rows, row_bytes or {})
     kept = kept_names(candidates, sizes, budget)
     kept_b = sum(sizes[n] for n in kept)
@@ -95,11 +139,14 @@ def keep_for_stack(candidates, rows, hidden, layers, itemsize, row_bytes,
     reg.gauge("remat/kept_names").set(len(kept))
     reg.gauge("remat/kept_mb").set(kept_b / 1e6)
     reg.gauge("remat/budget_mb").set(budget / 1e6)
+    reg.gauge("remat/reserve_mb").set(reserve / 1e6)
+    reg.gauge("remat/scan_states_kept").set(int(SCAN_NAME in kept))
     if free:
         log_dist(
             f"rematted blocks keep {', '.join(kept) or 'their base names only'}"
             f" ({kept_b / 1e6:.0f} MB of a budget of {budget / 1e6:.0f} MB; "
             + ", ".join(f"{n} {sizes.get(n, 0) / 1e6:.0f}" for n in candidates)
             + f" MB; {rows} rows x {layers} layers, {free / 1e6:.0f} MB free "
-            "before the reserve)", ranks=[0])
+            f"before a reserve of {reserve / 1e6:.0f} MB: "
+            f"{inflight_row_bytes} bytes a row in flight)", ranks=[0])
     return kept

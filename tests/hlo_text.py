@@ -89,14 +89,24 @@ FORWARD_ATTENTION_SCOPES = ("flash_fwd", "flash_fwd_chunk", "swa_fwd")
 
 def rematted_forward_attention(text):
     """The forward attention kernel calls a step runs a SECOND time, inside
-    a rematted block's recomputation: the distinct scope paths of ``text``
-    — a compiled step's (``op_name="..."``) or a lowered one's with
-    ``debug_info`` (``loc("...")``) — that hold JAX's
-    ``rematted_computation`` and, below it, one of
-    ``FORWARD_ATTENTION_SCOPES``, cut at that scope: one path a call site,
-    on the TPU one Pallas call (the scope holds nothing else), on the CPU
-    the interpreter's many instructions under it. Empty where the blocks
-    keep ``flash_o`` / ``flash_lse``."""
+    a rematted block's recomputation: ``rematted_scopes`` of
+    ``FORWARD_ATTENTION_SCOPES``. Empty where the blocks keep ``flash_o`` /
+    ``flash_lse``."""
+    return rematted_scopes(text, FORWARD_ATTENTION_SCOPES)
+
+
+# the scopes round the scan kernels' forward ``pallas_call``s
+# (ops/pallas/gated_delta.py, ops/pallas/ssd.py)
+FORWARD_SCAN_SCOPES = ("gdn_scan_fwd", "ssd_scan_fwd")
+
+
+def rematted_scopes(text, scopes):
+    """The distinct scope paths of ``text`` — a compiled step's
+    (``op_name="..."``) or a lowered one's with ``debug_info``
+    (``loc("...")``) — that hold JAX's ``rematted_computation`` and, below
+    it, one of ``scopes``, cut at that scope: one path a call site, on the
+    TPU one Pallas call (the scope holds nothing else), on the CPU the
+    interpreter's many instructions under it."""
     sites = set()
     for path in re.findall(r'"([^"]*rematted_computation[^"]*)"', text):
         parts = path.split("/")
@@ -104,12 +114,24 @@ def rematted_forward_attention(text):
             continue
         start = parts.index("rematted_computation")
         for i in range(start, len(parts)):
-            if parts[i] in FORWARD_ATTENTION_SCOPES:
+            if parts[i] in scopes:
                 # from the recomputation down: XLA leaves the path's head
                 # off some of a call site's instructions
                 sites.add("/".join(parts[start:i + 1]))
                 break
     return sorted(sites)
+
+
+def scan_forward_calls(grads, *args):
+    """Of a jitted gradient function of a scan under remat: (the
+    ``rematted_scopes`` of ``FORWARD_SCAN_SCOPES`` in its lowered text, the
+    sorted output counts of its ``pallas_call``s — a primal call writes 1,
+    a forward rule's its residuals too, the backward kernel the
+    gradients)."""
+    text = grads.lower(*args).as_text(debug_info=True)
+    return (rematted_scopes(text, FORWARD_SCAN_SCOPES),
+            sorted(len(eqn.outvars) for eqn in pallas_calls(
+                jax.make_jaxpr(grads)(*args).jaxpr)))
 
 
 def rematted_matmuls(text):

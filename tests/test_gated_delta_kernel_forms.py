@@ -2,8 +2,9 @@
 the CPU, in the interpreter, beside ``tests/test_gated_delta_kernel.py``'s
 float32 parity: in bf16 against the XLA form; where keys are alike; under
 ``jax.checkpoint`` (a block's remat runs the primal call, the forward rule
-and the backward kernel); and the rule that says which head sizes take the
-kernels, with the gauges that say which form took a call.
+and the backward kernel; one whose policy keeps ``scan_states`` the forward
+rule's kernel once, in the forward pass); and the rule that says which head
+sizes take the kernels, with the gauges that say which form took a call.
 """
 
 import jax
@@ -16,6 +17,7 @@ from deepspeed_tpu.ops.gated_delta import (CHUNK, gated_delta_recurrence,
                                            gated_delta_rule_xla)
 from deepspeed_tpu.ops.pallas import gated_delta as kernels
 from deepspeed_tpu.telemetry.registry import default_registry
+from tests import hlo_text
 from tests.gated_delta_cases import _grads, _inputs
 
 HEADS = "linear_attn/gdn_kernel_heads_per_step"
@@ -92,6 +94,38 @@ def test_primal_forward_rule_and_backward_agree_under_checkpoint():
              if eqn.primitive.name == "pallas_call"]
     outs = sorted(len(eqn.outvars) for eqn in calls)
     assert outs == [1, 3, 5], outs
+
+
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "not_kept"])
+def test_a_policy_that_keeps_the_scans_name_runs_the_forward_kernel_once(
+        kept):
+    """``scan_states`` (``ops/pallas/scan_residuals.py``) on o, the states
+    and ``T`` where the forward rule returns them. A block whose policy keeps
+    the name runs the forward rule's kernel in its forward pass and holds
+    no scan forward call under ``rematted_computation``; one that does not
+    lowers as if the name did not exist — the primal call (o alone), the
+    forward rule under the recomputation, the backward kernel. Gradients
+    are the unrematted ones either way."""
+    from deepspeed_tpu.ops.pallas.scan_residuals import SCAN_NAME
+    args = _inputs(3 * CHUNK)
+    names = jax.checkpoint_policies.save_only_these_names
+
+    def grads(policy):
+        block = jax.checkpoint(gated_delta_rule, policy=policy)
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(jnp.sin(3.0 * block(*a))),
+            argnums=(0, 1, 2, 3, 4)))
+
+    ours = grads(names(SCAN_NAME) if kept else names("flash_o"))
+    again, outs = hlo_text.scan_forward_calls(ours, *args)
+    if kept:
+        assert again == [] and outs == [3, 5], (again, outs)
+    else:
+        assert len(again) == 1 and outs == [1, 3, 5], (again, outs)
+        nothing = grads(jax.checkpoint_policies.nothing_saveable)
+        assert ours.lower(*args).as_text() == nothing.lower(*args).as_text()
+    for a, b in zip(ours(*args), _grads(gated_delta_rule, args)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
 def _eqns(jaxpr):

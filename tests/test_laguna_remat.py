@@ -76,7 +76,8 @@ def _tiny(family):
 
 @pytest.mark.parametrize("family,free,kept,again", [
     ("laguna", 10 ** 8, 4, ()),   # moe_scores, attn_proj, qkv, mlp_fc
-    ("mamba2", 10 ** 8, 4, ()),   # moe_scores, qkv, mlp_fc, mixer_in
+    # moe_scores, qkv, mlp_fc, mixer_in, scan_states
+    ("mamba2", 10 ** 8, 5, ()),
     ("mamba2", 0, 0, ("in_proj", "moe_router", "q_proj"))],
     ids=["laguna-kept", "mamba2-kept", "mamba2-base"])
 def test_rematted_blocks_keep_what_the_bytes_fit(family, free, kept, again,
@@ -88,7 +89,10 @@ def test_rematted_blocks_keep_what_the_bytes_fit(family, free, kept, again,
     handed shows a kept projection as the ``dot_general`` that wrote it), the
     base names are still handed on, and the gradients are the unrematted
     ones. With none (no engine round the model, a device kind unknown) the
-    blocks keep their base names and every projection is run again."""
+    blocks keep their base names and every projection is run again. The
+    Mamba-2 layer's scan (its kernels, in the interpreter) runs its forward
+    rule's kernel again under ``rematted_computation`` with none, and not
+    where ``scan_states`` is among what is kept."""
     from deepspeed_tpu.telemetry.registry import default_registry
     ids = jnp.asarray(np.random.default_rng(4).integers(0, 256, (1, 128)),
                       jnp.int32)
@@ -108,6 +112,12 @@ def test_rematted_blocks_keep_what_the_bytes_fit(family, free, kept, again,
     assert "named 'flash_lse'" in handed
     assert default_registry().peek_gauge("remat/kept_names") == kept
     assert (default_registry().peek_gauge("remat/kept_mb") > 0) == bool(kept)
+    if family == "mamba2":
+        scans = hlo_text.rematted_scopes(step.as_text(debug_info=True),
+                                         hlo_text.FORWARD_SCAN_SCOPES)
+        assert len(scans) == (0 if kept else 1), scans
+        assert default_registry().peek_gauge("remat/scan_states_kept") \
+            == bool(kept)
     if kept:
         want = jax.jit(jax.grad(loss(False)))(params)
         for a, b in zip(jax.tree_util.tree_leaves(step.compile()(params)),
@@ -126,7 +136,8 @@ def test_no_budget_and_a_named_policy_lower_as_without_the_rule(policy):
     from deepspeed_tpu.models import gpt2
     from deepspeed_tpu.parallel import mesh as mesh_lib
     stack = dict(rows=128, hidden=64, layers=1, itemsize=4,
-                 row_bytes={"qkv": 4 * 64, "mlp_fc": 4 * 64})
+                 row_bytes={"qkv": 4 * 64, "mlp_fc": 4 * 64},
+                 inflight_row_bytes=12 * 64)
 
     def text(policy_of, free=None):
         def block(x, w):

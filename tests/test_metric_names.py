@@ -206,12 +206,13 @@ def test_zero_gather_edge_metric_names_documented():
 
 
 @pytest.mark.parametrize("name", ["remat/kept_names", "remat/kept_mb",
-                                  "remat/budget_mb",
+                                  "remat/budget_mb", "remat/reserve_mb",
+                                  "remat/scan_states_kept",
                                   "remat/fell_back_to_base"])
 def test_remat_budget_metric_names_documented(name):
-    """What a rematted block keeps beside its base names (ISSUE 61): the
-    three trace-time gauges and the fall-back's counter stay documented AND
-    emitted."""
+    """What a rematted block keeps beside its base names (ISSUE 61; the
+    reserve and the scans' name: ISSUE 64): the five trace-time gauges and
+    the fall-back's counter stay documented AND emitted."""
     assert name in documented_metric_names(), (
         f"{name} missing from the docs/observability.md train table")
     assert name in _package_source(), name

@@ -26,8 +26,10 @@ def test_rematted_blocks_keep_what_their_attention_kernel_produced(
     the router's choice it is. The "kept" case runs with free bytes handed
     to the trace (``runtime/remat_budget.py``; the base set alone:
     ``tests/test_laguna_remat.py``): the period — three Gated DeltaNet
-    layers, one gated attention layer — then keeps all five candidates its
-    layers carry, and no projection matmul (DeltaNet's two, the attention
+    layers, one gated attention layer — then keeps all six candidates its
+    layers carry (``scan_states`` among them: a name of the delta rule's
+    KERNELS, which the XLA form this test runs does not carry —
+    ``tests/test_gated_delta_kernel_forms.py``), and no projection matmul (DeltaNet's two, the attention
     layer's with its gate) sits under ``rematted_computation``; the delta
     rule's own preparation and the shared expert's gated output alone are
     formed again."""
@@ -62,8 +64,8 @@ def test_rematted_blocks_keep_what_their_attention_kernel_produced(
     if base is None:
         assert [m for m in matmuls if "moe_shared" not in m
                 and "gdn_scan" not in m] == [], matmuls
-        # moe_scores, attn_proj, qkv, mlp_fc, mixer_in
-        assert default_registry().peek_gauge("remat/kept_names") == 5
+        # moe_scores, attn_proj, qkv, mlp_fc, mixer_in, scan_states
+        assert default_registry().peek_gauge("remat/kept_names") == 6
     else:
         for part in ("in_proj_qkvz", "in_proj_ba", "q_proj", "out_proj"):
             assert any(f"/{part}/" in m for m in matmuls), (part, matmuls)

@@ -1,6 +1,8 @@
 """The byte budget of what a rematted block keeps (``runtime/remat_budget.py``):
-the selection, a name's bytes at two cells' real shapes, the engine's figure
-on abstract state, and the fall-back when the compiler refuses the program.
+the selection, a name's bytes at two cells' real shapes, the reserve counted
+from each cell's shapes against what its program compiled to, the engine's
+figure on abstract state, and the fall-back when the compiler refuses the
+program.
 What a model's step then holds: ``tests/test_laguna_remat.py``,
 ``tests/test_qwen3_next_remat_attention.py``.
 """
@@ -20,9 +22,11 @@ from deepspeed_tpu.runtime import remat_budget as rb
 GB = 10 ** 9
 # the granite cell's names at 16,384 rows: 10 gated MLPs of 8,192, nine
 # mixers whose ``in_proj`` writes 8,512 columns, one layer of 32 / 8 heads
-# of 64, ten branches of 2,048 (bf16)
+# of 64, ten branches of 2,048 (bf16); the nine scans' y (64 heads of 64)
+# and a float32 state [128, 64] a head every 128 tokens
 GRANITE = {"mlp_fc": 10 * 16384 * 2 * 8192 * 2,
            "mixer_in": 9 * 16384 * 8512 * 2,
+           "scan_states": 9 * 16384 * (4096 * 2 + 4 * 64 * 128 * 64 // 128),
            "attn_proj": 10 * 16384 * 2048 * 2,
            "qkv": 16384 * (32 + 2 * 8) * 64 * 2}
 
@@ -32,7 +36,8 @@ GRANITE = {"mlp_fc": 10 * 16384 * 2 * 8192 * 2,
     (GRANITE["attn_proj"] - 1, ("qkv",)),            # passed over, not a stop
     (1.2 * GB, ("attn_proj", "qkv")),
     (3.3 * GB, ("attn_proj", "qkv", "mixer_in")),    # the MLP's 5.4 GB never
-    (9 * GB, ("attn_proj", "qkv", "mixer_in", "mlp_fc"))])
+    (9 * GB, ("attn_proj", "qkv", "mixer_in", "scan_states")),
+    (13 * GB, ("attn_proj", "qkv", "mixer_in", "scan_states", "mlp_fc"))])
 def test_names_are_kept_in_order_whole_or_not_at_all(budget, want):
     assert rb.kept_names(REMAT_CANDIDATES, GRANITE, budget) == want
     assert sum(GRANITE[n] for n in want) <= budget
@@ -80,9 +85,131 @@ def test_the_budget_is_what_the_table_and_the_reserve_leave():
     assert rb.free_bytes("TPU v5 lite", 17 * GB) == 0
     assert rb.free_bytes("TPU v5 lite", held) \
         == 16_911_433_728 - 10 ** 9 - held
-    # a block input a layer (four streams wide) and the constant's widths
-    assert rb.reserve_bytes(4096, 3584, 6, 2, streams=4) \
-        == 4096 * 3584 * 2 * (24 + rb.RESERVE_BLOCK_WIDTHS)
+    # a block input a layer and the ends of the block in flight (four
+    # streams wide), and what its widest branch holds a row
+    assert rb.reserve_bytes(4096, 3584, 6, 2, 81920, streams=4) \
+        == 4096 * (3584 * 2 * 4 * (6 + rb.BLOCK_END_WIDTHS) + 81920)
+
+
+def test_the_counts_of_a_branch_in_flight():
+    """Laguna's full-attention layer (48 heads of 128 over 8, an output
+    gate, 2.5 rows of float32 dq partials a row at 16,384 in chunks of
+    4,096) is 40 widths of its 2,048-wide stream; a window layer leaves no
+    partials; granite's gated MLP of 8,192 is 12 widths, a mixer's 8,512
+    projected columns 8.3."""
+    from deepspeed_tpu.ops.pallas.flash_attention import bwd_dq_slab_rows
+    assert bwd_dq_slab_rows(16384, 128, 128, 2) == 2.5
+    assert bwd_dq_slab_rows(8192, 256, 256, 2) == 2.5     # chunks of 2,048
+    assert bwd_dq_slab_rows(4096, 192, 128, 2) == 0.0     # one chunk
+    assert bwd_dq_slab_rows(1024, 64, 64, 2) == 0.0       # the whole row
+    q = 48 * 128
+    assert rb.attention_inflight(q, q, 2 * 8 * 128, 2, 2.5, gated=True) \
+        == 2 * (3 * q + 5 * q + 2048) + 10 * q == 40 * 4096
+    assert rb.attention_inflight(q, q, 2048, 2) == 2 * (6 * q + 2048)
+    assert rb.mlp_inflight(8192, 2) == 12 * 4096
+    assert rb.mlp_inflight(3712, 2, gated=False) == 4 * 3712
+    assert rb.projection_inflight(8512, 2) == 4 * 8512
+
+
+# the eight cells that remat block by block, at their configurations'
+# shapes: MB free before the reserve as the engine counts it on the cell's
+# abstract state (the log line ``rematted blocks keep ...`` of ``python -m
+# benchmark.tools.rehearse_compile <cell>``), the widths of one stream the
+# compiled program held beside the engine's bytes, a block input a layer
+# and the kept names (PERF.md Findings PR 61 and PR 64: the larger of the
+# two tables' C for the set the rule picks), and the names the rule keeps
+CELLS = {
+    "granite4hmicro-train-1chip-s16384": (5101, 8.1, (
+        "attn_proj", "qkv", "mixer_in")),
+    "qwen3next-train-1chip-s8192": (7152, 23.7, (
+        "moe_scores", "attn_proj", "qkv", "mixer_in", "scan_states",
+        "mlp_fc")),
+    "xing4-train-1chip-s4096": (3123, 4.0, (
+        "moe_scores", "attn_proj", "mlp_proj", "qkv", "mlp_fc")),
+    "nemotron3nano-train-1chip-s16384": (6574, 12.3, (
+        "moe_scores", "qkv", "mixer_in", "scan_states", "mlp_fc")),
+    "kanana2-train-1chip-s16384": (6286, 33.9, (
+        "moe_scores", "attn_proj", "mlp_fc")),
+    "laguna-train-1chip-s16384": (6229, 40.1, (
+        "moe_scores", "attn_proj", "qkv", "mlp_fc")),
+    "smallthinker-train-1chip-s16384": (6720, 19.0, (
+        "moe_scores", "attn_proj", "qkv"))}
+
+
+def _stack_of(cell_name):
+    """The figures a cell's model hands ``keep_for_stack``, from its
+    configuration file through its family, as the model's ``__call__``
+    reads them."""
+    import importlib
+    from benchmark import manifest
+    bench = manifest.load()
+    cell = manifest.cell_of(bench, cell_name)
+    config = manifest.config_of(bench, cell)
+    traffic = manifest.traffic_of(cell)
+    cfg = manifest.family_module(config).model_config(config, False)
+    model = importlib.import_module(type(cfg).__module__)
+    seq = traffic["seq_len"]
+    return dict(
+        rows=traffic["global_batch"] * seq, hidden=cfg.hidden_size,
+        layers=cfg.num_hidden_layers
+        + getattr(cfg, "num_nextn_predict_layers", 0), itemsize=2,
+        row_bytes=model.remat_row_bytes(cfg),
+        inflight_row_bytes=model.remat_inflight_row_bytes(cfg, seq),
+        streams=getattr(cfg, "hc_mult", 1))
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_the_reserve_from_a_cells_shapes_covers_its_compiled_peak(cell):
+    """The reserve is at least what the cell's compiled program held beside
+    the engine's bytes and the kept names, and with it the rule keeps the
+    set the cell was compiled and measured with — Kanana-2's ``qkv`` (3,334
+    MB: refused by the compiler at 16.17 GiB when forced) stays out by the
+    rule's own arithmetic; the gauges say what was taken."""
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+    from deepspeed_tpu.telemetry.registry import default_registry
+    free_mb, compiled_widths, want = CELLS[cell]
+    stack = _stack_of(cell)
+    sizes = stack.pop("row_bytes")
+    reserve = rb.reserve_bytes(**stack)
+    width = stack["rows"] * stack["hidden"] * 2
+    assert reserve / width - stack["layers"] * stack["streams"] \
+        >= compiled_widths
+    with mesh_lib.layout_pins(None, remat_free_bytes=free_mb * 10 ** 6):
+        kept = rb.keep_for_stack(REMAT_CANDIDATES, row_bytes=sizes, **stack)
+    assert kept == want
+    if cell.startswith("kanana2"):
+        assert "qkv" not in kept and sizes["qkv"] * stack["rows"] > 3.3 * GB
+    gauge = default_registry().peek_gauge
+    assert gauge("remat/reserve_mb") == pytest.approx(reserve / 1e6)
+    assert gauge("remat/budget_mb") == pytest.approx(free_mb - reserve / 1e6)
+    assert gauge("remat/kept_names") == len(want)
+    assert gauge("remat/scan_states_kept") == ("scan_states" in want)
+    # every name kept and the reserve fit what the chip has left
+    assert gauge("remat/kept_mb") + gauge("remat/reserve_mb") <= free_mb
+
+
+def test_a_stack_with_no_figures_keeps_the_base_names():
+    """``models/llama._maybe_remat`` (SDAR's scanned stack: measured slower
+    with more, PERF.md Findings PR 61) calls with no figures: the policy
+    saves the base names and nothing else, whatever the trace was handed."""
+    from jax.ad_checkpoint import checkpoint_name
+    from deepspeed_tpu.models import gpt2
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+
+    def block(x):
+        y = checkpoint_name(jnp.sin(x), "qkv")
+        return checkpoint_name(jnp.cos(y), "flash_o").sum()
+
+    with mesh_lib.layout_pins(None, remat_free_bytes=8 * GB):
+        policy = gpt2.block_remat_policy()
+        text = str(jax.make_jaxpr(jax.grad(jax.checkpoint(
+            block, policy=policy)))(jnp.ones(8)))
+    # what ``qkv`` names is made again in the backward pass, what
+    # ``flash_o`` names is not
+    assert text.count("name[name=qkv]") == 2
+    assert text.count("name[name=flash_o]") == 1
+    assert gpt2.REMAT_CANDIDATES.index("scan_states") \
+        == gpt2.REMAT_CANDIDATES.index("mixer_in") + 1
 
 
 def test_the_engine_counts_what_one_chip_holds_on_abstract_state():

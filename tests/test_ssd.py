@@ -142,6 +142,42 @@ def test_strong_decay_neither_overflows_nor_leaks_across_chunks():
         np.testing.assert_allclose(got, own, atol=1e-4)
 
 
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "not_kept"])
+def test_a_policy_that_keeps_the_scans_name_runs_the_forward_kernel_once(
+        kept):
+    """``scan_states`` (``ops/pallas/scan_residuals.py``) on y and the
+    states where the forward rule returns them. A block whose policy keeps
+    the name runs the forward rule's kernel in its forward pass and holds
+    no scan forward call under ``rematted_computation``; one that does not
+    lowers as if the name did not exist — the primal call (y alone), the
+    forward rule under the recomputation, the backward kernel. Gradients
+    are the unrematted ones either way."""
+    from deepspeed_tpu.ops.pallas.scan_residuals import SCAN_NAME
+    from tests import hlo_text
+    B, S, H, P, G, N, chunk = SHAPES["three_chunks_groups_of_two"]
+    args = _inputs(B, S, H, P, G, N, 1.0)
+    scan = _kernel(chunk)
+    names = jax.checkpoint_policies.save_only_these_names
+
+    def grads(fn):
+        return jax.jit(jax.grad(
+            lambda *a: jnp.sum(jnp.sin(fn(*a))), argnums=tuple(range(6))))
+
+    def rematted(policy):
+        return grads(jax.checkpoint(scan, policy=policy))
+
+    ours = rematted(names(SCAN_NAME) if kept else names("flash_o"))
+    again, outs = hlo_text.scan_forward_calls(ours, *args)
+    if kept:
+        assert again == [] and outs == [2, 6], (again, outs)
+    else:
+        assert len(again) == 1 and outs == [1, 2, 6], (again, outs)
+        nothing = rematted(jax.checkpoint_policies.nothing_saveable)
+        assert ours.lower(*args).as_text() == nothing.lower(*args).as_text()
+    for a, b in zip(ours(*args), grads(scan)(*args)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
 def test_the_dispatcher_takes_the_kernel_off_the_tpu_and_gauges_it():
     """``ssd_scan`` off a TPU runs the kernels in the interpreter at any
     shape whose heads divide into the groups; ``takes_kernel`` says what a

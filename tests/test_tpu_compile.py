@@ -449,6 +449,109 @@ def test_ssd_scan_fwd_and_grad_compile_at_nemotron_h_shape(groups,
     assert compiled.memory_analysis().peak_memory_in_bytes < 1.2e9
 
 
+def _scan_under_remat(kind):
+    """(the scan of ``kind`` as a loss, its operands' shapes at the cells'
+    sizes, the scopes of its two kernels)."""
+    if kind == "gdn":
+        from deepspeed_tpu.ops.gated_delta import gated_delta_rule as scan
+        shapes = (SDS((2, 8192, 16, 128), BF16), SDS((2, 8192, 16, 128), BF16),
+                  SDS((2, 8192, 32, 128), BF16), SDS((2, 8192, 32), F32),
+                  SDS((2, 8192, 32), F32))
+    else:
+        from deepspeed_tpu.ops.ssd import ssd_scan as scan
+        shapes = (SDS((1, 16384, 64, 64), BF16), SDS((1, 16384, 64), F32),
+                  SDS((64,), F32), SDS((1, 16384, 8, 128), BF16),
+                  SDS((1, 16384, 8, 128), BF16), SDS((64,), F32))
+    return (lambda *a: scan(*a).astype(F32).sum()), shapes
+
+
+@pytest.mark.parametrize("kept", [True, False], ids=["kept", "not_kept"])
+@pytest.mark.parametrize("kind,residuals", [
+    # o and the states bf16, T float32; y bf16 and the states float32
+    ("gdn", ("bf16[2,8192,4096]", "bf16[2,32,128,128,128]",
+             "f32[2,32,128,64,64]")),
+    ("ssd", ("bf16[1,16384,4096]", "f32[1,32,128,128,128]"))])
+def test_a_scan_whose_name_is_kept_compiles_to_one_forward_call(kind, kept,
+                                                                residuals):
+    """The two scans at their cells' shapes under a block's remat, for the
+    described chip. A policy that keeps ``scan_states``: TWO Pallas calls —
+    the forward rule's kernel in the forward pass, whose results (the
+    output, the states; the delta rule's ``T``) live to the backward
+    kernel — and nothing under ``rematted_computation``. One that does not:
+    three, the primal call writing the output alone."""
+    from deepspeed_tpu.ops.pallas.scan_residuals import SCAN_NAME
+    loss, shapes = _scan_under_remat(kind)
+    block = jax.checkpoint(
+        loss, prevent_cse=True,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *((SCAN_NAME,) if kept else ())))
+    # the loss too, as a step returns it: the forward pass's output is read
+    grads = jax.value_and_grad(block, argnums=tuple(range(len(shapes))))
+    _, compiled = compile_on_chip(grads, *shapes)
+    hlo = compiled.as_text()
+    calls = flash_calls(hlo)
+    again = hlo_text.rematted_scopes(hlo, hlo_text.FORWARD_SCAN_SCOPES)
+    forwards = [c for c in calls if re.search(r"_scan_fwd/", c)]
+    if kept:
+        assert len(calls) == 2 and again == [] and len(forwards) == 1
+        assert all(shape in forwards[0].split(" custom-call(")[0]
+                   for shape in residuals), forwards
+    else:
+        assert len(calls) == 3 and len(again) == 1 and len(forwards) == 2
+        alone = [c for c in forwards if "rematted_computation" not in c]
+        assert len(alone) == 1 and not any(
+            shape in alone[0].split(" custom-call(")[0]
+            for shape in residuals[1:]), alone
+
+
+# what ``runtime/remat_budget.py`` keeps in the cells whose kept set PR 64
+# moved, and in Kanana-2, whose ``qkv`` (3,334 MB) the compiler refused at
+# 16.17 GiB when forced: names kept, their MB, ``scan_states`` among them
+REMAT_RULE_CELLS = {
+    "granite4hmicro-train-1chip-s16384": (3, 3282, False),
+    "qwen3next-train-1chip-s8192": (6, 4217, True),
+    "xing4-train-1chip-s4096": (5, 1464, False),
+    "nemotron3nano-train-1chip-s16384": (5, 3632, True),
+    "kanana2-train-1chip-s16384": (3, 1351, False)}
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("cell_name", sorted(REMAT_RULE_CELLS))
+def test_the_remat_rules_kept_set_fits_the_chips_program_memory(cell_name):
+    """The WHOLE step of a cell with what the byte rule keeps, compiled for
+    a described v5e: at or under 15.9 GB (the program's 15.75 GiB less the
+    rule's headroom), the fall-back not taken. Where ``scan_states`` is kept
+    a scan's forward kernel is called once a layer and never under
+    ``rematted_computation``. Kanana-2's kept set and compiled peak are the
+    parent's, byte for byte (13,478,071,296: ``qkv`` stays out by the
+    rule's arithmetic). 1-2.5 minutes a cell: slow-marked (the rule at
+    these shapes without a compile: ``tests/test_remat_budget.py``)."""
+    from benchmark import manifest
+    from deepspeed_tpu.runtime import remat_budget
+    names, kept_mb, scans = REMAT_RULE_CELLS[cell_name]
+    bench = manifest.load()
+    cell = manifest.cell_of(bench, cell_name)
+    config = manifest.config_of(bench, cell)
+    compiled = manifest.family_module(config).lower_train_step(
+        config, manifest.traffic_of(cell), topo().devices[:1]).compile()
+    gauge = default_registry().peek_gauge
+    assert gauge("remat/kept_names") == names
+    assert gauge("remat/kept_mb") == pytest.approx(kept_mb, abs=1)
+    assert gauge("remat/scan_states_kept") == scans
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert peak <= remat_budget.PROGRAM_HBM_BYTES["TPU v5 lite"] \
+        - remat_budget.HEADROOM_BYTES, peak
+    if cell_name.startswith("kanana2"):
+        assert peak == 13_478_071_296
+    hlo = compiled.as_text()
+    again = hlo_text.rematted_scopes(hlo, hlo_text.FORWARD_SCAN_SCOPES)
+    forwards = [c for c in flash_calls(hlo) if re.search(r"_scan_fwd/", c)]
+    if scans:
+        assert again == [] and len(forwards) in (3, 4), (again, forwards)
+    elif forwards:                     # granite: nine mixers, run twice
+        assert len(again) == 9 and len(forwards) == 18
+
+
 def mixer_sites():
     """The ``mixer/*_sites`` gauges by their short names."""
     return {name: default_registry().peek_gauge(f"mixer/{name}_sites") or 0
