@@ -306,7 +306,7 @@ class LlamaBlock(nn.Module):
         norm = lambda name: RMSNorm(  # noqa: E731
             eps=cfg.rms_eps, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             name=name)
-        attn = LlamaAttention(cfg, self.max_out_tokens, self.diffusion,
+        attn = _attn_cls(cfg)(cfg, self.max_out_tokens, self.diffusion,
                               name="attn")(norm("input_norm")(x), positions)
         x = x + attn
         if cfg.num_experts:
@@ -343,7 +343,7 @@ def _maybe_remat(cfg, parent, name):
     # choice, the attention kernel's output and log-sum-exp), nothing else
     policy = block_remat_policy() if cfg.remat_policy == "block" \
         else _remat_policy(cfg.remat_policy)
-    return nn.remat(block, prevent_cse=False, policy=policy)
+    return nn.remat(block, prevent_cse=False, policy=_pinned(cfg, policy))
 
 
 class _ScanBody(nn.Module):
@@ -416,8 +416,8 @@ class LlamaForCausalLM(nn.Module):
         """{variable sown into ``stats``: the gauge it is read under}."""
         from deepspeed_tpu.moe.dropless import HELD_STAT_GAUGES, STAT_GAUGES
         cfg = self.config
-        gauges = {} if not cfg.num_experts else \
-            HELD_STAT_GAUGES if cfg.experts_held else STAT_GAUGES
+        gauges = _with_dsa_gauges(cfg, {} if not cfg.num_experts else (
+            HELD_STAT_GAUGES if cfg.experts_held else STAT_GAUGES))
         return dict(gauges, **DIFFUSION_STAT_GAUGES) if cfg.block_length \
             else gauges
 
@@ -434,7 +434,7 @@ class LlamaForCausalLM(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, labels=None, deterministic=True,
-                 keep_prob=1.0, position_offset=0):
+                 keep_prob=1.0, position_offset=0, positions=None):
         cfg = self.config
         B, S = input_ids.shape
         embed = self.param("embed_tokens", nn.initializers.normal(0.02),
@@ -457,7 +457,7 @@ class LlamaForCausalLM(nn.Module):
                 self.sow("intermediates", "bd_noise", (noisy, masked, t_row))
         with annotate("ds_embed"):
             x = _embed_lookup(embed, input_ids).astype(cfg.dtype)
-        positions = position_offset + jnp.arange(S)
+        positions = _positions(positions, position_offset, S)
         if diffusion:
             positions = jnp.concatenate([positions, positions])
 
@@ -704,3 +704,215 @@ def from_hf_llama(hf_model, cfg: LlamaConfig, scan_layers=True):
         "lm_head": jnp.asarray(head.astype(np.float32)),
     })
     return tree
+
+
+# ------------------------------------------- learned sparse attention, mrope
+#
+# Everything below was added at the END of the file (PR 65), and the five
+# places above that reach it were changed WITHIN their lines: a Pallas
+# kernel's serialized body carries the line numbers of the frames that called
+# it, so a line added above ``LlamaAttention.__call__`` would change every
+# lowered step that runs this file (``benchmark/tools/lowered_step_hash.py``
+# holds OLMoE's and SDAR's to their parent's, unmasked).
+
+# what a layer with an indexer sows into ``stats``, and the gauges
+DSA_STAT_GAUGES = {"dsa_selected_share": "attention/dsa_selected_share",
+                   "dsa_kl": "attention/dsa_kl"}
+
+_LlamaConfigBase = LlamaConfig
+
+
+@dataclasses.dataclass(frozen=True)
+class LlamaConfig(_LlamaConfigBase):
+    """``LlamaConfig`` with what Keye-VL-2.0's published config has and the
+    others lack; every default is their behaviour, their programs letter for
+    letter.
+
+    Learned sparse attention (DeepSeek-V3.2's indexer, a published
+    ``sa_config``): ``index_topk`` 0 -> off. >0 -> each layer's attention
+    carries an indexer of ``index_heads`` x ``index_head_dim`` that scores
+    every causal pair from the block's DETACHED input, a query attends to
+    its ``index_topk`` best keys, and the indexer learns from the KL of the
+    attention's mean probabilities against its own
+    (``ops/attention.learned_sparse_attention``), sown into ``losses`` times
+    ``dsa_kl_weight``, the mean over layers and rows.
+
+    ``mrope_section``: multimodal rotary — frequency pair i of a head takes
+    its angle from row 0, 1 or 2 of [3, S] positions (temporal, height,
+    width), the sections in order; () -> one row. On text the rows are
+    equal."""
+    index_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
+    dsa_kl_weight: float = 1.0
+    mrope_section: tuple = ()
+
+    def num_params(self):
+        count = super().num_params()
+        if self.index_topk:
+            # the indexer's queries, its one key a token with a LayerNorm
+            # (weight and bias), its weight a head
+            E, J, Di = self.hidden_size, self.index_heads, self.index_head_dim
+            count += self.n_layers * (E * J * Di + E * Di + 2 * Di + E * J)
+        return count
+
+
+def _positions(positions, offset, S):
+    """The rotary positions of a forward: ``offset + arange(S)`` (every
+    program before PR 65), or the caller's [3, S] multimodal rows for a
+    config with ``mrope_section`` (``LlamaForCausalLM(positions=)``)."""
+    return offset + jnp.arange(S) if positions is None else positions
+
+
+def mrope_angles(positions, head_dim, theta, sections):
+    """[3, S] positions (or [S]: the three rows equal) -> (cos, sin)
+    [S, head_dim//2] fp32: pair i takes row 0 for i < sections[0], row 1
+    for the next sections[1], row 2 for the rest. Equal rows give
+    ``rope_angles`` bit for bit."""
+    if sum(sections) != head_dim // 2:
+        raise ValueError(f"mrope_section {tuple(sections)} does not add up "
+                         f"to the {head_dim // 2} frequency pairs of a head")
+    if positions.ndim == 1:
+        positions = jnp.broadcast_to(positions, (3,) + positions.shape)
+    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
+                                      dtype=jnp.float32) / head_dim))
+    ang = positions.astype(jnp.float32)[:, :, None] * inv[None, None, :]
+    row = np.repeat(np.arange(3), sections)
+    ang = jnp.take_along_axis(ang, jnp.asarray(row)[None, None, :],
+                              axis=0)[0]
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+class _IndexKeyNorm(nn.Module):
+    """LayerNorm with weight and bias over the indexer key's width."""
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                       self.param_dtype)
+        b = self.param("bias", nn.initializers.zeros, (x.shape[-1],),
+                       self.param_dtype)
+        xf = x.astype(jnp.float32)
+        xf = xf - jnp.mean(xf, axis=-1, keepdims=True)
+        n = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                               + self.eps)
+        return (n * w.astype(jnp.float32)
+                + b.astype(jnp.float32)).astype(self.dtype)
+
+
+class _IndexedLlamaAttention(LlamaAttention):
+    """``LlamaAttention`` for a config with ``mrope_section`` or an indexer
+    (training: no KV cache — the indexer's key cache is ROADMAP's). The
+    projections, the head norms and the names are ``LlamaAttention``'s, so a
+    weight tree and a remat policy fit both; the rotary takes the sections,
+    and with ``index_topk`` > 0 the indexer reads the block's normed input
+    DETACHED — the cross-entropy reaches none of its weights — and its KL
+    reaches nothing else (the op hands d kl to the indexer's operands
+    alone)."""
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.config
+        if self.max_out_tokens or self.diffusion:
+            raise NotImplementedError(
+                "mrope / a learned indexer with a KV cache or under "
+                "block-diffusion training")
+        B, S, E = x.shape
+        H, Hkv, D = cfg.n_heads, cfg.kv_heads, cfg.head_dim
+        dense = lambda n, name: nn.Dense(  # noqa: E731
+            n, use_bias=False, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            kernel_init=nn.initializers.normal(0.02), name=name)
+        q, k, v = (dense(h * D, name)(x) for h, name in (
+            (H, "q_proj"), (Hkv, "k_proj"), (Hkv, "v_proj")))
+        if cfg.qk_norm:
+            with annotate("qk_norm"):
+                norm = lambda name: RMSNorm(  # noqa: E731
+                    eps=cfg.rms_eps, dtype=cfg.dtype,
+                    param_dtype=cfg.param_dtype, name=name)
+                if cfg.qk_norm == "head":
+                    q = norm("q_norm")(q.reshape(B, S, H, D)).reshape(q.shape)
+                    k = norm("k_norm")(k.reshape(B, S, Hkv, D)) \
+                        .reshape(k.shape)
+                else:
+                    q, k = norm("q_norm")(q), norm("k_norm")(k)
+        q, k, v = (checkpoint_name(t, "qkv") for t in (q, k, v))
+        qh, kh, vh = (t.reshape(B, S, h, D).transpose(0, 2, 1, 3)
+                      for t, h in ((q, H), (k, Hkv), (v, Hkv)))
+        cos, sin = mrope_angles(positions, D, cfg.rope_theta,
+                                cfg.mrope_section) if cfg.mrope_section \
+            else rope_angles(positions, D, cfg.rope_theta)
+        qh, kh = apply_rope(qh, cos, sin), apply_rope(kh, cos, sin)
+        if cfg.index_topk:
+            out = self._learned_sparse(x, qh, kh, vh, positions, dense)
+        else:
+            out = dot_product_attention(qh, kh, vh, causal=True,
+                                        use_flash=cfg.use_flash)
+        out = out.transpose(0, 2, 1, 3).reshape(B, S, H * D)
+        return checkpoint_name(dense(E, "o_proj")(out), "attn_proj")
+
+    def _learned_sparse(self, x, qh, kh, vh, positions, dense):
+        from deepspeed_tpu.ops.attention import learned_sparse_attention
+        cfg = self.config
+        B, S, _ = x.shape
+        J, Di = cfg.index_heads, cfg.index_head_dim
+        with annotate("dsa_index_proj"):
+            xi = jax.lax.stop_gradient(x)
+            # rotate-half over all Di dims, on the temporal row
+            cos, sin = rope_angles(positions if positions.ndim == 1
+                                   else positions[0], Di, cfg.rope_theta)
+            iq = dense(J * Di, "index_q")(xi).reshape(B, S, J, Di) \
+                .transpose(0, 2, 1, 3)
+            iq = apply_rope(iq, cos, sin)
+            ik = _IndexKeyNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                               name="index_k_norm")(
+                dense(Di, "index_k")(xi))
+            ik = apply_rope(ik[:, None], cos, sin)[:, 0]
+            iw = dense(J, "index_w")(xi).astype(jnp.float32) \
+                * (J ** -0.5 * Di ** -0.5)
+        out, kl, kept, bits = learned_sparse_attention(
+            qh, kh, vh, iq, ik, iw, cfg.index_topk, use_flash=cfg.use_flash)
+        if cfg.remat:
+            from deepspeed_tpu.runtime.remat_budget import selection_pin_bytes
+            selection_pin_bytes(B, S, cfg.n_layers)
+        kl = jnp.mean(kl)
+        if self.is_mutable_collection("losses"):
+            self.sow("losses", "dsa_kl",
+                     cfg.dsa_kl_weight / cfg.n_layers * kl)
+        if self.is_mutable_collection("stats"):
+            self.sow("stats", "dsa_kl", jax.lax.stop_gradient(kl))
+            self.sow("stats", "dsa_selected_share",
+                     jnp.sum(kept).astype(jnp.float32)
+                     / (B * S * (S + 1) // 2))
+        if self.is_mutable_collection("intermediates"):
+            # a caller's look (the benchmark's check against its reference)
+            self.sow("intermediates", "index_operands", (iq, ik, iw))
+            self.sow("intermediates", "selection", bits)
+            self.sow("intermediates", "attn_in", x)
+        return out
+
+
+def _attn_cls(cfg):
+    """The attention module class of a block: ``LlamaAttention`` for every
+    config without ``mrope_section`` and ``index_topk``."""
+    return _IndexedLlamaAttention if cfg.index_topk or cfg.mrope_section \
+        else LlamaAttention
+
+
+def _pinned(cfg, policy):
+    """``policy`` joined, for a stack whose layers carry an indexer, with
+    what pins a layer's selection: the recomputed forward attends to the
+    keys the first one chose (its bytes:
+    ``runtime/remat_budget.selection_pin_bytes``)."""
+    if not cfg.index_topk or policy is None:
+        return policy
+    from deepspeed_tpu.ops.pallas.learned_sparse_attention import \
+        SELECTION_NAME
+    return jax.checkpoint_policies.save_from_both_policies(
+        policy, jax.checkpoint_policies.save_only_these_names(SELECTION_NAME))
+
+
+def _with_dsa_gauges(cfg, gauges):
+    return dict(gauges, **DSA_STAT_GAUGES) if cfg.index_topk else gauges

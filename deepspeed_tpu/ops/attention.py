@@ -228,3 +228,64 @@ def block_diffusion_attention(q, k, v, block_length, scale=None,
     spec = jax.sharding.PartitionSpec(batch_axes, model_axis)
     return jax.shard_map(kernel, mesh=mesh, in_specs=(spec, spec, spec),
                          out_specs=spec, check_vma=False)(q, k, v)
+
+
+# The dispatch of this file in one place (at its END: a line added above a
+# kernel's calling frame moves the frames' line numbers inside every cell's
+# serialized kernels, and ``benchmark/tools/lowered_step_hash.py`` holds the
+# accepted cells' lowered steps to their parent's, unmasked).
+DISPATCH = """The kernel families this file routes to, and the rule for each (what decides
+is the caller's arguments and the shapes, never a model's name):
+
+    causal / full    ``dot_product_attention`` (``fused_qkv_attention`` on the
+                     fused layout): the whole-row flash kernels while a head's
+                     row fits VMEM, the chunked ones past that and for a value
+                     width that is not the q·k width
+    band             ``dot_product_attention(window=)`` with a window shorter
+                     than the sequence: the window kernels walk the band alone
+    block-diffusion  ``block_diffusion_attention``: the mask given by
+                     ``block_length`` and the rows' count, its own walk
+    learned-sparse   ``learned_sparse_attention``: the mask is DATA, chosen a
+                     step at a time by an indexer's exact top-k; the causal
+                     tiles walked under it, with the indexer's KL
+
+Each takes the Pallas kernels on a TPU (``use_flash=None``) and has a dense
+plain-XLA form (``use_flash=False``, the kernels' oracle); under a
+multi-device engine mesh the kernels run per device (``_device_axes``).
+"""
+
+def learned_sparse_attention(q, k, v, index_q, index_k, index_w, topk,
+                             scale=None, use_flash=None):
+    """(o [B, H, S, D], kl [B, S] float32, kept keys a query [B, S] int32,
+    the kept set's bits uint8 [B, ceil(S / 8), S] by key and query: a
+    caller's look, dead code where nothing reads it) of
+    causal attention pruned by a learned indexer (DeepSeek-V3.2's sparse
+    attention): query t scores every key s <= t as ``sum_j index_w[t, j]
+    relu(index_q[t, j] . index_k[s])`` in float32, keeps the ``topk`` largest
+    (all while t < topk; a tie goes to the smaller s; exact, no gradient
+    through the choice), and every head attends to those keys alone; ``kl``
+    is KL(mean over heads of the attention's probabilities || softmax of the
+    kept scores), whose gradient reaches the indexer's three operands and
+    nothing else, as ``o``'s reaches q, k, v alone. q [B, H, S, D]; k, v
+    [B, Hkv, S, D]; index_q [B, J, S, Di]; index_k [B, S, Di] (one key a
+    token); index_w [B, S, J], every scale folded in. The Pallas kernels
+    (``ops/pallas/learned_sparse_attention.py``) on a TPU; ``use_flash=False``
+    (and off a TPU) the dense plain-XLA oracle."""
+    from deepspeed_tpu.ops.pallas import learned_sparse_attention as lsa
+    check_qkv_shapes(q, k, v)
+    scale = float(scale) if scale is not None else 1.0 / float(
+        np.sqrt(q.shape[-1]))
+    if use_flash is None:
+        use_flash = is_tpu_backend()
+    if not use_flash:
+        return lsa.reference_learned_sparse_attention(
+            q, k, v, index_q, index_k, index_w, int(topk), scale)
+    kernel = functools.partial(lsa.learned_sparse_attention, topk=int(topk),
+                               scale=scale)
+    mesh, batch_axes, _ = _device_axes(q.shape[0], 1)
+    if mesh is None:
+        return kernel(q, k, v, index_q, index_k, index_w)
+    spec = jax.sharding.PartitionSpec(batch_axes)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(spec,) * 6,
+                         out_specs=(spec,) * 4, check_vma=False)(
+        q, k, v, index_q, index_k, index_w)

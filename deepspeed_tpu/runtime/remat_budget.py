@@ -40,7 +40,10 @@ policy object). No process state enters: a cell lowers to the same text in
 every process. The gauges ``remat/kept_names``, ``remat/kept_mb``,
 ``remat/budget_mb`` and ``remat/reserve_mb`` say what the last stack traced
 took, ``remat/scan_states_kept`` whether the scan kernels' forward-rule
-outputs (``ops/pallas/scan_residuals.py``) were among it.
+outputs (``ops/pallas/scan_residuals.py``) were among it. A stack that prunes
+its attention by a learned indexer keeps one name more whatever the memory —
+the selection (``selection_pin_bytes``, counted from shapes:
+``remat/selection_pin_mb``).
 """
 
 from deepspeed_tpu.ops.pallas.scan_residuals import SCAN_NAME
@@ -108,6 +111,19 @@ def name_bytes(rows, row_bytes):
     ``row_bytes`` {name: bytes a row, summed over the layers that carry
     it}."""
     return {name: rows * b for name, b in row_bytes.items()}
+
+
+def selection_pin_bytes(batch, seq, layers, tile=512):
+    """Bytes a stack whose layers prune their attention by a learned indexer
+    keeps under the kernels' ``SELECTION_NAME`` whatever the memory, beside
+    the base names: a layer's kept set as bits (a bit a (key, query) pair of
+    the sequence padded to the kernels' tile) and two float32 / int32 rows a
+    query (the kept scores' log-sum-exp, their count). 33.6 MB a layer at
+    16,384 tokens. Sets ``remat/selection_pin_mb``."""
+    padded = -(-seq // tile) * tile
+    pinned = layers * batch * (padded * padded // 8 + 8 * padded)
+    default_registry().gauge("remat/selection_pin_mb").set(pinned / 1e6)
+    return pinned
 
 
 def kept_names(candidates, bytes_by_name, budget):

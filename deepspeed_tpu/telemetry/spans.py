@@ -187,6 +187,15 @@ def annotate(tag):
       block_diffusion_attention.py, the same for the block-diffusion mask;
       ``bd_bwd_dq_sum`` the XLA sum of dq's partials): ``bd_attn_share``,
       ``bd_*_roofline``; ``bd_noise`` (models/llama.py): ``bd_noise_ms``;
+    - ``dsa_indexer``, ``dsa_indexer_bwd``, ``dsa_select``, ``dsa_fwd``,
+      ``dsa_bwd``, ``dsa_kl`` (ops/pallas/learned_sparse_attention.py, round
+      the six ``pallas_call``s of a layer whose attention a learned indexer
+      prunes; ``dsa_select_pin``, ``dsa_kl_bwd``, ``dsa_indexer_bwd_sum``,
+      ``dsa_bwd_dq_sum`` the XLA passes beside them, each under its kernel's
+      tag) and ``dsa_index_proj`` (models/llama.py: the indexer's three
+      projections, its key's norm and their rotary): ``dsa_attn_share``,
+      ``dsa_indexer_ms``, ``dsa_select_ms``, ``dsa_kl_ms`` and a
+      ``dsa_*_roofline`` a kernel;
     - ``dense_mlp`` (laguna.py, deepseek_v3.py: the leading SwiGLU): a row.
 
     - ``mla_latent``, ``mla_expand``, ``mla_rope`` (models/deepseek_v3.py,
@@ -245,7 +254,13 @@ def annotate(tag):
     the block-diffusion kernels ``attention/bd_tile_overcompute`` (read by
     ``bd_tile_overcompute``) and ``attention/bd_tiles_per_grid_step``, and
     their model's step ``diffusion/masked_share`` and
-    ``diffusion/weight_max`` (the largest 1 / t that met a masked row).
+    ``diffusion/weight_max`` (the largest 1 / t that met a masked row);
+    the learned-sparse kernels ``attention/dsa_tile_overcompute`` (read by
+    ``dsa_tile_overcompute``), their model's step
+    ``attention/dsa_selected_share`` (``dsa_selected_share``) and
+    ``attention/dsa_kl`` (the indexer's loss L_I, no metric), and
+    ``remat/selection_pin_mb`` (what a rematted stack keeps of its layers'
+    selections, no metric).
     A chunked call whose q·k width is not its value
     width (latent attention) leaves ``attention/mla_qk_dim`` and
     ``attention/mla_v_dim``, the two widths as the kernels saw them (192 /

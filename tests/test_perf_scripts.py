@@ -35,10 +35,13 @@ def test_the_scripts_that_stay_are_the_nine():
     since PR 60: ``bd_attention_bench`` (the block-diffusion mask kernels at
     the SDAR cell's shape over (tile, chunk) plans) is what ``_CHUNK_ROWS``'s
     comment in ``ops/pallas/block_diffusion_attention.py`` and PERF.md's
-    Findings PR 60 quote."""
+    Findings PR 60 quote. Thirteen since PR 65: ``dsa_bench`` (the
+    learned-sparse-attention kernels at the Keye-VL-2.0 cell's shape, each
+    stage alone, after a check against the dense plain-XLA form) is what
+    PERF.md's Findings PR 65 quote."""
     assert SCRIPTS == [
         "adam_test", "aio_bench", "bd_attention_bench", "blocksparse_sweep",
-        "flash_chunked_bench",
+        "dsa_bench", "flash_chunked_bench",
         "gdn_scan_bench", "gmm_tile_bench", "mhc_stream_bench",
         "mixer_elementwise_bench", "mla_flash_bench", "rows_to_tokens_bench",
         "swa_bench"]

@@ -114,11 +114,21 @@ def test_the_step_in_a_lower_precision_is_told_apart(weights, compared):
     assert min(diffs["grad_leaf_rel"].values()) > 1e-3
 
 
-def test_the_eight_shares_add_up_to_the_uncut_layer(weights):
+@pytest.mark.parametrize("which", ["sdar", "keye_vl2"])
+def test_the_eight_shares_add_up_to_the_uncut_layer(which):
     """The share tied to the model: the partial expert sums of the 4 ranks
     (the rehearsal's expert_parallel_size) add up to the reference's layer
-    with all 8 experts held."""
-    cfg = family.model_config(F32_CONFIG, True)
+    with all 8 experts held — for every configuration that is one rank's
+    share of this expert layer (SDAR's, and since PR 65 Keye-VL-2.0's)."""
+    if which == "sdar":
+        cfg = family.model_config(F32_CONFIG, True)
+    else:
+        from benchmark.families import keye_vl2
+        with open(os.path.join(
+                manifest.HERE, "configs",
+                "keye-vl-2.0-30b-a3b-ep8-depth6.json")) as f:
+            cfg = keye_vl2.model_config(json.load(f), True)
+        assert cfg.index_topk and cfg.experts_held == 2
     E, held, H, F = cfg.num_experts, cfg.experts_held, 64, 32
     ks = jax.random.split(jax.random.PRNGKey(5), 5)
     full = {"router": 0.5 * jax.random.normal(ks[0], (H, E)),

@@ -1214,3 +1214,52 @@ def test_block_diffusion_attention_compiles_at_the_sdar_cells_shape(
         assert re.search(r'op_name="[^"]*/' + scope + "/", hlo), scope
     assert f"{2 * L},{2 * L}" not in hlo
     assert_dense_lse_kept(hlo, calls, f"f32[{heads},{2 * L // 128},1,128]")
+
+
+def test_learned_sparse_attention_compiles_at_the_keye_cells_shape():
+    """1 x 32 / 4 heads x 16,384 rows x head_dim 128, an indexer of 16 heads
+    of 64 keeping 2,048 keys, bf16 (``keyevl2-train-1chip-s16384``), forward
+    and backward under the blocks' remat policy with the selection's name:
+    the six kernels under their scopes; the selection and the pruned forward
+    kernel ONCE (the recomputed forward reads the kept bits and ``flash_o`` /
+    ``flash_lse``), the indexer's scores and the KL pass twice; the mask
+    [16384, 16384] int8 is the one dense array of the step beside the
+    float32 scores and the KL's gradient in them, and none has a head axis;
+    the kept bits are [2048, 16384] uint8."""
+    from deepspeed_tpu.models.gpt2 import block_remat_policy
+    from deepspeed_tpu.ops import attention
+    from deepspeed_tpu.ops.pallas import learned_sparse_attention as lsa
+    S, H, Hkv, J = 16384, 32, 4, 16
+
+    def attend(*a):
+        with jax.named_scope("attn"):
+            o, kl, _, _ = attention.learned_sparse_attention(*a, 2048)
+            return o.astype(F32).sum() + kl.mean()
+
+    policy = jax.checkpoint_policies.save_from_both_policies(
+        block_remat_policy(), jax.checkpoint_policies.save_only_these_names(
+            lsa.SELECTION_NAME))
+
+    def grads(*a):
+        # the value too, as a step takes it: the forward pass's KL is read
+        return jax.value_and_grad(
+            jax.checkpoint(attend, prevent_cse=True, policy=policy),
+            argnums=tuple(range(6)))(*a)
+
+    shapes = (SDS((1, H, S, 128), BF16), SDS((1, Hkv, S, 128), BF16),
+              SDS((1, Hkv, S, 128), BF16), SDS((1, J, S, 64), BF16),
+              SDS((1, S, 64), BF16), SDS((1, S, J), F32))
+    text, compiled = compile_on_chip(grads, *shapes)
+    assert kernel_names(text) == {
+        "_indexer_kernel", "_select_kernel", "_masked_fwd_kernel",
+        "_kl_kernel", "_masked_bwd_kernel", "_indexer_bwd_kernel"}
+    hlo = compiled.as_text()
+    count = lambda scope: sum(  # noqa: E731
+        1 for ln in hlo.splitlines() if "tpu_custom_call" in ln
+        and re.search(r'op_name="[^"]*/' + scope + "/", ln))
+    assert (count("dsa_select"), count("dsa_fwd"), count("dsa_bwd"),
+            count("dsa_indexer_bwd")) == (1, 1, 1, 1)
+    assert (count("dsa_indexer"), count("dsa_kl")) == (2, 2)
+    assert f"u8[{S // 8},{S}]" in hlo
+    assert f"{H},{S},{S}" not in hlo and f"{S},{S},{H}" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
