@@ -332,7 +332,7 @@ class LlamaBlock(nn.Module):
         return x + out
 
 
-def _maybe_remat(cfg, parent, name):
+def _maybe_remat(cfg, parent, name, x):
     """The block class for the child ``name`` of ``parent``: the ZeRO-3
     gather edge innermost (models/gpt2.gather_edge_block), remat round
     it."""
@@ -343,7 +343,7 @@ def _maybe_remat(cfg, parent, name):
     # choice, the attention kernel's output and log-sum-exp), nothing else
     policy = block_remat_policy() if cfg.remat_policy == "block" \
         else _remat_policy(cfg.remat_policy)
-    return nn.remat(block, prevent_cse=False, policy=_pinned(cfg, policy))
+    return nn.remat(block, prevent_cse=False, policy=_pinned(cfg, policy, x))
 
 
 class _ScanBody(nn.Module):
@@ -353,7 +353,7 @@ class _ScanBody(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions):
-        block = _maybe_remat(self.config, self, "blk")
+        block = _maybe_remat(self.config, self, "blk", x)
         return block(self.config, self.max_out_tokens, self.diffusion,
                      name="blk")(x, positions), None
 
@@ -475,7 +475,7 @@ class LlamaForCausalLM(nn.Module):
                            name="layers")(x, positions)
         else:
             for i in range(cfg.n_layers):
-                block = _maybe_remat(cfg, self, f"layers_{i}")
+                block = _maybe_remat(cfg, self, f"layers_{i}", x)
                 x = block(cfg, self.max_out_tokens, diffusion,
                           name=f"layers_{i}")(x, positions)
 
@@ -901,18 +901,47 @@ def _attn_cls(cfg):
         else LlamaAttention
 
 
-def _pinned(cfg, policy):
+def _pinned(cfg, policy, x):
     """``policy`` joined, for a stack whose layers carry an indexer, with
     what pins a layer's selection: the recomputed forward attends to the
     keys the first one chose (its bytes:
-    ``runtime/remat_budget.selection_pin_bytes``)."""
+    ``runtime/remat_budget.selection_pin_bytes``) — and, where the chip has
+    the room for it beside the blocks' input ``x`` [B, S, E]
+    (``runtime/remat_budget.keep_kl_grad``), with the KL's gradient in the
+    indexer's scores: the recomputed forward then runs neither the indexer
+    nor the KL pass."""
     if not cfg.index_topk or policy is None:
         return policy
     from deepspeed_tpu.ops.pallas.learned_sparse_attention import \
-        SELECTION_NAME
+        KL_GRAD_NAME, SELECTION_NAME
+    from deepspeed_tpu.runtime.remat_budget import keep_kl_grad
+    B, S, E = x.shape
+    kept = keep_kl_grad(B, S, E, cfg.n_layers, jnp.dtype(cfg.dtype).itemsize,
+                        remat_inflight_row_bytes(cfg, S))
+    names = (SELECTION_NAME,) + ((KL_GRAD_NAME,) if kept else ())
     return jax.checkpoint_policies.save_from_both_policies(
-        policy, jax.checkpoint_policies.save_only_these_names(SELECTION_NAME))
+        policy, jax.checkpoint_policies.save_only_these_names(*names))
 
 
 def _with_dsa_gauges(cfg, gauges):
     return dict(gauges, **DSA_STAT_GAUGES) if cfg.index_topk else gauges
+
+
+def remat_inflight_row_bytes(cfg, seq_len):
+    """Bytes a row the widest branch of a block with an indexer holds
+    between its recomputation and the end of its backward (what
+    ``runtime/remat_budget.reserve_bytes`` counts): the experts' (or the
+    MLP's), or the attention's with what the learned selection adds to it."""
+    from deepspeed_tpu.moe.dropless import inflight_row_bytes
+    from deepspeed_tpu.ops.pallas.flash_attention import bwd_dq_slab_rows
+    from deepspeed_tpu.runtime import remat_budget as rb
+    b, D = jnp.dtype(cfg.dtype).itemsize, cfg.head_dim
+    q = cfg.n_heads * D
+    attn = rb.attention_inflight(q, q, 2 * cfg.kv_heads * D, b,
+                                 bwd_dq_slab_rows(seq_len, D, D, b)) \
+        + rb.learned_sparse_inflight(seq_len, b)
+    ffn = inflight_row_bytes(
+        cfg.hidden_size, cfg.intermediate_size, cfg.num_experts_per_tok,
+        cfg.num_experts, cfg.experts_held or cfg.num_experts, itemsize=b) \
+        if cfg.num_experts else rb.mlp_inflight(cfg.intermediate_size, b)
+    return max(attn, ffn)

@@ -27,17 +27,23 @@ count over keys is a sum down the sublanes):
                          walking the causal tiles under the mask's tile; ONE
                          mask for all heads, read a head
     ``dsa_kl``           the heads' probabilities summed a tile, the KL of a
-                         query and GT = softmax_S(I) - p, its gradient in I
-    ``dsa_indexer_bwd``  GT through the indexer: relu's gate a head, the
-                         query side accumulated over a query block's keys,
-                         the key side left as partials a query block
+                         query and GT = softmax_S(I) - p, its gradient in I,
+                         written as the causal tiles alone (``packed_tile``)
+    ``dsa_indexer_bwd``  GT, times the KL's cotangent a query, through the
+                         indexer: relu's gate a head, the query side
+                         accumulated over a query block's keys, the key side
+                         left as partials a query block
 
 What is walked is every causal tile (the mask is data: a tile may hold any
 of its pairs); what the mathematics needs is the selected pairs alone, and
 the rooflines count those (``selected_pairs``). The mask is what a rematted
 block keeps of the selection (``pin_selection``: packed to bits, 1/8 byte a
 pair, under the name ``SELECTION_NAME``) so that a recomputed forward attends
-to the keys the first one chose.
+to the keys the first one chose. GT carries a name of its own
+(``KL_GRAD_NAME``): a block whose policy keeps it (``runtime/remat_budget.
+keep_kl_grad``, where the bytes fit) hands the first forward's GT to the
+indexer's backward, and its recomputed forward runs neither ``dsa_indexer``
+nor ``dsa_kl``; one that does not runs both again.
 
 ``reference_*``: the same mathematics in plain XLA, dense, the kernels'
 oracle and the path off a TPU.
@@ -61,6 +67,8 @@ fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
 
 # the name a remat policy keeps the selection by (``pin_selection``)
 SELECTION_NAME = "dsa_selection"
+# ... and the KL's gradient in the scores, as causal tiles (``_index_kl``)
+KL_GRAD_NAME = "dsa_kl_grad"
 INT_MIN = -2 ** 31
 # scoped VMEM the selection may take: a query block's scores over all keys,
 # twice (the pipeline's), their integer image and the mask
@@ -84,6 +92,13 @@ def tiles_walked(S, tile):
     """Causal tiles of ``tile`` x ``tile`` a pass walks."""
     n = -(-S // tile)
     return n * (n + 1) // 2
+
+
+def packed_tile(i, c):
+    """Where the [key block c, query block i] tile of an [S, S] array lies
+    among its causal tiles alone, query block by query block; a tile above
+    the diagonal (c > i: never written, never read) maps to the diagonal's."""
+    return i * (i + 1) // 2 + jnp.minimum(c, i)
 
 
 def tile_overcompute(S, topk, tile):
@@ -216,7 +231,10 @@ def _indexer_kernel(iq_ref, ik_ref, wt_ref, out_ref, *, heads):
         out_ref[0] = jnp.where(acc == 0, 0.0, acc)      # -0.0 sorts as 0.0
 
 
-def _index_scores_fwd_call(iq, ik, wt, tile, interpret):
+def _index_scores(iq, ik, iw, tile, interpret):
+    """IT [B, S_k, S_q] float32. No derivative of its own: the selection
+    reads it under ``stop_gradient`` and the KL's rule (``_index_kl``) goes
+    from its cotangent to the three operands itself."""
     B, J, S, Di = iq.shape
     n = S // tile
     call = pl.pallas_call(
@@ -234,11 +252,11 @@ def _index_scores_fwd_call(iq, ik, wt, tile, interpret):
         compiler_params=_params(interpret, None,
                                 ("parallel", "arbitrary", "arbitrary")))
     with annotate("dsa_indexer"):
-        return call(iq, ik, wt)
+        return call(iq, ik, jnp.swapaxes(iw, 1, 2))
 
 
-def _indexer_bwd_kernel(iq_ref, qw_ref, ik_ref, gt_ref, u_ref, dk_ref, *,
-                        heads):
+def _indexer_bwd_kernel(iq_ref, qw_ref, ik_ref, gt_ref, g_ref, u_ref, dk_ref,
+                        *, heads):
     qi, ki = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -248,7 +266,9 @@ def _indexer_bwd_kernel(iq_ref, qw_ref, ik_ref, gt_ref, u_ref, dk_ref, *,
     @pl.when(ki <= qi)
     def _tile_grads():
         k = ik_ref[0]
-        g_all = gt_ref[0]                               # [Tk, Tq]
+        # [Tk, Tq]: the KL's gradient times its cotangent a query
+        g_all = (gt_ref[0, 0].astype(jnp.float32) * g_ref[0]) \
+            .astype(gt_ref.dtype)
         dk = jnp.zeros(k.shape, jnp.float32)
         for j in range(heads):
             q = iq_ref[0, j]
@@ -263,10 +283,12 @@ def _indexer_bwd_kernel(iq_ref, qw_ref, ik_ref, gt_ref, u_ref, dk_ref, *,
         dk_ref[0, 0] = dk
 
 
-def _index_scores_bwd_call(iq, qw, ik, gt, tile, interpret):
+def _index_scores_bwd_call(iq, qw, ik, gt, g, tile, interpret):
     """(u [B, J, S, Di] float32: sum_s G[t, s] 1[iq . ik > 0] ik[s], without
     the weight; dik partials [B, S / tile, S, Di] float32, a query block's
-    share of every key block at or under it, the rest never written)."""
+    share of every key block at or under it, the rest never written) of G =
+    ``gt`` (the causal tiles, ``_kl_call``'s) x ``g`` [B, 1, S] float32 a
+    query, rounded to ``gt``'s dtype."""
     B, J, S, Di = iq.shape
     n = S // tile
     q_spec = pl.BlockSpec((1, J, tile, Di), lambda b, i, c: (b, 0, i, 0))
@@ -277,8 +299,9 @@ def _index_scores_bwd_call(iq, qw, ik, gt, tile, interpret):
             q_spec, q_spec,
             pl.BlockSpec((1, tile, Di),
                          lambda b, i, c: (b, jnp.minimum(c, i), 0)),
-            pl.BlockSpec((1, tile, tile),
-                         lambda b, i, c: (b, jnp.minimum(c, i), i))],
+            pl.BlockSpec((1, 1, tile, tile),
+                         lambda b, i, c: (b, packed_tile(i, c), 0, 0)),
+            pl.BlockSpec((1, 1, tile), lambda b, i, c: (b, 0, i))],
         out_specs=[
             q_spec,
             pl.BlockSpec((1, 1, tile, Di),
@@ -289,38 +312,7 @@ def _index_scores_bwd_call(iq, qw, ik, gt, tile, interpret):
         compiler_params=_params(interpret, _KL_VMEM_BYTES,
                                 ("parallel", "arbitrary", "arbitrary")))
     with annotate("dsa_indexer_bwd"):
-        return call(iq, qw, ik, gt)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _index_scores(iq, ik, iw, tile, interpret):
-    return _index_scores_fwd_call(iq, ik, jnp.swapaxes(iw, 1, 2), tile,
-                                  interpret)
-
-
-def _index_scores_fwd(iq, ik, iw, tile, interpret):
-    return _index_scores(iq, ik, iw, tile, interpret), (iq, ik, iw)
-
-
-def _index_scores_bwd(tile, interpret, residuals, gt):
-    iq, ik, iw = residuals
-    S, n = iq.shape[2], iq.shape[2] // tile
-    w = jnp.swapaxes(iw, 1, 2)[..., None]               # [B, J, S, 1]
-    qw = (iq.astype(jnp.float32) * w).astype(iq.dtype)
-    u, parts = _index_scores_bwd_call(iq, qw, ik, gt.astype(iq.dtype), tile,
-                                      interpret)
-    with annotate("dsa_indexer_bwd_sum"):
-        diq = (u * w).astype(iq.dtype)
-        diw = jnp.swapaxes(jnp.sum(u * iq.astype(jnp.float32), axis=-1), 1, 2)
-        # a query block's partial counts for the key blocks at or under it
-        held = np.tril(np.ones((n, n), bool))[None, :, :, None, None]
-        parts = parts.reshape(parts.shape[0], n, n, tile, -1)
-        dik = jnp.sum(jnp.where(held, parts, 0.0), axis=1) \
-            .reshape(-1, S, ik.shape[-1]).astype(ik.dtype)
-    return diq, dik, diw.astype(iw.dtype)
-
-
-_index_scores.defvjp(_index_scores_fwd, _index_scores_bwd)
+        return call(iq, qw, ik, gt, g)
 
 
 # ------------------------------------------------------------ selection
@@ -658,14 +650,14 @@ def _kl_kernel(q_ref, k_ref, lse_ref, mt_ref, it_ref, lsei_ref, kl_ref,
         term = jnp.where(seen, p * (jnp.log(jnp.where(seen, p, 1.0)) - logq),
                          0.0)
         kl_ref[0] += jnp.sum(term, axis=0, keepdims=True)
-        gt_ref[0] = jnp.where(keep, jnp.exp(logq) - p, 0.0) \
+        gt_ref[0, 0] = jnp.where(keep, jnp.exp(logq) - p, 0.0) \
             .astype(gt_ref.dtype)
 
 
 def _kl_call(it, lse_i, q, k, lse, mt, scale, tile, interpret, gt_dtype):
-    """(kl [B, 1, S] float32, GT [B, S_k, S_q]: softmax_S(I) - p on the
-    selected pairs of the causal tiles; the tiles above the diagonal are
-    not visited and hold nothing to read)."""
+    """(kl [B, 1, S] float32, GT [B, n (n + 1) / 2, tile, tile] of n = S /
+    tile: softmax_S(I) - p on the selected pairs, 0 on the others, the
+    [key, query] tiles at or under the diagonal alone, at ``packed_tile``)."""
     B, H, S, D = q.shape
     Hkv = k.shape[1]
     n = S // tile
@@ -685,9 +677,11 @@ def _kl_call(it, lse_i, q, k, lse, mt, scale, tile, interpret, gt_dtype):
             pl.BlockSpec((1, H, tile // piece, 1, piece),
                          lambda b, i, c: (b, 0, i, 0, 0)),
             tile_spec, tile_spec, row],
-        out_specs=[row, tile_spec],
+        out_specs=[row, pl.BlockSpec(
+            (1, 1, tile, tile), lambda b, i, c: (b, packed_tile(i, c), 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((B, 1, S), jnp.float32),
-                   jax.ShapeDtypeStruct((B, S, S), gt_dtype)],
+                   jax.ShapeDtypeStruct((B, n * (n + 1) // 2, tile, tile),
+                                        gt_dtype)],
         scratch_shapes=[pltpu.VMEM((tile, tile), jnp.float32)],
         interpret=interpret,
         compiler_params=_params(interpret, _KL_VMEM_BYTES,
@@ -696,29 +690,38 @@ def _kl_call(it, lse_i, q, k, lse, mt, scale, tile, interpret, gt_dtype):
         return call(q, k, lse, mt, it, lse_i)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
-def _index_kl(it, lse_i, q, k, lse, mt, scale, tile, interpret, gt_dtype):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
+def _index_kl(iq, ik, iw, it, lse_i, q, k, lse, mt, scale, tile, interpret):
+    """kl [B, 1, S] of IT = ``_index_scores(iq, ik, iw)``, the caller's. ONE
+    rule from d kl to the indexer's three operands, which is all it reaches:
+    the forward rule leaves GT under ``KL_GRAD_NAME``, the backward rule
+    hands it with the cotangent to ``dsa_indexer_bwd``."""
     return _kl_call(it, lse_i, q, k, lse, mt, scale, tile, interpret,
-                    gt_dtype)[0]
+                    iq.dtype)[0]
 
 
-def _index_kl_fwd(it, lse_i, q, k, lse, mt, scale, tile, interpret,
-                  gt_dtype):
+def _index_kl_fwd(iq, ik, iw, it, lse_i, q, k, lse, mt, scale, tile,
+                  interpret):
     kl, gt = _kl_call(it, lse_i, q, k, lse, mt, scale, tile, interpret,
-                      gt_dtype)
-    return kl, (gt, lse_i, q, k, lse)
+                      iq.dtype)
+    return kl, (iq, ik, iw, checkpoint_name(gt, KL_GRAD_NAME))
 
 
-def _index_kl_bwd(scale, tile, interpret, gt_dtype, residuals, g):
-    gt, lse_i, q, k, lse = residuals
-    with annotate("dsa_kl_bwd"):
-        # tiles above the diagonal were never written: nothing of them is
-        # selected, and what they hold is not a number to multiply
-        at = jnp.arange(gt.shape[1]) // tile
-        d_it = jnp.where(at[:, None] <= at[None, :],
-                         gt.astype(jnp.float32) * g, 0.0)
-    return (d_it, jnp.zeros_like(lse_i), jnp.zeros_like(q),
-            jnp.zeros_like(k), jnp.zeros_like(lse), None)
+def _index_kl_bwd(scale, tile, interpret, residuals, g):
+    iq, ik, iw, gt = residuals
+    S, n = iq.shape[2], iq.shape[2] // tile
+    w = jnp.swapaxes(iw, 1, 2)[..., None]               # [B, J, S, 1]
+    qw = (iq.astype(jnp.float32) * w).astype(iq.dtype)
+    u, parts = _index_scores_bwd_call(iq, qw, ik, gt, g, tile, interpret)
+    with annotate("dsa_indexer_bwd_sum"):
+        diq = (u * w).astype(iq.dtype)
+        diw = jnp.swapaxes(jnp.sum(u * iq.astype(jnp.float32), axis=-1), 1, 2)
+        # a query block's partial counts for the key blocks at or under it
+        held = np.tril(np.ones((n, n), bool))[None, :, :, None, None]
+        parts = parts.reshape(parts.shape[0], n, n, tile, -1)
+        dik = jnp.sum(jnp.where(held, parts, 0.0), axis=1) \
+            .reshape(-1, S, ik.shape[-1]).astype(ik.dtype)
+    return (diq, dik, diw.astype(iw.dtype)) + (None,) * 6
 
 
 _index_kl.defvjp(_index_kl_fwd, _index_kl_bwd)
@@ -747,17 +750,19 @@ def learned_sparse_attention(q, k, v, index_q, index_k, index_w, topk,
     block, chunk = _plan(Sp, D, jnp.dtype(q.dtype).itemsize, interpret)
     default_registry().gauge("attention/dsa_tile_overcompute").set(
         tile_overcompute(S, int(topk), tile))
-    it = _index_scores(index_q, index_k, index_w.astype(jnp.float32), tile,
+    index_w = index_w.astype(jnp.float32)
+    it = _index_scores(*(jax.lax.stop_gradient(x)
+                         for x in (index_q, index_k, index_w)), tile,
                        bool(interpret))
     mt, lse_i, count, bits = pin_selection(*_select_call(
-        jax.lax.stop_gradient(it), int(topk), bool(interpret)))
+        it, int(topk), bool(interpret)))
     o, lse = _masked_attention(
         q.reshape(B * H, Sp, D), k.reshape(B * Hkv, Sp, D),
         v.reshape(B * Hkv, Sp, D), mt, scale, block, chunk, bool(interpret),
         H, Hkv)
-    kl = _index_kl(it, lse_i, jax.lax.stop_gradient(q),
-                   jax.lax.stop_gradient(k), jax.lax.stop_gradient(lse), mt,
-                   scale, tile, bool(interpret),
-                   jnp.dtype(index_q.dtype).name)
+    kl = _index_kl(index_q, index_k, index_w, it, lse_i,
+                   jax.lax.stop_gradient(q), jax.lax.stop_gradient(k),
+                   jax.lax.stop_gradient(lse), mt, scale, tile,
+                   bool(interpret))
     return (o.reshape(B, H, Sp, D)[:, :, :S], kl[:, 0, :S], count[:, 0, :S],
             bits[:, :-(-S // 8), :S])

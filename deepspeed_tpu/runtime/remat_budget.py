@@ -43,7 +43,9 @@ took, ``remat/scan_states_kept`` whether the scan kernels' forward-rule
 outputs (``ops/pallas/scan_residuals.py``) were among it. A stack that prunes
 its attention by a learned indexer keeps one name more whatever the memory —
 the selection (``selection_pin_bytes``, counted from shapes:
-``remat/selection_pin_mb``).
+``remat/selection_pin_mb``) — and, where its bytes fit what the selection and
+the reserve leave, the KL's gradient in the indexer's scores
+(``keep_kl_grad``: ``remat/dsa_kl_grad_mb``, ``remat/dsa_kl_grad_kept``).
 """
 
 from deepspeed_tpu.ops.pallas.scan_residuals import SCAN_NAME
@@ -165,4 +167,52 @@ def keep_for_stack(candidates, rows, hidden, layers, itemsize, row_bytes,
             + f" MB; {rows} rows x {layers} layers, {free / 1e6:.0f} MB free "
             f"before a reserve of {reserve / 1e6:.0f} MB: "
             f"{inflight_row_bytes} bytes a row in flight)", ranks=[0])
+    return kept
+
+
+def kl_grad_bytes(batch, seq, layers, itemsize, tile=512):
+    """Bytes a stack whose layers prune their attention by a learned indexer
+    holds under the kernels' ``KL_GRAD_NAME`` where its blocks keep it: a
+    layer's KL gradient in the indexer's scores, the causal tiles alone, in
+    the indexer's dtype. 277 MB a layer at 16,384 tokens in bf16."""
+    from deepspeed_tpu.ops.pallas.learned_sparse_attention import \
+        tiles_walked
+    return layers * batch * tiles_walked(seq, tile) * tile * tile * itemsize
+
+
+def learned_sparse_inflight(seq, itemsize, tile=512):
+    """Bytes a row an attention branch pruned by a learned indexer holds
+    beside ``attention_inflight``'s: a query's row of the float32 scores, of
+    the int8 mask and of its transpose (the sequence padded to the kernels'
+    tile), and its share of one layer's KL gradient as ``kl_grad_bytes``
+    counts it."""
+    padded = -(-seq // tile) * tile
+    return 6 * padded + -(-kl_grad_bytes(1, seq, 1, itemsize, tile) // seq)
+
+
+def keep_kl_grad(batch, seq, hidden, layers, itemsize, inflight_row_bytes,
+                 tile=512):
+    """Whether the blocks of the stack being traced keep ``KL_GRAD_NAME``:
+    its bytes fit the scope's free bytes less the selection's pin less the
+    stack's reserve. Free bytes of 0 (no engine, a kind the table does not
+    know, a step the compiler refused once) keep nothing. Sets
+    ``remat/dsa_kl_grad_mb`` and ``remat/dsa_kl_grad_kept``."""
+    need = kl_grad_bytes(batch, seq, layers, itemsize, tile)
+    free = mesh_lib.pinned_remat_free_bytes()
+    pin = selection_pin_bytes(batch, seq, layers, tile)
+    reserve = reserve_bytes(batch * seq, hidden, layers, itemsize,
+                            inflight_row_bytes)
+    budget = max(0, free - pin - reserve)
+    kept = need <= budget
+    reg = default_registry()
+    reg.gauge("remat/dsa_kl_grad_mb").set(need / 1e6)
+    reg.gauge("remat/dsa_kl_grad_kept").set(int(kept))
+    if free:
+        log_dist(
+            f"rematted blocks {'keep' if kept else 'do not keep'} the KL's "
+            f"gradient in the indexer's scores ({need / 1e6:.0f} MB against "
+            f"a budget of {budget / 1e6:.0f} MB: {free / 1e6:.0f} MB free "
+            f"less the selection's {pin / 1e6:.0f} MB less a reserve of "
+            f"{reserve / 1e6:.0f} MB, {inflight_row_bytes} bytes a row in "
+            f"flight; {batch} x {seq} rows x {layers} layers)", ranks=[0])
     return kept

@@ -190,7 +190,7 @@ def annotate(tag):
     - ``dsa_indexer``, ``dsa_indexer_bwd``, ``dsa_select``, ``dsa_fwd``,
       ``dsa_bwd``, ``dsa_kl`` (ops/pallas/learned_sparse_attention.py, round
       the six ``pallas_call``s of a layer whose attention a learned indexer
-      prunes; ``dsa_select_pin``, ``dsa_kl_bwd``, ``dsa_indexer_bwd_sum``,
+      prunes; ``dsa_select_pin``, ``dsa_indexer_bwd_sum``,
       ``dsa_bwd_dq_sum`` the XLA passes beside them, each under its kernel's
       tag) and ``dsa_index_proj`` (models/llama.py: the indexer's three
       projections, its key's norm and their rotary): ``dsa_attn_share``,
@@ -260,7 +260,11 @@ def annotate(tag):
     ``attention/dsa_selected_share`` (``dsa_selected_share``) and
     ``attention/dsa_kl`` (the indexer's loss L_I, no metric), and
     ``remat/selection_pin_mb`` (what a rematted stack keeps of its layers'
-    selections, no metric).
+    selections, no metric), ``remat/dsa_kl_grad_mb`` and
+    ``remat/dsa_kl_grad_kept`` (what it would hold of the KL's gradient in
+    the indexer's scores, and whether the bytes fit and it does: the
+    recomputed forward then runs neither ``dsa_indexer`` nor ``dsa_kl``; no
+    metric).
     A chunked call whose q·k width is not its value
     width (latent attention) leaves ``attention/mla_qk_dim`` and
     ``attention/mla_v_dim``, the two widths as the kernels saw them (192 /

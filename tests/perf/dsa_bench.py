@@ -3,10 +3,11 @@
 — 32 query / 4 KV heads x 16,384 rows x head_dim 128, an indexer of 16 heads
 x 64, top-2,048, bf16 — one JSON line a stage: wall-clock ms over ``--iters``
 fenced calls after a warm-up of the indexer's scores, the selection, the
-masked forward, the KL pass, and the whole call forward and forward +
-backward; first a line that holds the kernels to the dense plain-XLA form at
-``--check-seq`` rows (output, KL, the selected set, every gradient). Not part
-of the benchmark: PERF.md's Findings quote it.
+masked forward, the KL pass (which leaves its gradient as the causal tiles),
+the indexer's backward from those tiles, and the whole call forward and
+forward + backward; first a line that holds the kernels to the dense
+plain-XLA form at ``--check-seq`` rows (output, KL, the selected set, every
+gradient). Not part of the benchmark: PERF.md's Findings quote it.
 
     chiprun -- python tests/perf/dsa_bench.py
 """
@@ -100,11 +101,17 @@ def main():
     flat = (q.reshape(H, S, D), k.reshape(Hkv, S, D), v.reshape(Hkv, S, D))
     o, lse = jax.jit(lambda *a: L._masked_attention(
         *a, scale, block, chunk, False, H, Hkv))(*flat, mt)
+    _, gt = jax.jit(lambda *a: L._kl_call(
+        *a, scale, tile, False, "bfloat16"))(it, lse_i, q, k, lse, mt)
+    qw = (iq.astype(jnp.float32)
+          * jnp.swapaxes(iw, 1, 2)[..., None]).astype(iq.dtype)
+    g = jnp.full((1, 1, S), 1.0 / S, jnp.float32)
     print(json.dumps({"selected_share": float(n.sum()) / L.causal_pairs(S),
                       "expected": L.selected_pairs(S, topk)
                       / L.causal_pairs(S),
                       "tile_overcompute": L.tile_overcompute(S, topk, tile),
-                      "plan": [tile, block, chunk]}), flush=True)
+                      "plan": [tile, block, chunk],
+                      "kl_grad_mb": gt.nbytes / 1e6}), flush=True)
     whole = lambda *a: L.learned_sparse_attention(*a, topk, scale)  # noqa: E731
     stages = {
         "indexer": (lambda *a: L._index_scores(*a, tile, False),
@@ -114,6 +121,8 @@ def main():
             *a, scale, block, chunk, False, H, Hkv), flat + (mt,)),
         "kl": (lambda *a: L._kl_call(*a, scale, tile, False, "bfloat16"),
                (it, lse_i, q, k, lse, mt)),
+        "indexer_bwd": (lambda *a: L._index_scores_bwd_call(*a, tile, False),
+                        (iq, qw, ik, gt, g)),
         "call_fwd": (whole, (q, k, v, iq, ik, iw)),
         "call_fwd_bwd": (jax.grad(
             lambda *a: (lambda o, kl, *_: o.astype(jnp.float32).sum()

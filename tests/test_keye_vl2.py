@@ -224,6 +224,54 @@ def test_a_rematted_block_selects_as_the_first_forward_did(weights):
         == 2 * (128 * 128 // 8 + 8 * 128)
 
 
+@pytest.mark.parametrize("free,kept", [
+    (10 ** 9, 1),
+    # the name's own bytes and not one more: nothing for the selection's pin
+    # and the reserve
+    (2 * 512 * 512 * 4, 0),
+    (0, 0)], ids=["fits", "does-not-fit", "no-engine"])
+def test_the_kl_gradients_name_joins_the_policy_where_its_bytes_fit(
+        weights, free, kept):
+    """The rematted two-layer scanned model on the kernels (interpreted):
+    ``_pinned`` puts ``KL_GRAD_NAME`` into the blocks' policy when the
+    trace's free bytes cover it beside the selection's pin and the reserve —
+    the backward pass then holds no indexer or KL kernel — and leaves
+    today's two passes when they do not; the two gauges say which."""
+    import collections
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+    from deepspeed_tpu.runtime import remat_budget
+    from deepspeed_tpu.telemetry.registry import default_registry
+    from tests.hlo_text import pallas_calls
+    ids = jnp.asarray(_ids(5))
+    cfg = dataclasses.replace(family.model_config(F32_CONFIG, True),
+                              use_flash=True)
+    assert cfg.remat and cfg.scan_layers and cfg.n_layers == 2
+    model = llama.LlamaForCausalLM(cfg)
+
+    def loss(p):
+        out, vs = model.apply({"params": p}, ids, labels=ids,
+                              mutable=["losses", "stats"])
+        return out + sum(jnp.sum(x) for x in jax.tree_util.tree_leaves(
+            vs["losses"]))
+
+    with mesh_lib.layout_pins(None, remat_free_bytes=free):
+        jaxpr = jax.make_jaxpr(jax.grad(loss))(weights).jaxpr
+    kernels = collections.Counter(
+        eqn.params["jaxpr"].debug_info.func_name
+        for eqn in pallas_calls(jaxpr))
+    again = 1 - kept
+    assert (kernels["_indexer_kernel"], kernels["_kl_kernel"]) \
+        == (1 + again, 1 + again)
+    assert (kernels["_select_kernel"], kernels["_masked_fwd_kernel"],
+            kernels["_masked_bwd_kernel"], kernels["_indexer_bwd_kernel"]) \
+        == (1, 1, 1, 1)
+    gauge = default_registry().peek_gauge
+    assert gauge("remat/dsa_kl_grad_kept") == kept
+    # 128 tokens are one tile of the chip's 512, float32, two layers
+    assert gauge("remat/dsa_kl_grad_mb") * 1e6 == 2 * 512 * 512 * 4 \
+        == remat_budget.kl_grad_bytes(1, SEQ, 2, 4)
+
+
 # ------------------------------------------------------ the switch is off
 
 def test_without_an_indexer_the_model_and_the_step_are_as_before(weights):
@@ -263,7 +311,9 @@ def test_without_an_indexer_the_model_and_the_step_are_as_before(weights):
 @pytest.mark.parametrize("name", ["attention/dsa_tile_overcompute",
                                   "attention/dsa_selected_share",
                                   "attention/dsa_kl",
-                                  "remat/selection_pin_mb"])
+                                  "remat/selection_pin_mb",
+                                  "remat/dsa_kl_grad_mb",
+                                  "remat/dsa_kl_grad_kept"])
 def test_the_gauges_are_documented_and_the_scopes_listed(name):
     """docs/observability.md's tables and ``spans.annotate``'s list."""
     from deepspeed_tpu.telemetry import spans
@@ -272,7 +322,11 @@ def test_the_gauges_are_documented_and_the_scopes_listed(name):
     assert name in spans.annotate.__doc__
     docs = open(os.path.join(manifest.ROOT, "docs",
                              "observability.md")).read()
+    # an XLA pass no program has since PR 66 (the family's module tags keep
+    # the name: that file is the benchmark's)
+    assert "dsa_kl_bwd" not in spans.annotate.__doc__ + docs
+    names = ("dsa_selection", "dsa_kl_grad")
     for scope in family.DSA_TAGS + ("dsa_index_proj", "dsa_select_pin",
-                                    "dsa_bwd_dq_sum", "dsa_selection"):
+                                    "dsa_bwd_dq_sum") + names:
         assert scope in docs, scope
-        assert scope == "dsa_selection" or scope in spans.annotate.__doc__
+        assert scope in names or scope in spans.annotate.__doc__

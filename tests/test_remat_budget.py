@@ -1,5 +1,6 @@
 """The byte budget of what a rematted block keeps (``runtime/remat_budget.py``):
-the selection, a name's bytes at two cells' real shapes, the reserve counted
+the selection, the KL gradient of a learned-sparse stack, a name's bytes at
+two cells' real shapes, the reserve counted
 from each cell's shapes against what its program compiled to, the engine's
 figure on abstract state, and the fall-back when the compiler refuses the
 program.
@@ -186,6 +187,68 @@ def test_the_reserve_from_a_cells_shapes_covers_its_compiled_peak(cell):
     assert gauge("remat/scan_states_kept") == ("scan_states" in want)
     # every name kept and the reserve fit what the chip has left
     assert gauge("remat/kept_mb") + gauge("remat/reserve_mb") <= free_mb
+
+
+# the twelfth cell's stack (``models/llama.py``: none of the candidates, one
+# name of its own — the KL's gradient in the indexer's scores) as ``CELLS``
+# has the others: MB free as the engine counts it, and the widths of its
+# stream the program compiled with the name kept (peak 15.021 GB, PERF.md
+# Findings PR 66) held beside the engine's bytes, a block input a layer, the
+# selection's pin and the kept gradient
+KEYE = ("keyevl2-train-1chip-s16384", 6683, 52.6)
+
+
+@pytest.mark.parametrize("seq,layers,free_mb,kept", [
+    (16384, 6, 6683, 1),      # the cell: 1,661 MB of a budget of 2,412
+    (16384, 6, 5900, 0),      # 0.8 GB less free: 1,629 left
+    (32768, 6, 6683, 0),      # twice the tokens: 6.5 GB of tiles
+    (16384, 12, 6683, 0),     # twice the layers a chip: 3.3 GB
+    (16384, 6, 0, 0)],        # no engine, or a step refused once
+    ids=["the-cell", "less-free", "longer", "deeper", "zero"])
+def test_the_keye_stack_keeps_the_kl_gradient_by_its_bytes(seq, layers,
+                                                           free_mb, kept):
+    """A layer's gradient is its 528 causal tiles of 512 x 512 in bf16 at
+    16,384 tokens — 277 MB, 1.66 GB for six, where the dense array is 537 MB
+    a layer; kept where that fits the free bytes less the selection's pin
+    less the reserve, which covers what the cell's program compiled to."""
+    import dataclasses
+    from benchmark import manifest
+    from deepspeed_tpu.models import llama
+    from deepspeed_tpu.parallel import mesh as mesh_lib
+    from deepspeed_tpu.telemetry.registry import default_registry
+    assert rb.kl_grad_bytes(1, 16384, 1, 2) == 528 * 512 * 512 * 2 \
+        == 276_824_064
+    assert rb.kl_grad_bytes(1, 16384, 6, 2) == 1_660_944_384
+    # a padded sequence: 1,000 tokens are 8 tiles of 128 a side, 36 causal
+    assert rb.kl_grad_bytes(2, 1000, 3, 4, tile=128) \
+        == 3 * 2 * 36 * 128 * 128 * 4
+    name, cell_free_mb, compiled_widths = KEYE
+    bench = manifest.load()
+    config = manifest.config_of(bench, manifest.cell_of(bench, name))
+    cfg = dataclasses.replace(
+        manifest.family_module(config).model_config(config, False),
+        n_layers=layers)
+    inflight = llama.remat_inflight_row_bytes(cfg, seq)
+    reserve = rb.reserve_bytes(seq, cfg.hidden_size, layers, 2, inflight)
+    if (seq, layers) == (16384, 6):
+        # the attention branch is the widest: q, o, their cotangents, dk, dv
+        # and the dq partials, a row of the float32 scores, of the mask and
+        # of its transpose, and 1 / 16,384 of a layer's gradient
+        assert inflight == 92160 + 6 * 16384 + 276_824_064 // 16384
+        assert reserve / (seq * cfg.hidden_size * 2) - layers \
+            >= compiled_widths
+        assert cell_free_mb * 10 ** 6 >= reserve + 1_660_944_384 \
+            + rb.selection_pin_bytes(1, seq, layers)
+    x = jax.ShapeDtypeStruct((1, seq, cfg.hidden_size), jnp.bfloat16)
+    policy = jax.checkpoint_policies.nothing_saveable
+    with mesh_lib.layout_pins(None, remat_free_bytes=free_mb * 10 ** 6):
+        assert llama._pinned(cfg, policy, x) is not policy
+    gauge = default_registry().peek_gauge
+    assert gauge("remat/dsa_kl_grad_kept") == kept
+    need = rb.kl_grad_bytes(1, seq, layers, 2)
+    assert gauge("remat/dsa_kl_grad_mb") == pytest.approx(need / 1e6)
+    pin = rb.selection_pin_bytes(1, seq, layers)
+    assert kept == (need + pin + reserve <= free_mb * 10 ** 6)
 
 
 def test_a_stack_with_no_figures_keeps_the_base_names():

@@ -1216,16 +1216,21 @@ def test_block_diffusion_attention_compiles_at_the_sdar_cells_shape(
     assert_dense_lse_kept(hlo, calls, f"f32[{heads},{2 * L // 128},1,128]")
 
 
-def test_learned_sparse_attention_compiles_at_the_keye_cells_shape():
+@pytest.mark.parametrize("kept", [True, False],
+                         ids=["kl-grad-kept", "kl-grad-recomputed"])
+def test_learned_sparse_attention_compiles_at_the_keye_cells_shape(kept):
     """1 x 32 / 4 heads x 16,384 rows x head_dim 128, an indexer of 16 heads
     of 64 keeping 2,048 keys, bf16 (``keyevl2-train-1chip-s16384``), forward
     and backward under the blocks' remat policy with the selection's name:
     the six kernels under their scopes; the selection and the pruned forward
     kernel ONCE (the recomputed forward reads the kept bits and ``flash_o`` /
-    ``flash_lse``), the indexer's scores and the KL pass twice; the mask
-    [16384, 16384] int8 is the one dense array of the step beside the
-    float32 scores and the KL's gradient in them, and none has a head axis;
-    the kept bits are [2048, 16384] uint8."""
+    ``flash_lse``). With the KL gradient's name kept (the cell's program: the
+    bytes fit) the indexer's scores and the KL pass run once too, and the
+    backward forms no [S, S] array but the mask; without it (a budget of
+    zero) both run again in the recomputation. The mask [16384, 16384] int8
+    is the one dense array of the step beside the forward's float32 scores —
+    the KL's gradient in them is its 528 causal tiles — and none has a head
+    axis; the kept bits are [2048, 16384] uint8."""
     from deepspeed_tpu.models.gpt2 import block_remat_policy
     from deepspeed_tpu.ops import attention
     from deepspeed_tpu.ops.pallas import learned_sparse_attention as lsa
@@ -1238,7 +1243,7 @@ def test_learned_sparse_attention_compiles_at_the_keye_cells_shape():
 
     policy = jax.checkpoint_policies.save_from_both_policies(
         block_remat_policy(), jax.checkpoint_policies.save_only_these_names(
-            lsa.SELECTION_NAME))
+            lsa.SELECTION_NAME, *((lsa.KL_GRAD_NAME,) if kept else ())))
 
     def grads(*a):
         # the value too, as a step takes it: the forward pass's KL is read
@@ -1259,7 +1264,13 @@ def test_learned_sparse_attention_compiles_at_the_keye_cells_shape():
         and re.search(r'op_name="[^"]*/' + scope + "/", ln))
     assert (count("dsa_select"), count("dsa_fwd"), count("dsa_bwd"),
             count("dsa_indexer_bwd")) == (1, 1, 1, 1)
-    assert (count("dsa_indexer"), count("dsa_kl")) == (2, 2)
+    assert (count("dsa_indexer"), count("dsa_kl")) == \
+        ((1, 1) if kept else (2, 2))
+    # a layer's KL gradient is its causal tiles; the float32 scores are each
+    # ``dsa_indexer`` call's output and nothing else of that shape is formed
+    assert f"bf16[1,{lsa.tiles_walked(S, 512)},512,512]" in hlo
+    assert len(re.findall(rf"= f32\[1,{S},{S}\]", hlo)) == (1 if kept else 2)
+    assert f"bf16[1,{S},{S}]" not in hlo
     assert f"u8[{S // 8},{S}]" in hlo
     assert f"{H},{S},{S}" not in hlo and f"{S},{S},{H}" not in hlo
     assert compiled.memory_analysis().temp_size_in_bytes < 6 * 2 ** 30
