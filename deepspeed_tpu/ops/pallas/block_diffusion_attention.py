@@ -180,23 +180,15 @@ def _bd_fwd_kernel(qi_of, kc_of, lo_of, full_of, hi_of, rule_of, first_of,
     q = q_ref[0] * scale if fold else q_ref[0]
     diff = _block_diff(block, sub, True) if sub else None
 
-    @pl.when(first_of[t] == 1)
-    def _init():
-        o_ref[0] = jnp.zeros_like(o_ref[0])
-        m_ref[...] = jnp.full_like(m_ref, fa.NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    def body(j, carry, masked):
+    def tile(j, masked):
         rows = pl.ds(pl.multiple_of(j * block, block), block)
-        mask = _rule_mask(diff, rule_of[t]) if masked and sub else None
-        return fa._fwd_block_step(q, k_ref[0, rows, :], v_ref[0, rows, :],
-                                  carry, mask, s_scale)
+        return (k_ref[0, rows, :], v_ref[0, rows, :],
+                _rule_mask(diff, rule_of[t]) if masked and sub else None)
 
-    o, m, l = fa._causal_split_loop(
-        lo_of[t], full_of[t], hi_of[t], body,
-        (o_ref[0], m_ref[...], l_ref[...]))
-    fa._finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l,
-                           last_of[t] == 1)
+    fa._fwd_walk(q, tile, [(lo_of[t], full_of[t], False),
+                           (full_of[t], hi_of[t], True)],
+                 o_ref.at[0], m_ref, l_ref, s_scale, first_of[t] == 1)
+    fa._finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, last_of[t] == 1)
 
 
 def _bd_bwd_kernel(qi_of, kc_of, lo_of, full_of, hi_of, rule_of, first_of,

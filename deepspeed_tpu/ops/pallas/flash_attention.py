@@ -57,16 +57,16 @@ Three kernel families share the same per-tile math (`_fwd_block_step` /
   v5e (PERF.md Findings PR 39; gauge
   ``attention/flash_grid_steps_walked_share``); a call that is not
   causal walks the rectangle, and a band would be a third list for the
-  same kernels. A step that DOES work has a fixed cost too (the (o, m, l)
-  carry read and written back, q re-scaled, the relative-position tile
-  rebuilt, the pipeline's turn-over), so a chunk is as many rows as
+  same kernels. A step that DOES work has a fixed cost too (q re-scaled,
+  the pipeline's turn-over; until PR 67 also the (o, m, l) carry's round
+  trip and a relative-position tile rebuilt), so a chunk is as many rows as
   ``_CHUNK_BYTES`` of K + V allow (``_pick_chunk``; gauge
   ``attention/flash_chunk_rows``): 80 pairs a head of 32 x 4 at S 16,384
   with blocks of 512 and chunks of 4,096 (272 of 32 x 16 at the 1,024 rows
   they had before PR 48), 40 of 16 x 4 at S 8,192 / head_dim 256 / 2,048
   (136), 8 at S 4,096 / one chunk (20). The forward's softmax m/l state
-  lives in fp32 VMEM
-  scratch; normalization happens in-kernel on a block's last chunk, which
+  lives in fp32 VMEM scratch and o in its output block (``_fwd_walk``: no
+  loop carries them); it is normalized in-kernel on a block's last chunk, which
   also writes lse lane-dense ([BH, S / 128, 1, 128] as the whole-row
   kernels store it — a [BH, S, 1] column is 128 x its
   values' size in HBM, which kept a rematted block from holding it:
@@ -104,7 +104,7 @@ Three kernel families share the same per-tile math (`_fwd_block_step` /
   cost 2.1-3.1 us for a 512 x 512 x 128 tile (PERF.md Findings PR 43):
   4,608 rows at W 4,096, 1,024 at W 512. A band whose rows pass
   ``_BAND_BYTES`` (or the caller's ``chunk=`` cap) goes in the FEWEST equal
-  steps that fit (``_band_plan``), through the raw (o, m, l) carry of the
+  steps that fit (``_band_plan``), through the raw (o, m, l) state of the
   chunked family; neither compute nor DMA is spent outside the band.
   Blocks wholly inside the band run unmasked; the edge blocks take the
   causal and the lower-bound compare (``_band_mask``). The BACKWARD is ONE
@@ -956,28 +956,50 @@ def _kv_row(heads, kv_heads):
     return lambda b: b
 
 
-def _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l, last):
-    """What a grid step of the chunked and the window forward leaves: the
-    raw (o, m, l) while a query block's walk goes on — o in its revisited
-    float32 output block, m (lane-replicated) and l (per-lane partial sums)
-    in VMEM scratch — and on the walk's ``last`` step the normalised o and
-    lse = m + log l, lane-dense through ``_dense_row``: no separate
-    [BH, S, D] normalisation pass, and no [BH, S, 1] statistic, in HBM.
-    ``last`` Python's own True (``_walk_phase``): a walk of one step, which
-    carries nothing and has no ``m_ref`` / ``l_ref``."""
-    piece = lse_ref.shape[-1]
+def _fwd_walk(q, tile, segments, o_ref, m_ref, l_ref, scale, first):
+    """The forward walk of one grid step over its k-tiles, IN PLACE:
+    ``_fwd_block_step`` on tile after tile with the softmax state where it
+    lives between grid steps anyway — o in the revisited float32 output block
+    (``o_ref``: its [rows, D] view), m and l in VMEM scratch — cleared on a
+    query block's ``first`` grid step, read and written a tile, and NOTHING
+    carried by the loops: as loop-carried values the 192 vregs of (o, m, l)
+    at 512 rows were spilled and copied slot to slot at every tile's head and
+    tail, ~370 of its 1,426 bundles with the MXU idle and the one store slot
+    full (PERF.md, PR 67). ``segments``: [(lo, hi, masked)] ranges of tile
+    indices (traced bounds, ``masked`` static), walked in order;
+    ``tile(j, masked)`` -> (k, v, mask) of tile j."""
+    @pl.when(first)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(False if last is True else jnp.logical_not(last))
-    def _carry():
-        o_ref[0] = o
-        m_ref[...] = m
-        l_ref[...] = l
+    def step(j, _, masked):
+        k, v, mask = tile(j, masked)
+        o_ref[...], m_ref[...], l_ref[...] = _fwd_block_step(
+            q, k, v, (o_ref[...], m_ref[...], l_ref[...]), mask, scale)
+        return _
+
+    for lo, hi, masked in segments:
+        jax.lax.fori_loop(lo, hi, functools.partial(step, masked=masked), 0)
+
+
+def _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, last):
+    """What the ``last`` grid step of a query block's walk leaves, chunked
+    and window forward alike: the normalised o over the raw one ``_fwd_walk``
+    has been keeping in the revisited float32 output block, and lse = m +
+    log l from the scratch m (lane-replicated) and l (per-lane partial sums),
+    lane-dense through ``_dense_row``: no separate [BH, S, D] normalisation
+    pass, and no [BH, S, 1] statistic, in HBM. ``last`` may be Python's own
+    True (``_walk_phase``: a walk of one step)."""
+    piece = lse_ref.shape[-1]
 
     @pl.when(last)
     def _finish():
+        m, l = m_ref[...], l_ref[...]
         total = _row_total(l)
         l_safe = jnp.maximum(total, 1e-30)
-        o_ref[0] = jnp.where(total > 0, o / l_safe, 0.0)
+        o_ref[0] = jnp.where(total > 0, o_ref[0] / l_safe, 0.0)
         lse = m + jnp.log(l_safe)
         for j in range(lse.shape[0] // piece):
             lse_ref[0, j] = _dense_row(lse[j * piece:(j + 1) * piece])
@@ -1037,31 +1059,26 @@ def _fwd_kernel_chunked(i_of, c_of, q_ref, k_ref, v_ref, o_ref, lse_ref,
     fold = _scale_folds(scale)
     s_scale = None if fold else scale
     q = q_ref[0] * scale if fold else q_ref[0]
-    rel = _rel_pos(block_q, block_k) if causal else None
 
-    @pl.when(kc == first)
-    def _init():
-        o_ref[0] = jnp.zeros_like(o_ref[0])
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    def body(j, carry, masked):
+    def tile(j, masked):
+        rows = pl.ds(j * block_k, block_k)
         kb = kc * cb + j                       # global k-block index
-        k = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        mask = _block_mask(rel, masked, qi * block_q, kb * block_k)
-        return _fwd_block_step(q, k, v, carry, mask, s_scale)
+        return (k_ref[0, rows, :], v_ref[0, rows, :],
+                _block_mask(_rel_pos(block_q, block_k) if causal else None,
+                            masked, qi * block_q, kb * block_k)
+                if masked else None)
 
-    carry0 = (o_ref[0], m_ref[...], l_ref[...])
     if causal:
         num_full = (qi * block_q) // block_k
         num_active = ((qi + 1) * block_q + block_k - 1) // block_k
         j_full = jnp.clip(num_full - kc * cb, 0, cb)
         j_hi = jnp.clip(num_active - kc * cb, 0, cb)
-        o, m, l = _causal_split_loop(0, j_full, j_hi, body, carry0)
+        segments = [(0, j_full, False), (j_full, j_hi, True)]
     else:
-        o, m, l = _causal_split_loop(0, cb, cb, body, carry0)
-    _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l, kc == last)
+        segments = [(0, cb, False)]
+    _fwd_walk(q, tile, segments, o_ref.at[0], m_ref, l_ref, s_scale,
+              kc == first)
+    _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, kc == last)
 
 
 def _chunked_fwd_outputs(q, block_q, block_k, block_of, width=None):
@@ -1428,32 +1445,14 @@ def _band_keys_spec(walk, block_q, block_k, D, at):
 
 def _walk_phase(c, steps):
     """(first, last) grid step of a walk of ``steps``: Python's own True
-    for a band in one step, whose kernel then has no carry to read, zero
-    or write back."""
+    for a band in one step — the backward then has no dq to carry between
+    steps, the forward clears and normalises its state in the one step
+    (``_fwd_walk``, ``_finish_chunked_fwd``)."""
     return (True, True) if steps == 1 else (c == 0, c == steps - 1)
 
 
-def _walk_carry(first, slots):
-    """What a grid step of a walk starts from. ``slots``: (ref, index,
-    shape, fill) of each float32 accumulator; they are filled on the walk's
-    first step and read on every step, or — a walk of one step — stay
-    untouched (the refs may be None) and the fills are the carry."""
-    fills = [jnp.full(shape, fill, jnp.float32)
-             for _, _, shape, fill in slots]
-    if first is True:
-        return tuple(fills)
-
-    @pl.when(first)
-    def _init():
-        for (ref, at, _, _), fill in zip(slots, fills):
-            ref[at] = fill
-
-    return tuple(ref[at] for ref, at, _, _ in slots)
-
-
-def _swa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state, scale,
-                    window, block_q, block_k, walk):
-    m_ref, l_ref = state or (None, None)    # none for a band in one step
+def _swa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, *,
+                    scale, window, block_q, block_k, walk):
     qi = pl.program_id(1)
     c = pl.program_id(2)
     first, last = _walk_phase(c, walk[1])
@@ -1462,27 +1461,26 @@ def _swa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *state, scale,
     q = q_ref[0] * scale if fold else q_ref[0]
     rel = _rel_pos(block_q, block_k)
     q0 = qi * block_q
-    at, ranges = _band_k_ranges(
+    at, (j_lo, j_a, j_b, j_hi) = _band_k_ranges(
         q0, _band_k_first(qi, c, block_q, block_k, walk), walk[0], block_q,
         block_k, window)
 
-    def body(j, carry, masked):
-        k = k_ref[0, pl.ds(j * block_k, block_k), :]
-        v = v_ref[0, pl.ds(j * block_k, block_k), :]
-        mask = (_band_mask(rel, q0, (at + j) * block_k, window) if masked
+    def tile(j, masked):
+        rows = pl.ds(j * block_k, block_k)
+        return (k_ref[0, rows, :], v_ref[0, rows, :],
+                _band_mask(rel, q0, (at + j) * block_k, window) if masked
                 else None)
-        return _fwd_block_step(q, k, v, carry, mask, s_scale)
 
-    stat = (block_q, _LANES)
-    o, m, l = _band_loop(ranges, body, _walk_carry(first, (
-        (o_ref, 0, q.shape, 0.0), (m_ref, ..., stat, NEG_INF),
-        (l_ref, ..., stat, 0.0))))
+    # edge (masked), inside (unmasked), edge (masked), as ``_band_loop``
+    _fwd_walk(q, tile, [(j_lo, j_a, True), (j_a, j_b, False),
+                        (j_b, j_hi, True)], o_ref.at[0], m_ref, l_ref, s_scale,
+              first)
     # as ``_fwd_kernel_chunked``: raw (o, m, l) between a block's steps, the
     # last step (the diagonal's) normalises in the kernel. A row its band's
     # first block hides whole takes exp(0) there; the next visible key's
     # alpha = exp(NEG_INF - m) = 0 wipes it, and the diagonal is always
     # visible and always last
-    _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, o, m, l, last)
+    _finish_chunked_fwd(o_ref, lse_ref, m_ref, l_ref, last)
 
 
 def _swa_fwd(q, k, v, scale, window, block_q, block_k, band, interpret,
@@ -1503,7 +1501,9 @@ def _swa_fwd(q, k, v, scale, window, block_q, block_k, band, interpret,
                   keys, keys],
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=scratch if steps > 1 else (),
+        # m and l are VMEM scratch for a band in one step too: the walk
+        # keeps its state there whatever the steps (``_fwd_walk``)
+        scratch_shapes=scratch,
         interpret=interpret,
     )
     with annotate("swa_fwd"):

@@ -457,22 +457,15 @@ def _masked_fwd_kernel(i_of, c_of, q_ref, k_ref, v_ref, m_ref, o_ref,
     s_scale = None if fold else scale
     q = q_ref[0] * scale if fold else q_ref[0]
 
-    @pl.when(kc == first)
-    def _init():
-        o_ref[0] = jnp.zeros_like(o_ref[0])
-        mx_ref[...] = jnp.full_like(mx_ref, fa.NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    def body(j, carry):
+    def tile(j, masked):
         rows = pl.ds(pl.multiple_of(j * block, block), block)
-        mask = m_ref[0, :, rows].astype(jnp.int32) != 0
-        return fa._fwd_block_step(q, k_ref[0, rows, :], v_ref[0, rows, :],
-                                  carry, mask, s_scale)
+        return (k_ref[0, rows, :], v_ref[0, rows, :],
+                m_ref[0, :, rows].astype(jnp.int32) != 0)
 
     hi = jnp.clip(qi + 1 - kc * cb, 0, cb)
-    o, m, l = jax.lax.fori_loop(0, hi, body,
-                                (o_ref[0], mx_ref[...], l_ref[...]))
-    fa._finish_chunked_fwd(o_ref, lse_ref, mx_ref, l_ref, o, m, l, kc == last)
+    fa._fwd_walk(q, tile, [(0, hi, True)], o_ref.at[0], mx_ref, l_ref,
+                 s_scale, kc == first)
+    fa._finish_chunked_fwd(o_ref, lse_ref, mx_ref, l_ref, kc == last)
 
 
 def _plan(S, D, itemsize, interpret):

@@ -102,3 +102,63 @@ def _lse_reference(q, k, causal, window):
     if window:
         seen &= rel < window
     return jax.nn.logsumexp(jnp.where(seen, s, -jnp.inf), axis=-1)
+
+
+def carried_walk(count):
+    """The forward walk that ``flash_attention._fwd_walk`` replaced (PR 67),
+    under its signature: the state cleared on a block's first step as its
+    kernels cleared it, then ``_fwd_block_step`` on the same tiles in the
+    same order with (o, m, l) as the loops' CARRIED values, read from the
+    state's refs before the first tile and written back after the last.
+    ``count``: a list that grows by one a traced walk, so a test can tell
+    that the kernel it compared against really ran this walk."""
+    from jax.experimental import pallas as pl
+    fa = _fa()
+
+    def walk(q, tile, segments, o_ref, m_ref, l_ref, scale, first):
+        count.append(1)
+
+        @pl.when(first)
+        def _init():
+            o_ref[...] = jnp.zeros_like(o_ref)
+            m_ref[...] = jnp.full_like(m_ref, fa.NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
+
+        carry = (o_ref[...], m_ref[...], l_ref[...])
+        for lo, hi, masked in segments:
+            def step(j, c, masked=masked):
+                k, v, mask = tile(j, masked)
+                return fa._fwd_block_step(q, k, v, c, mask, scale)
+            carry = jax.lax.fori_loop(lo, hi, step, carry)
+        o_ref[...], m_ref[...], l_ref[...] = carry
+    return walk
+
+
+# H, Hkv, S, D, Dv, dtype, causal, block_q, block_k, chunk — the chunked
+# forward's walks (PR 67): 1, 2 and 8 tiles a grid step, masked and not, the
+# three widths of the cells (64; 128; 192 with values of 128), grouped keys,
+# and a walk that crosses from the unmasked to the masked tiles INSIDE a chunk
+F32, BF16 = jnp.float32, jnp.bfloat16
+CHUNKED_WALKS = {
+    "causal-1-tile-a-step-d64": (2, 2, 256, 64, 64, F32, True, 64, 64, 64),
+    "causal-2-tiles-d64": (2, 2, 256, 64, 64, BF16, True, 64, 64, 128),
+    "causal-8-tiles-crosses-mid-chunk-d128":
+        (2, 1, 1024, 128, 128, BF16, True, 64, 64, 512),
+    "causal-8-tiles-d192-values-128":
+        (2, 2, 512, 192, 128, BF16, True, 64, 64, 512),
+    "causal-grouped-6-to-1-d128":
+        (6, 1, 256, 128, 128, F32, True, 64, 64, 128),
+    "causal-unequal-blocks-d64": (2, 2, 512, 64, 64, F32, True, 64, 32, 256),
+    "full-1-tile-a-step-d128": (2, 2, 256, 128, 128, BF16, False, 64, 64, 64),
+    "full-2-tiles-d192-values-128":
+        (2, 1, 256, 192, 128, F32, False, 64, 64, 128),
+    "full-8-tiles-d64": (4, 2, 512, 64, 64, BF16, False, 64, 64, 512),
+}
+# S, window, cap of a step's rows (0: the band whole), (tiles a step, steps)
+# — the window forward's bands of 1, 2 and 9 tiles at blocks of 64, and a
+# band in several steps, the sequence's first blocks walking steps with no
+# tile at all
+WINDOW_WALKS = {"band-1-tile": (256, 1, 0, (1, 1)),
+                "band-2-tiles": (256, 64, 0, (2, 1)),
+                "band-9-tiles": (1024, 512, 0, (9, 1)),
+                "band-9-tiles-in-5-steps": (1024, 512, 128, (2, 5))}

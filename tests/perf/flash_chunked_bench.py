@@ -11,8 +11,11 @@ dv), so that a tree's whole backward is the sum of its lines other than
 head_dim 128, bf16), ``qwen3next-train-1chip-s8192`` (2 x 16 / 2 heads x
 8,192 x 256) and ``olmoe-train-1chip-s4096`` (4 x 16 / 16 x 4,096 x 128), and
 at ``d64s32k`` (16 heads x 32,768 x 64: the shape whose VMEM overflow set the
-first chunk budget) — with the grid steps a head walks and the us a step:
-every time a DEVICE time from a profiler trace. ``--full`` drops the causal
+first chunk budget) — with the grid steps a head walks, the us a step and
+(PR 67) the line through the plan and the plan at half its chunk — what a
+grid step costs before its first tile (``us_a_step_fixed``) and a tile with
+that taken off (``us_a_tile``): every time a DEVICE time from a profiler
+trace. ``--full`` drops the causal
 mask (ring / Ulysses attention's calls: the rectangle). ``--plans`` sweeps
 (block, chunk) pairs (a plan that does not tile a shape's S is a ``skipped``
 line, one the compiler refuses a ``refused`` line with
@@ -154,6 +157,41 @@ def picked_plan(B, H, Hkv, S, D, dtype):
     return seen["plan"]
 
 
+def step_and_tile_us(us, steps, half_us, half_steps, tiles):
+    """A head's time as a line through two plans that walk the SAME tiles in
+    different numbers of grid steps (the plan and the one at half its
+    chunk): ``us = steps x us_a_step_fixed + tiles x us_a_tile`` — what a
+    grid step costs before its first tile, and a tile with that taken off
+    (Findings PR 48 read it by hand)."""
+    fixed = (half_us - us) / (half_steps - steps)
+    return {"half_chunk_us_a_head": half_us, "us_a_step_fixed": fixed,
+            "us_a_tile": (us - steps * fixed) / tiles}
+
+
+def plan_times(static, q, k, v, do):
+    """[(line's name, ms)] of the forward call and the whole backward under
+    one plan (``static``: the kernels' arguments after the operands)."""
+    fwd = jax.jit(lambda q, k, v: fa._flash_fwd_chunked(q, k, v, *static))
+    bwd = jax.jit(lambda *a: fa._flash_bwd_chunked(*a, *static))
+    o, lse = fwd(q, k, v)
+    return [("fwd", kernel_ms(fwd, q, k, v))] + bwd_times(
+        bwd, q, k, v, o, lse, do)
+
+
+def half_chunk_ms(static, block, operands):
+    """{kernel: ms} of the Pallas calls at HALF the plan's chunk, the second
+    point of ``step_and_tile_us``'s line; {} where half a chunk is no plan
+    (under a block, or refused)."""
+    chunk = static[4] // 2
+    if chunk < block:
+        return {}
+    try:
+        return dict(plan_times(static[:4] + (chunk,) + static[5:],
+                               *operands))
+    except Exception:  # noqa: BLE001 — the compiler's refusal: no line
+        return {}
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="flash_chunked_bench")
@@ -218,19 +256,17 @@ def main():
             causal = not args.full
             static = (D ** -0.5, causal, block, block, chunk,
                       args.rehearse_cpu, H, Hkv)
-            fwd = jax.jit(
-                lambda q, k, v: fa._flash_fwd_chunked(q, k, v, *static))
-            bwd = jax.jit(lambda *a: fa._flash_bwd_chunked(*a, *static))
             try:
-                o, lse = fwd(q, k, v)
-                times = [("fwd", kernel_ms(fwd, q, k, v))] + bwd_times(
-                    bwd, q, k, v, o, lse, do)
+                times = plan_times(static, q, k, v, do)
             except Exception as e:  # noqa: BLE001 — the compiler's refusal
                 note({"shape": name, "plan": plan,
                       "refused": str(e).splitlines()[0][:300]})
                 continue
             n = S // block
             tiles = n * (n + 1) // 2 if causal else n * n   # a head
+            # off the chip nothing is timed: no second point either
+            half = ({} if args.rehearse_cpu
+                    else half_chunk_ms(static, block, (q, k, v, do)))
             scores = S * (S + 1) // 2 if causal else S * S
             for kernel, ms in times:
                 line = {"shape": name, "plan": plan, "kernel": kernel,
@@ -247,6 +283,12 @@ def main():
                         ms=ms, us_a_head=ms * 1e3 / (B * H),
                         us_a_step=ms * 1e3 / (B * H * steps),
                         roofline_pct=100 * flops / PEAK / (ms / 1e3))
+                if half.get(kernel):
+                    line.update(step_and_tile_us(
+                        ms * 1e3 / (B * H), steps,
+                        half[kernel] * 1e3 / (B * H),
+                        steps_a_head(S, block, chunk // 2, causal, kernel),
+                        tiles))
                 was = before.get((name, kernel))
                 if ms and was and was["grid_steps_a_head"] > steps:
                     line.update(

@@ -99,7 +99,7 @@ def test_tier1_keeps_its_flash_cases_and_takes_no_new_whole_step_compile():
     flash = {f: cases for f, cases in fast_per_file.items()
              if f.startswith("test_flash_")}
     cases = collections.Counter(c for found in flash.values() for c in found)
-    assert sum(cases.values()) == 241, {f: len(c) for f, c in flash.items()}
+    assert sum(cases.values()) == 256, {f: len(c) for f, c in flash.items()}
     twice = sorted(c for c, n in cases.items() if n > 1)
     assert not twice, f"under two of {sorted(flash)}: {twice}"
     joined = sorted({

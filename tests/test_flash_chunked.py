@@ -12,8 +12,9 @@ import pytest
 from deepspeed_tpu.ops.attention import reference_attention
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from tests.hlo_text import pallas_grids
-from tests.flash_cases import (_fa, _lse_reference, _named, _qkv,
-                               _reference_grads)
+from tests.flash_cases import (CHUNKED_WALKS, WINDOW_WALKS, _fa,
+                               _lse_reference, _named, _qkv,
+                               _reference_grads, carried_walk)
 
 
 # ------------------------------------------------------------------------
@@ -443,3 +444,111 @@ def test_gpt2_dots_flash_fc_lean_is_unchanged_by_the_block_policy(
                       prevent_cse=False, static_argnums=(2,),
                       policy=gpt2.block_remat_policy(cfg.remat_policy))))
     assert jaxpr() == named
+
+
+# ------------------------------------------------------------------------
+# the forward walk keeps (o, m, l) in VMEM and carries nothing through its
+# loops (ISSUE 67: ``_fwd_walk``): the same tiles in the same order with the
+# same arithmetic as the carried walk it replaced, so (o, lse) are its to
+# the bit — for the chunked, window, block-diffusion and learned-sparse
+# forward alike
+
+def _both_walks(monkeypatch, call):
+    """(``call()`` under ``_fwd_walk``, under the carried walk)."""
+    got = call()
+    count = []
+    monkeypatch.setattr(_fa(), "_fwd_walk", carried_walk(count))
+    want = call()
+    assert count, "the reference walk was never traced"
+    return got, want
+
+
+def _assert_bit_equal(got, want):
+    for a, b, name in zip(got, want, ("o", "lse")):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert np.isfinite(np.asarray(a, np.float32)).all(), name
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32),
+                                      err_msg=name)
+
+
+@pytest.mark.parametrize("case", list(CHUNKED_WALKS))
+def test_forward_walk_in_place_is_the_carried_walk_to_the_bit(case,
+                                                             monkeypatch):
+    (H, Hkv, S, D, Dv, dtype, causal, block_q, block_k,
+     chunk) = CHUNKED_WALKS[case]
+    fa = _fa()
+    q, _, _ = _qkv((H, S, D), seed=67, dtype=dtype)
+    _, k, _ = _qkv((Hkv, S, D), seed=68, dtype=dtype)
+    _, _, v = _qkv((Hkv, S, Dv), seed=69, dtype=dtype)
+    got, want = _both_walks(monkeypatch, lambda: fa._flash_fwd_chunked(
+        q, k, v, D ** -0.5, causal, block_q, block_k, chunk, True, H, Hkv))
+    _assert_bit_equal(got, want)
+    ref = reference_attention(q[None], k[None], v[None], causal=causal)[0]
+    np.testing.assert_allclose(np.asarray(got[0], np.float32),
+                               np.asarray(ref, np.float32),
+                               **(dict(rtol=2e-4, atol=2e-5)
+                                  if dtype == jnp.float32
+                                  else dict(rtol=5e-2, atol=5e-2)))
+
+
+@pytest.mark.parametrize("case", list(WINDOW_WALKS))
+def test_window_forward_walk_is_the_carried_walk_to_the_bit(case,
+                                                            monkeypatch):
+    S, window, cap, walk = WINDOW_WALKS[case]
+    fa = _fa()
+    H, Hkv, D, block = 4, 2, 64, 64
+    q, _, _ = _qkv((H, S, D), seed=70, dtype=jnp.bfloat16)
+    _, k, v = _qkv((Hkv, S, D), seed=71, dtype=jnp.bfloat16)
+    band = fa._band_plan(S, block, block, window, 2 * D, H // Hkv, cap)
+    assert band[0] == walk
+    got, want = _both_walks(monkeypatch, lambda: fa._swa_fwd(
+        q, k, v, D ** -0.5, window, block, block, band, True, H, Hkv))
+    _assert_bit_equal(got, want)
+    np.testing.assert_allclose(
+        np.asarray(got[1]).reshape(1, H, S),
+        _lse_reference(q[None], k[None], True, window), rtol=2e-2, atol=2e-2)
+
+
+def test_block_diffusion_forward_walk_is_the_carried_walk_to_the_bit(
+        monkeypatch):
+    """Block length 4 under tiles of 64 and chunks of 128: a noised query
+    block's own diagonal tile is a grid step of ONE tile, a clean one's
+    chunks walk two."""
+    from deepspeed_tpu.ops.pallas import block_diffusion_attention as bd
+    H, Hkv, L, D = 4, 2, 256, 64
+    q, _, _ = _qkv((H, 2 * L, D), seed=72, dtype=jnp.bfloat16)
+    _, k, v = _qkv((Hkv, 2 * L, D), seed=73, dtype=jnp.bfloat16)
+    walk = bd._bd_walk(L, 4, 64, 128, False)
+    spans = {int(hi - lo) for lo, hi in zip(walk[2], walk[4])}
+    assert {1, 2} <= spans
+    got, want = _both_walks(monkeypatch, lambda: bd._fwd(
+        q, k, v, D ** -0.5, 4, 64, 128, True, H, Hkv))
+    _assert_bit_equal(got, want)
+
+
+def test_learned_sparse_forward_walk_is_the_carried_walk_to_the_bit(
+        monkeypatch):
+    """A mask tile that is ALL ZERO inside a row's walk (the selection kept
+    none of a tile's keys): the row statistics stay finite and the in-place
+    walk is still the carried one to the bit."""
+    from deepspeed_tpu.ops.pallas import learned_sparse_attention as lsa
+    H, Hkv, S, D, block, chunk = 4, 2, 512, 64, 128, 256
+    q, _, _ = _qkv((H, S, D), seed=74, dtype=jnp.bfloat16)
+    _, k, v = _qkv((Hkv, S, D), seed=75, dtype=jnp.bfloat16)
+    rows = np.arange(S)
+    keep = (rows[:, None] >= rows[None]) & ((rows[:, None] * 7 + rows[None])
+                                            % 3 != 0)
+    keep[np.arange(S), np.arange(S)] = True      # a query sees itself
+    keep[2 * block:3 * block, :block] = False    # tile (2, 0): none kept
+    keep[3 * block:, block:2 * block] = False    # tile (3, 1): none kept
+    mask = jnp.asarray(keep[None], jnp.int8)
+    got, want = _both_walks(monkeypatch, lambda: lsa._masked_fwd(
+        q, k, v, mask, D ** -0.5, block, chunk, True, H, Hkv))
+    _assert_bit_equal(got, want)
+    s = jnp.einsum("hqd,hkd->hqk", q.astype(jnp.float32),
+                   jnp.repeat(k, H // Hkv, axis=0).astype(jnp.float32)) \
+        * D ** -0.5
+    lse = jax.nn.logsumexp(jnp.where(keep[None], s, -jnp.inf), axis=-1)
+    np.testing.assert_allclose(np.asarray(got[1]).reshape(H, S), lse,
+                               rtol=2e-2, atol=2e-2)

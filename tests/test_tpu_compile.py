@@ -361,6 +361,34 @@ def test_flash_attention_chunked_compiles_at_the_latent_attention_shape():
     assert_dense_lse_kept(hlo, calls, "f32[32,128,1,128]")
 
 
+@pytest.mark.parametrize("D,Dv", [(128, 128), (192, 128)],
+                         ids=["d128", "d192-values-128"])
+def test_chunked_forward_alone_compiles_inside_the_default_scoped_vmem(D, Dv):
+    """The chunked FORWARD alone at [32, 16,384, 128] and at latent
+    attention's 192 / 128, bf16, causal: one ``_fwd_kernel_chunked`` on grid
+    (32, 80) — blocks of 512 under the 4,096-row chunk ``_pick_chunk`` gives
+    both — that asks for NO scoped VMEM of its own and compiles inside the
+    default 16 MiB: its walk keeps (o, m, l) in the revisited output block
+    and two [512, 128] scratch tiles and carries nothing (ISSUE 67), beside
+    the double-buffered K and V chunks. A later change to ``_CHUNK_BYTES`` or
+    to what the walk holds cannot silently refuse the step."""
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    shapes = (SDS((1, 32, 16384, D), BF16), SDS((1, 32, 16384, D), BF16),
+              SDS((1, 32, 16384, Dv), BF16))
+    text, compiled = compile_on_chip(attend, *shapes)
+    assert kernel_names(text) == {"_fwd_kernel_chunked"}
+    assert pallas_grids(attend, *shapes) == [(32, 80)]
+    assert not re.findall(r'size\\22: (\d+)', text)      # no vmem_limit asked
+    hlo = compiled.as_text()
+    (call,) = flash_calls(hlo)
+    assert f"f32[32,16384,{Dv}]" in call and "16384,16384" not in hlo
+    assert re.search(r'op_name="[^"]*/flash_fwd_chunk/', hlo)
+
+
 @pytest.mark.parametrize("D,kernels,scopes", [
     (128, {"_gdn_fwd_kernel", "_gdn_bwd_kernel"},
      ("gdn_scan_prep/", "gdn_scan_fwd/", "gdn_scan_bwd/")),
