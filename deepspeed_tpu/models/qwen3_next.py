@@ -67,10 +67,12 @@ from deepspeed_tpu.ops.gated_delta import CHUNK, gated_delta_rule
 from deepspeed_tpu.ops.pallas.flash_attention import bwd_dq_slab_rows
 from deepspeed_tpu.ops.pallas.gated_delta import \
     kept_row_bytes as scan_kept_row_bytes
+from deepspeed_tpu.ops.pallas.gated_delta import lane_heads
 from deepspeed_tpu.ops.pallas.scan_residuals import SCAN_NAME
 from deepspeed_tpu.runtime.remat_budget import (attention_inflight,
                                                 projection_inflight)
-from deepspeed_tpu.ops.mixer_elementwise import conv_act, gated_group_norm
+from deepspeed_tpu.ops.mixer_elementwise import (conv_act, gated_group_norm,
+                                                tile_group_norm)
 from deepspeed_tpu.telemetry.spans import annotate
 
 
@@ -94,6 +96,7 @@ class Qwen3NextConfig:
     linear_num_value_heads: int = 32
     linear_value_head_dim: int = 128
     linear_conv_kernel_dim: int = 4
+    linear_allow_neg_eigval: bool = False   # beta = 2 sigmoid(b), in (0, 2)
     # experts
     num_experts: int = 512
     num_experts_per_tok: int = 10
@@ -173,13 +176,36 @@ def _a_log_init(key, shape, dtype):
                                       16.0)).astype(dtype)
 
 
+def _to_lane_tiles(t, runs):
+    """The last axis of ``t`` — runs of heads, ``runs`` = ((heads, width,
+    tiles), ...) — with every head zero-padded from ``width`` to ``tiles``
+    columns."""
+    out, at = [], 0
+    for heads, width, tiles in runs:
+        part = t[..., at:at + heads * width].reshape(
+            *t.shape[:-1], heads, width)
+        at += heads * width
+        out.append(jnp.pad(part, ((0, 0),) * (t.ndim - 1) + ((0, 0), (
+            0, tiles - width))).reshape(*t.shape[:-1], heads * tiles))
+    return jnp.concatenate(out, axis=-1)
+
+
 class GatedDeltaNet(nn.Module):
     """The linear-attention branch: three projections round
     ``gated_delta_rule``, and round the rule the two elementwise stages of
     ``ops/mixer_elementwise.py`` (convolution + SiLU + q / k L2 norm before
     it, per-head RMS norm + gate after it), each one pass over HBM where
-    the kernels take the shapes."""
-    config: Qwen3NextConfig
+    the kernels take the shapes.
+
+    ``config``: ``Qwen3NextConfig`` or another model's with the same
+    ``linear_*`` keys (``models/olmo_hybrid.py``). ``linear_allow_neg_eigval``
+    makes beta ``2 sigmoid(b)`` in (0, 2). Heads off the 128-lane grid that
+    ``ops.pallas.gated_delta.lane_heads`` rounds up (96 x 192 -> 128 x 256)
+    are laid out ONCE, zero-padded, between the projection and the
+    convolution (under ``gdn_conv``), so that all three stages run their
+    kernels on whole tiles, and cut back to their own width after the norm
+    (under ``gdn_out_norm``); the parameters keep the published shapes."""
+    config: Any
 
     @nn.compact
     def __call__(self, x):
@@ -187,38 +213,57 @@ class GatedDeltaNet(nn.Module):
         B, S, _ = x.shape
         Hk, Dk = cfg.linear_num_key_heads, cfg.linear_key_head_dim
         Hv, Dv = cfg.linear_num_value_heads, cfg.linear_value_head_dim
-        key, val = Hk * Dk, Hv * Dv
+        Pk, Pv = lane_heads(Dk, Dv)
+        tiles = (Pk, Pv) != (Dk, Dv)
+        key, val = Hk * Pk, Hv * Pv
         # ``mixer_in``: kept by a rematted block that has the bytes
         # (``runtime/remat_budget.py``), the projections are not run again
-        qkvz = checkpoint_name(
-            _dense(cfg, 2 * key + 2 * val, "in_proj_qkvz")(x), "mixer_in")
+        # (nor, of heads laid out in whole tiles, the re-layout)
+        qkvz = _dense(cfg, 2 * Hk * Dk + 2 * Hv * Dv, "in_proj_qkvz")(x)
+        if not tiles:
+            qkvz = checkpoint_name(qkvz, "mixer_in")
         ba = checkpoint_name(_dense(cfg, 2 * Hv, "in_proj_ba")(x), "mixer_in")
         taps = self.param("conv", nn.initializers.normal(0.02),
-                          (cfg.linear_conv_kernel_dim, 2 * key + val),
-                          cfg.param_dtype)
+                          (cfg.linear_conv_kernel_dim,
+                           2 * Hk * Dk + Hv * Dv), cfg.param_dtype)
         a_log = self.param("A_log", _a_log_init, (Hv,), cfg.param_dtype)
         dt_bias = self.param("dt_bias", nn.initializers.ones, (Hv,),
                              cfg.param_dtype)
+        if tiles:
+            with annotate("gdn_conv"):
+                runs = ((Hk, Dk, Pk), (Hk, Dk, Pk), (Hv, Dv, Pv))
+                qkvz = checkpoint_name(
+                    _to_lane_tiles(qkvz, runs + ((Hv, Dv, Pv),)), "mixer_in")
+                taps = _to_lane_tiles(taps, runs)
         with annotate("gdn_conv"):
             # q | k | v out of the projection's output by column offset,
             # each as the scan reads it: q and k leave L2-normalised a head
             q, k, v = conv_act(
-                qkvz, taps, head_width=Dk,
+                qkvz, taps, head_width=Pk,
                 runs=((key, Dk ** -0.5), (key, 1.0), (val, None)))
         with annotate("gdn_gates"):
             b, a = (t.astype(jnp.float32) for t in jnp.split(ba, 2, axis=-1))
             beta = jax.nn.sigmoid(b)
+            if getattr(cfg, "linear_allow_neg_eigval", False):
+                beta = 2.0 * beta
             g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
                 a + dt_bias.astype(jnp.float32))
-        o = gated_delta_rule(q.reshape(B, S, Hk, Dk), k.reshape(B, S, Hk, Dk),
-                             v.reshape(B, S, Hv, Dv), g, beta)
+        o = gated_delta_rule(q.reshape(B, S, Hk, Pk), k.reshape(B, S, Hk, Pk),
+                             v.reshape(B, S, Hv, Pv), g, beta,
+                             heads=(Dk, Dv) if tiles else None)
         w = self.param("norm", nn.initializers.ones, (Dv,), cfg.param_dtype)
         with annotate("gdn_out_norm"):
             # a head's RMS norm, one weight for every head, then the gate z
             # (the projection's last columns, read where they lie)
-            o = gated_group_norm(
-                o.reshape(B, S, val), qkvz, w, group=Dv,
-                eps=cfg.rms_norm_eps, gate_first=False, offset=2 * key + val)
+            o = o.reshape(B, S, val)
+            if tiles:
+                o = tile_group_norm(o, qkvz, w, group=Pv, width=Dv,
+                                    eps=cfg.rms_norm_eps, offset=2 * key + val)
+                o = o.reshape(B, S, Hv, Pv)[..., :Dv].reshape(B, S, Hv * Dv)
+            else:
+                o = gated_group_norm(
+                    o, qkvz, w, group=Dv, eps=cfg.rms_norm_eps,
+                    gate_first=False, offset=2 * key + val)
         return checkpoint_name(_dense(cfg, cfg.hidden_size, "out_proj")(o),
                                "attn_proj")
 
@@ -300,9 +345,28 @@ class Qwen3NextBlock(nn.Module):
 
 
 def _mixer_in_cols(cfg):
-    """Columns ``GatedDeltaNet``'s two input projections write a row."""
-    return 2 * cfg.linear_num_key_heads * cfg.linear_key_head_dim \
-        + 2 * cfg.linear_num_value_heads * (cfg.linear_value_head_dim + 1)
+    """Columns ``GatedDeltaNet``'s two input projections leave a row under
+    ``mixer_in``: q | k | v | z as the kernels read them (heads off the
+    lane grid zero-padded to whole tiles) and b | a."""
+    Pk, Pv = lane_heads(cfg.linear_key_head_dim, cfg.linear_value_head_dim)
+    return 2 * cfg.linear_num_key_heads * Pk \
+        + 2 * cfg.linear_num_value_heads * (Pv + 1)
+
+
+def gdn_row_bytes(cfg):
+    """{checkpoint name: bytes a row} of ONE ``GatedDeltaNet`` layer."""
+    b = jnp.dtype(cfg.dtype).itemsize
+    Pk, Pv = lane_heads(cfg.linear_key_head_dim, cfg.linear_value_head_dim)
+    return {"mixer_in": b * _mixer_in_cols(cfg),
+            SCAN_NAME: scan_kept_row_bytes(cfg.linear_num_value_heads, Pk,
+                                           Pv, CHUNK, b)}
+
+
+def gdn_inflight_row_bytes(cfg):
+    """Bytes a row a ``GatedDeltaNet`` branch holds between its
+    recomputation and the end of its backward."""
+    return projection_inflight(_mixer_in_cols(cfg),
+                               jnp.dtype(cfg.dtype).itemsize)
 
 
 def remat_row_bytes(cfg):
@@ -312,11 +376,7 @@ def remat_row_bytes(cfg):
     b = jnp.dtype(cfg.dtype).itemsize
     q = cfg.num_attention_heads * cfg.head_dim
     kv = cfg.num_key_value_heads * cfg.head_dim
-    each = {"linear": {"mixer_in": b * _mixer_in_cols(cfg),
-                       SCAN_NAME: scan_kept_row_bytes(
-                           cfg.linear_num_value_heads,
-                           cfg.linear_key_head_dim,
-                           cfg.linear_value_head_dim, CHUNK, b)},
+    each = {"linear": gdn_row_bytes(cfg),
             # q with its gate and k as projected, q, k and v as the kernel
             # reads them
             "attention": {"qkv": b * (3 * q + 3 * kv)}}
@@ -337,7 +397,7 @@ def remat_inflight_row_bytes(cfg, seq_len):
     The gated attention's q is projected with its gate (an output gate)."""
     b = jnp.dtype(cfg.dtype).itemsize
     q = cfg.num_attention_heads * cfg.head_dim
-    each = {"linear": projection_inflight(_mixer_in_cols(cfg), b),
+    each = {"linear": gdn_inflight_row_bytes(cfg),
             "attention": attention_inflight(
                 q, q, 2 * cfg.num_key_value_heads * cfg.head_dim, b,
                 bwd_dq_slab_rows(seq_len, cfg.head_dim, cfg.head_dim, b),

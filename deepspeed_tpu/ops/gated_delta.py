@@ -26,10 +26,12 @@ backend (``ops.pallas.gated_delta.takes_kernel``), which of two forms of
 that one algorithm runs:
 
 - **the Pallas kernels** (``ops/pallas/gated_delta.py``) where both head
-  sizes are multiples of 128 lanes on a TPU (and in the interpreter, at any
-  head size, on every other backend): a program a (batch row, key head and
-  the value heads it serves) walks the chunks in order with ``S`` in VMEM
-  and builds the whole preparation above per chunk in VMEM from the q, k,
+  sizes are multiples of 128 lanes on a TPU, as they come or zero-padded
+  (``lane_heads``: where whole tiles add at most a third, 96 x 192 -> 128 x
+  256), and in the interpreter on every other backend: a program a (batch
+  row, key head and the value heads it serves) walks the chunks in order
+  with ``S`` in VMEM and builds the whole preparation above per chunk in
+  VMEM from the q, k,
   v, G, beta tiles, read as column blocks of the model's own [B, S, H*D]
   arrays. For the backward pass the forward rule keeps the state every
   chunk STARTS from, in the inputs' dtype, and the backward kernel walks
@@ -130,38 +132,58 @@ def _chunks(t, n):
     return jnp.moveaxis(jnp.moveaxis(t, 1, 0), 3, 2)
 
 
-def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK):
+def gated_delta_rule(q, k, v, g, beta, chunk=CHUNK, heads=None):
     """o [B, S, Hv, Dv] of the gated delta rule from a zero state.
 
     q, k [B, S, Hk, Dk] (k L2-normalised, q normalised and scaled, as the
     layer does before calling); v [B, S, Hv, Dv]; g [B, S, Hv] float32, the
-    log of the decay (<= 0); beta [B, S, Hv] float32 in (0, 1). Key head i
+    log of the decay (<= 0); beta [B, S, Hv] float32 in (0, 2): a token's
+    transition ``I - beta k k^T`` has its eigenvalue along k in (-1, 1)
+    (a layer without negative eigenvalues keeps beta under 1). Key head i
     serves value heads [i * Hv / Hk, (i + 1) * Hv / Hk). Any S: the tail
     of a last, short chunk is padded with tokens that write nothing.
 
     Which form runs is decided here from the operands' shapes and the
     backend (``ops.pallas.gated_delta.takes_kernel``): the Pallas kernels
-    where the heads are lane-aligned on a TPU, and in the interpreter on
-    any other backend; ``gated_delta_rule_xla`` for other head sizes."""
+    where the heads are lane-aligned on a TPU — as they come, or
+    zero-padded here to whole tiles where ``lane_heads`` says so (96 x 192
+    runs at 128 x 256; o's padded lanes are cut off again) — and in the
+    interpreter on any other backend; ``gated_delta_rule_xla`` for other
+    head sizes. ``heads``: the layer's own (Dk, Dv) where IT has laid q, k
+    and v out zero-padded already (``models/qwen3_next.GatedDeltaNet``: one
+    re-layout for the convolution, the rule and the norm), for the gauge
+    ``linear_attn/gdn_lane_overcompute`` alone."""
     from deepspeed_tpu.ops.pallas import gated_delta as kernels
     tpu = is_tpu_backend()
-    if not kernels.takes_kernel(k.shape[-1], v.shape[-1], tpu):
+    Dk, Dv = k.shape[-1], v.shape[-1]
+    if not kernels.takes_kernel(Dk, Dv, tpu):
         return gated_delta_rule_xla(q, k, v, g, beta, chunk)
+    Pk, Pv = kernels.lane_heads(Dk, Dv)
+    if (Pk, Pv) != (Dk, Dv):
+        with annotate("gdn_scan_prep"):
+            q, k = (jnp.pad(t, ((0, 0),) * 3 + ((0, Pk - Dk),))
+                    for t in (q, k))
+            v = jnp.pad(v, ((0, 0),) * 3 + ((0, Pv - Dv),))
+        heads = (Dk, Dv)
     rule = functools.partial(kernels.gated_delta_rule_kernel, chunk=chunk,
-                             interpret=not tpu)
+                             interpret=not tpu, heads=heads)
     mesh, batch_axes, model_axis = _device_axes(q.shape[0], k.shape[2])
     if mesh is None:
-        return rule(q, k, v, g, beta)
-    heads = jax.sharding.PartitionSpec(batch_axes, None, model_axis)
-    return jax.shard_map(rule, mesh=mesh, in_specs=(heads,) * 5,
-                         out_specs=heads, check_vma=False)(q, k, v, g, beta)
+        o = rule(q, k, v, g, beta)
+    else:
+        spec = jax.sharding.PartitionSpec(batch_axes, None, model_axis)
+        o = jax.shard_map(rule, mesh=mesh, in_specs=(spec,) * 5,
+                          out_specs=spec, check_vma=False)(q, k, v, g, beta)
+    return o[..., :Dv]
 
 
-def gated_delta_rule_xla(q, k, v, g, beta, chunk=CHUNK):
+def gated_delta_rule_xla(q, k, v, g, beta, chunk=CHUNK, heads=None):
     """``gated_delta_rule`` as XLA ops: the preparation for all chunks at
     once in batched matmuls and a ``lax.scan`` over the chunks. The path
     of head sizes the kernels do not take, and their second oracle beside
-    ``gated_delta_recurrence``."""
+    ``gated_delta_recurrence`` (``heads``, the kernels' gauge's, is taken
+    so that one form stands in for the other and read by nothing)."""
+    del heads
     default_registry().gauge("linear_attn/gdn_kernel_heads_per_step").set(0)
     default_registry().gauge("linear_attn/gdn_states_kept_every").set(_GROUP)
     B, S, Hv, Dv = v.shape

@@ -26,7 +26,7 @@ scans:
 - **the XLA forms** below (``conv_act_xla``, ``gated_group_norm_xla``), for
   widths, offsets or groups that are no multiple of 128 lanes, a sequence
   no row block divides, and heads of another width than 128 under the L2
-  norm. They are also the kernels' oracles.
+  norm (heads zero-padded to whole tiles are exact: ``tile_group_norm``).
 
 No option selects a form. The trace-time gauges ``mixer/conv_kernel_sites``
 / ``mixer/norm_kernel_sites`` count the call sites traced through the
@@ -66,24 +66,24 @@ def l2_normalise(x, eps=L2_EPS):
 _noted = set()
 
 
-def _note(stage, kernel, what):
+def _note(stage, why, what):
     """Trace-time engagement record: the stage's site gauge and, once a
-    distinct shape, a log line."""
+    distinct shape, a log line; ``why`` is the kernels' refusal, the
+    condition that sent the call to the XLA form (None: the kernels took
+    the call)."""
     # both gauges exist from the first call: a form that took no site reads 0
     sites = {form: default_registry().gauge(f"mixer/{stage}_{form}_sites")
              for form in ("kernel", "xla")}
-    took = sites["kernel" if kernel else "xla"]
+    took = sites["xla" if why else "kernel"]
     took.set(took.value + 1)
-    if (stage, kernel, what) not in _noted:
-        _noted.add((stage, kernel, what))
+    if (stage, why, what) not in _noted:
+        _noted.add((stage, why, what))
         logger.info(
             f"mixer {stage} {what}: " + (
+                f"the XLA form ({why})" if why else
                 "Pallas kernels, one HBM pass forward and one backward, "
                 "float32 in VMEM" + ("" if is_tpu_backend()
-                                     else " (interpreter)")
-                if kernel else "the XLA form (a width, an offset or a group "
-                "that is no multiple of 128 lanes, or a sequence no row "
-                "block divides)"))
+                                     else " (interpreter)")))
 
 
 def _over_batch(fn, rows, *whole):
@@ -115,12 +115,12 @@ def conv_act(x, taps, bias=None, *, offset=0, runs=None, head_width=None):
     runs = tuple(runs or ((C, None),))
     assert sum(width for width, _ in runs) == C, (runs, C)
     starts = [offset + sum(w for w, _ in runs[:n]) for n in range(len(runs))]
-    takes = all(kernels.conv_takes(
+    why = next(filter(None, (kernels.conv_refusal(
         S, width, total, start, W, None if scale is None else head_width)
-        for start, (width, scale) in zip(starts, runs))
-    _note("conv", takes, f"[{B}, {S}, {total}] {jnp.dtype(x.dtype).name} "
+        for start, (width, scale) in zip(starts, runs))), None)
+    _note("conv", why, f"[{B}, {S}, {total}] {jnp.dtype(x.dtype).name} "
           f"columns {offset}:{offset + C} W={W} runs={runs}")
-    if not takes:
+    if why:
         return conv_act_xla(x, taps, bias, offset=offset, runs=runs,
                             head_width=head_width)
     interpret = not is_tpu_backend()
@@ -169,11 +169,11 @@ def gated_group_norm(y, z, w, *, group, eps, gate_first, offset=0):
     gate before the norm), else ``norm(y) * w * gate``. y's dtype."""
     from deepspeed_tpu.ops.pallas import mixer_elementwise as kernels
     B, S, D = y.shape
-    takes = kernels.norm_takes(S, D, z.shape[2], offset, group)
-    _note("norm", takes, f"[{B}, {S}, {D}] {jnp.dtype(y.dtype).name} "
+    why = kernels.norm_refusal(S, D, z.shape[2], offset, group)
+    _note("norm", why, f"[{B}, {S}, {D}] {jnp.dtype(y.dtype).name} "
           f"group={group} gate {'before' if gate_first else 'after'} the "
           f"norm, at columns {offset}:{offset + D} of {z.shape[2]}")
-    if not takes:
+    if why:
         return gated_group_norm_xla(y, z, w, group=group, eps=eps,
                                     gate_first=gate_first, offset=offset)
     kernel = functools.partial(
@@ -202,3 +202,17 @@ def gated_group_norm_xla(y, z, w, *, group, eps, gate_first, offset=0):
     if not gate_first:
         yf = yf * gate
     return yf.astype(y.dtype)
+
+
+def tile_group_norm(y, z, w, *, group, width, eps, offset=0):
+    """``gated_group_norm`` with the gate after the norm for groups of
+    ``group`` lanes that hold a head of ``width`` channels and zeros (a
+    head zero-padded to whole tiles: ``ops.pallas.gated_delta.lane_heads``);
+    w [width], one weight for every head. The mean of squares over ``width``
+    channels is ``group / width`` times the one over the group, so the
+    norm over the group with ``eps * width / group`` and the weight times
+    ``sqrt(width / group)`` is the head's own norm, and the padded lanes
+    leave as zeros (their weight is zero)."""
+    w = jnp.pad(w.astype(_F32) * (width / group) ** 0.5, (0, group - width))
+    return gated_group_norm(y, z, w, group=group, eps=eps * width / group,
+                            gate_first=False, offset=offset)

@@ -15,7 +15,14 @@ the XLA form lays out in HBM for all chunks at once — the decay mask,
   repeated, chunk-major or head-major copy exists. The gates are the only
   operands laid out first: ``G`` (the running sum of g inside each chunk)
   and beta as [B, Hv / hb, N, hb, C] float32, a token a lane (2 MB each at
-  2 x 8192 tokens, 32 heads).
+  2 x 8192 tokens, 32 heads). A head's columns are whole 128-lane tiles:
+  head sizes off that grid which whole tiles widen by at most a third
+  (``lane_heads``: 96 x 192 -> 128 x 256) come ZERO-PADDED — by the layer,
+  which lays q | k | v out once for this rule and the elementwise stages
+  round it, or by ``ops.gated_delta.gated_delta_rule`` itself — and the
+  zeros are exact (no lane of q.k, k k^T or the state's other rows and
+  columns sees them); ``linear_attn/gdn_lane_overcompute`` prices the
+  lanes, and other sizes fall to the XLA form with one log line.
 - **The inverse** is float32 and exact in form: forward substitution inside
   the ``_SUB`` x ``_SUB`` diagonal blocks as rank-one updates on the VPU,
   then the doubling rounds of ``unit_lower_inverse`` (``X - X C_s X``) for
@@ -69,6 +76,7 @@ _SUB = 16
 _BLOCK_CHUNKS = 8
 _NN, _NT, _TN = ((1,), (0,)), ((1,), (1,)), ((0,), (0,))
 _F32 = jnp.float32
+LANES = 128
 
 
 class _Plan(collections.namedtuple("_Plan", "B Hk Hv Dk Dv C cb kg nb")):
@@ -90,11 +98,36 @@ def _plan_for(B, S, Hk, Hv, Dk, Dv, C):
     return _Plan(B, Hk, Hv, Dk, Dv, C, cb, _heads_per_step(Hk, Hv), n // cb)
 
 
+def lane_heads(Dk, Dv):
+    """The head sizes the kernels run a head of ``Dk`` x ``Dv`` at: each
+    rounded up to whole 128-lane tiles where that adds at most a third of
+    its lanes (96 -> 128, 192 -> 256), else itself. Zero lanes are exact:
+    they change neither a head's L2 norm nor q.k nor k k^T, the state's
+    padded rows and columns stay zero, and o's padded lanes are zero."""
+    def up(d):
+        tiles = -(-d // LANES) * LANES
+        return tiles if 3 * tiles <= 4 * d else d
+    return up(Dk), up(Dv)
+
+
+_refused = set()
+
+
 def takes_kernel(Dk, Dv, tpu):
     """Whether the kernels take heads of ``Dk`` x ``Dv``: on a TPU backend
-    the heads must be lane-aligned column blocks of the model's arrays; the
-    interpreter (any other backend) takes any shape."""
-    return not tpu or (Dk % 128 == 0 and Dv % 128 == 0)
+    a head must be lane-aligned column blocks of the model's arrays, as it
+    is or zero-padded by ``lane_heads``' rule; the interpreter (any other
+    backend) takes any shape. A refused shape is logged once, with the
+    size that refused it."""
+    odd = [f"{name} {d}" for name, d, lanes in zip(
+        ("Dk", "Dv"), (Dk, Dv), lane_heads(Dk, Dv)) if lanes % LANES]
+    if tpu and odd and (Dk, Dv) not in _refused:
+        _refused.add((Dk, Dv))
+        logger.info(
+            f"gated delta rule heads of {Dk} x {Dv}: the XLA form ("
+            + " and ".join(odd) + " is no multiple of 128 lanes and whole "
+            "tiles would add more than a third)")
+    return not (tpu and odd)
 
 
 def _dot(a, b, dims=_NN):
@@ -437,21 +470,35 @@ def _heads_per_step(Hk, Hv):
 _plans_logged = set()
 
 
-def _note_plan(plan, dtype, interpret):
+def lane_count(Dk, Dv):
+    """Lanes a token's q | k | v and a head's state take at ``Dk`` x
+    ``Dv``: what ``linear_attn/gdn_lane_overcompute`` is a ratio of."""
+    return 2 * Dk + Dv + Dk * Dv
+
+
+def _note_plan(plan, dtype, interpret, heads=None):
     """Trace-time engagement record: the gauges
-    ``linear_attn/gdn_kernel_heads_per_step`` and
-    ``linear_attn/gdn_states_kept_every`` and, once per distinct shape, a
-    log line."""
+    ``linear_attn/gdn_kernel_heads_per_step``,
+    ``linear_attn/gdn_states_kept_every`` and
+    ``linear_attn/gdn_lane_overcompute`` (the lanes of q | k | v | state
+    the kernels compute on over those of ``heads``, the model's own (Dk,
+    Dv) where the operands came zero-padded to whole tiles; 1.0 where they
+    are the plan's) and, once per distinct shape, a log line."""
     gauge = default_registry().gauge
     gauge("linear_attn/gdn_kernel_heads_per_step").set(plan.hb)
     gauge("linear_attn/gdn_states_kept_every").set(1)
-    key = (plan, jnp.dtype(dtype).name, interpret)
+    Dk, Dv = heads or (plan.Dk, plan.Dv)
+    gauge("linear_attn/gdn_lane_overcompute").set(
+        lane_count(plan.Dk, plan.Dv) / lane_count(Dk, Dv))
+    key = (plan, jnp.dtype(dtype).name, interpret, Dk, Dv)
     if key not in _plans_logged:
         _plans_logged.add(key)
         logger.info(
             f"gated delta rule S={plan.nb * plan.cb * plan.C} Hk={plan.Hk} "
             f"Hv={plan.Hv} Dk={plan.Dk} Dv={plan.Dv} {key[1]}: Pallas "
-            f"kernels on [B, S, H*D] column blocks, chunk={plan.C}, "
+            + (f"kernels on heads of {Dk} x {Dv} zero-padded to whole "
+               f"tiles, " if (Dk, Dv) != (plan.Dk, plan.Dv) else "kernels ")
+            + f"on [B, S, H*D] column blocks, chunk={plan.C}, "
             f"{plan.hb} value heads a grid step, {plan.cb} chunks a grid "
             f"step, a state kept every chunk for the backward pass"
             f"{' (interpreter)' if interpret else ''}")
@@ -468,14 +515,15 @@ def gate_layout(t, plan):
     return t.transpose(0, 1, 3, 2, 4)
 
 
-def gated_delta_rule_kernel(q, k, v, g, beta, chunk, interpret):
+def gated_delta_rule_kernel(q, k, v, g, beta, chunk, interpret, heads=None):
     """``ops.gated_delta.gated_delta_rule`` on the kernels: the same
     arguments and result, any S (a short last chunk is padded with tokens
-    that write nothing)."""
+    that write nothing). ``heads``: the model's (Dk, Dv) where the operands'
+    heads came zero-padded (the lane gauge's denominator)."""
     B, S, Hv, Dv = v.shape
     Hk, Dk = k.shape[2:]
     plan = _plan_for(B, S, Hk, Hv, Dk, Dv, chunk)
-    _note_plan(plan, v.dtype, interpret)
+    _note_plan(plan, v.dtype, interpret, heads)
     padded = plan.nb * plan.cb * chunk
     with annotate("gdn_scan_prep"):
         if padded > S:

@@ -304,10 +304,10 @@ def _conv_rule(plan, interpret):
 def conv_takes(S, C, total, offset, W, l2_head=None, block_rows=None):
     """Whether the convolution kernels take the call: whole 128-lane column
     blocks at the offset, a row block that divides S, the taps' history
-    inside one vreg row and, for the folded L2 norm, heads of 128 lanes."""
-    return (row_block(S, block_rows) is not None
-            and column_block(C, offset) is not None and 1 <= W - 1 <= _KEEP
-            and offset + C <= total and l2_head in (None, LANES))
+    inside one vreg row and, for the folded L2 norm, heads of 128 lanes
+    (as they are or zero-padded to 128: zeros add nothing to a head's sum
+    of squares). ``conv_refusal`` names the condition that refuses."""
+    return conv_refusal(S, C, total, offset, W, l2_head, block_rows) is None
 
 
 def conv_act_kernel(x, taps, bias=None, *, eps, offset=0, l2_scale=None,
@@ -508,12 +508,12 @@ def norm_row_block(S, bc, limit=None):
 def norm_takes(S, D, total, offset, group, block_rows=None):
     """Whether the norm kernels take the call: groups of whole vreg
     columns, each inside one column block or a column block itself, whole
-    column blocks at the gate's offset, a row block that divides S."""
-    if not group or group % LANES:
-        return False
-    bc = norm_block(D, offset, group)
-    return (bc is not None and offset + D <= total
-            and norm_row_block(S, bc, block_rows) is not None)
+    column blocks at the gate's offset, a row block that divides S.
+    ``norm_refusal`` names the condition that refuses a call. A head
+    zero-padded to whole tiles is a group of its tiles, its mean taken
+    over the channels it has by a rescaled eps and weight
+    (``ops.mixer_elementwise.tile_group_norm``)."""
+    return norm_refusal(S, D, total, offset, group, block_rows) is None
 
 
 def gated_group_norm_kernel(y, z, w, *, group, eps, gate_first, offset=0,
@@ -528,3 +528,36 @@ def gated_group_norm_kernel(y, z, w, *, group, eps, gate_first, offset=0,
                     float(eps), norm_row_block(S, bc, block_rows), bc)
     return _norm_rule(plan, bool(interpret))(
         y, z, w.astype(_F32).reshape(1, D))
+
+
+def conv_refusal(S, C, total, offset, W, l2_head=None, block_rows=None):
+    """The condition of ``conv_takes`` that refuses the call, in words
+    (None: the kernels take it)."""
+    if row_block(S, block_rows) is None:
+        return f"no row block of {_ROW_BLOCKS} divides S = {S}"
+    if column_block(C, offset) is None:
+        return (f"{C} columns at offset {offset} are not whole 128-lane "
+                f"column blocks")
+    if not 1 <= W - 1 <= _KEEP:
+        return f"{W} taps: the history is not 1 ... {_KEEP} rows"
+    if offset + C > total:
+        return f"columns {offset}:{offset + C} pass the array's {total}"
+    if l2_head not in (None, LANES):
+        return f"the L2 norm's heads are {l2_head} wide, not 128 lanes"
+    return None
+
+
+def norm_refusal(S, D, total, offset, group, block_rows=None):
+    """The condition of ``norm_takes`` that refuses the call, in words
+    (None: the kernels take it)."""
+    if not group or group % LANES:
+        return f"groups of {group} are not whole 128-lane vreg columns"
+    bc = norm_block(D, offset, group)
+    if bc is None:
+        return (f"no column block of whole groups of {group} divides {D} "
+                f"columns and the gate's offset {offset}")
+    if offset + D > total:
+        return f"columns {offset}:{offset + D} pass the array's {total}"
+    if norm_row_block(S, bc, block_rows) is None:
+        return f"no row block divides S = {S} at column blocks of {bc}"
+    return None

@@ -242,11 +242,15 @@ def annotate(tag):
     of its backward takes: 5, each tile computed once) and
     ``attention/flash_bwd_dq_slabs`` (float32 dq slabs a chunked backward
     leaves to be added, one a key chunk; 0 for a whole-row call), and
-    the gated delta rule two, ``linear_attn/gdn_kernel_heads_per_step``
+    the gated delta rule three, ``linear_attn/gdn_kernel_heads_per_step``
     (value heads a grid step of its kernels; 0: the XLA form took the
-    call) and ``linear_attn/gdn_states_kept_every`` (chunks between the
-    states kept for the backward pass): no benchmark metric reads
-    them. The window kernels leave ``attention/window_tile_overcompute``
+    call), ``linear_attn/gdn_states_kept_every`` (chunks between the
+    states kept for the backward pass) and
+    ``linear_attn/gdn_lane_overcompute`` (lanes of q | k | v | state the
+    kernels compute on over the model's own heads': 1.77 where 96 x 192
+    runs at 128 x 256), which ``gdn_lane_overcompute`` reads, as
+    ``gdn_xla_sites`` reads the first with the mixer stages'
+    ``mixer/*_xla_sites``. The window kernels leave ``attention/window_tile_overcompute``
     (score elements their tiles compute over those the band holds), which
     ``swa_tile_overcompute`` reads, ``attention/window_tiles_per_grid_step``
     and ``attention/window_bwd_tiles_per_grid_step`` (score tiles a grid

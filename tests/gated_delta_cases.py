@@ -5,14 +5,18 @@ import jax
 import jax.numpy as jnp
 
 
-def _inputs(S, rep=2, D=16, B=2, Hk=2, dtype=jnp.float32, seed=0):
+def _inputs(S, rep=2, D=16, B=2, Hk=2, dtype=jnp.float32, seed=0, Dv=None,
+            beta_scale=1.0):
+    """``Dv``: the value head's width where it is not the key head's ``D``;
+    ``beta_scale`` 2: beta in (0, 2), a layer with negative eigenvalues."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
     q = unit(jax.random.normal(ks[0], (B, S, Hk, D))) * D ** -0.5
     k = unit(jax.random.normal(ks[1], (B, S, Hk, D)))
-    v = jax.random.normal(ks[2], (B, S, Hk * rep, D))
+    v = jax.random.normal(ks[2], (B, S, Hk * rep, Dv or D))
     g = -2.0 * jax.nn.softplus(jax.random.normal(ks[3], (B, S, Hk * rep)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (B, S, Hk * rep)))
+    beta = beta_scale * jax.nn.sigmoid(
+        jax.random.normal(ks[4], (B, S, Hk * rep)))
     return q.astype(dtype), k.astype(dtype), v.astype(dtype), g, beta
 
 

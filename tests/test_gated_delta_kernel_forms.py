@@ -145,6 +145,37 @@ def test_which_head_sizes_take_the_kernels(D, tpu, kernel):
     assert kernels.takes_kernel(128, D, tpu) is kernel
 
 
+@pytest.mark.parametrize("Dk,Dv,lanes,takes", [
+    (96, 192, (128, 256), True),      # whole tiles add a third: padded
+    (192, 96, (256, 128), True), (128, 192, (128, 256), True),
+    (64, 64, (64, 64), False),        # ... would double: the XLA form
+    (24, 48, (24, 48), False), (160, 128, (160, 128), False),
+    (128, 128, (128, 128), True)])
+def test_heads_whole_tiles_add_a_third_to_are_padded_and_logged_once(
+        Dk, Dv, lanes, takes, caplog):
+    """``lane_heads``: a size is rounded up to whole 128-lane tiles where
+    that adds at most a third of its lanes; a head the rule leaves off the
+    grid is refused on a TPU with ONE log line naming the size."""
+    import logging
+    from deepspeed_tpu.utils.logging import logger
+    assert kernels.lane_heads(Dk, Dv) == lanes
+    kernels._refused.discard((Dk, Dv))
+    logger.addHandler(caplog.handler)
+    try:
+        with caplog.at_level(logging.INFO, logger=logger.name):
+            assert kernels.takes_kernel(Dk, Dv, True) is takes
+            assert kernels.takes_kernel(Dk, Dv, True) is takes
+            assert kernels.takes_kernel(Dk, Dv, False)
+    finally:
+        logger.removeHandler(caplog.handler)
+    said = [r.getMessage() for r in caplog.records
+            if "the XLA form" in r.getMessage()]
+    assert len(said) == (0 if takes else 1), said
+    for name, d in (("Dk", Dk), ("Dv", Dv)):
+        assert all((f"{name} {d}" in line) == bool(d % 128)
+                   for line in said), said
+
+
 def test_gauges_say_which_form_took_the_call(monkeypatch):
     args = _inputs(CHUNK, B=1)
     gauge = default_registry().peek_gauge

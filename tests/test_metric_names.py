@@ -218,6 +218,19 @@ def test_remat_budget_metric_names_documented(name):
     assert name in _package_source(), name
 
 
+@pytest.mark.parametrize("name", ["linear_attn/gdn_lane_overcompute",
+                                  "linear_attn/gdn_kernel_heads_per_step",
+                                  "linear_attn/gdn_states_kept_every"])
+def test_delta_rule_engagement_gauges_documented(name):
+    """The gated delta rule's trace-time gauges (ISSUE 32: heads a grid
+    step, 0 the XLA form; ISSUE 68: the lanes the kernels compute on over
+    the published heads' lanes, which ``gdn_lane_overcompute`` reads) stay
+    documented AND emitted."""
+    assert name in documented_metric_names(), (
+        f"{name} missing from the docs/observability.md train table")
+    assert f'"{name}"' in _package_source(), name
+
+
 @pytest.mark.parametrize("name", ["attention/flash_tile_overcompute",
                                   "attention/flash_heads_per_block",
                                   "attention/window_tile_overcompute",

@@ -270,3 +270,86 @@ def test_norm_refused_shape_takes_the_xla_form(what, kw):
     after = sites()
     assert after["norm_xla_sites"] == before.get("norm_xla_sites", 0) + 1
     assert after["norm_kernel_sites"] == before.get("norm_kernel_sites", 0)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_heads_zero_padded_to_whole_tiles_are_the_heads_own_stages(dtype):
+    """Heads of 96 (q, k) and 192 (v, z, o) laid out zero-padded to 128 and
+    256 lanes: the convolution's L2 norm over 128 lanes is the norm over the
+    head's 96 (zeros add nothing to the sum of squares) and
+    ``tile_group_norm`` over groups of 256 is the RMS norm over the head's
+    192 (eps and weight rescaled), values and every gradient against the
+    XLA forms at the heads' OWN widths; the padded lanes leave as zeros.
+    Both calls take the kernels."""
+    dt = jnp.dtype(dtype)
+    B, S, H = 1, 64, 2
+    pad = lambda t, d, p: jnp.pad(t.reshape(*t.shape[:-1], H, d), (  # noqa: E731
+        (0, 0),) * (t.ndim) + ((0, p - d),)).reshape(*t.shape[:-1], H * p)
+    cut = lambda t, d, p: t.reshape(*t.shape[:-1], H, p)[..., :d].reshape(  # noqa: E731
+        *t.shape[:-1], H * d)
+    x, z, o = draw(11, (B, S, H * 96), (B, S, H * 192), (B, S, H * 192),
+                   dtype=dt)
+    taps, w = draw(12, (4, H * 96), (192,))
+    before = sites()
+
+    def padded(x, taps, o, z, w):
+        q, = entry.conv_act(pad(x, 96, 128), pad(taps, 96, 128),
+                            runs=((H * 128, 0.5),), head_width=128)
+        n = entry.tile_group_norm(pad(o, 192, 256), pad(z, 192, 256), w,
+                                  group=256, width=192, eps=1e-6)
+        return q, n
+
+    def own(x, taps, o, z, w):
+        q, = entry.conv_act_xla(x, taps, runs=((H * 96, 0.5),),
+                                head_width=96)
+        n = entry.gated_group_norm_xla(o, z, w, group=192, eps=1e-6,
+                                       gate_first=False)
+        return q, n
+
+    q, n = padded(x, taps, o, z, w)
+    after = sites()
+    assert after["conv_kernel_sites"] == before.get("conv_kernel_sites",
+                                                    0) + 1
+    assert after["norm_kernel_sites"] == before.get("norm_kernel_sites",
+                                                    0) + 1
+    want_q, want_n = own(x, taps, o, z, w)
+    assert not np.any(np.asarray(q.reshape(B, S, H, 128)[..., 96:], F32))
+    assert not np.any(np.asarray(n.reshape(B, S, H, 256)[..., 192:], F32))
+    assert rel(cut(q, 96, 128), want_q) < LIMIT[dtype]
+    assert rel(cut(n, 192, 256), want_n) < LIMIT[dtype]
+
+    def loss(fn, shrink):
+        def total(*a):
+            q, n = fn(*a)
+            if shrink:
+                q, n = cut(q, 96, 128), cut(n, 192, 256)
+            return jnp.sum(jnp.sin(q.astype(F32))) + jnp.sum(
+                jnp.sin(n.astype(F32)))
+        return jax.jit(jax.grad(total, argnums=(0, 1, 2, 3, 4)))
+
+    got = loss(padded, True)(x, taps, o, z, w)
+    want = loss(own, False)(x, taps, o, z, w)
+    for name, a, b in zip("x taps o z w".split(), got, want):
+        assert rel(a, b) < 3 * LIMIT[dtype], name
+
+
+def test_a_refusal_names_the_condition_that_refused():
+    """``conv_refusal`` / ``norm_refusal`` are the ``*_takes`` rules in
+    words (None where the kernels take the call), one condition each, and
+    the entry's log line carries them."""
+    ok = dict(S=8192, C=3840, total=23040, offset=3840, W=4, l2_head=128)
+    assert kernels.conv_refusal(**ok) is None and kernels.conv_takes(**ok)
+    for over, said in ((dict(S=8200), "row block"),
+                       (dict(C=2880, offset=2880), "2880 columns at offset"),
+                       (dict(W=12), "12 taps"), (dict(total=7000), "pass"),
+                       (dict(l2_head=96), "96 wide")):
+        why = kernels.conv_refusal(**dict(ok, **over))
+        assert said in why and not kernels.conv_takes(**dict(ok, **over))
+    ok = dict(S=8192, D=7680, total=23040, offset=15360, group=256)
+    assert kernels.norm_refusal(**ok) is None and kernels.norm_takes(**ok)
+    for over, said in ((dict(group=192), "groups of 192"),
+                       (dict(offset=15360 + 128), "offset"),
+                       (dict(total=20000), "pass"),
+                       (dict(S=8200), "row block")):
+        why = kernels.norm_refusal(**dict(ok, **over))
+        assert said in why and not kernels.norm_takes(**dict(ok, **over))

@@ -134,7 +134,13 @@ CELLS = {
     "laguna-train-1chip-s16384": (6229, 40.1, (
         "moe_scores", "attn_proj", "qkv", "mlp_fc")),
     "smallthinker-train-1chip-s16384": (6720, 19.0, (
-        "moe_scores", "attn_proj", "qkv"))}
+        "moe_scores", "attn_proj", "qkv")),
+    # PR 68: 13.0 GB of step state; the compiled peak (13.945 GB) stands
+    # UNDER held + kept (13.004 + 1.576): the 14 B a parameter are not all
+    # alive at the peak, so nothing is left unexplained (-14.1 widths of
+    # 62.9 MB) and ``scan_states`` (1,510 MB at 128 x 256) stays out by 1.4 GB
+    "olmohybrid-train-1chip-s8192": (2907, -14.1, (
+        "attn_proj", "qkv", "mixer_in"))}
 
 
 def _stack_of(cell_name):

@@ -431,6 +431,43 @@ def test_gated_delta_rule_fwd_and_grad_compile_at_qwen3_next_shape(
         2e9 if kernels else 8e9)
 
 
+def test_gated_delta_rule_compiles_at_olmo_hybrids_heads_off_the_lane_grid():
+    """1 x 8192 tokens, 30 key = 30 value heads of the PUBLISHED 96 x 192
+    (the ``olmohybrid-train-1chip-s8192`` cell's DeltaNet layer), bf16
+    operands and float32 gates, forward and backward: the rule pads the
+    heads to whole tiles (128 x 256) under ``gdn_scan_prep`` and runs the
+    Pallas kernels, two value heads a grid step, 15 programs x 16 blocks of
+    8 chunks; o comes back 192 wide. Heads of 64 keep the XLA form (the
+    case above): whole tiles would double them."""
+    from deepspeed_tpu.ops.gated_delta import gated_delta_rule
+    from deepspeed_tpu.telemetry.registry import default_registry
+
+    def scan(*a):
+        with jax.named_scope("linear_attn"):
+            o = gated_delta_rule(*a)
+            assert o.shape == (1, 8192, 30, 192)
+            return o.astype(F32).sum()
+
+    def grads(q, k, v, g, beta):
+        return jax.grad(scan, argnums=(0, 1, 2, 3, 4))(q, k, v, g, beta)
+
+    shapes = (SDS((1, 8192, 30, 96), BF16), SDS((1, 8192, 30, 96), BF16),
+              SDS((1, 8192, 30, 192), BF16), SDS((1, 8192, 30), F32),
+              SDS((1, 8192, 30), F32))
+    text, compiled = compile_on_chip(grads, *shapes)
+    assert kernel_names(text) == {"_gdn_fwd_kernel", "_gdn_bwd_kernel"}
+    gauge = default_registry().peek_gauge
+    assert gauge("linear_attn/gdn_kernel_heads_per_step") == 2
+    assert gauge("linear_attn/gdn_lane_overcompute") == pytest.approx(
+        33280 / 18816)
+    assert pallas_grids(grads, *shapes) == [(15, 16), (15, 16)]
+    hlo = compiled.as_text()
+    for scope in ("gdn_scan_prep/", "gdn_scan_fwd/", "gdn_scan_bwd/"):
+        assert re.search(r'op_name="[^"]*/' + scope, hlo), scope
+    # the forward rule's states at 128 x 256 (251 MB) and inverses (126 MB)
+    assert compiled.memory_analysis().peak_memory_in_bytes < 1.5e9
+
+
 @pytest.mark.parametrize("groups,head_blocks", [(8, 1), (1, 8)],
                          ids=["nemotron_h_8_groups", "granite_one_group"])
 def test_ssd_scan_fwd_and_grad_compile_at_nemotron_h_shape(groups,
@@ -668,7 +705,10 @@ def test_mixer_elementwise_fwd_and_grad_compile_at_the_cells_shapes(
     ("qwen3next-train-1chip-s8192", "GatedDeltaNet",
      {"gdn_conv": "conv", "gdn_out_norm": "norm"}),
     ("nemotron3nano-train-1chip-s16384", "Mamba2Mixer",
-     {"ssm_conv": "conv", "ssm_norm": "norm"})])
+     {"ssm_conv": "conv", "ssm_norm": "norm"}),
+    # heads of 96 x 192, laid out zero-padded to whole lane tiles once
+    ("olmohybrid-train-1chip-s8192", "GatedDeltaNet",
+     {"gdn_conv": "conv", "gdn_out_norm": "norm"})])
 def test_recurrent_mixer_layer_compiles_with_the_mixer_kernels_in_its_scopes(
         cell, module, scopes):
     """One recurrent mixer of each cell at the cell's configuration and
